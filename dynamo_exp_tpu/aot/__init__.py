@@ -21,16 +21,18 @@ tax:
   dispatch profiler's freshness state so steady-state compile-miss
   flatness holds from the very first dispatch.
 
-Operator surface: ``llmctl aot compile|list|warm|smoke``,
-``dynamo_exp_tpu.run --prewarm --compile-cache-dir``, and the
-``DYN_COMPILE_CACHE`` environment variable.
+Operator surface: ``llmctl aot compile|list|warm|smoke`` and
+``dynamo_exp_tpu.run --prewarm``. The cache directory is
+``$JAX_COMPILATION_CACHE_DIR`` when set, else ``--compile-cache-dir``,
+else ``<checkout>/.jax_cache`` (:func:`resolve_cache_dir`).
 """
 
 from .compile import (
     aot_compile,
-    cache_dir_from_env,
     enable_persistent_cache,
+    fresh_cache_dir,
     manifest_for_engine,
+    resolve_cache_dir,
 )
 from .lattice import (
     CompileManifest,
@@ -51,14 +53,15 @@ __all__ = [
     "RaggedVariant",
     "aot_compile",
     "build_manifest",
-    "cache_dir_from_env",
     "enable_persistent_cache",
+    "fresh_cache_dir",
     "manifest_for_engine",
     "mixed_token_buckets",
     "page_bound_buckets",
     "page_move_buckets",
     "prewarm_engine",
     "ragged_variants",
+    "resolve_cache_dir",
     "resolve_ragged_key",
     "windowed_token_buckets",
 ]

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import logging
 import os
+import shutil
 import time
 from dataclasses import dataclass, field
 
@@ -29,50 +30,67 @@ from .lattice import CompileManifest, build_manifest
 
 log = logging.getLogger(__name__)
 
-# Environment override for the persistent-cache directory; the
-# ``--compile-cache-dir`` flags on run.py / llmctl aot / bench.py win.
-CACHE_ENV = "DYN_COMPILE_CACHE"
+# JAX's own variable: set from outside (the chip tool, a deployment), it
+# IS the cache directory and this program never points JAX elsewhere.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# Unset, the cache lives at one fixed path in the checkout: the path is
+# part of what makes a later run find an earlier run's executables, so
+# it must never be derived from a pid, a clock or a temp name.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 MANIFEST_FILENAME = "manifest.json"
 
 
-def cache_dir_from_env() -> str:
+def _placed_from_outside() -> str:
     return os.environ.get(CACHE_ENV, "").strip()
 
 
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (created
-    if missing) and drop the min-compile-time / min-entry-size gates so
-    even small variants serialize. Returns False (and runs uncached)
-    when this jax build doesn't support the options."""
+def resolve_cache_dir(requested: str = "") -> str:
+    """THE compile-cache directory of this process: ``$JAX_COMPILATION_
+    CACHE_DIR`` when set (``requested`` is then ignored), else
+    ``requested`` (a ``--compile-cache-dir`` flag), else the fixed
+    in-checkout default."""
+    return _placed_from_outside() or requested or DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache(requested: str = "") -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`resolve_cache_dir` (created if missing) and drop the
+    min-compile-time / min-entry-size gates so even small variants
+    serialize. Returns the directory in use."""
     import jax
+    from jax._src.compilation_cache import reset_cache
 
-    if not cache_dir:
-        return False
+    cache_dir = resolve_cache_dir(requested)
     os.makedirs(cache_dir, exist_ok=True)
-    try:
+    if not _placed_from_outside():
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:  # unknown option on this jax version
-        log.warning("persistent compilation cache unsupported; running uncached")
-        return False
-    for opt, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except Exception:  # older jax: keep its default gate
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # The cache object is memoized at first use: a process that already
-    # compiled anything (engine construction jits device_puts) latched a
-    # disabled cache and would silently ignore the new directory — reset
-    # so the updated config is actually read.
-    try:
-        from jax._src.compilation_cache import reset_cache
+    # compiled anything (engine construction jits device_puts) latched
+    # its state and would ignore the options above — reset so the
+    # updated config is actually read.
+    reset_cache()
+    return cache_dir
 
-        reset_cache()
-    except Exception:  # private seam moved: cache may already be live
-        pass
-    return True
+
+def fresh_cache_dir(name: str) -> str:
+    """An EMPTY cache for a cold-vs-warm comparison: the fixed
+    sub-directory ``<default>/<name>``, emptied now. Cold arms need a
+    cache with nothing in it, not one that moves. Refused while
+    ``$JAX_COMPILATION_CACHE_DIR`` pins the process's cache — that
+    directory is shared and is neither emptied nor left."""
+    if _placed_from_outside():
+        raise SystemExit(
+            f"{name}: a cold arm needs an empty compile cache, and "
+            f"${CACHE_ENV} pins this process to a shared one; unset it"
+        )
+    path = os.path.join(DEFAULT_CACHE_DIR, name)
+    shutil.rmtree(path, ignore_errors=True)
+    return enable_persistent_cache(path)
 
 
 def manifest_for_engine(engine, **kwargs) -> CompileManifest:
@@ -116,17 +134,16 @@ def aot_compile(
     """AOT-lower and compile every manifest entry through the engine's
     own program builders. Pure compilation: nothing executes, no
     donated buffer is consumed, the engine's ``_ragged_fns`` cache ends
-    up populated with the (still-unexecuted) jit wrappers. With
-    ``cache_dir`` (or ``$DYN_COMPILE_CACHE``) set, every executable is
-    also serialized for other processes; the manifest JSON is dropped
-    next to the cache entries for ``llmctl aot list`` and hash checks."""
+    up populated with the (still-unexecuted) jit wrappers. Every
+    executable is also serialized to the persistent cache
+    (:func:`resolve_cache_dir`; ``cache_dir`` is the requested path)
+    for other processes; the manifest JSON is dropped next to the cache
+    entries for ``llmctl aot list`` and hash checks."""
     import jax.numpy as jnp
 
     from .warmup import variant_call_args
 
-    cache_dir = cache_dir or cache_dir_from_env()
-    if cache_dir:
-        enable_persistent_cache(cache_dir)
+    cache_dir = enable_persistent_cache(cache_dir)
     if manifest is None:
         manifest = manifest_for_engine(engine)
     t0 = time.monotonic()  # dynlint: determinism(prewarm wall-clock metric)
@@ -172,8 +189,7 @@ def aot_compile(
         log.exception("AOT compile failed for cow/init_row")
         report.failed.append(f"cow/init_row: {e}")
     report.seconds = time.monotonic() - t0  # dynlint: determinism(prewarm wall-clock metric)
-    if cache_dir:
-        write_manifest(cache_dir, manifest)
+    write_manifest(cache_dir, manifest)
     log.info(
         "aot: compiled %d variants in %.2fs (manifest %s)%s",
         report.compiled, report.seconds, report.manifest_hash[:12],

@@ -48,18 +48,27 @@ def resolve_ragged_key(
     live dispatch share it verbatim.
 
     ``attn_impl`` is the engine's *resolved* implementation
-    (``TPUEngine._attn_impl``). Two wrinkles live here: short contexts
-    (<= ~1k tokens of page bucket) take the XLA gather even when the
-    Pallas kernel is available (its serial per-row DMA grid costs more
-    than the trivial gather saves) — but only under ``auto``, an
-    explicit ``pallas`` is honored; and on the Pallas path the page
-    bound vanishes from the key entirely (the kernel DMAs true
-    lengths), which is what deletes the page axis from the TPU
-    lattice."""
+    (``TPUEngine._attn_impl``). Two wrinkles live here: in a
+    pure-decode window, short contexts (<= ~1k tokens of page bucket)
+    take the XLA gather even when the Pallas kernel is available (its
+    serial per-row DMA grid costs more than the trivial gather saves)
+    — but only under ``auto``, an explicit ``pallas`` is honored; and
+    on the Pallas path the page bound vanishes from the key entirely
+    (the kernel DMAs true lengths), which is what deletes the page
+    axis from the TPU lattice.
+
+    A mixed dispatch never takes the gather on an engine that has the
+    kernel: the reference gathers the whole page bucket once per QUERY
+    TOKEN (N x S), which is trivial for a window's <= max_decode_slots
+    rows and ruinous for a prefill batch. At llama-1b widths on a v5e
+    (compile-only, CHANGES.md PR 21) its temporaries are 9 GB at
+    N=2048, and XLA:TPU refuses to compile N >= 4096 in 16 GB of HBM —
+    a burst of eight 512-token prompts reaches that bucket."""
     impl = attn_impl
     if (
         impl == "pallas"
         and cfg.attention_impl == "auto"
+        and windowed
         and attn_pages * cfg.page_size <= 1024
     ):
         impl = "xla"

@@ -243,22 +243,25 @@ class TPUEngine(AsyncEngine):
         def sharding(spec):
             return NamedSharding(self.mesh, spec)
 
+        # Parameters and pools are born sharded: random weights come
+        # out of one jit whose out_shardings are the engine's, loaded
+        # weights are host arrays put straight to them. A model larger
+        # than one chip never passes through one chip.
+        shardings = jax.tree.map(
+            sharding,
+            param_shardings(mcfg),
+            is_leaf=lambda x: isinstance(x, P),
+        )
         if params is None:
-            params = init_params(jax.random.PRNGKey(seed), mcfg)
-        self.params = jax.device_put(
-            params,
-            jax.tree.map(
-                sharding,
-                param_shardings(mcfg),
-                is_leaf=lambda x: isinstance(x, P),
-            ),
+            self.params = jax.jit(
+                init_params, static_argnums=1, out_shardings=shardings
+            )(jax.random.PRNGKey(seed), mcfg)
+        else:
+            self.params = jax.device_put(params, shardings)
+        self.k_cache, self.v_cache = init_kv_cache(
+            mcfg, cfg.num_pages, cfg.page_size, dtype=cfg.kv_dtype_jnp,
+            shardings=tuple(sharding(s) for s in kv_cache_shardings()),
         )
-        kspec, vspec = kv_cache_shardings()
-        k, v = init_kv_cache(
-            mcfg, cfg.num_pages, cfg.page_size, dtype=cfg.kv_dtype_jnp
-        )
-        self.k_cache = jax.device_put(k, sharding(kspec))
-        self.v_cache = jax.device_put(v, sharding(vspec))
 
         self.host_pool: HostKvPool | None = None
         self.copy_stream: CopyStream | None = None
@@ -408,6 +411,11 @@ class TPUEngine(AsyncEngine):
         self._wake = threading.Event()
         self._running = False
         self._thread: threading.Thread | None = None
+        # Sticky: the loop thread died on an exception at least once.
+        # In-flight requests were failed and a later generate() may
+        # restart the loop, but the process is no longer trustworthy —
+        # /health reports it and run.py exits non-zero.
+        self.loop_crashed = False
         self.steps = 0  # decode step counter (metrics)
         # Warm-boot provisioning (docs/aot.md): variants loaded/built by
         # prewarm() and the boot time it took. 0/0.0 = cold boot.
@@ -533,6 +541,22 @@ class TPUEngine(AsyncEngine):
         # traffic, and a scrape must see the fingerprint from boot.
         self._build_info = self._compute_build_info()
         get_telemetry().set_build_info(**self._build_info)
+        # Say where it ran, once: what a chip run reads instead of
+        # trusting the command line. The peak covers construction, so a
+        # weight or pool that passed through one device shows here.
+        log.info(
+            "engine placed: platform=%s device_kind=%s device_count=%d "
+            "device_ids=%s attn_impl=%s peak_bytes_in_use=%s",
+            self._build_info["platform"],
+            self._build_info["device_kind"],
+            self._build_info["device_count"],
+            [d.id for d in self.mesh.devices.flat],
+            self._build_info["attn_impl"],
+            [
+                (d.memory_stats() or {}).get("peak_bytes_in_use")
+                for d in self.mesh.devices.flat
+            ],
+        )
 
     # ----------------------------------------------------------- compiled fns
     def _resolve_attn(self) -> tuple[str, bool]:
@@ -580,11 +604,13 @@ class TPUEngine(AsyncEngine):
         positions (plus the spec span when speculation is on) reach the
         vocab projection, so lm_head cost stays flat in chunk width.
 
-        Even when the Pallas kernel is available, short contexts take
-        the XLA gather: below ~1k tokens of page bucket the gather's
-        HBM traffic is trivial and the kernel's serial per-row DMA grid
-        costs more than it saves. That rule (and the Pallas page-bound
-        collapse) lives in ``aot.lattice.resolve_ragged_key`` — ONE key
+        Even when the Pallas kernel is available, a decode window over
+        short contexts takes the XLA gather: below ~1k tokens of page
+        bucket the gather's HBM traffic is trivial and the kernel's
+        serial per-row DMA grid costs more than it saves (a mixed
+        dispatch never does — the gather is per query token). That rule
+        (and the Pallas page-bound collapse) lives in
+        ``aot.lattice.resolve_ragged_key`` — ONE key
         function shared with the offline lattice enumeration, so the
         AOT manifest can never drift from what this loop dispatches."""
         key = resolve_ragged_key(
@@ -602,6 +628,15 @@ class TPUEngine(AsyncEngine):
             return fn
         nb, pages, windowed, full_sampler, want_lp, with_spec = key
         impl = impl_for_key(key)
+        # Once per variant, not per dispatch: which attention each
+        # program was really built with (a decode window over short
+        # contexts takes the XLA gather even on an engine that resolved
+        # to the kernel).
+        log.info(
+            "ragged variant: nb=%d pages=%s windowed=%s full_sampler=%s "
+            "want_lp=%s with_spec=%s attn=%s",
+            nb, pages, windowed, full_sampler, want_lp, with_spec, impl,
+        )
         fn = (
             self._windowed_program(nb, pages, impl, full_sampler, want_lp)
             if windowed
@@ -979,7 +1014,7 @@ class TPUEngine(AsyncEngine):
             if self.kv.lease_active(job["lease"]):
                 self.kv.confirm_lease(job["lease"])
 
-    def prewarm(self, manifest=None, cache_dir: str = ""):
+    def prewarm(self, manifest=None):
         """Warm-boot provisioning (docs/aot.md): compile/load every
         compile-lattice variant BEFORE the engine accepts traffic, so
         the first dispatch of every shape is steady-state fast and the
@@ -991,23 +1026,20 @@ class TPUEngine(AsyncEngine):
         all-padding batch, threading the donated KV pools through, then
         seeds the dispatch profiler's variant-freshness state so a
         prewarmed kernel's first traffic dispatch is never mis-charged
-        as a cold compile. With ``cache_dir`` (or ``$DYN_COMPILE_CACHE``)
-        naming a populated persistent compilation cache, the compiles
-        are deserializations and a boot collapses to program-load time.
+        as a cold compile. With a populated persistent compilation
+        cache (the entry point turns it on before building the engine:
+        ``aot.enable_persistent_cache``), the compiles are
+        deserializations and a boot collapses to program-load time.
 
         ``manifest`` defaults to this engine's own full lattice.
         Returns the :class:`~dynamo_exp_tpu.aot.warmup.PrewarmReport`.
         """
-        from ..aot.compile import cache_dir_from_env, enable_persistent_cache
         from ..aot.warmup import prewarm_engine
 
         if self._running:
             raise RuntimeError(
                 "prewarm() must run before the engine accepts traffic"
             )
-        cache_dir = cache_dir or cache_dir_from_env()
-        if cache_dir:
-            enable_persistent_cache(cache_dir)
         report = prewarm_engine(self, manifest)
         self.prewarmed_variants = report.variants
         self.prewarm_seconds = report.seconds
@@ -1635,6 +1667,7 @@ class TPUEngine(AsyncEngine):
                     self._wake.clear()
         except Exception:  # engine death must not hang clients
             log.exception("engine loop crashed; failing in-flight requests")
+            self.loop_crashed = True
             self._dump_flight("crash")
             self._running = False
             self._inflight = None
@@ -1981,9 +2014,12 @@ class TPUEngine(AsyncEngine):
     def _compute_build_info(self) -> dict:
         """Config-skew fingerprint for fleet scrapes: the AOT lattice
         manifest hash (the compile-identity of this engine shape,
-        docs/aot.md), the jax version, and the feature flags that change
-        serving behavior. Mirrored as the dynamo_build_info gauge and
-        the ``build_info`` metrics() key."""
+        docs/aot.md), the jax version, the feature flags that change
+        serving behavior, and where the engine runs — platform,
+        device kind, devices in its mesh, and the RESOLVED attention
+        implementation (``pallas`` / ``pallas-interpret`` / ``xla``).
+        Mirrored as the dynamo_build_info gauge and the ``build_info``
+        metrics() key."""
         try:
             from ..aot.compile import manifest_for_engine
 
@@ -1991,11 +2027,20 @@ class TPUEngine(AsyncEngine):
         except Exception:  # noqa: BLE001 - fingerprint is best-effort
             log.warning("build-info manifest hash failed", exc_info=True)
             manifest_hash = ""
+        dev = self.mesh.devices.flat[0]
         return {
             "manifest_hash": manifest_hash,
             "jax_version": jax.__version__,
             "prefix_sharing": bool(self.cfg.prefix_sharing),
             "spec": self.cfg.spec_mode,
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": int(self.mesh.devices.size),
+            "attn_impl": (
+                "pallas-interpret"
+                if self._attn_impl == "pallas" and self._attn_interpret
+                else self._attn_impl
+            ),
         }
 
     def _drain_submissions(self) -> None:

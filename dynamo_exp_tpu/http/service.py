@@ -84,8 +84,13 @@ class HttpService:
         request_template=None,
         admission: AdmissionController | None = None,
         slo=None,
+        unhealthy=None,
     ):
         self.manager = manager or ModelManager()
+        # ``unhealthy() -> str | None``: why the engine behind this
+        # edge can no longer be trusted (run.py: its loop thread died),
+        # or None. /health answers 503 with the reason.
+        self.unhealthy = unhealthy
         self.metrics = metrics or ServiceMetrics()
         self.host = host
         self.port = port
@@ -132,6 +137,11 @@ class HttpService:
 
     # --- handlers -----------------------------------------------------
     async def _health(self, request: web.Request) -> web.Response:
+        reason = self.unhealthy() if self.unhealthy is not None else None
+        if reason:
+            return web.json_response(
+                {"status": "unhealthy", "reason": reason}, status=503
+            )
         return web.json_response(
             {"status": "healthy", "models": self.manager.model_names()}
         )

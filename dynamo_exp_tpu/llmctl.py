@@ -347,10 +347,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
 
     # Offline bench regression comparator (docs/observability.md "Fleet
-    # plane"): compare two bench captures (raw bench.py JSONL or the
-    # checked-in BENCH_r*.json wrappers) and flag >threshold tok/s or
-    # TTFT/ITL regressions per metric, platform-tag aware. The
-    # pre-merge CI step runs it over the checked-in trajectory.
+    # plane"): compare two bench captures (raw bench.py JSONL or a
+    # driver's BENCH_r*.json wrappers) and flag >threshold tok/s or
+    # TTFT/ITL regressions per metric, platform-tag aware.
     bench = sub.add_parser(
         "bench", help="bench trajectory tools (offline)"
     )
@@ -484,14 +483,16 @@ def build_parser() -> argparse.ArgumentParser:
         "command", choices=("compile", "list", "warm", "smoke"),
         help="compile: AOT-compile every manifest entry into the cache "
         "dir; list: print the manifest (no compilation); warm: boot an "
-        "engine via prewarm and report; smoke: boot twice against a "
-        "tmp cache dir and fail on any second-boot compile miss",
+        "engine via prewarm and report; smoke: boot twice against an "
+        "emptied cache dir and fail on any second-boot compile miss",
     )
     aot.add_argument("--preset", default="tiny",
                      help="built-in model preset (random weights)")
     aot.add_argument("--compile-cache-dir", default="",
-                     help="persistent compilation cache directory "
-                     "(default: $DYN_COMPILE_CACHE; smoke uses a tmp dir)")
+                     help="persistent compilation cache directory; "
+                     "ignored when $JAX_COMPILATION_CACHE_DIR is set "
+                     "(default: <checkout>/.jax_cache; smoke empties "
+                     "and uses its aot-smoke/ sub-directory)")
     aot.add_argument("--tp", type=int, default=1)
     aot.add_argument("--max-decode-slots", type=int, default=4)
     aot.add_argument("--page-size", type=int, default=16)
@@ -886,13 +887,12 @@ async def run_aot(args) -> int:
     warm-boot the engine compile lattice. ``list`` is weight-free; the
     other commands build a random-weight engine of the given shape."""
     import os
-    import tempfile
 
     from .aot import (
         aot_compile,
         build_manifest,
-        cache_dir_from_env,
         enable_persistent_cache,
+        fresh_cache_dir,
         manifest_for_engine,
     )
     from .engine import EngineConfig, TPUEngine, resolve_attn_impl
@@ -915,7 +915,6 @@ async def run_aot(args) -> int:
         prefill_chunk=args.prefill_chunk,
         spec_mode=args.spec,
     )
-    cache_dir = args.compile_cache_dir or cache_dir_from_env()
     include_lp = not args.no_lp
 
     if args.command == "list":
@@ -937,9 +936,6 @@ async def run_aot(args) -> int:
         )
         return 0
 
-    if cache_dir:
-        enable_persistent_cache(cache_dir)
-
     async def traffic(engine, n: int = 2, prompt_len: int = 24) -> None:
         """A tiny mixed probe burst (greedy + seeded rows)."""
 
@@ -959,14 +955,17 @@ async def run_aot(args) -> int:
     if args.command == "compile":
         engine = TPUEngine(cfg, seed=0)
         manifest = manifest_for_engine(engine, include_lp=include_lp)
-        report = aot_compile(engine, manifest, cache_dir=cache_dir)
+        report = aot_compile(
+            engine, manifest, cache_dir=args.compile_cache_dir
+        )
         print(json.dumps(report.to_dict(), indent=2))
         return 1 if report.failed else 0
 
     if args.command == "warm":
+        enable_persistent_cache(args.compile_cache_dir)
         engine = TPUEngine(cfg, seed=0)
         manifest = manifest_for_engine(engine, include_lp=include_lp)
-        report = engine.prewarm(manifest, cache_dir=cache_dir)
+        report = engine.prewarm(manifest)
         await traffic(engine)
         m = engine.metrics()
         print(
@@ -988,19 +987,14 @@ async def run_aot(args) -> int:
 
     # smoke: two warm boots against one cache dir; the second must
     # compile nothing — no ragged misses, no variant growth, no new
-    # cache entries (the pre-merge `make prewarm-smoke` gate). Always a
-    # FRESH tmp dir (the help text's promise): running against a shared
-    # $DYN_COMPILE_CACHE would skip the population half of the test and
-    # write probe entries into a production cache.
-    cache_dir = tempfile.mkdtemp(prefix="dynamo_aot_smoke_")
-    enable_persistent_cache(cache_dir)
+    # cache entries (the pre-merge `make prewarm-smoke` gate). Always
+    # an EMPTY cache at a fixed path: a shared cache would skip the
+    # population half of the test, and a moving one never hits.
+    cache_dir = fresh_cache_dir("aot-smoke")
 
     async def boot() -> tuple[dict, int]:
         engine = TPUEngine(cfg, seed=0)
-        engine.prewarm(
-            manifest_for_engine(engine, include_lp=include_lp),
-            cache_dir=cache_dir,
-        )
+        engine.prewarm(manifest_for_engine(engine, include_lp=include_lp))
         await traffic(engine)
         m = engine.metrics()
         engine.stop()

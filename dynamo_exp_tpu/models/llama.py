@@ -169,13 +169,17 @@ def kv_cache_shardings(tp_axis: str = "tp") -> tuple[P, P]:
 
 
 def init_kv_cache(
-    cfg: ModelConfig, num_pages: int, page_size: int, dtype=None
+    cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
+    shardings=(None, None),
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Allocate the paged KV pools: each [L, num_pages, ps, Hkv*D].
 
     (kv head, head_dim) live collapsed in the trailing dim: TPU tiling
     pads the last dim to 128 lanes, so a bare D=64 axis would double
     every pool's HBM footprint; Hkv*D is 128-aligned for real configs.
+
+    ``shardings`` (K, V) creates each pool already sharded, so no
+    device ever holds more than its own shard.
     """
     dt = dtype or _dtype(cfg)
     shape = (
@@ -184,7 +188,10 @@ def init_kv_cache(
         page_size,
         cfg.num_kv_heads * cfg.head_dim_,
     )
-    return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+    return (
+        jnp.zeros(shape, dt, device=shardings[0]),
+        jnp.zeros(shape, dt, device=shardings[1]),
+    )
 
 
 def rms_norm(

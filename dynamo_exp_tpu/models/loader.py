@@ -12,7 +12,6 @@ from __future__ import annotations
 import json
 import os
 
-import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig
@@ -165,14 +164,18 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
             layers["w_up"].append(linear(p + "mlp.up_proj.weight"))
             layers["w_down"].append(linear(p + "mlp.down_proj.weight"))
 
+    def host(x) -> np.ndarray:
+        # Host arrays, not device arrays: the engine device_puts each
+        # tensor straight to its NamedSharding, so a checkpoint larger
+        # than one chip never passes through one chip.
+        return np.asarray(x).astype(dt)
+
     params: Params = {
-        "embed": jnp.asarray(get(pre + "embed_tokens.weight"), dt),
-        "layers": {
-            k: jnp.asarray(np.stack(v), dt) for k, v in layers.items()
-        },
-        "final_norm": jnp.asarray(get(pre + "norm.weight"), dt),
+        "embed": host(get(pre + "embed_tokens.weight")),
+        "layers": {k: host(np.stack(v)) for k, v in layers.items()},
+        "final_norm": host(get(pre + "norm.weight")),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = jnp.asarray(linear("lm_head.weight"), dt)
+        params["lm_head"] = host(linear("lm_head.weight"))
     handles.clear()  # drop mmap handles now rather than at caller GC
     return params, cfg

@@ -1,0 +1,158 @@
+"""Write a self-contained HF-style model directory from a seed.
+
+A machine with no network and no checkout history still has to serve a
+model through the normal path: ``--model-path <dir> --random-weights``
+needs a ``config.json`` and a tokenizer. This module generates both —
+never weights — at any :class:`ModelConfig`'s widths:
+
+- ``config.json`` in HF spelling, checked to parse back to the same
+  ``ModelConfig`` (so a family this writer does not cover fails here
+  instead of serving a different model under its name);
+- a byte-level BPE ``tokenizer.json`` whose vocabulary has exactly
+  ``vocab_size`` entries. Random weights sample any id below
+  ``vocab_size``, so every id must decode or the detokenizer path is
+  not being exercised;
+- ``tokenizer_config.json`` with the chat template the tests use.
+
+    python -m dynamo_exp_tpu.models.synth --preset llama-1b --out DIR
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import random
+
+from .config import PRESETS, ModelConfig
+
+CHAT_TEMPLATE = (
+    "{% for message in messages %}"
+    "<|{{ message.role }}|>{{ message.content }}</s>"
+    "{% endfor %}"
+    "{% if add_generation_prompt %}<|assistant|>{% endif %}"
+)
+SPECIAL_TOKENS = ["<s>", "</s>", "<|user|>", "<|assistant|>", "<|system|>"]
+BOS_ID, EOS_ID = 0, 1  # positions in SPECIAL_TOKENS
+# A merged token never grows past this many byte symbols, so decoded
+# text stays short however deep the merge chain.
+_MAX_TOKEN_SYMBOLS = 12
+
+
+def write_config(path: str, cfg: ModelConfig) -> None:
+    """``config.json`` in HF spelling for a llama-family ``cfg``."""
+    hf = {
+        "architectures": ["LlamaForCausalLM"],
+        "model_type": cfg.model_type,
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "intermediate_size": cfg.intermediate_size,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "rope_theta": cfg.rope_theta,
+        "bos_token_id": BOS_ID,
+        "eos_token_id": EOS_ID,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "torch_dtype": cfg.dtype,
+        # Read only by MoE families, but its parsed default differs
+        # from the dataclass default: spell it so the round trip holds.
+        "norm_topk_prob": cfg.norm_topk_prob,
+    }
+    if cfg.head_dim is not None:
+        hf["head_dim"] = cfg.head_dim
+    if cfg.rope_scaling is not None:
+        hf["rope_scaling"] = cfg.rope_scaling
+    if ModelConfig.from_hf_config(hf) != cfg:
+        raise ValueError(
+            f"config.json written for model_type={cfg.model_type!r} does "
+            "not parse back to the same ModelConfig; this writer covers "
+            "the llama family only"
+        )
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(hf, f)
+
+
+def write_tokenizer_config(path: str) -> None:
+    with open(os.path.join(path, "tokenizer_config.json"), "w") as f:
+        json.dump(
+            {
+                "bos_token": SPECIAL_TOKENS[BOS_ID],
+                "eos_token": SPECIAL_TOKENS[EOS_ID],
+                "chat_template": CHAT_TEMPLATE,
+            },
+            f,
+        )
+
+
+def write_full_vocab_tokenizer(path: str, vocab_size: int, seed: int) -> None:
+    """Byte-level BPE with exactly ``vocab_size`` ids, from ``seed``:
+    the special tokens, the 256 byte symbols, then synthetic merges —
+    each new token is an existing token plus one byte symbol, which is
+    all BPE needs for the merge table to be consistent."""
+    from tokenizers import (
+        AddedToken,
+        Tokenizer,
+        decoders,
+        models,
+        pre_tokenizers,
+    )
+
+    alphabet = sorted(pre_tokenizers.ByteLevel.alphabet())
+    n_merges = vocab_size - len(SPECIAL_TOKENS) - len(alphabet)
+    if n_merges < 0:
+        raise ValueError(
+            f"vocab_size {vocab_size} is smaller than the special tokens "
+            f"plus the byte alphabet ({len(SPECIAL_TOKENS) + len(alphabet)})"
+        )
+    rng = random.Random(seed)
+    vocab = {tok: i for i, tok in enumerate(SPECIAL_TOKENS)}
+    for sym in alphabet:
+        vocab[sym] = len(vocab)
+    growable = list(alphabet)  # tokens still short enough to extend
+    merges: list[tuple[str, str]] = []
+    while len(merges) < n_merges:
+        left, right = rng.choice(growable), rng.choice(alphabet)
+        token = left + right
+        if token in vocab:
+            continue
+        vocab[token] = len(vocab)
+        merges.append((left, right))
+        if len(token) < _MAX_TOKEN_SYMBOLS:
+            growable.append(token)
+    tok = Tokenizer(models.BPE(vocab=vocab, merges=merges, unk_token=None))
+    tok.pre_tokenizer = pre_tokenizers.ByteLevel(add_prefix_space=False)
+    tok.decoder = decoders.ByteLevel()
+    tok.add_special_tokens(
+        [AddedToken(t, special=True) for t in SPECIAL_TOKENS]
+    )
+    if tok.get_vocab_size() != vocab_size:
+        raise RuntimeError(
+            f"tokenizer has {tok.get_vocab_size()} ids, wanted {vocab_size}"
+        )
+    tok.save(os.path.join(path, "tokenizer.json"))
+
+
+def write_model_dir(path: str, cfg: ModelConfig, seed: int = 0) -> str:
+    """Config + full-vocabulary tokenizer + chat template; no weights."""
+    os.makedirs(path, exist_ok=True)
+    write_full_vocab_tokenizer(path, cfg.vocab_size, seed)
+    write_config(path, cfg)
+    write_tokenizer_config(path)
+    return path
+
+
+def main(argv: list[str] | None = None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--preset", required=True, choices=sorted(PRESETS))
+    ap.add_argument("--out", required=True, help="directory to write")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+    write_model_dir(args.out, PRESETS[args.preset], args.seed)
+    print(args.out)
+
+
+if __name__ == "__main__":
+    main()

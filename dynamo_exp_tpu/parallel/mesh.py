@@ -18,26 +18,8 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_HAS_VMA = "check_vma" in _inspect.signature(_shard_map).parameters
-
-
-def shard_map(*args, **kwargs):
-    """Version-portable ``shard_map``: callers use the current
-    ``check_vma`` spelling; on older jax (where the kwarg is
-    ``check_rep``) it is translated."""
-    if not _SHARD_MAP_HAS_VMA and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(*args, **kwargs)
-
 
 __all__ = [
     "build_mesh",

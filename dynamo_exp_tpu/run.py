@@ -89,10 +89,10 @@ def parse_args(argv: list[str]):
     p.add_argument("--prewarm", action="store_true",
                    help="prewarm the engine's compile lattice before "
                         "serving (docs/aot.md warm boot)")
-    p.add_argument("--compile-cache-dir",
-                   default=os.environ.get("DYN_COMPILE_CACHE", ""),
-                   help="JAX persistent compilation cache directory "
-                        "(default: $DYN_COMPILE_CACHE; empty = uncached)")
+    p.add_argument("--compile-cache-dir", default="",
+                   help="JAX persistent compilation cache directory; "
+                        "ignored when $JAX_COMPILATION_CACHE_DIR is set "
+                        "(default: <checkout>/.jax_cache)")
     p.add_argument("--echo-token-delay-ms", type=float, default=0.0)
     p.add_argument("--request-template", default="",
                    help="JSON file of request defaults (model/temperature/"
@@ -246,18 +246,17 @@ def build_tpu_engine(opts):
         spec_max_draft=getattr(opts, "spec_max_draft", 8),
         spec_ngram=getattr(opts, "spec_ngram", 3),
     )
-    cache_dir = getattr(opts, "compile_cache_dir", "")
-    if cache_dir:
-        from .aot import enable_persistent_cache
+    from .aot import enable_persistent_cache
 
-        enable_persistent_cache(cache_dir)
+    cache_dir = enable_persistent_cache(getattr(opts, "compile_cache_dir", ""))
+    logger.info("compile cache: %s", cache_dir)
     engine = TPUEngine(ecfg, params=params)
     if getattr(opts, "prewarm", False):
         # Warm boot (docs/aot.md): the lattice compiles/loads NOW, not
         # under first traffic — with a populated cache this is seconds,
         # and the compile-miss counters stay flat from the first
         # dispatch.
-        report = engine.prewarm(cache_dir=cache_dir)
+        report = engine.prewarm()
         logger.info(
             "prewarmed %d variants in %.2fs (manifest %s)",
             report.variants, report.seconds, report.manifest_hash[:12],
@@ -338,7 +337,10 @@ async def resolve_openai_engine(opts, drt, core, full, mdc):
 
 
 # -------------------------------------------------------------------- inputs
-async def run_http(opts, drt, core, full, mdc):
+ENGINE_LOOP_CRASHED = "engine loop crashed"
+
+
+async def run_http(opts, drt, core, full, mdc, tpu_engine=None):
     """OpenAI ingress (reference: input/http.rs + http/service)."""
     from .http import HttpService
     from .http.discovery import ModelWatcher
@@ -373,6 +375,11 @@ async def run_http(opts, drt, core, full, mdc):
         request_template=template,
         admission=admission,
         slo=slo,
+        unhealthy=(
+            (lambda: ENGINE_LOOP_CRASHED if tpu_engine.loop_crashed else None)
+            if tpu_engine is not None
+            else None
+        ),
     )
     watcher = None
     kv_router = None
@@ -717,7 +724,7 @@ async def main_async(opts) -> None:
     core, full, mdc, tpu_engine = build_output(opts, drt)
     try:
         if opts.input == "http":
-            await run_http(opts, drt, core, full, mdc)
+            await run_http(opts, drt, core, full, mdc, tpu_engine)
             return
         if opts.input.startswith("dyn://"):
             if core is None:
@@ -751,6 +758,10 @@ async def main_async(opts) -> None:
         except asyncio.TimeoutError:
             logger.warning("runtime close timed out after 15s")
         logger.info("runtime closed in %.2fs", time.monotonic() - t0)
+        if tpu_engine is not None and tpu_engine.loop_crashed:
+            # A node whose engine loop died must not look like a clean
+            # shutdown, whatever ended it (SIGTERM included).
+            raise SystemExit(f"{ENGINE_LOOP_CRASHED}; exiting non-zero")
 
 
 def main(argv: list[str] | None = None) -> None:

@@ -2,17 +2,37 @@
 
 Reference parity: ``deploy/dynamo/sdk/cli/allocator.py:53-120``
 (``ResourceAllocator.assign_gpus`` → ``CUDA_VISIBLE_DEVICES`` per
-watcher). TPU equivalent: disjoint chip sets per service process via
-``TPU_VISIBLE_CHIPS`` (libtpu) — also exported as
-``TPU_VISIBLE_DEVICES`` for older runtimes. A service asks with
-``resources={"tpu": n}``; services with no tpu request get no chips and
-the TPU runtime is told to stay off (``JAX_PLATFORMS=cpu``), so
-frontends/routers never grab the accelerator.
+watcher). A chip belongs to one process at a time, so every worker
+process of a host gets its own chip through libtpu's environment:
+``TPU_VISIBLE_CHIPS`` names the chip, and the per-process bounds tell
+libtpu the process is a whole one-chip slice of its own (without them
+each process still looks for the host's full topology). A service asks
+with ``resources={"tpu": n}``; only ``n == 1`` (a replica per chip) is
+established on the installed libtpu — a service that wants the whole
+host runs alone, unrestricted, with ``--tp``. Services with no tpu
+request are told to stay off the accelerator (``JAX_PLATFORMS=cpu``),
+so frontends/routers never grab a chip.
+
+Established on libtpu 0.0.34 / one v5litepod-4 host (CHANGES.md PR 21):
+``TPU_VISIBLE_CHIPS`` alone lets the first process in and fails the
+rest on libtpu's lock file; with both bounds, four processes hold four
+chips at once, each seeing its chip as device 0.
 """
 
 from __future__ import annotations
 
+import glob
 import os
+
+
+def host_chips() -> int:
+    """Chips this host has: ``$DYN_TPU_CHIPS`` when set, else the TPU
+    device nodes (a v5e host exposes one ``/dev/vfio/<n>`` per chip).
+    A host with neither has none — say ``--tpu-chips`` to pretend."""
+    env = os.environ.get("DYN_TPU_CHIPS", "").strip()
+    if env:
+        return int(env)
+    return len(glob.glob("/dev/vfio/[0-9]*"))
 
 
 class AllocationError(RuntimeError):
@@ -26,7 +46,7 @@ class TPUAllocator:
 
     def __init__(self, total_chips: int | None = None):
         if total_chips is None:
-            total_chips = int(os.environ.get("DYN_TPU_CHIPS", "4"))
+            total_chips = host_chips()
         self.total_chips = total_chips
         self._free = set(range(total_chips))
 
@@ -39,18 +59,26 @@ class TPUAllocator:
         if chips <= 0:
             # Host-side service: keep JAX off the TPU entirely.
             return {"JAX_PLATFORMS": "cpu"}
-        if chips > len(self._free):
+        if chips != 1:
             raise AllocationError(
-                f"{service_name} wants {chips} TPU chips but only "
-                f"{len(self._free)} of {self.total_chips} remain"
+                f"{service_name} wants {chips} TPU chips in one worker; "
+                "only one chip per worker process is supported here"
             )
-        ids = sorted(self._free)[:chips]
-        self._free -= set(ids)
-        joined = ",".join(str(i) for i in ids)
-        return {"TPU_VISIBLE_CHIPS": joined, "TPU_VISIBLE_DEVICES": joined}
+        if not self._free:
+            raise AllocationError(
+                f"{service_name} wants a TPU chip but none of "
+                f"{self.total_chips} remain"
+            )
+        chip = min(self._free)
+        self._free.discard(chip)
+        return {
+            "TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
 
     def release(self, env: dict[str, str]) -> None:
-        """Return a worker's chips (from its assign() env) to the pool."""
-        ids = env.get("TPU_VISIBLE_CHIPS", "")
-        if ids:
-            self._free |= {int(i) for i in ids.split(",")}
+        """Return a worker's chip (from its assign() env) to the pool."""
+        chip = env.get("TPU_VISIBLE_CHIPS", "")
+        if chip:
+            self._free.add(int(chip))

@@ -46,7 +46,13 @@ class Watcher:
         self.proc = await asyncio.create_subprocess_exec(
             *self.argv, env={**os.environ, **self.env}
         )
-        logger.info("started %s (pid %d)", self.name, self.proc.pid)
+        placement = " ".join(
+            f"{k}={v}" for k, v in sorted(self.env.items())
+            if k.startswith("TPU_") or k == "JAX_PLATFORMS"
+        )
+        logger.info(
+            "started %s (pid %d) %s", self.name, self.proc.pid, placement
+        )
 
     async def supervise(self) -> None:
         while not self.stopping:
@@ -308,7 +314,8 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument("--coordinator-port", type=int, default=0)
     p.add_argument("--service-name", default=None, help="run one service only")
     p.add_argument("--tpu-chips", type=int, default=None,
-                   help="host chip budget (default: env DYN_TPU_CHIPS or 4)")
+                   help="host chip budget (default: $DYN_TPU_CHIPS, else "
+                        "the TPU device nodes this host has)")
     p.add_argument("--num-nodes", type=int, default=1,
                    help="hosts in this service's TPU slice (multi-host)")
     p.add_argument("--node-rank", type=int, default=0)

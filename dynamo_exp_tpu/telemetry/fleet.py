@@ -36,6 +36,14 @@ from dataclasses import dataclass, field
 
 from .fingerprint import DRIFT_ALERT_THRESHOLD
 
+# Labels of ``dynamo_build_info`` (telemetry/spans.py), in order: what
+# an engine is (manifest, jax, feature flags) and where it runs. Skew
+# detection compares exactly these.
+BUILD_INFO_LABELS = (
+    "manifest_hash", "jax_version", "prefix_sharing", "spec",
+    "platform", "device_kind", "device_count", "attn_impl",
+)
+
 # EWMA weight for the per-link bandwidth estimate: new observations move
 # the estimate by this fraction, so a link's number settles within a
 # handful of transfers but one straggler doesn't erase the history.
@@ -344,10 +352,7 @@ class InstanceView:
     def fingerprint(self) -> str:
         """Comparable config identity for skew detection."""
         bi = self.build_info
-        return (
-            f"{bi.get('manifest_hash', '')}|{bi.get('jax_version', '')}"
-            f"|{bi.get('prefix_sharing', '')}|{bi.get('spec', '')}"
-        )
+        return "|".join(str(bi.get(k, "")) for k in BUILD_INFO_LABELS)
 
 
 @dataclass

@@ -32,6 +32,7 @@ from typing import Any
 from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram
 
 from .context import TraceContext, attach, current_trace, detach, new_trace
+from .fleet import BUILD_INFO_LABELS
 
 logger = logging.getLogger(__name__)
 
@@ -541,9 +542,11 @@ class Telemetry:
         self.build_info = Gauge(
             "dynamo_build_info",
             "Constant-1 config-skew fingerprint: AOT lattice manifest "
-            "hash, jax version, and serving feature flags — fleet "
-            "scrapes compare label sets across instances",
-            ["manifest_hash", "jax_version", "prefix_sharing", "spec"],
+            "hash, jax version, serving feature flags, and where the "
+            "engine runs (platform, device kind and count, resolved "
+            "attention implementation) — fleet scrapes compare label "
+            "sets across instances",
+            list(BUILD_INFO_LABELS),
             registry=self.registry,
         )
 
@@ -658,6 +661,10 @@ class Telemetry:
         jax_version: str = "",
         prefix_sharing: bool = False,
         spec: str = "off",
+        platform: str = "",
+        device_kind: str = "",
+        device_count: int = 0,
+        attn_impl: str = "",
     ) -> None:
         """Publish the constant-1 ``dynamo_build_info`` sample (clearing
         any previous label set, so one instance never exports two
@@ -668,6 +675,10 @@ class Telemetry:
             jax_version or "unknown",
             str(bool(prefix_sharing)).lower(),
             spec or "off",
+            platform or "unknown",
+            device_kind or "unknown",
+            str(device_count),
+            attn_impl or "unknown",
         ).set(1)
 
     def publish_engine_gauges(self, metrics: dict) -> None:

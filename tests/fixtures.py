@@ -3,14 +3,13 @@
 
 from __future__ import annotations
 
-import json
 import os
 
-CHAT_TEMPLATE = (
-    "{% for message in messages %}"
-    "<|{{ message.role }}|>{{ message.content }}</s>"
-    "{% endfor %}"
-    "{% if add_generation_prompt %}<|assistant|>{% endif %}"
+from dynamo_exp_tpu.models import ModelConfig
+from dynamo_exp_tpu.models.synth import (
+    SPECIAL_TOKENS,
+    write_config,
+    write_tokenizer_config,
 )
 
 TRAIN_TEXT = [
@@ -43,42 +42,24 @@ def build_tiny_model_dir(
         tok.decoder = decoders.ByteLevel()
         trainer = trainers.BpeTrainer(
             vocab_size=vocab_size,
-            special_tokens=["<s>", "</s>", "<|user|>", "<|assistant|>", "<|system|>"],
+            special_tokens=SPECIAL_TOKENS,
             initial_alphabet=pre_tokenizers.ByteLevel.alphabet(),
         )
         tok.train_from_iterator(TRAIN_TEXT, trainer)
         tok.save(tok_json)
-    real_vocab = _vocab_size(tok_json)
-    with open(os.path.join(path, "config.json"), "w") as f:
-        json.dump(
-            {
-                "architectures": ["LlamaForCausalLM"],
-                "model_type": "llama",
-                "vocab_size": real_vocab,
-                "hidden_size": hidden_size,
-                "num_hidden_layers": num_layers,
-                "num_attention_heads": num_heads,
-                "num_key_value_heads": num_kv_heads,
-                "intermediate_size": intermediate_size,
-                "max_position_embeddings": max_position_embeddings,
-                "rms_norm_eps": 1e-5,
-                "rope_theta": 10000.0,
-                "bos_token_id": 0,
-                "eos_token_id": 1,
-                "tie_word_embeddings": False,
-                "torch_dtype": "bfloat16",
-            },
-            f,
-        )
-    with open(os.path.join(path, "tokenizer_config.json"), "w") as f:
-        json.dump(
-            {
-                "bos_token": "<s>",
-                "eos_token": "</s>",
-                "chat_template": CHAT_TEMPLATE,
-            },
-            f,
-        )
+    write_config(
+        path,
+        ModelConfig(
+            vocab_size=_vocab_size(tok_json),
+            hidden_size=hidden_size,
+            num_layers=num_layers,
+            num_heads=num_heads,
+            num_kv_heads=num_kv_heads,
+            intermediate_size=intermediate_size,
+            max_position_embeddings=max_position_embeddings,
+        ),
+    )
+    write_tokenizer_config(path)
     return path
 
 

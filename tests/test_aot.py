@@ -179,12 +179,14 @@ def test_warm_boot_compiles_nothing(tmp_path):
     variant growth past the prewarmed set, zero new cache entries, and
     the move-kernel freshness state seeded (satellite: prewarm must
     never be mis-charged as a cold compile)."""
-    cache = str(tmp_path / "cache")
+    from dynamo_exp_tpu.aot import enable_persistent_cache
+
+    cache = enable_persistent_cache(str(tmp_path / "cache"))
 
     def boot():
         eng = _engine()
         manifest = manifest_for_engine(eng)
-        report = eng.prewarm(manifest, cache_dir=cache)
+        report = eng.prewarm(manifest)
         toks_g = asyncio.run(_collect(eng, range(20, 36)))
         toks_s = asyncio.run(
             _collect(eng, range(20, 36), seed=5, temperature=0.8)

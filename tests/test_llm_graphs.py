@@ -58,6 +58,7 @@ async def test_agg_graph_serves_openai_over_http(tiny_model_dir):
         os.environ,
         PYTHONPATH=REPO,
         JAX_PLATFORMS="cpu",
+        DYN_TPU_CHIPS="4",  # no chips here: a pretend budget for CPU workers
         DYN_SERVICE_CONFIG=json.dumps(overrides),
     )
     sup = await asyncio.create_subprocess_exec(
@@ -160,6 +161,7 @@ async def test_disagg_router_graph_remote_prefill_over_http(tiny_model_dir):
         os.environ,
         PYTHONPATH=REPO,
         JAX_PLATFORMS="cpu",
+        DYN_TPU_CHIPS="4",  # no chips here: a pretend budget for CPU workers
         DYN_SERVICE_CONFIG=json.dumps(overrides),
     )
     sup = await asyncio.create_subprocess_exec(

@@ -79,15 +79,40 @@ def test_service_config_yaml_env_merge(tmp_path, monkeypatch):
 
 # ----------------------------------------------------------------- allocator
 def test_tpu_allocator_assigns_disjoint_chips():
-    alloc = TPUAllocator(total_chips=4)
-    a = alloc.assign("decode", 2)
-    b = alloc.assign("prefill", 2)
-    assert a["TPU_VISIBLE_CHIPS"] == "0,1"
-    assert b["TPU_VISIBLE_CHIPS"] == "2,3"
+    alloc = TPUAllocator(total_chips=2)
+    a = alloc.assign("decode", 1)
+    b = alloc.assign("prefill", 1)
+    # One chip per process, and libtpu told the process is a whole
+    # one-chip slice of its own (TPU_VISIBLE_CHIPS alone fails every
+    # process after the first on the chip host).
+    for env, chip in ((a, "0"), (b, "1")):
+        assert env == {
+            "TPU_VISIBLE_CHIPS": chip,
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
     with pytest.raises(AllocationError):
         alloc.assign("extra", 1)
+    with pytest.raises(AllocationError, match="one chip per worker"):
+        alloc.assign("wide", 2)
+    alloc.release(a)
+    assert alloc.assign("again", 1)["TPU_VISIBLE_CHIPS"] == "0"
     # Host-side services stay off the TPU.
     assert alloc.assign("frontend", 0) == {"JAX_PLATFORMS": "cpu"}
+
+
+def test_host_chip_budget_is_the_hosts_not_a_constant(monkeypatch):
+    from dynamo_exp_tpu.sdk import allocator
+
+    monkeypatch.setenv("DYN_TPU_CHIPS", "3")
+    assert TPUAllocator().total_chips == 3
+    monkeypatch.delenv("DYN_TPU_CHIPS")
+    monkeypatch.setattr(
+        allocator.glob, "glob", lambda pat: ["/dev/vfio/0", "/dev/vfio/1"]
+    )
+    assert TPUAllocator().total_chips == 2
+    monkeypatch.setattr(allocator.glob, "glob", lambda pat: [])
+    assert TPUAllocator().total_chips == 0  # no chips: none to hand out
 
 
 # ----------------------------------------------------------------------- e2e

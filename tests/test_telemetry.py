@@ -589,6 +589,16 @@ def test_every_engine_metrics_mirror_key_is_documented():
             f for f in SUMMARY_FIELDS if f"`{f}`" not in doc
         ]
         assert not undocumented_fields, undocumented_fields
+        # ...and so is every build_info label: it is what a fleet
+        # scrape compares and what a chip run reads to learn where the
+        # engine really ran.
+        assert {"platform", "device_kind", "device_count", "attn_impl"} <= set(
+            m["build_info"]
+        )
+        undocumented_labels = [
+            k for k in m["build_info"] if f"`{k}`" not in doc
+        ]
+        assert not undocumented_labels, undocumented_labels
     finally:
         engine.stop()
 

@@ -356,7 +356,9 @@ def test_artifact_round_trip_boots_with_zero_compiles(tmp_path):
     write_artifact(art, path)
     art = load_artifact(path)
 
-    cache = str(tmp_path / "cache")
+    from dynamo_exp_tpu.aot import enable_persistent_cache
+
+    enable_persistent_cache(str(tmp_path / "cache"))
 
     def boot():
         cfg = engine_config_from_artifact(art, model=TINY)
@@ -367,7 +369,7 @@ def test_artifact_round_trip_boots_with_zero_compiles(tmp_path):
             == art["config_hash"]
         )
         eng = TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
-        eng.prewarm(manifest_from_artifact(art), cache_dir=cache)
+        eng.prewarm(manifest_from_artifact(art))
         toks = asyncio.run(_collect(eng, range(20, 36)))
         m = eng.metrics()
         eng.stop()
@@ -598,7 +600,7 @@ def test_bench_compare_pairs_by_config_hash_among_same_metric():
 
 @pytest.mark.pre_merge
 def test_bench_compare_legacy_untagged_lines_pair_by_metric():
-    # Checked-in BENCH_r*.json captures predate the stamp: one side (or
+    # Captures taken before the stamp existed: one side (or
     # both) untagged keeps the metric-name pairing unchanged.
     report = compare_bench(
         [_line(value=100.0)], [_line(value=50.0, config_hash="bbb")]
