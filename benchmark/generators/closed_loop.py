@@ -1,0 +1,46 @@
+"""Closed loop: ``clients`` callers that each wait for a reply and send
+the next request when the last one ends. The load follows the server, so
+what is judged is the rate of work completed.
+
+Lengths are drawn round by round from the traffic file's
+``schedule_seed``: the k-th requests of all clients are one evenly spaced
+sample of the distribution, so however many rounds a run gets through, it
+has seen a balanced mix, and every run is offered the same sequences. The
+run's seed decides which client gets which sequence, and makes the text.
+"""
+
+from __future__ import annotations
+
+import random
+
+from lib.textgen import stratified
+
+# A client's chain must outlast the window: unless the traffic file says
+# otherwise, this many requests a client for every second of the horizon
+# (a mean chat request, 170 tokens out, would have to end in under 2 s to
+# exhaust it). The generator stops sending at the window's end.
+REQUESTS_PER_CLIENT_SECOND = 0.5
+
+
+def generate(traffic: dict, cell: dict, seed: int, seconds: float, text) -> dict:
+    schedule = random.Random(int(traffic["schedule_seed"]))
+    lead_in = float(traffic["lead_in_s"])
+    clients = int(cell["clients"])
+    per_s = float(traffic.get("requests_per_client_s", REQUESTS_PER_CLIENT_SECOND))
+    rounds = max(4, int((lead_in + seconds) * per_s))
+    sequences: list[list] = [[] for _ in range(clients)]
+    for _ in range(rounds):
+        prompts = stratified(traffic["prompt_tokens"], clients, schedule)
+        outputs = stratified(traffic["output_tokens"], clients, schedule)
+        for seq, p, o in zip(sequences, prompts, outputs):
+            seq.append((p, o))
+    shift = random.Random(seed).randrange(clients)
+    chains = []
+    for c in range(clients):
+        reqs = [
+            {"messages": text.user_message(p), "max_tokens": o, "gap_s": 0.0}
+            for p, o in sequences[(c + shift) % clients]
+        ]
+        # Clients join over the first fifth of the lead-in, not at once.
+        chains.append({"start_s": lead_in * 0.2 * c / clients, "requests": reqs})
+    return {"lead_in_s": lead_in, "chains": chains}
