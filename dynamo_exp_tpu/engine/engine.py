@@ -41,6 +41,7 @@ reference's ``ExecutionContext`` contract (``lib/llm/src/backend.rs:60``).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import os
 import queue
@@ -225,6 +226,15 @@ class _PendingRagged:
     dispatched_at: float = 0.0
 
 
+_NO_PHASE = contextlib.nullcontext()
+
+
+def _no_phase(name: str):
+    """``DispatchProfiler.phase`` of an engine built without a profiler
+    (``profile_dispatches=False``): nothing is timed or annotated."""
+    return _NO_PHASE
+
+
 class TPUEngine(AsyncEngine):
     """Continuous-batching paged-KV engine on a TPU mesh."""
 
@@ -262,6 +272,11 @@ class TPUEngine(AsyncEngine):
             mcfg, cfg.num_pages, cfg.page_size, dtype=cfg.kv_dtype_jnp,
             shardings=tuple(sharding(s) for s in kv_cache_shardings()),
         )
+        layers, pages, page_size, width = self.k_cache.shape
+        get_telemetry().set_kv_pool_info(
+            layers, pages, page_size, width, str(self.k_cache.dtype)
+        )
+        get_telemetry().watch_jax_compiles()
 
         self.host_pool: HostKvPool | None = None
         self.copy_stream: CopyStream | None = None
@@ -334,6 +349,9 @@ class TPUEngine(AsyncEngine):
         self.profiler = (
             DispatchProfiler(get_telemetry()) if cfg.profile_dispatches else None
         )
+        self._phase = (
+            self.profiler.phase if self.profiler is not None else _no_phase
+        )
         self.flight = (
             FlightRecorder(cfg.flight_capacity) if cfg.flight_events else None
         )
@@ -348,27 +366,25 @@ class TPUEngine(AsyncEngine):
         # cache keys the O(log Pmax) bucket shapes. Scatter pads repeat
         # the last (pid, page) pair — duplicate indices with identical
         # updates are deterministic.
-        self._gather_pages = jax.jit(
-            lambda k, v, pids: (k[:, pids], v[:, pids])
-        )
-        self._inject_pages = jax.jit(
-            lambda k, v, pids, hk, hv: (
-                k.at[:, pids].set(hk),
-                v.at[:, pids].set(hv),
-            ),
-            donate_argnums=(0, 1),
-        )
+        # Each helper is a named function, not a lambda: the name is
+        # what the profiler's ``XLA Modules`` line shows for its runs
+        # (``jit_kv_gather_pages`` beside ``jit_ragged_window``).
+        def kv_gather_pages(k, v, pids):
+            return k[:, pids], v[:, pids]
+
+        def kv_inject_pages(k, v, pids, hk, hv):
+            return k.at[:, pids].set(hk), v.at[:, pids].set(hv)
+
+        def kv_cow_pages(k, v, src, dst):
+            return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
+
+        self._gather_pages = jax.jit(kv_gather_pages)
+        self._inject_pages = jax.jit(kv_inject_pages, donate_argnums=(0, 1))
         # Copy-on-write page copy (docs/prefix_sharing.md): device-to-
         # device duplicate of one shared page before its first divergent
         # write. Indices ride as traced device scalars, so every COW
         # shares ONE compiled variant.
-        self._cow_pages = jax.jit(
-            lambda k, v, src, dst: (
-                k.at[:, dst].set(k[:, src]),
-                v.at[:, dst].set(v[:, src]),
-            ),
-            donate_argnums=(0, 1),
-        )
+        self._cow_pages = jax.jit(kv_cow_pages, donate_argnums=(0, 1))
         # Evictions buffered by on_evict until the next compute dispatch.
         self._pending_offloads: list[tuple[int, int]] = []
 
@@ -402,10 +418,10 @@ class TPUEngine(AsyncEngine):
             self._spec = SpecManager(cfg)
         # Fresh penalty row for a slot: zero it, then count the first
         # sampled token so penalties see every generated token.
-        self._init_row = jax.jit(
-            lambda c, i, t: c.at[i].set(0).at[i, t].add(1),
-            donate_argnums=(0,),
-        )
+        def penalty_init_row(c, i, t):
+            return c.at[i].set(0).at[i, t].add(1)
+
+        self._init_row = jax.jit(penalty_init_row, donate_argnums=(0,))
 
         self._submit_q: queue.Queue[Sequence] = queue.Queue()
         self._wake = threading.Event()
@@ -691,26 +707,31 @@ class TPUEngine(AsyncEngine):
                     logits, k, v = run_forward(
                         params, tokens, positions, page_table, k, v
                     )
-                    shaped = apply_penalties(
-                        logits, counts, freq_pen, pres_pen, rep_pen
-                    )
-                    # Counter-based draw keyed by (seed, fed position):
-                    # deterministic replay across instances/windows, the
-                    # property resumable streams rebuild state from.
-                    next_tok = sample_tokens_seeded(
-                        shaped, seeds, positions, temp, top_k, top_p
-                    )
-                    # OpenAI logprobs: of the MODEL distribution (raw
-                    # logits, pre-penalty/temperature), chosen + top-k.
-                    # Compiled only into the want_lp variant — the common
-                    # no-logprobs workload pays neither the full-vocab
-                    # log_softmax nor the extra per-window host transfer.
-                    if want_lp:
-                        lp, top_ids, top_lp = token_logprobs(logits, next_tok)
-                    active = positions >= 0
-                    counts = counts.at[
-                        jnp.arange(counts.shape[0]), next_tok
-                    ].add(active.astype(jnp.int32))
+                    with jax.named_scope("sample"):
+                        shaped = apply_penalties(
+                            logits, counts, freq_pen, pres_pen, rep_pen
+                        )
+                        # Counter-based draw keyed by (seed, fed
+                        # position): deterministic replay across
+                        # instances/windows, the property resumable
+                        # streams rebuild state from.
+                        next_tok = sample_tokens_seeded(
+                            shaped, seeds, positions, temp, top_k, top_p
+                        )
+                        # OpenAI logprobs: of the MODEL distribution (raw
+                        # logits, pre-penalty/temperature), chosen +
+                        # top-k. Compiled only into the want_lp variant —
+                        # the common no-logprobs workload pays neither the
+                        # full-vocab log_softmax nor the extra per-window
+                        # host transfer.
+                        if want_lp:
+                            lp, top_ids, top_lp = token_logprobs(
+                                logits, next_tok
+                            )
+                        active = positions >= 0
+                        counts = counts.at[
+                            jnp.arange(counts.shape[0]), next_tok
+                        ].add(active.astype(jnp.int32))
                     tokens = jnp.where(active, next_tok, tokens)
                     positions = advance(
                         positions, max_pos, next_tok, stop_set, eos_gate,
@@ -742,9 +763,14 @@ class TPUEngine(AsyncEngine):
                     logits, k, v = run_forward(
                         params, tokens, positions, page_table, k, v
                     )
-                    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    if want_lp:
-                        lp, top_ids, top_lp = token_logprobs(logits, next_tok)
+                    with jax.named_scope("sample"):
+                        next_tok = jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32
+                        )
+                        if want_lp:
+                            lp, top_ids, top_lp = token_logprobs(
+                                logits, next_tok
+                            )
                     active = positions >= 0
                     tokens = jnp.where(active, next_tok, tokens)
                     positions = advance(
@@ -820,39 +846,40 @@ class TPUEngine(AsyncEngine):
                     params, k, v, tokens, positions, row_of, page_table,
                     out_indices(q_last, spec_idx),
                 )
-                logits0 = logits_all[:B1]
-                counts0 = counts_all[slot_map]
-                # Decode rows sample through their penalty counts (the
-                # window rule); a prompt's first token samples the raw
-                # model distribution (the prefill rule — the host
-                # initializes its counts row at consume).
-                shaped = apply_penalties(
-                    logits0, counts0, freq_pen, pres_pen, rep_pen
-                )
-                dec = is_decode[:, None]
-                tok0 = sample_tokens_seeded(
-                    jnp.where(dec, shaped, logits0),
-                    seeds, pos0, temp, top_k, top_p,
-                )
-                counts = counts0.at[jnp.arange(B1), tok0].add(
-                    is_decode.astype(jnp.int32)
-                )
-                if want_lp:
-                    lp0, tid0, tlp0 = token_logprobs(logits0, tok0)
-                ys = (tok0,)
-                if spec_on:
-                    spec_logits = logits_all[B1:].reshape(B1, T_s, -1)
-                    targets, n_emit, counts = spec_verify_tokens(
-                        spec_logits, spec_drafts, n_drafts, seeds,
-                        spec_pos, temp, top_k, top_p, counts, freq_pen,
-                        pres_pen, rep_pen,
+                with jax.named_scope("sample"):
+                    logits0 = logits_all[:B1]
+                    counts0 = counts_all[slot_map]
+                    # Decode rows sample through their penalty counts (the
+                    # window rule); a prompt's first token samples the raw
+                    # model distribution (the prefill rule — the host
+                    # initializes its counts row at consume).
+                    shaped = apply_penalties(
+                        logits0, counts0, freq_pen, pres_pen, rep_pen
                     )
-                    ys = ys + (targets, n_emit)
-                counts_all = counts_all.at[slot_map].set(counts)
-                if want_lp:
-                    ys = ys + (lp0, tid0, tlp0)
+                    dec = is_decode[:, None]
+                    tok0 = sample_tokens_seeded(
+                        jnp.where(dec, shaped, logits0),
+                        seeds, pos0, temp, top_k, top_p,
+                    )
+                    counts = counts0.at[jnp.arange(B1), tok0].add(
+                        is_decode.astype(jnp.int32)
+                    )
+                    if want_lp:
+                        lp0, tid0, tlp0 = token_logprobs(logits0, tok0)
+                    ys = (tok0,)
                     if spec_on:
-                        ys = ys + pack_spec_lp(spec_logits, targets)
+                        spec_logits = logits_all[B1:].reshape(B1, T_s, -1)
+                        targets, n_emit, counts = spec_verify_tokens(
+                            spec_logits, spec_drafts, n_drafts, seeds,
+                            spec_pos, temp, top_k, top_p, counts, freq_pen,
+                            pres_pen, rep_pen,
+                        )
+                        ys = ys + (targets, n_emit)
+                    counts_all = counts_all.at[slot_map].set(counts)
+                    if want_lp:
+                        ys = ys + (lp0, tid0, tlp0)
+                        if spec_on:
+                            ys = ys + pack_spec_lp(spec_logits, targets)
                 return ys, k, v, counts_all
 
         else:
@@ -865,24 +892,25 @@ class TPUEngine(AsyncEngine):
                     params, k, v, tokens, positions, row_of, page_table,
                     out_indices(q_last, spec_idx),
                 )
-                logits0 = logits_all[:B1]
-                tok0 = jnp.argmax(logits0, axis=-1).astype(jnp.int32)
-                if want_lp:
-                    lp0, tid0, tlp0 = token_logprobs(logits0, tok0)
-                ys = (tok0,)
-                if spec_on:
-                    spec_logits = logits_all[B1:].reshape(B1, T_s, -1)
-                    targets = jnp.argmax(spec_logits, axis=-1).astype(
-                        jnp.int32
-                    )
-                    n_emit = spec_accept_length(
-                        targets, spec_drafts, n_drafts
-                    )
-                    ys = ys + (targets, n_emit)
-                if want_lp:
-                    ys = ys + (lp0, tid0, tlp0)
+                with jax.named_scope("sample"):
+                    logits0 = logits_all[:B1]
+                    tok0 = jnp.argmax(logits0, axis=-1).astype(jnp.int32)
+                    if want_lp:
+                        lp0, tid0, tlp0 = token_logprobs(logits0, tok0)
+                    ys = (tok0,)
                     if spec_on:
-                        ys = ys + pack_spec_lp(spec_logits, targets)
+                        spec_logits = logits_all[B1:].reshape(B1, T_s, -1)
+                        targets = jnp.argmax(spec_logits, axis=-1).astype(
+                            jnp.int32
+                        )
+                        n_emit = spec_accept_length(
+                            targets, spec_drafts, n_drafts
+                        )
+                        ys = ys + (targets, n_emit)
+                    if want_lp:
+                        ys = ys + (lp0, tid0, tlp0)
+                        if spec_on:
+                            ys = ys + pack_spec_lp(spec_logits, targets)
                 return ys, k, v
 
         return ragged_mixed
@@ -1525,34 +1553,45 @@ class TPUEngine(AsyncEngine):
         registration, and admissions for window N overlap window N+1's
         device time. All scheduler mutation that could free pages still
         happens only when no unconsumed window could write to them."""
+        # Engine-loop phases (telemetry/dispatch.py PHASES): every
+        # stretch of the body below runs under one, the dispatch and
+        # consume helpers open their own (assemble / launch / sync /
+        # consume), and none nests. What is left outside is the loop's
+        # own overhead: a few branches an iteration.
+        phase = self._phase
         try:
             while self._running:
-                # Lease bookkeeping first: confirmations queued by the
-                # prefill worker's delivery ack, then the expiry reaper
-                # (orphaned handoffs whose decode instance died). Both
-                # mutate the page manager, so they run here — its single
-                # writer — every iteration, busy or idle.
-                self._service_leases()
-                self._service_pins()
-                self._service_reclaims()
-                # Conservation auditor: O(1) counter arithmetic over the
-                # page ledger, every iteration, busy or idle — a leaked
-                # ref or double-release is caught within one loop pass
-                # of the mutation that caused it.
-                if self.cfg.kv_ledger_check:
-                    self._check_ledger()
+                with phase("kv_admin"):
+                    # Lease bookkeeping first: confirmations queued by
+                    # the prefill worker's delivery ack, then the expiry
+                    # reaper (orphaned handoffs whose decode instance
+                    # died). Both mutate the page manager, so they run
+                    # here — its single writer — every iteration, busy
+                    # or idle.
+                    self._service_leases()
+                    self._service_pins()
+                    self._service_reclaims()
+                    # Conservation auditor: O(1) counter arithmetic over
+                    # the page ledger, every iteration, busy or idle — a
+                    # leaked ref or double-release is caught within one
+                    # loop pass of the mutation that caused it.
+                    if self.cfg.kv_ledger_check:
+                        self._check_ledger()
                 if self._inflight is not None:
                     # Steady state: launch the next window device-to-
                     # device, then consume the previous one while the
                     # new one executes.
+                    with phase("assemble"):
+                        can_chain = self._can_chain()
                     nxt = (
                         self._dispatch_chained(self._inflight)
-                        if self._can_chain()
+                        if can_chain
                         else None
                     )
                     prev, self._inflight = self._inflight, nxt
                     self._consume_ragged(prev)
-                    self._maybe_publish_gauges()
+                    with phase("publish"):
+                        self._maybe_publish_gauges()
                     self._progress_mark += 1  # consumed a window
                     if self._inflight is not None:
                         continue
@@ -1568,27 +1607,32 @@ class TPUEngine(AsyncEngine):
                     # on the final busy-loop snapshot. Completed
                     # prefetches whose target vanished still need their
                     # leases returned.
-                    self._apply_prefetches()
-                    self._flush_offloads()
-                    self._maybe_publish_gauges()
+                    with phase("kv_admin"):
+                        self._apply_prefetches()
+                        self._flush_offloads()
+                    with phase("publish"):
+                        self._maybe_publish_gauges()
                     if self.profiler is not None:
                         # Genuinely idle: wait time must never read as
                         # host gap on the next dispatch.
                         self.profiler.mark_idle()
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with phase("park"):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
                     continue
-                self._drain_submissions()
-                self._poll_cancellations()
-                # Reap dead work anywhere in the waiting deque before it
-                # can waste a prefill or hold an admission slot. The full
-                # O(queue-depth) scan is throttled: the loop can spin at
-                # kHz when the pool is dry, and admit_next's head check
-                # still prevents a wasted prefill between scans.
-                now_m = time.monotonic()
-                if now_m - self._last_reap >= 0.02:
-                    self._last_reap = now_m
-                    self.sched.reap_waiting()
+                with phase("intake"):
+                    self._drain_submissions()
+                    self._poll_cancellations()
+                    # Reap dead work anywhere in the waiting deque
+                    # before it can waste a prefill or hold an admission
+                    # slot. The full O(queue-depth) scan is throttled:
+                    # the loop can spin at kHz when the pool is dry, and
+                    # admit_next's head check still prevents a wasted
+                    # prefill between scans.
+                    now_m = time.monotonic()
+                    if now_m - self._last_reap >= 0.02:
+                        self._last_reap = now_m
+                        self.sched.reap_waiting()
                 # KV pressure: no window is in flight here (the chain
                 # broke above or never existed), so releasing a victim's
                 # pages cannot race a device write. Proactive offload
@@ -1596,43 +1640,58 @@ class TPUEngine(AsyncEngine):
                 # swap a cold row's bytes to the host tier so the stall
                 # clears before the preemption grace ever expires —
                 # preemption stays the fallback.
-                self._maybe_proactive_offload()
-                self._maybe_preempt()
-                # Completed G2→G1 prefetches inject before this
-                # iteration's compute dispatch (stream order then makes
-                # the restored pages readable by anything admitted
-                # below); swapped rows rejoin before admission so
-                # newcomers can't starve them.
-                self._apply_prefetches()
-                self._try_swap_in()
-                if not self._kv_pressure() and not self._swapped_rows():
-                    while (admitted := self.sched.admit_next()) is not None:
-                        self._on_admitted(admitted)
-                # Plan new prefetches over whatever is STILL waiting
-                # (couldn't admit: slots full or pool pressure) — the
-                # exact window where restoring ahead of admission
-                # overlaps the current batch's compute.
-                self._plan_prefetch()
-                self._maybe_publish_gauges()
+                with phase("kv_admin"):
+                    self._maybe_proactive_offload()
+                    self._maybe_preempt()
+                    # Completed G2→G1 prefetches inject before this
+                    # iteration's compute dispatch (stream order then
+                    # makes the restored pages readable by anything
+                    # admitted below); swapped rows rejoin before
+                    # admission so newcomers can't starve them.
+                    self._apply_prefetches()
+                    self._try_swap_in()
+                    may_admit = (
+                        not self._kv_pressure() and not self._swapped_rows()
+                    )
+                if may_admit:
+                    with phase("admit"):
+                        while (
+                            admitted := self.sched.admit_next()
+                        ) is not None:
+                            self._on_admitted(admitted)
+                with phase("kv_admin"):
+                    # Plan new prefetches over whatever is STILL waiting
+                    # (couldn't admit: slots full or pool pressure) —
+                    # the exact window where restoring ahead of
+                    # admission overlaps the current batch's compute.
+                    self._plan_prefetch()
+                with phase("publish"):
+                    self._maybe_publish_gauges()
                 progressed = False
-                prefilling = [
-                    s
-                    for s in self.sched.slots
-                    if s is not None and s.state is SeqState.PREFILL
-                ]
-                # Partition the snapshot BEFORE injecting: injection
-                # clears remote_kv and promotes the sequence to ACTIVE,
-                # so filtering afterwards would re-prefill it. Sequences
-                # attached to shared pages another sequence is still
-                # filling sit out until those fills are dispatched
-                # (fill_ready also claims orphans left by dead fillers)
-                # — device stream order then makes their reads safe.
-                ready = [s for s in prefilling if self.sched.fill_ready(s)]
-                batch = [s for s in ready if s.remote_kv is None]
-                for seq in ready:
-                    if seq.remote_kv is not None:
-                        self._run_remote_inject(seq)
-                        progressed = True
+                with phase("assemble"):
+                    prefilling = [
+                        s
+                        for s in self.sched.slots
+                        if s is not None and s.state is SeqState.PREFILL
+                    ]
+                    # Partition the snapshot BEFORE injecting: injection
+                    # clears remote_kv and promotes the sequence to
+                    # ACTIVE, so filtering afterwards would re-prefill
+                    # it. Sequences attached to shared pages another
+                    # sequence is still filling sit out until those
+                    # fills are dispatched (fill_ready also claims
+                    # orphans left by dead fillers) — device stream
+                    # order then makes their reads safe.
+                    ready = [
+                        s for s in prefilling if self.sched.fill_ready(s)
+                    ]
+                    batch = [s for s in ready if s.remote_kv is None]
+                remote = [s for s in ready if s.remote_kv is not None]
+                if remote:
+                    with phase("kv_admin"):
+                        for seq in remote:
+                            self._run_remote_inject(seq)
+                    progressed = True
                 # ONE ragged dispatch per iteration (per sampler
                 # partition): prefill chunks, decode steps/windows, and
                 # spec verify spans share the flat query stream, so a
@@ -1663,8 +1722,9 @@ class TPUEngine(AsyncEngine):
                     # watchdog must see as frozen.
                     if self.profiler is not None:
                         self.profiler.mark_idle()
-                    self._wake.wait(timeout=0.001)
-                    self._wake.clear()
+                    with phase("park"):
+                        self._wake.wait(timeout=0.001)
+                        self._wake.clear()
         except Exception:  # engine death must not hang clients
             log.exception("engine loop crashed; failing in-flight requests")
             self.loop_crashed = True
@@ -1712,6 +1772,13 @@ class TPUEngine(AsyncEngine):
                 priority=seq.priority,
             )
         tel = get_telemetry()
+        # Prompt tokens by source, from the allocation that just fed
+        # kv.prefix_hits its pages: what the prefix cache saved in the
+        # unit prefill is paid in.
+        tel.prompt_tokens.labels("cached").inc(seq.cached_len)
+        tel.prompt_tokens.labels("computed").inc(
+            len(seq.prompt) - seq.cached_len
+        )
         if seq.submitted_at:
             tel.queue_wait.observe(max(now - seq.submitted_at, 0.0))
             tel.emit_stage(
@@ -2930,6 +2997,23 @@ class TPUEngine(AsyncEngine):
         syncs once per window, chainable device-to-device. Returns the
         pending dispatches; [] when nothing could step (pool dry / no
         ACTIVE or ready-PREFILL rows)."""
+        with self._phase("assemble"):
+            batches, solo = self._plan_ragged(prefill_rows)
+        out: list[_PendingRagged] = []
+        for fs, dec, spec, pf, windowed in batches:
+            if windowed:
+                out.append(self._build_windowed(dec, fs, solo))
+            else:
+                out.append(self._build_mixed(dec, spec, pf, fs))
+        return out
+
+    def _plan_ragged(
+        self, prefill_rows: list[Sequence]
+    ) -> tuple[list[tuple], bool]:
+        """Partition every slot's next unit of work into this
+        iteration's batches ``(full_sampler, decode rows, spec rows,
+        prefill rows, windowed)``, provisioning pages as it goes; and
+        whether the one batch is a chainable window."""
         cfg = self.cfg
         ps, K = cfg.page_size, cfg.decode_window
         greedy: list[tuple[Sequence, int, int]] = []  # (seq, wpos, cap)
@@ -3021,13 +3105,7 @@ class TPUEngine(AsyncEngine):
         # dispatch — a concurrent mixed batch (like a second partition)
         # means the row set will be re-planned next round.
         solo = len(batches) == 1 and batches[0][4]
-        out: list[_PendingRagged] = []
-        for fs, dec, spec, pf, windowed in batches:
-            if windowed:
-                out.append(self._build_windowed(dec, fs, solo))
-            else:
-                out.append(self._build_mixed(dec, spec, pf, fs))
-        return out
+        return batches, solo
 
     # ------------------------------------------------------------ row helpers
     @staticmethod
@@ -3172,60 +3250,63 @@ class TPUEngine(AsyncEngine):
         """Build + dispatch one pure-decode windowed batch (no host
         sync): ``decode_window`` on-device steps over the compacted
         rows — the ragged family's one-query-per-row shape."""
-        cfg = self.cfg
-        ps, K, S = cfg.page_size, cfg.decode_window, cfg.device_stop_width
-        nb = cfg.ragged_tokens_bucket_for(len(part))
-        tokens = np.zeros(nb, np.int32)
-        positions = np.full(nb, -1, np.int32)
-        max_pos = np.full(nb, -1, np.int32)
-        table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
-        # Pad rows map to the scratch counts row (B) so their scatter
-        # can't touch a live slot.
-        slot_map = np.full(nb, cfg.max_decode_slots, np.int32)
-        stop_set = np.full((nb, S), -1, np.int32)
-        eos_gate = np.zeros(nb, np.int32)
-        budget_gate = np.full(nb, K, np.int32)  # pad: never fires
-        seeds = np.zeros(nb, np.int32)
-        temp = np.zeros(nb, np.float32)
-        top_k = np.zeros(nb, np.int32)
-        top_p = np.ones(nb, np.float32)
-        freq = np.zeros(nb, np.float32)
-        pres = np.zeros(nb, np.float32)
-        rep = np.ones(nb, np.float32)
+        with self._phase("assemble"):
+            cfg = self.cfg
+            ps, K, S = cfg.page_size, cfg.decode_window, cfg.device_stop_width
+            nb = cfg.ragged_tokens_bucket_for(len(part))
+            tokens = np.zeros(nb, np.int32)
+            positions = np.full(nb, -1, np.int32)
+            max_pos = np.full(nb, -1, np.int32)
+            table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
+            # Pad rows map to the scratch counts row (B) so their scatter
+            # can't touch a live slot.
+            slot_map = np.full(nb, cfg.max_decode_slots, np.int32)
+            stop_set = np.full((nb, S), -1, np.int32)
+            eos_gate = np.zeros(nb, np.int32)
+            budget_gate = np.full(nb, K, np.int32)  # pad: never fires
+            seeds = np.zeros(nb, np.int32)
+            temp = np.zeros(nb, np.float32)
+            top_k = np.zeros(nb, np.int32)
+            top_p = np.ones(nb, np.float32)
+            freq = np.zeros(nb, np.float32)
+            pres = np.zeros(nb, np.float32)
+            rep = np.ones(nb, np.float32)
 
-        rows: list[_RaggedRow] = []
-        max_pages = 1
-        capacity_capped = False
-        for r, (seq, wpos, cap) in enumerate(part):
-            capacity_capped = capacity_capped or cap < wpos + K
-            tokens[r] = seq.last_token()
-            positions[r] = wpos
-            max_pos[r] = cap
-            table[r, : len(seq.page_ids)] = seq.page_ids
-            slot_map[r] = seq.slot
-            max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
-            stops = self._stop_set(seq)
-            stop_set[r, : len(stops)] = stops
-            eos_gate[r], budget_gate[r] = self._stop_gates(seq, seq.generated)
-            self._row_sampler_args(
-                seq, r, (seeds, temp, top_k, top_p, freq, pres, rep)
-            )
-            rows.append(
-                _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
-            )
+            rows: list[_RaggedRow] = []
+            max_pages = 1
+            capacity_capped = False
+            for r, (seq, wpos, cap) in enumerate(part):
+                capacity_capped = capacity_capped or cap < wpos + K
+                tokens[r] = seq.last_token()
+                positions[r] = wpos
+                max_pos[r] = cap
+                table[r, : len(seq.page_ids)] = seq.page_ids
+                slot_map[r] = seq.slot
+                max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
+                stops = self._stop_set(seq)
+                stop_set[r, : len(stops)] = stops
+                eos_gate[r], budget_gate[r] = self._stop_gates(seq, seq.generated)
+                self._row_sampler_args(
+                    seq, r, (seeds, temp, top_k, top_p, freq, pres, rep)
+                )
+                rows.append(
+                    _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
+                )
 
-        want_lp = any(
-            self._wants_logprobs(e.seq) is not None for e in rows
-        )
-        n_variants = len(self._ragged_fns)
-        fn = self._ragged_fn(
-            nb, cfg.ragged_page_bucket_for(max_pages), True, full_sampler,
-            want_lp,
-        )
-        fresh = len(self._ragged_fns) > n_variants
-        self._flush_offloads()
+            want_lp = any(
+                self._wants_logprobs(e.seq) is not None for e in rows
+            )
+            n_variants = len(self._ragged_fns)
+            fn = self._ragged_fn(
+                nb, cfg.ragged_page_bucket_for(max_pages), True, full_sampler,
+                want_lp,
+            )
+            fresh = len(self._ragged_fns) > n_variants
+        if self._pending_offloads:
+            with self._phase("kv_admin"):
+                self._flush_offloads()
         prof = self.profiler
-        t0 = prof.begin("ragged") if prof is not None else 0.0
+        t0 = prof.begin("ragged", "window") if prof is not None else 0.0
         sampler_args = (seeds, temp, top_k, top_p, freq, pres, rep)
         if full_sampler:
             (ys, self.k_cache, self.v_cache, self._counts,
@@ -3259,7 +3340,7 @@ class TPUEngine(AsyncEngine):
                 windowed=True,
             )
         self.steps += K
-        get_telemetry().decode_batch_rows.observe(len(part))
+        get_telemetry().decode_batch_rows.labels("window").observe(len(part))
         return _PendingRagged(
             ys=ys,
             rows=rows,
@@ -3291,133 +3372,136 @@ class TPUEngine(AsyncEngine):
         Decode rows advance one step here (the window resumes once the
         batch is pure decode again); prompts completing this chunk
         sample their first token in the same dispatch."""
-        cfg = self.cfg
-        ps = cfg.page_size
-        B1 = cfg.max_decode_slots + 1
-        T_s = cfg.spec_max_draft + 1
-        align = self._ragged_align()
-        flat_tokens: list[int] = []
-        flat_pos: list[int] = []
-        flat_row: list[int] = []
+        with self._phase("assemble"):
+            cfg = self.cfg
+            ps = cfg.page_size
+            B1 = cfg.max_decode_slots + 1
+            T_s = cfg.spec_max_draft + 1
+            align = self._ragged_align()
+            flat_tokens: list[int] = []
+            flat_pos: list[int] = []
+            flat_row: list[int] = []
 
-        table = np.zeros((B1, cfg.max_pages_per_seq), np.int32)
-        q_last = np.zeros(B1, np.int32)
-        pos0 = np.full(B1, -1, np.int32)
-        is_decode = np.zeros(B1, np.bool_)
-        slot_map = np.full(B1, cfg.max_decode_slots, np.int32)
-        seeds = np.zeros(B1, np.int32)
-        temp = np.zeros(B1, np.float32)
-        top_k = np.zeros(B1, np.int32)
-        top_p = np.ones(B1, np.float32)
-        freq = np.zeros(B1, np.float32)
-        pres = np.zeros(B1, np.float32)
-        rep = np.ones(B1, np.float32)
-        spec_idx = np.zeros((B1, T_s), np.int32)
-        spec_pos = np.full((B1, T_s), -1, np.int32)
-        spec_drafts = np.full((B1, max(T_s - 1, 1)), -1, np.int32)
-        n_drafts = np.zeros(B1, np.int32)
-        sampler_arrs = (seeds, temp, top_k, top_p, freq, pres, rep)
+            table = np.zeros((B1, cfg.max_pages_per_seq), np.int32)
+            q_last = np.zeros(B1, np.int32)
+            pos0 = np.full(B1, -1, np.int32)
+            is_decode = np.zeros(B1, np.bool_)
+            slot_map = np.full(B1, cfg.max_decode_slots, np.int32)
+            seeds = np.zeros(B1, np.int32)
+            temp = np.zeros(B1, np.float32)
+            top_k = np.zeros(B1, np.int32)
+            top_p = np.ones(B1, np.float32)
+            freq = np.zeros(B1, np.float32)
+            pres = np.zeros(B1, np.float32)
+            rep = np.ones(B1, np.float32)
+            spec_idx = np.zeros((B1, T_s), np.int32)
+            spec_pos = np.full((B1, T_s), -1, np.int32)
+            spec_drafts = np.full((B1, max(T_s - 1, 1)), -1, np.int32)
+            n_drafts = np.zeros(B1, np.int32)
+            sampler_arrs = (seeds, temp, top_k, top_p, freq, pres, rep)
 
-        def add_span(toks: list[int], poss: list[int], r: int) -> int:
-            """Append one row's query span to the flat stream, aligned
-            to the kernel's q_tile (padding positions are -1: their
-            writes drop and their scores mask out)."""
-            start = len(flat_tokens)
-            flat_tokens.extend(toks)
-            flat_pos.extend(poss)
-            flat_row.extend([r] * len(toks))
-            pad = (-len(toks)) % align
-            if pad:
-                flat_tokens.extend([0] * pad)
-                flat_pos.extend([-1] * pad)
-                flat_row.extend([r] * pad)
-            return start
+            def add_span(toks: list[int], poss: list[int], r: int) -> int:
+                """Append one row's query span to the flat stream, aligned
+                to the kernel's q_tile (padding positions are -1: their
+                writes drop and their scores mask out)."""
+                start = len(flat_tokens)
+                flat_tokens.extend(toks)
+                flat_pos.extend(poss)
+                flat_row.extend([r] * len(toks))
+                pad = (-len(toks)) % align
+                if pad:
+                    flat_tokens.extend([0] * pad)
+                    flat_pos.extend([-1] * pad)
+                    flat_row.extend([r] * pad)
+                return start
 
-        rows: list[_RaggedRow] = []
-        max_pages = 1
-        r = 0
-        for seq in pf:
-            self._apply_uploads(seq)
-            n = min(len(seq.prompt) - seq.prefill_sent, cfg.prefill_chunk)
-            start_tok = seq.prefill_sent
-            qs = add_span(
-                list(seq.prompt[start_tok : start_tok + n]),
-                list(range(start_tok, start_tok + n)),
-                r,
-            )
-            seq.prefill_sent = start_tok + n
-            table[r, : len(seq.page_ids)] = seq.page_ids
-            q_last[r] = qs + n - 1
-            # Key the first-token draw by the absolute position of the
-            # prompt's last token — identical to the draw a decode
-            # window would make feeding that token, so prefill chunking
-            # and continuation re-prefills replay the same sample.
-            pos0[r] = start_tok + n - 1
-            max_pages = max(max_pages, (seq.prefill_sent + ps - 1) // ps)
-            self._row_sampler_args(seq, r, sampler_arrs)
-            rows.append(
-                _RaggedRow(
-                    seq,
-                    "prefill",
+            rows: list[_RaggedRow] = []
+            max_pages = 1
+            r = 0
+            for seq in pf:
+                self._apply_uploads(seq)
+                n = min(len(seq.prompt) - seq.prefill_sent, cfg.prefill_chunk)
+                start_tok = seq.prefill_sent
+                qs = add_span(
+                    list(seq.prompt[start_tok : start_tok + n]),
+                    list(range(start_tok, start_tok + n)),
                     r,
-                    completing=seq.prefill_sent == len(seq.prompt),
                 )
-            )
-            r += 1
-        for seq, wpos, _cap in dec:
-            qs = add_span([seq.last_token()], [wpos], r)
-            table[r, : len(seq.page_ids)] = seq.page_ids
-            q_last[r] = qs
-            pos0[r] = wpos
-            is_decode[r] = True
-            slot_map[r] = seq.slot
-            max_pages = max(max_pages, wpos // ps + 1)
-            self._row_sampler_args(seq, r, sampler_arrs)
-            rows.append(_RaggedRow(seq, "decode", r, n_valid=1))
-            r += 1
-        for seq, wpos, _cap, drafts in spec:
-            g = len(drafts)
-            qs = add_span(
-                [seq.last_token()] + list(drafts),
-                list(range(wpos, wpos + g + 1)),
-                r,
-            )
-            table[r, : len(seq.page_ids)] = seq.page_ids
-            q_last[r] = qs + g
-            slot_map[r] = seq.slot
-            spec_idx[r, : g + 1] = qs + np.arange(g + 1)
-            spec_pos[r, : g + 1] = np.arange(wpos, wpos + g + 1)
-            spec_drafts[r, :g] = drafts
-            n_drafts[r] = g
-            max_pages = max(max_pages, (wpos + g) // ps + 1)
-            self._row_sampler_args(seq, r, sampler_arrs)
-            rows.append(_RaggedRow(seq, "spec", r, n_drafts=g))
-            r += 1
+                seq.prefill_sent = start_tok + n
+                table[r, : len(seq.page_ids)] = seq.page_ids
+                q_last[r] = qs + n - 1
+                # Key the first-token draw by the absolute position of the
+                # prompt's last token — identical to the draw a decode
+                # window would make feeding that token, so prefill chunking
+                # and continuation re-prefills replay the same sample.
+                pos0[r] = start_tok + n - 1
+                max_pages = max(max_pages, (seq.prefill_sent + ps - 1) // ps)
+                self._row_sampler_args(seq, r, sampler_arrs)
+                rows.append(
+                    _RaggedRow(
+                        seq,
+                        "prefill",
+                        r,
+                        completing=seq.prefill_sent == len(seq.prompt),
+                    )
+                )
+                r += 1
+            for seq, wpos, _cap in dec:
+                qs = add_span([seq.last_token()], [wpos], r)
+                table[r, : len(seq.page_ids)] = seq.page_ids
+                q_last[r] = qs
+                pos0[r] = wpos
+                is_decode[r] = True
+                slot_map[r] = seq.slot
+                max_pages = max(max_pages, wpos // ps + 1)
+                self._row_sampler_args(seq, r, sampler_arrs)
+                rows.append(_RaggedRow(seq, "decode", r, n_valid=1))
+                r += 1
+            for seq, wpos, _cap, drafts in spec:
+                g = len(drafts)
+                qs = add_span(
+                    [seq.last_token()] + list(drafts),
+                    list(range(wpos, wpos + g + 1)),
+                    r,
+                )
+                table[r, : len(seq.page_ids)] = seq.page_ids
+                q_last[r] = qs + g
+                slot_map[r] = seq.slot
+                spec_idx[r, : g + 1] = qs + np.arange(g + 1)
+                spec_pos[r, : g + 1] = np.arange(wpos, wpos + g + 1)
+                spec_drafts[r, :g] = drafts
+                n_drafts[r] = g
+                max_pages = max(max_pages, (wpos + g) // ps + 1)
+                self._row_sampler_args(seq, r, sampler_arrs)
+                rows.append(_RaggedRow(seq, "spec", r, n_drafts=g))
+                r += 1
 
-        total_q = len(flat_tokens)
-        nb = cfg.ragged_tokens_bucket_for(max(total_q, 1), mixed=True)
-        tokens = np.zeros(nb, np.int32)
-        positions = np.full(nb, -1, np.int32)
-        # Flat padding maps to the scratch per-row index (B1 - 1 is
-        # always free: at most max_decode_slots rows hold slots).
-        row_of = np.full(nb, B1 - 1, np.int32)
-        tokens[:total_q] = flat_tokens
-        positions[:total_q] = flat_pos
-        row_of[:total_q] = flat_row
+            total_q = len(flat_tokens)
+            nb = cfg.ragged_tokens_bucket_for(max(total_q, 1), mixed=True)
+            tokens = np.zeros(nb, np.int32)
+            positions = np.full(nb, -1, np.int32)
+            # Flat padding maps to the scratch per-row index (B1 - 1 is
+            # always free: at most max_decode_slots rows hold slots).
+            row_of = np.full(nb, B1 - 1, np.int32)
+            tokens[:total_q] = flat_tokens
+            positions[:total_q] = flat_pos
+            row_of[:total_q] = flat_row
 
-        want_lp = any(
-            self._wants_logprobs(e.seq) is not None for e in rows
-        )
-        with_spec = bool(spec)
-        n_variants = len(self._ragged_fns)
-        fn = self._ragged_fn(
-            nb, cfg.ragged_page_bucket_for(max_pages), False, full_sampler,
-            want_lp, with_spec,
-        )
-        fresh = len(self._ragged_fns) > n_variants
-        self._flush_offloads()
+            want_lp = any(
+                self._wants_logprobs(e.seq) is not None for e in rows
+            )
+            with_spec = bool(spec)
+            n_variants = len(self._ragged_fns)
+            fn = self._ragged_fn(
+                nb, cfg.ragged_page_bucket_for(max_pages), False, full_sampler,
+                want_lp, with_spec,
+            )
+            fresh = len(self._ragged_fns) > n_variants
+        if self._pending_offloads:
+            with self._phase("kv_admin"):
+                self._flush_offloads()
         prof = self.profiler
-        t0 = prof.begin("ragged") if prof is not None else 0.0
+        t0 = prof.begin("ragged", "mixed") if prof is not None else 0.0
         if full_sampler:
             ys, self.k_cache, self.v_cache, self._counts = fn(
                 self.params, self.k_cache, self.v_cache,
@@ -3465,7 +3549,9 @@ class TPUEngine(AsyncEngine):
         self.steps += 1
         if spec:
             self.spec_dispatches += 1
-        get_telemetry().decode_batch_rows.observe(len(dec) + len(spec))
+        get_telemetry().decode_batch_rows.labels("mixed").observe(
+            len(dec) + len(spec)
+        )
         return _PendingRagged(
             ys=ys,
             rows=rows,
@@ -3540,46 +3626,49 @@ class TPUEngine(AsyncEngine):
         the host skips at consume). Pages are provisioned one extra
         window ahead; returns None (chain break) when the pool can't
         cover a row."""
-        cfg = self.cfg
-        ps, K = cfg.page_size, cfg.decode_window
-        nb = pending.nb
-        max_pos = np.full(nb, -1, np.int32)
-        table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
-        stop_set = pending.stop_tokens  # same rows, same stop sets
-        eos_gate = np.zeros(nb, np.int32)
-        budget_gate = np.full(nb, K, np.int32)
-        rows: list[_RaggedRow] = []
-        max_pages = 1
-        capacity_capped = False
-        for e in pending.rows:
-            seq, r = e.seq, e.row
-            wpos = len(seq.tokens) - 1 + K  # host view + inflight window
-            self.sched.ensure_pages_until(seq, wpos + K - 1)
-            cap = min(cfg.max_model_len, len(seq.page_ids) * ps) - 1
-            if cap < wpos:
-                return None  # pool dry: consume + rebuild instead
-            capacity_capped = capacity_capped or cap < wpos + K
-            max_pos[r] = cap
-            table[r, : len(seq.page_ids)] = seq.page_ids
-            max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
-            eos_gate[r], budget_gate[r] = self._stop_gates(
-                seq, seq.generated + K
+        with self._phase("assemble"):
+            cfg = self.cfg
+            ps, K = cfg.page_size, cfg.decode_window
+            nb = pending.nb
+            max_pos = np.full(nb, -1, np.int32)
+            table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
+            stop_set = pending.stop_tokens  # same rows, same stop sets
+            eos_gate = np.zeros(nb, np.int32)
+            budget_gate = np.full(nb, K, np.int32)
+            rows: list[_RaggedRow] = []
+            max_pages = 1
+            capacity_capped = False
+            for e in pending.rows:
+                seq, r = e.seq, e.row
+                wpos = len(seq.tokens) - 1 + K  # host view + inflight window
+                self.sched.ensure_pages_until(seq, wpos + K - 1)
+                cap = min(cfg.max_model_len, len(seq.page_ids) * ps) - 1
+                if cap < wpos:
+                    return None  # pool dry: consume + rebuild instead
+                capacity_capped = capacity_capped or cap < wpos + K
+                max_pos[r] = cap
+                table[r, : len(seq.page_ids)] = seq.page_ids
+                max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
+                eos_gate[r], budget_gate[r] = self._stop_gates(
+                    seq, seq.generated + K
+                )
+                rows.append(
+                    _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
+                )
+            n_variants = len(self._ragged_fns)
+            fn = self._ragged_fn(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
+                nb,
+                cfg.ragged_page_bucket_for(max_pages),
+                True,
+                pending.full_sampler,
+                pending.want_lp,
             )
-            rows.append(
-                _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
-            )
-        n_variants = len(self._ragged_fns)
-        fn = self._ragged_fn(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
-            nb,
-            cfg.ragged_page_bucket_for(max_pages),
-            True,
-            pending.full_sampler,
-            pending.want_lp,
-        )
-        fresh = len(self._ragged_fns) > n_variants
-        self._flush_offloads()
+            fresh = len(self._ragged_fns) > n_variants
+        if self._pending_offloads:
+            with self._phase("kv_admin"):
+                self._flush_offloads()
         prof = self.profiler
-        t0 = prof.begin("ragged") if prof is not None else 0.0
+        t0 = prof.begin("ragged", "window") if prof is not None else 0.0
         if pending.full_sampler:
             seeds, temp, top_k, top_p, freq, pres, rep = pending.sampler_args
             (ys, self.k_cache, self.v_cache, self._counts,
@@ -3614,7 +3703,7 @@ class TPUEngine(AsyncEngine):
                 chained=True,
             )
         self.steps += K
-        get_telemetry().decode_batch_rows.observe(len(rows))
+        get_telemetry().decode_batch_rows.labels("window").observe(len(rows))
         return _PendingRagged(
             ys=ys,
             rows=rows,
@@ -3646,64 +3735,66 @@ class TPUEngine(AsyncEngine):
         (page release) until that successor is force-consumed — the
         device already parked the row at position -1, so the successor
         writes nothing for it."""
-        if pending.want_lp:
-            sampled, lps, top_ids, top_lps = (
-                np.asarray(y) for y in pending.ys  # dynlint: sync-point(ragged consume)
-            )
-        else:
-            sampled = np.asarray(pending.ys[0])  # dynlint: sync-point(ragged consume)
+        with self._phase("sync"):
+            if pending.want_lp:
+                sampled, lps, top_ids, top_lps = (
+                    np.asarray(y) for y in pending.ys  # dynlint: sync-point(ragged consume)
+                )
+            else:
+                sampled = np.asarray(pending.ys[0])  # dynlint: sync-point(ragged consume)
         if self.profiler is not None:
             # The np.asarray above was this window's one host sync.
-            self.profiler.consume("ragged", pending.dispatched_at)
-        tel = get_telemetry()
-        finishes: list[Sequence] = []
-        wasted = 0
-        emitted = 0
-        for e in pending.rows:
-            seq, n_valid, row = e.seq, e.n_valid, e.row
-            if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
-                wasted += n_valid  # whole window past this row's stop
-                continue
-            kept: list[int] = []
-            reason = None
-            for token in sampled[:n_valid, row]:
-                token = int(token)
-                kept.append(token)
-                seq.tokens.append(token)
-                seq.generated += 1
-                reason = self.sched.check_stop(seq, token)
+            self.profiler.consume("ragged", pending.dispatched_at, "window")
+        with self._phase("consume"):
+            tel = get_telemetry()
+            finishes: list[Sequence] = []
+            wasted = 0
+            emitted = 0
+            for e in pending.rows:
+                seq, n_valid, row = e.seq, e.n_valid, e.row
+                if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
+                    wasted += n_valid  # whole window past this row's stop
+                    continue
+                kept: list[int] = []
+                reason = None
+                for token in sampled[:n_valid, row]:
+                    token = int(token)
+                    kept.append(token)
+                    seq.tokens.append(token)
+                    seq.generated += 1
+                    reason = self.sched.check_stop(seq, token)
+                    if reason is not None:
+                        break
+                wasted += n_valid - len(kept)
+                emitted += len(kept)
+                self.sched.register_full_pages(seq)
+                n_top = self._wants_logprobs(seq)
+                pack = None
+                if n_top is not None and kept:
+                    n = len(kept)
+                    pack = self._lp_pack(
+                        n_top,
+                        lps[:n, row],
+                        top_ids[:n, row],
+                        top_lps[:n, row],
+                    )
+                if kept:
+                    now = time.time()
+                    if seq.last_emit_at:
+                        tbt = max(now - seq.last_emit_at, 0.0) / len(kept)
+                        tel.time_between_tokens.observe(tbt)
+                    seq.last_emit_at = now
+                seq.emit(kept, None, pack)
                 if reason is not None:
-                    break
-            wasted += n_valid - len(kept)
-            emitted += len(kept)
-            self.sched.register_full_pages(seq)
-            n_top = self._wants_logprobs(seq)
-            pack = None
-            if n_top is not None and kept:
-                n = len(kept)
-                pack = self._lp_pack(
-                    n_top,
-                    lps[:n, row],
-                    top_ids[:n, row],
-                    top_lps[:n, row],
+                    seq.pending_finish = reason
+                    finishes.append(seq)
+            if self.flight is not None:
+                self.flight.record(
+                    "consume", dispatch="ragged", tokens=emitted, wasted=wasted
                 )
-            if kept:
-                now = time.time()
-                if seq.last_emit_at:
-                    tbt = max(now - seq.last_emit_at, 0.0) / len(kept)
-                    tel.time_between_tokens.observe(tbt)
-                seq.last_emit_at = now
-            seq.emit(kept, None, pack)
-            if reason is not None:
-                seq.pending_finish = reason
-                finishes.append(seq)
-        if self.flight is not None:
-            self.flight.record(
-                "consume", dispatch="ragged", tokens=emitted, wasted=wasted
-            )
-        if wasted:
-            self.wasted_steps += wasted
-            tel.decode_wasted_steps.inc(wasted)
+            if wasted:
+                self.wasted_steps += wasted
+                tel.decode_wasted_steps.inc(wasted)
         if finishes:
             # Pages about to be released must not have a window in
             # flight over them: sync the chained successor first (its
@@ -3712,9 +3803,10 @@ class TPUEngine(AsyncEngine):
             succ, self._inflight = self._inflight, None
             if succ is not None:
                 self._consume_ragged(succ)
-            for seq in finishes:
-                reason, seq.pending_finish = seq.pending_finish, None
-                self.sched.finish(seq, reason)
+            with self._phase("consume"):
+                for seq in finishes:
+                    reason, seq.pending_finish = seq.pending_finish, None
+                    self.sched.finish(seq, reason)
 
     def _consume_mixed(self, pending: _PendingRagged) -> None:
         """Host sync of one mixed ragged batch: decode rows emit their
@@ -3726,7 +3818,8 @@ class TPUEngine(AsyncEngine):
         Mixed batches are never chained over, so finishes (and their
         page releases) are safe immediately."""
         spec_on = pending.with_spec
-        ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
+        with self._phase("sync"):
+            ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
         tok0 = ys[0]
         i = 1
         if spec_on:
@@ -3738,120 +3831,121 @@ class TPUEngine(AsyncEngine):
             if spec_on:
                 s_lps, s_tids, s_tlps = ys[i], ys[i + 1], ys[i + 2]
         if self.profiler is not None:
-            self.profiler.consume("ragged", pending.dispatched_at)
-        if self.flight is not None:
-            self.flight.record(
-                "consume", dispatch="ragged", rows=len(pending.rows)
-            )
-        tel = get_telemetry()
-        for e in pending.rows:
-            seq, r = e.seq, e.row
-            if e.kind == "prefill":
-                if not e.completing:
-                    continue
-                n_top = self._wants_logprobs(seq)
-                pack = (
-                    self._lp_pack(
-                        n_top, lp0[r : r + 1],
-                        tid0[r : r + 1], tlp0[r : r + 1],
-                    )
-                    if pending.want_lp and n_top is not None
-                    else None
-                )
-                self._finish_first_token(seq, int(tok0[r]), pack)
-                continue
-            if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
-                self.wasted_steps += 1
-                tel.decode_wasted_steps.inc()
-                continue
-            if e.kind == "decode":
-                token = int(tok0[r])
-                seq.tokens.append(token)
-                seq.generated += 1
-                reason = self.sched.check_stop(seq, token)
-                self.sched.register_full_pages(seq)
-                n_top = self._wants_logprobs(seq)
-                pack = (
-                    self._lp_pack(
-                        n_top, lp0[r : r + 1],
-                        tid0[r : r + 1], tlp0[r : r + 1],
-                    )
-                    if pending.want_lp and n_top is not None
-                    else None
-                )
-                now = time.time()
-                if seq.last_emit_at:
-                    tel.time_between_tokens.observe(
-                        max(now - seq.last_emit_at, 0.0)
-                    )
-                seq.last_emit_at = now
-                seq.emit([token], None, pack)
-                if reason is not None:
-                    self.sched.finish(seq, reason)
-                continue
-            # Speculative row: the device already computed the
-            # acceptance (longest draft == target prefix plus the first
-            # correction token — the same rule that gated the on-device
-            # penalty counts); emit those tokens, rewind state past
-            # rejected positions, and feed the outcome back to the
-            # adaptive controller.
-            g = e.n_drafts
-            tgt = targets[r]
-            n_emit = int(n_emits[r])
-            accepted = n_emit - 1
-            kept: list[int] = []
-            reason = None
-            for j in range(n_emit):
-                token = int(tgt[j])
-                kept.append(token)
-                seq.tokens.append(token)
-                seq.generated += 1
-                reason = self.sched.check_stop(seq, token)
-                if reason is not None:
-                    break
-            if n_emit - len(kept):
-                # Tokens past a host-detected stop: computed, discarded.
-                self.wasted_steps += n_emit - len(kept)
-                tel.decode_wasted_steps.inc(n_emit - len(kept))
-            seq.spec_dispatches += 1
-            seq.spec_draft_tokens += g
-            seq.spec_accepted_tokens += accepted
-            seq.spec_emitted_tokens += len(kept)
-            self.spec_row_dispatches += 1
-            self.spec_draft_tokens += g
-            self.spec_accepted_tokens += accepted
-            self.spec_emitted_tokens += len(kept)
-            tel.spec_draft_tokens.inc(g)
-            tel.spec_accepted_tokens.inc(accepted)
-            tel.spec_tokens_per_dispatch.observe(len(kept))
+            self.profiler.consume("ragged", pending.dispatched_at, "mixed")
+        with self._phase("consume"):
             if self.flight is not None:
                 self.flight.record(
-                    "spec_accept",
-                    req=seq.request_id,
-                    proposed=g,
-                    accepted=accepted,
-                    emitted=len(kept),
+                    "consume", dispatch="ragged", rows=len(pending.rows)
                 )
-            self._spec.record(seq, proposed=g, accepted=accepted)
-            self.sched.register_full_pages(seq)
-            n_top = self._wants_logprobs(seq)
-            pack = None
-            if n_top is not None and kept:
-                n = len(kept)
-                pack = self._lp_pack(
-                    n_top, s_lps[r, :n], s_tids[r, :n], s_tlps[r, :n]
-                )
-            if kept:
-                now = time.time()
-                if seq.last_emit_at:
-                    tbt = max(now - seq.last_emit_at, 0.0) / len(kept)
-                    tel.time_between_tokens.observe(tbt)
-                seq.last_emit_at = now
-            seq.emit(kept, None, pack)
-            if reason is not None:
-                self.sched.finish(seq, reason)
-            else:
-                self._rewind_spec_pages(seq)
+            tel = get_telemetry()
+            for e in pending.rows:
+                seq, r = e.seq, e.row
+                if e.kind == "prefill":
+                    if not e.completing:
+                        continue
+                    n_top = self._wants_logprobs(seq)
+                    pack = (
+                        self._lp_pack(
+                            n_top, lp0[r : r + 1],
+                            tid0[r : r + 1], tlp0[r : r + 1],
+                        )
+                        if pending.want_lp and n_top is not None
+                        else None
+                    )
+                    self._finish_first_token(seq, int(tok0[r]), pack)
+                    continue
+                if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
+                    self.wasted_steps += 1
+                    tel.decode_wasted_steps.inc()
+                    continue
+                if e.kind == "decode":
+                    token = int(tok0[r])
+                    seq.tokens.append(token)
+                    seq.generated += 1
+                    reason = self.sched.check_stop(seq, token)
+                    self.sched.register_full_pages(seq)
+                    n_top = self._wants_logprobs(seq)
+                    pack = (
+                        self._lp_pack(
+                            n_top, lp0[r : r + 1],
+                            tid0[r : r + 1], tlp0[r : r + 1],
+                        )
+                        if pending.want_lp and n_top is not None
+                        else None
+                    )
+                    now = time.time()
+                    if seq.last_emit_at:
+                        tel.time_between_tokens.observe(
+                            max(now - seq.last_emit_at, 0.0)
+                        )
+                    seq.last_emit_at = now
+                    seq.emit([token], None, pack)
+                    if reason is not None:
+                        self.sched.finish(seq, reason)
+                    continue
+                # Speculative row: the device already computed the
+                # acceptance (longest draft == target prefix plus the first
+                # correction token — the same rule that gated the on-device
+                # penalty counts); emit those tokens, rewind state past
+                # rejected positions, and feed the outcome back to the
+                # adaptive controller.
+                g = e.n_drafts
+                tgt = targets[r]
+                n_emit = int(n_emits[r])
+                accepted = n_emit - 1
+                kept: list[int] = []
+                reason = None
+                for j in range(n_emit):
+                    token = int(tgt[j])
+                    kept.append(token)
+                    seq.tokens.append(token)
+                    seq.generated += 1
+                    reason = self.sched.check_stop(seq, token)
+                    if reason is not None:
+                        break
+                if n_emit - len(kept):
+                    # Tokens past a host-detected stop: computed, discarded.
+                    self.wasted_steps += n_emit - len(kept)
+                    tel.decode_wasted_steps.inc(n_emit - len(kept))
+                seq.spec_dispatches += 1
+                seq.spec_draft_tokens += g
+                seq.spec_accepted_tokens += accepted
+                seq.spec_emitted_tokens += len(kept)
+                self.spec_row_dispatches += 1
+                self.spec_draft_tokens += g
+                self.spec_accepted_tokens += accepted
+                self.spec_emitted_tokens += len(kept)
+                tel.spec_draft_tokens.inc(g)
+                tel.spec_accepted_tokens.inc(accepted)
+                tel.spec_tokens_per_dispatch.observe(len(kept))
+                if self.flight is not None:
+                    self.flight.record(
+                        "spec_accept",
+                        req=seq.request_id,
+                        proposed=g,
+                        accepted=accepted,
+                        emitted=len(kept),
+                    )
+                self._spec.record(seq, proposed=g, accepted=accepted)
+                self.sched.register_full_pages(seq)
+                n_top = self._wants_logprobs(seq)
+                pack = None
+                if n_top is not None and kept:
+                    n = len(kept)
+                    pack = self._lp_pack(
+                        n_top, s_lps[r, :n], s_tids[r, :n], s_tlps[r, :n]
+                    )
+                if kept:
+                    now = time.time()
+                    if seq.last_emit_at:
+                        tbt = max(now - seq.last_emit_at, 0.0) / len(kept)
+                        tel.time_between_tokens.observe(tbt)
+                    seq.last_emit_at = now
+                seq.emit(kept, None, pack)
+                if reason is not None:
+                    self.sched.finish(seq, reason)
+                else:
+                    self._rewind_spec_pages(seq)
 
     # --------------------------------------------------------------- metrics
     def metrics(self) -> dict:

@@ -235,29 +235,47 @@ def _attn_mlp_layer(
 
     Family variations live in the param pytree: ``bq/bk/bv`` present =
     QKV bias (qwen2); ``router`` present = sparse-MoE FFN (mixtral).
+
+    The ``jax.named_scope`` blocks (``qkv``, ``attn_out``, ``ffn`` /
+    ``moe`` here; ``kv_write`` and ``attend`` in the callers' ``attend``)
+    are HLO metadata only: they name each operation's place in the layer
+    for a profiler trace (docs/observability.md "Engine phases and the
+    device trace") and change no operation.
     """
     B, T = x.shape[:2]
     hd = cfg.head_dim_
     off = cfg.rms_norm_offset
     red = reduce if reduce is not None else (lambda y: y)
-    h = rms_norm(x, lp["attn_norm"], eps, off)
-    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-    if "bq" in lp:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, T, lp["wq"].shape[-1] // hd, hd)
-    k = k.reshape(B, T, lp["wk"].shape[-1] // hd, hd)
-    v = v.reshape(B, T, lp["wv"].shape[-1] // hd, hd)
-    if "q_norm" in lp:  # qwen3/gemma3: per-head RMSNorm before rope
-        # (gemma3 stores these gemma-style: scale = 1 + w)
-        q = rms_norm(q, lp["q_norm"], eps, off)
-        k = rms_norm(k, lp["k_norm"], eps, off)
-    q = apply_rope(q, rope_pos, inv_freq)
-    k = apply_rope(k, rope_pos, inv_freq)
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, lp["attn_norm"], eps, off)
+        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+        if "bq" in lp:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q.reshape(B, T, lp["wq"].shape[-1] // hd, hd)
+        k = k.reshape(B, T, lp["wk"].shape[-1] // hd, hd)
+        v = v.reshape(B, T, lp["wv"].shape[-1] // hd, hd)
+        if "q_norm" in lp:  # qwen3/gemma3: per-head RMSNorm before rope
+            # (gemma3 stores these gemma-style: scale = 1 + w)
+            q = rms_norm(q, lp["q_norm"], eps, off)
+            k = rms_norm(k, lp["k_norm"], eps, off)
+        q = apply_rope(q, rope_pos, inv_freq)
+        k = apply_rope(k, rope_pos, inv_freq)
     attn, kv_extra = attend(q, k, v)
-    attn_out = red(attn.reshape(B, T, -1) @ lp["wo"])
-    if "post_attn_norm" in lp:  # gemma2: norm the block OUTPUT too
-        attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps, off)
-    x = x + attn_out
+    with jax.named_scope("attn_out"):
+        attn_out = red(attn.reshape(B, T, -1) @ lp["wo"])
+        if "post_attn_norm" in lp:  # gemma2: norm the block OUTPUT too
+            attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps, off)
+        x = x + attn_out
+    with jax.named_scope("moe" if "router" in lp else "ffn"):
+        x = _ffn_block(x, lp, cfg, eps, red, mesh)
+    return x, kv_extra
+
+
+def _ffn_block(x, lp, cfg, eps, red, mesh):
+    """The layer's second half: norm, dense SwiGLU or sparse-MoE FFN,
+    residual add."""
+    B, T = x.shape[:2]
+    off = cfg.rms_norm_offset
     h = rms_norm(x, lp["mlp_norm"], eps, off)
     if "router" in lp:
         from ..ops.moe import moe_ffn, moe_ffn_ep
@@ -306,26 +324,29 @@ def _attn_mlp_layer(
         if "post_ffn_norm" in lp:  # gemma2
             ffn_out = rms_norm(ffn_out, lp["post_ffn_norm"], eps, off)
         x = x + ffn_out
-    return x, kv_extra
+    return x
 
 
 def _final_logits(params, cfg, x, eps):
-    x = rms_norm(x, params["final_norm"], eps, cfg.rms_norm_offset)
-    head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)
-    if head.dtype == jnp.bfloat16:
-        # Pin the logits to bf16 precision even inside a fused compiled
-        # program: XLA's excess-precision rules may otherwise elide the
-        # bf16 rounding between the matmul and a fused argmax/sampler,
-        # silently un-tying exactly-tied bf16 logits — and greedy
-        # identity across dispatch layouts (eager oracle, windowed
-        # window, mixed ragged batch) depends on every layout rounding
-        # the distribution identically before the tie-break.
-        logits = jax.lax.reduce_precision(logits, 8, 7)
-    if cfg.final_logit_softcap is not None:  # gemma2
-        cap = cfg.final_logit_softcap
-        logits = jnp.tanh(logits / cap) * cap
-    return logits
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"], eps, cfg.rms_norm_offset)
+        head = (
+            params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
+        )
+        logits = (x @ head).astype(jnp.float32)
+        if head.dtype == jnp.bfloat16:
+            # Pin the logits to bf16 precision even inside a fused compiled
+            # program: XLA's excess-precision rules may otherwise elide the
+            # bf16 rounding between the matmul and a fused argmax/sampler,
+            # silently un-tying exactly-tied bf16 logits — and greedy
+            # identity across dispatch layouts (eager oracle, windowed
+            # window, mixed ragged batch) depends on every layout rounding
+            # the distribution identically before the tie-break.
+            logits = jax.lax.reduce_precision(logits, 8, 7)
+        if cfg.final_logit_softcap is not None:  # gemma2
+            cap = cfg.final_logit_softcap
+            logits = jnp.tanh(logits / cap) * cap
+        return logits
 
 
 def _maybe_scale_embeds(cfg, x):
@@ -457,36 +478,36 @@ def forward(
         lp, k_pool, v_pool, win_l, invf_l = layer_in
 
         def attend(q, k, v):
-            kp, vp = write_kv_pages(
-                k_pool,
-                v_pool,
-                k.reshape(B * T, cfg.num_kv_heads * hd),
-                v.reshape(B * T, cfg.num_kv_heads * hd),
-                page_ids,
-                offsets,
-                valid,
-            )
-            if use_pallas:
-                attn = _pallas_decode(
-                    q[:, 0],
-                    kp,
-                    vp,
-                    page_table,
-                    lengths,
-                    cfg.num_kv_heads,
-                    mesh,
-                    interpret,
-                )[:, None]
-                return attn, (kp, vp)
-            return (
-                paged_attention(
-                    q, kp, vp, attn_table, positions,
-                    sm_scale=sm_scale,
-                    window=win_l if have_window else None,
-                    softcap=cfg.attn_logit_softcap,
-                ),
-                (kp, vp),
-            )
+            with jax.named_scope("kv_write"):
+                kp, vp = write_kv_pages(
+                    k_pool,
+                    v_pool,
+                    k.reshape(B * T, cfg.num_kv_heads * hd),
+                    v.reshape(B * T, cfg.num_kv_heads * hd),
+                    page_ids,
+                    offsets,
+                    valid,
+                )
+            with jax.named_scope("attend"):
+                if use_pallas:
+                    attn = _pallas_decode(
+                        q[:, 0],
+                        kp,
+                        vp,
+                        page_table,
+                        lengths,
+                        cfg.num_kv_heads,
+                        mesh,
+                        interpret,
+                    )[:, None]
+                else:
+                    attn = paged_attention(
+                        q, kp, vp, attn_table, positions,
+                        sm_scale=sm_scale,
+                        window=win_l if have_window else None,
+                        softcap=cfg.attn_logit_softcap,
+                    )
+            return attn, (kp, vp)
 
         return _attn_mlp_layer(
             x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh
@@ -690,29 +711,33 @@ def forward_ragged(
         lp, k_pool, v_pool, win_l, invf_l = layer_in
 
         def attend(q, k, v):
-            kp, vp = write_kv_pages(
-                k_pool,
-                v_pool,
-                k.reshape(N, cfg.num_kv_heads * hd),
-                v.reshape(N, cfg.num_kv_heads * hd),
-                page_ids,
-                offsets,
-                valid,
-            )
-            if use_pallas:
-                attn = _pallas_ragged(
-                    q[0], kp, vp, attn_table, row_of, positions,
-                    cfg.num_kv_heads, q_tile, mesh, interpret,
-                )[None]
-                return attn, (kp, vp)
-            from ..ops.ragged_attention import ragged_paged_attention_ref
+            with jax.named_scope("kv_write"):
+                kp, vp = write_kv_pages(
+                    k_pool,
+                    v_pool,
+                    k.reshape(N, cfg.num_kv_heads * hd),
+                    v.reshape(N, cfg.num_kv_heads * hd),
+                    page_ids,
+                    offsets,
+                    valid,
+                )
+            with jax.named_scope("attend"):
+                if use_pallas:
+                    attn = _pallas_ragged(
+                        q[0], kp, vp, attn_table, row_of, positions,
+                        cfg.num_kv_heads, q_tile, mesh, interpret,
+                    )[None]
+                else:
+                    from ..ops.ragged_attention import (
+                        ragged_paged_attention_ref,
+                    )
 
-            attn = ragged_paged_attention_ref(
-                q[0], kp, vp, attn_table, row_of, positions,
-                num_kv_heads=cfg.num_kv_heads, sm_scale=sm_scale,
-                window=win_l if have_window else None,
-                softcap=cfg.attn_logit_softcap,
-            )[None]
+                    attn = ragged_paged_attention_ref(
+                        q[0], kp, vp, attn_table, row_of, positions,
+                        num_kv_heads=cfg.num_kv_heads, sm_scale=sm_scale,
+                        window=win_l if have_window else None,
+                        softcap=cfg.attn_logit_softcap,
+                    )[None]
             return attn, (kp, vp)
 
         return _attn_mlp_layer(

@@ -1,4 +1,4 @@
-"""Profiling hooks: jax.profiler server + on-demand trace capture.
+"""Profiling hooks: jax.profiler server + a traced block.
 
 Reference capability: SURVEY.md §5 tracing — the reference has JSONL
 tracing but no accelerator profiler; the TPU-native extension is
@@ -7,8 +7,9 @@ tracing but no accelerator profiler; the TPU-native extension is
 - ``start_profiler_server(port)`` — expose the live profiling gRPC
   endpoint so ``tensorboard --logdir`` or ``xprof`` can attach to a
   serving worker (``run.py --profiler-port``).
-- ``capture_trace(dir, duration_ms)`` — one-shot programmatic capture
-  around the engine's hot loop.
+- ``trace_to(dir)`` — trace the enclosed block; the engine loop's
+  ``dyn/<phase>`` annotations and the model's named scopes land in it
+  (docs/observability.md "Engine phases and the device trace").
 """
 
 from __future__ import annotations
@@ -53,11 +54,3 @@ def trace_to(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def capture_trace(log_dir: str, duration_ms: int = 2000) -> None:
-    """Capture ``duration_ms`` of device activity into ``log_dir``."""
-    import time
-
-    with trace_to(log_dir):
-        time.sleep(duration_ms / 1000.0)

@@ -36,6 +36,17 @@ arxiv 2506.03296).
 Samples live in bounded per-kind reservoirs (recent-window deques), so
 ``summary()`` percentiles are cheap and memory is O(1); lifetime totals
 ride the prometheus histograms.
+
+**Engine-loop phases** (docs/observability.md "Engine phases and the
+device trace"). The same object says what the loop thread was doing
+between those stamps: ``phase(name)`` brackets one stretch of the loop
+body, adds its host time to ``dynamo_engine_phase_seconds_total{phase}``
+and opens a ``jax.profiler.TraceAnnotation("dyn/<name>")`` — about a
+microsecond and nothing written while no profiler session is on; while
+one is, an event on the profiler's own clock in the loop thread's line
+of the host plane, beside the device's ``XLA Ops``. The ``launch`` phase
+is the interval ``begin``/``end`` already bracket at a ragged dispatch,
+so it costs no clock read of its own. Phases do not nest.
 """
 
 from __future__ import annotations
@@ -53,6 +64,30 @@ from .slo import percentile
 # bench files for back-compat).
 DISPATCH_KINDS = ("ragged", "kv_move", "offload")
 
+# Which compiled program a ragged dispatch ran: a pure-decode ``window``
+# (``decode_window`` steps on the device) or a ``mixed`` batch (prefill
+# chunks, single decode steps, spec spans). The ``program`` label of the
+# dispatch histograms; the other kinds carry ``-``.
+PROGRAMS = ("window", "mixed")
+NO_PROGRAM = "-"
+
+# The engine loop's phases. Closed set: the ``phase`` label space and
+# the ``dyn/<phase>`` names a trace reader cuts idle gaps along.
+#   intake    submissions drained, cancellations polled, dead waiters reaped
+#   kv_admin  leases/pins/reclaims, ledger audit, offload, preemption,
+#             prefetch apply/plan, swap-in, remote inject, eviction flush
+#   admit     the admit_next loop
+#   assemble  the host arrays of one dispatch (and the choice of program)
+#   launch    the jitted call with its host-to-device transfers
+#   sync      np.asarray of a dispatch's outputs: the host waits here
+#   consume   emission, check_stop, page registration, finishes
+#   publish   gauge mirroring
+#   park      waiting on the wake event: no work, or everything stalled
+PHASES = (
+    "intake", "kv_admin", "admit", "assemble", "launch", "sync", "consume",
+    "publish", "park",
+)
+
 # Summary stat fields (also the bench JSON / docs contract).
 SUMMARY_FIELDS = (
     "count",
@@ -63,6 +98,31 @@ SUMMARY_FIELDS = (
     "compile_misses",
     "compile_total_s",
 )
+
+
+class _Phase:
+    """One stretch of the loop body under a phase name (see
+    :meth:`DispatchProfiler.phase`). One object a phase, reused: the
+    loop is single-threaded and phases do not nest."""
+
+    __slots__ = ("_prof", "_name", "_label", "_ann", "_t0")
+
+    def __init__(self, prof: "DispatchProfiler", name: str):
+        self._prof, self._name, self._label = prof, name, f"dyn/{name}"
+        self._ann = None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._ann = self._prof._annotation(self._label)
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        self._prof._add_phase(self._name, dt)
+        return False
 
 
 class DispatchProfiler:
@@ -93,15 +153,47 @@ class DispatchProfiler:
         # kernels key variants by bucket shape, invisibly to the
         # engine-level fn caches).
         self._seen_variants: set = set()
+        # Imported here, not at the top: the telemetry package is also
+        # imported by processes that never load jax (frontends, the sim).
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._phases = {name: _Phase(self, name) for name in PHASES}
+        self._phase_s = dict.fromkeys(PHASES, 0.0)
+        self._launch_ann = None  # open between begin() and end() of a launch
+
+    # -------------------------------------------------------------- phases
+    def phase(self, name: str) -> _Phase:
+        """Context manager round one stretch of the engine loop (a name
+        from :data:`PHASES`): its host time goes to
+        ``dynamo_engine_phase_seconds_total{phase}`` and, while a
+        profiler session is on, a ``dyn/<name>`` event goes to the
+        trace. Phases do not nest; ``launch`` is not opened here but by
+        ``begin``/``end`` at a ragged dispatch."""
+        return self._phases[name]
+
+    def _add_phase(self, name: str, seconds: float) -> None:
+        self._phase_s[name] += seconds
+        if self._tel is not None:
+            self._tel.engine_phase_seconds.labels(name).inc(seconds)
+
+    def phase_seconds(self) -> dict[str, float]:
+        """Host seconds so far by phase (every phase always present)."""
+        return dict(self._phase_s)
 
     # ------------------------------------------------------------ dispatch
-    def begin(self, kind: str) -> float:
+    def begin(self, kind: str, program: str = NO_PROGRAM) -> float:
         """Immediately before the dispatch call; returns its timestamp
         (pass to :meth:`end` and stash for :meth:`consume`). The host
         gap since the kind's previous consume is recorded only when no
         other dispatch activity intervened — back-to-back work of the
         same kind, the case where the elapsed time really is host
-        overhead."""
+        overhead. A ``program`` (ragged dispatches) also opens the
+        loop's ``launch`` phase, which :meth:`end` closes on the
+        timestamp it takes anyway."""
+        if program != NO_PROGRAM:
+            self._launch_ann = self._annotation("dyn/launch")
+            self._launch_ann.__enter__()
         now = time.monotonic()
         last = self._last_consume.get(kind)
         self._event_seq += 1
@@ -109,7 +201,7 @@ class DispatchProfiler:
             gap = max(now - last[0], 0.0)
             self._gap[kind].append(gap)
             if self._tel is not None:
-                self._tel.host_gap_seconds.labels(kind).observe(gap)
+                self._tel.host_gap_seconds.labels(kind, program).observe(gap)
         return now
 
     def end(self, kind: str, t0: float, fresh: bool = False) -> float:
@@ -119,6 +211,10 @@ class DispatchProfiler:
         the call; steady-state calls only enqueue). Returns the
         dispatch-completion timestamp for :meth:`consume`."""
         now = time.monotonic()
+        if self._launch_ann is not None:
+            self._launch_ann.__exit__(None, None, None)
+            self._launch_ann = None
+            self._add_phase("launch", max(now - t0, 0.0))
         self._count[kind] += 1
         if fresh:
             dur = max(now - t0, 0.0)
@@ -157,7 +253,9 @@ class DispatchProfiler:
             self._seen_variants.add((family, key))
 
     # ------------------------------------------------------------- consume
-    def consume(self, kind: str, t_dispatch: float) -> None:
+    def consume(
+        self, kind: str, t_dispatch: float, program: str = NO_PROGRAM
+    ) -> None:
         """Immediately after the dispatch's existing host sync. Records
         in-flight time and arms the kind's host-gap reference."""
         now = time.monotonic()
@@ -165,7 +263,9 @@ class DispatchProfiler:
             flight = max(now - t_dispatch, 0.0)
             self._flight[kind].append(flight)
             if self._tel is not None:
-                self._tel.dispatch_seconds.labels(kind).observe(flight)
+                self._tel.dispatch_seconds.labels(kind, program).observe(
+                    flight
+                )
         self._event_seq += 1
         self._last_consume[kind] = (now, self._event_seq)
 

@@ -57,6 +57,13 @@ _DISPATCH_BUCKETS = (
 )
 
 # Engine gauges: metrics()-dict key -> (prometheus name, help).
+# jax/_src/dispatch.py's event names (jax 0.9) to the ``stage`` label.
+_JAX_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+
 _ENGINE_GAUGES = (
     ("hbm_page_occupancy", "Fraction of device KV pages in use"),
     ("offload_hit_rate", "G2 host-tier hit rate (hits / (hits+misses))"),
@@ -184,7 +191,11 @@ class Telemetry:
         # thread-safe); the gauges ride the engine-gauge publisher.
         self.decode_batch_rows = Histogram(
             "dynamo_decode_batch_rows",
-            "True (uncompacted-slot-free) rows per decode window dispatch",
+            "Decode rows of each ragged dispatch, by program: a window's "
+            "true (uncompacted-slot-free) rows, or the decode and spec "
+            "rows a mixed batch carries beside its prefill chunks "
+            "(0 when it has none)",
+            ["program"],  # window | mixed
             buckets=(1, 2, 4, 8, 16, 32, 64, 128),
             registry=self.registry,
         )
@@ -406,8 +417,11 @@ class Telemetry:
         self.dispatch_seconds = Histogram(
             "dynamo_dispatch_seconds",
             "Device dispatch in-flight time (dispatch to the existing "
-            "host sync), by dispatch kind",
-            ["kind"],  # ragged | kv_move | offload (see telemetry/dispatch.py)
+            "host sync), by dispatch kind and, for ragged dispatches, "
+            "compiled program",
+            # ragged | kv_move | offload x window | mixed | - (see
+            # telemetry/dispatch.py)
+            ["kind", "program"],
             buckets=_DISPATCH_BUCKETS,
             registry=self.registry,
         )
@@ -415,7 +429,7 @@ class Telemetry:
             "dynamo_host_gap_seconds",
             "Host gap between consuming a dispatch and issuing the "
             "kind's next one (~0 in the overlapped steady state)",
-            ["kind"],
+            ["kind", "program"],
             buckets=_DISPATCH_BUCKETS,
             registry=self.registry,
         )
@@ -537,6 +551,51 @@ class Telemetry:
             "extract->ack lease-transfer durations) — the input surface "
             "for topology-aware decode-instance selection",
             ["src", "dst"],
+            registry=self.registry,
+        )
+        # Engine phases and the device trace (docs/observability.md):
+        # what the engine loop's thread spent its time on, by phase
+        # (fed by DispatchProfiler.phase, the same instrumentation that
+        # writes dyn/<phase> events into a profiler trace); the KV
+        # pools' geometry; JAX's own trace/lower/compile events, which
+        # see the re-lowering of an already-built program that
+        # dynamo_compile_cache_misses_total cannot; and prompt tokens
+        # by whether admission found them cached.
+        self.engine_phase_seconds = Counter(
+            "dynamo_engine_phase_seconds_total",
+            "Host seconds of the engine loop's thread, by loop phase "
+            "(sync = waiting for a dispatch's outputs, park = no work)",
+            ["phase"],  # telemetry/dispatch.py PHASES
+            registry=self.registry,
+        )
+        self.kv_pool_info = Gauge(
+            "dynamo_kv_pool_info",
+            "Constant-1 geometry of each of the two device KV pools "
+            "(K and V): stacked layers, pages, tokens a page, fused "
+            "kv-head width, element type",
+            ["layers", "pages", "page_size", "width", "dtype"],
+            registry=self.registry,
+        )
+        self.jax_compile_events = Counter(
+            "dynamo_jax_compile_events_total",
+            "JAX compile-pipeline events in this process, by stage "
+            "(trace = jaxpr tracing, lower = jaxpr to MLIR, "
+            "backend_compile = XLA compile or persistent-cache load)",
+            ["stage"],
+            registry=self.registry,
+        )
+        self.jax_compile_seconds = Counter(
+            "dynamo_jax_compile_seconds_total",
+            "Seconds spent in JAX compile-pipeline events, by stage",
+            ["stage"],
+            registry=self.registry,
+        )
+        self._jax_compile_listener = None
+        self.prompt_tokens = Counter(
+            "dynamo_prompt_tokens_total",
+            "Prompt tokens at admission by source: cached (served from "
+            "attached or restored KV pages) or computed (prefilled)",
+            ["source"],  # cached | computed
             registry=self.registry,
         )
         self.build_info = Gauge(
@@ -680,6 +739,36 @@ class Telemetry:
             str(device_count),
             attn_impl or "unknown",
         ).set(1)
+
+    def set_kv_pool_info(
+        self, layers: int, pages: int, page_size: int, width: int, dtype: str
+    ) -> None:
+        """Publish the constant-1 ``dynamo_kv_pool_info`` sample (one
+        label set an instance, like ``set_build_info``)."""
+        self.kv_pool_info.clear()
+        self.kv_pool_info.labels(
+            str(layers), str(pages), str(page_size), str(width), dtype
+        ).set(1)
+
+    def watch_jax_compiles(self) -> None:
+        """Count JAX's own trace / lower / backend-compile events into
+        ``dynamo_jax_compile_events_total{stage}`` and
+        ``dynamo_jax_compile_seconds_total{stage}``. Registers one
+        ``jax.monitoring`` listener, once; called by whoever builds an
+        engine, so a process that never loads jax never pays the
+        import."""
+        if self._jax_compile_listener is not None:
+            return
+        import jax.monitoring
+
+        def on_duration(event: str, seconds: float, **_kw) -> None:
+            stage = _JAX_COMPILE_STAGES.get(event)
+            if stage is not None:
+                self.jax_compile_events.labels(stage).inc()
+                self.jax_compile_seconds.labels(stage).inc(seconds)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        self._jax_compile_listener = on_duration
 
     def publish_engine_gauges(self, metrics: dict) -> None:
         """Mirror an engine ``metrics()`` dict into the engine gauges

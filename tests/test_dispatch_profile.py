@@ -151,8 +151,18 @@ async def test_all_dispatch_kinds_profiled_in_mixed_run():
         ) >= 1
 
         rendered = get_telemetry().render().decode()
-        for kind in DISPATCH_KINDS:
-            assert f'dynamo_dispatch_seconds_count{{kind="{kind}"}}' in rendered
+        # A ragged dispatch is labelled by its compiled program (a
+        # decode window or a mixed batch); the page-move kinds carry
+        # none. Window + mixed is what ``{kind="ragged"}`` sums to:
+        # tests/test_engine_phases.py.
+        for kind, program in (
+            ("ragged", "window"), ("ragged", "mixed"),
+            ("kv_move", "-"), ("offload", "-"),
+        ):
+            assert (
+                f'dynamo_dispatch_seconds_count{{kind="{kind}",'
+                f'program="{program}"}}' in rendered
+            )
             assert f'kind="{kind}"' in rendered
         assert "dynamo_host_gap_seconds_bucket" in rendered
         assert "dynamo_compile_seconds_bucket" in rendered

@@ -1,0 +1,324 @@
+"""Engine phases and the device trace (docs/observability.md).
+
+One instrumentation, read three ways: the loop's phases
+(``DispatchProfiler.phase`` → ``dynamo_engine_phase_seconds_total`` and
+``dyn/<phase>`` trace annotations), the ``program`` label of the
+dispatch histograms, JAX's own compile events, prompt tokens by source,
+the KV pools' geometry, and named scopes that are metadata only.
+"""
+
+import contextlib
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from prometheus_client import CollectorRegistry
+
+from dynamo_exp_tpu.aot.lattice import resolve_ragged_key
+from dynamo_exp_tpu.aot.warmup import variant_call_args
+from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
+from dynamo_exp_tpu.models import TINY, TINY_QWEN3
+from dynamo_exp_tpu.parallel import single_device_mesh
+from dynamo_exp_tpu.protocols.common import BackendInput
+from dynamo_exp_tpu.telemetry import Telemetry, get_telemetry
+from dynamo_exp_tpu.telemetry.dispatch import (
+    NO_PROGRAM,
+    PHASES,
+    PROGRAMS,
+    DispatchProfiler,
+)
+
+PS = 8
+
+
+def _cfg(**over) -> EngineConfig:
+    base = dict(
+        model=TINY,
+        max_decode_slots=2,
+        page_size=PS,
+        num_pages=64,
+        max_model_len=128,
+        eos_token_ids=[],
+        kv_dtype="float32",
+        decode_window=4,
+    )
+    return EngineConfig(**(base | over))
+
+
+async def _generate(engine, prompt, max_tokens=8):
+    b = BackendInput(token_ids=list(prompt))
+    b.stop_conditions.max_tokens = max_tokens
+    b.stop_conditions.ignore_eos = True
+    stream = await engine.generate(b.to_dict())
+    n = 0
+    async for item in stream:
+        n += len(item.get("token_ids", []))
+    return n
+
+
+def _samples(name: str) -> dict[tuple, float]:
+    """``{sorted label items: value}`` of one sample name on the
+    process-wide registry."""
+    out = {}
+    for family in get_telemetry().registry.collect():
+        for s in family.samples:
+            if s.name == name:
+                out[tuple(sorted(s.labels.items()))] = s.value
+    return out
+
+
+def _total(name: str, **match) -> float:
+    return sum(
+        v for labels, v in _samples(name).items()
+        if all(dict(labels).get(k) == val for k, val in match.items())
+    )
+
+
+# ------------------------------------------------------------------- units
+def test_phase_adds_its_host_time_and_launch_rides_begin_end():
+    tel = Telemetry(CollectorRegistry())
+    prof = DispatchProfiler(tel)
+    assert set(prof.phase_seconds()) == set(PHASES)
+    with prof.phase("intake"):
+        time.sleep(0.002)
+    t0 = prof.begin("ragged", "window")
+    time.sleep(0.002)
+    t_disp = prof.end("ragged", t0)
+    prof.consume("ragged", t_disp, "window")
+    # A page move is not a launch: no program, no phase.
+    prof.end("kv_move", prof.begin("kv_move"))
+    got = prof.phase_seconds()
+    assert got["intake"] >= 0.002 and got["launch"] >= 0.002
+    assert sum(got.values()) == pytest.approx(got["intake"] + got["launch"])
+    rendered = tel.render().decode()
+    assert 'dynamo_engine_phase_seconds_total{phase="intake"}' in rendered
+    assert 'dynamo_engine_phase_seconds_total{phase="launch"}' in rendered
+    assert (
+        'dynamo_dispatch_seconds_count{kind="ragged",program="window"} 1.0'
+        in rendered
+    )
+
+
+def test_jax_compile_listener_counts_a_forced_retrace():
+    tel = Telemetry(CollectorRegistry())
+    tel.watch_jax_compiles()
+    tel.watch_jax_compiles()  # registered once
+
+    def count(stage):
+        return tel.jax_compile_events.labels(stage)._value.get()
+
+    f = jax.jit(lambda x: x * 2 + 1)
+    x3, x5 = jnp.ones(3), jnp.ones(5)  # making them compiles too: first
+    f(x3).block_until_ready()
+    first = {s: count(s) for s in ("trace", "lower", "backend_compile")}
+    assert all(v >= 1 for v in first.values())
+    f(x3).block_until_ready()  # cached: nothing is traced again
+    assert {s: count(s) for s in first} == first
+    f(x5).block_until_ready()  # a new shape forces a re-trace
+    assert count("trace") > first["trace"]  # the jaxpr and what it calls
+    assert count("lower") == first["lower"] + 1
+    assert count("backend_compile") == first["backend_compile"] + 1
+    assert tel.jax_compile_seconds.labels("lower")._value.get() > 0.0
+
+
+# --------------------------------------------------- phases round the loop
+async def test_phases_cover_the_loop_and_do_not_nest(monkeypatch):
+    """A recording shim round ``phase`` and ``begin``/``end``: every
+    phase name occurs, none opens while another is open, and together
+    they account for nearly all of the loop thread's time."""
+    events: list[tuple[str, str, float]] = []
+    orig_phase = DispatchProfiler.phase
+    orig_begin, orig_end = DispatchProfiler.begin, DispatchProfiler.end
+
+    class Recorded:
+        def __init__(self, inner, name):
+            self.inner, self.name = inner, name
+
+        def __enter__(self):
+            events.append(("enter", self.name, time.monotonic()))
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            out = self.inner.__exit__(*exc)
+            events.append(("exit", self.name, time.monotonic()))
+            return out
+
+    def begin(self, kind, program=NO_PROGRAM):
+        if program != NO_PROGRAM:
+            events.append(("enter", "launch", time.monotonic()))
+        return orig_begin(self, kind, program)
+
+    def end(self, kind, t0, fresh=False):
+        launching = self._launch_ann is not None
+        out = orig_end(self, kind, t0, fresh)
+        if launching:
+            events.append(("exit", "launch", time.monotonic()))
+        return out
+
+    monkeypatch.setattr(
+        DispatchProfiler, "phase",
+        lambda self, name: Recorded(orig_phase(self, name), name),
+    )
+    monkeypatch.setattr(DispatchProfiler, "begin", begin)
+    monkeypatch.setattr(DispatchProfiler, "end", end)
+
+    before = _total("dynamo_engine_phase_seconds_total")
+    engine = TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
+    engine.start()
+    try:
+        await _generate(engine, range(20, 44), max_tokens=12)
+        await _generate(engine, range(60, 70), max_tokens=6)
+        time.sleep(0.12)  # a few parks on the idle path
+    finally:
+        engine.stop()
+
+    open_phase, covered = None, 0.0
+    for what, name, t in events:
+        if what == "enter":
+            assert open_phase is None, f"{name} opened inside {open_phase[0]}"
+            open_phase = (name, t)
+        else:
+            assert open_phase is not None and open_phase[0] == name
+            covered += t - open_phase[1]
+            open_phase = None
+    assert {name for _, name, _ in events} == set(PHASES)
+    span = events[-1][2] - events[0][2]
+    assert covered / span > 0.8, (covered, span)
+    # The same seconds reached /metrics and the profiler's own totals.
+    counted = _total("dynamo_engine_phase_seconds_total") - before
+    assert counted == pytest.approx(
+        sum(engine.profiler.phase_seconds().values()), rel=1e-6
+    )
+    assert counted == pytest.approx(covered, rel=0.2)
+
+
+async def test_program_label_splits_ragged_and_sums_to_it():
+    def snap():
+        return {
+            name: _samples(name)
+            for name in (
+                "dynamo_dispatch_seconds_count",
+                "dynamo_decode_batch_rows_count",
+                "dynamo_decode_batch_rows_sum",
+            )
+        }
+
+    def delta(after, before, name, **match):
+        def total(s):
+            return sum(
+                v for labels, v in s[name].items()
+                if all(dict(labels).get(k) == x for k, x in match.items())
+            )
+        return total(after) - total(before)
+
+    before = snap()
+    engine = TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
+    engine.start()
+    try:
+        # 20 prompt tokens in 8-token chunks: mixed dispatches, then
+        # pure-decode windows of 4.
+        await _generate(engine, range(20, 40), max_tokens=13)
+        ragged_count = engine.metrics()["dispatch"]["ragged"]["count"]
+    finally:
+        engine.stop()
+    after = snap()
+    name = "dynamo_dispatch_seconds_count"
+    window = delta(after, before, name, kind="ragged", program="window")
+    mixed = delta(after, before, name, kind="ragged", program="mixed")
+    assert window >= 3 and mixed >= 1
+    # What a reader matching only {kind: ragged} sums is both programs.
+    assert window + mixed == delta(after, before, name, kind="ragged")
+    assert window + mixed == ragged_count
+    programs = {
+        dict(labels).get("program")
+        for labels in after[name]
+        if dict(labels).get("kind") == "ragged"
+    }
+    assert programs <= set(PROGRAMS)
+    # A window's rows are its true rows; a prefill-only mixed batch has
+    # no decode row, which is what pulled the unlabelled mean down.
+    rows = "dynamo_decode_batch_rows"
+    n_window = delta(after, before, rows + "_count", program="window")
+    assert n_window == window
+    assert delta(after, before, rows + "_sum", program="window") == n_window
+    assert delta(after, before, rows + "_count", program="mixed") == mixed
+
+
+# ------------------------------------------------ counters beside old ones
+async def test_prompt_tokens_by_source_against_a_shared_prefix_pair():
+    tel = get_telemetry()
+
+    def count(source):
+        return tel.prompt_tokens.labels(source)._value.get()
+
+    engine = TPUEngine(
+        _cfg(prefix_sharing=True), mesh=single_device_mesh(), seed=0
+    )
+    engine.start()
+    try:
+        first = list(range(10, 42))  # 32 tokens = 4 pages
+        c0, m0 = count("cached"), count("computed")
+        await _generate(engine, first, max_tokens=4)
+        assert (count("cached") - c0, count("computed") - m0) == (0, 32)
+        # Same first three pages, then its own tail.
+        second = first[:24] + list(range(100, 108))
+        c1, m1 = count("cached"), count("computed")
+        pages1 = dict(engine.kv.prefix_hits)
+        await _generate(engine, second, max_tokens=4)
+        cached, computed = count("cached") - c1, count("computed") - m1
+        assert cached + computed == len(second)
+        hit_pages = sum(
+            engine.kv.prefix_hits[k] - pages1[k]
+            for k in ("shared", "restore", "persist")
+        )
+        assert hit_pages == 3 and cached == hit_pages * PS
+    finally:
+        engine.stop()
+
+
+def test_kv_pool_info_names_the_pools_geometry():
+    cfg = _cfg(num_pages=48)
+    TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
+    (labels,) = [dict(k) for k in _samples("dynamo_kv_pool_info")]
+    assert labels == {
+        "layers": str(TINY.num_layers),
+        "pages": "48",
+        "page_size": str(PS),
+        "width": str(TINY.num_kv_heads * TINY.head_dim_),
+        "dtype": "float32",
+    }
+
+
+# ----------------------------------------------- scopes are metadata only
+SCOPES = ("qkv", "kv_write", "attend", "attn_out", "ffn", "logits", "sample")
+
+
+def _lowered(windowed: bool):
+    cfg = _cfg(model=TINY_QWEN3)
+    engine = TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
+    nb = cfg.ragged_tokens_bucket_for(2 if windowed else 16, mixed=not windowed)
+    key = resolve_ragged_key(
+        cfg, engine._attn_impl, nb, 2, windowed, False, False
+    )
+    fn = engine._ragged_fn_from_key(key)
+    return fn.lower(*variant_call_args(engine, key))
+
+
+@pytest.mark.parametrize("windowed", [True, False], ids=["window", "mixed"])
+def test_named_scopes_change_no_operation(monkeypatch, windowed):
+    """Lowering ``ragged_window`` and ``ragged_mixed`` at ``tiny-qwen3``
+    with and without the scopes gives the same program once the
+    metadata (locations) is left out; with them, every scope is in the
+    metadata."""
+    scoped = _lowered(windowed)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = _lowered(windowed)
+    assert scoped.as_text() == bare.as_text()
+    with_locations = scoped.as_text(debug_info=True)
+    assert f"jit(ragged_{'window' if windowed else 'mixed'})" in with_locations
+    for scope in SCOPES:
+        assert re.search(rf'["/]{scope}["/]', with_locations), scope
+    assert not re.search(r'["/]qkv["/]', bare.as_text(debug_info=True))
