@@ -10,8 +10,9 @@ JAX engine:
   page bucket / sampler variant — one host sync per window, which is
   what survives a high-latency host↔device link) and batched chunked
   prefill (keyed by row bucket × token bucket × page bucket). Static
-  shapes, no recompiles in steady state; KV pools are donated so XLA
-  updates them in place in HBM.
+  shapes, no recompiles in steady state; KV pools are donated and
+  carried through both loops (window steps, layers), so XLA updates
+  them in place in HBM (docs/engine_perf.md).
 - **Decode cost tracks occupancy, not the slot envelope**
   (docs/engine_perf.md): ACTIVE rows are compacted into the smallest
   row bucket and partitioned greedy-vs-sampler; stop detection (EOS /

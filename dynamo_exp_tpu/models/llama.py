@@ -6,6 +6,15 @@ SURVEY.md §2.3):
 - **Stacked layer parameters + ``lax.scan``**: one compiled layer body,
   L-step scan. Compile time stays flat as L grows, and XLA pipelines the
   per-layer HBM traffic.
+- **The KV pools are the scan's carry, never its inputs**: the layer
+  scan carries ``(x, k_cache, v_cache)`` and scans over the stacked
+  parameters and the layer index. Layer ``l`` scatters its new tokens
+  into the whole ``[L, P, ps, W]`` pool at ``[l, page, offset]`` and
+  reads through a flat ``[L*P, ps, W]`` view with the page table moved
+  by ``l*P``, so XLA updates the (donated) pools in place. A scan's
+  ``xs`` and ``ys`` are different buffers: state that the body writes
+  does not belong there (as scanned inputs and stacked outputs the
+  pools were sliced, written back and copied whole on every step).
 - **One forward for prefill and decode**: write-then-gather paged
   attention (see ``ops/attention.py``) with static (B, T, Pmax) buckets.
 - **bfloat16 everywhere the MXU touches**, float32 for norms/softmax/rope.
@@ -356,13 +365,100 @@ def _maybe_scale_embeds(cfg, x):
     return x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
 
 
+def _per_layer_xs(cfg, inv_freq):
+    """What rides the layer scan as ``xs`` beside the stacked parameters:
+    (window width [L], rope inverse frequencies [L, hd/2], layer index [L]).
+
+    Per-layer window widths: gemma2 alternates sliding and full layers;
+    gemma3 follows its explicit layer_types; mistral uses one width
+    everywhere. 1<<30 ≈ no window for full layers. gemma3's sliding
+    layers rope at a separate (local) base; full layers use rope_theta +
+    rope_scaling.
+
+    The KV pools are NOT here: they travel in the carry (module
+    docstring)."""
+    L = cfg.num_layers
+    have_window = cfg.sliding_window is not None
+    if cfg.layer_types:
+        sliding = [t == "sliding_attention" for t in cfg.layer_types]
+    else:
+        sliding = [not cfg.alt_sliding_window or i % 2 == 0 for i in range(L)]
+    win_arr = jnp.asarray(
+        [
+            cfg.sliding_window if (have_window and sliding[i]) else 1 << 30
+            for i in range(L)
+        ],
+        jnp.int32,
+    )
+    if cfg.rope_local_base_freq is not None:
+        invf_local = rope_frequencies(cfg.head_dim_, cfg.rope_local_base_freq)
+        invf_arr = jnp.stack(
+            [invf_local if s else inv_freq for s in sliding]
+        )
+    else:
+        invf_arr = jnp.tile(inv_freq[None], (L, 1))
+    return win_arr, invf_arr, jnp.arange(L, dtype=jnp.int32)
+
+
+def _scan_paged_layers(
+    params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh
+):
+    """The layer loop of both paged forwards: ``lax.scan`` carrying
+    ``(x, k_cache, v_cache)`` over the stacked parameters, the per-layer
+    window / rope base and the layer index. Returns (x, k_cache, v_cache).
+
+    Layer ``l`` writes its new K/V with one scatter into the whole
+    ``[L, P, ps, W]`` pool at ``[l, page, offset]`` (``write_at`` =
+    (page_ids, offsets, valid), the same for every layer), then reads
+    without slicing the pool: ``read(q, k_flat, v_flat, table_l, win_l)``
+    gets the pools seen as one page axis ``[L*P, ps, W]`` (a bitcast)
+    and ``table`` moved to layer ``l``'s range. Every read path (the
+    kernel's page DMAs, the XLA gather) takes a pool and page ids, so
+    none needs to know the layer; a ``dynamic_slice`` of the layer's
+    pool would be a copy of it."""
+    L, num_pages = k_cache.shape[:2]
+    flat = (L * num_pages, *k_cache.shape[2:])
+    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    eps = cfg.rms_norm_eps
+
+    def layer(carry, layer_in):
+        x, k_pool, v_pool = carry
+        lp, win_l, invf_l, l = layer_in
+
+        def attend(q, k, v):
+            n = k.shape[0] * k.shape[1]  # this dispatch's new tokens
+            with jax.named_scope("kv_write"):
+                kp, vp = write_kv_pages(
+                    k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
+                    *write_at, layer=l,
+                )
+            with jax.named_scope("attend"):
+                attn = read(
+                    q, kp.reshape(flat), vp.reshape(flat),
+                    table + l * num_pages, win_l,
+                )
+            return attn, (kp, vp)
+
+        x, (k_pool, v_pool) = _attn_mlp_layer(
+            x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh
+        )
+        return (x, k_pool, v_pool), None
+
+    (x, k_cache, v_cache), _ = jax.lax.scan(
+        layer,
+        (x, k_cache, v_cache),
+        (params["layers"], *_per_layer_xs(cfg, inv_freq)),
+    )
+    return x, k_cache, v_cache
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B, T] int32 (pad with 0 where pos < 0)
     positions: jnp.ndarray,  # [B, T] int32, -1 for padding rows
     page_table: jnp.ndarray,  # [B, Pmax] int32
-    k_cache: jnp.ndarray,  # [L, P, ps, Hkv, D]
+    k_cache: jnp.ndarray,  # [L, P, ps, Hkv*D]
     v_cache: jnp.ndarray,
     *,
     attn_pages: int | None = None,
@@ -393,10 +489,8 @@ def forward(
     (attention is embarrassingly parallel in heads).
     """
     B, T = tokens.shape
-    hd = cfg.head_dim_
     ps = k_cache.shape[2]
     eps = cfg.rms_norm_eps
-    inv_freq = rope_frequencies(hd, cfg.rope_theta, cfg.rope_scaling)
 
     # Page-write coordinates, shared by every layer. Positions beyond the
     # page table's capacity are dropped (not clamped): a scheduler bug can
@@ -445,80 +539,29 @@ def forward(
         if cfg.query_pre_attn_scalar
         else None
     )
-    # Per-layer window widths ride the scan (gemma2 alternates sliding
-    # and full layers; gemma3 follows its explicit layer_types; mistral
-    # uses one width everywhere). 1<<30 ≈ no window for full layers.
     have_window = cfg.sliding_window is not None
-    if cfg.layer_types:
-        sliding = [t == "sliding_attention" for t in cfg.layer_types]
-    else:
-        sliding = [
-            not cfg.alt_sliding_window or i % 2 == 0
-            for i in range(cfg.num_layers)
-        ]
-    win_arr = jnp.asarray(
-        [
-            cfg.sliding_window if (have_window and sliding[i]) else 1 << 30
-            for i in range(cfg.num_layers)
-        ],
-        jnp.int32,
-    )
-    # gemma3: sliding layers rope at a separate (local) base; full
-    # layers use rope_theta + rope_scaling. Per-layer inv_freq rides
-    # the scan alongside the window widths.
-    if cfg.rope_local_base_freq is not None:
-        invf_local = rope_frequencies(hd, cfg.rope_local_base_freq)
-        invf_arr = jnp.stack(
-            [invf_local if s else inv_freq for s in sliding]
-        )
-    else:
-        invf_arr = jnp.tile(inv_freq[None], (cfg.num_layers, 1))
 
-    def layer(x, layer_in):
-        lp, k_pool, v_pool, win_l, invf_l = layer_in
-
-        def attend(q, k, v):
-            with jax.named_scope("kv_write"):
-                kp, vp = write_kv_pages(
-                    k_pool,
-                    v_pool,
-                    k.reshape(B * T, cfg.num_kv_heads * hd),
-                    v.reshape(B * T, cfg.num_kv_heads * hd),
-                    page_ids,
-                    offsets,
-                    valid,
-                )
-            with jax.named_scope("attend"):
-                if use_pallas:
-                    attn = _pallas_decode(
-                        q[:, 0],
-                        kp,
-                        vp,
-                        page_table,
-                        lengths,
-                        cfg.num_kv_heads,
-                        mesh,
-                        interpret,
-                    )[:, None]
-                else:
-                    attn = paged_attention(
-                        q, kp, vp, attn_table, positions,
-                        sm_scale=sm_scale,
-                        window=win_l if have_window else None,
-                        softcap=cfg.attn_logit_softcap,
-                    )
-            return attn, (kp, vp)
-
-        return _attn_mlp_layer(
-            x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh
+    def read(q, kf, vf, table_l, win_l):
+        if use_pallas:
+            return _pallas_decode(
+                q[:, 0], kf, vf, table_l, lengths, cfg.num_kv_heads, mesh,
+                interpret,
+            )[:, None]
+        return paged_attention(
+            q, kf, vf, table_l, positions,
+            sm_scale=sm_scale,
+            window=win_l if have_window else None,
+            softcap=cfg.attn_logit_softcap,
         )
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], k_cache, v_cache, win_arr, invf_arr)
+    x, k_cache, v_cache = _scan_paged_layers(
+        params, cfg, x, k_cache, v_cache, rope_pos,
+        (page_ids, offsets, valid),
+        page_table if use_pallas else attn_table, read, mesh,
     )
     if last_positions is not None:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
-    return _final_logits(params, cfg, x, eps), new_k, new_v
+    return _final_logits(params, cfg, x, eps), k_cache, v_cache
 
 
 def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
@@ -643,11 +686,8 @@ def forward_ragged(
     resolution enforces it, mirroring ``forward``'s ``use_pallas``
     guard).
     """
-    N = tokens.shape[0]
-    hd = cfg.head_dim_
     ps = k_cache.shape[2]
     eps = cfg.rms_norm_eps
-    inv_freq = rope_frequencies(hd, cfg.rope_theta, cfg.rope_scaling)
 
     # Page-write coordinates: each flat token writes its row's page at
     # its own position; padding (-1) and table-overflow positions are
@@ -682,73 +722,29 @@ def forward_ragged(
         if cfg.query_pre_attn_scalar
         else None
     )
-    # Per-layer sliding windows / rope bases ride the scan exactly as
-    # in forward() (gemma2/gemma3/mistral layer alternation).
     have_window = cfg.sliding_window is not None
-    if cfg.layer_types:
-        sliding = [t == "sliding_attention" for t in cfg.layer_types]
-    else:
-        sliding = [
-            not cfg.alt_sliding_window or i % 2 == 0
-            for i in range(cfg.num_layers)
-        ]
-    win_arr = jnp.asarray(
-        [
-            cfg.sliding_window if (have_window and sliding[i]) else 1 << 30
-            for i in range(cfg.num_layers)
-        ],
-        jnp.int32,
-    )
-    if cfg.rope_local_base_freq is not None:
-        invf_local = rope_frequencies(hd, cfg.rope_local_base_freq)
-        invf_arr = jnp.stack(
-            [invf_local if s else inv_freq for s in sliding]
-        )
-    else:
-        invf_arr = jnp.tile(inv_freq[None], (cfg.num_layers, 1))
 
-    def layer(x, layer_in):
-        lp, k_pool, v_pool, win_l, invf_l = layer_in
+    def read(q, kf, vf, table_l, win_l):
+        if use_pallas:
+            return _pallas_ragged(
+                q[0], kf, vf, table_l, row_of, positions,
+                cfg.num_kv_heads, q_tile, mesh, interpret,
+            )[None]
+        from ..ops.ragged_attention import ragged_paged_attention_ref
 
-        def attend(q, k, v):
-            with jax.named_scope("kv_write"):
-                kp, vp = write_kv_pages(
-                    k_pool,
-                    v_pool,
-                    k.reshape(N, cfg.num_kv_heads * hd),
-                    v.reshape(N, cfg.num_kv_heads * hd),
-                    page_ids,
-                    offsets,
-                    valid,
-                )
-            with jax.named_scope("attend"):
-                if use_pallas:
-                    attn = _pallas_ragged(
-                        q[0], kp, vp, attn_table, row_of, positions,
-                        cfg.num_kv_heads, q_tile, mesh, interpret,
-                    )[None]
-                else:
-                    from ..ops.ragged_attention import (
-                        ragged_paged_attention_ref,
-                    )
+        return ragged_paged_attention_ref(
+            q[0], kf, vf, table_l, row_of, positions,
+            num_kv_heads=cfg.num_kv_heads, sm_scale=sm_scale,
+            window=win_l if have_window else None,
+            softcap=cfg.attn_logit_softcap,
+        )[None]
 
-                    attn = ragged_paged_attention_ref(
-                        q[0], kp, vp, attn_table, row_of, positions,
-                        num_kv_heads=cfg.num_kv_heads, sm_scale=sm_scale,
-                        window=win_l if have_window else None,
-                        softcap=cfg.attn_logit_softcap,
-                    )[None]
-            return attn, (kp, vp)
-
-        return _attn_mlp_layer(
-            x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh
-        )
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], k_cache, v_cache, win_arr, invf_arr)
+    x, k_cache, v_cache = _scan_paged_layers(
+        params, cfg, x, k_cache, v_cache, rope_pos,
+        (page_ids, offsets, valid), attn_table, read, mesh,
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
-    return _final_logits(params, cfg, xo, eps), new_k, new_v
+    return _final_logits(params, cfg, xo, eps), k_cache, v_cache
 
 
 def forward_ring_prefill(

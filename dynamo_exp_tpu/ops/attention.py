@@ -28,32 +28,37 @@ import jax.numpy as jnp
 
 
 def write_kv_pages(
-    k_cache: jnp.ndarray,  # [P, ps, Hkv*D] (heads collapsed into lanes)
+    k_cache: jnp.ndarray,  # [L, P, ps, Hkv*D] (heads collapsed into lanes)
     v_cache: jnp.ndarray,
     k_new: jnp.ndarray,  # [N, Hkv*D] flattened new tokens
     v_new: jnp.ndarray,
-    page_ids: jnp.ndarray,  # [N] int32 global page id per new token
+    page_ids: jnp.ndarray,  # [N] int32 page id (within a layer) per new token
     offsets: jnp.ndarray,  # [N] int32 in-page offset per new token
     valid: jnp.ndarray,  # [N] bool — False rows are dropped
+    layer: jnp.ndarray | int | None = None,  # which layer of the pool
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter new K/V rows into the page pool. Invalid rows are given an
-    out-of-range page id, which XLA's ``mode="drop"`` scatter discards —
-    no write happens for them at all.
+    """Scatter new K/V rows into the page pools at ``[layer, page,
+    offset]``: one scatter into the whole ``[L, P, ps, Hkv*D]`` pool, so
+    a pool that is loop-carried state (``models/llama.py``) is updated
+    in place. ``layer=None`` takes one layer's ``[P, ps, Hkv*D]`` pool.
+
+    Invalid rows are given page id ``P``, out of range of the *page*
+    axis, which XLA's ``mode="drop"`` scatter discards — no write happens
+    for them at all. (The sentinel must be out of range of the axis it
+    indexes: on a pool flattened to ``[L*P, ...]`` page ``P`` would be
+    layer 1's page 0.)
 
     The pool keeps (kv head, head_dim) collapsed into one trailing
     dimension: TPU tiling pads the last dim to 128 lanes, so a separate
     D=64 axis would double every pool's HBM footprint (and every
     gather's traffic); Hkv*D is 128-aligned for the shapes we serve.
     """
-    num_pages = k_cache.shape[0]
+    num_pages = k_cache.shape[-3]
     # Out-of-range page id for invalid rows => XLA drops the scatter row.
     safe_pages = jnp.where(valid, page_ids, num_pages)
-    k_cache = k_cache.at[safe_pages, offsets].set(
-        k_new.astype(k_cache.dtype), mode="drop"
-    )
-    v_cache = v_cache.at[safe_pages, offsets].set(
-        v_new.astype(v_cache.dtype), mode="drop"
-    )
+    idx = (safe_pages, offsets) if layer is None else (layer, safe_pages, offsets)
+    k_cache = k_cache.at[idx].set(k_new.astype(k_cache.dtype), mode="drop")
+    v_cache = v_cache.at[idx].set(v_new.astype(v_cache.dtype), mode="drop")
     return k_cache, v_cache
 
 

@@ -295,8 +295,8 @@ def test_kv_pool_info_names_the_pools_geometry():
 SCOPES = ("qkv", "kv_write", "attend", "attn_out", "ffn", "logits", "sample")
 
 
-def _lowered(windowed: bool):
-    cfg = _cfg(model=TINY_QWEN3)
+def _lowered(windowed: bool, **over):
+    cfg = _cfg(model=TINY_QWEN3, **over)
     engine = TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
     nb = cfg.ragged_tokens_bucket_for(2 if windowed else 16, mixed=not windowed)
     key = resolve_ragged_key(
@@ -322,3 +322,23 @@ def test_named_scopes_change_no_operation(monkeypatch, windowed):
     for scope in SCOPES:
         assert re.search(rf'["/]{scope}["/]', with_locations), scope
     assert not re.search(r'["/]qkv["/]', bare.as_text(debug_info=True))
+
+
+# ------------------------------------- the pools are written where they are
+@pytest.mark.parametrize("windowed", [True, False], ids=["window", "mixed"])
+def test_programs_hold_no_second_copy_of_the_pools(windowed):
+    """The KV pools ride both loops (the decode window's steps, the
+    layers) as carried state that is written in place: a compiled
+    program's temporaries are smaller than ONE pool. Carried as the layer
+    scan's inputs and stacked outputs they were three pools' worth (a
+    copy of both for the window's carry, a fresh stacked output): the
+    form that cost a third to a half of the device's time (PERF.md §6,
+    PR 25). The pool (4096 pages, 8 MiB) dwarfs the activations."""
+    compiled = _lowered(windowed, num_pages=4096).compile()
+    one_pool = (
+        TINY_QWEN3.num_layers * 4096 * PS
+        * TINY_QWEN3.num_kv_heads * TINY_QWEN3.head_dim_ * 4
+    )
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * one_pool  # both donated, reused
+    assert mem.temp_size_in_bytes < one_pool // 4

@@ -23,6 +23,10 @@ from dynamo_exp_tpu.models import (
 
 PS = 8
 
+# Jitted once for the module: an eager ``forward`` compiles its layer
+# scan anew on every call.
+fwd = jax.jit(forward, static_argnums=(1,), static_argnames=("mesh",))
+
 
 def _full_logits(params, cfg, token_list):
     T = len(token_list)
@@ -31,7 +35,7 @@ def _full_logits(params, cfg, token_list):
     tokens = jnp.array([token_list], dtype=jnp.int32)
     positions = jnp.arange(T, dtype=jnp.int32)[None, :]
     table = jnp.arange(pmax, dtype=jnp.int32)[None, :] + 1
-    logits, _, _ = forward(params, cfg, tokens, positions, table, k, v)
+    logits, _, _ = fwd(params, cfg, tokens, positions, table, k, v)
     return np.asarray(logits[0])
 
 
@@ -107,14 +111,14 @@ def test_moe_incremental_decode_matches_full_prefill():
     k, v = init_kv_cache(cfg, num_pages=pmax + 1, page_size=PS, dtype=jnp.float32)
     table = jnp.arange(pmax, dtype=jnp.int32)[None, :] + 1
     split = 9
-    logits, k, v = forward(
+    logits, k, v = fwd(
         params, cfg,
         jnp.array([toks[:split]], jnp.int32),
         jnp.arange(split, dtype=jnp.int32)[None, :], table, k, v,
     )
     np.testing.assert_allclose(np.asarray(logits[0]), want[:split], rtol=1e-4, atol=1e-4)
     for i in range(split, len(toks)):
-        logits, k, v = forward(
+        logits, k, v = fwd(
             params, cfg,
             jnp.array([[toks[i]]], jnp.int32),
             jnp.array([[i]], jnp.int32), table, k, v,
@@ -140,7 +144,6 @@ def test_qwen2_bias_changes_logits_and_tp_matches():
 
     mesh = build_mesh(tp=2)
     sp, _ = shard_pytree(mesh, params, param_shardings(cfg))
-    fwd = jax.jit(forward, static_argnums=(1,))
     T = len(toks)
     pmax = (T + PS - 1) // PS
     k, v = init_kv_cache(cfg, num_pages=pmax + 1, page_size=PS, dtype=jnp.float32)
@@ -163,7 +166,6 @@ def test_moe_tp_sharded_matches_single_device():
 
     mesh = build_mesh(tp=2)
     sp, _ = shard_pytree(mesh, params, param_shardings(cfg))
-    fwd = jax.jit(forward, static_argnums=(1,))
     T = len(toks)
     pmax = (T + PS - 1) // PS
     k, v = init_kv_cache(cfg, num_pages=pmax + 1, page_size=PS, dtype=jnp.float32)
@@ -271,7 +273,6 @@ def test_moe_expert_parallel_matches_single_device():
         sp, _ = shard_pytree(
             mesh, params, param_shardings(cfg, ep_axis="ep")
         )
-        fwd = jax.jit(forward, static_argnums=(1,), static_argnames=("mesh",))
         T = len(toks)
         pmax = (T + PS - 1) // PS
         k, v = init_kv_cache(cfg, num_pages=pmax + 1, page_size=PS, dtype=jnp.float32)

@@ -266,6 +266,9 @@ def _oracle_stream(
         sample_tokens_seeded,
     )
 
+    # Jitted: an eager forward compiles its layer scan anew every call.
+    forward = jax.jit(forward, static_argnums=(1,))
+
     cfg = engine.cfg.model
     params = engine.params
     from dynamo_exp_tpu.models.llama import init_kv_cache
