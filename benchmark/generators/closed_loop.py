@@ -5,8 +5,17 @@ what is judged is the rate of work completed.
 Lengths are drawn round by round from the traffic file's
 ``schedule_seed``: the k-th requests of all clients are one evenly spaced
 sample of the distribution, so however many rounds a run gets through, it
-has seen a balanced mix, and every run is offered the same sequences. The
-run's seed decides which client gets which sequence, and makes the text.
+has seen a balanced mix, and every run is offered the same sequences, by
+the same clients, joining at the same times. The run's seed makes only
+the text, as in generators/sessions.py.
+
+Until PR 26 the seed also decided which client got which sequence. Two
+runs of one seed then agreed to the last token (824.63 tokens/s twice)
+while six seeds spread by 8 %: clients join over the first seconds of
+the lead-in, so another assignment is another order of arrivals, the loop
+never forgets it, and it decides how long the batch holds a context over
+1k tokens, at which the program changes its attention form and the step
+its cost (PERF.md section 6, PR 26). That was the draw, not the system.
 """
 
 from __future__ import annotations
@@ -34,12 +43,11 @@ def generate(traffic: dict, cell: dict, seed: int, seconds: float, text) -> dict
         outputs = stratified(traffic["output_tokens"], clients, schedule)
         for seq, p, o in zip(sequences, prompts, outputs):
             seq.append((p, o))
-    shift = random.Random(seed).randrange(clients)
     chains = []
     for c in range(clients):
         reqs = [
             {"messages": text.user_message(p), "max_tokens": o, "gap_s": 0.0}
-            for p, o in sequences[(c + shift) % clients]
+            for p, o in sequences[c]
         ]
         # Clients join over the first fifth of the lead-in, not at once.
         chains.append({"start_s": lead_in * 0.2 * c / clients, "requests": reqs})

@@ -53,11 +53,13 @@ import jax.numpy as jnp
 import numpy as np
 
 # Largest allowed |engine top log-probability - reference log-probability|.
-# Seen on the chip (PR 23, Qwen3-4B, five probe seeds): 0.030-0.052.
+# Qwen3-4B on the chip: sound 0.030-0.052 (PR 23, five probe seeds), 0.037-0.060 (my
+# chip run, PR 26, twelve seeds); the reference with every weight in fp8 (e4m3)
+# 0.468-0.619, with one part alone in fp8 0.175 at the least (the tied embedding;
+# rehearse/check_seeds.py --control fp8_weights, three seeds).
 LOGPROB_TOL = 0.1
 
 PAD_TO = 64  # every probe is padded to this length: one compiled shape
-
 
 def _f32(x):
     return x.astype(jnp.float32)
@@ -81,8 +83,9 @@ def _swiglu(h, w_gate, w_up, w_down):
     return (jax.nn.silu(h @ _f32(w_gate)) * (h @ _f32(w_up))) @ _f32(w_down)
 
 
-def _layer(x, lp, *, hd: int, eps: float, theta: float, top_k: int):
-    """One decoder layer on x: [T, D] float32, positions 0..T-1, causal."""
+def _attn_half(x, lp, *, hd: int, eps: float, theta: float):
+    """The layer's first half on x: [T, D] float32, positions 0..T-1,
+    causal: norm, attention, residual add."""
     T = x.shape[0]
     pos = jnp.arange(T)
     h = _rms_norm(x, lp["attn_norm"], eps)
@@ -98,11 +101,19 @@ def _layer(x, lp, *, hd: int, eps: float, theta: float, top_k: int):
     scores = jnp.einsum("thd,shd->hts", q, k) * hd**-0.5
     scores = jnp.where(pos[None, :, None] >= pos[None, None, :], scores, -jnp.inf)
     attn = jnp.einsum("hts,shd->thd", jax.nn.softmax(scores, axis=-1), v)
-    x = x + attn.reshape(T, -1) @ _f32(lp["wo"])
+    return x + attn.reshape(T, -1) @ _f32(lp["wo"])
+
+
+def _ffn_half(x, lp, *, eps: float, top_k: int):
+    """The layer's second half on x: [T, D] float32: norm, SwiGLU or the
+    routed experts, residual add. Returns the new x and, for a routed
+    layer, the router's margin a row: the k-th best score less the
+    (k+1)-th best (None for a dense layer)."""
     h = _rms_norm(x, lp["mlp_norm"], eps)
     if "router" not in lp:
-        return x + _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        return x + _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), None
     probs = jax.nn.softmax(h @ _f32(lp["router"]), axis=-1)  # [T, E]
+    best = jax.lax.top_k(probs, top_k + 1)[0]
     top_p, top_i = jax.lax.top_k(probs, top_k)
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     n_experts = probs.shape[-1]
@@ -111,16 +122,24 @@ def _layer(x, lp, *, hd: int, eps: float, theta: float, top_k: int):
     )  # [T, E], 0 where the expert was not chosen
     y = jnp.zeros_like(x)
     for e in range(n_experts):
-        y = y + weight[:, e:e + 1] * _swiglu(
-            h, lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e]
-        )
-    return x + y
+        y = y + weight[:, e:e + 1] * _swiglu(h, lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e])
+    return x + y, best[:, top_k - 1] - best[:, top_k]
+
+
+def _layer(x, lp, *, hd: int, eps: float, theta: float, top_k: int):
+    """One decoder layer on x: [T, D] float32, positions 0..T-1, causal."""
+    x = _attn_half(x, lp, hd=hd, eps=eps, theta=theta)
+    return _ffn_half(x, lp, eps=eps, top_k=top_k)[0]
 
 
 def _log_softmax_head(x, final_norm, head_t, *, eps: float):
     """x: [N, D]; head_t: [V, D] (the output head, one row a token)."""
     logits = _rms_norm(x, final_norm, eps) @ _f32(head_t).T
     return jax.nn.log_softmax(logits, axis=-1)
+
+
+def _head_t(params):
+    return params["embed"] if "lm_head" not in params else params["lm_head"].T
 
 
 def block_constants(hf: dict) -> dict:
@@ -141,6 +160,10 @@ def block_constants(hf: dict) -> dict:
     }
 
 
+def _layer_params(layers, l):
+    return {k: jax.lax.dynamic_index_in_dim(v, l, 0, keepdims=False) for k, v in layers.items()}
+
+
 @functools.partial(jax.jit, static_argnames=("hd", "eps", "theta", "top_k"))
 def _forward(params, ids, rows, *, hd: int, eps: float, theta: float, top_k: int):
     """Embedding, then the layers one at a time (layer ``l`` of every
@@ -150,12 +173,17 @@ def _forward(params, ids, rows, *, hd: int, eps: float, theta: float, top_k: int
     n_layers = layers["wq"].shape[0]
 
     def body(l, x):
-        lp = {k: jax.lax.dynamic_index_in_dim(v, l, 0, keepdims=False) for k, v in layers.items()}
-        return _layer(x, lp, hd=hd, eps=eps, theta=theta, top_k=top_k)
+        return _layer(x, _layer_params(layers, l), hd=hd, eps=eps, theta=theta, top_k=top_k)
 
     x = jax.lax.fori_loop(0, n_layers, body, _f32(params["embed"][ids]))
-    head_t = params["embed"] if "lm_head" not in params else params["lm_head"].T
-    return _log_softmax_head(x[rows], params["final_norm"], head_t, eps=eps)
+    return _log_softmax_head(x[rows], params["final_norm"], _head_t(params), eps=eps)
+
+
+def _padded(token_ids) -> list[int]:
+    ids = list(token_ids)
+    if len(ids) > PAD_TO:
+        raise ValueError(f"probe of {len(ids)} tokens exceeds PAD_TO={PAD_TO}")
+    return ids + [0] * (PAD_TO - len(ids))
 
 
 def log_probs(params: dict, hf: dict, token_ids, rows) -> np.ndarray:
@@ -163,12 +191,83 @@ def log_probs(params: dict, hf: dict, token_ids, rows) -> np.ndarray:
     sequence ``token_ids`` (padded to PAD_TO; padding follows the real
     tokens, so under the causal mask it changes nothing before it)."""
     c = block_constants(hf)
-    ids = list(token_ids)
-    if len(ids) > PAD_TO:
-        raise ValueError(f"probe of {len(ids)} tokens exceeds PAD_TO={PAD_TO}")
-    ids = ids + [0] * (PAD_TO - len(ids))
     with jax.default_matmul_precision("highest"):
         out = _forward(
-            params, jnp.asarray(ids, jnp.int32), jnp.asarray(list(rows), jnp.int32), **c
+            params, jnp.asarray(_padded(token_ids), jnp.int32),
+            jnp.asarray(list(rows), jnp.int32), **c,
         )
     return np.asarray(out)
+
+
+# What follows serves ``comparisons/routed_experts.py``: the hidden state
+# where a layer and each of its halves begin, and each half on rows given.
+@functools.partial(jax.jit, static_argnames=("hd", "eps", "theta", "top_k", "at"))
+def _hidden_states(params, ids, *, hd, eps, theta, top_k, at: tuple):
+    layers = params["layers"]
+    n_layers = layers["wq"].shape[0]
+    where = jnp.asarray(at, jnp.int32)
+
+    def body(l, carry):
+        x, entering, before_ffn = carry
+        lp = _layer_params(layers, l)
+        mid = _attn_half(x, lp, hd=hd, eps=eps, theta=theta)
+        here = (where == l)[:, None, None]
+        entering = jnp.where(here, x[None], entering)
+        before_ffn = jnp.where(here, mid[None], before_ffn)
+        return _ffn_half(mid, lp, eps=eps, top_k=top_k)[0], entering, before_ffn
+
+    x0 = _f32(params["embed"][ids])
+    kept0 = jnp.zeros((len(at),) + x0.shape, jnp.float32)
+    return jax.lax.fori_loop(0, n_layers, body, (x0, kept0, kept0))[1:]
+
+
+def hidden_states(params: dict, hf: dict, token_ids, layers) -> tuple[np.ndarray, np.ndarray]:
+    """For the sequence ``token_ids``, the float32 hidden state entering
+    each layer in ``layers`` and the one entering that layer's second
+    half (norm, feed-forward, residual): two arrays
+    [len(layers), len(token_ids), D]."""
+    c = block_constants(hf)
+    n = len(list(token_ids))
+    with jax.default_matmul_precision("highest"):
+        entering, before_ffn = _hidden_states(
+            params, jnp.asarray(_padded(token_ids), jnp.int32),
+            at=tuple(int(l) for l in layers), **c,
+        )
+    return np.asarray(entering)[:, :n], np.asarray(before_ffn)[:, :n]
+
+
+@functools.partial(jax.jit, static_argnames=("hd", "eps", "theta"))
+def _attn_half_and_head_of(params, x, layer, *, hd, eps, theta):
+    mid = _attn_half(_f32(x), _layer_params(params["layers"], layer), hd=hd, eps=eps, theta=theta)
+    return _log_softmax_head(mid, params["final_norm"], _head_t(params), eps=eps)
+
+
+def attn_half_and_head(params: dict, hf: dict, layer: int, x) -> np.ndarray:
+    """The first half of layer ``layer`` (norm, attention, residual) on
+    ``x`` ([T, D], any float type, taken as it is: one sequence at
+    positions 0..T-1, causal), then the final norm and the head:
+    log-softmax over the vocabulary, float32 [T, V]."""
+    c = block_constants(hf)
+    with jax.default_matmul_precision("highest"):
+        out = _attn_half_and_head_of(
+            params, x, int(layer), hd=c["hd"], eps=c["eps"], theta=c["theta"]
+        )
+    return np.asarray(out)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "top_k"))
+def _ffn_half_of(layers, x, layer, *, eps, top_k):
+    return _ffn_half(_f32(x), _layer_params(layers, layer), eps=eps, top_k=top_k)
+
+
+def ffn_half(params: dict, hf: dict, layer: int, x):
+    """The second half of layer ``layer`` on the rows ``x`` ([N, D], any
+    float type, taken as it is): (the rows after the residual add,
+    float32 [N, D]; the router's margin a row, or None for a dense
+    layer)."""
+    c = block_constants(hf)
+    with jax.default_matmul_precision("highest"):
+        y, margin = _ffn_half_of(
+            params["layers"], x, int(layer), eps=c["eps"], top_k=c["top_k"]
+        )
+    return np.asarray(y), (None if margin is None else np.asarray(margin))

@@ -40,7 +40,7 @@ MODEL_NAME = "bench"
 BOOT_TIMEOUT_S = 1100.0
 CONFIG_OWN_KEYS = (
     "source", "reduced", "reduced_from", "assumed", "deployment", "chips",
-    "serve_args", "num_pages_arithmetic",
+    "serve_args", "num_pages_arithmetic", "comparison", "reference",
 )
 TRACE_START_S = 2.0  # into the window
 TRACE_SLICE_S = 4.0
@@ -195,6 +195,15 @@ class Supervisor:
             time.sleep(0.25)
         raise BenchFailure(f"server not ready within {BOOT_TIMEOUT_S:.0f}s")
 
+    def comparison_ctx(self, seed: int) -> dict:
+        """What a comparison is given (``comparisons/__init__.py``)."""
+        return {
+            "base": self.base, "model": MODEL_NAME, "engine": self.engine,
+            "params": self.engine.params, "hf": hf_config(self.spec["config"]),
+            "tokenizer_file": os.path.join(self.model_dir, "tokenizer.json"),
+            "seed": seed, "reference": self.spec["comparison"][1],
+        }
+
     def _sleep_until(self, t: float) -> None:
         while True:
             left = t - time.monotonic()
@@ -233,18 +242,13 @@ class Supervisor:
             and (not on_chip or info.get("attn_impl") == "pallas")
         )
 
-        # (d) the reference, then (c) the fixed probes.
-        hf = hf_config(spec["config"])
-        import reference
-
+        # (d) the configuration's comparison with its reference.
+        compare = spec["comparison"][0].compare
         t = time.monotonic()
-        ref = correct.compare_with_reference(
-            self.base, MODEL_NAME, self.engine.params, hf,
-            os.path.join(self.model_dir, "tokenizer.json"), args.seed,
-            reference.LOGPROB_TOL,
-        )
+        ref = compare(self.comparison_ctx(args.seed))
         say(f"reference check {time.monotonic() - t:.1f}s: {ref}")
-        probes_before = correct.greedy_probe_texts(self.base, MODEL_NAME)
+        # (c) the fixed probes, at their second asking (warm: lib/correct.py).
+        probes_before = correct.second_asking_texts(self.base, MODEL_NAME)
 
         # The plan, from the seed.
         import random
@@ -298,7 +302,7 @@ class Supervisor:
         records = load_json(out_path)["records"]
         summary = stats.summarize(records, w0, w1)
 
-        probes_after = correct.greedy_probe_texts(self.base, MODEL_NAME)
+        probes_after = correct.second_asking_texts(self.base, MODEL_NAME)
         final = correct.placement(self._scrape())
         memory = [
             (d.memory_stats() or {}) for d in self.engine.mesh.devices.flat
@@ -341,7 +345,10 @@ class Supervisor:
         }
         detail = {
             "checks": checks,
-            "reference": {k: ref.get(k) for k in ("max_abs_logprob_diff", "tol", "steps", "why")},
+            "reference": {
+                "max_abs_logprob_diff": None, "tol": None, "steps": None, "why": None,
+                **{k: v for k, v in ref.items() if k != "ok"},
+            },
             "seed": args.seed,
             "workload": spec["name"],
             "setup": {"setup_s": setup_s, "warm": self.warm},
@@ -497,12 +504,29 @@ def main(argv: list[str] | None = None) -> int:
                     help="also write the trace's planes, lines and names here")
     args = ap.parse_args(argv)
 
-    spec = load_cell(args.workload)
+    sup, server_error = serve(load_cell(args.workload), args)
+    if server_error or sup.error or sup.result is None:
+        say(f"no result: server={server_error!r} supervisor={sup.error!r}")
+        return 1
+    sys.stdout.flush()
+    print(json.dumps(sup.result), flush=True)
+    return 0
+
+
+def serve(spec: dict, args, supervisor=Supervisor, warm: bool = True):
+    """Start the cell's server in this thread and ``supervisor`` beside
+    it; returns (the supervisor, what ended the server early or None)
+    when both have ended. ``warm=False`` leaves the lattice unbuilt (for
+    a supervisor that only asks probes: rehearse/check_seeds.py)."""
+    import comparisons
+
+    # Before the engine is built: a name that finds no file costs no chip time.
+    spec["comparison"] = comparisons.resolve(spec["config"])
     import dynamo_exp_tpu.run as server  # fails here outside a checkout
 
     model_dir = prepare_model_dir(spec)
     port = free_port()
-    sup = Supervisor(spec, args, f"http://127.0.0.1:{port}", model_dir)
+    sup = supervisor(spec, args, f"http://127.0.0.1:{port}", model_dir)
 
     build = server.build_tpu_engine
 
@@ -512,7 +536,7 @@ def main(argv: list[str] | None = None) -> int:
         sup.device = check_devices(spec)
         engine, mdc = build(opts)
         sup.engine = engine
-        sup.warm = warm_lattice(engine)
+        sup.warm = warm_lattice(engine) if warm else {}
         return engine, mdc
 
     server.build_tpu_engine = build_and_warm
@@ -529,12 +553,7 @@ def main(argv: list[str] | None = None) -> int:
     finally:
         sup.server_exited.set()
     sup.thread.join(timeout=120)
-    if server_error or sup.error or sup.result is None:
-        say(f"no result: server={server_error!r} supervisor={sup.error!r}")
-        return 1
-    sys.stdout.flush()
-    print(json.dumps(sup.result), flush=True)
-    return 0
+    return sup, server_error
 
 
 if __name__ == "__main__":

@@ -32,8 +32,20 @@ def result_of(proc, lines):
     return out
 
 
+def reference_detail(proc) -> dict:
+    """The ``reference`` part of the diagnostics line on stderr."""
+    line = [l for l in proc.stderr.splitlines() if l.startswith("[bench] detail: ")][-1]
+    return json.loads(line[len("[bench] detail: "):])["reference"]
+
+
 def test_open_loop_rehearsal_prints_the_contract_line_and_no_device_metric():
-    out = result_of(*run_cell("tiny-qwen3.rehearsal-open", 0))
+    proc, lines = run_cell("tiny-qwen3.rehearsal-open", 0)
+    out = result_of(proc, lines)
+    # No ``comparison`` key in the configuration's file: the walk against
+    # reference.py, two probes of eight steps, its tolerance, as ever.
+    ref = reference_detail(proc)
+    assert set(ref) == {"max_abs_logprob_diff", "tol", "steps", "why"}
+    assert ref["steps"] == 16 and ref["tol"] == 0.1 and ref["max_abs_logprob_diff"] < 0.1
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] >= 10
     assert out["device"]["platform"] == "cpu" and out["device"]["count"] == 1
     assert set(out["metrics"]) == {
@@ -63,12 +75,21 @@ def test_fixed_population_session_rehearsal():
 
 
 def test_tp4_closed_loop_rehearsal_on_four_virtual_devices():
-    out = result_of(*run_cell(
+    proc, lines = run_cell(
         "tiny-moe.rehearsal-closed", 0,
         env={"XLA_FLAGS": "--xla_force_host_platform_device_count=4"},
-    ))
+    )
+    out = result_of(proc, lines)
     assert out["correct"] is True and out["device"]["count"] == 4
     assert out["attempted"] >= 20 and out["failed"] == 0
+    # Its configuration names ``routed_experts``: all three parts ran, on the mesh.
+    ref = reference_detail(proc)
+    assert ref["steps"] == 32 and ref["why"] is None
+    assert [(c["layer"], c["rows"]) for c in ref["block"]] == [(0, 64), (0, 1024), (1, 64), (1, 1024)]
+    assert 0 < ref["block_max_rel_err"] < ref["block_tol"]
+    assert [c["layer"] for c in ref["first_half"]] == [0, 1]
+    assert 0 < ref["first_half_max_rel_err"] < ref["first_half_tol"]
+    assert ref["median_abs_logprob_diff"] < ref["tol"] and ref["rows_near_tie"] > 0
 
 
 @pytest.mark.parametrize("workload", ["qwen3-4b.chat-steady", "qwen3-4b.sessions-prefix"])

@@ -1,5 +1,5 @@
-"""The generators: the seed changes the order and the text, never the
-work; plans are deterministic; chains have the documented shape."""
+"""The generators: the seed changes the text (and turns an open loop's
+cycle), never the work; plans are deterministic; chains have the documented shape."""
 
 import importlib
 import json
@@ -91,9 +91,8 @@ def test_closed_loop_has_one_chain_a_client_and_balanced_rounds():
     third_round = sorted(c["requests"][2]["max_tokens"] for c in p["chains"])
     assert first_round == third_round  # every round is the same sample
     assert all(c["start_s"] < p["lead_in_s"] for c in p["chains"])
-    # Another seed hands the same sequences to other clients.
-    seqs = lambda pl: sorted(tuple(r["max_tokens"] for r in c["requests"]) for c in pl["chains"])
-    assert seqs(p) == seqs(q) and shape(p) != shape(q)
+    # Another seed is the same schedule with other words (PERF.md, PR 26).
+    assert shape(p) == shape(q)
 
 
 def test_sessions_replay_the_same_script_for_every_seed():

@@ -99,11 +99,17 @@ def test_shipped_rules_class_by_name_shape_and_scope():
     # A gathered context is not a pool: same width, another page count.
     assert cls(op("copy.56", "copy", "bf16[2048,8,32]")) == "other"
     for scope, want in (
-        ("ffn/dot_general", "ffn"), ("moe/ragged_dot", "ffn"), ("qkv/rsqrt", "qkv"),
+        ("ffn/dot_general", "ffn"), ("moe/ragged_dot", "moe"), ("qkv/rsqrt", "qkv"),
         ("attn_out/dot_general", "attn_out"), ("attend/gather", "attn_xla"),
     ):
         assert cls(op("fusion.1", "fusion", "bf16[32,1,64]", f"{base}/{scope}")) == want
     assert cls(op("f", "fusion", "f32[32,512]", "jit(ragged_window)/while/body/closed_call/logits/dot_general")) == "logits"
+    # A collective books as one wherever it stands, either half of it, fused or not.
+    assert cls(op("all-reduce.3", "all-reduce", "bf16[64,4096]", f"{base}/moe/add")) == "collective"
+    assert cls(op("all-gather-start.1", "all-gather-start", "bf16[64,32000]", f"{base}/logits/x")) == "collective"
+    assert cls(op("all-reduce-scatter.2", "fusion", "bf16[64,1024]", f"{base}/attn_out/dot_general")) == "collective"
+    # XLA's ragged_dot custom call keeps no scope path; its name says what it is.
+    assert cls(op("ragged-dot-none.2[tpu_custom_call]", "custom-call", "bf16[128,3584]")) == "moe"
     assert cls(op("f", "fusion", "s32[32]", "jit(ragged_mixed)/sample/argmax")) == "sample"
     # A name that merely contains a scope's letters is not that scope.
     assert cls(op("f", "fusion", "bf16[8]", "jit(x)/diffn/add")) == "other"
