@@ -921,6 +921,34 @@ class TPUEngine(AsyncEngine):
         ragged kernel requires every ``ragged_q_tile`` slice to belong
         to one row; the XLA reference packs tight."""
         return self.cfg.ragged_q_tile if self._attn_impl == "pallas" else 1
+    def _count_dispatch(
+        self, nb, attn_pages, windowed, full_sampler, want_lp, with_spec,
+        chained: bool, live: int,
+    ) -> tuple:
+        """Count one ragged dispatch under its whole variant key
+        (``dynamo_ragged_dispatch_total``) and, for a routed-expert
+        model, its live and padding rows (``dynamo_moe_rows_total``;
+        a window's rows times its steps). Host arithmetic on what the
+        builder already holds. Returns the key."""
+        key = resolve_ragged_key(
+            self.cfg, self._attn_impl, nb, attn_pages, windowed,
+            full_sampler, want_lp, with_spec,
+        )
+        tel = get_telemetry()
+        tel.ragged_dispatches.labels(
+            "window" if windowed else "mixed",
+            str(nb),
+            "kernel" if key[1] is None else str(key[1]),
+            str(int(want_lp)),
+            str(int(full_sampler)),
+            str(int(chained)),
+        ).inc()
+        if self.cfg.model.is_moe:
+            steps = self.cfg.decode_window if windowed else 1
+            tel.moe_rows.labels("live").inc(live)
+            tel.moe_rows.labels("pad").inc(nb * steps - live)
+        return key
+
     # -------------------------------------------------------------- lifecycle
     def start(self) -> None:
         if self._running:
@@ -1771,6 +1799,7 @@ class TPUEngine(AsyncEngine):
                 prompt=len(seq.prompt),
                 cached=seq.cached_len,
                 priority=seq.priority,
+                pages=self._attached_pages(seq),
             )
         tel = get_telemetry()
         # Prompt tokens by source, from the allocation that just fed
@@ -1789,6 +1818,31 @@ class TPUEngine(AsyncEngine):
                 seq.trace,
                 prompt_tokens=len(seq.prompt),
             )
+
+    def _attached_pages(self, seq: Sequence) -> list[list]:
+        """What an admission bound, for the flight recorder: one
+        ``[page id, how, writer]`` per page, in sequence order. ``how``
+        is ``matched`` (a registered page attached read-shared),
+        ``tail`` (the shared partial tail: read in place by the prompt's
+        last chunk, made private or copied before the first decode
+        write, which the ``cow`` event records), ``host`` (a fresh page
+        a host-tier upload fills) or ``fresh`` (this request computes
+        it). ``writer`` names the prefill dispatch that wrote an
+        attached page (``KvPageManager.page_writer``), so an asking's
+        numbers can be traced to the dispatches whose bits it read."""
+        uploads = {pid for pid, *_ in seq.pending_uploads}
+        out = []
+        for i, pid in enumerate(seq.page_ids):
+            if pid == seq.shared_tail_pid:
+                how = "tail"
+            elif pid in uploads:
+                how = "host"
+            else:
+                how = "matched" if i < seq.hashed_pages else "fresh"
+            out.append(
+                [pid, how, "" if how == "fresh" else self.kv.page_writer(pid)]
+            )
+        return out
 
     # ------------------------------------------------------ request anatomy
     def _record_anatomy(self, seq: Sequence, reason, now: float, was_bound: bool) -> None:
@@ -3171,13 +3225,19 @@ class TPUEngine(AsyncEngine):
             if prof is not None:
                 prof.end("kv_move", t0, fresh)
             seq.page_ids[idx] = new_pid
+            self.kv.note_writer(
+                [new_pid], f"cow<-{pid}:{self.kv.page_writer(pid)}"
+            )
             self.kv.release_sequence([pid])
             self.kv_page_moves += 1
             self.kv_move_dispatches += 1
             get_telemetry().kv_page_moves.labels("cow").inc()
             get_telemetry().kv_cow_copies.inc()
             if self.flight is not None:
-                self.flight.record("cow", req=seq.request_id, slot=seq.slot)
+                self.flight.record(
+                    "cow", req=seq.request_id, slot=seq.slot,
+                    src=pid, dst=new_pid,
+                )
         seq.shared_tail_pid = -1
         return True
 
@@ -3298,10 +3358,8 @@ class TPUEngine(AsyncEngine):
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
             n_variants = len(self._ragged_fns)
-            fn = self._ragged_fn(
-                nb, cfg.ragged_page_bucket_for(max_pages), True, full_sampler,
-                want_lp,
-            )
+            page_bucket = cfg.ragged_page_bucket_for(max_pages)
+            fn = self._ragged_fn(nb, page_bucket, True, full_sampler, want_lp)
             fresh = len(self._ragged_fns) > n_variants
         if self._pending_offloads:
             with self._phase("kv_admin"):
@@ -3332,6 +3390,10 @@ class TPUEngine(AsyncEngine):
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
+        key = self._count_dispatch(
+            nb, page_bucket, True, full_sampler, want_lp, False,
+            chained=False, live=sum(e.n_valid for e in rows),
+        )
         if self.flight is not None:
             self.flight.record(
                 "dispatch",
@@ -3339,6 +3401,8 @@ class TPUEngine(AsyncEngine):
                 rows=len(part),
                 bucket=nb,
                 windowed=True,
+                pages=key[1],
+                want_lp=want_lp,
             )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(part))
@@ -3417,6 +3481,7 @@ class TPUEngine(AsyncEngine):
                 return start
 
             rows: list[_RaggedRow] = []
+            chunks: list[tuple[Sequence, int, int]] = []  # (seq, from, n)
             max_pages = 1
             r = 0
             for seq in pf:
@@ -3429,6 +3494,7 @@ class TPUEngine(AsyncEngine):
                     r,
                 )
                 seq.prefill_sent = start_tok + n
+                chunks.append((seq, start_tok, n))
                 table[r, : len(seq.page_ids)] = seq.page_ids
                 q_last[r] = qs + n - 1
                 # Key the first-token draw by the absolute position of the
@@ -3493,9 +3559,9 @@ class TPUEngine(AsyncEngine):
             )
             with_spec = bool(spec)
             n_variants = len(self._ragged_fns)
+            page_bucket = cfg.ragged_page_bucket_for(max_pages)
             fn = self._ragged_fn(
-                nb, cfg.ragged_page_bucket_for(max_pages), False, full_sampler,
-                want_lp, with_spec,
+                nb, page_bucket, False, full_sampler, want_lp, with_spec,
             )
             fresh = len(self._ragged_fns) > n_variants
         if self._pending_offloads:
@@ -3527,6 +3593,10 @@ class TPUEngine(AsyncEngine):
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
+        key = self._count_dispatch(
+            nb, page_bucket, False, full_sampler, want_lp, with_spec,
+            chained=False, live=int((positions >= 0).sum()),
+        )
         if self.flight is not None:
             self.flight.record(
                 "dispatch",
@@ -3535,6 +3605,15 @@ class TPUEngine(AsyncEngine):
                 tokens=total_q,
                 bucket=nb,
                 windowed=False,
+                pages=key[1],
+                want_lp=want_lp,
+            )
+        # Name this dispatch on the pages its prompt chunks wrote, for
+        # the admission record of whoever attaches them later.
+        for seq, start_tok, n in chunks:
+            self.kv.note_writer(
+                seq.page_ids[start_tok // ps : (start_tok + n - 1) // ps + 1],
+                f"mixed:nb={nb}:computed={n}:cached={start_tok}",
             )
         # Pages this batch's chunks fully covered are now filled *in
         # dispatch order*: sharers gated on them may dispatch reads
@@ -3657,9 +3736,10 @@ class TPUEngine(AsyncEngine):
                     _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
                 )
             n_variants = len(self._ragged_fns)
+            page_bucket = cfg.ragged_page_bucket_for(max_pages)
             fn = self._ragged_fn(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
                 nb,
-                cfg.ragged_page_bucket_for(max_pages),
+                page_bucket,
                 True,
                 pending.full_sampler,
                 pending.want_lp,
@@ -3694,6 +3774,10 @@ class TPUEngine(AsyncEngine):
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
+        key = self._count_dispatch(
+            nb, page_bucket, True, pending.full_sampler, pending.want_lp,
+            False, chained=True, live=sum(e.n_valid for e in rows),
+        )
         if self.flight is not None:
             self.flight.record(
                 "dispatch",
@@ -3702,6 +3786,8 @@ class TPUEngine(AsyncEngine):
                 bucket=nb,
                 windowed=True,
                 chained=True,
+                pages=key[1],
+                want_lp=pending.want_lp,
             )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(rows))

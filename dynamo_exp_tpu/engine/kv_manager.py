@@ -60,6 +60,11 @@ class PageRecord:
     # a dead filler leaves it "" (orphaned) for a sharer to claim.
     filled: bool = True
     filler: str = ""
+    # Which dispatch last wrote prompt tokens into this page (the
+    # engine's own tag: program, bucket, tokens computed and found
+    # cached), for the flight recorder's admission record. "" = only
+    # decode steps, or nothing yet.
+    writer: str = ""
 
 
 @dataclass
@@ -545,6 +550,16 @@ class KvPageManager:
         rec.filled = False
         rec.filler = request_id
 
+    def note_writer(self, page_ids: Sequence[int], tag: str) -> None:
+        """``tag`` names the prefill dispatch that just wrote into
+        these pages (read back by :meth:`page_writer` when a later
+        admission attaches them)."""
+        for pid in page_ids:
+            self._records[pid].writer = tag
+
+    def page_writer(self, page_id: int) -> str:
+        return self._records[page_id].writer
+
     def fill_state(self, page_id: int) -> str:
         """"filled" | "pending" (live filler) | "orphaned" (filler died
         before dispatching the write; a sharer must claim + re-fill)."""
@@ -833,6 +848,7 @@ class KvPageManager:
         rec.seq_hash = None
         rec.filled = True
         rec.filler = ""
+        rec.writer = ""
         return pid
 
     def _evict(self, pid: int) -> None:

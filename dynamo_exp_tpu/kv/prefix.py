@@ -270,12 +270,19 @@ class PrefixIndex:
             return None
         tail = tuple(tail)
         # Successor candidates: the next block of the same run, else the
-        # first block of each child (deterministic insertion order).
+        # first block of each child. Several children may extend the
+        # tail (blocks that agree on it and differ later: two replies to
+        # one prompt). The answer is the smallest hash among them, a
+        # rule of the blocks' content alone: the children's order is
+        # the order of insertion, which evictions and re-registrations
+        # rearrange, and equal requests on equal cache contents must
+        # read the same page.
         if idx + 1 < len(node.hashes):
             candidates = [(node.hashes[idx + 1], node.tokens[idx + 1])]
         else:
             candidates = [(c.hashes[0], c.tokens[0]) for c in node.children.values()]
-        for h, tok in candidates:
-            if tok is not None and len(tok) >= len(tail) and tok[: len(tail)] == tail:
-                return h, len(tail)
-        return None
+        extending = [
+            h for h, tok in candidates
+            if tok is not None and len(tok) >= len(tail) and tok[: len(tail)] == tail
+        ]
+        return (min(extending), len(tail)) if extending else None

@@ -227,7 +227,8 @@ def _act(name: str):
 
 
 def _attn_mlp_layer(
-    x, lp, cfg, inv_freq, rope_pos, eps, attend, reduce=None, mesh=None
+    x, lp, cfg, inv_freq, rope_pos, eps, attend, reduce=None, mesh=None,
+    live=None,
 ):
     """One transformer layer, shared by the paged and ring paths.
 
@@ -244,6 +245,8 @@ def _attn_mlp_layer(
 
     Family variations live in the param pytree: ``bq/bk/bv`` present =
     QKV bias (qwen2); ``router`` present = sparse-MoE FFN (mixtral).
+    ``live`` ([B, T] bool, False = padding) is for that FFN alone: the
+    one operation in which the rows of a dispatch meet (``_ffn_block``).
 
     The ``jax.named_scope`` blocks (``qkv``, ``attn_out``, ``ffn`` /
     ``moe`` here; ``kv_write`` and ``attend`` in the callers' ``attend``)
@@ -276,13 +279,16 @@ def _attn_mlp_layer(
             attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps, off)
         x = x + attn_out
     with jax.named_scope("moe" if "router" in lp else "ffn"):
-        x = _ffn_block(x, lp, cfg, eps, red, mesh)
+        x = _ffn_block(x, lp, cfg, eps, red, mesh, live)
     return x, kv_extra
 
 
-def _ffn_block(x, lp, cfg, eps, red, mesh):
+def _ffn_block(x, lp, cfg, eps, red, mesh, live=None):
     """The layer's second half: norm, dense SwiGLU or sparse-MoE FFN,
-    residual add."""
+    residual add. ``live`` ([B, T] bool) keeps a dispatch's padding
+    rows out of the routed experts' sort (``ops/moe.py::moe_ffn``);
+    every other form computes a row from that row alone and ignores
+    it."""
     B, T = x.shape[:2]
     off = cfg.rms_norm_offset
     h = rms_norm(x, lp["mlp_norm"], eps, off)
@@ -322,6 +328,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh):
                 lp["w_down"],
                 cfg.num_experts_per_tok,
                 cfg.norm_topk_prob,
+                live=None if live is None else live.reshape(B * T),
             ).reshape(B, T, -1)
             if shared is not None:
                 y = y + shared
@@ -401,7 +408,8 @@ def _per_layer_xs(cfg, inv_freq):
 
 
 def _scan_paged_layers(
-    params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh
+    params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
+    live,
 ):
     """The layer loop of both paged forwards: ``lax.scan`` carrying
     ``(x, k_cache, v_cache)`` over the stacked parameters, the per-layer
@@ -415,7 +423,8 @@ def _scan_paged_layers(
     and ``table`` moved to layer ``l``'s range. Every read path (the
     kernel's page DMAs, the XLA gather) takes a pool and page ids, so
     none needs to know the layer; a ``dynamic_slice`` of the layer's
-    pool would be a copy of it."""
+    pool would be a copy of it. ``live`` ([B, T] bool) marks the rows
+    that are not padding, for the routed experts (``_ffn_block``)."""
     L, num_pages = k_cache.shape[:2]
     flat = (L * num_pages, *k_cache.shape[2:])
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
@@ -440,7 +449,7 @@ def _scan_paged_layers(
             return attn, (kp, vp)
 
         x, (k_pool, v_pool) = _attn_mlp_layer(
-            x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh
+            x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh, live=live
         )
         return (x, k_pool, v_pool), None
 
@@ -558,6 +567,7 @@ def forward(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid),
         page_table if use_pallas else attn_table, read, mesh,
+        positions >= 0,
     )
     if last_positions is not None:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
@@ -742,6 +752,7 @@ def forward_ragged(
     x, k_cache, v_cache = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid), attn_table, read, mesh,
+        (positions >= 0)[None],
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
     return _final_logits(params, cfg, xo, eps), k_cache, v_cache

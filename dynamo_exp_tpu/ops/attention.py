@@ -75,7 +75,10 @@ def paged_attention(
     """Causal attention of queries against their sequences' pages.
 
     Returns [B, T, H, D]. Positions beyond a query's own position are
-    masked, so garbage in not-yet-written slots never leaks. ``window``
+    masked, so garbage in not-yet-written slots never leaks; a padding
+    query (position -1) has nothing to attend to and returns exact
+    zeros (its softmax would otherwise be uniform over every gathered
+    slot: the mean of whatever those pages hold). ``window``
     (mistral/gemma2 sliding-window attention) additionally masks keys
     older than ``q_pos - window + 1`` — it may be a traced scalar, so a
     scan over layers can alternate window widths (gemma2). ``softcap``
@@ -118,6 +121,7 @@ def paged_attention(
         v,
         preferred_element_type=jnp.float32,
     )
+    out = jnp.where(q_positions[:, :, None, None, None] >= 0, out, 0.0)
     return out.reshape(B, T, H, D).astype(q.dtype)
 
 

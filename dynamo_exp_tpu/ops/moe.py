@@ -56,6 +56,7 @@ def moe_ffn(
     w_down: jnp.ndarray,  # [E, I, D]
     num_experts_per_tok: int,
     norm_topk_prob: bool = True,
+    live: jnp.ndarray | None = None,  # [N] bool, False = padding row
 ) -> jnp.ndarray:
     """SwiGLU expert FFN with exact top-k dispatch. Returns [N, D].
 
@@ -63,11 +64,22 @@ def moe_ffn(
     pairs by expert so each expert sees one contiguous slab, and
     ``ragged_dot`` runs the per-group matmuls without materialising a
     one-hot dispatch tensor or imposing a capacity.
+
+    This is the one place where the rows of a dispatch meet: the sort
+    and the group sizes run over every row. Rows that ``live`` marks
+    False (a dispatch's padding) therefore leave the path: they sort
+    past the last group, count in no ``group_sizes`` entry, add to no
+    row and return zeros — so where a live row lands among the sorted
+    rows, and hence every bit it computes, depends on the live rows
+    alone.
     """
     N, D = x.shape
     E = router_w.shape[-1]
     K = num_experts_per_tok
     weights, ids = moe_router(x, router_w, K, norm_topk_prob)
+    if live is not None:
+        ids = jnp.where(live[:, None], ids, E)  # past every group
+        weights = jnp.where(live[:, None], weights, 0.0)
 
     flat_ids = ids.reshape(-1)  # [N*K]
     # Stable sort so each token's k replicas keep a deterministic order.
@@ -83,6 +95,9 @@ def moe_ffn(
 
     w_sorted = jnp.take(weights.reshape(-1), order)  # [N*K] float32
     out = out.astype(jnp.float32) * w_sorted[:, None]
+    if live is not None:
+        # Rows past the last group are not the ragged_dot's to define.
+        out = jnp.where((jnp.take(flat_ids, order) < E)[:, None], out, 0.0)
     # Unsort + combine: scatter-add each replica back onto its token.
     y = jnp.zeros((N, D), jnp.float32).at[token_of].add(out)
     return y.astype(x.dtype)

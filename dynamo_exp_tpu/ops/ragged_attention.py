@@ -85,8 +85,7 @@ def ragged_paged_attention_ref(
     """Pure-JAX ragged paged attention (the CPU/tier-1 parity path).
 
     Returns ``[N, H, D]`` in q's dtype; padding tokens (position -1)
-    return garbage the caller must ignore (their writes were already
-    dropped, and nothing samples from them). Matmuls run in the cache
+    return exact zeros, like the kernel's. Matmuls run in the cache
     dtype with float32 accumulation, softmax in float32 — the same
     numeric contract as ``ops/attention.paged_attention``, so a
     one-token row here is bit-identical to the old decode gather.
@@ -190,8 +189,9 @@ def _ragged_kernel(
             dma.start()
 
     # Per-query absolute positions (the causal bound), read from SMEM.
-    # Padding queries carry -1: nothing satisfies kv_pos <= -1, their
-    # softmax sum stays 0 and the final divide maps them to zeros.
+    # Padding queries carry -1: nothing satisfies kv_pos <= -1, so with
+    # ``p`` masked below their softmax sum stays 0 and the final divide
+    # maps them to zeros, whatever the pages the tile brought in hold.
     pos_col = jnp.stack(
         [positions_ref[t * qt + i] for i in range(qt)]
     )  # [QT]
@@ -234,12 +234,19 @@ def _ragged_kernel(
                 )
                 * scale
             )  # [qpk*QT, S]
-            s = jnp.where(tok_idx <= pos_rows, s, -1e30)
+            live = tok_idx <= pos_rows
+            s = jnp.where(live, s, -1e30)
             m_prev = m_ref[rows, :1]  # [qpk*QT, 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
+            # The mask is finite, so a query with nothing to attend to
+            # (padding inside a live tile) has m_new == -1e30 and every
+            # exp(s - m_new) == 1: masking s alone would give it the
+            # mean of whatever V slots the tile's chunks hold (the row's
+            # context, its last page's slots past the length, clamped
+            # table entries). A live query's masked p is already 0.
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
             l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
                 p, axis=1, keepdims=True
             )
@@ -285,10 +292,11 @@ def ragged_paged_attention(
     exactly one row — each grid cell DMAs only that row's live pages
     and computes ``q_tile`` queries against them. ``q_tile=1`` is the
     pure-decode shape (one query per row, the old paged-decode kernel's
-    grid). Returns [N, H, D] in q's dtype. Padding slots inside a live
-    tile return unspecified values the caller must ignore (their KV
-    writes were dropped and nothing samples from them); fully-empty
-    tiles (inactive rows) return exact zeros.
+    grid). Returns [N, H, D] in q's dtype. Padding slots return exact
+    zeros, inside a live tile as in a fully-empty one (inactive rows):
+    routed experts sort every row of a dispatch together
+    (``ops/moe.py``), so a padding row must not carry what memory no
+    request owns into the rows beside it.
 
     The caller guarantees the fed tokens' K/V are already written
     (write-then-gather), so a tile's DMA bound is its max position + 1.

@@ -199,6 +199,29 @@ class Telemetry:
             buckets=(1, 2, 4, 8, 16, 32, 64, 128),
             registry=self.registry,
         )
+        # What a dispatch ran as, by the whole variant key
+        # (``aot.lattice.resolve_ragged_key``), so that two askings can
+        # be told apart by the programs they took, not only by row
+        # counts: label sets are bounded by the compile lattice. Host
+        # counts from the builders; nothing is read from the device.
+        self.ragged_dispatches = Counter(
+            "dynamo_ragged_dispatch_total",
+            "Ragged dispatches by program variant: token/row bucket, "
+            "XLA page bound ('kernel' where the Pallas kernel reads "
+            "true lengths), log-probability and sampler variants, and "
+            "whether a window was chained onto the one before it",
+            ["program", "nb", "pages", "want_lp", "full_sampler", "chained"],
+            registry=self.registry,
+        )
+        self.moe_rows = Counter(
+            "dynamo_moe_rows_total",
+            "Rows of routed-expert models' dispatches by kind: live "
+            "(a real query token; a window counts each row's valid "
+            "steps) or pad (bucket and tile padding, which "
+            "ops/moe.py::moe_ffn keeps out of every expert's group)",
+            ["kind"],  # live | pad
+            registry=self.registry,
+        )
         self.decode_wasted_steps = Counter(
             "dynamo_decode_wasted_steps_total",
             "Decode window steps computed for a row past its stop point",
