@@ -38,7 +38,7 @@ RECLAIM_SEED_SETS := 7,21,1337 5,8,13
 # re-prefill, and a failing store must degrade to G2-only, never hang.
 STORE_SEED_SETS := 7,21,1337 3,9,27
 
-.PHONY: test pre-merge nightly chaos sim sim-scale flight profile-smoke lint prewarm-smoke bench-compare anatomy-smoke tune-smoke
+.PHONY: test pre-merge nightly chaos sim sim-scale flight profile-smoke lint prewarm-smoke anatomy-smoke tune-smoke
 
 test:
 	$(PYTEST) tests/ -q -m "not tpu and not weekly"
@@ -131,20 +131,8 @@ prewarm-smoke:
 # Runs in the pre-merge lane next to `make chaos`; the same gate is a
 # tier-1 test (tests/test_analysis.py).
 lint:
-	ruff check dynamo_exp_tpu/ tests/ bench.py __graft_entry__.py
+	ruff check dynamo_exp_tpu/ tests/ __graft_entry__.py
 	python -m dynamo_exp_tpu.llmctl lint --json
-
-# Bench regression comparator (docs/observability.md "Fleet plane"):
-# compare the two newest BENCH_r*.json captures in the root, if a
-# driver left any there (none is checked in), and fail on >10% tok/s or
-# TTFT/ITL regressions per metric. Platform-tag aware: chip lines never
-# compare against CPU lines, and captures with no comparable pairs
-# compare clean. Runs pre-merge (pre-merge.yml).
-bench-compare:
-	@files=$$(ls BENCH_r*.json 2>/dev/null | sort | tail -2); \
-	set -- $$files; \
-	if [ $$# -lt 2 ]; then echo "fewer than two BENCH_r*.json files; nothing to compare"; exit 0; fi; \
-	python -m dynamo_exp_tpu.llmctl bench compare $$1 $$2
 
 # Request-anatomy + workload-fingerprint smoke (docs/observability.md
 # "Request anatomy" / "Workload fingerprint"): decompose every trace in

@@ -70,7 +70,7 @@ def default_root() -> str:
 
 def iter_source_files(root: str) -> list[str]:
     """Repo-relative posix paths of every package .py file under
-    ``<root>/dynamo_exp_tpu``. tests/, bench.py and examples/ are not
+    ``<root>/dynamo_exp_tpu``. tests/, benchmark/ and examples/ are not
     zone members; scanning only the package keeps fixtures and harness
     code out of the gate."""
     pkg_dir = os.path.join(root, "dynamo_exp_tpu")

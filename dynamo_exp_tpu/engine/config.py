@@ -144,9 +144,9 @@ class EngineConfig:
     # copy-on-write KV pages behind the radix prefix index — admissions
     # attach resident (even still-filling) shared prefix pages and
     # prefill only the unshared suffix. False is the private-copy
-    # baseline: every admission materializes its own pages (bench.py
-    # --prefix-sweep's comparison arm; identity tests prove the token
-    # streams are equal either way).
+    # baseline: every admission materializes its own pages (the
+    # reference of tests/test_prefix_sharing.py's identity tests, which
+    # prove the token streams are equal either way).
     prefix_sharing: bool = True
     # KV-pressure preemption (docs/fault_tolerance.md "Overload
     # protection"): when the page pool is dry and an ACTIVE row has been
@@ -167,7 +167,7 @@ class EngineConfig:
     spec_mode: str = "off"
     # Initial per-row draft length; the adaptive controller moves it
     # within [spec_min_draft, spec_max_draft] from the rolling
-    # acceptance rate (spec_adaptive=False pins it — bench sweeps).
+    # acceptance rate (spec_adaptive=False pins it — tests/test_spec.py).
     spec_draft_len: int = 4
     spec_min_draft: int = 1
     spec_max_draft: int = 8
@@ -187,8 +187,6 @@ class EngineConfig:
     profile_dispatches: bool = True
     # Engine flight recorder (docs/observability.md): bounded ring of
     # loop events dumped on watchdog stall / SIGUSR1 / loop crash.
-    flight_events: bool = True
-    flight_capacity: int = 2048
     # Dump target; empty resolves to $DYN_FLIGHT_DUMP or a per-process
     # file under the tempdir (telemetry.flight.default_dump_path).
     flight_dump_path: str = ""

@@ -180,6 +180,7 @@ class _RaggedRow:
     row: int  # per-row array index in the dispatch
     n_valid: int = 0  # decode: window steps the host may keep
     completing: bool = False  # prefill: prompt finishes this chunk
+    counted: bool = False  # prefill: the first token drew as a decode row
     n_drafts: int = 0  # spec: drafts fed for verification
 
 
@@ -354,9 +355,7 @@ class TPUEngine(AsyncEngine):
         self._phase = (
             self.profiler.phase if self.profiler is not None else _no_phase
         )
-        self.flight = (
-            FlightRecorder(cfg.flight_capacity) if cfg.flight_events else None
-        )
+        self.flight = FlightRecorder()
         self.sched = Scheduler(cfg, self.kv, flight=self.flight)
         if self.profiler is not None:
             self.sched.span_attrs = self._decode_span_attrs
@@ -453,7 +452,7 @@ class TPUEngine(AsyncEngine):
         # Chained decode: the dispatched-but-unconsumed window (if any).
         self._inflight: _PendingRagged | None = None
         # Occupancy/movement counters (mirrored to /metrics counters and
-        # surfaced by metrics() for bench.py's occupancy sweep).
+        # surfaced by metrics(); tests/test_decode_compaction.py reads them).
         self.wasted_steps = 0  # window steps computed past a row's stop
         self.kv_page_moves = 0  # pages moved by batched gather/scatter
         self.kv_move_dispatches = 0  # batched-move dispatches issued
@@ -462,7 +461,7 @@ class TPUEngine(AsyncEngine):
         # draft tokens, the prefix the verify pass accepted, tokens
         # actually emitted, and verify dispatches issued — acceptance
         # rate and tokens-per-dispatch derive from these (mirrored to
-        # /metrics and bench.py --spec-sweep).
+        # /metrics and tests/test_spec.py).
         self.spec_dispatches = 0  # batched verify dispatches (device)
         self.spec_row_dispatches = 0  # row participations (per-row basis)
         self.spec_draft_tokens = 0
@@ -986,18 +985,17 @@ class TPUEngine(AsyncEngine):
             target=self._loop, name="tpu-engine-loop", daemon=True
         )
         self._thread.start()
-        if self.flight is not None:
-            self._flight_handle = register_dumper(self._dump_flight)
-            if self.cfg.watchdog_stall_s > 0 and self._watchdog is None:
-                self._watchdog = Watchdog(
-                    self.cfg.watchdog_stall_s,
-                    progress=lambda: self._progress_mark,
-                    has_work=lambda: (
-                        self.sched.has_work() or not self._submit_q.empty()
-                    ),
-                    dump_fn=self._dump_flight,
-                )
-                self._watchdog.start()
+        self._flight_handle = register_dumper(self._dump_flight)
+        if self.cfg.watchdog_stall_s > 0 and self._watchdog is None:
+            self._watchdog = Watchdog(
+                self.cfg.watchdog_stall_s,
+                progress=lambda: self._progress_mark,
+                has_work=lambda: (
+                    self.sched.has_work() or not self._submit_q.empty()
+                ),
+                dump_fn=self._dump_flight,
+            )
+            self._watchdog.start()
 
     def stop(self) -> None:
         self._running = False
@@ -1110,12 +1108,11 @@ class TPUEngine(AsyncEngine):
         tel.prewarm_seconds.set(report.seconds)
         tel.prewarm_variants.labels("ragged").inc(report.ragged_variants)
         tel.prewarm_variants.labels("move").inc(report.move_variants)
-        if self.flight is not None:
-            self.flight.record(
-                "prewarm",
-                ragged=report.ragged_variants,
-                moves=report.move_variants,
-            )
+        self.flight.record(
+            "prewarm",
+            ragged=report.ragged_variants,
+            moves=report.move_variants,
+        )
         return report
 
     # ------------------------------------------------------------ AsyncEngine
@@ -1503,16 +1500,12 @@ class TPUEngine(AsyncEngine):
         k_np, v_np = np.asarray(k_b), np.asarray(v_b)  # dynlint: sync-point(reclaim extract gather consume)
         if self.profiler is not None:
             self.profiler.consume("kv_move", self._last_move_t)
-        if self.flight is not None:
-            self.flight.record("consume", dispatch="kv_move", pages=full)
+        self.flight.record("consume", dispatch="kv_move", pages=full)
         get_telemetry().kv_page_moves.labels("extract").inc(full)
         lease_id = self.kv.grant_lease(pids, ttl_s)
         if seq.trace is not None:
             self._lease_traces[lease_id] = (seq.trace, time.time())
-        if self.flight is not None:
-            self.flight.record(
-                "lease_grant", req=seq.request_id, pages=full
-            )
+        self.flight.record("lease_grant", req=seq.request_id, pages=full)
         pages = [
             (
                 np.ascontiguousarray(k_np[:, i]),
@@ -1632,8 +1625,7 @@ class TPUEngine(AsyncEngine):
                         continue
                     # Chain broken (arrivals / prefill / stop / dry
                     # pool): fall through to the full scheduling path.
-                    if self.flight is not None:
-                        self.flight.record("chain_break")
+                    self.flight.record("chain_break")
                 if not self.sched.has_work() and self._submit_q.empty():
                     # Flush buffered evictions before idling (the host
                     # tier must see them even with no next dispatch) and
@@ -1797,16 +1789,15 @@ class TPUEngine(AsyncEngine):
         if self.profiler is not None:
             seq.anat_compile_mark = self.profiler.compile_total_s()
         self._note_prefetch_admission(seq)
-        if self.flight is not None:
-            self.flight.record(
-                "admit",
-                req=seq.request_id,
-                slot=seq.slot,
-                prompt=len(seq.prompt),
-                cached=seq.cached_len,
-                priority=seq.priority,
-                pages=self._attached_pages(seq),
-            )
+        self.flight.record(
+            "admit",
+            req=seq.request_id,
+            slot=seq.slot,
+            prompt=len(seq.prompt),
+            cached=seq.cached_len,
+            priority=seq.priority,
+            pages=self._attached_pages(seq),
+        )
         tel = get_telemetry()
         # Prompt tokens by source, from the allocation that just fed
         # kv.prefix_hits its pages: what the prefix cache saved in the
@@ -1980,8 +1971,6 @@ class TPUEngine(AsyncEngine):
     def _dump_flight(self, reason: str) -> None:
         """Dump the flight ring + snapshot (watchdog stall, SIGUSR1 via
         the process registry, or engine-loop crash)."""
-        if self.flight is None:
-            return
         path = self.cfg.flight_dump_path or default_dump_path()
         self.flight.dump(path, reason, snapshot=self._flight_snapshot())
 
@@ -2030,8 +2019,7 @@ class TPUEngine(AsyncEngine):
                 lid = self._lease_confirm_q.get_nowait()
                 self.kv.confirm_lease(lid)
                 self._close_lease_span(lid, "confirmed")
-                if self.flight is not None:
-                    self.flight.record("lease_confirm")
+                self.flight.record("lease_confirm")
             except queue.Empty:
                 break
         if self.kv.active_leases:
@@ -2039,8 +2027,7 @@ class TPUEngine(AsyncEngine):
             if reclaimed:
                 for lid, pages in self.kv.last_reaped:
                     self._close_lease_span(lid, "reaped", pages=pages)
-                if self.flight is not None:
-                    self.flight.record("lease_reap", pages=reclaimed)
+                self.flight.record("lease_reap", pages=reclaimed)
                 get_telemetry().kv_lease_reclaims.inc(reclaimed)
                 log.warning(
                     "reaped %d KV pages from expired handoff leases "
@@ -2105,10 +2092,7 @@ class TPUEngine(AsyncEngine):
         get_telemetry().kv_ledger_violations.inc(len(violations))
         for v in violations:
             log.error("KV ledger violation: %s", v)
-        if self.flight is not None:
-            self.flight.record(
-                "ledger_violation", count=len(violations)
-            )
+        self.flight.record("ledger_violation", count=len(violations))
         if not self._ledger_dumped:
             self._ledger_dumped = True
             self._dump_flight("kv_ledger")
@@ -2370,14 +2354,13 @@ class TPUEngine(AsyncEngine):
         tel = get_telemetry()
         tel.kv_proactive_offloads.inc()
         tel.kv_page_moves.labels("offload").inc(len(off_pids))
-        if self.flight is not None:
-            self.flight.record(
-                "swap_out",
-                req=victim.request_id,
-                slot=victim.slot,
-                pages=freed,
-                kept=len(victim.page_ids),
-            )
+        self.flight.record(
+            "swap_out",
+            req=victim.request_id,
+            slot=victim.slot,
+            pages=freed,
+            kept=len(victim.page_ids),
+        )
         log.info(
             "KV pressure: proactively offloaded %d page(s) of request %s "
             "to the host tier (%d kept resident); preemption avoided",
@@ -2495,14 +2478,13 @@ class TPUEngine(AsyncEngine):
             seq.swapped_since = 0.0
             self.swap_ins += 1
             get_telemetry().kv_swap_ins.inc()
-            if self.flight is not None:
-                self.flight.record(
-                    "swap_in",
-                    req=seq.request_id,
-                    slot=seq.slot,
-                    pages=len(inj),
-                    attached=len(attach),
-                )
+            self.flight.record(
+                "swap_in",
+                req=seq.request_id,
+                slot=seq.slot,
+                pages=len(inj),
+                attached=len(attach),
+            )
 
     def _preempt_swapped(self, seq: Sequence) -> None:
         """Swap-in fallback: the host tier lost a swapped page, so the
@@ -2667,10 +2649,7 @@ class TPUEngine(AsyncEngine):
                 get_telemetry().kv_prefetch_pages.labels("restored").inc(
                     len(inj)
                 )
-                if self.flight is not None:
-                    self.flight.record(
-                        "prefetch", req=job["req"], pages=len(inj)
-                    )
+                self.flight.record("prefetch", req=job["req"], pages=len(inj))
             if served:
                 self._prefetch_served[job["req"]] = served
                 while len(self._prefetch_served) > 256:
@@ -2769,8 +2748,7 @@ class TPUEngine(AsyncEngine):
         )
         if prof is not None:
             self._last_move_t = prof.end(kind, t0, fresh)  # dynlint: thread-ownership(loop thread joined before teardown flush)
-        if self.flight is not None:
-            self.flight.record("dispatch", dispatch=kind, pages=len(pids))
+        self.flight.record("dispatch", dispatch=kind, pages=len(pids))
         self.kv_move_dispatches += 1  # dynlint: thread-ownership(loop thread joined before teardown flush)
         self.kv_page_moves += len(pids)  # dynlint: thread-ownership(loop thread joined before teardown flush)
         return k_b, v_b
@@ -2805,10 +2783,9 @@ class TPUEngine(AsyncEngine):
         )
         if prof is not None:
             prof.end("kv_move", t0, fresh)
-        if self.flight is not None:
-            self.flight.record(
-                "dispatch", dispatch="kv_move", op=op, pages=len(pids)
-            )
+        self.flight.record(
+            "dispatch", dispatch="kv_move", op=op, pages=len(pids)
+        )
         self.kv_move_dispatches += 1
         self.kv_page_moves += len(pids)
         get_telemetry().kv_page_moves.labels(op).inc(len(pids))
@@ -2877,13 +2854,15 @@ class TPUEngine(AsyncEngine):
         return ([float(x) for x in lps], tops)
 
     def _finish_first_token(
-        self, seq: Sequence, token: int, lp_pack=None
+        self, seq: Sequence, token: int, lp_pack=None, counted: bool = False
     ) -> None:
         """Shared tail of the two admission paths (computed prefill or
         remote-KV injection): record + announce the first sampled token
         and promote the sequence to decode. ``lp_pack`` is None on the
         remote-KV path — the first token was sampled on the prefill
-        worker, which doesn't ship its distribution."""
+        worker, which doesn't ship its distribution. ``counted``: the
+        dispatch that drew ``token`` already left the slot's penalty
+        counts at journaled tail + ``token`` (``_build_mixed``)."""
         now = time.time()
         seq.first_token_at = seq.last_emit_at = now
         tel = get_telemetry()
@@ -2917,30 +2896,18 @@ class TPUEngine(AsyncEngine):
                 else {}
             ),
         )
-        if self.flight is not None:
-            # Anatomy reconstruction from a flight dump alone needs the
-            # prefill/decode boundary (telemetry.anatomy.anatomy_from_flight).
-            self.flight.record("first_token", req=seq.request_id, slot=seq.slot)
+        # Anatomy reconstruction from a flight dump alone needs the
+        # prefill/decode boundary (telemetry.anatomy.anatomy_from_flight).
+        self.flight.record("first_token", req=seq.request_id, slot=seq.slot)
         seq.state = SeqState.ACTIVE
-        self._counts = self._init_row(self._counts, seq.slot, token)
-        resumed = seq.stop.resume_offset or 0
-        if resumed and self._needs_sampler(seq):
-            # Failover continuation with penalties: the re-prefilled tail
-            # of token_ids is journaled *completion* tokens — rebuild the
-            # penalty counts from it so every post-splice decode draw
-            # sees the counts the uninterrupted run would have. (The
-            # splice token itself was just sampled by prefill, which
-            # reads the raw model distribution — see the documented
-            # caveat in docs/fault_tolerance.md.)
-            V = self.cfg.model.vocab_size
-            vec = np.zeros(V, np.int32)
-            tail = np.clip(
-                np.asarray(seq.prompt[-resumed:], np.int64),  # dynlint: sync-point(host-list conversion)
-                0,
-                V - 1,
-            )
-            np.add.at(vec, tail, 1)
-            self._counts = self._counts.at[seq.slot].add(jnp.asarray(vec))
+        if not counted:
+            self._counts = self._init_row(self._counts, seq.slot, token)
+            # Remote-KV continuation with penalties: the first token was
+            # drawn on the prefill worker; every later draw sees the
+            # counts the uninterrupted run would have.
+            tail = self._journaled_tail_counts(seq)
+            if tail is not None:
+                self._counts = self._counts.at[seq.slot].add(jnp.asarray(tail))
         seq.tokens.append(token)
         seq.generated = 1
         self.sched.register_full_pages(seq)
@@ -2970,10 +2937,7 @@ class TPUEngine(AsyncEngine):
         k_np, v_np = np.asarray(k_b), np.asarray(v_b)  # dynlint: sync-point(extract gather consume)
         if self.profiler is not None:
             self.profiler.consume("kv_move", self._last_move_t)
-        if self.flight is not None:
-            self.flight.record(
-                "consume", dispatch="kv_move", pages=len(pids)
-            )
+        self.flight.record("consume", dispatch="kv_move", pages=len(pids))
         get_telemetry().kv_page_moves.labels("extract").inc(len(pids))
         lease_id = self.kv.grant_lease(pids, self.cfg.kv_lease_ttl_s)
         if seq.trace is not None:
@@ -2981,10 +2945,7 @@ class TPUEngine(AsyncEngine):
             # when the delivery ack confirms it or the reaper reclaims
             # it, so `llmctl trace` shows grant -> confirm | reap.
             self._lease_traces[lease_id] = (seq.trace, time.time())
-        if self.flight is not None:
-            self.flight.record(
-                "lease_grant", req=seq.request_id, pages=len(pids)
-            )
+        self.flight.record("lease_grant", req=seq.request_id, pages=len(pids))
         return [
             (
                 np.ascontiguousarray(k_np[:, i]),
@@ -3095,10 +3056,9 @@ class TPUEngine(AsyncEngine):
                 seq.stalled = True
                 if not seq.stalled_since:
                     seq.stalled_since = time.time()
-                    if self.flight is not None:
-                        self.flight.record(
-                            "stall_start", req=seq.request_id, slot=seq.slot
-                        )
+                    self.flight.record(
+                        "stall_start", req=seq.request_id, slot=seq.slot
+                    )
                 continue
             wpos = len(seq.tokens) - 1  # position of the token being fed
             # Provision the whole window up front (best effort: partial
@@ -3126,10 +3086,9 @@ class TPUEngine(AsyncEngine):
                 seq.stalled = True
                 if not seq.stalled_since:
                     seq.stalled_since = time.time()
-                    if self.flight is not None:
-                        self.flight.record(
-                            "stall_start", req=seq.request_id, slot=seq.slot
-                        )
+                    self.flight.record(
+                        "stall_start", req=seq.request_id, slot=seq.slot
+                    )
                 continue  # pool dry: this slot idles one window
             seq.stalled = len(seq.page_ids) * ps < min(
                 wpos + K, cfg.max_model_len
@@ -3137,10 +3096,9 @@ class TPUEngine(AsyncEngine):
             if seq.stalled_since:
                 # Anatomy: the page-stall window just closed.
                 seq.anat_swap_s += max(time.time() - seq.stalled_since, 0.0)
-                if self.flight is not None:
-                    self.flight.record(
-                        "stall_end", req=seq.request_id, slot=seq.slot
-                    )
+                self.flight.record(
+                    "stall_end", req=seq.request_id, slot=seq.slot
+                )
             seq.stalled_since = 0.0  # progressing (even if window-capped)
             part = sampler if self._needs_sampler(seq) else greedy
             part.append((seq, wpos, cap))
@@ -3180,6 +3138,22 @@ class TPUEngine(AsyncEngine):
             or so.presence_penalty
             or (so.repetition_penalty or 1.0) != 1.0
         )
+
+    def _journaled_tail_counts(self, seq: Sequence) -> np.ndarray | None:
+        """Penalty counts of a failover / preemption continuation: the
+        re-prefilled tail of its prompt is journaled *completion* tokens,
+        which the uninterrupted run had counted. None when the sequence
+        is no continuation or draws without penalties."""
+        resumed = seq.stop.resume_offset or 0
+        if not (resumed and self._needs_sampler(seq)):
+            return None
+        V = self.cfg.model.vocab_size
+        tail = np.clip(
+            np.asarray(seq.prompt[-resumed:], np.int64),  # dynlint: sync-point(host-list conversion)
+            0,
+            V - 1,
+        )
+        return np.bincount(tail, minlength=V).astype(np.int32)
 
     def _stop_gates(self, seq: Sequence, g0: int) -> tuple[int, int]:
         """On-device stop gates for a row whose window starts with ``g0``
@@ -3239,11 +3213,10 @@ class TPUEngine(AsyncEngine):
             self.kv_move_dispatches += 1
             get_telemetry().kv_page_moves.labels("cow").inc()
             get_telemetry().kv_cow_copies.inc()
-            if self.flight is not None:
-                self.flight.record(
-                    "cow", req=seq.request_id, slot=seq.slot,
-                    src=pid, dst=new_pid,
-                )
+            self.flight.record(
+                "cow", req=seq.request_id, slot=seq.slot,
+                src=pid, dst=new_pid,
+            )
         seq.shared_tail_pid = -1
         return True
 
@@ -3302,10 +3275,9 @@ class TPUEngine(AsyncEngine):
             extra = seq.page_ids[keep:]
             del seq.page_ids[keep:]
             self.kv.release_sequence(extra)
-            if self.flight is not None:
-                self.flight.record(
-                    "spec_rewind", req=seq.request_id, pages=len(extra)
-                )
+            self.flight.record(
+                "spec_rewind", req=seq.request_id, pages=len(extra)
+            )
 
     # --------------------------------------------------------------- builders
     def _build_windowed(
@@ -3400,16 +3372,15 @@ class TPUEngine(AsyncEngine):
             nb, page_bucket, True, full_sampler, want_lp, False,
             chained=False, live=sum(e.n_valid for e in rows),
         )
-        if self.flight is not None:
-            self.flight.record(
-                "dispatch",
-                dispatch="ragged",
-                rows=len(part),
-                bucket=nb,
-                windowed=True,
-                pages=key[1],
-                want_lp=want_lp,
-            )
+        self.flight.record(
+            "dispatch",
+            dispatch="ragged",
+            rows=len(part),
+            bucket=nb,
+            windowed=True,
+            pages=key[1],
+            want_lp=want_lp,
+        )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(part))
         return _PendingRagged(
@@ -3510,12 +3481,21 @@ class TPUEngine(AsyncEngine):
                 pos0[r] = start_tok + n - 1
                 max_pages = max(max_pages, (seq.prefill_sent + ps - 1) // ps)
                 self._row_sampler_args(seq, r, sampler_arrs)
+                completing = seq.prefill_sent == len(seq.prompt)
+                tail = self._journaled_tail_counts(seq) if completing else None
+                if tail is not None:
+                    # The splice token of a penalized continuation draws
+                    # as the decode row it was in the uninterrupted run:
+                    # through the counts of the journaled tail.
+                    self._counts = self._counts.at[seq.slot].set(
+                        jnp.asarray(tail)
+                    )
+                    is_decode[r] = True
+                    slot_map[r] = seq.slot
                 rows.append(
                     _RaggedRow(
-                        seq,
-                        "prefill",
-                        r,
-                        completing=seq.prefill_sent == len(seq.prompt),
+                        seq, "prefill", r,
+                        completing=completing, counted=tail is not None,
                     )
                 )
                 r += 1
@@ -3603,17 +3583,16 @@ class TPUEngine(AsyncEngine):
             nb, page_bucket, False, full_sampler, want_lp, with_spec,
             chained=False, live=int((positions >= 0).sum()),
         )
-        if self.flight is not None:
-            self.flight.record(
-                "dispatch",
-                dispatch="ragged",
-                rows=len(rows),
-                tokens=total_q,
-                bucket=nb,
-                windowed=False,
-                pages=key[1],
-                want_lp=want_lp,
-            )
+        self.flight.record(
+            "dispatch",
+            dispatch="ragged",
+            rows=len(rows),
+            tokens=total_q,
+            bucket=nb,
+            windowed=False,
+            pages=key[1],
+            want_lp=want_lp,
+        )
         # Name this dispatch on the pages its prompt chunks wrote, for
         # the admission record of whoever attaches them later.
         for seq, start_tok, n in chunks:
@@ -3784,17 +3763,16 @@ class TPUEngine(AsyncEngine):
             nb, page_bucket, True, pending.full_sampler, pending.want_lp,
             False, chained=True, live=sum(e.n_valid for e in rows),
         )
-        if self.flight is not None:
-            self.flight.record(
-                "dispatch",
-                dispatch="ragged",
-                rows=len(rows),
-                bucket=nb,
-                windowed=True,
-                chained=True,
-                pages=key[1],
-                want_lp=pending.want_lp,
-            )
+        self.flight.record(
+            "dispatch",
+            dispatch="ragged",
+            rows=len(rows),
+            bucket=nb,
+            windowed=True,
+            chained=True,
+            pages=key[1],
+            want_lp=pending.want_lp,
+        )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(rows))
         return _PendingRagged(
@@ -3881,10 +3859,9 @@ class TPUEngine(AsyncEngine):
                 if reason is not None:
                     seq.pending_finish = reason
                     finishes.append(seq)
-            if self.flight is not None:
-                self.flight.record(
-                    "consume", dispatch="ragged", tokens=emitted, wasted=wasted
-                )
+            self.flight.record(
+                "consume", dispatch="ragged", tokens=emitted, wasted=wasted
+            )
             if wasted:
                 self.wasted_steps += wasted
                 tel.decode_wasted_steps.inc(wasted)
@@ -3926,10 +3903,9 @@ class TPUEngine(AsyncEngine):
         if self.profiler is not None:
             self.profiler.consume("ragged", pending.dispatched_at, "mixed")
         with self._phase("consume"):
-            if self.flight is not None:
-                self.flight.record(
-                    "consume", dispatch="ragged", rows=len(pending.rows)
-                )
+            self.flight.record(
+                "consume", dispatch="ragged", rows=len(pending.rows)
+            )
             tel = get_telemetry()
             for e in pending.rows:
                 seq, r = e.seq, e.row
@@ -3945,7 +3921,9 @@ class TPUEngine(AsyncEngine):
                         if pending.want_lp and n_top is not None
                         else None
                     )
-                    self._finish_first_token(seq, int(tok0[r]), pack)
+                    self._finish_first_token(
+                        seq, int(tok0[r]), pack, counted=e.counted
+                    )
                     continue
                 if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
                     self.wasted_steps += 1
@@ -4011,14 +3989,13 @@ class TPUEngine(AsyncEngine):
                 tel.spec_draft_tokens.inc(g)
                 tel.spec_accepted_tokens.inc(accepted)
                 tel.spec_tokens_per_dispatch.observe(len(kept))
-                if self.flight is not None:
-                    self.flight.record(
-                        "spec_accept",
-                        req=seq.request_id,
-                        proposed=g,
-                        accepted=accepted,
-                        emitted=len(kept),
-                    )
+                self.flight.record(
+                    "spec_accept",
+                    req=seq.request_id,
+                    proposed=g,
+                    accepted=accepted,
+                    emitted=len(kept),
+                )
                 self._spec.record(seq, proposed=g, accepted=accepted)
                 self.sched.register_full_pages(seq)
                 n_top = self._wants_logprobs(seq)
@@ -4043,9 +4020,9 @@ class TPUEngine(AsyncEngine):
     # --------------------------------------------------------------- metrics
     def metrics(self) -> dict:
         m = self.sched.metrics()
-        # Occupancy-proportional decode counters (docs/engine_perf.md):
-        # bench.py's occupancy sweep and the proportionality tests read
-        # these; /metrics exposes the prometheus mirrors.
+        # Row-compacted decode counters (docs/engine_perf.md): the
+        # tests of tests/test_decode_compaction.py read these; /metrics
+        # exposes the prometheus mirrors.
         m["decode_steps"] = self.steps
         m["decode_wasted_steps"] = self.wasted_steps
         m["kv_page_moves"] = self.kv_page_moves
@@ -4069,16 +4046,16 @@ class TPUEngine(AsyncEngine):
         m["compiled_ragged_variants"] = len(self._ragged_fns)
         # Warm-boot provisioning (docs/aot.md): variants prewarm()
         # loaded before first traffic and what the boot cost — the
-        # prewarm-smoke gate and bench.py --coldstart-sweep read these.
+        # prewarm-smoke gate (`llmctl aot smoke`) reads these.
         m["prewarmed_variants"] = self.prewarmed_variants
         m["prewarm_seconds"] = round(self.prewarm_seconds, 6)
         # Per-dispatch profiler mirror (docs/observability.md): per-kind
         # host-gap / in-flight percentiles over the recent window plus
         # compile attribution — the same numbers the dynamo_dispatch_*
-        # prometheus series aggregate, in pullable form for bench.py's
-        # per-line dispatch field and sim/fit.py's bench fitting.
+        # prometheus series aggregate, in pullable form for the tests
+        # (tests/test_dispatch_profile.py) and `llmctl aot smoke`.
         # decode_window rides along so a per-dispatch time converts to a
-        # per-token ITL without a span file.
+        # per-token ITL.
         if self.profiler is not None:
             m["dispatch"] = self.profiler.summary()
         m["decode_window"] = self.cfg.decode_window
@@ -4123,7 +4100,7 @@ class TPUEngine(AsyncEngine):
         m["kv_wire_checksum_failures"] = wire_checksum_failures()
         # Predictive KV tiering (docs/engine_perf.md "Predictive KV
         # tiering"): G2→G1 prefetch outcomes and proactive-offload
-        # (swap) traffic — bench.py's offload-pressure axis reads these.
+        # (swap) traffic — tests/test_kv_tiering.py reads these.
         m["kv_prefetch_pages"] = self.prefetch_pages
         m["kv_prefetch_hits"] = self.prefetch_hits
         m["kv_prefetch_late"] = self.prefetch_late

@@ -155,8 +155,8 @@ class KvPageManager:
         self.clock = clock
         # Fleet-wide prefix sharing (docs/prefix_sharing.md). False is
         # the private-copy baseline: no cross-sequence reuse at all —
-        # every admission materializes its own pages (bench.py's
-        # --prefix-sweep comparison arm).
+        # every admission materializes its own pages (the reference
+        # of tests/test_prefix_sharing.py).
         self.sharing = sharing
         # G2 tier: evicted device pages are offloaded (via ``on_evict``,
         # which the engine wires to a device gather + CopyStream) and
@@ -193,7 +193,7 @@ class KvPageManager:
         self.offload_misses = 0
         # Prefix-sharing counters (docs/prefix_sharing.md): page-granular
         # hit breakdown at admission, copy-on-write copies, and the
-        # high-water mark of resident pages (bench.py --prefix-sweep
+        # high-water mark of resident pages (tests/test_prefix_sharing.py
         # reads pages-per-request off the peak).
         # "persist" = pages restored from the G3 store at admission —
         # the restart re-attachment proof the identity tests read.
@@ -201,7 +201,7 @@ class KvPageManager:
         self.cow_copies = 0
         self.peak_active_pages = 0
         # Incrementally tracked (refcount 1→2 / 2→1 crossings), so the
-        # gauge and the bench's high-water never scan the pool.
+        # gauge and the high-water mark never scan the pool.
         self.live_shared = 0
         self.peak_shared_pages = 0
         # Conservation ledger (docs/observability.md "KV conservation

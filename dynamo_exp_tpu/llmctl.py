@@ -347,7 +347,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
 
     # Offline bench regression comparator (docs/observability.md "Fleet
-    # plane"): compare two bench captures (raw bench.py JSONL or a
+    # plane"): compare two bench captures (BENCH JSON lines or a
     # driver's BENCH_r*.json wrappers) and flag >threshold tok/s or
     # TTFT/ITL regressions per metric, platform-tag aware.
     bench = sub.add_parser(
@@ -418,7 +418,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     sim.add_argument(
         "--fit-bench", action="append", default=[],
-        help="bench.py JSON (or BENCH_r*.json wrapper) to fit from",
+        help="BENCH JSON lines (or BENCH_r*.json wrapper) to fit from",
     )
     sim.add_argument("--events", action="store_true",
                      help="print the event log instead of the report")
@@ -1256,6 +1256,9 @@ def run_audit(args) -> int:
 
 
 def run_bench_compare(args) -> int:
+    """``llmctl bench compare``: judge two captures in the BENCH JSON
+    record format, whose producer, ``bench.py``, is gone from the tree
+    (PR 29); it reads files and drives no engine."""
     import os
 
     from .telemetry.bench_compare import (
@@ -1400,7 +1403,7 @@ def run_sim(args) -> int:
             itl_p99_slo_s=args.itl_slo_s,
             # Fitted-service hint: scale for where the trend will be
             # when a new worker actually lands. A measured cold/warm
-            # provision (bench.py --coldstart-sweep via --fit-bench, or
+            # provision (coldstart BENCH JSON lines via --fit-bench, or
             # the --provision-s study knob) flows in here (docs/aot.md).
             provision_s=(
                 args.provision_s

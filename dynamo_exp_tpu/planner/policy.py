@@ -321,13 +321,13 @@ class SloTargets:
     # Measured worker add -> serving delay. A scale-up decided now only
     # lands this far in the future, so the forecast looks that much
     # further along the trend (in addition to ``forecast_horizon``).
-    # 0 = unknown: no extension. Fitted from tagged coldstart bench
-    # lines (``bench.py --coldstart-sweep`` →
-    # ``ServiceTimeModel.planner_hints()``): a warm-booting fleet
-    # (docs/aot.md) plans with its measured warm landing delay — the
-    # whole chip-seconds win of AOT prewarm enters the policy through
-    # this one number (shorter horizon → scale on the burst edge
-    # instead of buying standby capacity ahead of it).
+    # 0 = unknown: no extension. An operator's input
+    # (``llmctl sim --provision-s``) or fitted from tagged coldstart
+    # BENCH JSON lines (``ServiceTimeModel.planner_hints()``): a
+    # warm-booting fleet (docs/aot.md) plans with its warm landing
+    # delay — AOT prewarm enters the policy through this one number
+    # (shorter horizon → scale on the burst edge instead of buying
+    # standby capacity ahead of it).
     provision_s: float = 0.0
 
 

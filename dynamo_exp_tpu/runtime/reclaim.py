@@ -459,9 +459,12 @@ class MigrationSink:
     async def drain(self) -> None:
         """Await every in-flight inject (tests / graceful shutdown)."""
         while self._tasks:
-            await asyncio.gather(
-                *list(self._tasks), return_exceptions=True
-            )
+            tasks = list(self._tasks)
+            await asyncio.gather(*tasks, return_exceptions=True)
+            # Not left to the done-callbacks: a gather over tasks all
+            # done already returns without yielding to the loop, so
+            # callbacks queued there never run and this loop would spin.
+            self._tasks.difference_update(tasks)
 
     def close(self) -> None:
         if self.receiver.on_unclaimed is self._claim:

@@ -9,12 +9,14 @@ actually produced:
   ``llmctl trace``): ``prefill`` spans carry ``prompt_tokens`` and a
   duration → per-prompt-token prefill time; ``decode`` spans carry
   ``generated_tokens`` → inter-token latency (ITL).
-- **BENCH JSON** (``bench.py`` output, or the driver's ``BENCH_r*.json``
-  wrappers with a ``parsed`` record): ``decode_throughput_*_c{N}``
+- **BENCH JSON** (metric lines, or a driver's ``BENCH_r*.json``
+  wrappers with a ``parsed`` record; see
+  :meth:`ServiceTimeModel.from_bench_json` for where the format
+  stands): ``decode_throughput_*_c{N}``
   lines give aggregate tok/s at concurrency N → per-row ITL = N/tok_s;
   ``p50_ttft_s`` over the metric's ISL gives prefill per token. Lines
   without a concurrency-tagged throughput metric fall back to their
-  per-kind ``dispatch`` percentiles (every bench line carries them):
+  per-kind ``dispatch`` percentiles (every such line carries them):
   the ``ragged`` kind's (in-flight + host-gap) p50 over
   ``decode_window`` tokens — pre-ragged bench files carry the old
   ``decode`` kind, which is read as a fallback, so existing
@@ -84,7 +86,7 @@ class ServiceTimeModel:
     itl_s: LatencyDist = field(default_factory=lambda: LatencyDist(0.02))
     batch_congestion: float = 0.25
     # Worker add → serving (the planner's SloTargets.provision_s hint).
-    # Fitted from ``bench.py --coldstart-sweep`` lines, which tag each
+    # Fitted from coldstart BENCH JSON lines, which tag each
     # sample ``prewarmed: true|false`` (docs/aot.md): warm (prewarmed)
     # samples win when present — a fleet that warm-boots its instances
     # must plan with the warm landing delay, not the cold one.
@@ -153,8 +155,11 @@ class ServiceTimeModel:
     def from_bench_json(
         cls, paths: Iterable[str | Path]
     ) -> "ServiceTimeModel":
-        """Fit from ``bench.py`` JSON lines, or the driver's
-        ``BENCH_r*.json`` wrapper (a dict with a ``parsed`` record)."""
+        """Fit from BENCH JSON lines, or the driver's ``BENCH_r*.json``
+        wrapper (a dict with a ``parsed`` record). The format's
+        producer, ``bench.py``, is gone from the tree (PR 29): this
+        parses records, drives no engine, and whether the simulator is
+        refitted from ``PERF_LEDGER.jsonl`` instead is ROADMAP S11."""
         prefill_per_token, itl, tpd, provision = _bench_samples(paths)
         model = cls.default()
         if itl:
@@ -315,7 +320,7 @@ def _bench_samples(
                 continue
             if value <= 0:
                 continue
-            # Coldstart lines (bench.py --coldstart-sweep): every line
+            # Coldstart lines: every line
             # carries ``prewarmed: bool`` + ``provision_s`` so the fit
             # can tell a warm-boot landing delay from a cold one
             # (docs/aot.md). These lines have no throughput metric —
@@ -333,8 +338,8 @@ def _bench_samples(
             if throughput_line:
                 itl.append(conc / float(value))
             else:
-                # Per-kind dispatch percentiles (bench.py attaches them
-                # to every line): (in-flight + host-gap) p50 over the
+                # Per-kind dispatch percentiles (attached to every
+                # line): (in-flight + host-gap) p50 over the
                 # line's decode_window is a per-token ITL sample — the
                 # fallback that fits service times from lines with no
                 # concurrency-tagged throughput metric. The ragged
@@ -362,7 +367,7 @@ def _bench_samples(
                 and isl_m is not None
             ):
                 prefill_per_token.append(float(ttft) / int(isl_m.group(1)))
-            # Spec-sweep lines (``bench.py --spec-sweep``) carry the
+            # Spec-tagged lines (``spec_decode*`` metrics) carry the
             # measured tokens-per-dispatch; speculation-off lines carry
             # None, which is correctly skipped here.
             spec = rec.get("tokens_per_dispatch")

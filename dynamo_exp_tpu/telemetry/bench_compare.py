@@ -1,14 +1,17 @@
 """Bench regression comparator (``llmctl bench compare A.json B.json``).
 
-Compares two bench captures — raw ``bench.py`` JSONL output or a
-driver's ``BENCH_r*.json`` wrapper (``{"n", "cmd", "rc", "tail",
-"parsed"}``) — metric by metric, and flags regressions: throughput
+Compares two bench captures — BENCH JSON lines or a driver's
+``BENCH_r*.json`` wrapper (``{"n", "cmd", "rc", "tail", "parsed"}``),
+a record format whose producer, ``bench.py``, is gone from the tree
+(PR 29; the repository's measurements are ``PERF_LEDGER.jsonl`` and
+PERF.md, and ROADMAP Queue 3 says what becomes of this module) —
+metric by metric, and flags regressions: throughput
 (``tok/s`` lines) dropping more than the threshold, or any latency
 field (``*ttft*``, ``*itl*`` — p50/p99 alike) growing more than the
 threshold.
 
-Platform-tag aware: ``bench.py`` tags every line with the platform it
-ran on, and a CPU number is not comparable to a chip number — such
+Platform-tag aware: every line is tagged with the platform it ran on,
+and a CPU number is not comparable to a chip number — such
 pairs are reported as skipped, never as regressions. Captures with no
 comparable pairs (e.g. two failed runs) compare clean.
 """
@@ -177,7 +180,7 @@ def compare_bench(
         ) and isinstance(old.get("value"), (int, float)):
             judge(f"value({unit})", float(old["value"]), float(new["value"]),
                   higher_is_worse=False)
-        # Spot-reclamation sweep fields (bench.py --reclaim-sweep):
+        # Spot-reclamation fields (``reclaim_sweep_*`` metrics):
         # billed chip-seconds are the spot-economics denominator
         # (growing spend at equal goodput is a regression), the
         # migrated fraction is the live-migration hit rate (falling
@@ -197,8 +200,8 @@ def compare_bench(
         lat_old, lat_new = _latency_fields(old), _latency_fields(new)
         for fld in sorted(set(lat_old) & set(lat_new)):
             judge(fld, lat_old[fld], lat_new[fld], higher_is_worse=True)
-        # Per-request anatomy components (bench.py _anatomy_stats, mean
-        # seconds per finished request): attribute a latency regression
+        # Per-request anatomy components (mean seconds per finished
+        # request): attribute a latency regression
         # to the component that moved. Seconds spent — higher is worse.
         an_old = old.get("anatomy") or {}
         an_new = new.get("anatomy") or {}

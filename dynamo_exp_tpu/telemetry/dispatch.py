@@ -57,8 +57,7 @@ from collections import deque
 from .slo import percentile
 
 # The engine's three device-dispatch kinds. Stable, closed set: the
-# prometheus label space, the metrics() mirror, and bench.py's per-kind
-# percentiles all key on these names. ``ragged`` covers every compute
+# prometheus label space and the metrics() mirror key on these names. ``ragged`` covers every compute
 # dispatch (the pre-ragged engine split it into prefill / decode /
 # spec_verify; sim/fit.py still reads those names from old span and
 # bench files for back-compat).
@@ -299,8 +298,7 @@ class DispatchProfiler:
 
     def summary(self) -> dict:
         """Per-kind stats over the recent reservoir window — the
-        ``engine.metrics()["dispatch"]`` mirror and bench.py's per-line
-        dispatch field. Every kind is always present (count 0, None
+        ``engine.metrics()["dispatch"]`` mirror. Every kind is always present (count 0, None
         percentiles before its first dispatch) so consumers see a
         stable shape."""
         out = {}

@@ -6,9 +6,9 @@ grid, and sim-vs-live applicability. Everything downstream derives
 from it:
 
 - the search (:mod:`.search`) walks the sim-applicable knobs' grids;
-- ``bench.py`` stamps every JSON line with the engine's resolved knob
-  dict and its :func:`config_hash`, so ``llmctl bench compare`` never
-  silently compares differently-knobbed runs;
+- a tuned artifact carries the engine's resolved knob dict and its
+  :func:`config_hash`, the key by which ``llmctl bench compare`` pairs
+  BENCH JSON lines, so differently-knobbed runs are never compared;
 - the docs knob table renders from :func:`render_knob_table` and a
   doc-sync guard keeps docs/tuning.md listing every knob;
 - a registry-walk guard test (tests/test_tune.py) asserts the registry
@@ -150,9 +150,8 @@ NON_TUNABLE: dict[str, frozenset] = {
         # Static stop-set width: a compile-key shape, sized to the API
         # contract (requests with more stop ids fall back to host).
         "device_stop_width",
-        # Observability plane (flight ring, watchdog, leases).
-        "flight_events", "flight_capacity", "watchdog_stall_s",
-        "kv_lease_ttl_s",
+        # Observability plane (watchdog, leases).
+        "watchdog_stall_s", "kv_lease_ttl_s",
         # Durable G3 tier capacity: sized to the local SSD, a
         # provisioning decision like max_tpu_budget, not a perf knob.
         "kv_store_pages",
@@ -293,8 +292,8 @@ def engine_kwargs_from_overrides(overrides: dict) -> dict:
 
 def resolved_engine_knobs(cfg) -> dict:
     """Every registered engine knob's resolved value on an
-    ``EngineConfig`` instance — the dict ``bench.py`` stamps on every
-    JSON line next to its :func:`config_hash`."""
+    ``EngineConfig`` instance — what :func:`config_hash` is taken
+    over."""
     return {k.name: getattr(cfg, k.name) for k in KNOBS if k.owner == "engine"}
 
 

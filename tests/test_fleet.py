@@ -363,7 +363,7 @@ def test_bench_compare_wrapper_and_jsonl_formats(tmp_path):
 
 
 def test_bench_compare_cli_over_failed_run_wrappers(capsys):
-    """The pre-merge CI step's shape: comparing two driver wrappers of
+    """Comparing two driver wrappers of
     runs that failed (no metric line in either) must exit 0 — no
     comparable pairs compare clean."""
     import os

@@ -473,8 +473,8 @@ async def test_engine_seeded_sampling_continuation_identical(resume_engine):
 
 async def test_engine_penalized_continuation_restores_counts(resume_engine):
     """A continuation marked with ``resume_offset`` rebuilds the penalty
-    counts from the journaled tail, so post-splice draws are penalized
-    exactly like the uninterrupted run's. Greedy + presence penalty on
+    counts from the journaled tail, so the splice token and every draw
+    after it are penalized exactly like the uninterrupted run's. Greedy + presence penalty on
     the TINY model is a sharp probe: the unpenalized greedy chain
     repeats tokens, so missing counts visibly change the argmax."""
     prompt = [6, 14, 27, 35, 9]
@@ -485,8 +485,8 @@ async def test_engine_penalized_continuation_restores_counts(resume_engine):
         resume_engine, prompt + full[:k], n - k, resume_offset=k, **so
     )
     # Counts restored → the spliced stream is token-identical to the
-    # uninterrupted run (the splice token's raw-argmax draw coincides
-    # here; post-splice identity is what the reconstruction guarantees).
+    # uninterrupted run, the splice token included: it draws through
+    # the journaled tail's counts as the decode row it was.
     assert marked == full[k:]
     # Without the marker the journaled tail is plain prompt (no counts):
     # the penalty forgets those tokens and the continuation diverges —

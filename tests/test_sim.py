@@ -17,7 +17,7 @@ Four pillars, mirroring the subsystem's claims:
   wall-clock (marked ``slow``; ``make sim-scale``).
 
 Plus the pure planner-policy units the ``plan_step`` extraction makes
-possible, and the bench.py device-check smoke tests.
+possible.
 
 Seeded like the chaos suites: ``SIM_SEEDS`` (comma-separated) selects
 the regression seed set; ``make sim`` sweeps several.
@@ -25,8 +25,6 @@ the regression seed set; ``make sim`` sweeps several.
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import pytest
@@ -216,8 +214,8 @@ def test_fit_from_bench_wrapper_json(tmp_path):
 
 
 def test_fit_learns_spec_tokens_per_dispatch(tmp_path):
-    """Spec-tagged telemetry scales the modeled decode ITL: bench
-    --spec-sweep lines carry `tokens_per_dispatch`, decode spans carry
+    """Spec-tagged telemetry scales the modeled decode ITL: spec-tagged
+    BENCH JSON lines carry `tokens_per_dispatch`, decode spans carry
     `spec_tokens_per_dispatch`, and the fitted factor divides every
     per-token interval (docs/speculative.md)."""
     import random
@@ -760,41 +758,3 @@ def test_million_user_run_bounded_wall_clock():
     assert rep.completed + rep.shed + rep.errors == rep.submitted
     assert rep.completed > 0 and rep.goodput_tok_s > 0
     assert rep.max_instances <= 64
-
-
-# ------------------------------------------------------ bench device check
-def _run_bench(env: dict, *argv: str) -> subprocess.CompletedProcess:
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    return subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), *argv],
-        capture_output=True, timeout=420, env=env, cwd=repo,
-    )
-
-
-def test_bench_without_accelerator_exits_nonzero():
-    """No TPU and the CPU not asked for by name: a measurement path
-    fails, it does not fall back."""
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    proc = _run_bench(env, "--model", "tiny")
-    assert proc.returncode != 0
-    assert "no TPU" in proc.stderr.decode()
-    assert not proc.stdout.decode().strip()
-
-
-@pytest.mark.nightly
-def test_bench_produces_parsed_json_on_cpu(tmp_path):
-    """An explicit ``JAX_PLATFORMS=cpu`` still yields a parseable JSON
-    line, tagged with the platform and device it ran on — and with no
-    ``vs_baseline``: the CPU is not in the peak table."""
-    proc = _run_bench(
-        os.environ | {"JAX_PLATFORMS": "cpu"},
-        "--model", "tiny", "--isl", "16", "--osl", "8", "--concurrency", "2",
-    )
-    assert proc.returncode == 0, proc.stderr.decode()[-2000:]
-    last = proc.stdout.decode().strip().splitlines()[-1]
-    point = json.loads(last)
-    assert point["platform"] == "cpu"
-    assert point["device_kind"] == "cpu" and point["device_count"] >= 1
-    assert "vs_baseline" not in point
-    assert point["value"] is not None and point["value"] > 0
-    assert point["metric"].startswith("decode_throughput_tiny")

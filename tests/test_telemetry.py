@@ -564,7 +564,7 @@ def test_every_engine_metrics_mirror_key_is_documented():
     """Doc-sync guard (PR 9 extension): every ``engine.metrics()``
     mirror key — including the host-tier keys and the per-kind
     dispatch-profiler stat fields — must appear in
-    docs/observability.md, so the stats-plane surface bench.py and the
+    docs/observability.md, so the stats-plane surface the tests and the
     sim fit consume can't drift undocumented."""
     from dynamo_exp_tpu.telemetry.dispatch import SUMMARY_FIELDS
 
