@@ -435,8 +435,8 @@ def run_request_set(base: str, concurrency: int = 8) -> list[dict]:
         ]
         recs += [f.result() for f in futures]
     # Longer than prefill_chunk (512) so prefill is chunked, and longer
-    # than 1024 tokens so the engine leaves the short-context XLA gather
-    # for the compiled Pallas kernel in both dispatch shapes.
+    # than 1024 tokens, past the smallest page bucket, so the kernel
+    # reads a context of several KV blocks in both dispatch shapes.
     long_rec = chat(base, "long-prompt", _words(330, 5), 24, stream=True)
     if not long_rec["prompt_tokens"] or long_rec["prompt_tokens"] <= 1024:
         raise SmokeFailure(

@@ -48,31 +48,38 @@ def resolve_ragged_key(
     live dispatch share it verbatim.
 
     ``attn_impl`` is the engine's *resolved* implementation
-    (``TPUEngine._attn_impl``). Two wrinkles live here: in a
-    pure-decode window, short contexts (<= ~1k tokens of page bucket)
-    take the XLA gather even when the Pallas kernel is available (its
-    serial per-row DMA grid costs more than the trivial gather saves)
-    — but only under ``auto``, an explicit ``pallas`` is honored; and
-    on the Pallas path the page bound vanishes from the key entirely
-    (the kernel DMAs true lengths), which is what deletes the page
-    axis from the TPU lattice.
+    (``TPUEngine._attn_impl``: an explicit ``attention_impl`` is
+    honored there, ``auto`` is the kernel on a TPU). The form is the
+    whole rule: on the Pallas path the page bound vanishes from the
+    key (the kernel DMAs true lengths), which is what deletes the page
+    axis from the TPU lattice, windows and mixed dispatches alike; on
+    the XLA path the key keeps the page bucket that bounds the gather —
+    a window's is its LONGEST row's (``_build_windowed``), so one long
+    request sets the gather's width for every row beside it.
 
-    A mixed dispatch never takes the gather on an engine that has the
+    A pure-decode window takes the kernel like any other dispatch of a
+    kernel engine. Whole decode steps timed on a TPU v5e in both forms
+    at every row bucket, page size 16, contexts of 100-1000 tokens
+    (``tools/attn_crossover.py``; the table is in PERF.md §6, PR 30)
+    read the kernel no slower at the two head layouts measured: at
+    qwen3-4b's 32/8 heads x 128 the gather step is 1.00x the kernel's
+    at 1 row, 1.2x at 16, 1.7-1.9x at 32 and 2.8-3.3x at 64; at
+    Mixtral's share of a chip under ``--tp 4`` (8/2 heads x 128) 1.00x
+    at 1 row to 1.1-1.2x at 64. No other head count, head size or
+    page size was measured: ``tools/attn_crossover.py --config
+    <preset>`` is the check before resting on this at another layout.
+    ``cfg`` is unused and stays for ``benchmark/rehearse/compile_v5e.py``'s
+    positional call (ROADMAP D9b).
+
+    A mixed dispatch never took the gather on an engine that has the
     kernel: the reference gathers the whole page bucket once per QUERY
-    TOKEN (N x S), which is trivial for a window's <= max_decode_slots
-    rows and ruinous for a prefill batch. At llama-1b widths on a v5e
-    (compile-only, CHANGES.md PR 21) its temporaries are 9 GB at
-    N=2048, and XLA:TPU refuses to compile N >= 4096 in 16 GB of HBM —
-    a burst of eight 512-token prompts reaches that bucket."""
-    impl = attn_impl
-    if (
-        impl == "pallas"
-        and cfg.attention_impl == "auto"
-        and windowed
-        and attn_pages * cfg.page_size <= 1024
-    ):
-        impl = "xla"
-    pages = None if impl == "pallas" else attn_pages
+    TOKEN (N x S), which is ruinous for a prefill batch. At llama-1b
+    widths on a v5e (compile-only, CHANGES.md PR 21) its temporaries
+    are 9 GB at N=2048, and XLA:TPU refuses to compile N >= 4096 in
+    16 GB of HBM — a burst of eight 512-token prompts reaches that
+    bucket."""
+    del cfg
+    pages = None if attn_impl == "pallas" else attn_pages
     return (nb, pages, windowed, full_sampler, want_lp, with_spec)
 
 
@@ -188,9 +195,9 @@ def ragged_variants(
     include_spec: bool | None = None,
 ) -> list[RaggedVariant]:
     """Enumerate the full reachable ragged lattice, deduplicated through
-    :func:`resolve_ragged_key` (the Pallas page-bound collapse and the
-    small-bucket XLA downgrade both fold enumeration points together
-    exactly as they fold live dispatches together).
+    :func:`resolve_ragged_key` (the Pallas page-bound collapse folds
+    enumeration points together exactly as it folds live dispatches
+    together).
 
     ``include_lp=False`` halves the lattice for deployments that never
     serve logprobs; ``include_spec`` defaults to whether the config has

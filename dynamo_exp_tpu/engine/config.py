@@ -387,13 +387,14 @@ class EngineConfig:
 
     def ragged_page_bucket_for(self, n_pages: int) -> int:
         """Static page bound of a ragged dispatch's XLA attention
-        gather. Floors at ~1024 tokens of pages: below that the
-        gather's HBM traffic is trivial (the same threshold the
-        attention-impl resolution uses), so bucketing finer than it
-        only multiplies compiled variants. Capped at the per-sequence
-        table width; the Pallas kernel ignores the bound entirely (it
-        DMAs true lengths), which is what deletes the page axis from
-        the TPU lattice."""
+        gather. Floors at ~1024 tokens of pages: bucketing finer than
+        that only multiplies compiled variants. The callers pass the
+        dispatch's LONGEST row, so a window's bound (and the gather's
+        width for every row in it) is set by one long request. Capped
+        at the per-sequence table width; the Pallas kernel ignores the
+        bound entirely (it DMAs true lengths), which is what deletes
+        the page axis from the TPU lattice — every program of a kernel
+        engine since PR 30 (``aot.lattice.resolve_ragged_key``)."""
         floor = min(self.max_pages_per_seq, max(4, 1024 // self.page_size))
         return self._pow2_bucket(
             max(n_pages, floor), 4, self.max_pages_per_seq

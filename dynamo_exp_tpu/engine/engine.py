@@ -621,15 +621,17 @@ class TPUEngine(AsyncEngine):
         positions (plus the spec span when speculation is on) reach the
         vocab projection, so lm_head cost stays flat in chunk width.
 
-        Even when the Pallas kernel is available, a decode window over
-        short contexts takes the XLA gather: below ~1k tokens of page
-        bucket the gather's HBM traffic is trivial and the kernel's
-        serial per-row DMA grid costs more than it saves (a mixed
-        dispatch never does — the gather is per query token). That rule
-        (and the Pallas page-bound collapse) lives in
-        ``aot.lattice.resolve_ragged_key`` — ONE key
-        function shared with the offline lattice enumeration, so the
-        AOT manifest can never drift from what this loop dispatches."""
+        Which attention form a program is built with is the engine's
+        resolved implementation (``resolve_attn_impl``) and nothing
+        else: on a kernel engine every window and every mixed dispatch
+        reads its contexts through the kernel, whatever the row bucket
+        and however short the contexts (the measured table and its
+        origin: ``aot.lattice.resolve_ragged_key``). On the XLA path
+        ``attn_pages`` bounds the gather; a window's is its longest
+        row's page bucket. The Pallas page-bound collapse lives in
+        ``resolve_ragged_key`` — ONE key function shared with the
+        offline lattice enumeration, so the AOT manifest can never
+        drift from what this loop dispatches."""
         key = resolve_ragged_key(
             self.cfg, self._attn_impl, nb, attn_pages, windowed,
             full_sampler, want_lp, with_spec,
@@ -646,9 +648,7 @@ class TPUEngine(AsyncEngine):
         nb, pages, windowed, full_sampler, want_lp, with_spec = key
         impl = impl_for_key(key)
         # Once per variant, not per dispatch: which attention each
-        # program was really built with (a decode window over short
-        # contexts takes the XLA gather even on an engine that resolved
-        # to the kernel).
+        # program was built with (the key's, which is the engine's).
         log.info(
             "ragged variant: nb=%d pages=%s windowed=%s full_sampler=%s "
             "want_lp=%s with_spec=%s attn=%s",

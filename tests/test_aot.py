@@ -40,7 +40,7 @@ from dynamo_exp_tpu.aot import (
     resolve_ragged_key,
     windowed_token_buckets,
 )
-from dynamo_exp_tpu.aot.lattice import CompileManifest
+from dynamo_exp_tpu.aot.lattice import CompileManifest, impl_for_key
 from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
 from dynamo_exp_tpu.models import TINY
 from dynamo_exp_tpu.parallel import single_device_mesh
@@ -156,6 +156,59 @@ def test_bucket_enumeration_covers_live_helpers():
     mv = set(page_move_buckets(cfg))
     for p in range(1, max(cfg.num_pages, cfg.max_pages_per_seq) + 1, 3):
         assert cfg.page_move_bucket_for(p) in mv
+
+
+# A shape with a page axis to lose: page buckets of 128, 256 and 512
+# pages (1024, 2048, 4096 tokens), window row buckets 1, 2, 4, 8.
+_DEEP = dict(max_decode_slots=8, max_model_len=4096, num_pages=1024)
+
+
+@pytest.mark.parametrize(
+    "attention_impl, resolved",
+    [("auto", "pallas"), ("pallas", "pallas"), ("auto", "xla"), ("xla", "xla")],
+)
+@pytest.mark.parametrize("windowed", [True, False], ids=["window", "mixed"])
+@pytest.mark.parametrize(
+    "nb, pages", [(1, 128), (16, 128), (32, 128), (64, 512)]
+)
+def test_resolve_ragged_key_form_is_the_resolved_one(
+    attention_impl, resolved, windowed, nb, pages
+):
+    """The key's form is the engine's resolved implementation and
+    nothing else: on the kernel no key holds a page bound — a window of
+    1 row or of 64 over the smallest page bucket (where ``auto`` sent it
+    down the gather until PR 30's table, PERF.md §6) as little as a
+    mixed dispatch — and on the gather every key holds its bucket.
+    ``auto`` adds nothing to what ``resolve_attn_impl`` decided, so it
+    can override neither an explicit ``pallas`` nor an explicit
+    ``xla``."""
+    cfg = _cfg(attention_impl=attention_impl, **_DEEP)
+    key = resolve_ragged_key(cfg, resolved, nb, pages, windowed, False, False)
+    assert key == (
+        nb, None if resolved == "pallas" else pages, windowed,
+        False, False, False,
+    )
+    assert impl_for_key(key) == resolved
+
+
+@pytest.mark.parametrize(
+    "attn_impl, windowed, mixed", [("pallas", 16, 20), ("xla", 48, 60)]
+)
+def test_manifest_lattice_counts(attn_impl, windowed, mixed):
+    """The lattice in numbers at ``_DEEP``: 4 window row buckets and 5
+    mixed token buckets (16 .. 256), each x 2 samplers x 2
+    log-probability forms; the gather multiplies both by its 3 page
+    buckets, the kernel by none (before PR 30 its windows kept the
+    1024-token bucket beside the unbounded key: 32 of them)."""
+    cfg = _cfg(**_DEEP)
+    manifest = build_manifest(
+        cfg, attn_impl=attn_impl, mesh_shape={"tp": 1, "sp": 1},
+        jax_version="test",
+    )
+    got = [v.windowed for v in manifest.ragged]
+    assert (sum(got), len(got) - sum(got)) == (windowed, mixed)
+    bounds = {v.pages for v in manifest.ragged}
+    assert bounds == ({None} if attn_impl == "pallas" else {128, 256, 512})
 
 
 def test_resolved_key_matches_live_ragged_fn():

@@ -500,7 +500,8 @@ def test_late_prompt_joins_in_flight_batch():
 
 
 # --------------------------------------------------------- recompile guard
-def test_steady_state_variant_count_small_constant():
+@pytest.mark.parametrize("interpret", [False, True], ids=["gather", "kernel"])
+def test_steady_state_variant_count_small_constant(interpret):
     """The collapsed lattice in numbers: a full mixed workload
     envelope (both sampler partitions, all occupancies, staggered
     arrivals) compiles a small constant number of ragged variants, and
@@ -510,10 +511,18 @@ def test_steady_state_variant_count_small_constant():
     key function the engine's ``_ragged_fn`` dispatches through — so
     the offline lattice is regression-pinned against the live engine:
     any compiled key the manifest failed to enumerate fails here before
-    it can become a prewarm blind spot (docs/aot.md)."""
+    it can become a prewarm blind spot (docs/aot.md).
+
+    Both forms under ``attention_impl="auto"``: the CPU's resolved
+    gather, and the kernel (interpreted) as a TPU resolves it. Since
+    PR 30 the form is the engine's alone — no window of a kernel engine
+    is keyed by a page bound, whatever its rows or contexts (these
+    contexts are all under 1024 tokens, where windows took the gather
+    before)."""
     from dynamo_exp_tpu.aot import manifest_for_engine
 
-    eng = _mixed_engine(max_decode_slots=4)
+    eng = _mixed_engine(max_decode_slots=4, pallas_interpret=interpret)
+    assert eng._attn_impl == ("pallas" if interpret else "xla")
     eng.start()
     try:
         rs = np.random.RandomState(3)
@@ -554,6 +563,13 @@ def test_steady_state_variant_count_small_constant():
         # it needs).
         assert variants <= len(lattice), (variants, len(lattice))
         assert variants <= 16, dict.fromkeys(eng._ragged_fns)
+        # The lattice itself, counted: 3 window row buckets (1, 2, 4)
+        # and 9 mixed token buckets (16 .. 4096), each x 2 samplers x
+        # 2 log-probability forms, x the page axis — one bucket at this
+        # model length on the gather, none at all on the kernel.
+        assert len(lattice) == (3 + 9) * 4 == 48
+        assert {k[1] for k in lattice} == ({None} if interpret else {16})
+        assert any(k[2] for k in eng._ragged_fns)  # windows were served
         for _ in range(3):
             asyncio.run(mix(2, 2))
         assert len(eng._ragged_fns) == variants
@@ -563,10 +579,21 @@ def test_steady_state_variant_count_small_constant():
 
 # ------------------------------------------------------ engine pallas e2e
 @pytest.mark.nightly
-def test_engine_decodes_with_pallas_interpret(tiny_model_dir):
-    """End-to-end: an engine configured with attention_impl=pallas +
-    interpret produces the same greedy tokens as the XLA engine — the
-    ragged kernel serving real windowed decode dispatches."""
+@pytest.mark.parametrize(
+    "attention_impl, n_requests",
+    [("pallas", 1), ("auto", 4)],
+    ids=["explicit-1row", "auto-4rows"],
+)
+def test_engine_decodes_with_pallas_interpret(
+    tiny_model_dir, attention_impl, n_requests
+):
+    """End-to-end: an engine on the (interpreted) kernel produces the
+    same greedy tokens as the XLA engine, token for token — the ragged
+    kernel serving real windowed decode dispatches. The second case is
+    the ``auto`` rule as a TPU resolves it, at a full row bucket over
+    contexts of a few dozen tokens: since PR 30 those windows read
+    their contexts through the kernel too (they took the gather under
+    ``auto`` while the page bucket was <= 1024 tokens)."""
     from dynamo_exp_tpu.models.config import ModelConfig
 
     mcfg = ModelConfig(
@@ -579,25 +606,26 @@ def test_engine_decodes_with_pallas_interpret(tiny_model_dir):
         max_position_embeddings=256,
         dtype="float32",
     )
+    prompts = [list(range(1 + i, 20 + 3 * i)) for i in range(n_requests)]
 
-    def run(attention_impl):
+    def run(impl):
         cfg = EngineConfig(
             model=mcfg,
-            max_decode_slots=2,
+            max_decode_slots=max(2, n_requests),
             page_size=8,
             num_pages=64,
             max_model_len=128,
-            attention_impl=attention_impl,
-            pallas_interpret=attention_impl == "pallas",
+            attention_impl=impl,
+            pallas_interpret=impl != "xla",
             enable_kv_events=False,
         )
         eng = TPUEngine(cfg, seed=7)
 
-        async def go():
+        async def one(prompt):
             stream = await eng.generate(
                 {
-                    "token_ids": list(range(1, 20)),
-                    "stop_conditions": {"max_tokens": 8},
+                    "token_ids": prompt,
+                    "stop_conditions": {"max_tokens": 12},
                     "sampling_options": {"temperature": 0.0},
                 }
             )
@@ -606,9 +634,22 @@ def test_engine_decodes_with_pallas_interpret(tiny_model_dir):
                 toks.extend(out.get("token_ids") or [])
             return toks
 
+        async def go():
+            return await asyncio.gather(*(one(p) for p in prompts))
+
         try:
-            return asyncio.run(asyncio.wait_for(go(), timeout=120))
+            out = asyncio.run(asyncio.wait_for(go(), timeout=300))
+            return out, {k[:3] for k in eng._ragged_fns if k[2]}
         finally:
             eng.stop()
 
-    assert run("pallas") == run("xla")
+    kernel_toks, kernel_windows = run(attention_impl)
+    xla_toks, xla_windows = run("xla")
+    assert kernel_toks == xla_toks
+    assert all(len(t) == 12 for t in kernel_toks)
+    # Every window of the kernel engine was keyed without a page bound,
+    # at a row bucket of several rows too; every window of the other
+    # with one.
+    assert kernel_windows and {p for _, p, _ in kernel_windows} == {None}
+    assert max(nb for nb, _, _ in kernel_windows) >= min(n_requests, 2)
+    assert {p for _, p, _ in xla_windows} == {16}

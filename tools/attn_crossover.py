@@ -1,0 +1,177 @@
+#!/usr/bin/env python3
+"""Time a decode step of the windowed program in both attention forms,
+at every row bucket, on the chip: the table behind
+``aot/lattice.py::resolve_ragged_key``, which since PR 30 builds every
+window of a kernel engine on the kernel (PERF.md §6, PR 30).
+
+    chiprun -- python3 tools/attn_crossover.py --config qwen3-4b
+    chiprun --chips 4 -- python3 tools/attn_crossover.py --config mixtral-8x7b-l16
+
+One engine with the configuration's widths, random weights and its
+``serve_args`` (``benchmark/configs/<name>.json``); for each row bucket
+the two programs an ``attention_impl`` of ``"pallas"`` and of ``"xla"``
+would build for a pure-decode window whose page bucket is the smallest
+(1024 tokens), run over the same live rows: contexts drawn from
+``--contexts`` by ``--seed``, each row on pages of its own as far as the
+pool goes. A call is ``decode_window`` whole steps (attention, relayouts,
+projections, feed-forward, head, sampling), timed on the host's clock
+around ``block_until_ready``; a step is a call over ``decode_window``.
+One JSON line a row bucket on stdout and in
+``chiprun_out/attn_crossover/<config>.jsonl``. It fails off the chip: a
+CPU time is not a device time (``--allow-cpu`` rehearses the control
+flow and writes ``<config>.cpu-rehearsal.jsonl``, never the chip's file).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, BENCH)
+sys.path.insert(1, ROOT)
+
+
+def draw_contexts(kind: str, nb: int, rng) -> list[int]:
+    """``uniform``: 100–1000 tokens, flat. ``chat``: the chat cells'
+    prompt (lognormal, median 160, sigma 1.0) plus half an output
+    (median 140, sigma 0.8), clipped to 100–1000, and one row at 1000:
+    what fixes a window's page bucket is its longest row."""
+    if kind == "uniform":
+        return [int(c) for c in rng.integers(100, 1001, nb)]
+    ctx = rng.lognormal(5.075, 1.0, nb) + 0.5 * rng.lognormal(4.942, 0.8, nb)
+    ctx = [int(min(max(c, 100), 1000)) for c in ctx]
+    ctx[0] = 1000
+    return ctx
+
+
+def live_window_args(engine, key: tuple, contexts: list[int], rng) -> tuple[list, int]:
+    """``aot.warmup.variant_call_args`` with every row live: row ``i``
+    feeds a token at position ``contexts[i]`` and stays in the window
+    for all its steps (no stop token, no capacity or budget gate).
+    Returns the arguments and the pages the rows' tables name (past
+    the pool's size they wrap onto pages another row holds)."""
+    import numpy as np
+
+    from dynamo_exp_tpu.aot.warmup import variant_call_args
+
+    cfg = engine.cfg
+    nb = key[0]
+    args = list(variant_call_args(engine, key))
+    # (params, k, v, tokens, positions, max_pos, table, stop_set, eos_gate, budget_gate)
+    args[3] = rng.integers(0, cfg.model.vocab_size, nb).astype(np.int32)
+    args[4] = np.asarray(contexts, np.int32)
+    args[5] = np.full(nb, cfg.max_model_len - 1, np.int32)
+    table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
+    order, at = rng.permutation(np.arange(1, cfg.num_pages)), 0
+    for i, c in enumerate(contexts):
+        n = (c + cfg.decode_window) // cfg.page_size + 1
+        table[i, :n] = order[(at + np.arange(n)) % len(order)]
+        at += n
+    args[6] = table
+    return args, at
+
+
+def time_program(engine, key: tuple, args: list, calls: int) -> list[float]:
+    import jax
+
+    fn = engine._ragged_fn_from_key(key)
+    out = []
+    for i in range(calls + 2):
+        args[0], args[1], args[2] = engine.params, engine.k_cache, engine.v_cache
+        t0 = time.perf_counter()
+        ys, engine.k_cache, engine.v_cache, _t, _p = fn(*args)
+        jax.block_until_ready((ys, engine.k_cache))
+        if i >= 2:  # the first call compiles, the second loads
+            out.append(time.perf_counter() - t0)
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--rows", default="1,2,4,8,16,32,64")
+    ap.add_argument("--contexts", default="uniform,chat")
+    ap.add_argument("--seed", type=int, default=30)
+    ap.add_argument("--calls", type=int, default=12)
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="rehearse the control flow off the chip (tiny sizes; times mean nothing)")
+    args = ap.parse_args()
+
+    import jax
+    import numpy as np
+
+    import run_cell
+    from dynamo_exp_tpu import run as server
+    from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
+    from dynamo_exp_tpu.models import ModelConfig
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.allow_cpu:
+        print(f"no chip: platform={dev.platform}", file=sys.stderr)
+        return 1
+    # A path to a file of the same form rehearses a tiny model off the chip.
+    path = args.config if args.config.endswith(".json") else os.path.join(
+        BENCH, "configs", f"{args.config}.json"
+    )
+    name = os.path.basename(path).removesuffix(".json")
+    config = run_cell.load_json(path)
+    mcfg = ModelConfig.from_hf_config(run_cell.hf_config(config))
+    opts = server.parse_args(["in=http", "out=tpu", *config["serve_args"]])
+    ecfg = EngineConfig(
+        model=mcfg, max_decode_slots=opts.max_decode_slots, page_size=opts.page_size,
+        num_pages=opts.num_pages, max_model_len=min(opts.max_model_len, mcfg.max_position_embeddings),
+        tp=opts.tp, kv_dtype=opts.kv_dtype,
+        # Both forms come from one engine: "pallas" makes the engine
+        # resolve the kernel (interpreted off the chip), and a key with a
+        # page bound is the program attention_impl="xla" builds.
+        attention_impl="pallas", pallas_interpret=dev.platform != "tpu",
+    )
+    engine = TPUEngine(ecfg)
+    if engine._attn_impl != "pallas":
+        print("the kernel cannot take this layout", file=sys.stderr)
+        return 1
+    pages = ecfg.ragged_page_bucket_for(1)
+    head = {
+        "config": name, "platform": dev.platform, "device_kind": dev.device_kind,
+        "device_count": jax.device_count(), "tp": ecfg.tp,
+        "heads_per_chip": [mcfg.num_heads // ecfg.tp, mcfg.num_kv_heads // ecfg.tp, mcfg.head_dim_],
+        "page_bucket_tokens": pages * ecfg.page_size, "decode_window": ecfg.decode_window,
+        "num_pages": ecfg.num_pages, "seed": args.seed, "calls": args.calls,
+    }
+    print(json.dumps(head), flush=True)
+    out_dir = os.path.join(ROOT, "chiprun_out", "attn_crossover")
+    os.makedirs(out_dir, exist_ok=True)
+    # A rehearsal off the chip never lands on the chip's record.
+    suffix = "" if dev.platform == "tpu" else ".cpu-rehearsal"
+    with open(os.path.join(out_dir, f"{name}{suffix}.jsonl"), "w") as f:
+        f.write(json.dumps(head) + "\n")
+        for kind in args.contexts.split(","):
+            for nb in (int(r) for r in args.rows.split(",")):
+                rng = np.random.default_rng([args.seed, nb])
+                contexts = draw_contexts(kind, nb, rng)
+                line = {"contexts": kind, "nb": nb, "context_mean": statistics.mean(contexts),
+                        "context_max": max(contexts)}
+                for form, key in (
+                    ("kernel", (nb, None, True, False, False, False)),
+                    ("gather", (nb, pages, True, False, False, False)),
+                ):
+                    call_args, used = live_window_args(engine, key, contexts, np.random.default_rng([args.seed, nb, 1]))
+                    times = time_program(engine, key, call_args, args.calls)
+                    line[f"{form}_step_ms"] = 1e3 * statistics.median(times) / ecfg.decode_window
+                    line[f"{form}_step_ms_min"] = 1e3 * min(times) / ecfg.decode_window
+                    line[f"{form}_step_ms_max"] = 1e3 * max(times) / ecfg.decode_window
+                line["pages_used"] = used
+                line["gather_over_kernel"] = line["gather_step_ms"] / line["kernel_step_ms"]
+                print(json.dumps(line), flush=True)
+                f.write(json.dumps(line) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
