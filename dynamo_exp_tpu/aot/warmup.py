@@ -69,6 +69,25 @@ def variant_call_args(engine, key: tuple) -> tuple:
     arguments and ``prewarm_engine`` executes with them, so an aval
     drift from the live call sites shows up as a prewarm-then-traffic
     compile miss the smoke gate fails on."""
+    args = _variant_call_args(engine, key)
+    state = getattr(engine, "seq_state", None)
+    if state is None:
+        return args
+    # A model with sequence state: the pool and the rows' entries last,
+    # every row at an entry past the pool (nothing read back, nothing
+    # written), in the shapes ``_build_windowed`` / ``_build_mixed`` give.
+    nb, windowed = key[0], key[2]
+    no_entry = np.int32(state.shape[1])
+    if windowed:
+        return (*args, state, np.full(nb, no_entry, np.int32))
+    B1 = engine.cfg.max_decode_slots + 1
+    return (*args, state, (
+        np.full(B1, no_entry, np.int32), np.zeros(2 * B1, np.int32),
+        np.full(2 * B1, no_entry, np.int32),
+    ))
+
+
+def _variant_call_args(engine, key: tuple) -> tuple:
     cfg = engine.cfg
     nb, _pages, windowed, full_sampler, _want_lp, _with_spec = key
     pmax = cfg.max_pages_per_seq
@@ -146,6 +165,8 @@ def _exec_ragged(engine, key: tuple) -> None:
     dispatch consume would."""
     fn = engine._ragged_fn_from_key(key)
     out = fn(*variant_call_args(engine, key))
+    if getattr(engine, "seq_state", None) is not None:
+        *out, engine.seq_state = out
     _nb, _pages, windowed, full_sampler, _lp, _spec = key
     if windowed and full_sampler:
         _ys, engine.k_cache, engine.v_cache, engine._counts, _t, _p = out
@@ -180,6 +201,12 @@ def _exec_moves(engine, buckets) -> int:
         jnp.asarray(0, jnp.int32),
         jnp.asarray(0, jnp.int32),
     )
+    if getattr(engine, "seq_state", None) is not None:
+        # A prefix hit's state copy (entry 0 onto itself).
+        engine.seq_state = engine._copy_state(
+            engine.seq_state, jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32)
+        )
+        n += 1
     return n + 1
 
 

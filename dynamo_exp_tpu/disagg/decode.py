@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import logging
 
+from ..engine.config import refuse_seq_state_tiers
 from ..engine.engine import TPUEngine
 from ..engine.scheduler import RemoteKv
 from ..protocols.common import BackendInput
@@ -45,6 +46,7 @@ class DisaggDecodeEngine(AsyncEngine):
         transfer_timeout_s: float = 60.0,
         breaker: CircuitBreaker | None = None,
     ):
+        refuse_seq_state_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         self.engine = engine
         self.queue = queue
         self.receiver = receiver

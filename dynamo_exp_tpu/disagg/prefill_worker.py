@@ -12,6 +12,7 @@ from __future__ import annotations
 import logging
 import time
 
+from ..engine.config import refuse_seq_state_tiers
 from ..engine.engine import TPUEngine
 from ..protocols.common import BackendInput, SamplingOptions
 from ..runtime.runtime import CancellationToken
@@ -33,6 +34,7 @@ class PrefillWorker:
         cancel: CancellationToken | None = None,
         component=None,
     ):
+        refuse_seq_state_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         self.engine = engine
         self.queue = queue
         self.cancel = cancel or CancellationToken()

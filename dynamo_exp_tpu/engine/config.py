@@ -77,6 +77,47 @@ def _parse_env_flag(name: str, raw: str) -> bool:
     )
 
 
+def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
+    """The one place a model whose sequences carry state beside their
+    pages (``ModelConfig.has_seq_state``: lfm2_moe's short convolution)
+    is refused by what moves or re-reads pages alone. Each of these would
+    resume or verify a sequence with a state that does not belong to its
+    tokens, and serve wrong numbers without any error:
+
+    - host offload and its prefetch / proactive swap (``engine/offload.py``,
+      ``host_cache_pages``), G3 persistence (``kv/persistent.py``,
+      ``kv_store_dir``): an uploaded page brings no state;
+    - speculative decoding (``spec_mode``): a rejected draft has already
+      advanced the state;
+    - ring prefill (``sp`` > 1): it writes pages from outside the paged
+      layer loop that carries the state;
+    - ``tier`` = "disaggregated transfer" / "migration"
+      (``disagg/transfer.py``, ``runtime/reclaim.py``): named by the
+      component that is being built round the engine.
+
+    With ``tier`` None the engine's own configuration is checked (called
+    by ``TPUEngine.__init__``); with a name, that component is refused.
+    A model without such state passes, and so does ``cfg`` None (a
+    component built round a stand-in engine that has no configuration)."""
+    if cfg is None or not cfg.model.has_seq_state:
+        return
+    armed = [tier] if tier else [
+        name for name, on in (
+            ("host offload (host_cache_pages)", cfg.host_cache_pages > 0),
+            ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
+            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
+            ("ring prefill (sp > 1)", cfg.sp > 1),
+        ) if on
+    ]
+    if armed:
+        raise ValueError(
+            f"model_type {cfg.model.model_type!r} keeps per-sequence state "
+            f"beside its KV pages (a short-convolution state a layer), which "
+            f"{', '.join(armed)} would not carry: a sequence would resume with "
+            "a state that is not its own. Serve it without (README.md, models/)"
+        )
+
+
 def default_prefill_buckets(max_len: int) -> list[int]:
     buckets = []
     b = 16

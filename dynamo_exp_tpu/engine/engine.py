@@ -68,6 +68,7 @@ from ..models.llama import (
     forward_ragged,
     init_kv_cache,
     init_params,
+    init_seq_state,
     kv_cache_shardings,
     param_shardings,
     routed_experts_form,
@@ -100,7 +101,7 @@ from ..telemetry.flight import (
     register_dumper,
     unregister_dumper,
 )
-from .config import EngineConfig
+from .config import EngineConfig, refuse_seq_state_tiers
 from .kv_manager import KvEvent, KvPageManager
 from .offload import CopyStream, HostKvPool
 from .scheduler import RemoteKv, Scheduler, SeqState, Sequence
@@ -224,6 +225,9 @@ class _PendingRagged:
     # (seeds, temp, top_k, top_p, f, p, r) np arrays, reused by a chain.
     sampler_args: tuple | None = None
     slot_map: object | None = None  # np (sampler variants only)
+    # np [nb]: each row's sequence-state entry (a model with such state);
+    # reused verbatim by a chain.
+    state_slots: object | None = None
     # Dispatch-profiler stamp (monotonic, taken right after the dispatch
     # call returned): the consume's existing host sync closes the pair.
     dispatched_at: float = 0.0
@@ -250,6 +254,7 @@ class TPUEngine(AsyncEngine):
         seed: int = 0,
     ):
         self.cfg = cfg
+        refuse_seq_state_tiers(cfg)
         self.mesh = mesh or build_mesh(tp=cfg.tp, sp=cfg.sp)
         mcfg = cfg.model
 
@@ -275,6 +280,18 @@ class TPUEngine(AsyncEngine):
             mcfg, cfg.num_pages, cfg.page_size, dtype=cfg.kv_dtype_jnp,
             shardings=tuple(sharding(s) for s in kv_cache_shardings()),
         )
+        # What a sequence carries beside its pages (lfm2_moe's short
+        # convolution; None for every other model): one entry a decode
+        # slot, then the snapshots a prefix hit restores from, four a
+        # slot (8 KiB a convolution layer each). Replicated over the mesh.
+        state_slots = cfg.max_decode_slots if mcfg.has_seq_state else 0
+        state_snapshots = 4 * state_slots
+        # Entries of the pool; as a row's entry it means "none": a write
+        # there is past the pool and dropped.
+        self._state_entries = state_slots + state_snapshots
+        self.seq_state = init_seq_state(
+            mcfg, self._state_entries, sharding=sharding(P())
+        )
         layers, pages, page_size, width = self.k_cache.shape
         get_telemetry().set_kv_pool_info(
             layers, pages, page_size, width, str(self.k_cache.dtype)
@@ -286,7 +303,7 @@ class TPUEngine(AsyncEngine):
         on_evict = None
         if cfg.host_cache_pages > 0:
             page_shape = (
-                mcfg.num_layers,
+                mcfg.num_attn_layers,
                 cfg.page_size,
                 mcfg.num_kv_heads * mcfg.head_dim_,
             )
@@ -344,6 +361,8 @@ class TPUEngine(AsyncEngine):
             on_evict=on_evict,
             sharing=cfg.prefix_sharing,
             g3_store=self.g3_store,
+            state_slots=state_slots,
+            state_snapshots=state_snapshots,
         )
         # Observability (docs/observability.md): per-dispatch profiler
         # (host gap vs in-flight, compile attribution — pure timestamps
@@ -379,6 +398,12 @@ class TPUEngine(AsyncEngine):
         def kv_cow_pages(k, v, src, dst):
             return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
 
+        def seq_state_copy(pool, src, dst):
+            return pool.at[:, dst].set(pool[:, src])
+
+        # A prefix hit's state: snapshot entry -> the sequence's own entry.
+        self._copy_state = jax.jit(seq_state_copy, donate_argnums=(0,))
+        self._pub_state_hits = {"restored": 0, "shortened": 0, "not_taken": 0}
         self._gather_pages = jax.jit(kv_gather_pages)
         self._inject_pages = jax.jit(kv_inject_pages, donate_argnums=(0, 1))
         # Copy-on-write page copy (docs/prefix_sharing.md): device-to-
@@ -670,13 +695,20 @@ class TPUEngine(AsyncEngine):
         mcfg = self.cfg.model
         K = self.cfg.decode_window
 
-        def run_forward(params, tokens, positions, page_table, k, v):
-            logits, k, v = forward(
+        # A model with sequence state takes the pool and the rows'
+        # entries last, donates the pool and returns it last; for every
+        # other model both are None and the programs are what they were.
+        has_state = mcfg.has_seq_state
+
+        def run_forward(params, tokens, positions, page_table, k, v,
+                        state, state_slots):
+            logits, k, v, *state = forward(
                 params, mcfg, tokens[:, None], positions[:, None],
                 page_table, k, v, attn_pages=pages, attn_impl=impl,
                 mesh=mesh, interpret=interpret,
+                seq_state=state, state_slots=state_slots,
             )
-            return logits[:, 0], k, v  # [nb, V]
+            return logits[:, 0], k, v, (state[0] if state else None)  # [nb, V]
 
         def advance(positions, max_pos, next_tok, stop_set, eos_gate,
                     budget_gate, t, active):
@@ -693,20 +725,25 @@ class TPUEngine(AsyncEngine):
 
         if full_sampler:
 
-            @partial(jax.jit, donate_argnums=(1, 2, 8))
+            @partial(
+                jax.jit,
+                donate_argnums=(1, 2, 8, 19) if has_state else (1, 2, 8),
+            )
             def ragged_window(params, k, v, tokens, positions, max_pos,
                               page_table, seeds, counts_all, slot_map, temp,
                               top_k, top_p, freq_pen, pres_pen, rep_pen,
-                              stop_set, eos_gate, budget_gate):
+                              stop_set, eos_gate, budget_gate,
+                              state=None, state_slots=None):
                 # Compaction: penalty rows live slot-indexed in the
                 # [B+1, V] pool; gather the stepped rows in, scatter
                 # back out (pad rows map to the scratch row B).
                 counts0 = counts_all[slot_map]
 
                 def step(carry, t):
-                    tokens, positions, k, v, counts = carry
-                    logits, k, v = run_forward(
-                        params, tokens, positions, page_table, k, v
+                    tokens, positions, k, v, counts, state = carry
+                    logits, k, v, state = run_forward(
+                        params, tokens, positions, page_table, k, v,
+                        state, state_slots,
                     )
                     with jax.named_scope("sample"):
                         shaped = apply_penalties(
@@ -743,26 +780,31 @@ class TPUEngine(AsyncEngine):
                         if want_lp
                         else (next_tok,)
                     )
-                    return (tokens, positions, k, v, counts), ys
+                    return (tokens, positions, k, v, counts, state), ys
 
-                (tokens, positions, k, v, counts), ys = jax.lax.scan(
-                    step, (tokens, positions, k, v, counts0),
+                (tokens, positions, k, v, counts, state), ys = jax.lax.scan(
+                    step, (tokens, positions, k, v, counts0, state),
                     jnp.arange(K),
                 )
                 counts_all = counts_all.at[slot_map].set(counts)
                 # ys: toks [K,nb] (+ lp [K,nb], top_ids/top_lp
                 # [K,nb,N] when want_lp).
-                return ys, k, v, counts_all, tokens, positions
+                out = (ys, k, v, counts_all, tokens, positions)
+                return out + (state,) if has_state else out
 
         else:
 
-            @partial(jax.jit, donate_argnums=(1, 2))
+            @partial(
+                jax.jit, donate_argnums=(1, 2, 10) if has_state else (1, 2)
+            )
             def ragged_window(params, k, v, tokens, positions, max_pos,
-                              page_table, stop_set, eos_gate, budget_gate):
+                              page_table, stop_set, eos_gate, budget_gate,
+                              state=None, state_slots=None):
                 def step(carry, t):
-                    tokens, positions, k, v = carry
-                    logits, k, v = run_forward(
-                        params, tokens, positions, page_table, k, v
+                    tokens, positions, k, v, state = carry
+                    logits, k, v, state = run_forward(
+                        params, tokens, positions, page_table, k, v,
+                        state, state_slots,
                     )
                     with jax.named_scope("sample"):
                         next_tok = jnp.argmax(logits, axis=-1).astype(
@@ -783,12 +825,13 @@ class TPUEngine(AsyncEngine):
                         if want_lp
                         else (next_tok,)
                     )
-                    return (tokens, positions, k, v), ys
+                    return (tokens, positions, k, v, state), ys
 
-                (tokens, positions, k, v), ys = jax.lax.scan(
-                    step, (tokens, positions, k, v), jnp.arange(K)
+                (tokens, positions, k, v, state), ys = jax.lax.scan(
+                    step, (tokens, positions, k, v, state), jnp.arange(K)
                 )
-                return ys, k, v, tokens, positions
+                out = (ys, k, v, tokens, positions)
+                return out + (state,) if has_state else out
 
         return ragged_window
 
@@ -811,13 +854,17 @@ class TPUEngine(AsyncEngine):
         T_s = self.cfg.spec_max_draft + 1
         q_tile = self._ragged_align()
 
+        has_state = mcfg.has_seq_state  # as in _windowed_program
+
         def run_forward(params, k, v, tokens, positions, row_of,
-                        page_table, out_idx):
-            return forward_ragged(
+                        page_table, out_idx, state, state_rows):
+            logits, k, v, *state = forward_ragged(
                 params, mcfg, tokens, positions, row_of, page_table,
                 k, v, out_idx, attn_pages=pages, attn_impl=impl,
                 q_tile=q_tile, mesh=mesh, interpret=interpret,
+                seq_state=state, state_rows=state_rows,
             )
+            return logits, k, v, (state[0] if state else None)
 
         def out_indices(q_last, spec_idx):
             if not spec_on:
@@ -837,15 +884,19 @@ class TPUEngine(AsyncEngine):
 
         if full_sampler:
 
-            @partial(jax.jit, donate_argnums=(1, 2, 9))
+            @partial(
+                jax.jit,
+                donate_argnums=(1, 2, 9, 23) if has_state else (1, 2, 9),
+            )
             def ragged_mixed(params, k, v, tokens, positions, row_of,
                              page_table, q_last, pos0, counts_all, slot_map,
                              is_decode, seeds, temp, top_k, top_p, freq_pen,
                              pres_pen, rep_pen, spec_idx, spec_pos,
-                             spec_drafts, n_drafts):
-                logits_all, k, v = run_forward(
+                             spec_drafts, n_drafts,
+                             state=None, state_rows=None):
+                logits_all, k, v, state = run_forward(
                     params, k, v, tokens, positions, row_of, page_table,
-                    out_indices(q_last, spec_idx),
+                    out_indices(q_last, spec_idx), state, state_rows,
                 )
                 with jax.named_scope("sample"):
                     logits0 = logits_all[:B1]
@@ -881,17 +932,20 @@ class TPUEngine(AsyncEngine):
                         ys = ys + (lp0, tid0, tlp0)
                         if spec_on:
                             ys = ys + pack_spec_lp(spec_logits, targets)
-                return ys, k, v, counts_all
+                out = (ys, k, v, counts_all)
+                return out + (state,) if has_state else out
 
         else:
 
-            @partial(jax.jit, donate_argnums=(1, 2))
+            @partial(
+                jax.jit, donate_argnums=(1, 2, 11) if has_state else (1, 2)
+            )
             def ragged_mixed(params, k, v, tokens, positions, row_of,
                              page_table, q_last, spec_idx, spec_drafts,
-                             n_drafts):
-                logits_all, k, v = run_forward(
+                             n_drafts, state=None, state_rows=None):
+                logits_all, k, v, state = run_forward(
                     params, k, v, tokens, positions, row_of, page_table,
-                    out_indices(q_last, spec_idx),
+                    out_indices(q_last, spec_idx), state, state_rows,
                 )
                 with jax.named_scope("sample"):
                     logits0 = logits_all[:B1]
@@ -912,7 +966,8 @@ class TPUEngine(AsyncEngine):
                         ys = ys + (lp0, tid0, tlp0)
                         if spec_on:
                             ys = ys + pack_spec_lp(spec_logits, targets)
-                return ys, k, v
+                out = (ys, k, v)
+                return out + (state,) if has_state else out
 
         return ragged_mixed
 
@@ -921,6 +976,19 @@ class TPUEngine(AsyncEngine):
         ragged kernel requires every ``ragged_q_tile`` slice to belong
         to one row; the XLA reference packs tight."""
         return self.cfg.ragged_q_tile if self._attn_impl == "pallas" else 1
+
+    def _run_program(self, fn, state_rows, *args) -> tuple:
+        """Call a ragged program. For a model with sequence state it also
+        gets the state pool and ``state_rows`` (a window: each row's entry
+        [nb]; a mixed dispatch: ``forward_ragged``'s ``state_rows``), and
+        the pool it returns replaces the engine's."""
+        if self.seq_state is None:
+            return fn(*args)
+        *out, self.seq_state = fn(
+            *args, self.seq_state, jax.tree.map(jnp.asarray, state_rows)
+        )
+        return tuple(out)
+
     def _count_dispatch(
         self, nb, attn_pages, windowed, full_sampler, want_lp, with_spec,
         chained: bool, live: int,
@@ -1342,7 +1410,7 @@ class TPUEngine(AsyncEngine):
         itemsize = 2 if self.cfg.kv_dtype == "bfloat16" else 4
         return (
             2
-            * m.num_layers
+            * m.num_attn_layers
             * self.cfg.page_size
             * m.num_kv_heads
             * m.head_dim_
@@ -1789,10 +1857,25 @@ class TPUEngine(AsyncEngine):
         if self.profiler is not None:
             seq.anat_compile_mark = self.profiler.compile_total_s()
         self._note_prefetch_admission(seq)
+        if seq.state_restore is not None:
+            # The hit's pages hold K and V of tokens this sequence will not
+            # compute; the state that belongs to the last of them is the
+            # snapshot's. Stream order puts the copy after the dispatch
+            # that wrote the snapshot and before this sequence's first.
+            self.seq_state = self._copy_state(
+                self.seq_state,
+                jnp.asarray(seq.state_restore, jnp.int32),
+                jnp.asarray(seq.state_slot, jnp.int32),
+            )
+            get_telemetry().seq_state_restores.labels(
+                "resume" if seq.preemptions else "prefix"
+            ).inc()
         self.flight.record(
             "admit",
             req=seq.request_id,
             slot=seq.slot,
+            state_slot=seq.state_slot if seq.state_slot >= 0 else None,
+            state_from=seq.state_restore,
             prompt=len(seq.prompt),
             cached=seq.cached_len,
             priority=seq.priority,
@@ -2008,6 +2091,11 @@ class TPUEngine(AsyncEngine):
                 if delta:
                     tel.kv_prefix_hits.labels(kind).inc(delta)
                     self._pub_prefix_hits[kind] = total
+            for outcome, total in self.kv.state_hits.items():
+                delta = total - self._pub_state_hits[outcome]
+                if delta:
+                    tel.seq_state_prefix_hits.labels(outcome).inc(delta)
+                    self._pub_state_hits[outcome] = total
 
     def _service_leases(self) -> None:
         """Engine-loop-thread lease upkeep: apply queued delivery
@@ -3311,6 +3399,9 @@ class TPUEngine(AsyncEngine):
             pres = np.zeros(nb, np.float32)
             rep = np.ones(nb, np.float32)
 
+            # Padding rows keep no state: past the pool, so never written.
+            state_slots = np.full(nb, self._state_entries, np.int32)
+
             rows: list[_RaggedRow] = []
             max_pages = 1
             capacity_capped = False
@@ -3321,6 +3412,7 @@ class TPUEngine(AsyncEngine):
                 max_pos[r] = cap
                 table[r, : len(seq.page_ids)] = seq.page_ids
                 slot_map[r] = seq.slot
+                state_slots[r] = seq.state_slot
                 max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
                 stops = self._stop_set(seq)
                 stop_set[r, : len(stops)] = stops
@@ -3347,7 +3439,8 @@ class TPUEngine(AsyncEngine):
         sampler_args = (seeds, temp, top_k, top_p, freq, pres, rep)
         if full_sampler:
             (ys, self.k_cache, self.v_cache, self._counts,
-             tok_dev, pos_dev) = fn(
+             tok_dev, pos_dev) = self._run_program(
+                fn, state_slots,
                 self.params, self.k_cache, self.v_cache,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(max_pos), jnp.asarray(table),
@@ -3358,7 +3451,9 @@ class TPUEngine(AsyncEngine):
                 jnp.asarray(budget_gate),
             )
         else:
-            ys, self.k_cache, self.v_cache, tok_dev, pos_dev = fn(
+            (ys, self.k_cache, self.v_cache,
+             tok_dev, pos_dev) = self._run_program(
+                fn, state_slots,
                 self.params, self.k_cache, self.v_cache,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(max_pos), jnp.asarray(table),
@@ -3397,6 +3492,7 @@ class TPUEngine(AsyncEngine):
             stop_tokens=stop_set,
             sampler_args=sampler_args if full_sampler else None,
             slot_map=slot_map if full_sampler else None,
+            state_slots=state_slots,
             dispatched_at=dispatched_at,
         )
 
@@ -3441,6 +3537,14 @@ class TPUEngine(AsyncEngine):
             spec_drafts = np.full((B1, max(T_s - 1, 1)), -1, np.int32)
             n_drafts = np.zeros(B1, np.int32)
             sampler_arrs = (seeds, temp, top_k, top_p, freq, pres, rep)
+            # Sequence state (a model that has it): the entry each row
+            # reads, and the writes (stream index -> entry): rows' own in
+            # the first B1, a prefill row's snapshot in the second. An
+            # entry past the pool is no write.
+            no_entry = self._state_entries
+            state_slots = np.full(B1, no_entry, np.int32)
+            state_src = np.zeros(2 * B1, np.int32)
+            state_dst = np.full(2 * B1, no_entry, np.int32)
 
             def add_span(toks: list[int], poss: list[int], r: int) -> int:
                 """Append one row's query span to the flat stream, aligned
@@ -3474,6 +3578,19 @@ class TPUEngine(AsyncEngine):
                 chunks.append((seq, start_tok, n))
                 table[r, : len(seq.page_ids)] = seq.page_ids
                 q_last[r] = qs + n - 1
+                if seq.state_slot >= 0:
+                    state_slots[r] = state_dst[r] = seq.state_slot
+                    state_src[r] = q_last[r]
+                    # The deepest block boundary this chunk crosses: keep
+                    # the state there for a later prefix hit to restore.
+                    b = seq.prefill_sent // ps * ps
+                    entry = (
+                        self.kv.snapshot_entry(seq.prompt_hashes[b // ps - 1])
+                        if b > start_tok else None
+                    )
+                    if entry is not None:
+                        state_src[B1 + r] = qs + b - 1 - start_tok
+                        state_dst[B1 + r] = entry
                 # Key the first-token draw by the absolute position of the
                 # prompt's last token — identical to the draw a decode
                 # window would make feeding that token, so prefill chunking
@@ -3503,6 +3620,9 @@ class TPUEngine(AsyncEngine):
                 qs = add_span([seq.last_token()], [wpos], r)
                 table[r, : len(seq.page_ids)] = seq.page_ids
                 q_last[r] = qs
+                if seq.state_slot >= 0:
+                    state_slots[r] = state_dst[r] = seq.state_slot
+                    state_src[r] = qs
                 pos0[r] = wpos
                 is_decode[r] = True
                 slot_map[r] = seq.slot
@@ -3555,8 +3675,11 @@ class TPUEngine(AsyncEngine):
                 self._flush_offloads()
         prof = self.profiler
         t0 = prof.begin("ragged", "mixed") if prof is not None else 0.0
+        state_rows = (state_slots, state_src, state_dst)
         if full_sampler:
-            ys, self.k_cache, self.v_cache, self._counts = fn(
+            (ys, self.k_cache, self.v_cache,
+             self._counts) = self._run_program(
+                fn, state_rows,
                 self.params, self.k_cache, self.v_cache,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(row_of), jnp.asarray(table),
@@ -3569,7 +3692,8 @@ class TPUEngine(AsyncEngine):
                 jnp.asarray(n_drafts),
             )
         else:
-            ys, self.k_cache, self.v_cache = fn(
+            ys, self.k_cache, self.v_cache = self._run_program(
+                fn, state_rows,
                 self.params, self.k_cache, self.v_cache,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(row_of), jnp.asarray(table),
@@ -3738,7 +3862,8 @@ class TPUEngine(AsyncEngine):
         if pending.full_sampler:
             seeds, temp, top_k, top_p, freq, pres, rep = pending.sampler_args
             (ys, self.k_cache, self.v_cache, self._counts,
-             tok_dev, pos_dev) = fn(
+             tok_dev, pos_dev) = self._run_program(
+                fn, pending.state_slots,
                 self.params, self.k_cache, self.v_cache,
                 pending.tokens_dev, pending.positions_dev,
                 jnp.asarray(max_pos), jnp.asarray(table),
@@ -3749,7 +3874,9 @@ class TPUEngine(AsyncEngine):
                 jnp.asarray(budget_gate),
             )
         else:
-            ys, self.k_cache, self.v_cache, tok_dev, pos_dev = fn(
+            (ys, self.k_cache, self.v_cache,
+             tok_dev, pos_dev) = self._run_program(
+                fn, pending.state_slots,
                 self.params, self.k_cache, self.v_cache,
                 pending.tokens_dev, pending.positions_dev,
                 jnp.asarray(max_pos), jnp.asarray(table),
@@ -3789,6 +3916,7 @@ class TPUEngine(AsyncEngine):
             stop_tokens=stop_set,
             sampler_args=pending.sampler_args,
             slot_map=pending.slot_map,
+            state_slots=pending.state_slots,
             dispatched_at=dispatched_at,
         )
 

@@ -93,6 +93,11 @@ class Allocation:
     cached_pages: int = 0
     wait_fill: list[int] = field(default_factory=list)
     shared_tail: tuple[int, int] | None = None
+    # Sequence state (a model with ``has_seq_state``): the pool entry
+    # holding the state that belongs to token ``cached_len - 1``, which the
+    # engine copies into the sequence's own entry before its first
+    # dispatch. None where nothing is cached (or the model has no state).
+    state_restore: int | None = None
 
 
 @dataclass
@@ -148,6 +153,8 @@ class KvPageManager:
         clock: Callable[[], float] = time.monotonic,
         sharing: bool = True,
         g3_store=None,
+        state_slots: int = 0,
+        state_snapshots: int = 0,
     ):
         self.num_pages = num_pages
         self.page_size = page_size
@@ -217,6 +224,44 @@ class KvPageManager:
         # Leases reclaimed by the most recent reap_expired() call —
         # (lease_id, pages) pairs the engine reads to close lease spans.
         self.last_reaped: list[tuple[str, int]] = []
+        # Sequence state beside the pages (a model whose layers are not all
+        # attention: ``ModelConfig.has_seq_state``; 0 slots = none). The
+        # device pool (``models/llama.py::init_seq_state``) has
+        # ``state_slots + state_snapshots`` entries and this manager
+        # tracks them by id, as it does pages:
+        #
+        # - entries ``[0, state_slots)`` are live: one a bound sequence,
+        #   taken at admission and released at finish or preemption, in
+        #   the same ledger as the pages (``ledger_check``);
+        # - the rest are snapshots: the state as it stood at the end of a
+        #   registered block, keyed by that block's chained hash, written
+        #   by the prefill dispatch that crossed the boundary and kept
+        #   LRU. A prefix hit is only as deep as the deepest matched block
+        #   that has one: the next token's convolution needs the products
+        #   of the very tokens the hit skips, so a hit without them would
+        #   serve wrong numbers without any error.
+        #
+        # WHY THIS FORM. A state beside every cached block would make every
+        # hit whole, at 96 KiB beside a block's 128 KiB of K and V (12
+        # convolution layers, 4 attention) and a write of it every 16
+        # tokens of every prefill. Taking no hit at all costs a re-prefill
+        # of every shared prompt. One snapshot a prefill dispatch and row,
+        # at the deepest block boundary the chunk crosses, costs one more
+        # row in a scatter the forward makes anyway, and covers what
+        # prefix sharing is for: a prompt asked again, a resumed sequence,
+        # and prompts that share a head of whole chunks.
+        self.state_slots = state_slots
+        self.state_snapshots = state_snapshots
+        self._free_state: list[int] = list(range(state_slots - 1, -1, -1))
+        self._held_state = 0
+        self._snap_by_hash: OrderedDict[int, int] = OrderedDict()  # LRU
+        self._free_snaps: list[int] = list(
+            range(state_slots + state_snapshots - 1, state_slots - 1, -1)
+        )
+        # Admissions by what their prefix match came to for want of a
+        # state: taken whole, shortened to a deeper-held boundary, or not
+        # taken (pages matched and no boundary held).
+        self.state_hits = {"restored": 0, "shortened": 0, "not_taken": 0}
 
     # ---------------------------------------------------------------- stats
     @property
@@ -257,7 +302,49 @@ class KvPageManager:
             # G3 tier occupancy (docs/fault_tolerance.md "Durable KV"):
             # store-resident pages (mirrored as dynamo_kv_store_pages).
             "kv_store_pages": self.g3_store.resident if self.g3_store else 0,
+            "seq_state_slots_in_use": self._held_state,
         }
+
+    # -------------------------------------------------------- sequence state
+    def take_state_slot(self) -> int:
+        """The pool entry a sequence being bound keeps its state in (-1
+        for a model without state). There are as many as decode slots,
+        so a sequence that got a slot gets one."""
+        if not self.state_slots:
+            return -1
+        self._held_state += 1
+        return self._free_state.pop()
+
+    def release_state_slot(self, slot: int) -> None:
+        if slot >= 0:
+            self._held_state -= 1
+            self._free_state.append(slot)
+
+    def snapshot_entry(self, seq_hash: int) -> int | None:
+        """The pool entry the state at the end of block ``seq_hash`` is to
+        be written to by the dispatch being assembled, or None where one
+        is held already (it is then only marked used). The oldest snapshot
+        goes when none is free. Registered at once: the caller's dispatch
+        precedes any admission that could restore from it."""
+        if not self.state_snapshots or not self.sharing:
+            return None
+        if seq_hash in self._snap_by_hash:
+            self._snap_by_hash.move_to_end(seq_hash)
+            return None
+        if self._free_snaps:
+            entry = self._free_snaps.pop()
+        else:
+            _, entry = self._snap_by_hash.popitem(last=False)
+        self._snap_by_hash[seq_hash] = entry
+        return entry
+
+    def _deepest_state(self, hashes: list[int]) -> int:
+        """How many of the matched blocks ``hashes`` a hit may take: up to
+        the deepest whose end has a snapshot."""
+        for j in range(len(hashes), 0, -1):
+            if hashes[j - 1] in self._snap_by_hash:
+                return j
+        return 0
 
     def _note_active(self) -> None:
         active = self.active_pages
@@ -315,7 +402,29 @@ class KvPageManager:
         if need_total > max_pages:
             return None  # exceeds per-sequence capacity; caller must reject
         hashes = compute_block_hashes_for_seq(tokens, ps)
-        if self.sharing:
+        state_restore: int | None = None
+        if self.sharing and self.state_slots:
+            # Filled pages only: a snapshot's writer has dispatched every
+            # block before its boundary, so nothing is lost, and a sharer
+            # that had to re-fill an orphan would rewind to a position it
+            # holds no state for. No partial tail for the same reason.
+            matched_pages, matched_hashes = self._match_hashes(
+                hashes, require_filled=True
+            )
+            # The prompt's last token is always computed.
+            reach = min(len(matched_pages), (n_tokens - 1) // ps)
+            keep = self._deepest_state(matched_hashes[:reach])
+            if matched_pages:
+                outcome = (
+                    "restored" if keep == reach
+                    else "shortened" if keep else "not_taken"
+                )
+                self.state_hits[outcome] += 1
+            matched_pages, matched_hashes = matched_pages[:keep], matched_hashes[:keep]
+            if keep:
+                self._snap_by_hash.move_to_end(matched_hashes[-1])
+                state_restore = self._snap_by_hash[matched_hashes[-1]]
+        elif self.sharing:
             matched_pages, matched_hashes = self._match_hashes(hashes)
         else:
             matched_pages, matched_hashes = [], []
@@ -340,6 +449,7 @@ class KvPageManager:
         tail_tokens = tokens[(n_tokens // ps) * ps :]
         if (
             self.sharing
+            and not self.state_slots
             and tail_tokens
             and not g2_hashes
             and not g3_hashes
@@ -448,6 +558,7 @@ class KvPageManager:
             cached_pages=cached_pages,
             wait_fill=wait_fill,
             shared_tail=shared_tail,
+            state_restore=state_restore,
         )
 
     def allocate_page(self) -> int | None:
@@ -721,6 +832,15 @@ class KvPageManager:
             violations.append(
                 f"negative ledger counter: ref_total={self._ref_total} "
                 f"held={held}"
+            )
+        if len(self._free_state) + self._held_state != self.state_slots or (
+            len(self._free_snaps) + len(self._snap_by_hash) != self.state_snapshots
+        ):
+            violations.append(
+                f"sequence-state conservation broken: free={len(self._free_state)} "
+                f"held={self._held_state} of {self.state_slots} slots; "
+                f"free={len(self._free_snaps)} kept={len(self._snap_by_hash)} "
+                f"of {self.state_snapshots} snapshots"
             )
         lease_pins = sum(len(l.page_ids) for l in self._leases.values())
         if lease_pins > self._ref_total:

@@ -74,6 +74,12 @@ class Sequence:
     is_cancelled: Callable[[], bool]
     state: SeqState = SeqState.WAITING
     slot: int = -1
+    # A model whose sequences carry state beside their pages: the entry of
+    # the state pool this sequence keeps its own in while bound, and the
+    # entry its prefix hit restores from before the first dispatch
+    # (``KvPageManager.take_state_slot`` / ``Allocation.state_restore``).
+    state_slot: int = -1
+    state_restore: int | None = None
     page_ids: list[int] = field(default_factory=list)
     cached_len: int = 0  # prefix reused from the page pool
     tokens: list[int] = field(default_factory=list)  # prompt + confirmed
@@ -366,6 +372,8 @@ class Scheduler:
             seq.tokens = list(seq.prompt)
             seq.prefill_sent = seq.cached_len
             seq.slot = slot
+            seq.state_slot = self.kv.take_state_slot()
+            seq.state_restore = alloc.state_restore
             seq.state = SeqState.PREFILL
             self.slots[slot] = seq
             self.active_count += 1
@@ -559,12 +567,17 @@ class Scheduler:
             self.slots[seq.slot] = None
             self.active_count -= 1
             seq.slot = -1
+            self._release_state(seq)
         # Fills this sequence owed but never dispatched orphan first so
         # sharers can claim them; THEN the refs drop (a zero-ref
         # unfilled page unregisters instead of parking as matchable).
         self.kv.abort_fills(seq.request_id, seq.page_ids)
         self.kv.release_sequence(seq.page_ids)
         seq.emit([], reason)
+
+    def _release_state(self, seq: Sequence) -> None:
+        self.kv.release_state_slot(seq.state_slot)
+        seq.state_slot, seq.state_restore = -1, None
 
     # ------------------------------------------------------------ preemption
     def preemption_victim(self, max_preemptions: int) -> Sequence | None:
@@ -619,6 +632,7 @@ class Scheduler:
             self.slots[seq.slot] = None
             self.active_count -= 1
             seq.slot = -1
+            self._release_state(seq)
         self.kv.abort_fills(seq.request_id, seq.page_ids)
         self.kv.release_sequence(seq.page_ids)
         seq.page_ids = []

@@ -93,12 +93,81 @@ class ModelConfig:
     # qwen2_moe: an always-on shared expert of this width, blended via
     # a learned sigmoid gate. None = no shared expert.
     shared_expert_intermediate_size: int | None = None
+    # lfm2_moe: layers of two kinds in one stack. ``layer_types`` says
+    # which layers' operator is a gated short convolution ("conv") and
+    # which attention; the first ``num_dense_layers`` have a dense
+    # feed-forward and the rest routed experts. The convolution spans
+    # ``conv_L_cache`` tokens, so a sequence carries ``conv_L_cache - 1``
+    # products a convolution layer from token to token (``has_seq_state``).
+    num_dense_layers: int = 0
+    conv_L_cache: int = 3
+    # How the router scores and chooses (``ops/moe.py::moe_router``):
+    # "softmax" (mixtral, qwen moe) or "sigmoid" (lfm2_moe: sigmoid
+    # scores, chosen by score + ``router_bias`` where ``use_expert_bias``,
+    # weighed by the unbiased score, times ``routed_scaling_factor``).
+    router_score: str = "softmax"
+    use_expert_bias: bool = False
+    routed_scaling_factor: float = 1.0
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def op_kinds(self) -> tuple[str, ...]:
+        """Each layer's operator: "conv" (gated short convolution) or
+        "attn" (every attention form, windowed or full)."""
+        types = self.layer_types or ("full_attention",) * self.num_layers
+        return tuple("conv" if t == "conv" else "attn" for t in types)
+
+    @property
+    def ff_kinds(self) -> tuple[str, ...]:
+        """Each layer's feed-forward: "dense" or "routed"."""
+        return tuple(
+            "routed" if self.is_moe and i >= self.num_dense_layers else "dense"
+            for i in range(self.num_layers)
+        )
+
+    @property
+    def layer_runs(self) -> tuple[tuple[str, str, int, int, int, int], ...]:
+        """The stack as runs of layers of one kind, in order: (operator
+        kind, feed-forward kind, first layer, layers, the first layer's
+        ordinal among layers of its operator kind, and among layers of its
+        feed-forward kind). Parameters are stacked by kind
+        (``models/llama.py::init_params``) and the paged layer loop is one
+        ``lax.scan`` a run, so a model of one kind is one run."""
+        runs: list[tuple[str, str, int, int, int, int]] = []
+        seen = {"conv": 0, "attn": 0, "dense": 0, "routed": 0}
+        for i, kind in enumerate(zip(self.op_kinds, self.ff_kinds)):
+            if runs and runs[-1][:2] == kind:
+                runs[-1] = (*runs[-1][:3], runs[-1][3] + 1, *runs[-1][4:])
+            else:
+                runs.append((*kind, i, 1, seen[kind[0]], seen[kind[1]]))
+            seen[kind[0]] += 1
+            seen[kind[1]] += 1
+        return tuple(runs)
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that write K and V: the depth of the paged pools."""
+        return self.op_kinds.count("attn")
+
+    @property
+    def num_conv_layers(self) -> int:
+        return self.op_kinds.count("conv")
+
+    @property
+    def num_routed_layers(self) -> int:
+        return self.ff_kinds.count("routed")
+
+    @property
+    def has_seq_state(self) -> bool:
+        """Whether a sequence holds state that is not a page (the short
+        convolution's last products): what the tiers that move pages
+        alone refuse (``engine/config.py::refuse_seq_state_tiers``)."""
+        return self.num_conv_layers > 0
 
     @property
     def expert_intermediate_size(self) -> int:
@@ -119,6 +188,16 @@ class ModelConfig:
         ``attention_bias`` key); mistral/mixtral carry ``sliding_window``;
         mixtral's experts are ``num_local_experts``."""
         model_type = cfg.get("model_type", "llama")
+        if model_type not in KNOWN_MODEL_TYPES:
+            # Every family is this one class with other field values, so
+            # an unknown family's config would build *something*: a
+            # uniform softmax-routed llama under another model's name.
+            raise ValueError(
+                f"unsupported model_type {model_type!r}; supported: "
+                f"{', '.join(sorted(KNOWN_MODEL_TYPES))}"
+            )
+        if model_type == "lfm2_moe":
+            return cls._from_lfm2_moe(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -193,10 +272,64 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_lfm2_moe(cls, cfg: dict) -> "ModelConfig":
+        """LiquidAI's ``lfm2_moe`` (LFM2-8B-A1B): the keys are its own
+        (``norm_eps``, ``num_experts``, ``conv_L_cache``...). The tied
+        head is the family's convention (its config class defaults it and
+        the published file leaves the key out)."""
+        if cfg.get("conv_bias", False):
+            raise ValueError("lfm2_moe with conv_bias=true is not supported")
+        if cfg.get("conv_L_cache", 3) < 2:
+            raise ValueError("lfm2_moe needs conv_L_cache >= 2")
+        n_layers = cfg["num_hidden_layers"]
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != n_layers or not set(layer_types) <= {
+            "conv", "full_attention"
+        }:
+            raise ValueError(
+                f"lfm2_moe layer_types must name {n_layers} layers as "
+                f"'conv' or 'full_attention', got {layer_types!r}"
+            )
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rope_theta=cfg.get("rope_theta", 1000000.0),
+            rms_norm_eps=cfg.get("norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 128000),
+            tie_word_embeddings=cfg.get(
+                "tie_word_embeddings", cfg.get("tie_embedding", True)
+            ),
+            qk_norm=True,
+            layer_types=layer_types,
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            num_dense_layers=cfg.get("num_dense_layers", 0),
+            conv_L_cache=cfg.get("conv_L_cache", 3),
+            router_score="sigmoid",
+            use_expert_bias=cfg.get("use_expert_bias", True),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type="lfm2_moe",
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             return cls.from_hf_config(json.load(f))
 
+
+# The families ``from_hf_config`` builds; any other ``model_type`` raises.
+KNOWN_MODEL_TYPES = frozenset({
+    "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
+    "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe",
+})
 
 # Presets used by tests, the dry-run entrypoints, and the benchmark.
 TINY = ModelConfig(
@@ -368,6 +501,27 @@ MISTRAL_7B = ModelConfig(  # Mistral-7B-v0.1 shape (4k sliding window)
     model_type="mistral",
 )
 
+TINY_LFM2 = ModelConfig(  # lfm2_moe family shape: both operator kinds, both
+    # feed-forward kinds, every run boundary the 16-layer cut has
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=6,
+    num_heads=4,
+    num_kv_heads=2,
+    max_position_embeddings=512,
+    qk_norm=True,
+    tie_word_embeddings=True,
+    layer_types=("conv", "conv", "full_attention", "conv", "conv", "full_attention"),
+    num_experts=8,
+    num_experts_per_tok=2,
+    moe_intermediate_size=64,
+    num_dense_layers=2,
+    router_score="sigmoid",
+    use_expert_bias=True,
+    model_type="lfm2_moe",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -387,6 +541,7 @@ PRESETS = {
     "tiny-qwen2": TINY_QWEN2,
     "tiny-qwen3": TINY_QWEN3,
     "tiny-moe": TINY_MOE,
+    "tiny-lfm2": TINY_LFM2,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

@@ -43,7 +43,7 @@ this module is their TPU-native replacement.
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +69,26 @@ def _dtype(cfg: ModelConfig):
         raise ValueError(f"unsupported model dtype: {cfg.dtype!r}") from None
 
 
+# The selection bias of a randomly initialised sigmoid router
+# (``router_bias``): normal with this deviation. The scores it moves are
+# sigmoids of unit-variance logits, whose 4th and 5th of 32 lie ~0.05
+# apart at the median, so a bias of this size changes the chosen set of
+# most rows (tests/test_lfm2.py counts them) and moves no weight.
+ROUTER_BIAS_SCALE = 0.1
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
-    """Random-init parameters (tests, benchmarks, and shape reference)."""
+    """Random-init parameters (tests, benchmarks, and shape reference).
+
+    Parameters are stacked by the kind of layer that has them
+    (``ModelConfig.layer_runs``): the two norms over all ``L`` layers,
+    attention's over the attention layers, the short convolution's
+    (``conv_in``/``conv_w``/``conv_out``) over the convolution layers,
+    the router and the expert matrices over the routed layers. A model
+    whose feed-forwards are of both kinds keeps its dense layers' under
+    ``dense_gate``/``dense_up``/``dense_down``; ``w_gate``/``w_up``/
+    ``w_down`` are then the routed layers' (``EXPERT_STACKS``). In a
+    model of one kind every stack is ``[L, ...]``, as it always was."""
     dt = _dtype(cfg)
     hd = cfg.head_dim_
     L, D, H, Hkv, I, V = (
@@ -81,36 +99,55 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         cfg.intermediate_size,
         cfg.vocab_size,
     )
+    La, Lc, Lr = cfg.num_attn_layers, cfg.num_conv_layers, cfg.num_routed_layers
+    Ld = L - Lr
     ks = jax.random.split(rng, 17)
+    # Keys for what lfm2 added, split off a folded key: ``ks`` (and so
+    # every other family's weights) stays what it was.
+    kc = jax.random.split(jax.random.fold_in(rng, 1), 5)
 
     def init(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * fan_in**-0.5).astype(dt)
 
     layers: Params = {
         "attn_norm": jnp.ones((L, D), dt),
-        "wq": init(ks[1], (L, D, H * hd), D),
-        "wk": init(ks[2], (L, D, Hkv * hd), D),
-        "wv": init(ks[3], (L, D, Hkv * hd), D),
-        "wo": init(ks[4], (L, H * hd, D), H * hd),
+        "wq": init(ks[1], (La, D, H * hd), D),
+        "wk": init(ks[2], (La, D, Hkv * hd), D),
+        "wv": init(ks[3], (La, D, Hkv * hd), D),
+        "wo": init(ks[4], (La, H * hd, D), H * hd),
         "mlp_norm": jnp.ones((L, D), dt),
     }
+    if Lc:  # lfm2: gated short convolution, no bias
+        Kc = cfg.conv_L_cache
+        layers["conv_in"] = init(kc[0], (Lc, D, 3 * D), D)
+        layers["conv_w"] = init(kc[1], (Lc, Kc, D), Kc)
+        layers["conv_out"] = init(kc[2], (Lc, D, D), D)
     if cfg.attention_bias:  # qwen2: bias on q/k/v, none on o
         layers["bq"] = init(ks[9], (L, H * hd), H * hd)
         layers["bk"] = init(ks[10], (L, Hkv * hd), Hkv * hd)
         layers["bv"] = init(ks[11], (L, Hkv * hd), Hkv * hd)
     if cfg.qk_norm:  # qwen3: per-head q/k RMSNorm
-        layers["q_norm"] = jnp.ones((L, hd), dt)
-        layers["k_norm"] = jnp.ones((L, hd), dt)
+        layers["q_norm"] = jnp.ones((La, hd), dt)
+        layers["k_norm"] = jnp.ones((La, hd), dt)
     if cfg.post_norms:  # gemma2: norms on block outputs too
         init_norm = jnp.zeros if cfg.rms_norm_offset else jnp.ones
         layers["post_attn_norm"] = init_norm((L, D), dt)
         layers["post_ffn_norm"] = init_norm((L, D), dt)
     if cfg.is_moe:
         E, Ie = cfg.num_experts, cfg.expert_intermediate_size
-        layers["router"] = init(ks[12], (L, D, E), D)
-        layers["w_gate"] = init(ks[5], (L, E, D, Ie), D)
-        layers["w_up"] = init(ks[6], (L, E, D, Ie), D)
-        layers["w_down"] = init(ks[7], (L, E, Ie, D), Ie)
+        layers["router"] = init(ks[12], (Lr, D, E), D)
+        layers["w_gate"] = init(ks[5], (Lr, E, D, Ie), D)
+        layers["w_up"] = init(ks[6], (Lr, E, D, Ie), D)
+        layers["w_down"] = init(ks[7], (Lr, E, Ie, D), Ie)
+        if cfg.use_expert_bias:  # lfm2: chooses the experts, weighs nothing
+            layers["router_bias"] = ROUTER_BIAS_SCALE * jax.random.normal(
+                kc[3], (Lr, E), jnp.float32
+            )
+        if Ld:  # lfm2: dense feed-forwards ahead of the routed ones
+            layers["dense_gate"] = init(kc[4], (Ld, D, I), D)
+            kd = jax.random.split(kc[4])
+            layers["dense_up"] = init(kd[0], (Ld, D, I), D)
+            layers["dense_down"] = init(kd[1], (Ld, I, D), I)
         if cfg.shared_expert_intermediate_size:  # qwen2_moe
             Is = cfg.shared_expert_intermediate_size
             layers["shared_gate"] = init(ks[13], (L, D, Is), D)
@@ -147,6 +184,13 @@ def param_shardings(
         "wo": P(None, tp_axis, None),
         "mlp_norm": P(None, None),
     }
+    if cfg.num_conv_layers:
+        # Replicated: the three-way split of ``conv_in``'s columns and the
+        # per-channel taps do not fall on a tp shard's boundaries. 17 M
+        # parameters a layer, no collective.
+        layers["conv_in"] = P(None, None, None)
+        layers["conv_w"] = P(None, None, None)
+        layers["conv_out"] = P(None, None, None)
     if cfg.attention_bias:
         layers["bq"] = P(None, tp_axis)
         layers["bk"] = P(None, tp_axis)
@@ -157,6 +201,12 @@ def param_shardings(
     if cfg.post_norms:
         layers["post_attn_norm"] = P(None, None)
         layers["post_ffn_norm"] = P(None, None)
+    if cfg.is_moe and cfg.use_expert_bias:
+        layers["router_bias"] = P(None, None)
+    if cfg.is_moe and cfg.num_routed_layers < cfg.num_layers:
+        layers["dense_gate"] = P(None, None, tp_axis)
+        layers["dense_up"] = P(None, None, tp_axis)
+        layers["dense_down"] = P(None, tp_axis, None)
     if cfg.is_moe:
         # Replicated router; every expert's FFN tp-sharded on the ffn
         # dim (same layout as the dense path, so MoE composes with the
@@ -198,7 +248,9 @@ def init_kv_cache(
     cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
     shardings=(None, None),
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Allocate the paged KV pools: each [L, num_pages, ps, Hkv*D].
+    """Allocate the paged KV pools: each [L, num_pages, ps, Hkv*D], L
+    the layers that write K and V (``cfg.num_attn_layers``: all of them
+    but for a model with layers of other kinds).
 
     (kv head, head_dim) live collapsed in the trailing dim: TPU tiling
     pads the last dim to 128 lanes, so a bare D=64 axis would double
@@ -209,7 +261,7 @@ def init_kv_cache(
     """
     dt = dtype or _dtype(cfg)
     shape = (
-        cfg.num_layers,
+        cfg.num_attn_layers,
         num_pages,
         page_size,
         cfg.num_kv_heads * cfg.head_dim_,
@@ -217,6 +269,22 @@ def init_kv_cache(
     return (
         jnp.zeros(shape, dt, device=shardings[0]),
         jnp.zeros(shape, dt, device=shardings[1]),
+    )
+
+
+def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
+    """The pool of what a sequence carries from token to token beside its
+    pages: ``[L_conv, entries, conv_L_cache - 1, D]``, entry ``e`` holding
+    for each convolution layer the products ``z`` of the last tokens that
+    layer saw of the sequence bound to ``e`` (``short_conv``). None for a
+    model whose sequences hold pages alone. Zeros are what a sequence
+    starts from, though no forward relies on it: positions 0 and 1 mask
+    what lies before the sequence's start."""
+    if not cfg.has_seq_state:
+        return None
+    return jnp.zeros(
+        (cfg.num_conv_layers, entries, cfg.conv_L_cache - 1, cfg.hidden_size),
+        dtype or _dtype(cfg), device=sharding,
     )
 
 
@@ -273,10 +341,18 @@ def _attn_mlp_layer(
     for a profiler trace (docs/observability.md "Engine phases and the
     device trace") and change no operation.
     """
+    red = reduce if reduce is not None else (lambda y: y)
+    x, kv_extra = _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red)
+    x = _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret)
+    return x, kv_extra
+
+
+def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red):
+    """A layer's first half where its operator is attention: norm,
+    projections, rope, ``attend``, output projection, residual add."""
     B, T = x.shape[:2]
     hd = cfg.head_dim_
     off = cfg.rms_norm_offset
-    red = reduce if reduce is not None else (lambda y: y)
     with jax.named_scope("qkv"):
         h = rms_norm(x, lp["attn_norm"], eps, off)
         q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
@@ -297,9 +373,80 @@ def _attn_mlp_layer(
         if "post_attn_norm" in lp:  # gemma2: norm the block OUTPUT too
             attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps, off)
         x = x + attn_out
-    with jax.named_scope("moe" if "router" in lp else "ffn"):
-        x = _ffn_block(x, lp, cfg, eps, red, mesh, live, layer, interpret)
     return x, kv_extra
+
+
+class SeqStateIO(NamedTuple):
+    """Where the rows of one forward keep what their sequences carry from
+    token to token (``init_seq_state``'s pool), as the forward's flat
+    token stream sees it. Tokens of a row lie in order; a token whose
+    predecessor in the stream is its row's previous position reads that
+    token's products, any other reads its row's pool entry.
+
+    ``src``/``dst`` are the writes: the state as it stands after stream
+    token ``src[w]`` goes to pool entry ``dst[w]``. A ``dst`` past the
+    pool is no write (a padding row, a row with no token). The engine
+    writes each row's own entry and, for a prefill row, a second entry a
+    later prefix hit may restore from (``engine/kv_manager.py``)."""
+
+    slots: jnp.ndarray  # [R] int32: the pool entry each row reads
+    row_of: jnp.ndarray  # [N] int32: each stream token's row
+    positions: jnp.ndarray  # [N] int32: its position, -1 = padding
+    src: jnp.ndarray  # [W] int32: stream index of a token
+    dst: jnp.ndarray  # [W] int32: pool entry the state after it goes to
+
+
+def short_conv(u, lp, pool, layer, io: SeqStateIO):
+    """LFM2's gated short convolution over a flat token stream, with the
+    carried state read from and written to ``pool[layer]``.
+
+    ``[B, C, X] = split3(u @ conv_in)``; ``z = B * X``; ``c_t = sum_j
+    conv_w[j] * z_{t-(K-1)+j}`` (depthwise, causal, zeros before the
+    sequence's start); returns ``(C * c) @ conv_out`` ([N, D]) and the
+    pool. ``z_{t-j}`` is the stream's where token ``t-j`` of the same row
+    is in it and the row's entry otherwise; an entry holds the ``K-1``
+    products that end at the last token its row has seen, oldest first.
+    Plain ``jax.numpy``: three taps over D channels are a fused
+    multiply-add between two matmuls."""
+    N, D = u.shape
+    taps = lp["conv_w"]  # [K, D]; taps[K-1] weighs z_t
+    K = taps.shape[0]
+    gate_b, gate_c, xin = jnp.split(u @ lp["conv_in"], 3, axis=-1)
+    z = gate_b * xin  # [N, D]
+    pos = io.positions
+    # Whether stream token i-1 is token i's predecessor in its row.
+    prev = jnp.concatenate([
+        jnp.zeros((1,), bool),
+        (io.row_of[1:] == io.row_of[:-1])
+        & (pos[1:] == pos[:-1] + 1) & (pos[:-1] >= 0),
+    ])[:, None]
+    held = pool[layer][io.slots[io.row_of]]  # [N, K-1, D], oldest first
+    acc = taps[K - 1] * z
+    back = [z]  # back[j] = z_{t-j} for each stream token t
+    for j in range(1, K):
+        shifted = jnp.concatenate([jnp.zeros((1, D), z.dtype), back[-1][:-1]])
+        zj = jnp.where(prev, shifted, held[:, K - 1 - j])
+        zj = jnp.where((pos >= j)[:, None], zj, 0)  # before the start
+        acc = acc + taps[K - 1 - j] * zj
+        back.append(zj)
+    y = (gate_c * acc) @ lp["conv_out"]
+    new = jnp.stack([back[K - 2 - i][io.src] for i in range(K - 1)], axis=1)
+    pool = pool.at[layer, io.dst].set(new.astype(pool.dtype), mode="drop")
+    return y, pool
+
+
+def _conv_half(x, lp, cfg, eps, pool, layer, io):
+    """A layer's first half where its operator is the short convolution:
+    norm, ``short_conv`` on the flattened rows, residual add."""
+    with jax.named_scope("short_conv"):
+        h = rms_norm(x, lp["attn_norm"], eps, cfg.rms_norm_offset)
+        y, pool = short_conv(h.reshape(-1, h.shape[-1]), lp, pool, layer, io)
+        return x + y.reshape(x.shape), pool
+
+
+def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret):
+    with jax.named_scope("moe" if "router" in lp else "ffn"):
+        return _ffn_block(x, lp, cfg, eps, red, mesh, live, layer, interpret)
 
 
 def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=False):
@@ -331,6 +478,11 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
             )[..., None]
             shared = (blend * s_out.astype(jnp.float32)).astype(x.dtype)
         if _expert_parallel(mesh):
+            if cfg.router_score != "softmax":
+                raise NotImplementedError(
+                    "experts sharded over ep route by softmax alone "
+                    f"(moe_ffn_ep); {cfg.model_type} routes by {cfg.router_score}"
+                )
             # Experts sharded over the mesh's ep axis (shard_map path);
             # the psum inside covers both ep and tp, so no outer reduce
             # (the shared expert stays on the GSPMD path).
@@ -353,6 +505,8 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
                 cfg.norm_topk_prob,
                 live=None if live is None else live.reshape(B * T),
                 layer=layer, mesh=mesh, interpret=interpret,
+                score=cfg.router_score, select_bias=lp.get("router_bias"),
+                scale=cfg.routed_scaling_factor,
             ).reshape(B, T, -1)
             if shared is not None:
                 y = y + shared
@@ -455,75 +609,151 @@ def _per_layer_xs(cfg, inv_freq):
     return win_arr, invf_arr, jnp.arange(L, dtype=jnp.int32)
 
 
+# A layer's parameters by the kind that has them, each stacked over the
+# layers of that kind (``init_params``).
+_EVERY_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_ffn_norm")
+_OP_PARAMS = {
+    "attn": ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm"),
+    "conv": ("conv_in", "conv_w", "conv_out"),
+}
+_ROUTED_PARAMS = (
+    "router", "router_bias", "shared_gate", "shared_up", "shared_down",
+    "shared_router",
+)
+
+
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
-    live, interpret=False,
+    live, interpret=False, seq_state=None, state_io=None,
 ):
-    """The layer loop of both paged forwards: ``lax.scan`` carrying
-    ``(x, k_cache, v_cache)`` over the stacked parameters, the per-layer
-    window / rope base and the layer index. Returns (x, k_cache, v_cache).
+    """The layer loop of both paged forwards: one ``lax.scan`` for each
+    run of layers of one kind (``ModelConfig.layer_runs``), carrying
+    ``(x, k_cache, v_cache)`` and, where the model has convolution layers,
+    ``seq_state``. Returns (x, k_cache, v_cache, seq_state). A model of
+    one kind is one run over the stacked parameters, the per-layer window
+    / rope base and the layer index, as it always was.
 
-    Layer ``l`` writes its new K/V with one scatter into the whole
-    ``[L, P, ps, W]`` pool at ``[l, page, offset]`` (``write_at`` =
-    (page_ids, offsets, valid), the same for every layer), then reads
+    Attention layer ``l`` (counted among attention layers) writes its new
+    K/V with one scatter into the whole ``[L_attn, P, ps, W]`` pool at
+    ``[l, page, offset]`` (``write_at`` = (page_ids, offsets, valid), the
+    same for every layer), then reads
     without slicing the pool: ``read(q, k_flat, v_flat, table_l, win_l)``
-    gets the pools seen as one page axis ``[L*P, ps, W]`` (a bitcast)
+    gets the pools seen as one page axis ``[L_attn*P, ps, W]`` (a bitcast)
     and ``table`` moved to layer ``l``'s range. Every read path (the
     kernel's page DMAs, the XLA gather) takes a pool and page ids, so
     none needs to know the layer; a ``dynamic_slice`` of the layer's
     pool would be a copy of it. ``live`` ([B, T] bool) marks the rows
-    that are not padding, for the routed experts (``_ffn_block``).
+    that are not padding, for the routed experts (``_ffn_block``). A
+    convolution layer reads and writes ``seq_state`` the same way, at its
+    ordinal among convolution layers (``short_conv``; ``state_io``).
 
     A routed model's expert matrices (``EXPERT_STACKS``) leave ``xs`` for
     the same reason the pools left ``ys``: the body closes over the whole
-    stacks and ``moe_ffn(layer=l)`` addresses layer ``l`` in them. That
-    is where ``routed_experts_form`` says ``inplace`` for this forward's
-    rows. Everywhere else ``xs`` are all of ``params["layers"]``, as
-    ever: a dense model, experts sharded over ``ep`` (``moe_ffn_ep``),
-    and a forward whose expert matmuls take ``ragged_dot`` on the slice
-    (off the TPU, or more rows than ``ops/moe.py::IN_PLACE_MAX_ROWS``)."""
-    L, num_pages = k_cache.shape[:2]
-    flat = (L * num_pages, *k_cache.shape[2:])
+    stacks and ``moe_ffn(layer=l)`` addresses routed layer ``l`` in them.
+    That is where ``routed_experts_form`` says ``inplace`` for this
+    forward's rows. Everywhere else a one-run model's ``xs`` are all of
+    ``params["layers"]``, as ever: a dense model, experts sharded over
+    ``ep`` (``moe_ffn_ep``), and a forward whose expert matmuls take
+    ``ragged_dot`` on the slice (off the TPU, or more rows than
+    ``ops/moe.py::IN_PLACE_MAX_ROWS``).
+
+    A run that is not the whole of a stack closes over the stack and
+    indexes it by the layer's ordinal, which is what ``scan`` does with
+    ``xs``: a static slice handed over as ``xs`` would be a copy of the
+    run's parameters on every forward."""
+    L = cfg.num_layers
+    num_pages = k_cache.shape[1]
+    flat = (k_cache.shape[0] * num_pages, *k_cache.shape[2:])
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     eps = cfg.rms_norm_eps
     layers = params["layers"]
-    routed = "router" in layers and routed_experts_form(
+    in_place = "router" in layers and routed_experts_form(
         cfg, mesh, x.shape[0] * x.shape[1], interpret
     ) == "inplace"
-    stacks = {k: layers[k] for k in EXPERT_STACKS} if routed else {}
-    layers = {k: v for k, v in layers.items() if k not in stacks}
-
-    def layer(carry, layer_in):
-        x, k_pool, v_pool = carry
-        lp, win_l, invf_l, l = layer_in
-        lp = {**lp, **stacks}
-
-        def attend(q, k, v):
-            n = k.shape[0] * k.shape[1]  # this dispatch's new tokens
-            with jax.named_scope("kv_write"):
-                kp, vp = write_kv_pages(
-                    k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
-                    *write_at, layer=l,
-                )
-            with jax.named_scope("attend"):
-                attn = read(
-                    q, kp.reshape(flat), vp.reshape(flat),
-                    table + l * num_pages, win_l,
-                )
-            return attn, (kp, vp)
-
-        x, (k_pool, v_pool) = _attn_mlp_layer(
-            x, lp, cfg, invf_l, rope_pos, eps, attend, mesh=mesh, live=live,
-            layer=l if routed else None, interpret=interpret,
-        )
-        return (x, k_pool, v_pool), None
-
-    (x, k_cache, v_cache), _ = jax.lax.scan(
-        layer,
-        (x, k_cache, v_cache),
-        (layers, *_per_layer_xs(cfg, inv_freq)),
+    win_arr, invf_arr, _ = _per_layer_xs(cfg, inv_freq)
+    dense_names = (
+        ("dense_gate", "dense_up", "dense_down") if "dense_gate" in layers
+        else EXPERT_STACKS
     )
-    return x, k_cache, v_cache
+    red = lambda y: y  # noqa: E731 - GSPMD reduces; no explicit psum here
+    carry = (x, k_cache, v_cache, seq_state)
+
+    for op, ff, first, n, op0, ff0 in cfg.layer_runs:
+        # name in the body -> (stack, the run's first index in it)
+        source = {k: (layers[k], first) for k in _EVERY_LAYER if k in layers}
+        source.update(
+            (k, (layers[k], op0)) for k in _OP_PARAMS[op] if k in layers
+        )
+        if ff == "dense":
+            source.update(
+                (k, (layers[d], ff0)) for k, d in zip(EXPERT_STACKS, dense_names)
+            )
+        else:
+            source.update(
+                (k, (layers[k], ff0)) for k in _ROUTED_PARAMS if k in layers
+            )
+        # Expert matrices: whole, with the layer to read, where the matmuls
+        # read in place and wherever the run is not the whole stack
+        # (``moe_ffn`` then slices the layer it multiplies); else ``xs``.
+        stacks = {}
+        if ff == "routed":
+            whole = layers["w_gate"].shape[0] == n
+            if in_place or not whole:
+                stacks = {k: layers[k] for k in EXPERT_STACKS}
+            else:
+                source.update((k, (layers[k], ff0)) for k in EXPERT_STACKS)
+        xs = {k: a for k, (a, at) in source.items() if a.shape[0] == n}
+        closed = {k: v for k, v in source.items() if k not in xs}
+
+        def layer(carry, layer_in, op=op, op0=op0, ff0=ff0, stacks=stacks,
+                  closed=closed):
+            x, k_pool, v_pool, state = carry
+            lp, win_l, invf_l, i = layer_in
+            lp = {**lp, **stacks}
+            for name, (stack, at) in closed.items():
+                lp[name] = jax.lax.dynamic_index_in_dim(
+                    stack, at + i, 0, keepdims=False
+                )
+            l = i + op0 if op0 else i  # ordinal among layers of this operator
+            if op == "conv":
+                x, state = _conv_half(x, lp, cfg, eps, state, l, state_io)
+            else:
+
+                def attend(q, k, v):
+                    n = k.shape[0] * k.shape[1]  # this dispatch's new tokens
+                    with jax.named_scope("kv_write"):
+                        kp, vp = write_kv_pages(
+                            k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
+                            *write_at, layer=l,
+                        )
+                    with jax.named_scope("attend"):
+                        attn = read(
+                            q, kp.reshape(flat), vp.reshape(flat),
+                            table + l * num_pages, win_l,
+                        )
+                    return attn, (kp, vp)
+
+                x, (k_pool, v_pool) = _attn_half(
+                    x, lp, cfg, invf_l, rope_pos, eps, attend, red
+                )
+            x = _ffn_half(
+                x, lp, cfg, eps, red, mesh, live,
+                (i + ff0 if ff0 else i) if stacks else None, interpret,
+            )
+            return (x, k_pool, v_pool, state), None
+
+        whole_model = n == L
+        carry, _ = jax.lax.scan(
+            layer,
+            carry,
+            (
+                xs,
+                win_arr if whole_model else win_arr[first : first + n],
+                invf_arr if whole_model else invf_arr[first : first + n],
+                jnp.arange(n, dtype=jnp.int32),
+            ),
+        )
+    return carry
 
 
 def forward(
@@ -541,11 +771,20 @@ def forward(
     interpret: bool = False,
     last_positions: jnp.ndarray | None = None,
     token_embeds: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    seq_state: jnp.ndarray | None = None,
+    state_slots: jnp.ndarray | None = None,
+) -> tuple[jnp.ndarray, ...]:
     """One forward step (prefill or decode by bucket shape).
 
     Writes new K/V into the paged pools, attends, and returns
     (logits [B, T, V] float32, new_k_cache, new_v_cache).
+
+    A model whose sequences carry state beside their pages
+    (``init_seq_state``) also takes that pool as ``seq_state`` and each
+    row's entry in it as ``state_slots`` ([B] int32), and returns the pool
+    fourth: every row with a token reads its entry and leaves the state
+    after its last token there. Rows' real tokens come first (``t`` such
+    that ``positions[b, t] >= 0`` are ``0..n_b-1``).
 
     ``last_positions`` ([B] int32, in-chunk index of each row's last real
     token) gathers one hidden state per row before the vocab projection,
@@ -628,15 +867,28 @@ def forward(
             softcap=cfg.attn_logit_softcap,
         )
 
-    x, k_cache, v_cache = _scan_paged_layers(
+    state_io = None
+    if seq_state is not None:
+        n_real = jnp.sum(positions >= 0, axis=1).astype(jnp.int32)  # [B]
+        state_io = SeqStateIO(
+            slots=state_slots,
+            row_of=batch_idx,
+            positions=flat_pos,
+            src=jnp.arange(B, dtype=jnp.int32) * T + jnp.maximum(n_real - 1, 0),
+            dst=jnp.where(n_real > 0, state_slots, seq_state.shape[1]),
+        )
+    x, k_cache, v_cache, seq_state = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid),
         page_table if use_pallas else attn_table, read, mesh,
-        positions >= 0, interpret,
+        positions >= 0, interpret, seq_state, state_io,
     )
     if last_positions is not None:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
-    return _final_logits(params, cfg, x, eps), k_cache, v_cache
+    logits = _final_logits(params, cfg, x, eps)
+    if seq_state is None:
+        return logits, k_cache, v_cache
+    return logits, k_cache, v_cache, seq_state
 
 
 def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
@@ -737,9 +989,16 @@ def forward_ragged(
     q_tile: int = 8,
     mesh=None,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    seq_state: jnp.ndarray | None = None,
+    state_rows: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None = None,
+) -> tuple[jnp.ndarray, ...]:
     """One ragged forward over a flat mixed batch (the single-dispatch
     prefill+decode+spec path, docs/engine_perf.md).
+
+    With ``seq_state`` (``init_seq_state``; returned fourth) the rows'
+    sequences carry state beside their pages: ``state_rows`` = (the pool
+    entry each row reads [R], and the writes ``src``/``dst`` [W] of
+    :class:`SeqStateIO`).
 
     Every non-attention op is per-token, so the whole transformer runs
     on the flattened ``[N]`` stream — chunked-prefill rows, decode rows,
@@ -814,13 +1073,20 @@ def forward_ragged(
             softcap=cfg.attn_logit_softcap,
         )[None]
 
-    x, k_cache, v_cache = _scan_paged_layers(
+    state_io = None
+    if seq_state is not None:
+        slots, src, dst = state_rows
+        state_io = SeqStateIO(slots, row_of, positions, src, dst)
+    x, k_cache, v_cache, seq_state = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid), attn_table, read, mesh,
-        (positions >= 0)[None], interpret,
+        (positions >= 0)[None], interpret, seq_state, state_io,
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
-    return _final_logits(params, cfg, xo, eps), k_cache, v_cache
+    logits = _final_logits(params, cfg, xo, eps)
+    if seq_state is None:
+        return logits, k_cache, v_cache
+    return logits, k_cache, v_cache, seq_state
 
 
 def forward_ring_prefill(

@@ -36,10 +36,69 @@ def _open_safetensors(path: str):
     return handles, index
 
 
+def _host_params(params: dict, dt) -> dict:
+    """Host arrays of the model's dtype (``router_bias`` stays float32)."""
+    def host(path, x):
+        return np.asarray(x).astype(np.float32 if path == "router_bias" else dt)
+
+    out = {k: host(k, v) for k, v in params.items() if k != "layers"}
+    out["layers"] = {k: host(k, v) for k, v in params["layers"].items()}
+    return out
+
+
+def _lfm2_moe_params(cfg: ModelConfig, get, linear) -> dict:
+    """LiquidAI's ``lfm2_moe`` tensor names into the stacks by kind of
+    ``models/llama.py::init_params``: ``operator_norm`` / ``ffn_norm``
+    over every layer; ``self_attn.{q,k,v,out}_proj`` and
+    ``{q,k}_layernorm`` over the attention layers; ``conv.in_proj``
+    ([3D, D]), ``conv.conv`` ([D, 1, K] depthwise) and ``conv.out_proj``
+    over the convolution layers; ``feed_forward.{w1,w3,w2}`` (gate, up,
+    down) over the dense layers; ``feed_forward.gate``, ``expert_bias``
+    and ``experts.N.{w1,w3,w2}`` over the routed ones. The head is tied
+    to ``embed_tokens``; the final norm is ``embedding_norm``."""
+    names = (
+        "attn_norm", "mlp_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
+        "conv_in", "conv_w", "conv_out", "dense_gate", "dense_up", "dense_down",
+        "router", "router_bias", "w_gate", "w_up", "w_down",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    for i, (op, ff) in enumerate(zip(cfg.op_kinds, cfg.ff_kinds)):
+        p = f"model.layers.{i}."
+        layers["attn_norm"].append(get(p + "operator_norm.weight"))
+        layers["mlp_norm"].append(get(p + "ffn_norm.weight"))
+        if op == "conv":
+            layers["conv_in"].append(linear(p + "conv.in_proj.weight"))
+            layers["conv_w"].append(get(p + "conv.conv.weight")[:, 0, :].T)  # [K, D]
+            layers["conv_out"].append(linear(p + "conv.out_proj.weight"))
+        else:
+            a = p + "self_attn."
+            for key, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "out_proj")):
+                layers[key].append(linear(f"{a}{name}.weight"))
+            layers["q_norm"].append(get(a + "q_layernorm.weight"))
+            layers["k_norm"].append(get(a + "k_layernorm.weight"))
+        f = p + "feed_forward."
+        if ff == "dense":
+            for key, name in (("dense_gate", "w1"), ("dense_up", "w3"), ("dense_down", "w2")):
+                layers[key].append(linear(f"{f}{name}.weight"))
+        else:
+            layers["router"].append(linear(f + "gate.weight"))
+            if cfg.use_expert_bias:
+                layers["router_bias"].append(get(f + "expert_bias"))
+            for key, name in (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2")):
+                layers[key].append(np.stack([
+                    linear(f"{f}experts.{e}.{name}.weight") for e in range(cfg.num_experts)
+                ]))
+    return {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items() if v},
+        "final_norm": get("model.embedding_norm.weight"),
+    }
+
+
 SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
-    "mixtral", "qwen2_moe", "qwen3_moe",
+    "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe",
 )
 
 
@@ -72,6 +131,10 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         return get(name).T
 
     pre = "model."
+    if cfg.model_type == "lfm2_moe":
+        params = _lfm2_moe_params(cfg, get, linear)
+        handles.clear()
+        return _host_params(params, dt), cfg
     L = cfg.num_layers
     keys = ["attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
             "w_gate", "w_up", "w_down"]

@@ -61,6 +61,17 @@ def write_config(path: str, cfg: ModelConfig) -> None:
         # from the dataclass default: spell it so the round trip holds.
         "norm_topk_prob": cfg.norm_topk_prob,
     }
+    if cfg.model_type == "lfm2_moe":  # the family's own keys
+        del hf["rms_norm_eps"]
+        hf.update(
+            architectures=["Lfm2MoeForCausalLM"], norm_eps=cfg.rms_norm_eps,
+            layer_types=list(cfg.layer_types), num_dense_layers=cfg.num_dense_layers,
+            num_experts=cfg.num_experts, num_experts_per_tok=cfg.num_experts_per_tok,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            conv_L_cache=cfg.conv_L_cache, conv_bias=False,
+            use_expert_bias=cfg.use_expert_bias,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+        )
     if cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -69,7 +80,7 @@ def write_config(path: str, cfg: ModelConfig) -> None:
         raise ValueError(
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
-            "the llama family only"
+            "the llama family and lfm2_moe only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

@@ -57,18 +57,40 @@ def moe_router(
     router_w: jnp.ndarray,  # [D, E]
     num_experts_per_tok: int,
     norm_topk_prob: bool = True,
+    *,
+    score: str = "softmax",
+    select_bias: jnp.ndarray | None = None,  # [E] float32
+    scale: float = 1.0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k routing. Returns (weights [N, K] float32, expert ids [N, K]).
 
-    Softmax over ALL experts first, then top-k (Mixtral order); with
-    ``norm_topk_prob`` the selected weights are renormalised to sum to 1.
+    The rule is the model's (``ModelConfig.router_score``), in float32:
+
+    - ``"softmax"``: softmax over ALL experts first, then top-k (Mixtral
+      order); with ``norm_topk_prob`` the selected weights are
+      renormalised to sum to 1.
+    - ``"sigmoid"`` (lfm2_moe): ``s = sigmoid(logits)``; the experts are
+      the top-k of ``s + select_bias`` and their weights are ``s`` there,
+      so the bias chooses and the unbiased score weighs; with
+      ``norm_topk_prob`` the weights are divided by their sum plus 1e-6
+      (the published form), then multiplied by ``scale``.
     """
     logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, ids = jax.lax.top_k(probs, num_experts_per_tok)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, ids = jax.lax.top_k(probs, num_experts_per_tok)
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return weights, ids.astype(jnp.int32)
+    if score != "sigmoid":
+        raise ValueError(f"unknown router score {score!r}")
+    s = jax.nn.sigmoid(logits)
+    chosen_by = s if select_bias is None else s + select_bias.astype(jnp.float32)
+    _, ids = jax.lax.top_k(chosen_by, num_experts_per_tok)
+    weights = jnp.take_along_axis(s, ids, axis=-1)
     if norm_topk_prob:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return weights, ids.astype(jnp.int32)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return weights * scale, ids.astype(jnp.int32)
 
 
 # The most sorted rows (tokens x experts a token) of one call that take
@@ -124,6 +146,9 @@ def moe_ffn(
     layer: jnp.ndarray | None = None,  # scalar: which layer of stacked weights
     mesh=None,
     interpret: bool = False,
+    score: str = "softmax",
+    select_bias: jnp.ndarray | None = None,  # [E]: moe_router's, one layer's
+    scale: float = 1.0,
 ) -> jnp.ndarray:
     """SwiGLU expert FFN with exact top-k dispatch. Returns [N, D].
 
@@ -153,10 +178,10 @@ def moe_ffn(
     experts = functools.partial(
         _routed_experts,
         num_experts_per_tok=num_experts_per_tok, norm_topk_prob=norm_topk_prob,
-        kernel=kernel, interpret=interpret,
+        kernel=kernel, interpret=interpret, score=score, scale=scale,
     )
     if not (kernel and tp > 1):
-        y = experts(x, router_w, w_gate, w_up, w_down, live, layer)
+        y = experts(x, router_w, w_gate, w_up, w_down, live, layer, select_bias)
         return y.astype(x.dtype)
 
     from ..parallel.mesh import shard_map
@@ -168,29 +193,37 @@ def moe_ffn(
         mesh=mesh,
         in_specs=(
             P(), P(), P(*lead, None, "tp"), P(*lead, None, "tp"),
-            P(*lead, "tp", None), P(), P(),
+            P(*lead, "tp", None), P(), P(), P(),
         ),
         out_specs=P(),
         check_vma=False,
     )
-    def sharded(x_l, router_l, wg, wu, wd, live_l, layer_l):
+    def sharded(x_l, router_l, wg, wu, wd, live_l, layer_l, bias_l):
         # Each chip holds its columns of the experts' width: the sum over
         # chips of what w_down's rows give is the whole product.
-        return jax.lax.psum(experts(x_l, router_l, wg, wu, wd, live_l, layer_l), "tp")
+        return jax.lax.psum(
+            experts(x_l, router_l, wg, wu, wd, live_l, layer_l, bias_l), "tp"
+        )
 
-    return sharded(x, router_w, w_gate, w_up, w_down, live, layer).astype(x.dtype)
+    return sharded(
+        x, router_w, w_gate, w_up, w_down, live, layer, select_bias
+    ).astype(x.dtype)
 
 
 def _routed_experts(
-    x, router_w, w_gate, w_up, w_down, live, layer, *,
+    x, router_w, w_gate, w_up, w_down, live, layer, select_bias=None, *,
     num_experts_per_tok, norm_topk_prob, kernel, interpret,
+    score="softmax", scale=1.0,
 ):
     """``moe_ffn``'s arithmetic on the weights one device holds: [N, D]
     float32, before the sum over ``tp`` where the width is sharded."""
     N, D = x.shape
     E = router_w.shape[-1]
     K = num_experts_per_tok
-    weights, ids = moe_router(x, router_w, K, norm_topk_prob)
+    weights, ids = moe_router(
+        x, router_w, K, norm_topk_prob,
+        score=score, select_bias=select_bias, scale=scale,
+    )
     if live is not None:
         ids = jnp.where(live[:, None], ids, E)  # past every group
         weights = jnp.where(live[:, None], weights, 0.0)

@@ -472,7 +472,19 @@ async def run_worker(opts, drt, core, tpu_engine, mdc=None):
         metadata[TOPOLOGY_KEY] = topo.encode()
     migrate_rx = None
     migrate_sink = None
-    if tpu_engine is not None and tpu_engine.kv.sharing:
+    if tpu_engine is not None and tpu_engine.cfg.model.has_seq_state:
+        # Migration ships pages alone (engine/config.py::
+        # refuse_seq_state_tiers): this worker serves, and a reclaim
+        # notice drains it like any SIGTERM.
+        logger.warning(
+            "model_type %s keeps per-sequence state beside its KV pages: "
+            "live migration is not armed for this worker",
+            tpu_engine.cfg.model.model_type,
+        )
+        migrates = False
+    else:
+        migrates = tpu_engine is not None
+    if migrates and tpu_engine.kv.sharing:
         from .disagg.transfer import KvPageReceiver
 
         migrate_rx = KvPageReceiver()
@@ -482,7 +494,7 @@ async def run_worker(opts, drt, core, tpu_engine, mdc=None):
     served = await ep.serve_endpoint(
         handler, stats_handler=metrics_pub.stats_handler, metadata=metadata
     )
-    if tpu_engine is not None:
+    if migrates:
 
         async def _survivors():
             infos = await drt.discovery.list_instances(ep.component.path)

@@ -65,6 +65,14 @@ MIGRATE = "migrate"
 FAILOVER = "failover"
 
 
+def _refuse_seq_state(engine) -> None:
+    """Migration ships pages alone. Imported here: this module is host
+    code and the engine's package brings JAX."""
+    from ..engine.config import refuse_seq_state_tiers
+
+    refuse_seq_state_tiers(getattr(engine, "cfg", None), "migration")
+
+
 def _env_margin(default: float = DEFAULT_SAFETY_MARGIN_S) -> float:
     raw = os.environ.get("DYN_RECLAIM_MARGIN_S", "").strip()
     if not raw:
@@ -277,6 +285,7 @@ class ReclaimController:
         est_fn: Callable[[str, str, int], float | None] | None = None,
         clock: Callable[[], float] = time.monotonic,
     ):
+        _refuse_seq_state(engine)
         self.engine = engine
         self.instance = instance or get_telemetry().instance
         self.topology = (
@@ -420,6 +429,7 @@ class MigrationSink:
     cache with the shipped blocks."""
 
     def __init__(self, engine, receiver):
+        _refuse_seq_state(engine)
         self.engine = engine
         self.receiver = receiver
         self.transfers = 0

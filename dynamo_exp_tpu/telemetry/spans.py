@@ -232,6 +232,34 @@ class Telemetry:
             ["experts"],  # inplace | sliced
             registry=self.registry,
         )
+        # Sequence state beside the pages (a model with layers that are
+        # not attention: lfm2_moe's short convolution; all zero for any
+        # other model). Host counts from the page manager and the
+        # admission path.
+        self.seq_state_slots = Gauge(
+            "dynamo_seq_state_slots_in_use",
+            "Entries of the sequence-state pool bound to a sequence "
+            "(one a decode slot holder; 0 for a model without such state)",
+            registry=self.registry,
+        )
+        self.seq_state_restores = Counter(
+            "dynamo_seq_state_restores_total",
+            "Sequence states copied from a kept snapshot into an admitted "
+            "sequence's entry, by why the sequence had tokens cached: "
+            "prefix (another request's pages) or resume (its own, after a "
+            "preemption)",
+            ["cause"],  # prefix | resume
+            registry=self.registry,
+        )
+        self.seq_state_prefix_hits = Counter(
+            "dynamo_seq_state_prefix_hits_total",
+            "Admissions of a model with sequence state whose prompt matched "
+            "cached pages, by what the match came to: restored (taken as "
+            "deep as it went), shortened (to the deepest block boundary a "
+            "state was kept for) or not_taken (none was)",
+            ["outcome"],  # restored | shortened | not_taken
+            registry=self.registry,
+        )
         self.decode_wasted_steps = Counter(
             "dynamo_decode_wasted_steps_total",
             "Decode window steps computed for a row past its stop point",
@@ -813,6 +841,8 @@ class Telemetry:
             # Standalone gauge (not dynamo_engine_*-prefixed): the
             # fleet-wide prefix-sharing headline series.
             self.kv_shared_pages.set(float(metrics["kv_shared_pages"]))
+        if "seq_state_slots_in_use" in metrics:
+            self.seq_state_slots.set(float(metrics["seq_state_slots_in_use"]))
 
     def render(self) -> bytes:
         from prometheus_client import generate_latest

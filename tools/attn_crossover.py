@@ -74,6 +74,8 @@ def live_window_args(engine, key: tuple, contexts: list[int], rng) -> tuple[list
         table[i, :n] = order[(at + np.arange(n)) % len(order)]
         at += n
     args[6] = table
+    if engine.seq_state is not None:  # each live row its own state entry
+        args[-1] = (np.arange(nb) % cfg.max_decode_slots).astype(np.int32)
     return args, at
 
 
@@ -82,10 +84,16 @@ def time_program(engine, key: tuple, args: list, calls: int) -> list[float]:
 
     fn = engine._ragged_fn_from_key(key)
     out = []
+    has_state = engine.seq_state is not None  # the pool rides second to last, donated
     for i in range(calls + 2):
         args[0], args[1], args[2] = engine.params, engine.k_cache, engine.v_cache
+        if has_state:
+            args[-2] = engine.seq_state
         t0 = time.perf_counter()
-        ys, engine.k_cache, engine.v_cache, _t, _p = fn(*args)
+        res = fn(*args)
+        if has_state:
+            *res, engine.seq_state = res
+        ys, engine.k_cache, engine.v_cache, _t, _p = res
         jax.block_until_ready((ys, engine.k_cache))
         if i >= 2:  # the first call compiles, the second loads
             out.append(time.perf_counter() - t0)
