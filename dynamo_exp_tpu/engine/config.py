@@ -417,7 +417,12 @@ class EngineConfig:
 
         ``mixed`` batches floor at 16 tokens: a short prefill tail or a
         draft span costs one 16-wide forward either way, and the floor
-        keeps transient small shapes from fragmenting the lattice.
+        keeps transient small shapes from fragmenting the lattice. On a
+        kernel engine ``n`` is the laid-out extent, not the token count:
+        spans of several tokens padded to ``ragged_q_tile``, decode rows
+        one slot each in the bucket's packed tail
+        (:meth:`ragged_packed_slots`; ``engine._mixed_bucket_for`` picks the
+        bucket that holds both).
         Windowed (pure-decode) batches floor at 1 — so decode cost
         keeps tracking occupancy exactly — and cap at
         ``max_decode_slots`` (a non-power-of-two slot envelope must not
@@ -425,6 +430,23 @@ class EngineConfig:
         if mixed:
             return self._pow2_bucket(n, 16, self.ragged_max_tokens)
         return self._pow2_bucket(n, 1, self.max_decode_slots)
+
+    def ragged_packed_slots(self, nb: int) -> int:
+        """How many slots at the end of a kernel engine's mixed stream of
+        ``nb`` are read one query a grid cell (the form a decode window
+        uses) and not a tile of ``ragged_q_tile`` a row: where a decode
+        row's single token costs one slot, not eight. A function of
+        ``nb`` and this configuration alone, so the split is a static
+        part of the program keyed by ``nb`` and the variant lattice has
+        no second axis: ``min(max_decode_slots, nb // 4)``, widened
+        until the tiled part before it is whole tiles (8 of 16, 8 of 32,
+        16 of 64, 32 of 128, then 64 of every larger bucket at 64
+        slots). A quarter because a dispatch that has more decode rows
+        than that is nearly a window, and a prompt chunk keeps three
+        quarters of its bucket in the cheaper tiled form."""
+        tile = self.ragged_q_tile
+        tiled = (nb - min(self.max_decode_slots, nb // 4)) // tile * tile
+        return nb - tiled
 
     def ragged_page_bucket_for(self, n_pages: int) -> int:
         """Static page bound of a ragged dispatch's XLA attention
@@ -450,10 +472,12 @@ class EngineConfig:
         n = self.max_decode_slots * (per_row + self.ragged_q_tile - 1)
         return self._pow2_bucket(n, 1)
 
-    # Flat-stream alignment of each row's query span when the Pallas
-    # ragged kernel serves the dispatch: every kernel grid cell must
-    # belong to exactly one row (ops/ragged_attention.py). The XLA
-    # reference path packs tight (alignment 1).
+    # Flat-stream alignment of a row's span of several query tokens (a
+    # prefill chunk, a draft span) when the Pallas ragged kernel serves
+    # the dispatch: every kernel grid cell must belong to exactly one
+    # row (ops/ragged_attention.py). Decode rows go one slot each in the
+    # stream's packed tail (ragged_packed_slots); the XLA reference
+    # path packs everything tight (alignment 1).
     ragged_q_tile: int = 8
 
     def page_move_bucket_for(self, n: int) -> int:

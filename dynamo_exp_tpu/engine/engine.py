@@ -853,6 +853,10 @@ class TPUEngine(AsyncEngine):
         spec_on = with_spec
         T_s = self.cfg.spec_max_draft + 1
         q_tile = self._ragged_align()
+        # The stream's packed tail (_decode_from): static, from nb alone.
+        packed_from = (
+            nb - self.cfg.ragged_packed_slots(nb) if impl == "pallas" else None
+        )
 
         has_state = mcfg.has_seq_state  # as in _windowed_program
 
@@ -861,8 +865,8 @@ class TPUEngine(AsyncEngine):
             logits, k, v, *state = forward_ragged(
                 params, mcfg, tokens, positions, row_of, page_table,
                 k, v, out_idx, attn_pages=pages, attn_impl=impl,
-                q_tile=q_tile, mesh=mesh, interpret=interpret,
-                seq_state=state, state_rows=state_rows,
+                q_tile=q_tile, packed_from=packed_from, mesh=mesh,
+                interpret=interpret, seq_state=state, state_rows=state_rows,
             )
             return logits, k, v, (state[0] if state else None)
 
@@ -972,10 +976,45 @@ class TPUEngine(AsyncEngine):
         return ragged_mixed
 
     def _ragged_align(self) -> int:
-        """Flat-stream alignment of each row's query span: the Pallas
-        ragged kernel requires every ``ragged_q_tile`` slice to belong
-        to one row; the XLA reference packs tight."""
+        """Flat-stream alignment of a row's span of several query tokens
+        (a prefill chunk, a draft span): the Pallas ragged kernel
+        requires every ``ragged_q_tile`` slice of the stream's tiled
+        part to belong to one row; the XLA reference packs tight. A
+        decode row's one token is not such a span (``_mixed_bucket_for``)."""
         return self.cfg.ragged_q_tile if self._attn_impl == "pallas" else 1
+
+    def _mixed_bucket_for(self, aligned: int, singles: int) -> int:
+        """Token bucket of a mixed dispatch, given the extent of its
+        spans of several tokens (``aligned``: each already padded to
+        ``_ragged_align()``) and how many decode rows it carries
+        (``singles``, one token each).
+
+        The reference packs tight: the decode rows follow the spans.
+        A kernel engine's stream is two static parts (docs/engine_perf.md
+        "One ragged dispatch"): tiles of ``ragged_q_tile`` slots, one
+        row a tile, then ``cfg.ragged_packed_slots(nb)`` slots read one
+        query a cell. The decode rows go side by side into the second
+        part, one slot each (``_decode_from``); spans that outgrow the
+        first part run on into the second (a cell there serves any one
+        token, a padding slot included) and the decode rows follow them.
+        The bucket is the smallest whose second part holds every decode
+        row and whose whole holds both; never larger than
+        ``aligned + 8 * singles`` would have taken."""
+        cfg = self.cfg
+        nb = cfg.ragged_tokens_bucket_for(
+            max(aligned + singles, 1), mixed=True
+        )
+        if self._attn_impl == "pallas":
+            while singles > cfg.ragged_packed_slots(nb):
+                nb *= 2
+        return nb
+
+    def _decode_from(self, nb: int, aligned: int) -> int:
+        """The slot a mixed stream's decode rows start at: behind the
+        spans, and on a kernel engine no earlier than the packed tail."""
+        if self._attn_impl != "pallas":
+            return aligned
+        return max(aligned, nb - self.cfg.ragged_packed_slots(nb))
 
     def _run_program(self, fn, state_rows, *args) -> tuple:
         """Call a ragged program. For a model with sequence state it also
@@ -994,9 +1033,10 @@ class TPUEngine(AsyncEngine):
         chained: bool, live: int,
     ) -> tuple:
         """Count one ragged dispatch under its whole variant key
-        (``dynamo_ragged_dispatch_total``) and, for a routed-expert
-        model, its live and padding rows (``dynamo_moe_rows_total``;
-        a window's rows times its steps) and the way its expert matmuls
+        (``dynamo_ragged_dispatch_total``), its live and padding slots
+        (``dynamo_ragged_slots_total``; a window's rows times its
+        steps) and, for a routed-expert model, the same as rows
+        (``dynamo_moe_rows_total``) and the way its expert matmuls
         read their weights (``dynamo_moe_dispatch_total``). Host
         arithmetic on what the builder already holds. Returns the key."""
         key = resolve_ragged_key(
@@ -1004,18 +1044,21 @@ class TPUEngine(AsyncEngine):
             full_sampler, want_lp, with_spec,
         )
         tel = get_telemetry()
+        program = "window" if windowed else "mixed"
         tel.ragged_dispatches.labels(
-            "window" if windowed else "mixed",
+            program,
             str(nb),
             "kernel" if key[1] is None else str(key[1]),
             str(int(want_lp)),
             str(int(full_sampler)),
             str(int(chained)),
         ).inc()
+        pad = nb * (self.cfg.decode_window if windowed else 1) - live
+        tel.ragged_slots.labels(program, "live").inc(live)
+        tel.ragged_slots.labels(program, "pad").inc(pad)
         if self.cfg.model.is_moe:
-            steps = self.cfg.decode_window if windowed else 1
             tel.moe_rows.labels("live").inc(live)
-            tel.moe_rows.labels("pad").inc(nb * steps - live)
+            tel.moe_rows.labels("pad").inc(pad)
             # nb: a window's rows a step, a mixed dispatch's flat tokens.
             tel.moe_dispatches.labels(routed_experts_form(
                 self.cfg.model, self.mesh, nb, self._attn_interpret
@@ -3616,13 +3659,13 @@ class TPUEngine(AsyncEngine):
                     )
                 )
                 r += 1
+            # A decode row's one token is no span to align: the rows take
+            # their slots side by side once the spans' extent is known.
+            dec_rows = np.arange(r, r + len(dec))
             for seq, wpos, _cap in dec:
-                qs = add_span([seq.last_token()], [wpos], r)
                 table[r, : len(seq.page_ids)] = seq.page_ids
-                q_last[r] = qs
                 if seq.state_slot >= 0:
                     state_slots[r] = state_dst[r] = seq.state_slot
-                    state_src[r] = qs
                 pos0[r] = wpos
                 is_decode[r] = True
                 slot_map[r] = seq.slot
@@ -3649,16 +3692,25 @@ class TPUEngine(AsyncEngine):
                 rows.append(_RaggedRow(seq, "spec", r, n_drafts=g))
                 r += 1
 
-            total_q = len(flat_tokens)
-            nb = cfg.ragged_tokens_bucket_for(max(total_q, 1), mixed=True)
+            aligned = len(flat_tokens)
+            nb = self._mixed_bucket_for(aligned, len(dec))
+            dec_from = self._decode_from(nb, aligned)
+            total_q = dec_from + len(dec) if dec else aligned
             tokens = np.zeros(nb, np.int32)
             positions = np.full(nb, -1, np.int32)
             # Flat padding maps to the scratch per-row index (B1 - 1 is
             # always free: at most max_decode_slots rows hold slots).
             row_of = np.full(nb, B1 - 1, np.int32)
-            tokens[:total_q] = flat_tokens
-            positions[:total_q] = flat_pos
-            row_of[:total_q] = flat_row
+            tokens[:aligned] = flat_tokens
+            positions[:aligned] = flat_pos
+            row_of[:aligned] = flat_row
+            dec_at = slice(dec_from, total_q)
+            tokens[dec_at] = [seq.last_token() for seq, _, _ in dec]
+            positions[dec_at] = pos0[dec_rows]
+            row_of[dec_at] = dec_rows
+            q_last[dec_rows] = state_src[dec_rows] = np.arange(
+                dec_from, total_q
+            )
 
             want_lp = any(
                 self._wants_logprobs(e.seq) is not None for e in rows

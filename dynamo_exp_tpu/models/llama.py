@@ -987,6 +987,7 @@ def forward_ragged(
     attn_pages: int | None = None,
     attn_impl: str = "xla",
     q_tile: int = 8,
+    packed_from: int | None = None,
     mesh=None,
     interpret: bool = False,
     seq_state: jnp.ndarray | None = None,
@@ -994,6 +995,13 @@ def forward_ragged(
 ) -> tuple[jnp.ndarray, ...]:
     """One ragged forward over a flat mixed batch (the single-dispatch
     prefill+decode+spec path, docs/engine_perf.md).
+
+    The kernel reads the stream in tiles of ``q_tile`` slots, one row a
+    tile. With ``packed_from`` (static, a multiple of ``q_tile``) only
+    the slots before it are such tiles; every slot from it on is a grid
+    cell of its own (the kernel's one-query form, a decode window's), so
+    rows of one token each lie there side by side. None: the whole
+    stream is tiles. The reference reads any layout and ignores both.
 
     With ``seq_state`` (``init_seq_state``; returned fourth) the rows'
     sequences carry state beside their pages: ``state_rows`` = (the pool
@@ -1060,10 +1068,18 @@ def forward_ragged(
 
     def read(q, kf, vf, table_l, win_l):
         if use_pallas:
-            return _pallas_ragged(
-                q[0], kf, vf, table_l, row_of, positions,
-                cfg.num_kv_heads, q_tile, mesh, interpret,
-            )[None]
+            def kernel(part, tile):
+                return _pallas_ragged(
+                    q[0, part], kf, vf, table_l, row_of[part],
+                    positions[part], cfg.num_kv_heads, tile, mesh, interpret,
+                )
+
+            if packed_from is None:
+                return kernel(slice(None), q_tile)[None]
+            return jnp.concatenate([
+                kernel(slice(0, packed_from), q_tile),
+                kernel(slice(packed_from, None), 1),
+            ])[None]
         from ..ops.ragged_attention import ragged_paged_attention_ref
 
         return ragged_paged_attention_ref(

@@ -27,7 +27,10 @@ Two implementations with identical semantics:
   (``ceil(kv_len / page_size)``, double-buffered), and runs a
   flash-style online softmax in VMEM scratch. ``q_tile=1`` degenerates
   to the old per-row decode kernel (one query per grid cell — the
-  shape pure-decode windows dispatch).
+  shape pure-decode windows dispatch, and the one a mixed dispatch
+  reads its decode rows through: ``forward_ragged`` calls the kernel
+  twice, at ``q_tile`` over the stream's aligned spans and at 1 over
+  its packed tail, where rows of one token lie side by side).
 
 HBM traffic per dispatch per layer is ``sum_rows(tiles_row * kv_row)``
 tokens instead of the XLA gather's ``N * S``: the kernel reads each
@@ -287,14 +290,16 @@ def ragged_paged_attention(
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
-    The caller aligns each row's query span to ``q_tile`` flat slots
-    (padding tokens carry position -1), so every tile belongs to
-    exactly one row — each grid cell DMAs only that row's live pages
-    and computes ``q_tile`` queries against them. ``q_tile=1`` is the
-    pure-decode shape (one query per row, the old paged-decode kernel's
-    grid). Returns [N, H, D] in q's dtype. Padding slots return exact
-    zeros, inside a live tile as in a fully-empty one (inactive rows):
-    routed experts sort every row of a dispatch together
+    Within one call the caller aligns each row's query span to
+    ``q_tile`` flat slots (padding tokens carry position -1), so every
+    tile belongs to exactly one row — each grid cell DMAs only that
+    row's live pages and computes ``q_tile`` queries against them.
+    ``q_tile=1`` needs no alignment: any slot is a cell of its own (one
+    query per row, the old paged-decode kernel's grid; a decode window's
+    step and the packed tail of a mixed stream). Returns [N, H, D] in
+    q's dtype. Padding slots return exact zeros, inside a live tile as
+    in a fully-empty one (inactive rows): routed experts sort every row
+    of a dispatch together
     (``ops/moe.py``), so a padding row must not carry what memory no
     request owns into the rows beside it.
 

@@ -213,6 +213,17 @@ class Telemetry:
             ["program", "nb", "pages", "want_lp", "full_sampler", "chained"],
             registry=self.registry,
         )
+        self.ragged_slots = Counter(
+            "dynamo_ragged_slots_total",
+            "Slots of ragged dispatches by program and kind: live (a "
+            "real query token; a window counts each row's valid steps) "
+            "or pad (what the token bucket, a span's tile and a row "
+            "past its stop add: every one a row of every projection "
+            "and feed-forward). Every model; dynamo_moe_rows_total is "
+            "its sum over programs for a routed one",
+            ["program", "kind"],  # window | mixed; live | pad
+            registry=self.registry,
+        )
         self.moe_rows = Counter(
             "dynamo_moe_rows_total",
             "Rows of routed-expert models' dispatches by kind: live "

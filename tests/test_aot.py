@@ -211,6 +211,33 @@ def test_manifest_lattice_counts(attn_impl, windowed, mixed):
     assert bounds == ({None} if attn_impl == "pallas" else {128, 256, 512})
 
 
+@pytest.mark.parametrize("slots", [8, 64])
+def test_a_mixed_variant_is_keyed_by_its_token_bucket_alone(slots):
+    """Packing a mixed stream's decode rows (PR 32) added no axis: where
+    the packed tail starts is ``EngineConfig.ragged_packed_slots(nb)``,
+    so a kernel engine's lattice has one mixed variant a token bucket,
+    sampler and log-probability form as before — the count
+    ``benchmark/run_cell.py::warm_lattice`` builds and ``setup_s`` pays
+    for — and every bucket ``_mixed_bucket_for`` can choose is in it."""
+    import dataclasses
+
+    cfg = _cfg(max_decode_slots=slots, max_model_len=4096, num_pages=1152)
+    manifest = build_manifest(
+        cfg, attn_impl="pallas", mesh_shape={"tp": 1}, jax_version="test",
+        include_lp=False, include_spec=False,
+    )
+    mixed = [v for v in manifest.ragged if not v.windowed]
+    buckets = mixed_token_buckets(cfg)
+    assert len(mixed) == 2 * len(buckets)  # greedy and the full sampler
+    assert sorted({v.nb for v in mixed}) == buckets
+    assert [f.name for f in dataclasses.fields(mixed[0])] == [
+        "nb", "pages", "windowed", "full_sampler", "want_lp", "with_spec"]
+    eng = _engine(max_decode_slots=slots, pallas_interpret=True)
+    for rows in range(0, slots + 1, max(1, slots // 8)):
+        for aligned in (0, 8, 64, 160, 304, 512, 1024):
+            assert eng._mixed_bucket_for(aligned, rows) in buckets
+
+
 def test_resolved_key_matches_live_ragged_fn():
     """The engine's ``_ragged_fn`` and the offline ``resolve_ragged_key``
     are literally the same keying rule (one computes through the

@@ -6,6 +6,8 @@ it. All host counts: nothing here reads the device."""
 
 import asyncio
 
+import pytest
+
 from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
 from dynamo_exp_tpu.models import TINY, TINY_MOE
 from dynamo_exp_tpu.parallel import single_device_mesh
@@ -76,6 +78,36 @@ async def test_dispatches_are_counted_by_variant_key_and_a_routed_models_rows_by
         m3 = _delta(m2, _samples("dynamo_moe_rows_total"))
         assert again == first
         assert m3[('kind="live"',)] == 1 + 8 and m3[('kind="pad"',)] == 15
+    finally:
+        engine.stop()
+
+
+@pytest.mark.parametrize("model", [TINY, TINY_MOE], ids=["dense", "routed"])
+async def test_every_models_slots_are_counted_by_program_and_kind(model):
+    """``dynamo_ragged_slots_total``: live + pad is the token bucket of
+    a mixed dispatch and the row bucket times the steps of a window."""
+    engine = _engine(model)
+    engine.start()
+    try:
+        s0 = _samples("dynamo_ragged_slots_total")
+        d0 = _samples("dynamo_ragged_dispatch_total")
+        await _ask(engine, list(range(5, 5 + 21)), 9)
+        await asyncio.sleep(0.1)
+        s1 = _delta(s0, _samples("dynamo_ragged_slots_total"))
+        d1 = _delta(d0, _samples("dynamo_ragged_dispatch_total"))
+        # One prefill of 21 tokens at the 32 bucket; 8 more tokens from
+        # one window of 8 steps at 1 row.
+        assert s1 == {
+            ('kind="live"', 'program="mixed"'): 21, ('kind="pad"', 'program="mixed"'): 11,
+            ('kind="live"', 'program="window"'): 8,
+        }
+        for program, steps in (("mixed", 1), ("window", 8)):
+            slots = sum(v for k, v in s1.items() if f'program="{program}"' in k)
+            nb = sum(
+                v * int(dict(x.split("=") for x in k)["nb"].strip('"'))
+                for k, v in d1.items() if f'program="{program}"' in k
+            )
+            assert slots == nb * steps
     finally:
         engine.stop()
 
