@@ -77,7 +77,9 @@ def variant_call_args(engine, key: tuple) -> tuple:
     # every row at an entry past the pool (nothing read back, nothing
     # written), in the shapes ``_build_windowed`` / ``_build_mixed`` give.
     nb, windowed = key[0], key[2]
-    no_entry = np.int32(state.shape[1])
+    from ..models.llama import seq_state_entries
+
+    no_entry = np.int32(seq_state_entries(state))
     if windowed:
         return (*args, state, np.full(nb, no_entry, np.int32))
     B1 = engine.cfg.max_decode_slots + 1

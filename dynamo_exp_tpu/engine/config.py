@@ -79,7 +79,8 @@ def _parse_env_flag(name: str, raw: str) -> bool:
 
 def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
     """The one place a model whose sequences carry state beside their
-    pages (``ModelConfig.has_seq_state``: lfm2_moe's short convolution)
+    pages (``ModelConfig.has_seq_state``: a short convolution's last
+    products, a selective scan's state)
     is refused by what moves or re-reads pages alone. Each of these would
     resume or verify a sequence with a state that does not belong to its
     tokens, and serve wrong numbers without any error:
@@ -91,6 +92,8 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
       advanced the state;
     - ring prefill (``sp`` > 1): it writes pages from outside the paged
       layer loop that carries the state;
+    - tensor parallelism (``tp`` > 1) where the state is a selective
+      scan's: the scan and its pool are one chip's (no channel split);
     - ``tier`` = "disaggregated transfer" / "migration"
       (``disagg/transfer.py``, ``runtime/reclaim.py``): named by the
       component that is being built round the engine.
@@ -107,12 +110,18 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
             ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
             ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
             ("ring prefill (sp > 1)", cfg.sp > 1),
+            (
+                "tensor parallelism (tp > 1) of a selective scan",
+                cfg.tp > 1 and cfg.model.num_mamba_layers > 0,
+            ),
         ) if on
     ]
     if armed:
         raise ValueError(
             f"model_type {cfg.model.model_type!r} keeps per-sequence state "
-            f"beside its KV pages (a short-convolution state a layer), which "
+            f"beside its KV pages (what its "
+            f"{'selective-scan' if cfg.model.num_mamba_layers else 'short-convolution'} "
+            f"layers carry from token to token), which "
             f"{', '.join(armed)} would not carry: a sequence would resume with "
             "a state that is not its own. Serve it without (README.md, models/)"
         )

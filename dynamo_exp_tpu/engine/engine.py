@@ -69,6 +69,7 @@ from ..models.llama import (
     init_kv_cache,
     init_params,
     init_seq_state,
+    seq_state_entries,
     kv_cache_shardings,
     param_shardings,
     routed_experts_form,
@@ -280,10 +281,15 @@ class TPUEngine(AsyncEngine):
             mcfg, cfg.num_pages, cfg.page_size, dtype=cfg.kv_dtype_jnp,
             shardings=tuple(sharding(s) for s in kv_cache_shardings()),
         )
-        # What a sequence carries beside its pages (lfm2_moe's short
-        # convolution; None for every other model): one entry a decode
-        # slot, then the snapshots a prefix hit restores from, four a
-        # slot (8 KiB a convolution layer each). Replicated over the mesh.
+        # What a sequence carries beside its pages (a model with layers
+        # whose operator is not attention: a short convolution's last
+        # products, a selective scan's state and its convolution's inputs;
+        # None for every other model): one entry a decode slot, then the
+        # snapshots a prefix hit restores from, four a slot. An entry is
+        # kilobytes for a convolution and megabytes for a scan (9.32 MB at
+        # Jamba2-3B's 26 layers: 2.98 GB of pool), so its bytes are
+        # published with the KV pool's (``dynamo_seq_state_pool_bytes``).
+        # Replicated over the mesh.
         state_slots = cfg.max_decode_slots if mcfg.has_seq_state else 0
         state_snapshots = 4 * state_slots
         # Entries of the pool; as a row's entry it means "none": a write
@@ -292,6 +298,9 @@ class TPUEngine(AsyncEngine):
         self.seq_state = init_seq_state(
             mcfg, self._state_entries, sharding=sharding(P())
         )
+        get_telemetry().seq_state_pool_bytes.set(sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self.seq_state)
+        ))
         layers, pages, page_size, width = self.k_cache.shape
         get_telemetry().set_kv_pool_info(
             layers, pages, page_size, width, str(self.k_cache.dtype)
@@ -399,7 +408,8 @@ class TPUEngine(AsyncEngine):
             return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
 
         def seq_state_copy(pool, src, dst):
-            return pool.at[:, dst].set(pool[:, src])
+            # every part of the state (``init_seq_state``'s pytree)
+            return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), pool)
 
         # A prefix hit's state: snapshot entry -> the sequence's own entry.
         self._copy_state = jax.jit(seq_state_copy, donate_argnums=(0,))
@@ -427,6 +437,17 @@ class TPUEngine(AsyncEngine):
         # for failover replay pins the seed request-side instead.
         self._seed_rng = random.Random(seed + 1)
         self._attn_impl, self._attn_interpret = self._resolve_attn()
+        if mcfg.num_mamba_layers and self._attn_impl == "pallas" and (
+            cfg.page_size % cfg.ragged_q_tile or cfg.prefill_chunk % cfg.ragged_q_tile
+        ):
+            # The scan kernel stores a state at the end of a tile: a
+            # snapshot's block boundary and a chunk's end must be one
+            # (ops/selective_scan.py, WRITES).
+            raise ValueError(
+                f"a selective-scan model on the kernel path needs page_size "
+                f"({cfg.page_size}) and prefill_chunk ({cfg.prefill_chunk}) to "
+                f"be multiples of ragged_q_tile ({cfg.ragged_q_tile})"
+            )
         # The ONE compiled-variant cache (docs/engine_perf.md "One
         # ragged dispatch"): every device program — pure-decode windows,
         # mixed prefill+decode+spec batches — is keyed by
@@ -1035,7 +1056,9 @@ class TPUEngine(AsyncEngine):
         """Count one ragged dispatch under its whole variant key
         (``dynamo_ragged_dispatch_total``), its live and padding slots
         (``dynamo_ragged_slots_total``; a window's rows times its
-        steps) and, for a routed-expert model, the same as rows
+        steps), for a selective-scan model the live ones as tokens
+        through the scan (``dynamo_ssm_scan_tokens_total``) and, for a
+        routed-expert model, the same as rows
         (``dynamo_moe_rows_total``) and the way its expert matmuls
         read their weights (``dynamo_moe_dispatch_total``). Host
         arithmetic on what the builder already holds. Returns the key."""
@@ -1056,6 +1079,8 @@ class TPUEngine(AsyncEngine):
         pad = nb * (self.cfg.decode_window if windowed else 1) - live
         tel.ragged_slots.labels(program, "live").inc(live)
         tel.ragged_slots.labels(program, "pad").inc(pad)
+        if self.cfg.model.num_mamba_layers:
+            tel.ssm_scan_tokens.labels(program).inc(live)
         if self.cfg.model.is_moe:
             tel.moe_rows.labels("live").inc(live)
             tel.moe_rows.labels("pad").inc(pad)

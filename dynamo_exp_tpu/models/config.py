@@ -108,6 +108,20 @@ class ModelConfig:
     router_score: str = "softmax"
     use_expert_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # jamba: layers whose operator is a selective scan (Mamba-1),
+    # ``layer_types`` "mamba". Inner width ``mamba_expand * hidden_size``,
+    # a depthwise causal convolution of ``mamba_d_conv`` taps ahead of the
+    # scan, ``mamba_d_state`` states a channel, the step's projection of
+    # rank ``mamba_dt_rank``. A sequence carries the convolution's last
+    # ``mamba_d_conv - 1`` inputs and the scan's state
+    # ``[inner, mamba_d_state]`` (float32) a layer (``has_seq_state``).
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # Whether attention turns q and k by their positions. jamba's does
+    # not: its Mamba layers carry the order of the tokens.
+    use_rope: bool = True
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -117,10 +131,11 @@ class ModelConfig:
 
     @property
     def op_kinds(self) -> tuple[str, ...]:
-        """Each layer's operator: "conv" (gated short convolution) or
-        "attn" (every attention form, windowed or full)."""
+        """Each layer's operator: "conv" (gated short convolution),
+        "mamba" (selective scan) or "attn" (every attention form,
+        windowed or full)."""
         types = self.layer_types or ("full_attention",) * self.num_layers
-        return tuple("conv" if t == "conv" else "attn" for t in types)
+        return tuple(t if t in ("conv", "mamba") else "attn" for t in types)
 
     @property
     def ff_kinds(self) -> tuple[str, ...]:
@@ -139,7 +154,7 @@ class ModelConfig:
         (``models/llama.py::init_params``) and the paged layer loop is one
         ``lax.scan`` a run, so a model of one kind is one run."""
         runs: list[tuple[str, str, int, int, int, int]] = []
-        seen = {"conv": 0, "attn": 0, "dense": 0, "routed": 0}
+        seen = {"conv": 0, "mamba": 0, "attn": 0, "dense": 0, "routed": 0}
         for i, kind in enumerate(zip(self.op_kinds, self.ff_kinds)):
             if runs and runs[-1][:2] == kind:
                 runs[-1] = (*runs[-1][:3], runs[-1][3] + 1, *runs[-1][4:])
@@ -159,15 +174,25 @@ class ModelConfig:
         return self.op_kinds.count("conv")
 
     @property
+    def num_mamba_layers(self) -> int:
+        return self.op_kinds.count("mamba")
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
     def num_routed_layers(self) -> int:
         return self.ff_kinds.count("routed")
 
     @property
     def has_seq_state(self) -> bool:
-        """Whether a sequence holds state that is not a page (the short
-        convolution's last products): what the tiers that move pages
-        alone refuse (``engine/config.py::refuse_seq_state_tiers``)."""
-        return self.num_conv_layers > 0
+        """Whether a sequence holds state that is not a page: what a
+        layer whose operator is not attention carries from token to token
+        (``models/llama.py::init_seq_state`` has the parts by kind). What
+        the tiers that move pages alone refuse
+        (``engine/config.py::refuse_seq_state_tiers``)."""
+        return self.num_attn_layers < self.num_layers
 
     @property
     def expert_intermediate_size(self) -> int:
@@ -198,6 +223,8 @@ class ModelConfig:
             )
         if model_type == "lfm2_moe":
             return cls._from_lfm2_moe(cfg)
+        if model_type == "jamba":
+            return cls._from_jamba(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -320,6 +347,58 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_jamba(cls, cfg: dict) -> "ModelConfig":
+        """AI21's ``jamba`` (Jamba2-3B): layer ``i`` is attention where
+        ``i % attn_layer_period == attn_layer_offset`` and a Mamba-1 mixer
+        otherwise; attention has no positional term, no bias and no q/k
+        norm; every feed-forward is a dense SwiGLU unless ``num_experts``
+        > 1 (the routed Jamba models, which are refused: their expert
+        layers alternate by ``expert_layer_period``, a table this class
+        does not hold)."""
+        if cfg.get("num_experts", 1) > 1:
+            raise ValueError(
+                "jamba with num_experts > 1 (expert_layer_period/offset "
+                "choosing routed layers) is not supported"
+            )
+        if cfg.get("mamba_proj_bias", False):
+            raise ValueError("jamba with mamba_proj_bias=true is not supported")
+        if not cfg.get("mamba_conv_bias", True):
+            raise ValueError("jamba with mamba_conv_bias=false is not supported")
+        if cfg.get("sliding_window") is not None:
+            raise ValueError("jamba with a sliding_window is not supported")
+        if str(cfg.get("hidden_act", "silu")) != "silu":
+            raise ValueError("jamba with hidden_act other than silu is not supported")
+        n_layers = cfg["num_hidden_layers"]
+        period, offset = cfg["attn_layer_period"], cfg["attn_layer_offset"]
+        hidden = cfg["hidden_size"]
+        dt_rank = cfg.get("mamba_dt_rank", "auto")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=cfg.get("max_position_embeddings", 262144),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            layer_types=tuple(
+                "full_attention" if i % period == offset else "mamba"
+                for i in range(n_layers)
+            ),
+            mamba_d_state=cfg.get("mamba_d_state", 16),
+            mamba_d_conv=cfg.get("mamba_d_conv", 4),
+            mamba_expand=cfg.get("mamba_expand", 2),
+            mamba_dt_rank=(
+                -(-hidden // 16) if dt_rank == "auto" else int(dt_rank)
+            ),
+            use_rope=False,
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type="jamba",
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             return cls.from_hf_config(json.load(f))
@@ -328,7 +407,7 @@ class ModelConfig:
 # The families ``from_hf_config`` builds; any other ``model_type`` raises.
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
-    "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe",
+    "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe", "jamba",
 })
 
 # Presets used by tests, the dry-run entrypoints, and the benchmark.
@@ -522,6 +601,26 @@ TINY_LFM2 = ModelConfig(  # lfm2_moe family shape: both operator kinds, both
     model_type="lfm2_moe",
 )
 
+TINY_JAMBA = ModelConfig(  # jamba family shape: period 4 with one attention
+    # layer (offset 1), so runs mamba / attn / mamba; 1 KV head, no rope
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=4,
+    num_heads=4,
+    num_kv_heads=1,
+    max_position_embeddings=512,
+    tie_word_embeddings=True,
+    rms_norm_eps=1e-6,
+    layer_types=("mamba", "full_attention", "mamba", "mamba"),
+    mamba_d_state=16,
+    mamba_d_conv=4,
+    mamba_expand=2,
+    mamba_dt_rank=8,
+    use_rope=False,
+    model_type="jamba",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -542,6 +641,7 @@ PRESETS = {
     "tiny-qwen3": TINY_QWEN3,
     "tiny-moe": TINY_MOE,
     "tiny-lfm2": TINY_LFM2,
+    "tiny-jamba": TINY_JAMBA,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

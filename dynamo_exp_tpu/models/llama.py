@@ -84,7 +84,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     (``ModelConfig.layer_runs``): the two norms over all ``L`` layers,
     attention's over the attention layers, the short convolution's
     (``conv_in``/``conv_w``/``conv_out``) over the convolution layers,
-    the router and the expert matrices over the routed layers. A model
+    the selective scan's (``mamba_*``, :func:`_init_mamba`) over the Mamba
+    layers, the router and the expert matrices over the routed layers. A model
     whose feed-forwards are of both kinds keeps its dense layers' under
     ``dense_gate``/``dense_up``/``dense_down``; ``w_gate``/``w_up``/
     ``w_down`` are then the routed layers' (``EXPERT_STACKS``). In a
@@ -100,6 +101,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         cfg.vocab_size,
     )
     La, Lc, Lr = cfg.num_attn_layers, cfg.num_conv_layers, cfg.num_routed_layers
+    Lm = cfg.num_mamba_layers
     Ld = L - Lr
     ks = jax.random.split(rng, 17)
     # Keys for what lfm2 added, split off a folded key: ``ks`` (and so
@@ -122,6 +124,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         layers["conv_in"] = init(kc[0], (Lc, D, 3 * D), D)
         layers["conv_w"] = init(kc[1], (Lc, Kc, D), Kc)
         layers["conv_out"] = init(kc[2], (Lc, D, D), D)
+    if Lm:  # jamba: the Mamba-1 mixer
+        layers.update(_init_mamba(jax.random.fold_in(rng, 2), cfg, init))
     if cfg.attention_bias:  # qwen2: bias on q/k/v, none on o
         layers["bq"] = init(ks[9], (L, H * hd), H * hd)
         layers["bk"] = init(ks[10], (L, Hkv * hd), Hkv * hd)
@@ -168,6 +172,55 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     return params
 
 
+# The step a randomly initialised Mamba layer takes: ``mamba_dt_b`` is the
+# inverse softplus of a step drawn log-uniform in this range, the family's
+# own initialisation. With ``A = -(1..S)`` a row the slowest state then
+# forgets over 10 to 1000 tokens and the fastest within one: a state that
+# neither dies nor explodes over 16k tokens.
+MAMBA_DT_RANGE = (1e-3, 1e-1)
+# The Mamba mixer's parameters, each stacked over the Mamba layers.
+MAMBA_PARAMS = (
+    "mamba_in", "mamba_conv_w", "mamba_conv_b", "mamba_x", "mamba_dt_w",
+    "mamba_dt_b", "mamba_A_log", "mamba_D", "mamba_dt_norm", "mamba_b_norm",
+    "mamba_c_norm", "mamba_out",
+)
+
+
+def _init_mamba(rng, cfg: ModelConfig, init) -> Params:
+    """Random Mamba-1 mixers, ``[L_mamba, ...]`` each: the projections by
+    ``init`` (normal, fan-in scaled); ``A_log = log(1..S)`` a channel and
+    ``D = 1`` as the family initialises them; the step's bias from
+    ``MAMBA_DT_RANGE``. ``mamba_A_log`` is ``[S, E]`` (the published
+    tensor transposed: the channels on lanes, as the scan's state lies),
+    and with ``mamba_D`` and ``mamba_dt_b`` stays float32."""
+    dt = _dtype(cfg)
+    Lm, D, E = cfg.num_mamba_layers, cfg.hidden_size, cfg.mamba_inner
+    S, K, R = cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_dt_rank
+    km = jax.random.split(rng, 7)
+    lo, hi = MAMBA_DT_RANGE
+    step = jnp.exp(
+        jax.random.uniform(km[5], (Lm, E), jnp.float32)
+        * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo)
+    )
+    return {
+        "mamba_in": init(km[0], (Lm, D, 2 * E), D),
+        "mamba_conv_w": init(km[1], (Lm, K, E), K),
+        "mamba_conv_b": init(km[6], (Lm, E), K),
+        "mamba_x": init(km[2], (Lm, E, R + 2 * S), E),
+        "mamba_dt_w": init(km[3], (Lm, R, E), R),
+        "mamba_dt_b": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "mamba_A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, S + 1, dtype=jnp.float32))[None, :, None],
+            (Lm, S, E),
+        ),
+        "mamba_D": jnp.ones((Lm, E), jnp.float32),
+        "mamba_dt_norm": jnp.ones((Lm, R), dt),
+        "mamba_b_norm": jnp.ones((Lm, S), dt),
+        "mamba_c_norm": jnp.ones((Lm, S), dt),
+        "mamba_out": init(km[4], (Lm, E, D), E),
+    }
+
+
 def param_shardings(
     cfg: ModelConfig, tp_axis: str = "tp", ep_axis: str | None = None
 ) -> Params:
@@ -191,6 +244,10 @@ def param_shardings(
         layers["conv_in"] = P(None, None, None)
         layers["conv_w"] = P(None, None, None)
         layers["conv_out"] = P(None, None, None)
+    if cfg.num_mamba_layers:
+        # Replicated: a selective scan under tp > 1 is refused
+        # (``engine/config.py::refuse_seq_state_tiers``).
+        layers.update((k, P()) for k in MAMBA_PARAMS)
     if cfg.attention_bias:
         layers["bq"] = P(None, tp_axis)
         layers["bk"] = P(None, tp_axis)
@@ -272,20 +329,51 @@ def init_kv_cache(
     )
 
 
+class MambaState(NamedTuple):
+    """The two parts a Mamba sequence carries a layer, as pools
+    ``[L_mamba, entries, ...]``."""
+
+    conv: jnp.ndarray  # [Lm, entries, d_conv - 1, E], the model's dtype
+    ssm: jnp.ndarray  # [Lm, entries, S, E] float32
+
+
 def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
     """The pool of what a sequence carries from token to token beside its
-    pages: ``[L_conv, entries, conv_L_cache - 1, D]``, entry ``e`` holding
-    for each convolution layer the products ``z`` of the last tokens that
-    layer saw of the sequence bound to ``e`` (``short_conv``). None for a
-    model whose sequences hold pages alone. Zeros are what a sequence
-    starts from, though no forward relies on it: positions 0 and 1 mask
-    what lies before the sequence's start."""
+    pages: a pytree by part, every leaf ``[layers of the kind, entries,
+    ...]``, entry ``e`` holding the state of the sequence bound to ``e``.
+    None for a model whose sequences hold pages alone.
+
+    - a short-convolution model (lfm2_moe): one array ``[L_conv, entries,
+      conv_L_cache - 1, D]``, the products ``z`` of the last tokens each
+      convolution layer saw (``short_conv``);
+    - a selective-scan model (jamba): :class:`MambaState`, the
+      convolution's last ``mamba_d_conv - 1`` inputs and the scan's state
+      ``[S, E]`` in float32 (``mamba_mixer``): 9.32 MB an entry at
+      Jamba2-3B's 26 layers, not kilobytes, so the engine counts the pool
+      among what it holds (``dynamo_seq_state_pool_bytes``).
+
+    Zeros are what a sequence starts from, though no forward relies on
+    it: the first positions mask what lies before the sequence's start."""
     if not cfg.has_seq_state:
         return None
+    dt = dtype or _dtype(cfg)
+    if cfg.num_mamba_layers:
+        if cfg.num_conv_layers:
+            raise ValueError("a model of both convolution and Mamba layers has no state pool")
+        Lm, E = cfg.num_mamba_layers, cfg.mamba_inner
+        return MambaState(
+            jnp.zeros((Lm, entries, cfg.mamba_d_conv - 1, E), dt, device=sharding),
+            jnp.zeros((Lm, entries, cfg.mamba_d_state, E), jnp.float32, device=sharding),
+        )
     return jnp.zeros(
         (cfg.num_conv_layers, entries, cfg.conv_L_cache - 1, cfg.hidden_size),
-        dtype or _dtype(cfg), device=sharding,
+        dt, device=sharding,
     )
+
+
+def seq_state_entries(state) -> int:
+    """How many entries a state pool (``init_seq_state``) has."""
+    return jax.tree.leaves(state)[0].shape[1]
 
 
 def rms_norm(
@@ -365,8 +453,9 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red):
             # (gemma3 stores these gemma-style: scale = 1 + w)
             q = rms_norm(q, lp["q_norm"], eps, off)
             k = rms_norm(k, lp["k_norm"], eps, off)
-        q = apply_rope(q, rope_pos, inv_freq)
-        k = apply_rope(k, rope_pos, inv_freq)
+        if cfg.use_rope:  # jamba's attention has no positional term
+            q = apply_rope(q, rope_pos, inv_freq)
+            k = apply_rope(k, rope_pos, inv_freq)
     attn, kv_extra = attend(q, k, v)
     with jax.named_scope("attn_out"):
         attn_out = red(attn.reshape(B, T, -1) @ lp["wo"])
@@ -381,19 +470,69 @@ class SeqStateIO(NamedTuple):
     token to token (``init_seq_state``'s pool), as the forward's flat
     token stream sees it. Tokens of a row lie in order; a token whose
     predecessor in the stream is its row's previous position reads that
-    token's products, any other reads its row's pool entry.
+    token's state, any other reads its row's pool entry.
 
     ``src``/``dst`` are the writes: the state as it stands after stream
     token ``src[w]`` goes to pool entry ``dst[w]``. A ``dst`` past the
     pool is no write (a padding row, a row with no token). The engine
     writes each row's own entry and, for a prefill row, a second entry a
-    later prefix hit may restore from (``engine/kv_manager.py``)."""
+    later prefix hit may restore from (``engine/kv_manager.py``).
+
+    A state that is a few products a layer (the convolutions') is kept a
+    token and any ``src`` is honoured. A scan's state is ``[S, E]``
+    float32 a token and layer (320 KiB at Jamba2-3B's widths) and is not:
+    the scan kernel stores at the ends of its tiles, so there ``src`` is
+    the last real token of its row in its tile of ``ragged_q_tile`` slots
+    (``ops/selective_scan.py``, WRITES), which is what the engine's two
+    writes a row are."""
 
     slots: jnp.ndarray  # [R] int32: the pool entry each row reads
     row_of: jnp.ndarray  # [N] int32: each stream token's row
     positions: jnp.ndarray  # [N] int32: its position, -1 = padding
     src: jnp.ndarray  # [W] int32: stream index of a token
     dst: jnp.ndarray  # [W] int32: pool entry the state after it goes to
+
+
+class ScanForm(NamedTuple):
+    """How a forward runs the selective scan (static): ``kernel`` (the
+    Pallas kernel over the stream, else the oracle), ``packed_from`` (with
+    the kernel: the stream's slots from here on are one-token rows unless
+    a span runs on into them, and take the one-step form), ``one_token``
+    (every row is one token and ``src`` its row's: a decode step)."""
+
+    kernel: bool = False
+    packed_from: int | None = None
+    one_token: bool = False
+    interpret: bool = False
+
+
+def _stream_history(z, held, io: SeqStateIO, K: int):
+    """``back[j] = z_{t-j}`` for each stream token ``t`` and ``j < K``:
+    the stream's where token ``t-j`` of the same row is in it, the row's
+    pool entry otherwise (``held`` [N, K-1, C], oldest first, ending at
+    the last token the row has seen), zeros before the sequence's start.
+    The one implementation of "predecessor in the stream, else the row's
+    entry" for both convolutions; the scan reads the same rule
+    (``ops/selective_scan.py::stream_predecessor``)."""
+    from ..ops.selective_scan import stream_predecessor
+
+    pos = io.positions
+    prev = stream_predecessor(io.row_of, pos)[:, None]
+    back = [z]
+    for j in range(1, K):
+        shifted = jnp.concatenate(
+            [jnp.zeros((1, z.shape[1]), z.dtype), back[-1][:-1]]
+        )
+        zj = jnp.where(prev, shifted, held[:, K - 1 - j])
+        back.append(jnp.where((pos >= j)[:, None], zj, 0))  # before the start
+    return back
+
+
+def _history_after(back, src):
+    """The ``K-1`` newest of ``back`` as they stand after stream tokens
+    ``src``, oldest first: what a pool entry holds ([W, K-1, C])."""
+    K = len(back)
+    return jnp.stack([back[K - 2 - i][src] for i in range(K - 1)], axis=1)
 
 
 def short_conv(u, lp, pool, layer, io: SeqStateIO):
@@ -408,31 +547,127 @@ def short_conv(u, lp, pool, layer, io: SeqStateIO):
     products that end at the last token its row has seen, oldest first.
     Plain ``jax.numpy``: three taps over D channels are a fused
     multiply-add between two matmuls."""
-    N, D = u.shape
     taps = lp["conv_w"]  # [K, D]; taps[K-1] weighs z_t
     K = taps.shape[0]
     gate_b, gate_c, xin = jnp.split(u @ lp["conv_in"], 3, axis=-1)
     z = gate_b * xin  # [N, D]
-    pos = io.positions
-    # Whether stream token i-1 is token i's predecessor in its row.
-    prev = jnp.concatenate([
-        jnp.zeros((1,), bool),
-        (io.row_of[1:] == io.row_of[:-1])
-        & (pos[1:] == pos[:-1] + 1) & (pos[:-1] >= 0),
-    ])[:, None]
     held = pool[layer][io.slots[io.row_of]]  # [N, K-1, D], oldest first
+    back = _stream_history(z, held, io, K)
     acc = taps[K - 1] * z
-    back = [z]  # back[j] = z_{t-j} for each stream token t
     for j in range(1, K):
-        shifted = jnp.concatenate([jnp.zeros((1, D), z.dtype), back[-1][:-1]])
-        zj = jnp.where(prev, shifted, held[:, K - 1 - j])
-        zj = jnp.where((pos >= j)[:, None], zj, 0)  # before the start
-        acc = acc + taps[K - 1 - j] * zj
-        back.append(zj)
+        acc = acc + taps[K - 1 - j] * back[j]
     y = (gate_c * acc) @ lp["conv_out"]
-    new = jnp.stack([back[K - 2 - i][io.src] for i in range(K - 1)], axis=1)
+    new = _history_after(back, io.src)
     pool = pool.at[layer, io.dst].set(new.astype(pool.dtype), mode="drop")
     return y, pool
+
+
+def _selective_scan(x, dt, a, b, c, d, pool, layer, io: SeqStateIO, form: ScanForm):
+    """The recurrence over this forward's stream in the form ``form``
+    names (``ops/selective_scan.py`` has the three). Returns (y [N, E]
+    float32, the scan's pool)."""
+    from ..ops import selective_scan as ss
+
+    if form.one_token:
+        return ss.selective_scan_step(
+            x, dt, a, b, c, d, pool, layer, io.slots[io.row_of], io.positions,
+            io.dst,
+        )
+    if not form.kernel:
+        return ss.selective_scan_ref(
+            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, io.positions,
+            io.src, io.dst,
+        )
+    pos, src, dst = io.positions, io.src, io.dst
+    at = form.packed_from
+    if at is None:
+        return ss.selective_scan_kernel(
+            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, pos, src, dst,
+            interpret=form.interpret,
+        )
+    # The packed tail: a slot there is a row of one token (a decode row),
+    # unless a span of the tiles ran on into it. The kernel walks every
+    # span, the rows of one token take one step each.
+    entries = pool.shape[1]
+    n = pos.shape[0]
+    prev = ss.stream_predecessor(io.row_of, pos)
+    alone = ~prev & ~jnp.concatenate([prev[1:], jnp.zeros((1,), bool)])
+    alone = alone & (jnp.arange(n) >= at) & (pos >= 0)
+    y, pool = ss.selective_scan_kernel(
+        x, dt, a, b, c, d, pool, layer, io.slots, io.row_of,
+        jnp.where(alone, -1, pos), src, jnp.where(alone[src], entries, dst),
+        interpret=form.interpret,
+    )
+    tail = slice(at, None)
+    to = jnp.full((n,), entries, jnp.int32).at[src].min(
+        jnp.minimum(dst, entries).astype(jnp.int32)
+    )
+    y1, pool = ss.selective_scan_step(
+        x[tail], dt[tail], a, b[tail], c[tail], d, pool, layer,
+        io.slots[io.row_of[tail]], jnp.where(alone[tail], pos[tail], -1),
+        jnp.where(alone[tail], to[tail], entries),
+    )
+    y = jnp.concatenate([y[:at], jnp.where(alone[tail][:, None], y1, y[tail])])
+    return y, pool
+
+
+def mamba_mixer(u, lp, cfg, state: "MambaState", layer, io: SeqStateIO, form: ScanForm):
+    """Jamba's Mamba-1 mixer over a flat token stream ``u`` ([N, D], the
+    normed input), the carried state read from and written to
+    ``state.conv[layer]`` and ``state.ssm[layer]``. Returns ([N, D], the
+    state). ``E`` the inner width, ``S`` states, ``R`` the step's rank:
+
+    ``[x, g] = split2(u @ mamba_in)``; ``c_t = silu(conv_b + sum_j
+    conv_w[j] * x_{t-(K-1)+j})`` (depthwise, causal); ``[tau, B, C] =
+    split(c @ mamba_x)``, each RMS-normed (Jamba's inner norms);
+    ``dt = softplus(tau @ mamba_dt_w + mamba_dt_b)``; ``A = -exp(A_log)``;
+    ``h_t = exp(dt_t A) h_{t-1} + dt_t c_t B_t``; ``y_t = h_t C_t + D
+    c_t``; returns ``(y * silu(g)) @ mamba_out``.
+
+    The matrix products run in the model's dtype with float32 results
+    where a norm, the softplus or the scan reads them; everything between
+    the products is float32, the scan's state from end to end."""
+    f32 = jnp.float32
+    S, R, eps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.rms_norm_eps
+    xin, gate = jnp.split(u @ lp["mamba_in"], 2, axis=-1)  # [N, E] each
+    with jax.named_scope("mamba_conv"):
+        taps = lp["mamba_conv_w"].astype(f32)  # [K, E]; taps[K-1] weighs x_t
+        K = taps.shape[0]
+        held = state.conv[layer, io.slots[io.row_of]]  # [N, K-1, E], one gather
+        back = _stream_history(xin, held, io, K)
+        acc = lp["mamba_conv_b"].astype(f32) + taps[K - 1] * xin
+        for j in range(1, K):
+            acc = acc + taps[K - 1 - j] * back[j]
+        c = jax.nn.silu(acc)  # [N, E] float32
+        conv = state.conv.at[layer, io.dst].set(
+            _history_after(back, io.src).astype(state.conv.dtype), mode="drop"
+        )
+    tbc = jnp.dot(c.astype(u.dtype), lp["mamba_x"], preferred_element_type=f32)
+    tau = rms_norm(tbc[:, :R], lp["mamba_dt_norm"], eps)
+    b = rms_norm(tbc[:, R : R + S], lp["mamba_b_norm"], eps)
+    cc = rms_norm(tbc[:, R + S :], lp["mamba_c_norm"], eps)
+    dt = jax.nn.softplus(
+        jnp.dot(tau.astype(u.dtype), lp["mamba_dt_w"], preferred_element_type=f32)
+        + lp["mamba_dt_b"]
+    )
+    with jax.named_scope("ssm_scan"):
+        y, ssm = _selective_scan(
+            c, dt, -jnp.exp(lp["mamba_A_log"]), b, cc, lp["mamba_D"],
+            state.ssm, layer, io, form,
+        )
+    out = (y * jax.nn.silu(gate.astype(f32))).astype(u.dtype) @ lp["mamba_out"]
+    return out, MambaState(conv, ssm)
+
+
+def _mamba_half(x, lp, cfg, eps, state, layer, io, form):
+    """A layer's first half where its operator is the Mamba mixer: norm,
+    ``mamba_mixer`` on the flattened rows, residual add."""
+    with jax.named_scope("mamba"):
+        h = rms_norm(x, lp["attn_norm"], eps, cfg.rms_norm_offset)
+        y, state = mamba_mixer(
+            h.reshape(-1, h.shape[-1]), lp, cfg, state, layer, io, form
+        )
+        return x + y.reshape(x.shape), state
 
 
 def _conv_half(x, lp, cfg, eps, pool, layer, io):
@@ -615,6 +850,7 @@ _EVERY_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_ffn_norm")
 _OP_PARAMS = {
     "attn": ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm"),
     "conv": ("conv_in", "conv_w", "conv_out"),
+    "mamba": MAMBA_PARAMS,
 }
 _ROUTED_PARAMS = (
     "router", "router_bias", "shared_gate", "shared_up", "shared_down",
@@ -624,12 +860,13 @@ _ROUTED_PARAMS = (
 
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
-    live, interpret=False, seq_state=None, state_io=None,
+    live, interpret=False, seq_state=None, state_io=None, scan_form=ScanForm(),
 ):
     """The layer loop of both paged forwards: one ``lax.scan`` for each
     run of layers of one kind (``ModelConfig.layer_runs``), carrying
     ``(x, k_cache, v_cache)`` and, where the model has convolution layers,
-    ``seq_state``. Returns (x, k_cache, v_cache, seq_state). A model of
+    ``seq_state`` (a pytree by part: ``init_seq_state``). Returns (x,
+    k_cache, v_cache, seq_state). A model of
     one kind is one run over the stacked parameters, the per-layer window
     / rope base and the layer index, as it always was.
 
@@ -645,7 +882,9 @@ def _scan_paged_layers(
     pool would be a copy of it. ``live`` ([B, T] bool) marks the rows
     that are not padding, for the routed experts (``_ffn_block``). A
     convolution layer reads and writes ``seq_state`` the same way, at its
-    ordinal among convolution layers (``short_conv``; ``state_io``).
+    ordinal among convolution layers (``short_conv``; ``state_io``), a
+    Mamba layer both parts of its state at its ordinal among Mamba layers
+    (``mamba_mixer``; ``scan_form`` says which form the scan takes).
 
     A routed model's expert matrices (``EXPERT_STACKS``) leave ``xs`` for
     the same reason the pools left ``ys``: the body closes over the whole
@@ -717,6 +956,10 @@ def _scan_paged_layers(
             l = i + op0 if op0 else i  # ordinal among layers of this operator
             if op == "conv":
                 x, state = _conv_half(x, lp, cfg, eps, state, l, state_io)
+            elif op == "mamba":
+                x, state = _mamba_half(
+                    x, lp, cfg, eps, state, l, state_io, scan_form
+                )
             else:
 
                 def attend(q, k, v):
@@ -875,13 +1118,14 @@ def forward(
             row_of=batch_idx,
             positions=flat_pos,
             src=jnp.arange(B, dtype=jnp.int32) * T + jnp.maximum(n_real - 1, 0),
-            dst=jnp.where(n_real > 0, state_slots, seq_state.shape[1]),
+            dst=jnp.where(n_real > 0, state_slots, seq_state_entries(seq_state)),
         )
     x, k_cache, v_cache, seq_state = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid),
         page_table if use_pallas else attn_table, read, mesh,
         positions >= 0, interpret, seq_state, state_io,
+        _scan_form(cfg, attn_impl, T, None, interpret, one_token=T == 1),
     )
     if last_positions is not None:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
@@ -889,6 +1133,21 @@ def forward(
     if seq_state is None:
         return logits, k_cache, v_cache
     return logits, k_cache, v_cache, seq_state
+
+
+def _scan_form(cfg, attn_impl, span_align, packed_from, interpret,
+               one_token=False) -> ScanForm:
+    """The selective scan's form in a forward whose rows' spans are
+    aligned to ``span_align`` slots: the kernel where the attention kernel
+    serves the forward too (the engine then lays the stream in tiles) and
+    the widths tile, the oracle otherwise."""
+    from ..ops.selective_scan import supported
+
+    kernel = (
+        cfg.num_mamba_layers > 0 and attn_impl == "pallas"
+        and supported(cfg.mamba_inner, span_align)
+    )
+    return ScanForm(kernel, packed_from if kernel else None, one_token, interpret)
 
 
 def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
@@ -1097,6 +1356,7 @@ def forward_ragged(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid), attn_table, read, mesh,
         (positions >= 0)[None], interpret, seq_state, state_io,
+        _scan_form(cfg, attn_impl, q_tile, packed_from, interpret),
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
     logits = _final_logits(params, cfg, xo, eps)
@@ -1150,6 +1410,12 @@ def forward_ring_prefill(
         raise ValueError(
             "ring prefill does not implement sliding-window attention; "
             "use the paged prefill path for mistral-family models"
+        )
+    if cfg.has_seq_state:
+        raise ValueError(
+            f"ring prefill walks attention layers alone; {cfg.model_type} has "
+            "layers that carry a state from token to token (a convolution's "
+            "or a scan's), which live in the paged layer loop"
         )
     if T % sp:
         raise ValueError(f"seq len {T} not divisible by sp={sp}")

@@ -36,10 +36,14 @@ def _open_safetensors(path: str):
     return handles, index
 
 
+# Parameters that stay float32 whatever the model's dtype.
+_FLOAT32 = ("router_bias", "mamba_A_log", "mamba_D", "mamba_dt_b")
+
+
 def _host_params(params: dict, dt) -> dict:
-    """Host arrays of the model's dtype (``router_bias`` stays float32)."""
+    """Host arrays of the model's dtype (``_FLOAT32`` stay float32)."""
     def host(path, x):
-        return np.asarray(x).astype(np.float32 if path == "router_bias" else dt)
+        return np.asarray(x).astype(np.float32 if path in _FLOAT32 else dt)
 
     out = {k: host(k, v) for k, v in params.items() if k != "layers"}
     out["layers"] = {k: host(k, v) for k, v in params["layers"].items()}
@@ -95,10 +99,60 @@ def _lfm2_moe_params(cfg: ModelConfig, get, linear) -> dict:
     }
 
 
+def _jamba_params(cfg: ModelConfig, get, linear) -> dict:
+    """AI21's ``jamba`` tensor names into the stacks by kind of
+    ``models/llama.py::init_params``: ``input_layernorm`` /
+    ``pre_ff_layernorm`` and ``feed_forward.{gate,up,down}_proj`` over
+    every layer; ``self_attn.{q,k,v,o}_proj`` over the attention layers;
+    over the Mamba layers ``mamba.in_proj`` ([2E, D]), ``mamba.conv1d``
+    ([E, 1, K] depthwise, with its bias), ``mamba.x_proj``,
+    ``mamba.dt_proj`` (with its bias), ``mamba.A_log`` ([E, S], held
+    transposed), ``mamba.D``, ``mamba.{dt,b,c}_layernorm`` and
+    ``mamba.out_proj``. The head is tied to ``embed_tokens``; the final
+    norm is ``final_layernorm``."""
+    names = (
+        "attn_norm", "mlp_norm", "w_gate", "w_up", "w_down", "wq", "wk", "wv",
+        "wo", "mamba_in", "mamba_conv_w", "mamba_conv_b", "mamba_x",
+        "mamba_dt_w", "mamba_dt_b", "mamba_A_log", "mamba_D", "mamba_dt_norm",
+        "mamba_b_norm", "mamba_c_norm", "mamba_out",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    for i, op in enumerate(cfg.op_kinds):
+        p = f"model.layers.{i}."
+        layers["attn_norm"].append(get(p + "input_layernorm.weight"))
+        layers["mlp_norm"].append(get(p + "pre_ff_layernorm.weight"))
+        for key, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj")):
+            layers[key].append(linear(f"{p}feed_forward.{name}.weight"))
+        if op == "attn":
+            for key, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj")):
+                layers[key].append(linear(f"{p}self_attn.{name}.weight"))
+            continue
+        m = p + "mamba."
+        layers["mamba_in"].append(linear(m + "in_proj.weight"))
+        layers["mamba_conv_w"].append(get(m + "conv1d.weight")[:, 0, :].T)  # [K, E]
+        layers["mamba_conv_b"].append(get(m + "conv1d.bias"))
+        layers["mamba_x"].append(linear(m + "x_proj.weight"))
+        layers["mamba_dt_w"].append(linear(m + "dt_proj.weight"))
+        layers["mamba_dt_b"].append(get(m + "dt_proj.bias"))
+        layers["mamba_A_log"].append(get(m + "A_log").T)  # [S, E]
+        layers["mamba_D"].append(get(m + "D"))
+        for key, name in (("mamba_dt_norm", "dt"), ("mamba_b_norm", "b"), ("mamba_c_norm", "c")):
+            layers[key].append(get(f"{m}{name}_layernorm.weight"))
+        layers["mamba_out"].append(linear(m + "out_proj.weight"))
+    params = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items() if v},
+        "final_norm": get("model.final_layernorm.weight"),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = linear("lm_head.weight")
+    return params
+
+
 SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
-    "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe",
+    "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe", "jamba",
 )
 
 
@@ -131,8 +185,9 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         return get(name).T
 
     pre = "model."
-    if cfg.model_type == "lfm2_moe":
-        params = _lfm2_moe_params(cfg, get, linear)
+    by_kind = {"lfm2_moe": _lfm2_moe_params, "jamba": _jamba_params}
+    if cfg.model_type in by_kind:
+        params = by_kind[cfg.model_type](cfg, get, linear)
         handles.clear()
         return _host_params(params, dt), cfg
     L = cfg.num_layers

@@ -72,6 +72,17 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             use_expert_bias=cfg.use_expert_bias,
             routed_scaling_factor=cfg.routed_scaling_factor,
         )
+    if cfg.model_type == "jamba":  # the family's own keys
+        attn = [i for i, k in enumerate(cfg.op_kinds) if k == "attn"]
+        hf.update(
+            architectures=["JambaForCausalLM"],
+            attn_layer_offset=attn[0],
+            attn_layer_period=attn[1] - attn[0] if len(attn) > 1 else cfg.num_layers,
+            num_experts=1, num_experts_per_tok=1, hidden_act="silu",
+            mamba_d_state=cfg.mamba_d_state, mamba_d_conv=cfg.mamba_d_conv,
+            mamba_expand=cfg.mamba_expand, mamba_dt_rank=cfg.mamba_dt_rank,
+            mamba_conv_bias=True, mamba_proj_bias=False, sliding_window=None,
+        )
     if cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -80,7 +91,7 @@ def write_config(path: str, cfg: ModelConfig) -> None:
         raise ValueError(
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
-            "the llama family and lfm2_moe only"
+            "the llama family, lfm2_moe and jamba only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

@@ -195,13 +195,18 @@ def _ragged_kernel(
     # Padding queries carry -1: nothing satisfies kv_pos <= -1, so with
     # ``p`` masked below their softmax sum stays 0 and the final divide
     # maps them to zeros, whatever the pages the tile brought in hold.
-    pos_col = jnp.stack(
-        [positions_ref[t * qt + i] for i in range(qt)]
-    )  # [QT]
     # Score rows are laid out (kv head, group, query): each head's
     # block is contiguous, and within it the query index varies
-    # fastest — so the per-query causal bound tiles as [qpk*QT].
-    pos_rows = jnp.tile(pos_col, qpk)[:, None]  # [qpk*QT, 1]
+    # fastest — so row r's causal bound is query r % QT's position.
+    # Built as a column from the start: a flat [qpk*QT] vector tiled
+    # from [QT] outgrows one lane tile at 20 query heads a group
+    # (Mosaic: "input offsets outside of the first tile").
+    query = jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (qpk * qt, 1), 0), qt
+    )
+    pos_rows = jnp.zeros((qpk * qt, 1), jnp.int32)
+    for i in range(qt):
+        pos_rows = jnp.where(query == i, positions_ref[t * qt + i], pos_rows)
 
     # [QT, H, D] -> [H', QT, D] with H' rows ordered (kv head, group):
     # per-kv-head slices are then contiguous row blocks.

@@ -244,13 +244,29 @@ class Telemetry:
             registry=self.registry,
         )
         # Sequence state beside the pages (a model with layers that are
-        # not attention: lfm2_moe's short convolution; all zero for any
-        # other model). Host counts from the page manager and the
-        # admission path.
+        # not attention: a short convolution's products, a selective
+        # scan's state; all zero for any other model). Host counts from
+        # the page manager and the admission path; every part of a state
+        # moves together, so one series covers them.
         self.seq_state_slots = Gauge(
             "dynamo_seq_state_slots_in_use",
             "Entries of the sequence-state pool bound to a sequence "
             "(one a decode slot holder; 0 for a model without such state)",
+            registry=self.registry,
+        )
+        self.seq_state_pool_bytes = Gauge(
+            "dynamo_seq_state_pool_bytes",
+            "Device bytes of the sequence-state pool, all parts and "
+            "entries (live and snapshots); 0 for a model without such state",
+            registry=self.registry,
+        )
+        self.ssm_scan_tokens = Counter(
+            "dynamo_ssm_scan_tokens_total",
+            "Tokens a dispatch put through the selective scan of each Mamba "
+            "layer (its live slots: dynamo_ragged_slots_total{kind=live} "
+            "for a model with such layers), by program: mixed is prompt "
+            "chunks and the decode rows beside them, window is decode alone",
+            ["program"],  # window | mixed
             registry=self.registry,
         )
         self.seq_state_restores = Counter(
