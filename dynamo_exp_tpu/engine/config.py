@@ -155,8 +155,11 @@ class EngineConfig:
     # Run the Pallas kernel in interpreter mode (CPU correctness tests).
     pallas_interpret: bool = False
     # Prefill batching/chunking: up to ``prefill_batch`` sequences share
-    # one prefill dispatch; prompts are fed ``prefill_chunk`` tokens at a
-    # time so decode interleaves between chunks of long prompts.
+    # one prefill dispatch, and decode interleaves between chunks of long
+    # prompts. ``prefill_chunk`` is the prompt tokens a prefilling row is
+    # guaranteed a dispatch, not its ceiling: a row with more prompt left
+    # also takes, in whole pages and oldest first, what the dispatch's
+    # token bucket would have left empty (``engine._plan_chunks``).
     prefill_batch: int = 8
     prefill_chunk: int = 512
     # Decode steps per dispatch: one compiled window runs this many

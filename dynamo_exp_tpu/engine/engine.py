@@ -1037,6 +1037,56 @@ class TPUEngine(AsyncEngine):
             return aligned
         return max(aligned, nb - self.cfg.ragged_packed_slots(nb))
 
+    def _plan_chunks(
+        self,
+        remaining: list[int],
+        admitted: list[float],
+        singles: int,
+        spans: list[int],
+    ) -> list[int]:
+        """Prompt tokens each prefilling row of a mixed dispatch feeds,
+        given every row's prompt left (``remaining``) and when it was
+        admitted, the decode rows beside them (``singles``) and the
+        speculative spans' lengths (``spans``).
+
+        ``cfg.prefill_chunk`` is the share every row is guaranteed. The
+        token bucket those shares take (``_mixed_bucket_for``, as ever)
+        is a power of two, and what it leaves empty is paid for all the
+        same: a slot is a row of every projection whether a token lies
+        in it or not. So the room goes to rows with prompt left beyond
+        their share, in whole pages (a chunk then ends on a block
+        boundary wherever the share alone would have, which a state's
+        snapshot and the scan kernel's writes rest on) or up to the
+        prompt's end, the row admitted earliest first: it reaches its
+        first token soonest and leaves the next dispatch smaller. The
+        bucket stays the one the shares took."""
+        cfg = self.cfg
+        align, ps = self._ragged_align(), cfg.page_size
+
+        def up(n: int) -> int:
+            return -(-n // align) * align
+
+        chunks = [min(n, cfg.prefill_chunk) for n in remaining]
+        aligned = sum(map(up, chunks)) + sum(map(up, spans))
+        nb = self._mixed_bucket_for(aligned, singles)
+        # Spans lie before the decode rows, which a kernel engine reads
+        # from the packed tail; spans that already run on into the tail
+        # leave no room.
+        tiled = nb - singles
+        if singles and self._attn_impl == "pallas":
+            tiled = nb - cfg.ragged_packed_slots(nb)
+        room = tiled - aligned
+        if room <= 0:
+            return chunks
+        for i in sorted(range(len(chunks)), key=admitted.__getitem__):
+            more = remaining[i] - chunks[i]
+            if more > room:
+                more = room // ps * ps
+            room -= up(chunks[i] + more) - up(chunks[i])
+            chunks[i] += more
+        assert self._mixed_bucket_for(tiled - room, singles) == nb, (chunks, nb)
+        return chunks
+
     def _run_program(self, fn, state_rows, *args) -> tuple:
         """Call a ragged program. For a model with sequence state it also
         gets the state pool and ``state_rows`` (a window: each row's entry
@@ -3633,9 +3683,14 @@ class TPUEngine(AsyncEngine):
             chunks: list[tuple[Sequence, int, int]] = []  # (seq, from, n)
             max_pages = 1
             r = 0
-            for seq in pf:
+            sizes = self._plan_chunks(
+                [len(seq.prompt) - seq.prefill_sent for seq in pf],
+                [seq.admitted_at for seq in pf],
+                len(dec),
+                [len(drafts) + 1 for *_, drafts in spec],
+            )
+            for seq, n in zip(pf, sizes):
                 self._apply_uploads(seq)
-                n = min(len(seq.prompt) - seq.prefill_sent, cfg.prefill_chunk)
                 start_tok = seq.prefill_sent
                 qs = add_span(
                     list(seq.prompt[start_tok : start_tok + n]),
@@ -3796,11 +3851,17 @@ class TPUEngine(AsyncEngine):
         )
         # Name this dispatch on the pages its prompt chunks wrote, for
         # the admission record of whoever attaches them later.
+        tel = get_telemetry()
         for seq, start_tok, n in chunks:
             self.kv.note_writer(
                 seq.page_ids[start_tok // ps : (start_tok + n - 1) // ps + 1],
                 f"mixed:nb={nb}:computed={n}:cached={start_tok}",
             )
+            # What the row was guaranteed, and what the bucket's empty
+            # slots gave it beyond that (_plan_chunks).
+            share = min(n, cfg.prefill_chunk)
+            tel.prefill_chunk_tokens.labels("share").inc(share)
+            tel.prefill_chunk_tokens.labels("fill").inc(n - share)
         # Pages this batch's chunks fully covered are now filled *in
         # dispatch order*: sharers gated on them may dispatch reads
         # from the next iteration on (docs/prefix_sharing.md).
@@ -3815,9 +3876,7 @@ class TPUEngine(AsyncEngine):
         self.steps += 1
         if spec:
             self.spec_dispatches += 1
-        get_telemetry().decode_batch_rows.labels("mixed").observe(
-            len(dec) + len(spec)
-        )
+        tel.decode_batch_rows.labels("mixed").observe(len(dec) + len(spec))
         return _PendingRagged(
             ys=ys,
             rows=rows,

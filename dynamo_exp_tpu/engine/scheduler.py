@@ -8,8 +8,10 @@ Policy (v2): **batched, chunked, decode-interleaved prefill.** Waiting
 prompts are admitted to slots as soon as pages are available and then
 prefilled in bucketed chunks, several sequences per dispatch — so a
 burst of arrivals shares prefill forwards instead of serializing, and a
-long prompt is fed ``prefill_chunk`` tokens at a time so decode steps
-interleave between chunks instead of stalling behind one giant forward.
+long prompt is fed a chunk at a time so decode steps interleave between
+chunks instead of stalling behind one giant forward: ``prefill_chunk``
+tokens guaranteed a dispatch, and whatever the dispatch's token bucket
+would have left empty besides (``engine._plan_chunks``).
 Decode runs every loop iteration over all ACTIVE slots; sequences whose
 prompt is still being chunked sit in PREFILL state and don't join decode
 until their first token is sampled.

@@ -224,6 +224,17 @@ class Telemetry:
             ["program", "kind"],  # window | mixed; live | pad
             registry=self.registry,
         )
+        self.prefill_chunk_tokens = Counter(
+            "dynamo_prefill_chunk_tokens_total",
+            "Prompt tokens mixed dispatches carried by kind: share (up "
+            "to prefill_chunk a prefilling row, what every row is "
+            "guaranteed a dispatch) or fill (what a row took beyond "
+            "that from slots its dispatch's token bucket would have "
+            "left empty: engine._plan_chunks). fill / all says how "
+            "often the filling engages",
+            ["kind"],  # share | fill
+            registry=self.registry,
+        )
         self.moe_rows = Counter(
             "dynamo_moe_rows_total",
             "Rows of routed-expert models' dispatches by kind: live "

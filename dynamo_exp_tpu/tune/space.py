@@ -65,7 +65,8 @@ KNOBS: tuple[Knob, ...] = (
          note="sequences sharing one prefill dispatch"),
     Knob("prefill_chunk", "engine", "int", (128, 256, 512, 1024),
          sim=False,
-         note="prompt tokens fed per chunk (decode interleaves between)"),
+         note="prompt tokens a row is guaranteed per dispatch (more where "
+              "the token bucket has room; decode interleaves between)"),
     Knob("decode_window", "engine", "int", (4, 8, 16, 32), sim=False,
          note="decode steps per compiled dispatch (host syncs once)"),
     Knob("preempt_stall_grace_s", "engine", "float",

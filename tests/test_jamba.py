@@ -468,6 +468,17 @@ async def test_second_asking_restores_both_parts(engine, asking):
     assert engine.kv.prefix_hits["shared"] - before[1] == 4  # 32 of its tokens
 
 
+async def test_a_snapshot_an_extended_chunk_wrote_restores(engine):
+    """A chunk that took its bucket's empty page leaves its snapshot where
+    chunks of ``prefill_chunk`` would not have, and a second asking
+    restores from it (``tests/test_chunk_fill.py`` has the steps)."""
+    from .test_chunk_fill import restore_from_an_extended_chunks_snapshot
+
+    await restore_from_an_extended_chunks_snapshot(
+        engine, _run, _greedy_reference, PROMPT[50:59], PROMPT[2:43]
+    )
+
+
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 async def test_preempt_and_resume(impl):
     """Two requests on a pool that holds one and a half: one is preempted

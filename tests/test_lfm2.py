@@ -310,6 +310,17 @@ async def test_hit_is_shortened_to_a_held_state(engine):
     assert engine.kv.state_hits["shortened"] == before["shortened"] + 1
 
 
+async def test_a_snapshot_an_extended_chunk_wrote_restores(engine):
+    """A chunk that took its bucket's empty page leaves its snapshot where
+    chunks of ``prefill_chunk`` would not have, and a second asking
+    restores from it (``tests/test_chunk_fill.py`` has the steps)."""
+    from .test_chunk_fill import restore_from_an_extended_chunks_snapshot
+
+    await restore_from_an_extended_chunks_snapshot(
+        engine, _run, _greedy_reference, PROMPT[20:29], PROMPT[1:40]
+    )
+
+
 async def test_preempt_and_resume():
     """Two requests on a pool that holds one and a half: one is preempted,
     re-admitted onto its own cached pages with the state restored (or
