@@ -243,6 +243,16 @@ def _no_phase(name: str):
     return _NO_PHASE
 
 
+def _no_part(phase: str, name: str):
+    """``DispatchProfiler.part`` of such an engine."""
+    return _NO_PHASE
+
+
+def _h2d(*arrays) -> tuple:
+    """A launch's host arrays on the device, in the order given."""
+    return tuple(jnp.asarray(a) for a in arrays)
+
+
 class TPUEngine(AsyncEngine):
     """Continuous-batching paged-KV engine on a TPU mesh."""
 
@@ -382,6 +392,9 @@ class TPUEngine(AsyncEngine):
         )
         self._phase = (
             self.profiler.phase if self.profiler is not None else _no_phase
+        )
+        self._part = (
+            self.profiler.part if self.profiler is not None else _no_part
         )
         self.flight = FlightRecorder()
         self.sched = Scheduler(cfg, self.kv, flight=self.flight)
@@ -1087,16 +1100,23 @@ class TPUEngine(AsyncEngine):
         assert self._mixed_bucket_for(tiled - room, singles) == nb, (chunks, nb)
         return chunks
 
+    def _state_rows_h2d(self, state_rows):
+        """``state_rows`` of a dispatch on the device, for a model with
+        sequence state (the last of a launch's transfers); None for any
+        other, whose program takes none."""
+        if self.seq_state is None:
+            return None
+        return jax.tree.map(jnp.asarray, state_rows)
+
     def _run_program(self, fn, state_rows, *args) -> tuple:
         """Call a ragged program. For a model with sequence state it also
-        gets the state pool and ``state_rows`` (a window: each row's entry
-        [nb]; a mixed dispatch: ``forward_ragged``'s ``state_rows``), and
-        the pool it returns replaces the engine's."""
+        gets the state pool and ``state_rows`` from ``_state_rows_h2d`` (a
+        window: each row's entry [nb]; a mixed dispatch:
+        ``forward_ragged``'s ``state_rows``), and the pool it returns
+        replaces the engine's."""
         if self.seq_state is None:
             return fn(*args)
-        *out, self.seq_state = fn(
-            *args, self.seq_state, jax.tree.map(jnp.asarray, state_rows)
-        )
+        *out, self.seq_state = fn(*args, self.seq_state, state_rows)
         return tuple(out)
 
     def _count_dispatch(
@@ -1332,7 +1352,8 @@ class TPUEngine(AsyncEngine):
             logprobs=None,  # (lps: list[float], tops: list[dict]) | None
         ) -> None:
             loop.call_soon_threadsafe(
-                out_q.put_nowait, (tokens, reason, logprobs)
+                out_q.put_nowait,
+                (tokens, reason, logprobs, time.monotonic()),
             )
 
         seq = Sequence(
@@ -1351,11 +1372,19 @@ class TPUEngine(AsyncEngine):
         self._submit_q.put(seq)
         self._wake.set()
         prompt_tokens = len(binput.token_ids)
+        # The engine thread's posting to this thread's taking: the
+        # program's own reading of what a client sees as a late chunk.
+        # Like the loop's phases it follows ``profile_dispatches``.
+        handoff = (
+            get_telemetry().emit_handoff if self.profiler is not None else None
+        )
 
         async def _gen() -> AsyncIterator[dict]:
             completion = 0
             while True:
-                tokens, reason, logprobs = await out_q.get()
+                tokens, reason, logprobs, posted = await out_q.get()
+                if handoff is not None:
+                    handoff.observe(time.monotonic() - posted)
                 if tokens:
                     completion += len(tokens)
                     yield LLMEngineOutput(
@@ -1772,7 +1801,7 @@ class TPUEngine(AsyncEngine):
         # consume helpers open their own (assemble / launch / sync /
         # consume), and none nests. What is left outside is the loop's
         # own overhead: a few branches an iteration.
-        phase = self._phase
+        phase, part = self._phase, self._part
         try:
             while self._running:
                 with phase("kv_admin"):
@@ -1795,7 +1824,7 @@ class TPUEngine(AsyncEngine):
                     # Steady state: launch the next window device-to-
                     # device, then consume the previous one while the
                     # new one executes.
-                    with phase("assemble"):
+                    with phase("assemble"), part("assemble", "plan"):
                         can_chain = self._can_chain()
                     nxt = (
                         self._dispatch_chained(self._inflight)
@@ -1868,10 +1897,15 @@ class TPUEngine(AsyncEngine):
                     )
                 if may_admit:
                     with phase("admit"):
-                        while (
-                            admitted := self.sched.admit_next()
-                        ) is not None:
-                            self._on_admitted(admitted)
+                        while True:
+                            with part("admit", "schedule"):
+                                admitted = self.sched.admit_next()
+                            if admitted is None:
+                                break
+                            with part("admit", "bind"):
+                                self._on_admitted(admitted)
+                            with part("admit", "record"):
+                                self._record_admission(admitted)
                 with phase("kv_admin"):
                     # Plan new prefetches over whatever is STILL waiting
                     # (couldn't admit: slots full or pool pressure) —
@@ -1988,17 +2022,6 @@ class TPUEngine(AsyncEngine):
             get_telemetry().seq_state_restores.labels(
                 "resume" if seq.preemptions else "prefix"
             ).inc()
-        self.flight.record(
-            "admit",
-            req=seq.request_id,
-            slot=seq.slot,
-            state_slot=seq.state_slot if seq.state_slot >= 0 else None,
-            state_from=seq.state_restore,
-            prompt=len(seq.prompt),
-            cached=seq.cached_len,
-            priority=seq.priority,
-            pages=self._attached_pages(seq),
-        )
         tel = get_telemetry()
         # Prompt tokens by source, from the allocation that just fed
         # kv.prefix_hits its pages: what the prefix cache saved in the
@@ -2016,6 +2039,21 @@ class TPUEngine(AsyncEngine):
                 seq.trace,
                 prompt_tokens=len(seq.prompt),
             )
+
+    def _record_admission(self, seq: Sequence) -> None:
+        """The flight recorder's ``admit`` event of a sequence
+        ``_on_admitted`` has just bound: what it got, page by page."""
+        self.flight.record(
+            "admit",
+            req=seq.request_id,
+            slot=seq.slot,
+            state_slot=seq.state_slot if seq.state_slot >= 0 else None,
+            state_from=seq.state_restore,
+            prompt=len(seq.prompt),
+            cached=seq.cached_len,
+            priority=seq.priority,
+            pages=self._attached_pages(seq),
+        )
 
     def _attached_pages(self, seq: Sequence) -> list[list]:
         """What an admission bound, for the flight recorder: one
@@ -3225,7 +3263,7 @@ class TPUEngine(AsyncEngine):
         syncs once per window, chainable device-to-device. Returns the
         pending dispatches; [] when nothing could step (pool dry / no
         ACTIVE or ready-PREFILL rows)."""
-        with self._phase("assemble"):
+        with self._phase("assemble"), self._part("assemble", "plan"):
             batches, solo = self._plan_ragged(prefill_rows)
         out: list[_PendingRagged] = []
         for fs, dec, spec, pf, windowed in batches:
@@ -3495,7 +3533,7 @@ class TPUEngine(AsyncEngine):
         """Build + dispatch one pure-decode windowed batch (no host
         sync): ``decode_window`` on-device steps over the compacted
         rows — the ragged family's one-query-per-row shape."""
-        with self._phase("assemble"):
+        with self._phase("assemble"), self._part("assemble", "build"):
             cfg = self.cfg
             ps, K, S = cfg.page_size, cfg.decode_window, cfg.device_stop_width
             nb = cfg.ragged_tokens_bucket_for(len(part))
@@ -3545,6 +3583,7 @@ class TPUEngine(AsyncEngine):
             want_lp = any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
+        with self._phase("assemble"), self._part("assemble", "lookup"):
             n_variants = len(self._ragged_fns)
             page_bucket = cfg.ragged_page_bucket_for(max_pages)
             fn = self._ragged_fn(nb, page_bucket, True, full_sampler, want_lp)
@@ -3555,29 +3594,24 @@ class TPUEngine(AsyncEngine):
         prof = self.profiler
         t0 = prof.begin("ragged", "window") if prof is not None else 0.0
         sampler_args = (seeds, temp, top_k, top_p, freq, pres, rep)
+        with self._part("launch", "h2d"):
+            args = _h2d(tokens, positions, max_pos, table)
+            if full_sampler:
+                args += (
+                    *_h2d(seeds), self._counts,
+                    *_h2d(slot_map, temp, top_k, top_p, freq, pres, rep),
+                )
+            args += _h2d(stop_set, eos_gate, budget_gate)
+            state_rows = self._state_rows_h2d(state_slots)
+        with self._part("launch", "call"):
+            out = self._run_program(
+                fn, state_rows, self.params, self.k_cache, self.v_cache, *args
+            )
         if full_sampler:
             (ys, self.k_cache, self.v_cache, self._counts,
-             tok_dev, pos_dev) = self._run_program(
-                fn, state_slots,
-                self.params, self.k_cache, self.v_cache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(max_pos), jnp.asarray(table),
-                jnp.asarray(seeds), self._counts, jnp.asarray(slot_map),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(freq), jnp.asarray(pres), jnp.asarray(rep),
-                jnp.asarray(stop_set), jnp.asarray(eos_gate),
-                jnp.asarray(budget_gate),
-            )
+             tok_dev, pos_dev) = out
         else:
-            (ys, self.k_cache, self.v_cache,
-             tok_dev, pos_dev) = self._run_program(
-                fn, state_slots,
-                self.params, self.k_cache, self.v_cache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(max_pos), jnp.asarray(table),
-                jnp.asarray(stop_set), jnp.asarray(eos_gate),
-                jnp.asarray(budget_gate),
-            )
+            ys, self.k_cache, self.v_cache, tok_dev, pos_dev = out
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
@@ -3628,7 +3662,14 @@ class TPUEngine(AsyncEngine):
         Decode rows advance one step here (the window resumes once the
         batch is pure decode again); prompts completing this chunk
         sample their first token in the same dispatch."""
-        with self._phase("assemble"):
+        with self._phase("assemble"), self._part("assemble", "plan"):
+            sizes = self._plan_chunks(
+                [len(seq.prompt) - seq.prefill_sent for seq in pf],
+                [seq.admitted_at for seq in pf],
+                len(dec),
+                [len(drafts) + 1 for *_, drafts in spec],
+            )
+        with self._phase("assemble"), self._part("assemble", "build"):
             cfg = self.cfg
             ps = cfg.page_size
             B1 = cfg.max_decode_slots + 1
@@ -3683,12 +3724,6 @@ class TPUEngine(AsyncEngine):
             chunks: list[tuple[Sequence, int, int]] = []  # (seq, from, n)
             max_pages = 1
             r = 0
-            sizes = self._plan_chunks(
-                [len(seq.prompt) - seq.prefill_sent for seq in pf],
-                [seq.admitted_at for seq in pf],
-                len(dec),
-                [len(drafts) + 1 for *_, drafts in spec],
-            )
             for seq, n in zip(pf, sizes):
                 self._apply_uploads(seq)
                 start_tok = seq.prefill_sent
@@ -3796,6 +3831,7 @@ class TPUEngine(AsyncEngine):
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
             with_spec = bool(spec)
+        with self._phase("assemble"), self._part("assemble", "lookup"):
             n_variants = len(self._ragged_fns)
             page_bucket = cfg.ragged_page_bucket_for(max_pages)
             fn = self._ragged_fn(
@@ -3807,31 +3843,29 @@ class TPUEngine(AsyncEngine):
                 self._flush_offloads()
         prof = self.profiler
         t0 = prof.begin("ragged", "mixed") if prof is not None else 0.0
-        state_rows = (state_slots, state_src, state_dst)
+        with self._part("launch", "h2d"):
+            args = _h2d(tokens, positions, row_of, table)
+            if full_sampler:
+                args += (
+                    *_h2d(q_last, pos0), self._counts,
+                    *_h2d(
+                        slot_map, is_decode, *sampler_arrs,
+                        spec_idx, spec_pos, spec_drafts, n_drafts,
+                    ),
+                )
+            else:
+                args += _h2d(q_last, spec_idx, spec_drafts, n_drafts)
+            state_rows = self._state_rows_h2d(
+                (state_slots, state_src, state_dst)
+            )
+        with self._part("launch", "call"):
+            out = self._run_program(
+                fn, state_rows, self.params, self.k_cache, self.v_cache, *args
+            )
         if full_sampler:
-            (ys, self.k_cache, self.v_cache,
-             self._counts) = self._run_program(
-                fn, state_rows,
-                self.params, self.k_cache, self.v_cache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(row_of), jnp.asarray(table),
-                jnp.asarray(q_last), jnp.asarray(pos0), self._counts,
-                jnp.asarray(slot_map), jnp.asarray(is_decode),
-                jnp.asarray(seeds), jnp.asarray(temp), jnp.asarray(top_k),
-                jnp.asarray(top_p), jnp.asarray(freq), jnp.asarray(pres),
-                jnp.asarray(rep), jnp.asarray(spec_idx),
-                jnp.asarray(spec_pos), jnp.asarray(spec_drafts),
-                jnp.asarray(n_drafts),
-            )
+            ys, self.k_cache, self.v_cache, self._counts = out
         else:
-            ys, self.k_cache, self.v_cache = self._run_program(
-                fn, state_rows,
-                self.params, self.k_cache, self.v_cache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(row_of), jnp.asarray(table),
-                jnp.asarray(q_last), jnp.asarray(spec_idx),
-                jnp.asarray(spec_drafts), jnp.asarray(n_drafts),
-            )
+            ys, self.k_cache, self.v_cache = out
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
@@ -3951,7 +3985,7 @@ class TPUEngine(AsyncEngine):
         the host skips at consume). Pages are provisioned one extra
         window ahead; returns None (chain break) when the pool can't
         cover a row."""
-        with self._phase("assemble"):
+        with self._phase("assemble"), self._part("assemble", "build"):
             cfg = self.cfg
             ps, K = cfg.page_size, cfg.decode_window
             nb = pending.nb
@@ -3980,6 +4014,7 @@ class TPUEngine(AsyncEngine):
                 rows.append(
                     _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
                 )
+        with self._phase("assemble"), self._part("assemble", "lookup"):
             n_variants = len(self._ragged_fns)
             page_bucket = cfg.ragged_page_bucket_for(max_pages)
             fn = self._ragged_fn(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
@@ -3995,30 +4030,28 @@ class TPUEngine(AsyncEngine):
                 self._flush_offloads()
         prof = self.profiler
         t0 = prof.begin("ragged", "window") if prof is not None else 0.0
+        with self._part("launch", "h2d"):
+            args = (
+                pending.tokens_dev, pending.positions_dev,
+                *_h2d(max_pos, table),
+            )
+            if pending.full_sampler:
+                seeds, *sampler_args = pending.sampler_args
+                args += (
+                    *_h2d(seeds), self._counts,
+                    *_h2d(pending.slot_map, *sampler_args),
+                )
+            args += _h2d(stop_set, eos_gate, budget_gate)
+            state_rows = self._state_rows_h2d(pending.state_slots)
+        with self._part("launch", "call"):
+            out = self._run_program(
+                fn, state_rows, self.params, self.k_cache, self.v_cache, *args
+            )
         if pending.full_sampler:
-            seeds, temp, top_k, top_p, freq, pres, rep = pending.sampler_args
             (ys, self.k_cache, self.v_cache, self._counts,
-             tok_dev, pos_dev) = self._run_program(
-                fn, pending.state_slots,
-                self.params, self.k_cache, self.v_cache,
-                pending.tokens_dev, pending.positions_dev,
-                jnp.asarray(max_pos), jnp.asarray(table),
-                jnp.asarray(seeds), self._counts, jnp.asarray(pending.slot_map),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(freq), jnp.asarray(pres), jnp.asarray(rep),
-                jnp.asarray(stop_set), jnp.asarray(eos_gate),
-                jnp.asarray(budget_gate),
-            )
+             tok_dev, pos_dev) = out
         else:
-            (ys, self.k_cache, self.v_cache,
-             tok_dev, pos_dev) = self._run_program(
-                fn, pending.state_slots,
-                self.params, self.k_cache, self.v_cache,
-                pending.tokens_dev, pending.positions_dev,
-                jnp.asarray(max_pos), jnp.asarray(table),
-                jnp.asarray(stop_set), jnp.asarray(eos_gate),
-                jnp.asarray(budget_gate),
-            )
+            ys, self.k_cache, self.v_cache, tok_dev, pos_dev = out
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
@@ -4085,11 +4118,16 @@ class TPUEngine(AsyncEngine):
             finishes: list[Sequence] = []
             wasted = 0
             emitted = 0
+            # The parts of this phase alternate row by row: summed here
+            # from clock reads, handed over once (PHASE_PARTS).
+            clock = time.monotonic
+            tokens_s = pages_s = emit_s = 0.0
             for e in pending.rows:
                 seq, n_valid, row = e.seq, e.n_valid, e.row
                 if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
                     wasted += n_valid  # whole window past this row's stop
                     continue
+                t0 = clock()
                 kept: list[int] = []
                 reason = None
                 for token in sampled[:n_valid, row]:
@@ -4102,7 +4140,9 @@ class TPUEngine(AsyncEngine):
                         break
                 wasted += n_valid - len(kept)
                 emitted += len(kept)
+                t1 = clock()
                 self.sched.register_full_pages(seq)
+                t2 = clock()
                 n_top = self._wants_logprobs(seq)
                 pack = None
                 if n_top is not None and kept:
@@ -4120,6 +4160,10 @@ class TPUEngine(AsyncEngine):
                         tel.time_between_tokens.observe(tbt)
                     seq.last_emit_at = now
                 seq.emit(kept, None, pack)
+                t3 = clock()
+                tokens_s += t1 - t0
+                pages_s += t2 - t1
+                emit_s += t3 - t2
                 if reason is not None:
                     seq.pending_finish = reason
                     finishes.append(seq)
@@ -4129,6 +4173,10 @@ class TPUEngine(AsyncEngine):
             if wasted:
                 self.wasted_steps += wasted
                 tel.decode_wasted_steps.inc(wasted)
+            if self.profiler is not None:
+                self.profiler.add_parts(
+                    "consume", tokens=tokens_s, pages=pages_s, emit=emit_s
+                )
         if finishes:
             # Pages about to be released must not have a window in
             # flight over them: sync the chained successor first (its
@@ -4137,7 +4185,7 @@ class TPUEngine(AsyncEngine):
             succ, self._inflight = self._inflight, None
             if succ is not None:
                 self._consume_ragged(succ)
-            with self._phase("consume"):
+            with self._phase("consume"), self._part("consume", "finish"):
                 for seq in finishes:
                     reason, seq.pending_finish = seq.pending_finish, None
                     self.sched.finish(seq, reason)
@@ -4171,11 +4219,15 @@ class TPUEngine(AsyncEngine):
                 "consume", dispatch="ragged", rows=len(pending.rows)
             )
             tel = get_telemetry()
+            # As in _consume_windowed: the parts alternate row by row.
+            clock = time.monotonic
+            tokens_s = pages_s = emit_s = first_s = finish_s = 0.0
             for e in pending.rows:
                 seq, r = e.seq, e.row
                 if e.kind == "prefill":
                     if not e.completing:
                         continue
+                    t0 = clock()
                     n_top = self._wants_logprobs(seq)
                     pack = (
                         self._lp_pack(
@@ -4188,17 +4240,21 @@ class TPUEngine(AsyncEngine):
                     self._finish_first_token(
                         seq, int(tok0[r]), pack, counted=e.counted
                     )
+                    first_s += clock() - t0
                     continue
                 if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
                     self.wasted_steps += 1
                     tel.decode_wasted_steps.inc()
                     continue
+                t0 = clock()
                 if e.kind == "decode":
                     token = int(tok0[r])
                     seq.tokens.append(token)
                     seq.generated += 1
                     reason = self.sched.check_stop(seq, token)
+                    t1 = clock()
                     self.sched.register_full_pages(seq)
+                    t2 = clock()
                     n_top = self._wants_logprobs(seq)
                     pack = (
                         self._lp_pack(
@@ -4215,8 +4271,13 @@ class TPUEngine(AsyncEngine):
                         )
                     seq.last_emit_at = now
                     seq.emit([token], None, pack)
+                    t3 = clock()
                     if reason is not None:
                         self.sched.finish(seq, reason)
+                        finish_s += clock() - t3
+                    tokens_s += t1 - t0
+                    pages_s += t2 - t1
+                    emit_s += t3 - t2
                     continue
                 # Speculative row: the device already computed the
                 # acceptance (longest draft == target prefix plus the first
@@ -4238,6 +4299,7 @@ class TPUEngine(AsyncEngine):
                     reason = self.sched.check_stop(seq, token)
                     if reason is not None:
                         break
+                tokens_s += clock() - t0
                 if n_emit - len(kept):
                     # Tokens past a host-detected stop: computed, discarded.
                     self.wasted_steps += n_emit - len(kept)
@@ -4261,7 +4323,9 @@ class TPUEngine(AsyncEngine):
                     emitted=len(kept),
                 )
                 self._spec.record(seq, proposed=g, accepted=accepted)
+                t1 = clock()
                 self.sched.register_full_pages(seq)
+                t2 = clock()
                 n_top = self._wants_logprobs(seq)
                 pack = None
                 if n_top is not None and kept:
@@ -4276,10 +4340,19 @@ class TPUEngine(AsyncEngine):
                         tel.time_between_tokens.observe(tbt)
                     seq.last_emit_at = now
                 seq.emit(kept, None, pack)
+                t3 = clock()
+                pages_s += t2 - t1
+                emit_s += t3 - t2
                 if reason is not None:
                     self.sched.finish(seq, reason)
+                    finish_s += clock() - t3
                 else:
                     self._rewind_spec_pages(seq)
+            if self.profiler is not None:
+                self.profiler.add_parts(
+                    "consume", tokens=tokens_s, pages=pages_s, emit=emit_s,
+                    first_token=first_s, finish=finish_s,
+                )
 
     # --------------------------------------------------------------- metrics
     def metrics(self) -> dict:

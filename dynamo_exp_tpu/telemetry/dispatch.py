@@ -47,6 +47,21 @@ one is, an event on the profiler's own clock in the loop thread's line
 of the host plane, beside the device's ``XLA Ops``. The ``launch`` phase
 is the interval ``begin``/``end`` already bracket at a ragged dispatch,
 so it costs no clock read of its own. Phases do not nest.
+
+**Inside a phase.** Beside its wall time a phase's CPU time
+(``time.thread_time()`` at the same two points) goes to
+``dynamo_engine_phase_cpu_seconds_total{phase}``: wall less CPU is time
+the loop thread did not run (it waited for the GIL, a lock or a system
+call, or was descheduled). The four phases a device's idle gaps name
+are cut into the named parts of :data:`PHASE_PARTS`
+(``dynamo_engine_phase_part_seconds_total{phase,part}``; a phase's
+parts and ``other`` sum to its wall seconds). A part that is one
+stretch is bracketed by ``part(phase, name)``, which also opens a
+``dynpart/<phase>.<name>`` annotation: not ``dyn/``, which a trace
+reader takes for a phase, and phases do not nest. Parts that alternate
+row by row are summed by the caller from its own clock reads and handed
+over once, by ``add_parts``. Either way the seconds wait in the profiler
+until the phase's exit books them.
 """
 
 from __future__ import annotations
@@ -87,6 +102,39 @@ PHASES = (
     "publish", "park",
 )
 
+# Named parts of the phases that a device's idle gaps name. Closed set:
+# the ``part`` label space under each ``phase`` (beside ``other``, what
+# the named parts leave of the phase) and the ``dynpart/<phase>.<part>``
+# names of a trace.
+#   consume   tokens       the appends and check_stop
+#             pages        register_full_pages
+#             emit         _lp_pack, the time-between-tokens stamp, seq.emit
+#             first_token  _finish_first_token, whole
+#             finish       sched.finish with everything it calls
+#   launch    h2d          the jnp.asarray transfers of a dispatch's arguments
+#             call         the jitted call until it returns
+#   admit     schedule     sched.admit_next
+#             bind         _on_admitted
+#             record       the flight recorder's admission record
+#   assemble  plan         _plan_ragged, _plan_chunks, _can_chain
+#             build        the host arrays of one dispatch
+#             lookup       the compiled-variant lookup
+PHASE_PARTS = {
+    "consume": ("tokens", "pages", "emit", "first_token", "finish"),
+    "launch": ("h2d", "call"),
+    "admit": ("schedule", "bind", "record"),
+    "assemble": ("plan", "build", "lookup"),
+}
+OTHER_PART = "other"
+
+# The thread's CPU clock is a system call (5 µs under a sandboxed kernel,
+# where the wall clock is 0.1 µs): a phase that opens within this long of
+# the last one's exit starts from the reading taken there. Phases follow
+# one another within microseconds, so a run of them costs one reading
+# each and not two; what the thread computed in between goes to the
+# phase that follows.
+CPU_REUSE_S = 50e-6
+
 # Summary stat fields (also the bench JSON / docs contract).
 SUMMARY_FIELDS = (
     "count",
@@ -104,10 +152,39 @@ class _Phase:
     :meth:`DispatchProfiler.phase`). One object a phase, reused: the
     loop is single-threaded and phases do not nest."""
 
-    __slots__ = ("_prof", "_name", "_label", "_ann", "_t0")
+    __slots__ = ("_prof", "_name", "_label", "_ann", "_t0", "_cpu0")
 
     def __init__(self, prof: "DispatchProfiler", name: str):
         self._prof, self._name, self._label = prof, name, f"dyn/{name}"
+        self._ann = None
+        self._t0 = self._cpu0 = 0.0
+
+    def __enter__(self):
+        self._ann = self._prof._annotation(self._label)
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        self._cpu0 = self._prof._cpu_at(self._t0)
+        return self
+
+    def __exit__(self, *exc):
+        cpu = self._prof._cpu_now() - self._cpu0
+        dt = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        self._prof._add_phase(self._name, dt, cpu)
+        return False
+
+
+class _Part:
+    """One contiguous stretch of a phase under a part's name (see
+    :meth:`DispatchProfiler.part`). One object a part, reused, like
+    :class:`_Phase`; parts do not nest either."""
+
+    __slots__ = ("_prof", "_open", "_name", "_label", "_ann", "_t0")
+
+    def __init__(self, prof: "DispatchProfiler", phase: str, name: str):
+        self._prof, self._name = prof, name
+        self._open = prof._open_parts[phase]
+        self._label = f"dynpart/{phase}.{name}"
         self._ann = None
         self._t0 = 0.0
 
@@ -118,9 +195,8 @@ class _Phase:
         return self
 
     def __exit__(self, *exc):
-        dt = time.monotonic() - self._t0
+        self._open[self._name] += time.monotonic() - self._t0
         self._ann.__exit__(*exc)
-        self._prof._add_phase(self._name, dt)
         return False
 
 
@@ -159,7 +235,42 @@ class DispatchProfiler:
         self._annotation = TraceAnnotation
         self._phases = {name: _Phase(self, name) for name in PHASES}
         self._phase_s = dict.fromkeys(PHASES, 0.0)
+        self._phase_cpu_s = dict.fromkeys(PHASES, 0.0)
+        # phase -> {part: seconds of the open (or next) phase, not yet
+        # booked}; _add_phase books and zeroes them.
+        self._open_parts = {
+            phase: dict.fromkeys(parts, 0.0)
+            for phase, parts in PHASE_PARTS.items()
+        }
+        self._parts = {
+            (phase, part): _Part(self, phase, part)
+            for phase, parts in PHASE_PARTS.items()
+            for part in parts
+        }
+        self._part_s = {
+            (phase, part): 0.0
+            for phase, parts in PHASE_PARTS.items()
+            for part in (*parts, OTHER_PART)
+        }
+        # The counters' labelled children, looked up once: a phase's
+        # exit books up to eight values, a few dozen an iteration.
+        self._wall_c = self._cpu_c = self._part_c = None
+        if telemetry is not None:
+            self._wall_c = {
+                p: telemetry.engine_phase_seconds.labels(p) for p in PHASES
+            }
+            self._cpu_c = {
+                p: telemetry.engine_phase_cpu_seconds.labels(p) for p in PHASES
+            }
+            self._part_c = {
+                key: telemetry.engine_phase_part_seconds.labels(*key)
+                for key in self._part_s
+            }
         self._launch_ann = None  # open between begin() and end() of a launch
+        self._launch_cpu0 = 0.0
+        # The CPU clock's last reading and the wall clock beside it.
+        self._cpu_last = 0.0
+        self._cpu_read_at = float("-inf")
 
     # -------------------------------------------------------------- phases
     def phase(self, name: str) -> _Phase:
@@ -171,14 +282,76 @@ class DispatchProfiler:
         ``begin``/``end`` at a ragged dispatch."""
         return self._phases[name]
 
-    def _add_phase(self, name: str, seconds: float) -> None:
+    def part(self, phase: str, name: str) -> _Part:
+        """Context manager round one contiguous stretch of an open
+        ``phase`` (names from :data:`PHASE_PARTS`; anything else raises
+        ``KeyError``): its seconds go to
+        ``dynamo_engine_phase_part_seconds_total{phase,part}`` at the
+        phase's exit and, while a profiler session is on, a
+        ``dynpart/<phase>.<name>`` event goes to the trace."""
+        return self._parts[phase, name]
+
+    def add_parts(self, phase: str, **seconds: float) -> None:
+        """Seconds of parts that alternate row by row inside an open
+        ``phase``, summed by the caller from its own clock reads: one
+        call a phase, no annotation."""
+        open_ = self._open_parts[phase]
+        for name, s in seconds.items():
+            if name not in open_:
+                raise KeyError((phase, name))
+            open_[name] += s
+
+    def _cpu_now(self) -> float:
+        """The loop thread's CPU clock, at a phase's exit: read inside
+        the wall clock's interval, so that wall less CPU is not negative
+        by the reads' own cost."""
+        self._cpu_last = cpu = time.thread_time()
+        self._cpu_read_at = time.monotonic()
+        return cpu
+
+    def _cpu_at(self, now: float) -> float:
+        """The same at a phase's entry, ``now`` on the wall clock: the
+        last exit's reading when that was under ``CPU_REUSE_S`` ago."""
+        if now - self._cpu_read_at < CPU_REUSE_S:
+            return self._cpu_last
+        return time.thread_time()
+
+    def _add_phase(self, name: str, seconds: float, cpu: float) -> None:
         self._phase_s[name] += seconds
-        if self._tel is not None:
-            self._tel.engine_phase_seconds.labels(name).inc(seconds)
+        self._phase_cpu_s[name] += cpu
+        if self._wall_c is not None:
+            self._wall_c[name].inc(seconds)
+            self._cpu_c[name].inc(cpu)
+        open_ = self._open_parts.get(name)
+        if open_ is None:
+            return
+        # ``other`` is what the named parts leave, so that the parts of
+        # a phase sum to it. Each part was timed inside the phase on the
+        # same clock: the rest is negative only by rounding.
+        rest = seconds
+        for part, s in open_.items():
+            if s:
+                open_[part] = 0.0
+                rest -= s
+                self._book_part(name, part, s)
+        self._book_part(name, OTHER_PART, max(rest, 0.0))
+
+    def _book_part(self, phase: str, part: str, seconds: float) -> None:
+        self._part_s[phase, part] += seconds
+        if self._part_c is not None:
+            self._part_c[phase, part].inc(seconds)
 
     def phase_seconds(self) -> dict[str, float]:
         """Host seconds so far by phase (every phase always present)."""
         return dict(self._phase_s)
+
+    def phase_cpu_seconds(self) -> dict[str, float]:
+        """The loop thread's CPU seconds so far by phase."""
+        return dict(self._phase_cpu_s)
+
+    def part_seconds(self) -> dict[tuple[str, str], float]:
+        """Host seconds so far by (phase, part), ``other`` included."""
+        return dict(self._part_s)
 
     # ------------------------------------------------------------ dispatch
     def begin(self, kind: str, program: str = NO_PROGRAM) -> float:
@@ -194,6 +367,8 @@ class DispatchProfiler:
             self._launch_ann = self._annotation("dyn/launch")
             self._launch_ann.__enter__()
         now = time.monotonic()
+        if program != NO_PROGRAM:
+            self._launch_cpu0 = self._cpu_at(now)
         last = self._last_consume.get(kind)
         self._event_seq += 1
         if last is not None and last[1] == self._event_seq - 1:
@@ -209,11 +384,13 @@ class DispatchProfiler:
         first-compile duration (jit traces/compiles synchronously inside
         the call; steady-state calls only enqueue). Returns the
         dispatch-completion timestamp for :meth:`consume`."""
+        launching = self._launch_ann is not None
+        cpu = self._cpu_now() - self._launch_cpu0 if launching else 0.0
         now = time.monotonic()
-        if self._launch_ann is not None:
+        if launching:
             self._launch_ann.__exit__(None, None, None)
             self._launch_ann = None
-            self._add_phase("launch", max(now - t0, 0.0))
+            self._add_phase("launch", max(now - t0, 0.0), cpu)
         self._count[kind] += 1
         if fresh:
             dur = max(now - t0, 0.0)

@@ -42,6 +42,10 @@ _STAGE_BUCKETS = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
     0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0,
 )
+_HANDOFF_BUCKETS = (
+    0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+    0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
+)
 _TBT_BUCKETS = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
 )
@@ -665,6 +669,29 @@ class Telemetry:
             "Host seconds of the engine loop's thread, by loop phase "
             "(sync = waiting for a dispatch's outputs, park = no work)",
             ["phase"],  # telemetry/dispatch.py PHASES
+            registry=self.registry,
+        )
+        self.engine_phase_cpu_seconds = Counter(
+            "dynamo_engine_phase_cpu_seconds_total",
+            "CPU seconds of the engine loop's thread, by loop phase: a "
+            "phase's host seconds less these it did not run (waiting "
+            "for the GIL, a lock or a system call, or descheduled)",
+            ["phase"],
+            registry=self.registry,
+        )
+        self.engine_phase_part_seconds = Counter(
+            "dynamo_engine_phase_part_seconds_total",
+            "Host seconds of the engine loop's thread inside a phase, "
+            "by named part; a phase's parts (other = what the named "
+            "ones leave) sum to its dynamo_engine_phase_seconds_total",
+            ["phase", "part"],  # telemetry/dispatch.py PHASE_PARTS
+            registry=self.registry,
+        )
+        self.emit_handoff = Histogram(
+            "dynamo_engine_emit_handoff_seconds",
+            "From the engine loop posting a request's tokens to the "
+            "asyncio thread taking them off the request's queue",
+            buckets=_HANDOFF_BUCKETS,
             registry=self.registry,
         )
         self.kv_pool_info = Gauge(

@@ -7,6 +7,7 @@ dispatch histograms, JAX's own compile events, prompt tokens by source,
 the KV pools' geometry, and named scopes that are metadata only.
 """
 
+import asyncio
 import contextlib
 import re
 import time
@@ -25,6 +26,8 @@ from dynamo_exp_tpu.protocols.common import BackendInput
 from dynamo_exp_tpu.telemetry import Telemetry, get_telemetry
 from dynamo_exp_tpu.telemetry.dispatch import (
     NO_PROGRAM,
+    OTHER_PART,
+    PHASE_PARTS,
     PHASES,
     PROGRAMS,
     DispatchProfiler,
@@ -99,6 +102,84 @@ def test_phase_adds_its_host_time_and_launch_rides_begin_end():
         'dynamo_dispatch_seconds_count{kind="ragged",program="window"} 1.0'
         in rendered
     )
+
+
+def test_parts_of_a_phase_sum_to_it_and_other_takes_the_rest():
+    tel = Telemetry(CollectorRegistry())
+    prof = DispatchProfiler(tel)
+    with prof.phase("consume"):
+        time.sleep(0.002)  # no part's: other, with what add_parts leaves
+        with prof.part("consume", "finish"):
+            time.sleep(0.002)
+        prof.add_parts("consume", tokens=0.0004, emit=0.0003)
+    t0 = prof.begin("ragged", "mixed")
+    with prof.part("launch", "h2d"):
+        time.sleep(0.001)
+    with prof.part("launch", "call"):
+        time.sleep(0.001)
+    prof.end("ragged", t0)
+    with prof.phase("intake"):  # a phase without parts books none
+        pass
+    phases, parts = prof.phase_seconds(), prof.part_seconds()
+    assert {ph for ph, _ in parts} == set(PHASE_PARTS)
+    for phase, names in PHASE_PARTS.items():
+        assert {p for ph, p in parts if ph == phase} == {*names, OTHER_PART}
+        assert sum(
+            s for (ph, _), s in parts.items() if ph == phase
+        ) == pytest.approx(phases[phase], rel=1e-9)
+    assert all(s >= 0.0 for s in parts.values())
+    assert parts["consume", "finish"] >= 0.002
+    assert parts["consume", "tokens"] == 0.0004
+    assert parts["consume", "emit"] == 0.0003
+    assert parts["consume", OTHER_PART] >= 0.001
+    assert parts["consume", "pages"] == 0.0
+    assert parts["launch", "h2d"] >= 0.001 and parts["launch", "call"] >= 0.001
+    # A part is booked at its phase's exit, never before: one opened
+    # and closed with the phase still open has not reached /metrics.
+    with prof.phase("admit"):
+        with prof.part("admit", "record"):
+            pass
+        assert prof.part_seconds()["admit", "record"] == 0.0
+    assert prof.part_seconds()["admit", "record"] > 0.0
+    rendered = tel.render().decode()
+    assert (
+        'dynamo_engine_phase_part_seconds_total{part="finish",phase="consume"}'
+        in rendered
+    )
+    assert 'dynamo_engine_phase_cpu_seconds_total{phase="consume"}' in rendered
+
+
+@pytest.mark.parametrize(
+    "phase, part",
+    [
+        ("consume", "other"),  # what is left, not a part to open
+        ("consume", "h2d"),  # another phase's
+        ("launch", "transfer"),
+        ("sync", "wait"),  # a phase without parts
+    ],
+)
+def test_an_unknown_part_raises(phase, part):
+    prof = DispatchProfiler(Telemetry(CollectorRegistry()))
+    with pytest.raises(KeyError):
+        prof.part(phase, part)
+    with pytest.raises(KeyError):
+        prof.add_parts(phase, **{part: 0.1})
+
+
+def test_a_sleeping_phase_takes_wall_time_and_no_cpu_time():
+    """The CPU counter's own control: a phase that sleeps reads its
+    wall time and next to no CPU time; one that computes reads both."""
+    prof = DispatchProfiler(Telemetry(CollectorRegistry()))
+    with prof.phase("park"):
+        time.sleep(0.05)
+    with prof.phase("intake"):
+        t_end = time.thread_time() + 0.02
+        while time.thread_time() < t_end:
+            pass
+    wall, cpu = prof.phase_seconds(), prof.phase_cpu_seconds()
+    assert wall["park"] >= 0.05 and cpu["park"] < 0.1 * wall["park"]
+    assert cpu["intake"] >= 0.02 and wall["intake"] >= cpu["intake"] * 0.99
+    assert set(cpu) == set(PHASES)
 
 
 def test_jax_compile_listener_counts_a_forced_retrace():
@@ -192,6 +273,97 @@ async def test_phases_cover_the_loop_and_do_not_nest(monkeypatch):
         sum(engine.profiler.phase_seconds().values()), rel=1e-6
     )
     assert counted == pytest.approx(covered, rel=0.2)
+
+
+async def test_an_engines_parts_sum_to_their_phases_on_metrics():
+    """Two requests through mixed dispatches, windows, first tokens and
+    finishes: on /metrics every part of a phase is there, none is
+    negative, and the parts of each phase add up to the phase."""
+    name = "dynamo_engine_phase_part_seconds_total"
+    before = _samples(name)
+    phase_before = _samples("dynamo_engine_phase_seconds_total")
+    engine = TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
+    engine.start()
+    try:
+        await _generate(engine, range(20, 44), max_tokens=12)
+        await _generate(engine, range(60, 70), max_tokens=6)
+    finally:
+        engine.stop()
+    after = _samples(name)
+    phase_after = _samples("dynamo_engine_phase_seconds_total")
+    got = {
+        (dict(k)["phase"], dict(k)["part"]): v - before.get(k, 0.0)
+        for k, v in after.items()
+    }
+    for phase, names in PHASE_PARTS.items():
+        mine = {part: s for (ph, part), s in got.items() if ph == phase}
+        assert set(mine) == {*names, OTHER_PART}, phase
+        assert all(s >= 0.0 for s in mine.values()), mine
+        key = (("phase", phase),)
+        assert sum(mine.values()) == pytest.approx(
+            phase_after[key] - phase_before.get(key, 0.0), rel=1e-6
+        ), phase
+        # Every named part ran at least once in this traffic.
+        assert all(mine[part] > 0.0 for part in names), mine
+    assert got == pytest.approx(engine.profiler.part_seconds(), rel=1e-6)
+
+
+async def test_a_parked_engine_takes_no_cpu_time_in_park():
+    engine = TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
+    engine.start()
+    try:
+        await _generate(engine, range(20, 30), max_tokens=4)
+        time.sleep(0.4)  # eight parks of 50 ms on the idle path
+    finally:
+        engine.stop()
+    wall = engine.profiler.phase_seconds()
+    cpu = engine.profiler.phase_cpu_seconds()
+    assert wall["park"] >= 0.3
+    assert cpu["park"] < 0.1 * wall["park"], (cpu["park"], wall["park"])
+    # A phase's CPU time is a part of its wall time (the two clocks'
+    # resolutions apart), and a working engine's loop took some.
+    assert all(cpu[p] <= wall[p] + 0.005 for p in PHASES), (cpu, wall)
+    assert sum(cpu.values()) > 0.0
+    on_metrics = _samples("dynamo_engine_phase_cpu_seconds_total")
+    assert {dict(k)["phase"] for k in on_metrics} == set(PHASES)
+
+
+async def test_the_handoff_histogram_counts_every_posted_tuple():
+    """One observation for each tuple the loop posts: a request's
+    chunks of tokens and the finish that ends it."""
+    name = "dynamo_engine_emit_handoff_seconds_count"
+    engine = TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
+    engine.start()
+    try:
+        posted = 0
+        for prompt, n in ((range(20, 44), 12), (range(60, 70), 6)):
+            b = BackendInput(token_ids=list(prompt))
+            b.stop_conditions.max_tokens = n
+            b.stop_conditions.ignore_eos = True
+            before = _total(name)
+            stream = await engine.generate(b.to_dict())
+            items = [item async for item in stream]
+            # A tuple carries tokens or the finish reason, never both.
+            assert all(
+                bool(i.get("token_ids")) != bool(i.get("finish_reason"))
+                for i in items
+            )
+            assert _total(name) - before == len(items) >= 3
+            posted += len(items)
+        assert _total("dynamo_engine_emit_handoff_seconds_sum") >= 0.0
+    finally:
+        engine.stop()
+    # An engine without the profiler stamps and observes nothing.
+    before = _total(name)
+    engine = TPUEngine(
+        _cfg(profile_dispatches=False), mesh=single_device_mesh(), seed=0
+    )
+    engine.start()
+    try:
+        assert await _generate(engine, range(20, 30), max_tokens=4) == 4
+    finally:
+        engine.stop()
+    assert _total(name) == before and posted > 0
 
 
 async def test_program_label_splits_ragged_and_sums_to_it():
@@ -342,3 +514,40 @@ def test_programs_hold_no_second_copy_of_the_pools(windowed):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * one_pool  # both donated, reused
     assert mem.temp_size_in_bytes < one_pool // 4
+
+
+# --------------------------------- the parts change no program either
+def _launched(profile: bool) -> dict[str, str]:
+    """The text each ragged program lowers to from the arguments an
+    engine's launches really pass it (a launch's transfers are made in
+    one tuple before the call; ``TPUEngine._run_program`` sees them)."""
+    engine = TPUEngine(
+        _cfg(model=TINY_QWEN3, profile_dispatches=profile),
+        mesh=single_device_mesh(), seed=0,
+    )
+    seen: dict[str, tuple] = {}
+    run_program = engine._run_program
+
+    def spy(fn, state_rows, *args):
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args
+        )
+        seen.setdefault(fn.__name__, (fn, shapes))
+        return run_program(fn, state_rows, *args)
+
+    engine._run_program = spy
+    engine.start()
+    try:
+        asyncio.run(_generate(engine, range(20, 40), max_tokens=13))
+    finally:
+        engine.stop()
+    return {name: fn.lower(*shapes).as_text() for name, (fn, shapes) in seen.items()}
+
+
+def test_the_parts_and_the_hoisted_transfers_change_no_program():
+    """With the profiler (phases, parts, CPU clock) and without it an
+    engine launches both ragged programs with arguments of the same
+    shapes and types, and they lower to the same text."""
+    with_parts, bare = _launched(True), _launched(False)
+    assert set(with_parts) == {"ragged_window", "ragged_mixed"} == set(bare)
+    assert with_parts == bare

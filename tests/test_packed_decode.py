@@ -22,6 +22,7 @@ attention output; through two layers and the head the logits agree to
 
 import asyncio
 import dataclasses
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -312,13 +313,15 @@ def test_packed_rows_of_a_model_with_state_read_their_own_entry_only():
 F32 = dataclasses.replace(TINY_QWEN3, dtype="float32")
 
 
-async def _ask(eng, prompt, n, **sampling):
+async def _ask(eng, prompt, n, arrived=None, **sampling):
     b = BackendInput(token_ids=list(prompt))
     b.stop_conditions.max_tokens = n
     b.stop_conditions.ignore_eos = True
     d = b.to_dict()
     d["sampling_options"].update(sampling)
     stream = await eng.generate(d)
+    if arrived is not None:
+        arrived.set()  # submitted: the loop's next intake finds it
     out = []
     async for item in stream:
         out += item.get("token_ids", [])
@@ -327,16 +330,41 @@ async def _ask(eng, prompt, n, **sampling):
 
 async def _serve(eng, sampling):
     """Two requests decoding when a third arrives, then a fourth: mixed
-    dispatches that carry decode rows beside a prompt's chunk."""
+    dispatches that carry decode rows beside a prompt's chunk.
+
+    Which rows ride together must not turn on how fast the two threads
+    run (a compiled window takes 10 ms, so the first two could be done
+    before a busy machine lets this coroutine see them decode: the test
+    failed that way once in a few whole runs). So the loop thread waits,
+    before the first dispatch at which a late request is due, until
+    that request has been submitted."""
     rs = np.random.RandomState(11)
     prompts = [list(rs.randint(3, 200, size=n)) for n in (21, 9, 13, 30)]
+
+    def decoding() -> int:
+        return sum(s is not None and s.generated > 0 for s in eng.sched.slots)
+
+    # When each late request is due, and whether it has been submitted.
+    late = [
+        (lambda: eng.steps >= 12, threading.Event()),  # both in their windows
+        (lambda: decoding() >= 3, threading.Event()),  # the third decodes too
+    ]
+    dispatch = eng._dispatch_ragged
+
+    def held(prefill_rows):
+        for due, arrived in late:
+            if due() and not arrived.is_set():
+                arrived.wait(timeout=60)
+        return dispatch(prefill_rows)
+
+    eng._dispatch_ragged = held
     first = [asyncio.ensure_future(_ask(eng, p, 40, **sampling)) for p in prompts[:2]]
-    while eng.steps < 12:  # both are in their windows
-        await asyncio.sleep(0.01)
-    third = asyncio.ensure_future(_ask(eng, prompts[2], 24, **sampling))
-    await asyncio.sleep(0.05)
-    fourth = asyncio.ensure_future(_ask(eng, prompts[3], 16, **sampling))
-    return await asyncio.wait_for(asyncio.gather(*first, third, fourth), timeout=300)
+    asked = []
+    for (due, arrived), prompt, n in zip(late, prompts[2:], (24, 16)):
+        while not due():
+            await asyncio.sleep(0.01)
+        asked.append(asyncio.ensure_future(_ask(eng, prompt, n, arrived, **sampling)))
+    return await asyncio.wait_for(asyncio.gather(*first, *asked), timeout=300)
 
 
 @pytest.mark.parametrize("sampling", [{"temperature": 0.0}, {"temperature": 0.8, "seed": 5}],
