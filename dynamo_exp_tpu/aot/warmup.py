@@ -36,6 +36,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .launch import launch_buffer
 from .lattice import CompileManifest
 
 log = logging.getLogger(__name__)
@@ -57,107 +58,46 @@ class PrewarmReport:
 
 
 # ------------------------------------------------------- argument builders
-def variant_call_args(engine, key: tuple) -> tuple:
+def variant_call_args(engine, key: tuple, packed=None) -> tuple:
     """The full positional argument tuple for one ragged variant's jit
-    wrapper, as an all-padding batch: real (sharded, donatable) params /
-    KV pools / penalty counts in their live slots, neutral numpy arrays
-    everywhere else.
+    wrapper: real (sharded, donatable) params / KV pools / penalty
+    counts / state pool in their live slots, and the one packed buffer
+    of everything else (``launch.launch_buffer``), placed as a live
+    launch places it: the all-padding batch, or ``packed``, such a
+    buffer whose fields a caller has filled in.
 
     This is THE shape contract between the AOT compiler, the prewarm
     executor, and the engine's live builders (``_build_windowed`` /
-    ``_build_mixed``): ``compile.py`` lowers with exactly these
-    arguments and ``prewarm_engine`` executes with them, so an aval
-    drift from the live call sites shows up as a prewarm-then-traffic
-    compile miss the smoke gate fails on."""
-    args = _variant_call_args(engine, key)
-    state = getattr(engine, "seq_state", None)
-    if state is None:
-        return args
-    # A model with sequence state: the pool and the rows' entries last,
-    # every row at an entry past the pool (nothing read back, nothing
-    # written), in the shapes ``_build_windowed`` / ``_build_mixed`` give.
-    nb, windowed = key[0], key[2]
-    from ..models.llama import seq_state_entries
-
-    no_entry = np.int32(seq_state_entries(state))
-    if windowed:
-        return (*args, state, np.full(nb, no_entry, np.int32))
-    B1 = engine.cfg.max_decode_slots + 1
-    return (*args, state, (
-        np.full(B1, no_entry, np.int32), np.zeros(2 * B1, np.int32),
-        np.full(2 * B1, no_entry, np.int32),
-    ))
-
-
-def _variant_call_args(engine, key: tuple) -> tuple:
-    cfg = engine.cfg
+    ``_build_mixed``): the builders write their rows into the same
+    buffer, ``compile.py`` lowers with exactly these arguments and
+    ``prewarm_engine`` executes with them, so an aval drift from the
+    live call sites shows up as a prewarm-then-traffic compile miss the
+    smoke gate fails on."""
     nb, _pages, windowed, full_sampler, _want_lp, _with_spec = key
-    pmax = cfg.max_pages_per_seq
-    if windowed:
-        K, S = cfg.decode_window, cfg.device_stop_width
-        tokens = np.zeros(nb, np.int32)
-        positions = np.full(nb, -1, np.int32)  # all rows parked: writes drop
-        max_pos = np.full(nb, -1, np.int32)
-        table = np.zeros((nb, pmax), np.int32)
-        stop_set = np.full((nb, S), -1, np.int32)
-        eos_gate = np.zeros(nb, np.int32)
-        budget_gate = np.full(nb, K, np.int32)
-        if not full_sampler:
-            return (
-                engine.params, engine.k_cache, engine.v_cache,
-                tokens, positions, max_pos, table,
-                stop_set, eos_gate, budget_gate,
-            )
-        seeds = np.zeros(nb, np.int32)
-        # Pad rows scatter through the scratch counts row, same as live.
-        slot_map = np.full(nb, cfg.max_decode_slots, np.int32)
-        temp = np.zeros(nb, np.float32)
-        top_k = np.zeros(nb, np.int32)
-        top_p = np.ones(nb, np.float32)
-        freq = np.zeros(nb, np.float32)
-        pres = np.zeros(nb, np.float32)
-        rep = np.ones(nb, np.float32)
-        return (
-            engine.params, engine.k_cache, engine.v_cache,
-            tokens, positions, max_pos, table,
-            seeds, engine._counts, slot_map,
-            temp, top_k, top_p, freq, pres, rep,
-            stop_set, eos_gate, budget_gate,
+    state = getattr(engine, "seq_state", None)
+    if packed is None:
+        from ..models.llama import seq_state_entries
+
+        packed, _fields = launch_buffer(
+            engine.cfg, key, 0 if state is None else seq_state_entries(state)
         )
-    B1 = cfg.max_decode_slots + 1
-    T_s = cfg.spec_max_draft + 1
-    tokens = np.zeros(nb, np.int32)
-    positions = np.full(nb, -1, np.int32)
-    row_of = np.full(nb, B1 - 1, np.int32)  # flat pad -> scratch row
-    table = np.zeros((B1, pmax), np.int32)
-    q_last = np.zeros(B1, np.int32)
-    spec_idx = np.zeros((B1, T_s), np.int32)
-    spec_drafts = np.full((B1, max(T_s - 1, 1)), -1, np.int32)
-    n_drafts = np.zeros(B1, np.int32)
-    if not full_sampler:
-        return (
-            engine.params, engine.k_cache, engine.v_cache,
-            tokens, positions, row_of, table, q_last,
-            spec_idx, spec_drafts, n_drafts,
-        )
-    pos0 = np.full(B1, -1, np.int32)
-    slot_map = np.full(B1, cfg.max_decode_slots, np.int32)
-    is_decode = np.zeros(B1, np.bool_)
-    seeds = np.zeros(B1, np.int32)
-    temp = np.zeros(B1, np.float32)
-    top_k = np.zeros(B1, np.int32)
-    top_p = np.ones(B1, np.float32)
-    freq = np.zeros(B1, np.float32)
-    pres = np.zeros(B1, np.float32)
-    rep = np.ones(B1, np.float32)
-    spec_pos = np.full((B1, T_s), -1, np.int32)
-    return (
-        engine.params, engine.k_cache, engine.v_cache,
-        tokens, positions, row_of, table,
-        q_last, pos0, engine._counts, slot_map, is_decode,
-        seeds, temp, top_k, top_p, freq, pres, rep,
-        spec_idx, spec_pos, spec_drafts, n_drafts,
-    )
+    # A compile-only stand-in (benchmark/rehearse/compile_v5e.py) has
+    # described devices that hold no array: it lowers from host arrays.
+    sharding = getattr(engine, "_launch_sharding", None)
+    if sharding is not None:
+        import jax
+
+        packed = jax.device_put(packed, sharding)
+    args = (engine.params, engine.k_cache, engine.v_cache)
+    if windowed:  # no chain: the carry is not read
+        carry = np.zeros(nb, np.int32)
+        if sharding is not None:
+            carry = engine._no_carry(nb)
+        args += (carry, carry)
+    args += (packed,)
+    if full_sampler:
+        args += (engine._counts,)
+    return args if state is None else (*args, state)
 
 
 # ------------------------------------------------------------- execution

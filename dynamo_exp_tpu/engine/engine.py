@@ -60,6 +60,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from ..aot.launch import (
+    SAMPLER_FIELDS,
+    launch_buffer,
+    launch_layout,
+    unpack_launch,
+)
 from ..aot.lattice import impl_for_key, resolve_ragged_key
 from ..models.config import ModelConfig
 from ..models.llama import (
@@ -222,13 +228,10 @@ class _PendingRagged:
     # requires this to be False; stop/budget deaths are safe (the host
     # finishes those rows at consume and skips them in the successor).
     capacity_capped: bool = False
-    stop_tokens: object = None  # np [nb, S], reused verbatim by a chain
-    # (seeds, temp, top_k, top_p, f, p, r) np arrays, reused by a chain.
-    sampler_args: tuple | None = None
-    slot_map: object | None = None  # np (sampler variants only)
-    # np [nb]: each row's sequence-state entry (a model with such state);
-    # reused verbatim by a chain.
-    state_slots: object | None = None
+    # The host buffer a window launched with (``aot/launch.py``): a
+    # window chained onto it starts from a copy, its rows' stop sets,
+    # sampler rows, counts slots and sequence-state entries being these.
+    packed: object = None
     # Dispatch-profiler stamp (monotonic, taken right after the dispatch
     # call returned): the consume's existing host sync closes the pair.
     dispatched_at: float = 0.0
@@ -246,11 +249,6 @@ def _no_phase(name: str):
 def _no_part(phase: str, name: str):
     """``DispatchProfiler.part`` of such an engine."""
     return _NO_PHASE
-
-
-def _h2d(*arrays) -> tuple:
-    """A launch's host arrays on the device, in the order given."""
-    return tuple(jnp.asarray(a) for a in arrays)
 
 
 class TPUEngine(AsyncEngine):
@@ -469,6 +467,10 @@ class TPUEngine(AsyncEngine):
         # full-vs-greedy sampler, want_lp). This replaces the old
         # _decode_fns x _prefill_fns x _spec_fns lattice.
         self._ragged_fns: dict[tuple, Callable] = {}
+        # Where a launch's packed buffer goes (``_put_launch``): every
+        # chip of the mesh holds the whole of it.
+        self._launch_sharding = sharding(P())
+        self._no_carries: dict[int, jax.Array] = {}
         # Host-side speculation state (drafter + per-row adaptive
         # controller); None = speculation off.
         self._spec = None
@@ -729,10 +731,24 @@ class TPUEngine(AsyncEngine):
         mcfg = self.cfg.model
         K = self.cfg.decode_window
 
-        # A model with sequence state takes the pool and the rows'
-        # entries last, donates the pool and returns it last; for every
-        # other model both are None and the programs are what they were.
+        # A model with sequence state takes the pool last, donates it and
+        # returns it last (the rows' entries are fields of the packed
+        # buffer); for every other model there is neither.
         has_state = mcfg.has_seq_state
+        layout = launch_layout(
+            self.cfg, (nb, pages, True, full_sampler, want_lp, False)
+        )
+
+        def unpack(packed, carry_tokens, carry_positions):
+            # The launch's one buffer, cut apart (aot/launch.py). A
+            # chained window feeds the carry of the one before it.
+            f = unpack_launch(layout, packed)
+            chained = f.pop("chained")[0] != 0
+            f["tokens"] = jnp.where(chained, carry_tokens, f["tokens"])
+            f["positions"] = jnp.where(
+                chained, carry_positions, f["positions"]
+            )
+            return f
 
         def run_forward(params, tokens, positions, page_table, k, v,
                         state, state_slots):
@@ -761,13 +777,19 @@ class TPUEngine(AsyncEngine):
 
             @partial(
                 jax.jit,
-                donate_argnums=(1, 2, 8, 19) if has_state else (1, 2, 8),
+                donate_argnums=(1, 2, 6, 7) if has_state else (1, 2, 6),
             )
-            def ragged_window(params, k, v, tokens, positions, max_pos,
-                              page_table, seeds, counts_all, slot_map, temp,
-                              top_k, top_p, freq_pen, pres_pen, rep_pen,
-                              stop_set, eos_gate, budget_gate,
-                              state=None, state_slots=None):
+            def ragged_window(params, k, v, carry_tokens, carry_positions,
+                              packed, counts_all, state=None):
+                f = unpack(packed, carry_tokens, carry_positions)
+                tokens, positions = f["tokens"], f["positions"]
+                max_pos, page_table = f["max_pos"], f["table"]
+                seeds, slot_map = f["seeds"], f["slot_map"]
+                temp, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
+                freq_pen, pres_pen, rep_pen = f["freq"], f["pres"], f["rep"]
+                stop_set, eos_gate = f["stop_set"], f["eos_gate"]
+                budget_gate = f["budget_gate"]
+                state_slots = f.get("state_slots")
                 # Compaction: penalty rows live slot-indexed in the
                 # [B+1, V] pool; gather the stepped rows in, scatter
                 # back out (pad rows map to the scratch row B).
@@ -829,11 +851,16 @@ class TPUEngine(AsyncEngine):
         else:
 
             @partial(
-                jax.jit, donate_argnums=(1, 2, 10) if has_state else (1, 2)
+                jax.jit, donate_argnums=(1, 2, 6) if has_state else (1, 2)
             )
-            def ragged_window(params, k, v, tokens, positions, max_pos,
-                              page_table, stop_set, eos_gate, budget_gate,
-                              state=None, state_slots=None):
+            def ragged_window(params, k, v, carry_tokens, carry_positions,
+                              packed, state=None):
+                f = unpack(packed, carry_tokens, carry_positions)
+                tokens, positions = f["tokens"], f["positions"]
+                max_pos, page_table = f["max_pos"], f["table"]
+                stop_set, eos_gate = f["stop_set"], f["eos_gate"]
+                budget_gate = f["budget_gate"]
+                state_slots = f.get("state_slots")
                 def step(carry, t):
                     tokens, positions, k, v, state = carry
                     logits, k, v, state = run_forward(
@@ -893,6 +920,14 @@ class TPUEngine(AsyncEngine):
         )
 
         has_state = mcfg.has_seq_state  # as in _windowed_program
+        layout = launch_layout(
+            self.cfg, (nb, pages, False, full_sampler, want_lp, with_spec)
+        )
+
+        def state_rows_of(f):
+            if not has_state:
+                return None
+            return f["state_slots"], f["state_src"], f["state_dst"]
 
         def run_forward(params, k, v, tokens, positions, row_of,
                         page_table, out_idx, state, state_rows):
@@ -924,14 +959,20 @@ class TPUEngine(AsyncEngine):
 
             @partial(
                 jax.jit,
-                donate_argnums=(1, 2, 9, 23) if has_state else (1, 2, 9),
+                donate_argnums=(1, 2, 4, 5) if has_state else (1, 2, 4),
             )
-            def ragged_mixed(params, k, v, tokens, positions, row_of,
-                             page_table, q_last, pos0, counts_all, slot_map,
-                             is_decode, seeds, temp, top_k, top_p, freq_pen,
-                             pres_pen, rep_pen, spec_idx, spec_pos,
-                             spec_drafts, n_drafts,
-                             state=None, state_rows=None):
+            def ragged_mixed(params, k, v, packed, counts_all, state=None):
+                f = unpack_launch(layout, packed)
+                tokens, positions = f["tokens"], f["positions"]
+                row_of, page_table = f["row_of"], f["table"]
+                q_last, pos0 = f["q_last"], f["pos0"]
+                slot_map, is_decode = f["slot_map"], f["is_decode"]
+                seeds = f["seeds"]
+                temp, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
+                freq_pen, pres_pen, rep_pen = f["freq"], f["pres"], f["rep"]
+                spec_idx, spec_pos = f.get("spec_idx"), f.get("spec_pos")
+                spec_drafts, n_drafts = f.get("spec_drafts"), f.get("n_drafts")
+                state_rows = state_rows_of(f)
                 logits_all, k, v, state = run_forward(
                     params, k, v, tokens, positions, row_of, page_table,
                     out_indices(q_last, spec_idx), state, state_rows,
@@ -976,11 +1017,15 @@ class TPUEngine(AsyncEngine):
         else:
 
             @partial(
-                jax.jit, donate_argnums=(1, 2, 11) if has_state else (1, 2)
+                jax.jit, donate_argnums=(1, 2, 4) if has_state else (1, 2)
             )
-            def ragged_mixed(params, k, v, tokens, positions, row_of,
-                             page_table, q_last, spec_idx, spec_drafts,
-                             n_drafts, state=None, state_rows=None):
+            def ragged_mixed(params, k, v, packed, state=None):
+                f = unpack_launch(layout, packed)
+                tokens, positions = f["tokens"], f["positions"]
+                row_of, page_table = f["row_of"], f["table"]
+                q_last, spec_idx = f["q_last"], f.get("spec_idx")
+                spec_drafts, n_drafts = f.get("spec_drafts"), f.get("n_drafts")
+                state_rows = state_rows_of(f)
                 logits_all, k, v, state = run_forward(
                     params, k, v, tokens, positions, row_of, page_table,
                     out_indices(q_last, spec_idx), state, state_rows,
@@ -1100,23 +1145,45 @@ class TPUEngine(AsyncEngine):
         assert self._mixed_bucket_for(tiled - room, singles) == nb, (chunks, nb)
         return chunks
 
-    def _state_rows_h2d(self, state_rows):
-        """``state_rows`` of a dispatch on the device, for a model with
-        sequence state (the last of a launch's transfers); None for any
-        other, whose program takes none."""
-        if self.seq_state is None:
-            return None
-        return jax.tree.map(jnp.asarray, state_rows)
+    def _launch_buffer(
+        self, nb: int, windowed: bool, full_sampler: bool,
+        with_spec: bool = False, like: np.ndarray | None = None,
+    ) -> tuple[np.ndarray, dict]:
+        """The one buffer a dispatch's program takes and its builder's
+        arrays by name, as the all-padding batch or as ``like``, an
+        earlier launch's (``aot/launch.py``)."""
+        return launch_buffer(
+            self.cfg, (nb, None, windowed, full_sampler, False, with_spec),
+            self._state_entries, like,
+        )
 
-    def _run_program(self, fn, state_rows, *args) -> tuple:
+    def _put_launch(self, program: str, *host) -> tuple:
+        """A launch's host arrays on the device, replicated over the
+        mesh: the whole of ``launch.h2d``. One packed buffer a launch
+        (``aot/launch.py``), so ``dynamo_launch_transfers_total`` over
+        ``dynamo_ragged_dispatch_total`` reads 1; a path that hands
+        over field by field again shows there as more."""
+        get_telemetry().launch_transfers.labels(program).inc(len(host))
+        return tuple(jax.device_put(host, self._launch_sharding))
+
+    def _no_carry(self, nb: int) -> jax.Array:
+        """What a window that is not chained passes for the carry of
+        tokens and of positions: zeros the program does not read
+        (``chained`` is 0), on the device since the first such window."""
+        carry = self._no_carries.get(nb)
+        if carry is None:
+            carry = self._no_carries[nb] = jax.device_put(
+                np.zeros(nb, np.int32), self._launch_sharding
+            )
+        return carry
+
+    def _run_program(self, fn, *args) -> tuple:
         """Call a ragged program. For a model with sequence state it also
-        gets the state pool and ``state_rows`` from ``_state_rows_h2d`` (a
-        window: each row's entry [nb]; a mixed dispatch:
-        ``forward_ragged``'s ``state_rows``), and the pool it returns
-        replaces the engine's."""
+        gets the state pool, last, and the pool it returns replaces the
+        engine's (the rows' entries ride the packed buffer)."""
         if self.seq_state is None:
             return fn(*args)
-        *out, self.seq_state = fn(*args, self.seq_state, state_rows)
+        *out, self.seq_state = fn(*args, self.seq_state)
         return tuple(out)
 
     def _count_dispatch(
@@ -3535,28 +3602,18 @@ class TPUEngine(AsyncEngine):
         rows — the ragged family's one-query-per-row shape."""
         with self._phase("assemble"), self._part("assemble", "build"):
             cfg = self.cfg
-            ps, K, S = cfg.page_size, cfg.decode_window, cfg.device_stop_width
+            ps, K = cfg.page_size, cfg.decode_window
             nb = cfg.ragged_tokens_bucket_for(len(part))
-            tokens = np.zeros(nb, np.int32)
-            positions = np.full(nb, -1, np.int32)
-            max_pos = np.full(nb, -1, np.int32)
-            table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
-            # Pad rows map to the scratch counts row (B) so their scatter
-            # can't touch a live slot.
-            slot_map = np.full(nb, cfg.max_decode_slots, np.int32)
-            stop_set = np.full((nb, S), -1, np.int32)
-            eos_gate = np.zeros(nb, np.int32)
-            budget_gate = np.full(nb, K, np.int32)  # pad: never fires
-            seeds = np.zeros(nb, np.int32)
-            temp = np.zeros(nb, np.float32)
-            top_k = np.zeros(nb, np.int32)
-            top_p = np.ones(nb, np.float32)
-            freq = np.zeros(nb, np.float32)
-            pres = np.zeros(nb, np.float32)
-            rep = np.ones(nb, np.float32)
-
-            # Padding rows keep no state: past the pool, so never written.
-            state_slots = np.full(nb, self._state_entries, np.int32)
+            # Padding rows park at -1, map to the scratch counts row (B)
+            # so their scatter can't touch a live slot, have a budget
+            # gate that never fires and keep no state (an entry past
+            # the pool is never written).
+            packed, f = self._launch_buffer(nb, True, full_sampler)
+            tokens, positions = f["tokens"], f["positions"]
+            max_pos, table, slot_map = f["max_pos"], f["table"], f["slot_map"]
+            stop_set, eos_gate = f["stop_set"], f["eos_gate"]
+            budget_gate, state_slots = f["budget_gate"], f["state_slots"]
+            sampler_args = tuple(f[name] for name in SAMPLER_FIELDS)
 
             rows: list[_RaggedRow] = []
             max_pages = 1
@@ -3573,9 +3630,7 @@ class TPUEngine(AsyncEngine):
                 stops = self._stop_set(seq)
                 stop_set[r, : len(stops)] = stops
                 eos_gate[r], budget_gate[r] = self._stop_gates(seq, seq.generated)
-                self._row_sampler_args(
-                    seq, r, (seeds, temp, top_k, top_p, freq, pres, rep)
-                )
+                self._row_sampler_args(seq, r, sampler_args)
                 rows.append(
                     _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
                 )
@@ -3593,19 +3648,16 @@ class TPUEngine(AsyncEngine):
                 self._flush_offloads()
         prof = self.profiler
         t0 = prof.begin("ragged", "window") if prof is not None else 0.0
-        sampler_args = (seeds, temp, top_k, top_p, freq, pres, rep)
         with self._part("launch", "h2d"):
-            args = _h2d(tokens, positions, max_pos, table)
+            args = (
+                self._no_carry(nb), self._no_carry(nb),
+                *self._put_launch("window", packed),
+            )
             if full_sampler:
-                args += (
-                    *_h2d(seeds), self._counts,
-                    *_h2d(slot_map, temp, top_k, top_p, freq, pres, rep),
-                )
-            args += _h2d(stop_set, eos_gate, budget_gate)
-            state_rows = self._state_rows_h2d(state_slots)
+                args += (self._counts,)
         with self._part("launch", "call"):
             out = self._run_program(
-                fn, state_rows, self.params, self.k_cache, self.v_cache, *args
+                fn, self.params, self.k_cache, self.v_cache, *args
             )
         if full_sampler:
             (ys, self.k_cache, self.v_cache, self._counts,
@@ -3641,10 +3693,7 @@ class TPUEngine(AsyncEngine):
             tokens_dev=tok_dev,
             positions_dev=pos_dev,
             capacity_capped=capacity_capped,
-            stop_tokens=stop_set,
-            sampler_args=sampler_args if full_sampler else None,
-            slot_map=slot_map if full_sampler else None,
-            state_slots=state_slots,
+            packed=packed,
             dispatched_at=dispatched_at,
         )
 
@@ -3673,37 +3722,37 @@ class TPUEngine(AsyncEngine):
             cfg = self.cfg
             ps = cfg.page_size
             B1 = cfg.max_decode_slots + 1
-            T_s = cfg.spec_max_draft + 1
             align = self._ragged_align()
             flat_tokens: list[int] = []
             flat_pos: list[int] = []
             flat_row: list[int] = []
 
-            table = np.zeros((B1, cfg.max_pages_per_seq), np.int32)
-            q_last = np.zeros(B1, np.int32)
-            pos0 = np.full(B1, -1, np.int32)
-            is_decode = np.zeros(B1, np.bool_)
-            slot_map = np.full(B1, cfg.max_decode_slots, np.int32)
-            seeds = np.zeros(B1, np.int32)
-            temp = np.zeros(B1, np.float32)
-            top_k = np.zeros(B1, np.int32)
-            top_p = np.ones(B1, np.float32)
-            freq = np.zeros(B1, np.float32)
-            pres = np.zeros(B1, np.float32)
-            rep = np.ones(B1, np.float32)
-            spec_idx = np.zeros((B1, T_s), np.int32)
-            spec_pos = np.full((B1, T_s), -1, np.int32)
-            spec_drafts = np.full((B1, max(T_s - 1, 1)), -1, np.int32)
-            n_drafts = np.zeros(B1, np.int32)
-            sampler_arrs = (seeds, temp, top_k, top_p, freq, pres, rep)
-            # Sequence state (a model that has it): the entry each row
-            # reads, and the writes (stream index -> entry): rows' own in
-            # the first B1, a prefill row's snapshot in the second. An
-            # entry past the pool is no write.
-            no_entry = self._state_entries
-            state_slots = np.full(B1, no_entry, np.int32)
-            state_src = np.zeros(2 * B1, np.int32)
-            state_dst = np.full(2 * B1, no_entry, np.int32)
+            # The bucket is known before the spans are laid out: their
+            # extents are the plan's sizes, each rounded up to its tile.
+            with_spec = bool(spec)
+            nb = self._mixed_bucket_for(
+                sum(
+                    -(-n // align) * align
+                    for n in (*sizes, *(len(d) + 1 for *_, d in spec))
+                ),
+                len(dec),
+            )
+            # As the all-padding batch: flat padding maps to the scratch
+            # per-row index (B1 - 1 is always free: at most
+            # max_decode_slots rows hold slots) at position -1. Sequence
+            # state (a model that has it): the entry each row reads, and
+            # the writes (stream index -> entry): rows' own in the first
+            # B1, a prefill row's snapshot in the second. An entry past
+            # the pool is no write.
+            packed, f = self._launch_buffer(nb, False, full_sampler, with_spec)
+            tokens, positions, row_of = f["tokens"], f["positions"], f["row_of"]
+            table, q_last, pos0 = f["table"], f["q_last"], f["pos0"]
+            is_decode, slot_map = f["is_decode"], f["slot_map"]
+            spec_idx, spec_pos = f["spec_idx"], f["spec_pos"]
+            spec_drafts, n_drafts = f["spec_drafts"], f["n_drafts"]
+            sampler_arrs = tuple(f[name] for name in SAMPLER_FIELDS)
+            state_slots = f["state_slots"]
+            state_src, state_dst = f["state_src"], f["state_dst"]
 
             def add_span(toks: list[int], poss: list[int], r: int) -> int:
                 """Append one row's query span to the flat stream, aligned
@@ -3808,14 +3857,9 @@ class TPUEngine(AsyncEngine):
                 r += 1
 
             aligned = len(flat_tokens)
-            nb = self._mixed_bucket_for(aligned, len(dec))
+            assert self._mixed_bucket_for(aligned, len(dec)) == nb, (aligned, nb)
             dec_from = self._decode_from(nb, aligned)
             total_q = dec_from + len(dec) if dec else aligned
-            tokens = np.zeros(nb, np.int32)
-            positions = np.full(nb, -1, np.int32)
-            # Flat padding maps to the scratch per-row index (B1 - 1 is
-            # always free: at most max_decode_slots rows hold slots).
-            row_of = np.full(nb, B1 - 1, np.int32)
             tokens[:aligned] = flat_tokens
             positions[:aligned] = flat_pos
             row_of[:aligned] = flat_row
@@ -3830,7 +3874,6 @@ class TPUEngine(AsyncEngine):
             want_lp = any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
-            with_spec = bool(spec)
         with self._phase("assemble"), self._part("assemble", "lookup"):
             n_variants = len(self._ragged_fns)
             page_bucket = cfg.ragged_page_bucket_for(max_pages)
@@ -3844,23 +3887,12 @@ class TPUEngine(AsyncEngine):
         prof = self.profiler
         t0 = prof.begin("ragged", "mixed") if prof is not None else 0.0
         with self._part("launch", "h2d"):
-            args = _h2d(tokens, positions, row_of, table)
+            args = self._put_launch("mixed", packed)
             if full_sampler:
-                args += (
-                    *_h2d(q_last, pos0), self._counts,
-                    *_h2d(
-                        slot_map, is_decode, *sampler_arrs,
-                        spec_idx, spec_pos, spec_drafts, n_drafts,
-                    ),
-                )
-            else:
-                args += _h2d(q_last, spec_idx, spec_drafts, n_drafts)
-            state_rows = self._state_rows_h2d(
-                (state_slots, state_src, state_dst)
-            )
+                args += (self._counts,)
         with self._part("launch", "call"):
             out = self._run_program(
-                fn, state_rows, self.params, self.k_cache, self.v_cache, *args
+                fn, self.params, self.k_cache, self.v_cache, *args
             )
         if full_sampler:
             ys, self.k_cache, self.v_cache, self._counts = out
@@ -3989,11 +4021,15 @@ class TPUEngine(AsyncEngine):
             cfg = self.cfg
             ps, K = cfg.page_size, cfg.decode_window
             nb = pending.nb
-            max_pos = np.full(nb, -1, np.int32)
-            table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
-            stop_set = pending.stop_tokens  # same rows, same stop sets
-            eos_gate = np.zeros(nb, np.int32)
-            budget_gate = np.full(nb, K, np.int32)
+            # The rows are the window's before: same stop sets, sampler
+            # rows and entries; their pages only grow and every row's
+            # gates are set anew. Tokens and positions are the carry's.
+            packed, f = self._launch_buffer(
+                nb, True, pending.full_sampler, like=pending.packed
+            )
+            f["chained"][0] = 1
+            max_pos, table = f["max_pos"], f["table"]
+            eos_gate, budget_gate = f["eos_gate"], f["budget_gate"]
             rows: list[_RaggedRow] = []
             max_pages = 1
             capacity_capped = False
@@ -4033,19 +4069,13 @@ class TPUEngine(AsyncEngine):
         with self._part("launch", "h2d"):
             args = (
                 pending.tokens_dev, pending.positions_dev,
-                *_h2d(max_pos, table),
+                *self._put_launch("window", packed),
             )
             if pending.full_sampler:
-                seeds, *sampler_args = pending.sampler_args
-                args += (
-                    *_h2d(seeds), self._counts,
-                    *_h2d(pending.slot_map, *sampler_args),
-                )
-            args += _h2d(stop_set, eos_gate, budget_gate)
-            state_rows = self._state_rows_h2d(pending.state_slots)
+                args += (self._counts,)
         with self._part("launch", "call"):
             out = self._run_program(
-                fn, state_rows, self.params, self.k_cache, self.v_cache, *args
+                fn, self.params, self.k_cache, self.v_cache, *args
             )
         if pending.full_sampler:
             (ys, self.k_cache, self.v_cache, self._counts,
@@ -4082,10 +4112,7 @@ class TPUEngine(AsyncEngine):
             tokens_dev=tok_dev,
             positions_dev=pos_dev,
             capacity_capped=capacity_capped,
-            stop_tokens=stop_set,
-            sampler_args=pending.sampler_args,
-            slot_map=pending.slot_map,
-            state_slots=pending.state_slots,
+            packed=packed,
             dispatched_at=dispatched_at,
         )
 

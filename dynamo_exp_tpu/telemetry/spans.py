@@ -228,6 +228,16 @@ class Telemetry:
             ["program", "kind"],  # window | mixed; live | pad
             registry=self.registry,
         )
+        self.launch_transfers = Counter(
+            "dynamo_launch_transfers_total",
+            "Host arrays handed to the device by ragged launches, by "
+            "program (TPUEngine._put_launch, the whole of launch.h2d). "
+            "Over dynamo_ragged_dispatch_total it is transfers a launch: "
+            "1 since a launch's fields travel as one packed buffer "
+            "(aot/launch.py); 6-19 field by field before",
+            ["program"],  # window | mixed
+            registry=self.registry,
+        )
         self.prefill_chunk_tokens = Counter(
             "dynamo_prefill_chunk_tokens_total",
             "Prompt tokens mixed dispatches carried by kind: share (up "

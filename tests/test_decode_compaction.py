@@ -13,10 +13,11 @@ import asyncio
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynamo_exp_tpu.aot.launch import launch_buffer
+from dynamo_exp_tpu.aot.warmup import variant_call_args
 from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
 from dynamo_exp_tpu.engine.scheduler import RemoteKv
 from dynamo_exp_tpu.models import TINY
@@ -460,20 +461,17 @@ def test_single_sequence_decode_faster_than_full_batch():
     )
     eng = TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
     k, v = eng.k_cache, eng.v_cache
-    S = cfg.device_stop_width
-    K = cfg.decode_window
     pages = cfg.max_pages_per_seq  # 32 pages x 32 tokens: 1k context
 
     def window_args(rows):
-        return (
-            jnp.zeros(rows, jnp.int32),  # tokens
-            jnp.full(rows, 1000, jnp.int32),  # positions: deep context
-            jnp.full(rows, cfg.max_model_len - 1, jnp.int32),
-            jnp.tile(jnp.arange(pages, dtype=jnp.int32)[None], (rows, 1)),
-            jnp.full((rows, S), -1, jnp.int32),  # stop set
-            jnp.zeros(rows, jnp.int32),  # eos gate
-            jnp.full(rows, K, jnp.int32),  # budget gate: never
-        )
+        """A launch's arguments behind params and the pools: the carry a
+        window that is not chained leaves unread, and the packed buffer."""
+        key = (rows, pages, True, False, False, False)
+        packed, fields = launch_buffer(cfg, key)
+        fields["positions"][:] = 1000  # deep context
+        fields["max_pos"][:] = cfg.max_model_len - 1
+        fields["table"][:] = np.arange(pages)
+        return variant_call_args(eng, key, packed)[3:]
 
     def timed(rows, k, v, reps=5):
         fn = eng._ragged_fn(rows, pages, True, False, False)

@@ -519,8 +519,10 @@ def test_programs_hold_no_second_copy_of_the_pools(windowed):
 # --------------------------------- the parts change no program either
 def _launched(profile: bool) -> dict[str, str]:
     """The text each ragged program lowers to from the arguments an
-    engine's launches really pass it (a launch's transfers are made in
-    one tuple before the call; ``TPUEngine._run_program`` sees them)."""
+    engine's launches really pass it (a launch's one packed buffer is on
+    the device before the call; ``TPUEngine._run_program`` sees it), and,
+    under ``<name>/padding``, from ``variant_call_args``' for the same
+    variant."""
     engine = TPUEngine(
         _cfg(model=TINY_QWEN3, profile_dispatches=profile),
         mesh=single_device_mesh(), seed=0,
@@ -528,12 +530,13 @@ def _launched(profile: bool) -> dict[str, str]:
     seen: dict[str, tuple] = {}
     run_program = engine._run_program
 
-    def spy(fn, state_rows, *args):
+    def spy(fn, *args):
         shapes = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+            args,
         )
         seen.setdefault(fn.__name__, (fn, shapes))
-        return run_program(fn, state_rows, *args)
+        return run_program(fn, *args)
 
     engine._run_program = spy
     engine.start()
@@ -541,13 +544,25 @@ def _launched(profile: bool) -> dict[str, str]:
         asyncio.run(_generate(engine, range(20, 40), max_tokens=13))
     finally:
         engine.stop()
-    return {name: fn.lower(*shapes).as_text() for name, (fn, shapes) in seen.items()}
+    out = {name: fn.lower(*shapes).as_text() for name, (fn, shapes) in seen.items()}
+    by_fn = {fn: key for key, fn in engine._ragged_fns.items()}
+    for name, (fn, _shapes) in seen.items():
+        out[f"{name}/padding"] = fn.lower(
+            *variant_call_args(engine, by_fn[fn])
+        ).as_text()
+    return out
 
 
-def test_the_parts_and_the_hoisted_transfers_change_no_program():
+def test_the_parts_and_the_packed_transfer_change_no_program():
     """With the profiler (phases, parts, CPU clock) and without it an
     engine launches both ragged programs with arguments of the same
-    shapes and types, and they lower to the same text."""
+    shapes and types, and they lower to the same text: the text the
+    prewarm's and the AOT compiler's padding arguments lower to."""
     with_parts, bare = _launched(True), _launched(False)
-    assert set(with_parts) == {"ragged_window", "ragged_mixed"} == set(bare)
+    assert set(bare) == {
+        "ragged_window", "ragged_mixed",
+        "ragged_window/padding", "ragged_mixed/padding",
+    }
     assert with_parts == bare
+    for name in ("ragged_window", "ragged_mixed"):
+        assert bare[name] == bare[f"{name}/padding"]

@@ -58,25 +58,24 @@ def live_window_args(engine, key: tuple, contexts: list[int], rng) -> tuple[list
     the pool's size they wrap onto pages another row holds)."""
     import numpy as np
 
+    from dynamo_exp_tpu.aot.launch import launch_buffer
     from dynamo_exp_tpu.aot.warmup import variant_call_args
 
     cfg = engine.cfg
     nb = key[0]
-    args = list(variant_call_args(engine, key))
-    # (params, k, v, tokens, positions, max_pos, table, stop_set, eos_gate, budget_gate)
-    args[3] = rng.integers(0, cfg.model.vocab_size, nb).astype(np.int32)
-    args[4] = np.asarray(contexts, np.int32)
-    args[5] = np.full(nb, cfg.max_model_len - 1, np.int32)
-    table = np.zeros((nb, cfg.max_pages_per_seq), np.int32)
+    packed, fields = launch_buffer(cfg, key)
+    fields["tokens"][:] = rng.integers(0, cfg.model.vocab_size, nb)
+    fields["positions"][:] = contexts
+    fields["max_pos"][:] = cfg.max_model_len - 1
+    table = fields["table"]
     order, at = rng.permutation(np.arange(1, cfg.num_pages)), 0
     for i, c in enumerate(contexts):
         n = (c + cfg.decode_window) // cfg.page_size + 1
         table[i, :n] = order[(at + np.arange(n)) % len(order)]
         at += n
-    args[6] = table
-    if engine.seq_state is not None:  # each live row its own state entry
-        args[-1] = (np.arange(nb) % cfg.max_decode_slots).astype(np.int32)
-    return args, at
+    # Each live row its own state entry (a field only such a model has).
+    fields["state_slots"][:] = np.arange(nb) % cfg.max_decode_slots
+    return list(variant_call_args(engine, key, packed)), at
 
 
 def time_program(engine, key: tuple, args: list, calls: int) -> list[float]:
@@ -84,11 +83,11 @@ def time_program(engine, key: tuple, args: list, calls: int) -> list[float]:
 
     fn = engine._ragged_fn_from_key(key)
     out = []
-    has_state = engine.seq_state is not None  # the pool rides second to last, donated
+    has_state = engine.seq_state is not None  # the pool rides last, donated
     for i in range(calls + 2):
         args[0], args[1], args[2] = engine.params, engine.k_cache, engine.v_cache
         if has_state:
-            args[-2] = engine.seq_state
+            args[-1] = engine.seq_state
         t0 = time.perf_counter()
         res = fn(*args)
         if has_state:
