@@ -25,6 +25,9 @@ HOT_PATH_ZONES: tuple[Zone, ...] = (
         "dynamo_exp_tpu/engine/engine.py",
         exclude=("TPUEngine.generate", "TPUEngine.prefill_extract"),
     ),
+    # The block-diffusion half of the engine loop (a mixin of TPUEngine:
+    # its builders, chained launch and consume run where the engine's do).
+    Zone("dynamo_exp_tpu/engine/block.py"),
     Zone("dynamo_exp_tpu/engine/scheduler.py"),
     Zone("dynamo_exp_tpu/engine/offload.py"),
     Zone("dynamo_exp_tpu/engine/kv_manager.py"),
@@ -345,5 +348,9 @@ VARIANT_SITE_MANIFESTS: tuple[VariantSiteManifest, ...] = (
             "_gather_pages": (2,),
             "_inject_pages": (2,),
         },
+    ),
+    VariantSiteManifest(
+        path="dynamo_exp_tpu/engine/block.py",
+        sites={"_ragged_fn": (0, 1)},
     ),
 )

@@ -46,9 +46,40 @@ _SAMPLER = (
 SAMPLER_FIELDS = tuple(name for name, _ in _SAMPLER)
 
 
+def _block_fields(nb, windowed, full_sampler, block, B1, pmax) -> list:
+    """A block-diffusion model's launch (``engine/block.py``): a decode
+    row is a block of ``block`` positions with what is fixed in it
+    (``fixed_at``: ``ops/sampling.py::denoise_select``) and the denoising
+    passes it has had. The block program takes a block a row (``tokens``
+    [nb, block] from ``positions``, the block's start); the mixed one a
+    flat stream in which ``blk_idx`` finds each decode row's block
+    (``passes`` -1: not a decode row)."""
+    rows = nb if windowed else B1
+    if windowed:
+        named = [
+            ("tokens", (nb, block), "int32"), ("positions", (nb,), "int32"),
+        ]
+    else:
+        named = [
+            ("tokens", (nb,), "int32"), ("positions", (nb,), "int32"),
+            ("row_of", (nb,), "int32"), ("blk_idx", (B1, block), "int32"),
+        ]
+    named += [
+        ("fixed_at", (rows, block), "int32"), ("passes", (rows,), "int32"),
+        ("table", (rows, pmax), "int32"),
+    ]
+    if full_sampler:
+        named += [(name, (rows,), dtype) for name, dtype in _SAMPLER]
+    return named
+
+
 @functools.lru_cache(maxsize=None)
 def _layout(nb, windowed, full_sampler, with_spec, has_seq_state,
-            B1, pmax, S, T_s) -> tuple[LaunchField, ...]:
+            B1, pmax, S, T_s, block=0) -> tuple[LaunchField, ...]:
+    if block:
+        return _at_offsets(
+            _block_fields(nb, windowed, full_sampler, block, B1, pmax)
+        )
     rows = nb if windowed else B1
     if windowed:
         # ``chained``: 1 where tokens and positions are the carry of the
@@ -89,6 +120,10 @@ def _layout(nb, windowed, full_sampler, with_spec, has_seq_state,
                 ("state_src", (2 * B1,), "int32"),
                 ("state_dst", (2 * B1,), "int32"),
             ]
+    return _at_offsets(named)
+
+
+def _at_offsets(named) -> tuple[LaunchField, ...]:
     fields, offset = [], 0
     for name, shape, dtype in named:
         fields.append(LaunchField(name, shape, dtype, offset))
@@ -100,6 +135,7 @@ def _sizes(cfg) -> tuple:
     return (
         cfg.max_decode_slots + 1, cfg.max_pages_per_seq,
         cfg.device_stop_width, cfg.spec_max_draft + 1,
+        cfg.model.block_length,
     )
 
 
@@ -125,7 +161,8 @@ def _padding(nb, windowed, full_sampler, with_spec, has_seq_state, sizes,
     program does not take; that buffer's bytes as the all-padding batch;
     and the layout's length in elements."""
     layout = _layout(nb, windowed, full_sampler, with_spec, has_seq_state, *sizes)
-    every = _layout(nb, windowed, True, not windowed, True, *sizes)
+    block = sizes[-1]
+    every = _layout(nb, windowed, True, not windowed and not block, True, *sizes)
     taken = {f.name: f.offset for f in layout}
     total = end = layout[-1].offset + layout[-1].size
     plan = []
@@ -141,6 +178,7 @@ def _padding(nb, windowed, full_sampler, with_spec, has_seq_state, sizes,
         "spec_pos": -1, "spec_drafts": -1, "top_p": 1.0, "rep": 1.0,
         "budget_gate": decode_window, "slot_map": B, "row_of": B,
         "state_slots": no_entry, "state_dst": no_entry,
+        "fixed_at": -1, "passes": -1,
     }
     raw = bytearray(4 * end)
     for name, shape, dtype, at in plan:

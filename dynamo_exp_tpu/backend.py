@@ -130,6 +130,7 @@ class Backend:
                         text="".join(text_parts) or None,
                         logprobs=out.logprobs,
                         top_logprobs=out.top_logprobs,
+                        fixed_at_pass=out.fixed_at_pass,
                         finish_reason=finished,
                         prompt_tokens=prompt_tokens if finished else None,
                         completion_tokens=emitted if finished else None,

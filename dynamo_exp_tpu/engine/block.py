@@ -1,0 +1,626 @@
+"""Generation by diffusion over blocks, on the engine's normal path
+(``ModelConfig.block_length`` B > 0; docs/architecture.md "A step is a
+token or a block pass").
+
+A decoding row's unit of work is a *pass* over its block of B positions:
+the block's open positions hold the mask token, a pass runs the model
+over all B against the cache of everything before the block, and the
+confidence rule (``ops/sampling.py::denoise_select``) fixes some of them.
+After at most ``denoising_steps`` passes nothing is masked, and one more
+pass over the final tokens writes the block's K/V. Every pass writes the
+block's K/V into the row's pages before attention reads them (as a prompt
+chunk's are), so only the last pass's stay: a block in flight keeps no
+state on the device. Then the block's tokens are emitted at once;
+``max_tokens`` and a stop token cut the last block on emission.
+
+What rides which program:
+
+- **block** (``_block_program``; the ``windowed`` variants of a block
+  model's lattice, so ``aot/`` and the benchmark's warm-up build them
+  unchanged): a pure-decode dispatch. Every row's whole block, its passes
+  chained on the device in one ``while_loop`` (at most
+  ``denoising_steps + 1``), rows tiled ``B`` slots a tile, the routed
+  experts reading their weights in place at any row count. The next
+  block's inputs are known on the host (its start, all mask), so block
+  N+1 is launched before the host syncs on block N
+  (``_dispatch_chained_block``).
+- **mixed** (``_mixed_block_program``): prompt chunks beside decoding rows,
+  each of which rides as a span of B tokens making ONE pass (denoising or
+  writing), so prefill does not stall decode; the host keeps the block's
+  state (``Sequence.blk_*``) between dispatches and the next dispatch of
+  either kind goes on from it.
+
+A prompt of P tokens is prefilled up to its last block boundary
+(``Sequence.prefill_target``); the ``P mod B`` tokens left open the first
+block, already fixed (``CONTEXT``). Prefill samples nothing: logits are
+the distribution of the token AT a position, and a row's first tokens
+come with its first block. Chunks begin and end on block boundaries.
+Penalties (frequency, presence, repetition) are not applied: a block's
+positions are fixed out of order, and no published form says what counts
+then. Temperature, top-k and top-p are, each draw keyed by (seed,
+position, pass).
+"""
+
+from __future__ import annotations
+
+import time
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..aot.launch import SAMPLER_FIELDS, launch_layout, unpack_launch
+from ..models.llama import final_logits, forward_ragged
+from ..ops.sampling import CONTEXT, TOP_LOGPROBS, UNFIXED, denoise_select
+from ..telemetry import get_telemetry
+from .scheduler import SeqState, Sequence
+
+
+def block_pass(logits, tokens, fixed_at, passes, rows, mcfg, sampler, lps):
+    """One denoising pass's outcome for the rows ``rows`` marks ([R]
+    bool): ``tokens`` and ``fixed_at`` ([R, B]) with what the confidence
+    rule fixed, and ``lps`` (None, or the log-probability, top ids and top
+    log-probabilities [R, B, ..] so far) with that pass's at those
+    positions."""
+    with jax.named_scope("denoise_select"):
+        out = denoise_select(
+            logits, fixed_at, passes,
+            steps=mcfg.denoising_steps,
+            dynamic=mcfg.remasking_strategy == "low_confidence_dynamic",
+            threshold=mcfg.confidence_threshold,
+            sampler=sampler,
+            top_n=TOP_LOGPROBS if lps is not None else None,
+        )
+        x0, newly = out[0], out[1] & rows[:, None]
+        tokens = jnp.where(newly, x0, tokens)
+        fixed_at = jnp.where(newly, passes[:, None], fixed_at)
+        if lps is not None:
+            lp, tid, tlp = lps
+            lps = (
+                jnp.where(newly, out[2], lp),
+                jnp.where(newly[..., None], out[3], tid),
+                jnp.where(newly[..., None], out[4], tlp),
+            )
+    return tokens, fixed_at, lps
+
+
+def _no_lps(rows: int, block: int):
+    return (
+        jnp.zeros((rows, block), jnp.float32),
+        jnp.zeros((rows, block, TOP_LOGPROBS), jnp.int32),
+        jnp.zeros((rows, block, TOP_LOGPROBS), jnp.float32),
+    )
+
+
+class BlockDecode:
+    """``TPUEngine``'s block-diffusion half: the two programs, their
+    builders, the chained launch and the consume. A mixin: everything
+    else (scheduler, pages, prefix cache, launch buffer, telemetry) is the
+    engine's own."""
+
+    # ---------------------------------------------------------------- programs
+    def _block_program(self, nb, pages, impl, full_sampler, want_lp):
+        """The pure-decode variant of a block model: ``nb`` rows, a block
+        each, every pass of every block in one ``while_loop``. Takes and
+        returns what ``_windowed_program`` does (the carry it ignores and
+        the counts it leaves alone included), so ``aot/warmup.py``'s and
+        ``aot/compile.py``'s calls fit both."""
+        interpret, mesh = self._attn_interpret, self.mesh
+        mcfg = self.cfg.model
+        B, T = mcfg.block_length, mcfg.denoising_steps
+        layout = launch_layout(
+            self.cfg, (nb, pages, True, full_sampler, want_lp, False)
+        )
+        row_of = jnp.repeat(jnp.arange(nb, dtype=jnp.int32), B)
+        every = jnp.arange(nb * B, dtype=jnp.int32)
+        offsets = jnp.arange(B, dtype=jnp.int32)[None, :]
+
+        def run(params, k, v, packed):
+            f = unpack_launch(layout, packed)
+            start, table = f["positions"], f["table"]
+            live = start >= 0
+            sampler = None
+            if full_sampler:
+                sampler = (
+                    f["seeds"], start[:, None] + offsets, f["temp"],
+                    f["top_k"], f["top_p"],
+                )
+
+            def body(c):
+                i, tokens, fixed_at, passes, written, n_denoise, k, v, lps = c
+                todo = live & ~written
+                pos = jnp.where(todo[:, None], start[:, None] + offsets, -1)
+                fed = jnp.where(fixed_at != UNFIXED, tokens, mcfg.mask_token_id)
+                hidden, k, v = forward_ragged(
+                    params, mcfg, fed.reshape(-1), pos.reshape(-1), row_of,
+                    table, k, v, every, attn_pages=pages, attn_impl=impl,
+                    q_tile=B, mesh=mesh, interpret=interpret,
+                    experts_any_rows=True, project=False,
+                )
+                # A row with a masked position denoises; one with none is
+                # written by this very pass (its K/V are the final tokens').
+                denoise = todo & jnp.any(fixed_at == UNFIXED, axis=1)
+
+                def select():
+                    logits = final_logits(params, mcfg, hidden)
+                    return block_pass(
+                        logits.reshape(nb, B, -1), tokens, fixed_at, passes,
+                        denoise, mcfg, sampler, lps,
+                    )
+
+                # The pass that only writes needs no logits: with rows in
+                # step (random weights) a fifth of the passes skip the head.
+                tokens, fixed_at, lps = jax.lax.cond(
+                    jnp.any(denoise), select, lambda: (tokens, fixed_at, lps)
+                )
+                step = denoise.astype(jnp.int32)
+                return (
+                    i + 1, tokens, fixed_at, passes + step,
+                    written | (todo & ~denoise), n_denoise + step, k, v, lps,
+                )
+
+            def cond(c):
+                return (c[0] <= T) & jnp.any(live & ~c[4])
+
+            zeros = jnp.zeros(nb, jnp.int32)
+            c = jax.lax.while_loop(cond, body, (
+                jnp.int32(0), f["tokens"], f["fixed_at"],
+                jnp.maximum(f["passes"], 0), ~live, zeros, k, v,
+                _no_lps(nb, B) if want_lp else None,
+            ))
+            _i, tokens, fixed_at, _passes, written, n_denoise, k, v, lps = c
+            ys = (tokens, fixed_at, n_denoise, (written & live).astype(jnp.int32))
+            return (ys + lps if want_lp else ys), k, v
+
+        if full_sampler:
+
+            @partial(jax.jit, donate_argnums=(1, 2, 6))
+            def ragged_block(params, k, v, carry_tokens, carry_positions,
+                             packed, counts_all):
+                ys, k, v = run(params, k, v, packed)
+                return ys, k, v, counts_all, carry_tokens, carry_positions
+
+        else:
+
+            @partial(jax.jit, donate_argnums=(1, 2))
+            def ragged_block(params, k, v, carry_tokens, carry_positions,
+                             packed):
+                ys, k, v = run(params, k, v, packed)
+                return ys, k, v, carry_tokens, carry_positions
+
+        return ragged_block
+
+    def _mixed_block_program(self, nb, pages, impl, full_sampler, want_lp):
+        """The mixed variant of a block model: one ragged forward over
+        prompt chunks (which sample nothing) and decoding rows' blocks,
+        ONE pass each: the confidence rule on the rows that have masked
+        positions, and the others' K/V written for good."""
+        interpret, mesh = self._attn_interpret, self.mesh
+        mcfg = self.cfg.model
+        B = mcfg.block_length
+        B1 = self.cfg.max_decode_slots + 1
+        q_tile = self._ragged_align()
+        packed_from = (
+            nb - self.cfg.ragged_packed_slots(nb) if impl == "pallas" else None
+        )
+        layout = launch_layout(
+            self.cfg, (nb, pages, False, full_sampler, want_lp, False)
+        )
+
+        def run(params, k, v, packed):
+            f = unpack_launch(layout, packed)
+            blk_idx, fixed_at, passes = f["blk_idx"], f["fixed_at"], f["passes"]
+            logits, k, v = forward_ragged(
+                params, mcfg, f["tokens"], f["positions"], f["row_of"],
+                f["table"], k, v, blk_idx.reshape(-1), attn_pages=pages,
+                attn_impl=impl, q_tile=q_tile, packed_from=packed_from,
+                mesh=mesh, interpret=interpret,
+            )
+            live = passes >= 0
+            denoise = live & jnp.any(fixed_at == UNFIXED, axis=1)
+            sampler = None
+            if full_sampler:
+                sampler = (
+                    f["seeds"], f["positions"][blk_idx], f["temp"],
+                    f["top_k"], f["top_p"],
+                )
+            tokens, fixed_at, lps = block_pass(
+                logits.reshape(B1, B, -1), f["tokens"][blk_idx], fixed_at,
+                jnp.maximum(passes, 0), denoise, mcfg, sampler,
+                _no_lps(B1, B) if want_lp else None,
+            )
+            ys = (
+                tokens, fixed_at, denoise.astype(jnp.int32),
+                (live & ~denoise).astype(jnp.int32),
+            )
+            return (ys + lps if want_lp else ys), k, v
+
+        if full_sampler:
+
+            @partial(jax.jit, donate_argnums=(1, 2, 4))
+            def ragged_mixed_block(params, k, v, packed, counts_all):
+                ys, k, v = run(params, k, v, packed)
+                return ys, k, v, counts_all
+
+        else:
+
+            @partial(jax.jit, donate_argnums=(1, 2))
+            def ragged_mixed_block(params, k, v, packed):
+                ys, k, v = run(params, k, v, packed)
+                return ys, k, v
+
+        return ragged_mixed_block
+
+    # ------------------------------------------------------------- row state
+    def _block_of(self, seq: Sequence) -> int:
+        """The start of ``seq``'s block, opening it where none is: the
+        tokens past the last block boundary (a prompt's tail) are its
+        context, every other position the mask token."""
+        mcfg = self.cfg.model
+        B = mcfg.block_length
+        start = len(seq.tokens) // B * B
+        if seq.blk_tokens is None:
+            pre = seq.tokens[start:]
+            seq.blk_tokens = pre + [mcfg.mask_token_id] * (B - len(pre))
+            seq.blk_fixed_at = [CONTEXT] * len(pre) + [UNFIXED] * (B - len(pre))
+            seq.blk_lp = [None] * B
+            seq.blk_passes = 0
+        return start
+
+    def _fill_block_row(self, seq: Sequence, r: int, f: dict, sampler) -> None:
+        f["fixed_at"][r] = seq.blk_fixed_at
+        f["passes"][r] = seq.blk_passes
+        f["table"][r, : len(seq.page_ids)] = seq.page_ids
+        self._row_sampler_args(seq, r, sampler)
+
+    def _launch(self, fn, program: str, packed, full_sampler: bool,
+                fresh: bool, carry=()):
+        """Hand a launch's buffer over and call ``fn``: (ys, when the call
+        returned). The pools the program returns replace the engine's."""
+        if self._pending_offloads:
+            with self._phase("kv_admin"):
+                self._flush_offloads()
+        prof = self.profiler
+        t0 = prof.begin("ragged", program) if prof is not None else 0.0
+        with self._part("launch", "h2d"):
+            args = (*carry, *self._put_launch(program, packed))
+            if full_sampler:
+                args += (self._counts,)
+        with self._part("launch", "call"):
+            out = fn(self.params, self.k_cache, self.v_cache, *args)
+        ys, self.k_cache, self.v_cache = out[:3]
+        if full_sampler:
+            self._counts = out[3]
+        return ys, (prof.end("ragged", t0, fresh) if prof is not None else 0.0)
+
+    # ---------------------------------------------------------------- builders
+    def _build_block(self, part, full_sampler: bool, solo: bool, chained=None):
+        """Build + dispatch one pure-decode batch of a block model (no
+        host sync): every row's block from where the host has it through
+        its last pass. ``part``: (row, seq, block start) a row; start -1
+        sits the dispatch out. With ``chained`` (the pending dispatch
+        before it) the rows are that one's and each block is the one
+        after the block in flight."""
+        from .engine import _PendingRagged, _RaggedRow
+
+        with self._phase("assemble"), self._part("assemble", "build"):
+            cfg = self.cfg
+            B, ps = cfg.model.block_length, cfg.page_size
+            nb = chained.nb if chained else cfg.ragged_tokens_bucket_for(len(part))
+            packed, f = self._launch_buffer(
+                nb, True, full_sampler,
+                like=chained.packed if chained else None,
+            )
+            sampler = tuple(f[name] for name in SAMPLER_FIELDS)
+            rows: list[_RaggedRow] = []
+            max_pages = 1
+            for r, seq, start in part:
+                f["positions"][r] = start
+                if start >= 0:
+                    if chained:  # a fresh block: nothing fixed, no pass made
+                        f["fixed_at"][r] = UNFIXED
+                        f["passes"][r] = 0
+                        f["table"][r, : len(seq.page_ids)] = seq.page_ids
+                    else:
+                        f["tokens"][r] = seq.blk_tokens
+                        self._fill_block_row(seq, r, f, sampler)
+                    max_pages = max(max_pages, (start + B + ps - 1) // ps)
+                rows.append(
+                    _RaggedRow(
+                        seq, "decode", r, n_valid=B if start >= 0 else 0,
+                        start=start,
+                    )
+                )
+            want_lp = chained.want_lp if chained else any(
+                self._wants_logprobs(e.seq) is not None for e in rows
+            )
+        with self._phase("assemble"), self._part("assemble", "lookup"):
+            n_variants = len(self._ragged_fns)
+            page_bucket = cfg.ragged_page_bucket_for(max_pages)
+            fn = self._ragged_fn(nb, page_bucket, True, full_sampler, want_lp)  # dynlint: recompile-hazard(a chained block reuses the dispatched bucket)
+            fresh = len(self._ragged_fns) > n_variants
+        carry = (self._no_carry(nb), self._no_carry(nb))  # the window's contract
+        ys, dispatched_at = self._launch(
+            fn, "block", packed, full_sampler, fresh, carry
+        )
+        live = sum(1 for e in rows if e.start >= 0)
+        key = self._count_dispatch(
+            nb, page_bucket, True, full_sampler, want_lp, False,
+            chained=chained is not None, live=None,
+        )
+        self.flight.record(
+            "dispatch", dispatch="ragged", program="block", rows=live,
+            bucket=nb, windowed=True, chained=chained is not None or None,
+            pages=key[1], want_lp=want_lp,
+        )
+        get_telemetry().decode_batch_rows.labels("block").observe(live)
+        return _PendingRagged(
+            ys=ys, rows=rows, nb=nb, windowed=True, full_sampler=full_sampler,
+            want_lp=want_lp, solo=solo, packed=packed,
+            dispatched_at=dispatched_at,
+        )
+
+    def _dispatch_chained_block(self, pending):
+        """Dispatch block N+1 over block N's rows before the host syncs on
+        N: a fresh block's inputs are its start and the mask token, both
+        known here, and the cache it reads is on the device. A row whose
+        budget or model length block N exhausts sits the dispatch out
+        (start -1); a stop token inside block N is found at its consume,
+        and the row's block N+1 is then computed for nothing. None (chain
+        break) when the pool cannot cover a row."""
+        cfg = self.cfg
+        B, ps = cfg.model.block_length, cfg.page_size
+        part = []
+        for e in pending.rows:
+            seq, start = e.seq, e.start + B
+            emits = B - (seq.blk_fixed_at or []).count(CONTEXT)
+            sc = seq.stop.stop_conditions
+            left = (sc.max_tokens or cfg.default_max_tokens) - seq.generated
+            if (
+                e.start < 0 or left <= emits
+                or start + B > cfg.max_model_len
+                or seq.state is not SeqState.ACTIVE
+                or seq.pending_finish is not None
+            ):
+                part.append((e.row, seq, -1))
+                continue
+            self.sched.ensure_pages_until(seq, start + B - 1)
+            if len(seq.page_ids) * ps < start + B:
+                return None  # pool dry: consume + rebuild instead
+            part.append((e.row, seq, start))
+        if all(start < 0 for _r, _s, start in part):
+            return None
+        return self._build_block(part, pending.full_sampler, True, pending)
+
+    def _build_mixed_block(self, dec, pf, full_sampler: bool):
+        """Build + dispatch one mixed batch of a block model (no host
+        sync): each prefilling row's next chunk (cut at block boundaries)
+        and each decoding row's block as a span of B tokens making one
+        pass. ``_build_mixed``'s stream, without sampling rows."""
+        from .engine import _PendingRagged, _RaggedRow
+
+        cfg = self.cfg
+        B, ps = cfg.model.block_length, cfg.page_size
+        with self._phase("assemble"), self._part("assemble", "plan"):
+            sizes = self._plan_chunks(
+                [seq.prefill_target(B) - seq.prefill_sent for seq in pf],
+                [seq.admitted_at for seq in pf], 0, [B] * len(dec),
+            )
+        with self._phase("assemble"), self._part("assemble", "build"):
+            align = self._ragged_align()
+            up = lambda n: -(-n // align) * align  # noqa: E731
+            nb = self._mixed_bucket_for(
+                sum(map(up, sizes)) + up(B) * len(dec), 0
+            )
+            packed, f = self._launch_buffer(nb, False, full_sampler)
+            tokens, positions, row_of = f["tokens"], f["positions"], f["row_of"]
+            sampler = tuple(f[name] for name in SAMPLER_FIELDS)
+            at = 0
+
+            def add_span(toks, first_pos: int, r: int) -> int:
+                nonlocal at
+                start, n = at, len(toks)
+                tokens[start : start + n] = toks
+                positions[start : start + n] = np.arange(first_pos, first_pos + n)
+                row_of[start : start + up(n)] = r
+                at += up(n)
+                return start
+
+            rows: list[_RaggedRow] = []
+            chunks: list[tuple[Sequence, int, int]] = []
+            max_pages = 1
+            r = 0
+            for seq, n in zip(pf, sizes):
+                self._apply_uploads(seq)
+                first = seq.prefill_sent
+                add_span(seq.prompt[first : first + n], first, r)
+                seq.prefill_sent = first + n
+                chunks.append((seq, first, n))
+                f["table"][r, : len(seq.page_ids)] = seq.page_ids
+                max_pages = max(max_pages, (seq.prefill_sent + ps - 1) // ps)
+                rows.append(
+                    _RaggedRow(
+                        seq, "prefill", r,
+                        completing=seq.prefill_sent == seq.prefill_target(B),
+                    )
+                )
+                r += 1
+            mask = cfg.model.mask_token_id
+            for seq, start, _cap in dec:
+                fed = [
+                    t if at_pass != UNFIXED else mask
+                    for t, at_pass in zip(seq.blk_tokens, seq.blk_fixed_at)
+                ]
+                qs = add_span(fed, start, r)
+                f["blk_idx"][r] = qs + np.arange(B)
+                self._fill_block_row(seq, r, f, sampler)
+                max_pages = max(max_pages, (start + B + ps - 1) // ps)
+                rows.append(_RaggedRow(seq, "decode", r, n_valid=B, start=start))
+                r += 1
+            assert self._mixed_bucket_for(at, 0) == nb, (at, nb)
+            want_lp = any(
+                self._wants_logprobs(e.seq) is not None for e in rows
+            )
+        with self._phase("assemble"), self._part("assemble", "lookup"):
+            n_variants = len(self._ragged_fns)
+            page_bucket = cfg.ragged_page_bucket_for(max_pages)
+            fn = self._ragged_fn(nb, page_bucket, False, full_sampler, want_lp)
+            fresh = len(self._ragged_fns) > n_variants
+        ys, dispatched_at = self._launch(fn, "mixed", packed, full_sampler, fresh)
+        key = self._count_dispatch(
+            nb, page_bucket, False, full_sampler, want_lp, False,
+            chained=False, live=int((positions >= 0).sum()),
+        )
+        self.flight.record(
+            "dispatch", dispatch="ragged", rows=len(rows), tokens=at,
+            bucket=nb, windowed=False, pages=key[1], want_lp=want_lp,
+        )
+        self._note_prefill_chunks(pf, chunks, nb)
+        self.steps += 1
+        get_telemetry().decode_batch_rows.labels("mixed").observe(len(dec))
+        return _PendingRagged(
+            ys=ys, rows=rows, nb=nb, windowed=False, full_sampler=full_sampler,
+            want_lp=want_lp, solo=False, dispatched_at=dispatched_at,
+        )
+
+    # ----------------------------------------------------------------- consume
+    def _consume_block(self, pending) -> None:
+        """Host sync of one dispatch of a block model, of either program:
+        each decoding row's block takes what its pass(es) fixed; a block
+        that was written is emitted whole (cut by ``check_stop``) and the
+        row opens its next; a prompt whose last chunk this was joins
+        decode. A stop found while a chained successor is in flight
+        defers the finish until that one is consumed, as
+        ``_consume_windowed`` does."""
+        program = "block" if pending.windowed else "mixed"
+        with self._phase("sync"):
+            ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
+        toks, fixed_at, n_denoise, wrote = ys[:4]
+        if pending.want_lp:
+            lps, top_ids, top_lps = ys[4:7]
+        if self.profiler is not None:
+            self.profiler.consume("ragged", pending.dispatched_at, program)
+        B = self.cfg.model.block_length
+        tel = get_telemetry()
+        finishes: list[Sequence] = []
+        emitted = wasted = passes_denoise = passes_write = 0
+        with self._phase("consume"):
+            clock = time.monotonic
+            tokens_s = pages_s = emit_s = first_s = 0.0
+            for e in pending.rows:
+                seq, r = e.seq, e.row
+                if e.kind == "prefill":
+                    if e.completing:
+                        t0 = clock()
+                        self._promote_to_decode(seq)
+                        self.sched.register_full_pages(seq)
+                        first_s += clock() - t0
+                    continue
+                if e.start < 0:
+                    continue  # sat a chained dispatch out
+                d, w = int(n_denoise[r]), int(wrote[r])
+                passes_denoise += d
+                passes_write += w
+                if (
+                    seq.state is not SeqState.ACTIVE
+                    or seq.pending_finish is not None
+                ):
+                    wasted += (d + w) * B  # past this row's stop
+                    continue
+                t0 = clock()
+                assert self._block_of(seq) == e.start, (seq.request_id, e.start)
+                n_top = self._wants_logprobs(seq)
+                fixed = 0
+                for j in range(B):
+                    at_pass = int(fixed_at[r, j])
+                    if seq.blk_fixed_at[j] != UNFIXED or at_pass == UNFIXED:
+                        continue
+                    fixed += 1
+                    seq.blk_tokens[j] = int(toks[r, j])
+                    seq.blk_fixed_at[j] = at_pass
+                    if n_top is not None and pending.want_lp:
+                        seq.blk_lp[j] = (
+                            float(lps[r, j]),
+                            {
+                                int(t): float(l) for t, l in
+                                zip(top_ids[r, j, :n_top], top_lps[r, j, :n_top])
+                            },
+                        )
+                seq.blk_passes += d
+                tel.diffusion_tokens_fixed.inc(fixed)
+                if not w:
+                    tokens_s += clock() - t0
+                    continue
+                # Written: the block is final. Emit what is not context.
+                kept, at, reason = [], [], None
+                for j in range(B):
+                    if seq.blk_fixed_at[j] == CONTEXT:
+                        continue
+                    token = seq.blk_tokens[j]
+                    kept.append(token)
+                    at.append(j)
+                    seq.tokens.append(token)
+                    seq.generated += 1
+                    reason = self.sched.check_stop(seq, token)
+                    if reason is not None:
+                        break
+                emitted += len(kept)
+                pack = None
+                if n_top is not None and all(
+                    seq.blk_lp[j] is not None for j in at
+                ):
+                    pack = (
+                        [seq.blk_lp[j][0] for j in at],
+                        [seq.blk_lp[j][1] for j in at] if n_top > 0 else None,
+                        [seq.blk_fixed_at[j] for j in at],
+                    )
+                seq.close_block()
+                tel.diffusion_blocks.inc()
+                t1 = clock()
+                self.sched.register_full_pages(seq)
+                t2 = clock()
+                now = time.time()
+                if seq.last_emit_at and kept:
+                    tel.time_between_tokens.observe(
+                        max(now - seq.last_emit_at, 0.0) / len(kept)
+                    )
+                seq.last_emit_at = now
+                seq.emit(kept, None, pack)
+                t3 = clock()
+                tokens_s += t1 - t0
+                pages_s += t2 - t1
+                emit_s += t3 - t2
+                if reason is not None:
+                    seq.pending_finish = reason
+                    finishes.append(seq)
+            tel.diffusion_passes.labels("denoise").inc(passes_denoise)
+            tel.diffusion_passes.labels("write").inc(passes_write)
+            if pending.windowed:
+                # The program's slots, known now that its passes are: every
+                # iteration of its loop carried the whole bucket.
+                loops = int((n_denoise + wrote).max(initial=0))
+                live = (passes_denoise + passes_write) * B
+                self._count_slots("block", live, pending.nb * B * loops - live)
+                self.steps += loops
+            self.flight.record(
+                "consume", dispatch="ragged", tokens=emitted, wasted=wasted
+            )
+            if wasted:
+                self.wasted_steps += wasted
+                tel.decode_wasted_steps.inc(wasted)
+            if self.profiler is not None:
+                self.profiler.add_parts(
+                    "consume", tokens=tokens_s, pages=pages_s, emit=emit_s,
+                    first_token=first_s,
+                )
+        if finishes:
+            # Pages about to be released must have no dispatch in flight
+            # over them (``_consume_windowed``).
+            succ, self._inflight = self._inflight, None
+            if succ is not None:
+                self._consume_ragged(succ)
+            with self._phase("consume"), self._part("consume", "finish"):
+                for seq in finishes:
+                    reason, seq.pending_finish = seq.pending_finish, None
+                    self.sched.finish(seq, reason)

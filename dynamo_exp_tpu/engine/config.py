@@ -127,6 +127,52 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
         )
 
 
+def refuse_block_model_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
+    """The one place a model that generates by diffusion over blocks
+    (``ModelConfig.block_length`` > 0: a row's step is a pass over a block
+    of positions, and a prompt yields no first token of its own) is
+    refused by what assumes a step is one token, in
+    ``refuse_seq_state_tiers``' manner:
+
+    - speculative decoding (``spec_mode``): a draft span is verified one
+      token after another, each seeing only what lies before it;
+    - ring prefill (``sp`` > 1): its attention is causal by position;
+    - tensor parallelism (``tp`` > 1): the block program has not run on a
+      mesh, and nothing tests it there;
+    - ``tier`` = "disaggregated transfer" / "migration": the hand-off
+      carries a prompt's pages and its first sampled token, which a
+      block model's prefill does not produce.
+
+    The engine's shapes have to hold whole blocks too: a page, a prompt
+    chunk and the model length are multiples of the block length, so a
+    block lies in one page and a chunk ends where a block does."""
+    if cfg is None or not cfg.model.block_length:
+        return
+    B = cfg.model.block_length
+    armed = [tier] if tier else [
+        name for name, on in (
+            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
+            ("ring prefill (sp > 1)", cfg.sp > 1),
+            ("tensor parallelism (tp > 1)", cfg.tp > 1),
+            (
+                f"a page_size, prefill_chunk or max_model_len that is no "
+                f"multiple of the block length {B}",
+                bool(
+                    cfg.page_size % B or cfg.prefill_chunk % B
+                    or cfg.max_model_len % B
+                ),
+            ),
+        ) if on
+    ]
+    if armed:
+        raise ValueError(
+            f"model_type {cfg.model.model_type!r} generates by diffusion over "
+            f"blocks of {B} positions (a step is a pass over a block, not a "
+            f"token), which {', '.join(armed)} does not allow for. Serve it "
+            "without (README.md, models/)"
+        )
+
+
 def default_prefill_buckets(max_len: int) -> list[int]:
     buckets = []
     b = 16

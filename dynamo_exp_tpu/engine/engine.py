@@ -108,7 +108,8 @@ from ..telemetry.flight import (
     register_dumper,
     unregister_dumper,
 )
-from .config import EngineConfig, refuse_seq_state_tiers
+from .block import BlockDecode
+from .config import EngineConfig, refuse_block_model_tiers, refuse_seq_state_tiers
 from .kv_manager import KvEvent, KvPageManager
 from .offload import CopyStream, HostKvPool
 from .scheduler import RemoteKv, Scheduler, SeqState, Sequence
@@ -190,6 +191,7 @@ class _RaggedRow:
     completing: bool = False  # prefill: prompt finishes this chunk
     counted: bool = False  # prefill: the first token drew as a decode row
     n_drafts: int = 0  # spec: drafts fed for verification
+    start: int = -1  # a block model's decode row: its block's first position
 
 
 @dataclass
@@ -251,7 +253,7 @@ def _no_part(phase: str, name: str):
     return _NO_PHASE
 
 
-class TPUEngine(AsyncEngine):
+class TPUEngine(BlockDecode, AsyncEngine):
     """Continuous-batching paged-KV engine on a TPU mesh."""
 
     def __init__(
@@ -264,6 +266,7 @@ class TPUEngine(AsyncEngine):
     ):
         self.cfg = cfg
         refuse_seq_state_tiers(cfg)
+        refuse_block_model_tiers(cfg)
         self.mesh = mesh or build_mesh(tp=cfg.tp, sp=cfg.sp)
         mcfg = cfg.model
 
@@ -380,6 +383,7 @@ class TPUEngine(AsyncEngine):
             g3_store=self.g3_store,
             state_slots=state_slots,
             state_snapshots=state_snapshots,
+            partial_tail=not mcfg.block_length,
         )
         # Observability (docs/observability.md): per-dispatch profiler
         # (host gap vs in-flight, compile attribution — pure timestamps
@@ -715,13 +719,17 @@ class TPUEngine(AsyncEngine):
             "want_lp=%s with_spec=%s attn=%s",
             nb, pages, windowed, full_sampler, want_lp, with_spec, impl,
         )
-        fn = (
-            self._windowed_program(nb, pages, impl, full_sampler, want_lp)
-            if windowed
-            else self._mixed_program(
+        if self.cfg.model.block_length:
+            # Generation by diffusion over blocks (engine/block.py): the
+            # same two families, a block where the others have a token.
+            build = self._block_program if windowed else self._mixed_block_program
+            fn = build(nb, pages, impl, full_sampler, want_lp)
+        elif windowed:
+            fn = self._windowed_program(nb, pages, impl, full_sampler, want_lp)
+        else:
+            fn = self._mixed_program(
                 nb, pages, impl, full_sampler, want_lp, with_spec
             )
-        )
         self._ragged_fns[key] = fn
         return fn
 
@@ -1204,7 +1212,8 @@ class TPUEngine(AsyncEngine):
             full_sampler, want_lp, with_spec,
         )
         tel = get_telemetry()
-        program = "window" if windowed else "mixed"
+        block = self.cfg.model.block_length
+        program = ("block" if block else "window") if windowed else "mixed"
         tel.ragged_dispatches.labels(
             program,
             str(nb),
@@ -1213,7 +1222,25 @@ class TPUEngine(AsyncEngine):
             str(int(full_sampler)),
             str(int(chained)),
         ).inc()
-        pad = nb * (self.cfg.decode_window if windowed else 1) - live
+        if live is not None:
+            self._count_slots(
+                program, live,
+                nb * (self.cfg.decode_window if windowed else 1) - live,
+            )
+        if self.cfg.model.is_moe:
+            # nb: a window's rows a step, a mixed dispatch's flat tokens; a
+            # block program's rows are blocks, read in place at any count.
+            by_block = bool(block and windowed)
+            tel.moe_dispatches.labels(routed_experts_form(
+                self.cfg.model, self.mesh, nb * block if by_block else nb,
+                self._attn_interpret, any_rows=by_block,
+            )).inc()
+        return key
+
+    def _count_slots(self, program: str, live: int, pad: int) -> None:
+        """``_count_dispatch``'s slots. A block program's are counted at
+        its consume: how many passes it made is the device's to say."""
+        tel = get_telemetry()
         tel.ragged_slots.labels(program, "live").inc(live)
         tel.ragged_slots.labels(program, "pad").inc(pad)
         if self.cfg.model.num_mamba_layers:
@@ -1221,11 +1248,6 @@ class TPUEngine(AsyncEngine):
         if self.cfg.model.is_moe:
             tel.moe_rows.labels("live").inc(live)
             tel.moe_rows.labels("pad").inc(pad)
-            # nb: a window's rows a step, a mixed dispatch's flat tokens.
-            tel.moe_dispatches.labels(routed_experts_form(
-                self.cfg.model, self.mesh, nb, self._attn_interpret
-            )).inc()
-        return key
 
     # -------------------------------------------------------------- lifecycle
     def start(self) -> None:
@@ -1458,6 +1480,10 @@ class TPUEngine(AsyncEngine):
                         token_ids=tokens,
                         logprobs=logprobs[0] if logprobs else None,
                         top_logprobs=logprobs[1] if logprobs else None,
+                        # A block model's pack has the passes third.
+                        fixed_at_pass=(
+                            logprobs[2] if logprobs and len(logprobs) > 2 else None
+                        ),
                     ).to_dict()
                 if reason is not None:
                     yield LLMEngineOutput(
@@ -1893,11 +1919,12 @@ class TPUEngine(AsyncEngine):
                     # new one executes.
                     with phase("assemble"), part("assemble", "plan"):
                         can_chain = self._can_chain()
-                    nxt = (
-                        self._dispatch_chained(self._inflight)
-                        if can_chain
-                        else None
+                    chain = (
+                        self._dispatch_chained_block
+                        if self.cfg.model.block_length
+                        else self._dispatch_chained
                     )
+                    nxt = chain(self._inflight) if can_chain else None
                     prev, self._inflight = self._inflight, nxt
                     self._consume_ragged(prev)
                     with phase("publish"):
@@ -2000,6 +2027,16 @@ class TPUEngine(AsyncEngine):
                         s for s in prefilling if self.sched.fill_ready(s)
                     ]
                     batch = [s for s in ready if s.remote_kv is None]
+                    block = self.cfg.model.block_length
+                    if block:
+                        # A prompt whose whole blocks all came from the
+                        # cache (or that has none) has nothing to prefill:
+                        # it decodes from this iteration on.
+                        for s in batch:
+                            if s.prefill_sent >= s.prefill_target(block):
+                                self._apply_uploads(s)
+                                self._promote_to_decode(s)
+                        batch = [s for s in batch if s.state is SeqState.PREFILL]
                 remote = [s for s in ready if s.remote_kv is not None]
                 if remote:
                     with phase("kv_admin"):
@@ -3164,16 +3201,10 @@ class TPUEngine(AsyncEngine):
             ]
         return ([float(x) for x in lps], tops)
 
-    def _finish_first_token(
-        self, seq: Sequence, token: int, lp_pack=None, counted: bool = False
-    ) -> None:
-        """Shared tail of the two admission paths (computed prefill or
-        remote-KV injection): record + announce the first sampled token
-        and promote the sequence to decode. ``lp_pack`` is None on the
-        remote-KV path — the first token was sampled on the prefill
-        worker, which doesn't ship its distribution. ``counted``: the
-        dispatch that drew ``token`` already left the slot's penalty
-        counts at journaled tail + ``token`` (``_build_mixed``)."""
+    def _promote_to_decode(self, seq: Sequence) -> None:
+        """A prompt's K/V is whole: close the request's prefill stage and
+        make the sequence a decoding row. ``_finish_first_token``'s first
+        half; a block model, whose prefill samples nothing, stops here."""
         now = time.time()
         seq.first_token_at = seq.last_emit_at = now
         tel = get_telemetry()
@@ -3211,6 +3242,18 @@ class TPUEngine(AsyncEngine):
         # prefill/decode boundary (telemetry.anatomy.anatomy_from_flight).
         self.flight.record("first_token", req=seq.request_id, slot=seq.slot)
         seq.state = SeqState.ACTIVE
+
+    def _finish_first_token(
+        self, seq: Sequence, token: int, lp_pack=None, counted: bool = False
+    ) -> None:
+        """Shared tail of the two admission paths (computed prefill or
+        remote-KV injection): record + announce the first sampled token
+        and promote the sequence to decode. ``lp_pack`` is None on the
+        remote-KV path — the first token was sampled on the prefill
+        worker, which doesn't ship its distribution. ``counted``: the
+        dispatch that drew ``token`` already left the slot's penalty
+        counts at journaled tail + ``token`` (``_build_mixed``)."""
+        self._promote_to_decode(seq)
         if not counted:
             self._counts = self._init_row(self._counts, seq.slot, token)
             # Remote-KV continuation with penalties: the first token was
@@ -3333,8 +3376,14 @@ class TPUEngine(AsyncEngine):
         with self._phase("assemble"), self._part("assemble", "plan"):
             batches, solo = self._plan_ragged(prefill_rows)
         out: list[_PendingRagged] = []
+        block = self.cfg.model.block_length
         for fs, dec, spec, pf, windowed in batches:
-            if windowed:
+            if block and windowed:
+                part = [(r, seq, start) for r, (seq, start, _) in enumerate(dec)]
+                out.append(self._build_block(part, fs, solo))
+            elif block:
+                out.append(self._build_mixed_block(dec, pf, fs))
+            elif windowed:
                 out.append(self._build_windowed(dec, fs, solo))
             else:
                 out.append(self._build_mixed(dec, spec, pf, fs))
@@ -3348,7 +3397,8 @@ class TPUEngine(AsyncEngine):
         prefill rows, windowed)``, provisioning pages as it goes; and
         whether the one batch is a chainable window."""
         cfg = self.cfg
-        ps, K = cfg.page_size, cfg.decode_window
+        block = cfg.model.block_length
+        ps, K = cfg.page_size, block or cfg.decode_window
         greedy: list[tuple[Sequence, int, int]] = []  # (seq, wpos, cap)
         sampler: list[tuple[Sequence, int, int]] = []
         for seq in self.sched.slots:
@@ -3372,11 +3422,18 @@ class TPUEngine(AsyncEngine):
                     )
                 continue
             wpos = len(seq.tokens) - 1  # position of the token being fed
+            if block:
+                # A block model feeds its whole block or nothing: from the
+                # block's start (engine/block.py) through its last position.
+                wpos = self._block_of(seq)
+                if wpos + K > cfg.max_model_len:
+                    self.sched.finish(seq, FinishReason.LENGTH)
+                    continue
             # Provision the whole window up front (best effort: partial
             # allocation still lets the row run until its pages end).
             self.sched.ensure_pages_until(seq, wpos + K - 1)
             cap = min(cfg.max_model_len, len(seq.page_ids) * ps) - 1
-            if cap < wpos:
+            if cap < (wpos + K - 1 if block else wpos):
                 if wpos // ps >= self.kv.num_pages:
                     # The row's own context now exceeds the ENTIRE pool:
                     # no preemption or wait can ever feed its next token
@@ -3915,6 +3972,30 @@ class TPUEngine(AsyncEngine):
             pages=key[1],
             want_lp=want_lp,
         )
+        self._note_prefill_chunks(pf, chunks, nb)
+        tel = get_telemetry()
+        self.steps += 1
+        if spec:
+            self.spec_dispatches += 1
+        tel.decode_batch_rows.labels("mixed").observe(len(dec) + len(spec))
+        return _PendingRagged(
+            ys=ys,
+            rows=rows,
+            nb=nb,
+            windowed=False,
+            full_sampler=full_sampler,
+            want_lp=want_lp,
+            solo=False,
+            with_spec=with_spec,
+            dispatched_at=dispatched_at,
+        )
+
+    def _note_prefill_chunks(self, pf, chunks, nb: int) -> None:
+        """After a mixed dispatch went out: its prompt chunks (seq, from,
+        n) named on the pages they wrote, counted by share and fill, and
+        the pages they covered marked filled."""
+        cfg = self.cfg
+        ps = cfg.page_size
         # Name this dispatch on the pages its prompt chunks wrote, for
         # the admission record of whoever attaches them later.
         tel = get_telemetry()
@@ -3939,21 +4020,6 @@ class TPUEngine(AsyncEngine):
                 seq.fill_marked = n_full
         if newly_filled:
             self.kv.mark_filled(newly_filled)
-        self.steps += 1
-        if spec:
-            self.spec_dispatches += 1
-        tel.decode_batch_rows.labels("mixed").observe(len(dec) + len(spec))
-        return _PendingRagged(
-            ys=ys,
-            rows=rows,
-            nb=nb,
-            windowed=False,
-            full_sampler=full_sampler,
-            want_lp=want_lp,
-            solo=False,
-            with_spec=with_spec,
-            dispatched_at=dispatched_at,
-        )
 
     # ---------------------------------------------------------------- chaining
     def _can_chain(self) -> bool:
@@ -3998,7 +4064,9 @@ class TPUEngine(AsyncEngine):
                 # after the chain started; chaining over the old row set
                 # would starve it — rebuild a fresh compacted window.
                 return False
-        K = self.cfg.decode_window
+        # What the inflight dispatch yields a row at the most: a window's
+        # steps, or a block model's block.
+        K = self.cfg.model.block_length or self.cfg.decode_window
         for e in p.rows:
             sc = e.seq.stop.stop_conditions
             max_tokens = sc.max_tokens or self.cfg.default_max_tokens
@@ -4118,7 +4186,9 @@ class TPUEngine(AsyncEngine):
 
     # ----------------------------------------------------------------- consume
     def _consume_ragged(self, pending: _PendingRagged) -> None:
-        if pending.windowed:
+        if self.cfg.model.block_length:
+            self._consume_block(pending)
+        elif pending.windowed:
             self._consume_windowed(pending)
         else:
             self._consume_mixed(pending)

@@ -155,9 +155,15 @@ class KvPageManager:
         g3_store=None,
         state_slots: int = 0,
         state_snapshots: int = 0,
+        partial_tail: bool = True,
     ):
         self.num_pages = num_pages
         self.page_size = page_size
+        # Whether a prompt that ends inside a block another sequence
+        # registered attaches that page read-shared (``allocate_sequence``).
+        # Off for a model that generates by diffusion over blocks: its
+        # prompt's tail is rewritten with the first block's every pass.
+        self.partial_tail = partial_tail
         self.event_cb = event_cb
         self.clock = clock
         # Fleet-wide prefix sharing (docs/prefix_sharing.md). False is
@@ -450,6 +456,7 @@ class KvPageManager:
         tail_tokens = tokens[(n_tokens // ps) * ps :]
         if (
             self.sharing
+            and self.partial_tail
             and not self.state_slots
             and tail_tokens
             and not g2_hashes

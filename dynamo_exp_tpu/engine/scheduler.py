@@ -114,6 +114,16 @@ class Sequence:
     # on-device stop already flipped the row's position to -1, making
     # the in-flight window's output for it pure discard.
     pending_finish: "FinishReason | None" = None
+    # A block-diffusion model's block in flight (``engine/block.py``):
+    # its tokens, for each position the pass that fixed it
+    # (``ops/sampling.py`` UNFIXED / CONTEXT), the log-probability pack of
+    # that pass, and the denoising passes it has had. None between
+    # blocks: preemption, resume and a prefix hit all restart a row at a
+    # block's start, since a block in flight has no state worth keeping.
+    blk_tokens: "list[int] | None" = None
+    blk_fixed_at: "list[int] | None" = None
+    blk_lp: "list | None" = None
+    blk_passes: int = 0
     # G2→G1 injections the engine must dispatch before this prefill:
     # (page_id, seq_hash, k_page, v_page) per page (see kv_manager).
     pending_uploads: list = field(default_factory=list)
@@ -210,6 +220,19 @@ class Sequence:
 
     def last_token(self) -> int:
         return self.tokens[-1]
+
+    def prefill_target(self, block: int) -> int:
+        """Prompt tokens prefill writes: all of them, or for a model that
+        generates by diffusion over blocks of ``block`` positions the
+        whole blocks among them (the rest open the first block, already
+        fixed: their K/V is that block's to write)."""
+        n = len(self.prompt)
+        return n // block * block if block else n
+
+    def close_block(self) -> None:
+        """No block in flight (emitted, or dropped by a preemption)."""
+        self.blk_tokens = self.blk_fixed_at = self.blk_lp = None
+        self.blk_passes = 0
 
 
 def select_preemption_victim(candidates, max_preemptions: int):
@@ -373,6 +396,11 @@ class Scheduler:
             self._register_uploads(seq, alloc.hashes)
             seq.tokens = list(seq.prompt)
             seq.prefill_sent = seq.cached_len
+            block = self.cfg.model.block_length
+            if block:  # chunks begin and end where blocks do
+                seq.prefill_sent = (
+                    min(seq.cached_len, seq.prefill_target(block)) // block * block
+                )
             seq.slot = slot
             seq.state_slot = self.kv.take_state_slot()
             seq.state_restore = alloc.state_restore
@@ -663,6 +691,7 @@ class Scheduler:
         seq.stalled = False
         seq.stalled_since = 0.0
         seq.pending_finish = None
+        seq.close_block()
         seq.pending_uploads = []
         seq.wait_fill = []
         seq.shared_tail_pid = -1

@@ -122,6 +122,20 @@ class ModelConfig:
     # Whether attention turns q and k by their positions. jamba's does
     # not: its Mamba layers carry the order of the tokens.
     use_rope: bool = True
+    # sdar_moe: generation by diffusion over blocks. ``block_length`` B > 0
+    # (a power of two) makes a row's step a *pass* over a block of B
+    # positions instead of one token: attention is causal across blocks
+    # and full inside one, ``logits[i]`` is the distribution of the token
+    # AT position ``i`` (no shift), a block starts as ``mask_token_id`` and
+    # takes up to ``denoising_steps`` passes, each fixing the positions
+    # ``remasking_strategy`` picks by confidence (``ops/sampling.py::
+    # denoise_select``), then one pass that writes its final K/V. 0: one
+    # token a step, every other family.
+    block_length: int = 0
+    denoising_steps: int = 0
+    remasking_strategy: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 0
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -225,7 +239,7 @@ class ModelConfig:
             return cls._from_lfm2_moe(cfg)
         if model_type == "jamba":
             return cls._from_jamba(cfg)
-        if model_type in ("qwen2_moe", "qwen3_moe") and (
+        if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
             # Per-layer dense/sparse mixing stores mlp.gate_proj for the
@@ -255,7 +269,7 @@ class ModelConfig:
             attention_bias=cfg.get(
                 "attention_bias", model_type in ("qwen2", "qwen2_moe")
             ),
-            qk_norm=model_type in ("qwen3", "qwen3_moe", "gemma3_text"),
+            qk_norm=model_type in ("qwen3", "qwen3_moe", "sdar_moe", "gemma3_text"),
             hidden_act=(
                 "gelu_tanh"
                 if str(
@@ -296,6 +310,7 @@ class ModelConfig:
             ),
             dtype=cfg.get("torch_dtype", "bfloat16"),
             model_type=model_type,
+            **(_block_generation(cfg) if model_type == "sdar_moe" else {}),
         )
 
     @classmethod
@@ -401,14 +416,58 @@ class ModelConfig:
     @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
-            return cls.from_hf_config(json.load(f))
+            cfg = json.load(f)
+        # A block-diffusion model's generation keys may also lie in the
+        # directory's ``generation_config.json``, which then has the say.
+        gen = os.path.join(path, "generation_config.json")
+        if cfg.get("model_type") == "sdar_moe" and os.path.exists(gen):
+            with open(gen) as f:
+                cfg = {**cfg, **{
+                    k: v for k, v in json.load(f).items() if k in _BLOCK_KEYS
+                }}
+        return cls.from_hf_config(cfg)
 
 
 # The families ``from_hf_config`` builds; any other ``model_type`` raises.
+# ``sdar_moe`` is ``qwen3_moe``'s stack, block for block, generating by
+# diffusion over blocks (``_block_generation``).
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
-    "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe", "jamba",
+    "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
 })
+
+# A block-diffusion model's generation keys and the SDAR family's
+# published defaults (the catalog's row of ``config.json`` has none of
+# them: benchmark/configs/sdar-30b-a3b-l6.json, ``assumed``).
+_BLOCK_KEYS = {
+    "block_length": 4,
+    "denoising_steps": 4,
+    "remasking_strategy": "low_confidence_dynamic",
+    "confidence_threshold": 0.9,
+    "mask_token_id": 151669,
+}
+REMASKING_STRATEGIES = ("low_confidence_dynamic", "low_confidence_static")
+
+
+def _block_generation(cfg: dict) -> dict:
+    """``ModelConfig``'s block-diffusion fields from a ``config.json``."""
+    out = {k: cfg.get(k, default) for k, default in _BLOCK_KEYS.items()}
+    B, T = int(out["block_length"]), int(out["denoising_steps"])
+    if B < 1 or B & (B - 1) or T < 1:
+        raise ValueError(
+            f"block_length {B} must be a power of two and denoising_steps "
+            f"{T} at least 1"
+        )
+    if out["remasking_strategy"] not in REMASKING_STRATEGIES:
+        raise ValueError(
+            f"remasking_strategy {out['remasking_strategy']!r}; supported: "
+            f"{', '.join(REMASKING_STRATEGIES)}"
+        )
+    # Any id inside the vocabulary serves: a small test vocabulary lacks
+    # the published one.
+    vocab = int(cfg.get("vocab_size", 32000))
+    out["mask_token_id"] = min(int(out["mask_token_id"]), vocab - 1)
+    return out
 
 # Presets used by tests, the dry-run entrypoints, and the benchmark.
 TINY = ModelConfig(

@@ -679,12 +679,15 @@ def _conv_half(x, lp, cfg, eps, pool, layer, io):
         return x + y.reshape(x.shape), pool
 
 
-def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret):
+def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows=False):
     with jax.named_scope("moe" if "router" in lp else "ffn"):
-        return _ffn_block(x, lp, cfg, eps, red, mesh, live, layer, interpret)
+        return _ffn_block(
+            x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows
+        )
 
 
-def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=False):
+def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=False,
+               any_rows=False):
     """The layer's second half: norm, dense SwiGLU or sparse-MoE FFN,
     residual add. ``live`` ([B, T] bool) keeps a dispatch's padding
     rows out of the routed experts' sort (``ops/moe.py::moe_ffn``);
@@ -741,7 +744,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
                 live=None if live is None else live.reshape(B * T),
                 layer=layer, mesh=mesh, interpret=interpret,
                 score=cfg.router_score, select_bias=lp.get("router_bias"),
-                scale=cfg.routed_scaling_factor,
+                scale=cfg.routed_scaling_factor, any_rows=any_rows,
             ).reshape(B, T, -1)
             if shared is not None:
                 y = y + shared
@@ -763,7 +766,8 @@ def _expert_parallel(mesh) -> bool:
 
 
 def routed_experts_form(
-    cfg: ModelConfig, mesh, tokens: int, interpret: bool = False
+    cfg: ModelConfig, mesh, tokens: int, interpret: bool = False,
+    any_rows: bool = False,
 ) -> str:
     """How a paged forward over ``tokens`` rows has its expert matmuls
     read their weights on this mesh, by the rule the forwards themselves
@@ -775,7 +779,7 @@ def routed_experts_form(
 
     in_place = not _expert_parallel(mesh) and experts_in_place(
         cfg.hidden_size, cfg.expert_intermediate_size,
-        tokens * cfg.num_experts_per_tok, mesh, interpret,
+        tokens * cfg.num_experts_per_tok, mesh, interpret, any_rows,
     )
     return "inplace" if in_place else "sliced"
 
@@ -800,6 +804,12 @@ def _final_logits(params, cfg, x, eps):
             cap = cfg.final_logit_softcap
             logits = jnp.tanh(logits / cap) * cap
         return logits
+
+
+def final_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """The model's end on hidden states ``x`` ([.., D]): final norm and
+    head, float32 logits (what ``forward_ragged(project=False)`` left out)."""
+    return _final_logits(params, cfg, x, cfg.rms_norm_eps)
 
 
 def _maybe_scale_embeds(cfg, x):
@@ -861,6 +871,7 @@ _ROUTED_PARAMS = (
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
     live, interpret=False, seq_state=None, state_io=None, scan_form=ScanForm(),
+    experts_any_rows=False,
 ):
     """The layer loop of both paged forwards: one ``lax.scan`` for each
     run of layers of one kind (``ModelConfig.layer_runs``), carrying
@@ -907,7 +918,7 @@ def _scan_paged_layers(
     eps = cfg.rms_norm_eps
     layers = params["layers"]
     in_place = "router" in layers and routed_experts_form(
-        cfg, mesh, x.shape[0] * x.shape[1], interpret
+        cfg, mesh, x.shape[0] * x.shape[1], interpret, experts_any_rows
     ) == "inplace"
     win_arr, invf_arr, _ = _per_layer_xs(cfg, inv_freq)
     dense_names = (
@@ -982,6 +993,7 @@ def _scan_paged_layers(
             x = _ffn_half(
                 x, lp, cfg, eps, red, mesh, live,
                 (i + ff0 if ff0 else i) if stacks else None, interpret,
+                experts_any_rows,
             )
             return (x, k_pool, v_pool, state), None
 
@@ -1251,9 +1263,21 @@ def forward_ragged(
     interpret: bool = False,
     seq_state: jnp.ndarray | None = None,
     state_rows: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None = None,
+    experts_any_rows: bool = False,
+    project: bool = True,
 ) -> tuple[jnp.ndarray, ...]:
     """One ragged forward over a flat mixed batch (the single-dispatch
     prefill+decode+spec path, docs/engine_perf.md).
+
+    A block-diffusion model (``cfg.block_length`` B > 0) sees its whole
+    block: a query at position ``p`` attends to kv positions up to
+    ``p | (B - 1)`` (causal across blocks, full inside one), while rotary
+    embedding and the page writes stay at ``p``. The caller feeds whole
+    blocks (every position of a block a query of this dispatch, or
+    written by an earlier one), since the write of a span's K/V precedes
+    the read in the same layer. ``experts_any_rows``: the routed experts
+    read their weights in place at any row count (the block program;
+    ``ops/moe.py::experts_in_place``).
 
     The kernel reads the stream in tiles of ``q_tile`` slots, one row a
     tile. With ``packed_from`` (static, a multiple of ``q_tile``) only
@@ -1324,13 +1348,19 @@ def forward_ragged(
         else None
     )
     have_window = cfg.sliding_window is not None
+    # What a query may see: its own position, or its block's last.
+    bounds = positions
+    if cfg.block_length:
+        bounds = jnp.where(
+            positions >= 0, positions | (cfg.block_length - 1), -1
+        )
 
     def read(q, kf, vf, table_l, win_l):
         if use_pallas:
             def kernel(part, tile):
                 return _pallas_ragged(
                     q[0, part], kf, vf, table_l, row_of[part],
-                    positions[part], cfg.num_kv_heads, tile, mesh, interpret,
+                    bounds[part], cfg.num_kv_heads, tile, mesh, interpret,
                 )
 
             if packed_from is None:
@@ -1345,7 +1375,7 @@ def forward_ragged(
             q[0], kf, vf, table_l, row_of, positions,
             num_kv_heads=cfg.num_kv_heads, sm_scale=sm_scale,
             window=win_l if have_window else None,
-            softcap=cfg.attn_logit_softcap,
+            softcap=cfg.attn_logit_softcap, bounds=bounds,
         )[None]
 
     state_io = None
@@ -1357,9 +1387,10 @@ def forward_ragged(
         (page_ids, offsets, valid), attn_table, read, mesh,
         (positions >= 0)[None], interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, q_tile, packed_from, interpret),
+        experts_any_rows,
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
-    logits = _final_logits(params, cfg, xo, eps)
+    logits = _final_logits(params, cfg, xo, eps) if project else xo
     if seq_state is None:
         return logits, k_cache, v_cache
     return logits, k_cache, v_cache, seq_state

@@ -152,7 +152,7 @@ def _jamba_params(cfg: ModelConfig, get, linear) -> dict:
 SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
-    "mixtral", "qwen2_moe", "qwen3_moe", "lfm2_moe", "jamba",
+    "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
 )
 
 
@@ -249,8 +249,9 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
             # Stack per-expert weights to [E, D, I] / [E, I, D] for the
             # grouped ragged_dot matmuls. Mixtral names them
             # block_sparse_moe.experts.N.{w1=gate, w3=up, w2=down};
-            # qwen3_moe uses mlp.experts.N.{gate,up,down}_proj.
-            if cfg.model_type in ("qwen2_moe", "qwen3_moe"):
+            # qwen3_moe (and sdar_moe, the same stack) uses
+            # mlp.experts.N.{gate,up,down}_proj.
+            if cfg.model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe"):
                 m = p + "mlp."
                 names = ("gate_proj.weight", "up_proj.weight", "down_proj.weight")
             else:

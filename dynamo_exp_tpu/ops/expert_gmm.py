@@ -69,17 +69,38 @@ def _largest_tile(dim: int, cap: int) -> int:
     return best
 
 
-def tiles(m: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
-    """(tm, tk, tn) for ``[m, k] x [.., k, n]``.
+# Where a call's rows span more than one block of ``MAX_TM`` and lie over
+# many groups, a block of ``MAX_TM`` rows holds many groups of a few rows
+# and every one of its tiles multiplies the whole block: ``tm`` is then
+# this many times the mean group (``tiles``).
+ROWS_OVER_MEAN_GROUP = 8
+
+
+def tiles(
+    m: int, k: int, n: int, itemsize: int, groups: int | None = None
+) -> tuple[int, int, int]:
+    """(tm, tk, tn) for ``[m, k] x [.., k, n]`` over ``groups`` groups.
 
     ``tm``: every row tile multiplies ``tm`` rows by a [k, n] matrix it
     reads whole, so at ~240 rows a v5e's MXU and HBM take as long as each
     other; fewer rows leave the tile HBM-bound, which is what a decode
     step is anyway, and more would multiply rows of other groups for
-    nothing. ``tk`` depends on ``k`` alone (module docstring). ``tn`` is
-    as wide as VMEM allows: a [tk, n] block of a row-major matrix is one
-    contiguous run, and ``lhs`` is read once per n tile."""
+    nothing. A call of at most ``MAX_TM`` rows is one block whatever its
+    groups (Mixtral's and LFM2's decode windows; pinned by
+    tests/test_sdar.py). A call of more rows over many groups (a
+    block-diffusion pass: 2048 rows over 128 experts, 16 a group) takes
+    ``ROWS_OVER_MEAN_GROUP`` mean groups a block: a group that straddles
+    two blocks has its matrix fetched twice, so a block is several groups
+    long, and no longer, because each of a block's tiles multiplies all
+    of its rows (at ``MAX_TM`` that was 1.7 ms of MXU a layer against
+    1.5 ms of weights; ROADMAP R1). ``tk`` depends on ``k`` alone (module
+    docstring). ``tn`` is as wide as VMEM allows: a [tk, n] block of a
+    row-major matrix is one contiguous run, and ``lhs`` is read once per
+    n tile."""
     tm = min(MAX_TM, -(-m // ROW_ALIGN) * ROW_ALIGN)
+    if groups and m > MAX_TM:
+        per_block = -(-ROWS_OVER_MEAN_GROUP * m // groups)
+        tm = min(MAX_TM, max(ROW_ALIGN, -(-per_block // ROW_ALIGN) * ROW_ALIGN))
     tk = _largest_tile(k, MAX_TK)
     fixed = 2 * tm * tk * itemsize
     per_col = 2 * tk * itemsize + 2 * tm * itemsize + 4 * tm
@@ -171,7 +192,7 @@ def expert_gmm(
         w, layer = w[None], 0
     M, K = lhs.shape
     _L, E, _K, N = w.shape
-    tm, tk, tn = tiles(M, K, N, lhs.dtype.itemsize)
+    tm, tk, tn = tiles(M, K, N, lhs.dtype.itemsize, groups=E)
     row_blocks = -(-M // tm)
     if row_blocks * tm != M:
         lhs = jnp.pad(lhs, ((0, row_blocks * tm - M), (0, 0)))

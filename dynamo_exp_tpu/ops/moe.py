@@ -105,11 +105,15 @@ def moe_router(
 # only ``ragged-dot`` calls and have to keep reading in the Mixtral cell,
 # and many-row calls are where the slice costs least a row. Once a
 # benchmark issue has retired the two (ROADMAP S10c), delete this bound.
+# A program that is not the mixed one owes those two metrics nothing: a
+# block-diffusion model's block program asks with ``any_rows`` and reads
+# in place at 2048 sorted rows (``engine/block.py``).
 IN_PLACE_MAX_ROWS = expert_gmm.MAX_TM
 
 
 def experts_in_place(
-    hidden: int, expert_width: int, rows: int, mesh=None, interpret: bool = False
+    hidden: int, expert_width: int, rows: int, mesh=None, interpret: bool = False,
+    any_rows: bool = False,
 ) -> bool:
     """Whether ``moe_ffn`` multiplies through ``ops/expert_gmm.py`` (which
     reads a layer of the stacked weights in place) or through
@@ -117,8 +121,9 @@ def experts_in_place(
     (or ``interpret`` runs it on the CPU, as for the attention kernel:
     ``engine.resolve_attn_impl``), a chip's share of the widths can be
     tiled and the call's ``rows`` (tokens x experts a token) are at most
-    ``IN_PLACE_MAX_ROWS``. ``expert_width`` is the experts' whole
-    intermediate size; ``tp`` divides it."""
+    ``IN_PLACE_MAX_ROWS``, or any number with ``any_rows``.
+    ``expert_width`` is the experts' whole intermediate size; ``tp``
+    divides it."""
     platform = (
         mesh.devices.flat[0].platform if mesh is not None
         else jax.default_backend()
@@ -126,7 +131,7 @@ def experts_in_place(
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     return (
         (platform == "tpu" or interpret)
-        and rows <= IN_PLACE_MAX_ROWS
+        and (any_rows or rows <= IN_PLACE_MAX_ROWS)
         and expert_width % tp == 0
         and expert_gmm.supported(hidden, expert_width // tp)
         and expert_gmm.supported(expert_width // tp, hidden)
@@ -149,6 +154,7 @@ def moe_ffn(
     score: str = "softmax",
     select_bias: jnp.ndarray | None = None,  # [E]: moe_router's, one layer's
     scale: float = 1.0,
+    any_rows: bool = False,  # experts_in_place's: the kernel at any row count
 ) -> jnp.ndarray:
     """SwiGLU expert FFN with exact top-k dispatch. Returns [N, D].
 
@@ -173,7 +179,7 @@ def moe_ffn(
     D, width = w_gate.shape[-2:]
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     kernel = experts_in_place(
-        D, width, x.shape[0] * num_experts_per_tok, mesh, interpret
+        D, width, x.shape[0] * num_experts_per_tok, mesh, interpret, any_rows
     )
     experts = functools.partial(
         _routed_experts,

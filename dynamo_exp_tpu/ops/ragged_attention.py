@@ -10,7 +10,9 @@ Layout: queries arrive as a **flat token stream** ``q[N, H, D]`` with
 per-token ``positions[N]`` (absolute context position, ``-1`` =
 padding) and ``row_of[N]`` (which batch row owns the token). Rows own
 KV pages via ``page_table[R, Pmax]``; a token at position ``p`` attends
-causally to its row's kv positions ``<= p``. Total compute therefore
+causally to its row's kv positions ``<= p``, or ``<= bounds[i]`` where
+the caller gives each query its own bound (a block-diffusion model sees
+its whole block: ``p | (B - 1)``; padding stays -1). Total compute therefore
 tracks the *true* total query tokens — a lone decode row costs one
 token, a mixed batch costs the sum, never ``rows x max_chunk``.
 
@@ -84,6 +86,7 @@ def ragged_paged_attention_ref(
     sm_scale: float | None = None,
     window: int | jnp.ndarray | None = None,
     softcap: float | None = None,
+    bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
 ) -> jnp.ndarray:
     """Pure-JAX ragged paged attention (the CPU/tier-1 parity path).
 
@@ -113,7 +116,7 @@ def ragged_paged_attention_ref(
         k_cache,
         v_cache,
         page_table[row_of],  # [N, PB]
-        positions[:, None],  # [N, 1]
+        (positions if bounds is None else bounds)[:, None],  # [N, 1]
         sm_scale=sm_scale,
         window=window,
         softcap=softcap,
@@ -126,7 +129,7 @@ def _ragged_kernel(
     # scalar prefetch (SMEM)
     tile_row_ref,  # [T] int32 — owning batch row per query tile
     tile_kvlen_ref,  # [T] int32 — kv tokens the tile attends over (0=skip)
-    positions_ref,  # [N] int32 — absolute position per flat query
+    positions_ref,  # [N] int32 — last kv position each flat query sees
     table_ref,  # [R, Pmax] int32 — page ids per row
     # inputs
     q_ref,  # [QT, H, D] VMEM — this tile's queries
@@ -292,6 +295,7 @@ def ragged_paged_attention(
     q_tile: int = 8,
     sm_scale: float | None = None,
     interpret: bool = False,
+    bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
@@ -310,7 +314,12 @@ def ragged_paged_attention(
 
     The caller guarantees the fed tokens' K/V are already written
     (write-then-gather), so a tile's DMA bound is its max position + 1.
+    ``bounds`` replaces the positions as what a query may see (its own
+    position included; -1 for padding): the kernel reads nothing else of
+    them, so the tile's DMA bound follows.
     """
+    if bounds is not None:
+        positions = bounds
     N, H, D = q.shape
     _, ps, fused = k_cache.shape
     Hkv = num_kv_heads if num_kv_heads is not None else fused // D

@@ -258,3 +258,84 @@ def token_logprobs(
     chosen_lp = jnp.take_along_axis(lp, chosen[:, None], axis=-1)[:, 0]
     top_lp, top_ids = jax.lax.top_k(lp, top_n)
     return chosen_lp, top_ids.astype(jnp.int32), top_lp
+
+
+# ``fixed_at`` of a block-diffusion block's position (``denoise_select``):
+# the denoising pass that fixed it (0-based), or one of these.
+UNFIXED = -2  # still the mask token
+CONTEXT = -1  # fixed before the block began: the prompt's tail
+
+
+def denoise_counts(block: int, steps: int) -> list[int]:
+    """How many positions each of ``steps`` denoising passes fixes at
+    the least: ``block // steps``, the first ``block % steps`` one more."""
+    return [block // steps + (s < block % steps) for s in range(steps)]
+
+
+def denoise_select(
+    logits: jnp.ndarray,  # [R, B, V] float32: a pass's logits, a block a row
+    fixed_at: jnp.ndarray,  # [R, B] int32: UNFIXED where the mask token stands
+    passes: jnp.ndarray,  # [R] int32 denoising passes the block has had
+    *,
+    steps: int,
+    dynamic: bool,
+    threshold: float,
+    sampler: tuple | None = None,  # (seeds [R], positions [R, B], temp, top_k, top_p)
+    top_n: int | None = None,
+) -> tuple[jnp.ndarray, ...]:
+    """The confidence rule of generation by diffusion over blocks: which
+    masked positions a denoising pass fixes, and to what.
+
+    Of every masked position ``x0 = argmax logits`` (or, with ``sampler``,
+    a draw keyed by (seed, position, pass): ``sample_tokens_seeded`` at
+    position ``p * (steps + 1) + pass``) and its confidence ``c =
+    softmax(logits)[x0]``, of the model's own distribution. With ``n`` the
+    schedule's count for this pass (``denoise_counts``): ``dynamic``
+    (``low_confidence_dynamic``) fixes every masked position with ``c >
+    threshold`` if those are at least ``n``, else the ``n`` most
+    confident; static always the ``n`` most confident (ties to the
+    earlier position). The schedule's last pass fixes whatever is left.
+    A row with nothing masked changes nothing.
+
+    Returns (x0 [R, B], newly [R, B] bool, lp [R, B] = log c) and, with
+    ``top_n``, the top ids and log-probabilities [R, B, top_n] of the
+    pass's distribution: a token's log-probability is that of the pass
+    that fixed it."""
+    R, B, V = logits.shape
+    flat = logits.reshape(R * B, V)
+    if sampler is None:
+        x0 = jnp.argmax(flat, axis=-1).astype(jnp.int32)
+    else:
+        seeds, positions, temp, top_k, top_p = sampler
+        key_pos = jnp.maximum(positions, 0) * (steps + 1) + passes[:, None]
+        x0 = sample_tokens_seeded(
+            flat, jnp.repeat(seeds, B), key_pos.reshape(-1),
+            jnp.repeat(temp, B), jnp.repeat(top_k, B), jnp.repeat(top_p, B),
+        )
+    # log softmax at the few entries read, not over the vocabulary.
+    lse = jax.nn.logsumexp(flat, axis=-1)
+    lp = (jnp.take_along_axis(flat, x0[:, None], axis=-1)[:, 0] - lse).reshape(R, B)
+    x0 = x0.reshape(R, B)
+    masked = fixed_at == UNFIXED
+    conf = jnp.where(masked, jnp.exp(lp), -1.0)
+    counts = jnp.asarray(denoise_counts(B, steps), jnp.int32)
+    n = counts[jnp.clip(passes, 0, steps - 1)][:, None]  # [R, 1]
+    # Rank among the row's positions, most confident first.
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (jnp.arange(B)[None, None, :] < jnp.arange(B)[None, :, None])
+    )
+    newly = masked & (jnp.sum(ahead, axis=-1) < n)
+    if dynamic:
+        high = masked & (conf > threshold)
+        newly = jnp.where(
+            jnp.sum(high, axis=-1, keepdims=True) >= n, high, newly
+        )
+    newly = jnp.where((passes >= steps - 1)[:, None], masked, newly)
+    if top_n is None:
+        return x0, newly, lp
+    top, top_ids = jax.lax.top_k(flat, top_n)
+    return (
+        x0, newly, lp, top_ids.astype(jnp.int32).reshape(R, B, top_n),
+        (top - lse[:, None]).reshape(R, B, top_n),
+    )

@@ -154,13 +154,19 @@ class OpenAIPreprocessor(Operator):
 
         want_logprobs = backend_input.sampling_options.logprobs is not None
 
-        def _token_entry(tid: int, lp: float, tops: dict | None) -> dict:
+        def _token_entry(
+            tid: int, lp: float, tops: dict | None, at_pass: int | None = None
+        ) -> dict:
             text = self.tokenizer.decode([tid])
             entry: dict = {
                 "token": text,
                 "logprob": lp,
                 "bytes": list(text.encode("utf-8")),
             }
+            if at_pass is not None:
+                # Extension, block-diffusion models only: the denoising
+                # pass that fixed the token (its logprob is that pass's).
+                entry["pass"] = at_pass
             if is_chat:
                 entry["top_logprobs"] = [
                     {
@@ -173,21 +179,24 @@ class OpenAIPreprocessor(Operator):
             return entry
 
         def _shape(raw: list) -> dict | None:
-            """(tid, lp, tops) tuples → the OpenAI logprobs object."""
+            """(tid, lp, tops, pass) tuples → the OpenAI logprobs object."""
             if not raw:
                 return None
-            entries = [_token_entry(tid, lp, tp) for tid, lp, tp in raw]
+            entries = [_token_entry(*item) for item in raw]
             if is_chat:
                 return {"content": entries}
             # Legacy completions shape.
-            has_tops = any(tp for _, _, tp in raw)
-            return {
+            has_tops = any(item[2] for item in raw)
+            shaped = {
                 "tokens": [e["token"] for e in entries],
                 "token_logprobs": [e["logprob"] for e in entries],
-                "top_logprobs": [self._top_map(tp) for _, _, tp in raw]
+                "top_logprobs": [self._top_map(item[2]) for item in raw]
                 if has_tops
                 else None,
             }
+            if "pass" in entries[0]:
+                shaped["passes"] = [e["pass"] for e in entries]
+            return shaped
 
         async def _chunks() -> AsyncIterator[Any]:
             completion_tokens = 0
@@ -205,7 +214,10 @@ class OpenAIPreprocessor(Operator):
                 completion_tokens += len(out.token_ids)
                 if want_logprobs and out.logprobs:
                     tops = out.top_logprobs or [None] * len(out.logprobs)
-                    pending += list(zip(out.token_ids, out.logprobs, tops))
+                    passes = out.fixed_at_pass or [None] * len(out.logprobs)
+                    pending += list(
+                        zip(out.token_ids, out.logprobs, tops, passes)
+                    )
                 if out.text:
                     chunk = gen.text_chunk(out.text, _shape(pending))
                     # Sequence-index the chunk (cumulative token count)

@@ -96,6 +96,8 @@ async def aggregate_completion_stream(
                     choice.logprobs.get("token_logprobs") or []
                 )
                 agg["top_logprobs"] += choice.logprobs.get("top_logprobs") or []
+                if choice.logprobs.get("passes"):  # a block-diffusion model's
+                    agg.setdefault("passes", []).extend(choice.logprobs["passes"])
             if choice.finish_reason is not None:
                 finish[choice.index] = choice.finish_reason
     if meta is None:

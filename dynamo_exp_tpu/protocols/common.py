@@ -145,6 +145,10 @@ class LLMEngineOutput(BaseModel):
     # as {token_id: logprob} (N = the request's top_logprobs).
     logprobs: list[float] | None = None
     top_logprobs: list[dict[int, float]] | None = None
+    # A model that generates by diffusion over blocks, with logprobs asked:
+    # the denoising pass (0-based) that fixed each token, whose
+    # distribution its log-probability is of. Aligned with token_ids.
+    fixed_at_pass: list[int] | None = None
     finish_reason: FinishReason | None = None
     # Usage accounting, set on the final frame.
     prompt_tokens: int | None = None

@@ -103,7 +103,7 @@ class ReplayJournal:
                 # pre-detokenized ``text`` (Backend-level frames) can't
                 # be split by token and is dropped with the duplicates —
                 # journaling is meant to sit *below* the detokenizer.
-                for key in ("logprobs", "top_logprobs"):
+                for key in ("logprobs", "top_logprobs", "fixed_at_pass"):
                     if isinstance(frame.get(key), list):
                         frame[key] = frame[key][overlap:]
                 frame.pop("text", None)

@@ -79,10 +79,13 @@ from .slo import percentile
 DISPATCH_KINDS = ("ragged", "kv_move", "offload")
 
 # Which compiled program a ragged dispatch ran: a pure-decode ``window``
-# (``decode_window`` steps on the device) or a ``mixed`` batch (prefill
-# chunks, single decode steps, spec spans). The ``program`` label of the
-# dispatch histograms; the other kinds carry ``-``.
-PROGRAMS = ("window", "mixed")
+# (``decode_window`` steps on the device), a ``mixed`` batch (prefill
+# chunks, single decode steps, spec spans) or, for a model that generates
+# by diffusion over blocks, a ``block`` (every row's block through its
+# denoising passes and the pass that writes it: ``engine/block.py``).
+# The ``program`` label of the dispatch histograms; the other kinds
+# carry ``-``.
+PROGRAMS = ("window", "mixed", "block")
 NO_PROGRAM = "-"
 
 # The engine loop's phases. Closed set: the ``phase`` label space and

@@ -199,7 +199,7 @@ class Telemetry:
             "true (uncompacted-slot-free) rows, or the decode and spec "
             "rows a mixed batch carries beside its prefill chunks "
             "(0 when it has none)",
-            ["program"],  # window | mixed
+            ["program"],  # window | mixed | block
             buckets=(1, 2, 4, 8, 16, 32, 64, 128),
             registry=self.registry,
         )
@@ -225,7 +225,7 @@ class Telemetry:
             "past its stop add: every one a row of every projection "
             "and feed-forward). Every model; dynamo_moe_rows_total is "
             "its sum over programs for a routed one",
-            ["program", "kind"],  # window | mixed; live | pad
+            ["program", "kind"],  # window | mixed | block; live | pad
             registry=self.registry,
         )
         self.launch_transfers = Counter(
@@ -266,6 +266,31 @@ class Telemetry:
             "a backend the kernel cannot take: a copy of the layer goes "
             "to ragged_dot)",
             ["experts"],  # inplace | sliced
+            registry=self.registry,
+        )
+        # Generation by diffusion over blocks (engine/block.py; all zero
+        # for a model whose step is a token). Counted at the consume of
+        # each dispatch from what the device reports.
+        self.diffusion_blocks = Counter(
+            "dynamo_diffusion_blocks_total",
+            "Blocks a block-diffusion model finished: every position "
+            "fixed and the block's K/V written",
+            registry=self.registry,
+        )
+        self.diffusion_passes = Counter(
+            "dynamo_diffusion_passes_total",
+            "Passes over a row's block by kind: denoise (the confidence "
+            "rule fixes masked positions) or write (nothing masked: the "
+            "pass leaves the block's final K/V). denoise / blocks is the "
+            "passes a block took; write / all the share that fixes nothing",
+            ["kind"],  # denoise | write
+            registry=self.registry,
+        )
+        self.diffusion_tokens_fixed = Counter(
+            "dynamo_diffusion_tokens_fixed_total",
+            "Positions denoising passes fixed (over denoise passes: how "
+            "many a pass fixes; the threshold's doing where above the "
+            "schedule's count)",
             registry=self.registry,
         )
         # Sequence state beside the pages (a model with layers that are
