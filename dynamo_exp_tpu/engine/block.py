@@ -494,6 +494,7 @@ class BlockDecode:
         defers the finish until that one is consumed, as
         ``_consume_windowed`` does."""
         program = "block" if pending.windowed else "mixed"
+        self._flush_outbox()
         with self._phase("sync"):
             ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
         toks, fixed_at, n_denoise, wrote = ys[:4]

@@ -49,7 +49,7 @@ import queue
 import random
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, defaultdict
 from dataclasses import dataclass
 from functools import partial
 from typing import AsyncIterator, Callable
@@ -251,6 +251,13 @@ def _no_phase(name: str):
 def _no_part(phase: str, name: str):
     """``DispatchProfiler.part`` of such an engine."""
     return _NO_PHASE
+
+
+def _deliver(batch: list) -> None:
+    """One posting's emitted tuples into their requests' queues, in
+    emission order, on the asyncio thread (``TPUEngine._flush_outbox``)."""
+    for put, item in batch:
+        put(item)
 
 
 class TPUEngine(BlockDecode, AsyncEngine):
@@ -493,6 +500,15 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self._wake = threading.Event()
         self._running = False
         self._thread: threading.Thread | None = None
+        # What the loop thread has emitted and not yet handed to the
+        # asyncio threads: asyncio loop -> [(put_nowait, tuple), ...] in
+        # emission order, posted whole where the loop thread is about
+        # to wait (_flush_outbox; docs/engine_perf.md "How tokens reach
+        # the HTTP thread"). Owned by the loop thread, whose ident
+        # ``_outbox_owner`` holds while it runs: an emit from any other
+        # thread is posted at once.
+        self._outbox: defaultdict[asyncio.AbstractEventLoop, list] = defaultdict(list)
+        self._outbox_owner: int | None = None
         # Sticky: the loop thread died on an exception at least once.
         # In-flight requests were failed and a later generate() may
         # restart the loop, but the process is no longer trustworthy —
@@ -1435,15 +1451,16 @@ class TPUEngine(BlockDecode, AsyncEngine):
         loop = asyncio.get_running_loop()
         out_q: asyncio.Queue = asyncio.Queue()
 
+        put = out_q.put_nowait
+
         def emit(
             tokens: list[int],
             reason: FinishReason | None,
             logprobs=None,  # (lps: list[float], tops: list[dict]) | None
         ) -> None:
-            loop.call_soon_threadsafe(
-                out_q.put_nowait,
-                (tokens, reason, logprobs, time.monotonic()),
-            )
+            # Stamped here and not at the posting: the hand-off's
+            # histogram then holds the wait in the outbox too.
+            self._post(loop, put, (tokens, reason, logprobs, time.monotonic()))
 
         seq = Sequence(
             request_id=ctx.id,
@@ -1870,6 +1887,44 @@ class TPUEngine(BlockDecode, AsyncEngine):
             except RuntimeError:
                 pass
 
+    # ------------------------------------------------------------ the outbox
+    def _post(self, loop, put, item: tuple) -> None:
+        """One emitted tuple on its way to ``put`` (a request's
+        ``out_q.put_nowait``) on ``loop``'s thread. On the engine loop
+        thread it joins the outbox and travels with the next posting;
+        from any other thread (a teardown after the loop has gone) it
+        is posted by itself, at once."""
+        if threading.get_ident() == self._outbox_owner:
+            self._outbox[loop].append((put, item))
+            return
+        tel = get_telemetry()
+        tel.emit_postings.inc()
+        tel.emit_items.inc()
+        loop.call_soon_threadsafe(put, item)
+
+    def _flush_outbox(self) -> None:
+        """Post what the loop thread has emitted: one
+        ``call_soon_threadsafe`` an asyncio loop, whose callback does
+        every ``put_nowait`` of the batch on that loop's thread. Called
+        where the loop thread is about to stop needing the GIL — before
+        the wait of each ``sync``, before each ``park``, when the loop
+        ends — so the threads it wakes write their chunks while this one
+        sleeps and the device runs, and the outbox is empty whenever
+        this thread blocks or exits. Booked to ``consume.emit``: the
+        cost of emission wherever it is paid. Call outside any phase."""
+        if not self._outbox:
+            return
+        with self._phase("consume"), self._part("consume", "emit"):
+            tel = get_telemetry()
+            for loop, batch in self._outbox.items():
+                tel.emit_postings.inc()
+                tel.emit_items.inc(len(batch))
+                try:
+                    loop.call_soon_threadsafe(_deliver, batch)
+                except RuntimeError:
+                    pass  # that asyncio loop is closed: nobody reads
+            self._outbox.clear()
+
     # -------------------------------------------------------------- the loop
     def _loop(self) -> None:
         """One iteration = admit everything admissible, then dispatch
@@ -1895,6 +1950,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # consume), and none nests. What is left outside is the loop's
         # own overhead: a few branches an iteration.
         phase, part = self._phase, self._part
+        self._outbox_owner = threading.get_ident()
         try:
             while self._running:
                 with phase("kv_admin"):
@@ -1952,6 +2008,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                         # Genuinely idle: wait time must never read as
                         # host gap on the next dispatch.
                         self.profiler.mark_idle()
+                    self._flush_outbox()
                     with phase("park"):
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
@@ -2073,6 +2130,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                     # watchdog must see as frozen.
                     if self.profiler is not None:
                         self.profiler.mark_idle()
+                    self._flush_outbox()
                     with phase("park"):
                         self._wake.wait(timeout=0.001)
                         self._wake.clear()
@@ -2084,6 +2142,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self._inflight = None
             self._fail_all()
             raise
+        finally:
+            # Every way out: the normal end, and a crash's errors.
+            self._flush_outbox()
+            self._outbox_owner = None
 
     def _on_admitted(self, seq: Sequence) -> None:
         """Close the request's queue-wait stage (submission -> slot +
@@ -4200,6 +4262,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         (page release) until that successor is force-consumed — the
         device already parked the row at position -1, so the successor
         writes nothing for it."""
+        self._flush_outbox()
         with self._phase("sync"):
             if pending.want_lp:
                 sampled, lps, top_ids, top_lps = (
@@ -4297,6 +4360,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         Mixed batches are never chained over, so finishes (and their
         page releases) are safe immediately."""
         spec_on = pending.with_spec
+        self._flush_outbox()
         with self._phase("sync"):
             ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
         tok0 = ys[0]

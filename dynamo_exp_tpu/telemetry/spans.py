@@ -238,6 +238,23 @@ class Telemetry:
             ["program"],  # window | mixed
             registry=self.registry,
         )
+        self.emit_postings = Counter(
+            "dynamo_emit_postings_total",
+            "call_soon_threadsafe calls that handed emitted tuples to an "
+            "asyncio loop: one a dispatch and loop, made where the engine "
+            "loop is about to wait (TPUEngine._flush_outbox: before a "
+            "sync, a park, at the loop's end), and one for each emit "
+            "made off the loop thread",
+            registry=self.registry,
+        )
+        self.emit_items = Counter(
+            "dynamo_emit_items_total",
+            "Emitted tuples (tokens, finish reason, log-probabilities, "
+            "stamp) those calls carried; over dynamo_emit_postings_total "
+            "it is tuples a posting: the rows of a dispatch, 1 where "
+            "every emit is posted by itself",
+            registry=self.registry,
+        )
         self.prefill_chunk_tokens = Counter(
             "dynamo_prefill_chunk_tokens_total",
             "Prompt tokens mixed dispatches carried by kind: share (up "

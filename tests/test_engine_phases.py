@@ -278,9 +278,12 @@ async def test_phases_cover_the_loop_and_do_not_nest(monkeypatch):
 async def test_an_engines_parts_sum_to_their_phases_on_metrics():
     """Two requests through mixed dispatches, windows, first tokens and
     finishes: on /metrics every part of a phase is there, none is
-    negative, and the parts of each phase add up to the phase."""
+    negative, and the parts of each phase add up to the phase, the
+    outbox's postings (stretches of ``consume.emit`` of their own,
+    before a sync or a park) among them."""
     name = "dynamo_engine_phase_part_seconds_total"
     before = _samples(name)
+    postings = _total("dynamo_emit_postings_total")
     phase_before = _samples("dynamo_engine_phase_seconds_total")
     engine = TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
     engine.start()
@@ -289,6 +292,7 @@ async def test_an_engines_parts_sum_to_their_phases_on_metrics():
         await _generate(engine, range(60, 70), max_tokens=6)
     finally:
         engine.stop()
+    assert _total("dynamo_emit_postings_total") - postings >= 4
     after = _samples(name)
     phase_after = _samples("dynamo_engine_phase_seconds_total")
     got = {
