@@ -93,7 +93,8 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
     - ring prefill (``sp`` > 1): it writes pages from outside the paged
       layer loop that carries the state;
     - tensor parallelism (``tp`` > 1) where the state is a selective
-      scan's: the scan and its pool are one chip's (no channel split);
+      scan's or the delta rule's: the recurrence, its kernel and its pool
+      are one chip's (no channel or head split);
     - ``tier`` = "disaggregated transfer" / "migration"
       (``disagg/transfer.py``, ``runtime/reclaim.py``): named by the
       component that is being built round the engine.
@@ -114,13 +115,21 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
                 "tensor parallelism (tp > 1) of a selective scan",
                 cfg.tp > 1 and cfg.model.num_mamba_layers > 0,
             ),
+            (
+                "tensor parallelism (tp > 1) of linear attention",
+                cfg.tp > 1 and cfg.model.num_linear_layers > 0,
+            ),
         ) if on
     ]
     if armed:
+        kind = (
+            "selective-scan" if cfg.model.num_mamba_layers
+            else "linear-attention" if cfg.model.num_linear_layers
+            else "short-convolution"
+        )
         raise ValueError(
             f"model_type {cfg.model.model_type!r} keeps per-sequence state "
-            f"beside its KV pages (what its "
-            f"{'selective-scan' if cfg.model.num_mamba_layers else 'short-convolution'} "
+            f"beside its KV pages (what its {kind} "
             f"layers carry from token to token), which "
             f"{', '.join(armed)} would not carry: a sequence would resume with "
             "a state that is not its own. Serve it without (README.md, models/)"

@@ -300,14 +300,17 @@ class TPUEngine(BlockDecode, AsyncEngine):
             shardings=tuple(sharding(s) for s in kv_cache_shardings()),
         )
         # What a sequence carries beside its pages (a model with layers
-        # whose operator is not attention: a short convolution's last
-        # products, a selective scan's state and its convolution's inputs;
-        # None for every other model): one entry a decode slot, then the
-        # snapshots a prefix hit restores from, four a slot. An entry is
-        # kilobytes for a convolution and megabytes for a scan (9.32 MB at
-        # Jamba2-3B's 26 layers: 2.98 GB of pool), so its bytes are
-        # published with the KV pool's (``dynamo_seq_state_pool_bytes``).
-        # Replicated over the mesh.
+        # whose operator is not softmax attention: a short convolution's
+        # last products, a selective scan's state and its convolution's
+        # inputs, linear attention's matrix state and its convolutions'
+        # inputs; None for every other model): one entry a decode slot,
+        # then the snapshots a prefix hit restores from, four a slot. An
+        # entry is kilobytes for a convolution and megabytes for a
+        # recurrence (9.32 MB at Jamba2-3B's 26 Mamba layers: 2.98 GB of
+        # pool at 64 slots; 13.7 MB at the 6 linear layers of
+        # Olmo-Hybrid-7B's cut: 2.19 GB at 32), so its bytes are published
+        # with the KV pool's (``dynamo_seq_state_pool_bytes``). Replicated
+        # over the mesh.
         state_slots = cfg.max_decode_slots if mcfg.has_seq_state else 0
         state_snapshots = 4 * state_slots
         # Entries of the pool; as a row's entry it means "none": a write
@@ -1218,7 +1221,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         (``dynamo_ragged_dispatch_total``), its live and padding slots
         (``dynamo_ragged_slots_total``; a window's rows times its
         steps), for a selective-scan model the live ones as tokens
-        through the scan (``dynamo_ssm_scan_tokens_total``) and, for a
+        through the scan (``dynamo_ssm_scan_tokens_total``), for a
+        linear-attention model the same through the delta rule
+        (``dynamo_linear_attn_tokens_total``) and, for a
         routed-expert model, the same as rows
         (``dynamo_moe_rows_total``) and the way its expert matmuls
         read their weights (``dynamo_moe_dispatch_total``). Host
@@ -1261,6 +1266,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
         tel.ragged_slots.labels(program, "pad").inc(pad)
         if self.cfg.model.num_mamba_layers:
             tel.ssm_scan_tokens.labels(program).inc(live)
+        if self.cfg.model.num_linear_layers:
+            tel.linear_attn_tokens.labels(program).inc(live)
         if self.cfg.model.is_moe:
             tel.moe_rows.labels("live").inc(live)
             tel.moe_rows.labels("pad").inc(pad)

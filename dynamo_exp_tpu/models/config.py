@@ -120,8 +120,29 @@ class ModelConfig:
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
     # Whether attention turns q and k by their positions. jamba's does
-    # not: its Mamba layers carry the order of the tokens.
+    # not: its Mamba layers carry the order of the tokens. Nor does
+    # olmo_hybrid's (``rope_theta: null``).
     use_rope: bool = True
+    # olmo_hybrid: layers whose operator is linear attention by the gated
+    # delta rule (Gated DeltaNet), ``layer_types`` "linear_attention":
+    # ``linear_num_heads`` heads of a ``linear_key_head_dim`` by
+    # ``linear_value_head_dim`` float32 state, a depthwise causal
+    # convolution of ``linear_conv_kernel_dim`` taps ahead of each of q, k
+    # and v, a step in (0, 2) where ``linear_allow_neg_eigval`` and in
+    # (0, 1) otherwise. A sequence carries the convolutions' last
+    # ``linear_conv_kernel_dim - 1`` inputs and the state a layer
+    # (``has_seq_state``).
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+    # olmo_hybrid's block (Olmo 2's): each half reads the stream un-normed
+    # and its *output* is normed before the add: ``pre_norms`` False with
+    # ``post_norms``. And its q/k norms span the whole projection
+    # (``qk_norm_whole``), not a head.
+    pre_norms: bool = True
+    qk_norm_whole: bool = False
     # sdar_moe: generation by diffusion over blocks. ``block_length`` B > 0
     # (a power of two) makes a row's step a *pass* over a block of B
     # positions instead of one token: attention is causal across blocks
@@ -146,10 +167,12 @@ class ModelConfig:
     @property
     def op_kinds(self) -> tuple[str, ...]:
         """Each layer's operator: "conv" (gated short convolution),
-        "mamba" (selective scan) or "attn" (every attention form,
-        windowed or full)."""
+        "mamba" (selective scan), "linear" (linear attention by the gated
+        delta rule) or "attn" (every softmax attention form, windowed or
+        full)."""
         types = self.layer_types or ("full_attention",) * self.num_layers
-        return tuple(t if t in ("conv", "mamba") else "attn" for t in types)
+        names = {"conv": "conv", "mamba": "mamba", "linear_attention": "linear"}
+        return tuple(names.get(t, "attn") for t in types)
 
     @property
     def ff_kinds(self) -> tuple[str, ...]:
@@ -168,7 +191,7 @@ class ModelConfig:
         (``models/llama.py::init_params``) and the paged layer loop is one
         ``lax.scan`` a run, so a model of one kind is one run."""
         runs: list[tuple[str, str, int, int, int, int]] = []
-        seen = {"conv": 0, "mamba": 0, "attn": 0, "dense": 0, "routed": 0}
+        seen = dict.fromkeys(("conv", "mamba", "linear", "attn", "dense", "routed"), 0)
         for i, kind in enumerate(zip(self.op_kinds, self.ff_kinds)):
             if runs and runs[-1][:2] == kind:
                 runs[-1] = (*runs[-1][:3], runs[-1][3] + 1, *runs[-1][4:])
@@ -192,8 +215,20 @@ class ModelConfig:
         return self.op_kinds.count("mamba")
 
     @property
+    def num_linear_layers(self) -> int:
+        return self.op_kinds.count("linear")
+
+    @property
     def mamba_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
+
+    @property
+    def linear_conv_width(self) -> int:
+        """Channels of a linear-attention layer's convolutions: q, k and v
+        side by side."""
+        return self.linear_num_heads * (
+            2 * self.linear_key_head_dim + self.linear_value_head_dim
+        )
 
     @property
     def num_routed_layers(self) -> int:
@@ -202,8 +237,10 @@ class ModelConfig:
     @property
     def has_seq_state(self) -> bool:
         """Whether a sequence holds state that is not a page: what a
-        layer whose operator is not attention carries from token to token
-        (``models/llama.py::init_seq_state`` has the parts by kind). What
+        layer whose operator is not softmax attention carries from token to
+        token: a convolution's last inputs, a scan's state, linear
+        attention's matrix state (``models/llama.py::init_seq_state`` has
+        the parts by kind). What
         the tiers that move pages alone refuse
         (``engine/config.py::refuse_seq_state_tiers``)."""
         return self.num_attn_layers < self.num_layers
@@ -239,6 +276,8 @@ class ModelConfig:
             return cls._from_lfm2_moe(cfg)
         if model_type == "jamba":
             return cls._from_jamba(cfg)
+        if model_type == "olmo_hybrid":
+            return cls._from_olmo_hybrid(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -414,6 +453,69 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_olmo_hybrid(cls, cfg: dict) -> "ModelConfig":
+        """Ai2's ``olmo_hybrid`` (Olmo-Hybrid-7B): layer ``i`` is what
+        ``layer_types[i]`` says, "linear_attention" (the gated delta rule,
+        its widths under the ``linear_*`` keys as Qwen3-Next spells them)
+        or "full_attention" (multi-head, no positional term: the config
+        gives ``rope_theta: null``, and a base that is given is refused);
+        every layer in Olmo 2's block (each half's output normed, its input
+        not; q/k norms over the whole projection); every feed-forward a
+        dense SwiGLU."""
+        rope = cfg.get("rope_parameters") or {}
+        if cfg.get("rope_theta", rope.get("rope_theta")) is not None:
+            raise ValueError(
+                "olmo_hybrid with a rope_theta is not supported: the family's "
+                "attention has no positional term (rope_theta: null)"
+            )
+        if str(cfg.get("hidden_act", "silu")) != "silu":
+            raise ValueError("olmo_hybrid with hidden_act other than silu is not supported")
+        if cfg.get("attention_bias", False):
+            raise ValueError("olmo_hybrid with attention_bias=true is not supported")
+        n_layers = cfg["num_hidden_layers"]
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != n_layers or not set(layer_types) <= {
+            "linear_attention", "full_attention"
+        }:
+            raise ValueError(
+                f"olmo_hybrid layer_types must name {n_layers} layers as "
+                f"'linear_attention' or 'full_attention', got {layer_types!r}"
+            )
+        heads = cfg["linear_num_value_heads"]
+        if cfg["linear_num_key_heads"] != heads:
+            raise ValueError(
+                "olmo_hybrid with linear_num_key_heads != linear_num_value_heads "
+                "(value heads sharing a key head) is not supported"
+            )
+        if cfg.get("linear_conv_kernel_dim", 4) < 2:
+            raise ValueError("olmo_hybrid needs linear_conv_kernel_dim >= 2")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
+            head_dim=cfg.get("head_dim"),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=cfg.get("max_position_embeddings", 65536),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            qk_norm=True,
+            qk_norm_whole=True,
+            pre_norms=False,
+            post_norms=True,
+            layer_types=layer_types,
+            linear_num_heads=heads,
+            linear_key_head_dim=cfg["linear_key_head_dim"],
+            linear_value_head_dim=cfg["linear_value_head_dim"],
+            linear_conv_kernel_dim=cfg.get("linear_conv_kernel_dim", 4),
+            linear_allow_neg_eigval=bool(cfg.get("linear_allow_neg_eigval", False)),
+            use_rope=False,
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type="olmo_hybrid",
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -434,6 +536,7 @@ class ModelConfig:
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
+    "olmo_hybrid",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -680,6 +783,35 @@ TINY_JAMBA = ModelConfig(  # jamba family shape: period 4 with one attention
     model_type="jamba",
 )
 
+TINY_OLMO_HYBRID = ModelConfig(  # olmo_hybrid family shape: one period of
+    # (linear, linear, linear, full) and a linear layer more, so runs linear /
+    # attn / linear; as many KV heads as heads, no rope, Olmo 2's block; two
+    # heads' values fill a lane group, which the delta-rule kernel asks
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=5,
+    num_heads=4,
+    num_kv_heads=4,
+    max_position_embeddings=512,
+    rms_norm_eps=1e-6,
+    qk_norm=True,
+    qk_norm_whole=True,
+    pre_norms=False,
+    post_norms=True,
+    layer_types=(
+        "linear_attention", "linear_attention", "linear_attention",
+        "full_attention", "linear_attention",
+    ),
+    linear_num_heads=2,
+    linear_key_head_dim=8,
+    linear_value_head_dim=64,
+    linear_conv_kernel_dim=4,
+    linear_allow_neg_eigval=True,
+    use_rope=False,
+    model_type="olmo_hybrid",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -701,6 +833,7 @@ PRESETS = {
     "tiny-moe": TINY_MOE,
     "tiny-lfm2": TINY_LFM2,
     "tiny-jamba": TINY_JAMBA,
+    "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

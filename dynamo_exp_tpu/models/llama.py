@@ -85,7 +85,10 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     attention's over the attention layers, the short convolution's
     (``conv_in``/``conv_w``/``conv_out``) over the convolution layers,
     the selective scan's (``mamba_*``, :func:`_init_mamba`) over the Mamba
-    layers, the router and the expert matrices over the routed layers. A model
+    layers, the delta rule's (``gdn_*``, :func:`_init_linear`) over the
+    linear-attention layers, the router and the expert matrices over the
+    routed layers. A model whose block norms its halves' outputs alone
+    (``pre_norms`` False) has no ``attn_norm``/``mlp_norm``. A model
     whose feed-forwards are of both kinds keeps its dense layers' under
     ``dense_gate``/``dense_up``/``dense_down``; ``w_gate``/``w_up``/
     ``w_down`` are then the routed layers' (``EXPERT_STACKS``). In a
@@ -119,6 +122,10 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         "wo": init(ks[4], (La, H * hd, D), H * hd),
         "mlp_norm": jnp.ones((L, D), dt),
     }
+    if not cfg.pre_norms:  # olmo_hybrid: the halves' outputs are normed alone
+        del layers["attn_norm"], layers["mlp_norm"]
+    if cfg.num_linear_layers:  # olmo_hybrid: the gated delta rule
+        layers.update(_init_linear(jax.random.fold_in(rng, 3), cfg, init))
     if Lc:  # lfm2: gated short convolution, no bias
         Kc = cfg.conv_L_cache
         layers["conv_in"] = init(kc[0], (Lc, D, 3 * D), D)
@@ -130,9 +137,10 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         layers["bq"] = init(ks[9], (L, H * hd), H * hd)
         layers["bk"] = init(ks[10], (L, Hkv * hd), Hkv * hd)
         layers["bv"] = init(ks[11], (L, Hkv * hd), Hkv * hd)
-    if cfg.qk_norm:  # qwen3: per-head q/k RMSNorm
-        layers["q_norm"] = jnp.ones((La, hd), dt)
-        layers["k_norm"] = jnp.ones((La, hd), dt)
+    if cfg.qk_norm:  # qwen3: per-head q/k RMSNorm; olmo: over the projection
+        whole = cfg.qk_norm_whole
+        layers["q_norm"] = jnp.ones((La, H * hd if whole else hd), dt)
+        layers["k_norm"] = jnp.ones((La, Hkv * hd if whole else hd), dt)
     if cfg.post_norms:  # gemma2: norms on block outputs too
         init_norm = jnp.zeros if cfg.rms_norm_offset else jnp.ones
         layers["post_attn_norm"] = init_norm((L, D), dt)
@@ -221,6 +229,49 @@ def _init_mamba(rng, cfg: ModelConfig, init) -> Params:
     }
 
 
+# A randomly initialised delta-rule layer's decay: ``gdn_A_log = log a``
+# with ``a`` uniform in this range a head, and ``gdn_dt_b`` from
+# ``MAMBA_DT_RANGE`` as the Mamba step's, the family's own initialisation
+# (flash-linear-attention's GatedDeltaNet). With keys of unit length and a
+# step below 2 every token contracts the state: it neither dies nor
+# explodes over 16k tokens.
+LINEAR_A_RANGE = (0.0, 16.0)
+# The delta-rule mixer's parameters, each stacked over the linear layers.
+LINEAR_PARAMS = (
+    "gdn_qkv", "gdn_conv_w", "gdn_ab", "gdn_A_log", "gdn_dt_b", "gdn_gate",
+    "gdn_norm", "gdn_out",
+)
+
+
+def _init_linear(rng, cfg: ModelConfig, init) -> Params:
+    """Random gated-delta-rule mixers, ``[L_linear, ...]`` each: the
+    projections by ``init`` (normal, fan-in scaled), q, k and v's side by
+    side in ``gdn_qkv`` (as their convolutions' taps in ``gdn_conv_w``) and
+    the decay's and the step's in ``gdn_ab``; ``gdn_A_log`` and
+    ``gdn_dt_b`` (float32) from ``LINEAR_A_RANGE`` and ``MAMBA_DT_RANGE``."""
+    dt = _dtype(cfg)
+    Ll, D, H = cfg.num_linear_layers, cfg.hidden_size, cfg.linear_num_heads
+    dv, K, C = cfg.linear_value_head_dim, cfg.linear_conv_kernel_dim, cfg.linear_conv_width
+    kl = jax.random.split(rng, 7)
+    lo, hi = MAMBA_DT_RANGE
+    step = jnp.exp(
+        jax.random.uniform(kl[5], (Ll, H), jnp.float32)
+        * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo)
+    )
+    a_lo, a_hi = LINEAR_A_RANGE
+    a = jax.random.uniform(kl[6], (Ll, H), jnp.float32, a_lo, a_hi)
+    return {
+        "gdn_qkv": init(kl[0], (Ll, D, C), D),
+        "gdn_conv_w": init(kl[1], (Ll, K, C), K),
+        "gdn_ab": init(kl[2], (Ll, D, 2 * H), D),
+        "gdn_A_log": jnp.log(jnp.maximum(a, 1e-4)),
+        "gdn_dt_b": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "gdn_gate": init(kl[3], (Ll, D, H * dv), D),
+        "gdn_norm": jnp.ones((Ll, dv), dt),
+        "gdn_out": init(kl[4], (Ll, H * dv, D), H * dv),
+    }
+
+
 def param_shardings(
     cfg: ModelConfig, tp_axis: str = "tp", ep_axis: str | None = None
 ) -> Params:
@@ -237,6 +288,12 @@ def param_shardings(
         "wo": P(None, tp_axis, None),
         "mlp_norm": P(None, None),
     }
+    if not cfg.pre_norms:
+        del layers["attn_norm"], layers["mlp_norm"]
+    if cfg.num_linear_layers:
+        # Replicated: the delta rule under tp > 1 is refused
+        # (``engine/config.py::refuse_seq_state_tiers``).
+        layers.update((k, P()) for k in LINEAR_PARAMS)
     if cfg.num_conv_layers:
         # Replicated: the three-way split of ``conv_in``'s columns and the
         # per-channel taps do not fall on a tp shard's boundaries. 17 M
@@ -337,6 +394,14 @@ class MambaState(NamedTuple):
     ssm: jnp.ndarray  # [Lm, entries, S, E] float32
 
 
+class LinearState(NamedTuple):
+    """The two parts a linear-attention sequence carries a layer, as pools
+    ``[L_linear, entries, ...]``."""
+
+    conv: jnp.ndarray  # [Ll, entries, K - 1, H * (2 dk + dv)], the model's dtype
+    state: jnp.ndarray  # [Ll, entries, dk, H * dv] float32
+
+
 def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
     """The pool of what a sequence carries from token to token beside its
     pages: a pytree by part, every leaf ``[layers of the kind, entries,
@@ -350,16 +415,38 @@ def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
       convolution's last ``mamba_d_conv - 1`` inputs and the scan's state
       ``[S, E]`` in float32 (``mamba_mixer``): 9.32 MB an entry at
       Jamba2-3B's 26 layers, not kilobytes, so the engine counts the pool
-      among what it holds (``dynamo_seq_state_pool_bytes``).
+      among what it holds (``dynamo_seq_state_pool_bytes``);
+    - a linear-attention model (olmo_hybrid): :class:`LinearState`, the
+      last ``linear_conv_kernel_dim - 1`` inputs of q's, k's and v's
+      convolutions and the delta rule's matrix state ``[dk, H * dv]`` in
+      float32 (``gated_delta_mixer``; the layout:
+      ``ops/gated_delta.py``): 2.28 MB a layer at Olmo-Hybrid-7B's 30 heads
+      of 96 by 192, 13.7 MB an entry over the 6 linear layers of its cut.
 
     Zeros are what a sequence starts from, though no forward relies on
     it: the first positions mask what lies before the sequence's start."""
     if not cfg.has_seq_state:
         return None
     dt = dtype or _dtype(cfg)
+    kinds = [k for k in ("conv", "mamba", "linear") if k in cfg.op_kinds]
+    if len(kinds) > 1:
+        raise ValueError(
+            f"a model of {' and '.join(kinds)} layers has no state pool: a "
+            "pool holds one kind's parts"
+        )
+    if cfg.num_linear_layers:
+        Ll, H = cfg.num_linear_layers, cfg.linear_num_heads
+        return LinearState(
+            jnp.zeros(
+                (Ll, entries, cfg.linear_conv_kernel_dim - 1, cfg.linear_conv_width),
+                dt, device=sharding,
+            ),
+            jnp.zeros(
+                (Ll, entries, cfg.linear_key_head_dim, H * cfg.linear_value_head_dim),
+                jnp.float32, device=sharding,
+            ),
+        )
     if cfg.num_mamba_layers:
-        if cfg.num_conv_layers:
-            raise ValueError("a model of both convolution and Mamba layers has no state pool")
         Lm, E = cfg.num_mamba_layers, cfg.mamba_inner
         return MambaState(
             jnp.zeros((Lm, entries, cfg.mamba_d_conv - 1, E), dt, device=sharding),
@@ -442,14 +529,19 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red):
     hd = cfg.head_dim_
     off = cfg.rms_norm_offset
     with jax.named_scope("qkv"):
-        h = rms_norm(x, lp["attn_norm"], eps, off)
+        # olmo_hybrid's block has no norm here: its output is normed below
+        h = rms_norm(x, lp["attn_norm"], eps, off) if "attn_norm" in lp else x
         q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
         if "bq" in lp:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        whole = cfg.qk_norm_whole and "q_norm" in lp
+        if whole:  # olmo: one RMSNorm over all heads' projection
+            q = rms_norm(q, lp["q_norm"], eps, off)
+            k = rms_norm(k, lp["k_norm"], eps, off)
         q = q.reshape(B, T, lp["wq"].shape[-1] // hd, hd)
         k = k.reshape(B, T, lp["wk"].shape[-1] // hd, hd)
         v = v.reshape(B, T, lp["wv"].shape[-1] // hd, hd)
-        if "q_norm" in lp:  # qwen3/gemma3: per-head RMSNorm before rope
+        if "q_norm" in lp and not whole:  # qwen3/gemma3: per-head RMSNorm before rope
             # (gemma3 stores these gemma-style: scale = 1 + w)
             q = rms_norm(q, lp["q_norm"], eps, off)
             k = rms_norm(k, lp["k_norm"], eps, off)
@@ -478,13 +570,16 @@ class SeqStateIO(NamedTuple):
     writes each row's own entry and, for a prefill row, a second entry a
     later prefix hit may restore from (``engine/kv_manager.py``).
 
-    A state that is a few products a layer (the convolutions') is kept a
-    token and any ``src`` is honoured. A scan's state is ``[S, E]``
-    float32 a token and layer (320 KiB at Jamba2-3B's widths) and is not:
-    the scan kernel stores at the ends of its tiles, so there ``src`` is
-    the last real token of its row in its tile of ``ragged_q_tile`` slots
-    (``ops/selective_scan.py``, WRITES), which is what the engine's two
-    writes a row are."""
+    A state that is a few products a layer (the convolutions', all three
+    kinds') is kept a token and any ``src`` is honoured. A selective
+    scan's state is ``[S, E]`` float32 a token and layer (320 KiB at
+    Jamba2-3B's widths) and is not: the scan kernel stores at the ends of
+    its tiles, so there ``src`` is the last real token of its row in its
+    tile of ``ragged_q_tile`` slots (``ops/selective_scan.py``, WRITES),
+    which is what the engine's two writes a row are. The delta rule's
+    matrix state (2.2 MB a token and layer at Olmo-Hybrid-7B's) is not
+    kept a token either, and its kernel honours any ``src`` that is a real
+    token, two a chunk (``ops/gated_delta.py``, WRITES)."""
 
     slots: jnp.ndarray  # [R] int32: the pool entry each row reads
     row_of: jnp.ndarray  # [N] int32: each stream token's row
@@ -494,7 +589,8 @@ class SeqStateIO(NamedTuple):
 
 
 class ScanForm(NamedTuple):
-    """How a forward runs the selective scan (static): ``kernel`` (the
+    """How a forward runs its recurrence, the selective scan or the delta
+    rule (static): ``kernel`` (the
     Pallas kernel over the stream, else the oracle), ``packed_from`` (with
     the kernel: the stream's slots from here on are one-token rows unless
     a span runs on into them, and take the one-step form), ``one_token``
@@ -562,53 +658,146 @@ def short_conv(u, lp, pool, layer, io: SeqStateIO):
     return y, pool
 
 
-def _selective_scan(x, dt, a, b, c, d, pool, layer, io: SeqStateIO, form: ScanForm):
-    """The recurrence over this forward's stream in the form ``form``
-    names (``ops/selective_scan.py`` has the three). Returns (y [N, E]
-    float32, the scan's pool)."""
-    from ..ops import selective_scan as ss
+def _stream_recurrence(pool, io: SeqStateIO, form: ScanForm, ref, step, kernel):
+    """A recurrence over this forward's stream in the form ``form`` names,
+    whichever recurrence it is: ``ref(pool)`` the oracle over the stream,
+    ``kernel(pool, positions, dst)`` the Pallas kernel over it,
+    ``step(pool, part, slots, positions, dst)`` one token a row for the
+    slots ``part`` of the stream. Each returns (y, pool); so does this."""
+    from ..ops.selective_scan import stream_predecessor
 
     if form.one_token:
-        return ss.selective_scan_step(
-            x, dt, a, b, c, d, pool, layer, io.slots[io.row_of], io.positions,
-            io.dst,
-        )
+        return step(pool, slice(None), io.slots[io.row_of], io.positions, io.dst)
     if not form.kernel:
-        return ss.selective_scan_ref(
-            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, io.positions,
-            io.src, io.dst,
-        )
+        return ref(pool)
     pos, src, dst = io.positions, io.src, io.dst
     at = form.packed_from
     if at is None:
-        return ss.selective_scan_kernel(
-            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, pos, src, dst,
-            interpret=form.interpret,
-        )
+        return kernel(pool, pos, dst)
     # The packed tail: a slot there is a row of one token (a decode row),
     # unless a span of the tiles ran on into it. The kernel walks every
     # span, the rows of one token take one step each.
     entries = pool.shape[1]
     n = pos.shape[0]
-    prev = ss.stream_predecessor(io.row_of, pos)
+    prev = stream_predecessor(io.row_of, pos)
     alone = ~prev & ~jnp.concatenate([prev[1:], jnp.zeros((1,), bool)])
     alone = alone & (jnp.arange(n) >= at) & (pos >= 0)
-    y, pool = ss.selective_scan_kernel(
-        x, dt, a, b, c, d, pool, layer, io.slots, io.row_of,
-        jnp.where(alone, -1, pos), src, jnp.where(alone[src], entries, dst),
-        interpret=form.interpret,
+    y, pool = kernel(
+        pool, jnp.where(alone, -1, pos), jnp.where(alone[src], entries, dst)
     )
     tail = slice(at, None)
     to = jnp.full((n,), entries, jnp.int32).at[src].min(
         jnp.minimum(dst, entries).astype(jnp.int32)
     )
-    y1, pool = ss.selective_scan_step(
-        x[tail], dt[tail], a, b[tail], c[tail], d, pool, layer,
-        io.slots[io.row_of[tail]], jnp.where(alone[tail], pos[tail], -1),
+    y1, pool = step(
+        pool, tail, io.slots[io.row_of[tail]],
+        jnp.where(alone[tail], pos[tail], -1),
         jnp.where(alone[tail], to[tail], entries),
     )
     y = jnp.concatenate([y[:at], jnp.where(alone[tail][:, None], y1, y[tail])])
     return y, pool
+
+
+def _selective_scan(x, dt, a, b, c, d, pool, layer, io: SeqStateIO, form: ScanForm):
+    """The selective scan over this forward's stream
+    (``ops/selective_scan.py`` has the three forms). Returns (y [N, E]
+    float32, the scan's pool)."""
+    from ..ops import selective_scan as ss
+
+    return _stream_recurrence(
+        pool, io, form,
+        lambda pool: ss.selective_scan_ref(
+            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, io.positions,
+            io.src, io.dst,
+        ),
+        lambda pool, part, slots, pos, dst: ss.selective_scan_step(
+            x[part], dt[part], a, b[part], c[part], d, pool, layer, slots, pos, dst,
+        ),
+        lambda pool, pos, dst: ss.selective_scan_kernel(
+            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, pos, io.src, dst,
+            interpret=form.interpret,
+        ),
+    )
+
+
+def _gated_delta(q, k, v, g, beta, pool, layer, io: SeqStateIO, form: ScanForm):
+    """The gated delta rule over this forward's stream
+    (``ops/gated_delta.py`` has the three forms). Returns (o [N, H * dv]
+    float32, the states' pool)."""
+    from ..ops import gated_delta as gd
+
+    return _stream_recurrence(
+        pool, io, form,
+        lambda pool: gd.gated_delta_ref(
+            q, k, v, g, beta, pool, layer, io.slots, io.row_of, io.positions,
+            io.src, io.dst,
+        ),
+        lambda pool, part, slots, pos, dst: gd.gated_delta_step(
+            q[part], k[part], v[part], g[part], beta[part], pool, layer, slots,
+            pos, dst,
+        ),
+        lambda pool, pos, dst: gd.gated_delta_kernel(
+            q, k, v, g, beta, pool, layer, io.slots, io.row_of, pos, io.src, dst,
+            interpret=form.interpret,
+        ),
+    )
+
+
+def gated_delta_mixer(u, lp, cfg, state: "LinearState", layer, io: SeqStateIO,
+                      form: ScanForm):
+    """Olmo-Hybrid's linear attention (Gated DeltaNet) over a flat token
+    stream ``u`` ([N, D], the un-normed residual stream), the carried state
+    read from and written to ``state.conv[layer]`` and
+    ``state.state[layer]``. Returns ([N, D], the state). ``H`` heads of
+    ``dk`` by ``dv``:
+
+    ``[z^q, z^k, z^v] = u @ gdn_qkv``; ``c_t = silu(sum_j conv_w[j] *
+    z_{t-(K-1)+j})`` (depthwise, causal, no bias), split back into heads;
+    ``q = c^q / |c^q| / sqrt(dk)``, ``k = c^k / |c^k|``, ``v = c^v``;
+    ``[a, b] = u @ gdn_ab``; ``g = -exp(A_log) softplus(a + dt_b)``;
+    ``beta = sigmoid(b)``, doubled where ``linear_allow_neg_eigval``; the
+    recurrence (``ops/gated_delta.py``); returns ``(RMS_head(o) *
+    silu(u @ gdn_gate)) @ gdn_out``, the norm a head at a time with one
+    learned scale of ``dv``.
+
+    The matrix products run in the model's dtype with float32 results
+    where the decay and the step read them; everything between the
+    convolution and the output's gate is float32, the state from end to
+    end."""
+    f32 = jnp.float32
+    H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    n = u.shape[0]
+    # float32 from here to the output's gate; an entry holds its three
+    # inputs in the model's dtype, as every kind's convolution does
+    z = jnp.dot(u, lp["gdn_qkv"], preferred_element_type=f32)  # [N, H (2 dk + dv)]
+    with jax.named_scope("gdn_conv"):
+        taps = lp["gdn_conv_w"].astype(f32)  # [K, C]; taps[K-1] weighs z_t
+        K = taps.shape[0]
+        held = state.conv[layer, io.slots[io.row_of]]  # [N, K-1, C], one gather
+        back = _stream_history(z, held, io, K)
+        acc = taps[K - 1] * z
+        for j in range(1, K):
+            acc = acc + taps[K - 1 - j] * back[j]
+        c = jax.nn.silu(acc)  # [N, C] float32
+        conv = state.conv.at[layer, io.dst].set(
+            _history_after(back, io.src).astype(state.conv.dtype), mode="drop"
+        )
+    ab = jnp.dot(u, lp["gdn_ab"], preferred_element_type=f32)  # [N, 2 H]
+    g = -jnp.exp(lp["gdn_A_log"]) * jax.nn.softplus(ab[:, :H] + lp["gdn_dt_b"])
+    beta = jax.nn.sigmoid(ab[:, H:]) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+    with jax.named_scope("gdn_scan"):
+        def unit(x):  # each head's vector to length 1
+            x = x.reshape(n, H, dk)
+            return (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)).reshape(n, H * dk)
+
+        o, pool = _gated_delta(
+            unit(c[:, : H * dk]) * dk**-0.5, unit(c[:, H * dk : 2 * H * dk]),
+            c[:, 2 * H * dk :], g, beta, state.state, layer, io, form,
+        )
+    o = rms_norm(o.reshape(n, H, dv), lp["gdn_norm"].astype(f32), cfg.rms_norm_eps)
+    gate = jax.nn.silu((u @ lp["gdn_gate"]).astype(f32))
+    out = (o.reshape(n, H * dv) * gate).astype(u.dtype) @ lp["gdn_out"]
+    return out, LinearState(conv, pool)
 
 
 def mamba_mixer(u, lp, cfg, state: "MambaState", layer, io: SeqStateIO, form: ScanForm):
@@ -670,6 +859,21 @@ def _mamba_half(x, lp, cfg, eps, state, layer, io, form):
         return x + y.reshape(x.shape), state
 
 
+def _linear_half(x, lp, cfg, eps, state, layer, io, form):
+    """A layer's first half where its operator is linear attention:
+    ``gated_delta_mixer`` on the flattened rows, its output normed (Olmo's
+    block), residual add."""
+    with jax.named_scope("gated_delta"):
+        h = rms_norm(x, lp["attn_norm"], eps, cfg.rms_norm_offset) if "attn_norm" in lp else x
+        y, state = gated_delta_mixer(
+            h.reshape(-1, h.shape[-1]), lp, cfg, state, layer, io, form
+        )
+        y = y.reshape(x.shape)
+        if "post_attn_norm" in lp:
+            y = rms_norm(y, lp["post_attn_norm"], eps, cfg.rms_norm_offset)
+        return x + y, state
+
+
 def _conv_half(x, lp, cfg, eps, pool, layer, io):
     """A layer's first half where its operator is the short convolution:
     norm, ``short_conv`` on the flattened rows, residual add."""
@@ -699,7 +903,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
     the experts' kernel off the TPU, as it does the attention kernel."""
     B, T = x.shape[:2]
     off = cfg.rms_norm_offset
-    h = rms_norm(x, lp["mlp_norm"], eps, off)
+    h = rms_norm(x, lp["mlp_norm"], eps, off) if "mlp_norm" in lp else x
     if "router" in lp:
         from ..ops.moe import moe_ffn, moe_ffn_ep
 
@@ -861,6 +1065,7 @@ _OP_PARAMS = {
     "attn": ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm"),
     "conv": ("conv_in", "conv_w", "conv_out"),
     "mamba": MAMBA_PARAMS,
+    "linear": LINEAR_PARAMS,
 }
 _ROUTED_PARAMS = (
     "router", "router_bias", "shared_gate", "shared_up", "shared_down",
@@ -875,8 +1080,8 @@ def _scan_paged_layers(
 ):
     """The layer loop of both paged forwards: one ``lax.scan`` for each
     run of layers of one kind (``ModelConfig.layer_runs``), carrying
-    ``(x, k_cache, v_cache)`` and, where the model has convolution layers,
-    ``seq_state`` (a pytree by part: ``init_seq_state``). Returns (x,
+    ``(x, k_cache, v_cache)`` and, where the model has layers that are not
+    softmax attention, ``seq_state`` (a pytree by part: ``init_seq_state``). Returns (x,
     k_cache, v_cache, seq_state). A model of
     one kind is one run over the stacked parameters, the per-layer window
     / rope base and the layer index, as it always was.
@@ -895,7 +1100,9 @@ def _scan_paged_layers(
     convolution layer reads and writes ``seq_state`` the same way, at its
     ordinal among convolution layers (``short_conv``; ``state_io``), a
     Mamba layer both parts of its state at its ordinal among Mamba layers
-    (``mamba_mixer``; ``scan_form`` says which form the scan takes).
+    (``mamba_mixer``; ``scan_form`` says which form the scan takes), a
+    linear-attention layer both of its own at its ordinal among those
+    (``gated_delta_mixer``; ``scan_form`` likewise).
 
     A routed model's expert matrices (``EXPERT_STACKS``) leave ``xs`` for
     the same reason the pools left ``ys``: the body closes over the whole
@@ -969,6 +1176,10 @@ def _scan_paged_layers(
                 x, state = _conv_half(x, lp, cfg, eps, state, l, state_io)
             elif op == "mamba":
                 x, state = _mamba_half(
+                    x, lp, cfg, eps, state, l, state_io, scan_form
+                )
+            elif op == "linear":
+                x, state = _linear_half(
                     x, lp, cfg, eps, state, l, state_io, scan_form
                 )
             else:
@@ -1149,16 +1360,21 @@ def forward(
 
 def _scan_form(cfg, attn_impl, span_align, packed_from, interpret,
                one_token=False) -> ScanForm:
-    """The selective scan's form in a forward whose rows' spans are
-    aligned to ``span_align`` slots: the kernel where the attention kernel
-    serves the forward too (the engine then lays the stream in tiles) and
-    the widths tile, the oracle otherwise."""
-    from ..ops.selective_scan import supported
+    """The recurrence's form (a selective scan's or the delta rule's) in a
+    forward whose rows' spans are aligned to ``span_align`` slots: the
+    kernel where the attention kernel serves the forward too (the engine
+    then lays the stream in tiles) and the widths tile, the oracle
+    otherwise."""
+    from ..ops import gated_delta, selective_scan
 
-    kernel = (
-        cfg.num_mamba_layers > 0 and attn_impl == "pallas"
-        and supported(cfg.mamba_inner, span_align)
-    )
+    if cfg.num_mamba_layers:
+        fits = selective_scan.supported(cfg.mamba_inner, span_align)
+    else:
+        fits = cfg.num_linear_layers > 0 and gated_delta.supported(
+            cfg.linear_num_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim, span_align,
+        )
+    kernel = attn_impl == "pallas" and fits
     return ScanForm(kernel, packed_from if kernel else None, one_token, interpret)
 
 

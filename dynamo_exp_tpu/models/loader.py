@@ -37,7 +37,9 @@ def _open_safetensors(path: str):
 
 
 # Parameters that stay float32 whatever the model's dtype.
-_FLOAT32 = ("router_bias", "mamba_A_log", "mamba_D", "mamba_dt_b")
+_FLOAT32 = (
+    "router_bias", "mamba_A_log", "mamba_D", "mamba_dt_b", "gdn_A_log", "gdn_dt_b",
+)
 
 
 def _host_params(params: dict, dt) -> dict:
@@ -149,10 +151,69 @@ def _jamba_params(cfg: ModelConfig, get, linear) -> dict:
     return params
 
 
+def _olmo_hybrid_params(cfg: ModelConfig, get, linear) -> dict:
+    """Ai2's ``olmo_hybrid`` tensor names into the stacks by kind of
+    ``models/llama.py::init_params``. ASSUMED (no checkpoint is read in
+    this repository's tests or benchmark): Olmo 2's names for what every
+    layer has (``post_attention_layernorm`` / ``post_feedforward_layernorm``
+    on the halves' outputs, ``mlp.{gate,up,down}_proj``) and for the
+    attention layers (``self_attn.{q,k,v,o}_proj``, ``self_attn.{q,k}_norm``
+    over the whole projection); flash-linear-attention's GatedDeltaNet
+    names under ``linear_attn.`` for the linear layers: ``{q,k,v}_proj``
+    and their depthwise ``{q,k,v}_conv1d`` ([C, 1, K]), held side by side
+    as ``gdn_qkv`` / ``gdn_conv_w``; ``a_proj`` and ``b_proj`` as
+    ``gdn_ab``; ``A_log``, ``dt_bias``; ``g_proj``, ``o_norm``, ``o_proj``.
+    The head is ``lm_head``; the final norm ``model.norm``."""
+    names = (
+        "post_attn_norm", "post_ffn_norm", "w_gate", "w_up", "w_down", "wq",
+        "wk", "wv", "wo", "q_norm", "k_norm", "gdn_qkv", "gdn_conv_w", "gdn_ab",
+        "gdn_A_log", "gdn_dt_b", "gdn_gate", "gdn_norm", "gdn_out",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    for i, op in enumerate(cfg.op_kinds):
+        p = f"model.layers.{i}."
+        layers["post_attn_norm"].append(get(p + "post_attention_layernorm.weight"))
+        layers["post_ffn_norm"].append(get(p + "post_feedforward_layernorm.weight"))
+        for key, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj")):
+            layers[key].append(linear(f"{p}mlp.{name}.weight"))
+        if op == "attn":
+            a = p + "self_attn."
+            for key, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj")):
+                layers[key].append(linear(f"{a}{name}.weight"))
+            layers["q_norm"].append(get(a + "q_norm.weight"))
+            layers["k_norm"].append(get(a + "k_norm.weight"))
+            continue
+        m = p + "linear_attn."
+        qkv = ("q", "k", "v")
+        layers["gdn_qkv"].append(
+            np.concatenate([linear(f"{m}{x}_proj.weight") for x in qkv], axis=1)
+        )
+        layers["gdn_conv_w"].append(  # [K, C]
+            np.concatenate([get(f"{m}{x}_conv1d.weight")[:, 0, :].T for x in qkv], axis=1)
+        )
+        layers["gdn_ab"].append(
+            np.concatenate([linear(m + "a_proj.weight"), linear(m + "b_proj.weight")], axis=1)
+        )
+        layers["gdn_A_log"].append(get(m + "A_log"))
+        layers["gdn_dt_b"].append(get(m + "dt_bias"))
+        layers["gdn_gate"].append(linear(m + "g_proj.weight"))
+        layers["gdn_norm"].append(get(m + "o_norm.weight"))
+        layers["gdn_out"].append(linear(m + "o_proj.weight"))
+    params = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items() if v},
+        "final_norm": get("model.norm.weight"),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = linear("lm_head.weight")
+    return params
+
+
 SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
+    "olmo_hybrid",
 )
 
 
@@ -185,7 +246,10 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         return get(name).T
 
     pre = "model."
-    by_kind = {"lfm2_moe": _lfm2_moe_params, "jamba": _jamba_params}
+    by_kind = {
+        "lfm2_moe": _lfm2_moe_params, "jamba": _jamba_params,
+        "olmo_hybrid": _olmo_hybrid_params,
+    }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)
         handles.clear()

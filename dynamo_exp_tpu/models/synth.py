@@ -83,6 +83,19 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             mamba_expand=cfg.mamba_expand, mamba_dt_rank=cfg.mamba_dt_rank,
             mamba_conv_bias=True, mamba_proj_bias=False, sliding_window=None,
         )
+    if cfg.model_type == "olmo_hybrid":  # the family's own keys
+        del hf["rope_theta"]
+        hf.update(
+            architectures=["OlmoHybridForCausalLM"], hidden_act="silu",
+            attention_bias=False, layer_types=list(cfg.layer_types),
+            linear_num_key_heads=cfg.linear_num_heads,
+            linear_num_value_heads=cfg.linear_num_heads,
+            linear_key_head_dim=cfg.linear_key_head_dim,
+            linear_value_head_dim=cfg.linear_value_head_dim,
+            linear_conv_kernel_dim=cfg.linear_conv_kernel_dim,
+            linear_allow_neg_eigval=cfg.linear_allow_neg_eigval,
+            rope_parameters={"rope_theta": None},
+        )
     if cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -91,7 +104,7 @@ def write_config(path: str, cfg: ModelConfig) -> None:
         raise ValueError(
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
-            "the llama family, lfm2_moe and jamba only"
+            "the llama family, lfm2_moe, jamba and olmo_hybrid only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

@@ -336,6 +336,16 @@ class Telemetry:
             ["program"],  # window | mixed
             registry=self.registry,
         )
+        self.linear_attn_tokens = Counter(
+            "dynamo_linear_attn_tokens_total",
+            "Tokens a dispatch put through the gated delta rule of each "
+            "linear-attention layer (its live slots, as "
+            "dynamo_ssm_scan_tokens_total counts a selective scan's), by "
+            "program: mixed is prompt chunks and the decode rows beside "
+            "them, window is decode alone",
+            ["program"],  # window | mixed
+            registry=self.registry,
+        )
         self.seq_state_restores = Counter(
             "dynamo_seq_state_restores_total",
             "Sequence states copied from a kept snapshot into an admitted "

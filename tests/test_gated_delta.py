@@ -1,0 +1,277 @@
+"""The gated delta rule's three forms (``ops/gated_delta.py``): the Pallas
+kernel in interpret mode and the one-step form against the token
+recurrence, states included, and the chunk's matrix form against the
+recurrence in float64.
+
+TOLERANCES. The kernel computes the same float32 numbers in another order
+(a chunk's products against a token's sums): ``KERNEL_TOL`` 2e-5 on
+outputs and states of order 1, a hundred times the rounding read (2e-6
+over 2048 tokens) and far below any fault (a state started from the wrong
+entry, a slot of another row let in, a write one slot off: 1e-2 and more).
+In float64 the matrix form agrees with the recurrence to 1e-12.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_exp_tpu.ops import gated_delta as gd
+
+KERNEL_TOL = 2e-5
+H, DK, DV = 4, 8, 64  # two pairs of heads; 2 * DV fills a lane group
+
+
+def _inputs(rng, n, h=H, dk=DK, dv=DV, beta_top=2.0, dtype=np.float32):
+    """Keys of unit length, scaled queries, decays whose memory runs from
+    a token to hundreds, steps up to ``beta_top``."""
+    q = rng.standard_normal((n, h, dk))
+    k = rng.standard_normal((n, h, dk))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * dk**0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.standard_normal((n, h * dv))
+    g = -np.exp(rng.uniform(np.log(1e-3), np.log(3.0), (n, h)))
+    beta = beta_top * rng.uniform(0.0, 1.0, (n, h)) ** 0.5
+    return [x.astype(dtype) for x in (q.reshape(n, -1), k.reshape(n, -1), v, g, beta)]
+
+
+def _stream(spans, tail=(), pad_tiles=1):
+    """(row_of, positions) of ``spans`` = (row, first position, tokens),
+    each padded to the tile of 8, then ``tail`` = (row, position) slots one
+    a row, then tiles of padding."""
+    row_of, pos = [], []
+    for r, p0, n in spans:
+        pad = -n % gd.TILE
+        row_of += [r] * (n + pad)
+        pos += list(range(p0, p0 + n)) + [-1] * pad
+    for r, p in tail:
+        row_of, pos = row_of + [r], pos + [p]
+    more = -len(pos) % gd.TILE + pad_tiles * gd.TILE
+    row_of += [len(spans) + len(tail)] * more
+    pos += [-1] * more
+    return jnp.asarray(row_of, jnp.int32), jnp.asarray(pos, jnp.int32)
+
+
+def _check(spans, src, dst, seed=0, layers=2, entries=8, beta_top=2.0, tail=()):
+    rng = np.random.default_rng(seed)
+    row_of, pos = _stream(spans, tail)
+    n = pos.shape[0]
+    args = [jnp.asarray(x) for x in _inputs(rng, n, beta_top=beta_top)]
+    pool = jnp.asarray(rng.standard_normal((layers, entries, DK, H * DV)), jnp.float32)
+    rows = len(spans) + len(tail)
+    slots = jnp.asarray([r for r, *_ in spans] + [r for r, _ in tail] + [entries], jnp.int32)
+    pad = [0] * (2 * (rows + 1) - len(src))
+    io = (
+        slots, row_of, pos, jnp.asarray(src + pad, jnp.int32),
+        jnp.asarray(dst + [entries] * len(pad), jnp.int32),
+    )
+    o_ref, pool_ref = gd.gated_delta_ref(*args, pool, 1, *io)
+    o, out = gd.gated_delta_kernel(*args, pool, jnp.int32(1), *io, interpret=True)
+    live = np.asarray(pos) >= 0
+    assert np.abs(np.asarray(o) - np.asarray(o_ref))[live].max() < KERNEL_TOL
+    assert np.abs(np.asarray(out) - np.asarray(pool_ref)).max() < KERNEL_TOL
+    assert np.array_equal(np.asarray(out[0]), np.asarray(pool[0]))  # the other layer
+    assert out.dtype == jnp.float32
+    untouched = [e for e in range(entries) if e not in set(dst)]
+    assert np.array_equal(np.asarray(out[1, untouched]), np.asarray(pool[1, untouched]))
+    return float(np.abs(np.asarray(out) - np.asarray(pool_ref)).max())
+
+
+# ------------------------------------------------------------------ kernel
+@pytest.mark.parametrize("tokens", [1, 7, 8, 15, 63, 64, 65, 72, 127, 128, 129, 191])
+@pytest.mark.parametrize("start", [0, 16])
+def test_a_span_of_every_remainder(tokens, start):
+    """One span of every length round the chunk's 64 and the tile's 8, from
+    position 0 (zeros) and resumed from its entry, its own write after its
+    last token wherever in a chunk that falls."""
+    _check([(0, start, tokens)], [tokens - 1], [0], seed=tokens)
+
+
+@pytest.mark.parametrize("spans,src,dst", [
+    # several rows a stream: chunks that begin where another row's end
+    ([(0, 0, 100), (1, 40, 5), (2, 8, 64), (3, 0, 3)], [99, 108, 175, 178], [0, 1, 2, 3]),
+    # both writes a row: its own entry and a snapshot at a block boundary,
+    # 16 tokens into a chunk, at a chunk's end, and in the chunk of the last token
+    ([(0, 16, 200)], [199, 15], [0, 5]),
+    ([(0, 0, 200)], [199, 63], [0, 5]),
+    ([(0, 32, 200), (1, 0, 70)], [199, 191, 200 + 69, 200 + 47], [0, 5, 1, 6]),
+    # a row with no write at all, and a write to an entry past the pool
+    ([(0, 0, 24), (1, 0, 24)], [23, 47], [0, 8]),
+])
+def test_rows_and_writes(spans, src, dst):
+    _check(spans, src, dst, seed=len(spans))
+
+
+def test_a_packed_tail_is_left_to_the_one_step_form():
+    """Decode rows in the packed tail are marked padding for the kernel
+    (``models/llama.py::_stream_recurrence``): it must not touch their
+    entries, nor let their slots into a chunk that is read across them."""
+    rng = np.random.default_rng(5)
+    row_of, pos = _stream([(0, 0, 40)], tail=[(1, 9), (2, 30)])
+    alone = np.asarray(row_of) > 0
+    n = pos.shape[0]
+    args = [jnp.asarray(x) for x in _inputs(rng, n)]
+    pool = jnp.asarray(rng.standard_normal((1, 4, DK, H * DV)), jnp.float32)
+    slots = jnp.asarray([0, 1, 2, 4], jnp.int32)
+    src = jnp.asarray([39, 40, 41, 0, 0, 0, 0, 0], jnp.int32)
+    dst = jnp.asarray([0, 1, 2, 4, 4, 4, 4, 4], jnp.int32)
+    o, out = gd.gated_delta_kernel(
+        *args, pool, jnp.int32(0), slots, row_of, jnp.where(alone, -1, pos), src,
+        jnp.where(jnp.asarray(alone)[src], 4, dst), interpret=True,
+    )
+    o_ref, pool_ref = gd.gated_delta_ref(*args, pool, 0, slots, row_of, pos, src, dst)
+    assert np.abs(np.asarray(o) - np.asarray(o_ref))[:40].max() < KERNEL_TOL
+    assert np.abs(np.asarray(out[0, 0]) - np.asarray(pool_ref[0, 0])).max() < KERNEL_TOL
+    assert np.array_equal(np.asarray(out[0, 1:]), np.asarray(pool[0, 1:]))
+    # and the one-step form gives those rows what the oracle does
+    tail = slice(40, 42)
+    o1, out1 = gd.gated_delta_step(
+        *(a[tail] for a in args), out, 0, slots[1:3], pos[tail], slots[1:3]
+    )
+    assert np.abs(np.asarray(o1) - np.asarray(o_ref)[tail]).max() < KERNEL_TOL
+    assert np.abs(np.asarray(out1) - np.asarray(pool_ref)).max() < KERNEL_TOL
+
+
+def test_steps_up_to_two_and_2048_tokens():
+    """2048 tokens in one span with steps up to 2 (the negative
+    eigenvalues): 32 chunks, the state handed on 31 times; the error does
+    not grow with the length."""
+    worst = _check([(0, 0, 2048)], [2047, 1023], [0, 3], seed=11)
+    assert worst < 1e-5
+
+
+def test_a_stream_shorter_than_a_chunk():
+    _check([(0, 4, 9)], [8], [0], seed=3)  # 24 slots
+
+
+def test_chunk_tables_name_every_chunk():
+    row_of, pos = _stream([(0, 0, 70), (1, 16, 8)])
+    n = pos.shape[0]
+    base, lo, hi, load, ea, ata, eb, atb = (np.asarray(t) for t in gd._chunk_tables(
+        jnp.asarray([0, 1, 9], jnp.int32), row_of, pos,
+        jnp.asarray([69, 79, 15, 0, 0, 0], jnp.int32), jnp.asarray([0, 1, 5, 9, 9, 9], jnp.int32),
+        64, n // 64 + 3, 9,
+    ))
+    assert n == 88
+    # chunks: row 0's [0, 64) and [64, 70) (read as slots 24..87), row 1's [72, 80)
+    assert list(base[:3]) == [0, 24, 24] and list(lo[:3]) == [0, 40, 48]
+    assert list(hi[:3]) == [64, 46, 56] and all(hi[3:] == lo[3:])
+    assert list(load[:3]) == [9, -1, 1]  # zeros / go on / entry 1
+    assert (ea[0], ata[0], eb[0]) == (5, 15, 9)  # the snapshot, 16 tokens in
+    assert (ea[1], ata[1]) == (0, 45) and (ea[2], ata[2]) == (1, 55)
+
+
+# ---------------------------------------------------------------- one step
+def test_step_matches_its_oracle():
+    rng = np.random.default_rng(9)
+    r = 4
+    args = [jnp.asarray(x) for x in _inputs(rng, r)]
+    pool = jnp.asarray(rng.standard_normal((2, 7, DK, H * DV)), jnp.float32)
+    pos = jnp.asarray([0, 5, 9, -1], jnp.int32)
+    slots = jnp.asarray([0, 1, 2, 7], jnp.int32)
+    o, out = gd.gated_delta_step(*args, pool, 0, slots, pos, slots)
+    o_ref, pool_ref = gd.gated_delta_ref(
+        *args, pool, 0, slots, jnp.arange(r), pos, jnp.arange(r), slots
+    )
+    assert np.abs(np.asarray(o) - np.asarray(o_ref))[:3].max() < KERNEL_TOL
+    assert np.abs(np.asarray(out) - np.asarray(pool_ref)).max() < KERNEL_TOL
+
+
+# --------------------------------------------------------- the matrix form
+def _recurrence64(q, k, v, g, beta, s0):
+    s, out, after = s0.copy(), [], []
+    for t in range(q.shape[0]):
+        s = np.exp(g[t]) * s
+        s = s + np.outer(k[t], beta[t] * (v[t] - s.T @ k[t]))
+        out.append(s.T @ q[t])
+        after.append(s.copy())
+    return np.stack(out), np.stack(after)
+
+
+def chunk_form(q, k, v, g, beta, s0):
+    """One head's chunk in the matrix form (the module's docstring), in
+    NumPy at the inputs' precision: ``q``, ``k`` [C, dk]; ``v`` [C, dv];
+    ``g``, ``beta`` [C]; ``s0`` [dk, dv]. Returns (o [C, dv], the state
+    after every token [C, dk, dv]). The algebra the kernel runs, its
+    ``unit_lower_inverse`` included."""
+    c = q.shape[0]
+    rows, cols = np.arange(c)[:, None], np.arange(c)[None, :]
+    cum = np.cumsum(g)
+    decay = np.where(rows >= cols, np.exp(np.minimum(cum[:, None] - cum[None, :], 0)), 0)
+    a = np.where(rows > cols, beta[:, None] * decay * (k @ k.T), 0)
+    t = gd.unit_lower_inverse(a, rows, cols, np.matmul)
+    u = t @ (beta[:, None] * v)
+    w = t @ ((beta * np.exp(cum))[:, None] * k)
+    vp = u - w @ s0
+    o = np.exp(cum)[:, None] * (q @ s0) + (decay * (q @ k.T)) @ vp
+    after = np.stack([
+        np.exp(cum[m]) * s0
+        + (np.where(np.arange(c) <= m, np.exp(np.minimum(cum[m] - cum, 0)), 0)[:, None] * k).T @ vp
+        for m in range(c)
+    ])
+    return o, after
+
+
+@pytest.mark.parametrize("c", [2, 16, 64])
+def test_matrix_form_is_the_recurrence_in_float64(c):
+    """``chunk_form`` (the algebra the kernel runs, the triangular inverse
+    by blocks included) against the token recurrence, both in float64:
+    outputs and the state after every token."""
+    rng = np.random.default_rng(c)
+    q, k, v, g, beta = _inputs(rng, c, h=1, dk=12, dv=20, dtype=np.float64)
+    s0 = rng.standard_normal((12, 20))
+    o, after = chunk_form(q, k, v, g[:, 0], beta[:, 0], s0)
+    o_ref, after_ref = _recurrence64(q, k, v, g[:, 0], beta[:, 0], s0)
+    assert np.abs(o - o_ref).max() < 1e-12
+    assert np.abs(after - after_ref).max() < 1e-12
+
+
+def test_block_inverse_holds_where_a_power_series_would_not():
+    """Neighbouring keys that agree and steps of 2: ``A``'s entries reach
+    2 and its powers 1e18, while the inverse stays of order 1. Forward
+    substitution in blocks has it to float64's rounding."""
+    c = 64
+    rows, cols = np.arange(c)[:, None], np.arange(c)[None, :]
+    a = np.where(rows > cols, 1.99, 0.0)
+    t = gd.unit_lower_inverse(a, rows, cols, np.matmul)
+    assert np.abs(t @ (np.eye(c) + a) - np.eye(c)).max() < 1e-12
+    assert np.abs(t).max() < 2.0
+
+
+# ----------------------------------------------------------------- compile
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("slots", [4096, 64])
+def test_kernel_compiles_for_v5e_at_the_published_widths(one_chip, slots):
+    """Mosaic takes the kernel at Olmo-Hybrid-7B's widths (30 heads of 96
+    by 192, the pool of 6 layers x 160 entries) for a 4096-slot mixed
+    dispatch and a 64-slot one, and the pool is updated in place (aliased,
+    no copy of 2.1 GB among the temporaries)."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    f, i = jnp.float32, jnp.int32
+    n, h, dk, dv = slots, 30, 96, 192
+    assert gd.supported(h, dk, dv, 8)
+    pool = (6, 160, dk, h * dv)
+    args = (
+        sds((n, h * dk), f), sds((n, h * dk), f), sds((n, h * dv), f), sds((n, h), f),
+        sds((n, h), f), sds(pool, f), sds((), i), sds((33,), i), sds((n,), i), sds((n,), i),
+        sds((66,), i), sds((66,), i),
+    )
+    compiled = jax.jit(gd.gated_delta_kernel, donate_argnums=(5,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool)) * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 8
+    assert "gated_delta_chunk" in compiled.as_text()
