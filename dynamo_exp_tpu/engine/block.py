@@ -472,6 +472,7 @@ class BlockDecode:
             nb, page_bucket, False, full_sampler, want_lp, False,
             chained=False, live=int((positions >= 0).sum()),
         )
+        self._count_attn_passes(nb, row_of, positions)
         self.flight.record(
             "dispatch", dispatch="ragged", rows=len(rows), tokens=at,
             bucket=nb, windowed=False, pages=key[1], want_lp=want_lp,

@@ -1258,6 +1258,28 @@ class TPUEngine(BlockDecode, AsyncEngine):
             )).inc()
         return key
 
+    def _count_attn_passes(self, nb: int, row_of, positions) -> None:
+        """Of a mixed stream's tiled part, the live slots whose query
+        block the attention kernel reads in one pass over their row's
+        context and those it walks tile by tile
+        (``dynamo_attn_query_slots_total``): the kernel's own rule
+        (``ops/ragged_attention.py::block_plan``) on the host's copy of
+        what it takes. The reference has no tiles and counts nothing."""
+        if self._attn_impl != "pallas":
+            return
+        from ..ops.ragged_attention import merged_slot_counts, query_block
+
+        cfg = self.cfg
+        tiled = nb - cfg.ragged_packed_slots(nb)
+        tile = cfg.ragged_q_tile
+        merged, by_tile = merged_slot_counts(
+            row_of[:tiled], positions[:tiled], tile,
+            query_block(tile, cfg.model.q_per_kv),
+        )
+        slots = get_telemetry().attn_query_slots
+        slots.labels("merged").inc(merged)
+        slots.labels("tile").inc(by_tile)
+
     def _count_slots(self, program: str, live: int, pad: int) -> None:
         """``_count_dispatch``'s slots. A block program's are counted at
         its consume: how many passes it made is the device's to say."""
@@ -4031,6 +4053,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             nb, page_bucket, False, full_sampler, want_lp, with_spec,
             chained=False, live=int((positions >= 0).sum()),
         )
+        self._count_attn_passes(nb, row_of, positions)
         self.flight.record(
             "dispatch",
             dispatch="ragged",

@@ -1420,11 +1420,14 @@ def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
 
 
 def _pallas_ragged(
-    q, kp, vp, attn_table, row_of, positions, hkv, q_tile, mesh, interpret
+    q, kp, vp, attn_table, row_of, positions, hkv, q_tile, mesh, interpret,
+    q_block=None,
 ):
     """Dispatch the ragged kernel over a flat mixed query stream,
     sharded over tp exactly like :func:`_pallas_decode` (the kernel is
-    per-head data-parallel; each shard sees its local heads)."""
+    per-head data-parallel; each shard sees its local heads, and a
+    shard's query heads a KV head are the model's, so ``q_block``, the
+    slots a grid cell serves (None: one tile), is every shard's)."""
     from functools import partial as _partial
 
     from ..ops.ragged_attention import ragged_paged_attention
@@ -1433,7 +1436,7 @@ def _pallas_ragged(
     if tp <= 1:
         return ragged_paged_attention(
             q, kp, vp, attn_table, row_of, positions, num_kv_heads=hkv,
-            q_tile=q_tile, interpret=interpret,
+            q_tile=q_tile, interpret=interpret, q_block=q_block,
         )
     from ..parallel.mesh import shard_map
 
@@ -1454,7 +1457,7 @@ def _pallas_ragged(
     def f(q_l, k_l, v_l, table, rows, pos):
         return ragged_paged_attention(
             q_l, k_l, v_l, table, rows, pos, num_kv_heads=hkv // tp,
-            q_tile=q_tile, interpret=interpret,
+            q_tile=q_tile, interpret=interpret, q_block=q_block,
         )
 
     return f(q, kp, vp, attn_table, row_of, positions)
@@ -1573,16 +1576,24 @@ def forward_ragged(
 
     def read(q, kf, vf, table_l, win_l):
         if use_pallas:
-            def kernel(part, tile):
+            from ..ops.ragged_attention import query_block
+
+            def kernel(part, tile, block=None):
                 return _pallas_ragged(
                     q[0, part], kf, vf, table_l, row_of[part],
                     bounds[part], cfg.num_kv_heads, tile, mesh, interpret,
+                    block,
                 )
 
             if packed_from is None:
                 return kernel(slice(None), q_tile)[None]
+            # A mixed stream's spans run over many tiles of one row: a
+            # grid cell takes a block of them (ops/ragged_attention.py).
             return jnp.concatenate([
-                kernel(slice(0, packed_from), q_tile),
+                kernel(
+                    slice(0, packed_from), q_tile,
+                    query_block(q_tile, cfg.q_per_kv),
+                ),
                 kernel(slice(packed_from, None), 1),
             ])[None]
         from ..ops.ragged_attention import ragged_paged_attention_ref

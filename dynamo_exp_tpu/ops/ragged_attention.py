@@ -22,21 +22,32 @@ Two implementations with identical semantics:
   softmax), the always-correct CPU/tier-1 path and the kernel's test
   oracle. It re-gathers the owning row's pages per query token, so its
   HBM traffic is ``N * S``; fine for the CPU mesh, not the fast path.
-- :func:`ragged_paged_attention` — the Pallas TPU kernel. Grid over
-  ``q_tile``-sized slices of the flat stream; the caller aligns each
-  row's query span to ``q_tile`` so every grid cell belongs to exactly
-  one row (``tile_row``), DMAs only that row's live pages
-  (``ceil(kv_len / page_size)``, double-buffered), and runs a
-  flash-style online softmax in VMEM scratch. ``q_tile=1`` degenerates
-  to the old per-row decode kernel (one query per grid cell — the
-  shape pure-decode windows dispatch, and the one a mixed dispatch
-  reads its decode rows through: ``forward_ragged`` calls the kernel
-  twice, at ``q_tile`` over the stream's aligned spans and at 1 over
+- :func:`ragged_paged_attention` — the Pallas TPU kernel. The caller
+  aligns each row's query span to ``q_tile`` slots of the flat stream,
+  so every tile belongs to exactly one row. A grid cell serves a
+  **query block** of ``q_block`` consecutive slots, a whole number of
+  tiles (:func:`query_block`: enough that a KV head's score product has
+  about the MXU's 128 rows; one tile where the caller names none).
+  Where every live tile of the block belongs to one row
+  (:func:`block_plan`, derived on the device from ``row_of`` and the
+  positions) the cell makes ONE double-buffered pass over that row's
+  live pages (``ceil(kv_len / page_size)``) for all its queries, a
+  flash-style online softmax in VMEM scratch, each query masked at its
+  own bound; a block that holds several rows (a span's first and last)
+  walks its tiles one pass each. ``q_tile=1`` degenerates to the old
+  per-row decode kernel (one query per grid cell — the shape
+  pure-decode windows dispatch, and the one a mixed dispatch reads its
+  decode rows through: ``forward_ragged`` calls the kernel twice, at
+  ``q_tile`` in blocks over the stream's aligned spans and at 1 over
   its packed tail, where rows of one token lie side by side).
 
-HBM traffic per dispatch per layer is ``sum_rows(tiles_row * kv_row)``
-tokens instead of the XLA gather's ``N * S``: the kernel reads each
-row's context once per query tile, never the page-bucket envelope.
+HBM traffic per dispatch per layer is ``sum_rows(blocks_row * kv_row)``
+tokens instead of the XLA gather's ``N * S``: the kernel reads a row's
+context once per query block of its span (once per tile in a block it
+shares with another row), never the page-bucket envelope. At one tile
+of 8 a block that traffic bound the call: a 4096-slot stream of
+long-document chunks read 18 GB a layer at 30 KV heads, 1.2 GB at a
+block of 128 (PERF.md §6, PR 40).
 """
 
 from __future__ import annotations
@@ -45,6 +56,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -125,39 +137,106 @@ def ragged_paged_attention_ref(
 
 
 # ------------------------------------------------------------------ kernel
-def _ragged_kernel(
-    # scalar prefetch (SMEM)
-    tile_row_ref,  # [T] int32 — owning batch row per query tile
-    tile_kvlen_ref,  # [T] int32 — kv tokens the tile attends over (0=skip)
-    positions_ref,  # [N] int32 — last kv position each flat query sees
-    table_ref,  # [R, Pmax] int32 — page ids per row
-    # inputs
-    q_ref,  # [QT, H, D] VMEM — this tile's queries
+# Rows of the MXU: what a head's score product should have to fill it.
+_MXU_ROWS = 128
+# Above every row index: the row of a tile that holds no query.
+_NO_ROW = 2**30
+
+
+def query_block(q_tile: int, q_per_kv: int) -> int:
+    """Stream slots a grid cell of the tiled call serves in one pass over
+    a row's context: enough tiles that a KV head's score product
+    (``q_per_kv`` query heads of every slot) has about the MXU's rows,
+    a whole number of ``q_tile``s and never fewer than one. From the
+    call's shapes alone: the kernel's caller and the engine's counter
+    (``dynamo_attn_query_slots_total``) both ask here."""
+    want = max(q_tile, _MXU_ROWS // q_per_kv)
+    return -(-want // q_tile) * q_tile
+
+
+def block_plan(row_of, positions, q_tile: int, q_block: int, xp=jnp):
+    """What each tile and each query block of a flat stream reads:
+    ``(tile_row, tile_kvlen, block_row, block_kvlen)``, the stream
+    padded with ``-1`` to whole blocks.
+
+    A tile is live where one of its positions is. A block whose live
+    tiles all belong to one row (a tile of all ``-1`` belongs to any)
+    is read in one pass of ``block_kvlen`` tokens of ``block_row``; a
+    block with several rows has ``block_kvlen`` -1 and walks its tiles,
+    each by its own ``(tile_row, tile_kvlen)``. ``xp``: ``jnp`` inside
+    the program, ``numpy`` for the engine's count of the same rule."""
+    nt = q_block // q_tile
+    pad = (-positions.shape[0]) % q_block
+    if pad:
+        positions = xp.concatenate(
+            [positions, xp.full((pad,), -1, positions.dtype)]
+        )
+        row_of = xp.concatenate([row_of, xp.zeros((pad,), row_of.dtype)])
+    # Alignment makes every tile single-row; padding positions are -1,
+    # so the max is the tile's true causal horizon.
+    tile_row = row_of.reshape(-1, q_tile)[:, 0]
+    tile_kvlen = positions.reshape(-1, q_tile).max(axis=1) + 1
+    rows, lens = tile_row.reshape(-1, nt), tile_kvlen.reshape(-1, nt)
+    live = lens > 0
+    last = xp.where(live, rows, -1).max(axis=1)
+    first = xp.where(live, rows, _NO_ROW).min(axis=1)
+    block_kvlen = xp.where(first >= last, lens.max(axis=1), -1)
+    return tile_row, tile_kvlen, xp.maximum(last, 0), block_kvlen
+
+
+def merged_slot_counts(row_of, positions, q_tile: int, q_block: int):
+    """Live slots of a stream's tiled part (host arrays) that lie in a
+    block read in one pass, and those whose block walks its tiles:
+    :func:`block_plan`'s rule, as the kernel applies it. Where a block
+    is one tile nothing merges."""
+    live = np.asarray(positions) >= 0
+    if q_block == q_tile:
+        return 0, int(live.sum())
+    *_, block_kvlen = block_plan(
+        np.asarray(row_of), np.asarray(positions), q_tile, q_block, xp=np
+    )
+    pad = (-live.shape[0]) % q_block
+    by_block = np.pad(live, (0, pad)).reshape(-1, q_block).sum(axis=1)
+    merged = int(by_block[block_kvlen >= 0].sum())
+    return merged, int(by_block.sum()) - merged
+
+
+def _attend(
+    q_ref,  # [W, H, D] VMEM — the pass's queries
+    o_ref,  # [W, H, D] VMEM
+    pos_rows,  # () -> [qpk*W, 1] int32: last kv position a score row sees
+    row,  # scalar: the batch row whose pages the pass walks
+    kvlen,  # scalar: kv tokens it walks (0 = none)
+    table_ref,  # [R, Pmax] int32 SMEM — page ids per row
     k_hbm,  # [P, ps, Hkv*D] — page pool, stays in HBM
     v_hbm,
-    # output
-    o_ref,  # [QT, H, D] VMEM
-    # scratch
     k_buf,  # [2, cp, ps, Hkv*D] VMEM double buffer
     v_buf,
-    acc_ref,  # [H*QT, D] f32 — output accumulator, rows = (kv head, g, i)
-    m_ref,  # [H*QT, 128] f32 — running max (lane-replicated)
-    l_ref,  # [H*QT, 128] f32 — running sum (lane-replicated)
+    acc_ref,  # [>= H*W, D] f32 — output accumulator, rows = (kv head, g, i)
+    m_ref,  # [>= H*W, 128] f32 — running max (lane-replicated)
+    l_ref,  # [>= H*W, 128] f32 — running sum (lane-replicated)
     sems,  # DMA semaphores [2, 2*cp]
     *,
+    w: int,
     ps: int,
     cp: int,
     hkv: int,
     hd: int,
     qpk: int,
-    qt: int,
     pmax: int,
     scale: float,
 ):
-    t = pl.program_id(0)
-    row = tile_row_ref[t]
-    kvlen = tile_kvlen_ref[t]
+    """One double-buffered pass over ``row``'s first ``kvlen`` tokens
+    for ``w`` queries: flash-style online softmax, ``qpk * w`` score
+    rows a KV head. Every DMA it starts it waits for, so passes follow
+    one another in a grid cell on the same buffers and semaphores."""
     n_chunks = pl.cdiv(kvlen, ps * cp)
+    # The score rows of this pass: all the state's where it is the cell's
+    # only one, the first ``H * w`` in a pass of one tile of a block.
+    n = hkv * qpk * w
+    whole = n == acc_ref.shape[0]
+    head = slice(None) if whole else slice(0, n)
+    used = ... if whole else (head, slice(None))
 
     def chunk_dmas(c, slot):
         """The 2*cp page copies of chunk ``c`` into buffer ``slot``.
@@ -185,35 +264,19 @@ def _ragged_kernel(
             )
         return dmas
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, -1e30)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[used] = jnp.zeros((n, hd), jnp.float32)
+    m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
+    l_ref[used] = jnp.zeros((n, 128), jnp.float32)
 
     @pl.when(n_chunks > 0)
     def _():
         for dma in chunk_dmas(0, 0):
             dma.start()
 
-    # Per-query absolute positions (the causal bound), read from SMEM.
-    # Padding queries carry -1: nothing satisfies kv_pos <= -1, so with
-    # ``p`` masked below their softmax sum stays 0 and the final divide
-    # maps them to zeros, whatever the pages the tile brought in hold.
-    # Score rows are laid out (kv head, group, query): each head's
-    # block is contiguous, and within it the query index varies
-    # fastest — so row r's causal bound is query r % QT's position.
-    # Built as a column from the start: a flat [qpk*QT] vector tiled
-    # from [QT] outgrows one lane tile at 20 query heads a group
-    # (Mosaic: "input offsets outside of the first tile").
-    query = jax.lax.rem(
-        jax.lax.broadcasted_iota(jnp.int32, (qpk * qt, 1), 0), qt
-    )
-    pos_rows = jnp.zeros((qpk * qt, 1), jnp.int32)
-    for i in range(qt):
-        pos_rows = jnp.where(query == i, positions_ref[t * qt + i], pos_rows)
-
-    # [QT, H, D] -> [H', QT, D] with H' rows ordered (kv head, group):
+    pos_rows = pos_rows()
+    # [W, H, D] -> [H', W, D] with H' rows ordered (kv head, group):
     # per-kv-head slices are then contiguous row blocks.
-    q = jnp.swapaxes(q_ref[...].astype(jnp.float32), 0, 1)  # [H, QT, D]
+    q = jnp.swapaxes(q_ref[...].astype(jnp.float32), 0, 1)  # [H, W, D]
     S = cp * ps
 
     def body(c, _):
@@ -232,9 +295,9 @@ def _ragged_kernel(
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
         v = v_buf[slot].reshape(S, hkv * hd)
         for h in range(hkv):
-            rows = slice(h * qpk * qt, (h + 1) * qpk * qt)
+            rows = slice(h * qpk * w, (h + 1) * qpk * w)
             cols = slice(h * hd, (h + 1) * hd)
-            qh = q[h * qpk : (h + 1) * qpk].reshape(qpk * qt, hd)
+            qh = q[h * qpk : (h + 1) * qpk].reshape(qpk * w, hd)
             kh = k[:, cols].astype(jnp.float32)  # [S, D]
             s = (
                 jax.lax.dot_general(
@@ -244,19 +307,22 @@ def _ragged_kernel(
                     preferred_element_type=jnp.float32,
                 )
                 * scale
-            )  # [qpk*QT, S]
+            )  # [qpk*W, S]
             live = tok_idx <= pos_rows
             s = jnp.where(live, s, -1e30)
-            m_prev = m_ref[rows, :1]  # [qpk*QT, 1]
+            m_prev = m_ref[rows, :1]  # [qpk*W, 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_new)
             # The mask is finite, so a query with nothing to attend to
             # (padding inside a live tile) has m_new == -1e30 and every
             # exp(s - m_new) == 1: masking s alone would give it the
-            # mean of whatever V slots the tile's chunks hold (the row's
+            # mean of whatever V slots the pass's chunks hold (the row's
             # context, its last page's slots past the length, clamped
-            # table entries). A live query's masked p is already 0.
+            # table entries). A live query's masked p is already 0: a
+            # chunk wholly past its own bound, which a pass over a block
+            # of tiles walks for the later ones' sake, leaves its state
+            # as it was (``alpha`` 1, ``p`` 0).
             p = jnp.where(live, jnp.exp(s - m_new), 0.0)
             l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
                 p, axis=1, keepdims=True
@@ -266,23 +332,138 @@ def _ragged_kernel(
                 v[:, cols],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [qpk*QT, D]
+            )  # [qpk*W, D]
             acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
             m_ref[rows, :] = jnp.broadcast_to(m_new, m_ref[rows, :].shape)
         return 0
 
     jax.lax.fori_loop(0, n_chunks, body, 0)
 
-    l = l_ref[:, :1]
+    l = l_ref[head, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = acc_ref[...] / l_safe  # [H*QT, D], rows (kv head, g, i)
-    out = out.reshape(hkv, qpk, qt, hd).transpose(2, 0, 1, 3)
-    o_ref[...] = out.reshape(qt, hkv * qpk, hd).astype(o_ref.dtype)
+    out = acc_ref[used] / l_safe  # [H*W, D], rows (kv head, g, i)
+    out = out.reshape(hkv, qpk, w, hd).transpose(2, 0, 1, 3)
+    o_ref[...] = out.reshape(w, hkv * qpk, hd).astype(o_ref.dtype)
+
+
+def _tile_kernel(
+    # scalar prefetch (SMEM)
+    tile_row_ref,  # [T] int32 — owning batch row per query tile
+    tile_kvlen_ref,  # [T] int32 — kv tokens the tile attends over (0=skip)
+    positions_ref,  # [N] int32 — last kv position each flat query sees
+    table_ref,
+    # inputs, output, scratch: _attend's
+    q_ref,  # [QT, H, D] VMEM — this tile's queries
+    k_hbm,
+    v_hbm,
+    o_ref,
+    *scratch,
+    qt: int,
+    qpk: int,
+    **shape,
+):
+    """A grid cell is one tile of ``qt`` slots, all of one row."""
+    t = pl.program_id(0)
+    # Per-query absolute positions (the causal bound), read from SMEM.
+    # Padding queries carry -1: nothing satisfies kv_pos <= -1, so with
+    # ``p`` masked below their softmax sum stays 0 and the final divide
+    # maps them to zeros, whatever the pages the tile brought in hold.
+    # Score rows are laid out (kv head, group, query): each head's
+    # block is contiguous, and within it the query index varies
+    # fastest — so row r's causal bound is query r % QT's position.
+    # Built as a column from the start: a flat [qpk*QT] vector tiled
+    # from [QT] outgrows one lane tile at 20 query heads a group
+    # (Mosaic: "input offsets outside of the first tile").
+    def pos_rows():
+        query = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (qpk * qt, 1), 0), qt
+        )
+        rows = jnp.zeros((qpk * qt, 1), jnp.int32)
+        for i in range(qt):
+            rows = jnp.where(query == i, positions_ref[t * qt + i], rows)
+        return rows
+
+    _attend(
+        q_ref, o_ref, pos_rows, tile_row_ref[t], tile_kvlen_ref[t],
+        table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, **shape,
+    )
+
+
+def _block_kernel(
+    # scalar prefetch (SMEM): block_plan's four, and the page table
+    tile_row_ref,  # [B*nt] int32
+    tile_kvlen_ref,  # [B*nt] int32
+    block_row_ref,  # [B] int32
+    block_kvlen_ref,  # [B] int32 — kv tokens of the one pass; -1 = by tile
+    table_ref,
+    # inputs, output, scratch: _attend's, behind the block's positions
+    pos_ref,  # [QB, 1] int32 VMEM
+    q_ref,  # [QB, H, D] VMEM — this block's queries
+    k_hbm,
+    v_hbm,
+    o_ref,
+    *scratch,
+    qt: int,
+    nt: int,
+    qpk: int,
+    **shape,
+):
+    """A grid cell is a block of ``nt`` tiles. Where they belong to one
+    row it reads that row's context once for all ``nt * qt`` queries;
+    where to several, tile after tile as :func:`_tile_kernel` does."""
+    b = pl.program_id(0)
+    merged_len = block_kvlen_ref[b]
+
+    def score_rows(at):
+        """() -> the positions ``pos_ref[at]`` [W, 1] as score rows
+        [qpk*W, 1]: the query index varies fastest inside a head."""
+        def read():
+            pos = pos_ref[at, :]
+            return jnp.concatenate([pos] * qpk, axis=0) if qpk > 1 else pos
+
+        return read
+
+    @pl.when(merged_len >= 0)
+    def _():
+        _attend(
+            q_ref, o_ref, score_rows(slice(None)), block_row_ref[b],
+            merged_len, table_ref, k_hbm, v_hbm, *scratch, w=nt * qt,
+            qpk=qpk, **shape,
+        )
+
+    @pl.when(merged_len < 0)
+    def _():
+        def tile(j, _):
+            t = b * nt + j
+            at = pl.ds(pl.multiple_of(j * qt, qt), qt)
+            _attend(
+                q_ref.at[at], o_ref.at[at], score_rows(at),
+                tile_row_ref[t], tile_kvlen_ref[t], table_ref, k_hbm,
+                v_hbm, *scratch, w=qt, qpk=qpk, **shape,
+            )
+            return 0
+
+        jax.lax.fori_loop(0, nt, tile, 0)
+
+
+def _block_vmem_bytes(qb, H, D, kv_lanes, cp, ps, q_item, kv_item) -> int:
+    """VMEM a block cell holds, from its shapes: the query and output
+    blocks (double-buffered by the pipeline, heads padded to a sublane
+    tile), the K/V double buffers, the three softmax states, and the
+    float32 queries and finished output as values."""
+    heads = -(-H // 16) * 16
+    io = 2 * 2 * qb * heads * D * q_item
+    kv = 2 * 2 * cp * ps * kv_lanes * kv_item
+    state = H * qb * (D + 2 * 128) * 4
+    values = 2 * H * qb * D * 4
+    return io + kv + state + values
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_kv_heads", "q_tile", "sm_scale", "interpret"),
+    static_argnames=(
+        "num_kv_heads", "q_tile", "sm_scale", "interpret", "q_block",
+    ),
 )
 def ragged_paged_attention(
     q: jnp.ndarray,  # [N, H, D] flat query stream (N % q_tile == 0)
@@ -296,13 +477,22 @@ def ragged_paged_attention(
     sm_scale: float | None = None,
     interpret: bool = False,
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
+    q_block: int | None = None,  # slots a grid cell serves; None = q_tile
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
     Within one call the caller aligns each row's query span to
     ``q_tile`` flat slots (padding tokens carry position -1), so every
-    tile belongs to exactly one row — each grid cell DMAs only that
-    row's live pages and computes ``q_tile`` queries against them.
+    tile belongs to exactly one row. A grid cell serves ``q_block``
+    consecutive slots (a multiple of ``q_tile``; :func:`query_block`
+    sizes it for a stream of long spans). At ``q_block == q_tile`` a
+    cell is one tile: it DMAs only that row's live pages and computes
+    ``q_tile`` queries against them. At more, a cell whose live tiles
+    all belong to one row (:func:`block_plan`, derived here on the
+    device) walks that row's pages once for all its queries, each
+    masked at its own bound, and a cell that holds several rows walks
+    its tiles one after another: either way a query's softmax sees the
+    same chunks in the same order as in a cell of its tile alone.
     ``q_tile=1`` needs no alignment: any slot is a cell of its own (one
     query per row, the old paged-decode kernel's grid; a decode window's
     step and the packed tail of a mixed stream). Returns [N, H, D] in
@@ -328,53 +518,84 @@ def ragged_paged_attention(
     scale = sm_scale if sm_scale is not None else D**-0.5
     cp = max(1, min(_CHUNK_TOKENS // ps, pmax))
     qt = q_tile
-    n_tiles = N // qt
+    qb = q_block if q_block is not None else qt
+    nt = qb // qt
+    shape = dict(
+        ps=ps, cp=cp, hkv=Hkv, hd=D, qpk=qpk, qt=qt, pmax=pmax, scale=scale
+    )
 
-    # Per-tile row + DMA bound, derived on device from the flat stream
-    # (alignment makes every tile single-row; padding positions are -1
-    # so the max is the tile's true causal horizon).
-    tile_row = row_of.reshape(n_tiles, qt)[:, 0]
-    tile_kvlen = positions.reshape(n_tiles, qt).max(axis=1) + 1
+    def query_spec():
+        return pl.BlockSpec(
+            (qb, H, D), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
+        )
 
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    scratch_shapes = [
+        pltpu.VMEM((2, cp, ps, Hkv * D), k_cache.dtype),
+        pltpu.VMEM((2, cp, ps, Hkv * D), v_cache.dtype),
+        pltpu.VMEM((H * qb, D), jnp.float32),
+        pltpu.VMEM((H * qb, 128), jnp.float32),
+        pltpu.VMEM((H * qb, 128), jnp.float32),
+        pltpu.SemaphoreType.DMA((2, 2 * cp)),
+    ]
+    out_shape = jax.ShapeDtypeStruct((N, H, D), q.dtype)
+    if nt == 1:
+        # Per-tile row + DMA bound, derived on device from the flat stream
+        # (alignment makes every tile single-row; padding positions are -1
+        # so the max is the tile's true causal horizon).
+        n_tiles = N // qt
+        tile_row = row_of.reshape(n_tiles, qt)[:, 0]
+        tile_kvlen = positions.reshape(n_tiles, qt).max(axis=1) + 1
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_tiles,),
+            in_specs=[query_spec(), pool_spec, pool_spec],
+            out_specs=query_spec(),
+            scratch_shapes=scratch_shapes,
+        )
+        return pl.pallas_call(
+            functools.partial(_tile_kernel, **shape),
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            interpret=interpret,
+        )(tile_row, tile_kvlen, positions, page_table, q, k_cache, v_cache)
+
+    # The last block of a stream that is no whole number of blocks reads
+    # past the queries' end: its plan and positions are padded here to
+    # -1, what it computes there is masked, and what it writes dropped.
+    plan = block_plan(row_of, positions, qt, qb)
+    n_blocks = plan[2].shape[0]
+    pos_col = jnp.pad(
+        positions, (0, n_blocks * qb - N), constant_values=-1
+    )[:, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n_tiles,),
+        num_scalar_prefetch=5,
+        grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec(
-                (qt, H, D), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
+                (qb, 1), lambda t, *_: (t, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            query_spec(),
+            pool_spec,
+            pool_spec,
         ],
-        out_specs=pl.BlockSpec(
-            (qt, H, D), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, cp, ps, Hkv * D), k_cache.dtype),
-            pltpu.VMEM((2, cp, ps, Hkv * D), v_cache.dtype),
-            pltpu.VMEM((H * qt, D), jnp.float32),
-            pltpu.VMEM((H * qt, 128), jnp.float32),
-            pltpu.VMEM((H * qt, 128), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2 * cp)),
-        ],
+        out_specs=query_spec(),
+        scratch_shapes=scratch_shapes,
     )
-    kernel = functools.partial(
-        _ragged_kernel,
-        ps=ps,
-        cp=cp,
-        hkv=Hkv,
-        hd=D,
-        qpk=qpk,
-        qt=qt,
-        pmax=pmax,
-        scale=scale,
+    vmem = _block_vmem_bytes(
+        qb, H, D, Hkv * D, cp, ps, q.dtype.itemsize, k_cache.dtype.itemsize
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_block_kernel, nt=nt, **shape),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, H, D), q.dtype),
+        out_shape=out_shape,
+        # Mosaic's default scoped VMEM is 16 MiB; 30 heads of 128
+        # queries hold more than that.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, 2 * vmem)
+        ),
         interpret=interpret,
-    )(tile_row, tile_kvlen, positions, page_table, q, k_cache, v_cache)
+    )(*plan, page_table, pos_col, q, k_cache, v_cache)
 
 
 def ragged_decode_attention(

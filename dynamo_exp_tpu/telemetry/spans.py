@@ -228,6 +228,18 @@ class Telemetry:
             ["program", "kind"],  # window | mixed | block; live | pad
             registry=self.registry,
         )
+        self.attn_query_slots = Counter(
+            "dynamo_attn_query_slots_total",
+            "Live slots of the tiled part of mixed dispatches by how the "
+            "attention kernel reads their row's context: merged (the "
+            "slot's query block belongs to one row and walks its pages "
+            "once for every tile in it) or tile (a block of several rows, "
+            "or a model whose block is one tile: the pages once a tile). "
+            "Counted by the dispatch builders with the kernel's own rule "
+            "(ops/ragged_attention.py); a kernel engine only",
+            ["pass"],  # merged | tile
+            registry=self.registry,
+        )
         self.launch_transfers = Counter(
             "dynamo_launch_transfers_total",
             "Host arrays handed to the device by ragged launches, by "

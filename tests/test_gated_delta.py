@@ -275,3 +275,34 @@ def test_kernel_compiles_for_v5e_at_the_published_widths(one_chip, slots):
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 8
     assert "gated_delta_chunk" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "heads, kv_heads, head_dim, block",
+    [(30, 30, 128, 128), (32, 8, 128, 32), (32, 4, 128, 16), (20, 1, 128, 8)],
+    ids=["olmo-hybrid", "qwen3", "sdar", "jamba"],
+)
+def test_attention_query_block_compiles_for_v5e(one_chip, heads, kv_heads, head_dim, block):
+    """Mosaic takes the attention kernel's tiled call of a 4096-slot mixed
+    dispatch at the query block its head layout gives: 128 slots at this
+    model's 30 KV heads of one query head (more VMEM than Mosaic's default
+    16 MiB: the call asks for what its shapes need), 32 and 16 at grouped
+    heads, and one tile at 20 heads a group. The call keeps its name, which
+    the trace's ``attn_kernel`` class reads."""
+    from dynamo_exp_tpu.ops.ragged_attention import query_block, ragged_paged_attention
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert query_block(8, heads // kv_heads) == block
+    n, ps, pages = 4096 - 64, 16, 2048
+    pool = sds((pages, ps, kv_heads * head_dim), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, t, r, p: ragged_paged_attention(
+            q, k, v, t, r, p, num_kv_heads=kv_heads, q_tile=8, q_block=block
+        )
+    ).lower(
+        sds((n, heads, head_dim), jnp.bfloat16), pool, pool,
+        sds((33, 1024), jnp.int32), sds((n,), jnp.int32), sds((n,), jnp.int32),
+    ).compile()
+    assert "ragged_paged_attention" in compiled.as_text()

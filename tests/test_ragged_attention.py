@@ -31,6 +31,9 @@ from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
 from dynamo_exp_tpu.models import TINY
 from dynamo_exp_tpu.ops.attention import paged_attention
 from dynamo_exp_tpu.ops.ragged_attention import (
+    block_plan,
+    merged_slot_counts,
+    query_block,
     ragged_decode_attention,
     ragged_paged_attention,
     ragged_paged_attention_ref,
@@ -242,6 +245,241 @@ def test_tp_shard_map_ragged_dispatch():
     np.testing.assert_allclose(
         np.asarray(got)[live], np.asarray(want)[live], atol=2e-5
     )
+
+
+# ------------------------------------------------------------ query blocks
+@pytest.mark.parametrize(
+    "heads, kv_heads, q_tile, want",
+    [
+        (30, 30, 8, 128),  # olmo-hybrid: one query head a KV head
+        (32, 8, 8, 32),  # qwen3-4b, lfm2-8b-a1b
+        (8, 2, 8, 32),  # a mixtral shard under tp 4
+        (32, 4, 8, 16),  # sdar's mixed program
+        (20, 1, 8, 8),  # jamba2-3b: a tile fills the rows already
+        (2, 2, 12, 132),  # a whole number of tiles, rounded up
+    ],
+)
+def test_query_block_follows_from_the_head_layout(heads, kv_heads, q_tile, want):
+    qb = query_block(q_tile, heads // kv_heads)
+    assert qb == want and qb % q_tile == 0
+
+
+# (query_len, kv_len) a row, laid out one span after another in tiles of
+# 8, then padding slots to ``_BLOCK_SLOTS`` (3.75 blocks of 128: the last
+# block is cut short; one shape for every stream, so a layout compiles
+# once); pages of 8, so a chunk is 128 tokens.
+_BLOCK_SLOTS = 480
+_BLOCK_STREAMS = {
+    # Spans that start and end inside a block, whatever the block.
+    "inside": [(24, 24), (40, 200), (16, 16)],
+    # Two rows in one block of 16 or more: it walks its tiles.
+    "two_rows": [(8, 30), (8, 90)],
+    # 37 = four tiles and five: padding queries inside a live tile, then
+    # tiles of nothing but padding to the end of the block and beyond.
+    "padded_tail": [(37, 200)],
+    # A span of many blocks whose contexts cross chunks at 128, 256, 384.
+    "long": [(300, 420)],
+    # Of everything: a fresh prompt, a short span, a late chunk.
+    "mixed": [(300, 300), (5, 40), (130, 400)],
+}
+_LAYOUTS = {"1to1": (3, 3), "4to1": (8, 2), "20to1": (20, 1)}
+
+
+def _block_stream(name, H, Hkv, D=32, seed=7):
+    spans = _BLOCK_STREAMS[name]
+    q, k, v, table, row_of, positions = _flat_batch(
+        seed, spans, H, Hkv, D, 256, 8, 64, q_tile=8
+    )
+    pad = _BLOCK_SLOTS - q.shape[0]
+    # Padding slots carry queries too (the embedding of token 0): ones,
+    # so that a leak would show.
+    q = jnp.concatenate([q, jnp.ones((pad, H, D), q.dtype)])
+    row_of = jnp.pad(row_of, (0, pad))
+    positions = jnp.pad(positions, (0, pad), constant_values=-1)
+    table = jnp.pad(table, ((0, 3 - table.shape[0]), (0, 0)))
+    return q, k, v, table, row_of, positions
+
+
+@pytest.mark.parametrize("stream", list(_BLOCK_STREAMS))
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream):
+    """A grid cell of a block of tiles (``q_block`` from the head layout:
+    128, 32 and 8 slots) against the reference at the tile kernel's
+    tolerance, against the tile kernel's own output of the same stream
+    (a query walks the same chunks in the same order either way), and
+    exact zeros at every padding slot."""
+    H, Hkv = _LAYOUTS[layout]
+    q, k, v, table, row_of, positions = _block_stream(stream, H, Hkv)
+    qb = query_block(8, H // Hkv)
+    assert qb == {"1to1": 128, "4to1": 32, "20to1": 8}[layout]
+    got = ragged_paged_attention(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
+        interpret=True, q_block=qb,
+    )
+    by_tile = ragged_paged_attention(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
+        interpret=True,
+    )
+    want = ragged_paged_attention_ref(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv
+    )
+    live = np.asarray(positions) >= 0
+    got = np.asarray(got)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=2e-5)
+    np.testing.assert_allclose(got, np.asarray(by_tile), atol=2e-6)
+    assert (got[~live] == 0.0).all()
+    # What the stream was built to meet, by the kernel's own rule.
+    merged, walked = merged_slot_counts(row_of, positions, 8, qb)
+    assert merged + walked == live.sum()
+    if qb == 8:
+        assert merged == 0
+    elif stream == "two_rows":
+        assert walked == live.sum()
+    elif stream in ("padded_tail", "long"):
+        assert merged == live.sum()
+    elif stream == "inside":
+        assert walked  # 80 slots of three rows: one block at 128
+    else:
+        assert merged and walked
+
+
+@pytest.mark.parametrize("q_block", [None, 16], ids=["tile", "block16"])
+def test_block_bounds_reach_the_block_kernel(q_block):
+    """``bounds`` (a block-diffusion model sees its whole block of 4) at
+    a cell of one tile, the block program's call, and at sdar's mixed
+    program's block of 16: the kernel reads the bounds and nothing else
+    of the positions."""
+    spans = [(8, 8), (12, 40), (4, 24)]
+    H, Hkv, D, ps, pmax = 8, 1, 32, 8, 8
+    q, k, v, table, row_of, positions = _flat_batch(
+        8, spans, H, Hkv, D, 32, ps, pmax, q_tile=4
+    )
+    bounds = jnp.where(positions >= 0, positions | 3, -1)
+    got = ragged_paged_attention(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=4,
+        interpret=True, bounds=bounds, q_block=q_block,
+    )
+    want = ragged_paged_attention_ref(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, bounds=bounds
+    )
+    causal = ragged_paged_attention_ref(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv
+    )
+    live = np.asarray(positions) >= 0
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(want)[live], atol=2e-5
+    )
+    assert np.abs(np.asarray(want) - np.asarray(causal))[live].max() > 1e-3
+
+
+def test_tp_shard_map_block_dispatch():
+    """The tp>1 path at a query block: a shard's heads a KV head are the
+    model's, so every shard takes the block the whole model would."""
+    from dynamo_exp_tpu.models.llama import _pallas_ragged
+    from dynamo_exp_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(tp=4)
+    spans = [(70, 90), (3, 11), (40, 40)]
+    H, Hkv, D, ps, pmax = 8, 4, 64, 16, 8
+    q, k, v, table, row_of, positions = _flat_batch(
+        9, spans, H, Hkv, D, 32, ps, pmax, q_tile=8
+    )
+    qb = query_block(8, H // Hkv)
+    assert qb == 64 == query_block(8, (H // 4) // (Hkv // 4))
+    got = _pallas_ragged(
+        q, k, v, table, row_of, positions, Hkv, 8, mesh, True, qb
+    )
+    want = ragged_paged_attention_ref(
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv
+    )
+    live = np.asarray(positions) >= 0
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(want)[live], atol=2e-5
+    )
+    assert (np.asarray(got)[~live] == 0.0).all()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_host_count_and_device_rule_agree_on_a_drawn_stream(seed):
+    """The engine counts merged slots on the host with ``numpy``; the
+    kernel plans its blocks inside the program with ``jax.numpy``: one
+    function, and here the same answer block for block on a drawn
+    stream of spans, padding tiles between them and rows in any order."""
+    rng = np.random.default_rng(seed)
+    row_of, positions = [], []
+    for r in rng.permutation(12):
+        n = int(rng.choice([1, 5, 8, 40, 128, 300]))
+        first = int(rng.integers(0, 500))
+        width = -(-n // 8) * 8 + 8 * int(rng.integers(0, 3))
+        row_of += [int(r)] * width
+        positions += list(range(first, first + n)) + [-1] * (width - n)
+    row_of = np.asarray(row_of, np.int32)
+    positions = np.asarray(positions, np.int32)
+    kinds = set()
+    for qb in (16, 32, 128):
+        on_host = block_plan(row_of, positions, 8, qb, xp=np)
+        on_device = jax.jit(
+            lambda r, p: block_plan(r, p, 8, qb)  # noqa: B023
+        )(jnp.asarray(row_of), jnp.asarray(positions))
+        for a, b in zip(on_host, on_device):
+            np.testing.assert_array_equal(a, np.asarray(b))
+        merged, walked = merged_slot_counts(row_of, positions, 8, qb)
+        assert merged + walked == (positions >= 0).sum()
+        # A merged block's tiles are all its row's or padding.
+        tile_row, tile_kvlen, block_row, block_kvlen = on_host
+        for b in np.flatnonzero(block_kvlen >= 0):
+            tiles = slice(b * qb // 8, (b + 1) * qb // 8)
+            rows = tile_row[tiles][tile_kvlen[tiles] > 0]
+            assert (rows == block_row[b]).all()
+            assert block_kvlen[b] == tile_kvlen[tiles].max()
+        kinds |= set(np.sign(block_kvlen))
+    assert kinds >= {-1, 1}  # blocks of one row and of several were drawn
+
+
+def test_engine_counts_every_tiled_slot_under_one_pass_label():
+    """``dynamo_attn_query_slots_total``: a kernel engine's mixed
+    builder counts the live slots it laid into the stream's tiled part,
+    each under ``merged`` or ``tile``; an engine on the reference counts
+    nothing."""
+    from dynamo_exp_tpu.telemetry import get_telemetry
+
+    def read():
+        out = {}
+        for line in get_telemetry().render().decode().splitlines():
+            if line.startswith("dynamo_attn_query_slots_total{"):
+                out[line.split('"')[1]] = float(line.rsplit(" ", 1)[1])
+        return out
+
+    for interpret in (True, False):
+        eng = _mixed_engine(
+            max_decode_slots=4, pallas_interpret=interpret, prefill_chunk=16
+        )
+        seen = []
+        count = eng._count_attn_passes
+
+        def spy(nb, row_of, positions, count=count, seen=seen, eng=eng):
+            tiled = nb - eng.cfg.ragged_packed_slots(nb)
+            seen.append(int((positions[:tiled] >= 0).sum()))
+            count(nb, row_of, positions)
+
+        eng._count_attn_passes = spy
+        before = read()
+        eng.start()
+        try:
+            prompt = list(np.random.RandomState(5).randint(3, 200, size=70))
+            toks, _ = asyncio.run(_collect(eng, prompt, 4))
+            assert len(toks) == 4
+        finally:
+            eng.stop()
+        grew = {k: v - before.get(k, 0.0) for k, v in read().items()}
+        assert seen and sum(seen) > 0
+        if interpret:
+            # TINY has 4 query heads on 2 KV heads: blocks of 64.
+            assert sum(grew.values()) == sum(seen)
+            assert set(grew) == {"merged", "tile"}
+        else:
+            assert not any(grew.values())
 
 
 # --------------------------------------------------- two-program oracle

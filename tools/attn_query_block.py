@@ -1,0 +1,195 @@
+#!/usr/bin/env python3
+"""Time the attention kernel's tiled call alone at several query blocks,
+on the chip: the table behind ``ops/ragged_attention.py::query_block``
+(PERF.md §6, PR 40).
+
+    chiprun -- python3 tools/attn_query_block.py --config olmo-hybrid-7b-l8
+    chiprun -- python3 tools/attn_query_block.py --config qwen3-4b
+
+No engine and no weights: the call ``forward_ragged`` makes for the tiled
+part of one mixed dispatch of ``--nb`` slots, with a chip's share of the
+configuration's heads (``num_attention_heads`` and ``num_key_value_heads``
+over ``--tp`` of its ``serve_args``; ``benchmark/configs/<name>.json``), its
+page size and a page pool of one layer no larger than its ``--num-pages``.
+The stream is a long-document one: ``--spans`` gives each prefilling row's
+chunk as ``tokens@first_position`` (default a 512-token chunk deep in one
+prompt and the rest of the tiles from the start of another, as
+``_plan_chunks`` hands the bucket's room to the earliest row), each span
+aligned to the tile of 8, random queries and pages. For every ``--blocks``
+entry that is a multiple of the tile: the call's time on the host's clock
+around ``block_until_ready`` (median of ``--calls``), the context bytes its
+passes read by ``block_plan``'s rule and their time at the HBM peak
+(``benchmark/peaks.json``), the share of live slots in merged blocks, and
+the largest difference from the output at a block of one tile. The packed
+tail's one-query call over ``--tail-rows`` decode rows is timed once beside
+them. One JSON line a block on stdout and in
+``chiprun_out/attn_query_block/<config>.jsonl``. It fails off the chip: a
+CPU time is not a device time (``--allow-cpu`` rehearses the control flow
+at the sizes given and writes ``<config>.cpu-rehearsal.jsonl``, never the
+chip's file).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, BENCH)
+sys.path.insert(1, ROOT)
+
+TILE = 8  # EngineConfig.ragged_q_tile
+
+
+def build_stream(spans: list[tuple[int, int]], tiled: int, ps: int, pmax: int, rng):
+    """(row_of, positions, table, pages used) of the tiled part: each
+    span ``(tokens, first position)`` a row, aligned to the tile, the
+    rest padding; every row on pages of its own."""
+    import numpy as np
+
+    row_of = np.zeros(tiled, np.int32)
+    positions = np.full(tiled, -1, np.int32)
+    table = np.zeros((len(spans), pmax), np.int32)
+    at, page = 0, 1
+    for r, (n, first) in enumerate(spans):
+        width = -(-n // TILE) * TILE
+        if at + width > tiled:
+            raise SystemExit(f"spans outgrow the {tiled} tiled slots")
+        row_of[at : at + width] = r
+        positions[at : at + n] = first + np.arange(n)
+        pages = -(-(first + n) // ps)
+        table[r, :pages] = page + rng.permutation(pages)
+        at, page = at + width, page + pages
+    return row_of, positions, table, page
+
+
+def timed(fn, calls: int) -> tuple[float, object]:
+    import jax
+
+    times, out = [], None
+    for i in range(calls + 2):  # the first call compiles, the second loads
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn())
+        if i >= 2:
+            times.append(time.perf_counter() - t0)
+    return statistics.median(times), out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--nb", type=int, default=4096, help="the mixed dispatch's token bucket")
+    ap.add_argument("--spans", default="512@6144,3520@0")
+    ap.add_argument("--tail-rows", type=int, default=30)
+    ap.add_argument("--tail-context", type=int, default=4200)
+    ap.add_argument("--blocks", default="8,32,64,128")
+    ap.add_argument("--seed", type=int, default=40)
+    ap.add_argument("--calls", type=int, default=10)
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="rehearse the control flow off the chip (times mean nothing)")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import run_cell
+    from dynamo_exp_tpu import run as server
+    from dynamo_exp_tpu.engine import EngineConfig
+    from dynamo_exp_tpu.models import ModelConfig
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        block_plan, merged_slot_counts, query_block, ragged_paged_attention,
+    )
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.allow_cpu:
+        print(f"no chip: platform={dev.platform}", file=sys.stderr)
+        return 1
+    path = args.config if args.config.endswith(".json") else os.path.join(
+        BENCH, "configs", f"{args.config}.json"
+    )
+    name = os.path.basename(path).removesuffix(".json")
+    config = run_cell.load_json(path)
+    mcfg = ModelConfig.from_hf_config(run_cell.hf_config(config))
+    opts = server.parse_args(["in=http", "out=tpu", *config["serve_args"]])
+    ecfg = EngineConfig(model=mcfg, max_decode_slots=opts.max_decode_slots, page_size=opts.page_size)
+    heads, kv_heads, hd = mcfg.num_heads // opts.tp, mcfg.num_kv_heads // opts.tp, mcfg.head_dim_
+    ps = opts.page_size
+    tiled = args.nb - ecfg.ragged_packed_slots(args.nb)
+    spans = [tuple(int(x) for x in s.split("@")) for s in args.spans.split(",")]
+    rng = np.random.default_rng(args.seed)
+    pmax = -(-max(max(first + n for n, first in spans), args.tail_context + 1) // ps)
+    row_of, positions, table, used = build_stream(spans, tiled, ps, pmax, rng)
+    tail_pages = -(-(args.tail_context + 1) // ps)
+    pool_pages = used + tail_pages
+    if pool_pages > opts.num_pages:
+        raise SystemExit(f"{pool_pages} pages wanted, the configuration's pool holds {opts.num_pages}")
+    dt = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+    q = jax.random.normal(keys[0], (tiled, heads, hd), dt)
+    k = jax.random.normal(keys[1], (pool_pages, ps, kv_heads * hd), dt)
+    v = jax.random.normal(keys[2], (pool_pages, ps, kv_heads * hd), dt)
+    interpret = dev.platform != "tpu"
+    token_bytes = 2 * kv_heads * hd * k.dtype.itemsize
+    hbm = run_cell.load_json(BENCH, "peaks.json")["devices"].get(dev.device_kind, {}).get("hbm_gbps")
+    rule = query_block(TILE, heads // kv_heads)
+    head = {
+        "config": name, "platform": dev.platform, "device_kind": dev.device_kind,
+        "heads_per_chip": [heads, kv_heads, hd], "page_size": ps, "nb": args.nb, "tiled_slots": tiled,
+        "spans": spans, "live_slots": int((positions >= 0).sum()),
+        "context_mean": float((positions[positions >= 0] + 1).mean()),
+        "kv_bytes_a_token": token_bytes, "query_block_rule": rule, "seed": args.seed, "calls": args.calls,
+    }
+    print(json.dumps(head), flush=True)
+    out_dir = os.path.join(ROOT, "chiprun_out", "attn_query_block")
+    os.makedirs(out_dir, exist_ok=True)
+    suffix = "" if dev.platform == "tpu" else ".cpu-rehearsal"
+    dev_args = (jnp.asarray(table), jnp.asarray(row_of), jnp.asarray(positions))
+    base = None
+    with open(os.path.join(out_dir, f"{name}{suffix}.jsonl"), "w") as f:
+        f.write(json.dumps(head) + "\n")
+        for qb in (int(b) for b in args.blocks.split(",")):
+            if qb % TILE:
+                continue
+            ms, out = timed(lambda: ragged_paged_attention(
+                q, k, v, *dev_args, num_kv_heads=kv_heads, q_tile=TILE, q_block=qb, interpret=interpret,
+            ), args.calls)
+            out = np.asarray(out, np.float32)
+            base = out if base is None else base
+            *_, tile_kvlen, _row, block_kvlen = block_plan(row_of, positions, TILE, qb, xp=np)
+            by_tile = tile_kvlen.reshape(block_kvlen.shape[0], -1).sum(axis=1)
+            tokens = int(np.where(block_kvlen >= 0, block_kvlen, by_tile).sum())
+            merged, walked = merged_slot_counts(row_of, positions, TILE, qb)
+            line = {
+                "q_block": qb, "is_rule": qb == rule, "call_ms": 1e3 * ms,
+                "context_gb_read": tokens * token_bytes / 1e9,
+                "hbm_bound_ms": None if hbm is None else tokens * token_bytes / hbm / 1e6,
+                "merged_slot_share": 100.0 * merged / max(merged + walked, 1),
+                "max_abs_diff_vs_first": float(np.abs(out - base).max()),
+            }
+            print(json.dumps(line), flush=True)
+            f.write(json.dumps(line) + "\n")
+        # The packed tail's call: one query a cell, each row its own context.
+        n = args.tail_rows
+        tail_table = np.tile(used + np.arange(tail_pages, dtype=np.int32), (n, 1))
+        tail_args = (
+            jnp.asarray(tail_table), jnp.arange(n, dtype=jnp.int32),
+            jnp.full((n,), args.tail_context, jnp.int32),
+        )
+        qt = jax.random.normal(keys[3], (n, heads, hd), dt)
+        ms, _ = timed(lambda: ragged_paged_attention(
+            qt, k, v, *tail_args, num_kv_heads=kv_heads, q_tile=1, interpret=interpret,
+        ), args.calls)
+        line = {"tail_rows": n, "tail_context": args.tail_context, "call_ms": 1e3 * ms}
+        print(json.dumps(line), flush=True)
+        f.write(json.dumps(line) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
