@@ -51,13 +51,18 @@ def _block_fields(nb, windowed, full_sampler, block, B1, pmax) -> list:
     row is a block of ``block`` positions with what is fixed in it
     (``fixed_at``: ``ops/sampling.py::denoise_select``) and the denoising
     passes it has had. The block program takes a block a row (``tokens``
-    [nb, block] from ``positions``, the block's start); the mixed one a
-    flat stream in which ``blk_idx`` finds each decode row's block
-    (``passes`` -1: not a decode row)."""
+    [nb, block] from ``positions``, the block's start) and, where ``prev``
+    is 1, the block before it, final but not in the pages yet, whose K/V
+    the opening pass writes: ``prev_tokens``, or with ``chained`` 1 the
+    tokens the dispatch before returned (a device argument). The mixed
+    one takes a flat stream in which ``blk_idx`` finds each decode row's
+    block (``passes`` -1: not a decode row)."""
     rows = nb if windowed else B1
     if windowed:
         named = [
             ("tokens", (nb, block), "int32"), ("positions", (nb,), "int32"),
+            ("prev_tokens", (nb, block), "int32"), ("prev", (nb,), "int32"),
+            ("chained", (1,), "int32"),
         ]
     else:
         named = [

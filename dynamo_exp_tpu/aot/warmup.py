@@ -90,10 +90,12 @@ def variant_call_args(engine, key: tuple, packed=None) -> tuple:
         packed = jax.device_put(packed, sharding)
     args = (engine.params, engine.k_cache, engine.v_cache)
     if windowed:  # no chain: the carry is not read
-        carry = np.zeros(nb, np.int32)
-        if sharding is not None:
-            carry = engine._no_carry(nb)
-        args += (carry, carry)
+        # Tokens, then positions; a block model's tokens are a block a row.
+        for block in (engine.cfg.model.block_length, 0):
+            if sharding is not None:
+                args += (engine._no_carry(nb, block),)
+            else:
+                args += (np.zeros((nb, block) if block else nb, np.int32),)
     args += (packed,)
     if full_sampler:
         args += (engine._counts,)

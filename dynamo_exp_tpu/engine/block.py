@@ -6,29 +6,42 @@ A decoding row's unit of work is a *pass* over its block of B positions:
 the block's open positions hold the mask token, a pass runs the model
 over all B against the cache of everything before the block, and the
 confidence rule (``ops/sampling.py::denoise_select``) fixes some of them.
-After at most ``denoising_steps`` passes nothing is masked, and one more
-pass over the final tokens writes the block's K/V. Every pass writes the
+After at most ``denoising_steps`` passes nothing is masked: the block's
+tokens are emitted at once (``max_tokens`` and a stop token cut the last
+block on emission) and the row opens its next. Every pass writes the
 block's K/V into the row's pages before attention reads them (as a prompt
-chunk's are), so only the last pass's stay: a block in flight keeps no
-state on the device. Then the block's tokens are emitted at once;
-``max_tokens`` and a stop token cut the last block on emission.
+chunk's are), so a block in flight keeps no state on the device; but what
+the last denoising pass wrote came of an input that still held mask
+tokens. The final tokens' K/V are written by the FIRST pass of the block
+after it, in the same forward: a query sees kv positions up to the end
+of its own block, so a stream of the row's previous block (final tokens)
+and then its new block (first pass) gives the one exactly the K/V a pass
+of its own would write and the other exactly the logits it would read
+after such a pass. Until then the row's newest block is *unwritten*
+(``Sequence.unwritten``): the prefix index, offload and reclaim stop
+short of it, and a row that finishes never has its last block written,
+since nothing reads it.
 
 What rides which program:
 
 - **block** (``_block_program``; the ``windowed`` variants of a block
   model's lattice, so ``aot/`` and the benchmark's warm-up build them
-  unchanged): a pure-decode dispatch. Every row's whole block, its passes
-  chained on the device in one ``while_loop`` (at most
-  ``denoising_steps + 1``), rows tiled ``B`` slots a tile, the routed
-  experts reading their weights in place at any row count. The next
-  block's inputs are known on the host (its start, all mask), so block
-  N+1 is launched before the host syncs on block N
+  unchanged): a pure-decode dispatch. Every row's whole block, rows tiled
+  ``B`` slots a tile, the routed experts reading their weights in place
+  at any row count: an opening pass of two tiles a row (the unwritten
+  block, where there is one, and this block's first pass), then the
+  other passes chained on the device in one ``while_loop`` until nothing
+  is masked (at most ``denoising_steps`` in all). The next block's inputs
+  are known on the host (its start, all mask) but for the tokens of the
+  block in flight, which stay on the device: block N+1 is launched before
+  the host syncs on block N, with N's returned tokens as its carry
   (``_dispatch_chained_block``).
 - **mixed** (``_mixed_block_program``): prompt chunks beside decoding rows,
-  each of which rides as a span of B tokens making ONE pass (denoising or
-  writing), so prefill does not stall decode; the host keeps the block's
-  state (``Sequence.blk_*``) between dispatches and the next dispatch of
-  either kind goes on from it.
+  each of which rides as a span of B tokens making ONE pass, so prefill
+  does not stall decode: a row with an unwritten block a pass that only
+  writes it (the one pass left that fixes no token), any other row a
+  denoising pass. The host keeps the block's state (``Sequence.blk_*``)
+  between dispatches and the next dispatch of either kind goes on from it.
 
 A prompt of P tokens is prefilled up to its last block boundary
 (``Sequence.prefill_target``); the ``P mod B`` tokens left open the first
@@ -102,21 +115,37 @@ class BlockDecode:
     # ---------------------------------------------------------------- programs
     def _block_program(self, nb, pages, impl, full_sampler, want_lp):
         """The pure-decode variant of a block model: ``nb`` rows, a block
-        each, every pass of every block in one ``while_loop``. Takes and
-        returns what ``_windowed_program`` does (the carry it ignores and
-        the counts it leaves alone included), so ``aot/warmup.py``'s and
-        ``aot/compile.py``'s calls fit both."""
+        each, every denoising pass of every block in one program. The
+        opening pass runs ``2B`` slots a row: first the block before
+        this one where that is final and not in the pages yet (``prev``;
+        padding elsewhere), then this block's first pass. A pass writes
+        its slots' K/V before attention reads them and a query sees
+        nothing past its own block, so the earlier block's K/V are what a
+        pass of its own would write and this block's logits what they
+        would be after one. A ``while_loop`` makes the other passes at
+        ``B`` slots a row and ends when nothing is masked: the block it
+        returns is final and its K/V are the next dispatch's to write.
+
+        Takes and returns what ``_windowed_program`` does (the counts it
+        leaves alone included), so ``aot/warmup.py``'s and
+        ``aot/compile.py``'s calls fit both; of the carry it reads the
+        tokens ([nb, B]: the blocks the dispatch before returned, where
+        ``chained``)."""
         interpret, mesh = self._attn_interpret, self.mesh
         mcfg = self.cfg.model
         B, T = mcfg.block_length, mcfg.denoising_steps
         layout = launch_layout(
             self.cfg, (nb, pages, True, full_sampler, want_lp, False)
         )
-        row_of = jnp.repeat(jnp.arange(nb, dtype=jnp.int32), B)
-        every = jnp.arange(nb * B, dtype=jnp.int32)
+        rows = jnp.arange(nb, dtype=jnp.int32)
         offsets = jnp.arange(B, dtype=jnp.int32)[None, :]
+        # A pass's stream and the slots that reach the head: a block a
+        # row, or in the opening pass two, the second of which does.
+        row_of, every = jnp.repeat(rows, B), jnp.arange(nb * B, dtype=jnp.int32)
+        row_of2 = jnp.repeat(rows, 2 * B)
+        own = (rows[:, None] * (2 * B) + B + offsets).reshape(-1)
 
-        def run(params, k, v, packed):
+        def run(params, k, v, carry_tokens, packed):
             f = unpack_launch(layout, packed)
             start, table = f["positions"], f["table"]
             live = start >= 0
@@ -127,50 +156,51 @@ class BlockDecode:
                     f["top_k"], f["top_p"],
                 )
 
-            def body(c):
-                i, tokens, fixed_at, passes, written, n_denoise, k, v, lps = c
-                todo = live & ~written
+            def masked(fixed_at):
+                return live & jnp.any(fixed_at == UNFIXED, axis=1)
+
+            def denoise(tokens, fixed_at, passes, k, v, lps, before=None):
+                """One denoising pass of the rows with a masked position;
+                ``before``: (tokens, positions) [nb, B] of the tile that
+                precedes each row's block in the stream."""
+                todo = masked(fixed_at)
                 pos = jnp.where(todo[:, None], start[:, None] + offsets, -1)
                 fed = jnp.where(fixed_at != UNFIXED, tokens, mcfg.mask_token_id)
+                of, out = row_of, every
+                if before is not None:
+                    fed = jnp.concatenate([before[0], fed], axis=1)
+                    pos = jnp.concatenate([before[1], pos], axis=1)
+                    of, out = row_of2, own
                 hidden, k, v = forward_ragged(
-                    params, mcfg, fed.reshape(-1), pos.reshape(-1), row_of,
-                    table, k, v, every, attn_pages=pages, attn_impl=impl,
+                    params, mcfg, fed.reshape(-1), pos.reshape(-1), of,
+                    table, k, v, out, attn_pages=pages, attn_impl=impl,
                     q_tile=B, mesh=mesh, interpret=interpret,
                     experts_any_rows=True, project=False,
                 )
-                # A row with a masked position denoises; one with none is
-                # written by this very pass (its K/V are the final tokens').
-                denoise = todo & jnp.any(fixed_at == UNFIXED, axis=1)
-
-                def select():
-                    logits = final_logits(params, mcfg, hidden)
-                    return block_pass(
-                        logits.reshape(nb, B, -1), tokens, fixed_at, passes,
-                        denoise, mcfg, sampler, lps,
-                    )
-
-                # The pass that only writes needs no logits: with rows in
-                # step (random weights) a fifth of the passes skip the head.
-                tokens, fixed_at, lps = jax.lax.cond(
-                    jnp.any(denoise), select, lambda: (tokens, fixed_at, lps)
+                logits = final_logits(params, mcfg, hidden)
+                tokens, fixed_at, lps = block_pass(
+                    logits.reshape(nb, B, -1), tokens, fixed_at, passes,
+                    todo, mcfg, sampler, lps,
                 )
-                step = denoise.astype(jnp.int32)
-                return (
-                    i + 1, tokens, fixed_at, passes + step,
-                    written | (todo & ~denoise), n_denoise + step, k, v, lps,
-                )
+                return tokens, fixed_at, passes + todo.astype(jnp.int32), k, v, lps
 
-            def cond(c):
-                return (c[0] <= T) & jnp.any(live & ~c[4])
-
-            zeros = jnp.zeros(nb, jnp.int32)
-            c = jax.lax.while_loop(cond, body, (
-                jnp.int32(0), f["tokens"], f["fixed_at"],
-                jnp.maximum(f["passes"], 0), ~live, zeros, k, v,
-                _no_lps(nb, B) if want_lp else None,
-            ))
-            _i, tokens, fixed_at, _passes, written, n_denoise, k, v, lps = c
-            ys = (tokens, fixed_at, n_denoise, (written & live).astype(jnp.int32))
+            writes = live & (f["prev"] != 0)
+            before = (
+                jnp.where(f["chained"][0] != 0, carry_tokens, f["prev_tokens"]),
+                jnp.where(writes[:, None], start[:, None] - B + offsets, -1),
+            )
+            had = jnp.maximum(f["passes"], 0)
+            opened = denoise(
+                f["tokens"], f["fixed_at"], had, k, v,
+                _no_lps(nb, B) if want_lp else None, before,
+            )
+            c = jax.lax.while_loop(
+                lambda c: (c[0] < T) & jnp.any(masked(c[2])),
+                lambda c: (c[0] + 1, *denoise(*c[1:])),
+                (jnp.int32(1), *opened),
+            )
+            _i, tokens, fixed_at, passes, k, v, lps = c
+            ys = (tokens, fixed_at, passes - had, writes.astype(jnp.int32))
             return (ys + lps if want_lp else ys), k, v
 
         if full_sampler:
@@ -178,7 +208,7 @@ class BlockDecode:
             @partial(jax.jit, donate_argnums=(1, 2, 6))
             def ragged_block(params, k, v, carry_tokens, carry_positions,
                              packed, counts_all):
-                ys, k, v = run(params, k, v, packed)
+                ys, k, v = run(params, k, v, carry_tokens, packed)
                 return ys, k, v, counts_all, carry_tokens, carry_positions
 
         else:
@@ -186,7 +216,7 @@ class BlockDecode:
             @partial(jax.jit, donate_argnums=(1, 2))
             def ragged_block(params, k, v, carry_tokens, carry_positions,
                              packed):
-                ys, k, v = run(params, k, v, packed)
+                ys, k, v = run(params, k, v, carry_tokens, packed)
                 return ys, k, v, carry_tokens, carry_positions
 
         return ragged_block
@@ -195,7 +225,8 @@ class BlockDecode:
         """The mixed variant of a block model: one ragged forward over
         prompt chunks (which sample nothing) and decoding rows' blocks,
         ONE pass each: the confidence rule on the rows that have masked
-        positions, and the others' K/V written for good."""
+        positions, and the others' K/V written for good (a row's closed
+        block that no opening pass of ``_block_program`` has written)."""
         interpret, mesh = self._attn_interpret, self.mesh
         mcfg = self.cfg.model
         B = mcfg.block_length
@@ -298,10 +329,13 @@ class BlockDecode:
     def _build_block(self, part, full_sampler: bool, solo: bool, chained=None):
         """Build + dispatch one pure-decode batch of a block model (no
         host sync): every row's block from where the host has it through
-        its last pass. ``part``: (row, seq, block start) a row; start -1
-        sits the dispatch out. With ``chained`` (the pending dispatch
-        before it) the rows are that one's and each block is the one
-        after the block in flight."""
+        its last denoising pass, and the write of the block before it
+        where that one is not in the pages. ``part``: (row, seq, block
+        start) a row; start -1 sits the dispatch out. With ``chained``
+        (the pending dispatch before it) the rows are that one's, each
+        block is the one after the block in flight, and the block to
+        write is the one in flight: its tokens are what ``chained``
+        returns, on the device."""
         from .engine import _PendingRagged, _RaggedRow
 
         with self._phase("assemble"), self._part("assemble", "build"):
@@ -317,14 +351,19 @@ class BlockDecode:
             max_pages = 1
             for r, seq, start in part:
                 f["positions"][r] = start
+                f["prev"][r] = 0
                 if start >= 0:
                     if chained:  # a fresh block: nothing fixed, no pass made
                         f["fixed_at"][r] = UNFIXED
                         f["passes"][r] = 0
                         f["table"][r, : len(seq.page_ids)] = seq.page_ids
+                        f["prev"][r] = 1  # the block in flight, on the device
                     else:
                         f["tokens"][r] = seq.blk_tokens
                         self._fill_block_row(seq, r, f, sampler)
+                        if seq.unwritten:
+                            f["prev"][r] = 1
+                            f["prev_tokens"][r] = seq.tokens[start - B : start]
                     max_pages = max(max_pages, (start + B + ps - 1) // ps)
                 rows.append(
                     _RaggedRow(
@@ -332,6 +371,7 @@ class BlockDecode:
                         start=start,
                     )
                 )
+            f["chained"][0] = chained is not None
             want_lp = chained.want_lp if chained else any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
@@ -340,7 +380,11 @@ class BlockDecode:
             page_bucket = cfg.ragged_page_bucket_for(max_pages)
             fn = self._ragged_fn(nb, page_bucket, True, full_sampler, want_lp)  # dynlint: recompile-hazard(a chained block reuses the dispatched bucket)
             fresh = len(self._ragged_fns) > n_variants
-        carry = (self._no_carry(nb), self._no_carry(nb))  # the window's contract
+        # The window's contract; a block model reads the tokens alone.
+        carry = (
+            chained.ys[0] if chained else self._no_carry(nb, B),
+            self._no_carry(nb),
+        )
         ys, dispatched_at = self._launch(
             fn, "block", packed, full_sampler, fresh, carry
         )
@@ -364,7 +408,8 @@ class BlockDecode:
     def _dispatch_chained_block(self, pending):
         """Dispatch block N+1 over block N's rows before the host syncs on
         N: a fresh block's inputs are its start and the mask token, both
-        known here, and the cache it reads is on the device. A row whose
+        known here; the cache it reads and block N's final tokens, whose
+        K/V its opening pass writes, are on the device. A row whose
         budget or model length block N exhausts sits the dispatch out
         (start -1); a stop token inside block N is found at its consume,
         and the row's block N+1 is then computed for nothing. None (chain
@@ -448,13 +493,21 @@ class BlockDecode:
                 r += 1
             mask = cfg.model.mask_token_id
             for seq, start, _cap in dec:
-                fed = [
-                    t if at_pass != UNFIXED else mask
-                    for t, at_pass in zip(seq.blk_tokens, seq.blk_fixed_at)
-                ]
-                qs = add_span(fed, start, r)
+                if seq.unwritten:
+                    # The block before first: a pass that only writes it.
+                    start -= B
+                    qs = add_span(seq.tokens[start : start + B], start, r)
+                    f["fixed_at"][r] = CONTEXT
+                    f["passes"][r] = 0
+                    f["table"][r, : len(seq.page_ids)] = seq.page_ids
+                else:
+                    fed = [
+                        t if at_pass != UNFIXED else mask
+                        for t, at_pass in zip(seq.blk_tokens, seq.blk_fixed_at)
+                    ]
+                    qs = add_span(fed, start, r)
+                    self._fill_block_row(seq, r, f, sampler)
                 f["blk_idx"][r] = qs + np.arange(B)
-                self._fill_block_row(seq, r, f, sampler)
                 max_pages = max(max_pages, (start + B + ps - 1) // ps)
                 rows.append(_RaggedRow(seq, "decode", r, n_valid=B, start=start))
                 r += 1
@@ -489,12 +542,15 @@ class BlockDecode:
     def _consume_block(self, pending) -> None:
         """Host sync of one dispatch of a block model, of either program:
         each decoding row's block takes what its pass(es) fixed; a block
-        that was written is emitted whole (cut by ``check_stop``) and the
-        row opens its next; a prompt whose last chunk this was joins
+        with nothing masked left is emitted whole (cut by ``check_stop``)
+        and the row opens its next, remembering that this one's K/V are
+        not in the pages (``Sequence.unwritten``) until a later dispatch
+        says it wrote them; a prompt whose last chunk this was joins
         decode. A stop found while a chained successor is in flight
         defers the finish until that one is consumed, as
         ``_consume_windowed`` does."""
         program = "block" if pending.windowed else "mixed"
+        wrote_by = "next_first_pass" if pending.windowed else "own_pass"
         self._flush_outbox()
         with self._phase("sync"):
             ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
@@ -506,7 +562,7 @@ class BlockDecode:
         B = self.cfg.model.block_length
         tel = get_telemetry()
         finishes: list[Sequence] = []
-        emitted = wasted = passes_denoise = passes_write = 0
+        emitted = wasted = passes_denoise = written = 0
         with self._phase("consume"):
             clock = time.monotonic
             tokens_s = pages_s = emit_s = first_s = 0.0
@@ -523,7 +579,10 @@ class BlockDecode:
                     continue  # sat a chained dispatch out
                 d, w = int(n_denoise[r]), int(wrote[r])
                 passes_denoise += d
-                passes_write += w
+                written += w
+                if w and seq.unwritten:
+                    seq.unwritten = 0  # the block before is in the pages
+                    tel.diffusion_block_writes.labels(wrote_by).inc()
                 if (
                     seq.state is not SeqState.ACTIVE
                     or seq.pending_finish is not None
@@ -531,7 +590,12 @@ class BlockDecode:
                     wasted += (d + w) * B  # past this row's stop
                     continue
                 t0 = clock()
-                assert self._block_of(seq) == e.start, (seq.request_id, e.start)
+                start = self._block_of(seq)
+                if e.start < start:  # the mixed program's pass wrote the block before
+                    self.sched.register_full_pages(seq)
+                    pages_s += clock() - t0
+                    continue
+                assert start == e.start, (seq.request_id, e.start)
                 n_top = self._wants_logprobs(seq)
                 fixed = 0
                 for j in range(B):
@@ -551,10 +615,10 @@ class BlockDecode:
                         )
                 seq.blk_passes += d
                 tel.diffusion_tokens_fixed.inc(fixed)
-                if not w:
+                if UNFIXED in seq.blk_fixed_at:
                     tokens_s += clock() - t0
                     continue
-                # Written: the block is final. Emit what is not context.
+                # Nothing masked: the block is final. Emit what is not context.
                 kept, at, reason = [], [], None
                 for j in range(B):
                     if seq.blk_fixed_at[j] == CONTEXT:
@@ -578,6 +642,7 @@ class BlockDecode:
                         [seq.blk_fixed_at[j] for j in at],
                     )
                 seq.close_block()
+                seq.unwritten = len(seq.tokens) - start
                 tel.diffusion_blocks.inc()
                 t1 = clock()
                 self.sched.register_full_pages(seq)
@@ -597,14 +662,18 @@ class BlockDecode:
                     seq.pending_finish = reason
                     finishes.append(seq)
             tel.diffusion_passes.labels("denoise").inc(passes_denoise)
-            tel.diffusion_passes.labels("write").inc(passes_write)
             if pending.windowed:
                 # The program's slots, known now that its passes are: every
-                # iteration of its loop carried the whole bucket.
-                loops = int((n_denoise + wrote).max(initial=0))
-                live = (passes_denoise + passes_write) * B
-                self._count_slots("block", live, pending.nb * B * loops - live)
+                # pass carried the whole bucket, the opening one twice
+                # (the blocks before, written as they rode it).
+                loops = int(n_denoise.max(initial=0))
+                live = (passes_denoise + written) * B
+                self._count_slots(
+                    "block", live, pending.nb * B * (loops + 1) - live
+                )
                 self.steps += loops
+            else:
+                tel.diffusion_passes.labels("write").inc(written)
             self.flight.record(
                 "consume", dispatch="ragged", tokens=emitted, wasted=wasted
             )

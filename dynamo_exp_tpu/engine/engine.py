@@ -484,7 +484,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # Where a launch's packed buffer goes (``_put_launch``): every
         # chip of the mesh holds the whole of it.
         self._launch_sharding = sharding(P())
-        self._no_carries: dict[int, jax.Array] = {}
+        self._no_carries: dict[tuple, jax.Array] = {}
         # Host-side speculation state (drafter + per-row adaptive
         # controller); None = speculation off.
         self._spec = None
@@ -1193,14 +1193,16 @@ class TPUEngine(BlockDecode, AsyncEngine):
         get_telemetry().launch_transfers.labels(program).inc(len(host))
         return tuple(jax.device_put(host, self._launch_sharding))
 
-    def _no_carry(self, nb: int) -> jax.Array:
+    def _no_carry(self, nb: int, block: int = 0) -> jax.Array:
         """What a window that is not chained passes for the carry of
         tokens and of positions: zeros the program does not read
-        (``chained`` is 0), on the device since the first such window."""
-        carry = self._no_carries.get(nb)
+        (``chained`` is 0), on the device since the first such window.
+        ``block``: a block model's tokens, a block a row."""
+        shape = (nb, block) if block else (nb,)
+        carry = self._no_carries.get(shape)
         if carry is None:
-            carry = self._no_carries[nb] = jax.device_put(
-                np.zeros(nb, np.int32), self._launch_sharding
+            carry = self._no_carries[shape] = jax.device_put(
+                np.zeros(shape, np.int32), self._launch_sharding
             )
         return carry
 
@@ -1806,10 +1808,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 or seq.extract_cb is not None
             ):
                 continue
-            # Only positions up to pos-1 have KV written (the newest
-            # sampled token's KV lands next step) — same bound as
-            # Scheduler.register_full_pages.
-            full = min(max(0, (seq.pos - 1) // ps), len(seq.page_ids))
+            # The same bound as Scheduler.register_full_pages.
+            full = min(seq.written // ps, len(seq.page_ids))
             out.append(
                 {
                     "request_id": seq.request_id,
@@ -1840,7 +1840,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         if seq is None:
             return None
         ps = self.cfg.page_size
-        full = min(max(0, (seq.pos - 1) // ps), len(seq.page_ids))
+        full = min(seq.written // ps, len(seq.page_ids))
         if full <= 0:
             return None
         pids = seq.page_ids[:full]
@@ -2748,7 +2748,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         victims this pass."""
         entries, off_pids, off_keys, park_pids, drop_pids = plan_swap_entries(
             victim.page_ids,
-            victim.tokens,
+            victim.tokens[: len(victim.tokens) - victim.unwritten],
             self.cfg.page_size,
             self.kv.page_ref,
             self.kv.page_hash,

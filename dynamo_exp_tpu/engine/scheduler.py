@@ -124,6 +124,11 @@ class Sequence:
     blk_fixed_at: "list[int] | None" = None
     blk_lp: "list | None" = None
     blk_passes: int = 0
+    # The newest tokens of ``tokens`` whose K/V are not in the pages: a
+    # block-diffusion model's last emitted block (B) until the next
+    # dispatch writes it, which it does before it reads it. Whatever
+    # trusts the pages (the prefix index, offload) stops short of them.
+    unwritten: int = 0
     # G2→G1 injections the engine must dispatch before this prefill:
     # (page_id, seq_hash, k_page, v_page) per page (see kv_manager).
     pending_uploads: list = field(default_factory=list)
@@ -217,6 +222,13 @@ class Sequence:
     def pos(self) -> int:
         """Next token position to be confirmed-written."""
         return len(self.tokens)
+
+    @property
+    def written(self) -> int:
+        """Positions whose K/V the row's pages hold: all but the newest
+        token's (it lands on the next step) and a block model's newest
+        block where no dispatch has written it (``unwritten``)."""
+        return max(0, len(self.tokens) - self.unwritten - 1)
 
     def last_token(self) -> int:
         return self.tokens[-1]
@@ -527,10 +539,10 @@ class Scheduler:
     def register_full_pages(self, seq: Sequence) -> None:
         """Register every newly completed page for reuse + router events.
 
-        Only positions up to ``pos - 1`` have KV written (the newest
-        sampled token's KV lands on the next step), hence the -1."""
+        Only the pages below the row's written frontier
+        (``Sequence.written``) are whole."""
         ps = self.kv.page_size
-        full = (seq.pos - 1) // ps
+        full = seq.written // ps
         while seq.hashed_pages < full:
             i = seq.hashed_pages
             block = seq.tokens[i * ps : (i + 1) * ps]
@@ -593,6 +605,7 @@ class Scheduler:
         if self.on_finish is not None:
             self.on_finish(seq, reason, now, was_bound)
         seq.state = SeqState.FINISHED
+        self._drop_unwritten(seq)
         if seq.slot >= 0 and was_bound:
             self.slots[seq.slot] = None
             self.active_count -= 1
@@ -604,6 +617,14 @@ class Scheduler:
         self.kv.abort_fills(seq.request_id, seq.page_ids)
         self.kv.release_sequence(seq.page_ids)
         seq.emit([], reason)
+
+    @staticmethod
+    def _drop_unwritten(seq: Sequence) -> None:
+        """A row leaves its pages (finished, or preempted: it comes back
+        prefilling) with a block no dispatch has written: none will."""
+        if seq.unwritten:
+            get_telemetry().diffusion_block_writes.labels("never").inc()
+            seq.unwritten = 0
 
     def _release_state(self, seq: Sequence) -> None:
         self.kv.release_state_slot(seq.state_slot)
@@ -692,6 +713,7 @@ class Scheduler:
         seq.stalled_since = 0.0
         seq.pending_finish = None
         seq.close_block()
+        self._drop_unwritten(seq)
         seq.pending_uploads = []
         seq.wait_fill = []
         seq.shared_tail_pid = -1

@@ -303,14 +303,27 @@ class Telemetry:
         self.diffusion_blocks = Counter(
             "dynamo_diffusion_blocks_total",
             "Blocks a block-diffusion model finished: every position "
-            "fixed and the block's K/V written",
+            "fixed and the block's tokens emitted",
+            registry=self.registry,
+        )
+        self.diffusion_block_writes = Counter(
+            "dynamo_diffusion_block_writes_total",
+            "Finished blocks by what put their final K/V into the pages: "
+            "own_pass (a pass that only writes: the mixed program's), "
+            "next_first_pass (they rode the opening pass of the block "
+            "program's next dispatch over the row) or never (the row "
+            "left its pages first: nothing reads them). Each block once, "
+            "and the three add up to dynamo_diffusion_blocks_total",
+            ["by"],  # own_pass | next_first_pass | never
             registry=self.registry,
         )
         self.diffusion_passes = Counter(
             "dynamo_diffusion_passes_total",
             "Passes over a row's block by kind: denoise (the confidence "
-            "rule fixes masked positions) or write (nothing masked: the "
-            "pass leaves the block's final K/V). denoise / blocks is the "
+            "rule fixes masked positions; the block program's opening "
+            "pass, which also writes the block before, is one) or write "
+            "(a pass of its own that fixes nothing and leaves a block's "
+            "final K/V: the mixed program's). denoise / blocks is the "
             "passes a block took; write / all the share that fixes nothing",
             ["kind"],  # denoise | write
             registry=self.registry,
