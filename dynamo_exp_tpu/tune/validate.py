@@ -7,12 +7,14 @@ test, fronted by the real AdmissionController) — and the live ranking
 must agree with the sim ranking (Kendall tau + top-1). A candidate
 that only wins in the model is a modeling artifact, not a tuning.
 
-This module necessarily reads wall clocks (it measures a real engine);
-the reads are inline-waived for the determinism zone the rest of
-``tune/`` lives in.
+This module necessarily reads the wall clock (it measures a real engine):
+``_wall_clock``, the one read, inline-waived for the determinism zone the
+rest of ``tune/`` lives in.
 """
 
 from __future__ import annotations
+
+import time
 
 from .search import SearchSettings, TuneTarget, evaluate
 from . import space
@@ -38,6 +40,10 @@ def kendall_tau(a: list[float], b: list[float]) -> float:
                 disc += 1
     total = n * (n - 1) / 2
     return round((conc - disc) / total, 4)
+
+
+def _wall_clock() -> float:
+    return time.monotonic()  # dynlint: determinism(live validation wall-clock measurement)
 
 
 def harness_workload(
@@ -80,6 +86,7 @@ async def measure_live(
     harness: dict,
     slo_ttft_s: float = 30.0,
     slo_itl_s: float = 2.0,
+    clock=None,
 ) -> dict:
     """Run one candidate's live-applicable engine knobs on a tiny real
     engine against the shared workload; score with the same composite
@@ -93,9 +100,10 @@ async def measure_live(
     2s/0.2s constants): the tiny engine runs on whatever host CI
     provides, and production-scale gates would make the compliance
     fractions encode host speed rather than config quality — the
-    ranking, not the absolute score, is the validated signal."""
+    ranking, not the absolute score, is the validated signal.
+    ``clock``: what the durations are read from, the wall clock unless
+    a test passes its own."""
     import asyncio
-    import time
 
     from ..engine import EngineConfig, TPUEngine
     from ..http.admission import AdmissionController, RequestShedError
@@ -103,6 +111,7 @@ async def measure_live(
     from ..parallel import single_device_mesh
     from ..protocols.common import BackendInput, SamplingOptions
 
+    clock = clock or _wall_clock
     kwargs = dict(harness)
     kwargs.update(space.engine_kwargs_from_overrides(overrides))
     cfg = EngineConfig(
@@ -139,12 +148,12 @@ async def measure_live(
                 bi.sampling_options = SamplingOptions(
                     temperature=0.9, seed=1000 + req.index
                 )
-                start = time.monotonic()  # dynlint: determinism(live validation wall-clock measurement)
+                start = clock()
                 first = last = None
                 tokens = 0
                 stream = await engine.generate(bi.to_dict())
                 async for item in stream:
-                    now = time.monotonic()  # dynlint: determinism(live validation wall-clock measurement)
+                    now = clock()
                     if item.get("token_ids"):
                         tokens += len(item["token_ids"])
                         if first is None:
@@ -176,9 +185,9 @@ async def measure_live(
             *[one(r, admit=False, record=False) for r in workload]
         )
 
-        t0 = time.monotonic()  # dynlint: determinism(live validation wall-clock measurement)
+        t0 = clock()
         await asyncio.gather(*[one(r) for r in workload])
-        duration = max(time.monotonic() - t0, 1e-6)  # dynlint: determinism(live validation wall-clock measurement)
+        duration = max(clock() - t0, 1e-6)
     finally:
         engine.stop()
 
@@ -207,6 +216,7 @@ async def validate_candidates(
     n: int = 10,
     slo_ttft_s: float = 30.0,
     slo_itl_s: float = 2.0,
+    clock=None,
 ) -> dict:
     """Rank the candidates in the sim AND on the live tiny harness over
     one shared clamped workload; report both rankings plus Kendall tau
@@ -215,7 +225,8 @@ async def validate_candidates(
     ``slo_ttft_s``/``slo_itl_s`` gate the live composite (harness-scale
     defaults; pass large values to rank on goodput alone — a cold-start
     compile stall on a slow host can blow a single inter-token gap past
-    any fixed gate and flip a ranking the throughput still decides)."""
+    any fixed gate and flip a ranking the throughput still decides).
+    ``clock`` is ``measure_live``'s."""
     harness = harness or {
         "max_decode_slots": 4,
         "page_size": 8,
@@ -253,6 +264,7 @@ async def validate_candidates(
             harness,
             slo_ttft_s=slo_ttft_s,
             slo_itl_s=slo_itl_s,
+            clock=clock,
         )
         sim_scores.append(sim_comp["score"])
         live_scores.append(live_comp["score"])

@@ -182,25 +182,38 @@ def _stall_seq(rid: str, prompt: list[int], max_tokens: int) -> Sequence:
     )
 
 
-async def _warmup(engine: TPUEngine, seed: int) -> None:
+def _warmup(engine: TPUEngine, seed: int) -> None:
     """Compile every variant the stall phase touches (prefill rows 1+2,
     decode rows 1+2 at the small page buckets) so no multi-second
-    compile pauses the loop once the watchdog is armed."""
+    compile pauses the loop once the watchdog is armed. Takes the engine
+    stopped and leaves it stopped. Each round's sequences are in the
+    submit queue before the loop starts, as the stall phase's are: its
+    first pass admits them together, so the two rows of the first round
+    share their dispatches in every run. (Submitted to a running loop
+    they shared one or took two by an OS race, and the stall phase's
+    admission records name the dispatch that wrote the pages it reuses.)"""
+    import threading
+
     import numpy as np
 
     rs = np.random.RandomState(seed)
-
-    async def one(prompt, toks):
-        b = BackendInput(token_ids=[int(t) for t in prompt])
-        b.stop_conditions.max_tokens = toks
-        b.stop_conditions.ignore_eos = True
-        stream = await engine.generate(b.to_dict())
-        async for _ in stream:
-            pass
-
-    prompts = [rs.randint(10, TINY.vocab_size - 10, size=16) for _ in range(2)]
-    await asyncio.gather(*[one(p, 8) for p in prompts])  # rows-2 shapes
-    await one(rs.randint(10, TINY.vocab_size - 10, size=16), 8)  # rows-1
+    for n_rows in (2, 1):
+        finished = []
+        for i in range(n_rows):
+            prompt = [int(t) for t in rs.randint(10, TINY.vocab_size - 10, size=16)]
+            seq = _stall_seq(f"warm-{n_rows}-{i}", prompt, max_tokens=8)
+            done = threading.Event()
+            seq.emit = lambda _toks, reason, _lp=None, done=done: (
+                done.set() if reason is not None else None
+            )
+            finished.append(done)
+            engine._submit_q.put(seq)
+        engine.start()
+        try:
+            for done in finished:
+                assert done.wait(timeout=120), "warm-up request never finished"
+        finally:
+            engine.stop()
 
 
 def _run_stall_once(tmp_path, seed: int, tag: str) -> tuple[list, dict, str]:
@@ -212,11 +225,9 @@ def _run_stall_once(tmp_path, seed: int, tag: str) -> tuple[list, dict, str]:
     engine = TPUEngine(
         _stall_cfg(dump_path), mesh=single_device_mesh(), seed=0
     )
-    engine.start()
-    asyncio.run(_warmup(engine, seed))
+    _warmup(engine, seed)
     # Re-arm: pre-queue the stall workload while the loop is down, so
     # the first iteration drains and admits it deterministically.
-    engine.stop()
     engine.flight.clear()
     engine.cfg.watchdog_stall_s = 1.5
     rs = np.random.RandomState(seed)
@@ -291,9 +302,7 @@ def test_no_false_positive_under_slow_but_progressing_workload(tmp_path):
         mesh=single_device_mesh(),
         seed=0,
     )
-    engine.start()
-    asyncio.run(_warmup(engine, 3))
-    engine.stop()
+    _warmup(engine, 3)
     engine.cfg.watchdog_stall_s = 0.6
     engine.start()
     try:
