@@ -40,8 +40,10 @@ _BUCKET_SUFFIX = "bucket_for"
 @dataclass(frozen=True)
 class VariantSiteManifest:
     path: str
-    # callee name (Name or self.<name>) -> shape-carrying arg positions
-    sites: dict[str, tuple[int, ...]]
+    # callee name (Name or self.<name>) -> shape-carrying arg positions,
+    # and the names of its shape-carrying keywords where it says which
+    # they are (``_launch``: the bucket among a dozen that are no key)
+    sites: dict[str, tuple[int | str, ...]]
 
 
 def _callee_name(func: ast.AST) -> str | None:
@@ -140,7 +142,7 @@ class RecompileHazardChecker:
     def check(
         self, rel_path: str, tree: ast.Module, source: str
     ) -> list[Finding]:
-        sites: dict[str, tuple[int, ...]] = {}
+        sites: dict[str, tuple[int | str, ...]] = {}
         for m in self.manifests:
             if m.path == rel_path:
                 sites.update(m.sites)
@@ -158,17 +160,19 @@ class RecompileHazardChecker:
                 # Don't flag the builder's own recursive mentions (the
                 # def itself is matched by name, not the call).
                 suspect: list[tuple[str, ast.AST]] = []
+                named = {p for p in sites[callee] if isinstance(p, str)}
                 for pos in sites[callee]:
-                    if pos < len(node.args):
+                    if isinstance(pos, int) and pos < len(node.args):
                         suspect.append((f"arg {pos}", node.args[pos]))
                 # Keyword spellings can't be mapped to key positions
-                # without the signature, so EVERY keyword value must be
-                # bucket-derived (the builders are internal and called
-                # positionally by convention; a keyword call site that
-                # trips this either gets the positional spelling or a
-                # reviewed waiver).
+                # without the signature, so unless the manifest names the
+                # callee's shape-carrying keywords EVERY keyword value
+                # must be bucket-derived (the builders are internal and
+                # called positionally by convention; a keyword call site
+                # that trips this either gets the positional spelling or
+                # a reviewed waiver).
                 for kw in node.keywords:
-                    if kw.arg is not None:
+                    if kw.arg is not None and (not named or kw.arg in named):
                         suspect.append((f"keyword {kw.arg!r}", kw.value))
                 for label, value in suspect:
                     if not flow.ok(value, node.lineno):

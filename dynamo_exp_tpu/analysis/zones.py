@@ -2,10 +2,10 @@
 
 This file is the one place the lint suite learns *where* each contract
 applies. The ragged-dispatch rewrite (docs/engine_perf.md "One ragged
-dispatch") moved the engine's dispatch sites onto ``_ragged_fn`` /
-``_build_windowed`` / ``_build_mixed`` — when files split or move
-again, update the declarations here (and the doc) and the checkers
-follow.
+dispatch") moved the engine's dispatch sites onto ``_ragged_fn``, which
+the builders (``_build_windowed`` / ``_build_mixed`` and the others)
+reach through the one ``_launch`` — when files split or move again,
+update the declarations here (and the doc) and the checkers follow.
 """
 
 from __future__ import annotations
@@ -345,12 +345,16 @@ VARIANT_SITE_MANIFESTS: tuple[VariantSiteManifest, ...] = (
             # shape-carrying axes of the collapsed ragged lattice; the
             # trailing windowed/sampler/lp key components are bools.
             "_ragged_fn": (0, 1),
+            # A builder's token bucket, which ``_launch`` keys the
+            # program by (the page bound is bucketed inside it); its
+            # other keywords are no key.
+            "_launch": (0, "nb"),
             "_gather_pages": (2,),
             "_inject_pages": (2,),
         },
     ),
     VariantSiteManifest(
         path="dynamo_exp_tpu/engine/block.py",
-        sites={"_ragged_fn": (0, 1)},
+        sites={"_launch": (0, "nb")},
     ),
 )

@@ -43,6 +43,14 @@ What rides which program:
   denoising pass. The host keeps the block's state (``Sequence.blk_*``)
   between dispatches and the next dispatch of either kind goes on from it.
 
+Both programs go through the engine's one launch and a finished block
+through its one emission rule (docs/engine_perf.md "The life of a
+dispatch"): the builders here fill the launch buffer and the rows and call
+``TPUEngine._launch``; ``_consume_block`` takes what the passes fixed into
+the host's block and hands a block with nothing masked left to
+``TPUEngine._emit_row``, closing it (``_close_block``) between the appends
+and the page registration, which stops short of an unwritten block.
+
 A prompt of P tokens is prefilled up to its last block boundary
 (``Sequence.prefill_target``); the ``P mod B`` tokens left open the first
 block, already fixed (``CONTEXT``). Prefill samples nothing: logits are
@@ -67,7 +75,7 @@ from ..aot.launch import SAMPLER_FIELDS, launch_layout, unpack_launch
 from ..models.llama import final_logits, forward_ragged
 from ..ops.sampling import CONTEXT, TOP_LOGPROBS, UNFIXED, denoise_select
 from ..telemetry import get_telemetry
-from .scheduler import SeqState, Sequence
+from .scheduler import SeqState, Sequence, _RaggedRow
 
 
 def block_pass(logits, tokens, fixed_at, passes, rows, mcfg, sampler, lps):
@@ -108,9 +116,9 @@ def _no_lps(rows: int, block: int):
 
 class BlockDecode:
     """``TPUEngine``'s block-diffusion half: the two programs, their
-    builders, the chained launch and the consume. A mixin: everything
-    else (scheduler, pages, prefix cache, launch buffer, telemetry) is the
-    engine's own."""
+    builders (one of them chained) and the consume. A mixin: everything
+    else (scheduler, pages, prefix cache, launch buffer, the launch and
+    the emission rule, telemetry) is the engine's own."""
 
     # ---------------------------------------------------------------- programs
     def _block_program(self, nb, pages, impl, full_sampler, want_lp):
@@ -305,26 +313,6 @@ class BlockDecode:
         f["table"][r, : len(seq.page_ids)] = seq.page_ids
         self._row_sampler_args(seq, r, sampler)
 
-    def _launch(self, fn, program: str, packed, full_sampler: bool,
-                fresh: bool, carry=()):
-        """Hand a launch's buffer over and call ``fn``: (ys, when the call
-        returned). The pools the program returns replace the engine's."""
-        if self._pending_offloads:
-            with self._phase("kv_admin"):
-                self._flush_offloads()
-        prof = self.profiler
-        t0 = prof.begin("ragged", program) if prof is not None else 0.0
-        with self._part("launch", "h2d"):
-            args = (*carry, *self._put_launch(program, packed))
-            if full_sampler:
-                args += (self._counts,)
-        with self._part("launch", "call"):
-            out = fn(self.params, self.k_cache, self.v_cache, *args)
-        ys, self.k_cache, self.v_cache = out[:3]
-        if full_sampler:
-            self._counts = out[3]
-        return ys, (prof.end("ragged", t0, fresh) if prof is not None else 0.0)
-
     # ---------------------------------------------------------------- builders
     def _build_block(self, part, full_sampler: bool, solo: bool, chained=None):
         """Build + dispatch one pure-decode batch of a block model (no
@@ -336,8 +324,6 @@ class BlockDecode:
         block is the one after the block in flight, and the block to
         write is the one in flight: its tokens are what ``chained``
         returns, on the device."""
-        from .engine import _PendingRagged, _RaggedRow
-
         with self._phase("assemble"), self._part("assemble", "build"):
             cfg = self.cfg
             B, ps = cfg.model.block_length, cfg.page_size
@@ -375,34 +361,15 @@ class BlockDecode:
             want_lp = chained.want_lp if chained else any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
-        with self._phase("assemble"), self._part("assemble", "lookup"):
-            n_variants = len(self._ragged_fns)
-            page_bucket = cfg.ragged_page_bucket_for(max_pages)
-            fn = self._ragged_fn(nb, page_bucket, True, full_sampler, want_lp)  # dynlint: recompile-hazard(a chained block reuses the dispatched bucket)
-            fresh = len(self._ragged_fns) > n_variants
-        # The window's contract; a block model reads the tokens alone.
-        carry = (
-            chained.ys[0] if chained else self._no_carry(nb, B),
-            self._no_carry(nb),
-        )
-        ys, dispatched_at = self._launch(
-            fn, "block", packed, full_sampler, fresh, carry
-        )
         live = sum(1 for e in rows if e.start >= 0)
-        key = self._count_dispatch(
-            nb, page_bucket, True, full_sampler, want_lp, False,
-            chained=chained is not None, live=None,
-        )
-        self.flight.record(
-            "dispatch", dispatch="ragged", program="block", rows=live,
-            bucket=nb, windowed=True, chained=chained is not None or None,
-            pages=key[1], want_lp=want_lp,
-        )
         get_telemetry().decode_batch_rows.labels("block").observe(live)
-        return _PendingRagged(
-            ys=ys, rows=rows, nb=nb, windowed=True, full_sampler=full_sampler,
-            want_lp=want_lp, solo=solo, packed=packed,
-            dispatched_at=dispatched_at,
+        # Of the window's carry a block model reads the tokens alone: the
+        # blocks the dispatch in flight returns, on the device.
+        return self._launch(  # dynlint: recompile-hazard(a chained block reuses the dispatched bucket)
+            nb, max_pages, packed, rows, windowed=True,
+            full_sampler=full_sampler, want_lp=want_lp, solo=solo,
+            carry=(chained.ys[0], self._no_carry(nb)) if chained else None,
+            program="block", rows=live, chained=chained is not None or None,
         )
 
     def _dispatch_chained_block(self, pending):
@@ -443,8 +410,6 @@ class BlockDecode:
         sync): each prefilling row's next chunk (cut at block boundaries)
         and each decoding row's block as a span of B tokens making one
         pass. ``_build_mixed``'s stream, without sampling rows."""
-        from .engine import _PendingRagged, _RaggedRow
-
         cfg = self.cfg
         B, ps = cfg.model.block_length, cfg.page_size
         with self._phase("assemble"), self._part("assemble", "plan"):
@@ -515,30 +480,40 @@ class BlockDecode:
             want_lp = any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
-        with self._phase("assemble"), self._part("assemble", "lookup"):
-            n_variants = len(self._ragged_fns)
-            page_bucket = cfg.ragged_page_bucket_for(max_pages)
-            fn = self._ragged_fn(nb, page_bucket, False, full_sampler, want_lp)
-            fresh = len(self._ragged_fns) > n_variants
-        ys, dispatched_at = self._launch(fn, "mixed", packed, full_sampler, fresh)
-        key = self._count_dispatch(
-            nb, page_bucket, False, full_sampler, want_lp, False,
-            chained=False, live=int((positions >= 0).sum()),
+        pending = self._launch(
+            nb, max_pages, packed, rows, windowed=False,
+            full_sampler=full_sampler, want_lp=want_lp,
+            live=int((positions >= 0).sum()), rows=len(rows), tokens=at,
         )
         self._count_attn_passes(nb, row_of, positions)
-        self.flight.record(
-            "dispatch", dispatch="ragged", rows=len(rows), tokens=at,
-            bucket=nb, windowed=False, pages=key[1], want_lp=want_lp,
-        )
         self._note_prefill_chunks(pf, chunks, nb)
         self.steps += 1
         get_telemetry().decode_batch_rows.labels("mixed").observe(len(dec))
-        return _PendingRagged(
-            ys=ys, rows=rows, nb=nb, windowed=False, full_sampler=full_sampler,
-            want_lp=want_lp, solo=False, dispatched_at=dispatched_at,
-        )
+        return pending
 
     # ----------------------------------------------------------------- consume
+    @staticmethod
+    def _block_lp_pack(blk_lp, fixed_at, at, n_top: int, n: int):
+        """``_emit_row``'s pack of a finished block's first ``n`` emitted
+        positions (``at``), from what the host kept as each was fixed:
+        the log-probabilities, the alternatives and, third, the pass
+        each position was fixed at. None where a position has none."""
+        at = at[:n]
+        if any(blk_lp[j] is None for j in at):
+            return None
+        return (
+            [blk_lp[j][0] for j in at],
+            [blk_lp[j][1] for j in at] if n_top > 0 else None,
+            [fixed_at[j] for j in at],
+        )
+
+    def _close_block(self, seq: Sequence, start: int) -> None:
+        """A finished block's tokens are the row's: it opens its next,
+        remembering that this one's K/V are not in the pages."""
+        seq.close_block()
+        seq.unwritten = len(seq.tokens) - start
+        get_telemetry().diffusion_blocks.inc()
+
     def _consume_block(self, pending) -> None:
         """Host sync of one dispatch of a block model, of either program:
         each decoding row's block takes what its pass(es) fixed; a block
@@ -547,8 +522,8 @@ class BlockDecode:
         not in the pages (``Sequence.unwritten``) until a later dispatch
         says it wrote them; a prompt whose last chunk this was joins
         decode. A stop found while a chained successor is in flight
-        defers the finish until that one is consumed, as
-        ``_consume_windowed`` does."""
+        defers the finish until that one is consumed
+        (``_finish_deferred``), as a window's does."""
         program = "block" if pending.windowed else "mixed"
         wrote_by = "next_first_pass" if pending.windowed else "own_pass"
         self._flush_outbox()
@@ -565,7 +540,9 @@ class BlockDecode:
         emitted = wasted = passes_denoise = written = 0
         with self._phase("consume"):
             clock = time.monotonic
-            tokens_s = pages_s = emit_s = first_s = 0.0
+            parts = dict.fromkeys(
+                ("tokens", "pages", "emit", "first_token"), 0.0
+            )
             for e in pending.rows:
                 seq, r = e.seq, e.row
                 if e.kind == "prefill":
@@ -573,7 +550,7 @@ class BlockDecode:
                         t0 = clock()
                         self._promote_to_decode(seq)
                         self.sched.register_full_pages(seq)
-                        first_s += clock() - t0
+                        parts["first_token"] += clock() - t0
                     continue
                 if e.start < 0:
                     continue  # sat a chained dispatch out
@@ -593,7 +570,7 @@ class BlockDecode:
                 start = self._block_of(seq)
                 if e.start < start:  # the mixed program's pass wrote the block before
                     self.sched.register_full_pages(seq)
-                    pages_s += clock() - t0
+                    parts["pages"] += clock() - t0
                     continue
                 assert start == e.start, (seq.request_id, e.start)
                 n_top = self._wants_logprobs(seq)
@@ -615,49 +592,20 @@ class BlockDecode:
                         )
                 seq.blk_passes += d
                 tel.diffusion_tokens_fixed.inc(fixed)
+                parts["tokens"] += clock() - t0
                 if UNFIXED in seq.blk_fixed_at:
-                    tokens_s += clock() - t0
                     continue
-                # Nothing masked: the block is final. Emit what is not context.
-                kept, at, reason = [], [], None
-                for j in range(B):
-                    if seq.blk_fixed_at[j] == CONTEXT:
-                        continue
-                    token = seq.blk_tokens[j]
-                    kept.append(token)
-                    at.append(j)
-                    seq.tokens.append(token)
-                    seq.generated += 1
-                    reason = self.sched.check_stop(seq, token)
-                    if reason is not None:
-                        break
-                emitted += len(kept)
-                pack = None
-                if n_top is not None and all(
-                    seq.blk_lp[j] is not None for j in at
-                ):
-                    pack = (
-                        [seq.blk_lp[j][0] for j in at],
-                        [seq.blk_lp[j][1] for j in at] if n_top > 0 else None,
-                        [seq.blk_fixed_at[j] for j in at],
-                    )
-                seq.close_block()
-                seq.unwritten = len(seq.tokens) - start
-                tel.diffusion_blocks.inc()
-                t1 = clock()
-                self.sched.register_full_pages(seq)
-                t2 = clock()
-                now = time.time()
-                if seq.last_emit_at and kept:
-                    tel.time_between_tokens.observe(
-                        max(now - seq.last_emit_at, 0.0) / len(kept)
-                    )
-                seq.last_emit_at = now
-                seq.emit(kept, None, pack)
-                t3 = clock()
-                tokens_s += t1 - t0
-                pages_s += t2 - t1
-                emit_s += t3 - t2
+                # Nothing masked: the block is final. Emit what is not
+                # context; the block closes once its tokens are the row's
+                # and before its pages are registered, which stops short of
+                # a block nothing has written.
+                at = [j for j in range(B) if seq.blk_fixed_at[j] != CONTEXT]
+                kept, reason = self._emit_row(
+                    seq, [seq.blk_tokens[j] for j in at],
+                    partial(self._block_lp_pack, seq.blk_lp, seq.blk_fixed_at, at),
+                    parts, between=partial(self._close_block, seq, start),
+                )
+                emitted += kept
                 if reason is not None:
                     seq.pending_finish = reason
                     finishes.append(seq)
@@ -681,17 +629,5 @@ class BlockDecode:
                 self.wasted_steps += wasted
                 tel.decode_wasted_steps.inc(wasted)
             if self.profiler is not None:
-                self.profiler.add_parts(
-                    "consume", tokens=tokens_s, pages=pages_s, emit=emit_s,
-                    first_token=first_s,
-                )
-        if finishes:
-            # Pages about to be released must have no dispatch in flight
-            # over them (``_consume_windowed``).
-            succ, self._inflight = self._inflight, None
-            if succ is not None:
-                self._consume_ragged(succ)
-            with self._phase("consume"), self._part("consume", "finish"):
-                for seq in finishes:
-                    reason, seq.pending_finish = seq.pending_finish, None
-                    self.sched.finish(seq, reason)
+                self.profiler.add_parts("consume", **parts)
+        self._finish_deferred(finishes)

@@ -112,7 +112,7 @@ from .block import BlockDecode
 from .config import EngineConfig, refuse_block_model_tiers, refuse_seq_state_tiers
 from .kv_manager import KvEvent, KvPageManager
 from .offload import CopyStream, HostKvPool
-from .scheduler import RemoteKv, Scheduler, SeqState, Sequence
+from .scheduler import RemoteKv, Scheduler, SeqState, Sequence, _RaggedRow
 from .tiering import SwapRecord, plan_swap_entries
 
 log = logging.getLogger(__name__)
@@ -176,22 +176,6 @@ def resolve_attn_impl(cfg: EngineConfig, mesh: Mesh) -> tuple[str, bool]:
             )
             impl = "xla"
     return impl, interpret
-
-
-@dataclass
-class _RaggedRow:
-    """One row of a ragged dispatch (docs/engine_perf.md "One ragged
-    dispatch"): a chunked-prefill span, a decode step/window, or a
-    speculative verify span — all in the same flat query stream."""
-
-    seq: Sequence
-    kind: str  # "decode" | "prefill" | "spec"
-    row: int  # per-row array index in the dispatch
-    n_valid: int = 0  # decode: window steps the host may keep
-    completing: bool = False  # prefill: prompt finishes this chunk
-    counted: bool = False  # prefill: the first token drew as a decode row
-    n_drafts: int = 0  # spec: drafts fed for verification
-    start: int = -1  # a block model's decode row: its block's first position
 
 
 @dataclass
@@ -1215,9 +1199,84 @@ class TPUEngine(BlockDecode, AsyncEngine):
         *out, self.seq_state = fn(*args, self.seq_state)
         return tuple(out)
 
+    def _launch(
+        self, nb: int, max_pages: int, packed, rows: list, /, *,
+        windowed: bool, full_sampler: bool, want_lp: bool,
+        with_spec: bool = False, carry: tuple | None = None,
+        live: int | None = None, solo: bool = False,
+        capacity_capped: bool = False, **event,
+    ) -> _PendingRagged:
+        """The life of a dispatch, from its builder's packed buffer and
+        rows to the pending record its consumer takes (no host sync;
+        docs/engine_perf.md "The life of a dispatch"): look the program up,
+        flush the offloads that must precede it, hand the buffer over,
+        call, take the pools back, count, record. ``carry``: the device
+        state a window or a block chained onto the dispatch before it
+        starts from; None for any other, and a window or block that is
+        not chained gets zeros its program does not read. ``live``:
+        ``_count_dispatch``'s. ``event``: the builder's own fields of
+        the ``dispatch`` flight event."""
+        block = self.cfg.model.block_length
+        program = ("block" if block else "window") if windowed else "mixed"
+        with self._phase("assemble"), self._part("assemble", "lookup"):
+            n_variants = len(self._ragged_fns)
+            page_bucket = self.cfg.ragged_page_bucket_for(max_pages)
+            fn = self._ragged_fn(  # dynlint: recompile-hazard(a launch's bucket is its builder's, checked where _launch is called)
+                nb, page_bucket, windowed, full_sampler, want_lp, with_spec,
+            )
+            fresh = len(self._ragged_fns) > n_variants
+        if self._pending_offloads:
+            with self._phase("kv_admin"):
+                self._flush_offloads()
+        chained = carry is not None
+        if windowed and not chained:
+            carry = (self._no_carry(nb, block), self._no_carry(nb))
+        prof = self.profiler
+        t0 = prof.begin("ragged", program) if prof is not None else 0.0
+        with self._part("launch", "h2d"):
+            args = (*(carry or ()), *self._put_launch(program, packed))
+            if full_sampler:
+                args += (self._counts,)
+        with self._part("launch", "call"):
+            out = self._run_program(
+                fn, self.params, self.k_cache, self.v_cache, *args
+            )
+        # ys, the pools, the counts of a full sampler, and what a windowed
+        # program carries on to one chained onto it (tokens, positions).
+        ys, self.k_cache, self.v_cache, *rest = out
+        if full_sampler:
+            self._counts, *rest = rest
+        tokens_dev, positions_dev = rest or (None, None)
+        dispatched_at = (
+            prof.end("ragged", t0, fresh) if prof is not None else 0.0
+        )
+        key = self._count_dispatch(
+            program, nb, page_bucket, windowed, full_sampler, want_lp,
+            with_spec, chained=chained, live=live,
+        )
+        self.flight.record(
+            "dispatch", dispatch="ragged", **event, bucket=nb,
+            windowed=windowed, pages=key[1], want_lp=want_lp,
+        )
+        return _PendingRagged(
+            ys=ys,
+            rows=rows,
+            nb=nb,
+            windowed=windowed,
+            full_sampler=full_sampler,
+            want_lp=want_lp,
+            solo=solo,
+            with_spec=with_spec,
+            tokens_dev=tokens_dev,
+            positions_dev=positions_dev,
+            capacity_capped=capacity_capped,
+            packed=packed if windowed else None,
+            dispatched_at=dispatched_at,
+        )
+
     def _count_dispatch(
-        self, nb, attn_pages, windowed, full_sampler, want_lp, with_spec,
-        chained: bool, live: int,
+        self, program, nb, attn_pages, windowed, full_sampler, want_lp,
+        with_spec, chained: bool, live: int,
     ) -> tuple:
         """Count one ragged dispatch under its whole variant key
         (``dynamo_ragged_dispatch_total``), its live and padding slots
@@ -1236,7 +1295,6 @@ class TPUEngine(BlockDecode, AsyncEngine):
         )
         tel = get_telemetry()
         block = self.cfg.model.block_length
-        program = ("block" if block else "window") if windowed else "mixed"
         tel.ragged_dispatches.labels(
             program,
             str(nb),
@@ -3280,17 +3338,18 @@ class TPUEngine(BlockDecode, AsyncEngine):
         return seq.stop.sampling_options.logprobs
 
     @staticmethod
-    def _lp_pack(n_top: int, lps, top_ids, top_lps):
-        """Host-side logprob payload for emit: per-token chosen logprob
-        plus the top-n alternatives (n sliced from the static TOP_LOGPROBS
-        the device computes)."""
+    def _lp_pack(n_top: int, n: int, lps, top_ids, top_lps):
+        """Host-side logprob payload for emit, of the first ``n`` tokens
+        of a row's arrays: per-token chosen logprob plus the top-n
+        alternatives (n sliced from the static TOP_LOGPROBS the device
+        computes)."""
         tops = None
         if n_top > 0:
             tops = [
                 {int(t): float(l) for t, l in zip(tid[:n_top], tlp[:n_top])}
-                for tid, tlp in zip(top_ids, top_lps)
+                for tid, tlp in zip(top_ids[:n], top_lps[:n])
             ]
-        return ([float(x) for x in lps], tops)
+        return ([float(x) for x in lps[:n]], tops)
 
     def _promote_to_decode(self, seq: Sequence) -> None:
         """A prompt's K/V is whole: close the request's prefill stage and
@@ -3786,63 +3845,13 @@ class TPUEngine(BlockDecode, AsyncEngine):
             want_lp = any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
-        with self._phase("assemble"), self._part("assemble", "lookup"):
-            n_variants = len(self._ragged_fns)
-            page_bucket = cfg.ragged_page_bucket_for(max_pages)
-            fn = self._ragged_fn(nb, page_bucket, True, full_sampler, want_lp)
-            fresh = len(self._ragged_fns) > n_variants
-        if self._pending_offloads:
-            with self._phase("kv_admin"):
-                self._flush_offloads()
-        prof = self.profiler
-        t0 = prof.begin("ragged", "window") if prof is not None else 0.0
-        with self._part("launch", "h2d"):
-            args = (
-                self._no_carry(nb), self._no_carry(nb),
-                *self._put_launch("window", packed),
-            )
-            if full_sampler:
-                args += (self._counts,)
-        with self._part("launch", "call"):
-            out = self._run_program(
-                fn, self.params, self.k_cache, self.v_cache, *args
-            )
-        if full_sampler:
-            (ys, self.k_cache, self.v_cache, self._counts,
-             tok_dev, pos_dev) = out
-        else:
-            ys, self.k_cache, self.v_cache, tok_dev, pos_dev = out
-        dispatched_at = (
-            prof.end("ragged", t0, fresh) if prof is not None else 0.0
-        )
-        key = self._count_dispatch(
-            nb, page_bucket, True, full_sampler, want_lp, False,
-            chained=False, live=sum(e.n_valid for e in rows),
-        )
-        self.flight.record(
-            "dispatch",
-            dispatch="ragged",
-            rows=len(part),
-            bucket=nb,
-            windowed=True,
-            pages=key[1],
-            want_lp=want_lp,
-        )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(part))
-        return _PendingRagged(
-            ys=ys,
-            rows=rows,
-            nb=nb,
-            windowed=True,
-            full_sampler=full_sampler,
-            want_lp=want_lp,
-            solo=solo,
-            tokens_dev=tok_dev,
-            positions_dev=pos_dev,
-            capacity_capped=capacity_capped,
-            packed=packed,
-            dispatched_at=dispatched_at,
+        return self._launch(
+            nb, max_pages, packed, rows, windowed=True,
+            full_sampler=full_sampler, want_lp=want_lp,
+            live=sum(e.n_valid for e in rows), solo=solo,
+            capacity_capped=capacity_capped, rows=len(part),
         )
 
     def _build_mixed(
@@ -4022,65 +4031,20 @@ class TPUEngine(BlockDecode, AsyncEngine):
             want_lp = any(
                 self._wants_logprobs(e.seq) is not None for e in rows
             )
-        with self._phase("assemble"), self._part("assemble", "lookup"):
-            n_variants = len(self._ragged_fns)
-            page_bucket = cfg.ragged_page_bucket_for(max_pages)
-            fn = self._ragged_fn(
-                nb, page_bucket, False, full_sampler, want_lp, with_spec,
-            )
-            fresh = len(self._ragged_fns) > n_variants
-        if self._pending_offloads:
-            with self._phase("kv_admin"):
-                self._flush_offloads()
-        prof = self.profiler
-        t0 = prof.begin("ragged", "mixed") if prof is not None else 0.0
-        with self._part("launch", "h2d"):
-            args = self._put_launch("mixed", packed)
-            if full_sampler:
-                args += (self._counts,)
-        with self._part("launch", "call"):
-            out = self._run_program(
-                fn, self.params, self.k_cache, self.v_cache, *args
-            )
-        if full_sampler:
-            ys, self.k_cache, self.v_cache, self._counts = out
-        else:
-            ys, self.k_cache, self.v_cache = out
-        dispatched_at = (
-            prof.end("ragged", t0, fresh) if prof is not None else 0.0
-        )
-        key = self._count_dispatch(
-            nb, page_bucket, False, full_sampler, want_lp, with_spec,
-            chained=False, live=int((positions >= 0).sum()),
+        pending = self._launch(
+            nb, max_pages, packed, rows, windowed=False,
+            full_sampler=full_sampler, want_lp=want_lp, with_spec=with_spec,
+            live=int((positions >= 0).sum()), rows=len(rows), tokens=total_q,
         )
         self._count_attn_passes(nb, row_of, positions)
-        self.flight.record(
-            "dispatch",
-            dispatch="ragged",
-            rows=len(rows),
-            tokens=total_q,
-            bucket=nb,
-            windowed=False,
-            pages=key[1],
-            want_lp=want_lp,
-        )
         self._note_prefill_chunks(pf, chunks, nb)
-        tel = get_telemetry()
         self.steps += 1
         if spec:
             self.spec_dispatches += 1
-        tel.decode_batch_rows.labels("mixed").observe(len(dec) + len(spec))
-        return _PendingRagged(
-            ys=ys,
-            rows=rows,
-            nb=nb,
-            windowed=False,
-            full_sampler=full_sampler,
-            want_lp=want_lp,
-            solo=False,
-            with_spec=with_spec,
-            dispatched_at=dispatched_at,
+        get_telemetry().decode_batch_rows.labels("mixed").observe(
+            len(dec) + len(spec)
         )
+        return pending
 
     def _note_prefill_chunks(self, pf, chunks, nb: int) -> None:
         """After a mixed dispatch went out: its prompt chunks (seq, from,
@@ -4210,70 +4174,14 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 rows.append(
                     _RaggedRow(seq, "decode", r, n_valid=min(K, cap - wpos + 1))
                 )
-        with self._phase("assemble"), self._part("assemble", "lookup"):
-            n_variants = len(self._ragged_fns)
-            page_bucket = cfg.ragged_page_bucket_for(max_pages)
-            fn = self._ragged_fn(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
-                nb,
-                page_bucket,
-                True,
-                pending.full_sampler,
-                pending.want_lp,
-            )
-            fresh = len(self._ragged_fns) > n_variants
-        if self._pending_offloads:
-            with self._phase("kv_admin"):
-                self._flush_offloads()
-        prof = self.profiler
-        t0 = prof.begin("ragged", "window") if prof is not None else 0.0
-        with self._part("launch", "h2d"):
-            args = (
-                pending.tokens_dev, pending.positions_dev,
-                *self._put_launch("window", packed),
-            )
-            if pending.full_sampler:
-                args += (self._counts,)
-        with self._part("launch", "call"):
-            out = self._run_program(
-                fn, self.params, self.k_cache, self.v_cache, *args
-            )
-        if pending.full_sampler:
-            (ys, self.k_cache, self.v_cache, self._counts,
-             tok_dev, pos_dev) = out
-        else:
-            ys, self.k_cache, self.v_cache, tok_dev, pos_dev = out
-        dispatched_at = (
-            prof.end("ragged", t0, fresh) if prof is not None else 0.0
-        )
-        key = self._count_dispatch(
-            nb, page_bucket, True, pending.full_sampler, pending.want_lp,
-            False, chained=True, live=sum(e.n_valid for e in rows),
-        )
-        self.flight.record(
-            "dispatch",
-            dispatch="ragged",
-            rows=len(rows),
-            bucket=nb,
-            windowed=True,
-            chained=True,
-            pages=key[1],
-            want_lp=pending.want_lp,
-        )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(rows))
-        return _PendingRagged(
-            ys=ys,
-            rows=rows,
-            nb=nb,
-            windowed=True,
-            full_sampler=pending.full_sampler,
-            want_lp=pending.want_lp,
-            solo=True,
-            tokens_dev=tok_dev,
-            positions_dev=pos_dev,
-            capacity_capped=capacity_capped,
-            packed=packed,
-            dispatched_at=dispatched_at,
+        return self._launch(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
+            nb, max_pages, packed, rows, windowed=True,
+            full_sampler=pending.full_sampler, want_lp=pending.want_lp,
+            carry=(pending.tokens_dev, pending.positions_dev),
+            live=sum(e.n_valid for e in rows), solo=True,
+            capacity_capped=capacity_capped, rows=len(rows), chained=True,
         )
 
     # ----------------------------------------------------------------- consume
@@ -4284,6 +4192,74 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self._consume_windowed(pending)
         else:
             self._consume_mixed(pending)
+
+    def _emit_row(
+        self, seq: Sequence, tokens, lp_of, parts: dict, between=None
+    ) -> tuple[int, FinishReason | None]:
+        """What the tokens the device produced for a row turn into, for
+        every kind of row (a window's, a mixed dispatch's decode or
+        speculative row, a finished block): each is appended and counted
+        until the authoritative ``check_stop`` gives a reason, the pages
+        they completed are registered, and the kept ones are emitted with
+        their log-probabilities where the request asked (``lp_of(n_top,
+        kept)``: the pack of the first ``kept`` of them; None when the
+        dispatch computed none). ``between`` runs after the appends and
+        before the registration: a block closes there, so that
+        ``register_full_pages`` stops short of what it has not written.
+        Adds to ``parts``' ``tokens`` / ``pages`` / ``emit`` clocks
+        (``PHASE_PARTS``). Returns how many were kept, and the reason;
+        the finish is the consumer's: deferred under a chained successor
+        (``_finish_deferred``), at once after a mixed dispatch."""
+        clock = time.monotonic
+        t0 = clock()
+        kept: list[int] = []
+        reason = None
+        for token in tokens:
+            token = int(token)
+            kept.append(token)
+            seq.tokens.append(token)
+            seq.generated += 1
+            reason = self.sched.check_stop(seq, token)
+            if reason is not None:
+                break
+        if between is not None:
+            between()
+        t1 = clock()
+        self.sched.register_full_pages(seq)
+        t2 = clock()
+        n_top = self._wants_logprobs(seq)
+        pack = None
+        if n_top is not None and lp_of is not None and kept:
+            pack = lp_of(n_top, len(kept))
+        if kept:
+            now = time.time()
+            if seq.last_emit_at:
+                get_telemetry().time_between_tokens.observe(
+                    max(now - seq.last_emit_at, 0.0) / len(kept)
+                )
+            seq.last_emit_at = now
+        seq.emit(kept, None, pack)
+        t3 = clock()
+        parts["tokens"] += t1 - t0
+        parts["pages"] += t2 - t1
+        parts["emit"] += t3 - t2
+        return len(kept), reason
+
+    def _finish_deferred(self, finishes: list[Sequence]) -> None:
+        """Finish the rows a window's or a block's consume found stopped
+        (``Sequence.pending_finish``). Pages about to be released must
+        not have a dispatch in flight over them: the chained successor
+        is synced first (its surviving rows' tokens are consumed
+        normally; rows with a pending finish are skipped there)."""
+        if not finishes:
+            return
+        succ, self._inflight = self._inflight, None
+        if succ is not None:
+            self._consume_ragged(succ)
+        with self._phase("consume"), self._part("consume", "finish"):
+            for seq in finishes:
+                reason, seq.pending_finish = seq.pending_finish, None
+                self.sched.finish(seq, reason)
 
     def _consume_windowed(self, pending: _PendingRagged) -> None:
         """Host sync of one decode window: emit kept tokens, run the
@@ -4304,56 +4280,28 @@ class TPUEngine(BlockDecode, AsyncEngine):
             # The np.asarray above was this window's one host sync.
             self.profiler.consume("ragged", pending.dispatched_at, "window")
         with self._phase("consume"):
-            tel = get_telemetry()
             finishes: list[Sequence] = []
             wasted = 0
             emitted = 0
-            # The parts of this phase alternate row by row: summed here
-            # from clock reads, handed over once (PHASE_PARTS).
-            clock = time.monotonic
-            tokens_s = pages_s = emit_s = 0.0
+            # The parts of this phase alternate row by row: summed from
+            # clock reads (``_emit_row``), handed over once (PHASE_PARTS).
+            parts = dict.fromkeys(("tokens", "pages", "emit"), 0.0)
+            lp_of = None
             for e in pending.rows:
                 seq, n_valid, row = e.seq, e.n_valid, e.row
                 if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
                     wasted += n_valid  # whole window past this row's stop
                     continue
-                t0 = clock()
-                kept: list[int] = []
-                reason = None
-                for token in sampled[:n_valid, row]:
-                    token = int(token)
-                    kept.append(token)
-                    seq.tokens.append(token)
-                    seq.generated += 1
-                    reason = self.sched.check_stop(seq, token)
-                    if reason is not None:
-                        break
-                wasted += n_valid - len(kept)
-                emitted += len(kept)
-                t1 = clock()
-                self.sched.register_full_pages(seq)
-                t2 = clock()
-                n_top = self._wants_logprobs(seq)
-                pack = None
-                if n_top is not None and kept:
-                    n = len(kept)
-                    pack = self._lp_pack(
-                        n_top,
-                        lps[:n, row],
-                        top_ids[:n, row],
-                        top_lps[:n, row],
+                if pending.want_lp:
+                    lp_of = partial(
+                        self._lp_pack, lps=lps[:, row],
+                        top_ids=top_ids[:, row], top_lps=top_lps[:, row],
                     )
-                if kept:
-                    now = time.time()
-                    if seq.last_emit_at:
-                        tbt = max(now - seq.last_emit_at, 0.0) / len(kept)
-                        tel.time_between_tokens.observe(tbt)
-                    seq.last_emit_at = now
-                seq.emit(kept, None, pack)
-                t3 = clock()
-                tokens_s += t1 - t0
-                pages_s += t2 - t1
-                emit_s += t3 - t2
+                kept, reason = self._emit_row(
+                    seq, sampled[:n_valid, row], lp_of, parts
+                )
+                wasted += n_valid - kept
+                emitted += kept
                 if reason is not None:
                     seq.pending_finish = reason
                     finishes.append(seq)
@@ -4362,23 +4310,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
             )
             if wasted:
                 self.wasted_steps += wasted
-                tel.decode_wasted_steps.inc(wasted)
+                get_telemetry().decode_wasted_steps.inc(wasted)
             if self.profiler is not None:
-                self.profiler.add_parts(
-                    "consume", tokens=tokens_s, pages=pages_s, emit=emit_s
-                )
-        if finishes:
-            # Pages about to be released must not have a window in
-            # flight over them: sync the chained successor first (its
-            # surviving rows' tokens are consumed normally; rows with a
-            # pending finish are skipped above).
-            succ, self._inflight = self._inflight, None
-            if succ is not None:
-                self._consume_ragged(succ)
-            with self._phase("consume"), self._part("consume", "finish"):
-                for seq in finishes:
-                    reason, seq.pending_finish = seq.pending_finish, None
-                    self.sched.finish(seq, reason)
+                self.profiler.add_parts("consume", **parts)
+        self._finish_deferred(finishes)
 
     def _consume_mixed(self, pending: _PendingRagged) -> None:
         """Host sync of one mixed ragged batch: decode rows emit their
@@ -4412,7 +4347,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
             tel = get_telemetry()
             # As in _consume_windowed: the parts alternate row by row.
             clock = time.monotonic
-            tokens_s = pages_s = emit_s = first_s = finish_s = 0.0
+            parts = dict.fromkeys(
+                ("tokens", "pages", "emit", "first_token", "finish"), 0.0
+            )
+            lp_of = None
             for e in pending.rows:
                 seq, r = e.seq, e.row
                 if e.kind == "prefill":
@@ -4421,129 +4359,74 @@ class TPUEngine(BlockDecode, AsyncEngine):
                     t0 = clock()
                     n_top = self._wants_logprobs(seq)
                     pack = (
-                        self._lp_pack(
-                            n_top, lp0[r : r + 1],
-                            tid0[r : r + 1], tlp0[r : r + 1],
-                        )
+                        self._lp_pack(n_top, 1, lp0[r:], tid0[r:], tlp0[r:])
                         if pending.want_lp and n_top is not None
                         else None
                     )
                     self._finish_first_token(
                         seq, int(tok0[r]), pack, counted=e.counted
                     )
-                    first_s += clock() - t0
+                    parts["first_token"] += clock() - t0
                     continue
                 if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:
                     self.wasted_steps += 1
                     tel.decode_wasted_steps.inc()
                     continue
-                t0 = clock()
                 if e.kind == "decode":
-                    token = int(tok0[r])
-                    seq.tokens.append(token)
-                    seq.generated += 1
-                    reason = self.sched.check_stop(seq, token)
-                    t1 = clock()
-                    self.sched.register_full_pages(seq)
-                    t2 = clock()
-                    n_top = self._wants_logprobs(seq)
-                    pack = (
-                        self._lp_pack(
-                            n_top, lp0[r : r + 1],
-                            tid0[r : r + 1], tlp0[r : r + 1],
+                    if pending.want_lp:
+                        lp_of = partial(
+                            self._lp_pack, lps=lp0[r:], top_ids=tid0[r:],
+                            top_lps=tlp0[r:],
                         )
-                        if pending.want_lp and n_top is not None
-                        else None
-                    )
-                    now = time.time()
-                    if seq.last_emit_at:
-                        tel.time_between_tokens.observe(
-                            max(now - seq.last_emit_at, 0.0)
-                        )
-                    seq.last_emit_at = now
-                    seq.emit([token], None, pack)
-                    t3 = clock()
-                    if reason is not None:
-                        self.sched.finish(seq, reason)
-                        finish_s += clock() - t3
-                    tokens_s += t1 - t0
-                    pages_s += t2 - t1
-                    emit_s += t3 - t2
-                    continue
-                # Speculative row: the device already computed the
-                # acceptance (longest draft == target prefix plus the first
-                # correction token — the same rule that gated the on-device
-                # penalty counts); emit those tokens, rewind state past
-                # rejected positions, and feed the outcome back to the
-                # adaptive controller.
-                g = e.n_drafts
-                tgt = targets[r]
-                n_emit = int(n_emits[r])
-                accepted = n_emit - 1
-                kept: list[int] = []
-                reason = None
-                for j in range(n_emit):
-                    token = int(tgt[j])
-                    kept.append(token)
-                    seq.tokens.append(token)
-                    seq.generated += 1
-                    reason = self.sched.check_stop(seq, token)
-                    if reason is not None:
-                        break
-                tokens_s += clock() - t0
-                if n_emit - len(kept):
-                    # Tokens past a host-detected stop: computed, discarded.
-                    self.wasted_steps += n_emit - len(kept)
-                    tel.decode_wasted_steps.inc(n_emit - len(kept))
-                seq.spec_dispatches += 1
-                seq.spec_draft_tokens += g
-                seq.spec_accepted_tokens += accepted
-                seq.spec_emitted_tokens += len(kept)
-                self.spec_row_dispatches += 1
-                self.spec_draft_tokens += g
-                self.spec_accepted_tokens += accepted
-                self.spec_emitted_tokens += len(kept)
-                tel.spec_draft_tokens.inc(g)
-                tel.spec_accepted_tokens.inc(accepted)
-                tel.spec_tokens_per_dispatch.observe(len(kept))
-                self.flight.record(
-                    "spec_accept",
-                    req=seq.request_id,
-                    proposed=g,
-                    accepted=accepted,
-                    emitted=len(kept),
-                )
-                self._spec.record(seq, proposed=g, accepted=accepted)
-                t1 = clock()
-                self.sched.register_full_pages(seq)
-                t2 = clock()
-                n_top = self._wants_logprobs(seq)
-                pack = None
-                if n_top is not None and kept:
-                    n = len(kept)
-                    pack = self._lp_pack(
-                        n_top, s_lps[r, :n], s_tids[r, :n], s_tlps[r, :n]
-                    )
-                if kept:
-                    now = time.time()
-                    if seq.last_emit_at:
-                        tbt = max(now - seq.last_emit_at, 0.0) / len(kept)
-                        tel.time_between_tokens.observe(tbt)
-                    seq.last_emit_at = now
-                seq.emit(kept, None, pack)
-                t3 = clock()
-                pages_s += t2 - t1
-                emit_s += t3 - t2
-                if reason is not None:
-                    self.sched.finish(seq, reason)
-                    finish_s += clock() - t3
+                    _, reason = self._emit_row(seq, tok0[r : r + 1], lp_of, parts)
                 else:
+                    # Speculative row: the device already computed the
+                    # acceptance (longest draft == target prefix plus the
+                    # first correction token — the same rule that gated
+                    # the on-device penalty counts); emit those tokens,
+                    # rewind state past rejected positions, and feed the
+                    # outcome back to the adaptive controller.
+                    g, n_emit = e.n_drafts, int(n_emits[r])
+                    accepted = n_emit - 1
+                    if pending.want_lp:
+                        lp_of = partial(
+                            self._lp_pack, lps=s_lps[r], top_ids=s_tids[r],
+                            top_lps=s_tlps[r],
+                        )
+                    kept, reason = self._emit_row(
+                        seq, targets[r, :n_emit], lp_of, parts
+                    )
+                    if n_emit - kept:
+                        # Tokens past a host-detected stop: computed, discarded.
+                        self.wasted_steps += n_emit - kept
+                        tel.decode_wasted_steps.inc(n_emit - kept)
+                    seq.spec_dispatches += 1
+                    seq.spec_draft_tokens += g
+                    seq.spec_accepted_tokens += accepted
+                    seq.spec_emitted_tokens += kept
+                    self.spec_row_dispatches += 1
+                    self.spec_draft_tokens += g
+                    self.spec_accepted_tokens += accepted
+                    self.spec_emitted_tokens += kept
+                    tel.spec_draft_tokens.inc(g)
+                    tel.spec_accepted_tokens.inc(accepted)
+                    tel.spec_tokens_per_dispatch.observe(kept)
+                    self.flight.record(
+                        "spec_accept",
+                        req=seq.request_id,
+                        proposed=g,
+                        accepted=accepted,
+                        emitted=kept,
+                    )
+                    self._spec.record(seq, proposed=g, accepted=accepted)
+                if reason is not None:
+                    t0 = clock()
+                    self.sched.finish(seq, reason)
+                    parts["finish"] += clock() - t0
+                elif e.kind == "spec":
                     self._rewind_spec_pages(seq)
             if self.profiler is not None:
-                self.profiler.add_parts(
-                    "consume", tokens=tokens_s, pages=pages_s, emit=emit_s,
-                    first_token=first_s, finish=finish_s,
-                )
+                self.profiler.add_parts("consume", **parts)
 
     # --------------------------------------------------------------- metrics
     def metrics(self) -> dict:

@@ -247,6 +247,22 @@ class Sequence:
         self.blk_passes = 0
 
 
+@dataclass
+class _RaggedRow:
+    """One row of a ragged dispatch (docs/engine_perf.md "One ragged
+    dispatch"): a chunked-prefill span, a decode step/window, or a
+    speculative verify span — all in the same flat query stream."""
+
+    seq: Sequence
+    kind: str  # "decode" | "prefill" | "spec"
+    row: int  # per-row array index in the dispatch
+    n_valid: int = 0  # decode: window steps the host may keep
+    completing: bool = False  # prefill: prompt finishes this chunk
+    counted: bool = False  # prefill: the first token drew as a decode row
+    n_drafts: int = 0  # spec: drafts fed for verification
+    start: int = -1  # a block model's decode row: its block's first position
+
+
 def select_preemption_victim(candidates, max_preemptions: int):
     """Pure KV-pressure victim policy: lowest priority first, youngest
     (latest-submitted) on ties — the work with the least sunk cost and

@@ -8,6 +8,8 @@ import json
 import os
 import textwrap
 
+import pytest
+
 from dynamo_exp_tpu.analysis import (
     RULES,
     WAIVER_TOKENS,
@@ -336,6 +338,34 @@ def test_recompile_waived_with_reason():
         RecompileHazardChecker(manifests=(_FIX_SITES,)), "fix/eng.py", src
     )
     assert len(findings) == 1 and findings[0].waived
+
+
+@pytest.mark.parametrize(
+    "sites,call,flagged",
+    [
+        # No keyword named: every keyword value must be bucketed.
+        ((0,), "self._go(cfg.rows_bucket_for(n), live=n)", ["keyword 'live'"]),
+        # The callee's shape-carrying keywords named: only those, and the
+        # positions, are keys (``TPUEngine._launch``'s shape).
+        ((0, "nb"), "self._go(cfg.rows_bucket_for(n), live=n)", []),
+        ((0, "nb"), "self._go(n, live=n)", ["arg 0"]),
+        ((0, "nb"), "self._go(nb=n, live=n)", ["keyword 'nb'"]),
+        ((0, "nb"), "self._go(nb=cfg.rows_bucket_for(n), live=n)", []),
+    ],
+)
+def test_recompile_named_keywords_limit_what_is_a_key(sites, call, flagged):
+    src = f"""
+    class Eng:
+        def dispatch(self, n, cfg):
+            return {call}
+    """
+    findings, _ = run_checker(
+        RecompileHazardChecker(
+            manifests=(VariantSiteManifest("fix/eng.py", {"_go": sites}),)
+        ),
+        "fix/eng.py", src,
+    )
+    assert [f.message.split(" of ")[0].split("key ")[1] for f in findings] == flagged
 
 
 # --------------------------------------------------------- waiver grammar

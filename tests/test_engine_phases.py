@@ -9,6 +9,7 @@ the KV pools' geometry, and named scopes that are metadata only.
 
 import asyncio
 import contextlib
+import functools
 import re
 import time
 
@@ -518,6 +519,87 @@ def test_programs_hold_no_second_copy_of_the_pools(windowed):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * one_pool  # both donated, reused
     assert mem.temp_size_in_bytes < one_pool // 4
+
+
+# ------------------------------------------------ one launch, five callers
+# Which dispatches of a run are a launch of each kind, by the ``dispatch``
+# flight event, and the fields that event has beside the five every launch
+# gives it.
+LAUNCH_FIELDS = {"dispatch", "bucket", "windowed", "pages", "want_lp"}
+LAUNCHES = {
+    "window": ("token", lambda d: d["windowed"] and not d.get("chained"), {"rows"}),
+    "chained-window": (
+        "token", lambda d: d["windowed"] and d.get("chained"), {"rows", "chained"},
+    ),
+    "mixed": ("token", lambda d: not d["windowed"], {"rows", "tokens"}),
+    "block": ("block", lambda d: d["windowed"], {"program", "rows", "chained"}),
+    "mixed-block": ("block", lambda d: not d["windowed"], {"rows", "tokens"}),
+}
+
+
+@functools.cache
+def _launch_record(model: str) -> dict:
+    """One request through a token model's engine or a block model's:
+    the calls of ``_run_program`` and the ``dispatch`` flight events in
+    the order they came, what the launch counters grew by, and the
+    profiler's parts."""
+    from .test_sdar import PROMPT, _engine as block_engine
+
+    engine = (
+        block_engine() if model == "block"
+        else TPUEngine(_cfg(), mesh=single_device_mesh(), seed=0)
+    )
+    log: list = []
+    run_program, record = engine._run_program, engine.flight.record
+
+    def spy_run(fn, *args):
+        log.append("run")
+        return run_program(fn, *args)
+
+    def spy_record(kind, **data):
+        if kind == "dispatch" and data.get("dispatch") == "ragged":
+            log.append(data)
+        return record(kind, **data)
+
+    engine._run_program, engine.flight.record = spy_run, spy_record
+    counters = ("dynamo_launch_transfers_total", "dynamo_ragged_dispatch_total")
+    before = [_total(name) for name in counters]
+    engine.start()
+    try:
+        # Prompt chunks (mixed), then decode alone: a window or a block,
+        # and the ones chained onto it.
+        prompt = PROMPT[:40] if model == "block" else range(20, 40)
+        assert asyncio.run(_generate(engine, prompt, max_tokens=21)) == 21
+    finally:
+        engine.stop()
+    return {
+        "log": log,
+        "grew": [_total(name) - b for name, b in zip(counters, before)],
+        "parts": engine.profiler.part_seconds(),
+    }
+
+
+@pytest.mark.parametrize("kind", LAUNCHES)
+def test_every_launch_is_the_one_launch(kind):
+    """Each of the five callers of ``TPUEngine._launch``: every dispatch
+    calls its program through ``_run_program`` exactly once (a block
+    model's too: the sequence-state pool is threaded there, and a test
+    that watches launches watches that), hands over one buffer, books
+    the lookup and the launch's two parts, and records a ``dispatch``
+    event with the fields that kind has."""
+    model, is_kind, own = LAUNCHES[kind]
+    rec = _launch_record(model)
+    log = rec["log"]
+    events = [d for d in log if d != "run"]
+    assert any(is_kind(d) for d in events), [sorted(d) for d in events]
+    # run, event, run, event, ...: one call of the program a dispatch.
+    assert log[0::2] == ["run"] * len(events) and log[1::2] == events
+    assert rec["grew"] == [len(events), len(events)]
+    for d in filter(is_kind, events):
+        assert set(d) == LAUNCH_FIELDS | own, (kind, sorted(d))
+        assert d["bucket"] >= d["rows"] >= 1 and d["want_lp"] is False
+    for part in (("assemble", "lookup"), ("launch", "h2d"), ("launch", "call")):
+        assert rec["parts"][part] > 0.0, part
 
 
 # --------------------------------- the parts change no program either

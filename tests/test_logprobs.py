@@ -3,14 +3,18 @@ of the model distribution, and the OpenAI layer shapes them per spec
 (chat chunk choices[].logprobs.content, legacy completions fields,
 stream=false aggregation)."""
 
+import asyncio
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
 from dynamo_exp_tpu.models import TINY, forward, init_kv_cache
 from dynamo_exp_tpu.parallel import single_device_mesh
 from dynamo_exp_tpu.protocols.common import BackendInput
+from dynamo_exp_tpu.telemetry import get_telemetry
 
 PS = 8
 
@@ -200,3 +204,112 @@ async def test_top_logprobs_over_limit_rejected(tmp_path):
     })
     with pytest.raises(InvalidRequestError, match="top_logprobs"):
         pre.preprocess_chat(req)
+
+
+# ------------------------------------------- one emission rule, four row kinds
+# What a row's sampled tokens turn into (``TPUEngine._emit_row``) is one
+# rule whichever program sampled them. A decode window's rows are held to
+# the model above and a finished block's to its reference
+# (``tests/test_sdar.py``); here the other kinds are held to the window's:
+# the same request, its tokens emitted by another kind of row, streams the
+# same tokens, log-probability pack and finish reason.
+REPEAT_PROMPT = [5, 9, 17, 3] * 5  # the n-gram drafter hits on it
+
+
+def _row_kind_engine(**kw):
+    cfg = EngineConfig(
+        model=TINY, max_decode_slots=2, page_size=PS, num_pages=64,
+        max_model_len=128, eos_token_ids=[], prefill_chunk=8, **kw,
+    )
+    return TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
+
+
+async def _stream(engine, prompt, n, stop_ids=()):
+    b = BackendInput(token_ids=list(prompt))
+    b.stop_conditions.max_tokens = n
+    b.stop_conditions.ignore_eos = not stop_ids
+    b.stop_conditions.stop_token_ids = list(stop_ids)
+    b.sampling_options.logprobs = 2
+    out = {"tokens": [], "lps": [], "tops": [], "reason": None}
+    async for item in await engine.generate(b.to_dict()):
+        out["tokens"] += item.get("token_ids", [])
+        out["lps"] += item.get("logprobs") or []
+        out["tops"] += item.get("top_logprobs") or []
+        out["reason"] = item.get("finish_reason") or out["reason"]
+    return out
+
+
+def _assert_same_emission(got, want, upto=None):
+    """``got`` is ``want`` (cut after ``upto`` tokens): tokens and reason
+    exactly, log-probabilities to the rounding of another program's
+    matmul shapes (see the oracle test above; two alternatives that
+    close may change places, so the second is held to its value)."""
+    n = len(want["tokens"]) if upto is None else upto
+    assert got["tokens"] == want["tokens"][:n]
+    assert len(got["lps"]) == len(got["tops"]) == n
+    for tok, g, w, gt, wt in zip(
+        got["tokens"], got["lps"], want["lps"], got["tops"], want["tops"]
+    ):
+        assert abs(g - w) < 2e-2
+        assert len(gt) == len(wt) == 2
+        assert abs(gt[tok] - g) < 1e-6 and abs(wt[tok] - w) < 1e-6
+        for a, b in zip(sorted(gt.values()), sorted(wt.values())):
+            assert abs(a - b) < 2e-2
+
+
+async def _a_windows_rows(stop_at=None):
+    """The reference kind: the request alone, every token off a window."""
+    engine = _row_kind_engine()
+    engine.start()
+    try:
+        free = await _stream(engine, REPEAT_PROMPT, 16)
+        assert free["reason"] == "length" and len(free["tokens"]) == 16
+        if stop_at is None:
+            return engine, free, None
+        stop = free["tokens"][stop_at]
+        cut = free["tokens"].index(stop) + 1
+        return engine, free, (stop, cut)
+    finally:
+        engine.stop()
+
+
+@pytest.mark.parametrize("kind", ["window-stop", "mixed-decode", "spec", "spec-stop"])
+async def test_a_rows_emission_does_not_depend_on_its_kind(kind):
+    tel = get_telemetry()
+    # Token 12 of 16 is the first of its value, past a window's first step
+    # and inside the span the drafter's one hit has verified.
+    base, free, stop = await _a_windows_rows(12 if kind.endswith("stop") else None)
+    stop_ids, upto = ([stop[0]], stop[1]) if stop else ((), None)
+    if kind == "window-stop":
+        # A stop the host finds inside a window: the kept tokens' pack.
+        base.start()
+        try:
+            got = await _stream(base, REPEAT_PROMPT, 16, stop_ids)
+        finally:
+            base.stop()
+    elif kind == "mixed-decode":
+        # Submitted beside a prompt of 64 tokens, 8 a dispatch: the row's
+        # own 20 are in after three, and it decodes one token a dispatch
+        # beside the other's chunks.
+        engine = _row_kind_engine()
+        rows0 = tel.decode_batch_rows.labels("mixed")._sum.get()
+        engine.start()
+        try:
+            got, _ = await asyncio.gather(
+                _stream(engine, REPEAT_PROMPT, 16),
+                _stream(engine, range(30, 94), 2),
+            )
+        finally:
+            engine.stop()
+        assert tel.decode_batch_rows.labels("mixed")._sum.get() - rows0 >= 3
+    else:
+        # Speculative rows: the accepted prefix and the correction.
+        engine = _row_kind_engine(spec_mode="ngram")
+        engine.start()
+        try:
+            got = await _stream(engine, REPEAT_PROMPT, 16, stop_ids)
+            assert engine.spec_row_dispatches > 0
+        finally:
+            engine.stop()
+    _assert_same_emission(got, free, upto)
+    assert got["reason"] == ("eos" if stop else "length")

@@ -324,14 +324,25 @@ async def test_every_remainder_of_prompt_and_budget(engine, n_prompt, n_tokens):
     _held_to_the_reference(engine.params, HF, PROMPT[:n_prompt], out)
 
 
-async def test_a_stop_token_inside_a_block_cuts_it(engine):
-    cold = await _run(engine, PROMPT[:21], 20)
+@pytest.mark.parametrize("logprobs", [None, 2])
+async def test_a_stop_token_inside_a_block_cuts_it(engine, logprobs):
+    """And with it the block's pack: the kept positions' log-probabilities,
+    alternatives and passes are the uncut block's, position for position."""
+    cold = await _run(engine, PROMPT[:21], 20, logprobs=logprobs)
     stop = cold["token_ids"][9]
     at = cold["token_ids"].index(stop)
-    out = await _run(engine, PROMPT[:21], 20, stop_ids=[stop])
+    out = await _run(engine, PROMPT[:21], 20, logprobs=logprobs, stop_ids=[stop])
     assert out["token_ids"] == cold["token_ids"][: at + 1]
     assert out["final"]["finish_reason"] == "eos"
     assert out["final"]["completion_tokens"] == at + 1
+    if logprobs is None:
+        assert not out["logprobs"] and not out["fixed_at_pass"]
+        return
+    assert (21 + at + 1) % B, "the stop is to fall inside a block"
+    assert out["fixed_at_pass"] == cold["fixed_at_pass"][: at + 1]
+    assert out["logprobs"] == pytest.approx(cold["logprobs"][: at + 1], abs=LOGP_TOL)
+    for got, want in zip(out["top_logprobs"], cold["top_logprobs"]):
+        assert len(got) == logprobs and got == pytest.approx(want, abs=LOGP_TOL)
 
 
 @pytest.mark.parametrize("prefill_chunk", [4, 16, 64])

@@ -32,8 +32,8 @@ from dynamo_exp_tpu.telemetry.dispatch import PHASE_PARTS, DispatchProfiler  # n
 
 # The phases of one iteration that admits, dispatches a mixed batch and
 # consumes it (launch rides begin/end). ``assemble`` opens five times:
-# ``_loop``'s own lists, ``_plan_ragged``, and one a part of
-# ``_build_mixed``, each of which is a stretch of its own.
+# ``_loop``'s own lists, ``_plan_ragged``, ``_build_mixed``'s plan and
+# build, and ``_launch``'s lookup, each of which is a stretch of its own.
 PHASES = (
     "kv_admin", "intake", "kv_admin", "admit", "kv_admin", "publish",
     "assemble", "assemble", "assemble", "assemble", "assemble", "sync",
