@@ -165,15 +165,15 @@ def aot_compile(
             engine._gather_pages.lower(k, v, pids).compile()
             # The scatter's page payloads have the gather's output
             # shape: [L, bucket, page_size, HkvD] in the KV dtype.
-            L = engine.cfg.model.num_layers
-            hkv = (
-                engine.cfg.model.num_kv_heads * engine.cfg.model.head_dim_
+            # (a pool's own width: a latent model's second has no lanes)
+            k_page, v_page = (
+                jnp.zeros(
+                    (pool.shape[0], bucket, *pool.shape[2:]),
+                    engine.cfg.kv_dtype_jnp,
+                )
+                for pool in (k, v)
             )
-            page = jnp.zeros(
-                (L, bucket, engine.cfg.page_size, hkv),
-                engine.cfg.kv_dtype_jnp,
-            )
-            engine._inject_pages.lower(k, v, pids, page, page).compile()
+            engine._inject_pages.lower(k, v, pids, k_page, v_page).compile()
             report.compiled += 2
         except Exception as e:  # noqa: BLE001
             log.exception("AOT compile failed for move bucket %d", bucket)

@@ -325,6 +325,10 @@ def _engine_fingerprint(cfg, attn_impl: str, interpret: bool) -> dict:
         "device_stop_width": cfg.device_stop_width,
         "kv_dtype": cfg.kv_dtype,
         "attention_impl": attn_impl,
+        # what the kernel is told to read: heads of K and V in two pools, or
+        # one latent pool in the absorbed form; and the pools' row widths
+        "attention_form": "latent-absorbed" if cfg.model.is_latent else "heads",
+        "kv_pool_widths": list(cfg.model.kv_pool_widths),
         "pallas_interpret": interpret,
         "ragged_q_tile": cfg.ragged_q_tile,
         "spec_on": cfg.spec_mode != "off",

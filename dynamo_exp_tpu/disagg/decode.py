@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import logging
 
-from ..engine.config import refuse_seq_state_tiers
+from ..engine.config import refuse_latent_tiers, refuse_seq_state_tiers
 from ..engine.engine import TPUEngine
 from ..engine.scheduler import RemoteKv
 from ..protocols.common import BackendInput
@@ -47,6 +47,7 @@ class DisaggDecodeEngine(AsyncEngine):
         breaker: CircuitBreaker | None = None,
     ):
         refuse_seq_state_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
+        refuse_latent_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         self.engine = engine
         self.queue = queue
         self.receiver = receiver

@@ -12,7 +12,11 @@ from __future__ import annotations
 import logging
 import time
 
-from ..engine.config import refuse_block_model_tiers, refuse_seq_state_tiers
+from ..engine.config import (
+    refuse_block_model_tiers,
+    refuse_latent_tiers,
+    refuse_seq_state_tiers,
+)
 from ..engine.engine import TPUEngine
 from ..protocols.common import BackendInput, SamplingOptions
 from ..runtime.runtime import CancellationToken
@@ -36,6 +40,7 @@ class PrefillWorker:
     ):
         refuse_seq_state_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         refuse_block_model_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
+        refuse_latent_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         self.engine = engine
         self.queue = queue
         self.cancel = cancel or CancellationToken()

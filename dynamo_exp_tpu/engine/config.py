@@ -136,6 +136,51 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
         )
 
 
+def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
+    """The one place a model whose cache is a latent pool
+    (``ModelConfig.is_latent``: one row ``[c_kv | k_r]`` a token and layer
+    in ONE pool, and no value pool) is refused by what carries a page shape
+    it did not choose or splits heads, in ``refuse_seq_state_tiers``'
+    manner. Each of these lays pages out as two pools of ``Hkv x D`` lanes,
+    or divides attention by KV head, and none has run or been tested with a
+    latent row:
+
+    - host offload and its prefetch (``engine/offload.py``,
+      ``host_cache_pages``), G3 persistence (``kv/persistent.py``,
+      ``kv_store_dir``): their page records are a K and a V payload;
+    - ring prefill (``sp`` > 1): it rotates per-head K and V;
+    - tensor parallelism (``tp`` > 1): a latent cache has one head, and the
+      latent projections are replicated;
+    - speculative decoding (``spec_mode``): untested with the absorbed form
+      over draft spans;
+    - ``tier`` = "disaggregated transfer" / "migration"
+      (``disagg/transfer.py``, ``runtime/reclaim.py``): the wire's page
+      layout is the two pools'.
+
+    With ``tier`` None the engine's own configuration is checked; with a
+    name, that component is refused. Any other model passes, and so does
+    ``cfg`` None."""
+    if cfg is None or not cfg.model.is_latent:
+        return
+    armed = [tier] if tier else [
+        name for name, on in (
+            ("host offload (host_cache_pages)", cfg.host_cache_pages > 0),
+            ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
+            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
+            ("ring prefill (sp > 1)", cfg.sp > 1),
+            ("tensor parallelism (tp > 1)", cfg.tp > 1),
+        ) if on
+    ]
+    if armed:
+        raise ValueError(
+            f"model_type {cfg.model.model_type!r} caches one latent row a "
+            f"token ({cfg.model.latent_width} values in one pool, no value "
+            f"pool), which {', '.join(armed)} does not carry or split: it "
+            "moves pages as K and V of kv-head lanes. Serve it without "
+            "(README.md, models/)"
+        )
+
+
 def refuse_block_model_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
     """The one place a model that generates by diffusion over blocks
     (``ModelConfig.block_length`` > 0: a row's step is a pass over a block

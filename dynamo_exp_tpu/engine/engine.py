@@ -109,7 +109,12 @@ from ..telemetry.flight import (
     unregister_dumper,
 )
 from .block import BlockDecode
-from .config import EngineConfig, refuse_block_model_tiers, refuse_seq_state_tiers
+from .config import (
+    EngineConfig,
+    refuse_block_model_tiers,
+    refuse_latent_tiers,
+    refuse_seq_state_tiers,
+)
 from .kv_manager import KvEvent, KvPageManager
 from .offload import CopyStream, HostKvPool
 from .scheduler import RemoteKv, Scheduler, SeqState, Sequence, _RaggedRow
@@ -138,7 +143,7 @@ def resolve_attn_impl(cfg: EngineConfig, mesh: Mesh) -> tuple[str, bool]:
     A free function (not a method) because the resolved impl is part of
     the AOT compile-lattice key: ``llmctl aot list`` resolves it from
     (config, mesh) alone, without paying a weight init."""
-    from ..ops.ragged_attention import ragged_supported
+    from ..ops.ragged_attention import latent_supported, ragged_supported
 
     impl = cfg.attention_impl
     interpret = cfg.pallas_interpret
@@ -157,6 +162,25 @@ def resolve_attn_impl(cfg: EngineConfig, mesh: Mesh) -> tuple[str, bool]:
         # gather — otherwise decode would run the XLA path with an
         # unbounded Pmax-wide page table.
         impl = "xla"
+    if mcfg.is_latent:
+        # A latent pool is read by the kernel or, off the TPU, by its
+        # reference; never by the XLA gather on a chip: at 128 heads that
+        # path builds every query's context, a dead end from a few thousand
+        # tokens on. A layout the kernel cannot tile is refused here.
+        from ..models.llama import latent_value_lanes
+
+        on_tpu = mesh.devices.flat[0].platform == "tpu"
+        if on_tpu and not interpret and (impl != "pallas" or not latent_supported(
+            cfg.page_size, mcfg.latent_lanes, latent_value_lanes(mcfg),
+            cfg.kv_dtype_jnp,
+        )):
+            raise ValueError(
+                f"model_type {mcfg.model_type!r} reads its latent pool through "
+                f"the attention kernel alone on a TPU; attention_impl={impl!r}, "
+                f"page_size={cfg.page_size}, {mcfg.latent_lanes} lanes "
+                f"({cfg.kv_dtype}) cannot take it"
+            )
+        return impl, interpret
     if impl == "pallas" and not interpret:
         tp = mesh.shape.get("tp", 1)
         if not ragged_supported(
@@ -204,6 +228,10 @@ class _PendingRagged:
     # includes the verify outputs (and the compiled variant is the
     # spec-carrying one).
     with_spec: bool = False
+    # Live token-steps the dispatch routes (a model that holds a share of
+    # its experts: ``_count_picks``): each makes ``num_experts_per_tok``
+    # picks a routed layer, and ``ys`` ends with how many were held.
+    routed_rows: int = 0
     tokens_dev: object = None  # windowed carry: next window's tokens [nb]
     positions_dev: object = None  # windowed carry: next positions [nb]
     # True when some row could hit its page/model-length cap inside
@@ -258,6 +286,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self.cfg = cfg
         refuse_seq_state_tiers(cfg)
         refuse_block_model_tiers(cfg)
+        refuse_latent_tiers(cfg)
         self.mesh = mesh or build_mesh(tp=cfg.tp, sp=cfg.sp)
         mcfg = cfg.model
 
@@ -306,9 +335,15 @@ class TPUEngine(BlockDecode, AsyncEngine):
         get_telemetry().seq_state_pool_bytes.set(sum(
             leaf.nbytes for leaf in jax.tree.leaves(self.seq_state)
         ))
+        # The pools' geometry, from the pools themselves: a latent model
+        # has one, whose lanes hold ``latent_width`` values and padding.
         layers, pages, page_size, width = self.k_cache.shape
         get_telemetry().set_kv_pool_info(
-            layers, pages, page_size, width, str(self.k_cache.dtype)
+            layers, pages, page_size, width, str(self.k_cache.dtype),
+            pools=sum(1 for pool in (self.k_cache, self.v_cache) if pool.size),
+            pad_lanes=width - mcfg.latent_width if mcfg.is_latent else 0,
+            token_bytes=(self.k_cache.nbytes + self.v_cache.nbytes)
+            // (pages * page_size),
         )
         get_telemetry().watch_jax_compiles()
 
@@ -319,7 +354,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             page_shape = (
                 mcfg.num_attn_layers,
                 cfg.page_size,
-                mcfg.num_kv_heads * mcfg.head_dim_,
+                mcfg.kv_pool_widths[0],
             )
             self.host_pool = HostKvPool(
                 cfg.host_cache_pages, page_shape, cfg.kv_dtype_jnp
@@ -746,6 +781,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # returns it last (the rows' entries are fields of the packed
         # buffer); for every other model there is neither.
         has_state = mcfg.has_seq_state
+        count_picks = bool(mcfg.num_experts_held)
         layout = launch_layout(
             self.cfg, (nb, pages, True, full_sampler, want_lp, False)
         )
@@ -763,13 +799,17 @@ class TPUEngine(BlockDecode, AsyncEngine):
 
         def run_forward(params, tokens, positions, page_table, k, v,
                         state, state_slots):
-            logits, k, v, *state = forward(
+            logits, k, v, *rest = forward(
                 params, mcfg, tokens[:, None], positions[:, None],
                 page_table, k, v, attn_pages=pages, attn_impl=impl,
                 mesh=mesh, interpret=interpret,
                 seq_state=state, state_slots=state_slots,
+                count_picks=count_picks,
             )
-            return logits[:, 0], k, v, (state[0] if state else None)  # [nb, V]
+            # the state pool, then the step's held picks: each where the
+            # model has it (``ys`` carries the picks out, last)
+            picks = (rest.pop(),) if count_picks else ()
+            return logits[:, 0], k, v, (rest[0] if rest else None), picks  # [nb, V]
 
         def advance(positions, max_pos, next_tok, stop_set, eos_gate,
                     budget_gate, t, active):
@@ -808,7 +848,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
 
                 def step(carry, t):
                     tokens, positions, k, v, counts, state = carry
-                    logits, k, v, state = run_forward(
+                    logits, k, v, state, picks = run_forward(
                         params, tokens, positions, page_table, k, v,
                         state, state_slots,
                     )
@@ -847,7 +887,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                         if want_lp
                         else (next_tok,)
                     )
-                    return (tokens, positions, k, v, counts, state), ys
+                    return (tokens, positions, k, v, counts, state), ys + picks
 
                 (tokens, positions, k, v, counts, state), ys = jax.lax.scan(
                     step, (tokens, positions, k, v, counts0, state),
@@ -874,7 +914,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 state_slots = f.get("state_slots")
                 def step(carry, t):
                     tokens, positions, k, v, state = carry
-                    logits, k, v, state = run_forward(
+                    logits, k, v, state, picks = run_forward(
                         params, tokens, positions, page_table, k, v,
                         state, state_slots,
                     )
@@ -897,7 +937,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                         if want_lp
                         else (next_tok,)
                     )
-                    return (tokens, positions, k, v, state), ys
+                    return (tokens, positions, k, v, state), ys + picks
 
                 (tokens, positions, k, v, state), ys = jax.lax.scan(
                     step, (tokens, positions, k, v, state), jnp.arange(K)
@@ -931,6 +971,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         )
 
         has_state = mcfg.has_seq_state  # as in _windowed_program
+        count_picks = bool(mcfg.num_experts_held)
         layout = launch_layout(
             self.cfg, (nb, pages, False, full_sampler, want_lp, with_spec)
         )
@@ -942,13 +983,15 @@ class TPUEngine(BlockDecode, AsyncEngine):
 
         def run_forward(params, k, v, tokens, positions, row_of,
                         page_table, out_idx, state, state_rows):
-            logits, k, v, *state = forward_ragged(
+            logits, k, v, *rest = forward_ragged(
                 params, mcfg, tokens, positions, row_of, page_table,
                 k, v, out_idx, attn_pages=pages, attn_impl=impl,
                 q_tile=q_tile, packed_from=packed_from, mesh=mesh,
                 interpret=interpret, seq_state=state, state_rows=state_rows,
+                count_picks=count_picks,
             )
-            return logits, k, v, (state[0] if state else None)
+            picks = (rest.pop(),) if count_picks else ()  # _windowed_program's
+            return logits, k, v, (rest[0] if rest else None), picks
 
         def out_indices(q_last, spec_idx):
             if not spec_on:
@@ -984,7 +1027,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 spec_idx, spec_pos = f.get("spec_idx"), f.get("spec_pos")
                 spec_drafts, n_drafts = f.get("spec_drafts"), f.get("n_drafts")
                 state_rows = state_rows_of(f)
-                logits_all, k, v, state = run_forward(
+                logits_all, k, v, state, picks = run_forward(
                     params, k, v, tokens, positions, row_of, page_table,
                     out_indices(q_last, spec_idx), state, state_rows,
                 )
@@ -1022,7 +1065,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                         ys = ys + (lp0, tid0, tlp0)
                         if spec_on:
                             ys = ys + pack_spec_lp(spec_logits, targets)
-                out = (ys, k, v, counts_all)
+                out = (ys + picks, k, v, counts_all)
                 return out + (state,) if has_state else out
 
         else:
@@ -1037,7 +1080,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 q_last, spec_idx = f["q_last"], f.get("spec_idx")
                 spec_drafts, n_drafts = f.get("spec_drafts"), f.get("n_drafts")
                 state_rows = state_rows_of(f)
-                logits_all, k, v, state = run_forward(
+                logits_all, k, v, state, picks = run_forward(
                     params, k, v, tokens, positions, row_of, page_table,
                     out_indices(q_last, spec_idx), state, state_rows,
                 )
@@ -1060,7 +1103,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                         ys = ys + (lp0, tid0, tlp0)
                         if spec_on:
                             ys = ys + pack_spec_lp(spec_logits, targets)
-                out = (ys, k, v)
+                out = (ys + picks, k, v)
                 return out + (state,) if has_state else out
 
         return ragged_mixed
@@ -1204,7 +1247,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
         windowed: bool, full_sampler: bool, want_lp: bool,
         with_spec: bool = False, carry: tuple | None = None,
         live: int | None = None, solo: bool = False,
-        capacity_capped: bool = False, **event,
+        capacity_capped: bool = False, routed_rows: int | None = None,
+        **event,
     ) -> _PendingRagged:
         """The life of a dispatch, from its builder's packed buffer and
         rows to the pending record its consumer takes (no host sync;
@@ -1214,8 +1258,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         state a window or a block chained onto the dispatch before it
         starts from; None for any other, and a window or block that is
         not chained gets zeros its program does not read. ``live``:
-        ``_count_dispatch``'s. ``event``: the builder's own fields of
-        the ``dispatch`` flight event."""
+        ``_count_dispatch``'s. ``routed_rows``: ``_PendingRagged``'s (the
+        live count where the builder gives none). ``event``: the
+        builder's own fields of the ``dispatch`` flight event."""
         block = self.cfg.model.block_length
         program = ("block" if block else "window") if windowed else "mixed"
         with self._phase("assemble"), self._part("assemble", "lookup"):
@@ -1272,7 +1317,57 @@ class TPUEngine(BlockDecode, AsyncEngine):
             capacity_capped=capacity_capped,
             packed=packed if windowed else None,
             dispatched_at=dispatched_at,
+            routed_rows=(live or 0) if routed_rows is None else routed_rows,
         )
+
+    def _count_picks(self, pending: _PendingRagged) -> None:
+        """Of a consumed dispatch of a model that holds a share of its
+        experts, the picks that landed on held experts (the program's last
+        output, a pair, or one a window's step), those that lay elsewhere
+        (``dynamo_moe_picks_total``) and the held experts that had a pick
+        (``dynamo_moe_held_expert_reads_total``)."""
+        m = self.cfg.model
+        if not m.num_experts_held:
+            return
+        # [2], or a window's [steps, 2]: picks held, held experts with a pick
+        held, reached = (
+            int(n) for n in np.asarray(pending.ys[-1]).reshape(-1, 2).sum(axis=0)  # dynlint: sync-point(ragged consume)
+        )
+        total = pending.routed_rows * m.num_experts_per_tok * m.num_routed_layers
+        tel = get_telemetry()
+        tel.moe_picks.labels("held").inc(held)
+        tel.moe_picks.labels("elsewhere").inc(max(total - held, 0))
+        tel.moe_held_expert_reads.labels(
+            "window" if pending.windowed else "mixed"
+        ).inc(reached)
+
+    def _count_latent_reads(self, program: str, positions, row_of=None,
+                            steps=None) -> None:
+        """What latent attention attended and read in one dispatch, a
+        layer's (``dynamo_mla_*_total``): every query is absorbed. A mixed
+        dispatch gives its stream's ``positions`` and ``row_of`` (a row's
+        context is read once, up to its last query); a window gives each
+        row's first position and its ``steps`` (a step reads the row's
+        context anew). Host arithmetic on what the builder holds."""
+        if not self.cfg.model.is_latent:
+            return
+        positions = np.asarray(positions, np.int64)
+        if steps is None:
+            live = positions >= 0
+            seen = positions[live] + 1
+            queries, pairs = int(live.sum()), int(seen.sum())
+            by_row = np.zeros(int(row_of.max()) + 1 if len(row_of) else 0, np.int64)
+            np.maximum.at(by_row, row_of[live], seen)
+            context = int(by_row.sum())
+        else:
+            steps = np.asarray(steps, np.int64)
+            queries = int(steps.sum())
+            pairs = int((steps * (positions + 1) + steps * (steps - 1) // 2).sum())
+            context = pairs
+        tel = get_telemetry()
+        tel.mla_query_tokens.labels(program, "absorbed").inc(queries)
+        tel.mla_attended_pairs.labels(program).inc(pairs)
+        tel.mla_context_tokens.labels(program).inc(context)
 
     def _count_dispatch(
         self, program, nb, attn_pages, windowed, full_sampler, want_lp,
@@ -1755,11 +1850,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         m = self.cfg.model
         itemsize = 2 if self.cfg.kv_dtype == "bfloat16" else 4
         return (
-            2
+            sum(m.kv_pool_widths)
             * m.num_attn_layers
             * self.cfg.page_size
-            * m.num_kv_heads
-            * m.head_dim_
             * itemsize
         )
 
@@ -3847,12 +3940,26 @@ class TPUEngine(BlockDecode, AsyncEngine):
             )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(part))
+        fed = self._window_steps(rows, positions, budget_gate)
         return self._launch(
             nb, max_pages, packed, rows, windowed=True,
             full_sampler=full_sampler, want_lp=want_lp,
             live=sum(e.n_valid for e in rows), solo=solo,
-            capacity_capped=capacity_capped, rows=len(part),
+            capacity_capped=capacity_capped, routed_rows=fed, rows=len(part),
         )
+
+    def _window_steps(self, rows: list, first_pos, budget_gate) -> int:
+        """The tokens a window's rows really feed: a row's valid steps, cut
+        where its ``max_tokens`` runs out (``_stop_gates``: the device
+        parks it after step ``budget_gate``; a stop token is the device's
+        to find and is not foreseen). Counts a latent model's reads
+        (``_count_latent_reads``) from each row's first position in
+        ``first_pos`` (indexed by row) and returns the steps' sum."""
+        steps = [min(e.n_valid, int(budget_gate[e.row]) + 1) for e in rows]
+        self._count_latent_reads(
+            "window", [first_pos[e.row] for e in rows], steps=steps
+        )
+        return sum(steps)
 
     def _build_mixed(
         self,
@@ -4037,6 +4144,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             live=int((positions >= 0).sum()), rows=len(rows), tokens=total_q,
         )
         self._count_attn_passes(nb, row_of, positions)
+        self._count_latent_reads("mixed", positions, row_of)
         self._note_prefill_chunks(pf, chunks, nb)
         self.steps += 1
         if spec:
@@ -4176,12 +4284,16 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(rows))
+        fed = self._window_steps(
+            rows, {e.row: len(e.seq.tokens) - 1 + K for e in rows}, budget_gate
+        )
         return self._launch(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
             nb, max_pages, packed, rows, windowed=True,
             full_sampler=pending.full_sampler, want_lp=pending.want_lp,
             carry=(pending.tokens_dev, pending.positions_dev),
             live=sum(e.n_valid for e in rows), solo=True,
-            capacity_capped=capacity_capped, rows=len(rows), chained=True,
+            capacity_capped=capacity_capped, routed_rows=fed, rows=len(rows),
+            chained=True,
         )
 
     # ----------------------------------------------------------------- consume
@@ -4272,10 +4384,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
         with self._phase("sync"):
             if pending.want_lp:
                 sampled, lps, top_ids, top_lps = (
-                    np.asarray(y) for y in pending.ys  # dynlint: sync-point(ragged consume)
+                    np.asarray(y) for y in pending.ys[:4]  # dynlint: sync-point(ragged consume)
                 )
             else:
                 sampled = np.asarray(pending.ys[0])  # dynlint: sync-point(ragged consume)
+            self._count_picks(pending)
         if self.profiler is not None:
             # The np.asarray above was this window's one host sync.
             self.profiler.consume("ragged", pending.dispatched_at, "window")
@@ -4328,6 +4441,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self._flush_outbox()
         with self._phase("sync"):
             ys = [np.asarray(y) for y in pending.ys]  # dynlint: sync-point(ragged consume)
+            self._count_picks(pending)
         tok0 = ys[0]
         i = 1
         if spec_on:

@@ -157,6 +157,34 @@ class ModelConfig:
     remasking_strategy: str = "low_confidence_dynamic"
     confidence_threshold: float = 0.9
     mask_token_id: int = 0
+    # pangu_ultra_moe: multi-head latent attention. ``kv_lora_rank`` > 0
+    # makes a layer's keys and values one latent a token, ``c_kv``
+    # (``kv_lora_rank`` wide, after its norm) beside one rotary key ``k_r``
+    # (``qk_rope_head_dim``, after RoPE) shared by every head: what the page
+    # pool holds (``latent_width`` values a token and layer, in
+    # ``latent_lanes`` lanes) and all it holds. Queries come through a
+    # bottleneck of ``q_lora_rank``; a head's key is ``qk_nope_head_dim``
+    # expanded from the latent beside the rotary part, its value
+    # ``v_head_dim`` expanded likewise (``models/llama.py::_mla_half``).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Experts *held* beside experts routed over: the router scores all
+    # ``num_experts``; this device holds ``num_experts_held`` of them, ids
+    # ``[first_expert_held, first_expert_held + num_experts_held)`` (one
+    # rank's share of an expert-parallel layer), and a pick that lands
+    # elsewhere adds nothing here (``ops/moe.py``). 0: all of them.
+    num_experts_held: int = 0
+    first_expert_held: int = 0
+    # Shared experts without a gate (``n_shared_experts`` SwiGLUs of the
+    # routed experts' width, fused into one): added to every token's
+    # routed sum as they are. 0: none.
+    num_shared_experts: int = 0
+    # What a sigmoid router adds to the sum it renormalises by (lfm2_moe
+    # 1e-6, pangu_ultra_moe 1e-20).
+    router_norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -250,6 +278,43 @@ class ModelConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
+    def experts_held(self) -> int:
+        """Routed experts whose matrices this device holds."""
+        return self.num_experts_held or self.num_experts
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the gateless shared expert (0: none)."""
+        return self.num_shared_experts * self.expert_intermediate_size
+
+    @property
+    def is_latent(self) -> bool:
+        """Whether attention is multi-head latent attention."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token and layer of a latent cache: ``[c_kv | k_r]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """``latent_width`` padded to whole groups of 128 lanes: the width
+        of a latent pool's row and of the absorbed query (the padding is
+        zeros on both sides of the score product)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def kv_pool_widths(self) -> tuple[int, int]:
+        """Lanes of a token's row in the two page pools
+        (``models/llama.py::init_kv_cache``): K's and V's heads side by
+        side, or the latent row and nothing (the values are the row's
+        first ``kv_lora_rank`` lanes)."""
+        if self.is_latent:
+            return self.latent_lanes, 0
+        return (self.num_kv_heads * self.head_dim_,) * 2
+
+    @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
@@ -278,6 +343,8 @@ class ModelConfig:
             return cls._from_jamba(cfg)
         if model_type == "olmo_hybrid":
             return cls._from_olmo_hybrid(cfg)
+        if model_type == "pangu_ultra_moe":
+            return cls._from_pangu_ultra_moe(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -516,6 +583,89 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_pangu_ultra_moe(cls, cfg: dict) -> "ModelConfig":
+        """``pangu_ultra_moe`` (openPangu-Ultra-MoE-718B): multi-head
+        latent attention in every layer; ``first_k_dense_replace`` leading
+        dense SwiGLUs, then ``n_routed_experts`` experts chosen top-k by a
+        sigmoid router (no selection bias, no group limit), the chosen
+        scores renormalised and times ``routed_scaling_factor``, beside
+        ``n_shared_experts`` ungated shared experts; four norms a layer
+        (``sandwich_norm``). ``n_routed_experts`` is what this device
+        *holds*: where the file also gives ``n_routed_experts_global`` (an
+        expert-parallel rank's share: ``benchmark/configs/openpangu-*``),
+        the router is that wide and the held ids start at
+        ``first_routed_expert``. The multi-token-prediction module
+        (``num_nextn_predict_layers``) is refused: nothing here drafts
+        from it."""
+        if cfg.get("num_nextn_predict_layers", 0):
+            raise ValueError(
+                "pangu_ultra_moe with num_nextn_predict_layers > 0 is not "
+                "supported: the multi-token-prediction module is not served"
+            )
+        if not cfg.get("sandwich_norm", True):
+            raise ValueError("pangu_ultra_moe with sandwich_norm=false is not supported")
+        if str(cfg.get("hidden_act", "silu")) != "silu":
+            raise ValueError("pangu_ultra_moe with hidden_act other than silu is not supported")
+        if cfg.get("attention_bias", False):
+            raise ValueError("pangu_ultra_moe with attention_bias=true is not supported")
+        if cfg.get("rope_scaling"):
+            raise ValueError("pangu_ultra_moe with rope_scaling is not supported")
+        if cfg.get("n_group", 1) > 1 or cfg.get("topk_group", 1) > 1:
+            raise ValueError(
+                "pangu_ultra_moe with group-limited selection (n_group / "
+                "topk_group > 1) is not supported"
+            )
+        for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                    "qk_rope_head_dim", "v_head_dim"):
+            if int(cfg.get(key) or 0) <= 0:
+                raise ValueError(f"pangu_ultra_moe needs {key} > 0")
+        if cfg["qk_rope_head_dim"] % 2:
+            raise ValueError("pangu_ultra_moe needs an even qk_rope_head_dim")
+        held = cfg["n_routed_experts"]
+        routed = cfg.get("n_routed_experts_global", held)
+        first = cfg.get("first_routed_expert", 0)
+        if not 0 <= first <= routed - held:
+            raise ValueError(
+                f"pangu_ultra_moe holds experts [{first}, {first + held}) of "
+                f"{routed}: outside the router's range"
+            )
+        if cfg["num_experts_per_tok"] > routed:
+            raise ValueError("pangu_ultra_moe num_experts_per_tok exceeds its experts")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            # one latent a token stands for every head's key and value
+            num_kv_heads=1,
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 131072),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            post_norms=True,
+            num_experts=routed,
+            num_experts_held=held if held < routed else 0,
+            first_expert_held=first,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            num_shared_experts=cfg.get("n_shared_experts", 0) or 0,
+            num_dense_layers=cfg.get("first_k_dense_replace", 0),
+            router_score="sigmoid",
+            router_norm_eps=1e-20,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type="pangu_ultra_moe",
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -536,7 +686,7 @@ class ModelConfig:
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
-    "olmo_hybrid",
+    "olmo_hybrid", "pangu_ultra_moe",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -812,6 +962,39 @@ TINY_OLMO_HYBRID = ModelConfig(  # olmo_hybrid family shape: one period of
     model_type="olmo_hybrid",
 )
 
+TINY_PANGU = ModelConfig(  # pangu_ultra_moe family shape: latent attention
+    # whose ranks are no multiples of 128 (the pool's row is 40 + 16 = 56
+    # values in 128 lanes), one dense layer then two routed ones, 8 of 32
+    # sigmoid-routed experts held (ids 8..15) beside one ungated shared
+    # expert, four norms a layer
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=3,
+    num_heads=4,
+    num_kv_heads=1,
+    head_dim=32,
+    rope_theta=25600000.0,
+    max_position_embeddings=512,
+    post_norms=True,
+    num_experts=32,
+    num_experts_held=8,
+    first_expert_held=8,
+    num_experts_per_tok=4,
+    moe_intermediate_size=32,
+    num_shared_experts=1,
+    num_dense_layers=1,
+    router_score="sigmoid",
+    router_norm_eps=1e-20,
+    routed_scaling_factor=2.5,
+    q_lora_rank=24,
+    kv_lora_rank=40,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=16,
+    v_head_dim=16,
+    model_type="pangu_ultra_moe",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -834,6 +1017,7 @@ PRESETS = {
     "tiny-lfm2": TINY_LFM2,
     "tiny-jamba": TINY_JAMBA,
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
+    "tiny-pangu": TINY_PANGU,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

@@ -124,6 +124,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     }
     if not cfg.pre_norms:  # olmo_hybrid: the halves' outputs are normed alone
         del layers["attn_norm"], layers["mlp_norm"]
+    if cfg.is_latent:  # pangu_ultra_moe: latent attention's seven tensors
+        del layers["wq"], layers["wk"], layers["wv"]
+        layers.update(_init_latent(jax.random.fold_in(rng, 4), cfg, init))
     if cfg.num_linear_layers:  # olmo_hybrid: the gated delta rule
         layers.update(_init_linear(jax.random.fold_in(rng, 3), cfg, init))
     if Lc:  # lfm2: gated short convolution, no bias
@@ -146,8 +149,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         layers["post_attn_norm"] = init_norm((L, D), dt)
         layers["post_ffn_norm"] = init_norm((L, D), dt)
     if cfg.is_moe:
-        E, Ie = cfg.num_experts, cfg.expert_intermediate_size
-        layers["router"] = init(ks[12], (Lr, D, E), D)
+        # the router scores every expert; the matrices are the held ones'
+        E, Ie = cfg.experts_held, cfg.expert_intermediate_size
+        layers["router"] = init(ks[12], (Lr, D, cfg.num_experts), D)
         layers["w_gate"] = init(ks[5], (Lr, E, D, Ie), D)
         layers["w_up"] = init(ks[6], (Lr, E, D, Ie), D)
         layers["w_down"] = init(ks[7], (Lr, E, Ie, D), Ie)
@@ -166,6 +170,11 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             layers["shared_up"] = init(ks[14], (L, D, Is), D)
             layers["shared_down"] = init(ks[15], (L, Is, D), Is)
             layers["shared_router"] = init(ks[16], (L, D), D)
+        if cfg.shared_width:  # pangu_ultra_moe: shared experts, no gate
+            Is = cfg.shared_width
+            layers["shared_gate"] = init(ks[13], (Lr, D, Is), D)
+            layers["shared_up"] = init(ks[14], (Lr, D, Is), D)
+            layers["shared_down"] = init(ks[15], (Lr, Is, D), Is)
     else:
         layers["w_gate"] = init(ks[5], (L, D, I), D)
         layers["w_up"] = init(ks[6], (L, D, I), D)
@@ -178,6 +187,32 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     if not cfg.tie_word_embeddings:
         params["lm_head"] = init(ks[8], (D, V), D)
     return params
+
+
+# Latent attention's parameters, each stacked over the attention layers:
+# the queries' bottleneck (down, its norm, up to every head's ``[nope |
+# rope]``), the latent's (down to ``[c_kv | k_r]``, the norm of ``c_kv``, up
+# to every head's ``[k_nope | v]``) and the output projection ``wo``.
+LATENT_PARAMS = ("w_dq", "q_a_norm", "w_uq", "w_dkv", "kv_a_norm", "w_ukv")
+
+
+def _init_latent(rng, cfg: ModelConfig, init) -> Params:
+    """Random latent-attention projections, ``[L_attn, ...]`` each, and the
+    output projection at the values' width."""
+    dt = _dtype(cfg)
+    La, D, H = cfg.num_attn_layers, cfg.hidden_size, cfg.num_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    kl = jax.random.split(rng, 5)
+    return {
+        "w_dq": init(kl[0], (La, D, rq), D),
+        "q_a_norm": jnp.ones((La, rq), dt),
+        "w_uq": init(kl[1], (La, rq, H * (dn + dr)), rq),
+        "w_dkv": init(kl[2], (La, D, rkv + dr), D),
+        "kv_a_norm": jnp.ones((La, rkv), dt),
+        "w_ukv": init(kl[3], (La, rkv, H * (dn + dv)), rkv),
+        "wo": init(kl[4], (La, H * dv, D), H * dv),
+    }
 
 
 # The step a randomly initialised Mamba layer takes: ``mamba_dt_b`` is the
@@ -290,6 +325,11 @@ def param_shardings(
     }
     if not cfg.pre_norms:
         del layers["attn_norm"], layers["mlp_norm"]
+    if cfg.is_latent:
+        # Replicated: a latent cache has one head, and tp > 1 is refused
+        # (``engine/config.py::refuse_latent_tiers``).
+        del layers["wq"], layers["wk"], layers["wv"]
+        layers.update((k, P()) for k in (*LATENT_PARAMS, "wo"))
     if cfg.num_linear_layers:
         # Replicated: the delta rule under tp > 1 is refused
         # (``engine/config.py::refuse_seq_state_tiers``).
@@ -335,6 +375,10 @@ def param_shardings(
             layers["shared_up"] = P(None, None, tp_axis)
             layers["shared_down"] = P(None, tp_axis, None)
             layers["shared_router"] = P(None, None)
+        if cfg.shared_width:
+            layers["shared_gate"] = P(None, None, tp_axis)
+            layers["shared_up"] = P(None, None, tp_axis)
+            layers["shared_down"] = P(None, tp_axis, None)
     else:
         layers["w_gate"] = P(None, None, tp_axis)
         layers["w_up"] = P(None, None, tp_axis)
@@ -372,17 +416,20 @@ def init_kv_cache(
 
     ``shardings`` (K, V) creates each pool already sharded, so no
     device ever holds more than its own shard.
+
+    A latent-attention model has **one** pool, ``[L, num_pages, ps,
+    cfg.latent_lanes]``: a token's row is ``[c_kv | k_r]`` (the latent
+    after its norm, the rotary key after RoPE) padded with zeros to whole
+    groups of 128 lanes, and its values are that row's first lanes. It is
+    returned first; second comes an array of the same leading shape and
+    **no lanes** (0 bytes), which only keeps the programs' two-pool
+    signatures: nothing writes or reads it (``ModelConfig.kv_pool_widths``).
     """
     dt = dtype or _dtype(cfg)
-    shape = (
-        cfg.num_attn_layers,
-        num_pages,
-        page_size,
-        cfg.num_kv_heads * cfg.head_dim_,
-    )
-    return (
-        jnp.zeros(shape, dt, device=shardings[0]),
-        jnp.zeros(shape, dt, device=shardings[1]),
+    lead = (cfg.num_attn_layers, num_pages, page_size)
+    return tuple(
+        jnp.zeros((*lead, width), dt, device=sharding)
+        for width, sharding in zip(cfg.kv_pool_widths, shardings)
     )
 
 
@@ -555,6 +602,92 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red):
             attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps, off)
         x = x + attn_out
     return x, kv_extra
+
+
+def latent_value_lanes(cfg: ModelConfig) -> int:
+    """Lanes of a latent pool's row the attention kernel takes as a
+    token's values: ``c_kv``'s, up to a whole group of 128 (what lies
+    behind ``kv_lora_rank`` there is dropped after the read)."""
+    return min(cfg.latent_lanes, -(-cfg.kv_lora_rank // 128) * 128)
+
+
+# Stream slots of a latent model's tiled part that one call of the
+# attention kernel takes: the absorbed queries and the latent outputs of a
+# slab ([slots, H, lanes]: 168 KB a slot at 128 heads) are built, read and
+# dropped before the next slab's, so a dispatch of 8192 tokens holds 0.6 GB
+# of them at a time and not 2.4 (``forward_ragged``).
+LATENT_SLAB = 2048
+
+
+def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
+    """A layer's first half where its operator is multi-head latent
+    attention, in the **absorbed** form, for every query of the dispatch
+    (a prompt chunk's and a decode row's alike):
+
+    ``c_q = RMS(u W_dq)``; ``q_h = c_q W_uq -> [q_nope | q_rope]``, RoPE on
+    ``q_rope``; ``[c_kv | k_r] = u W_dkv``, ``c_kv = RMS(c_kv)``, RoPE on
+    ``k_r``: the row ``write`` puts into the pool. ``W_ukv`` a head is
+    ``[W_uk | W_uv]``; ``q'_h = q_nope_h W_uk_h^T`` and the scores are
+    ``(q'_h . c_kv + q_rope_h . k_r) / sqrt(nope + rope)``, so the kernel
+    reads the pool's rows as keys and their first lanes as values, and
+    ``o_h = o_lat_h W_uv_h``: the expanded form's mathematics reordered,
+    with no per-head K or V ever built. ``u`` is the normed stream, the
+    output is normed again before the add (four norms a layer).
+
+    ``read(q, pool, absorb, expand) -> o`` ([B, T, H, v]) runs the kernel
+    on ``absorb(q_part)`` for each part of the stream it reads at a time
+    and hands ``expand`` of each part's result back: the two products
+    either side of the kernel (scope ``mla_absorb``) are then as large as a
+    part, not as the dispatch."""
+    B, T = x.shape[:2]
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    lanes = cfg.latent_lanes
+    pad = lanes - cfg.latent_width
+    with jax.named_scope("mla"):
+        h = rms_norm(x, lp["attn_norm"], eps)
+        with jax.named_scope("mla_q"):
+            c_q = rms_norm(h @ lp["w_dq"], lp["q_a_norm"], eps)
+            q = (c_q @ lp["w_uq"]).reshape(B, T, H, dn + dr)
+            q = jnp.concatenate(
+                [q[..., :dn], apply_rope(q[..., dn:], rope_pos, inv_freq)], axis=-1
+            )
+        with jax.named_scope("mla_kv"):
+            ckr = h @ lp["w_dkv"]  # [B, T, r + dr]
+            c_kv = rms_norm(ckr[..., :r], lp["kv_a_norm"], eps)
+            k_r = apply_rope(ckr[..., None, r:], rope_pos, inv_freq)[..., 0, :]
+            row = jnp.concatenate(
+                [c_kv, k_r, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1
+            )
+            pool = write(row)
+        w_ukv = lp["w_ukv"].reshape(r, H, -1)  # [r, H, nope + v]
+        # One product lays the absorbed query out as the pool's row is:
+        # ``[q_nope W_uk^T | q_rope | 0]``. The rotary part rides an identity
+        # block (exact in any dtype), so the kernel's operand is written
+        # once and never concatenated.
+        w_abs = jnp.concatenate([
+            jnp.pad(
+                jnp.swapaxes(w_ukv[..., :dn], 0, 2), ((0, 0), (0, 0), (0, dr + pad))
+            ),  # [nope, H, lanes]: W_uk^T, zeros behind it
+            jnp.broadcast_to(
+                jnp.pad(jnp.eye(dr, dtype=w_ukv.dtype), ((0, 0), (r, pad)))[:, None],
+                (dr, H, lanes),
+            ),  # [rope, H, lanes]: lane r + j takes q_rope[j]
+        ])
+
+        def absorb(q_part):  # [.., H, nope + rope] -> [.., H, lanes]
+            with jax.named_scope("mla_absorb"):
+                return jnp.einsum("...hd,dhc->...hc", q_part, w_abs)
+
+        def expand(o_lat):  # [.., H, latent_value_lanes] -> [.., H, v]
+            with jax.named_scope("mla_absorb"):
+                return jnp.einsum("...hc,chv->...hv", o_lat[..., :r], w_ukv[..., dn:])
+
+        o = read(q, pool, absorb, expand)
+        with jax.named_scope("attn_out"):
+            attn_out = red(o.reshape(B, T, -1) @ lp["wo"])
+            attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps)
+        return x + attn_out, pool
 
 
 class SeqStateIO(NamedTuple):
@@ -883,15 +1016,16 @@ def _conv_half(x, lp, cfg, eps, pool, layer, io):
         return x + y.reshape(x.shape), pool
 
 
-def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows=False):
+def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows=False,
+              tally=None):
     with jax.named_scope("moe" if "router" in lp else "ffn"):
         return _ffn_block(
-            x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows
+            x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows, tally
         )
 
 
 def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=False,
-               any_rows=False):
+               any_rows=False, tally=None):
     """The layer's second half: norm, dense SwiGLU or sparse-MoE FFN,
     residual add. ``live`` ([B, T] bool) keeps a dispatch's padding
     rows out of the routed experts' sort (``ops/moe.py::moe_ffn``);
@@ -900,7 +1034,19 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
     ``w_down`` are the stacked ``[L, E, ..]`` arrays and which layer of
     them to read (``_scan_paged_layers``); without it they are one
     layer's, as everything else in ``lp`` always is. ``interpret`` runs
-    the experts' kernel off the TPU, as it does the attention kernel."""
+    the experts' kernel off the TPU, as it does the attention kernel.
+
+    Where the model *holds* a share of its experts
+    (``ModelConfig.num_experts_held``: the matrices are experts
+    ``[first_expert_held, first_expert_held + held)`` of the
+    ``num_experts`` the router scores), the routed sum is the held picks'
+    alone, each weighed as normalised over all of the token's picks; a
+    pick elsewhere is another rank's and adds nothing here
+    (``ops/moe.py``). The shared expert (qwen2_moe's behind its sigmoid
+    gate, pangu_ultra_moe's as it is) is added once, and a model with
+    ``post_norms`` norms the routed half's sum as it does a dense one's.
+    ``tally``: ``moe_ffn``'s (the picks held and the experts they reach,
+    where the model holds a share)."""
     B, T = x.shape[:2]
     off = cfg.rms_norm_offset
     h = rms_norm(x, lp["mlp_norm"], eps, off) if "mlp_norm" in lp else x
@@ -908,17 +1054,19 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
         from ..ops.moe import moe_ffn, moe_ffn_ep
 
         shared = None
-        if "shared_gate" in lp:  # qwen2_moe: always-on gated shared expert
-            act = _act(cfg.hidden_act)
-            sg = act((h @ lp["shared_gate"]).astype(jnp.float32)).astype(x.dtype)
-            s_out = (sg * (h @ lp["shared_up"])) @ lp["shared_down"]
-            # Learned sigmoid blend; the gate logit uses the replicated
-            # [D] vector, so it is identical on every tp rank and
-            # commutes with the psum over the I-sharded shared FFN.
-            blend = jax.nn.sigmoid(
-                (h @ lp["shared_router"]).astype(jnp.float32)
-            )[..., None]
-            shared = (blend * s_out.astype(jnp.float32)).astype(x.dtype)
+        if "shared_gate" in lp:  # an always-on shared expert
+            with jax.named_scope("shared_expert"):
+                act = _act(cfg.hidden_act)
+                sg = act((h @ lp["shared_gate"]).astype(jnp.float32)).astype(x.dtype)
+                shared = (sg * (h @ lp["shared_up"])) @ lp["shared_down"]
+                if "shared_router" in lp:  # qwen2_moe: behind a gate
+                    # Learned sigmoid blend; the gate logit uses the replicated
+                    # [D] vector, so it is identical on every tp rank and
+                    # commutes with the psum over the I-sharded shared FFN.
+                    blend = jax.nn.sigmoid(
+                        (h @ lp["shared_router"]).astype(jnp.float32)
+                    )[..., None]
+                    shared = (blend * shared.astype(jnp.float32)).astype(x.dtype)
         if _expert_parallel(mesh):
             if cfg.router_score != "softmax":
                 raise NotImplementedError(
@@ -949,10 +1097,16 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
                 layer=layer, mesh=mesh, interpret=interpret,
                 score=cfg.router_score, select_bias=lp.get("router_bias"),
                 scale=cfg.routed_scaling_factor, any_rows=any_rows,
+                norm_eps=cfg.router_norm_eps,
+                held_from=cfg.first_expert_held if cfg.num_experts_held else None,
+                tally=tally,
             ).reshape(B, T, -1)
             if shared is not None:
                 y = y + shared
-            x = x + red(y)
+            y = red(y)
+            if "post_ffn_norm" in lp:  # pangu_ultra_moe: four norms a layer
+                y = rms_norm(y, lp["post_ffn_norm"], eps, off)
+            x = x + y
     else:
         act = _act(cfg.hidden_act)
         gate = act((h @ lp["w_gate"]).astype(jnp.float32)).astype(x.dtype)
@@ -981,9 +1135,11 @@ def routed_experts_form(
     ``dynamo_moe_dispatch_total`` is labelled by."""
     from ..ops.moe import experts_in_place
 
+    # held experts' rows take the kernel at any count (``moe_ffn``)
     in_place = not _expert_parallel(mesh) and experts_in_place(
         cfg.hidden_size, cfg.expert_intermediate_size,
-        tokens * cfg.num_experts_per_tok, mesh, interpret, any_rows,
+        tokens * cfg.num_experts_per_tok, mesh, interpret,
+        any_rows or bool(cfg.num_experts_held),
     )
     return "inplace" if in_place else "sliced"
 
@@ -1062,7 +1218,10 @@ def _per_layer_xs(cfg, inv_freq):
 # layers of that kind (``init_params``).
 _EVERY_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_ffn_norm")
 _OP_PARAMS = {
-    "attn": ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm"),
+    "attn": (
+        "wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm",
+        *LATENT_PARAMS,
+    ),
     "conv": ("conv_in", "conv_w", "conv_out"),
     "mamba": MAMBA_PARAMS,
     "linear": LINEAR_PARAMS,
@@ -1076,15 +1235,23 @@ _ROUTED_PARAMS = (
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
     live, interpret=False, seq_state=None, state_io=None, scan_form=ScanForm(),
-    experts_any_rows=False,
+    experts_any_rows=False, count_picks=False,
 ):
     """The layer loop of both paged forwards: one ``lax.scan`` for each
     run of layers of one kind (``ModelConfig.layer_runs``), carrying
     ``(x, k_cache, v_cache)`` and, where the model has layers that are not
     softmax attention, ``seq_state`` (a pytree by part: ``init_seq_state``). Returns (x,
-    k_cache, v_cache, seq_state). A model of
+    k_cache, v_cache, seq_state, held_picks): the last None unless
+    ``count_picks`` (a model that holds a share of its experts: int32 [2],
+    the live rows' picks that landed on held experts and the held experts
+    that had one, each summed over the routed layers). A model of
     one kind is one run over the stacked parameters, the per-layer window
     / rope base and the layer index, as it always was.
+
+    A latent-attention model's layers carry one pool (``k_cache``: rows
+    ``[c_kv | k_r]``; ``v_cache`` has no lanes and passes through):
+    ``_mla_half`` writes a token's row and ``read`` gets the pool with no
+    value pool (None).
 
     Attention layer ``l`` (counted among attention layers) writes its new
     K/V with one scatter into the whole ``[L_attn, P, ps, W]`` pool at
@@ -1121,19 +1288,23 @@ def _scan_paged_layers(
     L = cfg.num_layers
     num_pages = k_cache.shape[1]
     flat = (k_cache.shape[0] * num_pages, *k_cache.shape[2:])
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    rope_dim = cfg.qk_rope_head_dim if cfg.is_latent else cfg.head_dim_
+    inv_freq = rope_frequencies(rope_dim, cfg.rope_theta, cfg.rope_scaling)
     eps = cfg.rms_norm_eps
     layers = params["layers"]
     in_place = "router" in layers and routed_experts_form(
         cfg, mesh, x.shape[0] * x.shape[1], interpret, experts_any_rows
     ) == "inplace"
     win_arr, invf_arr, _ = _per_layer_xs(cfg, inv_freq)
+    if cfg.is_latent:
+        invf_arr = jnp.tile(inv_freq[None], (L, 1))
     dense_names = (
         ("dense_gate", "dense_up", "dense_down") if "dense_gate" in layers
         else EXPERT_STACKS
     )
     red = lambda y: y  # noqa: E731 - GSPMD reduces; no explicit psum here
     carry = (x, k_cache, v_cache, seq_state)
+    held_picks = jnp.zeros((2,), jnp.int32)
 
     for op, ff, first, n, op0, ff0 in cfg.layer_runs:
         # name in the body -> (stack, the run's first index in it)
@@ -1182,6 +1353,27 @@ def _scan_paged_layers(
                 x, state = _linear_half(
                     x, lp, cfg, eps, state, l, state_io, scan_form
                 )
+            elif cfg.is_latent:
+                # One pool: a token's row, its values among its lanes.
+                # ``v_pool`` (no lanes) rides the carry untouched.
+
+                def write(row):
+                    with jax.named_scope("kv_write"):
+                        kp, _ = write_kv_pages(
+                            k_pool, None, row.reshape(-1, row.shape[-1]), None,
+                            *write_at, layer=l,
+                        )
+                    return kp
+
+                def read_latent(q, kp, absorb, expand):
+                    return read(
+                        q, kp.reshape(flat), None, table + l * num_pages,
+                        win_l, wrap=(absorb, expand),
+                    )
+
+                x, k_pool = _mla_half(
+                    x, lp, cfg, invf_l, rope_pos, eps, write, read_latent, red
+                )
             else:
 
                 def attend(q, k, v):
@@ -1201,15 +1393,17 @@ def _scan_paged_layers(
                 x, (k_pool, v_pool) = _attn_half(
                     x, lp, cfg, invf_l, rope_pos, eps, attend, red
                 )
+            tally = [] if count_picks else None
             x = _ffn_half(
                 x, lp, cfg, eps, red, mesh, live,
                 (i + ff0 if ff0 else i) if stacks else None, interpret,
-                experts_any_rows,
+                experts_any_rows, tally,
             )
-            return (x, k_pool, v_pool, state), None
+            picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
+            return (x, k_pool, v_pool, state), picks
 
         whole_model = n == L
-        carry, _ = jax.lax.scan(
+        carry, picks = jax.lax.scan(
             layer,
             carry,
             (
@@ -1219,7 +1413,9 @@ def _scan_paged_layers(
                 jnp.arange(n, dtype=jnp.int32),
             ),
         )
-    return carry
+        if count_picks:
+            held_picks = held_picks + jnp.sum(picks, axis=0)
+    return (*carry, held_picks if count_picks else None)
 
 
 def forward(
@@ -1239,6 +1435,7 @@ def forward(
     token_embeds: jnp.ndarray | None = None,
     seq_state: jnp.ndarray | None = None,
     state_slots: jnp.ndarray | None = None,
+    count_picks: bool = False,
 ) -> tuple[jnp.ndarray, ...]:
     """One forward step (prefill or decode by bucket shape).
 
@@ -1256,6 +1453,11 @@ def forward(
     token) gathers one hidden state per row before the vocab projection,
     so chunked prefill pays lm_head FLOPs for B positions instead of
     B*T — the returned logits are then [B, 1, V].
+
+    ``count_picks`` (a model that holds a share of its experts) returns,
+    last, int32 [2]: how many of the live rows' expert picks landed on held
+    experts, and how many held experts had one (the matrices a layer has
+    to fetch), each summed over the routed layers.
 
     ``attn_pages`` (static) bounds the XLA path's page gather: attention
     reads only the first ``attn_pages`` table columns, so short contexts
@@ -1319,19 +1521,28 @@ def forward(
         else None
     )
     have_window = cfg.sliding_window is not None
+    latent = _latent_read_args(cfg)
 
-    def read(q, kf, vf, table_l, win_l):
+    def read(q, kf, vf, table_l, win_l, wrap=None):
+        # ``wrap`` (a latent model: ``_mla_half``'s absorb and expand)
+        # stands either side of the read, which then names itself
+        pre, post, scope = _read_wrap(wrap)
         if use_pallas:
-            return _pallas_decode(
-                q[:, 0], kf, vf, table_l, lengths, cfg.num_kv_heads, mesh,
-                interpret,
-            )[:, None]
-        return paged_attention(
-            q, kf, vf, table_l, positions,
-            sm_scale=sm_scale,
-            window=win_l if have_window else None,
-            softcap=cfg.attn_logit_softcap,
-        )
+            with scope:
+                o = _pallas_decode(
+                    pre(q[:, 0]), kf, vf, table_l, lengths, cfg.num_kv_heads,
+                    mesh, interpret, **latent,
+                )
+            return post(o)[:, None]
+        q = pre(q)
+        with scope:
+            o = paged_attention(
+                q, kf, vf, table_l, positions,
+                window=win_l if have_window else None,
+                softcap=cfg.attn_logit_softcap,
+                **{"sm_scale": sm_scale, **latent},
+            )
+        return post(o)
 
     state_io = None
     if seq_state is not None:
@@ -1343,19 +1554,29 @@ def forward(
             src=jnp.arange(B, dtype=jnp.int32) * T + jnp.maximum(n_real - 1, 0),
             dst=jnp.where(n_real > 0, state_slots, seq_state_entries(seq_state)),
         )
-    x, k_cache, v_cache, seq_state = _scan_paged_layers(
+    x, k_cache, v_cache, seq_state, held_picks = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid),
         page_table if use_pallas else attn_table, read, mesh,
         positions >= 0, interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, T, None, interpret, one_token=T == 1),
+        count_picks=count_picks,
     )
     if last_positions is not None:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
     logits = _final_logits(params, cfg, x, eps)
-    if seq_state is None:
-        return logits, k_cache, v_cache
-    return logits, k_cache, v_cache, seq_state
+    return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks)
+
+
+def _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks):
+    """A paged forward's results: the logits and the pools, then the state
+    pool of a model that has one, then the held picks where asked for."""
+    out = (logits, k_cache, v_cache)
+    if seq_state is not None:
+        out += (seq_state,)
+    if held_picks is not None:
+        out += (held_picks,)
+    return out
 
 
 def _scan_form(cfg, attn_impl, span_align, packed_from, interpret,
@@ -1378,7 +1599,32 @@ def _scan_form(cfg, attn_impl, span_align, packed_from, interpret,
     return ScanForm(kernel, packed_from if kernel else None, one_token, interpret)
 
 
-def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
+def _read_wrap(wrap):
+    """(before, after, scope) of an attention read: ``wrap``'s pair with the
+    ``attend`` scope the caller then leaves to the read, or identities and
+    no scope (the caller has named it)."""
+    import contextlib
+
+    if wrap is None:
+        return (lambda a: a), (lambda a: a), contextlib.nullcontext()
+    return (*wrap, jax.named_scope("attend"))
+
+
+def _latent_read_args(cfg: ModelConfig) -> dict:
+    """What every read of a latent pool is told beside the pool: the
+    scores' scale (the published head's ``nope + rope`` width, not the
+    absorbed query's) and which lanes of a row are its values. Empty for
+    every other model."""
+    if not cfg.is_latent:
+        return {}
+    return {
+        "sm_scale": (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+        "value_dim": latent_value_lanes(cfg),
+    }
+
+
+def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret,
+                   **latent):
     """Dispatch the ragged kernel at its decode shape (one query per
     row), sharded over tp when the mesh has a tp axis wider than 1
     (heads are embarrassingly parallel, so the per-shard kernel sees its
@@ -1393,7 +1639,7 @@ def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
     if tp <= 1:
         return ragged_decode_attention(
             q, kp, vp, page_table, lengths, num_kv_heads=hkv,
-            interpret=interpret,
+            interpret=interpret, **latent,
         )
     from ..parallel.mesh import shard_map
 
@@ -1421,7 +1667,7 @@ def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret):
 
 def _pallas_ragged(
     q, kp, vp, attn_table, row_of, positions, hkv, q_tile, mesh, interpret,
-    q_block=None,
+    q_block=None, **latent,
 ):
     """Dispatch the ragged kernel over a flat mixed query stream,
     sharded over tp exactly like :func:`_pallas_decode` (the kernel is
@@ -1436,7 +1682,7 @@ def _pallas_ragged(
     if tp <= 1:
         return ragged_paged_attention(
             q, kp, vp, attn_table, row_of, positions, num_kv_heads=hkv,
-            q_tile=q_tile, interpret=interpret, q_block=q_block,
+            q_tile=q_tile, interpret=interpret, q_block=q_block, **latent,
         )
     from ..parallel.mesh import shard_map
 
@@ -1484,6 +1730,7 @@ def forward_ragged(
     state_rows: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None = None,
     experts_any_rows: bool = False,
     project: bool = True,
+    count_picks: bool = False,  # ``forward``'s
 ) -> tuple[jnp.ndarray, ...]:
     """One ragged forward over a flat mixed batch (the single-dispatch
     prefill+decode+spec path, docs/engine_perf.md).
@@ -1567,6 +1814,7 @@ def forward_ragged(
         else None
     )
     have_window = cfg.sliding_window is not None
+    latent = _latent_read_args(cfg)
     # What a query may see: its own position, or its block's last.
     bounds = positions
     if cfg.block_length:
@@ -1574,53 +1822,69 @@ def forward_ragged(
             positions >= 0, positions | (cfg.block_length - 1), -1
         )
 
-    def read(q, kf, vf, table_l, win_l):
+    def read(q, kf, vf, table_l, win_l, wrap=None):
+        pre, post, scope = _read_wrap(wrap)  # ``forward``'s
         if use_pallas:
             from ..ops.ragged_attention import query_block
 
             def kernel(part, tile, block=None):
-                return _pallas_ragged(
-                    q[0, part], kf, vf, table_l, row_of[part],
-                    bounds[part], cfg.num_kv_heads, tile, mesh, interpret,
-                    block,
-                )
+                with scope:
+                    o = _pallas_ragged(
+                        pre(q[0, part]), kf, vf, table_l, row_of[part],
+                        bounds[part], cfg.num_kv_heads, tile, mesh, interpret,
+                        block, **latent,
+                    )
+                return post(o)
 
+            def tiles(stop, block=None):
+                # The tiled part [0, stop): one call, or for a latent model
+                # a call a slab (LATENT_SLAB; a slab is whole tiles, and a
+                # tile is a cell of its own: the same reads in the same order)
+                if wrap is None:
+                    return [kernel(slice(0, stop), q_tile, block)]
+                slab = LATENT_SLAB // q_tile * q_tile
+                return [
+                    kernel(slice(a, min(a + slab, stop)), q_tile, block)
+                    for a in range(0, stop, slab)
+                ]
+
+            n = q.shape[1]
             if packed_from is None:
-                return kernel(slice(None), q_tile)[None]
+                parts = tiles(n)
+                return (parts[0] if len(parts) == 1 else jnp.concatenate(parts))[None]
             # A mixed stream's spans run over many tiles of one row: a
             # grid cell takes a block of them (ops/ragged_attention.py).
             return jnp.concatenate([
-                kernel(
-                    slice(0, packed_from), q_tile,
-                    query_block(q_tile, cfg.q_per_kv),
-                ),
+                *tiles(packed_from, query_block(q_tile, cfg.q_per_kv)),
                 kernel(slice(packed_from, None), 1),
             ])[None]
         from ..ops.ragged_attention import ragged_paged_attention_ref
 
-        return ragged_paged_attention_ref(
-            q[0], kf, vf, table_l, row_of, positions,
-            num_kv_heads=cfg.num_kv_heads, sm_scale=sm_scale,
-            window=win_l if have_window else None,
-            softcap=cfg.attn_logit_softcap, bounds=bounds,
-        )[None]
+        q = pre(q[0])
+        with scope:
+            o = ragged_paged_attention_ref(
+                q, kf, vf, table_l, row_of, positions,
+                num_kv_heads=cfg.num_kv_heads,
+                window=win_l if have_window else None,
+                softcap=cfg.attn_logit_softcap, bounds=bounds,
+                **{"sm_scale": sm_scale, **latent},
+            )
+        return post(o)[None]
 
     state_io = None
     if seq_state is not None:
         slots, src, dst = state_rows
         state_io = SeqStateIO(slots, row_of, positions, src, dst)
-    x, k_cache, v_cache, seq_state = _scan_paged_layers(
+    x, k_cache, v_cache, seq_state, held_picks = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid), attn_table, read, mesh,
         (positions >= 0)[None], interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, q_tile, packed_from, interpret),
-        experts_any_rows,
+        experts_any_rows, count_picks,
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
     logits = _final_logits(params, cfg, xo, eps) if project else xo
-    if seq_state is None:
-        return logits, k_cache, v_cache
-    return logits, k_cache, v_cache, seq_state
+    return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks)
 
 
 def forward_ring_prefill(
@@ -1668,6 +1932,11 @@ def forward_ring_prefill(
         raise ValueError(
             "ring prefill does not implement sliding-window attention; "
             "use the paged prefill path for mistral-family models"
+        )
+    if cfg.is_latent:
+        raise ValueError(
+            f"ring prefill rotates per-head K and V; {cfg.model_type} caches "
+            "one latent a token, which lives in the paged layer loop"
         )
     if cfg.has_seq_state:
         raise ValueError(

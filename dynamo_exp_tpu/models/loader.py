@@ -213,8 +213,65 @@ SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
-    "olmo_hybrid",
+    "olmo_hybrid", "pangu_ultra_moe",
 )
+
+
+def _pangu_ultra_moe_params(cfg: ModelConfig, get, linear) -> dict:
+    """``pangu_ultra_moe`` tensor names (DeepSeek-V3's, with the four norms
+    of ``sandwich_norm``) into the stacks by kind of
+    ``models/llama.py::init_params``: ``input_layernorm`` /
+    ``post_attention_layernorm`` / ``pre_mlp_layernorm`` /
+    ``post_mlp_layernorm`` over every layer; latent attention's
+    ``self_attn.{q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
+    kv_a_layernorm, kv_b_proj, o_proj}`` over every layer;
+    ``mlp.{gate,up,down}_proj`` over the dense layers; ``mlp.gate.weight``
+    (the router, every expert it scores), ``mlp.shared_experts.*`` and
+    ``mlp.experts.N.*`` over the routed ones, ``N`` running over the
+    experts this device holds (``first_expert_held`` onward) and no
+    others: an expert-parallel rank reads its share of the checkpoint."""
+    names = (
+        "attn_norm", "post_attn_norm", "mlp_norm", "post_ffn_norm", "w_dq",
+        "q_a_norm", "w_uq", "w_dkv", "kv_a_norm", "w_ukv", "wo", "dense_gate",
+        "dense_up", "dense_down", "router", "shared_gate", "shared_up",
+        "shared_down", "w_gate", "w_up", "w_down",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    proj = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
+    held = range(cfg.first_expert_held, cfg.first_expert_held + cfg.experts_held)
+    for i, ff in enumerate(cfg.ff_kinds):
+        p = f"model.layers.{i}."
+        for key, name in (
+            ("attn_norm", "input_layernorm"), ("post_attn_norm", "post_attention_layernorm"),
+            ("mlp_norm", "pre_mlp_layernorm"), ("post_ffn_norm", "post_mlp_layernorm"),
+        ):
+            layers[key].append(get(f"{p}{name}.weight"))
+        a = p + "self_attn."
+        for key, name in (
+            ("w_dq", "q_a_proj"), ("w_uq", "q_b_proj"), ("w_dkv", "kv_a_proj_with_mqa"),
+            ("w_ukv", "kv_b_proj"), ("wo", "o_proj"),
+        ):
+            layers[key].append(linear(f"{a}{name}.weight"))
+        layers["q_a_norm"].append(get(a + "q_a_layernorm.weight"))
+        layers["kv_a_norm"].append(get(a + "kv_a_layernorm.weight"))
+        m = p + "mlp."
+        if ff == "dense":
+            for key, name in proj:
+                layers[f"dense_{key}"].append(linear(f"{m}{name}.weight"))
+            continue
+        layers["router"].append(linear(m + "gate.weight"))
+        for key, name in proj:
+            if cfg.shared_width:
+                layers[f"shared_{key}"].append(linear(f"{m}shared_experts.{name}.weight"))
+            layers[f"w_{key}"].append(np.stack([
+                linear(f"{m}experts.{e}.{name}.weight") for e in held
+            ]))
+    return {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items() if v},
+        "final_norm": get("model.norm.weight"),
+        "lm_head": linear("lm_head.weight"),
+    }
 
 
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
@@ -249,6 +306,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
     by_kind = {
         "lfm2_moe": _lfm2_moe_params, "jamba": _jamba_params,
         "olmo_hybrid": _olmo_hybrid_params,
+        "pangu_ultra_moe": _pangu_ultra_moe_params,
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

@@ -58,7 +58,8 @@ def write_kv_pages(
     safe_pages = jnp.where(valid, page_ids, num_pages)
     idx = (safe_pages, offsets) if layer is None else (layer, safe_pages, offsets)
     k_cache = k_cache.at[idx].set(k_new.astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[idx].set(v_new.astype(v_cache.dtype), mode="drop")
+    if v_new is not None:  # a latent pool's row holds its values too
+        v_cache = v_cache.at[idx].set(v_new.astype(v_cache.dtype), mode="drop")
     return k_cache, v_cache
 
 
@@ -71,10 +72,14 @@ def paged_attention(
     sm_scale: float | None = None,
     window: int | jnp.ndarray | None = None,
     softcap: float | None = None,
+    value_dim: int | None = None,
 ) -> jnp.ndarray:
     """Causal attention of queries against their sequences' pages.
 
-    Returns [B, T, H, D]. Positions beyond a query's own position are
+    Returns [B, T, H, D] (with ``value_dim``: [B, T, H, value_dim], the
+    values being the first ``value_dim`` lanes of each head's key row and
+    ``v_cache`` unread: a latent pool, ``ops/ragged_attention.py``).
+    Positions beyond a query's own position are
     masked, so garbage in not-yet-written slots never leaks; a padding
     query (position -1) has nothing to attend to and returns exact
     zeros (its softmax would otherwise be uniform over every gathered
@@ -92,7 +97,10 @@ def paged_attention(
 
     # Gather this batch's pages: [B, Pmax, ps, Hkv*D] -> [B, S, Hkv, D]
     k = k_cache[page_table].reshape(B, S, Hkv, D)
-    v = v_cache[page_table].reshape(B, S, Hkv, D)
+    if value_dim is None:
+        v = v_cache[page_table].reshape(B, S, Hkv, D)
+    else:
+        v = k[..., :value_dim]
 
     # QK/PV matmuls run on the MXU in the cache dtype (bfloat16 in
     # production) with float32 accumulation; softmax stays float32.
@@ -122,7 +130,7 @@ def paged_attention(
         preferred_element_type=jnp.float32,
     )
     out = jnp.where(q_positions[:, :, None, None, None] >= 0, out, 0.0)
-    return out.reshape(B, T, H, D).astype(q.dtype)
+    return out.reshape(B, T, H, v.shape[-1]).astype(q.dtype)
 
 
 def dense_causal_attention(

@@ -39,6 +39,21 @@ TPU-first design:
   (Expert parallelism — experts sharded over their own mesh axis — is a
   layout change on the same weights; for inference the tp-within-expert
   layout keeps every chip busy regardless of routing skew.)
+- **A share of the experts** (``held``): a device that is one rank of an
+  expert-parallel layer *holds* ``E_held`` of the ``E`` experts its router
+  scores, ids ``[lo, lo + E_held)``; its weights are ``[.., E_held, ..]``
+  while the router stays ``[D, E]``. ``moe_ffn`` routes over all ``E``
+  (so a pick's weight is normalised over all of a token's picks), keeps
+  the picks that land on held experts and computes those rows; a pick
+  *elsewhere* belongs to another rank and adds nothing here: it sorts past
+  the last group, as a padding row does, and is never gathered. The
+  sorted rows are walked in blocks of ``held_block_rows`` (twice the
+  share an even router gives, so one block in the usual case and as many
+  as the picks ask for: exact at any skew, without a buffer of every
+  pick). Summed over the ranks, with the shared expert added once, that is
+  the whole layer (tests/test_pangu.py, the shares' test); on one device
+  the exchange that would do the sum is absent, and nothing stands in for
+  it.
 """
 
 from __future__ import annotations
@@ -61,6 +76,7 @@ def moe_router(
     score: str = "softmax",
     select_bias: jnp.ndarray | None = None,  # [E] float32
     scale: float = 1.0,
+    norm_eps: float = 1e-6,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k routing. Returns (weights [N, K] float32, expert ids [N, K]).
 
@@ -72,8 +88,9 @@ def moe_router(
     - ``"sigmoid"`` (lfm2_moe): ``s = sigmoid(logits)``; the experts are
       the top-k of ``s + select_bias`` and their weights are ``s`` there,
       so the bias chooses and the unbiased score weighs; with
-      ``norm_topk_prob`` the weights are divided by their sum plus 1e-6
-      (the published form), then multiplied by ``scale``.
+      ``norm_topk_prob`` the weights are divided by their sum plus
+      ``norm_eps`` (the published forms: lfm2_moe 1e-6, pangu_ultra_moe
+      1e-20, and no ``select_bias``), then multiplied by ``scale``.
     """
     logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
     if score == "softmax":
@@ -89,7 +106,7 @@ def moe_router(
     _, ids = jax.lax.top_k(chosen_by, num_experts_per_tok)
     weights = jnp.take_along_axis(s, ids, axis=-1)
     if norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + norm_eps)
     return weights * scale, ids.astype(jnp.int32)
 
 
@@ -155,6 +172,10 @@ def moe_ffn(
     select_bias: jnp.ndarray | None = None,  # [E]: moe_router's, one layer's
     scale: float = 1.0,
     any_rows: bool = False,  # experts_in_place's: the kernel at any row count
+    norm_eps: float = 1e-6,  # moe_router's
+    held_from: int | None = None,  # first expert id held; the weights' E = held
+    held_row_block: int | None = None,  # rows a block of the held walk takes
+    tally: list | None = None,  # with ``held_from``: gets [picks held, experts with one]
 ) -> jnp.ndarray:
     """SwiGLU expert FFN with exact top-k dispatch. Returns [N, D].
 
@@ -175,16 +196,29 @@ def moe_ffn(
     the matmuls read layer ``layer`` of them (module docstring); without
     it they are one layer's. ``mesh`` says where the weights lie: on a
     TPU mesh with ``tp`` > 1 the path runs under ``shard_map``.
+
+    ``held_from`` (module docstring, "a share of the experts"): the
+    weights hold experts ``[held_from, held_from + E_held)`` of the
+    ``E`` the router scores; the picks elsewhere add nothing. The held rows
+    multiply through the kernel at any row count (a block of them is this
+    call's ``rows``). ``tally`` (a list, or None) is then given, as one
+    traced int32 pair, how many of the live rows' picks landed here (the
+    caller's counter of ``held`` against ``elsewhere``) and how many of the
+    held experts had such a pick: the matrices this call has to fetch,
+    which a router that crowds its picks makes far fewer than the held.
     """
     D, width = w_gate.shape[-2:]
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     kernel = experts_in_place(
-        D, width, x.shape[0] * num_experts_per_tok, mesh, interpret, any_rows
+        D, width, x.shape[0] * num_experts_per_tok, mesh, interpret,
+        any_rows or held_from is not None,
     )
     experts = functools.partial(
         _routed_experts,
         num_experts_per_tok=num_experts_per_tok, norm_topk_prob=norm_topk_prob,
         kernel=kernel, interpret=interpret, score=score, scale=scale,
+        norm_eps=norm_eps, held_from=held_from, held_row_block=held_row_block,
+        tally=tally if held_from is not None else None,
     )
     if not (kernel and tp > 1):
         y = experts(x, router_w, w_gate, w_up, w_down, live, layer, select_bias)
@@ -216,20 +250,40 @@ def moe_ffn(
     ).astype(x.dtype)
 
 
+def held_block_rows(rows: int, held: int, routed: int) -> int:
+    """Sorted rows a block of the held walk takes, of ``rows`` picks over
+    ``routed`` experts of which ``held`` are here: twice the share an even
+    router sends here, in whole sublane groups of the kernel's rows, and
+    never more than there are. The usual dispatch is then one block, half
+    of whose rows are live: of a decode window's 256 picks 32 rows are
+    gathered and multiplied, not 256, and a reader of the kernel's calls
+    knows a call's live rows from its shape (``benchmark/lib/
+    held_expert_calls.py``)."""
+    align = expert_gmm.ROW_ALIGN
+    want = -(-2 * rows * held // routed // align) * align
+    return min(rows, max(align, want))
+
+
 def _routed_experts(
     x, router_w, w_gate, w_up, w_down, live, layer, select_bias=None, *,
     num_experts_per_tok, norm_topk_prob, kernel, interpret,
-    score="softmax", scale=1.0,
+    score="softmax", scale=1.0, norm_eps=1e-6, held_from=None,
+    held_row_block=None, tally=None,
 ):
     """``moe_ffn``'s arithmetic on the weights one device holds: [N, D]
     float32, before the sum over ``tp`` where the width is sharded."""
     N, D = x.shape
-    E = router_w.shape[-1]
+    E = w_gate.shape[-3]  # experts held: all the router's unless ``held_from``
     K = num_experts_per_tok
     weights, ids = moe_router(
         x, router_w, K, norm_topk_prob,
-        score=score, select_bias=select_bias, scale=scale,
+        score=score, select_bias=select_bias, scale=scale, norm_eps=norm_eps,
     )
+    if held_from is not None:
+        # A pick elsewhere is another rank's: past every group here.
+        here = (ids >= held_from) & (ids < held_from + E)
+        ids = jnp.where(here, ids - held_from, E)
+        weights = jnp.where(here, weights, 0.0)
     if live is not None:
         ids = jnp.where(live[:, None], ids, E)  # past every group
         weights = jnp.where(live[:, None], weights, 0.0)
@@ -237,31 +291,65 @@ def _routed_experts(
     flat_ids = ids.reshape(-1)  # [N*K]
     # Stable sort so each token's k replicas keep a deterministic order.
     order = jnp.argsort(flat_ids, stable=True)  # [N*K]
-    token_of = order // K  # originating token per sorted row
-    xs = jnp.take(x, token_of, axis=0)  # [N*K, D] in expert order
     group_sizes = jnp.bincount(flat_ids, length=E)
+    flat_w = weights.reshape(-1)
+    if tally is not None:
+        tally.append(jnp.stack(
+            [jnp.sum(group_sizes), jnp.sum(group_sizes > 0)]
+        ).astype(jnp.int32))
 
-    def grouped(rows, w):
+    def grouped(rows, w, sizes):
         if kernel:
             return expert_gmm.expert_gmm(
-                rows, w, group_sizes, layer, interpret=interpret
+                rows, w, sizes, layer, interpret=interpret
             )
         if layer is not None:  # a copy of the layer: ragged_dot takes no slice
             w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-        return jax.lax.ragged_dot(rows, w, group_sizes)
+        return jax.lax.ragged_dot(rows, w, sizes)
 
-    g = grouped(xs, w_gate)
-    u = grouped(xs, w_up)
-    h = (jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u)
-    out = grouped(h, w_down)  # [N*K, D]
+    def add_rows(acc, order_b, sizes, keep):
+        """Add the sorted rows ``order_b`` (``sizes`` of them a group) onto
+        their tokens in ``acc``; ``keep`` ([rows] bool or None) says which
+        lie in a group: the others are not the grouped matmul's to define."""
+        token_of = order_b // K  # originating token per sorted row
+        xs = jnp.take(x, token_of, axis=0)  # [rows, D] in expert order
+        g = grouped(xs, w_gate, sizes)
+        u = grouped(xs, w_up, sizes)
+        h = (jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u)
+        out = grouped(h, w_down, sizes)  # [rows, D]
+        out = out.astype(jnp.float32) * jnp.take(flat_w, order_b)[:, None]
+        if keep is not None:
+            out = jnp.where(keep[:, None], out, 0.0)
+        # Unsort + combine: scatter-add each replica back onto its token.
+        return acc.at[token_of].add(out)
 
-    w_sorted = jnp.take(weights.reshape(-1), order)  # [N*K] float32
-    out = out.astype(jnp.float32) * w_sorted[:, None]
-    if live is not None:
-        # Rows past the last group are not the grouped matmul's to define.
-        out = jnp.where((jnp.take(flat_ids, order) < E)[:, None], out, 0.0)
-    # Unsort + combine: scatter-add each replica back onto its token.
-    return jnp.zeros((N, D), jnp.float32).at[token_of].add(out)
+    acc = jnp.zeros((N, D), jnp.float32)
+    block = N * K if held_from is None else (
+        held_row_block or held_block_rows(N * K, E, router_w.shape[-1])
+    )
+    if block >= N * K:
+        grouped_rows = live is not None or held_from is not None
+        return add_rows(
+            acc, order, group_sizes,
+            jnp.take(flat_ids, order) < E if grouped_rows else None,
+        )
+
+    # The held rows lie first among the sorted ones: walk them a block at a
+    # time, as many blocks as they fill. A block's groups are the parts of
+    # the groups that fall inside it.
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    padded = jnp.concatenate([order, jnp.zeros((block,), order.dtype)])
+
+    def walk(b, acc):
+        lo = b * block
+        sizes = jnp.clip(ends, lo, lo + block) - jnp.clip(starts, lo, lo + block)
+        return add_rows(
+            acc, jax.lax.dynamic_slice_in_dim(padded, lo, block), sizes,
+            lo + jnp.arange(block) < ends[-1],
+        )
+
+    return jax.lax.fori_loop(0, -(-ends[-1] // block), walk, acc)
 
 
 def moe_ffn_ep(

@@ -41,6 +41,19 @@ Two implementations with identical semantics:
   ``q_tile`` in blocks over the stream's aligned spans and at 1 over
   its packed tail, where rows of one token lie side by side).
 
+**A latent pool** (multi-head latent attention, ``models/llama.py::
+_mla_half``): both implementations take ``value_dim``, with which a
+token's values are the first ``value_dim`` lanes of its key row, no value
+pool is read (``v_cache`` None) and the output is ``[N, H, value_dim]``.
+The pool then has one head (``num_kv_heads`` 1) whose row is ``[c_kv |
+k_r]`` padded to whole lane groups, the queries are the absorbed ones of
+the same width, and all ``H`` query heads share the one pass over the
+row's context: 128 heads of a tile of 8 are 1024 score rows, eight times
+what fills the MXU, so a grid cell there is one tile
+(:func:`query_block`) and its products run in the pool's dtype with
+float32 accumulation (exact on bfloat16 inputs, at the MXU's bfloat16
+rate).
+
 HBM traffic per dispatch per layer is ``sum_rows(blocks_row * kv_row)``
 tokens instead of the XLA gather's ``N * S``: the kernel reads a row's
 context once per query block of its span (once per tile in a block it
@@ -86,6 +99,14 @@ def ragged_supported(
     return (num_kv_heads * head_dim) % 128 == 0 and page_size % sublane == 0
 
 
+def latent_supported(page_size: int, lanes: int, value_dim: int, kv_dtype) -> bool:
+    """:func:`ragged_supported` for a latent pool: one head of ``lanes``
+    (the row ``[c_kv | k_r]``, padded to whole lane groups), whose values
+    are its first ``value_dim`` lanes, which must end on a lane group's
+    boundary too (the kernel slices them out of the row in VMEM)."""
+    return ragged_supported(page_size, 1, lanes, kv_dtype) and value_dim % 128 == 0
+
+
 # --------------------------------------------------------------- reference
 def ragged_paged_attention_ref(
     q: jnp.ndarray,  # [N, H, D] flat query stream
@@ -99,6 +120,7 @@ def ragged_paged_attention_ref(
     window: int | jnp.ndarray | None = None,
     softcap: float | None = None,
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
+    value_dim: int | None = None,  # a latent pool: values = the row's first lanes
 ) -> jnp.ndarray:
     """Pure-JAX ragged paged attention (the CPU/tier-1 parity path).
 
@@ -132,6 +154,7 @@ def ragged_paged_attention_ref(
         sm_scale=sm_scale,
         window=window,
         softcap=softcap,
+        value_dim=value_dim,
     )
     return out[:, 0]
 
@@ -149,7 +172,10 @@ def query_block(q_tile: int, q_per_kv: int) -> int:
     (``q_per_kv`` query heads of every slot) has about the MXU's rows,
     a whole number of ``q_tile``s and never fewer than one. From the
     call's shapes alone: the kernel's caller and the engine's counter
-    (``dynamo_attn_query_slots_total``) both ask here."""
+    (``dynamo_attn_query_slots_total``) both ask here. At 128 query heads
+    a KV head (a latent pool's one head) a tile of 8 is already 1024 score
+    rows, whose float32 states are what bounds a cell's VMEM
+    (:func:`_block_vmem_bytes`): the block is the tile."""
     want = max(q_tile, _MXU_ROWS // q_per_kv)
     return -(-want // q_tile) * q_tile
 
@@ -203,16 +229,16 @@ def merged_slot_counts(row_of, positions, q_tile: int, q_block: int):
 
 def _attend(
     q_ref,  # [W, H, D] VMEM — the pass's queries
-    o_ref,  # [W, H, D] VMEM
+    o_ref,  # [W, H, Dv] VMEM
     pos_rows,  # () -> [qpk*W, 1] int32: last kv position a score row sees
     row,  # scalar: the batch row whose pages the pass walks
     kvlen,  # scalar: kv tokens it walks (0 = none)
     table_ref,  # [R, Pmax] int32 SMEM — page ids per row
     k_hbm,  # [P, ps, Hkv*D] — page pool, stays in HBM
-    v_hbm,
+    v_hbm,  # None with ``vd``: the values are lanes of the key row
     k_buf,  # [2, cp, ps, Hkv*D] VMEM double buffer
-    v_buf,
-    acc_ref,  # [>= H*W, D] f32 — output accumulator, rows = (kv head, g, i)
+    v_buf,  # None with ``vd``
+    acc_ref,  # [>= H*W, Dv] f32 — output accumulator, rows = (kv head, g, i)
     m_ref,  # [>= H*W, 128] f32 — running max (lane-replicated)
     l_ref,  # [>= H*W, 128] f32 — running sum (lane-replicated)
     sems,  # DMA semaphores [2, 2*cp]
@@ -225,11 +251,16 @@ def _attend(
     qpk: int,
     pmax: int,
     scale: float,
+    vd: int | None = None,
 ):
     """One double-buffered pass over ``row``'s first ``kvlen`` tokens
     for ``w`` queries: flash-style online softmax, ``qpk * w`` score
     rows a KV head. Every DMA it starts it waits for, so passes follow
-    one another in a grid cell on the same buffers and semaphores."""
+    one another in a grid cell on the same buffers and semaphores.
+    ``vd`` (a latent pool): a head's values are the first ``vd`` lanes of
+    its key row, and the two products take their operands in the pool's
+    dtype (module docstring)."""
+    dv = hd if vd is None else vd
     n_chunks = pl.cdiv(kvlen, ps * cp)
     # The score rows of this pass: all the state's where it is the cell's
     # only one, the first ``H * w`` in a pass of one tile of a block.
@@ -257,14 +288,15 @@ def _attend(
                     k_hbm.at[pid], k_buf.at[slot, j], sems.at[slot, 2 * j]
                 )
             )
-            dmas.append(
-                pltpu.make_async_copy(
-                    v_hbm.at[pid], v_buf.at[slot, j], sems.at[slot, 2 * j + 1]
+            if vd is None:
+                dmas.append(
+                    pltpu.make_async_copy(
+                        v_hbm.at[pid], v_buf.at[slot, j], sems.at[slot, 2 * j + 1]
+                    )
                 )
-            )
         return dmas
 
-    acc_ref[used] = jnp.zeros((n, hd), jnp.float32)
+    acc_ref[used] = jnp.zeros((n, dv), jnp.float32)
     m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
     l_ref[used] = jnp.zeros((n, 128), jnp.float32)
 
@@ -277,6 +309,10 @@ def _attend(
     # [W, H, D] -> [H', W, D] with H' rows ordered (kv head, group):
     # per-kv-head slices are then contiguous row blocks.
     q = jnp.swapaxes(q_ref[...].astype(jnp.float32), 0, 1)  # [H, W, D]
+    if vd is not None:
+        # Back to the pool's dtype once the rows lie (head, query): exact,
+        # the queries came in it.
+        q = q.reshape(hkv * qpk * w, hd).astype(k_buf.dtype)
     S = cp * ps
 
     def body(c, _):
@@ -293,12 +329,17 @@ def _attend(
 
         tok_idx = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
-        v = v_buf[slot].reshape(S, hkv * hd)
+        v = v_buf[slot].reshape(S, hkv * hd) if vd is None else None
         for h in range(hkv):
             rows = slice(h * qpk * w, (h + 1) * qpk * w)
             cols = slice(h * hd, (h + 1) * hd)
-            qh = q[h * qpk : (h + 1) * qpk].reshape(qpk * w, hd)
-            kh = k[:, cols].astype(jnp.float32)  # [S, D]
+            if vd is None:
+                qh = q[h * qpk : (h + 1) * qpk].reshape(qpk * w, hd)
+                kh = k[:, cols].astype(jnp.float32)  # [S, D]
+                vh = v[:, cols]
+            else:
+                qh, kh = q[rows], k[:, cols]
+                vh = k[:, h * hd : h * hd + vd]
             s = (
                 jax.lax.dot_general(
                     qh,
@@ -328,11 +369,11 @@ def _attend(
                 p, axis=1, keepdims=True
             )
             pv = jax.lax.dot_general(
-                p.astype(v.dtype),
-                v[:, cols],
+                p.astype(vh.dtype),
+                vh,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [qpk*W, D]
+            )  # [qpk*W, Dv]
             acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
             m_ref[rows, :] = jnp.broadcast_to(m_new, m_ref[rows, :].shape)
         return 0
@@ -341,9 +382,20 @@ def _attend(
 
     l = l_ref[head, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = acc_ref[used] / l_safe  # [H*W, D], rows (kv head, g, i)
-    out = out.reshape(hkv, qpk, w, hd).transpose(2, 0, 1, 3)
-    o_ref[...] = out.reshape(w, hkv * qpk, hd).astype(o_ref.dtype)
+    out = acc_ref[used] / l_safe  # [H*W, Dv], rows (kv head, g, i)
+    out = out.reshape(hkv, qpk, w, dv).transpose(2, 0, 1, 3)
+    o_ref[...] = out.reshape(w, hkv * qpk, dv).astype(o_ref.dtype)
+
+
+def _pools_out_scratch(refs, vd):
+    """A kernel's references behind its queries, as ``_attend`` names them:
+    (k_hbm, v_hbm, o_ref, (k_buf, v_buf, acc, m, l, sems)). With ``vd`` the
+    call has neither a value pool nor its buffer."""
+    if vd is None:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, *state = refs
+        return k_hbm, v_hbm, o_ref, (k_buf, v_buf, *state)
+    k_hbm, o_ref, k_buf, *state = refs
+    return k_hbm, None, o_ref, (k_buf, None, *state)
 
 
 def _tile_kernel(
@@ -354,15 +406,13 @@ def _tile_kernel(
     table_ref,
     # inputs, output, scratch: _attend's
     q_ref,  # [QT, H, D] VMEM — this tile's queries
-    k_hbm,
-    v_hbm,
-    o_ref,
-    *scratch,
+    *refs,  # _pools_out_scratch's
     qt: int,
     qpk: int,
     **shape,
 ):
     """A grid cell is one tile of ``qt`` slots, all of one row."""
+    k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     t = pl.program_id(0)
     # Per-query absolute positions (the causal bound), read from SMEM.
     # Padding queries carry -1: nothing satisfies kv_pos <= -1, so with
@@ -399,10 +449,7 @@ def _block_kernel(
     # inputs, output, scratch: _attend's, behind the block's positions
     pos_ref,  # [QB, 1] int32 VMEM
     q_ref,  # [QB, H, D] VMEM — this block's queries
-    k_hbm,
-    v_hbm,
-    o_ref,
-    *scratch,
+    *refs,  # _pools_out_scratch's
     qt: int,
     nt: int,
     qpk: int,
@@ -411,6 +458,7 @@ def _block_kernel(
     """A grid cell is a block of ``nt`` tiles. Where they belong to one
     row it reads that row's context once for all ``nt * qt`` queries;
     where to several, tile after tile as :func:`_tile_kernel` does."""
+    k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     b = pl.program_id(0)
     merged_len = block_kvlen_ref[b]
 
@@ -463,12 +511,13 @@ def _block_vmem_bytes(qb, H, D, kv_lanes, cp, ps, q_item, kv_item) -> int:
     jax.jit,
     static_argnames=(
         "num_kv_heads", "q_tile", "sm_scale", "interpret", "q_block",
+        "value_dim",
     ),
 )
 def ragged_paged_attention(
     q: jnp.ndarray,  # [N, H, D] flat query stream (N % q_tile == 0)
     k_cache: jnp.ndarray,  # [P, ps, Hkv*D] (heads collapsed into lanes)
-    v_cache: jnp.ndarray,
+    v_cache: jnp.ndarray | None,  # None with ``value_dim``
     page_table: jnp.ndarray,  # [R, Pmax] int32
     row_of: jnp.ndarray,  # [N] int32 owning row per query token
     positions: jnp.ndarray,  # [N] int32 absolute position, -1 = padding
@@ -478,6 +527,7 @@ def ragged_paged_attention(
     interpret: bool = False,
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
     q_block: int | None = None,  # slots a grid cell serves; None = q_tile
+    value_dim: int | None = None,  # a latent pool: values = the row's first lanes
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
@@ -507,6 +557,10 @@ def ragged_paged_attention(
     ``bounds`` replaces the positions as what a query may see (its own
     position included; -1 for padding): the kernel reads nothing else of
     them, so the tile's DMA bound follows.
+
+    ``value_dim`` (a latent pool, module docstring): no ``v_cache``; a
+    head's values are the first ``value_dim`` lanes of its key row and the
+    result is [N, H, value_dim].
     """
     if bounds is not None:
         positions = bounds
@@ -523,22 +577,34 @@ def ragged_paged_attention(
     shape = dict(
         ps=ps, cp=cp, hkv=Hkv, hd=D, qpk=qpk, qt=qt, pmax=pmax, scale=scale
     )
-
-    def query_spec():
-        return pl.BlockSpec(
-            (qb, H, D), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
+    latent = value_dim is not None
+    Dv = value_dim if latent else D
+    pools = (k_cache,) if latent else (k_cache, v_cache)
+    params = {}
+    if latent:
+        shape["vd"] = value_dim
+        # 128 heads of a tile hold about Mosaic's default 16 MiB.
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, 2 * _block_vmem_bytes(
+                qb, H, max(D, Dv), Hkv * D, cp, ps, q.dtype.itemsize,
+                k_cache.dtype.itemsize,
+            ))
         )
 
-    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    def query_spec(width=D):
+        return pl.BlockSpec(
+            (qb, H, width), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
+        )
+
+    pool_specs = [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
     scratch_shapes = [
-        pltpu.VMEM((2, cp, ps, Hkv * D), k_cache.dtype),
-        pltpu.VMEM((2, cp, ps, Hkv * D), v_cache.dtype),
-        pltpu.VMEM((H * qb, D), jnp.float32),
+        *(pltpu.VMEM((2, cp, ps, Hkv * D), pool.dtype) for pool in pools),
+        pltpu.VMEM((H * qb, Dv), jnp.float32),
         pltpu.VMEM((H * qb, 128), jnp.float32),
         pltpu.VMEM((H * qb, 128), jnp.float32),
         pltpu.SemaphoreType.DMA((2, 2 * cp)),
     ]
-    out_shape = jax.ShapeDtypeStruct((N, H, D), q.dtype)
+    out_shape = jax.ShapeDtypeStruct((N, H, Dv), q.dtype)
     if nt == 1:
         # Per-tile row + DMA bound, derived on device from the flat stream
         # (alignment makes every tile single-row; padding positions are -1
@@ -549,8 +615,8 @@ def ragged_paged_attention(
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_tiles,),
-            in_specs=[query_spec(), pool_spec, pool_spec],
-            out_specs=query_spec(),
+            in_specs=[query_spec(), *pool_specs],
+            out_specs=query_spec(Dv),
             scratch_shapes=scratch_shapes,
         )
         return pl.pallas_call(
@@ -558,7 +624,8 @@ def ragged_paged_attention(
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
-        )(tile_row, tile_kvlen, positions, page_table, q, k_cache, v_cache)
+            **params,
+        )(tile_row, tile_kvlen, positions, page_table, q, *pools)
 
     # The last block of a stream that is no whole number of blocks reads
     # past the queries' end: its plan and positions are padded here to
@@ -576,26 +643,26 @@ def ragged_paged_attention(
                 (qb, 1), lambda t, *_: (t, 0), memory_space=pltpu.VMEM
             ),
             query_spec(),
-            pool_spec,
-            pool_spec,
+            *pool_specs,
         ],
-        out_specs=query_spec(),
+        out_specs=query_spec(Dv),
         scratch_shapes=scratch_shapes,
     )
     vmem = _block_vmem_bytes(
         qb, H, D, Hkv * D, cp, ps, q.dtype.itemsize, k_cache.dtype.itemsize
     )
+    # Mosaic's default scoped VMEM is 16 MiB; 30 heads of 128 queries hold
+    # more than that.
+    params.setdefault("compiler_params", pltpu.CompilerParams(
+        vmem_limit_bytes=max(16 << 20, 2 * vmem)
+    ))
     return pl.pallas_call(
         functools.partial(_block_kernel, nt=nt, **shape),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        # Mosaic's default scoped VMEM is 16 MiB; 30 heads of 128
-        # queries hold more than that.
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=max(16 << 20, 2 * vmem)
-        ),
         interpret=interpret,
-    )(*plan, page_table, pos_col, q, k_cache, v_cache)
+        **params,
+    )(*plan, page_table, pos_col, q, *pools)
 
 
 def ragged_decode_attention(
@@ -606,6 +673,8 @@ def ragged_decode_attention(
     lengths: jnp.ndarray,  # [B] int32 tokens to attend over (0 = inactive)
     num_kv_heads: int | None = None,
     interpret: bool = False,
+    sm_scale: float | None = None,
+    value_dim: int | None = None,
 ) -> jnp.ndarray:
     """Pure-decode shape of the ragged kernel: one query per row at
     ``q_tile=1`` (row b attends its own ``lengths[b]`` tokens; rows
@@ -623,4 +692,6 @@ def ragged_decode_attention(
         num_kv_heads=num_kv_heads,
         q_tile=1,
         interpret=interpret,
+        sm_scale=sm_scale,
+        value_dim=value_dim,
     )

@@ -287,6 +287,53 @@ class Telemetry:
             ["kind"],  # live | pad
             registry=self.registry,
         )
+        self.moe_picks = Counter(
+            "dynamo_moe_picks_total",
+            "Expert picks (a live token's chosen experts, every routed "
+            "layer) of a model that holds a share of the experts its "
+            "router scores, by where the expert lies: held (here: the row "
+            "is computed) or elsewhere (another rank's: nothing is added "
+            "here). held is counted on the device by the programs "
+            "(ops/moe.py::moe_ffn's tally), the total by the builders",
+            ["where"],  # held | elsewhere
+            registry=self.registry,
+        )
+        self.moe_held_expert_reads = Counter(
+            "dynamo_moe_held_expert_reads_total",
+            "Held experts that had a live pick, summed over routed layers "
+            "and dispatches (a window: over its steps too), by program: the "
+            "expert matrices (three each) the grouped matmul has to fetch. "
+            "Counted on the device beside dynamo_moe_picks_total{held}; a "
+            "router that crowds its picks reaches far fewer than are held",
+            ["program"],  # window | mixed
+            registry=self.registry,
+        )
+        self.mla_query_tokens = Counter(
+            "dynamo_mla_query_tokens_total",
+            "Query tokens through multi-head latent attention by program "
+            "and by the form their attention took: absorbed (scores "
+            "against the latent rows, 128 heads over one latent head) or "
+            "expanded (per-head K and V built from the latents; no program "
+            "takes it yet). A layer's: every attention layer sees as many",
+            ["program", "form"],  # window | mixed; absorbed | expanded
+            registry=self.registry,
+        )
+        self.mla_attended_pairs = Counter(
+            "dynamo_mla_attended_pairs_total",
+            "Query-key pairs latent attention attended, by program: a "
+            "query at position p sees p + 1 keys. A layer's and a head's: "
+            "times layers and heads for the model's",
+            ["program"],
+            registry=self.registry,
+        )
+        self.mla_context_tokens = Counter(
+            "dynamo_mla_context_tokens_total",
+            "Context tokens latent attention had to read, by program: a "
+            "row's context once a dispatch (a window: once a step). A "
+            "layer's; times the pool's bytes a token and layer for bytes",
+            ["program"],
+            registry=self.registry,
+        )
         self.moe_dispatches = Counter(
             "dynamo_moe_dispatch_total",
             "Ragged dispatches of routed-expert models by how the expert "
@@ -783,10 +830,14 @@ class Telemetry:
         )
         self.kv_pool_info = Gauge(
             "dynamo_kv_pool_info",
-            "Constant-1 geometry of each of the two device KV pools "
-            "(K and V): stacked layers, pages, tokens a page, fused "
-            "kv-head width, element type",
-            ["layers", "pages", "page_size", "width", "dtype"],
+            "Constant-1 geometry of each of the device KV pools (K and V; "
+            "pools=1: one latent pool whose row is [c_kv | k_r] and no value "
+            "pool): stacked layers, pages, tokens a page, a row's lanes "
+            "(fused kv-head width, or the latent row with its pad_lanes of "
+            "zero padding), element type, and token_bytes, the bytes a "
+            "token holds over all pools and layers",
+            ["layers", "pages", "page_size", "width", "dtype", "pools",
+             "pad_lanes", "token_bytes"],
             registry=self.registry,
         )
         self.jax_compile_events = Counter(
@@ -954,13 +1005,15 @@ class Telemetry:
         ).set(1)
 
     def set_kv_pool_info(
-        self, layers: int, pages: int, page_size: int, width: int, dtype: str
+        self, layers: int, pages: int, page_size: int, width: int, dtype: str,
+        pools: int = 2, pad_lanes: int = 0, token_bytes: int = 0,
     ) -> None:
         """Publish the constant-1 ``dynamo_kv_pool_info`` sample (one
         label set an instance, like ``set_build_info``)."""
         self.kv_pool_info.clear()
         self.kv_pool_info.labels(
-            str(layers), str(pages), str(page_size), str(width), dtype
+            str(layers), str(pages), str(page_size), str(width), dtype,
+            str(pools), str(pad_lanes), str(token_bytes),
         ).set(1)
 
     def watch_jax_compiles(self) -> None:

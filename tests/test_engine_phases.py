@@ -465,6 +465,9 @@ def test_kv_pool_info_names_the_pools_geometry():
         "page_size": str(PS),
         "width": str(TINY.num_kv_heads * TINY.head_dim_),
         "dtype": "float32",
+        "pools": "2",
+        "pad_lanes": "0",
+        "token_bytes": str(2 * TINY.num_layers * TINY.num_kv_heads * TINY.head_dim_ * 4),
     }
 
 

@@ -891,3 +891,67 @@ def test_engine_decodes_with_pallas_interpret(
     assert kernel_windows and {p for _, p, _ in kernel_windows} == {None}
     assert max(nb for nb, _, _ in kernel_windows) >= min(n_requests, 2)
     assert {p for _, p, _ in xla_windows} == {16}
+
+
+# ---------------------------------------------------- a latent pool beside
+def _golden_case(qpk: int, hkv: int, D: int = 128, ps: int = 16):
+    """Seeded inputs of three rows (a span of five tiles deep in its
+    context, a short span with padding, a span of two tiles) at ``qpk``
+    query heads a KV head."""
+    rng = np.random.default_rng(qpk)
+    pages, rows, pmax = 40, 3, 12
+    k = jnp.asarray(rng.standard_normal((pages, ps, hkv * D)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((pages, ps, hkv * D)), jnp.bfloat16)
+    table = jnp.asarray(rng.permutation(pages)[: rows * pmax].reshape(rows, pmax), jnp.int32)
+    pos = np.concatenate(
+        [np.arange(100, 140), np.r_[np.arange(5), -1, -1, -1], np.arange(30, 46)]
+    ).astype(np.int32)
+    row = np.concatenate([np.zeros(40), np.ones(8), np.full(16, 2)]).astype(np.int32)
+    q = jnp.asarray(rng.standard_normal((64, hkv * qpk, D)), jnp.bfloat16)
+    return q, k, v, table, jnp.asarray(row), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("qpk,hkv,digest", [
+    (1, 2, "b8b9bcd7444d11a4"), (8, 1, "b1996551425473f8"), (20, 1, "78e02f78dc374ff9"),
+])
+def test_kernel_bits_unchanged_beside_the_latent_form(qpk, hkv, digest):
+    """The kernel's readings at 1, 8 and 20 query heads a KV head, in
+    tiles, in merged blocks and one query a cell, are to the bit what the
+    parent of PR 44 (commit b4124f8) gave for these inputs: the latent
+    form (``value_dim``) took nothing from the two-pool form's
+    arithmetic."""
+    import hashlib
+
+    from dynamo_exp_tpu.ops.ragged_attention import query_block
+
+    q, k, v, table, row, pos = _golden_case(qpk, hkv)
+    for qt, qb in ((8, None), (8, query_block(8, qpk)), (1, None)):
+        out = ragged_paged_attention(
+            q, k, v, table, row, pos, num_kv_heads=hkv, q_tile=qt, interpret=True,
+            q_block=qb,
+        )
+        got = hashlib.sha256(np.asarray(out.astype(jnp.float32)).tobytes()).hexdigest()
+        assert got[:16] == digest, (qt, qb)
+
+
+@pytest.mark.parametrize("q_tile", [8, 1])
+def test_latent_pool_at_published_widths(q_tile):
+    """One pool of 640 lanes (576 values and padding), the values its
+    first 512, 128 query heads over the one latent head: the kernel
+    against the reference, padding slots exact zeros."""
+    rng = np.random.default_rng(5)
+    pages, ps, lanes, vd, H = 24, 16, 640, 512, 128
+    row_vals = rng.standard_normal((pages, ps, 576))
+    pool = jnp.asarray(np.pad(row_vals, ((0, 0), (0, 0), (0, lanes - 576))), jnp.bfloat16)
+    table = jnp.asarray(rng.permutation(pages)[:20].reshape(2, 10), jnp.int32)
+    pos = np.r_[np.arange(120, 136), np.arange(3), [-1] * 5].astype(np.int32)
+    row = np.r_[np.zeros(16), np.ones(8)].astype(np.int32)
+    q = np.pad(rng.standard_normal((24, H, 576)) * 0.2, ((0, 0), (0, 0), (0, lanes - 576)))
+    q = jnp.asarray(q, jnp.bfloat16)
+    args = (q, pool, None, table, jnp.asarray(row), jnp.asarray(pos))
+    kw = dict(num_kv_heads=1, sm_scale=192 ** -0.5, value_dim=vd)
+    want = ragged_paged_attention_ref(*args, **kw)
+    got = ragged_paged_attention(*args, q_tile=q_tile, interpret=True, **kw)
+    assert got.shape == (24, H, vd)
+    assert float(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()) < 2e-2
+    assert float(jnp.abs(got[19:].astype(jnp.float32)).max()) == 0.0
