@@ -311,6 +311,24 @@ def _model_fingerprint(mcfg) -> dict:
     return out
 
 
+def _attention_form(cfg, attn_impl: str, interpret: bool) -> str:
+    """The form(s) of attention the programs hold: a latent model's mixed
+    programs carry a second attention call where the kernel engine expands
+    rows of many queries (``models/llama.py::latent_form_queries``), so
+    executables built before that rule, or at another threshold, are not
+    these."""
+    if not cfg.model.is_latent:
+        return "heads"
+    from ..models.llama import latent_form_queries
+
+    least = latent_form_queries(
+        cfg.model, cfg.ragged_q_tile, cfg.page_size, cfg.kv_dtype_jnp, interpret
+    ) if attn_impl == "pallas" else None
+    if least is None:
+        return "latent-absorbed"
+    return f"latent-absorbed+expanded-from-{least}-queries"
+
+
 def _engine_fingerprint(cfg, attn_impl: str, interpret: bool) -> dict:
     """The EngineConfig knobs that shape compiled-program bytes or the
     lattice: pool/envelope shapes, bucket policies, the resolved
@@ -326,8 +344,9 @@ def _engine_fingerprint(cfg, attn_impl: str, interpret: bool) -> dict:
         "kv_dtype": cfg.kv_dtype,
         "attention_impl": attn_impl,
         # what the kernel is told to read: heads of K and V in two pools, or
-        # one latent pool in the absorbed form; and the pools' row widths
-        "attention_form": "latent-absorbed" if cfg.model.is_latent else "heads",
+        # one latent pool, absorbed, and expanded for a row with that many
+        # queries in a call; and the pools' row widths
+        "attention_form": _attention_form(cfg, attn_impl, interpret),
         "kv_pool_widths": list(cfg.model.kv_pool_widths),
         "pallas_interpret": interpret,
         "ragged_q_tile": cfg.ragged_q_tile,

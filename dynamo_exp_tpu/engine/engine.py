@@ -1341,17 +1341,36 @@ class TPUEngine(BlockDecode, AsyncEngine):
             "window" if pending.windowed else "mixed"
         ).inc(reached)
 
+    def _latent_form_queries(self) -> int | None:
+        """Live queries of one row in a mixed stream's tiled part from
+        which the row's latent attention takes the expanded form; None:
+        every row is absorbed (``models/llama.py::latent_form_queries``,
+        the function the program itself asks)."""
+        from ..models.llama import latent_form_queries
+
+        if self._attn_impl != "pallas":
+            return None
+        return latent_form_queries(
+            self.cfg.model, self.cfg.ragged_q_tile, self.cfg.page_size,
+            self.cfg.kv_dtype_jnp, self._attn_interpret,
+        )
+
     def _count_latent_reads(self, program: str, positions, row_of=None,
-                            steps=None) -> None:
+                            steps=None, tiled: int = 0) -> None:
         """What latent attention attended and read in one dispatch, a
-        layer's (``dynamo_mla_*_total``): every query is absorbed. A mixed
-        dispatch gives its stream's ``positions`` and ``row_of`` (a row's
-        context is read once, up to its last query); a window gives each
-        row's first position and its ``steps`` (a step reads the row's
-        context anew). Host arithmetic on what the builder holds."""
+        layer's (``dynamo_mla_*_total``), and the form each query took. A
+        mixed dispatch gives its stream's ``positions`` and ``row_of`` (a
+        row's context is read once, up to its last query) and how many of
+        its slots a kernel engine reads ``tiled``: a row with enough queries
+        there took the expanded form for them (``ops/ragged_attention.py::
+        expanded_rows``, the program's rule), everything else the absorbed
+        one. A window gives each row's first position and its ``steps``
+        (a step reads the row's context anew; every step is absorbed).
+        Host arithmetic on what the builder holds."""
         if not self.cfg.model.is_latent:
             return
         positions = np.asarray(positions, np.int64)
+        expanded = 0
         if steps is None:
             live = positions >= 0
             seen = positions[live] + 1
@@ -1359,13 +1378,22 @@ class TPUEngine(BlockDecode, AsyncEngine):
             by_row = np.zeros(int(row_of.max()) + 1 if len(row_of) else 0, np.int64)
             np.maximum.at(by_row, row_of[live], seen)
             context = int(by_row.sum())
+            least = self._latent_form_queries()
+            if least is not None and tiled >= least:
+                from ..ops.ragged_attention import expanded_rows
+
+                rows, at = row_of[:tiled], positions[:tiled]
+                takes = expanded_rows(rows, at, least, len(by_row), xp=np)
+                expanded = int((takes[rows] & (at >= 0)).sum())
         else:
             steps = np.asarray(steps, np.int64)
             queries = int(steps.sum())
             pairs = int((steps * (positions + 1) + steps * (steps - 1) // 2).sum())
             context = pairs
         tel = get_telemetry()
-        tel.mla_query_tokens.labels(program, "absorbed").inc(queries)
+        tel.mla_query_tokens.labels(program, "absorbed").inc(queries - expanded)
+        if expanded:
+            tel.mla_query_tokens.labels(program, "expanded").inc(expanded)
         tel.mla_attended_pairs.labels(program).inc(pairs)
         tel.mla_context_tokens.labels(program).inc(context)
 
@@ -4144,7 +4172,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
             live=int((positions >= 0).sum()), rows=len(rows), tokens=total_q,
         )
         self._count_attn_passes(nb, row_of, positions)
-        self._count_latent_reads("mixed", positions, row_of)
+        self._count_latent_reads(
+            "mixed", positions, row_of, tiled=nb - cfg.ragged_packed_slots(nb)
+        )
         self._note_prefill_chunks(pf, chunks, nb)
         self.steps += 1
         if spec:

@@ -611,6 +611,31 @@ def latent_value_lanes(cfg: ModelConfig) -> int:
     return min(cfg.latent_lanes, -(-cfg.kv_lora_rank // 128) * 128)
 
 
+def latent_form_queries(
+    cfg: ModelConfig, q_tile: int, page_size: int, kv_dtype, interpret: bool = False
+) -> int | None:
+    """The rule by which a row of a kernel call's tiled stream takes the
+    expanded form of latent attention: at least this many live queries of
+    the row in the call (``ops/ragged_attention.py::expanded_min_queries``,
+    from the model's widths and the tile). None: no row does (another kind
+    of model, or widths the expanded call does not tile on a chip). The
+    program (``forward_ragged``) and the engine's counter
+    (``_count_latent_reads``) both ask here."""
+    from ..ops.ragged_attention import expanded_min_queries, expanded_supported
+
+    if not cfg.is_latent:
+        return None
+    if not interpret and not expanded_supported(
+        page_size, cfg.latent_lanes, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+        cfg.v_head_dim, cfg.num_heads, kv_dtype,
+    ):
+        return None
+    return expanded_min_queries(
+        cfg.latent_lanes, latent_value_lanes(cfg), cfg.kv_lora_rank,
+        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, q_tile,
+    )
+
+
 # Stream slots of a latent model's tiled part that one call of the
 # attention kernel takes: the absorbed queries and the latent outputs of a
 # slab ([slots, H, lanes]: 168 KB a slot at 128 heads) are built, read and
@@ -621,24 +646,41 @@ LATENT_SLAB = 2048
 
 def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
     """A layer's first half where its operator is multi-head latent
-    attention, in the **absorbed** form, for every query of the dispatch
-    (a prompt chunk's and a decode row's alike):
+    attention. The projections and the cache are one thing, the form of the
+    attention a function of how many queries of a row meet its context in
+    the call (``latent_form_queries``):
 
     ``c_q = RMS(u W_dq)``; ``q_h = c_q W_uq -> [q_nope | q_rope]``, RoPE on
     ``q_rope``; ``[c_kv | k_r] = u W_dkv``, ``c_kv = RMS(c_kv)``, RoPE on
     ``k_r``: the row ``write`` puts into the pool. ``W_ukv`` a head is
-    ``[W_uk | W_uv]``; ``q'_h = q_nope_h W_uk_h^T`` and the scores are
-    ``(q'_h . c_kv + q_rope_h . k_r) / sqrt(nope + rope)``, so the kernel
-    reads the pool's rows as keys and their first lanes as values, and
-    ``o_h = o_lat_h W_uv_h``: the expanded form's mathematics reordered,
-    with no per-head K or V ever built. ``u`` is the normed stream, the
-    output is normed again before the add (four norms a layer).
+    ``[W_uk | W_uv]``.
 
-    ``read(q, pool, absorb, expand) -> o`` ([B, T, H, v]) runs the kernel
-    on ``absorb(q_part)`` for each part of the stream it reads at a time
-    and hands ``expand`` of each part's result back: the two products
-    either side of the kernel (scope ``mla_absorb``) are then as large as a
-    part, not as the dispatch."""
+    **Absorbed** (a row of few queries: a decode row, every step of a
+    window, a prompt's short last chunk; every row off the kernel):
+    ``q'_h = q_nope_h W_uk_h^T`` and the scores are ``(q'_h . c_kv +
+    q_rope_h . k_r) / sqrt(nope + rope)``, so the kernel reads the pool's
+    rows as keys and their first lanes as values, and ``o_h = o_lat_h
+    W_uv_h``: the expanded form's mathematics reordered, with no per-head K
+    or V ever built, at ``2 (lanes + value lanes)`` operations a query-key
+    pair and head.
+
+    **Expanded** (a row of many queries: a prompt chunk): ``k_h = [c_kv
+    W_uk_h | k_r]`` with the one rotated ``k_r`` shared by the heads, ``v_h =
+    c_kv W_uv_h``, built from the row's pages a chunk of context at a time
+    inside the kernel (``ops/ragged_attention.py::
+    ragged_paged_attention_expanded``), once for all the queries of a pass
+    and never cached: ``2 (nope + rope + v)`` operations a pair and head,
+    the published form's.
+
+    ``u`` is the normed stream, the output is normed again before the add
+    (four norms a layer).
+
+    ``read(q, pool, absorb, expand, w_ukv) -> o`` ([B, T, H, v]) runs the
+    kernel on ``absorb(q_part)`` for each part of the stream it reads
+    absorbed at a time and hands ``expand`` of each part's result back: the
+    two products either side of the kernel (scope ``mla_absorb``) are then
+    as large as a part, not as the dispatch; ``w_ukv`` ([rank, H * (nope +
+    v)]) is what the expanded call multiplies the latents by."""
     B, T = x.shape[:2]
     H, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -683,7 +725,7 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
             with jax.named_scope("mla_absorb"):
                 return jnp.einsum("...hc,chv->...hv", o_lat[..., :r], w_ukv[..., dn:])
 
-        o = read(q, pool, absorb, expand)
+        o = read(q, pool, absorb, expand, lp["w_ukv"])
         with jax.named_scope("attn_out"):
             attn_out = red(o.reshape(B, T, -1) @ lp["wo"])
             attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps)
@@ -1365,10 +1407,10 @@ def _scan_paged_layers(
                         )
                     return kp
 
-                def read_latent(q, kp, absorb, expand):
+                def read_latent(q, kp, absorb, expand, w_ukv):
                     return read(
                         q, kp.reshape(flat), None, table + l * num_pages,
-                        win_l, wrap=(absorb, expand),
+                        win_l, wrap=(absorb, expand, w_ukv),
                     )
 
                 x, k_pool = _mla_half(
@@ -1524,8 +1566,9 @@ def forward(
     latent = _latent_read_args(cfg)
 
     def read(q, kf, vf, table_l, win_l, wrap=None):
-        # ``wrap`` (a latent model: ``_mla_half``'s absorb and expand)
-        # stands either side of the read, which then names itself
+        # ``wrap`` (a latent model: ``_mla_half``'s absorb and expand, and
+        # ``W_ukv`` for a form this forward never takes) stands either side
+        # of the read, which then names itself
         pre, post, scope = _read_wrap(wrap)
         if use_pallas:
             with scope:
@@ -1607,7 +1650,7 @@ def _read_wrap(wrap):
 
     if wrap is None:
         return (lambda a: a), (lambda a: a), contextlib.nullcontext()
-    return (*wrap, jax.named_scope("attend"))
+    return (*wrap[:2], jax.named_scope("attend"))
 
 
 def _latent_read_args(cfg: ModelConfig) -> dict:
@@ -1707,6 +1750,41 @@ def _pallas_ragged(
         )
 
     return f(q, kp, vp, attn_table, row_of, positions)
+
+
+def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
+               sm_scale, interpret, scope, absorbed):
+    """Latent attention over a stream's tiled part in both forms: the rows
+    ``plan`` gave passes (``expanded_plan``: those with at least
+    ``latent_form_queries`` live queries here) through the expanded call,
+    every other row through ``absorbed(part, seen)`` a slab of ``slabs`` at
+    a time, which sees ``-1`` (no query, no page read) where the expanded
+    call serves; a slab with no query left for it skips its call and the
+    two products beside it. The split rides the stream as data: no shape of
+    the program knows it."""
+    from ..ops.ragged_attention import ragged_paged_attention_expanded
+
+    takes, items = plan
+    with scope:
+        o_x = ragged_paged_attention_expanded(
+            q, pool, w_ukv, table, bounds, items, rank=cfg.kv_lora_rank,
+            nope=cfg.qk_nope_head_dim, q_tile=q_tile, sm_scale=sm_scale,
+            interpret=interpret,
+        )
+    seen = jnp.where(takes, -1, bounds)
+    parts = []
+    for part in slabs:
+        shape = (part.stop - part.start, cfg.num_heads, cfg.v_head_dim)
+        parts.append(jax.lax.cond(
+            jnp.any(seen[part] >= 0),
+            lambda part=part: absorbed(part, seen[part]),
+            lambda shape=shape: jnp.zeros(
+                shape, jnp.result_type(o_x.dtype, w_ukv.dtype)
+            ),
+        ))
+    with jax.named_scope("mla_expand"):
+        o_a = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        return jnp.where(takes[:, None, None], o_x, o_a)
 
 
 def forward_ragged(
@@ -1822,17 +1900,36 @@ def forward_ragged(
             positions >= 0, positions | (cfg.block_length - 1), -1
         )
 
+    # A latent model's rows of many queries take the expanded form (None:
+    # none does; ``latent_form_queries``).
+    expand_from = latent_form_queries(
+        cfg, q_tile, ps, k_cache.dtype, interpret
+    ) if use_pallas else None
+    # Which of the tiled part's slots, and in which passes: the stream's,
+    # so planned once for every layer (None: the part is too short for any
+    # row to have that many queries).
+    tiled = positions.shape[0] if packed_from is None else packed_from
+    expand_plan = None
+    if expand_from is not None and tiled >= expand_from:
+        from ..ops.ragged_attention import expanded_plan
+
+        with jax.named_scope("mla_expand"):
+            expand_plan = expanded_plan(
+                row_of[:tiled], bounds[:tiled], page_table.shape[0], q_tile,
+                expand_from,
+            )
+
     def read(q, kf, vf, table_l, win_l, wrap=None):
         pre, post, scope = _read_wrap(wrap)  # ``forward``'s
         if use_pallas:
             from ..ops.ragged_attention import query_block
 
-            def kernel(part, tile, block=None):
+            def kernel(part, tile, block=None, seen=None):
                 with scope:
                     o = _pallas_ragged(
                         pre(q[0, part]), kf, vf, table_l, row_of[part],
-                        bounds[part], cfg.num_kv_heads, tile, mesh, interpret,
-                        block, **latent,
+                        bounds[part] if seen is None else seen, cfg.num_kv_heads,
+                        tile, mesh, interpret, block, **latent,
                     )
                 return post(o)
 
@@ -1843,10 +1940,17 @@ def forward_ragged(
                 if wrap is None:
                     return [kernel(slice(0, stop), q_tile, block)]
                 slab = LATENT_SLAB // q_tile * q_tile
-                return [
-                    kernel(slice(a, min(a + slab, stop)), q_tile, block)
-                    for a in range(0, stop, slab)
+                slabs = [
+                    slice(a, min(a + slab, stop)) for a in range(0, stop, slab)
                 ]
+                if expand_plan is None:
+                    return [kernel(part, q_tile, block) for part in slabs]
+                return [_two_forms(
+                    q[0, :stop], kf, wrap[2], table_l, bounds[:stop],
+                    expand_plan, slabs, cfg, q_tile, latent["sm_scale"],
+                    interpret, scope,
+                    lambda part, seen: kernel(part, q_tile, block, seen),
+                )]
 
             n = q.shape[1]
             if packed_from is None:

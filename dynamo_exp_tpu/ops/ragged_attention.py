@@ -52,7 +52,26 @@ row's context: 128 heads of a tile of 8 are 1024 score rows, eight times
 what fills the MXU, so a grid cell there is one tile
 (:func:`query_block`) and its products run in the pool's dtype with
 float32 accumulation (exact on bfloat16 inputs, at the MXU's bfloat16
-rate).
+rate). That is the **absorbed** form: ``2 * (lanes + value_dim)``
+operations a query-key pair and head, 3.6 x the published form's at 640 and
+512 lanes against 192 + 128, and the right one for a row of few queries.
+
+A row of many queries in a tiled stream (a prompt chunk) takes the
+**expanded** form, :func:`ragged_paged_attention_expanded`, a call of its
+own that no other model's trace reaches: a grid cell is a group of 16
+heads and a *pass* of at most 1,024 of one row's query slots
+(:func:`expanded_plan`, derived on the device from ``row_of`` and the
+positions, so the split between the forms rides the stream as data); it
+walks the row's live pages as ``_attend`` does and turns each chunk of
+latents into the group's K and V in VMEM (one product with its columns of
+``W_ukv``, rounded to the pool's dtype), scores ``q_nope . k_nope +
+q_rope . k_r`` against them and sums ``v``: ``2 * (nope + rope + v)``
+operations a pair and head plus ``2 * rank * (nope + v)`` a context token
+and head ONCE A PASS. Nothing expanded is ever written to HBM, and what is
+read there is the latent row: the call is compute-bound by construction.
+:func:`expanded_min_queries` is where the two forms cost the same (160
+queries at the published widths): the rule both the program and the
+engine's counter apply (:func:`expanded_rows`).
 
 HBM traffic per dispatch per layer is ``sum_rows(blocks_row * kv_row)``
 tokens instead of the XLA gather's ``N * S``: the kernel reads a row's
@@ -66,6 +85,7 @@ block of 128 (PERF.md §6, PR 40).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -663,6 +683,500 @@ def ragged_paged_attention(
         interpret=interpret,
         **params,
     )(*plan, page_table, pos_col, q, *pools)
+
+
+# ------------------------------------------- a latent pool, expanded form
+# Query slots of one row that a grid cell of the expanded call serves with
+# one pass over that row's context (one expansion of it for the cell's
+# heads), and the slots of it a score product takes at a time.
+_EXPANDED_QUERIES = 1024
+_EXPANDED_SUB = 256
+# Context tokens the expanded call expands and scores at a time: the
+# softmax's per-query bookkeeping (a column a query: as many vector
+# registers as a whole score tile) is paid once a chunk.
+_EXPANDED_CHUNK = 512
+# Query rows the expanded call lays out by head, or back by row, at a time.
+_EXPANDED_TURN = 32
+# Heads a cell of the expanded call holds: a whole sublane tile of a
+# bfloat16 ``[slots, heads, width]`` stream, and 4 MB of ``W_ukv``.
+_EXPANDED_HEADS = 16
+# Heads of them whose products and softmax the kernel's code lays side by
+# side (the others follow in a loop): all 16 were 13 % faster than one and
+# 4 MB more code a call, which a variant's warm load pays (PERF.md §6, PR 45).
+_EXPANDED_HEADS_AT_ONCE = 4
+
+
+def expanded_min_queries(
+    lanes: int, value_lanes: int, rank: int, nope: int, rope: int, v: int,
+    q_tile: int,
+) -> int:
+    """Queries of one row in a call from which the expanded form of latent
+    attention costs fewer operations than the absorbed one, in whole tiles.
+    A query-key pair and head costs ``2 * (lanes + value_lanes)`` absorbed
+    (scores over the pool's row, values over its first lanes) and ``2 *
+    (nope + rope + v)`` expanded; building a context token's K and V costs
+    ``2 * rank * (nope + v)`` a head, once for all the row's queries that
+    share a pass. From the widths alone: the program
+    (``models/llama.py::latent_form_queries``) and the engine's counter ask
+    the same function."""
+    saved = 2 * (lanes + value_lanes) - 2 * (nope + rope + v)
+    if saved <= 0:
+        return 2**30
+    least = -(-2 * rank * (nope + v) // saved)
+    return -(-least // q_tile) * q_tile
+
+
+def expanded_supported(
+    page_size: int, lanes: int, rank: int, nope: int, v: int, heads: int, kv_dtype
+) -> bool:
+    """Whether the expanded call compiles on real TPU hardware at these
+    widths: the pool's row splits into ``c_kv`` and ``[k_r | padding]`` on a
+    lane group's boundary, a head's K and V are whole lane groups of
+    ``W_ukv``'s columns, and the heads come in whole cells."""
+    return (
+        latent_supported(page_size, lanes, rank, kv_dtype)
+        and rank % 128 == 0 and nope % 128 == 0 and v % 128 == 0
+        and heads % _EXPANDED_HEADS == 0
+    )
+
+
+def expanded_rows(row_of, positions, min_queries: int, n_rows: int, xp=jnp):
+    """Which rows of a call's stream take the expanded form ([n_rows]
+    bool): those with at least ``min_queries`` live queries in it. ``xp``:
+    ``jnp`` inside the program, ``numpy`` for the engine's counter."""
+    hit = (row_of[:, None] == xp.arange(n_rows)[None]) & (positions >= 0)[:, None]
+    return hit.sum(axis=0) >= min_queries
+
+
+def expanded_items(n: int, n_rows: int, min_queries: int, q_max: int) -> int:
+    """Most passes (:func:`expanded_plan`'s items) a stream of ``n`` slots
+    can hold: a row's span in pieces of at most ``q_max`` slots, and no
+    more rows than ``n // min_queries`` take the form."""
+    return n // q_max + min(n_rows, n // min_queries)
+
+
+def expanded_plan(
+    row_of, positions, n_rows: int, q_tile: int, min_queries: int,
+    q_max: int = _EXPANDED_QUERIES, q_sub: int = _EXPANDED_SUB,
+):
+    """What the expanded call reads, from the stream alone (on the device,
+    once a forward: every layer reads the same stream): ``takes`` [N] bool,
+    the slots of the live tiles of rows that take the form (a tile's padding
+    slots included), and the passes (*items*) ``(row, start, slots, kvlen,
+    sub_kvlen, sub_first)``, each ``[items]`` but the last two, which give
+    the kv tokens the item's every ``q_sub`` slots see and the first
+    position among them (``[items * (q_max // q_sub)]``; a chunk of context
+    wholly before it needs no mask). A row's span (its live tiles, which
+    lie side by side in the stream) is cut into the fewest pieces of at
+    most ``q_max`` slots, of equal length in whole tiles; an item past the
+    last has no slots. Comparisons against the rows' and the items'
+    indices and reductions over the stream: no scatter, whose code and
+    time on the chip were most of what the form cost outside its kernel."""
+    n = positions.shape[0]
+    items = expanded_items(n, n_rows, min_queries, q_max)
+    subs = q_max // q_sub
+    slot = jnp.arange(n, dtype=jnp.int32)
+    live = positions >= 0
+    live_tile = jnp.repeat(live.reshape(-1, q_tile).any(axis=1), q_tile)
+
+    def over(index, size, values, least: bool, where=None):
+        """``[size]``: the least or largest of ``values`` over the slots
+        whose ``index`` is each of ``range(size)`` (and ``where``)."""
+        hit = index[:, None] == jnp.arange(size, dtype=jnp.int32)[None]
+        if where is not None:
+            hit = hit & where[:, None]
+        if least:
+            return jnp.where(hit, values[:, None], 2**30).min(axis=0)
+        return jnp.where(hit, values[:, None], 0).max(axis=0)
+
+    takes = expanded_rows(row_of, positions, min_queries, n_rows)[row_of]
+    span = takes & live_tile
+    first = jnp.minimum(over(row_of, n_rows, slot, True, span), n)
+    extent = jnp.maximum(over(row_of, n_rows, slot + 1, False, span) - first, 0)
+    pieces = -(-extent // q_max)
+    length = -(-extent // jnp.maximum(pieces, 1) // q_tile) * q_tile
+    base = jnp.cumsum(pieces) - pieces
+    item = jnp.where(
+        span,
+        base[row_of] + (slot - first[row_of]) // jnp.maximum(length[row_of], 1),
+        items,
+    )
+    start = over(item, items, slot, True)
+    slots = jnp.maximum(over(item, items, slot + 1, False) - start, 0)
+    start = jnp.where(slots > 0, start, 0)
+    row = over(item, items, row_of, False)
+    kvlen = over(item, items, positions + 1, False)
+    sub = jnp.where(
+        span, item * subs + (slot - start[jnp.minimum(item, items - 1)]) // q_sub,
+        items * subs,
+    )
+    sub_kvlen = over(sub, items * subs, positions + 1, False)
+    sub_first = over(sub, items * subs, positions, True, live)
+    return span, (row, start, slots, kvlen, sub_kvlen, sub_first)
+
+
+def _expanded_kernel(
+    # scalar prefetch (SMEM): expanded_plan's items, and the page table
+    item_row_ref,
+    item_start_ref,
+    item_slots_ref,
+    item_kvlen_ref,
+    sub_kvlen_ref,
+    sub_first_ref,
+    table_ref,
+    # inputs
+    pos_hbm,  # [N + q_max, 128] int32 — last kv position each slot sees (lane-replicated)
+    q_hbm,  # [N, H, nope + (lanes - rank)] — the published form's queries,
+    # zeros behind the rotary part as behind the pool's ``k_r``
+    k_hbm,  # [P, ps, lanes] — the latent pool
+    w_ref,  # [G, rank, nope + v] VMEM — this cell's heads of W_ukv
+    # output
+    o_hbm,  # [N, H, v]
+    # scratch
+    pos_buf,  # [q_max, 128] int32
+    q_raw,  # [q_sub, G, nope + (lanes - rank)]
+    qn_s,  # [G, q_max, nope] — queries by head, the pool's dtype
+    qr_s,  # [G, q_max, lanes - rank] — rotary part, zeros behind it
+    kv_s,  # [G, S, nope + v] — a chunk's K and V of these heads, by head
+    k_buf,  # [2, cp, ps, lanes]
+    acc_ref,  # [G, q_max, v] f32
+    m_ref,  # [G, q_max, 128] f32 (lane-replicated)
+    l_ref,  # [G, q_max, 128] f32
+    o_buf,  # [q_sub, G, v]
+    sems,  # DMA semaphores [2, cp]: a chunk's pages
+    io_sems,  # DMA semaphores [2]: positions; query and result tiles
+    *,
+    qt: int,
+    qs: int,
+    qmax: int,
+    G: int,
+    rank: int,
+    nope: int,
+    v: int,
+    ps: int,
+    cp: int,
+    pmax: int,
+    scale: float,
+    at_once: int,
+):
+    """A grid cell is (a group of ``G`` heads, an item): one pass over the
+    item's row's context, each chunk of latents expanded once to the
+    group's K and V (rounded to the pool's dtype, as the published form
+    rounds them) for all the item's queries, ``qs`` of them a score
+    product. ``_attend``'s softmax, chunk by chunk in the same order."""
+    g, i = pl.program_id(0), pl.program_id(1)
+    slots = item_slots_ref[i]
+    subs = qmax // qs
+    hw = nope + v
+
+    @pl.when(slots > 0)
+    def _():
+        row, kvlen = item_row_ref[i], item_kvlen_ref[i]
+        start = pl.multiple_of(item_start_ref[i], qt)
+        n_sub = pl.cdiv(slots, qs)
+        heads = pl.ds(pl.multiple_of(g * G, G), G)
+        S = cp * ps
+        n_chunks = pl.cdiv(kvlen, S)
+
+        def chunk_dmas(c, slot):
+            base = c * cp
+            return [
+                pltpu.make_async_copy(
+                    k_hbm.at[table_ref[row, jnp.minimum(base + j, pmax - 1)]],
+                    k_buf.at[slot, j], sems.at[slot, j],
+                )
+                for j in range(cp)
+            ]
+
+        @pl.when(n_chunks > 0)
+        def _():
+            for dma in chunk_dmas(0, 0):
+                dma.start()
+
+        pos_dma = pltpu.make_async_copy(
+            pos_hbm.at[pl.ds(start, qmax)], pos_buf, io_sems.at[0]
+        )
+        pos_dma.start()
+
+        def sub_at(j):
+            return pl.ds(pl.multiple_of(j * qs, qs), qs)
+
+        def move_tiles(j, dma):
+            """Start, then wait for, ``dma(t)`` of each whole tile ``t`` of
+            sub-block ``j`` that lies in the item."""
+            tiles = jnp.minimum(slots - j * qs, qs) // qt
+            jax.lax.fori_loop(0, tiles, lambda t, _: (dma(t).start(), 0)[1], 0)
+            jax.lax.fori_loop(0, tiles, lambda t, _: (dma(t).wait(), 0)[1], 0)
+
+        # Rows the kernel turns between [rows, heads] and [heads, rows] at a
+        # time: the code of a turn is a unit's, not a sub-block's.
+        unit = min(qs, _EXPANDED_TURN)
+        assert qs % unit == 0, (qs, unit)
+
+        def unit_at(u, base):
+            return pl.ds(pl.multiple_of(base + u * unit, unit), unit)
+
+        def load(j, _):
+            """Sub-block j's queries, tile by tile (a tile's start is any
+            multiple of ``qt`` in the stream), laid out by head."""
+            move_tiles(j, lambda t: pltpu.make_async_copy(
+                q_hbm.at[pl.ds(start + j * qs + t * qt, qt), heads],
+                q_raw.at[pl.ds(t * qt, qt)], io_sems.at[1],
+            ))
+
+            def by_head(u, _):  # [unit, G, D] -> [G, unit, D], a unit of rows
+                x = jnp.swapaxes(
+                    q_raw[unit_at(u, 0), :, :].astype(jnp.float32), 0, 1
+                )
+                at = unit_at(u, j * qs)
+                qn_s[:, at, :] = x[..., :nope].astype(qn_s.dtype)
+                qr_s[:, at, :] = x[..., nope:].astype(qr_s.dtype)
+                acc_ref[:, at, :] = jnp.zeros((G, unit, v), jnp.float32)
+                m_ref[:, at, :] = jnp.full((G, unit, 128), -1e30, jnp.float32)
+                l_ref[:, at, :] = jnp.zeros((G, unit, 128), jnp.float32)
+                return 0
+
+            jax.lax.fori_loop(0, qs // unit, by_head, 0)
+            return 0
+
+        jax.lax.fori_loop(0, n_sub, load, 0)
+        pos_dma.wait()
+
+        def chunk(c, _):
+            slot = jax.lax.rem(c, 2)
+
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                for dma in chunk_dmas(c + 1, jax.lax.rem(c + 1, 2)):
+                    dma.start()
+
+            for dma in chunk_dmas(c, slot):
+                dma.wait()
+            latent = k_buf[slot].reshape(S, k_buf.shape[-1])
+            # K and V of the group's heads, a head at a time: one product
+            # with its columns of W_ukv, rounded once to the pool's dtype.
+            c_kv = latent[:, :rank]
+
+            def expand(h, _):
+                kv_s[h] = jax.lax.dot_general(
+                    c_kv, w_ref[h], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).astype(kv_s.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, G, expand, 0)
+            k_rope = latent[:, rank:]  # [S, rope | padding], every head's
+            tok_idx = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+
+            def update(j, masked: bool):
+                at = sub_at(j)
+                if masked:
+                    inside = (
+                        j * qs + jax.lax.broadcasted_iota(jnp.int32, (qs, 1), 0)
+                        < slots
+                    )
+                    live = tok_idx <= jnp.where(inside, pos_buf[at, :1], -1)
+
+                def head(h, _):
+                    kh, vh = kv_s[h, :, :nope], kv_s[h, :, nope:]
+                    s = (jax.lax.dot_general(
+                        qn_s[h, at, :], kh, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    ) + jax.lax.dot_general(
+                        qr_s[h, at, :], k_rope, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )) * scale
+                    if masked:
+                        s = jnp.where(live, s, -1e30)
+                    m_prev = m_ref[h, at, :1]
+                    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(s - m_new)
+                    if masked:
+                        p = jnp.where(live, p, 0.0)  # _attend's
+                    l_ref[h, at, :] = l_ref[h, at, :] * alpha + jnp.sum(
+                        p, axis=1, keepdims=True
+                    )
+                    acc_ref[h, at, :] = acc_ref[h, at, :] * alpha + (
+                        jax.lax.dot_general(
+                            p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
+                    )
+                    m_ref[h, at, :] = jnp.broadcast_to(m_new, (qs, 128))
+                    return 0
+
+                # The heads a few at a time, not all side by side: a cell's
+                # code is a few heads', and a variant's executable loads
+                # fast; a few, so that one's products overlap another's
+                # softmax.
+                def some(i, _):
+                    for k in range(at_once):
+                        head(i * at_once + k, 0)
+                    return 0
+
+                jax.lax.fori_loop(0, G // at_once, some, 0)
+
+            def sub(j, _):
+                # A sub-block wholly before the chunk sees none of it; one
+                # wholly behind it sees all of it and masks nothing (what
+                # a slot without a query computes there is dropped at the
+                # store).
+                seen, first = sub_kvlen_ref[i * subs + j], sub_first_ref[i * subs + j]
+
+                @pl.when((seen > c * S) & (first >= (c + 1) * S - 1))
+                def _():
+                    update(j, masked=False)
+
+                @pl.when((seen > c * S) & (first < (c + 1) * S - 1))
+                def _():
+                    update(j, masked=True)
+
+                return 0
+
+            jax.lax.fori_loop(0, n_sub, sub, 0)
+            return 0
+
+        jax.lax.fori_loop(0, n_chunks, chunk, 0)
+
+        def store(j, _):
+            def by_row(u, _):  # [G, unit, v] -> [unit, G, v]
+                at = unit_at(u, j * qs)
+                l = l_ref[:, at, :1]
+                out = acc_ref[:, at, :] / jnp.where(l == 0.0, 1.0, l)
+                out = jnp.where((pos_buf[at, :1] >= 0)[None], out, 0.0)
+                o_buf[unit_at(u, 0), :, :] = jnp.swapaxes(out, 0, 1).astype(o_buf.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, qs // unit, by_row, 0)
+
+            move_tiles(j, lambda t: pltpu.make_async_copy(
+                o_buf.at[pl.ds(t * qt, qt)],
+                o_hbm.at[pl.ds(start + j * qs + t * qt, qt), heads],
+                io_sems.at[1],
+            ))
+            return 0
+
+        jax.lax.fori_loop(0, n_sub, store, 0)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "rank", "nope", "q_tile", "sm_scale", "interpret", "q_max", "q_sub",
+        "kv_chunk",
+    ),
+)
+def ragged_paged_attention_expanded(
+    q: jnp.ndarray,  # [N, H, nope + rope] the published form's queries
+    k_cache: jnp.ndarray,  # [P, ps, lanes] the latent pool
+    w_ukv: jnp.ndarray,  # [rank, H * (nope + v)], a head's [W_uk | W_uv]
+    page_table: jnp.ndarray,  # [R, Pmax] int32
+    positions: jnp.ndarray,  # [N] int32, -1 = padding
+    plan: tuple,  # expanded_plan's items for this stream, at q_max and q_sub
+    *,
+    rank: int,
+    nope: int,
+    q_tile: int,
+    sm_scale: float,
+    interpret: bool = False,
+    q_max: int = _EXPANDED_QUERIES,  # slots a pass serves at most
+    q_sub: int = _EXPANDED_SUB,  # slots of them a score product takes
+    kv_chunk: int = _EXPANDED_CHUNK,  # context tokens expanded at a time
+) -> jnp.ndarray:
+    """Latent attention in the **expanded** form for the rows of a tiled
+    stream that :func:`expanded_plan` gave passes (those with at least its
+    ``min_queries`` live queries in the stream): ``o [N, H, v]`` holds the
+    result at the slots the plan's ``takes`` marks (those rows' padding
+    slots exact zeros) and nothing a caller may read anywhere else: the
+    caller serves the other rows in the absorbed form
+    (:func:`ragged_paged_attention` at ``value_dim``, their positions
+    alone) and picks by ``takes``.
+
+    A head's key is ``[c_kv W_uk_h | k_r]`` and its value ``c_kv W_uv_h``,
+    built from the row's pages (``[c_kv | k_r | 0]`` a token) a chunk at a
+    time inside the kernel, rounded to the pool's dtype, and never stored:
+    scores ``(q_nope . k_nope + q_rope . k_r) * sm_scale`` in the pool's
+    dtype with float32 accumulation, softmax in float32
+    (``_expanded_kernel``). The stream's contract is the tiled call's: a
+    row's span is whole tiles of ``q_tile`` slots side by side, written
+    before it is read."""
+    N, H, D = q.shape
+    _, ps, lanes = k_cache.shape
+    v = w_ukv.shape[1] // H - nope
+    pmax = page_table.shape[1]
+    qmax, qs = q_max, q_sub
+    G = _EXPANDED_HEADS if H % _EXPANDED_HEADS == 0 else H
+    cp = max(1, min(kv_chunk // ps, pmax))
+    S = cp * ps
+    # What the form costs outside its kernel, a layer: two paddings and a
+    # layout of W_ukv (the plan is the forward's, not the layer's).
+    with jax.named_scope("mla_expand"):
+        # The rotary part meets the row's ``[k_r | 0]`` lanes: zeros behind it.
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, nope + lanes - rank - D)))
+        D = q.shape[-1]
+        # A column a slot, replicated over a lane group: any window of it
+        # is whole tiles for the DMA.
+        pos_col = jnp.broadcast_to(
+            jnp.pad(positions, (0, qmax), constant_values=-1)[:, None],
+            (N + qmax, 128),
+        )
+        # A head's [W_uk | W_uv] as one block of a cell's operand.
+        w_heads = jnp.swapaxes(w_ukv.reshape(rank, H, nope + v), 0, 1)
+    items = plan[0].shape[0]
+    kv_item, q_item = k_cache.dtype.itemsize, q.dtype.itemsize
+    vmem = (
+        2 * rank * G * (nope + v) * w_ukv.dtype.itemsize  # W_ukv, pipelined
+        + G * qmax * (nope + (lanes - rank)) * kv_item  # queries by head
+        + G * qmax * (v + 2 * 128) * 4  # softmax states
+        + S * G * (nope + v) * kv_item  # a chunk's K and V
+        + 2 * S * (nope + v) * 4  # a head's, as values
+        + 2 * qs * max(G, 16) * (-(-D // 128) * 128 + v) * (q_item + 4)
+        + 2 * cp * ps * lanes * kv_item + qmax * 512
+        + 8 * qs * S * 4  # scores and probabilities of a head in flight
+    )
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(H // G, items),
+        in_specs=[
+            any_space, any_space, any_space,
+            pl.BlockSpec(
+                (G, rank, nope + v), lambda g, i, *_: (g, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+        ],
+        out_specs=any_space,
+        scratch_shapes=[
+            pltpu.VMEM((qmax, 128), jnp.int32),
+            pltpu.VMEM((qs, G, D), q.dtype),
+            pltpu.VMEM((G, qmax, nope), k_cache.dtype),
+            pltpu.VMEM((G, qmax, lanes - rank), k_cache.dtype),
+            pltpu.VMEM((G, S, nope + v), k_cache.dtype),
+            pltpu.VMEM((2, cp, ps, lanes), k_cache.dtype),
+            pltpu.VMEM((G, qmax, v), jnp.float32),
+            pltpu.VMEM((G, qmax, 128), jnp.float32),
+            pltpu.VMEM((G, qmax, 128), jnp.float32),
+            pltpu.VMEM((qs, G, v), q.dtype),
+            pltpu.SemaphoreType.DMA((2, cp)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    o = pl.pallas_call(
+        functools.partial(
+            _expanded_kernel, qt=q_tile, qs=qs, qmax=qmax, G=G, rank=rank,
+            nope=nope, v=v, ps=ps, cp=cp, pmax=pmax, scale=sm_scale,
+            at_once=math.gcd(G, _EXPANDED_HEADS_AT_ONCE),
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((N, H, v), q.dtype),
+        interpret=interpret,
+        name="ragged_paged_attention_expanded",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, min(100 << 20, vmem * 5 // 4)),
+        ),
+    )(*plan, page_table, pos_col, q, k_cache, w_heads)
+    return o
 
 
 def ragged_decode_attention(

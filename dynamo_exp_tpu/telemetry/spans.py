@@ -313,8 +313,9 @@ class Telemetry:
             "Query tokens through multi-head latent attention by program "
             "and by the form their attention took: absorbed (scores "
             "against the latent rows, 128 heads over one latent head) or "
-            "expanded (per-head K and V built from the latents; no program "
-            "takes it yet). A layer's: every attention layer sees as many",
+            "expanded (per-head K and V built from the latents inside the "
+            "kernel; a row with many queries in a mixed dispatch's tiled "
+            "part). A layer's: every attention layer sees as many",
             ["program", "form"],  # window | mixed; absorbed | expanded
             registry=self.registry,
         )

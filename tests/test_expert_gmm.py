@@ -378,6 +378,41 @@ def test_the_sharded_expert_path_compiles_for_four_chips_with_no_copy_of_a_weigh
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def test_the_expanded_latent_call_compiles_for_a_v5e_at_the_published_widths(topo):
+    """The other kernel of a main path that this file's fixture can reach
+    (one process holds the TPU's compiler): latent attention's expanded
+    call at openPangu-Ultra-MoE's widths over a 4,032-slot stream, as the
+    mixed program of 4,096 slots calls it. Mosaic takes its VMEM (16 heads
+    of 1,024 queries' softmax states, a 512-token chunk's K and V), its
+    windows at any multiple of the tile and its name. Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        expanded_min_queries, expanded_plan, ragged_paged_attention_expanded,
+    )
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    heads, rank, nope, rope, v, lanes, n = 128, 512, 128, 64, 128, 640, 4032
+    least = expanded_min_queries(lanes, 512, rank, nope, rope, v, 8)
+
+    def call(q, pool, w, table, row_of, positions):
+        _, items = expanded_plan(row_of, positions, table.shape[0], 8, least)
+        return ragged_paged_attention_expanded(
+            q, pool, w, table, positions, items, rank=rank, nope=nope, q_tile=8,
+            sm_scale=(nope + rope) ** -0.5,
+        )
+
+    compiled = jax.jit(call).lower(
+        sds((n, heads, nope + rope), jnp.bfloat16), sds((5 * 24576, 16, lanes), jnp.bfloat16),
+        sds((rank, heads * (nope + v)), jnp.bfloat16), sds((33, 1024), jnp.int32),
+        sds((n,), jnp.int32), sds((n,), jnp.int32),
+    ).compile()
+    assert least == 160
+    assert re.search(r"ragged_paged_attention_expanded[.\d]* = bf16\[4032,128,128\]", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # ------------------------------------------------------ (e) the counter
 def _counter(name: str) -> dict:
     from dynamo_exp_tpu.telemetry import get_telemetry

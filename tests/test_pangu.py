@@ -3,7 +3,10 @@ latent attention over one latent page pool (no value pool), a share of the
 experts held beside a shared expert, four norms a layer. Tiny sizes, seeded
 weights, float32, so the engine and the plain reference
 (``benchmark/references/openpangu_moe.py``, the EXPANDED form) differ by
-float32 rounding alone, though the engine computes the ABSORBED form.
+float32 rounding alone, whichever form the engine computes: the ABSORBED
+one everywhere off the kernel and for a row of few queries, the expanded
+one for a row of at least 8 queries (tiny-pangu's widths and a tile of 8:
+``latent_form_queries``) in a kernel call's tiled stream.
 
 TOLERANCES. Both sides compute in float32 (the reference at ``highest``
 matmul precision, the engine at the CPU's default, also float32 products):
@@ -218,17 +221,94 @@ def _faulty(monkeypatch, fault):
             monkeypatch.setattr(llama, "rms_norm", rms_norm)
         else:
             monkeypatch.setattr(llama, "apply_rope", apply_rope)
+    elif fault in ("uk_and_uv_swapped", "rotary_key_a_head_apart"):
+        # faults of the expanded form alone: what its call is handed
+        from dynamo_exp_tpu.ops import ragged_attention
+
+        call = ragged_attention.ragged_paged_attention_expanded
+        r, dn = CFG.kv_lora_rank, CFG.qk_nope_head_dim
+
+        def faulty(q, pool, w_ukv, *args, **kw):
+            if fault == "uk_and_uv_swapped":
+                w = w_ukv.reshape(r, CFG.num_heads, -1)
+                w_ukv = jnp.concatenate([w[..., dn:], w[..., :dn]], axis=-1).reshape(r, -1)
+            else:  # the shared key taken as if each head had a slice of it
+                k_r = jnp.roll(pool[..., r:CFG.latent_width], 1, axis=-1)
+                pool = pool.at[..., r:CFG.latent_width].set(k_r)
+            return call(q, pool, w_ukv, *args, **kw)
+
+        monkeypatch.setattr(ragged_attention, "ragged_paged_attention_expanded", faulty)
 
 
-@pytest.mark.parametrize("fault", ["scale_without_rope", "latent_before_norm", "key_before_rope"])
-def test_a_fault_in_the_latent_half_shows(params, monkeypatch, fault):
+@pytest.mark.parametrize("impl,fault", [
+    ("xla", "scale_without_rope"), ("xla", "latent_before_norm"), ("xla", "key_before_rope"),
+    # the expanded form (rows of 16 queries through the kernel): its scale,
+    # its one rotated key, and which half of W_ukv makes keys
+    ("pallas", "scale_without_rope"), ("pallas", "key_before_rope"),
+    ("pallas", "rotary_key_a_head_apart"), ("pallas", "uk_and_uv_swapped"),
+])
+def test_a_fault_in_the_latent_half_shows(params, monkeypatch, impl, fault):
     """What the tolerance is far below: scores scaled by 1/sqrt(nope), the
-    latent cached before its norm, the rotary key cached before RoPE."""
+    latent cached before its norm, the rotary key cached before RoPE, in
+    either form; in the expanded one also ``W_uk`` and ``W_uv`` exchanged
+    and a rotary key that is not the one all heads share."""
     T = 32
     want = reference.log_probs(params, HF, PROMPT[:T], range(T))
     _faulty(monkeypatch, fault)
-    got, _ = _ragged(params, "xla", ((0, 16), (16, T)))
+    jax.clear_caches()  # a jitted call of the sound form must not answer
+    got, _ = _ragged(params, impl, ((0, 16), (16, T)))
+    jax.clear_caches()
     assert np.abs(got - want).max() > 30 * LOGP_TOL
+
+
+def test_two_rows_of_one_dispatch_take_the_two_forms(params):
+    """A stream whose tiled part holds a chunk of 19 queries on a context
+    another dispatch wrote (expanded) beside another sequence's chunk of 5
+    (absorbed: under the rule's 8), a decode row of a third in the packed
+    tail and padding tiles: each row's logits are its own uncut forward's,
+    and they are what the absorbed form alone gives (the reference path)."""
+    k, v = _pools()
+    a, b, c = PROMPT[:30], PROMPT[30:35], PROMPT[35:45]
+    table = jnp.stack([jnp.arange(1, 6), jnp.arange(6, 11), jnp.arange(11, 16)]).astype(jnp.int32)
+    table = jnp.concatenate([table, jnp.zeros((1, 5), jnp.int32)])
+    for row, ids in ((0, a[:11]), (2, c[:9])):  # what earlier dispatches wrote
+        _, k, v = llama.forward(
+            params, CFG, jnp.asarray(ids)[None], jnp.arange(len(ids), dtype=jnp.int32)[None],
+            table[row:row + 1], k, v,
+        )
+    tokens = a[11:30] + [0] * 5 + b + [0] * 3 + [0] * 8 + [c[9]] + [0] * 7
+    positions = list(range(11, 30)) + [-1] * 5 + list(range(5)) + [-1] * 3 + [-1] * 8 + [9] + [-1] * 7
+    row_of = [0] * 24 + [1] * 8 + [3] * 8 + [2] + [3] * 7
+    args = (
+        jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32),
+        jnp.asarray(row_of, jnp.int32), table,
+    )
+    out_idx = jnp.asarray([18, 28, 40])
+    logits, *_ = llama.forward_ragged(
+        params, CFG, *args, k, v, out_idx, attn_impl="pallas", q_tile=8, packed_from=40,
+        interpret=True,
+    )
+    absorbed, *_ = llama.forward_ragged(params, CFG, *args, k, v, out_idx, attn_impl="xla")
+    got = _logp(logits)
+    for i, (ids, at) in enumerate(((a, 29), (b, 4), (c, 9))):
+        assert np.abs(got[i] - reference.log_probs(params, HF, ids, [at])[0]).max() < LOGP_TOL
+    assert np.abs(got - _logp(absorbed)).max() < LOGP_TOL
+    assert llama.latent_form_queries(CFG, 8, PS, jnp.float32, interpret=True) == 8
+
+
+def test_the_rule_is_the_models_widths_and_none_off_the_kernels_tiling():
+    """160 queries at the published widths on a chip; tiny-pangu's ranks
+    tile in interpret mode alone; no other kind of model has a rule."""
+    from dynamo_exp_tpu.models import TINY
+
+    published = dataclasses.replace(
+        TINY_PANGU, num_heads=128, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+    )
+    assert llama.latent_form_queries(published, 8, 16, jnp.bfloat16) == 160
+    assert llama.latent_form_queries(TINY_PANGU, 8, 16, jnp.bfloat16) is None
+    assert llama.latent_form_queries(TINY_PANGU, 8, 16, jnp.bfloat16, interpret=True) == 8
+    assert llama.latent_form_queries(TINY, 8, 16, jnp.bfloat16, interpret=True) is None
 
 
 def test_mixed_stream_with_padding(params):
@@ -480,6 +560,8 @@ async def test_engine_through_the_kernel():
     programs and the experts' kernel in place."""
     eng = _engine(attention_impl="pallas", pallas_interpret=True)
     eng.start()
+    name = "dynamo_mla_query_tokens_total"
+    before = {form: _counter(name, form=form) for form in ("expanded", "absorbed")}
     try:
         prompt = PROMPT[3:30]
         got = await _run(eng, prompt, 10)
@@ -487,6 +569,57 @@ async def test_engine_through_the_kernel():
         assert eng._attn_impl == "pallas"
     finally:
         eng.stop()
+    # 27 prompt tokens in chunks of 16 and 11, each in a bucket of 16 slots
+    # whose tiled part is 8 (``ragged_packed_slots``: the span runs on into
+    # the packed tail): 8 queries of each chunk meet the rule's 8 in the tiled
+    # part and took the expanded form; the 8 + 3 in the tail and the 9 fed
+    # decode tokens (windows) the absorbed one
+    assert _counter(name, form="expanded") - before["expanded"] == 16
+    assert _counter(name, form="absorbed") - before["absorbed"] == 11 + 9
+
+
+def test_the_counter_asks_the_programs_rule(engine):
+    """``_count_latent_reads`` and the program split one stream alike: the
+    labels ``expanded`` and ``absorbed`` add up to the dispatch's query
+    tokens, and ``expanded`` counts the live queries of the slots the
+    device's plan marks (``expanded_plan``), for a stream with a row at the
+    rule's threshold, one a query under it, a long one and a decode row in
+    the packed tail. An engine off the kernel counts every query absorbed."""
+    from dynamo_exp_tpu.ops.ragged_attention import expanded_plan
+
+    eng = types.SimpleNamespace(
+        cfg=dataclasses.replace(engine.cfg, attention_impl="pallas"),
+        _attn_impl="pallas", _attn_interpret=True,
+    )
+    for method in ("_count_latent_reads", "_latent_form_queries"):
+        setattr(eng, method, types.MethodType(getattr(TPUEngine, method), eng))
+    least = eng._latent_form_queries()
+    assert least == llama.latent_form_queries(CFG, 8, PS, jnp.float32, interpret=True) == 8
+    spans = [(8, 20), (7, 0), (21, 3)]
+    row_of, positions = [], []
+    for r, (n, first) in enumerate(spans):
+        pad = (-n) % 8
+        row_of += [r] * (n + pad)
+        positions += list(range(first, first + n)) + [-1] * pad
+    tiled = len(row_of) + 8
+    row_of += [4] * 8 + [3] + [4] * 7
+    positions += [-1] * 8 + [30] + [-1] * 7
+    row_of, positions = np.asarray(row_of, np.int32), np.asarray(positions, np.int32)
+    name = "dynamo_mla_query_tokens_total"
+    before = {form: _counter(name, program="mixed", form=form) for form in ("expanded", "absorbed")}
+    eng._count_latent_reads("mixed", positions, row_of, tiled=tiled)
+    took = {
+        form: _counter(name, program="mixed", form=form) - before[form]
+        for form in ("expanded", "absorbed")
+    }
+    takes, _ = expanded_plan(
+        jnp.asarray(row_of[:tiled]), jnp.asarray(positions[:tiled]), 5, 8, least
+    )
+    assert took["expanded"] == int((np.asarray(takes) & (positions[:tiled] >= 0)).sum()) == 29
+    assert took["expanded"] + took["absorbed"] == int((positions >= 0).sum()) == 37
+    before = _counter(name, form="expanded")
+    engine._count_latent_reads("mixed", positions, row_of, tiled=tiled)
+    assert _counter(name, form="expanded") == before and engine._latent_form_queries() is None
 
 
 async def test_preempt_and_resume():
@@ -584,13 +717,62 @@ def test_manifest_names_the_attention_form_and_the_pools_widths():
     from dynamo_exp_tpu.aot.lattice import build_manifest
     from dynamo_exp_tpu.models import TINY
 
-    def fingerprint(model):
+    def manifest(model, impl="pallas", interpret=False):
         cfg = EngineConfig(model=model, max_decode_slots=4, page_size=16, num_pages=64)
-        return build_manifest(cfg, "pallas", {"tp": 1}, "x", interpret=False).engine
+        return build_manifest(cfg, impl, {"tp": 1}, "x", interpret=interpret)
+
+    def fingerprint(model, **kw):
+        return manifest(model, **kw).engine
 
     assert fingerprint(TINY_PANGU)["attention_form"] == "latent-absorbed"
     assert fingerprint(TINY_PANGU)["kv_pool_widths"] == [128, 0]
     assert fingerprint(TINY)["attention_form"] == "heads"
+    # The form is in the key: programs that hold the expanded call beside the
+    # absorbed one (the published widths on a chip, from 160 queries a row)
+    # are not the absorbed-only ones a warm boot might find.
+    published = dataclasses.replace(
+        TINY_PANGU, num_heads=128, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+    )
+    both = fingerprint(published)["attention_form"]
+    assert both == "latent-absorbed+expanded-from-160-queries"
+    assert fingerprint(published, impl="xla")["attention_form"] == "latent-absorbed"
+    assert fingerprint(TINY_PANGU, interpret=True)["attention_form"] == (
+        "latent-absorbed+expanded-from-8-queries"
+    )
+    hashes = {
+        manifest(TINY_PANGU).hash(), manifest(TINY_PANGU, interpret=True).hash(),
+    }
+    assert len(hashes) == 2
+
+
+def test_the_expanded_forms_scope_is_what_its_metric_reads():
+    """``mla_expand_time_share`` (the benchmark's, data alone) reads the scope
+    the program gives the expanded form's work outside its kernel, and
+    neither the kernel's own call nor the absorbed form's products; the call
+    keeps the name the attention metrics find it by."""
+    import inspect
+    import json
+    import re
+
+    from dynamo_exp_tpu.ops import ragged_attention
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    with open(os.path.join(root, "benchmark", "layer_metrics", "mla_expand_time_share.json")) as f:
+        reader = json.load(f)["reader"]
+    assert reader["kind"] == "trace_scope_share"
+    scope = re.compile(reader["scope"])
+    at = "jit(ragged_mixed)/while/body/closed_call/mla/attend/"
+    assert scope.search(at + "mla_expand/pad") and scope.search(at + "mla_expand/scatter-max")
+    assert not scope.search(at + "jit(ragged_paged_attention_expanded)/pallas_call")
+    assert not scope.search(at + "mla_absorb/dot_general")
+    for module in (llama, ragged_attention):
+        assert 'jax.named_scope("mla_expand")' in inspect.getsource(module)
+    assert 'name="ragged_paged_attention_expanded"' in inspect.getsource(ragged_attention)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "mla_expand_time_share"]
+    assert entry["workloads"] == ["openpangu-ultra-moe-718b.longdoc-saturate"]
+    assert entry["moves"] == "output_tok_s" and entry["layer"] == "Kernels"
 
 
 # ------------------------------------------------------------------ loader

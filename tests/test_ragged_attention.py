@@ -955,3 +955,212 @@ def test_latent_pool_at_published_widths(q_tile):
     assert got.shape == (24, H, vd)
     assert float(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()) < 2e-2
     assert float(jnp.abs(got[19:].astype(jnp.float32)).max()) == 0.0
+
+
+# ------------------------------------------- a latent pool, expanded form
+_TINY_WIDTHS = dict(H=4, r=40, dn=16, dr=16, dv=16, lanes=128)
+_PUBLISHED_WIDTHS = dict(H=2, r=512, dn=128, dr=64, dv=128, lanes=640)  # a few heads
+# (live queries, kv length) a row; a span is padded to whole tiles of 8
+_EXPANDED_STREAMS = {
+    # one row cut into three passes (q_max 16) of two score products each,
+    # deep in a context an earlier dispatch wrote
+    "one_row_several_blocks": [(40, 100)],
+    # both sides of the rule in one stream: 24 queries expanded, 5 absorbed
+    "expanded_beside_absorbed": [(24, 60), (5, 30)],
+    # a context of more than two 64-token chunks of latents
+    "crosses_the_chunk": [(19, 150), (8, 129)],
+    # the rule's two sides: one query under the threshold, and at it
+    "under_and_at_the_threshold": [(7, 40), (8, 40)],
+    # a prompt's first chunk: nothing before it
+    "from_the_start": [(33, 33), (16, 16)],
+}
+
+
+def _latent_case(spans, seed, *, H, r, dn, dr, dv, lanes, ps=8, tail_tiles=2):
+    """A latent pool and the published form's queries over ``spans``, with
+    ``tail_tiles`` padding tiles behind them; beside them the pools a
+    two-pool reference reads (every head's ``[c_kv W_uk | k_r]``, its
+    ``c_kv W_uv`` padded to the key's width) and the absorbed queries."""
+    rng = np.random.default_rng(seed)
+    pmax = max(-(-kv // ps) for _, kv in spans)
+    pages = 1 + sum(-(-kv // ps) for _, kv in spans)
+    table = np.zeros((len(spans) + 1, pmax), np.int32)
+    row_of, positions, page = [], [], 1
+    for i, (n, kv) in enumerate(spans):
+        need = -(-kv // ps)
+        table[i, :need] = page + rng.permutation(need)
+        page += need
+        pad = (-n) % 8
+        row_of += [i] * (n + pad)
+        positions += list(range(kv - n, kv)) + [-1] * pad
+    row_of += [len(spans)] * 8 * tail_tiles
+    positions += [-1] * 8 * tail_tiles
+    pool = np.zeros((pages, ps, lanes), np.float32)
+    pool[..., : r + dr] = rng.standard_normal((pages, ps, r + dr))
+    q = rng.standard_normal((len(row_of), H, dn + dr)).astype(np.float32)
+    w = (rng.standard_normal((r, H, dn + dv)) * r ** -0.5).astype(np.float32)
+    c, k_r = pool[..., :r], pool[..., r : r + dr]
+    kx = np.concatenate([
+        np.einsum("psc,chd->pshd", c, w[..., :dn]),
+        np.broadcast_to(k_r[:, :, None], (pages, ps, H, dr)),
+    ], axis=-1).reshape(pages, ps, -1)
+    vx = np.pad(
+        np.einsum("psc,chd->pshd", c, w[..., dn:]), ((0, 0),) * 3 + ((0, dn + dr - dv),)
+    ).reshape(pages, ps, -1)
+    q_abs = np.zeros((len(row_of), H, lanes), np.float32)
+    q_abs[..., :r] = np.einsum("nhd,chd->nhc", q[..., :dn], w[..., :dn])
+    q_abs[..., r : r + dr] = q[..., dn:]
+    arrays = dict(
+        q=q, pool=pool, w_ukv=w.reshape(r, -1), table=table, row_of=np.asarray(row_of, np.int32),
+        positions=np.asarray(positions, np.int32), kx=kx, vx=vx, q_abs=q_abs, w=w,
+    )
+    return {k: jnp.asarray(a) for k, a in arrays.items()}
+
+
+@pytest.mark.parametrize("widths", [_TINY_WIDTHS, _PUBLISHED_WIDTHS], ids=["tiny", "published"])
+@pytest.mark.parametrize("stream", list(_EXPANDED_STREAMS))
+def test_expanded_form_matches_reference_and_the_absorbed_form(stream, widths):
+    """The expanded call over a latent pool against the two-pool reference
+    over every head's K and V built outright, and against the absorbed
+    call on the same pool, row by row on each side of the rule: rows of at
+    least 8 queries through the expanded call (in passes of at most 16
+    slots, 8 a score product, 64 tokens of context a chunk, so that a row is
+    several of each), the others
+    through the absorbed one at ``-1`` where the expanded call serves;
+    padding slots are exact zeros in what the two give together."""
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        expanded_plan, ragged_paged_attention_expanded,
+    )
+
+    spans = _EXPANDED_STREAMS[stream]
+    c = _latent_case(spans, 11, **widths)
+    r, dn, dr, dv = (widths[k] for k in ("r", "dn", "dr", "dv"))
+    scale = (dn + dr) ** -0.5
+    want = ragged_paged_attention_ref(
+        c["q"], c["kx"], c["vx"], c["table"], c["row_of"], c["positions"],
+        num_kv_heads=widths["H"], sm_scale=scale,
+    )[..., :dv]
+    takes, items = expanded_plan(c["row_of"], c["positions"], len(spans) + 1, 8, 8, 16, 8)
+    o_x = ragged_paged_attention_expanded(
+        c["q"], c["pool"], c["w_ukv"], c["table"], c["positions"], items,
+        rank=r, nope=dn, q_tile=8, sm_scale=scale, interpret=True,
+        q_max=16, q_sub=8, kv_chunk=64,
+    )
+    takes = np.asarray(takes)
+    by_row = {i: n >= 8 for i, (n, _) in enumerate(spans)}
+    rows = np.asarray(c["row_of"])
+    tile_live = np.repeat((np.asarray(c["positions"]).reshape(-1, 8) >= 0).any(axis=1), 8)
+    assert (takes == (np.vectorize(lambda i: by_row.get(i, False))(rows) & tile_live)).all()
+
+    def absorbed(positions):
+        o = ragged_paged_attention(
+            c["q_abs"], c["pool"], None, c["table"], c["row_of"], positions,
+            num_kv_heads=1, q_tile=8, sm_scale=scale, interpret=True,
+            value_dim=min(widths["lanes"], -(-r // 128) * 128),
+        )
+        return jnp.einsum("nhc,chv->nhv", o[..., :r], c["w"][..., dn:])
+
+    both = jnp.where(takes[:, None, None], o_x, absorbed(jnp.where(takes, -1, c["positions"])))
+    assert float(jnp.abs(both - want).max()) < 2e-5
+    # the absorbed form of every row: the same mathematics, reordered
+    assert float(jnp.abs(absorbed(c["positions"]) - want).max()) < 2e-5
+    assert float(jnp.abs(both[np.asarray(c["positions"]) < 0]).max()) == 0.0
+
+
+def test_expanded_form_in_bfloat16_rounds_k_and_v_once():
+    """At the published head widths in bfloat16: the expanded call against
+    the reference over K and V rounded to bfloat16 once (the published
+    model's own rounding), float32 softmax."""
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        expanded_plan, ragged_paged_attention_expanded,
+    )
+
+    w = _PUBLISHED_WIDTHS
+    c = _latent_case([(24, 150), (16, 40)], 12, **w)
+    bf = {k: v.astype(jnp.bfloat16) for k, v in c.items() if v.dtype == jnp.float32}
+    kx = jnp.einsum(
+        "psc,chd->pshd", bf["pool"][..., : w["r"]], bf["w"], preferred_element_type=jnp.float32
+    ).astype(jnp.bfloat16)
+    pages, ps = kx.shape[:2]
+    k_r = jnp.broadcast_to(
+        bf["pool"][:, :, None, w["r"] : w["r"] + w["dr"]], (pages, ps, w["H"], w["dr"])
+    )
+    keys = jnp.concatenate([kx[..., : w["dn"]], k_r], axis=-1).reshape(pages, ps, -1)
+    values = jnp.pad(kx[..., w["dn"]:], ((0, 0),) * 3 + ((0, w["dr"]),)).reshape(pages, ps, -1)
+    scale = (w["dn"] + w["dr"]) ** -0.5
+    want = ragged_paged_attention_ref(
+        bf["q"], keys, values, c["table"], c["row_of"], c["positions"],
+        num_kv_heads=w["H"], sm_scale=scale,
+    )[..., : w["dv"]]
+    takes, items = expanded_plan(c["row_of"], c["positions"], 3, 8, 8)
+    got = ragged_paged_attention_expanded(
+        bf["q"], bf["pool"], bf["w_ukv"], c["table"], c["positions"], items,
+        rank=w["r"], nope=w["dn"], q_tile=8, sm_scale=scale, interpret=True,
+    )
+    assert bool(takes[:40].all()) and not bool(takes[40:].any())
+    diff = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))[:40]
+    assert float(diff.max()) < 2e-2
+
+
+def test_the_expanded_rule_follows_from_the_widths():
+    """160 queries at the published widths and a tile of 8 (33.6 M
+    operations of expansion a context token over 1,664 saved a pair and
+    head: 157.5), a tile at tiny-pangu's; never where the absorbed form
+    costs no more a pair."""
+    from dynamo_exp_tpu.ops.ragged_attention import expanded_min_queries, expanded_supported
+
+    assert expanded_min_queries(640, 512, 512, 128, 64, 128, 8) == 160
+    assert expanded_min_queries(128, 128, 40, 16, 16, 16, 8) == 8
+    assert expanded_min_queries(128, 128, 64, 64, 64, 128, 8) >= 2**30
+    assert expanded_supported(16, 640, 512, 128, 128, 128, jnp.bfloat16)
+    assert not expanded_supported(16, 128, 40, 16, 16, 4, jnp.bfloat16)
+    assert not expanded_supported(16, 640, 512, 128, 128, 8, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_expanded_plan_and_host_rule_agree_on_a_drawn_stream(seed):
+    """The device's plan of a drawn stream against the host's rule
+    (``expanded_rows`` in numpy, what the engine's counter asks): the same
+    rows take the form; their passes cover each span once, in pieces of at
+    most ``q_max`` slots, the last shorter by less than a tile a piece, each
+    with its row,
+    its kv length and its score products' own."""
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        expanded_items, expanded_plan, expanded_rows,
+    )
+
+    rng = np.random.default_rng(seed)
+    n_rows, q_max, q_sub, least = 9, 64, 16, 24
+    row_of, positions = [], []
+    for r in rng.permutation(n_rows - 1)[:6]:
+        n, first = int(rng.integers(1, 150)), int(rng.integers(0, 300))
+        pad = (-n) % 8
+        row_of += [r] * (n + pad)
+        positions += list(range(first, first + n)) + [-1] * pad
+    row_of += [n_rows - 1] * 24
+    positions += [-1] * 24
+    row_of, positions = np.asarray(row_of, np.int32), np.asarray(positions, np.int32)
+    takes, row, start, slots, kvlen, sub_kvlen, sub_first = (
+        np.asarray(a) for a in jax.tree.leaves(expanded_plan(
+            jnp.asarray(row_of), jnp.asarray(positions), n_rows, 8, least, q_max, q_sub
+        ))
+    )
+    host = expanded_rows(row_of, positions, least, n_rows, xp=np)
+    tile_live = np.repeat((positions.reshape(-1, 8) >= 0).any(axis=1), 8)
+    assert (takes == (host[row_of] & tile_live)).all()
+    assert len(row) == expanded_items(len(row_of), n_rows, least, q_max)
+    covered = np.zeros(len(row_of), int)
+    for i in np.flatnonzero(slots):
+        at = slice(start[i], start[i] + slots[i])
+        covered[at] += 1
+        assert slots[i] <= q_max and slots[i] % 8 == 0 and (row_of[at] == row[i]).all()
+        assert kvlen[i] == positions[at].max() + 1
+        for j in range(q_max // q_sub):
+            part = positions[start[i] + j * q_sub : start[i] + min((j + 1) * q_sub, slots[i])]
+            at = i * (q_max // q_sub) + j
+            assert sub_kvlen[at] == (part.max() + 1 if len(part) else 0)
+            assert sub_first[at] == (part[part >= 0].min() if (part >= 0).any() else 2**30)
+    assert (covered == takes).all()
+    for r in np.flatnonzero(host):
+        pieces = slots[(row == r) & (slots > 0)]
+        assert pieces.max() - pieces.min() < 8 * len(pieces)

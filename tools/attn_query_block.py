@@ -23,7 +23,17 @@ passes read by ``block_plan``'s rule and their time at the HBM peak
 the largest difference from the output at a block of one tile. The packed
 tail's one-query call over ``--tail-rows`` decode rows is timed once beside
 them. One JSON line a block on stdout and in
-``chiprun_out/attn_query_block/<config>.jsonl``. It fails off the chip: a
+``chiprun_out/attn_query_block/<config>.jsonl``.
+
+A latent-attention configuration (``--config
+openpangu-ultra-moe-718b-l5e16``) has two forms of the tiled call instead of
+several blocks (``models/llama.py::_mla_half``), and the table is the rule's
+(``latent_form_queries``): one row's chunk of ``--chunks`` queries at the
+end of a context of ``--contexts`` tokens in a stream of 2048 slots, the
+absorbed call alone, the absorbed call with the two products beside it
+(``mla_absorb``), and the expanded call whole (its plan, paddings and the
+expansion inside the kernel), each a median of ``--calls``; the operations
+each form executes and the largest difference between the two results. It fails off the chip: a
 CPU time is not a device time (``--allow-cpu`` rehearses the control flow
 at the sizes given and writes ``<config>.cpu-rehearsal.jsonl``, never the
 chip's file).
@@ -80,8 +90,134 @@ def timed(fn, calls: int) -> tuple[float, object]:
     return statistics.median(times), out
 
 
+def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
+    """The latent model's table: both forms of the tiled call over one
+    row's chunk, by chunk length and context."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_exp_tpu.models.llama import latent_form_queries, latent_value_lanes
+    import functools
+
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        _EXPANDED_QUERIES, expanded_plan, ragged_paged_attention,
+        ragged_paged_attention_expanded,
+    )
+
+    ps, H = opts.page_size, mcfg.num_heads
+    r, dn, dr, dv = (mcfg.kv_lora_rank, mcfg.qk_nope_head_dim,
+                     mcfg.qk_rope_head_dim, mcfg.v_head_dim)
+    lanes, vl = mcfg.latent_lanes, latent_value_lanes(mcfg)
+    chunks = [int(c) for c in args.chunks.split(",")]
+    contexts = [int(c) for c in args.contexts.split(",")]
+    n = max(2048, -(-max(chunks) // TILE) * TILE)
+    interpret = dev.platform != "tpu"
+    rule = latent_form_queries(mcfg, TILE, ps, jnp.bfloat16, interpret)
+    pmax = -(-max(contexts) // ps)
+    rng = np.random.default_rng(args.seed)
+    dt = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+    pool = jnp.pad(
+        jax.random.normal(keys[0], (pmax + 1, ps, r + dr), dt),
+        ((0, 0), (0, 0), (0, lanes - r - dr)),
+    )
+    q = jax.random.normal(keys[1], (n, H, dn + dr), dt)
+    w_ukv = jax.random.normal(keys[2], (r, H * (dn + dv)), dt) * r ** -0.5
+    table = jnp.asarray((1 + rng.permutation(pmax))[None].astype(np.int32))
+    row_of = jnp.zeros((n,), jnp.int32)
+    scale = (dn + dr) ** -0.5
+    w3 = w_ukv.reshape(r, H, dn + dv)
+    w_abs = jnp.concatenate([
+        jnp.pad(jnp.swapaxes(w3[..., :dn], 0, 2), ((0, 0), (0, 0), (0, lanes - r))),
+        jnp.broadcast_to(
+            jnp.pad(jnp.eye(dr, dtype=dt), ((0, 0), (r, lanes - r - dr)))[:, None], (dr, H, lanes)
+        ),
+    ])
+
+    def absorbed(q, positions):
+        return ragged_paged_attention(
+            q, pool, None, table, row_of, positions, num_kv_heads=1, q_tile=TILE,
+            sm_scale=scale, interpret=interpret, value_dim=vl,
+        )
+
+    @jax.jit
+    def absorbed_whole(q, positions):
+        o = absorbed(jnp.einsum("nhd,dhc->nhc", q, w_abs), positions)
+        return jnp.einsum("nhc,chv->nhv", o[..., :r], w3[..., dn:])
+
+    @functools.partial(jax.jit, static_argnames=("q_max", "q_sub", "kv_chunk"))
+    def expanded(q, positions, q_max=None, q_sub=None, kv_chunk=None):
+        plan_sizes = {k: v for k, v in (("q_max", q_max), ("q_sub", q_sub)) if v}
+        _, items = expanded_plan(row_of, positions, 1, TILE, TILE, **plan_sizes)
+        return ragged_paged_attention_expanded(
+            q, pool, w_ukv, table, positions, items, rank=r, nope=dn, q_tile=TILE,
+            sm_scale=scale, interpret=interpret, **plan_sizes,
+            **({"kv_chunk": kv_chunk} if kv_chunk else {}),
+        )
+
+    # other sizes of a pass than the module's: "q_max,q_sub,kv_chunk;..."
+    tried = [
+        dict(zip(("q_max", "q_sub", "kv_chunk"), (int(x) for x in v.split(","))))
+        for v in args.passes.split(";") if v
+    ]
+
+    q_abs = jax.block_until_ready(jnp.einsum("nhd,dhc->nhc", q, w_abs))
+    head = {
+        "config": name, "platform": dev.platform, "device_kind": dev.device_kind,
+        "heads": H, "widths": [r, dn, dr, dv], "lanes": [lanes, vl], "page_size": ps,
+        "stream_slots": n, "rule_min_queries": rule, "seed": args.seed, "calls": args.calls,
+    }
+    print(json.dumps(head), flush=True)
+    out_dir = os.path.join(ROOT, "chiprun_out", "attn_query_block")
+    os.makedirs(out_dir, exist_ok=True)
+    suffix = "" if dev.platform == "tpu" else ".cpu-rehearsal"
+    with open(os.path.join(out_dir, f"{name}{suffix}.jsonl"), "w") as f:
+        f.write(json.dumps(head) + "\n")
+        for context in contexts:
+            for chunk in chunks:
+                if chunk > context:
+                    continue
+                pos = np.full(n, -1, np.int32)
+                pos[:chunk] = context - chunk + np.arange(chunk)
+                pos = jnp.asarray(pos)
+                a_ms, _ = timed(lambda: absorbed(q_abs, pos), args.calls)
+                w_ms, o_a = timed(lambda: absorbed_whole(q, pos), args.calls)
+                x_ms, o_x = timed(lambda: expanded(q, pos), args.calls)
+                pairs = chunk * context - chunk * (chunk - 1) // 2
+                diff = np.abs(
+                    np.asarray(o_a[:chunk], np.float32) - np.asarray(o_x[:chunk], np.float32)
+                ).max()
+                passes = -(-chunk // _EXPANDED_QUERIES)
+                other = {
+                    ",".join(str(x) for x in sizes.values()):
+                        1e3 * timed(lambda: expanded(q, pos, **sizes), args.calls)[0]
+                    for sizes in tried
+                }
+                line = {
+                    "chunk": chunk, "context": context, "pairs": pairs,
+                    "absorbed_kernel_ms": 1e3 * a_ms, "absorbed_with_products_ms": 1e3 * w_ms,
+                    "expanded_ms": 1e3 * x_ms, "expanded_wins": x_ms < w_ms,
+                    "absorbed_gflop": pairs * H * 2 * (lanes + vl) / 1e9,
+                    "expanded_gflop": (pairs * H * 2 * (dn + dr + dv)
+                                       + passes * context * H * 2 * r * (dn + dv)) / 1e9,
+                    "max_abs_diff_between_forms": float(diff),
+                    **({"expanded_ms_at_other_passes": other} if other else {}),
+                }
+                print(json.dumps(line), flush=True)
+                f.write(json.dumps(line) + "\n")
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--chunks", default="64,128,160,256,512,2048",
+                    help="a latent configuration: the row's queries in the call")
+    ap.add_argument("--passes", default="",
+                    help="a latent configuration: other sizes of the expanded call's pass to time, "
+                         "'q_max,q_sub,kv_chunk;...' (ops/ragged_attention.py::_EXPANDED_*)")
+    ap.add_argument("--contexts", default="2048,4096,16384",
+                    help="a latent configuration: the context the chunk ends")
     ap.add_argument("--config", required=True)
     ap.add_argument("--nb", type=int, default=4096, help="the mixed dispatch's token bucket")
     ap.add_argument("--spans", default="512@6144,3520@0")
@@ -118,6 +254,8 @@ def main() -> int:
     mcfg = ModelConfig.from_hf_config(run_cell.hf_config(config))
     opts = server.parse_args(["in=http", "out=tpu", *config["serve_args"]])
     ecfg = EngineConfig(model=mcfg, max_decode_slots=opts.max_decode_slots, page_size=opts.page_size)
+    if mcfg.is_latent:
+        return latent_forms(args, name, config, mcfg, ecfg, opts, dev)
     heads, kv_heads, hd = mcfg.num_heads // opts.tp, mcfg.num_kv_heads // opts.tp, mcfg.head_dim_
     ps = opts.page_size
     tiled = args.nb - ecfg.ragged_packed_slots(args.nb)
