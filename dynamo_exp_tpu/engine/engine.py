@@ -1248,7 +1248,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         with_spec: bool = False, carry: tuple | None = None,
         live: int | None = None, solo: bool = False,
         capacity_capped: bool = False, routed_rows: int | None = None,
-        **event,
+        singles: int = 0, **event,
     ) -> _PendingRagged:
         """The life of a dispatch, from its builder's packed buffer and
         rows to the pending record its consumer takes (no host sync;
@@ -1257,10 +1257,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
         call, take the pools back, count, record. ``carry``: the device
         state a window or a block chained onto the dispatch before it
         starts from; None for any other, and a window or block that is
-        not chained gets zeros its program does not read. ``live``:
-        ``_count_dispatch``'s. ``routed_rows``: ``_PendingRagged``'s (the
-        live count where the builder gives none). ``event``: the
-        builder's own fields of the ``dispatch`` flight event."""
+        not chained gets zeros its program does not read. ``live``,
+        ``singles``: ``_count_dispatch``'s. ``routed_rows``:
+        ``_PendingRagged``'s (the live count where the builder gives
+        none). ``event``: the builder's own fields of the ``dispatch``
+        flight event."""
         block = self.cfg.model.block_length
         program = ("block" if block else "window") if windowed else "mixed"
         with self._phase("assemble"), self._part("assemble", "lookup"):
@@ -1297,7 +1298,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         )
         key = self._count_dispatch(
             program, nb, page_bucket, windowed, full_sampler, want_lp,
-            with_spec, chained=chained, live=live,
+            with_spec, chained=chained, live=live, singles=singles,
         )
         self.flight.record(
             "dispatch", dispatch="ragged", **event, bucket=nb,
@@ -1399,7 +1400,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
 
     def _count_dispatch(
         self, program, nb, attn_pages, windowed, full_sampler, want_lp,
-        with_spec, chained: bool, live: int,
+        with_spec, chained: bool, live: int, singles: int = 0,
     ) -> tuple:
         """Count one ragged dispatch under its whole variant key
         (``dynamo_ragged_dispatch_total``), its live and padding slots
@@ -1407,7 +1408,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
         steps), for a selective-scan model the live ones as tokens
         through the scan (``dynamo_ssm_scan_tokens_total``), for a
         linear-attention model the same through the delta rule
-        (``dynamo_linear_attn_tokens_total``) and, for a
+        (``dynamo_linear_attn_tokens_total``) and those of them that
+        took its one-token step (``dynamo_linear_attn_step_rows_total``:
+        a window's every live slot, of a mixed stream its ``singles``,
+        the decode rows, where the stream has a packed tail) and, for a
         routed-expert model, the same as rows
         (``dynamo_moe_rows_total``) and the way its expert matmuls
         read their weights (``dynamo_moe_dispatch_total``). Host
@@ -1431,6 +1435,13 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 program, live,
                 nb * (self.cfg.decode_window if windowed else 1) - live,
             )
+            if self.cfg.model.num_linear_layers:
+                # A mixed stream has a packed tail on the kernel engine; the
+                # reference's is the oracle's, walked whole.
+                tel.linear_attn_step_rows.labels(program).inc(
+                    live if windowed
+                    else singles if self._attn_impl == "pallas" else 0
+                )
         if self.cfg.model.is_moe:
             # nb: a window's rows a step, a mixed dispatch's flat tokens; a
             # block program's rows are blocks, read in place at any count.
@@ -3935,7 +3946,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
             # Padding rows park at -1, map to the scratch counts row (B)
             # so their scatter can't touch a live slot, have a budget
             # gate that never fires and keep no state (an entry past
-            # the pool is never written).
+            # the pool is never written). A live row reads and writes its
+            # own entry and no other's: what the delta rule's in-place
+            # step asks of a call (ops/gated_delta.py).
             packed, f = self._launch_buffer(nb, True, full_sampler)
             tokens, positions = f["tokens"], f["positions"]
             max_pos, table, slot_map = f["max_pos"], f["table"], f["slot_map"]
@@ -4035,7 +4048,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
             # state (a model that has it): the entry each row reads, and
             # the writes (stream index -> entry): rows' own in the first
             # B1, a prefill row's snapshot in the second. An entry past
-            # the pool is no write.
+            # the pool is no write. No row reads an entry another row of
+            # the dispatch writes (a row owns its entry and a snapshot's
+            # is fresh): the delta rule's in-place step takes the rows
+            # one after another and rests on it (ops/gated_delta.py).
             packed, f = self._launch_buffer(nb, False, full_sampler, with_spec)
             tokens, positions, row_of = f["tokens"], f["positions"], f["row_of"]
             table, q_last, pos0 = f["table"], f["q_last"], f["pos0"]
@@ -4169,7 +4185,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
         pending = self._launch(
             nb, max_pages, packed, rows, windowed=False,
             full_sampler=full_sampler, want_lp=want_lp, with_spec=with_spec,
-            live=int((positions >= 0).sum()), rows=len(rows), tokens=total_q,
+            live=int((positions >= 0).sum()), singles=len(dec), rows=len(rows),
+            tokens=total_q,
         )
         self._count_attn_passes(nb, row_of, positions)
         self._count_latent_reads(

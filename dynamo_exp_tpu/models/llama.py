@@ -43,6 +43,7 @@ this module is their TPU-native replacement.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -897,17 +898,23 @@ def _selective_scan(x, dt, a, b, c, d, pool, layer, io: SeqStateIO, form: ScanFo
 
 def _gated_delta(q, k, v, g, beta, pool, layer, io: SeqStateIO, form: ScanForm):
     """The gated delta rule over this forward's stream
-    (``ops/gated_delta.py`` has the three forms). Returns (o [N, H * dv]
-    float32, the states' pool)."""
+    (``ops/gated_delta.py`` has the four forms). Returns (o [N, H * dv]
+    float32, the states' pool). One token a row takes the in-place kernel
+    wherever the stream takes the chunk kernel (``form.kernel``: the chip),
+    the plain form otherwise."""
     from ..ops import gated_delta as gd
 
+    one_step = (
+        functools.partial(gd.gated_delta_step_kernel, interpret=form.interpret)
+        if form.kernel else gd.gated_delta_step
+    )
     return _stream_recurrence(
         pool, io, form,
         lambda pool: gd.gated_delta_ref(
             q, k, v, g, beta, pool, layer, io.slots, io.row_of, io.positions,
             io.src, io.dst,
         ),
-        lambda pool, part, slots, pos, dst: gd.gated_delta_step(
+        lambda pool, part, slots, pos, dst: one_step(
             q[part], k[part], v[part], g[part], beta[part], pool, layer, slots,
             pos, dst,
         ),
@@ -1636,7 +1643,9 @@ def _scan_form(cfg, attn_impl, span_align, packed_from, interpret,
     else:
         fits = cfg.num_linear_layers > 0 and gated_delta.supported(
             cfg.linear_num_heads, cfg.linear_key_head_dim,
-            cfg.linear_value_head_dim, span_align,
+            cfg.linear_value_head_dim,
+            # a row of one token is no span to align
+            gated_delta.TILE if one_token else span_align,
         )
     kernel = attn_impl == "pallas" and fits
     return ScanForm(kernel, packed_from if kernel else None, one_token, interpret)

@@ -15,7 +15,7 @@ keys' axis on sublanes and every head's values side by side on lanes, so
 that 30 heads of 192 fill 45 lane groups where ``[H, dk, dv]`` would pad
 each head's 192 lanes to 256), in VMEM and in every store.
 
-Three forms of one recurrence, as ``ops/selective_scan.py`` has them:
+Four forms of one recurrence (``ops/selective_scan.py`` has three of them):
 
 - :func:`gated_delta_kernel`: a Pallas TPU kernel, custom-call name
   ``gated_delta_chunk``, over the flat stream of a mixed dispatch. XLA's
@@ -27,7 +27,16 @@ Three forms of one recurrence, as ``ops/selective_scan.py`` has them:
   place by DMA.
 - :func:`gated_delta_step`: one token a row (a decode window's step, the
   packed tail of a mixed stream): gather the rows' states, one step of
-  the recurrence, scatter. Plain ``jax.numpy``, no matrix product.
+  the recurrence, scatter. Plain ``jax.numpy``, no matrix product. The
+  CPU engine's form and the next one's oracle.
+- :func:`gated_delta_step_kernel`: the same step on the chip, IN PLACE
+  (below): a Pallas TPU kernel, custom-call name ``gated_delta_step``,
+  that moves each live row's state once from the pool and once back.
+  XLA's form of the plain step moves it some twenty times (a gather that
+  copies, a relayout of ``H * dv`` lanes to ``[H, dv]``, four fusions
+  and a scatter: 1,458 MB for 70.8 MB of states at 32 rows of
+  Olmo-Hybrid-7B's widths, compile-only) and runs at a tenth of its HBM
+  bound. No matrix product either: float32 elementwise and sums.
 - :func:`gated_delta_ref`: the oracle and the definition, a ``lax.scan``
   over the stream a token at a time. Any layout, any write. The CPU
   engine and the tests.
@@ -53,6 +62,31 @@ bfloat16 pass there costs the state what rounding it to bfloat16 a chunk
 would. The state after token ``m`` inside a chunk is the last line with
 ``G_m`` for ``G_C`` and the rows after ``m`` left out, which is how a
 write that does not fall on a chunk's end is honoured.
+
+IN PLACE. The one-token kernel is a grid over the rows, ``layer``,
+``slots``, ``dst`` and ``positions`` prefetched as scalars and the pool
+aliased in to out. A row at a position below 0 (padding: a mixed stream's
+tail hands over every slot, of which half hold a decode row) reads,
+computes and writes nothing but a zero output row. Any other row has its
+``[dk, H * dv]`` entry brought into VMEM by DMA (zeros at position 0, no
+read), is stepped there and goes back by DMA to ``dst[r]`` unless that is
+past the pool, in a ring of three buffers: row ``r + 1`` loads and row
+``r - 1`` stores while row ``r`` computes, so the call costs what the
+DMAs cost (0.24 ms for 31 rows of 2.2 MB each way, 567 GB/s; with the
+computing taken out the call takes as long: my chip run, PR 46). The
+state keeps the pool's layout throughout: heads two at a time as in the
+chunk kernel (``2 * dv`` lanes, whole lane groups), ``k`` and ``q`` a head
+as ``[dk, 1]`` columns broadcast over the pair's lanes (picked from ``[dk,
+heads on lanes]`` by a masked sum over lanes), ``exp(g)``, ``beta`` and
+``v`` as ``[1, 2 * dv]`` rows, the sums over ``dk`` down the sublanes a
+group of 8 at a time. THE CONTRACT: reads follow ``slots``, writes follow
+``dst``, any ``dst[r] != slots[r]`` is honoured (a snapshot), and within
+one call no row reads an entry that another row writes. The plain form
+gathers everything before it scatters and would not care; the kernel
+takes the rows one after another with the next row's read already on its
+way. The engine's rows own their entries and a snapshot's entry is fresh
+(``engine._build_mixed``, ``engine._build_windowed``), so nothing asks
+for more.
 
 LAYOUT. Spans are aligned to ``TILE`` slots as ``engine._build_mixed`` lays
 them for the attention kernel (``ragged_q_tile``), real tokens first, one
@@ -192,6 +226,183 @@ def gated_delta_step(q, k, v, g, beta, pool, layer, slots, positions, dst):
         o.reshape(r, h * dv),
         pool.at[layer, dst].set(s.reshape(r, dk, h * dv), mode="drop"),
     )
+
+
+# ------------------------------------------------------ one step, in place
+STEP_BUFFERS = 3  # a row's state computing, the next loading, the last storing
+
+
+def _step_kernel(layer_ref, slots_ref, dst_ref, pos_ref,  # SMEM
+                 k_ref, q_ref, v_ref, a_ref, b_ref, pool_in,
+                 o_ref, pool_out,
+                 s_ref, load_sem, store_sem, *, heads, dk, dv, entries):
+    """One grid step: one row, every head. ``s_ref`` [STEP_BUFFERS, dk,
+    H * dv] is a ring: row ``r`` computes in place in buffer ``r % 3``
+    while row ``r + 1``'s state loads into the next and row ``r - 1``'s
+    goes back to the pool from the one before."""
+    # pool_in and pool_out are one buffer (input_output_aliases). What is
+    # in flight follows from the prefetched scalars alone, so every step
+    # recomputes it: a load for a live row past position 0, a store for a
+    # live row whose dst is an entry.
+    f32 = jnp.float32
+    r = pl.program_id(0)
+    rows = pl.num_programs(0)
+    layer = layer_ref[0]
+    w2 = 2 * dv
+
+    def at(i):  # a row's scalars are read inside the call's rows only
+        return jnp.clip(i, 0, rows - 1)
+
+    def loads(i):  # i = r or r + 1
+        return (i < rows) & (pos_ref[at(i)] > 0)
+
+    def stores(i):  # i = r - 2 .. r
+        e = dst_ref[at(i)]
+        return (i >= 0) & (pos_ref[at(i)] >= 0) & (e >= 0) & (e < entries)
+
+    def load(i):
+        return pltpu.make_async_copy(
+            pool_in.at[layer, slots_ref[at(i)]], s_ref.at[i % STEP_BUFFERS],
+            load_sem.at[i % STEP_BUFFERS],
+        )
+
+    def store(i):
+        return pltpu.make_async_copy(
+            s_ref.at[i % STEP_BUFFERS], pool_out.at[layer, dst_ref[at(i)]],
+            store_sem.at[i % STEP_BUFFERS],
+        )
+
+    @pl.when((r == 0) & loads(r))
+    def _():
+        load(r).start()
+
+    # Row r + 1's buffer last held row r - 2: its store has had a whole
+    # row's computing to finish.
+    @pl.when(stores(r - 2))
+    def _():
+        store(r - 2).wait()
+
+    @pl.when(loads(r + 1))
+    def _():
+        load(r + 1).start()
+
+    pos = pos_ref[r]
+
+    @pl.when(pos < 0)  # padding: nothing read, computed or written
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(pos >= 0)
+    def _():
+        buf = r % STEP_BUFFERS
+
+        @pl.when(pos == 0)  # a sequence's first token starts from zeros
+        def _():
+            s_ref[buf] = jnp.zeros(s_ref.shape[1:], f32)
+
+        @pl.when(pos > 0)
+        def _():
+            load(r).wait()
+
+        first_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, w2), 1) < dv
+        head_lane = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1)
+
+        def column(ref, head):  # [dk, HP] -> [dk, 1]: one head's vector
+            return jnp.sum(jnp.where(head_lane == head, ref[...], 0.0), axis=1, keepdims=True)
+
+        def pair(p, carry):
+            lanes = pl.ds(pl.multiple_of(p * w2, LANE), w2)
+            a, b, v = a_ref[:, lanes], b_ref[:, lanes], v_ref[:, lanes]  # [1, w2]
+            kk = jnp.where(first_lanes, column(k_ref, 2 * p), column(k_ref, 2 * p + 1))
+            qq = jnp.where(first_lanes, column(q_ref, 2 * p), column(q_ref, 2 * p + 1))
+            # Two walks over the pair's [dk, w2] plane a sublane group at a
+            # time, the sums over dk kept a group wide until their end.
+            sk = jnp.zeros((TILE, w2), f32)
+            for t in range(dk // TILE):
+                at_t = pl.ds(t * TILE, TILE)
+                sk = sk + (a * s_ref[buf, at_t, lanes]) * kk[t * TILE:(t + 1) * TILE]
+            err = b * (v - jnp.sum(sk, axis=0, keepdims=True))
+            so = jnp.zeros((TILE, w2), f32)
+            for t in range(dk // TILE):
+                at_t = pl.ds(t * TILE, TILE)
+                s = a * s_ref[buf, at_t, lanes] + kk[t * TILE:(t + 1) * TILE] * err
+                s_ref[buf, at_t, lanes] = s
+                so = so + s * qq[t * TILE:(t + 1) * TILE]
+            o_ref[:, lanes] = jnp.sum(so, axis=0, keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, heads // 2, pair, 0)
+
+        @pl.when(stores(r))
+        def _():
+            store(r).start()
+
+    @pl.when(r == rows - 1)  # the last rows' stores end with the call
+    def _():
+        for i in (r - 1, r):
+            @pl.when(stores(i))
+            def _(i=i):
+                store(i).wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gated_delta_step_kernel(q, k, v, g, beta, pool, layer, slots, positions, dst,
+                            interpret: bool = False):
+    """:func:`gated_delta_step` on the TPU (module docstring, IN PLACE):
+    the same arguments and results, each live row's state moved once from
+    the pool and once back, under that section's contract: no row reads an
+    entry another row of the call writes. A padding row's output is zeros
+    and its ``dst`` is never written."""
+    f32 = jnp.float32
+    h, dk, dv = _heads(q, v, g)
+    r = q.shape[0]
+    entries = pool.shape[1]
+    hp = -(-h // LANE) * LANE
+
+    def columns(x):  # [R, H * dk] -> [R, dk, heads on a lane group]
+        x = x.astype(f32).reshape(r, h, dk).transpose(0, 2, 1)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, hp - h)))
+
+    def by_lane(x):  # [R, H] -> [R, 1, H * dv]: a head's scalar on its lanes
+        return jnp.repeat(x.astype(f32), dv, axis=1)[:, None]
+
+    def a_row(width, lanes):
+        return pl.BlockSpec((None, width, lanes), lambda i, *_: (i, 0, 0))
+
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(r,),
+        in_specs=[a_row(dk, hp)] * 2 + [a_row(1, h * dv)] * 3 + [any_space],
+        out_specs=[a_row(1, h * dv), any_space],
+        scratch_shapes=[
+            pltpu.VMEM((STEP_BUFFERS, dk, h * dv), f32),
+            pltpu.SemaphoreType.DMA((STEP_BUFFERS,)),
+            pltpu.SemaphoreType.DMA((STEP_BUFFERS,)),
+        ],
+    )
+    o, pool = pl.pallas_call(
+        functools.partial(_step_kernel, heads=h, dk=dk, dv=dv, entries=entries),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((r, 1, h * dv), f32),
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        ],
+        # operand 9 (4 prefetched scalars, then k q v a b pool)
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_LIMIT
+        ),
+        name="gated_delta_step",
+        interpret=interpret,
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        jnp.clip(slots, 0, entries - 1).astype(jnp.int32),
+        dst.astype(jnp.int32), positions.astype(jnp.int32),
+        columns(k), columns(q), v.astype(f32)[:, None], by_lane(jnp.exp(g.astype(f32))),
+        by_lane(beta), pool,
+    )
+    return o[:, 0], pool
 
 
 # --------------------------------------------------------- the matrix form

@@ -419,6 +419,17 @@ class Telemetry:
             ["program"],  # window | mixed
             registry=self.registry,
         )
+        self.linear_attn_step_rows = Counter(
+            "dynamo_linear_attn_step_rows_total",
+            "Of dynamo_linear_attn_tokens_total, the tokens that took the "
+            "delta rule's one-token step (on the chip the in-place kernel "
+            "gated_delta_step, a row's state moved once each way): every "
+            "live slot of a window, the decode rows in the packed tail of a "
+            "mixed stream; 0 for the mixed streams of an engine on the "
+            "reference attention, which the oracle walks whole",
+            ["program"],  # window | mixed
+            registry=self.registry,
+        )
         self.seq_state_restores = Counter(
             "dynamo_seq_state_restores_total",
             "Sequence states copied from a kept snapshot into an admitted "

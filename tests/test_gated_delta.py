@@ -1,5 +1,6 @@
-"""The gated delta rule's three forms (``ops/gated_delta.py``): the Pallas
-kernel in interpret mode and the one-step form against the token
+"""The gated delta rule's four forms (``ops/gated_delta.py``): the Pallas
+kernels in interpret mode (the chunked one over a stream, the in-place one
+of a single token a row) and the plain one-step form against the token
 recurrence, states included, and the chunk's matrix form against the
 recurrence in float64.
 
@@ -177,6 +178,131 @@ def test_step_matches_its_oracle():
     assert np.abs(np.asarray(out) - np.asarray(pool_ref)).max() < KERNEL_TOL
 
 
+# ------------------------------------------------------ one step, in place
+# (rows, layers, entries, layer, positions, slots, dst) of a case of the
+# in-place kernel; an index of ``entries`` is past the pool.
+_E = 8
+STEP_CASES = {
+    # position 0 starts from zeros whatever its entry holds
+    "position_0": (3, 1, _E, 0, [0, 4, 0], [0, 1, 2], [0, 1, 2]),
+    # padding rows between and after the live ones
+    "padding": (6, 1, _E, 0, [-1, 3, -1, 7, -1, -1], [5, 1, 6, 2, _E, 0], [_E, 1, _E, 2, _E, _E]),
+    # no write, and o all the same
+    "dst_past_the_pool": (3, 1, _E, 0, [2, 0, 9], [0, 1, 2], [_E, _E, 2]),
+    # a snapshot: the source entry stays, the new state lands elsewhere
+    "snapshot": (3, 1, _E, 0, [5, 1, 6], [0, 1, 2], [4, 1, 7]),
+    "one_row": (1, 1, _E, 0, [3], [6], [6]),
+    "31_rows": (31, 1, 33, 0, list(range(31)), list(range(31)), list(range(31))),
+    "64_rows_30_live": (
+        64, 1, 33, 0, [i + 1 if i < 30 else -1 for i in range(64)],
+        [i if i < 30 else 33 for i in range(64)], [i if i < 30 else 33 for i in range(64)],
+    ),
+    "layer_2": (4, 3, _E, 2, [1, 0, -1, 8], [3, 2, _E, 0], [3, 2, _E, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(STEP_CASES))
+def test_step_kernel_matches_the_plain_step(case):
+    """The in-place kernel (interpret) against the plain one-step form: the
+    outputs of the live rows and THE WHOLE POOL within ``KERNEL_TOL``; what
+    a case is about is asserted on the kernel's pool alone too."""
+    r, layers, entries, layer, pos, slots, dst = STEP_CASES[case]
+    rng = np.random.default_rng(r + layer)
+    args = [jnp.asarray(x) for x in _inputs(rng, r)]
+    pool = jnp.asarray(rng.standard_normal((layers, entries, DK, H * DV)), jnp.float32)
+    pos, slots, dst = (jnp.asarray(a, jnp.int32) for a in (pos, slots, dst))
+    o, out = gd.gated_delta_step_kernel(
+        *args, pool, jnp.int32(layer), slots, pos, dst, interpret=True
+    )
+    o_ref, pool_ref = gd.gated_delta_step(*args, pool, layer, slots, pos, dst)
+    live = np.asarray(pos) >= 0
+    assert out.dtype == jnp.float32 and o.shape == o_ref.shape
+    assert np.abs(np.asarray(o) - np.asarray(o_ref))[live].max() < KERNEL_TOL
+    assert np.abs(np.asarray(out) - np.asarray(pool_ref)).max() < KERNEL_TOL
+    assert not np.asarray(o)[~live].any()  # a padding row's output is zeros
+    written = {int(d) for d, p in zip(dst, pos) if p >= 0 and d < entries}
+    for lyr in range(layers):
+        kept = [e for e in range(entries) if lyr != layer or e not in written]
+        assert np.array_equal(np.asarray(out[lyr, kept]), np.asarray(pool[lyr, kept]))
+    assert all(np.abs(np.asarray(out[layer, e] - pool[layer, e])).max() > 1e-3 for e in written)
+
+
+def test_step_kernel_reads_slots_and_writes_dst_row_by_row():
+    """The contract: reads follow ``slots``, writes follow ``dst``, and the
+    rows go one after another, so a call in which no row reads an entry
+    that another row writes is the plain form's gather-then-scatter. A
+    chain of snapshots (row i reads entry i and writes entry i + 8, which
+    nothing reads) is such a call; every source stays what it was."""
+    rng = np.random.default_rng(2)
+    r = 8
+    args = [jnp.asarray(x) for x in _inputs(rng, r)]
+    pool = jnp.asarray(rng.standard_normal((1, 16, DK, H * DV)), jnp.float32)
+    pos = jnp.arange(1, r + 1, dtype=jnp.int32)
+    slots = jnp.arange(r, dtype=jnp.int32)
+    o, out = gd.gated_delta_step_kernel(*args, pool, jnp.int32(0), slots, pos, slots + 8, interpret=True)
+    o_ref, pool_ref = gd.gated_delta_ref(
+        *args, pool, 0, slots, jnp.arange(r), pos, jnp.arange(r), slots + 8
+    )
+    assert np.abs(np.asarray(o) - np.asarray(o_ref)).max() < KERNEL_TOL
+    assert np.abs(np.asarray(out) - np.asarray(pool_ref)).max() < KERNEL_TOL
+    assert np.array_equal(np.asarray(out[0, :8]), np.asarray(pool[0, :8]))
+
+
+@pytest.mark.parametrize("form", ["packed_tail", "one_token"])
+def test_forward_takes_the_step_kernel_where_the_stream_takes_the_chunked(form):
+    """``models/llama.py`` through its forwards: a mixed stream whose
+    packed tail holds decode rows (``forward_ragged`` with ``ScanForm(kernel
+    =True, packed_from=16, interpret=True)`` behind ``attn_impl="pallas"``)
+    and a decode step of one token a row (``forward``: ``one_token=True``),
+    both against the oracle's forward (``attn_impl="xla"``): logits and
+    both parts of the state pool."""
+    import dataclasses
+
+    from dynamo_exp_tpu.models import llama
+    from dynamo_exp_tpu.models.config import TINY_OLMO_HYBRID
+
+    cfg = dataclasses.replace(TINY_OLMO_HYBRID, dtype="float32")
+    one_token = form == "one_token"
+    tail = None if one_token else 16
+    assert llama._scan_form(cfg, "pallas", 1 if one_token else 8, tail, True, one_token) == (
+        llama.ScanForm(True, tail, one_token, True)
+    )
+    params = llama.init_params(jax.random.PRNGKey(3), cfg)
+    toks = [int(t) for t in np.random.default_rng(1).integers(2, 256, 40)]
+    tables = jnp.stack([jnp.arange(1 + 4 * i, 5 + 4 * i) for i in range(4)]).astype(jnp.int32)
+    k, v = llama.init_kv_cache(cfg, 17, 8, dtype=jnp.float32)
+    st = llama.init_seq_state(cfg, 6)
+    for r, n in ((1, 9), (2, 6)):  # rows 1 and 2 have tokens behind them
+        _, k, v, st = llama.forward(
+            params, cfg, jnp.asarray(toks[:n])[None], jnp.arange(n, dtype=jnp.int32)[None],
+            tables[r:r + 1], k, v, seq_state=st, state_slots=jnp.asarray([r]),
+        )
+
+    def run(impl):
+        if one_token:  # rows 1, 2, a row at position 0 and a padding row
+            return llama.forward(
+                params, cfg, jnp.asarray([[toks[9]], [toks[6]], [toks[0]], [0]]),
+                jnp.asarray([[9], [6], [0], [-1]], jnp.int32), tables, k, v,
+                seq_state=st, state_slots=jnp.asarray([1, 2, 3, 6]), attn_impl=impl,
+                interpret=True,
+            )
+        # a span of 11 tokens (16 slots), then the tail: rows 1 and 2, padding
+        i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+        return llama.forward_ragged(
+            params, cfg, i32(toks[20:31] + [0] * 5 + [toks[9], toks[6]] + [0] * 6),
+            i32(list(range(11)) + [-1] * 5 + [9, 6] + [-1] * 6),
+            i32([0] * 16 + [1, 2] + [3] * 6), tables, k, v, i32([10, 16, 17]),
+            attn_impl=impl, q_tile=8, interpret=True, seq_state=st, packed_from=16,
+            state_rows=(i32([0, 1, 2, 6]), i32([10, 16, 17, 0, 7, 0, 0, 0]),
+                        i32([0, 1, 2, 6, 5, 6, 6, 6])),
+        )
+
+    got, want = run("pallas"), run("xla")
+    assert np.abs(np.asarray(got[0]) - np.asarray(want[0])).max() < 1e-3
+    for a, b in zip(jax.tree.leaves(got[3]), jax.tree.leaves(want[3])):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-4
+
+
 # --------------------------------------------------------- the matrix form
 def _recurrence64(q, k, v, g, beta, s0):
     s, out, after = s0.copy(), [], []
@@ -275,6 +401,30 @@ def test_kernel_compiles_for_v5e_at_the_published_widths(one_chip, slots):
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 8
     assert "gated_delta_chunk" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows, plain_temp_mb", [(32, 243), (64, 487)])
+def test_step_kernel_compiles_for_v5e_at_the_published_widths(one_chip, rows, plain_temp_mb):
+    """Mosaic takes the in-place step at Olmo-Hybrid-7B's widths for a
+    window's 32 rows and a mixed dispatch's packed tail of 64: the pool
+    aliased, and under a tenth of the temporaries the plain form compiles
+    to there (243 and 487 MB: its gather's copy and four fusions' results;
+    compile-only, PR 46)."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    f, i = jnp.float32, jnp.int32
+    r, h, dk, dv = rows, 30, 96, 192
+    pool = (6, 160, dk, h * dv)
+    args = (
+        sds((r, h * dk), f), sds((r, h * dk), f), sds((r, h * dv), f), sds((r, h), f),
+        sds((r, h), f), sds(pool, f), sds((), i), sds((r,), i), sds((r,), i), sds((r,), i),
+    )
+    compiled = jax.jit(gd.gated_delta_step_kernel, donate_argnums=(5,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 4
+    assert mem.temp_size_in_bytes < plain_temp_mb * 1e6 / 10
+    assert "gated_delta_step" in compiled.as_text()
 
 
 @pytest.mark.parametrize(

@@ -425,6 +425,30 @@ async def test_concurrent_requests_share_mixed_dispatches(engine):
         assert g == _greedy_reference(engine.params, p, 30)
 
 
+async def test_a_decode_row_beside_a_prompt_chunk_takes_the_one_token_step(engine):
+    """A request admitted while another decodes: its chunks ride with that
+    one's decode row, which on the kernel engine lies in the packed tail,
+    takes the delta rule's one-token step and is counted; the reference's
+    stream is the oracle's, walked whole. Both streams stay the reference's."""
+    from dynamo_exp_tpu.telemetry import get_telemetry
+
+    tail_steps = get_telemetry().linear_attn_step_rows.labels("mixed")._value
+    before = tail_steps.get()
+    first, second = PROMPT[:9], PROMPT[10:60]
+    b = BackendInput(token_ids=list(first))
+    b.stop_conditions.max_tokens = 40
+    b.stop_conditions.ignore_eos = True
+    stream = await engine.generate(b.to_dict())
+    got, other = [], None
+    async for item in stream:
+        got.extend(item.get("token_ids", []))
+        if other is None:
+            other = asyncio.ensure_future(_run(engine, second, 4))
+    assert got == _greedy_reference(engine.params, first, 40)
+    assert await other == _greedy_reference(engine.params, second, 4)
+    assert (tail_steps.get() > before) == (engine._attn_impl == "pallas")
+
+
 @pytest.mark.parametrize("asking", ["again", "longer"])
 async def test_second_asking_restores_both_parts(engine, asking):
     """A prompt whose head is in cached pages is served from them with
@@ -470,6 +494,11 @@ async def test_linear_tokens_are_counted_and_the_pool_is_published(engine):
     assert tel.linear_attn_tokens.labels("mixed")._value.get() >= before + 20
     assert tel.ssm_scan_tokens.labels("mixed")._value.get() == scans
     assert b"dynamo_linear_attn_tokens_total" in tel.render()
+    # every live slot of a window takes the one-token step
+    assert tel.linear_attn_step_rows.labels("window")._value.get() == (
+        tel.linear_attn_tokens.labels("window")._value.get()
+    )
+    assert b"dynamo_linear_attn_step_rows_total" in tel.render()
 
 
 # ----------------------------------------------------------------- refusals
