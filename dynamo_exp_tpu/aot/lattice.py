@@ -316,9 +316,13 @@ def _attention_form(cfg, attn_impl: str, interpret: bool) -> str:
     programs carry a second attention call where the kernel engine expands
     rows of many queries (``models/llama.py::latent_form_queries``), so
     executables built before that rule, or at another threshold, are not
-    these."""
+    these; a model with an indexer names its selector."""
     if not cfg.model.is_latent:
         return "heads"
+    if cfg.model.has_indexer:
+        # the selector: every read masks what the top-k of the index
+        # scores left out, inside the absorbed call
+        return f"latent-absorbed-selected-top-{cfg.model.index_topk}"
     from ..models.llama import latent_form_queries
 
     least = latent_form_queries(

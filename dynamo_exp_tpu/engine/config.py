@@ -139,7 +139,9 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
 def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
     """The one place a model whose cache is a latent pool
     (``ModelConfig.is_latent``: one row ``[c_kv | k_r]`` a token and layer
-    in ONE pool, and no value pool) is refused by what carries a page shape
+    in ONE pool, and no value pool; beside it, where the model has an
+    indexer, a second pool of another width that holds index keys, not
+    values) is refused by what carries a page shape
     it did not choose or splits heads, in ``refuse_seq_state_tiers``'
     manner. Each of these lays pages out as two pools of ``Hkv x D`` lanes,
     or divides attention by KV head, and none has run or been tested with a
@@ -175,7 +177,10 @@ def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> 
         raise ValueError(
             f"model_type {cfg.model.model_type!r} caches one latent row a "
             f"token ({cfg.model.latent_width} values in one pool, no value "
-            f"pool), which {', '.join(armed)} does not carry or split: it "
+            "pool" + (
+                f"; {cfg.model.index_head_dim} of index key in a second"
+                if cfg.model.has_indexer else ""
+            ) + f"), which {', '.join(armed)} does not carry or split: it "
             "moves pages as K and V of kv-head lanes. Serve it without "
             "(README.md, models/)"
         )

@@ -344,6 +344,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             pad_lanes=width - mcfg.latent_width if mcfg.is_latent else 0,
             token_bytes=(self.k_cache.nbytes + self.v_cache.nbytes)
             // (pages * page_size),
+            index_width=mcfg.index_lanes,
         )
         get_telemetry().watch_jax_compiles()
 
@@ -1367,15 +1368,23 @@ class TPUEngine(BlockDecode, AsyncEngine):
         expanded_rows``, the program's rule), everything else the absorbed
         one. A window gives each row's first position and its ``steps``
         (a step reads the row's context anew; every step is absorbed).
+        A model with an indexer attends ``min(p + 1, index_topk)`` pairs a
+        query and is asked to score all ``p + 1`` (``dynamo_dsa_*_total``:
+        every causal pair, also where a stream part skips the scoring
+        because none of its queries sees more than ``index_topk``).
         Host arithmetic on what the builder holds."""
         if not self.cfg.model.is_latent:
             return
         positions = np.asarray(positions, np.int64)
         expanded = 0
+        topk = self.cfg.model.index_topk
         if steps is None:
             live = positions >= 0
             seen = positions[live] + 1
             queries, pairs = int(live.sum()), int(seen.sum())
+            if topk:
+                selecting = int((seen > topk).sum())
+                kept = int(np.minimum(seen, topk).sum())
             by_row = np.zeros(int(row_of.max()) + 1 if len(row_of) else 0, np.int64)
             np.maximum.at(by_row, row_of[live], seen)
             context = int(by_row.sum())
@@ -1391,7 +1400,22 @@ class TPUEngine(BlockDecode, AsyncEngine):
             queries = int(steps.sum())
             pairs = int((steps * (positions + 1) + steps * (steps - 1) // 2).sum())
             context = pairs
+            if topk:
+                # step j of a row sees positions + 1 + j: those over topk
+                # keep topk, the others all they see
+                under = np.clip(topk - positions, 0, steps)  # steps seeing <= topk
+                selecting = int((steps - under).sum())
+                kept = int((
+                    under * (positions + 1) + under * (under - 1) // 2
+                    + (steps - under) * topk
+                ).sum())
         tel = get_telemetry()
+        if topk:
+            tel.dsa_index_pairs.labels(program).inc(pairs)
+            tel.dsa_selected_pairs.labels(program).inc(kept)
+            tel.dsa_query_tokens.labels(program, "selecting").inc(selecting)
+            tel.dsa_query_tokens.labels(program, "all").inc(queries - selecting)
+            pairs = kept  # what attention attends: the published work
         tel.mla_query_tokens.labels(program, "absorbed").inc(queries - expanded)
         if expanded:
             tel.mla_query_tokens.labels(program, "expanded").inc(expanded)
@@ -1461,14 +1485,15 @@ class TPUEngine(BlockDecode, AsyncEngine):
         what it takes. The reference has no tiles and counts nothing."""
         if self._attn_impl != "pallas":
             return
-        from ..ops.ragged_attention import merged_slot_counts, query_block
+        from ..models.llama import ragged_query_block
+        from ..ops.ragged_attention import merged_slot_counts
 
         cfg = self.cfg
         tiled = nb - cfg.ragged_packed_slots(nb)
         tile = cfg.ragged_q_tile
         merged, by_tile = merged_slot_counts(
             row_of[:tiled], positions[:tiled], tile,
-            query_block(tile, cfg.model.q_per_kv),
+            ragged_query_block(cfg.model, tile),
         )
         slots = get_telemetry().attn_query_slots
         slots.labels("merged").inc(merged)

@@ -171,6 +171,19 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # glm_moe_dsa: learned sparse attention over the latents. ``index_topk``
+    # > 0 gives every layer an *indexer*: ``index_n_heads`` queries of
+    # ``index_head_dim`` a token (from the queries' bottleneck) against one
+    # key of that width a context token (from the normed stream, after a
+    # LayerNorm; the first ``qk_rope_head_dim`` lanes of both rotary),
+    # summed over the heads with a weight a head and token; a query attends
+    # to the ``index_topk`` context tokens of largest index score and no
+    # others. The index keys are the second page pool's rows
+    # (``kv_pool_widths``; ``ops/sparse_index.py``). 0: no indexer, every
+    # query reads its whole context.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # Experts *held* beside experts routed over: the router scores all
     # ``num_experts``; this device holds ``num_experts_held`` of them, ids
     # ``[first_expert_held, first_expert_held + num_experts_held)`` (one
@@ -305,13 +318,27 @@ class ModelConfig:
         return -(-self.latent_width // 128) * 128
 
     @property
+    def has_indexer(self) -> bool:
+        """Whether attention reads a learned selection of its context
+        (``index_topk``) and not all of it."""
+        return self.index_topk > 0
+
+    @property
+    def index_lanes(self) -> int:
+        """``index_head_dim`` padded to whole groups of 128 lanes: the
+        width of an index key's row in the second page pool (0: no
+        indexer)."""
+        return -(-self.index_head_dim // 128) * 128 if self.has_indexer else 0
+
+    @property
     def kv_pool_widths(self) -> tuple[int, int]:
         """Lanes of a token's row in the two page pools
         (``models/llama.py::init_kv_cache``): K's and V's heads side by
-        side, or the latent row and nothing (the values are the row's
-        first ``kv_lora_rank`` lanes)."""
+        side, or the latent row and, where the model has an indexer, its
+        key (else nothing: the values are the latent row's first
+        ``kv_lora_rank`` lanes either way)."""
         if self.is_latent:
-            return self.latent_lanes, 0
+            return self.latent_lanes, self.index_lanes
         return (self.num_kv_heads * self.head_dim_,) * 2
 
     @property
@@ -345,6 +372,8 @@ class ModelConfig:
             return cls._from_olmo_hybrid(cfg)
         if model_type == "pangu_ultra_moe":
             return cls._from_pangu_ultra_moe(cfg)
+        if model_type == "glm_moe_dsa":
+            return cls._from_glm_moe_dsa(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -597,40 +626,11 @@ class ModelConfig:
         ``first_routed_expert``. The multi-token-prediction module
         (``num_nextn_predict_layers``) is refused: nothing here drafts
         from it."""
-        if cfg.get("num_nextn_predict_layers", 0):
-            raise ValueError(
-                "pangu_ultra_moe with num_nextn_predict_layers > 0 is not "
-                "supported: the multi-token-prediction module is not served"
-            )
         if not cfg.get("sandwich_norm", True):
             raise ValueError("pangu_ultra_moe with sandwich_norm=false is not supported")
-        if str(cfg.get("hidden_act", "silu")) != "silu":
-            raise ValueError("pangu_ultra_moe with hidden_act other than silu is not supported")
-        if cfg.get("attention_bias", False):
-            raise ValueError("pangu_ultra_moe with attention_bias=true is not supported")
         if cfg.get("rope_scaling"):
             raise ValueError("pangu_ultra_moe with rope_scaling is not supported")
-        if cfg.get("n_group", 1) > 1 or cfg.get("topk_group", 1) > 1:
-            raise ValueError(
-                "pangu_ultra_moe with group-limited selection (n_group / "
-                "topk_group > 1) is not supported"
-            )
-        for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                    "qk_rope_head_dim", "v_head_dim"):
-            if int(cfg.get(key) or 0) <= 0:
-                raise ValueError(f"pangu_ultra_moe needs {key} > 0")
-        if cfg["qk_rope_head_dim"] % 2:
-            raise ValueError("pangu_ultra_moe needs an even qk_rope_head_dim")
-        held = cfg["n_routed_experts"]
-        routed = cfg.get("n_routed_experts_global", held)
-        first = cfg.get("first_routed_expert", 0)
-        if not 0 <= first <= routed - held:
-            raise ValueError(
-                f"pangu_ultra_moe holds experts [{first}, {first + held}) of "
-                f"{routed}: outside the router's range"
-            )
-        if cfg["num_experts_per_tok"] > routed:
-            raise ValueError("pangu_ultra_moe num_experts_per_tok exceeds its experts")
+        held, routed, first = _latent_moe_share(cfg, "pangu_ultra_moe")
         return cls(
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -666,6 +666,74 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_glm_moe_dsa(cls, cfg: dict) -> "ModelConfig":
+        """``glm_moe_dsa`` (GLM-5): multi-head latent attention in every
+        layer whose queries read a learned selection of their context (an
+        indexer a layer: ``index_n_heads`` x ``index_head_dim``, the
+        ``index_topk`` context tokens of largest index score);
+        ``first_k_dense_replace`` leading dense SwiGLUs, then
+        ``n_routed_experts`` experts chosen top-k by ``sigmoid`` scores plus
+        a selection bias (``noaux_tc``: ``e_score_correction_bias``), the
+        chosen unbiased scores renormalised and times
+        ``routed_scaling_factor``, beside ``n_shared_experts`` ungated
+        shared experts; two norms a layer. ``n_routed_experts`` /
+        ``n_routed_experts_global`` / ``first_routed_expert`` as
+        ``_from_pangu_ultra_moe`` reads them (an expert-parallel rank's
+        share). Refused, because nothing here computes it: the
+        multi-token-prediction module, group-limited selection, a scaled
+        rotary base, a bias on attention's projections, another
+        activation than silu, another scoring than sigmoid."""
+        name = "glm_moe_dsa"
+        rope = cfg.get("rope_parameters") or {}
+        if cfg.get("rope_scaling") or rope.get("rope_type", "default") != "default":
+            raise ValueError(f"{name} with rope_scaling is not supported")
+        if str(cfg.get("scoring_func", "sigmoid")) != "sigmoid":
+            raise ValueError(f"{name} with scoring_func other than sigmoid is not supported")
+        held, routed, first = _latent_moe_share(
+            cfg, name, widths=("index_n_heads", "index_head_dim")
+        )
+        if int(cfg.get("index_topk") or 0) < 1:
+            raise ValueError(f"{name} needs index_topk >= 1")
+        if cfg["index_head_dim"] < cfg["qk_rope_head_dim"]:
+            raise ValueError(f"{name} needs index_head_dim >= qk_rope_head_dim")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=1,  # one latent a token stands for every head
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            # the published file keeps the base under ``rope_parameters``
+            rope_theta=float(rope.get("rope_theta") or cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 131072),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=routed,
+            num_experts_held=held if held < routed else 0,
+            first_expert_held=first,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            num_shared_experts=cfg.get("n_shared_experts", 0) or 0,
+            num_dense_layers=cfg.get("first_k_dense_replace", 0),
+            router_score="sigmoid",
+            use_expert_bias=True,
+            router_norm_eps=1e-20,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            index_n_heads=cfg["index_n_heads"],
+            index_head_dim=cfg["index_head_dim"],
+            index_topk=cfg["index_topk"],
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type=name,
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -683,10 +751,55 @@ class ModelConfig:
 # The families ``from_hf_config`` builds; any other ``model_type`` raises.
 # ``sdar_moe`` is ``qwen3_moe``'s stack, block for block, generating by
 # diffusion over blocks (``_block_generation``).
+
+def _latent_moe_share(cfg: dict, name: str, widths: tuple[str, ...] = ()) -> tuple[int, int, int]:
+    """What the readers of DeepSeek-V3's lineage (latent attention, sigmoid
+    routed experts beside shared ones: ``pangu_ultra_moe``, ``glm_moe_dsa``)
+    refuse alike, because nothing here computes it (the
+    multi-token-prediction module, another activation than silu, a bias on
+    attention's projections, group-limited selection, a missing or odd
+    latent width or one of ``widths``), and the share of the routed experts
+    the file describes: (held, the router's width, the first held id).
+    ``n_routed_experts`` is what this device holds; where the file also
+    gives ``n_routed_experts_global`` (an expert-parallel rank's share) the
+    router is that wide and the held ids start at ``first_routed_expert``."""
+    if cfg.get("num_nextn_predict_layers", 0):
+        raise ValueError(
+            f"{name} with num_nextn_predict_layers > 0 is not supported: "
+            "the multi-token-prediction module is not served"
+        )
+    if str(cfg.get("hidden_act", "silu")) != "silu":
+        raise ValueError(f"{name} with hidden_act other than silu is not supported")
+    if cfg.get("attention_bias", False):
+        raise ValueError(f"{name} with attention_bias=true is not supported")
+    if cfg.get("n_group", 1) > 1 or cfg.get("topk_group", 1) > 1:
+        raise ValueError(
+            f"{name} with group-limited selection (n_group / topk_group > 1) "
+            "is not supported"
+        )
+    for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", *widths):
+        if int(cfg.get(key) or 0) <= 0:
+            raise ValueError(f"{name} needs {key} > 0")
+    if cfg["qk_rope_head_dim"] % 2:
+        raise ValueError(f"{name} needs an even qk_rope_head_dim")
+    held = cfg["n_routed_experts"]
+    routed = cfg.get("n_routed_experts_global", held)
+    first = cfg.get("first_routed_expert", 0)
+    if not 0 <= first <= routed - held:
+        raise ValueError(
+            f"{name} holds experts [{first}, {first + held}) of {routed}: "
+            "outside the router's range"
+        )
+    if cfg["num_experts_per_tok"] > routed:
+        raise ValueError(f"{name} num_experts_per_tok exceeds its experts")
+    return held, routed, first
+
+
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
-    "olmo_hybrid", "pangu_ultra_moe",
+    "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -995,6 +1108,44 @@ TINY_PANGU = ModelConfig(  # pangu_ultra_moe family shape: latent attention
     model_type="pangu_ultra_moe",
 )
 
+TINY_GLM_DSA = ModelConfig(  # glm_moe_dsa family shape: latent attention
+    # (values wider than the keys' nope part) read through an indexer of 4
+    # heads of 32 whose keys lie in 128 lanes of the second pool, the 16
+    # context tokens of largest index score a query; one dense layer then
+    # two routed ones, 4 of 8 experts held (ids 4..7), chosen by sigmoid
+    # scores plus a selection bias, beside one ungated shared expert; two
+    # norms a layer
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=3,
+    num_heads=4,
+    num_kv_heads=1,
+    head_dim=32,
+    rope_theta=1000000.0,
+    max_position_embeddings=512,
+    num_experts=8,
+    num_experts_held=4,
+    first_expert_held=4,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    num_shared_experts=1,
+    num_dense_layers=1,
+    router_score="sigmoid",
+    use_expert_bias=True,
+    router_norm_eps=1e-20,
+    routed_scaling_factor=2.5,
+    q_lora_rank=24,
+    kv_lora_rank=40,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=16,
+    v_head_dim=24,
+    index_n_heads=4,
+    index_head_dim=32,
+    index_topk=16,
+    model_type="glm_moe_dsa",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -1018,6 +1169,7 @@ PRESETS = {
     "tiny-jamba": TINY_JAMBA,
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "tiny-pangu": TINY_PANGU,
+    "tiny-glm-dsa": TINY_GLM_DSA,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

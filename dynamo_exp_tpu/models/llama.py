@@ -128,6 +128,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     if cfg.is_latent:  # pangu_ultra_moe: latent attention's seven tensors
         del layers["wq"], layers["wk"], layers["wv"]
         layers.update(_init_latent(jax.random.fold_in(rng, 4), cfg, init))
+    if cfg.has_indexer:  # glm_moe_dsa: the selection's indexer, a layer's own
+        layers.update(_init_indexer(jax.random.fold_in(rng, 5), cfg, init))
     if cfg.num_linear_layers:  # olmo_hybrid: the gated delta rule
         layers.update(_init_linear(jax.random.fold_in(rng, 3), cfg, init))
     if Lc:  # lfm2: gated short convolution, no bias
@@ -158,7 +160,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         layers["w_down"] = init(ks[7], (Lr, E, Ie, D), Ie)
         if cfg.use_expert_bias:  # lfm2: chooses the experts, weighs nothing
             layers["router_bias"] = ROUTER_BIAS_SCALE * jax.random.normal(
-                kc[3], (Lr, E), jnp.float32
+                kc[3], (Lr, cfg.num_experts), jnp.float32
             )
         if Ld:  # lfm2: dense feed-forwards ahead of the routed ones
             layers["dense_gate"] = init(kc[4], (Ld, D, I), D)
@@ -213,6 +215,32 @@ def _init_latent(rng, cfg: ModelConfig, init) -> Params:
         "kv_a_norm": jnp.ones((La, rkv), dt),
         "w_ukv": init(kl[3], (La, rkv, H * (dn + dv)), rkv),
         "wo": init(kl[4], (La, H * dv, D), H * dv),
+    }
+
+
+# The indexer's parameters (learned sparse attention), each stacked over
+# the attention layers: its queries' projection from the queries'
+# bottleneck, its key's from the normed stream with the key's LayerNorm
+# (scale and bias), and the projection of a token's weight a head.
+INDEXER_PARAMS = ("idx_wq", "idx_wk", "idx_k_norm", "idx_k_bias", "idx_wp")
+# The key's LayerNorm's epsilon (DeepSeek-V3.2's indexer: torch's default).
+INDEX_NORM_EPS = 1e-6
+
+
+def _init_indexer(rng, cfg: ModelConfig, init) -> Params:
+    """Random indexer projections, ``[L_attn, ...]`` each. The key's
+    LayerNorm starts at scale 1 and a small random bias, so that a fault
+    that drops the bias shows."""
+    dt = _dtype(cfg)
+    La, D, rq = cfg.num_attn_layers, cfg.hidden_size, cfg.q_lora_rank
+    Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+    ki = jax.random.split(rng, 4)
+    return {
+        "idx_wq": init(ki[0], (La, rq, Hi * Di), rq),
+        "idx_wk": init(ki[1], (La, D, Di), D),
+        "idx_k_norm": jnp.ones((La, Di), dt),
+        "idx_k_bias": (0.1 * jax.random.normal(ki[3], (La, Di), jnp.float32)).astype(dt),
+        "idx_wp": init(ki[2], (La, D, Hi), D),
     }
 
 
@@ -331,6 +359,8 @@ def param_shardings(
         # (``engine/config.py::refuse_latent_tiers``).
         del layers["wq"], layers["wk"], layers["wv"]
         layers.update((k, P()) for k in (*LATENT_PARAMS, "wo"))
+    if cfg.has_indexer:
+        layers.update((k, P()) for k in INDEXER_PARAMS)
     if cfg.num_linear_layers:
         # Replicated: the delta rule under tp > 1 is refused
         # (``engine/config.py::refuse_seq_state_tiers``).
@@ -619,12 +649,15 @@ def latent_form_queries(
     expanded form of latent attention: at least this many live queries of
     the row in the call (``ops/ragged_attention.py::expanded_min_queries``,
     from the model's widths and the tile). None: no row does (another kind
-    of model, or widths the expanded call does not tile on a chip). The
+    of model, one with an indexer, whose selection only the absorbed call
+    applies, or widths the expanded call does not tile on a chip). The
     program (``forward_ragged``) and the engine's counter
     (``_count_latent_reads``) both ask here."""
     from ..ops.ragged_attention import expanded_min_queries, expanded_supported
 
-    if not cfg.is_latent:
+    if not cfg.is_latent or cfg.has_indexer:
+        # A model that reads a selection of its context masks it inside the
+        # absorbed call's pass over the pages, whatever the row's queries.
         return None
     if not interpret and not expanded_supported(
         page_size, cfg.latent_lanes, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
@@ -673,10 +706,21 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
     and never cached: ``2 (nope + rope + v)`` operations a pair and head,
     the published form's.
 
-    ``u`` is the normed stream, the output is normed again before the add
-    (four norms a layer).
+    ``u`` is the normed stream; a model with four norms a layer
+    (pangu_ultra_moe) norms the output again before the add.
 
-    ``read(q, pool, absorb, expand, w_ukv) -> o`` ([B, T, H, v]) runs the
+    **The indexer** (``cfg.has_indexer``: glm_moe_dsa), scope ``dsa_index``:
+    ``q_I = c_q W_Iq`` (``index_n_heads`` heads of ``index_head_dim``), its
+    first ``qk_rope_head_dim`` lanes rotary; ``k_I = LayerNorm(u W_Ik)``,
+    its first lanes rotary likewise: the row ``write`` puts into the second
+    pool beside the latent row; ``w = u W_Iw * (heads * width)^-1/2`` in
+    float32. ``read`` gets ``index = (q_I, w, the index pool)`` and lets a
+    query attend only to the ``index_topk`` positions of largest index
+    score (``ops/sparse_index.py``).
+
+    ``write(row, index_row) -> (pool, index pool)``; ``index_row`` None
+    where the model has no indexer.
+    ``read(q, pool, absorb, expand, w_ukv, index) -> o`` ([B, T, H, v]) runs the
     kernel on ``absorb(q_part)`` for each part of the stream it reads
     absorbed at a time and hands ``expand`` of each part's result back: the
     two products either side of the kernel (scope ``mla_absorb``) are then
@@ -702,7 +746,16 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
             row = jnp.concatenate(
                 [c_kv, k_r, jnp.zeros((B, T, pad), c_kv.dtype)], axis=-1
             )
-            pool = write(row)
+            if not cfg.has_indexer:
+                pool, index_pool = write(row, None)
+        index = None
+        if cfg.has_indexer:
+            with jax.named_scope("dsa_index"):
+                q_i, k_i, w_i = index_projections(
+                    h, c_q, lp, cfg, inv_freq, rope_pos
+                )
+                pool, index_pool = write(row, k_i)
+            index = (q_i, w_i, index_pool)
         w_ukv = lp["w_ukv"].reshape(r, H, -1)  # [r, H, nope + v]
         # One product lays the absorbed query out as the pool's row is:
         # ``[q_nope W_uk^T | q_rope | 0]``. The rotary part rides an identity
@@ -726,11 +779,45 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
             with jax.named_scope("mla_absorb"):
                 return jnp.einsum("...hc,chv->...hv", o_lat[..., :r], w_ukv[..., dn:])
 
-        o = read(q, pool, absorb, expand, lp["w_ukv"])
+        o = read(q, pool, absorb, expand, lp["w_ukv"], index)
         with jax.named_scope("attn_out"):
             attn_out = red(o.reshape(B, T, -1) @ lp["wo"])
-            attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps)
-        return x + attn_out, pool
+            if "post_attn_norm" in lp:
+                attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps)
+        return x + attn_out, (pool, index_pool)
+
+
+def index_projections(h, c_q, lp, cfg, inv_freq, rope_pos):
+    """The indexer's three products of a layer (``_mla_half``): from the
+    normed stream ``h`` [B, T, D] and the queries' bottleneck ``c_q`` [B, T,
+    q_lora_rank], (``q_I`` [B, T, Hi, Di], its first ``qk_rope_head_dim``
+    lanes rotary; the index pool's row [B, T, index_lanes]: ``k_I`` after
+    its LayerNorm, its first lanes rotary, zeros behind ``Di``; ``w`` [B, T,
+    Hi] float32, scaled by ``(Hi * Di)^-1/2``)."""
+    B, T = h.shape[:2]
+    Hi, Di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    q_i = (c_q @ lp["idx_wq"]).reshape(B, T, Hi, Di)
+    q_i = jnp.concatenate(
+        [apply_rope(q_i[..., :dr], rope_pos, inv_freq), q_i[..., dr:]], axis=-1
+    )
+    k_i = _layer_norm(
+        h @ lp["idx_wk"], lp["idx_k_norm"], lp["idx_k_bias"], INDEX_NORM_EPS
+    )
+    k_i = jnp.concatenate([
+        apply_rope(k_i[..., None, :dr], rope_pos, inv_freq)[..., 0, :],
+        k_i[..., dr:],
+        jnp.zeros((B, T, cfg.index_lanes - Di), k_i.dtype),
+    ], axis=-1)
+    w_i = (h @ lp["idx_wp"]).astype(jnp.float32) * (Hi * Di) ** -0.5
+    return q_i, k_i, w_i
+
+
+def _layer_norm(x, w, b, eps):
+    """LayerNorm over the last axis in float32, scale and bias, cast back."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    normed = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (normed * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
 class SeqStateIO(NamedTuple):
@@ -1269,7 +1356,7 @@ _EVERY_LAYER = ("attn_norm", "mlp_norm", "post_attn_norm", "post_ffn_norm")
 _OP_PARAMS = {
     "attn": (
         "wq", "wk", "wv", "wo", "bq", "bk", "bv", "q_norm", "k_norm",
-        *LATENT_PARAMS,
+        *LATENT_PARAMS, *INDEXER_PARAMS,
     ),
     "conv": ("conv_in", "conv_w", "conv_out"),
     "mamba": MAMBA_PARAMS,
@@ -1284,16 +1371,18 @@ _ROUTED_PARAMS = (
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
     live, interpret=False, seq_state=None, state_io=None, scan_form=ScanForm(),
-    experts_any_rows=False, count_picks=False,
+    experts_any_rows=False, count_picks=False, take_kept=False,
 ):
     """The layer loop of both paged forwards: one ``lax.scan`` for each
     run of layers of one kind (``ModelConfig.layer_runs``), carrying
     ``(x, k_cache, v_cache)`` and, where the model has layers that are not
     softmax attention, ``seq_state`` (a pytree by part: ``init_seq_state``). Returns (x,
-    k_cache, v_cache, seq_state, held_picks): the last None unless
+    k_cache, v_cache, seq_state, held_picks, kept): ``held_picks`` None unless
     ``count_picks`` (a model that holds a share of its experts: int32 [2],
     the live rows' picks that landed on held experts and the held experts
-    that had one, each summed over the routed layers). A model of
+    that had one, each summed over the routed layers); ``kept`` None unless
+    ``take_kept`` (a model with an indexer: what ``read`` put into the
+    ``taken`` list it was handed, stacked over the layers). A model of
     one kind is one run over the stacked parameters, the per-layer window
     / rope base and the layer index, as it always was.
 
@@ -1354,6 +1443,7 @@ def _scan_paged_layers(
     red = lambda y: y  # noqa: E731 - GSPMD reduces; no explicit psum here
     carry = (x, k_cache, v_cache, seq_state)
     held_picks = jnp.zeros((2,), jnp.int32)
+    kept_runs = []
 
     for op, ff, first, n, op0, ff0 in cfg.layer_runs:
         # name in the body -> (stack, the run's first index in it)
@@ -1387,6 +1477,7 @@ def _scan_paged_layers(
             x, k_pool, v_pool, state = carry
             lp, win_l, invf_l, i = layer_in
             lp = {**lp, **stacks}
+            taken = []  # ``take_kept``: the layer's selection, as ``read`` hands it out
             for name, (stack, at) in closed.items():
                 lp[name] = jax.lax.dynamic_index_in_dim(
                     stack, at + i, 0, keepdims=False
@@ -1406,21 +1497,34 @@ def _scan_paged_layers(
                 # One pool: a token's row, its values among its lanes.
                 # ``v_pool`` (no lanes) rides the carry untouched.
 
-                def write(row):
+                def write(row, index_row):
+                    # ``index_row`` (a model with an indexer): the second
+                    # pool's row, written at the same page and offset
                     with jax.named_scope("kv_write"):
-                        kp, _ = write_kv_pages(
-                            k_pool, None, row.reshape(-1, row.shape[-1]), None,
+                        kp, vp = write_kv_pages(
+                            k_pool, None if index_row is None else v_pool,
+                            row.reshape(-1, row.shape[-1]),
+                            None if index_row is None
+                            else index_row.reshape(-1, index_row.shape[-1]),
                             *write_at, layer=l,
                         )
-                    return kp
+                    return kp, v_pool if index_row is None else vp
 
-                def read_latent(q, kp, absorb, expand, w_ukv):
+                def read_latent(q, kp, absorb, expand, w_ukv, index):
+                    selector = {}
+                    if index is not None:
+                        q_i, w_i, ip = index
+                        selector["index"] = (
+                            q_i, w_i, ip.reshape(-1, *ip.shape[2:])
+                        )
+                        if take_kept:
+                            selector["taken"] = taken
                     return read(
                         q, kp.reshape(flat), None, table + l * num_pages,
-                        win_l, wrap=(absorb, expand, w_ukv),
+                        win_l, wrap=(absorb, expand, w_ukv), **selector,
                     )
 
-                x, k_pool = _mla_half(
+                x, (k_pool, v_pool) = _mla_half(
                     x, lp, cfg, invf_l, rope_pos, eps, write, read_latent, red
                 )
             else:
@@ -1449,10 +1553,10 @@ def _scan_paged_layers(
                 experts_any_rows, tally,
             )
             picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
-            return (x, k_pool, v_pool, state), picks
+            return (x, k_pool, v_pool, state), (picks, taken[0] if taken else None)
 
         whole_model = n == L
-        carry, picks = jax.lax.scan(
+        carry, (picks, kept) = jax.lax.scan(
             layer,
             carry,
             (
@@ -1464,7 +1568,12 @@ def _scan_paged_layers(
         )
         if count_picks:
             held_picks = held_picks + jnp.sum(picks, axis=0)
-    return (*carry, held_picks if count_picks else None)
+        if kept is not None:
+            kept_runs.append(kept)
+    return (
+        *carry, held_picks if count_picks else None,
+        jnp.concatenate(kept_runs) if kept_runs else None,
+    )
 
 
 def forward(
@@ -1485,6 +1594,7 @@ def forward(
     seq_state: jnp.ndarray | None = None,
     state_slots: jnp.ndarray | None = None,
     count_picks: bool = False,
+    kept_of: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, ...]:
     """One forward step (prefill or decode by bucket shape).
 
@@ -1507,6 +1617,12 @@ def forward(
     last, int32 [2]: how many of the live rows' expert picks landed on held
     experts, and how many held experts had one (the matrices a layer has
     to fetch), each summed over the routed layers.
+
+    ``kept_of`` ([M] int32 slots of the flat ``[B * T]`` stream; a model
+    with an indexer) returns, last, bool [L, M, S]: which positions of its
+    row's table each of those queries' selection kept in each layer, as the
+    attention was given it (``_selection_tiled`` / ``_selection_ref``; a
+    position the query does not see may read either).
 
     ``attn_pages`` (static) bounds the XLA path's page gather: attention
     reads only the first ``attn_pages`` table columns, so short contexts
@@ -1572,25 +1688,39 @@ def forward(
     have_window = cfg.sliding_window is not None
     latent = _latent_read_args(cfg)
 
-    def read(q, kf, vf, table_l, win_l, wrap=None):
+    def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None):
         # ``wrap`` (a latent model: ``_mla_half``'s absorb and expand, and
         # ``W_ukv`` for a form this forward never takes) stands either side
-        # of the read, which then names itself
+        # of the read, which then names itself; ``index`` (a model with an
+        # indexer) is what the read's selection is computed from, and
+        # ``taken`` the list that gets what it kept for ``kept_of``
         pre, post, scope = _read_wrap(wrap)
+        keep = {}
         if use_pallas:
+            if index is not None:
+                keep["keep"] = _selection_tiled(
+                    cfg, (index[0][:, 0], index[1][:, 0], index[2]), table_l,
+                    jnp.arange(B, dtype=jnp.int32), lengths - 1, 1, interpret,
+                )
+                if taken is not None:
+                    taken.append(_kept_tiled(keep["keep"], kept_of, 1))
             with scope:
                 o = _pallas_decode(
                     pre(q[:, 0]), kf, vf, table_l, lengths, cfg.num_kv_heads,
-                    mesh, interpret, **latent,
+                    mesh, interpret, **latent, **keep,
                 )
             return post(o)[:, None]
         q = pre(q)
+        if index is not None:
+            keep["keep"] = _selection_ref(cfg, index, table_l, positions)
+            if taken is not None:
+                taken.append(keep["keep"].reshape(B * T, -1)[kept_of])
         with scope:
             o = paged_attention(
                 q, kf, vf, table_l, positions,
                 window=win_l if have_window else None,
                 softcap=cfg.attn_logit_softcap,
-                **{"sm_scale": sm_scale, **latent},
+                **{"sm_scale": sm_scale, **latent, **keep},
             )
         return post(o)
 
@@ -1604,28 +1734,31 @@ def forward(
             src=jnp.arange(B, dtype=jnp.int32) * T + jnp.maximum(n_real - 1, 0),
             dst=jnp.where(n_real > 0, state_slots, seq_state_entries(seq_state)),
         )
-    x, k_cache, v_cache, seq_state, held_picks = _scan_paged_layers(
+    x, k_cache, v_cache, seq_state, held_picks, kept = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid),
         page_table if use_pallas else attn_table, read, mesh,
         positions >= 0, interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, T, None, interpret, one_token=T == 1),
-        count_picks=count_picks,
+        count_picks=count_picks, take_kept=kept_of is not None,
     )
     if last_positions is not None:
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
     logits = _final_logits(params, cfg, x, eps)
-    return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks)
+    return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks, kept)
 
 
-def _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks):
+def _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks, kept=None):
     """A paged forward's results: the logits and the pools, then the state
-    pool of a model that has one, then the held picks where asked for."""
+    pool of a model that has one, then the held picks and the kept
+    positions where asked for."""
     out = (logits, k_cache, v_cache)
     if seq_state is not None:
         out += (seq_state,)
     if held_picks is not None:
         out += (held_picks,)
+    if kept is not None:
+        out += (kept,)
     return out
 
 
@@ -1660,6 +1793,65 @@ def _read_wrap(wrap):
     if wrap is None:
         return (lambda a: a), (lambda a: a), contextlib.nullcontext()
     return (*wrap[:2], jax.named_scope("attend"))
+
+
+def _selection_tiled(cfg, index, table, row_of, bounds, q_tile, interpret):
+    """A stream part's learned selection as the attention kernel takes it
+    (its ``keep``): the index scores tiled, and a threshold and a number of
+    ties a query (``ops/sparse_index.py::select_tiled``), ``index`` being (``q_I`` [n, Hi, Di], ``w``
+    [n, Hi], the index pool as one page axis). A part none of whose queries
+    sees more than ``index_topk`` positions computes neither: every
+    threshold is ``-inf`` and the scores are not read."""
+    from ..ops import sparse_index as si
+
+    q_i, w_i, pool = index
+    n, k = bounds.shape[0], cfg.index_topk
+
+    def selecting():
+        with jax.named_scope("dsa_index"):
+            scores = si.index_scores(
+                q_i, w_i, pool, table, row_of, bounds, q_tile=q_tile,
+                interpret=interpret,
+            )
+        with jax.named_scope("dsa_select"):
+            tau, allow = si.select_tiled(
+                scores, bounds.reshape(-1, q_tile), k, interpret=interpret
+            )
+        return scores, tau.reshape(n), allow.reshape(n)
+
+    def all_seen():
+        shape = si.tiled_shape(n, q_tile, pool.shape[1], table.shape[1])
+        return (
+            jnp.zeros(shape, jnp.float32), jnp.full((n,), -jnp.inf),
+            jnp.full((n,), si.NO_LIMIT),
+        )
+
+    return jax.lax.cond(jnp.any(bounds >= k), selecting, all_seen)
+
+
+def _selection_ref(cfg, index, table, bounds):
+    """A learned selection as the gather takes it: which of its row's
+    ``table`` positions each query keeps ([B, T, S] bool), from ``index`` =
+    (``q_I`` [B, T, Hi, Di], ``w`` [B, T, Hi], the index pool) and the last
+    position each query sees ([B, T])."""
+    from ..ops import sparse_index as si
+
+    q_i, w_i, pool = index
+    with jax.named_scope("dsa_index"):
+        scores = si.index_scores_ref(q_i, w_i, pool, table, bounds)
+    with jax.named_scope("dsa_select"):
+        return si.kept(scores, *si.select(scores, bounds, cfg.index_topk))
+
+
+def _kept_tiled(selection, at, q_tile):
+    """Which positions of its row's table each stream slot of ``at`` ([M],
+    counted within the part) keeps ([M, S] bool), read off the part's
+    ``_selection_tiled`` as the attention kernel reads it."""
+    from ..ops import sparse_index as si
+
+    scores, tau, allow = selection
+    mine = scores[at // q_tile, :, at % q_tile].reshape(at.shape[0], -1)
+    return si.kept(mine, tau[at], allow[at])
 
 
 def _latent_read_args(cfg: ModelConfig) -> dict:
@@ -1761,6 +1953,17 @@ def _pallas_ragged(
     return f(q, kp, vp, attn_table, row_of, positions)
 
 
+def ragged_query_block(cfg: ModelConfig, q_tile: int) -> int:
+    """Stream slots a grid cell of a mixed stream's tiled attention call
+    serves (``ops/ragged_attention.py::query_block``, from the model's query
+    heads a KV head), or one tile for a model with an indexer, whose
+    selection rides cells of one tile. The program and the engine's counter
+    (``dynamo_attn_query_slots_total``) both ask here."""
+    from ..ops.ragged_attention import query_block
+
+    return q_tile if cfg.has_indexer else query_block(q_tile, cfg.q_per_kv)
+
+
 def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
                sm_scale, interpret, scope, absorbed):
     """Latent attention over a stream's tiled part in both forms: the rows
@@ -1818,6 +2021,7 @@ def forward_ragged(
     experts_any_rows: bool = False,
     project: bool = True,
     count_picks: bool = False,  # ``forward``'s
+    kept_of: jnp.ndarray | None = None,  # ``forward``'s: slots of the [N] stream
 ) -> tuple[jnp.ndarray, ...]:
     """One ragged forward over a flat mixed batch (the single-dispatch
     prefill+decode+spec path, docs/engine_perf.md).
@@ -1856,7 +2060,9 @@ def forward_ragged(
     ``out_idx`` picks the flat positions that reach the vocab
     projection (each row's sampling position(s)): the lm_head runs on
     ``M`` tokens, not ``N``, so a 512-token chunk still pays one row of
-    logits. Returns (logits [M, V] float32, new_k, new_v).
+    logits. Returns (logits [M, V] float32, new_k, new_v), then what
+    ``forward`` adds for ``seq_state``, ``count_picks`` and ``kept_of``
+    (slots of this stream; the kept positions [L, len(kept_of), S] bool).
 
     Sliding-window / softcapped / query-scaled models (gemma2, mistral)
     follow the same per-layer machinery as :func:`forward`; the Pallas
@@ -1928,17 +2134,34 @@ def forward_ragged(
                 expand_from,
             )
 
-    def read(q, kf, vf, table_l, win_l, wrap=None):
-        pre, post, scope = _read_wrap(wrap)  # ``forward``'s
+    def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None):
+        # ``forward``'s, as ``index`` and ``taken``
+        pre, post, scope = _read_wrap(wrap)
         if use_pallas:
-            from ..ops.ragged_attention import query_block
-
             def kernel(part, tile, block=None, seen=None):
+                keep = {}
+                if index is not None:
+                    keep["keep"] = _selection_tiled(
+                        cfg, (index[0][0, part], index[1][0, part], index[2]),
+                        table_l, row_of[part],
+                        bounds[part] if seen is None else seen, tile, interpret,
+                    )
+                    if taken is not None:
+                        # the slots of ``kept_of`` that lie in this part
+                        start, stop, _ = part.indices(positions.shape[0])
+                        mine = _kept_tiled(
+                            keep["keep"],
+                            jnp.clip(kept_of - start, 0, stop - start - 1), tile,
+                        )
+                        here = (kept_of >= start) & (kept_of < stop)
+                        taken.append(jnp.where(
+                            here[:, None], mine, taken.pop() if taken else False
+                        ))
                 with scope:
                     o = _pallas_ragged(
                         pre(q[0, part]), kf, vf, table_l, row_of[part],
                         bounds[part] if seen is None else seen, cfg.num_kv_heads,
-                        tile, mesh, interpret, block, **latent,
+                        tile, mesh, interpret, block, **latent, **keep,
                     )
                 return post(o)
 
@@ -1968,19 +2191,27 @@ def forward_ragged(
             # A mixed stream's spans run over many tiles of one row: a
             # grid cell takes a block of them (ops/ragged_attention.py).
             return jnp.concatenate([
-                *tiles(packed_from, query_block(q_tile, cfg.q_per_kv)),
+                *tiles(packed_from, ragged_query_block(cfg, q_tile)),
                 kernel(slice(packed_from, None), 1),
             ])[None]
         from ..ops.ragged_attention import ragged_paged_attention_ref
 
         q = pre(q[0])
+        keep = {}
+        if index is not None:
+            keep["keep"] = _selection_ref(
+                cfg, (index[0][0][:, None], index[1][0][:, None], index[2]),
+                table_l[row_of], bounds[:, None],
+            )[:, 0]
+            if taken is not None:
+                taken.append(keep["keep"][kept_of])
         with scope:
             o = ragged_paged_attention_ref(
                 q, kf, vf, table_l, row_of, positions,
                 num_kv_heads=cfg.num_kv_heads,
                 window=win_l if have_window else None,
                 softcap=cfg.attn_logit_softcap, bounds=bounds,
-                **{"sm_scale": sm_scale, **latent},
+                **{"sm_scale": sm_scale, **latent, **keep},
             )
         return post(o)[None]
 
@@ -1988,16 +2219,16 @@ def forward_ragged(
     if seq_state is not None:
         slots, src, dst = state_rows
         state_io = SeqStateIO(slots, row_of, positions, src, dst)
-    x, k_cache, v_cache, seq_state, held_picks = _scan_paged_layers(
+    x, k_cache, v_cache, seq_state, held_picks, kept = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
         (page_ids, offsets, valid), attn_table, read, mesh,
         (positions >= 0)[None], interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, q_tile, packed_from, interpret),
-        experts_any_rows, count_picks,
+        experts_any_rows, count_picks, kept_of is not None,
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
     logits = _final_logits(params, cfg, xo, eps) if project else xo
-    return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks)
+    return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks, kept)
 
 
 def forward_ring_prefill(

@@ -213,7 +213,7 @@ SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
-    "olmo_hybrid", "pangu_ultra_moe",
+    "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa",
 )
 
 
@@ -274,6 +274,101 @@ def _pangu_ultra_moe_params(cfg: ModelConfig, get, linear) -> dict:
     }
 
 
+def rotary_columns(cfg: ModelConfig) -> dict[str, list[int]]:
+    """Where the rotary blocks (``qk_rope_head_dim`` columns each) of a
+    ``glm_moe_dsa`` layer's tensors start on the last axis, by parameter:
+    the rope part of every head's query, the latent's ``k_r``, and the first
+    lanes of every index head's query, of the index key and of its
+    LayerNorm's scale and bias."""
+    dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    return {
+        "w_uq": [h * (dn + dr) + dn for h in range(cfg.num_heads)],
+        "w_dkv": [r],
+        "idx_wq": [j * cfg.index_head_dim for j in range(cfg.index_n_heads)],
+        "idx_wk": [0], "idx_k_norm": [0], "idx_k_bias": [0],
+    }
+
+
+def rotary_layout(arr: np.ndarray, starts: list[int], width: int, to: str) -> np.ndarray:
+    """``arr`` with each rotary block of ``width`` columns (last axis)
+    re-ordered: ``to="halves"`` takes the published interleaved pairs
+    ``(2i, 2i + 1)`` to this repo's ``apply_rope`` pairs ``(i, i + width /
+    2)``; ``to="pairs"`` takes them back. A permutation of columns on both
+    sides of every rotary dot product: exact."""
+    half = np.arange(width // 2)
+    order = np.concatenate([2 * half, 2 * half + 1])  # halves <- pairs
+    if to == "pairs":
+        order = np.argsort(order)
+    elif to != "halves":
+        raise ValueError(to)
+    out = np.array(arr)
+    for a in starts:
+        out[..., a:a + width] = arr[..., a:a + width][..., order]
+    return out
+
+
+def _glm_moe_dsa_params(cfg: ModelConfig, get, linear) -> dict:
+    """``glm_moe_dsa`` tensor names (DeepSeek-V3.2's) into the stacks by
+    kind of ``models/llama.py::init_params``: ``input_layernorm`` /
+    ``post_attention_layernorm`` over every layer; latent attention's
+    ``self_attn.{q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
+    kv_a_layernorm, kv_b_proj, o_proj}`` and the indexer's
+    ``self_attn.indexer.{wq_b, wk, k_norm (weight, bias), weights_proj}``
+    over every layer; ``mlp.{gate,up,down}_proj`` over the dense layers;
+    ``mlp.gate.weight`` (the router), ``mlp.gate.e_score_correction_bias``
+    (the selection bias), ``mlp.shared_experts.*`` and ``mlp.experts.N.*``
+    over the routed ones, ``N`` over the experts this device holds. The
+    family rotates interleaved pairs and ``apply_rope`` halves: every
+    rotary block's columns are re-ordered here (``rotary_layout``)."""
+    names = (
+        "attn_norm", "mlp_norm", "w_dq", "q_a_norm", "w_uq", "w_dkv",
+        "kv_a_norm", "w_ukv", "wo", "idx_wq", "idx_wk", "idx_k_norm",
+        "idx_k_bias", "idx_wp", "dense_gate", "dense_up", "dense_down",
+        "router", "router_bias", "shared_gate", "shared_up", "shared_down",
+        "w_gate", "w_up", "w_down",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    proj = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
+    held = range(cfg.first_expert_held, cfg.first_expert_held + cfg.experts_held)
+    for i, ff in enumerate(cfg.ff_kinds):
+        p = f"model.layers.{i}."
+        layers["attn_norm"].append(get(p + "input_layernorm.weight"))
+        layers["mlp_norm"].append(get(p + "post_attention_layernorm.weight"))
+        a = p + "self_attn."
+        for key, name in (
+            ("w_dq", "q_a_proj"), ("w_uq", "q_b_proj"), ("w_dkv", "kv_a_proj_with_mqa"),
+            ("w_ukv", "kv_b_proj"), ("wo", "o_proj"), ("idx_wq", "indexer.wq_b"),
+            ("idx_wk", "indexer.wk"), ("idx_wp", "indexer.weights_proj"),
+        ):
+            layers[key].append(linear(f"{a}{name}.weight"))
+        layers["q_a_norm"].append(get(a + "q_a_layernorm.weight"))
+        layers["kv_a_norm"].append(get(a + "kv_a_layernorm.weight"))
+        layers["idx_k_norm"].append(get(a + "indexer.k_norm.weight"))
+        layers["idx_k_bias"].append(get(a + "indexer.k_norm.bias"))
+        m = p + "mlp."
+        if ff == "dense":
+            for key, name in proj:
+                layers[f"dense_{key}"].append(linear(f"{m}{name}.weight"))
+            continue
+        layers["router"].append(linear(m + "gate.weight"))
+        layers["router_bias"].append(get(m + "gate.e_score_correction_bias"))
+        for key, name in proj:
+            if cfg.shared_width:
+                layers[f"shared_{key}"].append(linear(f"{m}shared_experts.{name}.weight"))
+            layers[f"w_{key}"].append(np.stack([
+                linear(f"{m}experts.{e}.{name}.weight") for e in held
+            ]))
+    stacked = {k: np.stack(v) for k, v in layers.items() if v}
+    for key, starts in rotary_columns(cfg).items():
+        stacked[key] = rotary_layout(stacked[key], starts, cfg.qk_rope_head_dim, "halves")
+    return {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": stacked,
+        "final_norm": get("model.norm.weight"),
+        "lm_head": linear("lm_head.weight"),
+    }
+
+
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
     """Load a HF checkpoint directory (llama/mistral/qwen2/mixtral
     families) into the stacked param pytree."""
@@ -307,6 +402,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         "lfm2_moe": _lfm2_moe_params, "jamba": _jamba_params,
         "olmo_hybrid": _olmo_hybrid_params,
         "pangu_ultra_moe": _pangu_ultra_moe_params,
+        "glm_moe_dsa": _glm_moe_dsa_params,
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

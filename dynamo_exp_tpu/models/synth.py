@@ -114,6 +114,27 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             routed_scaling_factor=cfg.routed_scaling_factor,
             num_nextn_predict_layers=0,
         )
+    elif cfg.model_type == "glm_moe_dsa":  # the family's own keys
+        del hf["num_key_value_heads"]
+        hf.update(
+            architectures=["GlmMoeDsaForCausalLM"], hidden_act="silu",
+            attention_bias=False,
+            q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+            index_n_heads=cfg.index_n_heads, index_head_dim=cfg.index_head_dim,
+            index_topk=cfg.index_topk,
+            first_k_dense_replace=cfg.num_dense_layers,
+            n_routed_experts=cfg.experts_held,
+            n_routed_experts_global=cfg.num_experts,
+            first_routed_expert=cfg.first_expert_held,
+            n_shared_experts=cfg.num_shared_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
+            topk_group=1, num_nextn_predict_layers=0,
+        )
     elif cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -122,7 +143,8 @@ def write_config(path: str, cfg: ModelConfig) -> None:
         raise ValueError(
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
-            "the llama family, lfm2_moe, jamba, olmo_hybrid and pangu_ultra_moe only"
+            "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe "
+            "and glm_moe_dsa only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

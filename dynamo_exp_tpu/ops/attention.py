@@ -73,6 +73,7 @@ def paged_attention(
     window: int | jnp.ndarray | None = None,
     softcap: float | None = None,
     value_dim: int | None = None,
+    keep: jnp.ndarray | None = None,  # [B, T, S] bool: a learned selection
 ) -> jnp.ndarray:
     """Causal attention of queries against their sequences' pages.
 
@@ -87,7 +88,9 @@ def paged_attention(
     (mistral/gemma2 sliding-window attention) additionally masks keys
     older than ``q_pos - window + 1`` — it may be a traced scalar, so a
     scan over layers can alternate window widths (gemma2). ``softcap``
-    applies gemma2's tanh cap to the scores before masking.
+    applies gemma2's tanh cap to the scores before masking. ``keep``
+    (learned sparse attention, ``ops/sparse_index.py``) additionally masks
+    every position a query's selection left out, for all its heads.
     """
     B, T, H, D = q.shape
     P, ps, _ = k_cache.shape
@@ -120,6 +123,8 @@ def paged_attention(
     mask = kv_pos <= qp  # causal by position
     if window is not None:
         mask &= kv_pos > qp - window
+    if keep is not None:
+        mask &= keep[:, None, None]
     scores = jnp.where(mask, scores, -1e30)
 
     probs = jax.nn.softmax(scores, axis=-1)

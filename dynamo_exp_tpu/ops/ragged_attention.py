@@ -141,6 +141,7 @@ def ragged_paged_attention_ref(
     softcap: float | None = None,
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
+    keep: jnp.ndarray | None = None,  # [N, S] bool: a learned selection
 ) -> jnp.ndarray:
     """Pure-JAX ragged paged attention (the CPU/tier-1 parity path).
 
@@ -175,6 +176,7 @@ def ragged_paged_attention_ref(
         window=window,
         softcap=softcap,
         value_dim=value_dim,
+        **({} if keep is None else {"keep": keep[:, None]}),
     )
     return out[:, 0]
 
@@ -272,6 +274,7 @@ def _attend(
     pmax: int,
     scale: float,
     vd: int | None = None,
+    keep=None,
 ):
     """One double-buffered pass over ``row``'s first ``kvlen`` tokens
     for ``w`` queries: flash-style online softmax, ``qpk * w`` score
@@ -279,7 +282,12 @@ def _attend(
     one another in a grid cell on the same buffers and semaphores.
     ``vd`` (a latent pool): a head's values are the first ``vd`` lanes of
     its key row, and the two products take their operands in the pool's
-    dtype (module docstring)."""
+    dtype (module docstring). ``keep`` (a learned selection,
+    ``ops/sparse_index.py``): ``(idx_ref [chunks, w, S], tau_ref [w, 1],
+    allow_ref [w, 1])``, float32 in VMEM; query ``i`` sees position ``s`` of
+    chunk ``c`` only where ``idx_ref[c, i, s] > tau_ref[i]``, or equal to
+    it and among the first ``allow_ref[i]`` such positions of the walk
+    (``sparse_index.kept``), every head alike."""
     dv = hd if vd is None else vd
     n_chunks = pl.cdiv(kvlen, ps * cp)
     # The score rows of this pass: all the state's where it is the cell's
@@ -326,6 +334,13 @@ def _attend(
             dma.start()
 
     pos_rows = pos_rows()
+    if keep is not None:
+        # [s', s] = 1 where s' <= s: a product with it counts a chunk's ties
+        # up to each position (0 / 1 and small counts: exact in any pass)
+        upto = (
+            jax.lax.broadcasted_iota(jnp.int32, (cp * ps, cp * ps), 0)
+            <= jax.lax.broadcasted_iota(jnp.int32, (cp * ps, cp * ps), 1)
+        ).astype(jnp.float32)
     # [W, H, D] -> [H', W, D] with H' rows ordered (kv head, group):
     # per-kv-head slices are then contiguous row blocks.
     q = jnp.swapaxes(q_ref[...].astype(jnp.float32), 0, 1)  # [H, W, D]
@@ -335,7 +350,7 @@ def _attend(
         q = q.reshape(hkv * qpk * w, hd).astype(k_buf.dtype)
     S = cp * ps
 
-    def body(c, _):
+    def body(c, ties_seen):
         slot = jax.lax.rem(c, 2)
         next_slot = jax.lax.rem(c + 1, 2)
 
@@ -350,6 +365,20 @@ def _attend(
         tok_idx = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
         v = v_buf[slot].reshape(S, hkv * hd) if vd is None else None
+        if keep is not None:
+            # [w, S] -> a head's score rows (group, query): the same for
+            # every head. Through float32: whole (8, 128) tiles stack.
+            idx, tau = keep[0][c], keep[1][...]
+            tie = jnp.where(idx == tau, 1.0, 0.0)
+            among = ties_seen + jax.lax.dot_general(
+                tie, upto, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            mark = jnp.where(
+                (idx > tau) | ((tie > 0.5) & (among <= keep[2][...])), 1.0, 0.0
+            )
+            ties_seen = ties_seen + jnp.sum(tie, axis=1, keepdims=True)
+            kept = jnp.broadcast_to(mark[None], (qpk, w, S)).reshape(qpk * w, S) > 0.5
         for h in range(hkv):
             rows = slice(h * qpk * w, (h + 1) * qpk * w)
             cols = slice(h * hd, (h + 1) * hd)
@@ -370,6 +399,8 @@ def _attend(
                 * scale
             )  # [qpk*W, S]
             live = tok_idx <= pos_rows
+            if keep is not None:
+                live = live & kept
             s = jnp.where(live, s, -1e30)
             m_prev = m_ref[rows, :1]  # [qpk*W, 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -396,9 +427,12 @@ def _attend(
             )  # [qpk*W, Dv]
             acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
             m_ref[rows, :] = jnp.broadcast_to(m_new, m_ref[rows, :].shape)
-        return 0
+        return 0 if keep is None else ties_seen
 
-    jax.lax.fori_loop(0, n_chunks, body, 0)
+    jax.lax.fori_loop(
+        0, n_chunks, body,
+        0 if keep is None else jnp.zeros((w, 1), jnp.float32),
+    )
 
     l = l_ref[head, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -429,9 +463,15 @@ def _tile_kernel(
     *refs,  # _pools_out_scratch's
     qt: int,
     qpk: int,
+    kept: bool = False,
     **shape,
 ):
-    """A grid cell is one tile of ``qt`` slots, all of one row."""
+    """A grid cell is one tile of ``qt`` slots, all of one row. With
+    ``kept`` the tile's index scores and thresholds come first among
+    ``refs`` (``_attend``'s ``keep``)."""
+    keep = None
+    if kept:
+        *keep, refs = refs[0], refs[1], refs[2], refs[3:]
     k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     t = pl.program_id(0)
     # Per-query absolute positions (the causal bound), read from SMEM.
@@ -455,7 +495,7 @@ def _tile_kernel(
 
     _attend(
         q_ref, o_ref, pos_rows, tile_row_ref[t], tile_kvlen_ref[t],
-        table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, **shape,
+        table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, keep=keep, **shape,
     )
 
 
@@ -548,6 +588,7 @@ def ragged_paged_attention(
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
     q_block: int | None = None,  # slots a grid cell serves; None = q_tile
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
+    keep: tuple[jnp.ndarray, ...] | None = None,  # a learned selection
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
@@ -581,6 +622,12 @@ def ragged_paged_attention(
     ``value_dim`` (a latent pool, module docstring): no ``v_cache``; a
     head's values are the first ``value_dim`` lanes of its key row and the
     result is [N, H, value_dim].
+
+    ``keep`` (learned sparse attention; a cell is then one tile):
+    ``(scores, tau, allow)`` of ``ops/sparse_index.py``, the stream's index
+    scores tiled as ``sparse_index.tiled_shape`` and ``select``'s threshold
+    and number of ties a query ([N] each); a query attends only the
+    positions ``sparse_index.kept`` names.
     """
     if bounds is not None:
         positions = bounds
@@ -632,10 +679,27 @@ def ragged_paged_attention(
         n_tiles = N // qt
         tile_row = row_of.reshape(n_tiles, qt)[:, 0]
         tile_kvlen = positions.reshape(n_tiles, qt).max(axis=1) + 1
+        kept_specs, kept_args = [], ()
+        if keep is not None:
+            scores, tau, allow = keep
+            shape["kept"] = True
+            column = pl.BlockSpec(
+                (None, qt, 1), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
+            )
+            kept_specs = [
+                pl.BlockSpec(
+                    (None, *scores.shape[1:]), lambda t, *_: (t, 0, 0, 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                column, column,
+            ]
+            kept_args = (
+                scores, tau.reshape(n_tiles, qt, 1), allow.reshape(n_tiles, qt, 1)
+            )
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_tiles,),
-            in_specs=[query_spec(), *pool_specs],
+            in_specs=[query_spec(), *kept_specs, *pool_specs],
             out_specs=query_spec(Dv),
             scratch_shapes=scratch_shapes,
         )
@@ -645,7 +709,9 @@ def ragged_paged_attention(
             out_shape=out_shape,
             interpret=interpret,
             **params,
-        )(tile_row, tile_kvlen, positions, page_table, q, *pools)
+        )(tile_row, tile_kvlen, positions, page_table, q, *kept_args, *pools)
+    if keep is not None:
+        raise ValueError("a learned selection rides a call whose block is one tile")
 
     # The last block of a stream that is no whole number of blocks reads
     # past the queries' end: its plan and positions are padded here to
@@ -1189,6 +1255,7 @@ def ragged_decode_attention(
     interpret: bool = False,
     sm_scale: float | None = None,
     value_dim: int | None = None,
+    keep: tuple[jnp.ndarray, ...] | None = None,
 ) -> jnp.ndarray:
     """Pure-decode shape of the ragged kernel: one query per row at
     ``q_tile=1`` (row b attends its own ``lengths[b]`` tokens; rows
@@ -1208,4 +1275,5 @@ def ragged_decode_attention(
         interpret=interpret,
         sm_scale=sm_scale,
         value_dim=value_dim,
+        keep=keep,
     )

@@ -322,9 +322,37 @@ class Telemetry:
         self.mla_attended_pairs = Counter(
             "dynamo_mla_attended_pairs_total",
             "Query-key pairs latent attention attended, by program: a "
-            "query at position p sees p + 1 keys. A layer's and a head's: "
-            "times layers and heads for the model's",
+            "query at position p sees p + 1 keys, or the index_topk of "
+            "them its selection kept where the model has an indexer (the "
+            "published work, whatever form executes it). A layer's and a "
+            "head's: times layers and heads for the model's",
             ["program"],
+            registry=self.registry,
+        )
+        self.dsa_index_pairs = Counter(
+            "dynamo_dsa_index_pairs_total",
+            "Query-context pairs a learned-sparse-attention model's indexer "
+            "is asked to score, by program: every causal pair, p + 1 index "
+            "keys a query at position p (a stream part none of whose queries "
+            "sees more than index_topk skips the scoring and is counted all "
+            "the same). A layer's: times layers, and index heads for products",
+            ["program"],
+            registry=self.registry,
+        )
+        self.dsa_selected_pairs = Counter(
+            "dynamo_dsa_selected_pairs_total",
+            "Query-context pairs attended after the selection, by program: "
+            "min(p + 1, index_topk) a query at position p. A layer's",
+            ["program"],
+            registry=self.registry,
+        )
+        self.dsa_query_tokens = Counter(
+            "dynamo_dsa_query_tokens_total",
+            "Query tokens of a model with an indexer by program and by "
+            "whether their context exceeded index_topk (selecting: the "
+            "selection dropped something) or not (all: every position "
+            "kept). A layer's",
+            ["program", "selecting"],  # window | mixed; selecting | all
             registry=self.registry,
         )
         self.mla_context_tokens = Counter(
@@ -844,12 +872,14 @@ class Telemetry:
             "dynamo_kv_pool_info",
             "Constant-1 geometry of each of the device KV pools (K and V; "
             "pools=1: one latent pool whose row is [c_kv | k_r] and no value "
-            "pool): stacked layers, pages, tokens a page, a row's lanes "
-            "(fused kv-head width, or the latent row with its pad_lanes of "
-            "zero padding), element type, and token_bytes, the bytes a "
-            "token holds over all pools and layers",
+            "pool; a latent model with an indexer has pools=2, the second "
+            "holding index_width lanes of index key a token): stacked "
+            "layers, pages, tokens a page, a row's lanes (fused kv-head "
+            "width, or the latent row with its pad_lanes of zero padding), "
+            "element type, and token_bytes, the bytes a token holds over "
+            "all pools and layers",
             ["layers", "pages", "page_size", "width", "dtype", "pools",
-             "pad_lanes", "token_bytes"],
+             "pad_lanes", "token_bytes", "index_width"],
             registry=self.registry,
         )
         self.jax_compile_events = Counter(
@@ -1019,13 +1049,14 @@ class Telemetry:
     def set_kv_pool_info(
         self, layers: int, pages: int, page_size: int, width: int, dtype: str,
         pools: int = 2, pad_lanes: int = 0, token_bytes: int = 0,
+        index_width: int = 0,
     ) -> None:
         """Publish the constant-1 ``dynamo_kv_pool_info`` sample (one
         label set an instance, like ``set_build_info``)."""
         self.kv_pool_info.clear()
         self.kv_pool_info.labels(
             str(layers), str(pages), str(page_size), str(width), dtype,
-            str(pools), str(pad_lanes), str(token_bytes),
+            str(pools), str(pad_lanes), str(token_bytes), str(index_width),
         ).set(1)
 
     def watch_jax_compiles(self) -> None:

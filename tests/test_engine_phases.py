@@ -468,6 +468,7 @@ def test_kv_pool_info_names_the_pools_geometry():
         "pools": "2",
         "pad_lanes": "0",
         "token_bytes": str(2 * TINY.num_layers * TINY.num_kv_heads * TINY.head_dim_ * 4),
+        "index_width": "0",
     }
 
 

@@ -413,6 +413,44 @@ def test_the_expanded_latent_call_compiles_for_a_v5e_at_the_published_widths(top
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+@pytest.mark.parametrize("q_tile,n", [(8, 2048), (1, 32)])
+def test_the_selection_and_the_masked_call_compile_for_a_v5e_at_glm5s_widths(topo, q_tile, n):
+    """Learned sparse attention's two kernels at GLM-5's widths (32 index
+    heads of 128 over a 128-lane index pool; 64 heads absorbed over a
+    640-lane latent pool with the selection masked inside the pass), a slab
+    of a mixed dispatch's tiled part and a decode window's rows, over page
+    tables of 16,384 tokens: Mosaic takes the scores' blocks, the tie
+    count's product and the mask's broadcast over the heads. Nothing runs."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_exp_tpu.ops import sparse_index
+    from dynamo_exp_tpu.ops.ragged_attention import ragged_paged_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    def call(q, q_i, w_i, pool, keys, table, row_of, bounds):
+        scores = sparse_index.index_scores(q_i, w_i, keys, table, row_of, bounds, q_tile=q_tile)
+        tau, allow = sparse_index.select_tiled(scores, bounds.reshape(-1, q_tile), 2048)
+        return ragged_paged_attention(
+            q, pool, None, table, row_of, bounds, num_kv_heads=1, q_tile=q_tile,
+            sm_scale=256 ** -0.5, value_dim=512,
+            keep=(scores, tau.reshape(-1), allow.reshape(-1)),
+        )
+
+    compiled = jax.jit(call).lower(
+        sds((n, 64, 640), jnp.bfloat16), sds((n, 32, 128), jnp.bfloat16),
+        sds((n, 32), jnp.float32), sds((5 * 24576, 16, 640), jnp.bfloat16),
+        sds((5 * 24576, 16, 128), jnp.bfloat16), sds((33, 1024), jnp.int32),
+        sds((n,), jnp.int32), sds((n,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert re.search(rf"dsa_index_scores[.\d]* = f32\[{n // q_tile},128,{q_tile},128\]", text)
+    assert re.search(rf"dsa_kth_largest[.\d]* = \(f32\[{n // q_tile},{q_tile},128\]", text)
+    assert re.search(rf"ragged_paged_attention[.\d]* = bf16\[{n},64,512\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # ------------------------------------------------------ (e) the counter
 def _counter(name: str) -> dict:
     from dynamo_exp_tpu.telemetry import get_telemetry

@@ -771,7 +771,7 @@ def test_the_expanded_forms_scope_is_what_its_metric_reads():
     assert 'name="ragged_paged_attention_expanded"' in inspect.getsource(ragged_attention)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == "mla_expand_time_share"]
-    assert entry["workloads"] == ["openpangu-ultra-moe-718b.longdoc-saturate"]
+    assert "openpangu-ultra-moe-718b.longdoc-saturate" in entry["workloads"]
     assert entry["moves"] == "output_tok_s" and entry["layer"] == "Kernels"
 
 
