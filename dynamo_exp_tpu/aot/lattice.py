@@ -316,21 +316,23 @@ def _attention_form(cfg, attn_impl: str, interpret: bool) -> str:
     programs carry a second attention call where the kernel engine expands
     rows of many queries (``models/llama.py::latent_form_queries``), so
     executables built before that rule, or at another threshold, are not
-    these; a model with an indexer names its selector."""
+    these; a model with an indexer names its selector behind the form(s),
+    which both apply."""
     if not cfg.model.is_latent:
         return "heads"
-    if cfg.model.has_indexer:
-        # the selector: every read masks what the top-k of the index
-        # scores left out, inside the absorbed call
-        return f"latent-absorbed-selected-top-{cfg.model.index_topk}"
+    # the selector: every read masks what the top-k of the index scores
+    # left out, inside whichever call reads the row
+    selected = (
+        f"-selected-top-{cfg.model.index_topk}" if cfg.model.has_indexer else ""
+    )
     from ..models.llama import latent_form_queries
 
     least = latent_form_queries(
         cfg.model, cfg.ragged_q_tile, cfg.page_size, cfg.kv_dtype_jnp, interpret
     ) if attn_impl == "pallas" else None
     if least is None:
-        return "latent-absorbed"
-    return f"latent-absorbed+expanded-from-{least}-queries"
+        return f"latent-absorbed{selected}"
+    return f"latent-absorbed+expanded-from-{least}-queries{selected}"
 
 
 def _engine_fingerprint(cfg, attn_impl: str, interpret: bool) -> dict:

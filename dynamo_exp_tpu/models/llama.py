@@ -648,20 +648,18 @@ def latent_form_queries(
     """The rule by which a row of a kernel call's tiled stream takes the
     expanded form of latent attention: at least this many live queries of
     the row in the call (``ops/ragged_attention.py::expanded_min_queries``,
-    from the model's widths and the tile). None: no row does (another kind
-    of model, one with an indexer, whose selection only the absorbed call
-    applies, or widths the expanded call does not tile on a chip). The
+    from the model's widths and the tile: 160 at openPangu's, 360 at
+    GLM-5's, whose selection both forms apply). None: no row does (another
+    kind of model, or widths the expanded call does not tile on a chip). The
     program (``forward_ragged``) and the engine's counter
     (``_count_latent_reads``) both ask here."""
     from ..ops.ragged_attention import expanded_min_queries, expanded_supported
 
-    if not cfg.is_latent or cfg.has_indexer:
-        # A model that reads a selection of its context masks it inside the
-        # absorbed call's pass over the pages, whatever the row's queries.
+    if not cfg.is_latent:
         return None
     if not interpret and not expanded_supported(
         page_size, cfg.latent_lanes, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-        cfg.v_head_dim, cfg.num_heads, kv_dtype,
+        cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.num_heads, kv_dtype,
     ):
         return None
     return expanded_min_queries(
@@ -698,7 +696,8 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
     or V ever built, at ``2 (lanes + value lanes)`` operations a query-key
     pair and head.
 
-    **Expanded** (a row of many queries: a prompt chunk): ``k_h = [c_kv
+    **Expanded** (a row of many queries: a prompt chunk, of a model with an
+    indexer too): ``k_h = [c_kv
     W_uk_h | k_r]`` with the one rotated ``k_r`` shared by the heads, ``v_h =
     c_kv W_uv_h``, built from the row's pages a chunk of context at a time
     inside the kernel (``ops/ragged_attention.py::
@@ -716,7 +715,9 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
     pool beside the latent row; ``w = u W_Iw * (heads * width)^-1/2`` in
     float32. ``read`` gets ``index = (q_I, w, the index pool)`` and lets a
     query attend only to the ``index_topk`` positions of largest index
-    score (``ops/sparse_index.py``).
+    score (``ops/sparse_index.py``), in whichever form its row takes: the
+    selection of a stream's tiled part is computed once and both calls
+    mask by it.
 
     ``write(row, index_row) -> (pool, index pool)``; ``index_row`` None
     where the model has no indexer.
@@ -1795,16 +1796,24 @@ def _read_wrap(wrap):
     return (*wrap[:2], jax.named_scope("attend"))
 
 
-def _selection_tiled(cfg, index, table, row_of, bounds, q_tile, interpret):
+def _selection_tiled(cfg, index, table, row_of, bounds, q_tile, interpret, pad=0):
     """A stream part's learned selection as the attention kernel takes it
     (its ``keep``): the index scores tiled, and a threshold and a number of
     ties a query (``ops/sparse_index.py::select_tiled``), ``index`` being (``q_I`` [n, Hi, Di], ``w``
     [n, Hi], the index pool as one page axis). A part none of whose queries
     sees more than ``index_topk`` positions computes neither: every
-    threshold is ``-inf`` and the scores are not read."""
+    threshold is ``-inf`` and the scores are not read. ``pad``: slots of no
+    query behind the part (whole tiles), which the expanded call's windows
+    may run into (``ragged_paged_attention_expanded``, ``keep``)."""
     from ..ops import sparse_index as si
 
     q_i, w_i, pool = index
+    if pad:
+        q_i, w_i, row_of = (
+            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+            for a in (q_i, w_i, row_of)
+        )
+        bounds = jnp.pad(bounds, (0, pad), constant_values=-1)
     n, k = bounds.shape[0], cfg.index_topk
 
     def selecting():
@@ -1957,15 +1966,17 @@ def ragged_query_block(cfg: ModelConfig, q_tile: int) -> int:
     """Stream slots a grid cell of a mixed stream's tiled attention call
     serves (``ops/ragged_attention.py::query_block``, from the model's query
     heads a KV head), or one tile for a model with an indexer, whose
-    selection rides cells of one tile. The program and the engine's counter
-    (``dynamo_attn_query_slots_total``) both ask here."""
+    selection rides cells of one tile in the absorbed call (its rows of many
+    queries take the expanded call, whose passes are ``expanded_plan``'s).
+    The program and the engine's counter (``dynamo_attn_query_slots_total``)
+    both ask here."""
     from ..ops.ragged_attention import query_block
 
     return q_tile if cfg.has_indexer else query_block(q_tile, cfg.q_per_kv)
 
 
 def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
-               sm_scale, interpret, scope, absorbed):
+               sm_scale, interpret, scope, absorbed, keep=None):
     """Latent attention over a stream's tiled part in both forms: the rows
     ``plan`` gave passes (``expanded_plan``: those with at least
     ``latent_form_queries`` live queries here) through the expanded call,
@@ -1973,7 +1984,9 @@ def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
     a time, which sees ``-1`` (no query, no page read) where the expanded
     call serves; a slab with no query left for it skips its call and the
     two products beside it. The split rides the stream as data: no shape of
-    the program knows it."""
+    the program knows it. ``keep``: the part's learned selection
+    (``_selection_tiled``, computed once by the caller), which the expanded
+    call applies to its rows and ``absorbed`` to a slab's own."""
     from ..ops.ragged_attention import ragged_paged_attention_expanded
 
     takes, items = plan
@@ -1981,7 +1994,7 @@ def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
         o_x = ragged_paged_attention_expanded(
             q, pool, w_ukv, table, bounds, items, rank=cfg.kv_lora_rank,
             nope=cfg.qk_nope_head_dim, q_tile=q_tile, sm_scale=sm_scale,
-            interpret=interpret,
+            interpret=interpret, **({} if keep is None else {"keep": keep}),
         )
     seen = jnp.where(takes, -1, bounds)
     parts = []
@@ -2138,25 +2151,31 @@ def forward_ragged(
         # ``forward``'s, as ``index`` and ``taken``
         pre, post, scope = _read_wrap(wrap)
         if use_pallas:
-            def kernel(part, tile, block=None, seen=None):
+            def select(part, tile, pad=0):
+                # ``part``'s learned selection (``_selection_tiled``), and
+                # what it kept for the slots of ``kept_of`` that lie in it
+                selection = _selection_tiled(
+                    cfg, (index[0][0, part], index[1][0, part], index[2]),
+                    table_l, row_of[part], bounds[part], tile, interpret, pad,
+                )
+                if taken is not None:
+                    start, stop, _ = part.indices(positions.shape[0])
+                    mine = _kept_tiled(
+                        selection,
+                        jnp.clip(kept_of - start, 0, stop - start - 1), tile,
+                    )
+                    here = (kept_of >= start) & (kept_of < stop)
+                    taken.append(jnp.where(
+                        here[:, None], mine, taken.pop() if taken else False
+                    ))
+                return selection
+
+            def kernel(part, tile, block=None, seen=None, selection=None):
                 keep = {}
                 if index is not None:
-                    keep["keep"] = _selection_tiled(
-                        cfg, (index[0][0, part], index[1][0, part], index[2]),
-                        table_l, row_of[part],
-                        bounds[part] if seen is None else seen, tile, interpret,
+                    keep["keep"] = (
+                        select(part, tile) if selection is None else selection
                     )
-                    if taken is not None:
-                        # the slots of ``kept_of`` that lie in this part
-                        start, stop, _ = part.indices(positions.shape[0])
-                        mine = _kept_tiled(
-                            keep["keep"],
-                            jnp.clip(kept_of - start, 0, stop - start - 1), tile,
-                        )
-                        here = (kept_of >= start) & (kept_of < stop)
-                        taken.append(jnp.where(
-                            here[:, None], mine, taken.pop() if taken else False
-                        ))
                 with scope:
                     o = _pallas_ragged(
                         pre(q[0, part]), kf, vf, table_l, row_of[part],
@@ -2177,11 +2196,26 @@ def forward_ragged(
                 ]
                 if expand_plan is None:
                     return [kernel(part, q_tile, block) for part in slabs]
+                # Both forms read ONE selection of the part, computed here
+                # (outside the slabs' ``cond``s, so ``kept_of`` has every
+                # row's); a slab's absorbed call takes its tiles of it.
+                keep = None
+                if index is not None:
+                    from ..ops.ragged_attention import EXPANDED_SELECTION_PAD
+
+                    keep = select(slice(0, stop), q_tile, EXPANDED_SELECTION_PAD)
+
+                def absorbed(part, seen):
+                    mine = None if keep is None else (
+                        keep[0][part.start // q_tile : part.stop // q_tile],
+                        keep[1][part], keep[2][part],
+                    )
+                    return kernel(part, q_tile, block, seen, mine)
+
                 return [_two_forms(
                     q[0, :stop], kf, wrap[2], table_l, bounds[:stop],
                     expand_plan, slabs, cfg, q_tile, latent["sm_scale"],
-                    interpret, scope,
-                    lambda part, seen: kernel(part, q_tile, block, seen),
+                    interpret, scope, absorbed, keep,
                 )]
 
             n = q.shape[1]

@@ -69,9 +69,30 @@ q_rope . k_r`` against them and sums ``v``: ``2 * (nope + rope + v)``
 operations a pair and head plus ``2 * rank * (nope + v)`` a context token
 and head ONCE A PASS. Nothing expanded is ever written to HBM, and what is
 read there is the latent row: the call is compute-bound by construction.
+The scores are two products where ``nope`` is whole lane groups
+(openPangu's 128, the rotary one against the row's own ``[k_r | 0]``
+lanes) and ONE over the published head ``[k_nope | k_r]`` where only
+``nope + rope`` is (GLM-5's 192 + 64 = 256: :func:`_expanded_joint`).
 :func:`expanded_min_queries` is where the two forms cost the same (160
-queries at the published widths): the rule both the program and the
-engine's counter apply (:func:`expanded_rows`).
+queries at openPangu's published widths, 360 at GLM-5's): the rule both
+the program and the engine's counter apply (:func:`expanded_rows`).
+
+**Under a learned selection** (``keep``, ``ops/sparse_index.py``: a model
+with an indexer) both forms apply the same mask, ``sparse_index.kept``:
+position ``s`` where a query's index score is over its threshold ``tau``,
+or equal to it and among the first ``allow`` such positions of the walk,
+every head alike. The absorbed call multiplies ``2 * (lanes +
+value_dim)`` a pair over ALL causal pairs for the kept few (2,304 at
+GLM-5's widths where the published form needs 1,024 over the kept
+third); the expanded call multiplies every causal pair of the (256
+queries, 512 tokens) blocks it visits too (a mask drops no product), at
+the published form's 1,024 plus the expansion's share. There the index
+scores of a block come by DMA from the scores kernel's own layout while
+the chunk is expanded, the count of ties before each position is a ``[256,
+512] x [512, 512]`` product of 0 / 1 in the pool's dtype with float32
+sums (exact), and the ties a query has passed in earlier chunks are a
+column of scratch beside its running maximum and sum, because the kernel
+walks chunks outside and sub-blocks inside.
 
 HBM traffic per dispatch per layer is ``sum_rows(blocks_row * kv_row)``
 tokens instead of the XLA gather's ``N * S``: the kernel reads a row's
@@ -757,6 +778,10 @@ def ragged_paged_attention(
 # heads), and the slots of it a score product takes at a time.
 _EXPANDED_QUERIES = 1024
 _EXPANDED_SUB = 256
+# Slots of no query a caller lays behind a stream's learned selection
+# (``ragged_paged_attention_expanded``, ``keep``): a sub-block's window of
+# the scores then lies inside them wherever its item ends.
+EXPANDED_SELECTION_PAD = _EXPANDED_SUB
 # Context tokens the expanded call expands and scores at a time: the
 # softmax's per-query bookkeeping (a column a query: as many vector
 # registers as a whole score tile) is paid once a chunk.
@@ -793,17 +818,32 @@ def expanded_min_queries(
 
 
 def expanded_supported(
-    page_size: int, lanes: int, rank: int, nope: int, v: int, heads: int, kv_dtype
+    page_size: int, lanes: int, rank: int, nope: int, rope: int, v: int,
+    heads: int, kv_dtype,
 ) -> bool:
     """Whether the expanded call compiles on real TPU hardware at these
     widths: the pool's row splits into ``c_kv`` and ``[k_r | padding]`` on a
-    lane group's boundary, a head's K and V are whole lane groups of
-    ``W_ukv``'s columns, and the heads come in whole cells."""
+    lane group's boundary, a head's K and V are whole lane groups (K as
+    ``k_nope`` alone, or as ``[k_nope | k_r]`` where ``nope`` is no whole
+    number of groups and ``nope + rope`` is: :func:`_expanded_joint`), and
+    the heads come in whole cells."""
     return (
         latent_supported(page_size, lanes, rank, kv_dtype)
-        and rank % 128 == 0 and nope % 128 == 0 and v % 128 == 0
+        and rank % 128 == 0 and v % 128 == 0
+        and (nope % 128 == 0 or (nope + rope) % 128 == 0)
+        and rope <= lanes - rank
         and heads % _EXPANDED_HEADS == 0
     )
+
+
+def _expanded_joint(nope: int, rope: int) -> bool:
+    """Which of its two score layouts the expanded call takes, from the
+    widths: *split* where ``nope`` is whole lane groups (``q_nope . k_nope +
+    q_rope . k_r``, the second product against the row's own ``[k_r | 0]``
+    lanes: 128 + 64 at openPangu's widths), *joint* where only ``nope +
+    rope`` is (ONE product over the published head ``[k_nope | k_r]``: 192
+    + 64 = 256 at GLM-5's, two whole groups with no padding)."""
+    return nope % 128 != 0 and (nope + rope) % 128 == 0
 
 
 def expanded_rows(row_of, positions, min_queries: int, n_rows: int, xp=jnp):
@@ -890,28 +930,8 @@ def _expanded_kernel(
     sub_kvlen_ref,
     sub_first_ref,
     table_ref,
-    # inputs
-    pos_hbm,  # [N + q_max, 128] int32 — last kv position each slot sees (lane-replicated)
-    q_hbm,  # [N, H, nope + (lanes - rank)] — the published form's queries,
-    # zeros behind the rotary part as behind the pool's ``k_r``
-    k_hbm,  # [P, ps, lanes] — the latent pool
-    w_ref,  # [G, rank, nope + v] VMEM — this cell's heads of W_ukv
-    # output
-    o_hbm,  # [N, H, v]
-    # scratch
-    pos_buf,  # [q_max, 128] int32
-    q_raw,  # [q_sub, G, nope + (lanes - rank)]
-    qn_s,  # [G, q_max, nope] — queries by head, the pool's dtype
-    qr_s,  # [G, q_max, lanes - rank] — rotary part, zeros behind it
-    kv_s,  # [G, S, nope + v] — a chunk's K and V of these heads, by head
-    k_buf,  # [2, cp, ps, lanes]
-    acc_ref,  # [G, q_max, v] f32
-    m_ref,  # [G, q_max, 128] f32 (lane-replicated)
-    l_ref,  # [G, q_max, 128] f32
-    o_buf,  # [q_sub, G, v]
-    sems,  # DMA semaphores [2, cp]: a chunk's pages
-    io_sems,  # DMA semaphores [2]: positions; query and result tiles
-    *,
+    # inputs, output and scratch: ``_expanded_refs`` names them
+    *refs,
     qt: int,
     qs: int,
     qmax: int,
@@ -924,16 +944,41 @@ def _expanded_kernel(
     pmax: int,
     scale: float,
     at_once: int,
+    rope: int = 0,
+    kept: int = 0,
 ):
     """A grid cell is (a group of ``G`` heads, an item): one pass over the
     item's row's context, each chunk of latents expanded once to the
     group's K and V (rounded to the pool's dtype, as the published form
     rounds them) for all the item's queries, ``qs`` of them a score
-    product. ``_attend``'s softmax, chunk by chunk in the same order."""
+    product. ``_attend``'s softmax, chunk by chunk in the same order.
+
+    ``rope`` > 0 is the *joint* score layout (:func:`_expanded_joint`): a
+    head's K is ``[k_nope | k_r]``, ``nope + rope`` wide, and the queries
+    come as the model makes them; ``w_ref`` a head is then ``[W_uk | 0 |
+    W_uv]`` with ``rope`` columns of zeros, into which the chunk's ``k_r``
+    is added before the one rounding (placed there by a 0/1 product once a
+    chunk: exact).
+
+    ``kept`` > 0 (a learned selection; the number of the scores' chunks
+    that make one of this kernel's): every (sub-block, chunk) masks, a
+    query seeing position ``s`` only where ``sparse_index.kept`` says. The
+    chunk's block of index scores of each sub-block comes by DMA while the
+    chunk is expanded; the ties at the threshold a query has passed in
+    earlier chunks are a column of scratch beside ``m`` and ``l``."""
+    r = _expanded_refs(refs, rope > 0, kept > 0)
+    pos_hbm, q_hbm, k_hbm, w_ref, o_hbm = r["pos"], r["q"], r["k"], r["w"], r["o"]
+    pos_buf, q_raw, qn_s, qr_s, kv_s = (
+        r["pos_buf"], r["q_raw"], r["qn_s"], r.get("qr_s"), r["kv_s"]
+    )
+    k_buf, acc_ref, m_ref, l_ref, o_buf = (
+        r["k_buf"], r["acc"], r["m"], r["l"], r["o_buf"]
+    )
+    sems, io_sems = r["sems"], r["io_sems"]
     g, i = pl.program_id(0), pl.program_id(1)
     slots = item_slots_ref[i]
     subs = qmax // qs
-    hw = nope + v
+    kw = nope + rope  # a head's K as the score product takes it
 
     @pl.when(slots > 0)
     def _():
@@ -963,6 +1008,17 @@ def _expanded_kernel(
             pos_hbm.at[pl.ds(start, qmax)], pos_buf, io_sems.at[0]
         )
         pos_dma.start()
+        if kept:
+            sel_dma = pltpu.make_async_copy(
+                r["sel"].at[pl.ds(start, qmax)], r["sel_buf"], r["keep_sems"].at[subs]
+            )
+            sel_dma.start()
+            # [s', s] = 1 where s' <= s: a product with it counts a chunk's
+            # ties up to each position (0 / 1 operands, float32 sums: exact)
+            r["upto"][...] = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
+                <= jax.lax.broadcasted_iota(jnp.int32, (S, S), 1), 1.0, 0.0,
+            ).astype(r["upto"].dtype)
 
         def sub_at(j):
             return pl.ds(pl.multiple_of(j * qs, qs), qs)
@@ -995,11 +1051,16 @@ def _expanded_kernel(
                     q_raw[unit_at(u, 0), :, :].astype(jnp.float32), 0, 1
                 )
                 at = unit_at(u, j * qs)
-                qn_s[:, at, :] = x[..., :nope].astype(qn_s.dtype)
-                qr_s[:, at, :] = x[..., nope:].astype(qr_s.dtype)
+                if rope:
+                    qn_s[:, at, :] = x.astype(qn_s.dtype)
+                else:
+                    qn_s[:, at, :] = x[..., :nope].astype(qn_s.dtype)
+                    qr_s[:, at, :] = x[..., nope:].astype(qr_s.dtype)
                 acc_ref[:, at, :] = jnp.zeros((G, unit, v), jnp.float32)
                 m_ref[:, at, :] = jnp.full((G, unit, 128), -1e30, jnp.float32)
                 l_ref[:, at, :] = jnp.zeros((G, unit, 128), jnp.float32)
+                if kept:
+                    r["ties"][at, :] = jnp.zeros((unit, 128), jnp.float32)
                 return 0
 
             jax.lax.fori_loop(0, qs // unit, by_head, 0)
@@ -1007,6 +1068,19 @@ def _expanded_kernel(
 
         jax.lax.fori_loop(0, n_sub, load, 0)
         pos_dma.wait()
+        if kept:
+            sel_dma.wait()
+
+        def idx_dma(c, j):
+            """Chunk ``c``'s index scores of sub-block ``j``'s tiles (the
+            scores are padded by a sub-block's tiles: the window is theirs
+            wherever the item ends)."""
+            return pltpu.make_async_copy(
+                r["scores"].at[
+                    pl.ds((start + j * qs) // qt, qs // qt), pl.ds(c * kept, kept)
+                ],
+                r["idx_buf"].at[j], r["keep_sems"].at[j],
+            )
 
         def chunk(c, _):
             slot = jax.lax.rem(c, 2)
@@ -1016,22 +1090,48 @@ def _expanded_kernel(
                 for dma in chunk_dmas(c + 1, jax.lax.rem(c + 1, 2)):
                     dma.start()
 
+            if kept:
+                # the sub-blocks that see the chunk (``sub`` waits under the
+                # same condition); the copies land while the chunk expands
+                for j in range(subs):
+                    @pl.when(sub_kvlen_ref[i * subs + j] > c * S)
+                    def _(j=j):
+                        idx_dma(c, j).start()
+
             for dma in chunk_dmas(c, slot):
                 dma.wait()
             latent = k_buf[slot].reshape(S, k_buf.shape[-1])
             # K and V of the group's heads, a head at a time: one product
             # with its columns of W_ukv, rounded once to the pool's dtype.
             c_kv = latent[:, :rank]
+            if rope:
+                # [S, kw] float32: k_r at lanes [nope, kw), zeros before
+                tail = k_buf.shape[-1] - rank
+                place = jnp.where(
+                    (jax.lax.broadcasted_iota(jnp.int32, (tail, kw), 0) + nope
+                     == jax.lax.broadcasted_iota(jnp.int32, (tail, kw), 1)),
+                    1.0, 0.0,
+                ).astype(latent.dtype)
+                k_placed = jax.lax.dot_general(
+                    latent[:, rank:], place, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
 
             def expand(h, _):
-                kv_s[h] = jax.lax.dot_general(
+                kv = jax.lax.dot_general(
                     c_kv, w_ref[h], (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                ).astype(kv_s.dtype)
+                )
+                if rope:
+                    kv_s[h, :, :kw] = (kv[:, :kw] + k_placed).astype(kv_s.dtype)
+                    kv_s[h, :, kw:] = kv[:, kw:].astype(kv_s.dtype)
+                else:
+                    kv_s[h] = kv.astype(kv_s.dtype)
                 return 0
 
             jax.lax.fori_loop(0, G, expand, 0)
-            k_rope = latent[:, rank:]  # [S, rope | padding], every head's
+            if not rope:
+                k_rope = latent[:, rank:]  # [S, rope | padding], every head's
             tok_idx = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
 
             def update(j, masked: bool):
@@ -1042,16 +1142,43 @@ def _expanded_kernel(
                         < slots
                     )
                     live = tok_idx <= jnp.where(inside, pos_buf[at, :1], -1)
+                if kept:
+                    idx_dma(c, j).wait()
+                    # [tiles, kept, qt, S / kept] -> [qs, S]: a query a row
+                    idx = r["idx_buf"][j]
+                    idx = jnp.concatenate(
+                        [idx[:, k].reshape(qs, S // kept) for k in range(kept)], axis=1
+                    ) if kept > 1 else idx.reshape(qs, S)
+                    tau = r["sel_buf"][at, :1]
+                    allow = r["sel_buf"][at, 64:65]
+                    tie = idx == tau
+                    ones = jnp.where(tie, 1.0, 0.0)
+                    among = r["ties"][at, :1] + jax.lax.dot_general(
+                        ones.astype(r["upto"].dtype), r["upto"][...],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    live = live & ((idx > tau) | (tie & (among <= allow)))
+                    r["ties"][at, :] = r["ties"][at, :] + jnp.sum(
+                        ones, axis=1, keepdims=True
+                    )
 
                 def head(h, _):
-                    kh, vh = kv_s[h, :, :nope], kv_s[h, :, nope:]
-                    s = (jax.lax.dot_general(
-                        qn_s[h, at, :], kh, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ) + jax.lax.dot_general(
-                        qr_s[h, at, :], k_rope, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )) * scale
+                    if rope:
+                        vh = kv_s[h, :, kw:]
+                        s = jax.lax.dot_general(
+                            qn_s[h, at, :], kv_s[h, :, :kw], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        ) * scale
+                    else:
+                        kh, vh = kv_s[h, :, :nope], kv_s[h, :, nope:]
+                        s = (jax.lax.dot_general(
+                            qn_s[h, at, :], kh, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        ) + jax.lax.dot_general(
+                            qr_s[h, at, :], k_rope, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )) * scale
                     if masked:
                         s = jnp.where(live, s, -1e30)
                     m_prev = m_ref[h, at, :1]
@@ -1087,8 +1214,14 @@ def _expanded_kernel(
                 # A sub-block wholly before the chunk sees none of it; one
                 # wholly behind it sees all of it and masks nothing (what
                 # a slot without a query computes there is dropped at the
-                # store).
+                # store), unless a selection says what it sees.
                 seen, first = sub_kvlen_ref[i * subs + j], sub_first_ref[i * subs + j]
+                if kept:
+                    @pl.when(seen > c * S)
+                    def _():
+                        update(j, masked=True)
+
+                    return 0
 
                 @pl.when((seen > c * S) & (first >= (c + 1) * S - 1))
                 def _():
@@ -1126,6 +1259,40 @@ def _expanded_kernel(
         jax.lax.fori_loop(0, n_sub, store, 0)
 
 
+def _expanded_refs(refs, joint: bool, kept: bool) -> dict:
+    """``_expanded_kernel``'s references by name, in the order the call
+    lays them: inputs, the output, scratch.
+
+    - ``pos`` [N + q_max, 128] int32 HBM: last kv position each slot sees
+      (lane-replicated); ``q`` [N, H, D] HBM: the published form's queries
+      (split layout: zeros behind the rotary part as behind the pool's
+      ``k_r``); ``k`` [P, ps, lanes] HBM: the latent pool; ``w`` [G, rank,
+      kw + v] VMEM: this cell's heads of W_ukv; with a selection ``scores``
+      [T + q_sub / qt, C, qt, S_a] float32 HBM and ``sel`` [N + q_max, 128]
+      float32 HBM (a slot's ``tau`` in lanes [0, 64), ``allow`` behind).
+    - ``o`` [N, H, v] HBM.
+    - ``pos_buf`` [q_max, 128]; ``q_raw`` [q_sub, G, D]; ``qn_s`` [G, q_max,
+      kw] queries by head, the pool's dtype (split: ``qr_s`` [G, q_max,
+      lanes - rank] the rotary part beside it); ``kv_s`` [G, S, kw + v] a
+      chunk's K and V by head; ``k_buf`` [2, cp, ps, lanes]; ``acc`` [G,
+      q_max, v], ``m``, ``l`` [G, q_max, 128] float32; ``o_buf`` [q_sub, G,
+      v]; ``sems`` [2, cp] a chunk's pages; ``io_sems`` [2] positions,
+      query and result tiles; with a selection ``idx_buf`` [subs, q_sub /
+      qt, kept, qt, S_a] float32, ``sel_buf`` [q_max, 128] float32, ``ties``
+      [q_max, 128] float32, ``upto`` [S, S] and ``keep_sems`` [subs + 1]."""
+    names = ["pos", "q", "k", "w"]
+    if kept:
+        names += ["scores", "sel"]
+    names += ["o", "pos_buf", "q_raw", "qn_s"]
+    if not joint:
+        names.append("qr_s")
+    names += ["kv_s", "k_buf", "acc", "m", "l", "o_buf", "sems", "io_sems"]
+    if kept:
+        names += ["idx_buf", "sel_buf", "ties", "upto", "keep_sems"]
+    assert len(names) == len(refs), (names, len(refs))
+    return dict(zip(names, refs))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -1149,6 +1316,7 @@ def ragged_paged_attention_expanded(
     q_max: int = _EXPANDED_QUERIES,  # slots a pass serves at most
     q_sub: int = _EXPANDED_SUB,  # slots of them a score product takes
     kv_chunk: int = _EXPANDED_CHUNK,  # context tokens expanded at a time
+    keep: tuple[jnp.ndarray, ...] | None = None,  # a learned selection
 ) -> jnp.ndarray:
     """Latent attention in the **expanded** form for the rows of a tiled
     stream that :func:`expanded_plan` gave passes (those with at least its
@@ -1163,10 +1331,17 @@ def ragged_paged_attention_expanded(
     built from the row's pages (``[c_kv | k_r | 0]`` a token) a chunk at a
     time inside the kernel, rounded to the pool's dtype, and never stored:
     scores ``(q_nope . k_nope + q_rope . k_r) * sm_scale`` in the pool's
-    dtype with float32 accumulation, softmax in float32
-    (``_expanded_kernel``). The stream's contract is the tiled call's: a
-    row's span is whole tiles of ``q_tile`` slots side by side, written
-    before it is read."""
+    dtype with float32 accumulation (one product or two:
+    :func:`_expanded_joint`), softmax in float32 (``_expanded_kernel``).
+    The stream's contract is the tiled call's: a row's span is whole tiles
+    of ``q_tile`` slots side by side, written before it is read.
+
+    ``keep`` (learned sparse attention): ``(scores, tau, allow)`` of
+    ``ops/sparse_index.py`` as :func:`ragged_paged_attention` takes them for
+    this stream, the scores' tiles and the two columns each longer by (at
+    least) ``q_sub`` slots of anything, so that a sub-block's window of them
+    lies inside wherever its item ends; a query attends only the positions
+    ``sparse_index.kept`` names."""
     N, H, D = q.shape
     _, ps, lanes = k_cache.shape
     v = w_ukv.shape[1] // H - nope
@@ -1174,65 +1349,108 @@ def ragged_paged_attention_expanded(
     qmax, qs = q_max, q_sub
     G = _EXPANDED_HEADS if H % _EXPANDED_HEADS == 0 else H
     cp = max(1, min(kv_chunk // ps, pmax))
+    rope = D - nope if _expanded_joint(nope, D - nope) else 0
+    kw = nope + rope
+    kept = 0
+    if keep is not None:
+        # A chunk of this call is a whole number of the scores' chunks, as
+        # many as divide their count: a chunk addresses them by a leading
+        # index.
+        scores, tau, allow = keep
+        T, C, _, S_a = scores.shape
+        if (T * q_tile - N) < qs or tau.shape[0] < N or allow.shape[0] < N:
+            raise ValueError(
+                f"a selection of {T * q_tile} slots for a stream of {N}: the "
+                f"expanded call asks {qs} slots more (EXPANDED_SELECTION_PAD)"
+            )
+        kept = max(k for k in range(1, max(1, cp * ps // S_a) + 1) if C % k == 0)
+        cp = kept * S_a // ps
     S = cp * ps
     # What the form costs outside its kernel, a layer: two paddings and a
     # layout of W_ukv (the plan is the forward's, not the layer's).
     with jax.named_scope("mla_expand"):
-        # The rotary part meets the row's ``[k_r | 0]`` lanes: zeros behind it.
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, nope + lanes - rank - D)))
-        D = q.shape[-1]
+        if not rope:
+            # The rotary part meets the row's ``[k_r | 0]`` lanes: zeros behind it.
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, nope + lanes - rank - D)))
+            D = q.shape[-1]
         # A column a slot, replicated over a lane group: any window of it
         # is whole tiles for the DMA.
         pos_col = jnp.broadcast_to(
             jnp.pad(positions, (0, qmax), constant_values=-1)[:, None],
             (N + qmax, 128),
         )
+        w_heads = w_ukv.reshape(rank, H, nope + v)
+        if rope:
+            # [W_uk | 0 | W_uv]: the zeros are where ``k_r`` goes
+            w_heads = jnp.concatenate([
+                w_heads[..., :nope], jnp.zeros((rank, H, rope), w_ukv.dtype),
+                w_heads[..., nope:],
+            ], axis=-1)
         # A head's [W_uk | W_uv] as one block of a cell's operand.
-        w_heads = jnp.swapaxes(w_ukv.reshape(rank, H, nope + v), 0, 1)
+        w_heads = jnp.swapaxes(w_heads, 0, 1)
+        kept_args = ()
+        if kept:
+            column = jnp.where(
+                jnp.arange(128)[None] < 64, tau[:N, None], allow[:N, None]
+            ).astype(jnp.float32)
+            kept_args = (scores, jnp.pad(column, ((0, qmax), (0, 0))))
     items = plan[0].shape[0]
     kv_item, q_item = k_cache.dtype.itemsize, q.dtype.itemsize
     vmem = (
-        2 * rank * G * (nope + v) * w_ukv.dtype.itemsize  # W_ukv, pipelined
+        2 * rank * G * (kw + v) * w_ukv.dtype.itemsize  # W_ukv, pipelined
         + G * qmax * (nope + (lanes - rank)) * kv_item  # queries by head
         + G * qmax * (v + 2 * 128) * 4  # softmax states
-        + S * G * (nope + v) * kv_item  # a chunk's K and V
-        + 2 * S * (nope + v) * 4  # a head's, as values
+        + S * G * (kw + v) * kv_item  # a chunk's K and V
+        + 2 * S * (kw + v) * 4  # a head's, as values
         + 2 * qs * max(G, 16) * (-(-D // 128) * 128 + v) * (q_item + 4)
         + 2 * cp * ps * lanes * kv_item + qmax * 512
         + 8 * qs * S * 4  # scores and probabilities of a head in flight
     )
+    if kept:
+        # the index scores of a chunk, the tie matrix, two columns, a mask
+        vmem += qmax * S * 4 + S * S * kv_item + 2 * qmax * 512 + 4 * qs * S * 4
     any_space = pl.BlockSpec(memory_space=pl.ANY)
+    scratch_shapes = [
+        pltpu.VMEM((qmax, 128), jnp.int32),
+        pltpu.VMEM((qs, G, D), q.dtype),
+        pltpu.VMEM((G, qmax, kw), k_cache.dtype),
+        *([] if rope else [pltpu.VMEM((G, qmax, lanes - rank), k_cache.dtype)]),
+        pltpu.VMEM((G, S, kw + v), k_cache.dtype),
+        pltpu.VMEM((2, cp, ps, lanes), k_cache.dtype),
+        pltpu.VMEM((G, qmax, v), jnp.float32),
+        pltpu.VMEM((G, qmax, 128), jnp.float32),
+        pltpu.VMEM((G, qmax, 128), jnp.float32),
+        pltpu.VMEM((qs, G, v), q.dtype),
+        pltpu.SemaphoreType.DMA((2, cp)),
+        pltpu.SemaphoreType.DMA((2,)),
+    ]
+    if kept:
+        scratch_shapes += [
+            pltpu.VMEM((qmax // qs, qs // q_tile, kept, q_tile, S // kept), jnp.float32),
+            pltpu.VMEM((qmax, 128), jnp.float32),
+            pltpu.VMEM((qmax, 128), jnp.float32),
+            pltpu.VMEM((S, S), k_cache.dtype),
+            pltpu.SemaphoreType.DMA((qmax // qs + 1,)),
+        ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(H // G, items),
         in_specs=[
             any_space, any_space, any_space,
             pl.BlockSpec(
-                (G, rank, nope + v), lambda g, i, *_: (g, 0, 0),
+                (G, rank, kw + v), lambda g, i, *_: (g, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
+            *([any_space, any_space] if kept else []),
         ],
         out_specs=any_space,
-        scratch_shapes=[
-            pltpu.VMEM((qmax, 128), jnp.int32),
-            pltpu.VMEM((qs, G, D), q.dtype),
-            pltpu.VMEM((G, qmax, nope), k_cache.dtype),
-            pltpu.VMEM((G, qmax, lanes - rank), k_cache.dtype),
-            pltpu.VMEM((G, S, nope + v), k_cache.dtype),
-            pltpu.VMEM((2, cp, ps, lanes), k_cache.dtype),
-            pltpu.VMEM((G, qmax, v), jnp.float32),
-            pltpu.VMEM((G, qmax, 128), jnp.float32),
-            pltpu.VMEM((G, qmax, 128), jnp.float32),
-            pltpu.VMEM((qs, G, v), q.dtype),
-            pltpu.SemaphoreType.DMA((2, cp)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=scratch_shapes,
     )
     o = pl.pallas_call(
         functools.partial(
             _expanded_kernel, qt=q_tile, qs=qs, qmax=qmax, G=G, rank=rank,
             nope=nope, v=v, ps=ps, cp=cp, pmax=pmax, scale=sm_scale,
-            at_once=math.gcd(G, _EXPANDED_HEADS_AT_ONCE),
+            at_once=math.gcd(G, _EXPANDED_HEADS_AT_ONCE), rope=rope, kept=kept,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, H, v), q.dtype),
@@ -1241,7 +1459,7 @@ def ragged_paged_attention_expanded(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(32 << 20, min(100 << 20, vmem * 5 // 4)),
         ),
-    )(*plan, page_table, pos_col, q, k_cache, w_heads)
+    )(*plan, page_table, pos_col, q, k_cache, w_heads, *kept_args)
     return o
 
 

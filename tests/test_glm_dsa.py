@@ -223,11 +223,37 @@ def test_select_keeps_everything_up_to_k_positions_and_breaks_ties_by_position()
 
 
 # ------------------------------------------- the model against the reference
-@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def _two_row_stream():
+    """A 40-token chunk (five tiles: the expanded form at the tiny preset's
+    rule of 8 queries) beside a row of 5 tokens (under it: absorbed), each
+    on its own eight pages: (tokens, positions, row_of, table)."""
+    tokens = PROMPT[:40] + PROMPT[40:45] + [0] * 3
+    positions = list(range(40)) + list(range(5)) + [-1] * 3
+    row_of = [0] * 40 + [1] * 8
+    table = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) + 1
+    return jnp.asarray(tokens), jnp.asarray(positions, jnp.int32), jnp.asarray(row_of, jnp.int32), table
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode", "chunk_in_the_expanded_form"])
 def test_forward_matches_reference(params, phase):
     """``forward`` (the gather): the prompt in one piece, longer than
-    ``index_topk``, then six steps through both caches."""
+    ``index_topk``, then six steps through both caches; and
+    ``forward_ragged`` through the kernels, where a chunk of 40 queries
+    takes the expanded form under its selection beside an absorbed row."""
     k, v = _pools()
+    if phase == "chunk_in_the_expanded_form":
+        assert llama.latent_form_queries(CFG, 8, PS, jnp.float32, interpret=True) == 8
+        tokens, positions, row_of, table = _two_row_stream()
+        logits, *_ = llama.forward_ragged(
+            params, CFG, tokens, positions, row_of, table, k, v, jnp.arange(45),
+            q_tile=8, **_impl("pallas"),
+        )
+        want = np.concatenate([
+            reference.log_probs(params, HF, PROMPT[:40], range(40)),
+            reference.log_probs(params, HF, PROMPT[40:45], range(5)),
+        ])
+        assert np.abs(_logp(logits) - want).max() < LOGP_TOL
+        return
     T = 37
     logits, k, v = llama.forward(
         params, CFG, jnp.asarray(PROMPT[:T])[None], jnp.arange(T, dtype=jnp.int32)[None],
@@ -307,15 +333,29 @@ def test_the_pools_hold_what_the_text_says(params):
     assert np.abs(np.asarray(v[0, 1:]).reshape(-1, 128)[T:]).max() == 0.0  # nothing past the prompt
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("impl", ["xla", "pallas", "expanded_row_of_the_program"])
 def test_selection_equals_the_references_on_every_row(params, impl):
     """Layer 0's selection from the engine's own pieces (its projections,
     its pool of index keys, the scores by the gather or by the kernel, the
     bisection) against the reference's ``lax.top_k``: the same set on every
     row of a 48-token sequence, ``min(16, t + 1)`` positions each; the row
-    at context 17 drops exactly one."""
+    at context 17 drops exactly one. Third case: what ``forward_ragged``
+    hands out (``kept_of``) for every query of that sequence as ONE chunk,
+    which takes the expanded form (48 queries against the rule's 8)."""
     T = 48
     lp, x, h, c_q = _layer0(params, PROMPT[:T])
+    if impl == "expanded_row_of_the_program":
+        k, v = _pools()
+        *_, kept = llama.forward_ragged(
+            params, CFG, jnp.asarray(PROMPT[:T]), jnp.arange(T, dtype=jnp.int32),
+            jnp.zeros((T,), jnp.int32), TABLE, k, v, jnp.arange(1),
+            kept_of=jnp.arange(T), q_tile=8, **_impl("pallas"),
+        )
+        got = np.asarray(kept)[0, :, :T] & (np.arange(T)[None] <= np.arange(T)[:, None])
+        ref = reference.attn_rows_and_head(params, HF, 0, np.asarray(x), range(T))
+        assert np.array_equal(got, ref["keep"])
+        assert got.sum(axis=1).tolist() == [min(TOPK, t + 1) for t in range(T)]
+        return
     inv_freq = llama.rope_frequencies(CFG.qk_rope_head_dim, CFG.rope_theta, None)
     pos = jnp.arange(T, dtype=jnp.int32)
     q_i, k_i, w_i = llama.index_projections(h[None], c_q[None], lp, CFG, inv_freq, pos[None])
@@ -346,6 +386,9 @@ def test_a_forward_hands_out_what_it_kept(params, impl):
     layers (two runs of the scan), and the logits are those of the call
     that does not ask."""
     T, rows = 41, [0, 7, TOPK - 1, TOPK, TOPK + 1, 31, 40]
+    # through the kernels the prompt's 40 queries take the expanded form
+    # (the rule: 8), the decode row the absorbed one: both hand out theirs
+    assert llama.latent_form_queries(CFG, 8, PS, jnp.float32, interpret=True) == 8
     k, v = _pools()
     tokens = PROMPT[:40] + [PROMPT[40]] + [0] * 7
     positions = jnp.asarray(list(range(40)) + [40] + [-1] * 7, jnp.int32)
@@ -648,6 +691,22 @@ def test_the_counters_arithmetic():
     pairs, kept = (_counter(n, program="window") - b for n, b in zip(names, before))
     assert pairs == sum(range(14, 22)) + sum(range(51, 59))
     assert kept == 14 + 15 + 16 * 6 + 16 * 8
+    # The form each query took, by the program's own rule
+    # (``latent_form_queries``: 8 queries of a row in the tiled part, indexer
+    # or not): a selecting row's chunk of 8 counts as expanded, the row of 5
+    # beside it and the packed tail's decode row as absorbed.
+    eng._attn_impl, eng._attn_interpret = "pallas", True
+    assert eng._latent_form_queries() == 8
+    forms = ("expanded", "absorbed")
+    before = [_counter("dynamo_mla_query_tokens_total", program="mixed", form=f) for f in forms]
+    positions = np.asarray(list(range(30, 38)) + [0, 1, 2, 3, 4, -1, -1, -1] + [50], np.int32)
+    row_of = np.asarray([0] * 8 + [1] * 8 + [2], np.int32)
+    eng._count_latent_reads("mixed", positions, row_of, tiled=16)
+    expanded, absorbed = (
+        _counter("dynamo_mla_query_tokens_total", program="mixed", form=f) - b
+        for f, b in zip(forms, before)
+    )
+    assert (expanded, absorbed) == (8, 6)
 
 
 def test_pool_info_says_what_a_token_holds(engine):
@@ -686,7 +745,8 @@ async def test_engine_through_the_kernels():
         prompt = PROMPT[3:40]
         got = await _run(eng, prompt, 10)
         assert got == _greedy_reference(eng.params, prompt, 10)
-        assert eng._attn_impl == "pallas" and eng._latent_form_queries() is None
+        # the prompt's chunks of 8 queries and more took the expanded form
+        assert eng._attn_impl == "pallas" and eng._latent_form_queries() == 8
     finally:
         eng.stop()
 
@@ -743,18 +803,32 @@ def test_manifest_names_the_selector_and_both_widths():
         return build_manifest(cfg, impl, {"tp": 1}, "x", interpret=interpret)
 
     glm = manifest(TINY_GLM_DSA)
+    # the tiny widths do not tile on a chip (4 heads): absorbed alone there
     assert glm.engine["attention_form"] == "latent-absorbed-selected-top-16"
     assert glm.engine["kv_pool_widths"] == [128, 128]
     assert glm.model["index_topk"] == 16
     assert manifest(TINY_GLM_DSA, interpret=True).engine["attention_form"] == (
+        "latent-absorbed+expanded-from-8-queries-selected-top-16"
+    )
+    assert manifest(TINY_GLM_DSA, impl="xla").engine["attention_form"] == (
         "latent-absorbed-selected-top-16"
     )
     other = manifest(dataclasses.replace(TINY_GLM_DSA, index_topk=32))
     assert other.hash() != glm.hash()
     assert manifest(TINY_PANGU).engine["attention_form"] == "latent-absorbed"
-    # the rule both the program and the counter ask: no row of a model with
-    # an indexer takes the expanded form
-    assert llama.latent_form_queries(TINY_GLM_DSA, 8, 16, jnp.bfloat16, interpret=True) is None
+    # GLM-5's published widths (a head of 192 + 64, values of 256): the
+    # kernel takes them, and the form names the selector AND the threshold
+    published = dataclasses.replace(
+        TINY_GLM_DSA, num_heads=64, num_kv_heads=64, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256, index_topk=2048,
+    )
+    assert manifest(published).engine["attention_form"] == (
+        "latent-absorbed+expanded-from-360-queries-selected-top-2048"
+    )
+    # the rule both the program and the counter ask, indexer or not
+    assert llama.latent_form_queries(TINY_GLM_DSA, 8, 16, jnp.bfloat16, interpret=True) == 8
+    assert llama.latent_form_queries(TINY_GLM_DSA, 8, 16, jnp.bfloat16) is None
+    assert llama.latent_form_queries(published, 8, 16, jnp.bfloat16) == 360
     assert llama.latent_form_queries(TINY_PANGU, 8, 16, jnp.bfloat16, interpret=True) == 8
 
 

@@ -1103,18 +1103,23 @@ def test_expanded_form_in_bfloat16_rounds_k_and_v_once():
 
 
 def test_the_expanded_rule_follows_from_the_widths():
-    """160 queries at the published widths and a tile of 8 (33.6 M
+    """160 queries at openPangu's published widths and a tile of 8 (33.6 M
     operations of expansion a context token over 1,664 saved a pair and
-    head: 157.5), a tile at tiny-pangu's; never where the absorbed form
-    costs no more a pair."""
+    head: 157.5), 360 at GLM-5's (2 x 512 x 448 over 1,280: 358.4), a tile
+    at tiny-pangu's; never where the absorbed form costs no more a pair.
+    The kernel takes a head whose ``nope`` is whole lane groups, or whose
+    ``nope + rope`` is (GLM-5's 192 + 64)."""
     from dynamo_exp_tpu.ops.ragged_attention import expanded_min_queries, expanded_supported
 
     assert expanded_min_queries(640, 512, 512, 128, 64, 128, 8) == 160
+    assert expanded_min_queries(640, 512, 512, 192, 64, 256, 8) == 360
     assert expanded_min_queries(128, 128, 40, 16, 16, 16, 8) == 8
     assert expanded_min_queries(128, 128, 64, 64, 64, 128, 8) >= 2**30
-    assert expanded_supported(16, 640, 512, 128, 128, 128, jnp.bfloat16)
-    assert not expanded_supported(16, 128, 40, 16, 16, 4, jnp.bfloat16)
-    assert not expanded_supported(16, 640, 512, 128, 128, 8, jnp.bfloat16)
+    assert expanded_supported(16, 640, 512, 192, 64, 256, 64, jnp.bfloat16)
+    assert not expanded_supported(16, 640, 512, 192, 32, 256, 64, jnp.bfloat16)
+    assert expanded_supported(16, 640, 512, 128, 64, 128, 128, jnp.bfloat16)
+    assert not expanded_supported(16, 128, 40, 16, 16, 16, 4, jnp.bfloat16)
+    assert not expanded_supported(16, 640, 512, 128, 64, 128, 8, jnp.bfloat16)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -1164,3 +1169,181 @@ def test_expanded_plan_and_host_rule_agree_on_a_drawn_stream(seed):
     for r in np.flatnonzero(host):
         pieces = slots[(row == r) & (slots > 0)]
         assert pieces.max() - pieces.min() < 8 * len(pieces)
+
+
+# ------------------------- the expanded form under a learned selection
+_GLM_WIDTHS = dict(H=2, r=512, dn=192, dr=64, dv=256, lanes=640)  # the joint layout
+# (spans, index_topk, distinct score values (0: all different), the call's
+# q_max / q_sub / kv_chunk)
+_KEPT_STREAMS = {
+    # a row wholly under index_topk (it keeps all it sees) beside one over it
+    "under_beside_over": ([(24, 24), (24, 150)], 32, 0, (16, 8, 64)),
+    # a handful of distinct scores: ties at the threshold in every chunk of
+    # 128 tokens and every sub-block of 8 queries; lower positions win
+    "ties_straddle_chunks_and_sub_blocks": ([(24, 300)], 40, 3, (32, 8, 128)),
+    # one span in three passes, each with its own count of ties
+    "a_span_in_passes": ([(40, 200)], 24, 4, (16, 8, 64)),
+    # tiles with padding slots, a row under the rule beside them
+    "padding_slots": ([(19, 150), (5, 90), (13, 129)], 16, 5, (16, 8, 64)),
+    # a chunk of this call is two of the scores' chunks
+    "two_score_chunks_a_chunk": ([(24, 500)], 64, 6, (16, 8, 256)),
+}
+
+
+def _kept_case(spans, widths, topk, values, seed=21, q_sub=8):
+    """``_latent_case`` with a selection: drawn index scores in the scores
+    kernel's layout (``-inf`` past a query's bound; ``values`` distinct
+    ones, so that many tie), the threshold and the ties a query may keep,
+    all longer by ``q_sub`` slots as the expanded call asks; and the kept
+    positions a query as the reference takes them."""
+    from dynamo_exp_tpu.ops import sparse_index as si
+
+    c = _latent_case(spans, seed, **widths)
+    ps, pmax = c["pool"].shape[1], c["table"].shape[1]
+    n = c["positions"].shape[0]
+    T, C, qt, S = si.tiled_shape(n, 8, ps, pmax)
+    rng = np.random.default_rng(seed + 1)
+    drawn = rng.standard_normal((n, C * S)).astype(np.float32)
+    if values:
+        drawn = np.floor(rng.random((n, C * S)) * values).astype(np.float32)
+    seen = np.arange(C * S)[None] <= np.asarray(c["positions"])[:, None]
+    by_query = jnp.asarray(np.where(seen, drawn, -np.inf))
+    tau, allow = si.select(by_query, c["positions"], topk)
+    c["kept"] = si.kept(by_query, tau, allow)[:, : pmax * ps]
+    tiled = jnp.swapaxes(by_query.reshape(T, qt, C, S), 1, 2)
+    assert bool((si.by_query(tiled) == by_query).all())
+    c["keep"] = (
+        jnp.pad(tiled, ((0, q_sub // 8), (0, 0), (0, 0), (0, 0)), constant_values=7.0),
+        jnp.pad(tau, (0, q_sub)), jnp.pad(allow, (0, q_sub)),
+    )
+    c["keep_tiles"] = (tiled, tau, allow)
+    return c
+
+
+@pytest.mark.parametrize("widths", [_TINY_WIDTHS, _GLM_WIDTHS], ids=["tiny", "glm5"])
+@pytest.mark.parametrize("stream", list(_KEPT_STREAMS))
+def test_expanded_form_applies_the_selection_as_the_reference_does(stream, widths):
+    """The expanded call with ``keep`` against the two-pool reference given
+    the same kept positions, and against the absorbed call with the same
+    ``keep``: the rows of at least 8 queries through the expanded call, the
+    others absorbed; in the split score layout (tiny widths) and the joint
+    one (GLM-5's head of 192 + 64)."""
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        expanded_plan, ragged_paged_attention_expanded,
+    )
+
+    spans, topk, values, (q_max, q_sub, kv_chunk) = _KEPT_STREAMS[stream]
+    c = _kept_case(spans, widths, topk, values, q_sub=q_sub)
+    r, dn, dr, dv = (widths[k] for k in ("r", "dn", "dr", "dv"))
+    scale = (dn + dr) ** -0.5
+    want = ragged_paged_attention_ref(
+        c["q"], c["kx"], c["vx"], c["table"], c["row_of"], c["positions"],
+        num_kv_heads=widths["H"], sm_scale=scale, keep=c["kept"],
+    )[..., :dv]
+    takes, items = expanded_plan(
+        c["row_of"], c["positions"], len(spans) + 1, 8, 8, q_max, q_sub
+    )
+    o_x = ragged_paged_attention_expanded(
+        c["q"], c["pool"], c["w_ukv"], c["table"], c["positions"], items,
+        rank=r, nope=dn, q_tile=8, sm_scale=scale, interpret=True,
+        q_max=q_max, q_sub=q_sub, kv_chunk=kv_chunk, keep=c["keep"],
+    )
+    o_a = ragged_paged_attention(
+        c["q_abs"], c["pool"], None, c["table"], c["row_of"],
+        jnp.where(takes, -1, c["positions"]), num_kv_heads=1, q_tile=8,
+        sm_scale=scale, interpret=True,
+        value_dim=min(widths["lanes"], -(-r // 128) * 128), keep=c["keep_tiles"],
+    )
+    o_a = jnp.einsum("nhc,chv->nhv", o_a[..., :r], c["w"][..., dn:])
+    both = jnp.where(takes[:, None, None], o_x, o_a)
+    assert bool(takes.any()) and float(jnp.abs(both - want).max()) < 3e-5
+    assert float(jnp.abs(both[np.asarray(c["positions"]) < 0]).max()) == 0.0
+    # the selection is felt: the dense result differs
+    dense = ragged_paged_attention_ref(
+        c["q"], c["kx"], c["vx"], c["table"], c["row_of"], c["positions"],
+        num_kv_heads=widths["H"], sm_scale=scale,
+    )[..., :dv]
+    assert float(jnp.abs(dense - want).max()) > 1e-3
+
+
+@pytest.mark.parametrize("stream", ["one_row_several_blocks", "crosses_the_chunk"])
+def test_joint_layout_matches_reference_without_a_selection(stream):
+    """GLM-5's head widths with no ``keep``: one 256-wide score product,
+    ``k_r`` placed behind ``k_nope`` before the one rounding."""
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        _expanded_joint, expanded_plan, ragged_paged_attention_expanded,
+    )
+
+    w = _GLM_WIDTHS
+    assert _expanded_joint(w["dn"], w["dr"]) and not _expanded_joint(128, 64)
+    spans = _EXPANDED_STREAMS[stream]
+    c = _latent_case(spans, 13, **w)
+    scale = (w["dn"] + w["dr"]) ** -0.5
+    want = ragged_paged_attention_ref(
+        c["q"], c["kx"], c["vx"], c["table"], c["row_of"], c["positions"],
+        num_kv_heads=w["H"], sm_scale=scale,
+    )[..., : w["dv"]]
+    takes, items = expanded_plan(c["row_of"], c["positions"], len(spans) + 1, 8, 8, 16, 8)
+    got = ragged_paged_attention_expanded(
+        c["q"], c["pool"], c["w_ukv"], c["table"], c["positions"], items,
+        rank=w["r"], nope=w["dn"], q_tile=8, sm_scale=scale, interpret=True,
+        q_max=16, q_sub=8, kv_chunk=64,
+    )
+    assert float(jnp.abs(jnp.where(takes[:, None, None], got - want, 0.0)).max()) < 3e-5
+
+
+# --------------------------- openPangu's kernels beside the selection's plumbing
+def _mixed_program_kernels(cfg):
+    """The kernel bodies (jaxpr text, in program order) of ``cfg``'s mixed
+    forward through the kernels at 48 slots with a packed tail of 8."""
+    import hashlib
+
+    from jax._src import core
+
+    from dynamo_exp_tpu.models import llama
+
+    def kernels(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(hashlib.sha256(str(eqn.params["jaxpr"]).encode()).hexdigest()[:16])
+            for sub in core.jaxprs_in_params(eqn.params):
+                kernels(sub, out)
+        return out
+
+    params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    k, v = jax.eval_shape(lambda: llama.init_kv_cache(cfg, 17, 8, dtype=jnp.float32))
+
+    def forward(params, tokens, positions, row_of, table, k, v, out_idx):
+        return llama.forward_ragged(
+            params, cfg, tokens, positions, row_of, table, k, v, out_idx,
+            attn_impl="pallas", interpret=True, q_tile=8, packed_from=40,
+        )
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    traced = jax.make_jaxpr(forward)(params, i32(48), i32(48), i32(48), i32(3, 16), k, v, i32(4))
+    return kernels(traced.jaxpr, [])
+
+
+@pytest.mark.parametrize("kernel,digest", [
+    ("expanded", "7d8ed2a8608d9026"), ("absorbed_tiles", "243b96dd1ee6bd56"),
+    ("absorbed_one_query", "c4ce710ae86c0081"),
+])
+def test_no_selection_leaves_the_latent_kernels_as_they_were(kernel, digest):
+    """tiny-pangu's mixed program traced through the kernels: the body of
+    each of its three attention kernels (a layer run's expanded call, its
+    absorbed tiled call, its one-query call; two runs) is, to the letter,
+    what the parent of PR 48 (commit c47eca4) traced. ``keep=None`` and the
+    split score layout take nothing from and add nothing to the kernels of
+    a latent model without an indexer, while tiny-glm-dsa's differ (they
+    carry the selection)."""
+    from dynamo_exp_tpu.models.config import TINY_GLM_DSA, TINY_PANGU
+
+    at = ["expanded", "absorbed_tiles", "absorbed_one_query"].index(kernel)
+    got = _mixed_program_kernels(TINY_PANGU)
+    assert len(got) == 6 and got[at] == got[at + 3] == digest
+    # a model with an indexer: the scores and threshold kernels of the tiled
+    # part once, then both forms, then the tail's three
+    glm = _mixed_program_kernels(TINY_GLM_DSA)
+    assert len(glm) == 2 * 7 and digest not in glm

@@ -33,7 +33,9 @@ end of a context of ``--contexts`` tokens in a stream of 2048 slots, the
 absorbed call alone, the absorbed call with the two products beside it
 (``mla_absorb``), and the expanded call whole (its plan, paddings and the
 expansion inside the kernel), each a median of ``--calls``; the operations
-each form executes and the largest difference between the two results. It fails off the chip: a
+each form executes and the largest difference between the two results. A
+configuration with an indexer (``--config glm-5-l5e16``) gives both forms
+one drawn selection of ``index_topk`` positions a query (``keep``). It fails off the chip: a
 CPU time is not a device time (``--allow-cpu`` rehearses the control flow
 at the sizes given and writes ``<config>.cpu-rehearsal.jsonl``, never the
 chip's file).
@@ -100,8 +102,9 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
     from dynamo_exp_tpu.models.llama import latent_form_queries, latent_value_lanes
     import functools
 
+    from dynamo_exp_tpu.ops import sparse_index as si
     from dynamo_exp_tpu.ops.ragged_attention import (
-        _EXPANDED_QUERIES, expanded_plan, ragged_paged_attention,
+        _EXPANDED_QUERIES, EXPANDED_SELECTION_PAD, expanded_plan, ragged_paged_attention,
         ragged_paged_attention_expanded,
     )
 
@@ -135,26 +138,44 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
         ),
     ])
 
-    def absorbed(q, positions):
+    def absorbed(q, positions, keep=None):
         return ragged_paged_attention(
             q, pool, None, table, row_of, positions, num_kv_heads=1, q_tile=TILE,
             sm_scale=scale, interpret=interpret, value_dim=vl,
+            **({} if keep is None else {"keep": (keep[0][: n // TILE], keep[1][:n], keep[2][:n])}),
         )
 
     @jax.jit
-    def absorbed_whole(q, positions):
-        o = absorbed(jnp.einsum("nhd,dhc->nhc", q, w_abs), positions)
+    def absorbed_whole(q, positions, keep=None):
+        o = absorbed(jnp.einsum("nhd,dhc->nhc", q, w_abs), positions, keep)
         return jnp.einsum("nhc,chv->nhv", o[..., :r], w3[..., dn:])
 
     @functools.partial(jax.jit, static_argnames=("q_max", "q_sub", "kv_chunk"))
-    def expanded(q, positions, q_max=None, q_sub=None, kv_chunk=None):
+    def expanded(q, positions, keep=None, q_max=None, q_sub=None, kv_chunk=None):
         plan_sizes = {k: v for k, v in (("q_max", q_max), ("q_sub", q_sub)) if v}
         _, items = expanded_plan(row_of, positions, 1, TILE, TILE, **plan_sizes)
         return ragged_paged_attention_expanded(
             q, pool, w_ukv, table, positions, items, rank=r, nope=dn, q_tile=TILE,
             sm_scale=scale, interpret=interpret, **plan_sizes,
             **({"kv_chunk": kv_chunk} if kv_chunk else {}),
+            **({} if keep is None else {"keep": keep}),
         )
+
+    @jax.jit
+    def selection(positions):
+        """A model with an indexer: drawn index scores in the scores kernel's
+        layout, longer by a sub-block as the expanded call asks, and the exact
+        top ``index_topk`` a query (``sparse_index.select_tiled``)."""
+        shape = si.tiled_shape(n + EXPANDED_SELECTION_PAD, TILE, ps, pmax)
+        bounds = jnp.pad(positions, (0, EXPANDED_SELECTION_PAD), constant_values=-1)
+        drawn = jax.random.normal(keys[3], shape, jnp.float32)
+        T, C, qt, S = shape
+        at = (jnp.arange(C)[:, None, None] * S + jnp.arange(S)[None, None])[None]
+        scores = jnp.where(at <= bounds.reshape(T, 1, qt, 1), drawn, -jnp.inf)
+        tau, allow = si.select_tiled(
+            scores, bounds.reshape(T, qt), mcfg.index_topk, interpret=interpret
+        )
+        return scores, tau.reshape(-1), allow.reshape(-1)
 
     # other sizes of a pass than the module's: "q_max,q_sub,kv_chunk;..."
     tried = [
@@ -181,9 +202,10 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
                 pos = np.full(n, -1, np.int32)
                 pos[:chunk] = context - chunk + np.arange(chunk)
                 pos = jnp.asarray(pos)
-                a_ms, _ = timed(lambda: absorbed(q_abs, pos), args.calls)
-                w_ms, o_a = timed(lambda: absorbed_whole(q, pos), args.calls)
-                x_ms, o_x = timed(lambda: expanded(q, pos), args.calls)
+                keep = jax.block_until_ready(selection(pos)) if mcfg.has_indexer else None
+                a_ms, _ = timed(lambda: absorbed(q_abs, pos, keep), args.calls)
+                w_ms, o_a = timed(lambda: absorbed_whole(q, pos, keep), args.calls)
+                x_ms, o_x = timed(lambda: expanded(q, pos, keep), args.calls)
                 pairs = chunk * context - chunk * (chunk - 1) // 2
                 diff = np.abs(
                     np.asarray(o_a[:chunk], np.float32) - np.asarray(o_x[:chunk], np.float32)
@@ -191,7 +213,7 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
                 passes = -(-chunk // _EXPANDED_QUERIES)
                 other = {
                     ",".join(str(x) for x in sizes.values()):
-                        1e3 * timed(lambda: expanded(q, pos, **sizes), args.calls)[0]
+                        1e3 * timed(lambda: expanded(q, pos, keep, **sizes), args.calls)[0]
                     for sizes in tried
                 }
                 line = {
@@ -201,6 +223,7 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
                     "absorbed_gflop": pairs * H * 2 * (lanes + vl) / 1e9,
                     "expanded_gflop": (pairs * H * 2 * (dn + dr + dv)
                                        + passes * context * H * 2 * r * (dn + dv)) / 1e9,
+                    **({"selected": True, "index_topk": mcfg.index_topk} if keep else {}),
                     "max_abs_diff_between_forms": float(diff),
                     **({"expanded_ms_at_other_passes": other} if other else {}),
                 }
