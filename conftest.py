@@ -10,6 +10,7 @@ hence env vars here (child processes inherit them).
 import asyncio
 import inspect
 import os
+import sys
 
 import pytest
 
@@ -107,3 +108,46 @@ def pytest_pyfunc_call(pyfuncitem):
         asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=120))
         return True
     return None
+
+
+_CACHE_OPTIONS = (
+    "jax_compilation_cache_dir",
+    "jax_persistent_cache_min_compile_time_secs",
+    "jax_persistent_cache_min_entry_size_bytes",
+)
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_stays_in_its_test():
+    """``aot.enable_persistent_cache`` sets process-wide JAX options. A
+    test that points them at its ``tmp_path`` (tests/test_aot.py,
+    tests/test_tune.py) used to leave every later test of its xdist
+    worker compiling through that directory: executables of earlier
+    engines came back by ``deserialize_executable``, which is where a
+    worker died inside ``test_recompile_guard_steady_state`` (PR 48's
+    run). What a test changed is put back when it ends."""
+    jax = sys.modules.get("jax")
+    before = (
+        {name: getattr(jax.config, name) for name in _CACHE_OPTIONS}
+        if jax is not None else None
+    )
+    yield
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    if before is None:
+        # jax came in with this test: what it found is JAX's default
+        # (the environment's directory, the stock gates).
+        before = {
+            "jax_compilation_cache_dir":
+                os.environ.get("JAX_COMPILATION_CACHE_DIR") or None,
+            "jax_persistent_cache_min_compile_time_secs": 1.0,
+            "jax_persistent_cache_min_entry_size_bytes": 0,
+        }
+    changed = [n for n in _CACHE_OPTIONS if getattr(jax.config, n) != before[n]]
+    if changed:
+        from jax._src.compilation_cache import reset_cache
+
+        for name in changed:
+            jax.config.update(name, before[name])
+        reset_cache()

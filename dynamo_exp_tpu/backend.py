@@ -14,6 +14,7 @@ from typing import Any, AsyncIterator
 from .model_card import ModelDeploymentCard
 from .protocols.common import BackendInput, FinishReason, LLMEngineOutput
 from .runtime.engine import AsyncEngine, AsyncEngineContext, ResponseStream
+from .telemetry.dispatch import http_chunk_clock
 from .tokenizer import Tokenizer
 
 
@@ -86,63 +87,71 @@ class Backend:
         decoder = self.tokenizer.decode_stream()
         jail = StopSequenceJail(stop.stop)
         prompt_tokens = len(binput.token_ids)
+        chunks = http_chunk_clock()
 
         async def _gen() -> AsyncIterator[dict]:
             emitted = 0
             finished: FinishReason | None = None
             async for item in engine_stream:
-                out = (
-                    LLMEngineOutput.from_dict(item) if isinstance(item, dict) else item
-                )
-                if out.finish_reason is not None:
-                    finished = FinishReason(out.finish_reason)
-                text_parts: list[str] = []
-                for tid in out.token_ids:
-                    emitted += 1
-                    hit_eos = (
-                        tid in stop_ids
-                        and not stop.ignore_eos
-                        and (stop.min_tokens is None or emitted >= stop.min_tokens)
+                # This thread's handling of the chunk, opened where the
+                # engine's tuple left its queue (a local engine) or here
+                # (a remote one): telemetry/dispatch.py HttpChunkClock.
+                chunks.taken()
+                try:
+                    out = (
+                        LLMEngineOutput.from_dict(item) if isinstance(item, dict) else item
                     )
-                    if not hit_eos:
-                        piece = decoder.step(tid)
-                        if piece is not None:
-                            safe, matched = jail.feed(piece)
-                            if safe:
-                                text_parts.append(safe)
-                            if matched:
-                                finished = FinishReason.STOP
-                                break
-                    else:
-                        finished = FinishReason.EOS
+                    if out.finish_reason is not None:
+                        finished = FinishReason(out.finish_reason)
+                    text_parts: list[str] = []
+                    for tid in out.token_ids:
+                        emitted += 1
+                        hit_eos = (
+                            tid in stop_ids
+                            and not stop.ignore_eos
+                            and (stop.min_tokens is None or emitted >= stop.min_tokens)
+                        )
+                        if not hit_eos:
+                            piece = decoder.step(tid)
+                            if piece is not None:
+                                safe, matched = jail.feed(piece)
+                                if safe:
+                                    text_parts.append(safe)
+                                if matched:
+                                    finished = FinishReason.STOP
+                                    break
+                        else:
+                            finished = FinishReason.EOS
+                            break
+                        if stop.max_tokens is not None and emitted >= stop.max_tokens:
+                            finished = finished or FinishReason.LENGTH
+                            break
+                    if finished is not None and finished is not FinishReason.STOP:
+                        # Generation ended without a stop-string match: release
+                        # any text the jail was still holding as a possible
+                        # stop-sequence prefix.
+                        text_parts.append(jail.flush())
+                    if text_parts or out.token_ids or finished:
+                        yield LLMEngineOutput(
+                            token_ids=out.token_ids,
+                            text="".join(text_parts) or None,
+                            logprobs=out.logprobs,
+                            top_logprobs=out.top_logprobs,
+                            fixed_at_pass=out.fixed_at_pass,
+                            finish_reason=finished,
+                            prompt_tokens=prompt_tokens if finished else None,
+                            completion_tokens=emitted if finished else None,
+                        ).to_dict()
+                    if finished is not None:
+                        ctx.stop_generating()
                         break
-                    if stop.max_tokens is not None and emitted >= stop.max_tokens:
-                        finished = finished or FinishReason.LENGTH
+                    if ctx.is_stopped:
+                        yield LLMEngineOutput(
+                            finish_reason=FinishReason.CANCELLED
+                        ).to_dict()
                         break
-                if finished is not None and finished is not FinishReason.STOP:
-                    # Generation ended without a stop-string match: release
-                    # any text the jail was still holding as a possible
-                    # stop-sequence prefix.
-                    text_parts.append(jail.flush())
-                if text_parts or out.token_ids or finished:
-                    yield LLMEngineOutput(
-                        token_ids=out.token_ids,
-                        text="".join(text_parts) or None,
-                        logprobs=out.logprobs,
-                        top_logprobs=out.top_logprobs,
-                        fixed_at_pass=out.fixed_at_pass,
-                        finish_reason=finished,
-                        prompt_tokens=prompt_tokens if finished else None,
-                        completion_tokens=emitted if finished else None,
-                    ).to_dict()
-                if finished is not None:
-                    ctx.stop_generating()
-                    break
-                if ctx.is_stopped:
-                    yield LLMEngineOutput(
-                        finish_reason=FinishReason.CANCELLED
-                    ).to_dict()
-                    break
+                finally:
+                    chunks.idle()
             else:
                 # Engine stream ended without reporting a finish reason:
                 # release jailed text and close the stream cleanly.

@@ -574,12 +574,10 @@ class BlockDecode:
                     continue
                 assert start == e.start, (seq.request_id, e.start)
                 n_top = self._wants_logprobs(seq)
-                fixed = 0
                 for j in range(B):
                     at_pass = int(fixed_at[r, j])
                     if seq.blk_fixed_at[j] != UNFIXED or at_pass == UNFIXED:
                         continue
-                    fixed += 1
                     seq.blk_tokens[j] = int(toks[r, j])
                     seq.blk_fixed_at[j] = at_pass
                     if n_top is not None and pending.want_lp:
@@ -591,7 +589,6 @@ class BlockDecode:
                             },
                         )
                 seq.blk_passes += d
-                tel.diffusion_tokens_fixed.inc(fixed)
                 parts["tokens"] += clock() - t0
                 if UNFIXED in seq.blk_fixed_at:
                     continue

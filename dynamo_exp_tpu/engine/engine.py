@@ -94,7 +94,7 @@ from ..runtime.engine import AsyncEngine, AsyncEngineContext, ResponseStream
 from ..telemetry import current_trace, get_telemetry
 from ..tokens import compute_block_hashes_for_seq
 from ..telemetry.anatomy import COMPONENTS, AnatomyRing, anatomy_from_timing
-from ..telemetry.dispatch import DispatchProfiler
+from ..telemetry.dispatch import PROGRAMS, DispatchProfiler, http_chunk_clock
 from ..telemetry.fingerprint import (
     FingerprintBuilder,
     WorkloadDriftWatch,
@@ -102,6 +102,7 @@ from ..telemetry.fingerprint import (
 )
 from ..kv.persistent import PersistentKvStore
 from ..telemetry.flight import (
+    host_witness,
     FlightRecorder,
     Watchdog,
     default_dump_path,
@@ -270,6 +271,9 @@ def _deliver(batch: list) -> None:
     emission order, on the asyncio thread (``TPUEngine._flush_outbox``)."""
     for put, item in batch:
         put(item)
+    # Behind the tasks those puts woke: what this thread spends on the
+    # posting's chunks is booked once, when they have been handled.
+    asyncio.get_running_loop().call_soon(http_chunk_clock().flush)
 
 
 class TPUEngine(BlockDecode, AsyncEngine):
@@ -429,6 +433,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self.profiler.part if self.profiler is not None else _no_part
         )
         self.flight = FlightRecorder()
+        launch_seconds = get_telemetry().launch_seconds
+        self._launch_seconds = {
+            p: launch_seconds.labels(p) for p in PROGRAMS
+        }
         self.sched = Scheduler(cfg, self.kv, flight=self.flight)
         if self.profiler is not None:
             self.sched.span_attrs = self._decode_span_attrs
@@ -550,6 +558,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self._progress_mark = 0
         self._watchdog: Watchdog | None = None
         self._flight_handle: int | None = None
+        # The process's HostWitness notes collector pauses and stalls
+        # into this engine's ring while it runs; a stall's note names the
+        # newest launch (``_launch`` keeps it here).
+        self._witness_handle: int | None = None
+        self._last_launch: dict = {}
         # Dispatch stamp of the last page-move gather (engine-loop
         # local; the caller's sync consumes it in the same call chain).
         self._last_move_t = 0.0
@@ -1263,6 +1276,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         ``_PendingRagged``'s (the live count where the builder gives
         none). ``event``: the builder's own fields of the ``dispatch``
         flight event."""
+        t_in = time.monotonic()
         block = self.cfg.model.block_length
         program = ("block" if block else "window") if windowed else "mixed"
         with self._phase("assemble"), self._part("assemble", "lookup"):
@@ -1297,6 +1311,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
         dispatched_at = (
             prof.end("ragged", t0, fresh) if prof is not None else 0.0
         )
+        # From this function's entry: the lookup, the offloads and the
+        # transfer lie before what ``host_gap_seconds`` ends at (``begin``).
+        self._launch_seconds[program].observe(
+            (dispatched_at or time.monotonic()) - t_in
+        )
         key = self._count_dispatch(
             program, nb, page_bucket, windowed, full_sampler, want_lp,
             with_spec, chained=chained, live=live, singles=singles,
@@ -1305,6 +1324,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
             "dispatch", dispatch="ragged", **event, bucket=nb,
             windowed=windowed, pages=key[1], want_lp=want_lp,
         )
+        self._last_launch = {
+            "dispatch_seq": self.flight.seq - 1, "program": program, "bucket": nb,
+        }
         return _PendingRagged(
             ys=ys,
             rows=rows,
@@ -1545,6 +1567,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         )
         self._thread.start()
         self._flight_handle = register_dumper(self._dump_flight)
+        self._witness_handle = host_witness().attach(
+            self.flight, lambda: self._last_launch
+        )
         if self.cfg.watchdog_stall_s > 0 and self._watchdog is None:
             self._watchdog = Watchdog(
                 self.cfg.watchdog_stall_s,
@@ -1565,6 +1590,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         if self._flight_handle is not None:
             unregister_dumper(self._flight_handle)
             self._flight_handle = None
+        if self._witness_handle is not None:
+            host_witness().detach(self._witness_handle)
+            self._witness_handle = None
         if self._thread:
             # The teardown below mutates loop-owned state, so it may only
             # run once the loop thread has actually exited. A wedged loop
@@ -1733,30 +1761,40 @@ class TPUEngine(BlockDecode, AsyncEngine):
             get_telemetry().emit_handoff if self.profiler is not None else None
         )
 
+        # This thread's handling of each tuple, from here to the
+        # response's write (telemetry/dispatch.py HttpChunkClock).
+        chunks = http_chunk_clock()
+
         async def _gen() -> AsyncIterator[dict]:
             completion = 0
             while True:
                 tokens, reason, logprobs, posted = await out_q.get()
-                if handoff is not None:
-                    handoff.observe(time.monotonic() - posted)
-                if tokens:
-                    completion += len(tokens)
-                    yield LLMEngineOutput(
-                        token_ids=tokens,
-                        logprobs=logprobs[0] if logprobs else None,
-                        top_logprobs=logprobs[1] if logprobs else None,
-                        # A block model's pack has the passes third.
-                        fixed_at_pass=(
-                            logprobs[2] if logprobs and len(logprobs) > 2 else None
-                        ),
-                    ).to_dict()
-                if reason is not None:
-                    yield LLMEngineOutput(
-                        finish_reason=reason,
-                        prompt_tokens=prompt_tokens,
-                        completion_tokens=completion,
-                    ).to_dict()
-                    return
+                chunks.taken()
+                try:
+                    if handoff is not None:
+                        handoff.observe(time.monotonic() - posted)
+                    if tokens:
+                        completion += len(tokens)
+                        yield LLMEngineOutput(
+                            token_ids=tokens,
+                            logprobs=logprobs[0] if logprobs else None,
+                            top_logprobs=logprobs[1] if logprobs else None,
+                            # A block model's pack has the passes third.
+                            fixed_at_pass=(
+                                logprobs[2]
+                                if logprobs and len(logprobs) > 2 else None
+                            ),
+                        ).to_dict()
+                    if reason is not None:
+                        yield LLMEngineOutput(
+                            finish_reason=reason,
+                            prompt_tokens=prompt_tokens,
+                            completion_tokens=completion,
+                        ).to_dict()
+                        return
+                finally:
+                    # Whoever iterates is back for the next tuple.
+                    chunks.idle()
 
         return ResponseStream(_gen(), ctx)
 

@@ -31,6 +31,7 @@ from ..runtime.annotated import Annotated
 from ..runtime.engine import AsyncEngine, AsyncEngineContext, DeadlineExceededError
 from ..runtime.push_router import NoInstancesError, RecoveryExhaustedError
 from ..telemetry import get_telemetry, span
+from ..telemetry.dispatch import http_chunk_clock
 from .admission import AdmissionController, RequestShedError, parse_priority
 from .metrics import CONTENT_TYPE_LATEST, ServiceMetrics
 
@@ -333,6 +334,11 @@ class HttpService:
                 root.set(status="error")
                 return _error_response(500, str(e))
 
+            # This thread's handling of each chunk in parts
+            # (telemetry/dispatch.py HTTP_PARTS): what comes up the
+            # stream is detokenised; from here it is framed and written.
+            chunks = http_chunk_clock()
+
             async def _typed_chunks():
                 for idx, stream in enumerate(streams):
                     # Resumable-stream belt-and-braces: chunks carry a
@@ -344,6 +350,7 @@ class HttpService:
                     high = 0  # emitted watermark (cumulative tokens)
                     last = 0  # previous chunk's index, arrival order
                     async for item in stream:
+                        chunks.lap("frame")
                         if streaming:
                             tracker.first_token()
                         chunk = (
@@ -427,7 +434,10 @@ class HttpService:
             try:
                 async for chunk in _typed_chunks():
                     frame = Annotated.from_data(chunk.model_dump(exclude_none=True))
-                    await resp.write(encode_frame(frame).encode())
+                    data = encode_frame(frame).encode()
+                    chunks.lap("write")
+                    await resp.write(data)
+                    chunks.idle()
                 await resp.write(encode_done().encode())
                 # Attributed only on a fully drained stream: a request
                 # that errored or lost its client is not goodput and

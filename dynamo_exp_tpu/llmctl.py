@@ -250,14 +250,14 @@ def build_parser() -> argparse.ArgumentParser:
     # into a sim workload trace.
     fprint = sub.add_parser(
         "fingerprint", help="characterize a workload from spans / trace "
-                            "/ bench files (offline)",
+                            "files (offline)",
     )
     fprint.add_argument(
-        "path", help="span JSONL, sim workload trace, bench capture, or "
-                     "a saved fingerprint JSON",
+        "path", help="span JSONL, sim workload trace, or a saved "
+                     "fingerprint JSON",
     )
     fprint.add_argument(
-        "--kind", choices=("auto", "spans", "trace", "bench", "ref"),
+        "--kind", choices=("auto", "spans", "trace", "ref"),
         default="auto",
         help="input format (default: sniff from content)",
     )
@@ -344,22 +344,6 @@ def build_parser() -> argparse.ArgumentParser:
     audit.add_argument(
         "--index", type=int, default=-1,
         help="which dump block to audit (default: the last)",
-    )
-
-    # Offline bench regression comparator (docs/observability.md "Fleet
-    # plane"): compare two bench captures (BENCH JSON lines or a
-    # driver's BENCH_r*.json wrappers) and flag >threshold tok/s or
-    # TTFT/ITL regressions per metric, platform-tag aware.
-    bench = sub.add_parser(
-        "bench", help="bench trajectory tools (offline)"
-    )
-    bsub = bench.add_subparsers(dest="command", required=True)
-    bcmp = bsub.add_parser("compare")
-    bcmp.add_argument("old_file", help="baseline bench capture")
-    bcmp.add_argument("new_file", help="candidate bench capture")
-    bcmp.add_argument(
-        "--threshold", type=float, default=0.10,
-        help="regression threshold as a fraction (default 0.10 = 10%%)",
     )
 
     # Offline static analysis (docs/static_analysis.md): run the
@@ -726,7 +710,6 @@ def run_fingerprint(args) -> int:
     distribution summary, and optionally pins/diffs/replays it."""
     from .telemetry import (
         drift_score,
-        fingerprint_from_bench,
         fingerprint_from_spans,
         fingerprint_from_trace,
         load_fingerprint,
@@ -748,8 +731,6 @@ def run_fingerprint(args) -> int:
             fp = fingerprint_from_spans(load_spans([args.path]))
         elif kind == "trace":
             fp = fingerprint_from_trace(args.path)
-        elif kind == "bench":
-            fp = fingerprint_from_bench(args.path)
         else:
             fp = load_fingerprint(args.path)
     except OSError as e:
@@ -830,8 +811,6 @@ def _sniff_fingerprint_kind(path: str) -> str | None:
         return "spans"
     if "arrival_s" in obj and "prompt_len" in obj:
         return "trace"
-    if "metric" in obj or "tail" in obj or "parsed" in obj:
-        return "bench"
     return None
 
 
@@ -1255,31 +1234,6 @@ def run_audit(args) -> int:
     return 1
 
 
-def run_bench_compare(args) -> int:
-    """``llmctl bench compare``: judge two captures in the BENCH JSON
-    record format, whose producer, ``bench.py``, is gone from the tree
-    (PR 29); it reads files and drives no engine."""
-    import os
-
-    from .telemetry.bench_compare import (
-        compare_bench,
-        load_bench_lines,
-        render_compare,
-    )
-
-    for path in (args.old_file, args.new_file):
-        if not os.path.exists(path):
-            print(f"no such bench file: {path}", file=sys.stderr)
-            return 2
-    report = compare_bench(
-        load_bench_lines(args.old_file),
-        load_bench_lines(args.new_file),
-        threshold=args.threshold,
-    )
-    print(render_compare(report, args.old_file, args.new_file))
-    return 0 if report.ok else 1
-
-
 async def run_top(drt, args) -> int:
     """Live fleet dashboard: scrape + render on an interval (`--once`
     prints a single snapshot for scripts and tests; `--json` prints the
@@ -1504,8 +1458,6 @@ async def run(args) -> int:
         return run_flight(args)
     if args.plane == "audit":  # offline: reads flight dumps, no cluster
         return run_audit(args)
-    if args.plane == "bench":  # offline: reads bench captures, no cluster
-        return run_bench_compare(args)
     if args.plane == "sim":  # offline: modeled fleet, no cluster
         return run_sim(args)
     if args.plane == "fingerprint":  # offline: reads recorded files

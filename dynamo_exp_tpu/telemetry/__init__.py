@@ -34,7 +34,6 @@ from .fingerprint import (
     WorkloadDriftWatch,
     WorkloadFingerprint,
     drift_score,
-    fingerprint_from_bench,
     fingerprint_from_spans,
     fingerprint_from_trace,
     load_fingerprint,
@@ -100,7 +99,6 @@ __all__ = [
     "drift_score",
     "dump_all",
     "find_trace",
-    "fingerprint_from_bench",
     "fingerprint_from_spans",
     "fingerprint_from_trace",
     "get_telemetry",

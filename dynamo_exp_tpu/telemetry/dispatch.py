@@ -62,14 +62,42 @@ reader takes for a phase, and phases do not nest. Parts that alternate
 row by row are summed by the caller from its own clock reads and handed
 over once, by ``add_parts``. Either way the seconds wait in the profiler
 until the phase's exit books them.
+
+**Off the loop thread** (docs/observability.md "Off the loop thread").
+What the device waits behind while the loop sits in ``sync`` is not the
+loop's: :class:`HttpChunkClock` reads a streamed chunk's way through the
+HTTP thread in the parts of :data:`HTTP_PARTS` (``dynhttp/<part>`` in a
+trace), and ``telemetry/flight.py``'s ``HostWitness`` the collector's
+pauses and the process's stalls (``dyngc/``, ``dynstall/``). Every event
+of every family is made by the one class :func:`trace_annotation` hands
+out, so all of them lie on the one clock of the host plane.
 """
 
 from __future__ import annotations
 
+import sys
 import time
 from collections import deque
 
 from .slo import percentile
+
+_ANNOTATION = None
+
+
+def trace_annotation(load: bool = False):
+    """``jax.profiler.TraceAnnotation``: the one class every ``dyn/``,
+    ``dynpart/``, ``dynhttp/``, ``dyngc/`` and ``dynstall/`` event of a
+    profiler trace is made by (its ``is_enabled()`` says whether a
+    session is on). None in a process that has not loaded jax, unless
+    ``load``: the telemetry package is also imported by processes that
+    never do (frontends, the sim), and none of them can be traced."""
+    global _ANNOTATION
+    if _ANNOTATION is None and (load or "jax" in sys.modules):
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
 
 # The engine's three device-dispatch kinds. Stable, closed set: the
 # prometheus label space and the metrics() mirror key on these names. ``ragged`` covers every compute
@@ -137,6 +165,22 @@ OTHER_PART = "other"
 # each and not two; what the thread computed in between goes to the
 # phase that follows.
 CPU_REUSE_S = 50e-6
+
+# The parts of a streamed chunk's way through the HTTP thread. Closed
+# set: the ``part`` label space of ``dynamo_http_chunk_seconds_total``
+# and the ``dynhttp/<part>`` names of a trace (not ``dyn/``: a trace
+# reader takes that for a phase of the loop).
+#   detokenize  from the engine's tuple leaving the request's queue to
+#               the OpenAI chunk: the engine's and the backend's
+#               generators (incremental detokenisation, the stop jail)
+#               and the preprocessor's delta
+#   frame       validation, the watermark, model_dump, the SSE frame
+#   write       the response's write
+HTTP_PARTS = ("detokenize", "frame", "write")
+HTTP_PREFIX = "dynhttp/"
+# A clock that nothing flushes (a frontend whose engine is remote has no
+# posting to end on) books itself after this many chunks.
+HTTP_FLUSH_CHUNKS = 256
 
 # Summary stat fields (also the bench JSON / docs contract).
 SUMMARY_FIELDS = (
@@ -231,11 +275,7 @@ class DispatchProfiler:
         # kernels key variants by bucket shape, invisibly to the
         # engine-level fn caches).
         self._seen_variants: set = set()
-        # Imported here, not at the top: the telemetry package is also
-        # imported by processes that never load jax (frontends, the sim).
-        from jax.profiler import TraceAnnotation
-
-        self._annotation = TraceAnnotation
+        self._annotation = trace_annotation(load=True)
         self._phases = {name: _Phase(self, name) for name in PHASES}
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         self._phase_cpu_s = dict.fromkeys(PHASES, 0.0)
@@ -507,3 +547,124 @@ class DispatchProfiler:
             "host_gap_p50_s": gap if gap is not None else 0.0,
             **extra,
         }
+
+
+class HttpChunkClock:
+    """The HTTP thread's handling of streamed chunks, cut into
+    :data:`HTTP_PARTS`. One stretch is open from ``taken`` (a tuple left
+    its request's queue) to ``idle`` (the generators are back at the
+    queue); ``lap`` ends the part it is in and goes on in the next. Wall
+    time by ``time.monotonic()``, CPU by ``time.thread_time()`` with the
+    chained reads of :data:`CPU_REUSE_S`, summed here and added to the
+    counters once a posting by ``flush`` (the engine schedules it behind
+    the tasks a posting wakes), so that the parts booked sum to the wall
+    time the thread spent with a chunk in hand. While a profiler session
+    is on, and only then, each part of each chunk is a
+    ``dynhttp/<part>`` event on this thread.
+
+    One asyncio thread calls it. A write that suspends leaves its stretch
+    open, and whatever runs meanwhile is booked to it: the sum stays
+    right, the parts blur."""
+
+    def __init__(self, telemetry=None):
+        self._wall = dict.fromkeys(HTTP_PARTS, 0.0)
+        self._cpu = dict.fromkeys(HTTP_PARTS, 0.0)
+        self._chunks = 0
+        self._part: str | None = None  # of the open stretch; None = idle
+        self._t0 = self._cpu0 = 0.0
+        self._cpu_last = 0.0
+        self._cpu_read_at = float("-inf")
+        self._labels = {p: HTTP_PREFIX + p for p in HTTP_PARTS}
+        self._ann = None
+        self._annotation = trace_annotation()
+        self._wall_c = self._cpu_c = self._chunks_c = None
+        if telemetry is not None:
+            self._wall_c = {
+                p: telemetry.http_chunk_seconds.labels(p) for p in HTTP_PARTS
+            }
+            self._cpu_c = {
+                p: telemetry.http_chunk_cpu_seconds.labels(p) for p in HTTP_PARTS
+            }
+            self._chunks_c = telemetry.http_chunks
+
+    def _open(self, part: str) -> None:
+        self._chunks += 1
+        self._part = part
+        self._t0 = now = time.monotonic()
+        if now - self._cpu_read_at < CPU_REUSE_S:
+            self._cpu0 = self._cpu_last
+        else:
+            self._cpu0 = time.thread_time()
+        self._annotate(part)
+
+    def _annotate(self, part: str | None) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        a = self._annotation
+        if part is not None and a is not None and a.is_enabled():
+            self._ann = a(self._labels[part])
+            self._ann.__enter__()
+
+    def _book(self) -> None:
+        """The open stretch so far, to its part."""
+        self._cpu_last = cpu = time.thread_time()
+        self._cpu_read_at = now = time.monotonic()
+        self._wall[self._part] += now - self._t0
+        self._cpu[self._part] += cpu - self._cpu0
+        self._t0, self._cpu0 = now, cpu
+
+    def taken(self) -> None:
+        """A tuple left its request's queue (the engine's generator, and
+        again the backend's above it: the same chunk on its way up)."""
+        if self._part is None:
+            self._open("detokenize")
+
+    def lap(self, part: str) -> None:
+        """The chunk goes on in ``part``. With no stretch open (an
+        engine without the generators that open one) it opens here."""
+        if self._part is None:
+            self._open(part)
+        elif part != self._part:
+            self._book()
+            self._part = part
+            self._annotate(part)
+
+    def idle(self) -> None:
+        """The chunk is handled: the thread goes back to a queue."""
+        if self._part is None:
+            return
+        self._book()
+        self._part = None
+        self._annotate(None)
+        if self._chunks >= HTTP_FLUSH_CHUNKS:
+            self.flush()
+
+    def flush(self) -> None:
+        """What was summed since the last call, to the counters."""
+        n, self._chunks = self._chunks, 0
+        if self._chunks_c is None:
+            return
+        if n:
+            self._chunks_c.inc(n)
+        for part in HTTP_PARTS:
+            wall, cpu = self._wall[part], self._cpu[part]
+            if wall:
+                self._wall[part] = 0.0
+                self._wall_c[part].inc(wall)
+            if cpu > 0:
+                self._cpu[part] = 0.0
+                self._cpu_c[part].inc(cpu)
+
+
+_HTTP_CLOCK: HttpChunkClock | None = None
+
+
+def http_chunk_clock() -> HttpChunkClock:
+    """The process's :class:`HttpChunkClock`, on the process's hub."""
+    global _HTTP_CLOCK
+    if _HTTP_CLOCK is None:
+        from .spans import get_telemetry
+
+        _HTTP_CLOCK = HttpChunkClock(get_telemetry())
+    return _HTTP_CLOCK

@@ -9,9 +9,8 @@ arrival-rate statistics. It can be built
 - **live** (:class:`FingerprintBuilder`): the engine feeds it at
   admission (prompt/cached/priority/arrival) and at finish (generated
   tokens, spec acceptance) — counter arithmetic only, zero host syncs;
-- **offline** from a span file (:func:`fingerprint_from_spans`), a
-  ``sim/workload.py`` trace (:func:`fingerprint_from_trace`), or a
-  bench capture (:func:`fingerprint_from_bench`) via
+- **offline** from a span file (:func:`fingerprint_from_spans`) or a
+  ``sim/workload.py`` trace (:func:`fingerprint_from_trace`) via
   ``llmctl fingerprint``.
 
 The **digest** is the contract: a sha256 over the canonical JSON of the
@@ -256,27 +255,6 @@ def fingerprint_from_trace(path: str) -> WorkloadFingerprint:
         cached = min(req.prefix_len, req.prompt_len) if req.prefix_group >= 0 else 0
         b.observe_admit(req.prompt_len, cached, req.priority, req.arrival_s or 1e-9)
         b.observe_finish(req.max_tokens)
-    return b.snapshot()
-
-
-def fingerprint_from_bench(path: str) -> WorkloadFingerprint:
-    """Coarse build from a bench capture: ``_isl<N>_`` / ``_osl<N>``
-    markers in metric names, weighted by the line's request count."""
-    import re
-
-    from .bench_compare import load_bench_lines
-
-    b = FingerprintBuilder()
-    pat = re.compile(r"_isl(\d+)_osl(\d+)")
-    for line in load_bench_lines(path):
-        m = pat.search(str(line.get("metric", "")))
-        if not m:
-            continue
-        isl, osl = int(m.group(1)), int(m.group(2))
-        count = int(line.get("requests", 1) or 1)
-        for _ in range(max(count, 1)):
-            b.observe_admit(isl)
-            b.observe_finish(osl)
     return b.snapshot()
 
 

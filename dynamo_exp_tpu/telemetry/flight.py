@@ -20,18 +20,42 @@ chaos suite proves bit-identical event sequences across same-seed
 runs); only the per-event wall timestamp ``t`` differs between runs.
 ``llmctl flight <file>`` renders a dump as a per-slot timeline the way
 ``llmctl trace`` renders spans.
+
+What the host did *to* the loop is in the ring too, as notes
+(:data:`HOST_KINDS`; ``FlightRecorder.note``): a collector pause with
+the thread it ran on, and a whole-process stall with the dispatch it
+fell in. :class:`HostWitness` writes them (docs/observability.md "Off
+the loop thread"). They are no part of the deterministic sequence: a
+note takes a place in the ring and no ``seq``.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
 import tempfile
 import threading
 import time
+from collections import deque
 
 logger = logging.getLogger(__name__)
+
+# Kinds of ring entries that are notes (``FlightRecorder.note``): what
+# a witness outside the loop saw. A reader that compares two runs'
+# event sequences leaves them out.
+HOST_KINDS = ("gc_pause", "host_stall")
+# Why the stall witness woke late (``dynamo_host_stall_seconds_total``'s
+# ``cause``, the ``dynstall/<cause>`` names of a trace).
+STALL_CAUSES = ("busy", "descheduled")
+WITNESS_INTERVAL_S = 0.02  # the witness's sleep
+STALL_LATE_S = 0.1  # a wake later than this is a stall
+# A collection is noted in the ring when it is of the oldest generation
+# or took this long: the young ones run many times a second.
+GC_NOTE_S = 0.01
+GC_PREFIX, STALL_PREFIX = "dyngc/", "dynstall/"
+_GC_EVENTS = tuple(f"{GC_PREFIX}gen{g}" for g in range(3))
 
 
 def default_dump_path() -> str:
@@ -60,9 +84,24 @@ class FlightRecorder:
 
     def record(self, kind: str, **data) -> None:
         ev = {"seq": 0, "t": time.time(), "kind": kind, **data}  # dynlint: determinism(recorder-owned wall stamp)
+        self._append(ev, numbered=True)
+
+    def note(self, kind: str, **data) -> None:
+        """An observation from outside the loop (a kind of
+        :data:`HOST_KINDS`), from any thread: it takes the ring's next
+        place and no sequence number, so the loop's own events keep the
+        numbers a same-seed run gives them. ``after`` is the number of
+        the newest loop event before it (-1: none yet)."""
+        ev = {"after": 0, "t": time.time(), "kind": kind, **data}  # dynlint: determinism(recorder-owned wall stamp)
+        self._append(ev, numbered=False)
+
+    def _append(self, ev: dict, numbered: bool) -> None:
         with self._lock:
-            ev["seq"] = self.seq
-            self.seq += 1
+            if numbered:
+                ev["seq"] = self.seq
+                self.seq += 1
+            else:
+                ev["after"] = self.seq - 1
             if len(self._ring) < self.capacity:
                 self._ring.append(ev)
             else:
@@ -199,6 +238,213 @@ class Watchdog:
                     self._dump("watchdog")
                 except Exception:  # noqa: BLE001
                     logger.exception("watchdog dump failed")
+
+
+class HostWitness:
+    """What happens to the process while the engine loop waits: the
+    cyclic collector's pauses and stalls of the whole process, as
+    counters, as notes in every attached flight ring and, while a
+    profiler session is on, as ``dyngc/gen<n>`` and ``dynstall/<cause>``
+    events (docs/observability.md "Off the loop thread").
+
+    One a process (:func:`host_witness`): ``gc.callbacks`` and the
+    process's CPU clock are the process's. It runs while a ring is
+    attached: the first ``attach`` installs one ``gc.callbacks`` hook
+    and starts one daemon thread, the last ``detach`` takes both away.
+
+    **Collections.** The hook times each collection between its
+    ``start`` and ``stop`` calls, on the thread that ran it (collections
+    do not nest): ``dynamo_gc_pause_seconds_total{generation}``,
+    ``dynamo_gc_collections_total{generation}``,
+    ``dynamo_gc_pause_max_seconds``, and a ``gc_pause`` note for one of
+    the oldest generation or of :data:`GC_NOTE_S`. A collection starts
+    between any two bytecodes of whatever thread is running, inside a
+    ``with lock:`` as well, so the hook takes no lock: it leaves what it
+    timed in a deque, and the witness's thread books it at its next wake
+    (:meth:`publish`).
+
+    **Stalls.** The thread sleeps :data:`WITNESS_INTERVAL_S` and
+    measures how late it woke. Later than :data:`STALL_LATE_S`, the
+    lateness goes to ``dynamo_host_stall_seconds_total{cause}``:
+    ``busy`` where ``time.process_time()`` advanced by at least half of
+    it over the gap (the process ran: another thread held the GIL),
+    ``descheduled`` where it did not (the process did not run). The
+    ``host_stall`` note carries what each ring's ``context()`` says was
+    in flight. A trace event cannot be dated back, so ``dynstall/`` is a
+    mark at the wake whose ``late_ns`` says how far back it reaches."""
+
+    def __init__(
+        self, telemetry=None,
+        interval_s: float = WITNESS_INTERVAL_S, late_s: float = STALL_LATE_S,
+    ):
+        self._tel = telemetry
+        self.interval_s, self.late_s = interval_s, late_s
+        self._lock = threading.Lock()
+        self._sinks: dict[int, tuple] = {}  # handle -> (flight, context)
+        self._next = 0
+        self._stop = threading.Event()
+        self._thread: threading.Thread | None = None
+        self._gc_t0 = 0.0
+        self._gc_ann = None
+        self._gc_seen: deque = deque()  # (generation, seconds, thread, collected)
+        self._annotation = None  # dispatch.trace_annotation(), at attach
+        self.gc_pause_max_s = 0.0
+        self._gc_c = self._stall_c = None
+        if telemetry is not None:
+            # Every child now: a series that is there reads 0, one that
+            # is not reads as a program without it.
+            self._gc_c = {
+                g: (
+                    telemetry.gc_pause_seconds.labels(str(g)),
+                    telemetry.gc_collections.labels(str(g)),
+                )
+                for g in range(3)
+            }
+            self._stall_c = {
+                c: (
+                    telemetry.host_stall_seconds.labels(c),
+                    telemetry.host_stalls.labels(c),
+                )
+                for c in STALL_CAUSES
+            }
+
+    # ---------------------------------------------------------- lifecycle
+    def attach(self, flight: FlightRecorder, context=None) -> int:
+        """Note into ``flight`` from now on; ``context() -> dict`` is
+        what a ``host_stall`` note says was in flight. Returns the
+        handle for :meth:`detach`."""
+        with self._lock:
+            self._next += 1
+            self._sinks[self._next] = (flight, context)
+            if self._thread is None:
+                from .dispatch import trace_annotation
+
+                self._annotation = trace_annotation()
+                gc.callbacks.append(self._on_gc)
+                self._stop.clear()
+                self._thread = threading.Thread(
+                    target=self._run, name="host-witness", daemon=True
+                )
+                self._thread.start()
+            return self._next
+
+    def detach(self, handle: int) -> None:
+        with self._lock:
+            self._sinks.pop(handle, None)
+            if self._sinks or self._thread is None:
+                return
+            thread, self._thread = self._thread, None
+            if self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+            self._stop.set()
+        thread.join(timeout=5)
+        self.publish()
+
+    def _note(self, kind: str, with_context: bool = False, **data) -> None:
+        for flight, context in list(self._sinks.values()):
+            extra = {}
+            if with_context and context is not None:
+                try:
+                    extra = context() or {}
+                except Exception:  # noqa: BLE001 - owner mid-teardown
+                    extra = {}
+            flight.note(kind, **data, **extra)
+
+    # -------------------------------------------------------- collections
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            a = self._annotation
+            if a is not None and a.is_enabled():
+                self._gc_ann = a(_GC_EVENTS[info["generation"]])
+                self._gc_ann.__enter__()
+            self._gc_t0 = time.monotonic()
+            return
+        seconds = time.monotonic() - self._gc_t0
+        if self._gc_ann is not None:
+            self._gc_ann.__exit__(None, None, None)
+            self._gc_ann = None
+        gen = info["generation"]
+        noted = gen == 2 or seconds >= GC_NOTE_S
+        self._gc_seen.append((
+            gen, seconds,
+            threading.current_thread().name if noted else None,
+            info.get("collected", 0),
+        ))
+
+    def publish(self) -> None:
+        """What the hook has timed since the last call, to the counters
+        and the rings (the witness's thread, each wake; not the hook)."""
+        while self._gc_seen:
+            gen, seconds, thread, collected = self._gc_seen.popleft()
+            if self._gc_c is not None:
+                pause_c, count_c = self._gc_c[gen]
+                pause_c.inc(seconds)
+                count_c.inc()
+            if seconds > self.gc_pause_max_s:
+                self.gc_pause_max_s = seconds
+                if self._tel is not None:
+                    self._tel.gc_pause_max.set(seconds)
+            if thread is not None:
+                self._note(
+                    "gc_pause", generation=gen, seconds=round(seconds, 6),
+                    thread=thread, collected=collected,
+                )
+
+    # ------------------------------------------------------------- stalls
+    def _run(self) -> None:
+        clock, cpu_clock = time.monotonic, time.process_time
+        t0, cpu0 = clock(), cpu_clock()
+        while not self._stop.wait(self.interval_s):
+            now = clock()
+            self.publish()
+            late = now - t0 - self.interval_s
+            if late > self.late_s:
+                cpu = cpu_clock()
+                self.stalled(late, cpu - cpu0)
+                t0, cpu0 = clock(), cpu
+            else:
+                t0, cpu0 = now, cpu_clock()
+
+    def stalled(self, late_s: float, cpu_s: float) -> str:
+        """A wake ``late_s`` late, over a gap in which the process's CPU
+        clock advanced by ``cpu_s``: booked, noted, marked; returns the
+        cause. (The thread's own call; a test's, with a clock it made.)"""
+        cause = "busy" if cpu_s >= 0.5 * late_s else "descheduled"
+        if self._stall_c is not None:
+            seconds_c, count_c = self._stall_c[cause]
+            seconds_c.inc(late_s)
+            count_c.inc()
+        # Rare (a wake over 100 ms late) and what an operator reading a
+        # slow minute's log wants beside it.
+        logger.warning(
+            "host stall: the process lost %.3f s (%s; its CPU clock moved "
+            "%.3f s)", late_s, cause, cpu_s,
+        )
+        self._note(
+            "host_stall", with_context=True, cause=cause,
+            start=time.time() - late_s,
+            seconds=round(late_s, 6), process_cpu_s=round(cpu_s, 6),
+        )
+        a = self._annotation
+        if a is not None and a.is_enabled():
+            with a(STALL_PREFIX + cause, late_ns=int(late_s * 1e9)):
+                pass
+        return cause
+
+
+_WITNESS: HostWitness | None = None
+_witness_lock = threading.Lock()
+
+
+def host_witness() -> HostWitness:
+    """The process's :class:`HostWitness`, on the process's hub."""
+    global _WITNESS
+    with _witness_lock:
+        if _WITNESS is None:
+            from .spans import get_telemetry
+
+            _WITNESS = HostWitness(get_telemetry())
+        return _WITNESS
 
 
 # ------------------------------------------------------- process registry

@@ -404,13 +404,6 @@ class Telemetry:
             ["kind"],  # denoise | write
             registry=self.registry,
         )
-        self.diffusion_tokens_fixed = Counter(
-            "dynamo_diffusion_tokens_fixed_total",
-            "Positions denoising passes fixed (over denoise passes: how "
-            "many a pass fixes; the threshold's doing where above the "
-            "schedule's count)",
-            registry=self.registry,
-        )
         # Sequence state beside the pages (a model with layers that are
         # not attention: a short convolution's products, a selective
         # scan's state; all zero for any other model). Host counts from
@@ -866,6 +859,80 @@ class Telemetry:
             "From the engine loop posting a request's tokens to the "
             "asyncio thread taking them off the request's queue",
             buckets=_HANDOFF_BUCKETS,
+            registry=self.registry,
+        )
+        # What the device waits behind while the loop sits in ``sync``
+        # (docs/observability.md "Off the loop thread"): a chunk's way
+        # through the HTTP thread, collector pauses, whole-process
+        # stalls, and a launch from ``_launch``'s entry. Fed by
+        # telemetry/dispatch.py HttpChunkClock and telemetry/flight.py
+        # HostWitness; each also writes a ``dynhttp/`` / ``dyngc/`` /
+        # ``dynstall/`` event into a profiler trace while one is on.
+        self.http_chunk_seconds = Counter(
+            "dynamo_http_chunk_seconds_total",
+            "Host seconds the HTTP thread spent on streamed chunks, by "
+            "part: detokenize (from taking the engine's tuple off the "
+            "request's queue to the OpenAI chunk), frame (validation, "
+            "model_dump, the SSE frame), write (the response write). The "
+            "parts sum to the handling of dynamo_http_chunks_total",
+            ["part"],  # telemetry/dispatch.py HTTP_PARTS
+            registry=self.registry,
+        )
+        self.http_chunk_cpu_seconds = Counter(
+            "dynamo_http_chunk_cpu_seconds_total",
+            "CPU seconds of the HTTP thread inside the same parts: a "
+            "part's host seconds less these it did not run (it waited "
+            "for the GIL or was descheduled)",
+            ["part"],
+            registry=self.registry,
+        )
+        self.http_chunks = Counter(
+            "dynamo_http_chunks_total",
+            "Engine tuples the HTTP thread took off a request's queue "
+            "and handled (a row's tokens of one dispatch, or its finish)",
+            registry=self.registry,
+        )
+        self.gc_pause_seconds = Counter(
+            "dynamo_gc_pause_seconds_total",
+            "Seconds inside the cyclic garbage collector, by generation "
+            "(every thread waits: the collector holds the GIL)",
+            ["generation"],  # 0 | 1 | 2
+            registry=self.registry,
+        )
+        self.gc_collections = Counter(
+            "dynamo_gc_collections_total",
+            "Collections of the cyclic garbage collector, by generation",
+            ["generation"],
+            registry=self.registry,
+        )
+        self.gc_pause_max = Gauge(
+            "dynamo_gc_pause_max_seconds",
+            "The longest single collection since the witness was installed",
+            registry=self.registry,
+        )
+        self.host_stall_seconds = Counter(
+            "dynamo_host_stall_seconds_total",
+            "Seconds a 20 ms sleeper woke late by, summed over wakes more "
+            "than 100 ms late, by cause: busy (the process's CPU clock "
+            "advanced by at least half the lateness: another thread held "
+            "the GIL) or descheduled (it did not: the process did not run)",
+            ["cause"],  # telemetry/flight.py STALL_CAUSES
+            registry=self.registry,
+        )
+        self.host_stalls = Counter(
+            "dynamo_host_stalls_total",
+            "Wakes of that sleeper more than 100 ms late, by cause",
+            ["cause"],
+            registry=self.registry,
+        )
+        self.launch_seconds = Histogram(
+            "dynamo_launch_seconds",
+            "From TPUEngine._launch's entry (the variant lookup, the "
+            "offloads that must precede, the transfer) to the return of "
+            "the jitted call, by program: the part of a host gap that "
+            "dynamo_host_gap_seconds ends before",
+            ["program"],  # window | mixed | block
+            buckets=_DISPATCH_BUCKETS,
             registry=self.registry,
         )
         self.kv_pool_info = Gauge(

@@ -7,8 +7,8 @@ from it:
 
 - the search (:mod:`.search`) walks the sim-applicable knobs' grids;
 - a tuned artifact carries the engine's resolved knob dict and its
-  :func:`config_hash`, the key by which ``llmctl bench compare`` pairs
-  BENCH JSON lines, so differently-knobbed runs are never compared;
+  :func:`config_hash`, the key that tells a differently-knobbed run
+  from a regression of another's;
 - the docs knob table renders from :func:`render_knob_table` and a
   doc-sync guard keeps docs/tuning.md listing every knob;
 - a registry-walk guard test (tests/test_tune.py) asserts the registry
@@ -299,9 +299,9 @@ def resolved_engine_knobs(cfg) -> dict:
 
 
 def config_hash(knobs: dict) -> str:
-    """Stable short hash of a resolved knob dict: the pairing key
-    ``llmctl bench compare`` uses so differently-knobbed runs never
-    silently compare. Canonical JSON, so dict order cannot leak in."""
+    """Stable short hash of a resolved knob dict: the key by which
+    differently-knobbed runs are told apart. Canonical JSON, so dict
+    order cannot leak in."""
     blob = json.dumps(knobs, sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(blob.encode()).hexdigest()[:12]
 

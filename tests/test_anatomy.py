@@ -591,18 +591,3 @@ def test_llmctl_top_json_over_fake_runtime(capsys):
         pytest.approx(0.31)
     )
     assert doc["missing"] == {}
-
-
-def test_bench_compare_judges_anatomy_fields():
-    from dynamo_exp_tpu.telemetry.bench_compare import compare_bench
-
-    old = [{"metric": "m", "unit": "tok/s", "value": 100.0,
-            "anatomy": {"decode_compute": 1.0, "queue_wait": 0.1}}]
-    new = [{"metric": "m", "unit": "tok/s", "value": 100.0,
-            "anatomy": {"decode_compute": 1.5, "queue_wait": 0.1}}]
-    rep = compare_bench(old, new)
-    assert [f.field for f in rep.regressions] == ["anatomy.decode_compute"]
-    # Improvements report too; absent/zero components never divide.
-    rep2 = compare_bench(new, old)
-    assert [f.field for f in rep2.findings] == ["anatomy.decode_compute"]
-    assert rep2.findings[0].kind == "improvement"

@@ -303,6 +303,21 @@ def test_warm_boot_compiles_nothing(tmp_path):
     assert toks1 == toks2
 
 
+def test_the_persistent_cache_does_not_outlive_the_test_that_enabled_it():
+    """``test_warm_boot_compiles_nothing`` points JAX's process-wide
+    cache options at its ``tmp_path``. The root conftest puts them back
+    when a test ends, so no later test of this worker compiles through
+    (and deserialises executables from) a directory of an earlier one:
+    where a worker died in ``test_recompile_guard_steady_state``."""
+    import jax
+
+    assert jax.config.jax_compilation_cache_dir == (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+    )
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+
+
 def test_prewarm_refuses_running_engine():
     eng = _engine()
     eng.start()

@@ -1,6 +1,6 @@
 """Fleet observability plane (docs/observability.md "Fleet plane"):
 FleetAggregator rollups, scrape fault tolerance, the per-link
-TransferLedger, `llmctl top`/`bench compare`, the multi-instance trace
+TransferLedger, `llmctl top`, the multi-instance trace
 timeline, and the live↔sim fleet-rollup mirror."""
 
 from __future__ import annotations
@@ -13,11 +13,6 @@ import pytest
 
 from dynamo_exp_tpu import llmctl
 from dynamo_exp_tpu.telemetry import Span
-from dynamo_exp_tpu.telemetry.bench_compare import (
-    compare_bench,
-    load_bench_lines,
-    render_compare,
-)
 from dynamo_exp_tpu.telemetry.fleet import (
     FleetAggregator,
     FleetView,
@@ -309,85 +304,6 @@ def test_render_timeline_multi_instance_with_transfer_hops():
     # Single-instance traces keep the compact label format.
     solo = [_span("decode", "S", t, t + 1, instance="only")]
     assert "[only]" not in render_timeline(solo)
-
-
-# ------------------------------------------------------------ bench compare
-def _bench_line(metric, value=100.0, unit="tok/s", platform="cpu", **extra):
-    return {"metric": metric, "value": value, "unit": unit,
-            "platform": platform, **extra}
-
-
-def test_bench_compare_flags_regressions_and_improvements():
-    old = [_bench_line("decode_tp", 100.0, p99_ttft_s=1.0),
-           _bench_line("other", 50.0)]
-    new = [_bench_line("decode_tp", 80.0, p99_ttft_s=1.5),
-           _bench_line("other", 60.0)]
-    rep = compare_bench(old, new, threshold=0.10)
-    assert not rep.ok
-    kinds = {(f.field, f.kind) for f in rep.findings}
-    assert ("value(tok/s)", "regression") in kinds
-    assert ("p99_ttft_s", "regression") in kinds
-    assert ("value(tok/s)", "improvement") in kinds
-    text = render_compare(rep, "a.json", "b.json")
-    assert "REGRESSION" in text
-
-
-def test_bench_compare_is_platform_tag_aware():
-    """A chip line never compares against a CPU-fallback line — the
-    pair is skipped with a note, not flagged."""
-    old = [_bench_line("decode_tp", 500.0, platform="tpu")]
-    new = [_bench_line("decode_tp", 50.0, platform="cpu")]
-    rep = compare_bench(old, new)
-    assert rep.ok and rep.compared == 0
-    assert any("not comparable" in s for s in rep.skipped)
-
-
-def test_bench_compare_wrapper_and_jsonl_formats(tmp_path):
-    wrapper = {
-        "n": 9, "cmd": "bench", "rc": 0,
-        "tail": 'noise\n{"metric": "m1", "value": 10.0, "unit": "tok/s", '
-                '"platform": "cpu"}\nnot json {',
-        "parsed": {"metric": "m0", "value": 5.0, "unit": "tok/s",
-                   "platform": "cpu"},
-    }
-    a = tmp_path / "a.json"
-    a.write_text(json.dumps(wrapper))
-    lines = load_bench_lines(str(a))
-    assert {ln["metric"] for ln in lines} == {"m0", "m1"}
-    b = tmp_path / "b.jsonl"
-    b.write_text(
-        '{"metric": "m1", "value": 8.0, "unit": "tok/s", "platform": "cpu"}\n'
-    )
-    rep = compare_bench(load_bench_lines(str(a)), load_bench_lines(str(b)))
-    assert not rep.ok  # 10 -> 8 is a 20% drop
-
-
-def test_bench_compare_cli_over_failed_run_wrappers(capsys):
-    """Comparing two driver wrappers of
-    runs that failed (no metric line in either) must exit 0 — no
-    comparable pairs compare clean."""
-    import os
-
-    fixtures = os.path.join(os.path.dirname(__file__), "fixtures")
-    a, b = (
-        os.path.join(fixtures, f"bench_wrapper_failed_{x}.json")
-        for x in ("a", "b")
-    )
-    rc = llmctl.main(["bench", "compare", a, b])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "no comparable metrics" in out or "no regressions" in out
-
-
-def test_bench_compare_cli_exit_codes(tmp_path, capsys):
-    a = tmp_path / "a.jsonl"
-    b = tmp_path / "b.jsonl"
-    a.write_text(json.dumps(_bench_line("m", 100.0)) + "\n")
-    b.write_text(json.dumps(_bench_line("m", 50.0)) + "\n")
-    assert llmctl.main(["bench", "compare", str(a), str(b)]) == 1
-    assert llmctl.main(["bench", "compare", str(a), str(a)]) == 0
-    capsys.readouterr()
-    assert llmctl.main(["bench", "compare", str(a), "/nope.json"]) == 2
 
 
 # --------------------------------------------------------------- sim mirror

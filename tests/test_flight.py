@@ -31,6 +31,7 @@ from dynamo_exp_tpu.models import TINY
 from dynamo_exp_tpu.parallel import single_device_mesh
 from dynamo_exp_tpu.protocols.common import BackendInput
 from dynamo_exp_tpu.telemetry.flight import (
+    HOST_KINDS,
     FlightRecorder,
     Watchdog,
     load_dumps,
@@ -249,6 +250,8 @@ def _run_stall_once(tmp_path, seed: int, tag: str) -> tuple[list, dict, str]:
     assert len(blocks) == 1, f"expected exactly one dump, got {len(blocks)}"
     events = []
     for ev in blocks[0]["events"]:
+        if ev["kind"] in HOST_KINDS:
+            continue  # what the host did to the loop (a pause, a stall): no run's twice
         d = dict(ev)
         d.pop("t", None)  # the only cross-run-variable field
         events.append(d)

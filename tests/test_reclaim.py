@@ -830,34 +830,6 @@ def test_reclaim_event_log_bit_identical_per_seed(seed):
     assert d1 == d2
 
 
-def test_bench_compare_judges_reclaim_fields():
-    from dynamo_exp_tpu.telemetry.bench_compare import compare_bench
-
-    old = [{
-        "metric": "reclaim_sweep_spot50_g4_r6", "platform": "sim",
-        "unit": "goodput tok/s", "value": 200.0,
-        "billed_chip_seconds": 300.0, "migrated_fraction": 0.8,
-        "goodput_per_billed_chip_s": 80.0, "ttft_p99_s": 0.5,
-    }]
-    new = [{
-        "metric": "reclaim_sweep_spot50_g4_r6", "platform": "sim",
-        "unit": "goodput tok/s", "value": 150.0,       # goodput collapse
-        "billed_chip_seconds": 400.0,                   # spend regression
-        "migrated_fraction": 0.3,                       # migration hit rate
-        "goodput_per_billed_chip_s": 37.5,              # economics headline
-        "ttft_p99_s": 0.9,                              # latency regression
-    }]
-    report = compare_bench(old, new)
-    flagged = {f.field for f in report.regressions}
-    assert "value(goodput tok/s)" in flagged
-    assert "billed_chip_seconds" in flagged
-    assert "migrated_fraction" in flagged
-    assert "goodput_per_billed_chip_s" in flagged
-    assert "ttft_p99_s" in flagged
-    # Identical captures compare clean.
-    assert compare_bench(old, [dict(old[0])]).ok
-
-
 # ------------------------------------------------------------- doc-sync
 def test_reclaim_surface_is_documented():
     """Doc-sync guard (same contract as the fleet/anatomy planes): the

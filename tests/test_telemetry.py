@@ -606,7 +606,7 @@ def test_every_engine_metrics_mirror_key_is_documented():
 def test_fleet_plane_surface_is_documented():
     """Doc-sync guard (fleet-plane extension): the fleet-level rollup
     keys, the per-link ledger fields, and the new operator commands
-    (`llmctl top` / `llmctl audit` / `llmctl bench compare`) must land
+    (`llmctl top` / `llmctl audit`) must land
     in docs/observability.md's "Fleet plane" section, with matching
     suite rows in docs/testing.md and the README pointer — the same
     discipline as the metric-name guard above."""
@@ -615,7 +615,7 @@ def test_fleet_plane_surface_is_documented():
     doc = _observability_doc()
     assert "## Fleet plane" in doc
     assert "## KV conservation auditor" in doc
-    for cmd in ("llmctl top", "llmctl audit", "llmctl bench compare"):
+    for cmd in ("llmctl top", "llmctl audit"):
         assert cmd in doc, f"{cmd!r} undocumented in docs/observability.md"
     # Every fleet rollup key and ledger link field is contract surface
     # (llmctl top, SimReport.fleet, and the planner consume them).

@@ -22,8 +22,6 @@ Covers the four tune/ stages plus the surfaces the tuner reaches into:
    entry selection, and churn protection, inside ``plan_step_slo``.
 7. **Env-knob table** — ``DYN_*`` flag spellings validate at config
    construction; typos and malformed values raise, exempt names pass.
-8. **Bench pairing** — ``llmctl bench compare`` pairs by
-   ``(metric, config_hash)`` and skips differently-tuned runs.
 """
 
 import asyncio
@@ -45,7 +43,6 @@ from dynamo_exp_tpu.planner.policy import (
     plan_step,
     plan_step_slo,
 )
-from dynamo_exp_tpu.telemetry.bench_compare import compare_bench
 from dynamo_exp_tpu.telemetry.fingerprint import (
     DRIFT_ALERT_THRESHOLD,
     WorkloadFingerprint,
@@ -562,56 +559,6 @@ def test_env_proactive_grace(monkeypatch):
     monkeypatch.setenv("DYN_KV_PROACTIVE", "0")
     assert _ecfg(proactive_offload_grace_s=0.2).proactive_offload_grace_s \
         == -1.0
-
-
-# ------------------------------------------------- bench config pairing
-def _line(metric="decode tok/s", value=100.0, platform="cpu", **extra):
-    return {
-        "metric": metric, "unit": "tok/s", "value": value,
-        "platform": platform, **extra,
-    }
-
-
-@pytest.mark.pre_merge
-def test_bench_compare_same_hash_still_flags_regressions():
-    report = compare_bench(
-        [_line(value=100.0, config_hash="aaa")],
-        [_line(value=50.0, config_hash="aaa")],
-    )
-    assert [f.kind for f in report.findings] == ["regression"]
-
-
-@pytest.mark.pre_merge
-def test_bench_compare_skips_differently_tuned_runs():
-    report = compare_bench(
-        [_line(value=100.0, config_hash="aaa")],
-        [_line(value=50.0, config_hash="bbb")],
-    )
-    assert report.compared == 0 and not report.findings
-    assert any("differently-tuned" in s for s in report.skipped)
-
-
-@pytest.mark.pre_merge
-def test_bench_compare_pairs_by_config_hash_among_same_metric():
-    """An old capture holding the same metric under two configs pairs
-    the new line with ITS config, not whichever parsed last."""
-    old = [
-        _line(value=100.0, config_hash="aaa"),
-        _line(value=50.0, config_hash="bbb"),
-    ]
-    report = compare_bench(old, [_line(value=100.0, config_hash="aaa")])
-    assert report.compared == 1 and report.findings == []
-
-
-@pytest.mark.pre_merge
-def test_bench_compare_legacy_untagged_lines_pair_by_metric():
-    # Captures taken before the stamp existed: one side (or
-    # both) untagged keeps the metric-name pairing unchanged.
-    report = compare_bench(
-        [_line(value=100.0)], [_line(value=50.0, config_hash="bbb")]
-    )
-    assert report.compared == 1
-    assert [f.kind for f in report.findings] == ["regression"]
 
 
 # ------------------------------------------------------------- evaluate
