@@ -1521,6 +1521,40 @@ class TPUEngine(BlockDecode, AsyncEngine):
         slots.labels("merged").inc(merged)
         slots.labels("tile").inc(by_tile)
 
+    def _count_index_passes(self, nb: int, row_of, positions) -> None:
+        """Of a mixed stream's tiled part of a model with an indexer, the
+        live slots whose block the scores kernel reads in one pass over
+        their row's index keys and those it walks tile by tile
+        (``dynamo_dsa_index_slots_total``): the kernel's own rule
+        (``ops/ragged_attention.py::block_plan`` at ``ops/sparse_index.py::
+        index_block``) on the host's copy of what it takes, a part of the
+        stream at a time as ``forward_ragged`` calls it (the whole tiled
+        part where a row may take the expanded form, else a slab a call),
+        and only the parts that score (``_selection_tiled``: a query there
+        sees more than ``index_topk``)."""
+        m = self.cfg.model
+        if not m.has_indexer or self._attn_impl != "pallas":
+            return
+        from ..models.llama import LATENT_SLAB
+        from ..ops.ragged_attention import merged_slot_counts
+        from ..ops.sparse_index import index_block
+
+        tile = self.cfg.ragged_q_tile
+        tiled = nb - self.cfg.ragged_packed_slots(nb)
+        block = index_block(
+            tile, m.index_n_heads, m.index_lanes, np.dtype(self.cfg.kv_dtype_jnp).itemsize
+        )
+        least = self._latent_form_queries()
+        whole = least is not None and tiled >= least
+        step = tiled if whole else LATENT_SLAB // tile * tile
+        slots = get_telemetry().dsa_index_slots
+        for start in range(0, tiled, max(step, 1)):
+            rows, at = row_of[start : start + step], positions[start : start + step]
+            if (at >= m.index_topk).any():
+                in_block, by_tile = merged_slot_counts(rows, at, tile, block)
+                slots.labels("block").inc(in_block)
+                slots.labels("tile").inc(by_tile)
+
     def _count_slots(self, program: str, live: int, pad: int) -> None:
         """``_count_dispatch``'s slots. A block program's are counted at
         its consume: how many passes it made is the device's to say."""
@@ -4252,6 +4286,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             tokens=total_q,
         )
         self._count_attn_passes(nb, row_of, positions)
+        self._count_index_passes(nb, row_of, positions)
         self._count_latent_reads(
             "mixed", positions, row_of, tiled=nb - cfg.ragged_packed_slots(nb)
         )

@@ -339,6 +339,22 @@ class Telemetry:
             ["program"],
             registry=self.registry,
         )
+        self.dsa_index_slots = Counter(
+            "dynamo_dsa_index_slots_total",
+            "Live slots of the tiled part of a learned-sparse-attention "
+            "model's mixed dispatches that the indexer's scores kernel served, "
+            "by how it read their row's index keys: block (the slot's block "
+            "of ops/sparse_index.py::index_block slots belongs to one row and "
+            "walks its keys once for every tile in it) or tile (a block of "
+            "several rows: the keys once a tile). Counted by the dispatch "
+            "builders with the kernel's own rule (ops/ragged_attention.py::"
+            "block_plan at that block), a stream part at a time as the "
+            "program calls the kernel; a part none of whose queries sees more "
+            "than index_topk skips the kernel and counts nothing. A layer's; "
+            "a kernel engine only",
+            ["pass"],  # block | tile
+            registry=self.registry,
+        )
         self.dsa_selected_pairs = Counter(
             "dynamo_dsa_selected_pairs_total",
             "Query-context pairs attended after the selection, by program: "
