@@ -318,7 +318,13 @@ def _attention_form(cfg, attn_impl: str, interpret: bool) -> str:
     executables built before that rule, or at another threshold, are not
     these; a model with an indexer names its selector behind the form(s),
     which both apply."""
-    if not cfg.model.is_latent:
+    m = cfg.model
+    if not m.is_latent:
+        # a sliding width the kernel takes as its own argument, and whether
+        # the layers' forms have pools of their own (the sliding pool's
+        # shape follows from the fields beside this one)
+        if attn_impl == "pallas" and "sliding" in m.attn_forms and m.attn_kernel_form:
+            return f"heads-sliding-{m.sliding_window}" + ("-by-kind" if m.kv_by_kind else "")
         return "heads"
     # the selector: every read masks what the top-k of the index scores
     # left out, inside whichever call reads the row

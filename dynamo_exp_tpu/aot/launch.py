@@ -137,11 +137,24 @@ def _at_offsets(named) -> tuple[LaunchField, ...]:
 
 
 def _sizes(cfg) -> tuple:
+    # ``table``'s columns: a row's pages, and behind them, where the cache
+    # is by kind, its pages of the sliding pool (``split_tables``)
     return (
-        cfg.max_decode_slots + 1, cfg.max_pages_per_seq,
+        cfg.max_decode_slots + 1,
+        cfg.max_pages_per_seq * (2 if cfg.model.kv_by_kind else 1),
         cfg.device_stop_width, cfg.spec_max_draft + 1,
         cfg.model.block_length,
     )
+
+
+def split_tables(cfg, table):
+    """A launch's ``table`` field as the paged forwards take it: itself, or
+    for a model whose cache is by kind the pair (full, sliding) of its two
+    halves (``models/llama.py::init_kv_cache``)."""
+    if not cfg.model.kv_by_kind:
+        return table
+    pmax = cfg.max_pages_per_seq
+    return table[:, :pmax], table[:, pmax:]
 
 
 def launch_layout(cfg, key: tuple) -> tuple[LaunchField, ...]:

@@ -186,6 +186,56 @@ def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> 
         )
 
 
+def refuse_sliding_pool_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
+    """The one place a model whose cache is two pools by the layers' form
+    (``ModelConfig.kv_by_kind``: sliding and full attention layers in one
+    stack, a row's pages of the sliding pool given back behind the width)
+    is refused by what copies "a row's pages" through ONE table, in
+    ``refuse_seq_state_tiers``' manner. Each of these would carry the full
+    layers' pages and leave the sliding layers' behind, or ask for sliding
+    pages that were given back:
+
+    - host offload and its prefetch / proactive swap (``engine/offload.py``,
+      ``host_cache_pages``), G3 persistence (``kv/persistent.py``,
+      ``kv_store_dir``): a page record is one pool's K and V payload;
+    - speculative decoding (``spec_mode``): a rejected draft rewinds the
+      row's pages by count, and the sliding pool may already have given
+      back what the rewound position sees;
+    - ring prefill (``sp`` > 1): it has no sliding mask and writes pages
+      from outside the paged layer loop;
+    - tensor parallelism (``tp`` > 1): the pools by kind have not run on a
+      mesh, and nothing tests them there;
+    - ``tier`` = "disaggregated transfer" / "migration"
+      (``disagg/transfer.py``, ``runtime/reclaim.py``): the wire's page
+      layout is one pool's.
+
+    Prefix sharing is not refused but granted no hit (``TPUEngine`` builds
+    the page manager with ``sharing=False``). With ``tier`` None the
+    engine's own configuration is checked; with a name, that component is
+    refused. Any other model passes, and so does ``cfg`` None."""
+    if cfg is None or not cfg.model.kv_by_kind:
+        return
+    armed = [tier] if tier else [
+        name for name, on in (
+            ("host offload (host_cache_pages)", cfg.host_cache_pages > 0),
+            ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
+            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
+            ("ring prefill (sp > 1)", cfg.sp > 1),
+            ("tensor parallelism (tp > 1)", cfg.tp > 1),
+        ) if on
+    ]
+    if armed:
+        m = cfg.model
+        raise ValueError(
+            f"model_type {m.model_type!r} caches its {m.num_sliding_layers} "
+            f"sliding-attention layers (width {m.sliding_window}) in a page "
+            "pool of their own, whose pages go back behind the width, beside "
+            f"the full-attention layers' pool, which {', '.join(armed)} does "
+            "not carry: it moves a row's pages through one table. Serve it "
+            "without (README.md, models/)"
+        )
+
+
 def refuse_block_model_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
     """The one place a model that generates by diffusion over blocks
     (``ModelConfig.block_length`` > 0: a row's step is a pass over a block
@@ -499,6 +549,42 @@ class EngineConfig:
     @property
     def max_pages_per_seq(self) -> int:
         return (self.max_model_len + self.page_size - 1) // self.page_size
+
+    @property
+    def sliding_pool_pages(self) -> int | None:
+        """Pages of the sliding layers' pool of a model whose cache is by
+        kind (``ModelConfig.kv_by_kind``; None for any other): sized from
+        the largest dispatch, NOT from the context, because a row gives its
+        sliding pages back behind the width once a dispatch is consumed
+        (``kv_manager.SlidingPagePool``). A row holds the pages of
+        [its next position - (width - 1), the last position a dispatch in
+        flight writes]:
+
+        - the ``width - 1`` positions behind, and the two pages they and
+          the span's end cut into: ``ceil((width - 1) / ps) + 2`` a row;
+        - what its dispatches in flight write: a decode window and the one
+          chained onto it (``2 * decode_window`` positions a row, a page
+          more for where they start), or its share of a mixed dispatch,
+          whose spans sum to at most the largest mixed bucket:
+          ``prefill_batch`` rows' chunks and every slot's one token, each
+          aligned to the kernel's tile (speculation, whose spans would add
+          to it, is refused for such a model).
+
+        ``max_decode_slots`` rows at the most. At 32 slots, pages of 16, a
+        width of 128, windows of 8 and chunks of 512 that is a dispatch of
+        8,192 tokens: 512 + 32 x (8 + 2 + 2) = 896 pages where a pool sized
+        by a 32 k context would take 65,536."""
+        if not self.model.kv_by_kind:
+            return None
+        ps, tile = self.page_size, self.ragged_q_tile
+        behind = -(-(self.model.sliding_window - 1) // ps) + 2
+        window = -(-2 * self.decode_window // ps) + 1
+        dispatch = self.ragged_tokens_bucket_for(
+            self.prefill_batch * (self.prefill_chunk + tile)
+            + self.max_decode_slots * tile,
+            mixed=True,
+        )
+        return -(-dispatch // ps) + self.max_decode_slots * (behind + window)
 
     def bucket_for(self, n: int) -> int | None:
         for b in self.prefill_buckets:

@@ -64,6 +64,7 @@ from ..aot.launch import (
     SAMPLER_FIELDS,
     launch_buffer,
     launch_layout,
+    split_tables,
     unpack_launch,
 )
 from ..aot.lattice import impl_for_key, resolve_ragged_key
@@ -114,9 +115,10 @@ from .config import (
     EngineConfig,
     refuse_block_model_tiers,
     refuse_latent_tiers,
+    refuse_sliding_pool_tiers,
     refuse_seq_state_tiers,
 )
-from .kv_manager import KvEvent, KvPageManager
+from .kv_manager import KvEvent, KvPageManager, SlidingPagePool
 from .offload import CopyStream, HostKvPool
 from .scheduler import RemoteKv, Scheduler, SeqState, Sequence, _RaggedRow
 from .tiering import SwapRecord, plan_swap_entries
@@ -152,16 +154,15 @@ def resolve_attn_impl(cfg: EngineConfig, mesh: Mesh) -> tuple[str, bool]:
         platform = mesh.devices.flat[0].platform
         impl = "pallas" if (platform == "tpu" or interpret) else "xla"
     mcfg = cfg.model
-    if impl == "pallas" and (
-        mcfg.sliding_window is not None
-        or mcfg.attn_logit_softcap is not None
-        or mcfg.query_pre_attn_scalar is not None
-    ):
-        # forward() would silently refuse the kernel for these
-        # configs (window mask / softcap / scale live on the XLA
-        # path); resolve xla HERE so attn_pages keeps bounding the
-        # gather — otherwise decode would run the XLA path with an
-        # unbounded Pmax-wide page table.
+    if impl == "pallas" and not mcfg.attn_kernel_form:
+        # forward() would silently refuse the kernel for these configs
+        # (a softcap, a query pre-scale, a sliding width that alternates
+        # inside a run of layers: ``ModelConfig.attn_kernel_form``, which
+        # both paged forwards ask too); resolve xla HERE so attn_pages
+        # keeps bounding the gather — otherwise decode would run the XLA
+        # path with an unbounded Pmax-wide page table. A sliding width
+        # that is one run's (mistral's, exaone_moe's) is the kernel's own
+        # argument and stays.
         impl = "xla"
     if mcfg.is_latent:
         # A latent pool is read by the kernel or, off the TPU, by its
@@ -291,6 +292,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         refuse_seq_state_tiers(cfg)
         refuse_block_model_tiers(cfg)
         refuse_latent_tiers(cfg)
+        refuse_sliding_pool_tiers(cfg)
         self.mesh = mesh or build_mesh(tp=cfg.tp, sp=cfg.sp)
         mcfg = cfg.model
 
@@ -312,9 +314,15 @@ class TPUEngine(BlockDecode, AsyncEngine):
             )(jax.random.PRNGKey(seed), mcfg)
         else:
             self.params = jax.device_put(params, shardings)
+        # A model whose cache is by kind (sliding and full attention layers
+        # in one stack: ``ModelConfig.kv_by_kind``) has each of these as a
+        # pair (full, sliding), ``num_pages`` being the full layers' and the
+        # sliding pool sized from the largest dispatch
+        # (``EngineConfig.sliding_pool_pages``).
         self.k_cache, self.v_cache = init_kv_cache(
             mcfg, cfg.num_pages, cfg.page_size, dtype=cfg.kv_dtype_jnp,
             shardings=tuple(sharding(s) for s in kv_cache_shardings()),
+            sliding_pages=cfg.sliding_pool_pages,
         )
         # What a sequence carries beside its pages (a model with layers
         # whose operator is not softmax attention: a short convolution's
@@ -341,15 +349,37 @@ class TPUEngine(BlockDecode, AsyncEngine):
         ))
         # The pools' geometry, from the pools themselves: a latent model
         # has one, whose lanes hold ``latent_width`` values and padding.
-        layers, pages, page_size, width = self.k_cache.shape
+        # Of a cache by kind ``dynamo_kv_pool_info`` describes the full
+        # layers' pools (what a context token holds for as long as its row
+        # lives) and ``dynamo_kv_sliding_pool_info`` the sliding layers'.
+        k_pool, v_pool = (
+            (self.k_cache[0], self.v_cache[0]) if mcfg.kv_by_kind
+            else (self.k_cache, self.v_cache)
+        )
+        layers, pages, page_size, width = k_pool.shape
         get_telemetry().set_kv_pool_info(
-            layers, pages, page_size, width, str(self.k_cache.dtype),
-            pools=sum(1 for pool in (self.k_cache, self.v_cache) if pool.size),
+            layers, pages, page_size, width, str(k_pool.dtype),
+            pools=sum(1 for pool in (k_pool, v_pool) if pool.size),
             pad_lanes=width - mcfg.latent_width if mcfg.is_latent else 0,
-            token_bytes=(self.k_cache.nbytes + self.v_cache.nbytes)
+            token_bytes=(k_pool.nbytes + v_pool.nbytes)
             // (pages * page_size),
             index_width=mcfg.index_lanes,
         )
+        # Bytes a held page of each pool takes over its layers, K and V.
+        self._page_bytes = {"full": (k_pool.nbytes + v_pool.nbytes) // pages}
+        sliding_pool = None
+        if mcfg.kv_by_kind:
+            k_sl, v_sl = self.k_cache[1], self.v_cache[1]
+            self._page_bytes["sliding"] = (
+                (k_sl.nbytes + v_sl.nbytes) // k_sl.shape[1]
+            )
+            get_telemetry().set_kv_sliding_pool_info(
+                *k_sl.shape, str(k_sl.dtype), mcfg.sliding_window,
+                self._page_bytes["sliding"],
+            )
+            sliding_pool = SlidingPagePool(
+                k_sl.shape[1], cfg.page_size, mcfg.sliding_window
+            )
         get_telemetry().watch_jax_compiles()
 
         self.host_pool: HostKvPool | None = None
@@ -413,11 +443,16 @@ class TPUEngine(BlockDecode, AsyncEngine):
             event_cb=kv_event_cb if cfg.enable_kv_events else None,
             host_pool=self.host_pool,
             on_evict=on_evict,
-            sharing=cfg.prefix_sharing,
+            # A cache by kind grants no prefix hit: the sliding layers'
+            # keys of the ``width - 1`` positions before a boundary were
+            # given back, and without them a hit would serve wrong numbers
+            # (ROADMAP R2: a boundary held in both pools could be granted).
+            sharing=cfg.prefix_sharing and not mcfg.kv_by_kind,
             g3_store=self.g3_store,
             state_slots=state_slots,
             state_snapshots=state_snapshots,
             partial_tail=not mcfg.block_length,
+            sliding=sliding_pool,
         )
         # Observability (docs/observability.md): per-dispatch profiler
         # (host gap vs in-flight, compile attribution — pure timestamps
@@ -458,7 +493,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
             return k.at[:, pids].set(hk), v.at[:, pids].set(hv)
 
         def kv_cow_pages(k, v, src, dst):
-            return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
+            # a pool or, of a cache by kind, its pair (whose rows share no
+            # page, so that only the prewarm's copy of page 0 onto itself
+            # ever runs there)
+            return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), (k, v))
 
         def seq_state_copy(pool, src, dst):
             # every part of the state (``init_seq_state``'s pytree)
@@ -490,6 +528,13 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # for failover replay pins the seed request-side instead.
         self._seed_rng = random.Random(seed + 1)
         self._attn_impl, self._attn_interpret = self._resolve_attn()
+        # Attention layers by form, of a model that has sliding ones (what
+        # ``_count_attn_work`` counts by; empty for any other model).
+        forms = mcfg.attn_forms
+        self._attn_form_layers = {
+            form: forms.count(form) for form in ("sliding", "full")
+            if form in forms
+        } if "sliding" in forms else {}
         if mcfg.num_mamba_layers and self._attn_impl == "pallas" and (
             cfg.page_size % cfg.ragged_q_tile or cfg.prefill_chunk % cfg.ragged_q_tile
         ):
@@ -848,7 +893,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
                               packed, counts_all, state=None):
                 f = unpack(packed, carry_tokens, carry_positions)
                 tokens, positions = f["tokens"], f["positions"]
-                max_pos, page_table = f["max_pos"], f["table"]
+                max_pos = f["max_pos"]
+                page_table = split_tables(self.cfg, f["table"])
                 seeds, slot_map = f["seeds"], f["slot_map"]
                 temp, top_k, top_p = f["temp"], f["top_k"], f["top_p"]
                 freq_pen, pres_pen, rep_pen = f["freq"], f["pres"], f["rep"]
@@ -922,7 +968,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
                               packed, state=None):
                 f = unpack(packed, carry_tokens, carry_positions)
                 tokens, positions = f["tokens"], f["positions"]
-                max_pos, page_table = f["max_pos"], f["table"]
+                max_pos = f["max_pos"]
+                page_table = split_tables(self.cfg, f["table"])
                 stop_set, eos_gate = f["stop_set"], f["eos_gate"]
                 budget_gate = f["budget_gate"]
                 state_slots = f.get("state_slots")
@@ -1032,7 +1079,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
             def ragged_mixed(params, k, v, packed, counts_all, state=None):
                 f = unpack_launch(layout, packed)
                 tokens, positions = f["tokens"], f["positions"]
-                row_of, page_table = f["row_of"], f["table"]
+                row_of = f["row_of"]
+                page_table = split_tables(self.cfg, f["table"])
                 q_last, pos0 = f["q_last"], f["pos0"]
                 slot_map, is_decode = f["slot_map"], f["is_decode"]
                 seeds = f["seeds"]
@@ -1090,7 +1138,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
             def ragged_mixed(params, k, v, packed, state=None):
                 f = unpack_launch(layout, packed)
                 tokens, positions = f["tokens"], f["positions"]
-                row_of, page_table = f["row_of"], f["table"]
+                row_of = f["row_of"]
+                page_table = split_tables(self.cfg, f["table"])
                 q_last, spec_idx = f["q_last"], f.get("spec_idx")
                 spec_drafts, n_drafts = f.get("spec_drafts"), f.get("n_drafts")
                 state_rows = state_rows_of(f)
@@ -1444,6 +1493,54 @@ class TPUEngine(BlockDecode, AsyncEngine):
         tel.mla_attended_pairs.labels(program).inc(pairs)
         tel.mla_context_tokens.labels(program).inc(context)
 
+    def _count_attn_work(self, positions, steps=None, row_of=None) -> None:
+        """Of a model with sliding layers, the queries a dispatch feeds its
+        attention layers, the key positions those may see and the context
+        tokens a row's queries see between them, by the layers' form and
+        summed over the layers of each (``dynamo_attn_query_tokens_total``,
+        ``dynamo_attn_key_positions_total``,
+        ``dynamo_attn_context_tokens_total``): a query at position ``t``
+        sees ``t + 1`` keys in a full layer and ``min(t + 1, width)`` in a
+        sliding one; a row's queries of one dispatch see its context up to
+        their last, in a sliding layer from the first's ``width - 1``
+        predecessors on. The work the rooflines read, whatever computes it.
+        A mixed dispatch gives its stream's ``positions`` (-1: no query)
+        and ``row_of``; a window each row's first position and its
+        ``steps`` (a step reads anew). Host arithmetic on what the builder
+        holds; no other model counts."""
+        layers = self._attn_form_layers
+        if not layers:
+            return
+        t = np.asarray(positions, np.int64)  # dynlint: sync-point(host-list conversion)
+        width = self.cfg.model.sliding_window
+        if steps is not None:
+            n = np.asarray(steps, np.int64)  # dynlint: sync-point(host-list conversion)
+            t = np.concatenate(
+                [np.arange(a, a + k) for a, k in zip(t, n)] or [t[:0]]
+            )
+        live = t >= 0
+        seen = t[live] + 1
+        keys = {"full": seen, "sliding": np.minimum(seen, width)}
+        context = keys  # a window's step reads anew
+        if steps is None:
+            rows = np.asarray(row_of)[live]  # dynlint: sync-point(host-list conversion)
+            last = np.zeros(int(rows.max()) + 1 if len(rows) else 0, np.int64)
+            first = np.full(len(last), np.iinfo(np.int64).max)
+            np.maximum.at(last, rows, seen)
+            np.minimum.at(first, rows, seen)
+            last, first = last[last > 0], first[last > 0]
+            context = {
+                "full": last,
+                "sliding": last - np.maximum(first - width, 0),
+            }
+        tel = get_telemetry()
+        for form, count in layers.items():
+            tel.attn_query_tokens.labels(form).inc(count * len(seen))
+            tel.attn_key_positions.labels(form).inc(count * int(keys[form].sum()))
+            tel.attn_context_tokens.labels(form).inc(
+                count * int(context[form].sum())
+            )
+
     def _count_dispatch(
         self, program, nb, attn_pages, windowed, full_sampler, want_lp,
         with_spec, chained: bool, live: int, singles: int = 0,
@@ -1641,6 +1738,13 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 return
             self._thread = None
         self._inflight = None  # dynlint: thread-ownership(loop thread joined before teardown flush)
+        if self.kv.sliding is not None:
+            log.info(
+                "sliding page pool: at most %d of %d pages held at once, "
+                "%d given back behind the width",
+                self.kv.sliding.peak_held, self.kv.sliding.num_pages,
+                self.kv.sliding.released_total,
+            )
         # Final conservation audit (the loop thread is joined, so the
         # page ledger is quiescent): any violation the in-loop check
         # missed — or one that appeared in the teardown path itself —
@@ -2703,6 +2807,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self._last_gauge_pub = now
             tel = get_telemetry()
             tel.publish_engine_gauges(self.metrics())
+            if self.kv.sliding is not None:
+                self._publish_pools_by_kind(tel)
             if self.host_pool is not None:
                 # G2 tier occupancy (docs/engine_perf.md "Predictive KV
                 # tiering"): host-tier pressure is fleet-visible.
@@ -2823,6 +2929,29 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self._ledger_dumped = True
             self._dump_flight("kv_ledger")
 
+    def _publish_pools_by_kind(self, tel) -> None:
+        """A cache by kind: each pool's share in use and bytes held, and
+        the context tokens of the bound rows that those bytes hold
+        (``dynamo_kv_pool_usage{pool}``, ``dynamo_kv_pool_held_bytes{pool}``,
+        ``dynamo_kv_context_tokens``): held bytes over context tokens is
+        what a token of context costs, which a cache blind to the layers'
+        form pays at every layer; and the most the sliding pool has held at
+        once (``dynamo_kv_sliding_pool_peak_pages``)."""
+        held = {
+            "full": self.kv.active_pages, "sliding": self.kv.sliding.held,
+        }
+        usage = {"full": self.kv.usage, "sliding": self.kv.sliding.usage}
+        for pool, pages in held.items():
+            tel.kv_pool_usage.labels(pool).set(usage[pool])
+            tel.kv_pool_held_bytes.labels(pool).set(
+                pages * self._page_bytes[pool]
+            )
+        tel.kv_sliding_pool_peak_pages.set(self.kv.sliding.peak_held)
+        tel.kv_context_tokens.set(sum(
+            max(len(s.tokens), s.prefill_sent)
+            for s in self.sched.slots if s is not None
+        ))
+
     def kv_audit(self) -> dict:
         """Full on-demand conservation audit: every page classified into
         exactly one of {free, parked, active, leased, shared@ref>=2},
@@ -2832,13 +2961,18 @@ class TPUEngine(BlockDecode, AsyncEngine):
         flight snapshot (``llmctl audit <dump>`` renders it) and the
         stop()-time final check."""
         holders: dict[str, list[int]] = {}
+        sliding: dict[str, list[int]] = {}
         for s in self.sched.slots:
             if s is not None and s.page_ids:
                 holders[f"seq:{s.request_id}"] = list(s.page_ids)
+            if s is not None and s.sliding_ids:
+                sliding[f"seq:{s.request_id}"] = s.sliding_ids[s.sliding_first:]
         for s in self.sched.waiting:
             if getattr(s, "page_ids", None):
                 holders[f"seq:{s.request_id}"] = list(s.page_ids)
-        report = self.kv.audit(holders)
+        report = self.kv.audit(
+            holders, sliding if self.kv.sliding is not None else None
+        )
         if self.g3_store is not None:
             # G3 tier joins the audit: its own conservation ledger
             # (resident == adopted + stores - evictions - quarantined)
@@ -4061,7 +4195,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 tokens[r] = seq.last_token()
                 positions[r] = wpos
                 max_pos[r] = cap
-                table[r, : len(seq.page_ids)] = seq.page_ids
+                self._table_row(table, r, seq)
                 slot_map[r] = seq.slot
                 state_slots[r] = seq.state_slot
                 max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
@@ -4086,6 +4220,16 @@ class TPUEngine(BlockDecode, AsyncEngine):
             capacity_capped=capacity_capped, routed_rows=fed, rows=len(part),
         )
 
+    def _table_row(self, table, r: int, seq: Sequence) -> None:
+        """Row ``r`` of a launch's page table: the sequence's pages and,
+        where the cache is by kind, behind them its pages of the sliding
+        pool (``aot/launch.py::split_tables``; 0 where one went back
+        behind the width: no read reaches there)."""
+        table[r, : len(seq.page_ids)] = seq.page_ids
+        if seq.sliding_ids:
+            at = self.cfg.max_pages_per_seq
+            table[r, at : at + len(seq.sliding_ids)] = seq.sliding_ids
+
     def _window_steps(self, rows: list, first_pos, budget_gate) -> int:
         """The tokens a window's rows really feed: a row's valid steps, cut
         where its ``max_tokens`` runs out (``_stop_gates``: the device
@@ -4094,9 +4238,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         (``_count_latent_reads``) from each row's first position in
         ``first_pos`` (indexed by row) and returns the steps' sum."""
         steps = [min(e.n_valid, int(budget_gate[e.row]) + 1) for e in rows]
-        self._count_latent_reads(
-            "window", [first_pos[e.row] for e in rows], steps=steps
-        )
+        firsts = [first_pos[e.row] for e in rows]
+        self._count_latent_reads("window", firsts, steps=steps)
+        self._count_attn_work(firsts, steps)
         return sum(steps)
 
     def _build_mixed(
@@ -4188,7 +4332,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 )
                 seq.prefill_sent = start_tok + n
                 chunks.append((seq, start_tok, n))
-                table[r, : len(seq.page_ids)] = seq.page_ids
+                self.sched.ensure_sliding_until(seq, start_tok + n - 1)
+                self._table_row(table, r, seq)
                 q_last[r] = qs + n - 1
                 if seq.state_slot >= 0:
                     state_slots[r] = state_dst[r] = seq.state_slot
@@ -4232,7 +4377,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             # their slots side by side once the spans' extent is known.
             dec_rows = np.arange(r, r + len(dec))
             for seq, wpos, _cap in dec:
-                table[r, : len(seq.page_ids)] = seq.page_ids
+                self._table_row(table, r, seq)
                 if seq.state_slot >= 0:
                     state_slots[r] = state_dst[r] = seq.state_slot
                 pos0[r] = wpos
@@ -4249,7 +4394,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                     list(range(wpos, wpos + g + 1)),
                     r,
                 )
-                table[r, : len(seq.page_ids)] = seq.page_ids
+                self._table_row(table, r, seq)
                 q_last[r] = qs + g
                 slot_map[r] = seq.slot
                 spec_idx[r, : g + 1] = qs + np.arange(g + 1)
@@ -4290,6 +4435,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self._count_latent_reads(
             "mixed", positions, row_of, tiled=nb - cfg.ragged_packed_slots(nb)
         )
+        self._count_attn_work(positions, row_of=row_of)
         self._note_prefill_chunks(pf, chunks, nb)
         self.steps += 1
         if spec:
@@ -4419,7 +4565,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                     return None  # pool dry: consume + rebuild instead
                 capacity_capped = capacity_capped or cap < wpos + K
                 max_pos[r] = cap
-                table[r, : len(seq.page_ids)] = seq.page_ids
+                self._table_row(table, r, seq)
                 max_pages = max(max_pages, (min(wpos + K, cap + 1) + ps - 1) // ps)
                 eos_gate[r], budget_gate[r] = self._stop_gates(
                     seq, seq.generated + K
@@ -4483,6 +4629,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             between()
         t1 = clock()
         self.sched.register_full_pages(seq)
+        self._release_sliding_behind(seq)
         t2 = clock()
         n_top = self._wants_logprobs(seq)
         pack = None
@@ -4501,6 +4648,20 @@ class TPUEngine(BlockDecode, AsyncEngine):
         parts["pages"] += t2 - t1
         parts["emit"] += t3 - t2
         return len(kept), reason
+
+    def _release_sliding_behind(self, seq: Sequence) -> None:
+        """A cache by kind, in the consume phase's ``pages`` part: the
+        row's sliding pages no later query of it can see go back to their
+        pool (``Scheduler.release_sliding_behind``), counted."""
+        pool = self.kv.sliding
+        if pool is None or seq.state is SeqState.FINISHED:
+            return
+        before = pool.released_total
+        self.sched.release_sliding_behind(seq)
+        if pool.released_total > before:
+            get_telemetry().kv_sliding_pages_released.inc(
+                pool.released_total - before
+            )
 
     def _finish_deferred(self, finishes: list[Sequence]) -> None:
         """Finish the rows a window's or a block's consume found stopped
@@ -4614,6 +4775,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 seq, r = e.seq, e.row
                 if e.kind == "prefill":
                     if not e.completing:
+                        if seq.sliding_ids:  # a cache by kind
+                            t0 = clock()
+                            self._release_sliding_behind(seq)
+                            parts["pages"] += clock() - t0
                         continue
                     t0 = clock()
                     n_top = self._wants_logprobs(seq)
@@ -4625,6 +4790,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
                     self._finish_first_token(
                         seq, int(tok0[r]), pack, counted=e.counted
                     )
+                    self._release_sliding_behind(seq)
                     parts["first_token"] += clock() - t0
                     continue
                 if seq.state is not SeqState.ACTIVE or seq.pending_finish is not None:

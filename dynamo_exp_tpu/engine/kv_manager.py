@@ -136,6 +136,120 @@ class KvEvent:
     ts: float = field(default_factory=time.time)
 
 
+class SlidingPagePool:
+    """The sliding layers' page pool of a cache by kind
+    (``ModelConfig.kv_by_kind``), by id: a page is free or held by ONE row,
+    never shared, registered or parked, because a sliding layer's keys say
+    nothing without their ``width - 1`` predecessors and are of no use to
+    another request's prefix.
+
+    A row's pages are a list indexed by page-in-sequence
+    (``Sequence.sliding_ids``) of which the entries before
+    ``Sequence.sliding_first`` have been given back (0 stands there: the
+    kernel's walk starts behind them and the gather masks them):
+
+    - :meth:`extend` takes pages until the list covers a position, before
+      the dispatch that writes it;
+    - :meth:`release_behind`, once a dispatch is consumed, gives back the
+      pages that lie wholly before ``next_pos - (width - 1)``: the row's
+      next query stands at ``next_pos`` or later and sees no key before
+      that (``t - width < s``), so no query of the row can see them again.
+      A window chained onto the consumed one is already in flight, and its
+      queries stand later still;
+    - :meth:`release` gives back all a row holds (finish, preemption).
+
+    So a row holds the pages of ``[next_pos - (width - 1), last position
+    its dispatch in flight writes]``, and the pool is sized from the
+    dispatch, not from the context (``EngineConfig.sliding_pool_pages``):
+    it cannot run dry, and :meth:`extend` raises if it does. Single-writer
+    as the manager that owns it: the engine loop's thread."""
+
+    def __init__(self, num_pages: int, page_size: int, width: int):
+        self.num_pages = num_pages
+        self.page_size = page_size
+        self.width = width
+        self._free: list[int] = list(range(num_pages - 1, -1, -1))
+        self.held = 0
+        self.peak_held = 0
+        self.released_total = 0  # pages given back behind the width
+
+    @property
+    def usage(self) -> float:
+        return self.held / max(self.num_pages, 1)
+
+    def extend(self, ids: list[int], position: int) -> None:
+        """Take pages until ``ids`` covers ``position``."""
+        need = position // self.page_size + 1
+        while len(ids) < need:
+            if not self._free:
+                raise RuntimeError(
+                    f"the sliding page pool ({self.num_pages} pages) ran dry: "
+                    "it is sized from the largest dispatch "
+                    "(EngineConfig.sliding_pool_pages), so this is a fault "
+                    "of that arithmetic or a row that kept pages it cannot see"
+                )
+            ids.append(self._free.pop())
+            self.held += 1
+        self.peak_held = max(self.peak_held, self.held)
+
+    def release_behind(self, ids: list[int], first: int, next_pos: int) -> int:
+        """Give back ``ids[first:keep]``, the held pages wholly before the
+        first position a query at ``next_pos`` sees; returns ``keep``, the
+        row's new first held page."""
+        keep = min(max(next_pos - (self.width - 1), 0) // self.page_size, len(ids))
+        for j in range(first, keep):
+            self._free.append(ids[j])
+            ids[j] = 0
+        if keep > first:
+            self.held -= keep - first
+            self.released_total += keep - first
+        return max(first, keep)
+
+    def release(self, ids: list[int], first: int) -> None:
+        """Give back every page a row still holds."""
+        self._free.extend(ids[first:])
+        self.held -= len(ids) - first
+        del ids[:]
+
+    def ledger_check(self) -> list[str]:
+        if len(self._free) + self.held != self.num_pages or self.held < 0:
+            return [
+                f"sliding-page conservation broken: free={len(self._free)} "
+                f"held={self.held} != pool={self.num_pages}"
+            ]
+        return []
+
+    def audit(self, holders: dict[str, Sequence[int]]) -> list[dict]:
+        """Full scan: every page is free or held by exactly one of
+        ``holders`` (name -> the page ids it believes it holds)."""
+        owner: dict[int, str] = {}
+        violations: list[dict] = []
+
+        def flag(pid, kind, detail, names):
+            violations.append({
+                "page": pid, "kind": kind, "detail": detail,
+                "holders": sorted(names), "pool": "sliding",
+            })
+
+        for name, pids in holders.items():
+            for pid in pids:
+                if pid in owner:
+                    flag(pid, "shared_sliding_page",
+                         "a sliding page has one holder", [owner[pid], name])
+                owner[pid] = name
+        free = set(self._free)
+        if len(free) != len(self._free):
+            flag(None, "double_release", "a page appears twice in the free list", [])
+        for pid in range(self.num_pages):
+            if (pid in free) == (pid in owner):
+                flag(
+                    pid, "state_overlap" if pid in free else "leaked_ref",
+                    "free and held" if pid in free else "neither free nor held",
+                    [owner[pid]] if pid in owner else [],
+                )
+        return violations
+
+
 class KvPageManager:
     """Tracks ownership and reuse of the device page pool by id.
 
@@ -156,9 +270,19 @@ class KvPageManager:
         state_slots: int = 0,
         state_snapshots: int = 0,
         partial_tail: bool = True,
+        sliding: "SlidingPagePool | None" = None,
     ):
         self.num_pages = num_pages
         self.page_size = page_size
+        # The second pool of a cache by kind (None: one pool): pages of the
+        # sliding layers, private to a row and given back behind the width.
+        # Everything else here (sharing, leases, tiers, ``usage``) is of
+        # the full layers' pool, whose pages hold a context for as long as
+        # the row lives; the engine builds such a model's manager with
+        # ``sharing=False`` (a prefix hit would need the sliding layers'
+        # keys of the ``width - 1`` positions before the boundary, which
+        # were given back).
+        self.sliding = sliding
         # Whether a prompt that ends inside a block another sequence
         # registered attaches that page read-shared (``allocate_sequence``).
         # Off for a model that generates by diffusion over blocks: its
@@ -856,15 +980,22 @@ class KvPageManager:
                 f"lease pins exceed refcount total: lease_pins="
                 f"{lease_pins} ref_total={self._ref_total}"
             )
+        if self.sliding is not None:
+            violations.extend(self.sliding.ledger_check())
         return violations
 
-    def audit(self, holders: dict[str, Sequence[int]] | None = None) -> dict:
+    def audit(
+        self, holders: dict[str, Sequence[int]] | None = None,
+        sliding_holders: dict[str, Sequence[int]] | None = None,
+    ) -> dict:
         """Full on-demand conservation audit (``llmctl audit``): scan
         the pool, classify every page into exactly one state, and — when
         ``holders`` maps holder names (``seq:<request_id>``) to the page
         ids they believe they hold — cross-check per-page refcounts
         against the holder set so a leak is *named*, not just counted.
         Leases are joined in automatically as ``lease:<id>`` holders.
+        ``sliding_holders`` (a cache by kind): the same for the sliding
+        pool's pages, each of which has one holder or is free.
         Read-only; safe (best-effort) from a non-loop thread for flight
         dumps."""
         free_list = list(self._free)
@@ -935,6 +1066,8 @@ class KvPageManager:
                 {"page": None, "kind": "counter", "detail": check,
                  "holders": []}
             )
+        if self.sliding is not None and sliding_holders is not None:
+            violations.extend(self.sliding.audit(sliding_holders))
         return {
             "ok": not violations,
             "counts": counts,

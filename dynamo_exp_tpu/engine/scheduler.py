@@ -83,6 +83,12 @@ class Sequence:
     state_slot: int = -1
     state_restore: int | None = None
     page_ids: list[int] = field(default_factory=list)
+    # A cache by kind (``ModelConfig.kv_by_kind``): the row's pages of the
+    # sliding layers' pool by page-in-sequence, taken a dispatch at a time,
+    # and the first of them still held: those before it went back behind
+    # the width (``kv_manager.SlidingPagePool``). Empty for any other model.
+    sliding_ids: list[int] = field(default_factory=list)
+    sliding_first: int = 0
     cached_len: int = 0  # prefix reused from the page pool
     tokens: list[int] = field(default_factory=list)  # prompt + confirmed
     generated: int = 0
@@ -548,9 +554,39 @@ class Scheduler:
         while len(seq.page_ids) < need:
             pid = self.kv.allocate_page()
             if pid is None:
-                return False
+                break
             seq.page_ids.append(pid)
-        return True
+        # a cache by kind: the sliding pool's pages as far as the full one's
+        self.ensure_sliding_until(seq, len(seq.page_ids) * ps - 1)
+        return len(seq.page_ids) >= need
+
+    def ensure_sliding_until(self, seq: Sequence, position: int) -> None:
+        """A cache by kind: the row's sliding pages cover ``position``
+        (inclusive) before the dispatch that writes up to it. The pool is
+        sized so that this cannot fail (``SlidingPagePool``)."""
+        if self.kv.sliding is not None and position >= 0:
+            self.kv.sliding.extend(seq.sliding_ids, position)
+
+    def release_sliding_behind(self, seq: Sequence) -> None:
+        """A cache by kind, after a dispatch of the row is consumed: its
+        sliding pages that no later query of it can see go back. The next
+        query stands at the next prompt token to be fed while the row
+        prefills, and at the newest token (the next one fed) once it
+        decodes."""
+        if self.kv.sliding is None or not seq.sliding_ids:
+            return
+        next_pos = (
+            seq.prefill_sent if seq.state is SeqState.PREFILL
+            else len(seq.tokens) - 1
+        )
+        seq.sliding_first = self.kv.sliding.release_behind(
+            seq.sliding_ids, seq.sliding_first, next_pos
+        )
+
+    def _release_sliding(self, seq: Sequence) -> None:
+        if self.kv.sliding is not None:
+            self.kv.sliding.release(seq.sliding_ids, seq.sliding_first)
+            seq.sliding_first = 0
 
     def register_full_pages(self, seq: Sequence) -> None:
         """Register every newly completed page for reuse + router events.
@@ -632,6 +668,7 @@ class Scheduler:
         # unfilled page unregisters instead of parking as matchable).
         self.kv.abort_fills(seq.request_id, seq.page_ids)
         self.kv.release_sequence(seq.page_ids)
+        self._release_sliding(seq)
         seq.emit([], reason)
 
     @staticmethod
@@ -702,6 +739,7 @@ class Scheduler:
             self._release_state(seq)
         self.kv.abort_fills(seq.request_id, seq.page_ids)
         self.kv.release_sequence(seq.page_ids)
+        self._release_sliding(seq)
         seq.page_ids = []
         stop = seq.stop.model_copy(deep=True)
         sc = stop.stop_conditions

@@ -198,6 +198,9 @@ class ModelConfig:
     # What a sigmoid router adds to the sum it renormalises by (lfm2_moe
     # 1e-6, pangu_ultra_moe 1e-20).
     router_norm_eps: float = 1e-6
+    # exaone_moe: only the sliding layers turn q and k by their positions;
+    # a full-attention layer has no positional term (``attn_forms``).
+    rope_sliding_only: bool = False
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -209,11 +212,66 @@ class ModelConfig:
     def op_kinds(self) -> tuple[str, ...]:
         """Each layer's operator: "conv" (gated short convolution),
         "mamba" (selective scan), "linear" (linear attention by the gated
-        delta rule) or "attn" (every softmax attention form, windowed or
-        full)."""
+        delta rule) or "attn" (every softmax attention form, sliding or
+        full: ``attn_forms``)."""
         types = self.layer_types or ("full_attention",) * self.num_layers
         names = {"conv": "conv", "mamba": "mamba", "linear_attention": "linear"}
         return tuple(names.get(t, "attn") for t in types)
+
+    @property
+    def attn_forms(self) -> tuple[str, ...]:
+        """Each layer's form of softmax attention: "sliding" (a query sees
+        the ``sliding_window`` newest keys, its own among them), "full", or
+        "" where the operator is not attention. gemma2 alternates
+        (``alt_sliding_window``), a model with ``layer_types`` follows
+        them, a window without either (mistral) is every layer's."""
+        if self.sliding_window is None:
+            slides = [False] * self.num_layers
+        elif self.layer_types:
+            slides = [t == "sliding_attention" for t in self.layer_types]
+        else:
+            slides = [
+                not self.alt_sliding_window or i % 2 == 0
+                for i in range(self.num_layers)
+            ]
+        return tuple(
+            ("sliding" if s else "full") if op == "attn" else ""
+            for s, op in zip(slides, self.op_kinds)
+        )
+
+    @property
+    def attn_kernel_form(self) -> bool:
+        """Whether the attention kernel (``ops/ragged_attention.py``)
+        computes this model's attention: it has neither a tanh cap on the
+        scores nor a query pre-scale (gemma2, gemma3: the XLA gather's),
+        and a sliding width, where there is one, is one width a run of
+        layers (``layer_runs``), not one that alternates inside a run
+        (gemma2). The one predicate ``engine.resolve_attn_impl`` and both
+        paged forwards ask."""
+        return (
+            self.attn_logit_softcap is None
+            and self.query_pre_attn_scalar is None
+            and not (self.sliding_window is not None and self.alt_sliding_window)
+        )
+
+    @property
+    def kv_by_kind(self) -> bool:
+        """Whether the paged cache is two pools by the layers' form, each
+        with its own pages and page table a row (``models/llama.py::
+        init_kv_cache``): sliding AND full attention layers named by
+        ``layer_types`` in a model the kernel serves. A sliding layer's
+        pages are given back behind the window
+        (``engine/kv_manager.py::SlidingPagePool``), so its pool is sized
+        by the dispatch and not by the context. Every other model keeps
+        one pool and one table."""
+        forms = set(self.attn_forms) - {""}
+        return bool(self.layer_types) and forms == {"sliding", "full"} and (
+            self.attn_kernel_form
+        )
+
+    @property
+    def num_sliding_layers(self) -> int:
+        return self.attn_forms.count("sliding")
 
     @property
     def ff_kinds(self) -> tuple[str, ...]:
@@ -230,11 +288,15 @@ class ModelConfig:
         ordinal among layers of its operator kind, and among layers of its
         feed-forward kind). Parameters are stacked by kind
         (``models/llama.py::init_params``) and the paged layer loop is one
-        ``lax.scan`` a run, so a model of one kind is one run."""
+        ``lax.scan`` a run, so a model of one kind is one run. Where the
+        cache is by kind (``kv_by_kind``) a run also ends where attention's
+        form changes (``attn_forms``: the form is not in the tuple), so a
+        run reads one pool at one static width."""
         runs: list[tuple[str, str, int, int, int, int]] = []
         seen = dict.fromkeys(("conv", "mamba", "linear", "attn", "dense", "routed"), 0)
+        forms = self.attn_forms if self.kv_by_kind else ("",) * self.num_layers
         for i, kind in enumerate(zip(self.op_kinds, self.ff_kinds)):
-            if runs and runs[-1][:2] == kind:
+            if runs and runs[-1][:2] == kind and forms[i] == forms[i - 1]:
                 runs[-1] = (*runs[-1][:3], runs[-1][3] + 1, *runs[-1][4:])
             else:
                 runs.append((*kind, i, 1, seen[kind[0]], seen[kind[1]]))
@@ -374,6 +436,8 @@ class ModelConfig:
             return cls._from_pangu_ultra_moe(cfg)
         if model_type == "glm_moe_dsa":
             return cls._from_glm_moe_dsa(cfg)
+        if model_type == "exaone_moe":
+            return cls._from_exaone_moe(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -734,6 +798,98 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_exaone_moe(cls, cfg: dict) -> "ModelConfig":
+        """``exaone_moe`` (K-EXAONE-236B-A23B): grouped-query attention with
+        a per-head RMS norm on q and k; layer ``i`` is what ``layer_types[i]``
+        says, "sliding_attention" (a query sees the ``sliding_window``
+        newest keys, q and k turned by their positions) or "full_attention"
+        (the whole context and NO positional term: ``rope_sliding_only``);
+        two norms a layer, before each half; ``first_k_dense_replace``
+        leading dense SwiGLUs (``mlp_layer_types`` has to say the same),
+        then ``num_experts`` experts chosen top-k by sigmoid scores plus a
+        selection bias, the chosen unbiased scores renormalised and times
+        ``routed_scaling_factor``, beside ``num_shared_experts`` ungated
+        shared experts. ``num_experts`` is what this device HOLDS: where the
+        file also gives ``num_experts_global`` (an expert-parallel rank's
+        share: ``benchmark/configs/k-exaone-*``) the router is that wide and
+        the held ids start at ``first_routed_expert``. Refused, because
+        nothing here computes it: the multi-token-prediction module,
+        group-limited selection, a scaled rotary base, a bias on attention's
+        projections, another activation than silu, another scoring than
+        sigmoid."""
+        name = "exaone_moe"
+        rope = cfg.get("rope_parameters") or {}
+        if cfg.get("rope_scaling") or rope.get("rope_type", "default") != "default":
+            raise ValueError(f"{name} with a rope_type other than default is not supported")
+        if str(cfg.get("scoring_func", "sigmoid")) != "sigmoid":
+            raise ValueError(f"{name} with scoring_func other than sigmoid is not supported")
+        _refuse_unserved_moe(cfg, name)
+        n_layers = cfg["num_hidden_layers"]
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != n_layers or not set(layer_types) <= {
+            "sliding_attention", "full_attention"
+        }:
+            raise ValueError(
+                f"{name} layer_types must name {n_layers} layers as "
+                f"'sliding_attention' or 'full_attention', got {layer_types!r}"
+            )
+        width = cfg.get("sliding_window")
+        slides = "sliding_attention" in layer_types
+        if slides and int(width or 0) < 1:
+            raise ValueError(f"{name} with sliding layers needs sliding_window >= 1")
+        widths = cfg.get("sliding_windows")
+        if widths is not None and list(widths) != [
+            width if t == "sliding_attention" else 0 for t in layer_types
+        ]:
+            raise ValueError(
+                f"{name} sliding_windows {list(widths)!r} disagrees with "
+                f"layer_types x sliding_window {width}"
+            )
+        dense = int(cfg.get("first_k_dense_replace", 0))
+        ff = cfg.get("mlp_layer_types")
+        if ff is not None and list(ff) != (
+            ["dense"] * dense + ["sparse"] * (n_layers - dense)
+        ):
+            raise ValueError(
+                f"{name} mlp_layer_types must be first_k_dense_replace={dense} "
+                f"'dense' layers then 'sparse' ones, got {list(ff)!r}"
+            )
+        held, routed, first = _expert_share(
+            cfg, name, "num_experts", "num_experts_global"
+        )
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rope_theta=float(rope.get("rope_theta") or cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 262144),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            qk_norm=True,
+            layer_types=layer_types,
+            sliding_window=int(width) if slides else None,
+            rope_sliding_only=True,
+            num_experts=routed,
+            num_experts_held=held if held < routed else 0,
+            first_expert_held=first,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            num_shared_experts=cfg.get("num_shared_experts", 0) or 0,
+            num_dense_layers=dense,
+            router_score="sigmoid",
+            use_expert_bias=True,
+            router_norm_eps=1e-20,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type=name,
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -752,17 +908,12 @@ class ModelConfig:
 # ``sdar_moe`` is ``qwen3_moe``'s stack, block for block, generating by
 # diffusion over blocks (``_block_generation``).
 
-def _latent_moe_share(cfg: dict, name: str, widths: tuple[str, ...] = ()) -> tuple[int, int, int]:
-    """What the readers of DeepSeek-V3's lineage (latent attention, sigmoid
-    routed experts beside shared ones: ``pangu_ultra_moe``, ``glm_moe_dsa``)
-    refuse alike, because nothing here computes it (the
+def _refuse_unserved_moe(cfg: dict, name: str) -> None:
+    """What the readers of DeepSeek-V3's lineage of routed models (sigmoid
+    routed experts beside shared ones: ``pangu_ultra_moe``, ``glm_moe_dsa``,
+    ``exaone_moe``) refuse alike, because nothing here computes it: the
     multi-token-prediction module, another activation than silu, a bias on
-    attention's projections, group-limited selection, a missing or odd
-    latent width or one of ``widths``), and the share of the routed experts
-    the file describes: (held, the router's width, the first held id).
-    ``n_routed_experts`` is what this device holds; where the file also
-    gives ``n_routed_experts_global`` (an expert-parallel rank's share) the
-    router is that wide and the held ids start at ``first_routed_expert``."""
+    attention's projections, group-limited selection."""
     if cfg.get("num_nextn_predict_layers", 0):
         raise ValueError(
             f"{name} with num_nextn_predict_layers > 0 is not supported: "
@@ -777,14 +928,16 @@ def _latent_moe_share(cfg: dict, name: str, widths: tuple[str, ...] = ()) -> tup
             f"{name} with group-limited selection (n_group / topk_group > 1) "
             "is not supported"
         )
-    for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                "qk_rope_head_dim", "v_head_dim", *widths):
-        if int(cfg.get(key) or 0) <= 0:
-            raise ValueError(f"{name} needs {key} > 0")
-    if cfg["qk_rope_head_dim"] % 2:
-        raise ValueError(f"{name} needs an even qk_rope_head_dim")
-    held = cfg["n_routed_experts"]
-    routed = cfg.get("n_routed_experts_global", held)
+
+
+def _expert_share(cfg: dict, name: str, held_key: str, global_key: str) -> tuple[int, int, int]:
+    """The share of the routed experts a file describes: (held, the
+    router's width, the first held id). ``held_key`` counts what this
+    device holds; where the file also gives ``global_key`` (an
+    expert-parallel rank's share) the router is that wide and the held ids
+    start at ``first_routed_expert``."""
+    held = cfg[held_key]
+    routed = cfg.get(global_key, held)
     first = cfg.get("first_routed_expert", 0)
     if not 0 <= first <= routed - held:
         raise ValueError(
@@ -796,10 +949,26 @@ def _latent_moe_share(cfg: dict, name: str, widths: tuple[str, ...] = ()) -> tup
     return held, routed, first
 
 
+def _latent_moe_share(cfg: dict, name: str, widths: tuple[str, ...] = ()) -> tuple[int, int, int]:
+    """What the latent readers (``pangu_ultra_moe``, ``glm_moe_dsa``) refuse
+    beyond :func:`_refuse_unserved_moe` (a missing or odd latent width or
+    one of ``widths``), and their share of the routed experts
+    (:func:`_expert_share` under their keys, ``n_routed_experts`` /
+    ``n_routed_experts_global``)."""
+    _refuse_unserved_moe(cfg, name)
+    for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", *widths):
+        if int(cfg.get(key) or 0) <= 0:
+            raise ValueError(f"{name} needs {key} > 0")
+    if cfg["qk_rope_head_dim"] % 2:
+        raise ValueError(f"{name} needs an even qk_rope_head_dim")
+    return _expert_share(cfg, name, "n_routed_experts", "n_routed_experts_global")
+
+
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
-    "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa",
+    "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -1146,6 +1315,42 @@ TINY_GLM_DSA = ModelConfig(  # glm_moe_dsa family shape: latent attention
     model_type="glm_moe_dsa",
 )
 
+TINY_EXAONE_MOE = ModelConfig(  # exaone_moe family shape: sliding and full
+    # attention layers 3 : 1 and a sliding layer more (runs S-dense / S S /
+    # F / S), a width of 8 that is two pages of 4, rotary on the sliding
+    # layers alone; one dense layer then four routed ones, 4 of 8 experts
+    # held (ids 4..7), chosen by sigmoid scores plus a selection bias,
+    # beside one ungated shared expert; two norms a layer
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=5,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    rope_theta=1000000.0,
+    max_position_embeddings=512,
+    qk_norm=True,
+    layer_types=(
+        "sliding_attention", "sliding_attention", "sliding_attention",
+        "full_attention", "sliding_attention",
+    ),
+    sliding_window=8,
+    rope_sliding_only=True,
+    num_experts=8,
+    num_experts_held=4,
+    first_expert_held=4,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    num_shared_experts=1,
+    num_dense_layers=1,
+    router_score="sigmoid",
+    use_expert_bias=True,
+    router_norm_eps=1e-20,
+    routed_scaling_factor=2.5,
+    model_type="exaone_moe",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -1170,6 +1375,7 @@ PRESETS = {
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "tiny-pangu": TINY_PANGU,
     "tiny-glm-dsa": TINY_GLM_DSA,
+    "tiny-exaone-moe": TINY_EXAONE_MOE,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

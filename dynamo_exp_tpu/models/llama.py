@@ -435,7 +435,7 @@ def kv_cache_shardings(tp_axis: str = "tp") -> tuple[P, P]:
 
 def init_kv_cache(
     cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
-    shardings=(None, None),
+    shardings=(None, None), sliding_pages: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Allocate the paged KV pools: each [L, num_pages, ps, Hkv*D], L
     the layers that write K and V (``cfg.num_attn_layers``: all of them
@@ -455,13 +455,44 @@ def init_kv_cache(
     returned first; second comes an array of the same leading shape and
     **no lanes** (0 bytes), which only keeps the programs' two-pool
     signatures: nothing writes or reads it (``ModelConfig.kv_pool_widths``).
+
+    A model whose cache is **by kind** (``ModelConfig.kv_by_kind``: sliding
+    and full attention layers in one stack) has each of K and V as a PAIR
+    ``(full, sliding)``: ``[L_full, num_pages, ps, Hkv*D]`` and
+    ``[L_sliding, sliding_pages, ps, Hkv*D]`` (``sliding_pages`` defaults to
+    ``num_pages``). The two have page ids, page tables and free lists of
+    their own (``engine/kv_manager.py``), and every paged forward takes its
+    ``page_table`` as the same kind of pair: a sliding layer's pages are
+    given back behind its width, so its pool is sized by the dispatch and
+    the full layers' by the context.
     """
     dt = dtype or _dtype(cfg)
-    lead = (cfg.num_attn_layers, num_pages, page_size)
-    return tuple(
-        jnp.zeros((*lead, width), dt, device=sharding)
-        for width, sharding in zip(cfg.kv_pool_widths, shardings)
-    )
+
+    def pools(layers, pages):
+        lead = (layers, pages, page_size)
+        return tuple(
+            jnp.zeros((*lead, width), dt, device=sharding)
+            for width, sharding in zip(cfg.kv_pool_widths, shardings)
+        )
+
+    if not cfg.kv_by_kind:
+        return pools(cfg.num_attn_layers, num_pages)
+    n_sliding = cfg.num_sliding_layers
+    full = pools(cfg.num_attn_layers - n_sliding, num_pages)
+    sliding = pools(n_sliding, num_pages if sliding_pages is None else sliding_pages)
+    return (full[0], sliding[0]), (full[1], sliding[1])
+
+
+def _by_kind(cfg: ModelConfig, one) -> tuple:
+    """``one`` as the pair by kind a paged forward works on: itself where
+    the cache is by kind, else ``(one, one)`` (the one pool, table or write
+    both forms share)."""
+    return tuple(one) if cfg.kv_by_kind else (one, one)
+
+
+def kv_page_size(k_cache) -> int:
+    """Tokens a page of the pool(s) ``k_cache`` holds."""
+    return (k_cache[0] if isinstance(k_cache, tuple) else k_cache).shape[2]
 
 
 class MambaState(NamedTuple):
@@ -600,9 +631,11 @@ def _attn_mlp_layer(
     return x, kv_extra
 
 
-def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red):
+def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
     """A layer's first half where its operator is attention: norm,
-    projections, rope, ``attend``, output projection, residual add."""
+    projections, rope, ``attend``, output projection, residual add.
+    ``rope`` False: this layer has no positional term though the model's
+    other layers do (exaone_moe's full-attention layers)."""
     B, T = x.shape[:2]
     hd = cfg.head_dim_
     off = cfg.rms_norm_offset
@@ -623,7 +656,7 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red):
             # (gemma3 stores these gemma-style: scale = 1 + w)
             q = rms_norm(q, lp["q_norm"], eps, off)
             k = rms_norm(k, lp["k_norm"], eps, off)
-        if cfg.use_rope:  # jamba's attention has no positional term
+        if cfg.use_rope and rope:  # jamba's attention has no positional term
             q = apply_rope(q, rope_pos, inv_freq)
             k = apply_rope(k, rope_pos, inv_freq)
     attn, kv_extra = attend(q, k, v)
@@ -1329,15 +1362,14 @@ def _per_layer_xs(cfg, inv_freq):
     The KV pools are NOT here: they travel in the carry, and a routed
     model's expert matrices are closed over (module docstring)."""
     L = cfg.num_layers
-    have_window = cfg.sliding_window is not None
     if cfg.layer_types:
         sliding = [t == "sliding_attention" for t in cfg.layer_types]
     else:
         sliding = [not cfg.alt_sliding_window or i % 2 == 0 for i in range(L)]
     win_arr = jnp.asarray(
         [
-            cfg.sliding_window if (have_window and sliding[i]) else 1 << 30
-            for i in range(L)
+            cfg.sliding_window if form == "sliding" else 1 << 30
+            for form in cfg.attn_forms
         ],
         jnp.int32,
     )
@@ -1392,6 +1424,16 @@ def _scan_paged_layers(
     ``_mla_half`` writes a token's row and ``read`` gets the pool with no
     value pool (None).
 
+    A model whose cache is by kind (``ModelConfig.kv_by_kind``) hands
+    ``k_cache``, ``v_cache``, ``write_at`` and ``table`` each as a pair
+    (full, sliding): a run's layers are of one form (``layer_runs``), write
+    and read that form's pool through that form's table at their ordinal
+    among the layers of that form, and the other pool rides the carry
+    untouched. A run of sliding layers whose attention the kernel serves
+    hands ``read`` its width as ``sliding=`` (static) and stands under the
+    ``swa_attn`` scope, so that a device trace tells its calls from the
+    full layers'.
+
     Attention layer ``l`` (counted among attention layers) writes its new
     K/V with one scatter into the whole ``[L_attn, P, ps, W]`` pool at
     ``[l, page, offset]`` (``write_at`` = (page_ids, offsets, valid), the
@@ -1425,8 +1467,9 @@ def _scan_paged_layers(
     ``xs``: a static slice handed over as ``xs`` would be a copy of the
     run's parameters on every forward."""
     L = cfg.num_layers
-    num_pages = k_cache.shape[1]
-    flat = (k_cache.shape[0] * num_pages, *k_cache.shape[2:])
+    by_kind = cfg.kv_by_kind
+    forms = cfg.attn_forms
+    tables, writes = _by_kind(cfg, table), _by_kind(cfg, write_at)
     rope_dim = cfg.qk_rope_head_dim if cfg.is_latent else cfg.head_dim_
     inv_freq = rope_frequencies(rope_dim, cfg.rope_theta, cfg.rope_scaling)
     eps = cfg.rms_norm_eps
@@ -1472,10 +1515,29 @@ def _scan_paged_layers(
                 source.update((k, (layers[k], ff0)) for k in EXPERT_STACKS)
         xs = {k: a for k, (a, at) in source.items() if a.shape[0] == n}
         closed = {k: v for k, v in source.items() if k not in xs}
+        # The run's form of attention: which pool of a cache by kind, the
+        # run's first layer's ordinal in it, and the width the kernel takes
+        # as a static argument (None: a full layer, or a width that rides
+        # ``xs`` to the gather).
+        form = forms[first]
+        kind = int(by_kind and form == "sliding")
+        pool0 = forms[:first].count(form) if by_kind else op0
+        width = (
+            cfg.sliding_window
+            if form == "sliding" and cfg.attn_kernel_form else None
+        )
+        rope = not (cfg.rope_sliding_only and form == "full")
 
         def layer(carry, layer_in, op=op, op0=op0, ff0=ff0, stacks=stacks,
-                  closed=closed):
-            x, k_pool, v_pool, state = carry
+                  closed=closed, kind=kind, pool0=pool0, width=width,
+                  rope=rope):
+            x, k_all, v_all, state = carry
+            k_pool, v_pool = (
+                (k_all[kind], v_all[kind]) if by_kind else (k_all, v_all)
+            )
+            num_pages = k_pool.shape[1]
+            flat = (k_pool.shape[0] * num_pages, *k_pool.shape[2:])
+            table, write_at = tables[kind], writes[kind]
             lp, win_l, invf_l, i = layer_in
             lp = {**lp, **stacks}
             taken = []  # ``take_kept``: the layer's selection, as ``read`` hands it out
@@ -1483,7 +1545,9 @@ def _scan_paged_layers(
                 lp[name] = jax.lax.dynamic_index_in_dim(
                     stack, at + i, 0, keepdims=False
                 )
-            l = i + op0 if op0 else i  # ordinal among layers of this operator
+            # ordinal among layers of this operator (of this form, where the
+            # cache is by kind): the layer's place in its pool
+            l = i + pool0 if pool0 else i
             if op == "conv":
                 x, state = _conv_half(x, lp, cfg, eps, state, l, state_io)
             elif op == "mamba":
@@ -1538,14 +1602,22 @@ def _scan_paged_layers(
                             *write_at, layer=l,
                         )
                     with jax.named_scope("attend"):
-                        attn = read(
-                            q, kp.reshape(flat), vp.reshape(flat),
-                            table + l * num_pages, win_l,
-                        )
+                        if width is None:
+                            attn = read(
+                                q, kp.reshape(flat), vp.reshape(flat),
+                                table + l * num_pages, win_l,
+                            )
+                        else:
+                            with jax.named_scope("swa_attn"):
+                                attn = read(
+                                    q, kp.reshape(flat), vp.reshape(flat),
+                                    table + l * num_pages, win_l,
+                                    sliding=width,
+                                )
                     return attn, (kp, vp)
 
                 x, (k_pool, v_pool) = _attn_half(
-                    x, lp, cfg, invf_l, rope_pos, eps, attend, red
+                    x, lp, cfg, invf_l, rope_pos, eps, attend, red, rope
                 )
             tally = [] if count_picks else None
             x = _ffn_half(
@@ -1554,6 +1626,9 @@ def _scan_paged_layers(
                 experts_any_rows, tally,
             )
             picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
+            if by_kind:  # the other form's pool rides on untouched
+                k_pool = (*k_all[:kind], k_pool, *k_all[kind + 1:])
+                v_pool = (*v_all[:kind], v_pool, *v_all[kind + 1:])
             return (x, k_pool, v_pool, state), (picks, taken[0] if taken else None)
 
         whole_model = n == L
@@ -1636,19 +1711,30 @@ def forward(
     (attention is embarrassingly parallel in heads).
     """
     B, T = tokens.shape
-    ps = k_cache.shape[2]
+    ps = kv_page_size(k_cache)
     eps = cfg.rms_norm_eps
+    by_kind = cfg.kv_by_kind  # then ``page_table`` is a pair, as the pools
 
-    # Page-write coordinates, shared by every layer. Positions beyond the
+    # Page-write coordinates, shared by every layer (of one form, where the
+    # cache is by kind). Positions beyond the
     # page table's capacity are dropped (not clamped): a scheduler bug can
     # truncate a sequence but never silently corrupt another's pages.
     flat_pos = positions.reshape(-1)  # [B*T]
     safe_pos = jnp.maximum(flat_pos, 0)
     page_in_seq = safe_pos // ps
-    valid = (flat_pos >= 0) & (page_in_seq < page_table.shape[1])
+    # both tables of a cache by kind are one width
+    width = (page_table[0] if by_kind else page_table).shape[1]
+    valid = (flat_pos >= 0) & (page_in_seq < width)
     batch_idx = jnp.repeat(jnp.arange(B, dtype=jnp.int32), T)
-    page_ids = page_table[batch_idx, page_in_seq]  # [B*T]
-    offsets = safe_pos % ps
+
+    def write_at_of(table_):
+        page_ids = table_[batch_idx, page_in_seq]  # [B*T]
+        return page_ids, safe_pos % ps, valid
+
+    write_at = (
+        tuple(map(write_at_of, page_table)) if by_kind
+        else write_at_of(page_table)
+    )
 
     # ``token_embeds`` ([B, T, D]) overrides the id lookup — the
     # multimodal seam: image (or other modality) features projected to
@@ -1662,25 +1748,16 @@ def forward(
     x = _maybe_scale_embeds(cfg, x)
     rope_pos = jnp.maximum(positions, 0)
 
-    # Pallas decode reads full ragged context; sliding-window and
-    # softcapped (gemma2) models stay on the XLA path where those live,
-    # as do meshes whose tp doesn't divide the kv heads (e.g. gemma's
-    # Hkv=1 with tp>1 — the shard_map head split would be empty on some
-    # ranks).
-    tp_size = mesh.shape.get("tp", 1) if mesh is not None else 1
-    use_pallas = (
-        attn_impl == "pallas"
-        and T == 1
-        and cfg.sliding_window is None
-        and cfg.attn_logit_softcap is None
-        and cfg.query_pre_attn_scalar is None
-        and cfg.num_kv_heads % tp_size == 0
-    )
+    # Pallas decode reads each row's ragged context (a sliding layer's
+    # through its width: ``sliding=``); softcapped and query-scaled models
+    # (gemma2, gemma3: ``ModelConfig.attn_kernel_form``) stay on the XLA
+    # path where those live, as do meshes whose tp doesn't divide the kv
+    # heads (e.g. gemma's Hkv=1 with tp>1 — the shard_map head split would
+    # be empty on some ranks).
+    use_pallas = _kernel_serves(cfg, attn_impl, mesh) and T == 1
     if use_pallas:
         lengths = jnp.maximum(positions[:, 0] + 1, 0)
-    attn_table = (
-        page_table if attn_pages is None else page_table[:, :attn_pages]
-    )
+    attn_table = _cut_tables(page_table, None if use_pallas else attn_pages)
     sm_scale = (
         cfg.query_pre_attn_scalar ** -0.5
         if cfg.query_pre_attn_scalar
@@ -1689,14 +1766,17 @@ def forward(
     have_window = cfg.sliding_window is not None
     latent = _latent_read_args(cfg)
 
-    def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None):
+    def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None,
+             sliding=None):
         # ``wrap`` (a latent model: ``_mla_half``'s absorb and expand, and
         # ``W_ukv`` for a form this forward never takes) stands either side
         # of the read, which then names itself; ``index`` (a model with an
         # indexer) is what the read's selection is computed from, and
-        # ``taken`` the list that gets what it kept for ``kept_of``
+        # ``taken`` the list that gets what it kept for ``kept_of``;
+        # ``sliding`` (a run of sliding layers): the width, static, for the
+        # kernel (the gather masks by ``win_l``, which says the same)
         pre, post, scope = _read_wrap(wrap)
-        keep = {}
+        keep = {} if sliding is None else {"sliding": sliding}
         if use_pallas:
             if index is not None:
                 keep["keep"] = _selection_tiled(
@@ -1712,6 +1792,7 @@ def forward(
                 )
             return post(o)[:, None]
         q = pre(q)
+        keep.pop("sliding", None)
         if index is not None:
             keep["keep"] = _selection_ref(cfg, index, table_l, positions)
             if taken is not None:
@@ -1737,8 +1818,7 @@ def forward(
         )
     x, k_cache, v_cache, seq_state, held_picks, kept = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
-        (page_ids, offsets, valid),
-        page_table if use_pallas else attn_table, read, mesh,
+        write_at, attn_table, read, mesh,
         positions >= 0, interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, T, None, interpret, one_token=T == 1),
         count_picks=count_picks, take_kept=kept_of is not None,
@@ -1747,6 +1827,31 @@ def forward(
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
     logits = _final_logits(params, cfg, x, eps)
     return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks, kept)
+
+
+def _kernel_serves(cfg: ModelConfig, attn_impl: str, mesh) -> bool:
+    """Whether a paged forward built with ``attn_impl`` reads its contexts
+    through the attention kernel: the engine resolved to it
+    (``engine.resolve_attn_impl``, which asks the same
+    ``ModelConfig.attn_kernel_form``) and the mesh's ``tp`` divides the KV
+    heads (else a ``shard_map`` rank would hold none: gemma's one KV head
+    under tp > 1)."""
+    tp_size = mesh.shape.get("tp", 1) if mesh is not None else 1
+    return (
+        attn_impl == "pallas"
+        and cfg.attn_kernel_form
+        and cfg.num_kv_heads % tp_size == 0
+    )
+
+
+def _cut_tables(page_table, attn_pages: int | None):
+    """The table(s) an attention read walks: the first ``attn_pages``
+    columns of each (the gather's bound), or all of them."""
+    if attn_pages is None:
+        return page_table
+    if isinstance(page_table, tuple):
+        return tuple(t[:, :attn_pages] for t in page_table)
+    return page_table[:, :attn_pages]
 
 
 def _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks, kept=None):
@@ -1912,7 +2017,7 @@ def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret,
     def f(q_l, k_l, v_l, table, lens):
         return ragged_decode_attention(
             q_l, k_l, v_l, table, lens, num_kv_heads=hkv // tp,
-            interpret=interpret,
+            interpret=interpret, sliding=latent.get("sliding"),
         )
 
     return f(q, kp, vp, page_table, lengths)
@@ -1957,6 +2062,7 @@ def _pallas_ragged(
         return ragged_paged_attention(
             q_l, k_l, v_l, table, rows, pos, num_kv_heads=hkv // tp,
             q_tile=q_tile, interpret=interpret, q_block=q_block,
+            sliding=latent.get("sliding"),
         )
 
     return f(q, kp, vp, attn_table, row_of, positions)
@@ -2077,43 +2183,45 @@ def forward_ragged(
     ``forward`` adds for ``seq_state``, ``count_picks`` and ``kept_of``
     (slots of this stream; the kept positions [L, len(kept_of), S] bool).
 
-    Sliding-window / softcapped / query-scaled models (gemma2, mistral)
-    follow the same per-layer machinery as :func:`forward`; the Pallas
-    path is only legal when none of those are set (the engine's attn
-    resolution enforces it, mirroring ``forward``'s ``use_pallas``
-    guard).
+    Sliding / softcapped / query-scaled models (mistral, gemma2) follow
+    the same per-layer machinery as :func:`forward`: the kernel takes a
+    sliding width (``sliding=``), and a cap or a pre-scale keeps a model
+    on the reference path (``ModelConfig.attn_kernel_form``; the engine's
+    attn resolution enforces it, mirroring ``forward``'s ``use_pallas``
+    guard). A cache by kind (``ModelConfig.kv_by_kind``): ``page_table``,
+    ``k_cache`` and ``v_cache`` are pairs (full, sliding), as ``forward``'s.
     """
-    ps = k_cache.shape[2]
+    ps = kv_page_size(k_cache)
     eps = cfg.rms_norm_eps
+    by_kind = cfg.kv_by_kind
 
     # Page-write coordinates: each flat token writes its row's page at
     # its own position; padding (-1) and table-overflow positions are
     # dropped, never clamped into another row's pages.
     safe_pos = jnp.maximum(positions, 0)
     page_in_seq = safe_pos // ps
-    valid = (positions >= 0) & (page_in_seq < page_table.shape[1])
-    page_ids = page_table[row_of, page_in_seq]  # [N]
-    offsets = safe_pos % ps
+    # both tables of a cache by kind are one shape
+    n_rows, width = (page_table[0] if by_kind else page_table).shape
+    valid = (positions >= 0) & (page_in_seq < width)
+
+    def write_at_of(table_):
+        page_ids = table_[row_of, page_in_seq]  # [N]
+        return page_ids, safe_pos % ps, valid
+
+    write_at = (
+        tuple(map(write_at_of, page_table)) if by_kind
+        else write_at_of(page_table)
+    )
 
     x = jnp.take(params["embed"], tokens, axis=0)[None]  # [1, N, D]
     x = _maybe_scale_embeds(cfg, x)
     rope_pos = jnp.maximum(positions, 0)[None]  # [1, N]
 
-    attn_table = (
-        page_table if attn_pages is None else page_table[:, :attn_pages]
-    )
-    # Same gate as forward()'s use_pallas: window/softcap/query-scale
-    # live on the reference path, and a tp that doesn't divide the kv
-    # heads (gemma's Hkv=1 with tp>1) would leave some shard_map ranks
-    # with zero heads.
-    tp_size = mesh.shape.get("tp", 1) if mesh is not None else 1
-    use_pallas = (
-        attn_impl == "pallas"
-        and cfg.sliding_window is None
-        and cfg.attn_logit_softcap is None
-        and cfg.query_pre_attn_scalar is None
-        and cfg.num_kv_heads % tp_size == 0
-    )
+    attn_table = _cut_tables(page_table, attn_pages)
+    # forward()'s gate: a softcap or a query pre-scale lives on the
+    # reference path, and a tp that doesn't divide the kv heads (gemma's
+    # Hkv=1 with tp>1) would leave some shard_map ranks with zero heads.
+    use_pallas = _kernel_serves(cfg, attn_impl, mesh)
     sm_scale = (
         cfg.query_pre_attn_scalar ** -0.5
         if cfg.query_pre_attn_scalar
@@ -2132,7 +2240,7 @@ def forward_ragged(
     # none does; ``latent_form_queries``).
     expand_from = latent_form_queries(
         cfg, q_tile, ps, k_cache.dtype, interpret
-    ) if use_pallas else None
+    ) if use_pallas and cfg.is_latent else None
     # Which of the tiled part's slots, and in which passes: the stream's,
     # so planned once for every layer (None: the part is too short for any
     # row to have that many queries).
@@ -2143,13 +2251,15 @@ def forward_ragged(
 
         with jax.named_scope("mla_expand"):
             expand_plan = expanded_plan(
-                row_of[:tiled], bounds[:tiled], page_table.shape[0], q_tile,
+                row_of[:tiled], bounds[:tiled], n_rows, q_tile,
                 expand_from,
             )
 
-    def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None):
-        # ``forward``'s, as ``index`` and ``taken``
+    def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None,
+             sliding=None):
+        # ``forward``'s, as ``index``, ``taken`` and ``sliding``
         pre, post, scope = _read_wrap(wrap)
+        swa = {} if sliding is None else {"sliding": sliding}
         if use_pallas:
             def select(part, tile, pad=0):
                 # ``part``'s learned selection (``_selection_tiled``), and
@@ -2180,7 +2290,7 @@ def forward_ragged(
                     o = _pallas_ragged(
                         pre(q[0, part]), kf, vf, table_l, row_of[part],
                         bounds[part] if seen is None else seen, cfg.num_kv_heads,
-                        tile, mesh, interpret, block, **latent, **keep,
+                        tile, mesh, interpret, block, **latent, **keep, **swa,
                     )
                 return post(o)
 
@@ -2255,7 +2365,7 @@ def forward_ragged(
         state_io = SeqStateIO(slots, row_of, positions, src, dst)
     x, k_cache, v_cache, seq_state, held_picks, kept = _scan_paged_layers(
         params, cfg, x, k_cache, v_cache, rope_pos,
-        (page_ids, offsets, valid), attn_table, read, mesh,
+        write_at, attn_table, read, mesh,
         (positions >= 0)[None], interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, q_tile, packed_from, interpret),
         experts_any_rows, count_picks, kept_of is not None,

@@ -9,6 +9,8 @@ layer axis for the scan.
 
 from __future__ import annotations
 
+import functools
+
 import json
 import os
 
@@ -213,7 +215,7 @@ SUPPORTED_MODEL_TYPES = (
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2",
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
-    "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa",
+    "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
 )
 
 
@@ -369,6 +371,63 @@ def _glm_moe_dsa_params(cfg: ModelConfig, get, linear) -> dict:
     }
 
 
+def _exaone_moe_params(cfg: ModelConfig, get, linear, has=None) -> dict:
+    """``exaone_moe`` tensor names (the DeepSeek-V3 / Qwen2-MoE decoder
+    layer's) into the stacks by kind of ``models/llama.py::init_params``:
+    ``input_layernorm`` / ``post_attention_layernorm`` over every layer,
+    BEFORE each half; ``self_attn.{q,k,v,o}_proj`` and the per-head
+    ``self_attn.{q_norm, k_norm}`` over every layer (rotary as HF's
+    ``rotate_half``: this repo's ``apply_rope`` pairs, no re-ordering);
+    ``mlp.{gate,up,down}_proj`` over the dense layers; ``mlp.gate.weight``
+    (the router), ``mlp.gate.e_score_correction_bias`` (the selection
+    bias), ``mlp.shared_experts.*`` and ``mlp.experts.N.*`` over the routed
+    ones, ``N`` over the experts this device holds. A checkpoint that
+    carries ``post_feedforward_layernorm`` is EXAONE 4.0's block (each
+    half's OUTPUT normed) and is refused rather than guessed at."""
+    if has is not None and has("model.layers.0.post_feedforward_layernorm.weight"):
+        raise ValueError(
+            "exaone_moe checkpoint carries post_feedforward_layernorm: that "
+            "is the exaone4 block (norms on each half's output), not the "
+            "pre-norm block this reader builds"
+        )
+    names = (
+        "attn_norm", "mlp_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
+        "dense_gate", "dense_up", "dense_down", "router", "router_bias",
+        "shared_gate", "shared_up", "shared_down", "w_gate", "w_up", "w_down",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    proj = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
+    held = range(cfg.first_expert_held, cfg.first_expert_held + cfg.experts_held)
+    for i, ff in enumerate(cfg.ff_kinds):
+        p = f"model.layers.{i}."
+        layers["attn_norm"].append(get(p + "input_layernorm.weight"))
+        layers["mlp_norm"].append(get(p + "post_attention_layernorm.weight"))
+        a = p + "self_attn."
+        for key, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj")):
+            layers[key].append(linear(f"{a}{name}.weight"))
+        layers["q_norm"].append(get(a + "q_norm.weight"))
+        layers["k_norm"].append(get(a + "k_norm.weight"))
+        m = p + "mlp."
+        if ff == "dense":
+            for key, name in proj:
+                layers[f"dense_{key}"].append(linear(f"{m}{name}.weight"))
+            continue
+        layers["router"].append(linear(m + "gate.weight"))
+        layers["router_bias"].append(get(m + "gate.e_score_correction_bias"))
+        for key, name in proj:
+            if cfg.shared_width:
+                layers[f"shared_{key}"].append(linear(f"{m}shared_experts.{name}.weight"))
+            layers[f"w_{key}"].append(np.stack([
+                linear(f"{m}experts.{e}.{name}.weight") for e in held
+            ]))
+    return {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items() if v},
+        "final_norm": get("model.norm.weight"),
+        "lm_head": linear("lm_head.weight"),
+    }
+
+
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
     """Load a HF checkpoint directory (llama/mistral/qwen2/mixtral
     families) into the stacked param pytree."""
@@ -403,6 +462,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         "olmo_hybrid": _olmo_hybrid_params,
         "pangu_ultra_moe": _pangu_ultra_moe_params,
         "glm_moe_dsa": _glm_moe_dsa_params,
+        "exaone_moe": functools.partial(_exaone_moe_params, has=index.__contains__),
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

@@ -135,6 +135,30 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
             topk_group=1, num_nextn_predict_layers=0,
         )
+    elif cfg.model_type == "exaone_moe":  # the family's own keys
+        del hf["rope_theta"]
+        sliding = [t == "sliding_attention" for t in cfg.layer_types]
+        hf.update(
+            architectures=["ExaoneMoEForCausalLM"], hidden_act="silu",
+            head_dim=cfg.head_dim_, layer_types=list(cfg.layer_types),
+            sliding_window=cfg.sliding_window,
+            sliding_windows=[cfg.sliding_window if s else 0 for s in sliding],
+            rope_parameters={"rope_theta": cfg.rope_theta, "rope_type": "default"},
+            first_k_dense_replace=cfg.num_dense_layers,
+            mlp_layer_types=[
+                "dense" if i < cfg.num_dense_layers else "sparse"
+                for i in range(cfg.num_layers)
+            ],
+            num_experts=cfg.experts_held,
+            num_experts_global=cfg.num_experts,
+            first_routed_expert=cfg.first_expert_held,
+            num_shared_experts=cfg.num_shared_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            scoring_func="sigmoid", n_group=1, topk_group=1,
+            num_nextn_predict_layers=0,
+        )
     elif cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -143,8 +167,8 @@ def write_config(path: str, cfg: ModelConfig) -> None:
         raise ValueError(
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
-            "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe "
-            "and glm_moe_dsa only"
+            "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe, "
+            "glm_moe_dsa and exaone_moe only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

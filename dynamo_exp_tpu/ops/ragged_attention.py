@@ -171,11 +171,12 @@ def ragged_paged_attention_ref(
     dtype with float32 accumulation, softmax in float32 — the same
     numeric contract as ``ops/attention.paged_attention``, so a
     one-token row here is bit-identical to the old decode gather.
-    ``window``/``softcap`` carry the sliding-window and tanh-capped
-    score variants (mistral/gemma2) exactly as ``paged_attention``
-    does — the Pallas kernel does not implement them, so those model
-    families stay on this path (the engine's attn resolution enforces
-    it).
+    ``window``/``softcap`` carry the sliding and tanh-capped score
+    variants (mistral/gemma2) exactly as ``paged_attention`` does. The
+    Pallas kernel has the first as ``sliding`` and not the second, so a
+    capped or pre-scaled family stays on this path
+    (``ModelConfig.attn_kernel_form``; the engine's attn resolution
+    enforces it).
 
     Implementation note: each flat token becomes its own T=1 batch row
     of :func:`ops.attention.paged_attention` (its row's page table
@@ -270,6 +271,33 @@ def merged_slot_counts(row_of, positions, q_tile: int, q_block: int):
     return merged, int(by_block.sum()) - merged
 
 
+def sliding_starts(positions, q_tile: int, q_block: int, sliding: int, xp=jnp):
+    """Where a sliding layer's walks start: ``(tile_first, block_first)``,
+    the smallest position any live query of each tile, and of each query
+    block, sees (its own minus ``sliding - 1``, never below 0; 0 for a
+    tile or block of no query, which walks nothing). ``positions`` as
+    :func:`block_plan` takes them, padded alike. A merged block's one
+    pass then covers the union of its queries' windows: for 1,024
+    consecutive queries of one row ``1,023 + sliding`` positions, not the
+    context. ``xp``: ``jnp`` inside the program, ``numpy`` for the
+    engine's count of the same rule."""
+    pad = (-positions.shape[0]) % q_block
+    if pad:
+        positions = xp.concatenate(
+            [positions, xp.full((pad,), -1, positions.dtype)]
+        )
+    lowest = xp.where(positions >= 0, positions, _NO_ROW).reshape(-1, q_tile)
+    tile_low = lowest.min(axis=1)
+    block_low = tile_low.reshape(-1, q_block // q_tile).min(axis=1)
+
+    def first(low):
+        return xp.where(
+            low < _NO_ROW, xp.maximum(low - (sliding - 1), 0), 0
+        ).astype(positions.dtype)
+
+    return first(tile_low), first(block_low)
+
+
 def _attend(
     q_ref,  # [W, H, D] VMEM — the pass's queries
     o_ref,  # [W, H, Dv] VMEM
@@ -296,11 +324,19 @@ def _attend(
     scale: float,
     vd: int | None = None,
     keep=None,
+    sliding: int | None = None,
+    first=None,
 ):
     """One double-buffered pass over ``row``'s first ``kvlen`` tokens
     for ``w`` queries: flash-style online softmax, ``qpk * w`` score
     rows a KV head. Every DMA it starts it waits for, so passes follow
     one another in a grid cell on the same buffers and semaphores.
+    ``sliding`` (a sliding layer's width, static) with ``first`` (a
+    scalar: the smallest position any of the pass's queries sees): the
+    walk starts at ``first``'s page and not at the row's, a chunk is
+    ``cp`` pages from there, and a query masks the positions at or
+    before its own minus ``sliding``; what lies before ``first``'s page
+    is neither copied nor multiplied.
     ``vd`` (a latent pool): a head's values are the first ``vd`` lanes of
     its key row, and the two products take their operands in the pool's
     dtype (module docstring). ``keep`` (a learned selection,
@@ -310,7 +346,11 @@ def _attend(
     it and among the first ``allow_ref[i]`` such positions of the walk
     (``sparse_index.kept``), every head alike."""
     dv = hd if vd is None else vd
-    n_chunks = pl.cdiv(kvlen, ps * cp)
+    if sliding is None:
+        n_chunks = pl.cdiv(kvlen, ps * cp)
+    else:
+        page0 = first // ps  # the walk's first page
+        n_chunks = pl.cdiv(kvlen - page0 * ps, ps * cp)
     # The score rows of this pass: all the state's where it is the cell's
     # only one, the first ``H * w`` in a pass of one tile of a block.
     n = hkv * qpk * w
@@ -328,7 +368,7 @@ def _attend(
         only leading (untiled) dims — Mosaic rejects slices of a lane
         dim narrower than the 128-lane tile."""
         dmas = []
-        base = c * cp
+        base = c * cp if sliding is None else c * cp + page0
         for j in range(cp):
             idx = jnp.minimum(base + j, pmax - 1)
             pid = table_ref[row, idx]
@@ -384,6 +424,8 @@ def _attend(
             dma.wait()
 
         tok_idx = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        if sliding is not None:
+            tok_idx = tok_idx + page0 * ps
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
         v = v_buf[slot].reshape(S, hkv * hd) if vd is None else None
         if keep is not None:
@@ -420,6 +462,8 @@ def _attend(
                 * scale
             )  # [qpk*W, S]
             live = tok_idx <= pos_rows
+            if sliding is not None:
+                live = live & (tok_idx > pos_rows - sliding)
             if keep is not None:
                 live = live & kept
             s = jnp.where(live, s, -1e30)
@@ -489,7 +533,13 @@ def _tile_kernel(
 ):
     """A grid cell is one tile of ``qt`` slots, all of one row. With
     ``kept`` the tile's index scores and thresholds come first among
-    ``refs`` (``_attend``'s ``keep``)."""
+    ``refs`` (``_attend``'s ``keep``). With ``sliding`` (in ``shape``) a
+    fifth prefetched scalar array comes behind the table: each tile's
+    first visible position (``sliding_starts``)."""
+    first = {}
+    if shape.get("sliding") is not None:
+        tile_first_ref, q_ref, *refs = q_ref, *refs
+        first = {"first": tile_first_ref[pl.program_id(0)]}
     keep = None
     if kept:
         *keep, refs = refs[0], refs[1], refs[2], refs[3:]
@@ -516,7 +566,8 @@ def _tile_kernel(
 
     _attend(
         q_ref, o_ref, pos_rows, tile_row_ref[t], tile_kvlen_ref[t],
-        table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, keep=keep, **shape,
+        table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, keep=keep,
+        **first, **shape,
     )
 
 
@@ -538,10 +589,20 @@ def _block_kernel(
 ):
     """A grid cell is a block of ``nt`` tiles. Where they belong to one
     row it reads that row's context once for all ``nt * qt`` queries;
-    where to several, tile after tile as :func:`_tile_kernel` does."""
+    where to several, tile after tile as :func:`_tile_kernel` does. With
+    ``sliding`` (in ``shape``) two more prefetched scalar arrays come
+    behind the table (``sliding_starts``: each tile's and each block's
+    first visible position), and the one pass covers the union of its
+    queries' windows."""
+    tile_first = block_first = None
+    if shape.get("sliding") is not None:
+        tile_first, block_first, pos_ref, q_ref, *refs = pos_ref, q_ref, *refs
     k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     b = pl.program_id(0)
     merged_len = block_kvlen_ref[b]
+
+    def start(ref, at):
+        return {} if ref is None else {"first": ref[at]}
 
     def score_rows(at):
         """() -> the positions ``pos_ref[at]`` [W, 1] as score rows
@@ -557,7 +618,7 @@ def _block_kernel(
         _attend(
             q_ref, o_ref, score_rows(slice(None)), block_row_ref[b],
             merged_len, table_ref, k_hbm, v_hbm, *scratch, w=nt * qt,
-            qpk=qpk, **shape,
+            qpk=qpk, **start(block_first, b), **shape,
         )
 
     @pl.when(merged_len < 0)
@@ -568,7 +629,8 @@ def _block_kernel(
             _attend(
                 q_ref.at[at], o_ref.at[at], score_rows(at),
                 tile_row_ref[t], tile_kvlen_ref[t], table_ref, k_hbm,
-                v_hbm, *scratch, w=qt, qpk=qpk, **shape,
+                v_hbm, *scratch, w=qt, qpk=qpk, **start(tile_first, t),
+                **shape,
             )
             return 0
 
@@ -592,7 +654,7 @@ def _block_vmem_bytes(qb, H, D, kv_lanes, cp, ps, q_item, kv_item) -> int:
     jax.jit,
     static_argnames=(
         "num_kv_heads", "q_tile", "sm_scale", "interpret", "q_block",
-        "value_dim",
+        "value_dim", "sliding",
     ),
 )
 def ragged_paged_attention(
@@ -610,6 +672,7 @@ def ragged_paged_attention(
     q_block: int | None = None,  # slots a grid cell serves; None = q_tile
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
     keep: tuple[jnp.ndarray, ...] | None = None,  # a learned selection
+    sliding: int | None = None,  # a sliding layer's width (keys a query sees)
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
@@ -644,6 +707,14 @@ def ragged_paged_attention(
     head's values are the first ``value_dim`` lanes of its key row and the
     result is [N, H, value_dim].
 
+    ``sliding`` (static; a sliding layer): query ``t`` sees the keys
+    ``t - sliding < s <= t`` and no others. A cell's walk starts at the
+    page of the smallest position its live queries see
+    (:func:`sliding_starts`) and ends at its bound as ever, so a decode
+    row reads ``sliding`` keys whatever its context, and the pages behind
+    may have been given back (the table's entries there are never read).
+    None: the program is the one without the argument, to the letter.
+
     ``keep`` (learned sparse attention; a cell is then one tile):
     ``(scores, tau, allow)`` of ``ops/sparse_index.py``, the stream's index
     scores tiled as ``sparse_index.tiled_shape`` and ``select``'s threshold
@@ -668,6 +739,12 @@ def ragged_paged_attention(
     latent = value_dim is not None
     Dv = value_dim if latent else D
     pools = (k_cache,) if latent else (k_cache, v_cache)
+    starts = ()
+    if sliding is not None:
+        if keep is not None or bounds is not None:
+            raise ValueError("a sliding layer has neither a selection nor bounds")
+        shape["sliding"] = sliding
+        starts = sliding_starts(positions, qt, qb, sliding)
     params = {}
     if latent:
         shape["vd"] = value_dim
@@ -718,7 +795,7 @@ def ragged_paged_attention(
                 scores, tau.reshape(n_tiles, qt, 1), allow.reshape(n_tiles, qt, 1)
             )
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=4 + len(starts[:1]),
             grid=(n_tiles,),
             in_specs=[query_spec(), *kept_specs, *pool_specs],
             out_specs=query_spec(Dv),
@@ -730,7 +807,10 @@ def ragged_paged_attention(
             out_shape=out_shape,
             interpret=interpret,
             **params,
-        )(tile_row, tile_kvlen, positions, page_table, q, *kept_args, *pools)
+        )(
+            tile_row, tile_kvlen, positions, page_table, *starts[:1], q,
+            *kept_args, *pools,
+        )
     if keep is not None:
         raise ValueError("a learned selection rides a call whose block is one tile")
 
@@ -743,7 +823,7 @@ def ragged_paged_attention(
         positions, (0, n_blocks * qb - N), constant_values=-1
     )[:, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + len(starts),
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec(
@@ -769,7 +849,7 @@ def ragged_paged_attention(
         out_shape=out_shape,
         interpret=interpret,
         **params,
-    )(*plan, page_table, pos_col, q, *pools)
+    )(*plan, page_table, *starts, pos_col, q, *pools)
 
 
 # ------------------------------------------- a latent pool, expanded form
@@ -1474,6 +1554,7 @@ def ragged_decode_attention(
     sm_scale: float | None = None,
     value_dim: int | None = None,
     keep: tuple[jnp.ndarray, ...] | None = None,
+    sliding: int | None = None,
 ) -> jnp.ndarray:
     """Pure-decode shape of the ragged kernel: one query per row at
     ``q_tile=1`` (row b attends its own ``lengths[b]`` tokens; rows
@@ -1494,4 +1575,5 @@ def ragged_decode_attention(
         sm_scale=sm_scale,
         value_dim=value_dim,
         keep=keep,
+        sliding=sliding,
     )

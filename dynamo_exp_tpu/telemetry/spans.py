@@ -965,6 +965,83 @@ class Telemetry:
              "pad_lanes", "token_bytes", "index_width"],
             registry=self.registry,
         )
+        self.kv_sliding_pool_info = Gauge(
+            "dynamo_kv_sliding_pool_info",
+            "Constant-1 geometry of the sliding layers' KV pools (K and V) "
+            "of a model whose cache is by kind (sliding and full attention "
+            "layers in one stack; dynamo_kv_pool_info then describes the "
+            "full layers' pools): stacked layers, pages, tokens a page, a "
+            "row's lanes, element type, the sliding width (keys a query "
+            "sees) and page_bytes, the bytes a held page takes over those "
+            "layers, K and V. No sample for any other model",
+            ["layers", "pages", "page_size", "width", "dtype",
+             "sliding_width", "page_bytes"],
+            registry=self.registry,
+        )
+        self.kv_pool_usage = Gauge(
+            "dynamo_kv_pool_usage",
+            "Share of a pool's pages in use, by pool of a cache by kind "
+            "(full: held, shared or parked for reuse, as "
+            "dynamo_engine_hbm_page_occupancy; sliding: held by a row)",
+            ["pool"],  # full | sliding
+            registry=self.registry,
+        )
+        self.kv_pool_held_bytes = Gauge(
+            "dynamo_kv_pool_held_bytes",
+            "Bytes of a pool's pages in use over its layers, K and V, by "
+            "pool of a cache by kind",
+            ["pool"],
+            registry=self.registry,
+        )
+        self.kv_sliding_pool_peak_pages = Gauge(
+            "dynamo_kv_sliding_pool_peak_pages",
+            "The most pages the sliding layers' pool has held at once since "
+            "start (of dynamo_kv_sliding_pool_info's pages): the margin "
+            "behind EngineConfig.sliding_pool_pages' arithmetic, since a "
+            "pool that runs dry stops the engine loop",
+            registry=self.registry,
+        )
+        self.kv_context_tokens = Gauge(
+            "dynamo_kv_context_tokens",
+            "Context tokens of the rows bound to decode slots (written or "
+            "in flight), of a model whose cache is by kind: what "
+            "dynamo_kv_pool_held_bytes is held for",
+            registry=self.registry,
+        )
+        self.kv_sliding_pages_released = Counter(
+            "dynamo_kv_sliding_pages_released_total",
+            "Pages of the sliding layers' pool given back behind the width "
+            "while their row lived (no later query of it sees them), in the "
+            "consume phase's pages part",
+            registry=self.registry,
+        )
+        self.attn_query_tokens = Counter(
+            "dynamo_attn_query_tokens_total",
+            "Queries dispatches fed the attention layers of a model with "
+            "sliding layers, by the layers' form and summed over the "
+            "layers of each",
+            ["kind"],  # sliding | full
+            registry=self.registry,
+        )
+        self.attn_key_positions = Counter(
+            "dynamo_attn_key_positions_total",
+            "Key positions those queries may see (t + 1 in a full layer, "
+            "min(t + 1, width) in a sliding one), by the layers' form and "
+            "summed over the layers of each: counted on the host from the "
+            "dispatch's plan, the work a roofline reads whatever computes it",
+            ["kind"],
+            registry=self.registry,
+        )
+        self.attn_context_tokens = Counter(
+            "dynamo_attn_context_tokens_total",
+            "Context tokens a row's queries of one dispatch see between "
+            "them (a full layer: up to the last query's position; a sliding "
+            "layer: from width - 1 before the first query on), by the "
+            "layers' form and summed over the layers of each: what the "
+            "attention has to read at the least",
+            ["kind"],
+            registry=self.registry,
+        )
         self.jax_compile_events = Counter(
             "dynamo_jax_compile_events_total",
             "JAX compile-pipeline events in this process, by stage "
@@ -1140,6 +1217,17 @@ class Telemetry:
         self.kv_pool_info.labels(
             str(layers), str(pages), str(page_size), str(width), dtype,
             str(pools), str(pad_lanes), str(token_bytes), str(index_width),
+        ).set(1)
+
+    def set_kv_sliding_pool_info(
+        self, layers: int, pages: int, page_size: int, width: int, dtype: str,
+        sliding_width: int, page_bytes: int,
+    ) -> None:
+        """Publish the constant-1 ``dynamo_kv_sliding_pool_info`` sample."""
+        self.kv_sliding_pool_info.clear()
+        self.kv_sliding_pool_info.labels(
+            str(layers), str(pages), str(page_size), str(width), dtype,
+            str(sliding_width), str(page_bytes),
         ).set(1)
 
     def watch_jax_compiles(self) -> None:

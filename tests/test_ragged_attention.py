@@ -91,17 +91,20 @@ def _flat_batch(rng, spans, H, Hkv, D, P, ps, pmax, q_tile, dtype=jnp.float32):
         [(1, 64), (32, 32)],
     ],
 )
-def test_kernel_matches_reference_ragged(spans):
+# A sliding layer's width (keys a query sees): none, one that is no whole
+# number of pages of 16, one that is.
+@pytest.mark.parametrize("sliding", [None, 5, 16])
+def test_kernel_matches_reference_ragged(spans, sliding):
     H, Hkv, D, ps, pmax = 8, 4, 64, 16, 8
     q, k, v, table, row_of, positions = _flat_batch(
         0, spans, H, Hkv, D, 64, ps, pmax, q_tile=4
     )
     got = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=4,
-        interpret=True,
+        interpret=True, sliding=sliding,
     )
     want = ragged_paged_attention_ref(
-        q, k, v, table, row_of, positions, num_kv_heads=Hkv
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, window=sliding,
     )
     live = np.asarray(positions) >= 0
     np.testing.assert_allclose(
@@ -127,9 +130,12 @@ def test_reference_matches_paged_attention_per_token():
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(direct))
 
 
-def test_decode_shape_matches_reference_and_zeroes_inactive():
+@pytest.mark.parametrize("sliding", [None, 3, 8])
+def test_decode_shape_matches_reference_and_zeroes_inactive(sliding):
     """q_tile=1, one query per row — the compiled decode window's
-    per-step shape. Rows with length 0 return exact zeros."""
+    per-step shape. Rows with length 0 return exact zeros. With a sliding
+    width a row's walk starts at its newest ``sliding`` keys' page: the
+    pages before it may hold anything (here: NaN)."""
     lengths = [1, 17, 0, 5]
     H, Hkv, D, ps, pmax = 4, 4, 32, 8, 8
     spans = [(1, max(ln, 1)) for ln in lengths]
@@ -137,12 +143,19 @@ def test_decode_shape_matches_reference_and_zeroes_inactive():
         2, spans, H, Hkv, D, 64, ps, pmax, q_tile=1
     )
     lens = jnp.asarray(lengths, jnp.int32)
-    got = ragged_decode_attention(
-        q, k, v, table, lens, num_kv_heads=Hkv, interpret=True
-    )
     want = ragged_paged_attention_ref(
         q, k, v, table, jnp.arange(4, dtype=jnp.int32), lens - 1,
-        num_kv_heads=Hkv,
+        num_kv_heads=Hkv, window=sliding,
+    )
+    if sliding is not None:
+        # row 1's first page (positions 0..7) lies wholly behind 17 - 8: a
+        # page of its own that no other entry of the table names
+        gone = 63
+        table = table.at[1, 0].set(gone)
+        k, v = k.at[gone].set(jnp.nan), v.at[gone].set(jnp.nan)
+    got = ragged_decode_attention(
+        q, k, v, table, lens, num_kv_heads=Hkv, interpret=True,
+        sliding=sliding,
     )
     out = np.asarray(got)
     active = np.asarray(lengths) > 0
@@ -302,11 +315,17 @@ def _block_stream(name, H, Hkv, D=32, seed=7):
 
 @pytest.mark.parametrize("stream", list(_BLOCK_STREAMS))
 @pytest.mark.parametrize("layout", list(_LAYOUTS))
-def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream):
+# A sliding layer's width: none, or 20 keys, no whole number of pages of 8
+# (widths of whole pages: the tile and the one-query cases above).
+@pytest.mark.parametrize("sliding", [None, 20])
+def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream, sliding):
     """A grid cell of a block of tiles (``q_block`` from the head layout:
     128, 32 and 8 slots) against the reference at the tile kernel's
     tolerance, against the tile kernel's own output of the same stream
-    (a query walks the same chunks in the same order either way), and
+    (a query walks the same chunks in the same order either way; with a
+    sliding width a merged block's walk starts at the union of its
+    queries' windows and a tile's at its own, so the chunks differ and
+    the two agree as closely as either does with the reference), and
     exact zeros at every padding slot."""
     H, Hkv = _LAYOUTS[layout]
     q, k, v, table, row_of, positions = _block_stream(stream, H, Hkv)
@@ -314,20 +333,22 @@ def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream):
     assert qb == {"1to1": 128, "4to1": 32, "20to1": 8}[layout]
     got = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
-        interpret=True, q_block=qb,
+        interpret=True, q_block=qb, sliding=sliding,
     )
     by_tile = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
-        interpret=True,
+        interpret=True, sliding=sliding,
     )
     want = ragged_paged_attention_ref(
-        q, k, v, table, row_of, positions, num_kv_heads=Hkv
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, window=sliding,
     )
     live = np.asarray(positions) >= 0
     got = np.asarray(got)
     assert got.shape == q.shape
     np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=2e-5)
-    np.testing.assert_allclose(got, np.asarray(by_tile), atol=2e-6)
+    np.testing.assert_allclose(
+        got, np.asarray(by_tile), atol=2e-6 if sliding is None else 2e-5
+    )
     assert (got[~live] == 0.0).all()
     # What the stream was built to meet, by the kernel's own rule.
     merged, walked = merged_slot_counts(row_of, positions, 8, qb)
