@@ -1601,7 +1601,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         context and those it walks tile by tile
         (``dynamo_attn_query_slots_total``): the kernel's own rule
         (``ops/ragged_attention.py::block_plan``) on the host's copy of
-        what it takes. The reference has no tiles and counts nothing."""
+        what it takes; and its packed tail's rows by their first fetch
+        (``_count_one_query_rows``). The reference has no tiles and counts
+        nothing."""
         if self._attn_impl != "pallas":
             return
         from ..models.llama import ragged_query_block
@@ -1617,6 +1619,28 @@ class TPUEngine(BlockDecode, AsyncEngine):
         slots = get_telemetry().attn_query_slots
         slots.labels("merged").inc(merged)
         slots.labels("tile").inc(by_tile)
+        self._count_one_query_rows(positions[tiled:nb])
+
+    def _count_one_query_rows(self, positions) -> None:
+        """Of the attention kernel's one-query call over ``positions`` (a
+        mixed stream's packed tail, or a window's ``[steps, rows]``; -1: no
+        query), the live rows whose first chunk a grid cell fetches behind
+        the row before and those whose fetch is exposed
+        (``dynamo_attn_one_query_rows_total``): the kernel's own rule
+        (``ops/ragged_attention.py::one_query_chain``) at the rows a cell
+        the call's shapes give. The reference counts nothing."""
+        if self._attn_impl != "pallas":
+            return
+        from ..models.llama import one_query_rows
+        from ..ops.ragged_attention import one_query_fetches
+
+        behind, exposed = one_query_fetches(
+            positions,
+            one_query_rows(self.cfg.model, positions.shape[-1], self.cfg.tp),
+        )
+        rows = get_telemetry().attn_one_query_rows
+        rows.labels("behind").inc(behind)
+        rows.labels("exposed").inc(exposed)
 
     def _count_index_passes(self, nb: int, row_of, positions) -> None:
         """Of a mixed stream's tiled part of a model with an indexer, the
@@ -4212,7 +4236,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             )
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(part))
-        fed = self._window_steps(rows, positions, budget_gate)
+        fed = self._window_steps(nb, rows, positions, budget_gate)
         return self._launch(
             nb, max_pages, packed, rows, windowed=True,
             full_sampler=full_sampler, want_lp=want_lp,
@@ -4230,17 +4254,23 @@ class TPUEngine(BlockDecode, AsyncEngine):
             at = self.cfg.max_pages_per_seq
             table[r, at : at + len(seq.sliding_ids)] = seq.sliding_ids
 
-    def _window_steps(self, rows: list, first_pos, budget_gate) -> int:
+    def _window_steps(self, nb: int, rows: list, first_pos, budget_gate) -> int:
         """The tokens a window's rows really feed: a row's valid steps, cut
         where its ``max_tokens`` runs out (``_stop_gates``: the device
         parks it after step ``budget_gate``; a stop token is the device's
         to find and is not foreseen). Counts a latent model's reads
         (``_count_latent_reads``) from each row's first position in
-        ``first_pos`` (indexed by row) and returns the steps' sum."""
+        ``first_pos`` (indexed by row), the rows of the ``nb`` each step's
+        attention call reads by their first fetch
+        (``_count_one_query_rows``), and returns the steps' sum."""
         steps = [min(e.n_valid, int(budget_gate[e.row]) + 1) for e in rows]
         firsts = [first_pos[e.row] for e in rows]
         self._count_latent_reads("window", firsts, steps=steps)
         self._count_attn_work(firsts, steps)
+        fed = np.full((self.cfg.decode_window, nb), -1, np.int32)
+        for e, n in zip(rows, steps):
+            fed[:n, e.row] = 0
+        self._count_one_query_rows(fed)
         return sum(steps)
 
     def _build_mixed(
@@ -4576,7 +4606,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self.steps += K
         get_telemetry().decode_batch_rows.labels("window").observe(len(rows))
         fed = self._window_steps(
-            rows, {e.row: len(e.seq.tokens) - 1 + K for e in rows}, budget_gate
+            nb, rows, {e.row: len(e.seq.tokens) - 1 + K for e in rows},
+            budget_gate,
         )
         return self._launch(  # dynlint: recompile-hazard(chained window reuses the dispatched bucket)
             nb, max_pages, packed, rows, windowed=True,

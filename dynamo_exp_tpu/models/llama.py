@@ -2081,6 +2081,26 @@ def ragged_query_block(cfg: ModelConfig, q_tile: int) -> int:
     return q_tile if cfg.has_indexer else query_block(q_tile, cfg.q_per_kv)
 
 
+def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1) -> int:
+    """Rows a grid cell of the attention kernel's one-query call serves over
+    a stream of ``n`` slots (a window's bucket, a mixed stream's packed
+    tail) on a chip that holds one of ``tp`` shares of the heads
+    (``ops/ragged_attention.py::one_query_block`` at the model's widths: a
+    latent pool's absorbed queries are its row's lanes wide and the result
+    its value lanes), or one for a model with an indexer, whose selection
+    rides cells of one row. The engine's counter
+    (``dynamo_attn_one_query_rows_total``) asks here what the kernel asks
+    of its own shapes."""
+    from ..ops.ragged_attention import one_query_block
+
+    if cfg.has_indexer:
+        return 1
+    width = value = cfg.head_dim_
+    if cfg.is_latent:
+        width, value = cfg.latent_lanes, latent_value_lanes(cfg)
+    return one_query_block(n, cfg.num_heads // tp, width, value)
+
+
 def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
                sm_scale, interpret, scope, absorbed, keep=None):
     """Latent attention over a stream's tiled part in both forms: the rows

@@ -34,12 +34,25 @@ Two implementations with identical semantics:
   live pages (``ceil(kv_len / page_size)``) for all its queries, a
   flash-style online softmax in VMEM scratch, each query masked at its
   own bound; a block that holds several rows (a span's first and last)
-  walks its tiles one pass each. ``q_tile=1`` degenerates to the old
-  per-row decode kernel (one query per grid cell — the shape
-  pure-decode windows dispatch, and the one a mixed dispatch reads its
-  decode rows through: ``forward_ragged`` calls the kernel twice, at
-  ``q_tile`` in blocks over the stream's aligned spans and at 1 over
-  its packed tail, where rows of one token lie side by side).
+  walks its tiles one pass each. ``q_tile=1`` is the **one-query call**
+  (any slot a row of one query — the shape pure-decode windows dispatch,
+  and the one a mixed dispatch reads its decode rows through:
+  ``forward_ragged`` calls the kernel twice, at ``q_tile`` in blocks over
+  the stream's aligned spans and at 1 over its packed tail, where rows of
+  one token lie side by side). There a grid cell serves a block of
+  consecutive slots (:func:`one_query_block`, from the call's heads and
+  lanes: a window's whole bucket at most models' widths) and walks their
+  rows one after another on ONE double-buffered copy pipeline
+  (:func:`_rows_kernel`): while a row's last chunk is multiplied, the
+  first chunk of the next row that has something to read
+  (:func:`one_query_chain`, derived on the device from the positions) is
+  on its way, and the cell's query and output blocks travel once for all
+  its rows. A row's arithmetic is the pass's at one query, state zeroed at
+  its start: to the bit what a cell of one row gives, which is what a
+  stream of one slot and a call under a learned selection (``keep``, whose
+  scores come a tile a cell) still are. Before PR 52 every row was a cell
+  of its own and its first fetch, 16 page copies behind ~1 us of latency,
+  had nothing to hide behind (PERF.md §6, PR 52).
 
 **A latent pool** (multi-head latent attention, ``models/llama.py::
 _mla_half``): both implementations take ``value_dim``, with which a
@@ -224,6 +237,15 @@ def query_block(q_tile: int, q_per_kv: int) -> int:
     return -(-want // q_tile) * q_tile
 
 
+def _to_whole(positions, slots: int, xp):
+    """``positions`` padded with ``-1`` (no query) to whole cells of
+    ``slots``."""
+    pad = (-positions.shape[0]) % slots
+    if not pad:
+        return positions
+    return xp.concatenate([positions, xp.full((pad,), -1, positions.dtype)])
+
+
 def block_plan(row_of, positions, q_tile: int, q_block: int, xp=jnp):
     """What each tile and each query block of a flat stream reads:
     ``(tile_row, tile_kvlen, block_row, block_kvlen)``, the stream
@@ -237,10 +259,8 @@ def block_plan(row_of, positions, q_tile: int, q_block: int, xp=jnp):
     the program, ``numpy`` for the engine's count of the same rule."""
     nt = q_block // q_tile
     pad = (-positions.shape[0]) % q_block
+    positions = _to_whole(positions, q_block, xp)
     if pad:
-        positions = xp.concatenate(
-            [positions, xp.full((pad,), -1, positions.dtype)]
-        )
         row_of = xp.concatenate([row_of, xp.zeros((pad,), row_of.dtype)])
     # Alignment makes every tile single-row; padding positions are -1,
     # so the max is the tile's true causal horizon.
@@ -281,11 +301,7 @@ def sliding_starts(positions, q_tile: int, q_block: int, sliding: int, xp=jnp):
     consecutive queries of one row ``1,023 + sliding`` positions, not the
     context. ``xp``: ``jnp`` inside the program, ``numpy`` for the
     engine's count of the same rule."""
-    pad = (-positions.shape[0]) % q_block
-    if pad:
-        positions = xp.concatenate(
-            [positions, xp.full((pad,), -1, positions.dtype)]
-        )
+    positions = _to_whole(positions, q_block, xp)
     lowest = xp.where(positions >= 0, positions, _NO_ROW).reshape(-1, q_tile)
     tile_low = lowest.min(axis=1)
     block_low = tile_low.reshape(-1, q_block // q_tile).min(axis=1)
@@ -296,6 +312,89 @@ def sliding_starts(positions, q_tile: int, q_block: int, sliding: int, xp=jnp):
         ).astype(positions.dtype)
 
     return first(tile_low), first(block_low)
+
+
+# Elements of its query block and of its output block together that a cell
+# of the one-query call may hold (the pipeline keeps two of each: 4 MiB in
+# bfloat16): 128 rows of 32 heads of 128 lanes, 8 of a latent pool's 128
+# absorbed heads.
+_ONE_QUERY_IO_LANES = 1 << 20
+# Its floor: a sublane tile's rows, and the smallest window bucket that has
+# rows to put behind one another.
+_ONE_QUERY_ROWS = 8
+
+
+def one_query_block(n: int, heads: int, width: int, value_width: int) -> int:
+    """Rows (slots) a grid cell of the one-query call serves on one copy
+    pipeline, from the call's shapes alone: as many as keep the cell's
+    query and output blocks (``heads`` padded to a sublane tile of 16, of
+    ``width`` and ``value_width`` lanes) inside ``_ONE_QUERY_IO_LANES``, a
+    power of two and never under ``_ONE_QUERY_ROWS``; the whole stream
+    where it has no more rows than that. The kernel's caller and the
+    engine's counter (``dynamo_attn_one_query_rows_total``) both ask here."""
+    a_row = -(-heads // 16) * 16 * (width + value_width)
+    fit = max(_ONE_QUERY_ROWS, _ONE_QUERY_IO_LANES // a_row)
+    return min(n, 1 << (fit.bit_length() - 1))
+
+
+def one_query_chain(positions, rows: int, xp=jnp):
+    """The order a cell of the one-query call fetches in: ``(lead, after)``,
+    the stream padded with ``-1`` to whole cells of ``rows`` slots. ``lead``
+    [cells]: the first slot of each cell that has something to read (counted
+    within the cell; ``rows`` where none has), whose first chunk the cell
+    starts with nothing before it. ``after`` [cells * rows]: for each slot
+    the next such slot behind it in its cell (``rows``: none), whose first
+    chunk is started behind this slot's last. Slots of no query are stepped
+    over: they cost no copy. ``xp``: ``jnp`` inside the program, ``numpy``
+    for the engine's count of the same rule."""
+    at = xp.arange(rows, dtype=positions.dtype)
+    live = xp.where(_to_whole(positions, rows, xp).reshape(-1, rows) >= 0, at, rows)
+    # [cell, i, k]: slot k where it is live and behind i, else ``rows``
+    behind = xp.where(at[None, :, None] < at[None, None, :], live[:, None, :], rows)
+    return live.min(axis=1), behind.min(axis=2).reshape(-1)
+
+
+def one_query_fetches(positions, rows: int) -> tuple[int, int]:
+    """Of the live slots of a one-query call's stream (host array; a
+    window gives ``[steps, slots]``, a step a call), those whose first
+    chunk is fetched behind another row of their cell and those whose
+    fetch nothing hides, the first live one of each cell of ``rows``:
+    :func:`one_query_chain`'s rule, as the kernel walks it. At one row a
+    cell every fetch is exposed."""
+    live = np.asarray(positions) >= 0
+    live = live.reshape(-1, live.shape[-1])
+    live = np.pad(live, ((0, 0), (0, (-live.shape[1]) % rows)))
+    exposed = int(live.reshape(-1, rows).any(axis=1).sum())
+    return int(live.sum()) - exposed, exposed
+
+
+def _chunk_copies(row, base, slot, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
+                 *, cp: int, pmax: int):
+    """The ``cp`` page copies (``2 * cp`` with a value pool) of one chunk,
+    entries ``base`` on of ``row``'s table, into buffer ``slot``.
+
+    Page indices beyond the row's table are clamped to a valid
+    entry: the DMA still runs (keeping semaphore accounting static)
+    and the tokens are masked out of the softmax below. (Hkv, D)
+    are pre-collapsed into one lane dimension so every copy slices
+    only leading (untiled) dims — Mosaic rejects slices of a lane
+    dim narrower than the 128-lane tile."""
+    dmas = []
+    for j in range(cp):
+        idx = jnp.minimum(base + j, pmax - 1)
+        pid = table_ref[row, idx]
+        dmas.append(
+            pltpu.make_async_copy(
+                k_hbm.at[pid], k_buf.at[slot, j], sems.at[slot, 2 * j]
+            )
+        )
+        if v_hbm is not None:
+            dmas.append(
+                pltpu.make_async_copy(
+                    v_hbm.at[pid], v_buf.at[slot, j], sems.at[slot, 2 * j + 1]
+                )
+            )
+    return dmas
 
 
 def _attend(
@@ -326,11 +425,21 @@ def _attend(
     keep=None,
     sliding: int | None = None,
     first=None,
+    chain=None,
 ):
     """One double-buffered pass over ``row``'s first ``kvlen`` tokens
     for ``w`` queries: flash-style online softmax, ``qpk * w`` score
     rows a KV head. Every DMA it starts it waits for, so passes follow
     one another in a grid cell on the same buffers and semaphores.
+    ``chain`` (a cell of the one-query call, :func:`_rows_kernel`) makes
+    the passes of a cell ONE copy pipeline: ``(slot0, after_row,
+    after_page, after_live)``, scalars. The pass's first chunk is then
+    already on its way into buffer ``slot0`` (the pass before it, or the
+    cell, started it), and behind its LAST chunk it starts the first chunk
+    of the pass that follows, page ``after_page`` on of ``after_row``'s
+    table, where ``after_live`` says there is one: still every copy
+    started is waited for, by the pass it was started for. The pass must
+    have something to walk.
     ``sliding`` (a sliding layer's width, static) with ``first`` (a
     scalar: the smallest position any of the pass's queries sees): the
     walk starts at ``first``'s page and not at the row's, a chunk is
@@ -358,41 +467,24 @@ def _attend(
     head = slice(None) if whole else slice(0, n)
     used = ... if whole else (head, slice(None))
 
-    def chunk_dmas(c, slot):
-        """The 2*cp page copies of chunk ``c`` into buffer ``slot``.
+    pools = (table_ref, k_hbm, v_hbm, k_buf, v_buf, sems)
 
-        Page indices beyond the row's table are clamped to a valid
-        entry: the DMA still runs (keeping semaphore accounting static)
-        and the tokens are masked out of the softmax below. (Hkv, D)
-        are pre-collapsed into one lane dimension so every copy slices
-        only leading (untiled) dims — Mosaic rejects slices of a lane
-        dim narrower than the 128-lane tile."""
-        dmas = []
+    def chunk_dmas(c, slot):
+        """The page copies of this pass's chunk ``c`` into buffer ``slot``."""
         base = c * cp if sliding is None else c * cp + page0
-        for j in range(cp):
-            idx = jnp.minimum(base + j, pmax - 1)
-            pid = table_ref[row, idx]
-            dmas.append(
-                pltpu.make_async_copy(
-                    k_hbm.at[pid], k_buf.at[slot, j], sems.at[slot, 2 * j]
-                )
-            )
-            if vd is None:
-                dmas.append(
-                    pltpu.make_async_copy(
-                        v_hbm.at[pid], v_buf.at[slot, j], sems.at[slot, 2 * j + 1]
-                    )
-                )
-        return dmas
+        return _chunk_copies(row, base, slot, *pools, cp=cp, pmax=pmax)
 
     acc_ref[used] = jnp.zeros((n, dv), jnp.float32)
     m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
     l_ref[used] = jnp.zeros((n, 128), jnp.float32)
 
-    @pl.when(n_chunks > 0)
-    def _():
-        for dma in chunk_dmas(0, 0):
-            dma.start()
+    if chain is None:
+        @pl.when(n_chunks > 0)
+        def _():
+            for dma in chunk_dmas(0, 0):
+                dma.start()
+    else:
+        slot0, after_row, after_page, after_live = chain
 
     pos_rows = pos_rows()
     if keep is not None:
@@ -412,13 +504,30 @@ def _attend(
     S = cp * ps
 
     def body(c, ties_seen):
-        slot = jax.lax.rem(c, 2)
-        next_slot = jax.lax.rem(c + 1, 2)
+        if chain is None:
+            slot = jax.lax.rem(c, 2)
+            next_slot = jax.lax.rem(c + 1, 2)
 
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            for dma in chunk_dmas(c + 1, next_slot):
-                dma.start()
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                for dma in chunk_dmas(c + 1, next_slot):
+                    dma.start()
+        else:
+            # One set of copies for what comes next, whichever it is: this
+            # row's chunk c + 1, or the first chunk of the row that follows.
+            slot = jax.lax.rem(c + slot0, 2)
+            next_slot = 1 - slot
+            more = c + 1 < n_chunks
+            own = (c + 1) * cp if sliding is None else (c + 1) * cp + page0
+
+            @pl.when(more | after_live)
+            def _():
+                for dma in _chunk_copies(
+                    jnp.where(more, row, after_row),
+                    jnp.where(more, own, after_page),
+                    next_slot, *pools, cp=cp, pmax=pmax,
+                ):
+                    dma.start()
 
         for dma in chunk_dmas(c, slot):
             dma.wait()
@@ -637,6 +746,86 @@ def _block_kernel(
         jax.lax.fori_loop(0, nt, tile, 0)
 
 
+def _rows_kernel(
+    # scalar prefetch (SMEM): the stream's rows and bounds, one_query_chain's
+    # two, and the page table
+    row_ref,  # [C*rb] int32 — owning batch row per slot
+    pos_ref,  # [C*rb] int32 — last kv position each slot's query sees
+    lead_ref,  # [C] int32
+    after_ref,  # [C*rb] int32
+    table_ref,
+    # inputs, output, scratch: _attend's
+    q_ref,  # [rb, H, D] VMEM — this cell's queries, one a row
+    *refs,  # _pools_out_scratch's
+    rb: int,
+    qpk: int,
+    **shape,
+):
+    """A grid cell of the one-query call is ``rb`` consecutive slots, each
+    a row of one query, walked one after another on ONE copy pipeline
+    (``_attend``'s ``chain``): the cell starts its first live row's first
+    chunk, and from then on whatever is multiplied, the chunk that follows
+    it, of the same row or the first of the next live one, is on its way
+    into the other buffer. A row's own arithmetic is ``_attend``'s at one
+    query, state zeroed at its start. A slot of no query copies nothing
+    and is zeros. With ``sliding`` (in ``shape``) a sixth prefetched array
+    comes behind the table: each slot's first visible position
+    (``sliding_starts``), whose page its walk starts at."""
+    first_ref = None
+    if shape.get("sliding") is not None:
+        first_ref, q_ref, *refs = q_ref, *refs
+    k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
+    k_buf, v_buf, *_, sems = scratch
+    ps, cp = shape["ps"], shape["cp"]
+    cell = pl.program_id(0)
+    base = cell * rb
+
+    def first_page(t):
+        return 0 if first_ref is None else first_ref[t] // ps
+
+    lead = lead_ref[cell]
+
+    @pl.when(lead < rb)
+    def _():
+        t = base + lead
+        for dma in _chunk_copies(
+            row_ref[t], first_page(t), 0, table_ref, k_hbm, v_hbm, k_buf,
+            v_buf, sems, cp=cp, pmax=shape["pmax"],
+        ):
+            dma.start()
+
+    def row(j, slot0):
+        t = base + j
+        pos = pos_ref[t]
+        at = pl.ds(j, 1)
+        # The pass walks from its first page to its bound: at least a chunk
+        # where it has a query, so a copy started for it is waited for.
+        n_chunks = jnp.where(
+            pos >= 0, pl.cdiv(pos + 1 - first_page(t) * ps, ps * cp), 0
+        )
+
+        @pl.when(pos >= 0)
+        def _():
+            after = after_ref[t]
+            then = base + jnp.minimum(after, rb - 1)
+            start = {} if first_ref is None else {"first": first_ref[t]}
+            _attend(
+                q_ref.at[at], o_ref.at[at],
+                lambda: jnp.full((qpk, 1), pos, jnp.int32), row_ref[t],
+                pos + 1, table_ref, k_hbm, v_hbm, *scratch, w=1, qpk=qpk,
+                chain=(slot0, row_ref[then], first_page(then), after < rb),
+                **start, **shape,
+            )
+
+        @pl.when(pos < 0)
+        def _():
+            o_ref[at] = jnp.zeros((1, *o_ref.shape[1:]), o_ref.dtype)
+
+        return jax.lax.rem(slot0 + n_chunks, 2)
+
+    jax.lax.fori_loop(0, rb, row, 0)
+
+
 def _block_vmem_bytes(qb, H, D, kv_lanes, cp, ps, q_item, kv_item) -> int:
     """VMEM a block cell holds, from its shapes: the query and output
     blocks (double-buffered by the pipeline, heads padded to a sublane
@@ -669,7 +858,7 @@ def ragged_paged_attention(
     sm_scale: float | None = None,
     interpret: bool = False,
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
-    q_block: int | None = None,  # slots a grid cell serves; None = q_tile
+    q_block: int | None = None,  # slots a grid cell serves; None: the rule's
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
     keep: tuple[jnp.ndarray, ...] | None = None,  # a learned selection
     sliding: int | None = None,  # a sliding layer's width (keys a query sees)
@@ -688,12 +877,15 @@ def ragged_paged_attention(
     masked at its own bound, and a cell that holds several rows walks
     its tiles one after another: either way a query's softmax sees the
     same chunks in the same order as in a cell of its tile alone.
-    ``q_tile=1`` needs no alignment: any slot is a cell of its own (one
-    query per row, the old paged-decode kernel's grid; a decode window's
-    step and the packed tail of a mixed stream). Returns [N, H, D] in
-    q's dtype. Padding slots return exact zeros, inside a live tile as
-    in a fully-empty one (inactive rows): routed experts sort every row
-    of a dispatch together
+    ``q_tile=1`` needs no alignment: any slot is a row of one query (a
+    decode window's step and the packed tail of a mixed stream), and a
+    cell walks ``q_block`` of them on one copy pipeline, each row's first
+    chunk fetched behind the row before it (None:
+    :func:`one_query_block`'s, from the call's shapes; 1: a cell a row,
+    the old paged-decode kernel's grid, which a call with ``keep`` always
+    takes). Returns [N, H, D] in q's dtype. Padding slots return exact
+    zeros, inside a live tile as in a fully-empty one (inactive rows):
+    routed experts sort every row of a dispatch together
     (``ops/moe.py``), so a padding row must not carry what memory no
     request owns into the rows beside it.
 
@@ -731,13 +923,17 @@ def ragged_paged_attention(
     scale = sm_scale if sm_scale is not None else D**-0.5
     cp = max(1, min(_CHUNK_TOKENS // ps, pmax))
     qt = q_tile
-    qb = q_block if q_block is not None else qt
-    nt = qb // qt
-    shape = dict(
-        ps=ps, cp=cp, hkv=Hkv, hd=D, qpk=qpk, qt=qt, pmax=pmax, scale=scale
-    )
     latent = value_dim is not None
     Dv = value_dim if latent else D
+    # Rows a cell of the one-query call walks on one copy pipeline; 1: a
+    # cell is a tile (a learned selection's scores come a tile a cell).
+    rb = 1
+    if qt == 1 and keep is None:
+        rb = q_block if q_block is not None else one_query_block(N, H, D, Dv)
+    # Slots a cell of the tiled forms serves (the rows form has rows, not tiles).
+    qb = qt if rb > 1 or q_block is None else q_block
+    nt = qb // qt
+    shape = dict(ps=ps, cp=cp, hkv=Hkv, hd=D, qpk=qpk, pmax=pmax, scale=scale)
     pools = (k_cache,) if latent else (k_cache, v_cache)
     starts = ()
     if sliding is not None:
@@ -756,20 +952,50 @@ def ragged_paged_attention(
             ))
         )
 
-    def query_spec(width=D):
+    def query_spec(width=D, slots=qb):
         return pl.BlockSpec(
-            (qb, H, width), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
+            (slots, H, width), lambda t, *_: (t, 0, 0), memory_space=pltpu.VMEM
         )
 
+    def scratch_for(queries):
+        """``_attend``'s buffers and states for passes of ``queries``."""
+        return [
+            *(pltpu.VMEM((2, cp, ps, Hkv * D), pool.dtype) for pool in pools),
+            pltpu.VMEM((H * queries, Dv), jnp.float32),
+            pltpu.VMEM((H * queries, 128), jnp.float32),
+            pltpu.VMEM((H * queries, 128), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2 * cp)),
+        ]
+
     pool_specs = [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
-    scratch_shapes = [
-        *(pltpu.VMEM((2, cp, ps, Hkv * D), pool.dtype) for pool in pools),
-        pltpu.VMEM((H * qb, Dv), jnp.float32),
-        pltpu.VMEM((H * qb, 128), jnp.float32),
-        pltpu.VMEM((H * qb, 128), jnp.float32),
-        pltpu.SemaphoreType.DMA((2, 2 * cp)),
-    ]
     out_shape = jax.ShapeDtypeStruct((N, H, Dv), q.dtype)
+    if rb > 1:
+        # One query a row, ``rb`` rows a cell on one copy pipeline. The last
+        # cell of a stream that is no whole number of them reads past the
+        # queries' end: its rows are padded here to no query, and what it
+        # writes there is dropped.
+        lead, after = one_query_chain(positions, rb)
+        n_cells = lead.shape[0]
+        over = (0, n_cells * rb - N)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5 + len(starts[:1]),
+            grid=(n_cells,),
+            in_specs=[query_spec(slots=rb), *pool_specs],
+            out_specs=query_spec(Dv, rb),
+            scratch_shapes=scratch_for(1),
+        )
+        return pl.pallas_call(
+            functools.partial(_rows_kernel, rb=rb, **shape),
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            interpret=interpret,
+            **params,
+        )(
+            jnp.pad(row_of, over), jnp.pad(positions, over, constant_values=-1),
+            lead, after, page_table, *(jnp.pad(s, over) for s in starts[:1]),
+            q, *pools,
+        )
+    scratch_shapes = scratch_for(qb)
     if nt == 1:
         # Per-tile row + DMA bound, derived on device from the flat stream
         # (alignment makes every tile single-row; padding positions are -1
@@ -802,7 +1028,7 @@ def ragged_paged_attention(
             scratch_shapes=scratch_shapes,
         )
         return pl.pallas_call(
-            functools.partial(_tile_kernel, **shape),
+            functools.partial(_tile_kernel, qt=qt, **shape),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
@@ -844,7 +1070,7 @@ def ragged_paged_attention(
         vmem_limit_bytes=max(16 << 20, 2 * vmem)
     ))
     return pl.pallas_call(
-        functools.partial(_block_kernel, nt=nt, **shape),
+        functools.partial(_block_kernel, qt=qt, nt=nt, **shape),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,

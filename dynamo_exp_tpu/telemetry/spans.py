@@ -240,6 +240,20 @@ class Telemetry:
             ["pass"],  # merged | tile
             registry=self.registry,
         )
+        self.attn_one_query_rows = Counter(
+            "dynamo_attn_one_query_rows_total",
+            "Live rows of the attention kernel's one-query call (every step "
+            "of a decode window, the packed tail of a mixed dispatch) by how "
+            "their first chunk of context is fetched: behind (started while "
+            "the row before it in its grid cell was still multiplied) or "
+            "exposed (the first live row of a cell, or a model whose cell is "
+            "one row: nothing hides the fetch). Counted by the dispatch "
+            "builders with the kernel's own rule (ops/ragged_attention.py::"
+            "one_query_chain; a window's rows by the steps the host foresees); "
+            "a kernel engine only",
+            ["fetch"],  # behind | exposed
+            registry=self.registry,
+        )
         self.launch_transfers = Counter(
             "dynamo_launch_transfers_total",
             "Host arrays handed to the device by ragged launches, by "

@@ -33,6 +33,9 @@ from dynamo_exp_tpu.ops.attention import paged_attention
 from dynamo_exp_tpu.ops.ragged_attention import (
     block_plan,
     merged_slot_counts,
+    one_query_block,
+    one_query_chain,
+    one_query_fetches,
     query_block,
     ragged_decode_attention,
     ragged_paged_attention,
@@ -130,33 +133,67 @@ def test_reference_matches_paged_attention_per_token():
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(direct))
 
 
-@pytest.mark.parametrize("sliding", [None, 3, 8])
-def test_decode_shape_matches_reference_and_zeroes_inactive(sliding):
+# What a window's step, or a mixed stream's packed tail, may look like to a
+# grid cell of the one-query call: (lengths a row, rows a cell; None: what
+# the call's shapes give, here all the rows in one cell).
+_DECODE_ROWS = {
+    "short": ([1, 17, 0, 5], None),
+    # dead rows first, in the middle and last; rows of one chunk (of 128
+    # tokens: 8 pages of 16), of two and of three side by side
+    "dead_and_long_in_one_cell": ([0, 0, 5, 128, 0, 129, 300, 1, 0], None),
+    # the same stream in cells of 4, 4 and 1: no whole number of cells, a
+    # cell whose first live row is two slots in, a last cell that is dead
+    "cells_of_four": ([0, 0, 5, 128, 0, 129, 300, 1, 0], 4),
+}
+
+
+@pytest.mark.parametrize("sliding,rows", [
+    (None, "short"), (3, "short"), (8, "short"),
+    (None, "dead_and_long_in_one_cell"), (8, "dead_and_long_in_one_cell"),
+    (None, "cells_of_four"),
+])
+def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows):
     """q_tile=1, one query per row — the compiled decode window's
     per-step shape. Rows with length 0 return exact zeros. With a sliding
-    width a row's walk starts at its newest ``sliding`` keys' page: the
-    pages before it may hold anything (here: NaN)."""
-    lengths = [1, 17, 0, 5]
-    H, Hkv, D, ps, pmax = 4, 4, 32, 8, 8
+    width a row's walk starts at its newest ``sliding`` keys' page, each
+    row's its own: the pages before it may hold anything (here: NaN).
+    The rows of a cell share one copy pipeline (a row's first chunk is
+    fetched behind the row before it): to the bit what a cell a row gives."""
+    lengths, q_block = _DECODE_ROWS[rows]
+    short = rows == "short"
+    H, Hkv, D, ps, pmax = (4, 4, 32, 8, 8) if short else (2, 1, 32, 16, 20)
     spans = [(1, max(ln, 1)) for ln in lengths]
     q, k, v, table, row_of, positions = _flat_batch(
-        2, spans, H, Hkv, D, 64, ps, pmax, q_tile=1
+        2, spans, H, Hkv, D, 128, ps, pmax, q_tile=1
     )
+    n = len(lengths)
     lens = jnp.asarray(lengths, jnp.int32)
     want = ragged_paged_attention_ref(
-        q, k, v, table, jnp.arange(4, dtype=jnp.int32), lens - 1,
+        q, k, v, table, jnp.arange(n, dtype=jnp.int32), lens - 1,
         num_kv_heads=Hkv, window=sliding,
     )
     if sliding is not None:
-        # row 1's first page (positions 0..7) lies wholly behind 17 - 8: a
-        # page of its own that no other entry of the table names
-        gone = 63
-        table = table.at[1, 0].set(gone)
+        # the pages that lie wholly behind a row's window: one page of NaN
+        # that no other entry of the table names
+        gone = 127
+        for r, ln in enumerate(lengths):
+            table = table.at[r, : max(ln - sliding, 0) // ps].set(gone)
         k, v = k.at[gone].set(jnp.nan), v.at[gone].set(jnp.nan)
-    got = ragged_decode_attention(
-        q, k, v, table, lens, num_kv_heads=Hkv, interpret=True,
-        sliding=sliding,
-    )
+    if short:
+        got = ragged_decode_attention(
+            q, k, v, table, lens, num_kv_heads=Hkv, interpret=True,
+            sliding=sliding,
+        )
+    else:
+        got, a_row = (
+            ragged_paged_attention(
+                q, k, v, table, jnp.arange(n, dtype=jnp.int32), lens - 1,
+                num_kv_heads=Hkv, q_tile=1, interpret=True, sliding=sliding,
+                q_block=block,
+            )
+            for block in (q_block, 1)
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(a_row))
     out = np.asarray(got)
     active = np.asarray(lengths) > 0
     np.testing.assert_allclose(
@@ -165,15 +202,18 @@ def test_decode_shape_matches_reference_and_zeroes_inactive(sliding):
     assert (out[~active] == 0.0).all()
 
 
-def test_gqa_grouping():
+@pytest.mark.parametrize("q_tile", [4, 1])
+def test_gqa_grouping(q_tile):
     # 8 query heads over 2 kv heads: groups must read their own kv head.
+    # At one query a cell's rows (here a row's consecutive tokens: a span
+    # that ran on into a packed tail) follow each other on one pipeline.
     spans = [(2, 23), (3, 7)]
     H, Hkv, D, ps, pmax = 8, 2, 32, 16, 8
     q, k, v, table, row_of, positions = _flat_batch(
-        3, spans, H, Hkv, D, 16, ps, pmax, q_tile=4
+        3, spans, H, Hkv, D, 16, ps, pmax, q_tile=q_tile
     )
     got = ragged_paged_attention(
-        q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=4,
+        q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=q_tile,
         interpret=True,
     )
     want = ragged_paged_attention_ref(
@@ -183,6 +223,12 @@ def test_gqa_grouping():
     np.testing.assert_allclose(
         np.asarray(got)[live], np.asarray(want)[live], atol=2e-5
     )
+    if q_tile == 1:
+        a_row = ragged_paged_attention(
+            q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=1,
+            interpret=True, q_block=1,
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(a_row))
 
 
 def test_bfloat16_cache():
@@ -458,6 +504,50 @@ def test_host_count_and_device_rule_agree_on_a_drawn_stream(seed):
     assert kinds >= {-1, 1}  # blocks of one row and of several were drawn
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed):
+    """``dynamo_attn_one_query_rows_total``: the engine counts on the host
+    with ``numpy`` which rows of a one-query call have their first chunk
+    fetched behind another row; the kernel walks ``one_query_chain``'s
+    ``(lead, after)``, planned inside the program with ``jax.numpy``: one
+    function, the same answer slot for slot on a drawn window (rows that
+    park after some of its steps, a bucket wider than its rows) and a drawn
+    packed tail, and a walk of the chain as the kernel makes it (a cell
+    starts its ``lead``, every row starts its ``after``) visits each live
+    row once: started by the cell it is exposed, by a row behind."""
+    rng = np.random.default_rng(seed)
+    nb, steps = int(rng.choice([8, 16, 32])), 8
+    window = np.full((steps, nb), -1, np.int32)
+    for r in rng.permutation(nb)[: int(rng.integers(1, nb + 1))]:
+        window[: int(rng.integers(1, steps + 1)), r] = int(rng.integers(0, 900))
+    tail = np.where(rng.random(24) < 0.6, rng.integers(0, 900, 24), -1).astype(np.int32)
+    for positions in (window, tail):
+        for rows in (one_query_block(positions.shape[-1], 4, 32, 32), 5, 1):
+            behind = exposed = 0
+            for stream in positions.reshape(-1, positions.shape[-1]):
+                lead, after = one_query_chain(stream, rows, xp=np)
+                if stream is positions.reshape(-1, positions.shape[-1])[0]:
+                    on_device = one_query_chain(jnp.asarray(stream), rows)
+                    np.testing.assert_array_equal(lead, np.asarray(on_device[0]))
+                    np.testing.assert_array_equal(after, np.asarray(on_device[1]))
+                padded = np.pad(stream, (0, (-len(stream)) % rows), constant_values=-1)
+                seen = []
+                for cell, at in enumerate(lead):
+                    exposed += at < rows
+                    while at < rows:
+                        seen.append(cell * rows + at)
+                        at = after[cell * rows + at]
+                        behind += at < rows
+                assert seen == list(np.flatnonzero(padded >= 0))
+            assert (behind, exposed) == one_query_fetches(positions, rows)
+            if rows == 1:
+                assert behind == 0
+    assert one_query_block(64, 32, 128, 128) == 64  # a bucket of Qwen3's: one cell
+    assert one_query_block(256, 32, 128, 128) == 128
+    assert one_query_block(32, 128, 640, 512) == 8  # a latent pool's absorbed heads
+    assert one_query_block(1, 32, 128, 128) == 1
+
+
 def test_engine_counts_every_tiled_slot_under_one_pass_label():
     """``dynamo_attn_query_slots_total``: a kernel engine's mixed
     builder counts the live slots it laid into the stream's tiled part,
@@ -465,12 +555,14 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
     nothing."""
     from dynamo_exp_tpu.telemetry import get_telemetry
 
-    def read():
+    def read(name="dynamo_attn_query_slots_total"):
         out = {}
         for line in get_telemetry().render().decode().splitlines():
-            if line.startswith("dynamo_attn_query_slots_total{"):
+            if line.startswith(name + "{"):
                 out[line.split('"')[1]] = float(line.rsplit(" ", 1)[1])
         return out
+
+    rows_total = "dynamo_attn_one_query_rows_total"
 
     for interpret in (True, False):
         eng = _mixed_engine(
@@ -485,7 +577,15 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
             count(nb, row_of, positions)
 
         eng._count_attn_passes = spy
-        before = read()
+        offered = []
+        count_rows = eng._count_one_query_rows
+
+        def spy_rows(positions, count_rows=count_rows, offered=offered):
+            offered.append(int((np.asarray(positions) >= 0).sum()))
+            count_rows(positions)
+
+        eng._count_one_query_rows = spy_rows
+        before, rows_before = read(), read(rows_total)
         eng.start()
         try:
             prompt = list(np.random.RandomState(5).randint(3, 200, size=70))
@@ -494,13 +594,28 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
         finally:
             eng.stop()
         grew = {k: v - before.get(k, 0.0) for k, v in read().items()}
+        rows = {
+            k: v - rows_before.get(k, 0.0) for k, v in read(rows_total).items()
+        }
         assert seen and sum(seen) > 0
         if interpret:
             # TINY has 4 query heads on 2 KV heads: blocks of 64.
             assert sum(grew.values()) == sum(seen)
             assert set(grew) == {"merged", "tile"}
+            # ``dynamo_attn_one_query_rows_total``: every live row the
+            # one-query call was offered (a window's steps; a packed tail,
+            # where the prompt's chunks ran on into it a token a slot),
+            # each under ``behind`` or ``exposed``
+            assert sum(rows.values()) == sum(offered) > 0
+            assert rows["exposed"] > 0 and rows["behind"] > 0
+            # a window of two steps over a bucket of 4 (one cell): three
+            # live rows, then two
+            count_rows(np.array([[5, 9, -1, 7], [6, -1, -1, 8]]))
+            after = read(rows_total)
+            assert after["behind"] - rows_before.get("behind", 0.0) == rows["behind"] + 3
+            assert after["exposed"] - rows_before.get("exposed", 0.0) == rows["exposed"] + 2
         else:
-            assert not any(grew.values())
+            assert not any(grew.values()) and not any(rows.values())
 
 
 # --------------------------------------------------- two-program oracle
@@ -976,6 +1091,13 @@ def test_latent_pool_at_published_widths(q_tile):
     assert got.shape == (24, H, vd)
     assert float(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()) < 2e-2
     assert float(jnp.abs(got[19:].astype(jnp.float32)).max()) == 0.0
+    if q_tile == 1:
+        # cells of 8 rows at these widths (one_query_block), the last one
+        # with five dead rows behind its three: to the bit a cell a row
+        a_row = ragged_paged_attention(*args, q_tile=1, interpret=True, q_block=1, **kw)
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)), np.asarray(a_row.astype(jnp.float32))
+        )
 
 
 # ------------------------------------------- a latent pool, expanded form
@@ -1349,7 +1471,7 @@ def _mixed_program_kernels(cfg):
 
 @pytest.mark.parametrize("kernel,digest", [
     ("expanded", "7d8ed2a8608d9026"), ("absorbed_tiles", "243b96dd1ee6bd56"),
-    ("absorbed_one_query", "c4ce710ae86c0081"),
+    ("absorbed_one_query", "0eda8565ee3ac724"),
 ])
 def test_no_selection_leaves_the_latent_kernels_as_they_were(kernel, digest):
     """tiny-pangu's mixed program traced through the kernels: the body of
@@ -1358,7 +1480,12 @@ def test_no_selection_leaves_the_latent_kernels_as_they_were(kernel, digest):
     what the parent of PR 48 (commit c47eca4) traced. ``keep=None`` and the
     split score layout take nothing from and add nothing to the kernels of
     a latent model without an indexer, while tiny-glm-dsa's differ (they
-    carry the selection)."""
+    carry the selection). The one-query call's body is PR 52's, a block of
+    rows a grid cell on one copy pipeline (``_rows_kernel``; c4ce710ae86c0081
+    before it, a row a cell): its TEXT changed there and its results did
+    not, which ``test_latent_pool_at_published_widths[1]`` and the decode
+    tests hold it to bit for bit; the two tiled calls, which trace the same
+    ``_attend``, read as they did."""
     from dynamo_exp_tpu.models.config import TINY_GLM_DSA, TINY_PANGU
 
     at = ["expanded", "absorbed_tiles", "absorbed_one_query"].index(kernel)

@@ -20,6 +20,21 @@ One JSON line a row bucket on stdout and in
 ``chiprun_out/attn_crossover/<config>.jsonl``. It fails off the chip: a
 CPU time is not a device time (``--allow-cpu`` rehearses the control
 flow and writes ``<config>.cpu-rehearsal.jsonl``, never the chip's file).
+
+Behind them, a line a row bucket for **the one-query call alone**
+(``ops/ragged_attention.py::ragged_paged_attention`` at ``q_tile=1``, what
+a window's step calls once a layer): a chip's share of the heads, random
+queries and pages, every row live at a context of exactly 1, 3 and 32
+chunks of 128 tokens (``--one-query-chunks``), ``--one-query-reps`` calls
+chained inside one program (a call's result is the next one's queries) so
+that the host's dispatch is not in the number. A straight line through the
+three, ``time a row = a_row + a_chunk x chunks``, splits the call into what
+a row costs whatever it reads (its first fetch where nothing hides it, its
+state's zeroing, the divide and the transpose) and what a chunk costs (16
+page copies and a product pair a KV head): PERF.md §6, PR 52.
+``--one-query-rows 1,8,32`` times the same call at those rows a grid cell
+beside the rule's (``one_query_block``; 1 is the one-row cell every call
+was before PR 52), the table behind the rule. A two-pool model only.
 """
 
 from __future__ import annotations
@@ -99,6 +114,64 @@ def time_program(engine, key: tuple, args: list, calls: int) -> list[float]:
     return out
 
 
+def one_query_lines(args, mcfg, ecfg, dev):
+    """The one-query call alone, a line a row bucket: its time at each
+    context of ``--one-query-chunks`` and the fit a row / a chunk."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_exp_tpu.ops.ragged_attention import ragged_paged_attention
+
+    heads, kv_heads, hd = mcfg.num_heads // ecfg.tp, mcfg.num_kv_heads // ecfg.tp, mcfg.head_dim_
+    ps, chunk = ecfg.page_size, 128
+    chunks = [int(c) for c in args.one_query_chunks.split(",")]
+    forms = [None] + [int(r) for r in args.one_query_rows.split(",") if r]
+    interpret = dev.platform != "tpu"
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+    pool = (ecfg.num_pages, ps, kv_heads * hd)
+    k = jax.random.normal(keys[0], pool, jnp.bfloat16)
+    v = jax.random.normal(keys[1], pool, jnp.bfloat16)
+    pages = max(chunks) * chunk // ps
+
+    def chained(q_block):
+        kw = {} if q_block is None else {"q_block": q_block}
+
+        @jax.jit
+        def run(q, table, positions):
+            rows = jnp.arange(q.shape[0], dtype=jnp.int32)
+            return jax.lax.fori_loop(0, args.one_query_reps, lambda _, x: ragged_paged_attention(
+                x, k, v, table, rows, positions, num_kv_heads=kv_heads, q_tile=1,
+                interpret=interpret, **kw,
+            ), q)
+
+        return run
+
+    for nb in (int(r) for r in args.rows.split(",")):
+        rng = np.random.default_rng([args.seed, nb, 2])
+        # each row on pages of its own as far as the pool goes
+        table = (1 + (np.arange(nb * pages) % (ecfg.num_pages - 1))).reshape(nb, pages)
+        table = jnp.asarray(rng.permutation(table.reshape(-1)).reshape(nb, pages), jnp.int32)
+        q = jax.random.normal(keys[2], (nb, heads, hd), jnp.bfloat16)
+        for q_block in forms:
+            if q_block is not None and q_block > nb:
+                continue
+            run, line = chained(q_block), {"one_query": True, "nb": nb, "rows_a_cell": q_block or "rule"}
+            for c in chunks:
+                positions = jnp.full((nb,), c * chunk - 1, jnp.int32)
+                times = []
+                for i in range(args.calls + 2):  # the first call compiles, the second loads
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run(q, table, positions))
+                    if i >= 2:
+                        times.append(time.perf_counter() - t0)
+                line[f"call_us_at_{c}_chunks"] = 1e6 * statistics.median(times) / args.one_query_reps
+            if len(chunks) > 1:
+                a_chunk, a_row = np.polyfit(chunks, [line[f"call_us_at_{c}_chunks"] / nb for c in chunks], 1)
+                line["us_a_row"], line["us_a_chunk"] = float(a_row), float(a_chunk)
+            yield line
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", required=True)
@@ -106,6 +179,11 @@ def main() -> int:
     ap.add_argument("--contexts", default="uniform,chat")
     ap.add_argument("--seed", type=int, default=30)
     ap.add_argument("--calls", type=int, default=12)
+    ap.add_argument("--one-query-chunks", default="1,3,32",
+                    help="contexts of the one-query call alone, in chunks of 128 tokens ('' skips it)")
+    ap.add_argument("--one-query-rows", default="",
+                    help="rows a grid cell to time beside the rule's, e.g. 1,8,32")
+    ap.add_argument("--one-query-reps", type=int, default=128)
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearse the control flow off the chip (tiny sizes; times mean nothing)")
     args = ap.parse_args()
@@ -161,7 +239,8 @@ def main() -> int:
         for kind in args.contexts.split(","):
             for nb in (int(r) for r in args.rows.split(",")):
                 rng = np.random.default_rng([args.seed, nb])
-                contexts = draw_contexts(kind, nb, rng)
+                room = ecfg.max_model_len - ecfg.decode_window - 1  # a tiny rehearsal model's
+                contexts = [min(c, room) for c in draw_contexts(kind, nb, rng)]
                 line = {"contexts": kind, "nb": nb, "context_mean": statistics.mean(contexts),
                         "context_max": max(contexts)}
                 for form, key in (
@@ -175,6 +254,10 @@ def main() -> int:
                     line[f"{form}_step_ms_max"] = 1e3 * max(times) / ecfg.decode_window
                 line["pages_used"] = used
                 line["gather_over_kernel"] = line["gather_step_ms"] / line["kernel_step_ms"]
+                print(json.dumps(line), flush=True)
+                f.write(json.dumps(line) + "\n")
+        if args.one_query_chunks and not (mcfg.is_latent or mcfg.kv_by_kind):
+            for line in one_query_lines(args, mcfg, ecfg, dev):
                 print(json.dumps(line), flush=True)
                 f.write(json.dumps(line) + "\n")
     return 0
