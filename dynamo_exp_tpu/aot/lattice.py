@@ -322,10 +322,22 @@ def _attention_form(cfg, attn_impl: str, interpret: bool) -> str:
     if not m.is_latent:
         # a sliding width the kernel takes as its own argument, and whether
         # the layers' forms have pools of their own (the sliding pool's
-        # shape follows from the fields beside this one)
+        # shape follows from the fields beside this one); behind them what
+        # only a model whose forms differ adds: each form's KV heads at the
+        # key's and the value's width, and which forms have a sink
+        shapes = ""
+        if m.kv_heads_by_form or m.value_head_dim_ != m.head_dim_ or m.sink_forms:
+            shapes = "-" + "+".join(
+                f"{form}:{m.kv_heads_of(form)}x{m.head_dim_}/{m.value_head_dim_}"
+                + ("-sink" if form in m.sink_forms else "")
+                for form in ("full", "sliding") if form in m.attn_forms
+            )
         if attn_impl == "pallas" and "sliding" in m.attn_forms and m.attn_kernel_form:
-            return f"heads-sliding-{m.sliding_window}" + ("-by-kind" if m.kv_by_kind else "")
-        return "heads"
+            return (
+                f"heads-sliding-{m.sliding_window}"
+                + ("-by-kind" if m.kv_by_kind else "") + shapes
+            )
+        return "heads" + shapes
     # the selector: every read masks what the top-k of the index scores
     # left out, inside whichever call reads the row
     selected = (

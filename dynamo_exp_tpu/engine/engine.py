@@ -185,11 +185,18 @@ def resolve_attn_impl(cfg: EngineConfig, mesh: Mesh) -> tuple[str, bool]:
         return impl, interpret
     if impl == "pallas" and not interpret:
         tp = mesh.shape.get("tp", 1)
-        if not ragged_supported(
-            cfg.page_size,
-            cfg.model.num_kv_heads // tp,
-            cfg.model.head_dim_,
-            cfg.kv_dtype_jnp,
+        # every pool's row: K's, and V's and the sliding layers' where a
+        # model's are other widths (mimo_v2_flash)
+        rows = {(mcfg.num_kv_heads // tp, mcfg.head_dim_)}
+        if mcfg.kv_heads_by_form or mcfg.value_head_dim_ != mcfg.head_dim_:
+            rows |= {
+                (mcfg.kv_heads_of(form) // tp, width)
+                for form in ("full", "sliding")
+                for width in (mcfg.head_dim_, mcfg.value_head_dim_)
+            }
+        if not all(
+            ragged_supported(cfg.page_size, heads, width, cfg.kv_dtype_jnp)
+            for heads, width in rows
         ):
             log.warning(
                 "KV layout (ps=%d, Hkv=%d/tp=%d, D=%d, %s) is not "
@@ -365,6 +372,17 @@ class TPUEngine(BlockDecode, AsyncEngine):
             // (pages * page_size),
             index_width=mcfg.index_lanes,
         )
+        # What each form of attention the model has is served as: its
+        # layers, KV heads, a head's key and value widths, whether a sink
+        # logit joins its softmax.
+        get_telemetry().set_attn_form_info([
+            (
+                form, mcfg.attn_forms.count(form), mcfg.kv_heads_of(form),
+                mcfg.head_dim_, mcfg.value_head_dim_, form in mcfg.sink_forms,
+            )
+            for form in ("full", "sliding")
+            if form in mcfg.attn_forms and not mcfg.is_latent
+        ])
         # Bytes a held page of each pool takes over its layers, K and V.
         self._page_bytes = {"full": (k_pool.nbytes + v_pool.nbytes) // pages}
         sliding_pool = None
@@ -1612,13 +1630,20 @@ class TPUEngine(BlockDecode, AsyncEngine):
         cfg = self.cfg
         tiled = nb - cfg.ragged_packed_slots(nb)
         tile = cfg.ragged_q_tile
-        merged, by_tile = merged_slot_counts(
-            row_of[:tiled], positions[:tiled], tile,
-            ragged_query_block(cfg.model, tile),
-        )
+        # A model whose forms of attention differ in their KV heads has a
+        # block a form: each form's rule, by its share of the layers.
+        model = cfg.model
+        blocks: dict[int, int] = {}
+        for form in model.attn_forms:
+            block = ragged_query_block(model, tile, model.kv_heads_of(form))
+            blocks[block] = blocks.get(block, 0) + 1
         slots = get_telemetry().attn_query_slots
-        slots.labels("merged").inc(merged)
-        slots.labels("tile").inc(by_tile)
+        for block, layers in blocks.items():
+            merged, by_tile = merged_slot_counts(
+                row_of[:tiled], positions[:tiled], tile, block
+            )
+            slots.labels("merged").inc(merged * layers / model.num_layers)
+            slots.labels("tile").inc(by_tile * layers / model.num_layers)
         self._count_one_query_rows(positions[tiled:nb])
 
     def _count_one_query_rows(self, positions) -> None:

@@ -201,6 +201,22 @@ class ModelConfig:
     # exaone_moe: only the sliding layers turn q and k by their positions;
     # a full-attention layer has no positional term (``attn_forms``).
     rope_sliding_only: bool = False
+    # mimo_v2_flash: attention's shapes by the layers' form. A sliding
+    # layer has ``sliding_num_kv_heads`` KV heads (0: ``num_kv_heads``, as a
+    # full layer's), so the two page pools of a cache by kind have rows of
+    # their own widths (``kv_pool_widths_of``) and ``wk`` / ``wv`` are
+    # stacked by form (``kv_heads_by_form``). Outside latent attention
+    # ``v_head_dim`` > 0 is a head's value width where it is not the key's
+    # (``value_head_dim_``); the first ``rotary_dim`` values of a head turn
+    # by position and the rest stay (0: all of them; the sliding layers at
+    # ``rope_local_base_freq``); the values are multiplied by
+    # ``value_scale`` ahead of the cache; and the layers of the forms in
+    # ``sink_forms`` have a learned logit a query head that joins the
+    # softmax's denominator and carries no value (``sink``, float32).
+    sliding_num_kv_heads: int = 0
+    rotary_dim: int = 0
+    value_scale: float = 1.0
+    sink_forms: tuple[str, ...] = ()
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -398,14 +414,51 @@ class ModelConfig:
         (``models/llama.py::init_kv_cache``): K's and V's heads side by
         side, or the latent row and, where the model has an indexer, its
         key (else nothing: the values are the latent row's first
-        ``kv_lora_rank`` lanes either way)."""
+        ``kv_lora_rank`` lanes either way). Of a cache by kind: the full
+        layers' pools (``kv_pool_widths_of``)."""
+        return self.kv_pool_widths_of("full")
+
+    def kv_pool_widths_of(self, form: str) -> tuple[int, int]:
+        """``kv_pool_widths`` of the pools that the layers of ``form``
+        ("full" / "sliding") write: a head's keys at ``head_dim_`` lanes
+        and its values at ``value_head_dim_``, ``kv_heads_of(form)`` heads
+        side by side."""
         if self.is_latent:
             return self.latent_lanes, self.index_lanes
-        return (self.num_kv_heads * self.head_dim_,) * 2
+        heads = self.kv_heads_of(form)
+        return heads * self.head_dim_, heads * self.value_head_dim_
+
+    def kv_heads_of(self, form: str) -> int:
+        """KV heads of a layer whose attention is of ``form``."""
+        if form == "sliding" and self.sliding_num_kv_heads:
+            return self.sliding_num_kv_heads
+        return self.num_kv_heads
+
+    @property
+    def kv_heads_by_form(self) -> bool:
+        """Whether sliding and full layers differ in their KV heads: then
+        ``wk`` / ``wv`` hold the full layers' projections and ``swa_wk`` /
+        ``swa_wv`` the sliding layers', each stacked over its form's layers
+        (``models/llama.py::init_params``), and the cache is by kind."""
+        return self.kv_heads_of("sliding") != self.num_kv_heads
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def value_head_dim_(self) -> int:
+        """A head's value width outside latent attention: the key's unless
+        ``v_head_dim`` names another."""
+        return self.v_head_dim or self.head_dim_
+
+    @property
+    def rope_dim(self) -> int:
+        """Values of a head (of the rotary key, in latent attention) that
+        turn by position: what the inverse frequencies are made for."""
+        if self.is_latent:
+            return self.qk_rope_head_dim
+        return self.rotary_dim or self.head_dim_
 
     @property
     def q_per_kv(self) -> int:
@@ -438,6 +491,8 @@ class ModelConfig:
             return cls._from_glm_moe_dsa(cfg)
         if model_type == "exaone_moe":
             return cls._from_exaone_moe(cfg)
+        if model_type == "mimo_v2_flash":
+            return cls._from_mimo_v2_flash(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -890,6 +945,126 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_mimo_v2_flash(cls, cfg: dict) -> "ModelConfig":
+        """``mimo_v2_flash`` (MiMo-V2-Flash): layer ``i`` is full attention
+        where ``hybrid_layer_pattern[i]`` is 0 and sliding (``sliding_window``
+        keys, the query's own among them) where 1. The two forms have KV
+        head counts of their own (``num_key_value_heads`` /
+        ``swa_num_key_value_heads``), keys of ``head_dim`` and values of
+        ``v_head_dim`` (narrower), the values times
+        ``attention_value_scale``; the first ``int(head_dim *
+        partial_rotary_factor)`` values of a head turn by position, at
+        ``rope_theta`` in full layers and ``swa_rope_theta`` in sliding
+        ones; a form with ``add_*_attention_sink_bias`` has a learned sink
+        logit a query head. Two norms a layer (``layernorm_epsilon``); the
+        leading layers whose ``moe_layer_freq`` is 0 are dense SwiGLUs, the
+        rest ``n_routed_experts`` experts top-k by sigmoid scores plus a
+        selection bias, the chosen unbiased scores renormalised
+        (``norm_topk_prob``), with no shared expert unless
+        ``n_shared_experts`` says so. ``n_routed_experts`` is what this
+        device HOLDS where the file also gives ``n_routed_experts_global``
+        (``_expert_share``). Refused, because nothing here computes it or
+        no test has shown it: query heads or head sizes that differ by
+        form, a dense layer behind a routed one, another selection than
+        ``noaux_tc``, a rotary scaling, and what ``_refuse_unserved_moe``
+        names."""
+        name = "mimo_v2_flash"
+        for mine, swa in (
+            ("num_attention_heads", "swa_num_attention_heads"),
+            ("head_dim", "swa_head_dim"),
+            ("v_head_dim", "swa_v_head_dim"),
+        ):
+            if cfg.get(swa, cfg[mine]) != cfg[mine]:
+                raise ValueError(
+                    f"{name} with {swa}={cfg[swa]} != {mine}={cfg[mine]} is not "
+                    "supported: only the KV-head count differs by layer kind"
+                )
+        scaling = cfg.get("rope_scaling") or {}
+        if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+            raise ValueError(f"{name} with a rope_type other than default is not supported")
+        if str(cfg.get("scoring_func", "sigmoid")) != "sigmoid":
+            raise ValueError(f"{name} with scoring_func other than sigmoid is not supported")
+        if str(cfg.get("topk_method", "noaux_tc")) != "noaux_tc":
+            raise ValueError(f"{name} with topk_method other than noaux_tc is not supported")
+        _refuse_unserved_moe(cfg, name)
+        n_layers = cfg["num_hidden_layers"]
+        pattern, freq = list(cfg["hybrid_layer_pattern"]), list(cfg["moe_layer_freq"])
+        if len(pattern) != n_layers or not set(pattern) <= {0, 1}:
+            raise ValueError(
+                f"{name} hybrid_layer_pattern must name {n_layers} layers as 0 "
+                f"(full) or 1 (sliding), got {pattern!r}"
+            )
+        dense = freq.index(1) if 1 in freq else len(freq)
+        if len(freq) != n_layers or freq != [0] * dense + [1] * (n_layers - dense):
+            raise ValueError(
+                f"{name} moe_layer_freq must be leading 0s (dense) then 1s "
+                f"(routed) over {n_layers} layers, got {freq!r}"
+            )
+        slides = 1 in pattern
+        width = cfg.get("sliding_window")
+        if slides and int(width or 0) < 1:
+            raise ValueError(f"{name} with sliding layers needs sliding_window >= 1")
+        if cfg.get("sliding_window_size", width) != width:
+            raise ValueError(f"{name} sliding_window_size disagrees with sliding_window")
+        hd = cfg["head_dim"]
+        dv = cfg.get("v_head_dim", hd)
+        rotary = int(hd * float(cfg.get("partial_rotary_factor", 1.0)))
+        if rotary < 2 or rotary % 2:
+            raise ValueError(f"{name} turns {rotary} of {hd} values a head: not a whole number of pairs")
+        held, routed, first = _expert_share(
+            cfg, name, "n_routed_experts", "n_routed_experts_global"
+        )
+        scale = cfg.get("routed_scaling_factor")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            sliding_num_kv_heads=(
+                cfg.get("swa_num_key_value_heads", cfg["num_key_value_heads"])
+                if slides else 0
+            ),
+            head_dim=hd,
+            v_head_dim=dv if dv != hd else 0,
+            rotary_dim=rotary if rotary != hd else 0,
+            value_scale=float(cfg.get("attention_value_scale") or 1.0),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rope_local_base_freq=(
+                float(cfg["swa_rope_theta"])
+                if slides and cfg.get("swa_rope_theta") is not None else None
+            ),
+            rms_norm_eps=cfg.get("layernorm_epsilon", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 262144),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            layer_types=tuple(
+                "sliding_attention" if p else "full_attention" for p in pattern
+            ),
+            sliding_window=int(width) if slides else None,
+            sink_forms=tuple(
+                form for form, key in (
+                    ("full", "add_full_attention_sink_bias"),
+                    ("sliding", "add_swa_attention_sink_bias"),
+                ) if cfg.get(key, False) and (form == "full" or slides)
+            ),
+            num_experts=routed,
+            num_experts_held=held if held < routed else 0,
+            first_expert_held=first,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            num_shared_experts=cfg.get("n_shared_experts") or 0,
+            num_dense_layers=dense,
+            router_score="sigmoid",
+            use_expert_bias=True,
+            router_norm_eps=1e-20,
+            routed_scaling_factor=1.0 if scale is None else float(scale),
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type=name,
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -969,6 +1144,7 @@ KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
+    "mimo_v2_flash",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -1351,6 +1527,46 @@ TINY_EXAONE_MOE = ModelConfig(  # exaone_moe family shape: sliding and full
     model_type="exaone_moe",
 )
 
+TINY_MIMO = ModelConfig(  # mimo_v2_flash family shape: full and sliding
+    # layers F-dense / S S / F / S S, 1 KV head in a full layer and 2 in a
+    # sliding one (4 and 2 query heads a KV head), keys of 24 and values of
+    # 16, the first 8 of a head's 24 values rotary (base 5e6 full, 1e4
+    # sliding), a width of 8 that is two pages of 4, a sink on the sliding
+    # layers, values times 0.707; one dense layer then five routed ones, 8 of
+    # 16 experts held (ids 8..15) top-2 by sigmoid scores plus a selection
+    # bias, no shared expert; two norms a layer
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=6,
+    num_heads=4,
+    num_kv_heads=1,
+    sliding_num_kv_heads=2,
+    head_dim=24,
+    v_head_dim=16,
+    rotary_dim=8,
+    value_scale=0.707,
+    rope_theta=5000000.0,
+    rope_local_base_freq=10000.0,
+    max_position_embeddings=512,
+    layer_types=(
+        "full_attention", "sliding_attention", "sliding_attention",
+        "full_attention", "sliding_attention", "sliding_attention",
+    ),
+    sliding_window=8,
+    sink_forms=("sliding",),
+    num_experts=16,
+    num_experts_held=8,
+    first_expert_held=8,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    num_dense_layers=1,
+    router_score="sigmoid",
+    use_expert_bias=True,
+    router_norm_eps=1e-20,
+    model_type="mimo_v2_flash",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -1376,6 +1592,7 @@ PRESETS = {
     "tiny-pangu": TINY_PANGU,
     "tiny-glm-dsa": TINY_GLM_DSA,
     "tiny-exaone-moe": TINY_EXAONE_MOE,
+    "tiny-mimo": TINY_MIMO,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

@@ -76,6 +76,12 @@ def _dtype(cfg: ModelConfig):
 # apart at the median, so a bias of this size changes the chosen set of
 # most rows (tests/test_lfm2.py counts them) and moves no weight.
 ROUTER_BIAS_SCALE = 0.1
+# The sink logits of a randomly initialised model (``sink``, a query head
+# and layer): normal about this mean with this deviation. Scores of seeded
+# weights are about unit normal, so a sink near 4 holds about a fifth of a
+# 128-key window's mass: what a trained sink is for, and enough that a
+# program without it, or with another head's, gives other logits.
+SINK_MEAN, SINK_SCALE = 4.0, 1.0
 
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -93,9 +99,16 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     whose feed-forwards are of both kinds keeps its dense layers' under
     ``dense_gate``/``dense_up``/``dense_down``; ``w_gate``/``w_up``/
     ``w_down`` are then the routed layers' (``EXPERT_STACKS``). In a
-    model of one kind every stack is ``[L, ...]``, as it always was."""
+    model of one kind every stack is ``[L, ...]``, as it always was.
+
+    A model whose sliding and full layers differ in their KV heads
+    (``ModelConfig.kv_heads_by_form``) stacks the K and V projections by
+    form: ``wk`` / ``wv`` over the full layers, ``swa_wk`` / ``swa_wv``
+    over the sliding ones (``wq`` and ``wo`` are one shape and stay over
+    all attention layers). ``sink`` (float32 ``[layers of the forms in
+    sink_forms, H]``): the learned logit a query head of those layers."""
     dt = _dtype(cfg)
-    hd = cfg.head_dim_
+    hd, dv = cfg.head_dim_, cfg.value_head_dim_
     L, D, H, Hkv, I, V = (
         cfg.num_layers,
         cfg.hidden_size,
@@ -119,10 +132,23 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         "attn_norm": jnp.ones((L, D), dt),
         "wq": init(ks[1], (La, D, H * hd), D),
         "wk": init(ks[2], (La, D, Hkv * hd), D),
-        "wv": init(ks[3], (La, D, Hkv * hd), D),
-        "wo": init(ks[4], (La, H * hd, D), H * hd),
+        "wv": init(ks[3], (La, D, Hkv * dv), D),
+        "wo": init(ks[4], (La, H * dv, D), H * dv),
         "mlp_norm": jnp.ones((L, D), dt),
     }
+    if cfg.kv_heads_by_form or cfg.sink_forms:  # mimo_v2_flash
+        km = jax.random.split(jax.random.fold_in(rng, 6), 3)
+        Ls, Hs = cfg.num_sliding_layers, cfg.kv_heads_of("sliding")
+        if cfg.kv_heads_by_form:
+            layers["wk"] = init(ks[2], (La - Ls, D, Hkv * hd), D)
+            layers["wv"] = init(ks[3], (La - Ls, D, Hkv * dv), D)
+            layers["swa_wk"] = init(km[0], (Ls, D, Hs * hd), D)
+            layers["swa_wv"] = init(km[1], (Ls, D, Hs * dv), D)
+        sinks = sum(form in cfg.sink_forms for form in cfg.attn_forms)
+        if sinks:
+            layers["sink"] = SINK_MEAN + SINK_SCALE * jax.random.normal(
+                km[2], (sinks, H), jnp.float32
+            )
     if not cfg.pre_norms:  # olmo_hybrid: the halves' outputs are normed alone
         del layers["attn_norm"], layers["mlp_norm"]
     if cfg.is_latent:  # pangu_ultra_moe: latent attention's seven tensors
@@ -354,6 +380,11 @@ def param_shardings(
     }
     if not cfg.pre_norms:
         del layers["attn_norm"], layers["mlp_norm"]
+    if cfg.kv_heads_by_form:
+        layers["swa_wk"] = P(None, None, tp_axis)
+        layers["swa_wv"] = P(None, None, tp_axis)
+    if cfg.sink_forms:
+        layers["sink"] = P(None, tp_axis)
     if cfg.is_latent:
         # Replicated: a latent cache has one head, and tp > 1 is refused
         # (``engine/config.py::refuse_latent_tiers``).
@@ -460,7 +491,11 @@ def init_kv_cache(
     and full attention layers in one stack) has each of K and V as a PAIR
     ``(full, sliding)``: ``[L_full, num_pages, ps, Hkv*D]`` and
     ``[L_sliding, sliding_pages, ps, Hkv*D]`` (``sliding_pages`` defaults to
-    ``num_pages``). The two have page ids, page tables and free lists of
+    ``num_pages``), each at its own form's KV heads and K's and V's own
+    head widths (``ModelConfig.kv_pool_widths_of``: a model whose sliding
+    layers have other KV heads than its full ones, or values narrower than
+    keys, has pools of four row widths). The two have page ids, page tables
+    and free lists of
     their own (``engine/kv_manager.py``), and every paged forward takes its
     ``page_table`` as the same kind of pair: a sliding layer's pages are
     given back behind its width, so its pool is sized by the dispatch and
@@ -468,18 +503,20 @@ def init_kv_cache(
     """
     dt = dtype or _dtype(cfg)
 
-    def pools(layers, pages):
+    def pools(layers, pages, form="full"):
         lead = (layers, pages, page_size)
         return tuple(
             jnp.zeros((*lead, width), dt, device=sharding)
-            for width, sharding in zip(cfg.kv_pool_widths, shardings)
+            for width, sharding in zip(cfg.kv_pool_widths_of(form), shardings)
         )
 
     if not cfg.kv_by_kind:
         return pools(cfg.num_attn_layers, num_pages)
     n_sliding = cfg.num_sliding_layers
     full = pools(cfg.num_attn_layers - n_sliding, num_pages)
-    sliding = pools(n_sliding, num_pages if sliding_pages is None else sliding_pages)
+    sliding = pools(
+        n_sliding, num_pages if sliding_pages is None else sliding_pages, "sliding"
+    )
     return (full[0], sliding[0]), (full[1], sliding[1])
 
 
@@ -635,9 +672,13 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
     """A layer's first half where its operator is attention: norm,
     projections, rope, ``attend``, output projection, residual add.
     ``rope`` False: this layer has no positional term though the model's
-    other layers do (exaone_moe's full-attention layers)."""
+    other layers do (exaone_moe's full-attention layers). The KV heads
+    come from the weights' shapes (a model's forms may differ in them); a
+    head's values may be narrower than its keys (``value_head_dim_``) and
+    scaled (``value_scale``); with ``rotary_dim`` only a head's first values
+    turn."""
     B, T = x.shape[:2]
-    hd = cfg.head_dim_
+    hd, dv = cfg.head_dim_, cfg.value_head_dim_
     off = cfg.rms_norm_offset
     with jax.named_scope("qkv"):
         # olmo_hybrid's block has no norm here: its output is normed below
@@ -651,14 +692,16 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
             k = rms_norm(k, lp["k_norm"], eps, off)
         q = q.reshape(B, T, lp["wq"].shape[-1] // hd, hd)
         k = k.reshape(B, T, lp["wk"].shape[-1] // hd, hd)
-        v = v.reshape(B, T, lp["wv"].shape[-1] // hd, hd)
+        v = v.reshape(B, T, lp["wv"].shape[-1] // dv, dv)
+        if cfg.value_scale != 1.0:  # mimo_v2_flash: ahead of the cache
+            v = v * jnp.asarray(cfg.value_scale, v.dtype)
         if "q_norm" in lp and not whole:  # qwen3/gemma3: per-head RMSNorm before rope
             # (gemma3 stores these gemma-style: scale = 1 + w)
             q = rms_norm(q, lp["q_norm"], eps, off)
             k = rms_norm(k, lp["k_norm"], eps, off)
         if cfg.use_rope and rope:  # jamba's attention has no positional term
-            q = apply_rope(q, rope_pos, inv_freq)
-            k = apply_rope(k, rope_pos, inv_freq)
+            q = _turn(q, rope_pos, inv_freq, cfg.rotary_dim)
+            k = _turn(k, rope_pos, inv_freq, cfg.rotary_dim)
     attn, kv_extra = attend(q, k, v)
     with jax.named_scope("attn_out"):
         attn_out = red(attn.reshape(B, T, -1) @ lp["wo"])
@@ -666,6 +709,16 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
             attn_out = rms_norm(attn_out, lp["post_attn_norm"], eps, off)
         x = x + attn_out
     return x, kv_extra
+
+
+def _turn(x, rope_pos, inv_freq, rotary_dim: int):
+    """``apply_rope`` over a head's first ``rotary_dim`` values (its pairs
+    ``(i, i + rotary_dim / 2)``), the rest as they are; 0: the whole head."""
+    if not rotary_dim:
+        return apply_rope(x, rope_pos, inv_freq)
+    return jnp.concatenate([
+        apply_rope(x[..., :rotary_dim], rope_pos, inv_freq), x[..., rotary_dim:]
+    ], axis=-1)
 
 
 def latent_value_lanes(cfg: ModelConfig) -> int:
@@ -1374,7 +1427,7 @@ def _per_layer_xs(cfg, inv_freq):
         jnp.int32,
     )
     if cfg.rope_local_base_freq is not None:
-        invf_local = rope_frequencies(cfg.head_dim_, cfg.rope_local_base_freq)
+        invf_local = rope_frequencies(cfg.rope_dim, cfg.rope_local_base_freq)
         invf_arr = jnp.stack(
             [invf_local if s else inv_freq for s in sliding]
         )
@@ -1432,7 +1485,12 @@ def _scan_paged_layers(
     untouched. A run of sliding layers whose attention the kernel serves
     hands ``read`` its width as ``sliding=`` (static) and stands under the
     ``swa_attn`` scope, so that a device trace tells its calls from the
-    full layers'.
+    full layers'. Where the forms differ in their KV heads
+    (``kv_heads_by_form``) a sliding run's ``wk`` / ``wv`` are the
+    ``swa_`` stacks' at the run's ordinal among sliding layers, a full
+    run's the plain stacks' among full ones, and ``read`` is told the
+    run's KV heads (``heads=``); a run whose form has a sink hands ``read``
+    its layer's logits (``sink=``).
 
     Attention layer ``l`` (counted among attention layers) writes its new
     K/V with one scatter into the whole ``[L_attn, P, ps, W]`` pool at
@@ -1470,8 +1528,7 @@ def _scan_paged_layers(
     by_kind = cfg.kv_by_kind
     forms = cfg.attn_forms
     tables, writes = _by_kind(cfg, table), _by_kind(cfg, write_at)
-    rope_dim = cfg.qk_rope_head_dim if cfg.is_latent else cfg.head_dim_
-    inv_freq = rope_frequencies(rope_dim, cfg.rope_theta, cfg.rope_scaling)
+    inv_freq = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     eps = cfg.rms_norm_eps
     layers = params["layers"]
     in_place = "router" in layers and routed_experts_form(
@@ -1513,6 +1570,17 @@ def _scan_paged_layers(
                 stacks = {k: layers[k] for k in EXPERT_STACKS}
             else:
                 source.update((k, (layers[k], ff0)) for k in EXPERT_STACKS)
+        # What a model whose forms differ stacks by form: the K and V
+        # projections at the run's ordinal among the layers of its form,
+        # the sink logits among the layers that have them.
+        if op == "attn" and cfg.kv_heads_by_form:
+            stack = "swa_" if forms[first] == "sliding" else ""
+            nth = forms[:first].count(forms[first])
+            source.update((k, (layers[stack + k], nth)) for k in ("wk", "wv"))
+        if forms[first] in cfg.sink_forms:
+            source["sink"] = (
+                layers["sink"], sum(f in cfg.sink_forms for f in forms[:first]),
+            )
         xs = {k: a for k, (a, at) in source.items() if a.shape[0] == n}
         closed = {k: v for k, v in source.items() if k not in xs}
         # The run's form of attention: which pool of a cache by kind, the
@@ -1522,6 +1590,7 @@ def _scan_paged_layers(
         form = forms[first]
         kind = int(by_kind and form == "sliding")
         pool0 = forms[:first].count(form) if by_kind else op0
+        heads = cfg.kv_heads_of(form)
         width = (
             cfg.sliding_window
             if form == "sliding" and cfg.attn_kernel_form else None
@@ -1530,7 +1599,7 @@ def _scan_paged_layers(
 
         def layer(carry, layer_in, op=op, op0=op0, ff0=ff0, stacks=stacks,
                   closed=closed, kind=kind, pool0=pool0, width=width,
-                  rope=rope):
+                  rope=rope, heads=heads):
             x, k_all, v_all, state = carry
             k_pool, v_pool = (
                 (k_all[kind], v_all[kind]) if by_kind else (k_all, v_all)
@@ -1601,18 +1670,21 @@ def _scan_paged_layers(
                             k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
                             *write_at, layer=l,
                         )
+                    # the run's own KV heads, and its layer's sink logits if any
+                    by_form = {"heads": heads, "sink": lp.get("sink")}
+                    v_flat = (flat[0], flat[1], vp.shape[-1])
                     with jax.named_scope("attend"):
                         if width is None:
                             attn = read(
-                                q, kp.reshape(flat), vp.reshape(flat),
-                                table + l * num_pages, win_l,
+                                q, kp.reshape(flat), vp.reshape(v_flat),
+                                table + l * num_pages, win_l, **by_form,
                             )
                         else:
                             with jax.named_scope("swa_attn"):
                                 attn = read(
-                                    q, kp.reshape(flat), vp.reshape(flat),
+                                    q, kp.reshape(flat), vp.reshape(v_flat),
                                     table + l * num_pages, win_l,
-                                    sliding=width,
+                                    sliding=width, **by_form,
                                 )
                     return attn, (kp, vp)
 
@@ -1767,7 +1839,10 @@ def forward(
     latent = _latent_read_args(cfg)
 
     def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None,
-             sliding=None):
+             sliding=None, sink=None, heads=cfg.num_kv_heads):
+        # ``sink`` (a layer with sink logits, [H]) joins the softmax of
+        # either path; ``heads``: the layer's KV heads, where its form's
+        # are not the model's.
         # ``wrap`` (a latent model: ``_mla_half``'s absorb and expand, and
         # ``W_ukv`` for a form this forward never takes) stands either side
         # of the read, which then names itself; ``index`` (a model with an
@@ -1777,6 +1852,8 @@ def forward(
         # kernel (the gather masks by ``win_l``, which says the same)
         pre, post, scope = _read_wrap(wrap)
         keep = {} if sliding is None else {"sliding": sliding}
+        if sink is not None:
+            keep["sink"] = sink
         if use_pallas:
             if index is not None:
                 keep["keep"] = _selection_tiled(
@@ -1787,7 +1864,7 @@ def forward(
                     taken.append(_kept_tiled(keep["keep"], kept_of, 1))
             with scope:
                 o = _pallas_decode(
-                    pre(q[:, 0]), kf, vf, table_l, lengths, cfg.num_kv_heads,
+                    pre(q[:, 0]), kf, vf, table_l, lengths, heads,
                     mesh, interpret, **latent, **keep,
                 )
             return post(o)[:, None]
@@ -1981,6 +2058,14 @@ def _latent_read_args(cfg: ModelConfig) -> dict:
     }
 
 
+def _no_sink_over_tp(read_args: dict) -> None:
+    """The ``shard_map`` branches below split the heads of q and the pools
+    and nothing else: a sink's logits would need the same split, which
+    nothing has run (a cache by kind refuses ``tp > 1`` at start-up)."""
+    if read_args.get("sink") is not None:
+        raise ValueError("a sink logit a query head is not served under tp > 1")
+
+
 def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret,
                    **latent):
     """Dispatch the ragged kernel at its decode shape (one query per
@@ -1999,6 +2084,7 @@ def _pallas_decode(q, kp, vp, page_table, lengths, hkv, mesh, interpret,
             q, kp, vp, page_table, lengths, num_kv_heads=hkv,
             interpret=interpret, **latent,
         )
+    _no_sink_over_tp(latent)
     from ..parallel.mesh import shard_map
 
     @_partial(
@@ -2042,6 +2128,7 @@ def _pallas_ragged(
             q, kp, vp, attn_table, row_of, positions, num_kv_heads=hkv,
             q_tile=q_tile, interpret=interpret, q_block=q_block, **latent,
         )
+    _no_sink_over_tp(latent)
     from ..parallel.mesh import shard_map
 
     @_partial(
@@ -2068,17 +2155,23 @@ def _pallas_ragged(
     return f(q, kp, vp, attn_table, row_of, positions)
 
 
-def ragged_query_block(cfg: ModelConfig, q_tile: int) -> int:
+def ragged_query_block(
+    cfg: ModelConfig, q_tile: int, kv_heads: int | None = None
+) -> int:
     """Stream slots a grid cell of a mixed stream's tiled attention call
     serves (``ops/ragged_attention.py::query_block``, from the model's query
-    heads a KV head), or one tile for a model with an indexer, whose
+    heads a KV head; a layer's own where ``kv_heads`` names them: a model
+    whose forms differ there has a block a form), or one tile for a model
+    with an indexer, whose
     selection rides cells of one tile in the absorbed call (its rows of many
     queries take the expanded call, whose passes are ``expanded_plan``'s).
     The program and the engine's counter (``dynamo_attn_query_slots_total``)
     both ask here."""
     from ..ops.ragged_attention import query_block
 
-    return q_tile if cfg.has_indexer else query_block(q_tile, cfg.q_per_kv)
+    if cfg.has_indexer:
+        return q_tile
+    return query_block(q_tile, cfg.num_heads // (kv_heads or cfg.num_kv_heads))
 
 
 def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1) -> int:
@@ -2095,7 +2188,7 @@ def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1) -> int:
 
     if cfg.has_indexer:
         return 1
-    width = value = cfg.head_dim_
+    width, value = cfg.head_dim_, cfg.value_head_dim_
     if cfg.is_latent:
         width, value = cfg.latent_lanes, latent_value_lanes(cfg)
     return one_query_block(n, cfg.num_heads // tp, width, value)
@@ -2276,10 +2369,12 @@ def forward_ragged(
             )
 
     def read(q, kf, vf, table_l, win_l, wrap=None, index=None, taken=None,
-             sliding=None):
-        # ``forward``'s, as ``index``, ``taken`` and ``sliding``
+             sliding=None, sink=None, heads=cfg.num_kv_heads):
+        # ``forward``'s, as ``index``, ``taken``, ``sliding``, ``sink`` and
+        # ``heads``
         pre, post, scope = _read_wrap(wrap)
         swa = {} if sliding is None else {"sliding": sliding}
+        sinks = {} if sink is None else {"sink": sink}
         if use_pallas:
             def select(part, tile, pad=0):
                 # ``part``'s learned selection (``_selection_tiled``), and
@@ -2309,8 +2404,9 @@ def forward_ragged(
                 with scope:
                     o = _pallas_ragged(
                         pre(q[0, part]), kf, vf, table_l, row_of[part],
-                        bounds[part] if seen is None else seen, cfg.num_kv_heads,
+                        bounds[part] if seen is None else seen, heads,
                         tile, mesh, interpret, block, **latent, **keep, **swa,
+                        **sinks,
                     )
                 return post(o)
 
@@ -2355,7 +2451,7 @@ def forward_ragged(
             # A mixed stream's spans run over many tiles of one row: a
             # grid cell takes a block of them (ops/ragged_attention.py).
             return jnp.concatenate([
-                *tiles(packed_from, ragged_query_block(cfg, q_tile)),
+                *tiles(packed_from, ragged_query_block(cfg, q_tile, heads)),
                 kernel(slice(packed_from, None), 1),
             ])[None]
         from ..ops.ragged_attention import ragged_paged_attention_ref
@@ -2372,10 +2468,10 @@ def forward_ragged(
         with scope:
             o = ragged_paged_attention_ref(
                 q, kf, vf, table_l, row_of, positions,
-                num_kv_heads=cfg.num_kv_heads,
+                num_kv_heads=heads,
                 window=win_l if have_window else None,
                 softcap=cfg.attn_logit_softcap, bounds=bounds,
-                **{"sm_scale": sm_scale, **latent, **keep},
+                **{"sm_scale": sm_scale, **latent, **keep, **sinks},
             )
         return post(o)[None]
 

@@ -41,6 +41,7 @@ def _open_safetensors(path: str):
 # Parameters that stay float32 whatever the model's dtype.
 _FLOAT32 = (
     "router_bias", "mamba_A_log", "mamba_D", "mamba_dt_b", "gdn_A_log", "gdn_dt_b",
+    "sink",
 )
 
 
@@ -216,6 +217,7 @@ SUPPORTED_MODEL_TYPES = (
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
+    "mimo_v2_flash",
 )
 
 
@@ -428,6 +430,68 @@ def _exaone_moe_params(cfg: ModelConfig, get, linear, has=None) -> dict:
     }
 
 
+def _mimo_v2_flash_params(cfg: ModelConfig, get, linear, has=None) -> dict:
+    """``mimo_v2_flash`` tensor names (the DeepSeek-V3 / Qwen2-MoE decoder
+    layer's; ``benchmark/configs/mimo-v2-flash-*.json`` lists them as
+    assumed) into the stacks of ``models/llama.py::init_params``:
+    ``input_layernorm`` / ``post_attention_layernorm`` over every layer,
+    BEFORE each half; ``self_attn.{q,o}_proj`` over every layer and
+    ``self_attn.{k,v}_proj`` over the layers of each form (the forms' KV
+    heads differ: ``wk`` / ``wv`` the full layers', ``swa_wk`` / ``swa_wv``
+    the sliding ones'); ``self_attn.attention_sink_bias`` ([H], float32) over
+    the layers whose form has a sink; ``mlp.{gate,up,down}_proj`` over the
+    dense layers; ``mlp.gate.weight`` (the router),
+    ``mlp.gate.e_score_correction_bias`` (the selection bias) and
+    ``mlp.experts.N.*`` over the routed ones, ``N`` over the experts this
+    device holds. A checkpoint that carries a multi-token-prediction module
+    (``model.mtp_layers.*``) is refused rather than served without it."""
+    if has is not None and has("model.mtp_layers.0.input_layernorm.weight"):
+        raise ValueError(
+            "mimo_v2_flash checkpoint carries model.mtp_layers: the "
+            "multi-token-prediction module is not served"
+        )
+    names = (
+        "attn_norm", "mlp_norm", "wq", "wk", "wv", "swa_wk", "swa_wv", "wo", "sink",
+        "dense_gate", "dense_up", "dense_down", "router", "router_bias",
+        "shared_gate", "shared_up", "shared_down", "w_gate", "w_up", "w_down",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    proj = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
+    held = range(cfg.first_expert_held, cfg.first_expert_held + cfg.experts_held)
+    for i, (ff, form) in enumerate(zip(cfg.ff_kinds, cfg.attn_forms)):
+        p = f"model.layers.{i}."
+        layers["attn_norm"].append(get(p + "input_layernorm.weight"))
+        layers["mlp_norm"].append(get(p + "post_attention_layernorm.weight"))
+        a = p + "self_attn."
+        stack = "swa_" if cfg.kv_heads_by_form and form == "sliding" else ""
+        for key, name in (
+            ("wq", "q_proj"), (stack + "wk", "k_proj"), (stack + "wv", "v_proj"),
+            ("wo", "o_proj"),
+        ):
+            layers[key].append(linear(f"{a}{name}.weight"))
+        if form in cfg.sink_forms:
+            layers["sink"].append(get(a + "attention_sink_bias"))
+        m = p + "mlp."
+        if ff == "dense":
+            for key, name in proj:
+                layers[f"dense_{key}"].append(linear(f"{m}{name}.weight"))
+            continue
+        layers["router"].append(linear(m + "gate.weight"))
+        layers["router_bias"].append(get(m + "gate.e_score_correction_bias"))
+        for key, name in proj:
+            if cfg.shared_width:
+                layers[f"shared_{key}"].append(linear(f"{m}shared_experts.{name}.weight"))
+            layers[f"w_{key}"].append(np.stack([
+                linear(f"{m}experts.{e}.{name}.weight") for e in held
+            ]))
+    return {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items() if v},
+        "final_norm": get("model.norm.weight"),
+        "lm_head": linear("lm_head.weight"),
+    }
+
+
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
     """Load a HF checkpoint directory (llama/mistral/qwen2/mixtral
     families) into the stacked param pytree."""
@@ -463,6 +527,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         "pangu_ultra_moe": _pangu_ultra_moe_params,
         "glm_moe_dsa": _glm_moe_dsa_params,
         "exaone_moe": functools.partial(_exaone_moe_params, has=index.__contains__),
+        "mimo_v2_flash": functools.partial(_mimo_v2_flash_params, has=index.__contains__),
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

@@ -159,6 +159,34 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             scoring_func="sigmoid", n_group=1, topk_group=1,
             num_nextn_predict_layers=0,
         )
+    elif cfg.model_type == "mimo_v2_flash":  # the family's own keys
+        del hf["rms_norm_eps"]
+        sliding = [int(form == "sliding") for form in cfg.attn_forms]
+        hd, dv = cfg.head_dim_, cfg.value_head_dim_
+        hf.update(
+            architectures=["MiMoV2FlashForCausalLM"], hidden_act="silu",
+            layernorm_epsilon=cfg.rms_norm_eps, attention_bias=False,
+            head_dim=hd, v_head_dim=dv, swa_head_dim=hd, swa_v_head_dim=dv,
+            swa_num_attention_heads=cfg.num_heads,
+            swa_num_key_value_heads=cfg.kv_heads_of("sliding"),
+            # the smallest factor that int() takes back to the same count
+            partial_rotary_factor=(cfg.rope_dim + 0.5) / hd if cfg.rotary_dim else 1.0,
+            attention_value_scale=cfg.value_scale,
+            swa_rope_theta=cfg.rope_local_base_freq or cfg.rope_theta,
+            hybrid_layer_pattern=sliding, sliding_window=cfg.sliding_window,
+            sliding_window_size=cfg.sliding_window,
+            add_swa_attention_sink_bias="sliding" in cfg.sink_forms,
+            add_full_attention_sink_bias="full" in cfg.sink_forms,
+            moe_layer_freq=[int(i >= cfg.num_dense_layers) for i in range(cfg.num_layers)],
+            n_routed_experts=cfg.experts_held,
+            n_routed_experts_global=cfg.num_experts,
+            first_routed_expert=cfg.first_expert_held,
+            n_shared_experts=cfg.num_shared_experts or None,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            scoring_func="sigmoid", topk_method="noaux_tc", n_group=1, topk_group=1,
+        )
     elif cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -168,7 +196,7 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
             "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe, "
-            "glm_moe_dsa and exaone_moe only"
+            "glm_moe_dsa, exaone_moe and mimo_v2_flash only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

@@ -74,6 +74,7 @@ def paged_attention(
     softcap: float | None = None,
     value_dim: int | None = None,
     keep: jnp.ndarray | None = None,  # [B, T, S] bool: a learned selection
+    sink: jnp.ndarray | None = None,  # [H] a learned logit a query head
 ) -> jnp.ndarray:
     """Causal attention of queries against their sequences' pages.
 
@@ -91,6 +92,10 @@ def paged_attention(
     applies gemma2's tanh cap to the scores before masking. ``keep``
     (learned sparse attention, ``ops/sparse_index.py``) additionally masks
     every position a query's selection left out, for all its heads.
+    The value pool's heads may be narrower than the keys' (``v_cache``'s
+    row is ``Hkv`` heads of its own width, the result's). ``sink`` (a
+    learned logit a query head) joins the softmax's denominator as one
+    more key that carries no value.
     """
     B, T, H, D = q.shape
     P, ps, _ = k_cache.shape
@@ -101,7 +106,7 @@ def paged_attention(
     # Gather this batch's pages: [B, Pmax, ps, Hkv*D] -> [B, S, Hkv, D]
     k = k_cache[page_table].reshape(B, S, Hkv, D)
     if value_dim is None:
-        v = v_cache[page_table].reshape(B, S, Hkv, D)
+        v = v_cache[page_table].reshape(B, S, Hkv, v_cache.shape[2] // Hkv)
     else:
         v = k[..., :value_dim]
 
@@ -127,7 +132,16 @@ def paged_attention(
         mask &= keep[:, None, None]
     scores = jnp.where(mask, scores, -1e30)
 
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, Hkv, qpk, 1, 1),
+            (*scores.shape[:-1], 1),
+        )
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, column], axis=-1), axis=-1
+        )[..., :-1]
     out = jnp.einsum(
         "bhqts,bshd->bthqd",
         probs.astype(v.dtype),

@@ -107,6 +107,15 @@ sums (exact), and the ties a query has passed in earlier chunks are a
 column of scratch beside its running maximum and sum, because the kernel
 walks chunks outside and sub-blocks inside.
 
+**Shapes by layer kind** (``mimo_v2_flash``): the value pool's heads may be
+narrower than the keys' (the call takes the value pool's own row: keys of
+192 lanes a head, values of 128, the result at the values' width), and
+``sink`` gives every query head a learned logit that joins the softmax's
+denominator and carries no value: a pass's running maximum and sum start
+at ``(sink, 1)`` where they otherwise start at ``(-1e30, 0)``, which is the
+sink as one more key seen first; from there the pass is the same, in all
+three forms of the call.
+
 HBM traffic per dispatch per layer is ``sum_rows(blocks_row * kv_row)``
 tokens instead of the XLA gather's ``N * S``: the kernel reads a row's
 context once per query block of its span (once per tile in a block it
@@ -176,6 +185,7 @@ def ragged_paged_attention_ref(
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
     keep: jnp.ndarray | None = None,  # [N, S] bool: a learned selection
+    sink: jnp.ndarray | None = None,  # [H] a learned logit a query head
 ) -> jnp.ndarray:
     """Pure-JAX ragged paged attention (the CPU/tier-1 parity path).
 
@@ -212,6 +222,7 @@ def ragged_paged_attention_ref(
         softcap=softcap,
         value_dim=value_dim,
         **({} if keep is None else {"keep": keep[:, None]}),
+        **({} if sink is None else {"sink": sink}),
     )
     return out[:, 0]
 
@@ -426,6 +437,8 @@ def _attend(
     sliding: int | None = None,
     first=None,
     chain=None,
+    vw: int | None = None,
+    sink=None,
 ):
     """One double-buffered pass over ``row``'s first ``kvlen`` tokens
     for ``w`` queries: flash-style online softmax, ``qpk * w`` score
@@ -446,6 +459,13 @@ def _attend(
     ``cp`` pages from there, and a query masks the positions at or
     before its own minus ``sliding``; what lies before ``first``'s page
     is neither copied nor multiplied.
+    ``vw`` (static; a value pool whose heads are narrower than the keys'):
+    a head's values are ``vw`` lanes of the value row, not ``hd``.
+    ``sink`` ([H, 128] float32 in VMEM, lane-replicated: a learned logit a
+    query head): the logit joins every query's softmax as one more key
+    that carries no value, so the running state starts at ``(m, l) = (sink,
+    1)`` and not at ``(-1e30, 0)``: from there the pass is the same, and a
+    query of no key still ends as zeros.
     ``vd`` (a latent pool): a head's values are the first ``vd`` lanes of
     its key row, and the two products take their operands in the pool's
     dtype (module docstring). ``keep`` (a learned selection,
@@ -454,7 +474,8 @@ def _attend(
     chunk ``c`` only where ``idx_ref[c, i, s] > tau_ref[i]``, or equal to
     it and among the first ``allow_ref[i]`` such positions of the walk
     (``sparse_index.kept``), every head alike."""
-    dv = hd if vd is None else vd
+    dvh = hd if vw is None else vw  # lanes of a head in the value pool
+    dv = dvh if vd is None else vd
     if sliding is None:
         n_chunks = pl.cdiv(kvlen, ps * cp)
     else:
@@ -475,8 +496,15 @@ def _attend(
         return _chunk_copies(row, base, slot, *pools, cp=cp, pmax=pmax)
 
     acc_ref[used] = jnp.zeros((n, dv), jnp.float32)
-    m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
-    l_ref[used] = jnp.zeros((n, 128), jnp.float32)
+    if sink is None:
+        m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
+        l_ref[used] = jnp.zeros((n, 128), jnp.float32)
+    else:
+        # score rows lie (head, query): a head's logit for each of its ``w``
+        m_ref[used] = jnp.broadcast_to(
+            sink[...][:, None, :], (hkv * qpk, w, 128)
+        ).reshape(n, 128)
+        l_ref[used] = jnp.ones((n, 128), jnp.float32)
 
     if chain is None:
         @pl.when(n_chunks > 0)
@@ -536,7 +564,7 @@ def _attend(
         if sliding is not None:
             tok_idx = tok_idx + page0 * ps
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
-        v = v_buf[slot].reshape(S, hkv * hd) if vd is None else None
+        v = v_buf[slot].reshape(S, hkv * dvh) if vd is None else None
         if keep is not None:
             # [w, S] -> a head's score rows (group, query): the same for
             # every head. Through float32: whole (8, 128) tiles stack.
@@ -557,7 +585,7 @@ def _attend(
             if vd is None:
                 qh = q[h * qpk : (h + 1) * qpk].reshape(qpk * w, hd)
                 kh = k[:, cols].astype(jnp.float32)  # [S, D]
-                vh = v[:, cols]
+                vh = v[:, h * dvh : (h + 1) * dvh]
             else:
                 qh, kh = q[rows], k[:, cols]
                 vh = k[:, h * hd : h * hd + vd]
@@ -638,17 +666,22 @@ def _tile_kernel(
     qt: int,
     qpk: int,
     kept: bool = False,
+    sinks: bool = False,
     **shape,
 ):
     """A grid cell is one tile of ``qt`` slots, all of one row. With
+    ``sinks`` the heads' sink logits come first among ``refs``
+    (``_attend``'s ``sink``). With
     ``kept`` the tile's index scores and thresholds come first among
     ``refs`` (``_attend``'s ``keep``). With ``sliding`` (in ``shape``) a
     fifth prefetched scalar array comes behind the table: each tile's
     first visible position (``sliding_starts``)."""
-    first = {}
+    more = {}  # what only some calls hand the pass: its start, the sinks
     if shape.get("sliding") is not None:
         tile_first_ref, q_ref, *refs = q_ref, *refs
-        first = {"first": tile_first_ref[pl.program_id(0)]}
+        more["first"] = tile_first_ref[pl.program_id(0)]
+    if sinks:
+        more["sink"], *refs = refs
     keep = None
     if kept:
         *keep, refs = refs[0], refs[1], refs[2], refs[3:]
@@ -676,7 +709,7 @@ def _tile_kernel(
     _attend(
         q_ref, o_ref, pos_rows, tile_row_ref[t], tile_kvlen_ref[t],
         table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, keep=keep,
-        **first, **shape,
+        **more, **shape,
     )
 
 
@@ -694,6 +727,7 @@ def _block_kernel(
     qt: int,
     nt: int,
     qpk: int,
+    sinks: bool = False,
     **shape,
 ):
     """A grid cell is a block of ``nt`` tiles. Where they belong to one
@@ -706,6 +740,8 @@ def _block_kernel(
     tile_first = block_first = None
     if shape.get("sliding") is not None:
         tile_first, block_first, pos_ref, q_ref, *refs = pos_ref, q_ref, *refs
+    if sinks:  # the heads' sink logits, first behind the queries
+        shape["sink"], *refs = refs
     k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     b = pl.program_id(0)
     merged_len = block_kvlen_ref[b]
@@ -759,6 +795,7 @@ def _rows_kernel(
     *refs,  # _pools_out_scratch's
     rb: int,
     qpk: int,
+    sinks: bool = False,
     **shape,
 ):
     """A grid cell of the one-query call is ``rb`` consecutive slots, each
@@ -774,6 +811,9 @@ def _rows_kernel(
     first_ref = None
     if shape.get("sliding") is not None:
         first_ref, q_ref, *refs = q_ref, *refs
+    sink = {}
+    if sinks:  # the heads' sink logits, first behind the queries
+        sink["sink"], *refs = refs
     k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     k_buf, v_buf, *_, sems = scratch
     ps, cp = shape["ps"], shape["cp"]
@@ -814,7 +854,7 @@ def _rows_kernel(
                 lambda: jnp.full((qpk, 1), pos, jnp.int32), row_ref[t],
                 pos + 1, table_ref, k_hbm, v_hbm, *scratch, w=1, qpk=qpk,
                 chain=(slot0, row_ref[then], first_page(then), after < rb),
-                **start, **shape,
+                **start, **sink, **shape,
             )
 
         @pl.when(pos < 0)
@@ -862,6 +902,7 @@ def ragged_paged_attention(
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
     keep: tuple[jnp.ndarray, ...] | None = None,  # a learned selection
     sliding: int | None = None,  # a sliding layer's width (keys a query sees)
+    sink: jnp.ndarray | None = None,  # [H] a learned logit a query head
 ) -> jnp.ndarray:
     """Ragged paged attention over a flat query stream (Pallas TPU).
 
@@ -912,6 +953,15 @@ def ragged_paged_attention(
     scores tiled as ``sparse_index.tiled_shape`` and ``select``'s threshold
     and number of ties a query ([N] each); a query attends only the
     positions ``sparse_index.kept`` names.
+
+    **Values narrower than keys**: the value pool's row is ``Hkv`` heads of
+    its own width (``v_cache.shape[-1] // Hkv``), which the result then has:
+    [N, H, Dv]. The key's width is the queries'.
+
+    ``sink`` ([H] float32, or None): a learned logit a query head that
+    joins the softmax's denominator and carries no value:
+    ``p[j] = exp(s[j] - m) / (sum_j exp(s[j] - m) + exp(sink - m))``, in all
+    three forms of the call. None: the program is the one without it.
     """
     if bounds is not None:
         positions = bounds
@@ -924,7 +974,7 @@ def ragged_paged_attention(
     cp = max(1, min(_CHUNK_TOKENS // ps, pmax))
     qt = q_tile
     latent = value_dim is not None
-    Dv = value_dim if latent else D
+    Dv = value_dim if latent else v_cache.shape[-1] // Hkv
     # Rows a cell of the one-query call walks on one copy pipeline; 1: a
     # cell is a tile (a learned selection's scores come a tile a cell).
     rb = 1
@@ -935,6 +985,21 @@ def ragged_paged_attention(
     nt = qb // qt
     shape = dict(ps=ps, cp=cp, hkv=Hkv, hd=D, qpk=qpk, pmax=pmax, scale=scale)
     pools = (k_cache,) if latent else (k_cache, v_cache)
+    if not latent and Dv != D:
+        shape["vw"] = Dv
+    # The heads' sink logits: one more input behind the queries, whole in
+    # every cell and lane-replicated as the running maximum it starts.
+    sink_specs, sink_args = [], ()
+    if sink is not None:
+        if latent or keep is not None:
+            raise ValueError("a sink rides neither a latent pool nor a selection")
+        shape["sinks"] = True
+        sink_specs = [pl.BlockSpec(
+            (H, 128), lambda t, *_: (0, 0), memory_space=pltpu.VMEM
+        )]
+        sink_args = (jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None], (H, 128)
+        ),)
     starts = ()
     if sliding is not None:
         if keep is not None or bounds is not None:
@@ -960,7 +1025,7 @@ def ragged_paged_attention(
     def scratch_for(queries):
         """``_attend``'s buffers and states for passes of ``queries``."""
         return [
-            *(pltpu.VMEM((2, cp, ps, Hkv * D), pool.dtype) for pool in pools),
+            *(pltpu.VMEM((2, cp, ps, pool.shape[-1]), pool.dtype) for pool in pools),
             pltpu.VMEM((H * queries, Dv), jnp.float32),
             pltpu.VMEM((H * queries, 128), jnp.float32),
             pltpu.VMEM((H * queries, 128), jnp.float32),
@@ -980,7 +1045,7 @@ def ragged_paged_attention(
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5 + len(starts[:1]),
             grid=(n_cells,),
-            in_specs=[query_spec(slots=rb), *pool_specs],
+            in_specs=[query_spec(slots=rb), *sink_specs, *pool_specs],
             out_specs=query_spec(Dv, rb),
             scratch_shapes=scratch_for(1),
         )
@@ -993,7 +1058,7 @@ def ragged_paged_attention(
         )(
             jnp.pad(row_of, over), jnp.pad(positions, over, constant_values=-1),
             lead, after, page_table, *(jnp.pad(s, over) for s in starts[:1]),
-            q, *pools,
+            q, *sink_args, *pools,
         )
     scratch_shapes = scratch_for(qb)
     if nt == 1:
@@ -1023,7 +1088,7 @@ def ragged_paged_attention(
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4 + len(starts[:1]),
             grid=(n_tiles,),
-            in_specs=[query_spec(), *kept_specs, *pool_specs],
+            in_specs=[query_spec(), *sink_specs, *kept_specs, *pool_specs],
             out_specs=query_spec(Dv),
             scratch_shapes=scratch_shapes,
         )
@@ -1035,7 +1100,7 @@ def ragged_paged_attention(
             **params,
         )(
             tile_row, tile_kvlen, positions, page_table, *starts[:1], q,
-            *kept_args, *pools,
+            *sink_args, *kept_args, *pools,
         )
     if keep is not None:
         raise ValueError("a learned selection rides a call whose block is one tile")
@@ -1056,6 +1121,7 @@ def ragged_paged_attention(
                 (qb, 1), lambda t, *_: (t, 0), memory_space=pltpu.VMEM
             ),
             query_spec(),
+            *sink_specs,
             *pool_specs,
         ],
         out_specs=query_spec(Dv),
@@ -1075,7 +1141,7 @@ def ragged_paged_attention(
         out_shape=out_shape,
         interpret=interpret,
         **params,
-    )(*plan, page_table, *starts, pos_col, q, *pools)
+    )(*plan, page_table, *starts, pos_col, q, *sink_args, *pools)
 
 
 # ------------------------------------------- a latent pool, expanded form
@@ -1781,6 +1847,7 @@ def ragged_decode_attention(
     value_dim: int | None = None,
     keep: tuple[jnp.ndarray, ...] | None = None,
     sliding: int | None = None,
+    sink: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Pure-decode shape of the ragged kernel: one query per row at
     ``q_tile=1`` (row b attends its own ``lengths[b]`` tokens; rows
@@ -1802,4 +1869,5 @@ def ragged_decode_attention(
         value_dim=value_dim,
         keep=keep,
         sliding=sliding,
+        sink=sink,
     )

@@ -992,6 +992,16 @@ class Telemetry:
              "sliding_width", "page_bytes"],
             registry=self.registry,
         )
+        self.attn_form_info = Gauge(
+            "dynamo_attn_form_info",
+            "Constant-1 shape of each form of softmax attention the model "
+            "has (full / sliding; none for latent attention): the layers of "
+            "that form, their KV heads, a head's key and value widths as "
+            "cached, and sink=1 where a learned logit a query head joins "
+            "the softmax's denominator in those layers",
+            ["form", "layers", "kv_heads", "key_width", "value_width", "sink"],
+            registry=self.registry,
+        )
         self.kv_pool_usage = Gauge(
             "dynamo_kv_pool_usage",
             "Share of a pool's pages in use, by pool of a cache by kind "
@@ -1243,6 +1253,15 @@ class Telemetry:
             str(layers), str(pages), str(page_size), str(width), dtype,
             str(sliding_width), str(page_bytes),
         ).set(1)
+
+    def set_attn_form_info(self, forms) -> None:
+        """Publish the constant-1 ``dynamo_attn_form_info`` samples, one a
+        form: ``(form, layers, kv_heads, key_width, value_width, sink)``."""
+        self.attn_form_info.clear()
+        for form, layers, heads, key, value, sink in forms:
+            self.attn_form_info.labels(
+                form, str(layers), str(heads), str(key), str(value), str(int(sink)),
+            ).set(1)
 
     def watch_jax_compiles(self) -> None:
         """Count JAX's own trace / lower / backend-compile events into

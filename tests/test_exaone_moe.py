@@ -23,7 +23,7 @@ from dynamo_exp_tpu.engine.config import refuse_sliding_pool_tiers
 from dynamo_exp_tpu.engine.kv_manager import KvPageManager, SlidingPagePool
 from dynamo_exp_tpu.models import ModelConfig, llama
 from dynamo_exp_tpu.models.config import (
-    MISTRAL_7B, PRESETS, TINY_EXAONE_MOE, TINY_GLM_DSA, TINY_PANGU,
+    MISTRAL_7B, PRESETS, TINY_EXAONE_MOE, TINY_GLM_DSA, TINY_MIMO, TINY_PANGU,
 )
 from dynamo_exp_tpu.ops import moe
 from dynamo_exp_tpu.protocols.common import BackendInput
@@ -340,11 +340,14 @@ def _whole(cfg, params):
     return layers
 
 
-@pytest.mark.parametrize("preset", [TINY_EXAONE_MOE, TINY_GLM_DSA, TINY_PANGU], ids=lambda c: c.model_type)
+@pytest.mark.parametrize(
+    "preset", [TINY_EXAONE_MOE, TINY_GLM_DSA, TINY_PANGU, TINY_MIMO], ids=lambda c: c.model_type
+)
 def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(preset):
     """Every rank's share of the first routed layer, each by ``moe_ffn``
-    told which experts it holds, plus the shared expert ONCE, equals the
-    layer with every expert held (the guide's section 4 test)."""
+    told which experts it holds, plus the shared expert ONCE (mimo_v2_flash
+    has none to count), equals the layer with every expert held (the
+    guide's section 4 test)."""
     cfg = dataclasses.replace(preset, dtype="float32")
     held = cfg.experts_held
     layers = _whole(cfg, llama.init_params(jax.random.PRNGKey(SEED), cfg))

@@ -49,12 +49,14 @@ PS = 8
 
 
 # ------------------------------------------------------------ kernel parity
-def _flat_batch(rng, spans, H, Hkv, D, P, ps, pmax, q_tile, dtype=jnp.float32):
+def _flat_batch(rng, spans, H, Hkv, D, P, ps, pmax, q_tile, dtype=jnp.float32,
+                Dv=None):
     """Build a q_tile-aligned flat query stream from per-row
-    ``(q_len, kv_len)`` spans plus a scrambled page pool."""
+    ``(q_len, kv_len)`` spans plus a scrambled page pool. ``Dv``: the
+    value pool's heads are that wide, not ``D``."""
     ks = jax.random.split(jax.random.PRNGKey(rng), 3)
     k = jax.random.normal(ks[1], (P, ps, Hkv * D), dtype)
-    v = jax.random.normal(ks[2], (P, ps, Hkv * D), dtype)
+    v = jax.random.normal(ks[2], (P, ps, Hkv * (Dv or D)), dtype)
     perm = np.random.RandomState(rng).permutation(P)
     table = np.zeros((len(spans), pmax), np.int32)
     used = 0
@@ -79,40 +81,73 @@ def _flat_batch(rng, spans, H, Hkv, D, P, ps, pmax, q_tile, dtype=jnp.float32):
     )
 
 
-@pytest.mark.parametrize(
-    "spans",
-    [
-        # (query_len, kv_len) per row: decode, chunk, spec-verify-ish.
-        [(1, 17), (5, 5), (3, 20)],
-        # Page-boundary cases: span ends exactly on a page boundary,
-        # span crosses one, kv exactly page-aligned.
-        [(8, 16), (9, 25), (1, 8)],
-        # rows-1: a single row, chunk wider than one q tile.
-        [(13, 13)],
-        # Inactive row (0 queries is impossible flat — 1-query row with
-        # deep kv next to a fresh full-prefill row).
-        [(1, 64), (32, 32)],
-    ],
-)
+def _narrow(D: int) -> int:
+    """A value head narrower than the keys' ``D``: about two thirds of it
+    (192 -> 128 at the published widths), whole groups of 8."""
+    return D * 2 // 3 // 8 * 8
+
+
+def _extras(extra: str, H: int, D: int, seed: int = 11):
+    """What a case named ``extra`` adds to a kernel-against-reference test
+    (``""``, ``"sink"``, ``"narrow"`` or ``"sink+narrow"``): (the value
+    head's width for ``_flat_batch``, the keyword both implementations
+    take). The sinks are of the scores' size, so that leaving them out, or
+    giving one head another's, moves every output."""
+    sink = {}
+    if "sink" in extra:
+        sink["sink"] = 2.0 * jax.random.normal(
+            jax.random.PRNGKey(seed), (H,), jnp.float32
+        )
+    return (_narrow(D) if "narrow" in extra else None), sink
+
+
+_TILE_SPANS = [
+    # (query_len, kv_len) per row: decode, chunk, spec-verify-ish.
+    [(1, 17), (5, 5), (3, 20)],
+    # Page-boundary cases: span ends exactly on a page boundary,
+    # span crosses one, kv exactly page-aligned.
+    [(8, 16), (9, 25), (1, 8)],
+    # rows-1: a single row, chunk wider than one q tile.
+    [(13, 13)],
+    # Inactive row (0 queries is impossible flat — 1-query row with
+    # deep kv next to a fresh full-prefill row).
+    [(1, 64), (32, 32)],
+]
+
+
 # A sliding layer's width (keys a query sees): none, one that is no whole
-# number of pages of 16, one that is.
-@pytest.mark.parametrize("sliding", [None, 5, 16])
-def test_kernel_matches_reference_ragged(spans, sliding):
+# number of pages of 16, one that is. Behind the plain cases: a learned
+# sink logit a query head in the softmax, and values narrower than keys.
+@pytest.mark.parametrize("spans,sliding,extra", [
+    *((spans, sliding, "") for sliding in (None, 5, 16) for spans in _TILE_SPANS),
+    (_TILE_SPANS[0], None, "sink"),
+    (_TILE_SPANS[1], 5, "sink+narrow"),
+    (_TILE_SPANS[3], 16, "narrow"),
+])
+def test_kernel_matches_reference_ragged(spans, sliding, extra):
     H, Hkv, D, ps, pmax = 8, 4, 64, 16, 8
+    Dv, sink = _extras(extra, H, D)
     q, k, v, table, row_of, positions = _flat_batch(
-        0, spans, H, Hkv, D, 64, ps, pmax, q_tile=4
+        0, spans, H, Hkv, D, 64, ps, pmax, q_tile=4, Dv=Dv
     )
     got = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=4,
-        interpret=True, sliding=sliding,
+        interpret=True, sliding=sliding, **sink,
     )
     want = ragged_paged_attention_ref(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, window=sliding,
+        **sink,
     )
     live = np.asarray(positions) >= 0
+    assert got.shape == (q.shape[0], H, Dv or D)
     np.testing.assert_allclose(
         np.asarray(got)[live], np.asarray(want)[live], atol=2e-5
     )
+    if sink:  # the sink is no small thing: without it the result is another
+        plain = ragged_paged_attention_ref(
+            q, k, v, table, row_of, positions, num_kv_heads=Hkv, window=sliding,
+        )
+        assert np.abs(np.asarray(plain) - np.asarray(want))[live].max() > 1e-2
 
 
 def test_reference_matches_paged_attention_per_token():
@@ -147,12 +182,14 @@ _DECODE_ROWS = {
 }
 
 
-@pytest.mark.parametrize("sliding,rows", [
-    (None, "short"), (3, "short"), (8, "short"),
-    (None, "dead_and_long_in_one_cell"), (8, "dead_and_long_in_one_cell"),
-    (None, "cells_of_four"),
+@pytest.mark.parametrize("sliding,rows,extra", [
+    (None, "short", ""), (3, "short", ""), (8, "short", ""),
+    (None, "dead_and_long_in_one_cell", ""), (8, "dead_and_long_in_one_cell", ""),
+    (None, "cells_of_four", ""),
+    (None, "short", "sink+narrow"), (8, "dead_and_long_in_one_cell", "sink+narrow"),
+    (None, "cells_of_four", "sink"),
 ])
-def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows):
+def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows, extra):
     """q_tile=1, one query per row — the compiled decode window's
     per-step shape. Rows with length 0 return exact zeros. With a sliding
     width a row's walk starts at its newest ``sliding`` keys' page, each
@@ -162,15 +199,16 @@ def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows):
     lengths, q_block = _DECODE_ROWS[rows]
     short = rows == "short"
     H, Hkv, D, ps, pmax = (4, 4, 32, 8, 8) if short else (2, 1, 32, 16, 20)
+    Dv, sink = _extras(extra, H, D)
     spans = [(1, max(ln, 1)) for ln in lengths]
     q, k, v, table, row_of, positions = _flat_batch(
-        2, spans, H, Hkv, D, 128, ps, pmax, q_tile=1
+        2, spans, H, Hkv, D, 128, ps, pmax, q_tile=1, Dv=Dv
     )
     n = len(lengths)
     lens = jnp.asarray(lengths, jnp.int32)
     want = ragged_paged_attention_ref(
         q, k, v, table, jnp.arange(n, dtype=jnp.int32), lens - 1,
-        num_kv_heads=Hkv, window=sliding,
+        num_kv_heads=Hkv, window=sliding, **sink,
     )
     if sliding is not None:
         # the pages that lie wholly behind a row's window: one page of NaN
@@ -182,14 +220,14 @@ def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows):
     if short:
         got = ragged_decode_attention(
             q, k, v, table, lens, num_kv_heads=Hkv, interpret=True,
-            sliding=sliding,
+            sliding=sliding, **sink,
         )
     else:
         got, a_row = (
             ragged_paged_attention(
                 q, k, v, table, jnp.arange(n, dtype=jnp.int32), lens - 1,
                 num_kv_heads=Hkv, q_tile=1, interpret=True, sliding=sliding,
-                q_block=block,
+                q_block=block, **sink,
             )
             for block in (q_block, 1)
         )
@@ -316,6 +354,8 @@ def test_tp_shard_map_ragged_dispatch():
         (32, 4, 8, 16),  # sdar's mixed program
         (20, 1, 8, 8),  # jamba2-3b: a tile fills the rows already
         (2, 2, 12, 132),  # a whole number of tiles, rounded up
+        (64, 8, 8, 16),  # mimo-v2-flash's sliding layers
+        (64, 4, 8, 8),  # and its full ones, in the same model
     ],
 )
 def test_query_block_follows_from_the_head_layout(heads, kv_heads, q_tile, want):
@@ -344,10 +384,10 @@ _BLOCK_STREAMS = {
 _LAYOUTS = {"1to1": (3, 3), "4to1": (8, 2), "20to1": (20, 1)}
 
 
-def _block_stream(name, H, Hkv, D=32, seed=7):
+def _block_stream(name, H, Hkv, D=32, seed=7, Dv=None):
     spans = _BLOCK_STREAMS[name]
     q, k, v, table, row_of, positions = _flat_batch(
-        seed, spans, H, Hkv, D, 256, 8, 64, q_tile=8
+        seed, spans, H, Hkv, D, 256, 8, 64, q_tile=8, Dv=Dv
     )
     pad = _BLOCK_SLOTS - q.shape[0]
     # Padding slots carry queries too (the embedding of token 0): ones,
@@ -359,12 +399,20 @@ def _block_stream(name, H, Hkv, D=32, seed=7):
     return q, k, v, table, row_of, positions
 
 
-@pytest.mark.parametrize("stream", list(_BLOCK_STREAMS))
-@pytest.mark.parametrize("layout", list(_LAYOUTS))
 # A sliding layer's width: none, or 20 keys, no whole number of pages of 8
-# (widths of whole pages: the tile and the one-query cases above).
-@pytest.mark.parametrize("sliding", [None, 20])
-def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream, sliding):
+# (widths of whole pages: the tile and the one-query cases above). Behind
+# the plain cases: a sink and narrow values in a merged block, in one that
+# walks its tiles, and under a sliding width.
+@pytest.mark.parametrize("layout,stream,sliding,extra", [
+    *(
+        (layout, stream, sliding, "") for sliding in (None, 20)
+        for layout in _LAYOUTS for stream in _BLOCK_STREAMS
+    ),
+    ("4to1", "mixed", None, "sink+narrow"),
+    ("4to1", "long", 20, "sink+narrow"),
+    ("1to1", "two_rows", 20, "sink"),
+])
+def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream, sliding, extra):
     """A grid cell of a block of tiles (``q_block`` from the head layout:
     128, 32 and 8 slots) against the reference at the tile kernel's
     tolerance, against the tile kernel's own output of the same stream
@@ -374,23 +422,25 @@ def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream, slid
     the two agree as closely as either does with the reference), and
     exact zeros at every padding slot."""
     H, Hkv = _LAYOUTS[layout]
-    q, k, v, table, row_of, positions = _block_stream(stream, H, Hkv)
+    Dv, sink = _extras(extra, H, 32)
+    q, k, v, table, row_of, positions = _block_stream(stream, H, Hkv, Dv=Dv)
     qb = query_block(8, H // Hkv)
     assert qb == {"1to1": 128, "4to1": 32, "20to1": 8}[layout]
     got = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
-        interpret=True, q_block=qb, sliding=sliding,
+        interpret=True, q_block=qb, sliding=sliding, **sink,
     )
     by_tile = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
-        interpret=True, sliding=sliding,
+        interpret=True, sliding=sliding, **sink,
     )
     want = ragged_paged_attention_ref(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, window=sliding,
+        **sink,
     )
     live = np.asarray(positions) >= 0
     got = np.asarray(got)
-    assert got.shape == q.shape
+    assert got.shape == (*q.shape[:2], Dv or 32)
     np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=2e-5)
     np.testing.assert_allclose(
         got, np.asarray(by_tile), atol=2e-6 if sliding is None else 2e-5

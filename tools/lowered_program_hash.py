@@ -80,8 +80,11 @@ def main() -> int:
                 state_rows=rows if state is not None else None,
             )
 
+        table = i32(ROWS, PAGES_A_ROW)
+        if mcfg.kv_by_kind:  # a table a pool: (full, sliding)
+            table = (table, table)
         text = jax.jit(forward).lower(
-            params, i32(SLOTS), i32(SLOTS), i32(SLOTS), i32(ROWS, PAGES_A_ROW), k, v,
+            params, i32(SLOTS), i32(SLOTS), i32(SLOTS), table, k, v,
             i32(ROWS), state, (i32(ROWS), i32(2 * ROWS), i32(2 * ROWS)),
         ).as_text()
         text = "\n".join(
