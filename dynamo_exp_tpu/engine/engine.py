@@ -1653,19 +1653,23 @@ class TPUEngine(BlockDecode, AsyncEngine):
         the row before and those whose fetch is exposed
         (``dynamo_attn_one_query_rows_total``): the kernel's own rule
         (``ops/ragged_attention.py::one_query_chain``) at the rows a cell
-        the call's shapes give. The reference counts nothing."""
+        the call's shapes give; and the same rows by the schedule a chunk
+        of their call is worked in (``dynamo_attn_one_query_heads_total``:
+        ``ops/ragged_attention.py::one_query_schedule``, each form of
+        attention by its share of the layers). The reference counts
+        nothing."""
         if self._attn_impl != "pallas":
             return
-        from ..models.llama import one_query_rows
+        from ..models.llama import one_query_rows, one_query_schedules
         from ..ops.ragged_attention import one_query_fetches
 
-        behind, exposed = one_query_fetches(
-            positions,
-            one_query_rows(self.cfg.model, positions.shape[-1], self.cfg.tp),
-        )
-        rows = get_telemetry().attn_one_query_rows
-        rows.labels("behind").inc(behind)
-        rows.labels("exposed").inc(exposed)
+        model, n, tp = self.cfg.model, positions.shape[-1], self.cfg.tp
+        behind, exposed = one_query_fetches(positions, one_query_rows(model, n, tp))
+        tel = get_telemetry()
+        tel.attn_one_query_rows.labels("behind").inc(behind)
+        tel.attn_one_query_rows.labels("exposed").inc(exposed)
+        for schedule, share in one_query_schedules(model, n, tp).items():
+            tel.attn_one_query_heads.labels(schedule).inc((behind + exposed) * share)
 
     def _count_index_passes(self, nb: int, row_of, positions) -> None:
         """Of a mixed stream's tiled part of a model with an indexer, the

@@ -2194,6 +2194,26 @@ def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1) -> int:
     return one_query_block(n, cfg.num_heads // tp, width, value)
 
 
+def one_query_schedules(cfg: ModelConfig, n: int, tp: int = 1) -> dict[str, float]:
+    """The share of the model's attention layers whose one-query call over a
+    stream of ``n`` slots works a chunk for all its KV heads ``together``,
+    and the share that goes ``by_head``
+    (``ops/ragged_attention.py::one_query_schedule`` at each form's KV heads
+    on one of ``tp`` shares, at :func:`one_query_rows` a cell; a latent
+    pool's absorbed form has one KV head). The engine's counter
+    (``dynamo_attn_one_query_heads_total``) asks here what the kernel asks
+    of its own shapes."""
+    from ..ops.ragged_attention import one_query_schedule
+
+    rows = one_query_rows(cfg, n, tp)
+    shares = {"together": 0.0, "by_head": 0.0}
+    forms = [form for form in cfg.attn_forms if form]
+    for form in forms:
+        kv_heads = 1 if cfg.is_latent else cfg.kv_heads_of(form) // tp
+        shares[one_query_schedule(rows, kv_heads)] += 1 / len(forms)
+    return shares
+
+
 def _two_forms(q, pool, w_ukv, table, bounds, plan, slabs, cfg, q_tile,
                sm_scale, interpret, scope, absorbed, keep=None):
     """Latent attention over a stream's tiled part in both forms: the rows

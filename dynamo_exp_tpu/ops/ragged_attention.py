@@ -53,6 +53,21 @@ Two implementations with identical semantics:
   scores come a tile a cell) still are. Before PR 52 every row was a cell
   of its own and its first fetch, 16 page copies behind ~1 us of latency,
   had nothing to hide behind (PERF.md §6, PR 52).
+  Inside such a cell a chunk is worked **for all the call's KV heads
+  together, in phases** (:func:`_heads_together`; PR 54): the ``Hkv`` score
+  products side by side, ONE mask / running maximum / exponential / row
+  sum over the stacked score rows ``[H, S]`` (the position mask, the
+  sliding bound and the sink's start are every head's), the ``Hkv`` value
+  products, ONE ``acc * alpha + pv``; the running ``m``, ``l`` and ``acc``
+  are the chunk loop's carried values and the pass touches the state's
+  references not at all. The walk by head, every head's pair of products
+  and softmax update through ``m_ref`` / ``l_ref`` / ``acc_ref`` in turn,
+  left each head's products waiting for the one before: the call was bound
+  by that chain, not by its fetches (PERF.md §6, PR 54).
+  :func:`one_query_schedule` reads the schedule from the call's shapes:
+  a call of ONE KV head (a latent pool's absorbed form, 20 query heads on
+  one) has no chain to break and keeps the walk by head, as every cell of
+  one row and every tiled call does; the results are the same to the bit.
 
 **A latent pool** (multi-head latent attention, ``models/llama.py::
 _mla_half``): both implementations take ``value_dim``, with which a
@@ -379,6 +394,23 @@ def one_query_fetches(positions, rows: int) -> tuple[int, int]:
     return int(live.sum()) - exposed, exposed
 
 
+def one_query_schedule(rows: int, kv_heads: int) -> str:
+    """How a pass of the one-query call works a chunk, from the call's
+    shapes alone: ``"together"`` (all its KV heads' score products, ONE
+    softmax update over their stacked rows, all their value products, the
+    running state carried as values: :func:`_heads_together`) where a cell
+    walks ``rows`` > 1 slots (:func:`_rows_kernel`) of more than one KV
+    head; ``"by_head"`` (head after head through the state's references)
+    for one KV head, which has no chain to break and keeps its text, and for
+    a cell of one row (the tile kernel: a stream of one slot, a call under a
+    learned selection, the oracle ``q_block=1``). The query heads a KV head
+    decide nothing: at 16, 8, 4 and 1 of them the call alone measured
+    40–48 % faster a chunk together (PERF.md §6, PR 54). The kernel's caller
+    and the engine's counter (``dynamo_attn_one_query_heads_total``) both
+    ask here."""
+    return "together" if rows > 1 and kv_heads > 1 else "by_head"
+
+
 def _chunk_copies(row, base, slot, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
                  *, cp: int, pmax: int):
     """The ``cp`` page copies (``2 * cp`` with a value pool) of one chunk,
@@ -406,6 +438,71 @@ def _chunk_copies(row, base, slot, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
                 )
             )
     return dmas
+
+
+def _side_by_side(product, hkv: int, qpk: int):
+    """``hkv`` products laid side by side as score rows ``[hkv * qpk, ...]``
+    (kv head, group), none waiting for another. ``product(h, lo, hi)`` is KV
+    head ``h``'s product for the rows ``lo .. hi`` of that stack: the whole
+    sublane tiles of 8 its ``qpk`` rows lie in, so every piece is whole
+    vector registers and the stack a concatenation of them. Where ``qpk`` is
+    no whole number of tiles a tile holds several heads' rows: each head's
+    product covers the tile, a row's result depends on no other row, and a
+    select by the row's index takes each head's own (the MXU streams a
+    tile of 8 rows whatever the product has)."""
+    H = hkv * qpk
+    tiles: list = [None] * -(-H // 8)
+    for h in range(hkv):
+        lo, hi = h * qpk // 8 * 8, min(H, -(-(h + 1) * qpk // 8) * 8)
+        out = product(h, lo, hi)
+        for t in range(lo // 8, -(-hi // 8)):
+            piece = out[t * 8 - lo : min(t * 8 + 8, hi) - lo]
+            if tiles[t] is not None:  # rows from h's first on are h's, or a later head's
+                row = t * 8 + jax.lax.broadcasted_iota(jnp.int32, piece.shape, 0)
+                piece = jnp.where(row >= h * qpk, piece, tiles[t])
+            tiles[t] = piece
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+
+
+def _heads_together(q, k_of, v_of, live, state, *, hkv, qpk, scale):
+    """One chunk of a one-query pass for ALL its KV heads, in phases
+    (:func:`one_query_schedule`): the ``hkv`` score products, ONE mask /
+    running maximum / exponential / row sum over the stacked score rows
+    ``[H, S]``, the ``hkv`` value products, ONE ``acc * alpha + pv``.
+    ``q`` [H, D] float32 rows (kv head, group), ``k_of(h)`` [S, D] and
+    ``v_of(h)`` [S, Dv] a head's keys and values of the chunk as fetched,
+    ``live`` [1, S] the positions the query sees (the same for every head),
+    ``state`` ``(m, l, acc)`` as values ([H, 1], [H, 1], [H, Dv] float32).
+    Every score row sees the products, operands and order of chunks of
+    ``_attend``'s walk by head: the result is that walk's to the bit.
+    Returns the new state."""
+    m_prev, l_prev, acc = state
+
+    def scores(h, lo, hi):
+        return jax.lax.dot_general(
+            q[lo:hi], k_of(h).astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    s = _side_by_side(scores, hkv, qpk) * scale  # [H, S]
+    s = jnp.where(live, s, -1e30)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # a query of no key yet: ``_attend``'s note on the finite mask
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    rounded = {}  # a tile of p in the values' dtype, once for its heads
+
+    def values(h, lo, hi):
+        vh = v_of(h)
+        if (lo, hi) not in rounded:
+            rounded[lo, hi] = p[lo:hi].astype(vh.dtype)
+        return jax.lax.dot_general(
+            rounded[lo, hi], vh, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    return m_new, l_new, acc * alpha + _side_by_side(values, hkv, qpk)
 
 
 def _attend(
@@ -439,6 +536,7 @@ def _attend(
     chain=None,
     vw: int | None = None,
     sink=None,
+    together: bool = False,
 ):
     """One double-buffered pass over ``row``'s first ``kvlen`` tokens
     for ``w`` queries: flash-style online softmax, ``qpk * w`` score
@@ -453,6 +551,14 @@ def _attend(
     table, where ``after_live`` says there is one: still every copy
     started is waited for, by the pass it was started for. The pass must
     have something to walk.
+    ``together`` (static; with ``chain``, at one query:
+    :func:`one_query_schedule`) works each chunk for all the KV heads at
+    once (:func:`_heads_together`) with the running maximum, sum and accumulator
+    carried as the chunk loop's values, where the pass otherwise walks the
+    heads one after another through ``m_ref`` / ``l_ref`` / ``acc_ref``,
+    which such a pass neither reads nor writes; the copies, the order of
+    chunks, every product's operands and the end's divide and transpose
+    are the same.
     ``sliding`` (a sliding layer's width, static) with ``first`` (a
     scalar: the smallest position any of the pass's queries sees): the
     walk starts at ``first``'s page and not at the row's, a chunk is
@@ -495,16 +601,25 @@ def _attend(
         base = c * cp if sliding is None else c * cp + page0
         return _chunk_copies(row, base, slot, *pools, cp=cp, pmax=pmax)
 
-    acc_ref[used] = jnp.zeros((n, dv), jnp.float32)
-    if sink is None:
-        m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
-        l_ref[used] = jnp.zeros((n, 128), jnp.float32)
+    if together:
+        # The state as values, carried through the chunk loop: one query a
+        # pass, so a head's sink is its one score row's.
+        state = (
+            jnp.full((n, 1), -1e30, jnp.float32) if sink is None else sink[:, :1],
+            jnp.full((n, 1), 0.0 if sink is None else 1.0, jnp.float32),
+            jnp.zeros((n, dv), jnp.float32),
+        )
     else:
-        # score rows lie (head, query): a head's logit for each of its ``w``
-        m_ref[used] = jnp.broadcast_to(
-            sink[...][:, None, :], (hkv * qpk, w, 128)
-        ).reshape(n, 128)
-        l_ref[used] = jnp.ones((n, 128), jnp.float32)
+        acc_ref[used] = jnp.zeros((n, dv), jnp.float32)
+        if sink is None:
+            m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
+            l_ref[used] = jnp.zeros((n, 128), jnp.float32)
+        else:
+            # score rows lie (head, query): a head's logit for each of its ``w``
+            m_ref[used] = jnp.broadcast_to(
+                sink[...][:, None, :], (hkv * qpk, w, 128)
+            ).reshape(n, 128)
+            l_ref[used] = jnp.ones((n, 128), jnp.float32)
 
     if chain is None:
         @pl.when(n_chunks > 0)
@@ -531,7 +646,7 @@ def _attend(
         q = q.reshape(hkv * qpk * w, hd).astype(k_buf.dtype)
     S = cp * ps
 
-    def body(c, ties_seen):
+    def body(c, carry):
         if chain is None:
             slot = jax.lax.rem(c, 2)
             next_slot = jax.lax.rem(c + 1, 2)
@@ -563,8 +678,23 @@ def _attend(
         tok_idx = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         if sliding is not None:
             tok_idx = tok_idx + page0 * ps
+        if together:
+            pos = pos_rows[:1]  # one query, every head's: the mask is [1, S]
+            live = tok_idx <= pos
+            if sliding is not None:
+                live = live & (tok_idx > pos - sliding)
+
+            def lanes_of(buf, width):
+                # a head's lanes of the chunk, read where they lie
+                return lambda h: buf[slot, :, :, h * width : (h + 1) * width].reshape(S, width)
+
+            return _heads_together(
+                q.reshape(n, hd), lanes_of(k_buf, hd), lanes_of(v_buf, dvh),
+                live, carry, hkv=hkv, qpk=qpk, scale=scale,
+            )
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
         v = v_buf[slot].reshape(S, hkv * dvh) if vd is None else None
+        ties_seen = carry  # by head: the state is the references'
         if keep is not None:
             # [w, S] -> a head's score rows (group, query): the same for
             # every head. Through float32: whole (8, 128) tiles stack.
@@ -631,14 +761,16 @@ def _attend(
             m_ref[rows, :] = jnp.broadcast_to(m_new, m_ref[rows, :].shape)
         return 0 if keep is None else ties_seen
 
-    jax.lax.fori_loop(
-        0, n_chunks, body,
-        0 if keep is None else jnp.zeros((w, 1), jnp.float32),
-    )
-
-    l = l_ref[head, :1]
+    if together:
+        _, l, acc = jax.lax.fori_loop(0, n_chunks, body, state)
+    else:
+        jax.lax.fori_loop(
+            0, n_chunks, body,
+            0 if keep is None else jnp.zeros((w, 1), jnp.float32),
+        )
+        l = l_ref[head, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = acc_ref[used] / l_safe  # [H*W, Dv], rows (kv head, g, i)
+    out = (acc if together else acc_ref[used]) / l_safe  # [H*W, Dv], rows (kv head, g, i)
     out = out.reshape(hkv, qpk, w, dv).transpose(2, 0, 1, 3)
     o_ref[...] = out.reshape(w, hkv * qpk, dv).astype(o_ref.dtype)
 
@@ -795,6 +927,7 @@ def _rows_kernel(
     *refs,  # _pools_out_scratch's
     rb: int,
     qpk: int,
+    together: bool,
     sinks: bool = False,
     **shape,
 ):
@@ -804,8 +937,10 @@ def _rows_kernel(
     chunk, and from then on whatever is multiplied, the chunk that follows
     it, of the same row or the first of the next live one, is on its way
     into the other buffer. A row's own arithmetic is ``_attend``'s at one
-    query, state zeroed at its start. A slot of no query copies nothing
-    and is zeros. With ``sliding`` (in ``shape``) a sixth prefetched array
+    query, state zeroed at its start, a chunk worked for all the KV heads
+    ``together`` where the call has several (the caller's reading of
+    ``one_query_schedule``; ``_attend``'s ``together``). A slot
+    of no query copies nothing and is zeros. With ``sliding`` (in ``shape``) a sixth prefetched array
     comes behind the table: each slot's first visible position
     (``sliding_starts``), whose page its walk starts at."""
     first_ref = None
@@ -854,7 +989,7 @@ def _rows_kernel(
                 lambda: jnp.full((qpk, 1), pos, jnp.int32), row_ref[t],
                 pos + 1, table_ref, k_hbm, v_hbm, *scratch, w=1, qpk=qpk,
                 chain=(slot0, row_ref[then], first_page(then), after < rb),
-                **start, **sink, **shape,
+                together=together, **start, **sink, **shape,
             )
 
         @pl.when(pos < 0)
@@ -1050,7 +1185,10 @@ def ragged_paged_attention(
             scratch_shapes=scratch_for(1),
         )
         return pl.pallas_call(
-            functools.partial(_rows_kernel, rb=rb, **shape),
+            functools.partial(
+                _rows_kernel, rb=rb, **shape,
+                together=one_query_schedule(rb, Hkv) == "together",
+            ),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,

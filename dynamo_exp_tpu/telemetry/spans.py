@@ -254,6 +254,24 @@ class Telemetry:
             ["fetch"],  # behind | exposed
             registry=self.registry,
         )
+        self.attn_one_query_heads = Counter(
+            "dynamo_attn_one_query_heads_total",
+            "Live rows of the attention kernel's one-query call by the "
+            "schedule a chunk of their call's pass is worked in: together "
+            "(all its KV heads' score products, ONE softmax update over "
+            "their stacked rows, all their value products, the running "
+            "state carried as values) or by_head (head after head through "
+            "the state's references: one KV head, a latent pool's absorbed "
+            "form, a cell of one row as under a learned selection or in a "
+            "stream of one slot). Counted where "
+            "dynamo_attn_one_query_rows_total is, with the kernel's own rule "
+            "(ops/ragged_attention.py::one_query_schedule, from the call's "
+            "shapes; a model whose forms of attention differ in their KV "
+            "heads counts each form by its share of the layers); a kernel "
+            "engine only",
+            ["schedule"],  # together | by_head
+            registry=self.registry,
+        )
         self.launch_transfers = Counter(
             "dynamo_launch_transfers_total",
             "Host arrays handed to the device by ragged launches, by "

@@ -21,6 +21,7 @@ Three layers of proof, all on the CPU mesh:
 """
 
 import asyncio
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +37,7 @@ from dynamo_exp_tpu.ops.ragged_attention import (
     one_query_block,
     one_query_chain,
     one_query_fetches,
+    one_query_schedule,
     query_block,
     ragged_decode_attention,
     ragged_paged_attention,
@@ -54,9 +56,14 @@ def _flat_batch(rng, spans, H, Hkv, D, P, ps, pmax, q_tile, dtype=jnp.float32,
     """Build a q_tile-aligned flat query stream from per-row
     ``(q_len, kv_len)`` spans plus a scrambled page pool. ``Dv``: the
     value pool's heads are that wide, not ``D``."""
-    ks = jax.random.split(jax.random.PRNGKey(rng), 3)
-    k = jax.random.normal(ks[1], (P, ps, Hkv * D), dtype)
-    v = jax.random.normal(ks[2], (P, ps, Hkv * (Dv or D)), dtype)
+    # drawn on the host: a jitted draw compiles once a shape, ~0.3 s each
+    draw = np.random.RandomState(rng)
+
+    def normal(*shape):
+        return jnp.asarray(draw.standard_normal(shape), dtype)
+
+    k = normal(P, ps, Hkv * D)
+    v = normal(P, ps, Hkv * (Dv or D))
     perm = np.random.RandomState(rng).permutation(P)
     table = np.zeros((len(spans), pmax), np.int32)
     used = 0
@@ -70,7 +77,7 @@ def _flat_batch(rng, spans, H, Hkv, D, P, ps, pmax, q_tile, dtype=jnp.float32,
         row_of += [r] * (q_len + pad)
         positions += poss + [-1] * pad
     N = len(row_of)
-    q = jax.random.normal(ks[0], (N, H, D), dtype)
+    q = normal(N, H, D)
     return (
         q,
         k,
@@ -179,30 +186,64 @@ _DECODE_ROWS = {
     # the same stream in cells of 4, 4 and 1: no whole number of cells, a
     # cell whose first live row is two slots in, a last cell that is dead
     "cells_of_four": ([0, 0, 5, 128, 0, 129, 300, 1, 0], 4),
+    # a dead row first, in the middle and last of one cell; rows of two and
+    # of three chunks (under a window of 120: each straddles two)
+    "dead_first_middle_last": ([0, 129, 0, 260, 0], None),
+}
+
+# (KV heads) x (query heads a KV head) of the one-query call: (H, Hkv, D,
+# page size, table width). "4x1" is the short rows' (through
+# ``ragged_decode_attention``), "1x2" one KV head; the others are the
+# layouts whose chunks are worked for all KV heads together
+# (``one_query_schedule``): MiMo's full and sliding forms' and K-EXAONE's
+# heads at small widths, Qwen3's at a head narrower than a lane tile, Olmo's
+# one query head a KV head over more than a tile of 8, on pages of a whole
+# chunk (a trace of the interpreted kernel costs by its page copies). A
+# Mixtral shard's 2 x 4 is ``test_gqa_grouping``'s.
+_DECODE_LAYOUTS = {
+    "4x1": (4, 4, 32, 8, 8), "1x2": (2, 1, 32, 16, 20),
+    "4x16": (64, 4, 24, 128, 3), "8x8": (64, 8, 24, 128, 3),
+    "8x4": (32, 8, 16, 128, 3), "10x1": (10, 10, 32, 128, 3),
 }
 
 
-@pytest.mark.parametrize("sliding,rows,extra", [
-    (None, "short", ""), (3, "short", ""), (8, "short", ""),
-    (None, "dead_and_long_in_one_cell", ""), (8, "dead_and_long_in_one_cell", ""),
-    (None, "cells_of_four", ""),
-    (None, "short", "sink+narrow"), (8, "dead_and_long_in_one_cell", "sink+narrow"),
-    (None, "cells_of_four", "sink"),
+@pytest.mark.parametrize("sliding,rows,extra,layout", [
+    (None, "short", "", "4x1"), (3, "short", "", "4x1"), (8, "short", "", "4x1"),
+    (None, "dead_and_long_in_one_cell", "", "1x2"),
+    (8, "dead_and_long_in_one_cell", "", "1x2"),
+    (None, "cells_of_four", "", "1x2"),
+    (None, "short", "sink+narrow", "4x1"),
+    (8, "dead_and_long_in_one_cell", "sink+narrow", "1x2"),
+    (None, "cells_of_four", "sink", "1x2"),
+    (None, "dead_first_middle_last", "narrow", "4x16"),
+    (120, "dead_first_middle_last", "sink+narrow", "8x8"),
+    (None, "dead_first_middle_last", "", "8x4"),
+    (None, "dead_first_middle_last", "", "10x1"),
 ])
-def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows, extra):
+def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows, extra, layout):
     """q_tile=1, one query per row — the compiled decode window's
     per-step shape. Rows with length 0 return exact zeros. With a sliding
     width a row's walk starts at its newest ``sliding`` keys' page, each
     row's its own: the pages before it may hold anything (here: NaN).
     The rows of a cell share one copy pipeline (a row's first chunk is
-    fetched behind the row before it): to the bit what a cell a row gives."""
+    fetched behind the row before it): to the bit what a cell a row gives.
+    A cell of several rows of several KV heads works a chunk for all its KV
+    heads together, the state carried as values; a cell a row (``q_block=1``)
+    and a call of one KV head walk head by head through the state's
+    references: the same products in the same order of chunks, so the same
+    bits. (At ONE query head a KV head the interpreter on the CPU is a
+    rounding apart: XLA:CPU multiplies a lone row by its matrix-vector
+    emitter, which sums in another order than the product of the 8-row tile
+    the head's row lies in. On the chip a row's product does not depend on
+    the rows beside it: PERF.md §6, PR 54.)"""
     lengths, q_block = _DECODE_ROWS[rows]
     short = rows == "short"
-    H, Hkv, D, ps, pmax = (4, 4, 32, 8, 8) if short else (2, 1, 32, 16, 20)
+    H, Hkv, D, ps, pmax = _DECODE_LAYOUTS[layout]
     Dv, sink = _extras(extra, H, D)
     spans = [(1, max(ln, 1)) for ln in lengths]
+    pool = 128 if ps < 128 else 16  # pages: of a whole chunk, a row has few
     q, k, v, table, row_of, positions = _flat_batch(
-        2, spans, H, Hkv, D, 128, ps, pmax, q_tile=1, Dv=Dv
+        2, spans, H, Hkv, D, pool, ps, pmax, q_tile=1, Dv=Dv
     )
     n = len(lengths)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -213,7 +254,7 @@ def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows, extra
     if sliding is not None:
         # the pages that lie wholly behind a row's window: one page of NaN
         # that no other entry of the table names
-        gone = 127
+        gone = pool - 1
         for r, ln in enumerate(lengths):
             table = table.at[r, : max(ln - sliding, 0) // ps].set(gone)
         k, v = k.at[gone].set(jnp.nan), v.at[gone].set(jnp.nan)
@@ -231,7 +272,13 @@ def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows, extra
             )
             for block in (q_block, 1)
         )
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(a_row))
+        rows_a_cell = q_block or one_query_block(n, H, D, Dv or D)
+        together = one_query_schedule(rows_a_cell, Hkv) == "together"
+        assert together == (Hkv > 1) and one_query_schedule(1, Hkv) == "by_head"
+        if H == Hkv > 1:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(a_row), rtol=0, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(a_row))
     out = np.asarray(got)
     active = np.asarray(lengths) > 0
     np.testing.assert_allclose(
@@ -554,8 +601,8 @@ def test_host_count_and_device_rule_agree_on_a_drawn_stream(seed):
     assert kinds >= {-1, 1}  # blocks of one row and of several were drawn
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed):
+@pytest.mark.parametrize("seed,kv_heads", [(0, 2), (1, 2), (2, 2), (3, 1)])
+def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed, kv_heads):
     """``dynamo_attn_one_query_rows_total``: the engine counts on the host
     with ``numpy`` which rows of a one-query call have their first chunk
     fetched behind another row; the kernel walks ``one_query_chain``'s
@@ -564,7 +611,26 @@ def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed):
     park after some of its steps, a bucket wider than its rows) and a drawn
     packed tail, and a walk of the chain as the kernel makes it (a cell
     starts its ``lead``, every row starts its ``after``) visits each live
-    row once: started by the cell it is exposed, by a row behind."""
+    row once: started by the cell it is exposed, by a row behind.
+    ``dynamo_attn_one_query_heads_total``: the same rows under the schedule
+    their call's chunks are worked in, which the engine asks of the one
+    rule the kernel's caller asks (``one_query_schedule``): TINY's 4 query
+    heads on ``kv_heads`` KV heads, ``together`` at 2 wherever a cell has
+    more than one row, ``by_head`` at 1."""
+    from types import SimpleNamespace
+
+    from dynamo_exp_tpu.models.llama import one_query_rows
+    from dynamo_exp_tpu.telemetry import get_telemetry
+
+    model = dataclasses.replace(TINY, num_kv_heads=kv_heads)
+    engine = SimpleNamespace(_attn_impl="pallas", cfg=SimpleNamespace(model=model, tp=1))
+
+    def counted():
+        return {
+            label: get_telemetry().attn_one_query_heads.labels(label)._value.get()
+            for label in ("together", "by_head")
+        }
+
     rng = np.random.default_rng(seed)
     nb, steps = int(rng.choice([8, 16, 32])), 8
     window = np.full((steps, nb), -1, np.int32)
@@ -572,6 +638,13 @@ def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed):
         window[: int(rng.integers(1, steps + 1)), r] = int(rng.integers(0, 900))
     tail = np.where(rng.random(24) < 0.6, rng.integers(0, 900, 24), -1).astype(np.int32)
     for positions in (window, tail):
+        before = counted()
+        TPUEngine._count_one_query_rows(engine, positions)
+        n = positions.shape[-1]
+        schedule = one_query_schedule(one_query_rows(model, n), kv_heads)
+        assert schedule == ("together" if kv_heads > 1 and n > 1 else "by_head")
+        grew = {label: after - before[label] for label, after in counted().items()}
+        assert grew.pop(schedule) == (positions >= 0).sum() and not any(grew.values())
         for rows in (one_query_block(positions.shape[-1], 4, 32, 32), 5, 1):
             behind = exposed = 0
             for stream in positions.reshape(-1, positions.shape[-1]):
@@ -613,6 +686,7 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
         return out
 
     rows_total = "dynamo_attn_one_query_rows_total"
+    heads_total = "dynamo_attn_one_query_heads_total"
 
     for interpret in (True, False):
         eng = _mixed_engine(
@@ -635,7 +709,7 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
             count_rows(positions)
 
         eng._count_one_query_rows = spy_rows
-        before, rows_before = read(), read(rows_total)
+        before, rows_before, heads_before = read(), read(rows_total), read(heads_total)
         eng.start()
         try:
             prompt = list(np.random.RandomState(5).randint(3, 200, size=70))
@@ -647,8 +721,15 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
         rows = {
             k: v - rows_before.get(k, 0.0) for k, v in read(rows_total).items()
         }
+        heads = {
+            k: v - heads_before.get(k, 0.0) for k, v in read(heads_total).items()
+        }
         assert seen and sum(seen) > 0
         if interpret:
+            # ``dynamo_attn_one_query_heads_total``: the same rows, by the
+            # schedule of their call (TINY's 2 KV heads: together wherever
+            # a cell has more than one row)
+            assert sum(heads.values()) == sum(offered) and heads["together"] > 0
             # TINY has 4 query heads on 2 KV heads: blocks of 64.
             assert sum(grew.values()) == sum(seen)
             assert set(grew) == {"merged", "tile"}
@@ -666,6 +747,7 @@ def test_engine_counts_every_tiled_slot_under_one_pass_label():
             assert after["exposed"] - rows_before.get("exposed", 0.0) == rows["exposed"] + 2
         else:
             assert not any(grew.values()) and not any(rows.values())
+            assert not any(heads.values())
 
 
 # --------------------------------------------------- two-program oracle

@@ -31,10 +31,17 @@ that the host's dispatch is not in the number. A straight line through the
 three, ``time a row = a_row + a_chunk x chunks``, splits the call into what
 a row costs whatever it reads (its first fetch where nothing hides it, its
 state's zeroing, the divide and the transpose) and what a chunk costs (16
-page copies and a product pair a KV head): PERF.md §6, PR 52.
+page copies and a product pair a KV head): PERF.md §6, PR 52 and PR 54.
 ``--one-query-rows 1,8,32`` times the same call at those rows a grid cell
 beside the rule's (``one_query_block``; 1 is the one-row cell every call
-was before PR 52), the table behind the rule. A two-pool model only.
+was before PR 52, whose chunks are worked head by head: the reference for
+the schedule ``one_query_schedule`` gives a call, which a line names), the
+table behind the rule. A cache by kind (``mimo-v2-flash-l11e32``,
+``k-exaone-236b-l8e16``) gets a line a form and row bucket, each form at
+its own KV heads, widths, window and sink; where values are narrower than
+keys the next call's queries are the result beside the old queries' last
+lanes. ``--contexts ''`` skips the window lines and builds no engine. Not
+a latent pool.
 """
 
 from __future__ import annotations
@@ -114,60 +121,108 @@ def time_program(engine, key: tuple, args: list, calls: int) -> list[float]:
     return out
 
 
-def one_query_lines(args, mcfg, ecfg, dev):
-    """The one-query call alone, a line a row bucket: its time at each
-    context of ``--one-query-chunks`` and the fit a row / a chunk."""
+def one_query_forms(mcfg, ecfg) -> list[dict]:
+    """The shapes of the model's one-query calls on one chip, a form of
+    attention each: one for a model of one pool pair, ``full`` and
+    ``sliding`` for a cache by kind (their KV heads, the sliding form's
+    window, sink and smaller pool)."""
+    forms = sorted(set(mcfg.attn_forms) - {""}) if mcfg.kv_by_kind else [""]
+    return [
+        {
+            "form": form,
+            "heads": mcfg.num_heads // ecfg.tp,
+            "kv_heads": mcfg.kv_heads_of(form) // ecfg.tp,
+            "width": mcfg.head_dim_,
+            "value_width": mcfg.value_head_dim_,
+            "sliding": mcfg.sliding_window if form == "sliding" else None,
+            "sink": form in mcfg.sink_forms,
+            "num_pages": ecfg.sliding_pool_pages if form == "sliding" else ecfg.num_pages,
+        }
+        for form in forms
+    ]
+
+
+def one_query_lines(args, shape: dict, ps: int, dev):
+    """The one-query call alone at one form's ``shape``
+    (``one_query_forms``), a line a row bucket: its time at each context of
+    ``--one-query-chunks`` and the fit a row / a chunk. A sliding form
+    walks its window, not its context: one chunk at a context of one, two
+    at a longer one, whose query is put mid-chunk so that its window
+    straddles; the fit is over the chunks walked."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from dynamo_exp_tpu.ops.ragged_attention import ragged_paged_attention
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        one_query_block,
+        one_query_schedule,
+        ragged_paged_attention,
+    )
 
-    heads, kv_heads, hd = mcfg.num_heads // ecfg.tp, mcfg.num_kv_heads // ecfg.tp, mcfg.head_dim_
-    ps, chunk = ecfg.page_size, 128
+    heads, kv_heads, hd, vd = shape["heads"], shape["kv_heads"], shape["width"], shape["value_width"]
+    sliding, num_pages, chunk = shape["sliding"], shape["num_pages"], 128
     chunks = [int(c) for c in args.one_query_chunks.split(",")]
     forms = [None] + [int(r) for r in args.one_query_rows.split(",") if r]
     interpret = dev.platform != "tpu"
-    keys = jax.random.split(jax.random.PRNGKey(args.seed), 3)
-    pool = (ecfg.num_pages, ps, kv_heads * hd)
-    k = jax.random.normal(keys[0], pool, jnp.bfloat16)
-    v = jax.random.normal(keys[1], pool, jnp.bfloat16)
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
+    k = jax.random.normal(keys[0], (num_pages, ps, kv_heads * hd), jnp.bfloat16)
+    v = jax.random.normal(keys[1], (num_pages, ps, kv_heads * vd), jnp.bfloat16)
+    more = {"sliding": sliding}
+    if shape["sink"]:
+        more["sink"] = jax.random.normal(keys[3], (heads,), jnp.float32)
     pages = max(chunks) * chunk // ps
 
     def chained(q_block):
-        kw = {} if q_block is None else {"q_block": q_block}
+        kw = dict(more) if q_block is None else {"q_block": q_block, **more}
 
         @jax.jit
-        def run(q, table, positions):
+        def run(q, k, v, table, positions):  # the pools as arguments: a closure's are constants of the program
             rows = jnp.arange(q.shape[0], dtype=jnp.int32)
-            return jax.lax.fori_loop(0, args.one_query_reps, lambda _, x: ragged_paged_attention(
-                x, k, v, table, rows, positions, num_kv_heads=kv_heads, q_tile=1,
-                interpret=interpret, **kw,
-            ), q)
+
+            def call(_, x):
+                out = ragged_paged_attention(
+                    x, k, v, table, rows, positions, num_kv_heads=kv_heads, q_tile=1,
+                    interpret=interpret, **kw,
+                )
+                # the next call's queries: where values are narrower than
+                # keys, the lanes the result has not stay the queries'
+                return out if vd == hd else jnp.concatenate([out, x[..., vd:]], axis=-1)
+
+            return jax.lax.fori_loop(0, args.one_query_reps, call, q)
 
         return run
 
     for nb in (int(r) for r in args.rows.split(",")):
         rng = np.random.default_rng([args.seed, nb, 2])
         # each row on pages of its own as far as the pool goes
-        table = (1 + (np.arange(nb * pages) % (ecfg.num_pages - 1))).reshape(nb, pages)
+        table = (1 + (np.arange(nb * pages) % (num_pages - 1))).reshape(nb, pages)
         table = jnp.asarray(rng.permutation(table.reshape(-1)).reshape(nb, pages), jnp.int32)
         q = jax.random.normal(keys[2], (nb, heads, hd), jnp.bfloat16)
         for q_block in forms:
             if q_block is not None and q_block > nb:
                 continue
-            run, line = chained(q_block), {"one_query": True, "nb": nb, "rows_a_cell": q_block or "rule"}
+            run = chained(q_block)
+            rows_a_cell = q_block or one_query_block(nb, heads, hd, vd)
+            line = {
+                "one_query": True, "form": shape["form"], "nb": nb, "rows_a_cell": q_block or "rule",
+                "schedule": one_query_schedule(rows_a_cell, kv_heads),
+            }
+            walked = []
             for c in chunks:
-                positions = jnp.full((nb,), c * chunk - 1, jnp.int32)
+                at = c * chunk - 1
+                if sliding is not None and c > 1:
+                    at -= chunk // 2 + 1  # its window's first key then lies inside a page
+                walked.append(c if sliding is None else min(c, 2))
+                positions = jnp.full((nb,), at, jnp.int32)
                 times = []
                 for i in range(args.calls + 2):  # the first call compiles, the second loads
                     t0 = time.perf_counter()
-                    jax.block_until_ready(run(q, table, positions))
+                    jax.block_until_ready(run(q, k, v, table, positions))
                     if i >= 2:
                         times.append(time.perf_counter() - t0)
                 line[f"call_us_at_{c}_chunks"] = 1e6 * statistics.median(times) / args.one_query_reps
-            if len(chunks) > 1:
-                a_chunk, a_row = np.polyfit(chunks, [line[f"call_us_at_{c}_chunks"] / nb for c in chunks], 1)
+            if len(set(walked)) > 1:
+                a_chunk, a_row = np.polyfit(walked, [line[f"call_us_at_{c}_chunks"] / nb for c in chunks], 1)
                 line["us_a_row"], line["us_a_chunk"] = float(a_row), float(a_chunk)
             yield line
 
@@ -176,7 +231,8 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", required=True)
     ap.add_argument("--rows", default="1,2,4,8,16,32,64")
-    ap.add_argument("--contexts", default="uniform,chat")
+    ap.add_argument("--contexts", default="uniform,chat",
+                    help="context draws of the window lines ('' skips them: the one-query lines alone)")
     ap.add_argument("--seed", type=int, default=30)
     ap.add_argument("--calls", type=int, default=12)
     ap.add_argument("--one-query-chunks", default="1,3,32",
@@ -217,10 +273,12 @@ def main() -> int:
         # page bound is the program attention_impl="xla" builds.
         attention_impl="pallas", pallas_interpret=dev.platform != "tpu",
     )
-    engine = TPUEngine(ecfg)
-    if engine._attn_impl != "pallas":
-        print("the kernel cannot take this layout", file=sys.stderr)
-        return 1
+    kinds = [c for c in args.contexts.split(",") if c]
+    if kinds:
+        engine = TPUEngine(ecfg)
+        if engine._attn_impl != "pallas":
+            print("the kernel cannot take this layout", file=sys.stderr)
+            return 1
     pages = ecfg.ragged_page_bucket_for(1)
     head = {
         "config": name, "platform": dev.platform, "device_kind": dev.device_kind,
@@ -236,7 +294,7 @@ def main() -> int:
     suffix = "" if dev.platform == "tpu" else ".cpu-rehearsal"
     with open(os.path.join(out_dir, f"{name}{suffix}.jsonl"), "w") as f:
         f.write(json.dumps(head) + "\n")
-        for kind in args.contexts.split(","):
+        for kind in kinds:
             for nb in (int(r) for r in args.rows.split(",")):
                 rng = np.random.default_rng([args.seed, nb])
                 room = ecfg.max_model_len - ecfg.decode_window - 1  # a tiny rehearsal model's
@@ -256,10 +314,11 @@ def main() -> int:
                 line["gather_over_kernel"] = line["gather_step_ms"] / line["kernel_step_ms"]
                 print(json.dumps(line), flush=True)
                 f.write(json.dumps(line) + "\n")
-        if args.one_query_chunks and not (mcfg.is_latent or mcfg.kv_by_kind):
-            for line in one_query_lines(args, mcfg, ecfg, dev):
-                print(json.dumps(line), flush=True)
-                f.write(json.dumps(line) + "\n")
+        if args.one_query_chunks and not mcfg.is_latent:
+            for shape in one_query_forms(mcfg, ecfg):
+                for line in one_query_lines(args, shape, ecfg.page_size, dev):
+                    print(json.dumps(line), flush=True)
+                    f.write(json.dumps(line) + "\n")
     return 0
 
 
