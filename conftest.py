@@ -8,9 +8,13 @@ hence env vars here (child processes inherit them).
 """
 
 import asyncio
+import contextlib
+import faulthandler
 import inspect
 import os
+import signal
 import sys
+import threading
 
 import pytest
 
@@ -30,8 +34,9 @@ def pytest_configure(config):
 
 # ---------------------------------------------------------------- test tiers
 # Reference discipline: marker tiers (pre_merge/nightly/weekly) selected by
-# CI (.github/workflows/). The full suite is ~9.5 min; CI's per-commit
-# budget wants < 2 min. Tiering is centralized here instead of per-file
+# CI (.github/workflows/); what the driver holds a PR to is tier-1, every
+# test not marked ``slow`` (docs/testing.md "Tier-1": its command, budgets
+# and conventions). Tiering is centralized here instead of per-file
 # pytestmark lines so the split is auditable in one place: a test is
 # pre_merge unless its file (or name) is listed below.
 #
@@ -44,7 +49,9 @@ _NIGHTLY_FILES = {
     "test_run_cli.py",  # multi-process discovery serve
     "test_sdk.py",  # SDK supervisor lifecycle
     "test_multihost.py",  # jax.distributed bring-up subprocesses
-    "test_ragged_attention.py",  # ragged kernel interpret-mode vs ref oracle
+    "test_ragged_attention.py",  # interpret-mode engines, the recompile guard
+    "test_ragged_kernels.py",  # ragged kernel interpret-mode vs ref oracle
+    "test_ragged_expanded.py",  # the expanded call, interpret-mode vs ref oracle
     "test_logprobs.py",  # engine logprob oracle runs
     "test_disagg.py",  # two-engine disagg e2e
     "test_decode_compaction.py",  # occupancy-proportional decode proofs
@@ -96,18 +103,54 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.pre_merge)
 
 
+# A test's own clock (docs/testing.md "Budgets"): a coroutine test is
+# cancelled by its event loop, a synchronous one by SIGALRM, which raises in
+# the test and leaves the worker alive, so a hang costs one failure and the
+# stacks of all threads on stderr, never the run's whole time limit. 300 s:
+# the slowest test fits in it twice over. Constants, not options.
+_ASYNC_TEST_LIMIT_S = 120.0
+_SYNC_TEST_LIMIT_S = 300.0
+
+
+@contextlib.contextmanager
+def a_clock_of_its_own(nodeid: str, limit_s: float):
+    """Raise ``TimeoutError`` in the calling (main) thread once ``limit_s``
+    have passed inside the block; off the main thread a signal cannot be
+    taken, so there the block runs unlimited."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        raise TimeoutError(f"{nodeid} ran past its {limit_s:.0f} s")
+
+    before = signal.signal(signal.SIGALRM, expired)
+    # A hang inside a call that takes no signal still leaves its traceback.
+    faulthandler.dump_traceback_later(limit_s, exit=False, file=sys.__stderr__)
+    signal.setitimer(signal.ITIMER_REAL, limit_s)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, before)
+
+
 @pytest.hookimpl(tryfirst=True)
 def pytest_pyfunc_call(pyfuncitem):
-    """Minimal asyncio support (pytest-asyncio is not in the image)."""
+    """Minimal asyncio support (pytest-asyncio is not in the image) and the
+    per-test limit (neither is pytest-timeout)."""
     fn = pyfuncitem.obj
+    kwargs = {
+        name: pyfuncitem.funcargs[name]
+        for name in pyfuncitem._fixtureinfo.argnames
+    }
     if inspect.iscoroutinefunction(fn):
-        kwargs = {
-            name: pyfuncitem.funcargs[name]
-            for name in pyfuncitem._fixtureinfo.argnames
-        }
-        asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=120))
-        return True
-    return None
+        asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=_ASYNC_TEST_LIMIT_S))
+    else:
+        with a_clock_of_its_own(pyfuncitem.nodeid, _SYNC_TEST_LIMIT_S):
+            fn(**kwargs)
+    return True
 
 
 _CACHE_OPTIONS = (

@@ -28,6 +28,9 @@ from dynamo_exp_tpu.models.config import (
 from dynamo_exp_tpu.ops import moe
 from dynamo_exp_tpu.protocols.common import BackendInput
 
+# Jitted: a shape and set of options compiles once a file (tests/programs.py).
+from .programs import forward, forward_ragged, init_params
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 from references import exaone_moe as reference  # noqa: E402
 
@@ -60,7 +63,7 @@ PROMPT = [int(t) for t in np.random.default_rng(0).integers(2, 256, 96)]
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(SEED), CFG)
+    return init_params(jax.random.PRNGKey(SEED), CFG)
 
 
 def _logp(logits):
@@ -194,7 +197,7 @@ def test_the_pools_hold_what_the_text_says(params):
     n = 23
     full, sliding = _tables(1, 8, 8)
     k, v = llama.init_kv_cache(CFG, 8, PS, sliding_pages=8)
-    _, k, v = llama.forward(
+    _, k, v = forward(
         params, CFG, jnp.asarray([PROMPT[:n]], jnp.int32), jnp.arange(n)[None],
         (full, sliding), k, v,
     )
@@ -235,7 +238,7 @@ def test_a_fault_shows(params, fault):
         cfg = dataclasses.replace(CFG, rope_sliding_only=False)
     full, sliding = _tables(1, 10, 10)
     k, v = llama.init_kv_cache(cfg, 10, PS, sliding_pages=10)
-    logits, k, v = llama.forward(
+    logits, k, v = forward(
         params, cfg, jnp.asarray([PROMPT[:n - 1]], jnp.int32), jnp.arange(n - 1)[None],
         (full, sliding), k, v,
     )
@@ -243,7 +246,7 @@ def test_a_fault_shows(params, fault):
         # the page that still holds position (n - 1) - 7's neighbours
         gone = int(sliding[0, (n - 1 - (W - 1)) // PS])
         k = (k[0], k[1].at[:, gone].set(0.0))
-    last, *_ = llama.forward(
+    last, *_ = forward(
         params, cfg, jnp.asarray([[PROMPT[n - 1]]], jnp.int32), jnp.asarray([[n - 1]]),
         (full, sliding), k, v,
     )
@@ -328,7 +331,7 @@ def _whole(cfg, params):
     """The uncut layer's parameters: every expert, of which ``params`` holds
     ``cfg``'s share; the others seeded here."""
     lo, n = cfg.first_expert_held, cfg.experts_held
-    full = llama.init_params(
+    full = init_params(
         jax.random.PRNGKey(11), dataclasses.replace(cfg, num_experts_held=0, first_expert_held=0)
     )
     layers = dict(full["layers"])
@@ -350,7 +353,7 @@ def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(preset):
     guide's section 4 test)."""
     cfg = dataclasses.replace(preset, dtype="float32")
     held = cfg.experts_held
-    layers = _whole(cfg, llama.init_params(jax.random.PRNGKey(SEED), cfg))
+    layers = _whole(cfg, init_params(jax.random.PRNGKey(SEED), cfg))
     h = jnp.asarray(np.random.default_rng(7).standard_normal((96, cfg.hidden_size)), jnp.float32)
     router = dict(
         score="sigmoid", scale=cfg.routed_scaling_factor, norm_eps=cfg.router_norm_eps,
@@ -524,7 +527,7 @@ def test_engine_through_the_kernel():
     }
     eng = _engine(
         ModelConfig.from_hf_config(hf), attention_impl="pallas", pallas_interpret=True,
-        max_decode_slots=2, max_model_len=64,
+        max_decode_slots=2, max_model_len=32,  # 8 pages a row: 8 page copies a chunk
     )
     eng.start()
     try:

@@ -261,6 +261,9 @@ def test_forward_takes_the_step_kernel_where_the_stream_takes_the_chunked(form):
     from dynamo_exp_tpu.models import llama
     from dynamo_exp_tpu.models.config import TINY_OLMO_HYBRID
 
+    # Jitted (tests/programs.py): both cases' histories are one compiled program.
+    from .programs import forward, forward_ragged
+
     cfg = dataclasses.replace(TINY_OLMO_HYBRID, dtype="float32")
     one_token = form == "one_token"
     tail = None if one_token else 16
@@ -272,15 +275,16 @@ def test_forward_takes_the_step_kernel_where_the_stream_takes_the_chunked(form):
     tables = jnp.stack([jnp.arange(1 + 4 * i, 5 + 4 * i) for i in range(4)]).astype(jnp.int32)
     k, v = llama.init_kv_cache(cfg, 17, 8, dtype=jnp.float32)
     st = llama.init_seq_state(cfg, 6)
-    for r, n in ((1, 9), (2, 6)):  # rows 1 and 2 have tokens behind them
-        _, k, v, st = llama.forward(
-            params, cfg, jnp.asarray(toks[:n])[None], jnp.arange(n, dtype=jnp.int32)[None],
+    for r, n in ((1, 9), (2, 6)):  # rows 1 and 2 have tokens behind them, in 16 slots
+        _, k, v, st = forward(
+            params, cfg, jnp.asarray(toks[:n] + [0] * (16 - n))[None],
+            jnp.asarray(list(range(n)) + [-1] * (16 - n), jnp.int32)[None],
             tables[r:r + 1], k, v, seq_state=st, state_slots=jnp.asarray([r]),
         )
 
     def run(impl):
         if one_token:  # rows 1, 2, a row at position 0 and a padding row
-            return llama.forward(
+            return forward(
                 params, cfg, jnp.asarray([[toks[9]], [toks[6]], [toks[0]], [0]]),
                 jnp.asarray([[9], [6], [0], [-1]], jnp.int32), tables, k, v,
                 seq_state=st, state_slots=jnp.asarray([1, 2, 3, 6]), attn_impl=impl,
@@ -288,7 +292,7 @@ def test_forward_takes_the_step_kernel_where_the_stream_takes_the_chunked(form):
             )
         # a span of 11 tokens (16 slots), then the tail: rows 1 and 2, padding
         i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
-        return llama.forward_ragged(
+        return forward_ragged(
             params, cfg, i32(toks[20:31] + [0] * 5 + [toks[9], toks[6]] + [0] * 6),
             i32(list(range(11)) + [-1] * 5 + [9, 6] + [-1] * 6),
             i32([0] * 16 + [1, 2] + [3] * 6), tables, k, v, i32([10, 16, 17]),

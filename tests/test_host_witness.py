@@ -220,37 +220,53 @@ def stalls_of(ring) -> list[dict]:
 
 
 def test_a_thread_that_holds_the_gil_is_a_busy_stall():
-    tel = Telemetry()
-    witness = HostWitness(tel)
-    ring = FlightRecorder()
-    ring.record("dispatch", dispatch="ragged", bucket=4)
-    handle = witness.attach(
-        ring, lambda: {"dispatch_seq": 0, "program": "window", "bucket": 4}
+    # A machine under load may give the spin less CPU than wall time, and the
+    # stall is then no GIL stall alone: spin again, longer (a longer hold
+    # outlasts a short preemption), a bounded number of times. Never a skip: a
+    # slow machine takes nothing from the count. Where no spin got three
+    # quarters of its wall time (six busy workers gave 0.70 at all four holds,
+    # PR 55's own run), the stall is still held to everything below but its
+    # cause, which is then held to the witness's OWN rule on its own two
+    # clocks (``busy`` iff the process's CPU clock moved half the time lost).
+    readings = []
+    for hold in (0.3, 0.6, 1.2, 2.4):
+        tel = Telemetry()
+        witness = HostWitness(tel)
+        ring = FlightRecorder()
+        ring.record("dispatch", dispatch="ragged", bucket=4)
+        handle = witness.attach(
+            ring, lambda: {"dispatch_seq": 0, "program": "window", "bucket": 4}
+        )
+        try:
+            time.sleep(0.1)  # the witness is asleep, in step
+            spun = ran = 0.0
+            n = 4_000_000
+            while spun < hold:  # one call that keeps the GIL for ``hold`` s
+                n *= 2
+                t0, c0 = time.monotonic(), time.process_time()
+                sum(range(n))
+                spun, ran = time.monotonic() - t0, time.process_time() - c0
+            time.sleep(0.1)
+        finally:
+            witness.detach(handle)
+        stalls = stalls_of(ring)
+        assert stalls, f"a {hold} s hold of the GIL went unseen"
+        worst = max(stalls, key=lambda e: e["seconds"])
+        readings.append((round(ran, 2), round(spun, 2)))
+        if ran >= 0.75 * spun:
+            break
+    margin = worst["process_cpu_s"] - 0.5 * worst["seconds"]  # both rounded to 1e-6
+    assert worst["cause"] == ("busy" if margin >= 0 else "descheduled") or abs(margin) < 1e-5, (
+        worst, readings,
     )
-    try:
-        time.sleep(0.1)  # the witness is asleep, in step
-        spun = ran = 0.0
-        n = 4_000_000
-        while spun < 0.3:  # one call that keeps the GIL for 300 ms
-            n *= 2
-            t0, c0 = time.monotonic(), time.process_time()
-            sum(range(n))
-            spun, ran = time.monotonic() - t0, time.process_time() - c0
-        time.sleep(0.1)
-    finally:
-        witness.detach(handle)
-    stalls = stalls_of(ring)
-    assert stalls, "a 300 ms hold of the GIL went unseen"
-    worst = max(stalls, key=lambda e: e["seconds"])
-    if ran < 0.75 * spun:
-        pytest.skip(f"the machine gave the spin {ran:.2f} s of {spun:.2f}: not a GIL stall alone")
-    assert worst["cause"] == "busy"
+    if ran >= 0.75 * spun:  # the spin had its time: a GIL stall alone
+        assert worst["cause"] == "busy", (worst, readings)
+    cause = worst["cause"]
     assert 0.1 < worst["seconds"] <= spun + 0.5
-    assert worst["process_cpu_s"] >= 0.5 * worst["seconds"]
     # Which dispatch it fell in: the engine's context, and the ring's place.
     assert (worst["dispatch_seq"], worst["program"], worst["after"]) == (0, "window", 0)
-    assert value(tel, "dynamo_host_stall_seconds_total", cause="busy") >= worst["seconds"] - 1e-6
-    assert value(tel, "dynamo_host_stalls_total", cause="busy") >= 1
+    assert value(tel, "dynamo_host_stall_seconds_total", cause=cause) >= worst["seconds"] - 1e-6
+    assert value(tel, "dynamo_host_stalls_total", cause=cause) >= 1
 
 
 @pytest.mark.skipif(not hasattr(signal, "SIGSTOP"), reason="no job control")

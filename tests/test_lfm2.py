@@ -30,6 +30,9 @@ from dynamo_exp_tpu.models.config import TINY_LFM2
 from dynamo_exp_tpu.ops.moe import moe_router
 from dynamo_exp_tpu.protocols.common import BackendInput
 
+# Jitted: a shape and set of options compiles once a file (tests/programs.py).
+from .programs import forward, forward_ragged, init_params
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 from references import lfm2_moe as reference  # noqa: E402
 
@@ -54,7 +57,7 @@ PROMPT = [int(t) for t in np.random.default_rng(0).integers(2, 256, 40)]
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(SEED), CFG)
+    return init_params(jax.random.PRNGKey(SEED), CFG)
 
 
 def _pools(entries=4):
@@ -102,7 +105,7 @@ def test_forward_matches_reference(params, phase):
     k, v, st = _pools()
     T = 21
     slot = jnp.asarray([2])
-    logits, k, v, st = llama.forward(
+    logits, k, v, st = forward(
         params, CFG, jnp.asarray(PROMPT[:T])[None], jnp.arange(T, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=st, state_slots=slot,
     )
@@ -111,7 +114,7 @@ def test_forward_matches_reference(params, phase):
         assert np.abs(_logp(logits[0]) - want).max() < LOGP_TOL
         return
     for t in range(T, T + 6):
-        logits, k, v, st = llama.forward(
+        logits, k, v, st = forward(
             params, CFG, jnp.asarray(PROMPT[t:t + 1])[None], jnp.asarray([[t]], jnp.int32),
             TABLE, k, v, seq_state=st, state_slots=slot,
         )
@@ -129,11 +132,13 @@ def test_state_through_chunked_prefill(params, cut):
     k, v, st = _pools()
     got = []
     for a, b in ((0, cut), (cut, T)):
-        logits, k, v, st = llama.forward(
-            params, CFG, jnp.asarray(PROMPT[a:b])[None], jnp.arange(a, b, dtype=jnp.int32)[None],
+        pad = T - (b - a)  # every piece in T slots: one compiled program for all cuts
+        logits, k, v, st = forward(
+            params, CFG, jnp.asarray(PROMPT[a:b] + [0] * pad)[None],
+            jnp.asarray(list(range(a, b)) + [-1] * pad, jnp.int32)[None],
             TABLE, k, v, seq_state=st, state_slots=jnp.asarray([1]),
         )
-        got.append(_logp(logits[0]))
+        got.append(_logp(logits[0])[: b - a])
     assert np.abs(np.concatenate(got) - want).max() < LOGP_TOL
 
 
@@ -143,11 +148,11 @@ def test_a_state_left_at_zero_shows(params):
     T, cut = 12, 4
     want = reference.log_probs(params, HF, PROMPT[:T], range(cut, T))
     k, v, st = _pools()
-    _, k, v, _ = llama.forward(
+    _, k, v, _ = forward(
         params, CFG, jnp.asarray(PROMPT[:cut])[None], jnp.arange(cut, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=st, state_slots=jnp.asarray([1]),
     )
-    logits, *_ = llama.forward(
+    logits, *_ = forward(
         params, CFG, jnp.asarray(PROMPT[cut:T])[None], jnp.arange(cut, T, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=jnp.zeros_like(st), state_slots=jnp.asarray([1]),
     )
@@ -164,7 +169,7 @@ def test_mixed_stream_with_padding(params, stream):
     # Sequence B (row 1) has 9 tokens behind it; its state sits in entry 3.
     other = PROMPT[20:30]
     table = jnp.stack([jnp.arange(1, 9), jnp.arange(9, 17)]).astype(jnp.int32)
-    _, k, v, st = llama.forward(
+    _, k, v, st = forward(
         params, CFG, jnp.asarray(other[:9])[None], jnp.arange(9, dtype=jnp.int32)[None],
         table[1:], k, v, seq_state=st, state_slots=jnp.asarray([3]),
     )
@@ -180,7 +185,7 @@ def test_mixed_stream_with_padding(params, stream):
         jnp.asarray([4, 8, 0, 3, 0, 0], jnp.int32),
         jnp.asarray([0, 3, 6, 4, 6, 6], jnp.int32),  # + a snapshot of A after token 3
     )
-    logits, k, v, st = llama.forward_ragged(
+    logits, k, v, st = forward_ragged(
         params, CFG, tokens, positions, row_of, full_table, k, v, out_idx,
         seq_state=st, state_rows=rows,
     )
@@ -189,7 +194,7 @@ def test_mixed_stream_with_padding(params, stream):
     assert np.abs(got[1] - reference.log_probs(params, HF, other, [9])[0]).max() < LOGP_TOL
     assert float(jnp.abs(st[:, 5] - 7.0).max()) == 0.0
     # The snapshot (entry 4) is A's state after 4 tokens: continue from it.
-    logits, *_ = llama.forward(
+    logits, *_ = forward(
         params, CFG, jnp.asarray(PROMPT[4:7])[None], jnp.arange(4, 7, dtype=jnp.int32)[None],
         table[:1], k, v, seq_state=st, state_slots=jnp.asarray([4]),
     )

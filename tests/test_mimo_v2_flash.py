@@ -25,6 +25,9 @@ from dynamo_exp_tpu.ops.ragged_attention import (
 )
 from dynamo_exp_tpu.protocols.common import BackendInput
 
+# Jitted: a shape and set of options compiles once a file (tests/programs.py).
+from .programs import forward, forward_ragged, init_params
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 from references import mimo_v2_flash as reference  # noqa: E402
 
@@ -59,7 +62,7 @@ PROMPT = [int(t) for t in np.random.default_rng(0).integers(2, 256, 96)]
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(SEED), CFG)
+    return init_params(jax.random.PRNGKey(SEED), CFG)
 
 
 def _logp(logits):
@@ -150,7 +153,7 @@ def test_parameters_and_pools_by_kind(params):
     assert [a.shape for a in k] == [(2, 32, PS, 24), (4, 12, PS, 48)]
     assert [a.shape for a in v] == [(2, 32, PS, 16), (4, 12, PS, 32)]
     # one KV-head count, one head size: the stacks and pools every other model has
-    other = llama.init_params(jax.random.PRNGKey(0), TINY_EXAONE_MOE)["layers"]
+    other = init_params(jax.random.PRNGKey(0), TINY_EXAONE_MOE)["layers"]
     assert "swa_wk" not in other and "sink" not in other
     assert not TINY_EXAONE_MOE.kv_heads_by_form
     assert TINY_EXAONE_MOE.kv_pool_widths_of("sliding") == TINY_EXAONE_MOE.kv_pool_widths
@@ -265,7 +268,7 @@ def test_the_pools_hold_what_the_text_says(params):
     n = 23
     full, sliding = _tables(1, 8, 8)
     k, v = llama.init_kv_cache(CFG, 8, PS, sliding_pages=8)
-    _, k, v = llama.forward(
+    _, k, v = forward(
         params, CFG, jnp.asarray([PROMPT[:n]], jnp.int32), jnp.arange(n)[None],
         (full, sliding), k, v,
     )
@@ -320,7 +323,7 @@ def test_a_fault_shows(params, fault):
     weights = {**params, "layers": layers}
     full, sliding = _tables(1, 10, 10)
     k, v = llama.init_kv_cache(cfg, 10, PS, sliding_pages=10)
-    logits, *_ = llama.forward(
+    logits, *_ = forward(
         weights, cfg, jnp.asarray([PROMPT[:n]], jnp.int32), jnp.arange(n)[None],
         (full, sliding), k, v,
     )
@@ -333,14 +336,14 @@ def test_a_sink_on_the_full_layers_too_is_served(params):
     hf = {**HF, "add_full_attention_sink_bias": True}
     cfg = ModelConfig.from_hf_config(hf)
     assert cfg.sink_forms == ("full", "sliding")
-    weights = llama.init_params(jax.random.PRNGKey(SEED), cfg)
+    weights = init_params(jax.random.PRNGKey(SEED), cfg)
     assert weights["layers"]["sink"].shape == (6, 4)
     n = 30
     want = reference.log_probs(weights, hf, PROMPT[:n], range(n))
     for impl in ("xla", "pallas"):
         full, sliding = _tables(1, 8, 8)
         k, v = llama.init_kv_cache(cfg, 8, PS, sliding_pages=8)
-        logits, *_ = llama.forward_ragged(
+        logits, *_ = forward_ragged(
             weights, cfg, jnp.asarray(PROMPT[:n] + [0, 0], jnp.int32),
             jnp.asarray([*range(n), -1, -1], jnp.int32), jnp.zeros((32,), jnp.int32),
             (full, sliding), k, v, jnp.arange(n, dtype=jnp.int32), q_tile=8, **_impl(impl),
@@ -470,7 +473,7 @@ def test_engine_through_the_kernel():
     }
     eng = _engine(
         ModelConfig.from_hf_config(hf), attention_impl="pallas", pallas_interpret=True,
-        max_decode_slots=2, max_model_len=64,
+        max_decode_slots=2, max_model_len=32,  # 8 pages a row: 8 page copies a chunk
     )
     eng.start()
     try:

@@ -31,6 +31,9 @@ from dynamo_exp_tpu.models import PRESETS, ModelConfig, llama
 from dynamo_exp_tpu.models.config import TINY_JAMBA, TINY_LFM2, TINY_OLMO_HYBRID
 from dynamo_exp_tpu.protocols.common import BackendInput
 
+# Jitted: a shape and set of options compiles once a file (tests/programs.py).
+from .programs import forward, forward_ragged, init_params
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 from references import olmo_hybrid as reference  # noqa: E402
 
@@ -59,20 +62,25 @@ CATALOG = {
     "linear_value_head_dim": 192, "linear_conv_kernel_dim": 4,
     "linear_allow_neg_eigval": True, "rope_parameters": {"rope_theta": None},
 }
-PS = 8
+# Pages of 16 on tables of 4 (64 tokens a row: every sequence of this file is
+# shorter): a chunk of an interpreted kernel is four page copies to lower, a
+# quarter of what pages of 8 on a table of 16 cost (tests/ragged_cases.py
+# "SHAPES"). No rule under test here reads the page size but the prefix hit's
+# count of pages, which is asked as tokens over PS.
+PS = 16
 LOGP_TOL = 1e-3
 SEED = 3
 PROMPT = [int(t) for t in np.random.default_rng(0).integers(2, 256, 80)]
-TABLE = jnp.arange(16, dtype=jnp.int32)[None] + 1
+TABLE = jnp.arange(4, dtype=jnp.int32)[None] + 1
 
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(SEED), CFG)
+    return init_params(jax.random.PRNGKey(SEED), CFG)
 
 
 def _pools(entries=4):
-    k, v = llama.init_kv_cache(CFG, 33, PS, dtype=jnp.float32)
+    k, v = llama.init_kv_cache(CFG, 17, PS, dtype=jnp.float32)
     return k, v, llama.init_seq_state(CFG, entries)
 
 
@@ -158,7 +166,7 @@ def test_random_linear_parameters_are_the_familys(params):
     assert step.min() >= lo * 0.999 and step.max() <= hi * 1.001
     assert "attn_norm" not in layers and "mlp_norm" not in layers
     assert layers["q_norm"].shape == (1, 64) and layers["post_attn_norm"].shape == (5, 64)
-    bf16 = llama.init_params(jax.random.PRNGKey(SEED), TINY_OLMO_HYBRID)["layers"]
+    bf16 = init_params(jax.random.PRNGKey(SEED), TINY_OLMO_HYBRID)["layers"]
     for name in ("gdn_A_log", "gdn_dt_b"):
         assert bf16[name].dtype == jnp.float32
     assert bf16["gdn_qkv"].dtype == jnp.bfloat16
@@ -173,7 +181,7 @@ def test_forward_matches_reference(params, phase):
     k, v, st = _pools()
     T = 21
     slot = jnp.asarray([2])
-    logits, k, v, st = llama.forward(
+    logits, k, v, st = forward(
         params, CFG, jnp.asarray(PROMPT[:T])[None], jnp.arange(T, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=st, state_slots=slot,
     )
@@ -182,7 +190,7 @@ def test_forward_matches_reference(params, phase):
         assert np.abs(_logp(logits[0]) - want).max() < LOGP_TOL
         return
     for t in range(T, T + 6):
-        logits, k, v, st = llama.forward(
+        logits, k, v, st = forward(
             params, CFG, jnp.asarray(PROMPT[t:t + 1])[None], jnp.asarray([[t]], jnp.int32),
             TABLE, k, v, seq_state=st, state_slots=slot,
         )
@@ -196,7 +204,7 @@ def test_the_pools_state_is_the_references(params):
     the embedding)."""
     k, v, st = _pools()
     T = 21
-    *_, st = llama.forward(
+    *_, st = forward(
         params, CFG, jnp.asarray(PROMPT[:T])[None], jnp.arange(T, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=st, state_slots=jnp.asarray([2]),
     )
@@ -224,7 +232,7 @@ def _ragged(params, impl, pools, stream, table, state_rows, out_idx, **kw):
     """One ``forward_ragged`` over ``_stream``'s arrays, the kernels in
     interpret mode."""
     k, v, st = pools
-    return llama.forward_ragged(
+    return forward_ragged(
         params, CFG, *stream, table, k, v, jnp.asarray(out_idx, jnp.int32),
         attn_impl=impl, q_tile=8, interpret=True, seq_state=st,
         state_rows=tuple(jnp.asarray(a, jnp.int32) for a in state_rows), **kw,
@@ -241,17 +249,20 @@ def test_chunks_of_unequal_size_then_decode(params, impl):
     table = jnp.concatenate([TABLE, TABLE * 0])
     got, at = [], 0
     for n in (16, 24, 5, 8):
+        # every chunk in the longest one's 24 slots (padding tiles of the
+        # empty row behind it), so that the four dispatches are one program
+        pad = [(0, -1, 1)] * (24 - -(-n // 8) * 8)
         logits, *pools = _ragged(
-            params, impl, pools, _stream([(PROMPT[at:at + n], at, 0)]), table,
-            ([1, 4], [n - 1, 0], [1, 4]), range(n),
+            params, impl, pools, _stream([(PROMPT[at:at + n], at, 0)], tail=pad), table,
+            ([1, 4], [n - 1, 0], [1, 4]), range(24),
         )
-        got.append(_logp(logits))
+        got.append(_logp(logits)[:n])
         at += n
     want = reference.log_probs(params, HF, PROMPT[:at], range(at))
     assert np.abs(np.concatenate(got) - want).max() < LOGP_TOL
     k, v, st = pools
     for t in range(at, at + 4):
-        logits, k, v, st = llama.forward(
+        logits, k, v, st = forward(
             params, CFG, jnp.asarray(PROMPT[t:t + 1])[None], jnp.asarray([[t]], jnp.int32),
             TABLE, k, v, seq_state=st, state_slots=jnp.asarray([1]),
             attn_impl=impl, interpret=True,
@@ -268,12 +279,13 @@ def test_mixed_dispatch_of_spans_and_packed_decode_rows(params, impl):
     two more sequences and padding: each row's logits are its own uncut
     forward's, the snapshot continues, an entry no row owns is untouched."""
     seqs = [PROMPT[:13], PROMPT[20:48], PROMPT[50:60], PROMPT[60:67]]
-    tables = jnp.stack([jnp.arange(1 + 4 * i, 5 + 4 * i) for i in range(5)]).astype(jnp.int32)
+    tables = jnp.stack([jnp.arange(1 + 2 * i, 3 + 2 * i) for i in range(5)]).astype(jnp.int32)
     k, v, st = _pools(entries=8)
     # Rows 1, 2, 3 have 16, 9 and 6 tokens behind them, states in entries 1, 2, 3.
-    for r, n in ((1, 16), (2, 9), (3, 6)):
-        _, k, v, st = llama.forward(
-            params, CFG, jnp.asarray(seqs[r][:n])[None], jnp.arange(n, dtype=jnp.int32)[None],
+    for r, n in ((1, 16), (2, 9), (3, 6)):  # each in 16 slots: one compiled program
+        _, k, v, st = forward(
+            params, CFG, jnp.asarray(seqs[r][:n] + [0] * (16 - n))[None],
+            jnp.asarray(list(range(n)) + [-1] * (16 - n), jnp.int32)[None],
             tables[r:r + 1], k, v, seq_state=st, state_slots=jnp.asarray([r]),
         )
     st = jax.tree.map(lambda a: a.at[:, 7].set(7.0), st)
@@ -298,14 +310,14 @@ def test_mixed_dispatch_of_spans_and_packed_decode_rows(params, impl):
     for part in jax.tree.leaves(st2):
         assert float(jnp.abs(part[:, 7] - 7.0).max()) == 0.0
     # The snapshot (entry 5) is row 0's state after 8 tokens: continue from it.
-    logits, *_ = llama.forward(
+    logits, *_ = forward(
         params, CFG, jnp.asarray(seqs[0][8:13])[None], jnp.arange(8, 13, dtype=jnp.int32)[None],
         tables[:1], k2, v2, seq_state=st2, state_slots=jnp.asarray([5]),
     )
     want = reference.log_probs(params, HF, seqs[0], range(8, 13))
     assert np.abs(_logp(logits[0]) - want).max() < LOGP_TOL
     # A decode row goes on from the state the mixed dispatch left.
-    logits, *_ = llama.forward(
+    logits, *_ = forward(
         params, CFG, jnp.asarray([[PROMPT[0]]]), jnp.asarray([[10]], jnp.int32),
         tables[2:3], k2, v2, seq_state=st2, state_slots=jnp.asarray([2]),
     )
@@ -319,11 +331,11 @@ def test_a_matrix_state_left_at_zero_shows(params):
     T, cut = 24, 8
     want = reference.log_probs(params, HF, PROMPT[:T], range(cut, T))
     k, v, st = _pools()
-    _, k, v, st = llama.forward(
+    _, k, v, st = forward(
         params, CFG, jnp.asarray(PROMPT[:cut])[None], jnp.arange(cut, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=st, state_slots=jnp.asarray([1]),
     )
-    logits, *_ = llama.forward(
+    logits, *_ = forward(
         params, CFG, jnp.asarray(PROMPT[cut:T])[None], jnp.arange(cut, T, dtype=jnp.int32)[None],
         TABLE, k, v, seq_state=st._replace(state=jnp.zeros_like(st.state)),
         state_slots=jnp.asarray([1]),
@@ -351,7 +363,8 @@ def _engine(**kw) -> TPUEngine:
 
     cfg = EngineConfig(
         model=CFG, max_decode_slots=4, page_size=PS, num_pages=kw.pop("num_pages", 64),
-        max_model_len=256, prefill_chunk=16, decode_window=8, kv_dtype="float32",
+        max_model_len=kw.pop("max_model_len", 256), prefill_chunk=16, decode_window=8,
+        kv_dtype="float32",
         eos_token_ids=[], preempt_stall_grace_s=0.05, **kw,
     )
     return TPUEngine(cfg, mesh=single_device_mesh(), seed=SEED)
@@ -359,7 +372,8 @@ def _engine(**kw) -> TPUEngine:
 
 @pytest.fixture(scope="module", params=["xla", "pallas"])
 def engine(request):
-    eng = _engine(pallas_interpret=request.param == "pallas")
+    # a table of 4 pages: no sequence of these tests passes 60 tokens
+    eng = _engine(max_model_len=64, pallas_interpret=request.param == "pallas")
     assert eng._attn_impl == request.param
     eng.start()
     yield eng
@@ -460,7 +474,7 @@ async def test_second_asking_restores_both_parts(engine, asking):
     got = await _run(engine, prompt, 12)
     assert got == _greedy_reference(engine.params, prompt, 12)
     assert engine.kv.state_hits["restored"] == before[0]["restored"] + 1
-    assert engine.kv.prefix_hits["shared"] - before[1] == 4  # 32 of its tokens
+    assert engine.kv.prefix_hits["shared"] - before[1] == 32 // PS  # 32 of its tokens
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -468,7 +482,8 @@ async def test_preempt_and_resume(impl):
     """Two requests on a pool that holds one and a half: one is preempted
     and re-admitted with its state restored or recomputed; both streams
     equal the reference's and every entry is handed back."""
-    eng = _engine(num_pages=12, pallas_interpret=impl == "pallas")
+    # 96 tokens of pool; a table of 4 pages (no sequence here passes 53 tokens)
+    eng = _engine(num_pages=96 // PS, max_model_len=64, pallas_interpret=impl == "pallas")
     eng.start()
     try:
         p1, p2, n = PROMPT[:9], PROMPT[10:19], 44
@@ -611,7 +626,7 @@ def test_checkpoint_names_round_trip(tmp_path):
     from dynamo_exp_tpu.models.synth import write_config
 
     cfg = TINY_OLMO_HYBRID  # bfloat16: the float32 parameters must stay so
-    params = llama.init_params(jax.random.PRNGKey(SEED), cfg)
+    params = init_params(jax.random.PRNGKey(SEED), cfg)
     as_np = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
     layers = {k: as_np(v) for k, v in params["layers"].items()}
     out = {

@@ -15,7 +15,7 @@ tiled one: the kernel computes a query's scores in float32 from the same
 pages either way, so the two agree to the rounding of one bfloat16 value
 on the way out (2**-8 of the logits' largest magnitude; on the CPU they
 are in fact bit-equal). A float32 model against the reference gather:
-the kernel tests' 2e-5 (``tests/test_ragged_attention.py``) holds for the
+the kernel tests' 2e-5 (``tests/test_ragged_kernels.py``) holds for the
 attention output; through two layers and the head the logits agree to
 1e-4 of their spread.
 """
@@ -30,10 +30,12 @@ import numpy as np
 import pytest
 
 from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
-from dynamo_exp_tpu.models import TINY, TINY_MOE, TINY_QWEN3, init_kv_cache, init_params
-from dynamo_exp_tpu.models.llama import forward_ragged
+from dynamo_exp_tpu.models import TINY, TINY_MOE, TINY_QWEN3, init_kv_cache
 from dynamo_exp_tpu.parallel import single_device_mesh
 from dynamo_exp_tpu.protocols.common import BackendInput
+
+# Jitted: a shape and set of options compiles once a file (tests/programs.py).
+from .programs import forward, forward_ragged, init_params
 
 PS = 8
 
@@ -41,8 +43,9 @@ PS = 8
 # ------------------------------------------------------------- the layout
 def _engine(model=TINY, slots=64, **kw) -> TPUEngine:
     cfg = EngineConfig(
-        model=model, max_decode_slots=slots, page_size=PS,
-        num_pages=kw.pop("num_pages", 4 * slots + 32), max_model_len=256,
+        model=model, max_decode_slots=slots, page_size=kw.pop("page_size", PS),
+        num_pages=kw.pop("num_pages", 4 * slots + 32),
+        max_model_len=kw.pop("max_model_len", 256),
         eos_token_ids=[], **kw,
     )
     return TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
@@ -262,7 +265,7 @@ def test_packed_rows_of_a_model_with_state_read_their_own_entry_only():
     from .test_lfm2 import HF, LOGP_TOL
 
     cfg = dataclasses.replace(TINY_LFM2, dtype="float32")
-    params = llama.init_params(jax.random.PRNGKey(3), cfg)
+    params = init_params(jax.random.PRNGKey(3), cfg)
     k, v = llama.init_kv_cache(cfg, 24, PS, dtype=jnp.float32)
     st = llama.init_seq_state(cfg, 8)
     rng = np.random.default_rng(1)
@@ -270,8 +273,10 @@ def test_packed_rows_of_a_model_with_state_read_their_own_entry_only():
     fresh = [int(t) for t in rng.integers(2, 256, 5)]
     table = jnp.stack([jnp.arange(4 * r + 1, 4 * r + 5) for r in range(5)]).astype(jnp.int32)
     for r, ids in enumerate(seqs):  # each history but its last token, entry r + 1
-        _, k, v, st = llama.forward(
-            params, cfg, jnp.asarray(ids[:-1])[None], jnp.arange(len(ids) - 1, dtype=jnp.int32)[None],
+        n = len(ids) - 1  # in 16 slots, padding behind it: the three are one program
+        _, k, v, st = forward(
+            params, cfg, jnp.asarray(ids[:-1] + [0] * (16 - n))[None],
+            jnp.asarray(list(range(n)) + [-1] * (16 - n), jnp.int32)[None],
             table[r + 1 : r + 2], k, v, seq_state=st, state_slots=jnp.asarray([r + 1]),
         )
     nb, tail = 32, 24
@@ -288,7 +293,7 @@ def test_packed_rows_of_a_model_with_state_read_their_own_entry_only():
         jnp.asarray([4, tail, tail + 1, tail + 2], jnp.int32),
         jnp.asarray([0, 1, 2, 3], jnp.int32),
     )
-    logits, k, v, st = llama.forward_ragged(
+    logits, k, v, st = forward_ragged(
         params, cfg, jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(row_of),
         table, k, v, out_idx, attn_impl="pallas", q_tile=8, packed_from=tail,
         interpret=True, seq_state=st, state_rows=rows,
@@ -300,7 +305,7 @@ def test_packed_rows_of_a_model_with_state_read_their_own_entry_only():
     # One more token a sequence from the entries the dispatch left.
     for r, ids in enumerate(seqs):
         nxt = ids + [17 + r]
-        logits, *_ = llama.forward(
+        logits, *_ = forward(
             params, cfg, jnp.asarray(nxt[-1:])[None], jnp.asarray([[len(ids)]], jnp.int32),
             table[r + 1 : r + 2], k, v, seq_state=st, state_slots=jnp.asarray([r + 1]),
         )
@@ -372,8 +377,11 @@ async def _serve(eng, sampling):
 def test_a_kernel_engine_serving_a_late_arrival_emits_the_reference_engines_tokens(sampling):
     streams, mixed = {}, {}
     for form in ("kernel", "xla"):
+        # pages of 32 on a table of 4: four page copies a chunk of the
+        # interpreted kernel to lower, not sixteen (tests/ragged_cases.py "SHAPES")
         eng = _engine(
-            F32, slots=4, kv_dtype="float32", prefill_chunk=16, num_pages=64,
+            F32, slots=4, kv_dtype="float32", prefill_chunk=16, num_pages=32,
+            page_size=32, max_model_len=128,
             pallas_interpret=form == "kernel", chained_decode=False,
         )
         assert eng._attn_impl == ("pallas" if form == "kernel" else "xla")

@@ -35,6 +35,9 @@ from dynamo_exp_tpu.models.config import TINY_PANGU
 from dynamo_exp_tpu.ops import moe
 from dynamo_exp_tpu.protocols.common import BackendInput
 
+# Jitted: a shape and set of options compiles once a file (tests/programs.py).
+from .programs import forward, forward_ragged, init_params
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 from references import openpangu_moe as reference  # noqa: E402
 
@@ -60,16 +63,21 @@ PS = 8
 LOGP_TOL = 1e-3
 SEED = 3
 PROMPT = [int(t) for t in np.random.default_rng(0).integers(2, 256, 48)]
-TABLE = jnp.arange(16, dtype=jnp.int32)[None] + 1
+# The forwards called outright read pools of LARGE pages on narrow tables (two
+# pages of 32 a row: every context here is under 64 tokens): a chunk of the
+# interpreted kernel is then 2 page copies to lower, not 16
+# (tests/ragged_cases.py "SHAPES"). The engines keep pages of ``PS``.
+FPS = 32
+TABLE = jnp.arange(2, dtype=jnp.int32)[None] + 1
 
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(jax.random.PRNGKey(SEED), CFG)
+    return init_params(jax.random.PRNGKey(SEED), CFG)
 
 
 def _pools():
-    return llama.init_kv_cache(CFG, 17, PS, dtype=jnp.float32)
+    return llama.init_kv_cache(CFG, 9, FPS, dtype=jnp.float32)
 
 
 def _logp(logits):
@@ -125,7 +133,7 @@ def test_parameters_by_kind(params):
 
 def test_one_pool_and_no_value_pool():
     pool, values = _pools()
-    assert pool.shape == (3, 17, PS, 128) and values.shape == (3, 17, PS, 0)
+    assert pool.shape == (3, 9, FPS, 128) and values.shape == (3, 9, FPS, 0)
     assert values.nbytes == 0
 
 
@@ -136,7 +144,7 @@ def test_forward_matches_reference(params, phase):
     prompt in one piece, then six steps through the latent cache."""
     k, v = _pools()
     T = 21
-    logits, k, v = llama.forward(
+    logits, k, v = forward(
         params, CFG, jnp.asarray(PROMPT[:T])[None], jnp.arange(T, dtype=jnp.int32)[None],
         TABLE, k, v,
     )
@@ -145,7 +153,7 @@ def test_forward_matches_reference(params, phase):
         assert np.abs(_logp(logits[0]) - want).max() < LOGP_TOL
         return
     for t in range(T, T + 6):
-        logits, k, v = llama.forward(
+        logits, k, v = forward(
             params, CFG, jnp.asarray(PROMPT[t:t + 1])[None], jnp.asarray([[t]], jnp.int32),
             TABLE, k, v,
         )
@@ -155,19 +163,20 @@ def test_forward_matches_reference(params, phase):
 
 def _ragged(params, impl, chunks, pools=None, cfg=CFG):
     """``PROMPT`` through ``forward_ragged`` in ``chunks`` ((a, b) spans), a
-    row's span padded to whole tiles for the kernel. Log-probabilities of
-    every real token, and the pools."""
+    row's span padded to whole tiles for the kernel, and every chunk to the
+    longest one's slots (the dispatches are then one compiled program).
+    Log-probabilities of every real token, and the pools."""
     k, v = pools or _pools()
     got = []
+    n = max(-(-(b - a) // 8) * 8 for a, b in chunks)
     for a, b in chunks:
-        n = -(-(b - a) // 8) * 8
         tokens = jnp.asarray(PROMPT[a:b] + [0] * (n - (b - a)), jnp.int32)
         pos = jnp.asarray(list(range(a, b)) + [-1] * (n - (b - a)), jnp.int32)
-        logits, k, v = llama.forward_ragged(
+        logits, k, v = forward_ragged(
             params, cfg, tokens, pos, jnp.zeros(n, jnp.int32), TABLE, k, v,
-            jnp.arange(b - a), attn_impl=impl, q_tile=8, interpret=impl == "pallas",
+            jnp.arange(n), attn_impl=impl, q_tile=8, interpret=impl == "pallas",
         )
-        got.append(_logp(logits))
+        got.append(_logp(logits)[: b - a])
     return np.concatenate(got), (k, v)
 
 
@@ -181,7 +190,7 @@ def test_chunked_prefill_through_the_latent_pages(params, impl):
     got, (k, v) = _ragged(params, impl, ((0, 16), (16, 29), (29, T)))
     assert np.abs(got - want[:T]).max() < LOGP_TOL
     for t in range(T, T + 3):
-        logits, k, v = llama.forward(
+        logits, k, v = forward(
             params, CFG, jnp.asarray(PROMPT[t:t + 1])[None], jnp.asarray([[t]], jnp.int32),
             TABLE, k, v, attn_impl=impl, interpret=impl == "pallas",
         )
@@ -194,71 +203,10 @@ def test_the_pool_holds_the_normed_latent_and_the_rotated_key(params):
     T = 24
     _, (pool, _) = _ragged(params, "xla", ((0, T),))
     x = np.asarray(params["embed"])[PROMPT[:T]]
-    rows = np.asarray(pool[0, 1:4]).reshape(T, -1)  # pages 1..3 of layer 0
+    rows = np.asarray(pool[0, 1]).reshape(FPS, -1)[:T]  # page 1 of layer 0
     want = reference.latent_rows(params, HF, 0, x)
     assert np.abs(rows[:, :CFG.latent_width] - want).max() < 1e-4
     assert np.abs(rows[:, CFG.latent_width:]).max() == 0.0
-
-
-def _faulty(monkeypatch, fault):
-    """The engine with one of the faults the comparison's controls make."""
-    if fault == "scale_without_rope":
-        monkeypatch.setattr(
-            llama, "_latent_read_args",
-            lambda cfg: {"sm_scale": cfg.qk_nope_head_dim ** -0.5,
-                         "value_dim": llama.latent_value_lanes(cfg)},
-        )
-    elif fault in ("latent_before_norm", "key_before_rope"):
-        norm, rope = llama.rms_norm, llama.apply_rope
-
-        def rms_norm(x, w, eps, offset=False):  # kv_a_norm alone has this width
-            return x if x.shape[-1] == CFG.kv_lora_rank else norm(x, w, eps, offset)
-
-        def apply_rope(x, pos, inv_freq):  # the shared key alone has one head
-            return x if x.shape[-2] == 1 else rope(x, pos, inv_freq)
-
-        if fault == "latent_before_norm":
-            monkeypatch.setattr(llama, "rms_norm", rms_norm)
-        else:
-            monkeypatch.setattr(llama, "apply_rope", apply_rope)
-    elif fault in ("uk_and_uv_swapped", "rotary_key_a_head_apart"):
-        # faults of the expanded form alone: what its call is handed
-        from dynamo_exp_tpu.ops import ragged_attention
-
-        call = ragged_attention.ragged_paged_attention_expanded
-        r, dn = CFG.kv_lora_rank, CFG.qk_nope_head_dim
-
-        def faulty(q, pool, w_ukv, *args, **kw):
-            if fault == "uk_and_uv_swapped":
-                w = w_ukv.reshape(r, CFG.num_heads, -1)
-                w_ukv = jnp.concatenate([w[..., dn:], w[..., :dn]], axis=-1).reshape(r, -1)
-            else:  # the shared key taken as if each head had a slice of it
-                k_r = jnp.roll(pool[..., r:CFG.latent_width], 1, axis=-1)
-                pool = pool.at[..., r:CFG.latent_width].set(k_r)
-            return call(q, pool, w_ukv, *args, **kw)
-
-        monkeypatch.setattr(ragged_attention, "ragged_paged_attention_expanded", faulty)
-
-
-@pytest.mark.parametrize("impl,fault", [
-    ("xla", "scale_without_rope"), ("xla", "latent_before_norm"), ("xla", "key_before_rope"),
-    # the expanded form (rows of 16 queries through the kernel): its scale,
-    # its one rotated key, and which half of W_ukv makes keys
-    ("pallas", "scale_without_rope"), ("pallas", "key_before_rope"),
-    ("pallas", "rotary_key_a_head_apart"), ("pallas", "uk_and_uv_swapped"),
-])
-def test_a_fault_in_the_latent_half_shows(params, monkeypatch, impl, fault):
-    """What the tolerance is far below: scores scaled by 1/sqrt(nope), the
-    latent cached before its norm, the rotary key cached before RoPE, in
-    either form; in the expanded one also ``W_uk`` and ``W_uv`` exchanged
-    and a rotary key that is not the one all heads share."""
-    T = 32
-    want = reference.log_probs(params, HF, PROMPT[:T], range(T))
-    _faulty(monkeypatch, fault)
-    jax.clear_caches()  # a jitted call of the sound form must not answer
-    got, _ = _ragged(params, impl, ((0, 16), (16, T)))
-    jax.clear_caches()
-    assert np.abs(got - want).max() > 30 * LOGP_TOL
 
 
 def test_two_rows_of_one_dispatch_take_the_two_forms(params):
@@ -269,10 +217,10 @@ def test_two_rows_of_one_dispatch_take_the_two_forms(params):
     and they are what the absorbed form alone gives (the reference path)."""
     k, v = _pools()
     a, b, c = PROMPT[:30], PROMPT[30:35], PROMPT[35:45]
-    table = jnp.stack([jnp.arange(1, 6), jnp.arange(6, 11), jnp.arange(11, 16)]).astype(jnp.int32)
-    table = jnp.concatenate([table, jnp.zeros((1, 5), jnp.int32)])
+    table = jnp.stack([jnp.arange(1, 3), jnp.arange(3, 5), jnp.arange(5, 7)]).astype(jnp.int32)
+    table = jnp.concatenate([table, jnp.zeros((1, 2), jnp.int32)])
     for row, ids in ((0, a[:11]), (2, c[:9])):  # what earlier dispatches wrote
-        _, k, v = llama.forward(
+        _, k, v = forward(
             params, CFG, jnp.asarray(ids)[None], jnp.arange(len(ids), dtype=jnp.int32)[None],
             table[row:row + 1], k, v,
         )
@@ -284,16 +232,16 @@ def test_two_rows_of_one_dispatch_take_the_two_forms(params):
         jnp.asarray(row_of, jnp.int32), table,
     )
     out_idx = jnp.asarray([18, 28, 40])
-    logits, *_ = llama.forward_ragged(
+    logits, *_ = forward_ragged(
         params, CFG, *args, k, v, out_idx, attn_impl="pallas", q_tile=8, packed_from=40,
         interpret=True,
     )
-    absorbed, *_ = llama.forward_ragged(params, CFG, *args, k, v, out_idx, attn_impl="xla")
+    absorbed, *_ = forward_ragged(params, CFG, *args, k, v, out_idx, attn_impl="xla")
     got = _logp(logits)
     for i, (ids, at) in enumerate(((a, 29), (b, 4), (c, 9))):
         assert np.abs(got[i] - reference.log_probs(params, HF, ids, [at])[0]).max() < LOGP_TOL
     assert np.abs(got - _logp(absorbed)).max() < LOGP_TOL
-    assert llama.latent_form_queries(CFG, 8, PS, jnp.float32, interpret=True) == 8
+    assert llama.latent_form_queries(CFG, 8, FPS, jnp.float32, interpret=True) == 8
 
 
 def test_the_rule_is_the_models_widths_and_none_off_the_kernels_tiling():
@@ -317,16 +265,16 @@ def test_mixed_stream_with_padding(params):
     kernel in both forms: each row's logits are its own uncut forward's."""
     k, v = _pools()
     other = PROMPT[20:30]
-    table = jnp.stack([jnp.arange(1, 9), jnp.arange(9, 17)]).astype(jnp.int32)
-    _, k, v = llama.forward(
+    table = jnp.stack([jnp.arange(1, 3), jnp.arange(3, 5)]).astype(jnp.int32)
+    _, k, v = forward(
         params, CFG, jnp.asarray(other[:9])[None], jnp.arange(9, dtype=jnp.int32)[None],
         table[1:], k, v,
     )
     tokens = jnp.asarray(PROMPT[:5] + [0] * 3 + [other[9]] + [0] * 7, jnp.int32)
     positions = jnp.asarray([0, 1, 2, 3, 4, -1, -1, -1, 9] + [-1] * 7, jnp.int32)
     row_of = jnp.asarray([0] * 8 + [1] + [2] * 7, jnp.int32)
-    full_table = jnp.concatenate([table, jnp.zeros((1, 8), jnp.int32)])
-    logits, k, v, held = llama.forward_ragged(
+    full_table = jnp.concatenate([table, jnp.zeros((1, 2), jnp.int32)])
+    logits, k, v, held = forward_ragged(
         params, CFG, tokens, positions, row_of, full_table, k, v, jnp.asarray([4, 8]),
         attn_impl="pallas", q_tile=8, packed_from=8, interpret=True, count_picks=True,
     )
@@ -350,7 +298,7 @@ def _whole(params):
     """The uncut layer's parameters: 32 experts, of which ``params`` holds
     ids 8..15; the others seeded here."""
     cfg = dataclasses.replace(CFG, num_experts_held=0, first_expert_held=0)
-    full = llama.init_params(jax.random.PRNGKey(11), cfg)
+    full = init_params(jax.random.PRNGKey(11), cfg)
     layers = dict(full["layers"])
     for name in ("router", "shared_gate", "shared_up", "shared_down", "mlp_norm", "post_ffn_norm"):
         layers[name] = params["layers"][name]
@@ -453,8 +401,9 @@ def _engine(**kw) -> TPUEngine:
     from dynamo_exp_tpu.parallel import single_device_mesh
 
     cfg = EngineConfig(
-        model=CFG, max_decode_slots=4, page_size=PS, num_pages=kw.pop("num_pages", 64),
-        max_model_len=256, prefill_chunk=16, decode_window=8, kv_dtype="float32",
+        model=CFG, max_decode_slots=4, page_size=kw.pop("page_size", PS),
+        num_pages=kw.pop("num_pages", 64), max_model_len=kw.pop("max_model_len", 256),
+        prefill_chunk=16, decode_window=8, kv_dtype="float32",
         eos_token_ids=[], preempt_stall_grace_s=0.05, **kw,
     )
     return TPUEngine(cfg, mesh=single_device_mesh(), seed=SEED)
@@ -558,7 +507,12 @@ async def test_second_asking_from_cached_latent_pages(engine, asking):
 async def test_engine_through_the_kernel():
     """The same path with the attention kernel (interpreted) in both
     programs and the experts' kernel in place."""
-    eng = _engine(attention_impl="pallas", pallas_interpret=True)
+    # pages of 32 on a table of 4: four page copies a chunk of the interpreted
+    # kernel to lower, not sixteen (tests/ragged_cases.py "SHAPES")
+    eng = _engine(
+        attention_impl="pallas", pallas_interpret=True, page_size=32, max_model_len=128,
+        num_pages=16,
+    )
     eng.start()
     name = "dynamo_mla_query_tokens_total"
     before = {form: _counter(name, form=form) for form in ("expanded", "absorbed")}
@@ -825,3 +779,68 @@ def test_checkpoint_names_round_trip(tmp_path, params):
     for k, v in params["layers"].items():
         np.testing.assert_array_equal(loaded["layers"][k], np.asarray(v), err_msg=k)
     np.testing.assert_array_equal(loaded["lm_head"], np.asarray(params["lm_head"]))
+
+
+# ------------------------------------------------- what the tolerance is far below
+# Last in the file: each case clears JAX's caches (the engine's forward must be
+# traced anew with its fault), and with them the compiled programs the tests
+# above share.
+def _faulty(monkeypatch, fault):
+    """The engine with one of the faults the comparison's controls make."""
+    if fault == "scale_without_rope":
+        monkeypatch.setattr(
+            llama, "_latent_read_args",
+            lambda cfg: {"sm_scale": cfg.qk_nope_head_dim ** -0.5,
+                         "value_dim": llama.latent_value_lanes(cfg)},
+        )
+    elif fault in ("latent_before_norm", "key_before_rope"):
+        norm, rope = llama.rms_norm, llama.apply_rope
+
+        def rms_norm(x, w, eps, offset=False):  # kv_a_norm alone has this width
+            return x if x.shape[-1] == CFG.kv_lora_rank else norm(x, w, eps, offset)
+
+        def apply_rope(x, pos, inv_freq):  # the shared key alone has one head
+            return x if x.shape[-2] == 1 else rope(x, pos, inv_freq)
+
+        if fault == "latent_before_norm":
+            monkeypatch.setattr(llama, "rms_norm", rms_norm)
+        else:
+            monkeypatch.setattr(llama, "apply_rope", apply_rope)
+    elif fault in ("uk_and_uv_swapped", "rotary_key_a_head_apart"):
+        # faults of the expanded form alone: what its call is handed
+        from dynamo_exp_tpu.ops import ragged_attention
+
+        call = ragged_attention.ragged_paged_attention_expanded
+        r, dn = CFG.kv_lora_rank, CFG.qk_nope_head_dim
+
+        def faulty(q, pool, w_ukv, *args, **kw):
+            if fault == "uk_and_uv_swapped":
+                w = w_ukv.reshape(r, CFG.num_heads, -1)
+                w_ukv = jnp.concatenate([w[..., dn:], w[..., :dn]], axis=-1).reshape(r, -1)
+            else:  # the shared key taken as if each head had a slice of it
+                k_r = jnp.roll(pool[..., r:CFG.latent_width], 1, axis=-1)
+                pool = pool.at[..., r:CFG.latent_width].set(k_r)
+            return call(q, pool, w_ukv, *args, **kw)
+
+        monkeypatch.setattr(ragged_attention, "ragged_paged_attention_expanded", faulty)
+
+
+@pytest.mark.parametrize("impl,fault", [
+    ("xla", "scale_without_rope"), ("xla", "latent_before_norm"), ("xla", "key_before_rope"),
+    # the expanded form (rows of 16 queries through the kernel): its scale,
+    # its one rotated key, and which half of W_ukv makes keys
+    ("pallas", "scale_without_rope"), ("pallas", "key_before_rope"),
+    ("pallas", "rotary_key_a_head_apart"), ("pallas", "uk_and_uv_swapped"),
+])
+def test_a_fault_in_the_latent_half_shows(params, monkeypatch, impl, fault):
+    """What the tolerance is far below: scores scaled by 1/sqrt(nope), the
+    latent cached before its norm, the rotary key cached before RoPE, in
+    either form; in the expanded one also ``W_uk`` and ``W_uv`` exchanged
+    and a rotary key that is not the one all heads share."""
+    T = 32
+    want = reference.log_probs(params, HF, PROMPT[:T], range(T))
+    _faulty(monkeypatch, fault)
+    jax.clear_caches()  # a jitted call of the sound form must not answer
+    got, _ = _ragged(params, impl, ((0, 16), (16, T)))
+    jax.clear_caches()
+    assert np.abs(got - want).max() > 30 * LOGP_TOL

@@ -43,7 +43,7 @@ from dynamo_exp_tpu.telemetry import get_telemetry
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 from references import sdar_moe as reference  # noqa: E402
 
-from .test_ragged_attention import _flat_batch  # noqa: E402
+from .ragged_cases import flat_batch  # noqa: E402
 
 B = 4
 THRESHOLD = 0.012
@@ -69,8 +69,9 @@ def _hf(**kw) -> dict:
 
 def _engine(hf=HF, **kw) -> TPUEngine:
     cfg = EngineConfig(
-        model=ModelConfig.from_hf_config(hf), max_decode_slots=4, page_size=PS,
-        num_pages=kw.pop("num_pages", 64), max_model_len=256,
+        model=ModelConfig.from_hf_config(hf), max_decode_slots=4,
+        page_size=kw.pop("page_size", PS), num_pages=kw.pop("num_pages", 64),
+        max_model_len=kw.pop("max_model_len", 256),
         prefill_chunk=kw.pop("prefill_chunk", 16), decode_window=8, kv_dtype="float32",
         eos_token_ids=kw.pop("eos_token_ids", []), preempt_stall_grace_s=0.05, **kw,
     )
@@ -191,7 +192,7 @@ def test_block_bound_against_a_dense_mask(spans, impl):
     spans that start off a tile and off a block; without the argument
     both stay causal by position."""
     H, Hkv, D, ps, pmax = 8, 4, 64, 16, 8
-    q, k, v, table, row_of, positions = _flat_batch(
+    q, k, v, table, row_of, positions = flat_batch(
         1, spans, H, Hkv, D, 64, ps, pmax, q_tile=4)
     bounds = jnp.where(positions >= 0, positions | (B - 1), -1)
     if impl == "kernel":
@@ -607,8 +608,10 @@ def test_manifest_lists_the_block_variants_and_traffic_compiles_nothing():
     # what the runner's limit on an awaiting test is for. The traffic is.
     from dynamo_exp_tpu.aot.compile import manifest_for_engine
 
+    # pages of 32 on a table of 4: four page copies a chunk of each interpreted
+    # program to lower, not sixteen (tests/ragged_cases.py "SHAPES")
     eng = _engine(_hf(hidden_size=128, head_dim=32), pallas_interpret=True,
-                  attention_impl="pallas")
+                  attention_impl="pallas", page_size=32, max_model_len=128, num_pages=16)
     manifest = manifest_for_engine(eng, include_lp=False)
     windowed = [v for v in manifest.ragged if v.windowed]
     assert {v.nb for v in windowed} == {1, 2, 4} and all(v.pages is None for v in windowed)

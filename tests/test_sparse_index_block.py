@@ -18,15 +18,17 @@ from dynamo_exp_tpu.models.config import TINY_GLM_DSA
 from dynamo_exp_tpu.ops import sparse_index as si
 from dynamo_exp_tpu.ops.ragged_attention import block_plan, merged_slot_counts
 
-PS, HI, DI, LANES = 8, 4, 32, 128
+# Pages of 32 tokens: a chunk of the interpreted kernel is four page copies to
+# lower, not sixteen (tests/ragged_cases.py "SHAPES").
+PS, HI, DI, LANES = 32, 4, 32, 128
 
 
-def _case(spans, pages, q_tile=8, seed=0, slots=None):
+def _case(spans, pages, q_tile=8, seed=0, slots=None, rows=None):
     """A stream of ``spans`` (queries, first position[, bounds]) a row, each
     aligned to ``q_tile``, over tables of ``pages`` pages; ``slots`` pads the
-    stream with slots of no query."""
+    stream with slots of no query, ``rows`` the table with rows of none."""
     rng = np.random.default_rng(seed)
-    n_rows = len(spans)
+    n_rows = rows or len(spans)
     pool = np.zeros((n_rows * pages + 1, PS, LANES), np.float32)
     pool[..., :DI] = rng.standard_normal((*pool.shape[:2], DI))
     table = (rng.permutation(n_rows * pages).reshape(n_rows, pages) + 1).astype(np.int32)
@@ -46,27 +48,35 @@ def _case(spans, pages, q_tile=8, seed=0, slots=None):
     ))
 
 
-# name: (spans, pages a row, q_tile, block, whether block_plan gives each block one pass[, slots])
+# name: (spans, q_tile, block, whether block_plan gives each block one pass).
+# Every stream at tiles of 8 is laid in 64 slots (two blocks of 32) over a
+# table of 2 rows of 8 pages: one shape, so the three kernels (a block a cell,
+# a tile a cell, the rule's own block) compile once for the five; what a
+# stream crosses is in its data.
 STREAMS = {
     # every block is one row's: 64 queries deep in a context of 200
-    "one_row": ([(64, 136)], 25, 8, 32, [True, True]),
+    "one_row": ([(64, 136)], 8, 32, [True, True]),
     # the second block holds the first row's end and the second's start
-    "two_rows_a_block": ([(40, 60), (24, 0)], 13, 8, 32, [True, False]),
-    # a block whose last tiles hold no query
-    "trailing_padding_tiles": ([(13, 30)], 7, 8, 32, [True], 32),
+    "two_rows_a_block": ([(40, 60), (24, 0)], 8, 32, [True, False]),
+    # a block whose last tiles hold no query, and a block of none (one pass
+    # of nothing: no tile of it names a second row)
+    "trailing_padding_tiles": ([(13, 30)], 8, 32, [True, True]),
     # the context ends inside a chunk, and the stream inside a block
-    "ragged_context_and_stream": ([(56, 131)], 24, 8, 32, [True, True]),
+    "ragged_context_and_stream": ([(56, 131)], 8, 32, [True, True]),
     # queries of one block bounded apart (a block model's), one at -1
-    "bounds_apart": ([(32, 0, [90, 3, -1, 40] * 8)], 12, 8, 32, [True]),
+    "bounds_apart": ([(32, 0, [90, 3, -1, 40] * 8)], 8, 32, [True, True]),
     # a stream of one slot a row: the block is the tile
-    "q_tile_1": ([(1, 77), (1, 0), (1, 30)], 10, 1, 1, [True] * 3),
+    "q_tile_1": ([(1, 77), (1, 0), (1, 30)], 1, 1, [True] * 3),
 }
+PAGES = 8
 
 
 @pytest.mark.parametrize("stream", list(STREAMS))
 def test_block_pass_equals_the_gather_and_the_tile_pass_bit_for_bit(stream):
-    spans, pages, qt, block, merged, *slots = STREAMS[stream]
-    q, w, pool, table, row_of, bounds = _case(spans, pages, qt, slots=slots[0] if slots else None)
+    spans, qt, block, merged = STREAMS[stream]
+    pages = PAGES
+    shape = dict(slots=64, rows=2) if qt == 8 else {}
+    q, w, pool, table, row_of, bounds = _case(spans, pages, qt, **shape)
     plan = block_plan(row_of, bounds, qt, block)
     assert (np.asarray(plan[3]) >= 0).tolist() == merged
     got = si._index_scores(q, w, pool, table, row_of, bounds, qt, block, True)
@@ -100,7 +110,7 @@ def test_selection_over_the_block_pass_is_the_references():
     """``select_tiled`` and ``_kept_tiled`` over the kernel's scores equal
     ``select`` and ``kept`` over the gather's, where a query selects."""
     topk = 16
-    q, w, pool, table, row_of, bounds = _case([(40, 60), (24, 0)], 13)
+    q, w, pool, table, row_of, bounds = _case([(40, 60), (24, 0)], PAGES, slots=64, rows=2)
     want = si.index_scores_ref(q[:, None], w[:, None], pool, table[row_of], bounds[:, None])[:, 0]
     tau_w, allow_w = si.select(want, bounds, topk)
     kept_w = np.asarray(si.kept(want, tau_w, allow_w))
