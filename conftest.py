@@ -52,6 +52,7 @@ _NIGHTLY_FILES = {
     "test_ragged_attention.py",  # interpret-mode engines, the recompile guard
     "test_ragged_kernels.py",  # ragged kernel interpret-mode vs ref oracle
     "test_ragged_expanded.py",  # the expanded call, interpret-mode vs ref oracle
+    "test_ragged_tile_rows.py",  # tiles walked as rows, interpret-mode vs the tile kernel
     "test_logprobs.py",  # engine logprob oracle runs
     "test_disagg.py",  # two-engine disagg e2e
     "test_decode_compaction.py",  # occupancy-proportional decode proofs

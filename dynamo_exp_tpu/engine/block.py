@@ -492,6 +492,39 @@ class BlockDecode:
         return pending
 
     # ----------------------------------------------------------------- consume
+    def _count_tile_rows(self, n_denoise, wrote) -> None:
+        """Of a block program's attention calls, the live tiles by how a
+        grid cell fetches their first chunk and by the schedule their pass
+        works a chunk in (``dynamo_attn_tile_rows_total``): the kernel's own
+        rules (``ops/ragged_attention.py::one_query_chain`` at
+        ``one_query_block``'s tiles a cell, ``one_query_schedule``; each
+        form of attention by its share of the layers) on what the dispatch
+        says it made. A row with something masked rides every pass until
+        nothing is, so row ``r`` was live in the first ``n_denoise[r]``
+        passes; the opening pass's stream is two tiles a row, the block
+        before (live where the pass wrote it) and the block. The reference
+        has no tiles and counts nothing."""
+        if self._attn_impl != "pallas":
+            return
+        from ..models.llama import one_query_rows, one_query_schedules
+        from ..ops.ragged_attention import one_query_fetches
+
+        model, tp = self.cfg.model, self.cfg.tp
+        B = model.block_length
+        opening = np.stack([wrote > 0, n_denoise > 0], axis=1).reshape(1, -1)
+        later = np.arange(1, max(int(n_denoise.max(initial=0)), 1))
+        counter = get_telemetry().attn_tile_rows
+        for live in (opening, n_denoise[None, :] > later[:, None]):
+            if not live.any():
+                continue
+            tiles = live.shape[1]
+            fetches = one_query_fetches(
+                np.where(live, 0, -1), one_query_rows(model, tiles, tp, B)
+            )
+            for schedule, share in one_query_schedules(model, tiles, tp, B).items():
+                for fetch, count in zip(("behind", "exposed"), fetches):
+                    counter.labels(fetch, schedule).inc(count * share)
+
     @staticmethod
     def _block_lp_pack(blk_lp, fixed_at, at, n_top: int, n: int):
         """``_emit_row``'s pack of a finished block's first ``n`` emitted
@@ -616,6 +649,7 @@ class BlockDecode:
                 self._count_slots(
                     "block", live, pending.nb * B * (loops + 1) - live
                 )
+                self._count_tile_rows(n_denoise, wrote)
                 self.steps += loops
             else:
                 tel.diffusion_passes.labels("write").inc(written)

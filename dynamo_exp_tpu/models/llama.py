@@ -2117,7 +2117,9 @@ def _pallas_ragged(
     sharded over tp exactly like :func:`_pallas_decode` (the kernel is
     per-head data-parallel; each shard sees its local heads, and a
     shard's query heads a KV head are the model's, so ``q_block``, the
-    slots a grid cell serves (None: one tile), is every shard's)."""
+    slots a grid cell serves, is every shard's; None: rows on one copy
+    pipeline, as many a cell as the kernel's rule gives a shard's heads.
+    ``forward_ragged`` says which callers name none)."""
     from functools import partial as _partial
 
     from ..ops.ragged_attention import ragged_paged_attention
@@ -2174,43 +2176,49 @@ def ragged_query_block(
     return query_block(q_tile, cfg.num_heads // (kv_heads or cfg.num_kv_heads))
 
 
-def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1) -> int:
+def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1, q_tile: int = 1) -> int:
     """Rows a grid cell of the attention kernel's one-query call serves over
-    a stream of ``n`` slots (a window's bucket, a mixed stream's packed
-    tail) on a chip that holds one of ``tp`` shares of the heads
+    a stream of ``n`` rows (a window's bucket, a mixed stream's packed
+    tail; at ``q_tile`` > 1 a block program's ``n`` tiles, a tile a row) on
+    a chip that holds one of ``tp`` shares of the heads
     (``ops/ragged_attention.py::one_query_block`` at the model's widths: a
     latent pool's absorbed queries are its row's lanes wide and the result
     its value lanes), or one for a model with an indexer, whose selection
-    rides cells of one row. The engine's counter
-    (``dynamo_attn_one_query_rows_total``) asks here what the kernel asks
-    of its own shapes."""
+    rides cells of one row. The engine's counters
+    (``dynamo_attn_one_query_rows_total``, ``dynamo_attn_tile_rows_total``)
+    ask here what the kernel asks of its own shapes."""
     from ..ops.ragged_attention import one_query_block
 
     if cfg.has_indexer:
         return 1
     width, value = cfg.head_dim_, cfg.value_head_dim_
+    kv_heads = cfg.num_kv_heads // tp
     if cfg.is_latent:
-        width, value = cfg.latent_lanes, latent_value_lanes(cfg)
-    return one_query_block(n, cfg.num_heads // tp, width, value)
+        width, value, kv_heads = cfg.latent_lanes, latent_value_lanes(cfg), 1
+    return one_query_block(n, cfg.num_heads // tp, width, value, q_tile, kv_heads)
 
 
-def one_query_schedules(cfg: ModelConfig, n: int, tp: int = 1) -> dict[str, float]:
+def one_query_schedules(
+    cfg: ModelConfig, n: int, tp: int = 1, q_tile: int = 1
+) -> dict[str, float]:
     """The share of the model's attention layers whose one-query call over a
-    stream of ``n`` slots works a chunk for all its KV heads ``together``,
-    and the share that goes ``by_head``
-    (``ops/ragged_attention.py::one_query_schedule`` at each form's KV heads
-    on one of ``tp`` shares, at :func:`one_query_rows` a cell; a latent
-    pool's absorbed form has one KV head). The engine's counter
-    (``dynamo_attn_one_query_heads_total``) asks here what the kernel asks
-    of its own shapes."""
+    stream of ``n`` rows (of one query, or of a tile of ``q_tile``) works a
+    chunk for all its KV heads ``together``, and the share that goes
+    ``by_head`` (``ops/ragged_attention.py::one_query_schedule`` at each
+    form's KV heads on one of ``tp`` shares, at :func:`one_query_rows` a
+    cell; a latent pool's absorbed form has one KV head). The engine's
+    counters (``dynamo_attn_one_query_heads_total``,
+    ``dynamo_attn_tile_rows_total``) ask here what the kernel asks of its
+    own shapes."""
     from ..ops.ragged_attention import one_query_schedule
 
-    rows = one_query_rows(cfg, n, tp)
+    rows = one_query_rows(cfg, n, tp, q_tile)
+    stacked = cfg.num_heads // tp * q_tile
     shares = {"together": 0.0, "by_head": 0.0}
     forms = [form for form in cfg.attn_forms if form]
     for form in forms:
         kv_heads = 1 if cfg.is_latent else cfg.kv_heads_of(form) // tp
-        shares[one_query_schedule(rows, kv_heads)] += 1 / len(forms)
+        shares[one_query_schedule(rows, kv_heads, stacked)] += 1 / len(forms)
     return shares
 
 
@@ -2430,10 +2438,11 @@ def forward_ragged(
                     )
                 return post(o)
 
-            def tiles(stop, block=None):
-                # The tiled part [0, stop): one call, or for a latent model
-                # a call a slab (LATENT_SLAB; a slab is whole tiles, and a
-                # tile is a cell of its own: the same reads in the same order)
+            def tiles(stop, block):
+                # The tiled part [0, stop) in cells of ``block`` slots: one
+                # call, or for a latent model a call a slab (LATENT_SLAB; a
+                # slab is whole tiles and no cell spans two: the same reads
+                # in the same order)
                 if wrap is None:
                     return [kernel(slice(0, stop), q_tile, block)]
                 slab = LATENT_SLAB // q_tile * q_tile
@@ -2466,7 +2475,14 @@ def forward_ragged(
 
             n = q.shape[1]
             if packed_from is None:
-                parts = tiles(n)
+                # The ONE place a tiled call names no block: a block model's
+                # pass (a tile of ``block_length`` a row, rows side by side),
+                # whose tiles the kernel walks as the one-query call walks
+                # rows of one query (``ops/ragged_attention.py``, form 2; so
+                # does a stream at a tile of 1). Any other stream of whole
+                # spans (a comparison's prompts a row) names a tile a cell.
+                rows = q_tile == 1 or q_tile == cfg.block_length
+                parts = tiles(n, None if rows else q_tile)
                 return (parts[0] if len(parts) == 1 else jnp.concatenate(parts))[None]
             # A mixed stream's spans run over many tiles of one row: a
             # grid cell takes a block of them (ops/ragged_attention.py).

@@ -24,50 +24,69 @@ Two implementations with identical semantics:
   HBM traffic is ``N * S``; fine for the CPU mesh, not the fast path.
 - :func:`ragged_paged_attention` — the Pallas TPU kernel. The caller
   aligns each row's query span to ``q_tile`` slots of the flat stream,
-  so every tile belongs to exactly one row. A grid cell serves a
-  **query block** of ``q_block`` consecutive slots, a whole number of
-  tiles (:func:`query_block`: enough that a KV head's score product has
-  about the MXU's 128 rows; one tile where the caller names none).
-  Where every live tile of the block belongs to one row
-  (:func:`block_plan`, derived on the device from ``row_of`` and the
-  positions) the cell makes ONE double-buffered pass over that row's
-  live pages (``ceil(kv_len / page_size)``) for all its queries, a
-  flash-style online softmax in VMEM scratch, each query masked at its
-  own bound; a block that holds several rows (a span's first and last)
-  walks its tiles one pass each. ``q_tile=1`` is the **one-query call**
-  (any slot a row of one query — the shape pure-decode windows dispatch,
-  and the one a mixed dispatch reads its decode rows through:
-  ``forward_ragged`` calls the kernel twice, at ``q_tile`` in blocks over
-  the stream's aligned spans and at 1 over its packed tail, where rows of
-  one token lie side by side). There a grid cell serves a block of
-  consecutive slots (:func:`one_query_block`, from the call's heads and
-  lanes: a window's whole bucket at most models' widths) and walks their
-  rows one after another on ONE double-buffered copy pipeline
-  (:func:`_rows_kernel`): while a row's last chunk is multiplied, the
-  first chunk of the next row that has something to read
-  (:func:`one_query_chain`, derived on the device from the positions) is
-  on its way, and the cell's query and output blocks travel once for all
-  its rows. A row's arithmetic is the pass's at one query, state zeroed at
-  its start: to the bit what a cell of one row gives, which is what a
-  stream of one slot and a call under a learned selection (``keep``, whose
-  scores come a tile a cell) still are. Before PR 52 every row was a cell
-  of its own and its first fetch, 16 page copies behind ~1 us of latency,
-  had nothing to hide behind (PERF.md §6, PR 52).
-  Inside such a cell a chunk is worked **for all the call's KV heads
+  so every tile belongs to exactly one row (at ``q_tile=1`` any slot is a
+  row of one query). One online-softmax pass (:func:`_attend`: a
+  double-buffered walk over a row's live pages, ``ceil(kv_len /
+  page_size)``, flash-style, each query masked at its own bound) in
+  **three forms, by what a grid cell is**:
+
+  1. **A block of tiles** (:func:`_block_kernel`; the caller names
+     ``q_block``, a whole number of tiles: :func:`query_block`, enough that
+     a KV head's score product has about the MXU's 128 rows). A mixed
+     stream's aligned spans. Where every live tile of the block belongs to
+     one row (:func:`block_plan`, derived on the device from ``row_of`` and
+     the positions) the cell makes ONE pass over that row's context for all
+     its queries; a block that holds several rows (a span's first and last)
+     walks its tiles one pass each.
+  2. **Rows walked one after another on ONE copy pipeline**
+     (:func:`_rows_kernel`; PR 52). A row is one query (``q_tile=1``: the
+     shape pure-decode windows dispatch, and the one a mixed dispatch reads
+     its decode rows through: ``forward_ragged`` calls the kernel twice, at
+     ``q_tile`` in blocks over the stream's aligned spans and at 1 over its
+     packed tail, where rows of one token lie side by side) or, where the
+     caller names no block and the tiles' chunk is worked for the KV heads
+     together (below), ONE TILE of ``q_tile`` queries (PR 56: a block
+     model's pass, a tile of ``block_length`` queries a row and the rows
+     side by side; in its opening pass the block before and the block, two
+     tiles that read the same pages, are two rows of the walk). A cell
+     serves :func:`one_query_block`'s consecutive rows (from the call's
+     heads, lanes and tile: a window's whole bucket at most models' widths,
+     32 of SDAR's tiles of 4): while a row's last chunk is multiplied, the
+     first chunk of the next row that has something to read
+     (:func:`one_query_chain`, derived on the device from the positions) is
+     on its way, and the cell's query and output blocks travel once for all
+     its rows. A row's arithmetic is the pass's at one query or one tile,
+     state zeroed at its start: to the bit what a cell of that row alone
+     gives. Before PR 52 every row was a cell of its own and its first
+     fetch, 16 page copies behind ~1 us of latency, had nothing to hide
+     behind (PERF.md §6, PR 52); a block model's tiles were until PR 56.
+  3. **One tile** (:func:`_tile_kernel`): a stream of one row, a call under
+     a learned selection (``keep``, whose scores come a tile a cell), a
+     block named as one tile (a model of 20 query heads a KV head, a latent
+     pool's 128 heads; a stream of whole prompts that is no block model's,
+     ``models/llama.py::forward_ragged``; the oracle ``q_block=q_tile``),
+     and a call that names none whose tiles would go by head
+     (:func:`one_query_block` answers 1).
+
+  Inside a cell of form 2 a chunk is worked **for all the call's KV heads
   together, in phases** (:func:`_heads_together`; PR 54): the ``Hkv`` score
   products side by side, ONE mask / running maximum / exponential / row
-  sum over the stacked score rows ``[H, S]`` (the position mask, the
-  sliding bound and the sink's start are every head's), the ``Hkv`` value
-  products, ONE ``acc * alpha + pv``; the running ``m``, ``l`` and ``acc``
-  are the chunk loop's carried values and the pass touches the state's
-  references not at all. The walk by head, every head's pair of products
-  and softmax update through ``m_ref`` / ``l_ref`` / ``acc_ref`` in turn,
-  left each head's products waiting for the one before: the call was bound
-  by that chain, not by its fetches (PERF.md §6, PR 54).
-  :func:`one_query_schedule` reads the schedule from the call's shapes:
-  a call of ONE KV head (a latent pool's absorbed form, 20 query heads on
-  one) has no chain to break and keeps the walk by head, as every cell of
-  one row and every tiled call does; the results are the same to the bit.
+  sum over the stacked score rows ``[H * q_tile, S]`` (kv head, group,
+  query: the position mask, the sliding bound and the sink's start differ
+  by query and not by head), the ``Hkv`` value products, ONE ``acc * alpha
+  + pv``; the running ``m``, ``l`` and ``acc`` are the chunk loop's carried
+  values and the pass touches the state's references not at all. The walk
+  by head, every head's pair of products and softmax update through
+  ``m_ref`` / ``l_ref`` / ``acc_ref`` in turn, left each head's products
+  waiting for the one before: the call was bound by that chain, not by its
+  fetches (PERF.md §6, PR 54). :func:`one_query_schedule` is **the rule
+  that picks the schedule**, from the call's shapes alone: ``together``
+  where a cell walks more than one row, the call has more than one KV head
+  and its stacked score rows are at most the MXU's 128 (as far as the form
+  was measured: PERF.md §6, PR 56); ``by_head`` for ONE KV head (a latent
+  pool's absorbed form, 20 query heads on one: no chain to break), for more
+  stacked rows than that (a row of one query; a row of one tile is then
+  form 3's), and in forms 1 and 3. The results are the same to the bit.
 
 **A latent pool** (multi-head latent attention, ``models/llama.py::
 _mla_half``): both implementations take ``value_dim``, with which a
@@ -350,20 +369,36 @@ _ONE_QUERY_IO_LANES = 1 << 20
 _ONE_QUERY_ROWS = 8
 
 
-def one_query_block(n: int, heads: int, width: int, value_width: int) -> int:
-    """Rows (slots) a grid cell of the one-query call serves on one copy
-    pipeline, from the call's shapes alone: as many as keep the cell's
-    query and output blocks (``heads`` padded to a sublane tile of 16, of
-    ``width`` and ``value_width`` lanes) inside ``_ONE_QUERY_IO_LANES``, a
-    power of two and never under ``_ONE_QUERY_ROWS``; the whole stream
-    where it has no more rows than that. The kernel's caller and the
-    engine's counter (``dynamo_attn_one_query_rows_total``) both ask here."""
-    a_row = -(-heads // 16) * 16 * (width + value_width)
-    fit = max(_ONE_QUERY_ROWS, _ONE_QUERY_IO_LANES // a_row)
-    return min(n, 1 << (fit.bit_length() - 1))
+def one_query_block(
+    n: int, heads: int, width: int, value_width: int, q_tile: int = 1,
+    kv_heads: int = 1,
+) -> int:
+    """Rows a grid cell of the one-query call serves on one copy pipeline,
+    from the call's shapes alone: as many as keep the cell's query and
+    output blocks (``heads`` padded to a sublane tile of 16, of ``width``
+    and ``value_width`` lanes) inside ``_ONE_QUERY_IO_LANES``, a power of
+    two and never under ``_ONE_QUERY_ROWS``; the whole stream where it has
+    no more rows than that. A row of one TILE of ``q_tile`` queries (a block
+    model's pass: ``n`` tiles) is walked as a row of one query is, and its
+    cell's blocks are ``q_tile`` slots a row: the same slots hold that many
+    fewer tiles. Tiles are walked so only where their chunk is worked for
+    the call's ``kv_heads`` together (:func:`one_query_schedule`: the one
+    form of the walk that was timed, PERF.md §6, PR 56); where it is not
+    (one KV head, more than 128 stacked score rows, not even two tiles'
+    room: a latent pool's 128 heads of a tile of 8) the answer is 1, a cell
+    a tile (:func:`_tile_kernel`, as before PR 56). At one query a row the
+    KV heads decide nothing here. The kernel's caller and the engine's
+    counters (``dynamo_attn_one_query_rows_total``,
+    ``dynamo_attn_tile_rows_total``) both ask here."""
+    a_slot = -(-heads // 16) * 16 * (width + value_width)
+    fit = max(_ONE_QUERY_ROWS, _ONE_QUERY_IO_LANES // a_slot)
+    rows = min(n, max(1, (1 << (fit.bit_length() - 1)) // q_tile))
+    if q_tile > 1 and one_query_schedule(rows, kv_heads, heads * q_tile) == "by_head":
+        return 1
+    return rows
 
 
-def one_query_chain(positions, rows: int, xp=jnp):
+def one_query_chain(positions, rows: int, xp=jnp, q_tile: int = 1):
     """The order a cell of the one-query call fetches in: ``(lead, after)``,
     the stream padded with ``-1`` to whole cells of ``rows`` slots. ``lead``
     [cells]: the first slot of each cell that has something to read (counted
@@ -371,8 +406,12 @@ def one_query_chain(positions, rows: int, xp=jnp):
     starts with nothing before it. ``after`` [cells * rows]: for each slot
     the next such slot behind it in its cell (``rows``: none), whose first
     chunk is started behind this slot's last. Slots of no query are stepped
-    over: they cost no copy. ``xp``: ``jnp`` inside the program, ``numpy``
-    for the engine's count of the same rule."""
+    over: they cost no copy. At ``q_tile`` > 1 a row is a tile of that many
+    slots (``rows`` tiles a cell), live where one of its queries is. ``xp``:
+    ``jnp`` inside the program, ``numpy`` for the engine's count of the same
+    rule."""
+    if q_tile > 1:  # a tile's bound: -1 where it holds no query
+        positions = positions.reshape(-1, q_tile).max(axis=1)
     at = xp.arange(rows, dtype=positions.dtype)
     live = xp.where(_to_whole(positions, rows, xp).reshape(-1, rows) >= 0, at, rows)
     # [cell, i, k]: slot k where it is live and behind i, else ``rows``
@@ -386,7 +425,8 @@ def one_query_fetches(positions, rows: int) -> tuple[int, int]:
     chunk is fetched behind another row of their cell and those whose
     fetch nothing hides, the first live one of each cell of ``rows``:
     :func:`one_query_chain`'s rule, as the kernel walks it. At one row a
-    cell every fetch is exposed."""
+    cell every fetch is exposed. A block program's stream comes as its
+    tiles' bounds, a tile a row (-1: no query in it)."""
     live = np.asarray(positions) >= 0
     live = live.reshape(-1, live.shape[-1])
     live = np.pad(live, ((0, 0), (0, (-live.shape[1]) % rows)))
@@ -394,21 +434,30 @@ def one_query_fetches(positions, rows: int) -> tuple[int, int]:
     return int(live.sum()) - exposed, exposed
 
 
-def one_query_schedule(rows: int, kv_heads: int) -> str:
+def one_query_schedule(rows: int, kv_heads: int, stacked: int) -> str:
     """How a pass of the one-query call works a chunk, from the call's
     shapes alone: ``"together"`` (all its KV heads' score products, ONE
     softmax update over their stacked rows, all their value products, the
     running state carried as values: :func:`_heads_together`) where a cell
     walks ``rows`` > 1 slots (:func:`_rows_kernel`) of more than one KV
-    head; ``"by_head"`` (head after head through the state's references)
-    for one KV head, which has no chain to break and keeps its text, and for
-    a cell of one row (the tile kernel: a stream of one slot, a call under a
-    learned selection, the oracle ``q_block=1``). The query heads a KV head
-    decide nothing: at 16, 8, 4 and 1 of them the call alone measured
-    40–48 % faster a chunk together (PERF.md §6, PR 54). The kernel's caller
-    and the engine's counter (``dynamo_attn_one_query_heads_total``) both
-    ask here."""
-    return "together" if rows > 1 and kv_heads > 1 else "by_head"
+    head, and the pass's ``stacked`` score rows (its query heads, times the
+    queries of a row that is a tile) are no more than the MXU's 128: as far
+    as it was measured (SDAR's 32 heads of a tile of 4, where the state as
+    values is 16 vector registers an array and spills, and a chunk still
+    costs 0.68 us against 0.86 in two halves of 64 rows and 1.30 by head:
+    PERF.md §6, PR 56); ``"by_head"`` (head after head through the state's
+    references) for one KV head, which has no chain to break and keeps its
+    text, for more rows than that and for a cell of one row (the tile
+    kernel: a stream of one slot, a call under a learned selection, the
+    oracle ``q_block=q_tile``, and a tile whose chunk would not be worked
+    together: :func:`one_query_block` answers 1 there). The query heads a
+    KV head decide nothing: at 16, 8, 4 and 1 of them the call alone
+    measured 40–48 % faster a chunk together (PERF.md §6, PR 54). The
+    kernel's caller and the engine's counters
+    (``dynamo_attn_one_query_heads_total``, ``dynamo_attn_tile_rows_total``)
+    both ask here."""
+    few = rows > 1 and kv_heads > 1 and stacked <= _MXU_ROWS
+    return "together" if few else "by_head"
 
 
 def _chunk_copies(row, base, slot, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
@@ -465,14 +514,17 @@ def _side_by_side(product, hkv: int, qpk: int):
 
 
 def _heads_together(q, k_of, v_of, live, state, *, hkv, qpk, scale):
-    """One chunk of a one-query pass for ALL its KV heads, in phases
+    """One chunk of a pass of one row for ALL its KV heads, in phases
     (:func:`one_query_schedule`): the ``hkv`` score products, ONE mask /
     running maximum / exponential / row sum over the stacked score rows
     ``[H, S]``, the ``hkv`` value products, ONE ``acc * alpha + pv``.
-    ``q`` [H, D] float32 rows (kv head, group), ``k_of(h)`` [S, D] and
-    ``v_of(h)`` [S, Dv] a head's keys and values of the chunk as fetched,
-    ``live`` [1, S] the positions the query sees (the same for every head),
-    ``state`` ``(m, l, acc)`` as values ([H, 1], [H, 1], [H, Dv] float32).
+    ``q`` [H, D] float32 rows (kv head, group; ``qpk`` of them a KV head:
+    a row of a tile's queries has (group, query) there), ``k_of(h)`` [S, D]
+    and ``v_of(h)`` [S, Dv] a head's keys and values of the chunk as
+    fetched, ``live`` the positions a score row sees: [1, S] for one query
+    (the same for every head) or a mask a score row [H, S] for a tile's
+    (it differs by query and not by head), ``state`` ``(m, l, acc)`` as
+    values ([H, 1], [H, 1], [H, Dv] float32).
     Every score row sees the products, operands and order of chunks of
     ``_attend``'s walk by head: the result is that walk's to the bit.
     Returns the new state."""
@@ -509,6 +561,7 @@ def _attend(
     q_ref,  # [W, H, D] VMEM — the pass's queries
     o_ref,  # [W, H, Dv] VMEM
     pos_rows,  # () -> [qpk*W, 1] int32: last kv position a score row sees
+    #            ([H*W, 1], every head's, where ``together`` and W > 1)
     row,  # scalar: the batch row whose pages the pass walks
     kvlen,  # scalar: kv tokens it walks (0 = none)
     table_ref,  # [R, Pmax] int32 SMEM — page ids per row
@@ -551,8 +604,8 @@ def _attend(
     table, where ``after_live`` says there is one: still every copy
     started is waited for, by the pass it was started for. The pass must
     have something to walk.
-    ``together`` (static; with ``chain``, at one query:
-    :func:`one_query_schedule`) works each chunk for all the KV heads at
+    ``together`` (static; with ``chain``, a row of one query or of one
+    tile: :func:`one_query_schedule`) works each chunk for all the KV heads at
     once (:func:`_heads_together`) with the running maximum, sum and accumulator
     carried as the chunk loop's values, where the pass otherwise walks the
     heads one after another through ``m_ref`` / ``l_ref`` / ``acc_ref``,
@@ -601,11 +654,21 @@ def _attend(
         base = c * cp if sliding is None else c * cp + page0
         return _chunk_copies(row, base, slot, *pools, cp=cp, pmax=pmax)
 
+    def sink_rows():
+        # score rows lie (head, query): a head's logit for each of its ``w``
+        return jnp.broadcast_to(
+            sink[...][:, None, :], (hkv * qpk, w, 128)
+        ).reshape(n, 128)
+
     if together:
-        # The state as values, carried through the chunk loop: one query a
-        # pass, so a head's sink is its one score row's.
+        # The state as values, carried through the chunk loop; at one query
+        # a pass a head's sink is its one score row's.
+        if sink is None:
+            m0 = jnp.full((n, 1), -1e30, jnp.float32)
+        else:
+            m0 = sink[:, :1] if w == 1 else sink_rows()[:, :1]
         state = (
-            jnp.full((n, 1), -1e30, jnp.float32) if sink is None else sink[:, :1],
+            m0,
             jnp.full((n, 1), 0.0 if sink is None else 1.0, jnp.float32),
             jnp.zeros((n, dv), jnp.float32),
         )
@@ -615,10 +678,7 @@ def _attend(
             m_ref[used] = jnp.full((n, 128), -1e30, jnp.float32)
             l_ref[used] = jnp.zeros((n, 128), jnp.float32)
         else:
-            # score rows lie (head, query): a head's logit for each of its ``w``
-            m_ref[used] = jnp.broadcast_to(
-                sink[...][:, None, :], (hkv * qpk, w, 128)
-            ).reshape(n, 128)
+            m_ref[used] = sink_rows()
             l_ref[used] = jnp.ones((n, 128), jnp.float32)
 
     if chain is None:
@@ -679,7 +739,9 @@ def _attend(
         if sliding is not None:
             tok_idx = tok_idx + page0 * ps
         if together:
-            pos = pos_rows[:1]  # one query, every head's: the mask is [1, S]
+            # one query, every head's: the mask is [1, S]; a tile's queries
+            # each their own: a mask a stacked score row (``pos_rows`` [n, 1])
+            pos = pos_rows[:1] if w == 1 else pos_rows
             live = tok_idx <= pos
             if sliding is not None:
                 live = live & (tok_idx > pos - sliding)
@@ -690,7 +752,7 @@ def _attend(
 
             return _heads_together(
                 q.reshape(n, hd), lanes_of(k_buf, hd), lanes_of(v_buf, dvh),
-                live, carry, hkv=hkv, qpk=qpk, scale=scale,
+                live, carry, hkv=hkv, qpk=qpk * w, scale=scale,
             )
         k = k_buf[slot].reshape(S, hkv * hd)  # [S, Hkv*D]
         v = v_buf[slot].reshape(S, hkv * dvh) if vd is None else None
@@ -786,6 +848,20 @@ def _pools_out_scratch(refs, vd):
     return k_hbm, None, o_ref, (k_buf, None, *state)
 
 
+def _tile_bounds(positions_ref, t, qt: int, rows: int):
+    """The bounds of the ``qt`` queries of tile ``t``, read from SMEM, as a
+    column of ``rows`` score rows ([rows, 1] int32) in which the query
+    index varies fastest: row ``r`` sees what query ``r % qt`` does. Built
+    as a column from the start: a flat vector tiled from ``[qt]`` outgrows
+    one lane tile at 20 query heads a group (Mosaic: "input offsets outside
+    of the first tile")."""
+    query = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), qt)
+    column = jnp.zeros((rows, 1), jnp.int32)
+    for i in range(qt):
+        column = jnp.where(query == i, positions_ref[t * qt + i], column)
+    return column
+
+
 def _tile_kernel(
     # scalar prefetch (SMEM)
     tile_row_ref,  # [T] int32 — owning batch row per query tile
@@ -826,20 +902,9 @@ def _tile_kernel(
     # Score rows are laid out (kv head, group, query): each head's
     # block is contiguous, and within it the query index varies
     # fastest — so row r's causal bound is query r % QT's position.
-    # Built as a column from the start: a flat [qpk*QT] vector tiled
-    # from [QT] outgrows one lane tile at 20 query heads a group
-    # (Mosaic: "input offsets outside of the first tile").
-    def pos_rows():
-        query = jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (qpk * qt, 1), 0), qt
-        )
-        rows = jnp.zeros((qpk * qt, 1), jnp.int32)
-        for i in range(qt):
-            rows = jnp.where(query == i, positions_ref[t * qt + i], rows)
-        return rows
-
     _attend(
-        q_ref, o_ref, pos_rows, tile_row_ref[t], tile_kvlen_ref[t],
+        q_ref, o_ref, lambda: _tile_bounds(positions_ref, t, qt, qpk * qt),
+        tile_row_ref[t], tile_kvlen_ref[t],
         table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk, keep=keep,
         **more, **shape,
     )
@@ -917,17 +982,18 @@ def _block_kernel(
 def _rows_kernel(
     # scalar prefetch (SMEM): the stream's rows and bounds, one_query_chain's
     # two, and the page table
-    row_ref,  # [C*rb] int32 — owning batch row per slot
-    pos_ref,  # [C*rb] int32 — last kv position each slot's query sees
+    row_ref,  # [C*rb] int32 — owning batch row per row of the stream
+    pos_ref,  # [C*rb] int32 — last kv position a row reads to (-1: no query)
     lead_ref,  # [C] int32
     after_ref,  # [C*rb] int32
     table_ref,
     # inputs, output, scratch: _attend's
-    q_ref,  # [rb, H, D] VMEM — this cell's queries, one a row
+    q_ref,  # [rb*qt, H, D] VMEM — this cell's queries, ``qt`` a row
     *refs,  # _pools_out_scratch's
     rb: int,
     qpk: int,
     together: bool,
+    qt: int = 1,
     sinks: bool = False,
     **shape,
 ):
@@ -938,14 +1004,26 @@ def _rows_kernel(
     it, of the same row or the first of the next live one, is on its way
     into the other buffer. A row's own arithmetic is ``_attend``'s at one
     query, state zeroed at its start, a chunk worked for all the KV heads
-    ``together`` where the call has several (the caller's reading of
-    ``one_query_schedule``; ``_attend``'s ``together``). A slot
-    of no query copies nothing and is zeros. With ``sliding`` (in ``shape``) a sixth prefetched array
-    comes behind the table: each slot's first visible position
-    (``sliding_starts``), whose page its walk starts at."""
-    first_ref = None
+    ``together`` where the caller's reading of ``one_query_schedule`` says
+    so (``_attend``'s ``together``). A slot of no query copies nothing and
+    is zeros.
+    At ``qt`` > 1 a row of the stream is a TILE of ``qt`` slots of one
+    batch row (a block model's pass: its block, or in the opening pass the
+    block before and then its block, two rows of the stream that read the
+    same pages), walked as a row of one query is, always ``together``
+    (``one_query_block`` sends every other tiled call to a tile a cell):
+    ``pos_ref`` has each
+    tile's bound, the largest of its queries' (what ``_tile_kernel`` reads
+    to), and one more prefetched array, last, every slot's own, which masks
+    it (a padding query inside a live tile sees nothing and is zeros).
+    With ``sliding`` (in ``shape``) a sixth prefetched array comes behind
+    the table: each row's first visible position (``sliding_starts``),
+    whose page its walk starts at."""
+    first_ref = slot_pos_ref = None
     if shape.get("sliding") is not None:
         first_ref, q_ref, *refs = q_ref, *refs
+    if qt > 1:
+        slot_pos_ref, q_ref, *refs = q_ref, *refs
     sink = {}
     if sinks:  # the heads' sink logits, first behind the queries
         sink["sink"], *refs = refs
@@ -972,7 +1050,15 @@ def _rows_kernel(
     def row(j, slot0):
         t = base + j
         pos = pos_ref[t]
-        at = pl.ds(j, 1)
+        if qt == 1:
+            at = pl.ds(j, 1)
+            bounds = lambda: jnp.full((qpk, 1), pos, jnp.int32)  # noqa: E731
+        else:
+            at = pl.ds(pl.multiple_of(j * qt, qt), qt)
+            # score rows (kv head, group, query), all the heads': tiles are
+            # walked here only with their chunk worked for the heads together
+            n = shape["hkv"] * qpk * qt
+            bounds = lambda: _tile_bounds(slot_pos_ref, t, qt, n)  # noqa: E731
         # The pass walks from its first page to its bound: at least a chunk
         # where it has a query, so a copy started for it is waited for.
         n_chunks = jnp.where(
@@ -985,16 +1071,15 @@ def _rows_kernel(
             then = base + jnp.minimum(after, rb - 1)
             start = {} if first_ref is None else {"first": first_ref[t]}
             _attend(
-                q_ref.at[at], o_ref.at[at],
-                lambda: jnp.full((qpk, 1), pos, jnp.int32), row_ref[t],
-                pos + 1, table_ref, k_hbm, v_hbm, *scratch, w=1, qpk=qpk,
+                q_ref.at[at], o_ref.at[at], bounds, row_ref[t],
+                pos + 1, table_ref, k_hbm, v_hbm, *scratch, w=qt, qpk=qpk,
                 chain=(slot0, row_ref[then], first_page(then), after < rb),
                 together=together, **start, **sink, **shape,
             )
 
         @pl.when(pos < 0)
         def _():
-            o_ref[at] = jnp.zeros((1, *o_ref.shape[1:]), o_ref.dtype)
+            o_ref[at] = jnp.zeros((qt, *o_ref.shape[1:]), o_ref.dtype)
 
         return jax.lax.rem(slot0 + n_chunks, 2)
 
@@ -1033,7 +1118,7 @@ def ragged_paged_attention(
     sm_scale: float | None = None,
     interpret: bool = False,
     bounds: jnp.ndarray | None = None,  # [N] last kv position seen; None = positions
-    q_block: int | None = None,  # slots a grid cell serves; None: the rule's
+    q_block: int | None = None,  # slots a grid cell serves; None: rows, the rule's
     value_dim: int | None = None,  # a latent pool: values = the row's first lanes
     keep: tuple[jnp.ndarray, ...] | None = None,  # a learned selection
     sliding: int | None = None,  # a sliding layer's width (keys a query sees)
@@ -1047,7 +1132,13 @@ def ragged_paged_attention(
     consecutive slots (a multiple of ``q_tile``; :func:`query_block`
     sizes it for a stream of long spans). At ``q_block == q_tile`` a
     cell is one tile: it DMAs only that row's live pages and computes
-    ``q_tile`` queries against them. At more, a cell whose live tiles
+    ``q_tile`` queries against them. With no ``q_block`` (a block model's
+    pass: a tile a row, rows side by side) a cell walks
+    :func:`one_query_block`'s tiles one after another on one copy pipeline,
+    as the one-query call walks its rows, each tile's arithmetic the
+    one-tile cell's to the bit (one tile a cell where that rule answers 1:
+    tiles are walked only with their chunk worked for the KV heads
+    together). At more, a cell whose live tiles
     all belong to one row (:func:`block_plan`, derived here on the
     device) walks that row's pages once for all its queries, each
     masked at its own bound, and a cell that holds several rows walks
@@ -1110,11 +1201,14 @@ def ragged_paged_attention(
     qt = q_tile
     latent = value_dim is not None
     Dv = value_dim if latent else v_cache.shape[-1] // Hkv
-    # Rows a cell of the one-query call walks on one copy pipeline; 1: a
-    # cell is a tile (a learned selection's scores come a tile a cell).
+    # Rows a cell of the one-query call walks on one copy pipeline, a row a
+    # query or, where the caller names no block, a tile; 1: a cell is a tile
+    # (a learned selection's scores come a tile a cell).
     rb = 1
-    if qt == 1 and keep is None:
-        rb = q_block if q_block is not None else one_query_block(N, H, D, Dv)
+    if keep is None and q_block is None:
+        rb = one_query_block(N // qt, H, D, Dv, qt, Hkv)
+    elif keep is None and qt == 1:
+        rb = q_block
     # Slots a cell of the tiled forms serves (the rows form has rows, not tiles).
     qb = qt if rb > 1 or q_block is None else q_block
     nt = qb // qt
@@ -1170,33 +1264,40 @@ def ragged_paged_attention(
     pool_specs = [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
     out_shape = jax.ShapeDtypeStruct((N, H, Dv), q.dtype)
     if rb > 1:
-        # One query a row, ``rb`` rows a cell on one copy pipeline. The last
-        # cell of a stream that is no whole number of them reads past the
-        # queries' end: its rows are padded here to no query, and what it
-        # writes there is dropped.
-        lead, after = one_query_chain(positions, rb)
+        # One query a row, or one tile, ``rb`` rows a cell on one copy
+        # pipeline. The last cell of a stream that is no whole number of
+        # them reads past the queries' end: its rows are padded here to no
+        # query, and what it writes there is dropped.
+        lead, after = one_query_chain(positions, rb, q_tile=qt)
         n_cells = lead.shape[0]
-        over = (0, n_cells * rb - N)
+        over = (0, n_cells * rb - N // qt)
+        rows, bound, slots = row_of, positions, ()
+        if qt > 1:
+            # a tile's row and bound (``_tile_kernel``'s), and every slot's own
+            shape["qt"] = qt
+            rows = row_of.reshape(-1, qt)[:, 0]
+            bound = positions.reshape(-1, qt).max(axis=1)
+            slots = (jnp.pad(positions, (0, over[1] * qt), constant_values=-1),)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5 + len(starts[:1]),
+            num_scalar_prefetch=5 + len(starts[:1]) + len(slots),
             grid=(n_cells,),
-            in_specs=[query_spec(slots=rb), *sink_specs, *pool_specs],
-            out_specs=query_spec(Dv, rb),
-            scratch_shapes=scratch_for(1),
+            in_specs=[query_spec(slots=rb * qt), *sink_specs, *pool_specs],
+            out_specs=query_spec(Dv, rb * qt),
+            scratch_shapes=scratch_for(qt),
         )
         return pl.pallas_call(
             functools.partial(
                 _rows_kernel, rb=rb, **shape,
-                together=one_query_schedule(rb, Hkv) == "together",
+                together=one_query_schedule(rb, Hkv, H * qt) == "together",
             ),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
             **params,
         )(
-            jnp.pad(row_of, over), jnp.pad(positions, over, constant_values=-1),
+            jnp.pad(rows, over), jnp.pad(bound, over, constant_values=-1),
             lead, after, page_table, *(jnp.pad(s, over) for s in starts[:1]),
-            q, *sink_args, *pools,
+            *slots, q, *sink_args, *pools,
         )
     scratch_shapes = scratch_for(qb)
     if nt == 1:

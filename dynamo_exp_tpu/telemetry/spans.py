@@ -272,6 +272,26 @@ class Telemetry:
             ["schedule"],  # together | by_head
             registry=self.registry,
         )
+        self.attn_tile_rows = Counter(
+            "dynamo_attn_tile_rows_total",
+            "Live tiles of a block program's attention calls (a block "
+            "model's pure-decode dispatch: every pass a call of one tile of "
+            "block_length queries a row, the opening pass of two), by how "
+            "the tile's first chunk of context is fetched (behind: started "
+            "while the tile before it in its grid cell was still "
+            "multiplied; exposed: the first live tile of a cell, or a call "
+            "whose cell is one tile) and by the schedule a chunk of its "
+            "pass is worked in (together: all the KV heads' products side "
+            "by side, one softmax update over their stacked score rows, "
+            "the running state carried as values; by_head: head after head "
+            "through the state's references). Counted at the dispatch's "
+            "consume, once its passes are known, with the kernel's own "
+            "rules (ops/ragged_attention.py::one_query_chain, "
+            "one_query_block, one_query_schedule; each form of attention "
+            "by its share of the layers); a kernel engine only",
+            ["fetch", "schedule"],  # behind | exposed; together | by_head
+            registry=self.registry,
+        )
         self.launch_transfers = Counter(
             "dynamo_launch_transfers_total",
             "Host arrays handed to the device by ragged launches, by "

@@ -1,11 +1,13 @@
 """Ragged paged attention, the kernels against the reference: the Pallas
 kernel (interpret mode) in its three forms, a tile a grid cell, a block of
-tiles a cell (``q_block``) and one query a cell in rows (``q_tile=1``),
+tiles a cell (``q_block``) and rows walked on one pipeline a cell (one
+query a row at ``q_tile=1``, one tile a row where no block is named),
 against the pure-JAX reference across ragged (query_len, kv_len) shapes:
 page-boundary straddling spans, rows-1, inactive rows, GQA grouping, bf16
 pools, sliding widths, sinks, values narrower than keys, a latent pool; and
 the bit-for-bit streams (PR 44's digests, PR 52's and PR 54's cells of
-rows). The expanded call: ``tests/test_ragged_expanded.py``; the host's
+rows). Tiles walked as rows (PR 56): ``tests/test_ragged_tile_rows.py``. The
+expanded call: ``tests/test_ragged_expanded.py``; the host's
 rules: ``tests/test_ragged_rules.py``; engines and the tp dispatches:
 ``tests/test_ragged_attention.py``. What each parametrised case crosses is
 said at its table (docs/testing.md "Conventions").
@@ -181,8 +183,8 @@ def test_decode_shape_matches_reference_and_zeroes_inactive(sliding, rows, extra
             for block in (q_block, 1)
         )
         rows_a_cell = q_block or one_query_block(n, H, D, Dv or D)
-        together = one_query_schedule(rows_a_cell, Hkv) == "together"
-        assert together == (Hkv > 1) and one_query_schedule(1, Hkv) == "by_head"
+        together = one_query_schedule(rows_a_cell, Hkv, H) == "together"
+        assert together == (Hkv > 1) and one_query_schedule(1, Hkv, H) == "by_head"
         if H == Hkv > 1:
             np.testing.assert_allclose(np.asarray(got), np.asarray(a_row), rtol=0, atol=1e-6)
         else:
@@ -313,7 +315,7 @@ def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream, slid
     )
     by_tile = ragged_paged_attention(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, q_tile=8,
-        interpret=True, sliding=sliding, **sink,
+        interpret=True, q_block=8, sliding=sliding, **sink,
     )
     want = ragged_paged_attention_ref(
         q, k, v, table, row_of, positions, num_kv_heads=Hkv, window=sliding,
@@ -342,14 +344,15 @@ def test_block_kernel_matches_reference_and_the_tile_kernel(layout, stream, slid
         assert merged and walked
 
 
-@pytest.mark.parametrize("q_block", [None, 16], ids=["tile", "block16"])
+@pytest.mark.parametrize("q_block", [None, 4, 16], ids=["rows", "tile", "block16"])
 def test_block_bounds_reach_the_block_kernel(q_block):
     """``bounds`` (a block-diffusion model sees its whole block of 4) at
-    a cell of one tile, the block program's call, and at sdar's mixed
-    program's block of 16: the kernel reads the bounds and nothing else
-    of the positions."""
+    the block program's call (no block named: tiles walked as rows on one
+    pipeline, at two KV heads, which it works together), at a cell of one
+    tile, and at sdar's mixed program's block of 16: the kernel reads the
+    bounds and nothing else of the positions."""
     spans = [(8, 8), (12, 40), (4, 24)]
-    H, Hkv, D, ps, pmax = 8, 1, 32, 16, 3
+    H, Hkv, D, ps, pmax = 8, 2 if q_block is None else 1, 32, 16, 3
     q, k, v, table, row_of, positions = flat_batch(
         8, spans, H, Hkv, D, 32, ps, pmax, q_tile=4
     )
@@ -394,14 +397,15 @@ def _golden_case(qpk: int, hkv: int, D: int = 128, ps: int = 16):
 ])
 def test_kernel_bits_unchanged_beside_the_latent_form(qpk, hkv, digest):
     """The kernel's readings at 1, 8 and 20 query heads a KV head, in
-    tiles, in merged blocks and one query a cell, are to the bit what the
+    tiles (walked as rows on one pipeline, and a tile a cell), in merged
+    blocks and one query a cell, are to the bit what the
     parent of PR 44 (commit b4124f8) gave for these inputs: the latent
     form (``value_dim``) took nothing from the two-pool form's
     arithmetic."""
     import hashlib
 
     q, k, v, table, row, pos = _golden_case(qpk, hkv)
-    for qt, qb in ((8, None), (8, query_block(8, qpk)), (1, None)):
+    for qt, qb in ((8, None), (8, 8), (8, query_block(8, qpk)), (1, None)):
         out = ragged_paged_attention(
             q, k, v, table, row, pos, num_kv_heads=hkv, q_tile=qt, interpret=True,
             q_block=qb,

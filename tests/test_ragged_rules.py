@@ -152,7 +152,7 @@ def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed, kv_h
         before = counted()
         TPUEngine._count_one_query_rows(engine, positions)
         n = positions.shape[-1]
-        schedule = one_query_schedule(one_query_rows(model, n), kv_heads)
+        schedule = one_query_schedule(one_query_rows(model, n), kv_heads, model.num_heads)
         assert schedule == ("together" if kv_heads > 1 and n > 1 else "by_head")
         grew = {label: after - before[label] for label, after in counted().items()}
         assert grew.pop(schedule) == (positions >= 0).sum() and not any(grew.values())
@@ -180,6 +180,138 @@ def test_host_count_and_kernel_chain_agree_on_a_drawn_window_and_tail(seed, kv_h
     assert one_query_block(256, 32, 128, 128) == 128
     assert one_query_block(32, 128, 640, 512) == 8  # a latent pool's absorbed heads
     assert one_query_block(1, 32, 128, 128) == 1
+
+
+# (query heads, KV heads, key lanes, value lanes) a chip holds, by cell; the
+# rows a cell of a bucket of 64 and the schedule, as PR 55's tree answers at
+# one query a row (its two functions, asked at these shapes).
+_ONE_QUERY_LAYOUTS = {
+    "qwen3-4b": ((32, 8, 128, 128), 64, "together"),
+    "mixtral-8x7b, a share of four": ((8, 2, 128, 128), 64, "together"),
+    "lfm2-8b-a1b": ((32, 8, 64, 64), 64, "together"),
+    "jamba2-3b": ((20, 1, 128, 128), 64, "by_head"),
+    "sdar-30b-a3b": ((32, 4, 128, 128), 64, "together"),
+    "olmo-hybrid-7b": ((30, 30, 128, 128), 64, "together"),
+    "openpangu, the absorbed form": ((128, 1, 640, 512), 8, "by_head"),
+    "glm-5, the absorbed form": ((64, 1, 640, 512), 8, "by_head"),  # 14 fit: the power of two
+    "k-exaone-236b": ((64, 8, 128, 128), 64, "together"),
+    "mimo-v2-flash, full": ((64, 4, 192, 128), 32, "together"),
+    "mimo-v2-flash, sliding": ((64, 8, 192, 128), 32, "together"),
+}
+
+
+@pytest.mark.parametrize("layout", _ONE_QUERY_LAYOUTS)
+def test_the_rules_of_a_row_of_one_query_and_of_one_tile(layout):
+    """``one_query_block`` and ``one_query_schedule`` at ``q_tile`` 1 answer
+    what they answered before they knew of tiles, layout by layout; at a
+    tile a cell's query and output blocks are that many slots a row, so the
+    same slots hold that many fewer tiles, one where not even two fit (the
+    call is then the tile kernel), and tiles are walked so only with their
+    chunk worked for all the KV heads together: more than one KV head and
+    stacked score rows, heads x tile, the MXU's 128 at most; else the
+    answer is one tile a cell, the tile kernel as before PR 56."""
+    (heads, kv_heads, width, value_width), rows, schedule = _ONE_QUERY_LAYOUTS[layout]
+    assert one_query_block(64, heads, width, value_width) == rows
+    assert one_query_block(64, heads, width, value_width, 1) == rows
+    assert one_query_schedule(rows, kv_heads, heads) == schedule
+    assert one_query_schedule(1, kv_heads, heads) == "by_head"  # a cell of one row
+    for tile in (2, 4, 8):
+        tiles = one_query_block(64, heads, width, value_width, tile, kv_heads)
+        slots = one_query_block(64 * tile, heads, width, value_width)
+        fit = max(1, min(64, slots // tile))
+        together = fit > 1 and kv_heads > 1 and heads * tile <= 128
+        assert tiles == (fit if together else 1)
+        want = "together" if together else "by_head"
+        assert one_query_schedule(tiles, kv_heads, heads * tile) == want
+    if layout == "sdar-30b-a3b":
+        # a block pass: 64 tiles of 4 (128 in the opening pass), 32 a cell,
+        # 4 x 8 x 4 = 128 stacked rows: exactly on the bound
+        assert one_query_block(64, heads, width, value_width, 4, kv_heads) == 32
+        assert one_query_block(128, heads, width, value_width, 4, kv_heads) == 32
+        assert one_query_schedule(32, kv_heads, heads * 4) == "together"
+        # at one KV head the tiles would go by head: a tile a cell
+        assert one_query_block(64, heads // 4, width, value_width, 4, 1) == 1
+    if layout == "jamba2-3b":
+        # its mixed program names its block, one tile (``query_block``): the
+        # tile kernel; had it named none, one KV head's 160 stacked rows
+        # would go by head, so a tile a cell all the same
+        assert query_block(8, heads // kv_heads) == 8
+        assert one_query_block(64, heads, width, value_width, 8, kv_heads) == 1
+    if "absorbed" in layout:
+        assert one_query_block(64, heads, width, value_width, 8, kv_heads) == 1
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_host_count_and_kernel_chain_agree_on_a_drawn_block_stream(seed):
+    """``dynamo_attn_tile_rows_total``: a block program's passes, as the
+    dispatch reports them at its consume (passes made a row, blocks
+    written), rebuilt into the streams the program fed its attention calls
+    (the opening pass two tiles a row, the others one), counted with
+    ``one_query_fetches`` over the tiles' bounds: the same answer as a walk of
+    ``one_query_chain``'s ``(lead, after)`` over the tiles' bounds, as the
+    kernel makes it, planned with ``numpy`` and with ``jax.numpy``; every
+    live tile under the schedule ``one_query_schedule`` gives its call."""
+    from types import SimpleNamespace
+
+    from dynamo_exp_tpu.engine.block import BlockDecode
+    from dynamo_exp_tpu.models.llama import one_query_rows
+    from dynamo_exp_tpu.telemetry import get_telemetry
+
+    B = 4
+    model = dataclasses.replace(TINY, num_kv_heads=2, block_length=B)
+    engine = SimpleNamespace(_attn_impl="pallas", cfg=SimpleNamespace(model=model, tp=1))
+    counter = get_telemetry().attn_tile_rows
+
+    def counted():
+        return {
+            (fetch, schedule): counter.labels(fetch, schedule)._value.get()
+            for fetch in ("behind", "exposed") for schedule in ("together", "by_head")
+        }
+
+    rng = np.random.default_rng(seed)
+    nb = int(rng.choice([1, 4, 16, 64]))
+    n_denoise = np.where(rng.random(nb) < 0.8, rng.integers(1, 5, nb), 0)
+    wrote = (rng.random(nb) < 0.5) & (n_denoise > 0) | (rng.random(nb) < 0.1)
+    before = counted()
+    BlockDecode._count_tile_rows(engine, n_denoise, wrote.astype(np.int32))
+    grew = {k: v - before[k] for k, v in counted().items()}
+    # the streams, as ``_block_program`` builds them
+    start = 8 * B * (1 + np.arange(nb))
+    block = start[:, None] + np.arange(B)
+    opening = np.stack(
+        [np.where(wrote[:, None], block - B, -1), np.where(n_denoise[:, None] > 0, block, -1)],
+        axis=1,
+    ).reshape(-1)
+    streams = [opening] + [
+        np.where(n_denoise[:, None] > i, block, -1).reshape(-1)
+        for i in range(1, int(n_denoise.max(initial=0)))
+    ]
+    want = dict.fromkeys(grew, 0)
+    for stream in streams:
+        tiles = stream.size // B
+        rows = one_query_rows(model, tiles, 1, B)
+        assert rows == one_query_block(
+            tiles, model.num_heads, model.head_dim_, model.head_dim_, B, 2
+        )
+        schedule = one_query_schedule(rows, 2, model.num_heads * B)
+        lead, after = one_query_chain(stream.astype(np.int32), rows, xp=np, q_tile=B)
+        on_device = one_query_chain(jnp.asarray(stream, jnp.int32), rows, q_tile=B)
+        np.testing.assert_array_equal(lead, np.asarray(on_device[0]))
+        np.testing.assert_array_equal(after, np.asarray(on_device[1]))
+        seen = []
+        for cell, at in enumerate(lead):
+            want["exposed", schedule] += at < rows
+            while at < rows:
+                seen.append(cell * rows + at)
+                at = after[cell * rows + at]
+                want["behind", schedule] += at < rows
+        live = stream.reshape(-1, B).max(axis=1) >= 0
+        assert seen == list(np.flatnonzero(live))
+        assert one_query_fetches(stream.reshape(-1, B).max(axis=1), rows) == (
+            len(seen) - (lead < rows).sum(), (lead < rows).sum()
+        )
+    assert grew == want
+    assert sum(grew.values()) == n_denoise.sum() + wrote.sum()
 
 
 def test_the_expanded_rule_follows_from_the_widths():

@@ -624,6 +624,7 @@ def test_manifest_lists_the_block_variants_and_traffic_compiles_nothing():
         return await asyncio.gather(*[
             _run(eng, PROMPT[:p], n) for p, n in ((41, 15), (40, 8), (3, 9))])
 
+    tiles = _total("dynamo_attn_tile_rows_total")
     eng.start()
     try:
         outs = asyncio.run(asyncio.wait_for(traffic(), timeout=120))
@@ -632,6 +633,20 @@ def test_manifest_lists_the_block_variants_and_traffic_compiles_nothing():
         assert _total("dynamo_moe_dispatch_total", experts="inplace") > 0
     finally:
         eng.stop()
+    # The block programs' attention calls walked their tiles as rows on one
+    # copy pipeline (a bucket of more than one tile), the KV heads together:
+    # the live tiles were counted with the kernel's rules, and the tokens
+    # are the ones the reference's gather serves.
+    assert _total("dynamo_attn_tile_rows_total") > tiles
+    assert _total("dynamo_attn_tile_rows_total", fetch="behind", schedule="together") > 0
+    eng = _engine(_hf(hidden_size=128, head_dim=32), page_size=32, max_model_len=128,
+                  num_pages=16)
+    eng.start()
+    try:
+        by_gather = asyncio.run(asyncio.wait_for(traffic(), timeout=120))
+    finally:
+        eng.stop()
+    assert [o["token_ids"] for o in outs] == [o["token_ids"] for o in by_gather]
 
 
 # ----------------------------------------------------------------- loader

@@ -42,6 +42,18 @@ its own KV heads, widths, window and sink; where values are narrower than
 keys the next call's queries are the result beside the old queries' last
 lanes. ``--contexts ''`` skips the window lines and builds no engine. Not
 a latent pool.
+
+``--tile N`` times **the tiled call at one tile a row** in its place (a
+block model's pass: ``--config sdar-30b-a3b-l6 --tile 4``): every row a tile
+of N queries at the end of its context, each seeing its whole tile
+(``bounds``), no block named, so the kernel's own rule picks the form (tiles
+walked as rows on one copy pipeline where their chunk is worked for the KV
+heads together, else a tile a grid cell, as every such call was before PR
+56); ``--one-query-rows 1`` is the tile a cell. The same line,
+``a tile + a chunk x chunks``. ``--variant copies`` and ``--variant
+products`` time the call with its products, or its page copies, taken out
+(PR 52's and PR 54's split of a chunk's time; results mean nothing, and only
+a call whose chunks are worked for all the KV heads together has the seam).
 """
 
 from __future__ import annotations
@@ -153,11 +165,18 @@ def one_query_lines(args, shape: dict, ps: int, dev):
     import jax.numpy as jnp
     import numpy as np
 
-    from dynamo_exp_tpu.ops.ragged_attention import (
-        one_query_block,
-        one_query_schedule,
-        ragged_paged_attention,
-    )
+    from dynamo_exp_tpu.ops import ragged_attention as ra
+
+    tile = args.tile
+    attention = ra.ragged_paged_attention
+    if args.variant != "whole":
+        # Patched where the kernel's body looks them up; the jitted entry
+        # point would keep an earlier trace, so the call is its plain form.
+        attention = attention.__wrapped__
+        if args.variant == "copies":
+            ra._heads_together = lambda q, k_of, v_of, live, state, **_: state
+        else:
+            ra._chunk_copies = lambda *_, **__: []
 
     heads, kv_heads, hd, vd = shape["heads"], shape["kv_heads"], shape["width"], shape["value_width"]
     sliding, num_pages, chunk = shape["sliding"], shape["num_pages"], 128
@@ -177,12 +196,14 @@ def one_query_lines(args, shape: dict, ps: int, dev):
 
         @jax.jit
         def run(q, k, v, table, positions):  # the pools as arguments: a closure's are constants of the program
-            rows = jnp.arange(q.shape[0], dtype=jnp.int32)
+            rows = jnp.arange(q.shape[0], dtype=jnp.int32) // tile
+            # a block model's query sees its whole tile
+            seen = {"bounds": positions | (tile - 1)} if tile > 1 else {}
 
             def call(_, x):
-                out = ragged_paged_attention(
-                    x, k, v, table, rows, positions, num_kv_heads=kv_heads, q_tile=1,
-                    interpret=interpret, **kw,
+                out = attention(
+                    x, k, v, table, rows, positions, num_kv_heads=kv_heads, q_tile=tile,
+                    interpret=interpret, **kw, **seen,
                 )
                 # the next call's queries: where values are narrower than
                 # keys, the lanes the result has not stay the queries'
@@ -197,23 +218,26 @@ def one_query_lines(args, shape: dict, ps: int, dev):
         # each row on pages of its own as far as the pool goes
         table = (1 + (np.arange(nb * pages) % (num_pages - 1))).reshape(nb, pages)
         table = jnp.asarray(rng.permutation(table.reshape(-1)).reshape(nb, pages), jnp.int32)
-        q = jax.random.normal(keys[2], (nb, heads, hd), jnp.bfloat16)
+        q = jax.random.normal(keys[2], (nb * tile, heads, hd), jnp.bfloat16)
         for q_block in forms:
-            if q_block is not None and q_block > nb:
-                continue
-            run = chained(q_block)
-            rows_a_cell = q_block or one_query_block(nb, heads, hd, vd)
+            if q_block is not None and (q_block > nb or tile > 1 and q_block > 1):
+                continue  # tiles: the rule's, or a tile a cell
+            run = chained(q_block and q_block * tile)
             line = {
                 "one_query": True, "form": shape["form"], "nb": nb, "rows_a_cell": q_block or "rule",
-                "schedule": one_query_schedule(rows_a_cell, kv_heads),
+                "tile": tile, "variant": args.variant,
             }
+            rows_a_cell = q_block or ra.one_query_block(nb, heads, hd, vd, tile, kv_heads)
+            line["rows_a_cell_is"] = rows_a_cell
+            line["schedule"] = ra.one_query_schedule(rows_a_cell, kv_heads, heads * tile)
             walked = []
             for c in chunks:
                 at = c * chunk - 1
                 if sliding is not None and c > 1:
                     at -= chunk // 2 + 1  # its window's first key then lies inside a page
                 walked.append(c if sliding is None else min(c, 2))
-                positions = jnp.full((nb,), at, jnp.int32)
+                # a tile's queries end at ``at``; one query is its own tile
+                positions = jnp.tile(at - tile + 1 + jnp.arange(tile, dtype=jnp.int32), nb)
                 times = []
                 for i in range(args.calls + 2):  # the first call compiles, the second loads
                     t0 = time.perf_counter()
@@ -240,6 +264,10 @@ def main() -> int:
     ap.add_argument("--one-query-rows", default="",
                     help="rows a grid cell to time beside the rule's, e.g. 1,8,32")
     ap.add_argument("--one-query-reps", type=int, default=128)
+    ap.add_argument("--tile", type=int, default=1,
+                    help="time the tiled call at one tile of this many queries a row (a block model's pass)")
+    ap.add_argument("--variant", default="whole", choices=("whole", "copies", "products"),
+                    help="the call as it is, or with its products / its page copies taken out")
     ap.add_argument("--allow-cpu", action="store_true",
                     help="rehearse the control flow off the chip (tiny sizes; times mean nothing)")
     args = ap.parse_args()

@@ -5,7 +5,14 @@ that two trees can be compared without a chip: the lowered program
 packed tail of 64, lowered for a described ``v5e:2x2`` chip) with its
 kernels' payloads taken out, and each Pallas kernel's own module printed
 WITHOUT source locations (a kernel's payload embeds the file lines of its
-call sites, which move with any edit above them).
+call sites, which move with any edit above them). A second line,
+``whole_spans``: the same stream with NO packed tail (``packed_from=None``),
+the forward the comparisons under ``benchmark/comparisons/`` make of whole
+prompts a row, whose tiled call is the only one and names a tile a cell. A
+block model (``block_length``) gets a third: a pass of its block program
+(``engine/block.py::_block_program``'s forward: 64 rows, a tile of
+``block_length`` slots a row, no block named, the experts read in place),
+whose attention calls no mixed forward makes.
 
     JAX_PLATFORMS=cpu python3 tools/lowered_program_hash.py <tree> qwen3-4b olmo-hybrid-7b-l8 ...
 
@@ -73,31 +80,50 @@ def main() -> int:
         state = jax.eval_shape(lambda: llama.init_seq_state(mcfg, ROWS))
         state = described(state) if state is not None else None
 
-        def forward(params, tokens, positions, row_of, table, k, v, out_idx, state, rows):
-            return llama.forward_ragged(
-                params, mcfg, tokens, positions, row_of, table, k, v, out_idx,
-                attn_impl="pallas", q_tile=8, packed_from=SLOTS - TAIL, seq_state=state,
-                state_rows=rows if state is not None else None,
-            )
+        def lowered(packed_from):
+            def forward(params, tokens, positions, row_of, table, k, v, out_idx, state, rows):
+                return llama.forward_ragged(
+                    params, mcfg, tokens, positions, row_of, table, k, v, out_idx,
+                    attn_impl="pallas", q_tile=8, packed_from=packed_from, seq_state=state,
+                    state_rows=rows if state is not None else None,
+                )
 
-        table = i32(ROWS, PAGES_A_ROW)
-        if mcfg.kv_by_kind:  # a table a pool: (full, sliding)
-            table = (table, table)
-        text = jax.jit(forward).lower(
-            params, i32(SLOTS), i32(SLOTS), i32(SLOTS), table, k, v,
-            i32(ROWS), state, (i32(ROWS), i32(2 * ROWS), i32(2 * ROWS)),
-        ).as_text()
-        text = "\n".join(
-            re.sub(r'backend_config = "[^"]*"', 'backend_config = "<kernel>"', line)
-            if "tpu_custom_call" in line else line
-            for line in text.splitlines()
-        )
-        print(json.dumps({
-            "config": name,
-            "program_without_kernel_payloads": hashlib.sha256(text.encode()).hexdigest()[:20],
-            "kernels_without_locations": list(kernels), "chars": len(text),
-        }), flush=True)
-        kernels.clear()
+            table = i32(ROWS, PAGES_A_ROW)
+            if mcfg.kv_by_kind:  # a table a pool: (full, sliding)
+                table = (table, table)
+            return jax.jit(forward).lower(
+                params, i32(SLOTS), i32(SLOTS), i32(SLOTS), table, k, v,
+                i32(ROWS), state, (i32(ROWS), i32(2 * ROWS), i32(2 * ROWS)),
+            ).as_text()
+
+        def report(program: str, text: str) -> None:
+            text = "\n".join(
+                re.sub(r'backend_config = "[^"]*"', 'backend_config = "<kernel>"', line)
+                if "tpu_custom_call" in line else line
+                for line in text.splitlines()
+            )
+            print(json.dumps({
+                "config": name, "program": program,
+                "program_without_kernel_payloads": hashlib.sha256(text.encode()).hexdigest()[:20],
+                "kernels_without_locations": list(kernels), "chars": len(text),
+            }), flush=True)
+            kernels.clear()
+
+        report("mixed", lowered(SLOTS - TAIL))
+        report("whole_spans", lowered(None))
+        if mcfg.block_length:
+            B = mcfg.block_length
+
+            def block_pass(params, tokens, positions, row_of, table, k, v, out_idx):
+                return llama.forward_ragged(
+                    params, mcfg, tokens, positions, row_of, table, k, v, out_idx,
+                    attn_impl="pallas", q_tile=B, experts_any_rows=True, project=False,
+                )
+
+            n = 64 * B
+            report("block_pass", jax.jit(block_pass).lower(
+                params, i32(n), i32(n), i32(n), i32(64, PAGES_A_ROW), k, v, i32(n),
+            ).as_text())
     return 0
 
 
