@@ -12,7 +12,10 @@ from __future__ import annotations
 
 import logging
 
-from ..engine.config import refuse_latent_tiers, refuse_sliding_pool_tiers, refuse_seq_state_tiers
+from ..engine.config import (
+    refuse_latent_tiers, refuse_looped_tiers, refuse_sliding_pool_tiers,
+    refuse_seq_state_tiers,
+)
 from ..engine.engine import TPUEngine
 from ..engine.scheduler import RemoteKv
 from ..protocols.common import BackendInput
@@ -49,6 +52,7 @@ class DisaggDecodeEngine(AsyncEngine):
         refuse_seq_state_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         refuse_latent_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         refuse_sliding_pool_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
+        refuse_looped_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         self.engine = engine
         self.queue = queue
         self.receiver = receiver

@@ -14,7 +14,7 @@ import time
 
 from ..engine.config import (
     refuse_block_model_tiers,
-    refuse_latent_tiers, refuse_sliding_pool_tiers,
+    refuse_latent_tiers, refuse_looped_tiers, refuse_sliding_pool_tiers,
     refuse_seq_state_tiers,
 )
 from ..engine.engine import TPUEngine
@@ -42,6 +42,7 @@ class PrefillWorker:
         refuse_block_model_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         refuse_latent_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         refuse_sliding_pool_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
+        refuse_looped_tiers(getattr(engine, "cfg", None), "disaggregated transfer")
         self.engine = engine
         self.queue = queue
         self.cancel = cancel or CancellationToken()

@@ -136,6 +136,21 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
         )
 
 
+def _page_tiers_armed(cfg: "EngineConfig", tier: str | None) -> list[str]:
+    """What ``refuse_latent_tiers``, ``refuse_sliding_pool_tiers`` and
+    ``refuse_looped_tiers`` refuse alike: the named ``tier``, or those of the
+    engine's own configuration that are switched on."""
+    return [tier] if tier else [
+        name for name, on in (
+            ("host offload (host_cache_pages)", cfg.host_cache_pages > 0),
+            ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
+            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
+            ("ring prefill (sp > 1)", cfg.sp > 1),
+            ("tensor parallelism (tp > 1)", cfg.tp > 1),
+        ) if on
+    ]
+
+
 def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
     """The one place a model whose cache is a latent pool
     (``ModelConfig.is_latent``: one row ``[c_kv | k_r]`` a token and layer
@@ -164,15 +179,7 @@ def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> 
     ``cfg`` None."""
     if cfg is None or not cfg.model.is_latent:
         return
-    armed = [tier] if tier else [
-        name for name, on in (
-            ("host offload (host_cache_pages)", cfg.host_cache_pages > 0),
-            ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
-            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
-            ("ring prefill (sp > 1)", cfg.sp > 1),
-            ("tensor parallelism (tp > 1)", cfg.tp > 1),
-        ) if on
-    ]
+    armed = _page_tiers_armed(cfg, tier)
     if armed:
         raise ValueError(
             f"model_type {cfg.model.model_type!r} caches one latent row a "
@@ -215,15 +222,7 @@ def refuse_sliding_pool_tiers(cfg: "EngineConfig | None", tier: str | None = Non
     refused. Any other model passes, and so does ``cfg`` None."""
     if cfg is None or not cfg.model.kv_by_kind:
         return
-    armed = [tier] if tier else [
-        name for name, on in (
-            ("host offload (host_cache_pages)", cfg.host_cache_pages > 0),
-            ("G3 persistence (kv_store_dir)", bool(cfg.kv_store_dir)),
-            ("speculative decoding (spec_mode)", cfg.spec_mode != "off"),
-            ("ring prefill (sp > 1)", cfg.sp > 1),
-            ("tensor parallelism (tp > 1)", cfg.tp > 1),
-        ) if on
-    ]
+    armed = _page_tiers_armed(cfg, tier)
     if armed:
         m = cfg.model
         raise ValueError(
@@ -233,6 +232,46 @@ def refuse_sliding_pool_tiers(cfg: "EngineConfig | None", tier: str | None = Non
             f"the full-attention layers' pool, which {', '.join(armed)} does "
             "not carry: it moves a row's pages through one table. Serve it "
             "without (README.md, models/)"
+        )
+
+
+def refuse_looped_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> None:
+    """The one place a looped model (``ModelConfig.loop_passes`` > 1: the
+    stack of layers run several times a token over one set of weights, a
+    layer slot of the cache for every (pass, layer)) is refused by what
+    takes a page's shape from the pool or writes pages from outside the
+    paged layer loop, in ``refuse_seq_state_tiers``' manner. A page of such
+    a model is ``passes`` times a one-pass model's of the same widths (24
+    MiB at Ouro-2.6B's 192 slots and 16 tokens), and none of these has run
+    or been tested with one:
+
+    - host offload and its prefetch (``engine/offload.py``,
+      ``host_cache_pages``), G3 persistence (``kv/persistent.py``,
+      ``kv_store_dir``): their page records and the gather / inject
+      programs carry every slot of a page, untested at this depth;
+    - speculative decoding (``spec_mode``): a draft span's verify would run
+      every pass over the span, untested;
+    - ring prefill (``sp`` > 1): it runs the layers once, outside the
+      paged layer loop that holds the passes;
+    - tensor parallelism (``tp`` > 1): the loop has not run on a mesh;
+    - ``tier`` = "disaggregated transfer" / "migration"
+      (``disagg/transfer.py``, ``runtime/reclaim.py``): the wire's page
+      would be every slot's, untested.
+
+    With ``tier`` None the engine's own configuration is checked; with a
+    name, that component is refused. Any other model passes, and so does
+    ``cfg`` None."""
+    if cfg is None or cfg.model.loop_passes == 1:
+        return
+    armed = _page_tiers_armed(cfg, tier)
+    if armed:
+        m = cfg.model
+        raise ValueError(
+            f"model_type {m.model_type!r} runs its {m.num_layers} layers "
+            f"{m.loop_passes} times a token and caches a layer slot for every "
+            f"(pass, layer) ({m.kv_layer_slots} a page), which "
+            f"{', '.join(armed)} has not carried: nothing tests it with a "
+            "looped model. Serve it without (README.md, models/)"
         )
 
 

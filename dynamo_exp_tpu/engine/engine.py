@@ -115,6 +115,7 @@ from .config import (
     EngineConfig,
     refuse_block_model_tiers,
     refuse_latent_tiers,
+    refuse_looped_tiers,
     refuse_sliding_pool_tiers,
     refuse_seq_state_tiers,
 )
@@ -300,6 +301,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         refuse_block_model_tiers(cfg)
         refuse_latent_tiers(cfg)
         refuse_sliding_pool_tiers(cfg)
+        refuse_looped_tiers(cfg)
         self.mesh = mesh or build_mesh(tp=cfg.tp, sp=cfg.sp)
         mcfg = cfg.model
 
@@ -383,6 +385,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
             for form in ("full", "sliding")
             if form in mcfg.attn_forms and not mcfg.is_latent
         ])
+        # A looped model's passes over its layers of weights, and the
+        # cache's layer slots they fill (1 pass for every other model).
+        get_telemetry().set_model_loop_info(
+            mcfg.loop_passes, mcfg.num_layers, mcfg.kv_layer_slots
+        )
         # Bytes a held page of each pool takes over its layers, K and V.
         self._page_bytes = {"full": (k_pool.nbytes + v_pool.nbytes) // pages}
         sliding_pool = None
@@ -405,7 +412,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         on_evict = None
         if cfg.host_cache_pages > 0:
             page_shape = (
-                mcfg.num_attn_layers,
+                mcfg.kv_layer_slots,
                 cfg.page_size,
                 mcfg.kv_pool_widths[0],
             )
@@ -546,13 +553,14 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # for failover replay pins the seed request-side instead.
         self._seed_rng = random.Random(seed + 1)
         self._attn_impl, self._attn_interpret = self._resolve_attn()
-        # Attention layers by form, of a model that has sliding ones (what
+        # Attention layers by form, of a model that has sliding ones, and a
+        # looped model's layer-passes as full ones (what
         # ``_count_attn_work`` counts by; empty for any other model).
         forms = mcfg.attn_forms
         self._attn_form_layers = {
-            form: forms.count(form) for form in ("sliding", "full")
-            if form in forms
-        } if "sliding" in forms else {}
+            form: forms.count(form) * mcfg.loop_passes
+            for form in ("sliding", "full") if form in forms
+        } if "sliding" in forms or mcfg.loop_passes > 1 else {}
         if mcfg.num_mamba_layers and self._attn_impl == "pallas" and (
             cfg.page_size % cfg.ragged_q_tile or cfg.prefill_chunk % cfg.ragged_q_tile
         ):
@@ -1512,7 +1520,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
         tel.mla_context_tokens.labels(program).inc(context)
 
     def _count_attn_work(self, positions, steps=None, row_of=None) -> None:
-        """Of a model with sliding layers, the queries a dispatch feeds its
+        """Of a model with sliding layers, and of a looped model (whose
+        every layer-pass counts as a full layer: a token's queries, keys
+        and context are read anew in each pass), the queries a dispatch
+        feeds its
         attention layers, the key positions those may see and the context
         tokens a row's queries see between them, by the layers' form and
         summed over the layers of each (``dynamo_attn_query_tokens_total``,
@@ -1538,7 +1549,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
             )
         live = t >= 0
         seen = t[live] + 1
-        keys = {"full": seen, "sliding": np.minimum(seen, width)}
+        keys = {"full": seen}
+        if width is not None:
+            keys["sliding"] = np.minimum(seen, width)
         context = keys  # a window's step reads anew
         if steps is None:
             rows = np.asarray(row_of)[live]  # dynlint: sync-point(host-list conversion)
@@ -1547,10 +1560,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
             np.maximum.at(last, rows, seen)
             np.minimum.at(first, rows, seen)
             last, first = last[last > 0], first[last > 0]
-            context = {
-                "full": last,
-                "sliding": last - np.maximum(first - width, 0),
-            }
+            context = {"full": last}
+            if width is not None:
+                context["sliding"] = last - np.maximum(first - width, 0)
         tel = get_telemetry()
         for form, count in layers.items():
             tel.attn_query_tokens.labels(form).inc(count * len(seen))
@@ -1715,6 +1727,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
             tel.ssm_scan_tokens.labels(program).inc(live)
         if self.cfg.model.num_linear_layers:
             tel.linear_attn_tokens.labels(program).inc(live)
+        m = self.cfg.model
+        if m.loop_passes > 1:  # the layer applications they take
+            tel.layer_passes.inc(live * m.loop_passes * m.num_layers)
         if self.cfg.model.is_moe:
             tel.moe_rows.labels("live").inc(live)
             tel.moe_rows.labels("pad").inc(pad)
@@ -2144,7 +2159,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         itemsize = 2 if self.cfg.kv_dtype == "bfloat16" else 4
         return (
             sum(m.kv_pool_widths)
-            * m.num_attn_layers
+            * m.kv_layer_slots
             * self.cfg.page_size
             * itemsize
         )
@@ -2860,7 +2875,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self._last_gauge_pub = now
             tel = get_telemetry()
             tel.publish_engine_gauges(self.metrics())
-            if self.kv.sliding is not None:
+            if self.kv.sliding is not None or self.cfg.model.loop_passes > 1:
                 self._publish_pools_by_kind(tel)
             if self.host_pool is not None:
                 # G2 tier occupancy (docs/engine_perf.md "Predictive KV
@@ -2989,17 +3004,20 @@ class TPUEngine(BlockDecode, AsyncEngine):
         ``dynamo_kv_context_tokens``): held bytes over context tokens is
         what a token of context costs, which a cache blind to the layers'
         form pays at every layer; and the most the sliding pool has held at
-        once (``dynamo_kv_sliding_pool_peak_pages``)."""
-        held = {
-            "full": self.kv.active_pages, "sliding": self.kv.sliding.held,
-        }
-        usage = {"full": self.kv.usage, "sliding": self.kv.sliding.usage}
+        once (``dynamo_kv_sliding_pool_peak_pages``). A looped model
+        publishes its one pool's the same way (``pool="full"``): what a
+        token of context costs over every (pass, layer) slot, with the
+        pages' rounding."""
+        held, usage = {"full": self.kv.active_pages}, {"full": self.kv.usage}
+        if self.kv.sliding is not None:
+            held["sliding"] = self.kv.sliding.held
+            usage["sliding"] = self.kv.sliding.usage
+            tel.kv_sliding_pool_peak_pages.set(self.kv.sliding.peak_held)
         for pool, pages in held.items():
             tel.kv_pool_usage.labels(pool).set(usage[pool])
             tel.kv_pool_held_bytes.labels(pool).set(
                 pages * self._page_bytes[pool]
             )
-        tel.kv_sliding_pool_peak_pages.set(self.kv.sliding.peak_held)
         tel.kv_context_tokens.set(sum(
             max(len(s.tokens), s.prefill_sent)
             for s in self.sched.slots if s is not None

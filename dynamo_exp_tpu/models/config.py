@@ -217,6 +217,13 @@ class ModelConfig:
     rotary_dim: int = 0
     value_scale: float = 1.0
     sink_forms: tuple[str, ...] = ()
+    # ouro: the whole stack of layers runs ``loop_passes`` times a token over
+    # ONE set of weights (a looped language model), the final norm closing
+    # every pass and its output entering the next. A token's K and V at a
+    # layer differ from pass to pass, so the cache has a layer slot for every
+    # (pass, layer) (``kv_layer_slots``). 1: every layer once, every other
+    # family.
+    loop_passes: int = 1
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -322,8 +329,19 @@ class ModelConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        """Layers that write K and V: the depth of the paged pools."""
+        """Layers that write K and V: the depth of the attention weights'
+        stacks (and of the paged pools, where every layer runs once:
+        ``kv_layer_slots``)."""
         return self.op_kinds.count("attn")
+
+    @property
+    def kv_layer_slots(self) -> int:
+        """Layer slots of the paged pools, the ONE place that says how many
+        a page has: a slot for every (pass, attention layer), pass ``t``'s
+        layer ``l`` at ``t * num_attn_layers + l``
+        (``models/llama.py::init_kv_cache``, ``_scan_paged_layers``). What
+        turns "attention layers" into a page's bytes reads this."""
+        return self.loop_passes * self.num_attn_layers
 
     @property
     def num_conv_layers(self) -> int:
@@ -493,6 +511,8 @@ class ModelConfig:
             return cls._from_exaone_moe(cfg)
         if model_type == "mimo_v2_flash":
             return cls._from_mimo_v2_flash(cfg)
+        if model_type == "ouro":
+            return cls._from_ouro(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -1065,6 +1085,62 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_ouro(cls, cfg: dict) -> "ModelConfig":
+        """ByteDance's ``ouro`` (Ouro-2.6B, a looped language model): a
+        dense stack of full-attention layers, four norms a layer in the
+        sandwich placement (``post_norms``), run ``total_ut_steps`` times a
+        token over one set of weights (``loop_passes``), the final norm
+        closing every pass. Its exit gate (``early_exit_gate``: one logit a
+        token after a pass's norm) is held in the parameter tree and not
+        evaluated: at the published ``early_exit_threshold`` 1 no token
+        leaves before the last pass. A token that left early would have no
+        K/V for the passes it skipped, which nothing here fills, so a
+        threshold below 1 is refused, as is every key that nothing here
+        computes for this family."""
+        name = "ouro"
+        n_layers = cfg["num_hidden_layers"]
+        passes = cfg.get("total_ut_steps", 1)
+        if not isinstance(passes, int) or passes < 1:
+            raise ValueError(f"{name} needs total_ut_steps >= 1, got {passes!r}")
+        if float(cfg.get("early_exit_threshold", 1.0)) < 1.0:
+            raise ValueError(
+                f"{name} with early_exit_threshold < 1 is not supported: a token "
+                "that leaves at an early pass has no K/V for the passes it "
+                "skipped, and nothing here fills them"
+            )
+        if cfg.get("use_sliding_window", False):
+            raise ValueError(f"{name} with use_sliding_window=true is not supported")
+        if cfg.get("sliding_window") is not None:
+            raise ValueError(f"{name} with a sliding_window is not supported")
+        layer_types = tuple(cfg.get("layer_types") or ("full_attention",) * n_layers)
+        if len(layer_types) != n_layers or set(layer_types) != {"full_attention"}:
+            raise ValueError(
+                f"{name} layer_types must name {n_layers} layers as "
+                f"'full_attention', got {layer_types!r}"
+            )
+        if cfg.get("rope_scaling"):
+            raise ValueError(f"{name} with a rope_scaling is not supported")
+        if str(cfg.get("hidden_act", "silu")) != "silu":
+            raise ValueError(f"{name} with hidden_act other than silu is not supported")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n_layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
+            head_dim=cfg.get("head_dim"),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=cfg.get("max_position_embeddings", 65536),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            post_norms=True,
+            loop_passes=passes,
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type=name,
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -1144,7 +1220,7 @@ KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
-    "mimo_v2_flash",
+    "mimo_v2_flash", "ouro",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -1567,6 +1643,24 @@ TINY_MIMO = ModelConfig(  # mimo_v2_flash family shape: full and sliding
     model_type="mimo_v2_flash",
 )
 
+TINY_OURO = ModelConfig(  # ouro family shape: 4 layers run 3 times over one
+    # set of weights (passes != layers != 4 heads, so a transposed slot
+    # shows), as many KV heads as heads, four norms a layer, an exit gate held
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=4,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=16,
+    rope_theta=1000000.0,
+    max_position_embeddings=512,
+    rms_norm_eps=1e-6,
+    post_norms=True,
+    loop_passes=3,
+    model_type="ouro",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -1593,6 +1687,7 @@ PRESETS = {
     "tiny-glm-dsa": TINY_GLM_DSA,
     "tiny-exaone-moe": TINY_EXAONE_MOE,
     "tiny-mimo": TINY_MIMO,
+    "tiny-ouro": TINY_OURO,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

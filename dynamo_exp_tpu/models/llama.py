@@ -106,7 +106,14 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     form: ``wk`` / ``wv`` over the full layers, ``swa_wk`` / ``swa_wv``
     over the sliding ones (``wq`` and ``wo`` are one shape and stay over
     all attention layers). ``sink`` (float32 ``[layers of the forms in
-    sink_forms, H]``): the learned logit a query head of those layers."""
+    sink_forms, H]``): the learned logit a query head of those layers.
+
+    A looped model (``ModelConfig.loop_passes`` > 1: ouro) has ``L`` layers
+    of weights whatever its passes, and its exit gate at the top level:
+    ``exit_gate_w`` ``[D]`` and ``exit_gate_b`` ``[1]``, in the model's dtype
+    as the checkpoint stores them (a linear map ``D -> 1`` with a bias,
+    after a pass's norm). The tree holds them and no forward evaluates them
+    while no token may leave early (``ModelConfig._from_ouro``)."""
     dt = _dtype(cfg)
     hd, dv = cfg.head_dim_, cfg.value_head_dim_
     L, D, H, Hkv, I, V = (
@@ -215,6 +222,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = init(ks[8], (D, V), D)
+    if cfg.loop_passes > 1:  # ouro: the exit gate, held and not evaluated
+        params["exit_gate_w"] = init(jax.random.fold_in(rng, 7), (D,), D)
+        params["exit_gate_b"] = jnp.zeros((1,), dt)
     return params
 
 
@@ -452,6 +462,9 @@ def param_shardings(
     }
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P(None, tp_axis)
+    if cfg.loop_passes > 1:
+        specs["exit_gate_w"] = P(None)
+        specs["exit_gate_b"] = P(None)
     return specs
 
 
@@ -469,8 +482,9 @@ def init_kv_cache(
     shardings=(None, None), sliding_pages: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Allocate the paged KV pools: each [L, num_pages, ps, Hkv*D], L
-    the layers that write K and V (``cfg.num_attn_layers``: all of them
-    but for a model with layers of other kinds).
+    the cache's layer slots (``cfg.kv_layer_slots``: the layers that write
+    K and V, all of them but for a model with layers of other kinds, times
+    the passes of a looped model, whose every (pass, layer) has a slot).
 
     (kv head, head_dim) live collapsed in the trailing dim: TPU tiling
     pads the last dim to 128 lanes, so a bare D=64 axis would double
@@ -511,7 +525,7 @@ def init_kv_cache(
         )
 
     if not cfg.kv_by_kind:
-        return pools(cfg.num_attn_layers, num_pages)
+        return pools(cfg.kv_layer_slots, num_pages)
     n_sliding = cfg.num_sliding_layers
     full = pools(cfg.num_attn_layers - n_sliding, num_pages)
     sliding = pools(
@@ -1454,6 +1468,32 @@ _ROUTED_PARAMS = (
 )
 
 
+def _loop_passes(params, cfg, carry, run_layers):
+    """A looped model's passes (``ModelConfig.loop_passes``: ouro), the ONE
+    place the stack is run more than once: a ``lax.fori_loop`` over the
+    pass ``t`` round ``run_layers`` (the runs' ``lax.scan``s), so a program
+    holds one copy of the layer body whatever the passes. Pass ``t`` writes
+    and reads the pools' layer slots from ``t * num_attn_layers`` on (a
+    token's K and V differ in every pass), over the same weights and at the
+    same positions. The final norm closes every pass, its output the next
+    pass's input: between passes here (``loop_norm``), after the last by
+    ``_final_logits`` as for every model. The exit gate is not evaluated
+    (``init_params``)."""
+    T, La = cfg.loop_passes, cfg.num_attn_layers
+
+    def close(x):
+        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
+
+    def one_pass(t, carry):
+        with jax.named_scope("loop_pass"):
+            x, *rest = run_layers(carry, t * La)
+        with jax.named_scope("loop_norm"):
+            x = jax.lax.cond(t + 1 < T, close, lambda x: x, x)
+        return (x, *rest)
+
+    return jax.lax.fori_loop(0, T, one_pass, carry)
+
+
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
     live, interpret=False, seq_state=None, state_io=None, scan_form=ScanForm(),
@@ -1523,7 +1563,14 @@ def _scan_paged_layers(
     A run that is not the whole of a stack closes over the stack and
     indexes it by the layer's ordinal, which is what ``scan`` does with
     ``xs``: a static slice handed over as ``xs`` would be a copy of the
-    run's parameters on every forward."""
+    run's parameters on every forward.
+
+    A looped model (``ModelConfig.loop_passes`` > 1) walks the runs once a
+    pass (:func:`_loop_passes`): the pools have ``passes * L_attn`` layer
+    slots, and pass ``t``'s attention layer ``l`` writes and reads slot
+    ``t * L_attn + l`` (the scatter's ``[l, page, offset]`` and the table
+    "moved to layer l's range" both take the slot) while its weights stay
+    layer ``l``'s."""
     L = cfg.num_layers
     by_kind = cfg.kv_by_kind
     forms = cfg.attn_forms
@@ -1546,178 +1593,192 @@ def _scan_paged_layers(
     held_picks = jnp.zeros((2,), jnp.int32)
     kept_runs = []
 
-    for op, ff, first, n, op0, ff0 in cfg.layer_runs:
-        # name in the body -> (stack, the run's first index in it)
-        source = {k: (layers[k], first) for k in _EVERY_LAYER if k in layers}
-        source.update(
-            (k, (layers[k], op0)) for k in _OP_PARAMS[op] if k in layers
-        )
-        if ff == "dense":
+    def run_layers(carry, slot0=None):
+        # every run of layers once; ``slot0`` (a looped model's pass: traced)
+        # is the pass's first layer slot in the pools
+        nonlocal held_picks
+        for op, ff, first, n, op0, ff0 in cfg.layer_runs:
+            # name in the body -> (stack, the run's first index in it)
+            source = {k: (layers[k], first) for k in _EVERY_LAYER if k in layers}
             source.update(
-                (k, (layers[d], ff0)) for k, d in zip(EXPERT_STACKS, dense_names)
+                (k, (layers[k], op0)) for k in _OP_PARAMS[op] if k in layers
             )
-        else:
-            source.update(
-                (k, (layers[k], ff0)) for k in _ROUTED_PARAMS if k in layers
-            )
-        # Expert matrices: whole, with the layer to read, where the matmuls
-        # read in place and wherever the run is not the whole stack
-        # (``moe_ffn`` then slices the layer it multiplies); else ``xs``.
-        stacks = {}
-        if ff == "routed":
-            whole = layers["w_gate"].shape[0] == n
-            if in_place or not whole:
-                stacks = {k: layers[k] for k in EXPERT_STACKS}
+            if ff == "dense":
+                source.update(
+                    (k, (layers[d], ff0)) for k, d in zip(EXPERT_STACKS, dense_names)
+                )
             else:
-                source.update((k, (layers[k], ff0)) for k in EXPERT_STACKS)
-        # What a model whose forms differ stacks by form: the K and V
-        # projections at the run's ordinal among the layers of its form,
-        # the sink logits among the layers that have them.
-        if op == "attn" and cfg.kv_heads_by_form:
-            stack = "swa_" if forms[first] == "sliding" else ""
-            nth = forms[:first].count(forms[first])
-            source.update((k, (layers[stack + k], nth)) for k in ("wk", "wv"))
-        if forms[first] in cfg.sink_forms:
-            source["sink"] = (
-                layers["sink"], sum(f in cfg.sink_forms for f in forms[:first]),
+                source.update(
+                    (k, (layers[k], ff0)) for k in _ROUTED_PARAMS if k in layers
+                )
+            # Expert matrices: whole, with the layer to read, where the matmuls
+            # read in place and wherever the run is not the whole stack
+            # (``moe_ffn`` then slices the layer it multiplies); else ``xs``.
+            stacks = {}
+            if ff == "routed":
+                whole = layers["w_gate"].shape[0] == n
+                if in_place or not whole:
+                    stacks = {k: layers[k] for k in EXPERT_STACKS}
+                else:
+                    source.update((k, (layers[k], ff0)) for k in EXPERT_STACKS)
+            # What a model whose forms differ stacks by form: the K and V
+            # projections at the run's ordinal among the layers of its form,
+            # the sink logits among the layers that have them.
+            if op == "attn" and cfg.kv_heads_by_form:
+                stack = "swa_" if forms[first] == "sliding" else ""
+                nth = forms[:first].count(forms[first])
+                source.update((k, (layers[stack + k], nth)) for k in ("wk", "wv"))
+            if forms[first] in cfg.sink_forms:
+                source["sink"] = (
+                    layers["sink"], sum(f in cfg.sink_forms for f in forms[:first]),
+                )
+            xs = {k: a for k, (a, at) in source.items() if a.shape[0] == n}
+            closed = {k: v for k, v in source.items() if k not in xs}
+            # The run's form of attention: which pool of a cache by kind, the
+            # run's first layer's ordinal in it, and the width the kernel takes
+            # as a static argument (None: a full layer, or a width that rides
+            # ``xs`` to the gather).
+            form = forms[first]
+            kind = int(by_kind and form == "sliding")
+            pool0 = forms[:first].count(form) if by_kind else op0
+            heads = cfg.kv_heads_of(form)
+            width = (
+                cfg.sliding_window
+                if form == "sliding" and cfg.attn_kernel_form else None
             )
-        xs = {k: a for k, (a, at) in source.items() if a.shape[0] == n}
-        closed = {k: v for k, v in source.items() if k not in xs}
-        # The run's form of attention: which pool of a cache by kind, the
-        # run's first layer's ordinal in it, and the width the kernel takes
-        # as a static argument (None: a full layer, or a width that rides
-        # ``xs`` to the gather).
-        form = forms[first]
-        kind = int(by_kind and form == "sliding")
-        pool0 = forms[:first].count(form) if by_kind else op0
-        heads = cfg.kv_heads_of(form)
-        width = (
-            cfg.sliding_window
-            if form == "sliding" and cfg.attn_kernel_form else None
-        )
-        rope = not (cfg.rope_sliding_only and form == "full")
+            rope = not (cfg.rope_sliding_only and form == "full")
 
-        def layer(carry, layer_in, op=op, op0=op0, ff0=ff0, stacks=stacks,
-                  closed=closed, kind=kind, pool0=pool0, width=width,
-                  rope=rope, heads=heads):
-            x, k_all, v_all, state = carry
-            k_pool, v_pool = (
-                (k_all[kind], v_all[kind]) if by_kind else (k_all, v_all)
-            )
-            num_pages = k_pool.shape[1]
-            flat = (k_pool.shape[0] * num_pages, *k_pool.shape[2:])
-            table, write_at = tables[kind], writes[kind]
-            lp, win_l, invf_l, i = layer_in
-            lp = {**lp, **stacks}
-            taken = []  # ``take_kept``: the layer's selection, as ``read`` hands it out
-            for name, (stack, at) in closed.items():
-                lp[name] = jax.lax.dynamic_index_in_dim(
-                    stack, at + i, 0, keepdims=False
+            def layer(carry, layer_in, op=op, op0=op0, ff0=ff0, stacks=stacks,
+                      closed=closed, kind=kind, pool0=pool0, width=width,
+                      rope=rope, heads=heads):
+                x, k_all, v_all, state = carry
+                k_pool, v_pool = (
+                    (k_all[kind], v_all[kind]) if by_kind else (k_all, v_all)
                 )
-            # ordinal among layers of this operator (of this form, where the
-            # cache is by kind): the layer's place in its pool
-            l = i + pool0 if pool0 else i
-            if op == "conv":
-                x, state = _conv_half(x, lp, cfg, eps, state, l, state_io)
-            elif op == "mamba":
-                x, state = _mamba_half(
-                    x, lp, cfg, eps, state, l, state_io, scan_form
-                )
-            elif op == "linear":
-                x, state = _linear_half(
-                    x, lp, cfg, eps, state, l, state_io, scan_form
-                )
-            elif cfg.is_latent:
-                # One pool: a token's row, its values among its lanes.
-                # ``v_pool`` (no lanes) rides the carry untouched.
-
-                def write(row, index_row):
-                    # ``index_row`` (a model with an indexer): the second
-                    # pool's row, written at the same page and offset
-                    with jax.named_scope("kv_write"):
-                        kp, vp = write_kv_pages(
-                            k_pool, None if index_row is None else v_pool,
-                            row.reshape(-1, row.shape[-1]),
-                            None if index_row is None
-                            else index_row.reshape(-1, index_row.shape[-1]),
-                            *write_at, layer=l,
-                        )
-                    return kp, v_pool if index_row is None else vp
-
-                def read_latent(q, kp, absorb, expand, w_ukv, index):
-                    selector = {}
-                    if index is not None:
-                        q_i, w_i, ip = index
-                        selector["index"] = (
-                            q_i, w_i, ip.reshape(-1, *ip.shape[2:])
-                        )
-                        if take_kept:
-                            selector["taken"] = taken
-                    return read(
-                        q, kp.reshape(flat), None, table + l * num_pages,
-                        win_l, wrap=(absorb, expand, w_ukv), **selector,
+                num_pages = k_pool.shape[1]
+                flat = (k_pool.shape[0] * num_pages, *k_pool.shape[2:])
+                table, write_at = tables[kind], writes[kind]
+                lp, win_l, invf_l, i = layer_in
+                lp = {**lp, **stacks}
+                taken = []  # ``take_kept``: the layer's selection, as ``read`` hands it out
+                for name, (stack, at) in closed.items():
+                    lp[name] = jax.lax.dynamic_index_in_dim(
+                        stack, at + i, 0, keepdims=False
                     )
+                # ordinal among layers of this operator (of this form, where the
+                # cache is by kind): the layer's place in its pool
+                l = i + pool0 if pool0 else i
+                if slot0 is not None:  # a looped model: this pass's slots
+                    l = l + slot0
+                if op == "conv":
+                    x, state = _conv_half(x, lp, cfg, eps, state, l, state_io)
+                elif op == "mamba":
+                    x, state = _mamba_half(
+                        x, lp, cfg, eps, state, l, state_io, scan_form
+                    )
+                elif op == "linear":
+                    x, state = _linear_half(
+                        x, lp, cfg, eps, state, l, state_io, scan_form
+                    )
+                elif cfg.is_latent:
+                    # One pool: a token's row, its values among its lanes.
+                    # ``v_pool`` (no lanes) rides the carry untouched.
 
-                x, (k_pool, v_pool) = _mla_half(
-                    x, lp, cfg, invf_l, rope_pos, eps, write, read_latent, red
-                )
-            else:
-
-                def attend(q, k, v):
-                    n = k.shape[0] * k.shape[1]  # this dispatch's new tokens
-                    with jax.named_scope("kv_write"):
-                        kp, vp = write_kv_pages(
-                            k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
-                            *write_at, layer=l,
-                        )
-                    # the run's own KV heads, and its layer's sink logits if any
-                    by_form = {"heads": heads, "sink": lp.get("sink")}
-                    v_flat = (flat[0], flat[1], vp.shape[-1])
-                    with jax.named_scope("attend"):
-                        if width is None:
-                            attn = read(
-                                q, kp.reshape(flat), vp.reshape(v_flat),
-                                table + l * num_pages, win_l, **by_form,
+                    def write(row, index_row):
+                        # ``index_row`` (a model with an indexer): the second
+                        # pool's row, written at the same page and offset
+                        with jax.named_scope("kv_write"):
+                            kp, vp = write_kv_pages(
+                                k_pool, None if index_row is None else v_pool,
+                                row.reshape(-1, row.shape[-1]),
+                                None if index_row is None
+                                else index_row.reshape(-1, index_row.shape[-1]),
+                                *write_at, layer=l,
                             )
-                        else:
-                            with jax.named_scope("swa_attn"):
+                        return kp, v_pool if index_row is None else vp
+
+                    def read_latent(q, kp, absorb, expand, w_ukv, index):
+                        selector = {}
+                        if index is not None:
+                            q_i, w_i, ip = index
+                            selector["index"] = (
+                                q_i, w_i, ip.reshape(-1, *ip.shape[2:])
+                            )
+                            if take_kept:
+                                selector["taken"] = taken
+                        return read(
+                            q, kp.reshape(flat), None, table + l * num_pages,
+                            win_l, wrap=(absorb, expand, w_ukv), **selector,
+                        )
+
+                    x, (k_pool, v_pool) = _mla_half(
+                        x, lp, cfg, invf_l, rope_pos, eps, write, read_latent, red
+                    )
+                else:
+
+                    def attend(q, k, v):
+                        n = k.shape[0] * k.shape[1]  # this dispatch's new tokens
+                        with jax.named_scope("kv_write"):
+                            kp, vp = write_kv_pages(
+                                k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
+                                *write_at, layer=l,
+                            )
+                        # the run's own KV heads, and its layer's sink logits if any
+                        by_form = {"heads": heads, "sink": lp.get("sink")}
+                        v_flat = (flat[0], flat[1], vp.shape[-1])
+                        with jax.named_scope("attend"):
+                            if width is None:
                                 attn = read(
                                     q, kp.reshape(flat), vp.reshape(v_flat),
-                                    table + l * num_pages, win_l,
-                                    sliding=width, **by_form,
+                                    table + l * num_pages, win_l, **by_form,
                                 )
-                    return attn, (kp, vp)
+                            else:
+                                with jax.named_scope("swa_attn"):
+                                    attn = read(
+                                        q, kp.reshape(flat), vp.reshape(v_flat),
+                                        table + l * num_pages, win_l,
+                                        sliding=width, **by_form,
+                                    )
+                        return attn, (kp, vp)
 
-                x, (k_pool, v_pool) = _attn_half(
-                    x, lp, cfg, invf_l, rope_pos, eps, attend, red, rope
+                    x, (k_pool, v_pool) = _attn_half(
+                        x, lp, cfg, invf_l, rope_pos, eps, attend, red, rope
+                    )
+                tally = [] if count_picks else None
+                x = _ffn_half(
+                    x, lp, cfg, eps, red, mesh, live,
+                    (i + ff0 if ff0 else i) if stacks else None, interpret,
+                    experts_any_rows, tally,
                 )
-            tally = [] if count_picks else None
-            x = _ffn_half(
-                x, lp, cfg, eps, red, mesh, live,
-                (i + ff0 if ff0 else i) if stacks else None, interpret,
-                experts_any_rows, tally,
-            )
-            picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
-            if by_kind:  # the other form's pool rides on untouched
-                k_pool = (*k_all[:kind], k_pool, *k_all[kind + 1:])
-                v_pool = (*v_all[:kind], v_pool, *v_all[kind + 1:])
-            return (x, k_pool, v_pool, state), (picks, taken[0] if taken else None)
+                picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
+                if by_kind:  # the other form's pool rides on untouched
+                    k_pool = (*k_all[:kind], k_pool, *k_all[kind + 1:])
+                    v_pool = (*v_all[:kind], v_pool, *v_all[kind + 1:])
+                return (x, k_pool, v_pool, state), (picks, taken[0] if taken else None)
 
-        whole_model = n == L
-        carry, (picks, kept) = jax.lax.scan(
-            layer,
-            carry,
-            (
-                xs,
-                win_arr if whole_model else win_arr[first : first + n],
-                invf_arr if whole_model else invf_arr[first : first + n],
-                jnp.arange(n, dtype=jnp.int32),
-            ),
-        )
-        if count_picks:
-            held_picks = held_picks + jnp.sum(picks, axis=0)
-        if kept is not None:
-            kept_runs.append(kept)
+            whole_model = n == L
+            carry, (picks, kept) = jax.lax.scan(
+                layer,
+                carry,
+                (
+                    xs,
+                    win_arr if whole_model else win_arr[first : first + n],
+                    invf_arr if whole_model else invf_arr[first : first + n],
+                    jnp.arange(n, dtype=jnp.int32),
+                ),
+            )
+            if count_picks:
+                held_picks = held_picks + jnp.sum(picks, axis=0)
+            if kept is not None:
+                kept_runs.append(kept)
+        return carry
+
+    if cfg.loop_passes == 1:
+        carry = run_layers(carry)
+    else:
+        if count_picks or take_kept:
+            raise ValueError("a looped model counts no picks and keeps no selection")
+        carry = _loop_passes(params, cfg, carry, run_layers)
     return (
         *carry, held_picks if count_picks else None,
         jnp.concatenate(kept_runs) if kept_runs else None,

@@ -217,7 +217,7 @@ SUPPORTED_MODEL_TYPES = (
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
-    "mimo_v2_flash",
+    "mimo_v2_flash", "ouro",
 )
 
 
@@ -492,6 +492,47 @@ def _mimo_v2_flash_params(cfg: ModelConfig, get, linear, has=None) -> dict:
     }
 
 
+def _ouro_params(cfg: ModelConfig, get, linear) -> dict:
+    """``ouro`` tensor names (a looped language model; the family's
+    modeling file's, ``benchmark/configs/ouro-2.6b.json`` lists them as
+    assumed) into ``models/llama.py::init_params``' stacks: the four norms
+    of the sandwich placement ``input_layernorm`` (before attention),
+    ``input_layernorm_2`` (on attention's output),
+    ``post_attention_layernorm`` (before the feed-forward) and
+    ``post_attention_layernorm_2`` (on its output) over every layer;
+    ``self_attn.{q,k,v,o}_proj`` and ``mlp.{gate,up,down}_proj``, no bias;
+    ``model.norm`` (the norm that closes every pass) and
+    ``model.early_exit_gate`` (``[1, D]`` and its bias ``[1]``: held, not
+    evaluated). ONE set of layers whatever ``total_ut_steps``."""
+    norms = (
+        ("attn_norm", "input_layernorm"), ("post_attn_norm", "input_layernorm_2"),
+        ("mlp_norm", "post_attention_layernorm"),
+        ("post_ffn_norm", "post_attention_layernorm_2"),
+    )
+    mats = (
+        ("wq", "self_attn.q_proj"), ("wk", "self_attn.k_proj"),
+        ("wv", "self_attn.v_proj"), ("wo", "self_attn.o_proj"),
+        ("w_gate", "mlp.gate_proj"), ("w_up", "mlp.up_proj"), ("w_down", "mlp.down_proj"),
+    )
+    layers: dict[str, list] = {k: [] for k, _ in (*norms, *mats)}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        for key, name in norms:
+            layers[key].append(get(f"{p}{name}.weight"))
+        for key, name in mats:
+            layers[key].append(linear(f"{p}{name}.weight"))
+    params = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items()},
+        "final_norm": get("model.norm.weight"),
+        "exit_gate_w": get("model.early_exit_gate.weight").reshape(-1),
+        "exit_gate_b": get("model.early_exit_gate.bias").reshape(1),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = linear("lm_head.weight")
+    return params
+
+
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
     """Load a HF checkpoint directory (llama/mistral/qwen2/mixtral
     families) into the stacked param pytree."""
@@ -528,6 +569,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         "glm_moe_dsa": _glm_moe_dsa_params,
         "exaone_moe": functools.partial(_exaone_moe_params, has=index.__contains__),
         "mimo_v2_flash": functools.partial(_mimo_v2_flash_params, has=index.__contains__),
+        "ouro": _ouro_params,
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

@@ -187,6 +187,15 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             routed_scaling_factor=cfg.routed_scaling_factor,
             scoring_func="sigmoid", topk_method="noaux_tc", n_group=1, topk_group=1,
         )
+    elif cfg.model_type == "ouro":  # the family's own keys
+        hf.update(
+            architectures=["OuroForCausalLM"], hidden_act="silu",
+            head_dim=cfg.head_dim_, total_ut_steps=cfg.loop_passes,
+            early_exit_threshold=1.0, rope_scaling=None,
+            layer_types=["full_attention"] * cfg.num_layers,
+            sliding_window=None, use_sliding_window=False,
+            max_window_layers=cfg.num_layers,
+        )
     elif cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -196,7 +205,7 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
             "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe, "
-            "glm_moe_dsa, exaone_moe and mimo_v2_flash only"
+            "glm_moe_dsa, exaone_moe, mimo_v2_flash and ouro only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

@@ -69,13 +69,15 @@ def _refuse_seq_state(engine) -> None:
     """Migration ships pages alone. Imported here: this module is host
     code and the engine's package brings JAX."""
     from ..engine.config import (
-        refuse_block_model_tiers, refuse_latent_tiers, refuse_sliding_pool_tiers, refuse_seq_state_tiers,
+        refuse_block_model_tiers, refuse_latent_tiers, refuse_looped_tiers,
+        refuse_sliding_pool_tiers, refuse_seq_state_tiers,
     )
 
     refuse_seq_state_tiers(getattr(engine, "cfg", None), "migration")
     refuse_block_model_tiers(getattr(engine, "cfg", None), "migration")
     refuse_latent_tiers(getattr(engine, "cfg", None), "migration")
     refuse_sliding_pool_tiers(getattr(engine, "cfg", None), "migration")
+    refuse_looped_tiers(getattr(engine, "cfg", None), "migration")
 
 
 def _env_margin(default: float = DEFAULT_SAFETY_MARGIN_S) -> float:

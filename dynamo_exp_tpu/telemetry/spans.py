@@ -1080,8 +1080,8 @@ class Telemetry:
         self.attn_query_tokens = Counter(
             "dynamo_attn_query_tokens_total",
             "Queries dispatches fed the attention layers of a model with "
-            "sliding layers, by the layers' form and summed over the "
-            "layers of each",
+            "sliding layers (or of a looped model, every layer-pass a full "
+            "layer), by the layers' form and summed over the layers of each",
             ["kind"],  # sliding | full
             registry=self.registry,
         )
@@ -1102,6 +1102,25 @@ class Telemetry:
             "layers' form and summed over the layers of each: what the "
             "attention has to read at the least",
             ["kind"],
+            registry=self.registry,
+        )
+        self.model_loop_info = Gauge(
+            "dynamo_model_loop_info",
+            "Constant-1 shape of the model's loop over its layers: passes a "
+            "token makes through the stack (1 for every model but a looped "
+            "one), the layers of weights a pass runs, and kv_layers, the "
+            "layer slots of the KV pools (passes x attention layers: a slot "
+            "for every (pass, layer))",
+            ["passes", "weight_layers", "kv_layers"],
+            registry=self.registry,
+        )
+        self.layer_passes = Counter(
+            "dynamo_layer_passes_total",
+            "Layer applications a looped model's live query tokens took "
+            "(tokens x passes x layers), counted on the host from the "
+            "dispatch's plan beside dynamo_ragged_slots_total{kind=live}, "
+            "the tokens fed: their ratio is the layer-passes a token. No "
+            "sample moves for a model whose layers run once",
             registry=self.registry,
         )
         self.jax_compile_events = Counter(
@@ -1290,6 +1309,13 @@ class Telemetry:
         self.kv_sliding_pool_info.labels(
             str(layers), str(pages), str(page_size), str(width), dtype,
             str(sliding_width), str(page_bytes),
+        ).set(1)
+
+    def set_model_loop_info(self, passes: int, weight_layers: int, kv_layers: int) -> None:
+        """Publish the constant-1 ``dynamo_model_loop_info`` sample."""
+        self.model_loop_info.clear()
+        self.model_loop_info.labels(
+            str(passes), str(weight_layers), str(kv_layers)
         ).set(1)
 
     def set_attn_form_info(self, forms) -> None:
