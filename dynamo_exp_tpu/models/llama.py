@@ -1084,14 +1084,17 @@ def _selective_scan(x, dt, a, b, c, d, pool, layer, io: SeqStateIO, form: ScanFo
     )
 
 
-def _gated_delta(q, k, v, g, beta, pool, layer, io: SeqStateIO, form: ScanForm):
+def _gated_delta(qkv, g, beta, pool, layer, io: SeqStateIO, form: ScanForm):
     """The gated delta rule over this forward's stream
-    (``ops/gated_delta.py`` has the four forms). Returns (o [N, H * dv]
-    float32, the states' pool). One token a row takes the in-place kernel
-    wherever the stream takes the chunk kernel (``form.kernel``: the chip),
-    the plain form otherwise."""
+    (``ops/gated_delta.py`` has the four forms), ``qkv`` [N, H * (2 dk + dv)]
+    as the convolution leaves it. Returns (o [N, H * dv] float32, the
+    states' pool). The chunk kernel brings q and k to length 1 itself; the
+    other forms take them from ``unit_heads``, over the slots they serve.
+    One token a row takes the in-place kernel wherever the stream takes the
+    chunk kernel (``form.kernel``: the chip), the plain form otherwise."""
     from ..ops import gated_delta as gd
 
+    heads, dk = g.shape[-1], pool.shape[2]
     one_step = (
         functools.partial(gd.gated_delta_step_kernel, interpret=form.interpret)
         if form.kernel else gd.gated_delta_step
@@ -1099,15 +1102,15 @@ def _gated_delta(q, k, v, g, beta, pool, layer, io: SeqStateIO, form: ScanForm):
     return _stream_recurrence(
         pool, io, form,
         lambda pool: gd.gated_delta_ref(
-            q, k, v, g, beta, pool, layer, io.slots, io.row_of, io.positions,
-            io.src, io.dst,
+            *gd.unit_heads(qkv, heads, dk), g, beta, pool, layer, io.slots,
+            io.row_of, io.positions, io.src, io.dst,
         ),
         lambda pool, part, slots, pos, dst: one_step(
-            q[part], k[part], v[part], g[part], beta[part], pool, layer, slots,
-            pos, dst,
+            *gd.unit_heads(qkv[part], heads, dk), g[part], beta[part], pool, layer,
+            slots, pos, dst,
         ),
         lambda pool, pos, dst: gd.gated_delta_kernel(
-            q, k, v, g, beta, pool, layer, io.slots, io.row_of, pos, io.src, dst,
+            qkv, g, beta, pool, layer, io.slots, io.row_of, pos, io.src, dst,
             interpret=form.interpret,
         ),
     )
@@ -1133,7 +1136,10 @@ def gated_delta_mixer(u, lp, cfg, state: "LinearState", layer, io: SeqStateIO,
     The matrix products run in the model's dtype with float32 results
     where the decay and the step read them; everything between the
     convolution and the output's gate is float32, the state from end to
-    end."""
+    end. WHO BRINGS q AND k TO LENGTH 1: the recurrence does
+    (``_gated_delta`` takes ``c`` whole): the chunk kernel a head's rows
+    in VMEM, the other forms through ``ops/gated_delta.py::unit_heads``
+    over the slots they serve, both ``x rsqrt(sum x^2 + 1e-6)``."""
     f32 = jnp.float32
     H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     n = u.shape[0]
@@ -1156,14 +1162,7 @@ def gated_delta_mixer(u, lp, cfg, state: "LinearState", layer, io: SeqStateIO,
     g = -jnp.exp(lp["gdn_A_log"]) * jax.nn.softplus(ab[:, :H] + lp["gdn_dt_b"])
     beta = jax.nn.sigmoid(ab[:, H:]) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
     with jax.named_scope("gdn_scan"):
-        def unit(x):  # each head's vector to length 1
-            x = x.reshape(n, H, dk)
-            return (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)).reshape(n, H * dk)
-
-        o, pool = _gated_delta(
-            unit(c[:, : H * dk]) * dk**-0.5, unit(c[:, H * dk : 2 * H * dk]),
-            c[:, 2 * H * dk :], g, beta, state.state, layer, io, form,
-        )
+        o, pool = _gated_delta(c, g, beta, state.state, layer, io, form)
     o = rms_norm(o.reshape(n, H, dv), lp["gdn_norm"].astype(f32), cfg.rms_norm_eps)
     gate = jax.nn.silu((u @ lp["gdn_gate"]).astype(f32))
     out = (o.reshape(n, H * dv) * gate).astype(u.dtype) @ lp["gdn_out"]

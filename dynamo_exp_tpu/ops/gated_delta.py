@@ -24,7 +24,10 @@ Four forms of one recurrence (``ops/selective_scan.py`` has three of them):
   row's span in chunks of ``CHUNK`` tokens and a chunk in the matrix form
   (below), its products on the MXU, with the whole ``[dk, H * dv]`` state
   in VMEM across the span's chunks, read from and stored to the pool in
-  place by DMA.
+  place by DMA. It alone takes ``q | k | v`` in one array as the mixer's
+  convolution leaves it, q and k of no length in particular, and brings
+  a head's rows to length 1 where it holds them; the other three take q
+  and k ready (:func:`unit_heads`).
 - :func:`gated_delta_step`: one token a row (a decode window's step, the
   packed tail of a mixed stream): gather the rows' states, one step of
   the recurrence, scatter. Plain ``jax.numpy``, no matrix product. The
@@ -52,16 +55,37 @@ ever as differences of these sums, never a quotient of products),
     O  = diag(exp(G)) Q S_0 + lower(D * Q K^T) V'
     S_C = exp(G_C) S_0 + (diag(exp(G_C - G)) K)^T V'
 
-``T`` is forward substitution in blocks (:func:`unit_lower_inverse`): the
-inverse of ``[[I + A11, 0], [A21, I + A22]]`` is ``[[T11, 0], [-T22 A21
-T11, T22]]``, from blocks of 2 doubling to ``C``, two products a level and
-no power series (``A``'s entries reach 2 where neighbouring keys agree and
-its powers cancel catastrophically). Everything that reaches ``V'`` or the
-state is a float32 product at full precision (``STATE_PRECISION``): one
-bfloat16 pass there costs the state what rounding it to bfloat16 a chunk
-would. The state after token ``m`` inside a chunk is the last line with
-``G_m`` for ``G_C`` and the rows after ``m`` left out, which is how a
-write that does not fall on a chunk's end is honoured.
+``T`` is forward substitution (:func:`unit_lower_inverse`): a row at a time
+inside the diagonal blocks of ``INVERSE_START`` (8: the sixteen blocks of a
+chunk side by side in one ``[8, 128]`` plane, a row's sum a shift by lanes
+and a multiply, no product), then in blocks: the inverse of ``[[I + A11,
+0], [A21, I + A22]]`` is ``[[T11, 0], [-T22 A21 T11, T22]]``, from blocks
+of 8 doubling to ``C`` in four levels of two products (each over the rows
+of every second block, the only ones a level changes), and no power series
+(``A``'s entries reach 2 where neighbouring keys agree and its powers
+cancel catastrophically). Everything that reaches ``V'`` or the state is
+a float32 product at full precision (``STATE_PRECISION``, six bfloat16
+passes of the MXU): one pass there costs the state what rounding it to
+bfloat16 a chunk would, and three (the high and low halves by hand; Mosaic
+lowers no ``Precision.HIGH``) leave the states 4e-5 from the recurrence's
+where six leave them 4e-6 (interpreted, PR 59). The state after token
+``m`` inside a chunk is the last line with ``G_m`` for ``G_C`` and the rows
+after ``m`` left out, which is how a write that does not fall on a chunk's
+end is honoured.
+
+WHAT A CHUNK COSTS, in products of ``128 x 128 x 128`` a head of ``dk`` 96
+by ``dv`` 192 (two lane groups hold its values; a product at full
+precision is six passes, at the default one): ``K K^T`` 1, the inverse's
+eight of 64 rows each (a level changes the rows of every second block and
+no other) 4, ``T [b V | b e^G K]`` 2 + 1, ``W S_0`` 2, the state's ``dk``
+rows 1.5 (and 1.5 more for each write the chunk holds), at full precision;
+``Q S_0`` 2, ``Q K^T`` 1 and the output's 2 at the default: 74 passes for
+128 tokens of a head. (Two heads a product, 128 rows of 64 tokens each by
+their 384 lanes with head against head masked to zero, is 133 passes for
+the same tokens, three quarters of the large products zeros.) Nothing but ``V'``, ``O`` and the
+state reads ``S_0``, so a head's ``A``, ``T``, ``U`` and ``W`` do not wait
+for the chunk before; a loop step takes a PAIR of heads, each its own
+chain of products, so that one head's products fill the other's waits.
 
 IN PLACE. The one-token kernel is a grid over the rows, ``layer``,
 ``slots``, ``dst`` and ``positions`` prefetched as scalars and the pool
@@ -95,10 +119,21 @@ span is partial. A chunk is read as ``CHUNK`` consecutive slots of the
 stream (the slots beside a partial chunk belong to other rows): the slots
 outside it, and padding slots, have ``b = 0`` and ``g = 0``, which leave
 the state as it was and reach no other token's output, and only the
-chunk's own slots of the output are written. Heads are taken two at a
-time, ``2 * CHUNK`` rows by ``2 * dv`` lanes (``2 * 192 = 384``, whole lane
-groups), head against head masked to zero: products of 128 rows, and no
-lane slice that is not a multiple of 128.
+chunk's own slots of the output are written. A chunk's rows of the stream
+``[N, H * (2 dk + dv)]`` (every head's q, then every head's k, then v, the
+v part beginning a lane group: widths that do not give that are padded to
+it before the call) come into VMEM whole, by one DMA that starts while the
+chunk before computes; ``g`` and ``beta`` likewise, a lane a head. A
+head's ``dk`` lanes of q or k begin anywhere in a lane group (head ``j``'s
+at lane ``96 j``): the kernel loads the two groups they lie in, rotates
+them to lane 0, masks the rest and scales the row by ``rsqrt(sum x^2 +
+1e-6)``. A head's ``dv`` values and its columns of the state are read as
+the ``dvp`` lanes of whole groups that hold them (256 for 192: the even
+head of a pair from the pair's first lane, the odd one up to its last, the
+64 lanes beside a head's own being the other's and dropped when the pair's
+results are joined), so every product is 128 rows of ONE head and no lane
+slice begins off a group. A whole chunk's output leaves in one copy that
+the next step waits for; a span's last chunk's a tile at a time.
 
 WRITES. ``src``/``dst`` [W] as ``SeqStateIO`` has them: the state after
 stream token ``src[w]`` goes to entry ``dst[w]``; a ``dst`` past the pool
@@ -115,6 +150,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -122,7 +158,8 @@ from .selective_scan import stream_predecessor
 
 LANE = 128
 TILE = 8  # slots a span is aligned to: the engine's ragged_q_tile
-CHUNK = 64  # tokens a chunk: two heads are 128 rows of every product
+CHUNK = 128  # tokens a chunk: one head is 128 rows of every product
+INVERSE_START = TILE  # the inverse's diagonal blocks by rows: a sublane tile of lanes
 VMEM_LIMIT = 64 << 20
 # Products that reach V' or the state, and the rest: a chunk's output
 # beside what it hands on, which the mixer rounds to the model's dtype.
@@ -133,10 +170,10 @@ OUTPUT_PRECISION = jax.lax.Precision.DEFAULT
 def supported(heads: int, dk: int, dv: int, q_tile: int) -> bool:
     """Whether the kernel can take a stream of ``heads`` heads of ``dk`` by
     ``dv`` laid out in tiles of ``q_tile``: two heads' values fill whole
-    lane groups, the keys' axis whole sublanes, and the engine's spans are
-    aligned to the kernel's tiles."""
+    lane groups, a head's keys whole sublanes and no more than one lane
+    group, and the engine's spans are aligned to the kernel's tiles."""
     return (
-        heads % 2 == 0 and (2 * dv) % LANE == 0 and dk % TILE == 0
+        heads % 2 == 0 and (2 * dv) % LANE == 0 and dk % TILE == 0 and dk <= LANE
         and q_tile % TILE == 0
     )
 
@@ -406,59 +443,173 @@ def gated_delta_step_kernel(q, k, v, g, beta, pool, layer, slots, positions, dst
 
 
 # --------------------------------------------------------- the matrix form
-def unit_lower_inverse(a, rows, cols, dot, n=None):
+def unit_lower_inverse(a, rows, cols, dot, n=None, start=2, roll=np.roll):
     """``(I + a)^-1`` for ``a`` strictly lower triangular of size ``n``, a
     power of two (or block diagonal in blocks of ``n``): forward
-    substitution in blocks, from 2 doubling to ``n``. ``rows`` [.., 1] and
-    ``cols`` [1, ..] are the indices, ``dot`` the product: the kernel and
-    the float64 test share this text."""
-    n = n or a.shape[0]
+    substitution, a row at a time inside the diagonal blocks of ``start``
+    (elementwise, no product) and in blocks from there, doubling to ``n``.
+    ``rows`` [.., 1] and ``cols`` [1, ..] are the indices, ``dot`` the
+    product and ``roll`` the rotation along an axis: the kernel and the
+    float64 test share this text."""
+    return _in_step([inverse_steps(a, rows, cols, dot, n, start, roll)])[0]
+
+
+def inverse_steps(a, rows, cols, dot, n=None, start=2, roll=np.roll):
+    """:func:`unit_lower_inverse` as a generator that yields after every
+    product and returns the inverse: the kernel runs two heads' in step."""
+    size = a.shape[0]
+    n = n or size
     eye = rows == cols
-    t = eye - a * (rows // 2 == cols // 2)
-    b = 2
+
+    def block(x, b):  # the block of ``b`` (a power of two) an index lies in: a shift, not a division
+        return x >> (b.bit_length() - 1)
+
+    if start == 2:
+        t = eye - a * (block(rows, 2) == block(cols, 2))
+    else:
+        # The diagonal blocks side by side as ``a`` holds them, planes of
+        # ``start`` rows (one sublane tile at 8): ``at[i, c]`` is ``a[first
+        # of c's block + i, c]``, ``tt[i, c]`` that entry of the inverse. From
+        # ``T (I + a) = I``, column ``c`` of a block is ``e_c - sum_{m>c}
+        # a_mc column_m``, the block's last column first: the lanes of the
+        # blocks' columns ``i`` take their block's column ``m`` from ``m - i``
+        # lanes on.
+        diagonal = ((block(rows, start) == block(cols, start)) * a.dtype.type(1)).reshape(
+            size // start, start, size
+        )
+        at = (a.reshape(size // start, start, size) * diagonal).sum(axis=0)
+        within = cols & (start - 1)
+        tt = (rows[:start] == within) * a.dtype.type(1)
+        for i in range(start - 2, -1, -1):
+            acc = at[i + 1:i + 2] * roll(tt, size - 1, 1)
+            for m in range(i + 2, start):
+                acc = acc + at[m:m + 1] * roll(tt, size - (m - i), 1)
+            tt = tt - acc * (within == i)
+        t = (tt[None] * diagonal).reshape(size, size)
+    second = (rows[:2] == 1).reshape(1, 2, 1, 1) * a.dtype.type(1)
+    b = start
     while b < n:
-        below = (rows // b == cols // b + 1) & (rows // (2 * b) == cols // (2 * b))
-        t = t - dot(dot(t, a * below), t)
+        below = (block(rows, b) == block(cols, b) + 1) & (block(rows, 2 * b) == block(cols, 2 * b))
+        # ``t - t (a * below) t`` changes the rows of every second block and
+        # no other: those rows alone go through the two products, half of
+        # each.
+        pairs = size // (2 * b)
+        part = dot(t.reshape(pairs, 2, b, size)[:, 1].reshape(size // 2, size), a * below)
+        yield
+        part = dot(part, t)
+        yield
+        t = t - (part.reshape(pairs, 1, b, size) * second).reshape(size, size)
         b *= 2
     return t
 
 
+def _in_step(chains):
+    """What each generator returns, all run to their ends a step each in
+    turn."""
+    done = {}
+    while len(done) < len(chains):
+        for j, chain in enumerate(chains):
+            if j not in done:
+                try:
+                    next(chain)
+                except StopIteration as end:
+                    done[j] = end.value
+    return [done[j] for j in range(len(chains))]
+
+
+def unit_heads(qkv, heads, dk):
+    """(q, k, v) of a stream as the mixer's convolution leaves it, ``qkv``
+    [N, H * (2 dk + dv)]: ``q`` and ``k`` [N, H * dk] with each head's
+    vector brought to length 1 (``q`` scaled by ``dk^-0.5`` besides), ``v``
+    [N, H * dv], float32. What the chunk kernel does to a head's rows in
+    VMEM, for the forms that take ``q`` and ``k`` ready."""
+    f32 = jnp.float32
+    n = qkv.shape[0]
+
+    def unit(x):
+        x = x.astype(f32).reshape(n, heads, dk)
+        return (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)).reshape(n, heads * dk)
+
+    return (
+        unit(qkv[:, : heads * dk]) * dk**-0.5, unit(qkv[:, heads * dk : 2 * heads * dk]),
+        qkv[:, 2 * heads * dk :].astype(f32),
+    )
+
+
 # ------------------------------------------------------------------ kernel
+def _dot(a, b, contract, precision):
+    """Every product of the chunk kernel: ``a`` and ``b`` contracted over
+    the axes ``contract`` names (``tools/gdn_chunk.py`` times the kernel
+    with this replaced)."""
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision,
+    )
+
+
 def _chunk_kernel(layer_ref, base_ref, lo_ref, hi_ref, load_ref,  # SMEM
                   ea_ref, ata_ref, eb_ref, atb_ref,
-                  q_hbm, k_hbm, v_hbm, g_hbm, b_hbm, pool_in,
+                  x_hbm, g_hbm, b_hbm, pool_in,
                   o_hbm, pool_out,
-                  q_buf, k_buf, v_buf, g_buf, b_buf, o_buf, s_ref, snap_a,
-                  snap_b, sem, *, heads, dk, dv, c, entries):
+                  x_buf, g_buf, b_buf, o_buf, s_ref, snap_a, snap_b,
+                  in_sem, out_sem, sem, *, heads, dk, dv, c, entries):
     """One grid step: one chunk of one span, every head. ``s_ref``
     [dk padded, H * dv] is the span's running state, kept from step to
-    step; ``snap_a``/``snap_b`` the states the chunk's writes ask for."""
+    step; ``snap_a``/``snap_b`` the states the chunk's writes ask for;
+    ``x_buf``, ``g_buf``, ``b_buf`` and ``o_buf`` hold two chunks each, the
+    next one's inputs arriving and the last one's output leaving while
+    this one computes."""
     # pool_in and pool_out are one buffer (input_output_aliases): a span
     # reads its entry before any chunk of the call writes it.
     f32 = jnp.float32
     i = pl.program_id(0)
+    steps = pl.num_programs(0)
     layer = layer_ref[0]
     lo, hi = lo_ref[i], hi_ref[i]
-    c2, w2 = 2 * c, 2 * dv
+    w2 = 2 * dv
+    dvp = -(-dv // LANE) * LANE  # a head's values in whole lane groups
+    shared = 2 * dvp - w2  # lanes of the pair's middle group both windows hold
+    v_at = x_buf.shape[-1] - heads * dv  # the first lane of v in a stream row
+
+    def at(j):  # a chunk's scalars are read inside the call's chunks only
+        return jnp.clip(j, 0, steps - 1)
+
+    def live(j):
+        return (j >= 0) & (j < steps) & (hi_ref[at(j)] > lo_ref[at(j)])
+
+    def whole(j):  # its output leaves in one copy, waited for a step later
+        return live(j) & (lo_ref[at(j)] == 0) & (hi_ref[at(j)] == c)
+
+    def inputs(j):
+        rows_at = pl.ds(pl.multiple_of(base_ref[at(j)], TILE), c)
+        slot = j % 2
+        return [
+            pltpu.make_async_copy(hbm.at[rows_at], buf.at[slot], in_sem.at[slot, n])
+            for n, (hbm, buf) in enumerate(((x_hbm, x_buf), (g_hbm, g_buf), (b_hbm, b_buf)))
+        ]
+
+    def output(j):
+        rows_at = pl.ds(pl.multiple_of(base_ref[at(j)], TILE), c)
+        return pltpu.make_async_copy(o_buf.at[j % 2], o_hbm.at[rows_at], out_sem.at[j % 2])
 
     @pl.when(i == 0)
     def _():  # the rows past dk are never written again: zeros, not what VMEM held
         s_ref[...] = jnp.zeros_like(s_ref)
 
+        @pl.when(live(0))
+        def _():
+            for dma in inputs(0):
+                dma.start()
+
+    @pl.when(live(i + 1))
+    def _():
+        for dma in inputs(i + 1):
+            dma.start()
+
     @pl.when(hi > lo)
     def _():
-        base = pl.multiple_of(base_ref[i], TILE)
+        slot = i % 2
         load = load_ref[i]
-        rows_at = pl.ds(base, c)
-        copies = [
-            pltpu.make_async_copy(q_hbm.at[:, rows_at], q_buf, sem.at[0]),
-            pltpu.make_async_copy(k_hbm.at[:, rows_at], k_buf, sem.at[1]),
-            pltpu.make_async_copy(v_hbm.at[rows_at], v_buf, sem.at[2]),
-            pltpu.make_async_copy(g_hbm.at[rows_at], g_buf, sem.at[3]),
-            pltpu.make_async_copy(b_hbm.at[rows_at], b_buf, sem.at[4]),
-        ]
-        for dma in copies:
-            dma.start()
 
         @pl.when(load == entries)  # a span's first chunk, at position 0
         def _():
@@ -467,115 +618,150 @@ def _chunk_kernel(layer_ref, base_ref, lo_ref, hi_ref, load_ref,  # SMEM
         @pl.when((load >= 0) & (load < entries))  # a span resumed from its entry
         def _():
             dma = pltpu.make_async_copy(
-                pool_in.at[layer, load], s_ref.at[pl.ds(0, dk)], sem.at[5]
+                pool_in.at[layer, load], s_ref.at[pl.ds(0, dk)], sem.at[0]
             )
             dma.start()
             dma.wait()
 
-        for dma in copies:
+        for dma in inputs(i):
             dma.wait()
 
-        # Index planes of a pair of heads: rows 0..c-1 the first head's
-        # tokens, c..2c-1 the second's; lanes 0..dv-1 the first head's
-        # values, dv..2dv-1 the second's.
-        row = jax.lax.broadcasted_iota(jnp.int32, (c2, 1), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, c2), 1)
-        tok, half = row % c, row // c
-        same = half == col // c
+        # Index planes of a head's chunk: a token a row, a token a column.
+        row = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
         eye = row == col
-        incl = same & (row >= col)
-        strict = same & (row > col)
-        own = half == jax.lax.broadcasted_iota(jnp.int32, (1, w2), 1) // dv  # [c2, w2]
-        first_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, w2), 1) < dv
-        in_chunk = (tok >= lo) & (tok < hi)  # [c2, 1]
-        head_lane = jax.lax.broadcasted_iota(jnp.int32, g_buf.shape, 1)
+        incl = row >= col
+        strict = row > col
+        in_chunk = (row >= lo) & (row < hi)  # [c, 1]
+        head_lane = jax.lax.broadcasted_iota(jnp.int32, g_buf.shape[1:], 1)
+        key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1) < dk
+        value_lane = jax.lax.broadcasted_iota(jnp.int32, (1, dvp), 1)
 
         def dot(a, b, precision=STATE_PRECISION):
-            return jnp.dot(a, b, preferred_element_type=f32, precision=precision)
+            return _dot(a, b, (1, 0), precision)
 
         def dot_nt(a, b, precision=STATE_PRECISION):  # a b^T
-            return jax.lax.dot_general(
-                a, b, (((1,), (1,)), ((), ())), preferred_element_type=f32,
-                precision=precision,
-            )
+            return _dot(a, b, (1, 1), precision)
 
-        def dot_tn(a, b):  # a^T b
-            return jax.lax.dot_general(
-                a, b, (((0,), (0,)), ((), ())), preferred_element_type=f32,
-                precision=STATE_PRECISION,
-            )
-
-        def as_row(x):  # [c2, 1] -> [1, c2]
+        def as_row(x):  # [c, 1] -> [1, c]
             return jnp.sum(jnp.where(eye, x, 0.0), axis=0, keepdims=True)
 
-        def of_heads(buf, p):  # [c, GW] -> [c2, 1]: heads 2p and 2p + 1
-            return jnp.concatenate([
-                jnp.sum(jnp.where(head_lane == 2 * p + j, buf, 0.0), axis=1, keepdims=True)
-                for j in (0, 1)
-            ], axis=0)
+        def of_head(buf, h):  # [c, GW] -> [c, 1]
+            return jnp.sum(jnp.where(head_lane == h, buf[slot], 0.0), axis=1, keepdims=True)
 
-        def state_after(at, cum, k2, vp, s0):
-            """The pair's state after the chunk's slot ``at`` (traced)."""
-            cum_at = [
-                jnp.sum(jnp.where((tok == at) & (half == j), cum, 0.0), keepdims=True)
-                for j in (0, 1)
-            ]  # [1, 1] each
-            cum_at2 = jnp.where(half == 0, cum_at[0], cum_at[1])  # [c2, 1]
-            kd = jnp.where(tok <= at, jnp.exp(jnp.minimum(cum_at2 - cum, 0.0)), 0.0) * k2
-            carry = jnp.where(first_lanes, jnp.exp(cum_at[0]), jnp.exp(cum_at[1]))
-            return carry * s0 + dot_tn(kd, vp)
+        def unit(h):
+            """Vector ``h`` of a stream row's ``2 H`` (the queries, then the
+            keys) for every token of the chunk, brought to length 1: [c, a
+            lane group], zeros past ``dk``. Its ``dk`` lanes begin anywhere
+            in a lane group: the two groups they lie in, rotated."""
+            group, lane = jax.lax.div(h * dk, LANE), jax.lax.rem(h * dk, LANE)
+            two = x_buf[slot, :, pl.ds(pl.multiple_of(group * LANE, LANE), 2 * LANE)]
+            x = pltpu.roll(two, jax.lax.rem(2 * LANE - lane, 2 * LANE), 1)[:, :LANE]
+            x = jnp.where(key_lane, x, 0.0)
+            return x * jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6)
+
+        def join(x0, x1):  # two heads' [.., dvp] windows -> the pair's [.., 2 dv]
+            alone = dvp - shared  # lanes of a window that only its head's window holds
+            mine = jnp.where(value_lane[:, :shared] < dv - alone, x0[:, alone:], x1[:, :shared])
+            parts = [x0[:, :alone], mine, x1[:, shared:]]
+            return jnp.concatenate([x for x in parts if x.shape[1]], axis=1)
+
+        def one_head(h, first):
+            """Head ``h``, whose values are the lanes from ``first`` of the
+            stream's: (its output [c, dvp], its states after the chunk and
+            after each write's slot [dk, dvp]), all over the ``dvp`` lanes of
+            whole groups that hold its ``dv`` (the lanes beside them are the
+            pair's other head's, and :func:`join` drops them). A generator
+            that yields after every product, so that a pair's two chains of
+            products are laid down in step and one's fill the other's waits."""
+            window = pl.ds(pl.multiple_of(first, LANE), dvp)
+            g1 = jnp.where(in_chunk, of_head(g_buf, h), 0.0)
+            b1 = jnp.where(in_chunk, of_head(b_buf, h), 0.0)
+            cum = jnp.sum(jnp.where(incl, as_row(g1), 0.0), axis=1, keepdims=True)
+            decay = jnp.where(incl, jnp.exp(jnp.minimum(cum - as_row(cum), 0.0)), 0.0)
+            grown = jnp.exp(cum)  # e^G, [c, 1]
+            q1 = unit(h) * dk**-0.5
+            k1 = unit(heads + h)
+            vv = x_buf[slot, :, pl.ds(pl.multiple_of(v_at + first, LANE), dvp)]
+            s0 = s_ref[:, window]
+
+            a = jnp.where(strict, b1 * decay * dot_nt(k1, k1), 0.0)
+            yield
+            t = yield from inverse_steps(a, row, col, dot, c, INVERSE_START, pltpu.roll)
+            u = dot(t, b1 * vv)
+            yield
+            w = dot(t, (b1 * grown) * k1)
+            yield
+            vp = u - dot(w, s0)
+            yield
+            o1 = grown * dot(q1, s0, OUTPUT_PRECISION) + dot(
+                decay * dot_nt(q1, k1, OUTPUT_PRECISION), vp, OUTPUT_PRECISION
+            )
+
+            def state_after(slot_at):
+                """The head's state after the chunk's slot ``slot_at`` (traced)."""
+                cum_at = jnp.sum(jnp.where(row == slot_at, cum, 0.0), keepdims=True)  # [1, 1]
+                kd = jnp.where(row <= slot_at, jnp.exp(jnp.minimum(cum_at - cum, 0.0)), 0.0) * k1
+                return jnp.exp(cum_at) * s0[:dk] + _dot(kd[:, :dk], vp, (0, 0), STATE_PRECISION)
+
+            return o1, state_after
 
         def pair(p, carry):
             lanes = pl.ds(pl.multiple_of(p * w2, LANE), w2)
-            g2 = jnp.where(in_chunk, of_heads(g_buf[...], p), 0.0)
-            b2 = jnp.where(in_chunk, of_heads(b_buf[...], p), 0.0)
-            cum = jnp.sum(jnp.where(incl, as_row(g2), 0.0), axis=1, keepdims=True)
-            decay = jnp.where(incl, jnp.exp(jnp.minimum(cum - as_row(cum), 0.0)), 0.0)
-            q2 = q_buf[pl.ds(2 * p, 2)].reshape(c2, q_buf.shape[-1])
-            k2 = k_buf[pl.ds(2 * p, 2)].reshape(c2, k_buf.shape[-1])
-            vv = v_buf[:, lanes]
-            v2 = jnp.where(own, jnp.concatenate([vv, vv], axis=0), 0.0)
-            s0 = s_ref[:, lanes]
-
-            a = jnp.where(strict, b2 * decay * dot_nt(k2, k2), 0.0)
-            t = unit_lower_inverse(a, row, col, dot, c)
-            u = dot(t, b2 * v2)
-            w = dot(t, (b2 * jnp.exp(cum)) * k2)
-            vp = u - jnp.where(own, dot(w, s0), 0.0)
-            o2 = jnp.where(own, jnp.exp(cum) * dot(q2, s0, OUTPUT_PRECISION), 0.0) + dot(
-                decay * dot_nt(q2, k2, OUTPUT_PRECISION), vp, OUTPUT_PRECISION
+            (o_0, after_0), (o_1, after_1) = _in_step(
+                [one_head(2 * p + j, p * w2 + j * (w2 - dvp)) for j in (0, 1)]
             )
-            o_buf[:, lanes] = o2[:c] + o2[c:]
+            o_buf[slot, :, lanes] = join(o_0, o_1)
             # the slots after the chunk's last have g = 0: its end is slot c - 1
-            s_ref[:, lanes] = state_after(c - 1, cum, k2, vp, s0)
+            s_ref[pl.ds(0, dk), lanes] = join(after_0(c - 1), after_1(c - 1))
             for e_ref, at_ref, snap in (
                 (ea_ref, ata_ref, snap_a), (eb_ref, atb_ref, snap_b)
             ):
                 @pl.when(e_ref[i] < entries)
                 def _(at_ref=at_ref, snap=snap):
-                    snap[:, lanes] = state_after(at_ref[i], cum, k2, vp, s0)
+                    snap[pl.ds(0, dk), lanes] = join(after_0(at_ref[i]), after_1(at_ref[i]))
 
             return carry
 
         jax.lax.fori_loop(0, heads // 2, pair, 0)
 
-        for t in range(c // TILE):  # the chunk's own slots of the output
-            @pl.when((lo <= t * TILE) & (t * TILE < hi))
-            def _(t=t):
-                dma = pltpu.make_async_copy(
-                    o_buf.at[pl.ds(t * TILE, TILE)],
-                    o_hbm.at[pl.ds(base + t * TILE, TILE)], sem.at[5],
-                )
-                dma.start()
-                dma.wait()
+    # The chunk's own slots of the output: a whole chunk's in one copy that
+    # the next step waits for, a span's last chunk's a tile at a time.
+    @pl.when(whole(i - 1))
+    def _():
+        output(i - 1).wait()
 
+    @pl.when(whole(i))
+    def _():
+        output(i).start()
+
+        @pl.when(i == steps - 1)
+        def _():
+            output(i).wait()
+
+    @pl.when((hi > lo) & ~whole(i))
+    def _():
+        base = base_ref[i]
+
+        def tile(t):
+            return pltpu.make_async_copy(
+                o_buf.at[i % 2, pl.ds(pl.multiple_of(t * TILE, TILE), TILE)],
+                o_hbm.at[pl.ds(pl.multiple_of(base + t * TILE, TILE), TILE)], sem.at[0],
+            )
+
+        tiles = jax.lax.div(lo, TILE), jax.lax.div(hi + TILE - 1, TILE)  # a span begins a tile
+        jax.lax.fori_loop(*tiles, lambda t, _: tile(t).start(), None)
+        jax.lax.fori_loop(*tiles, lambda t, _: tile(t).wait(), None)
+
+    @pl.when(hi > lo)
+    def _():
         for e_ref, snap in ((ea_ref, snap_a), (eb_ref, snap_b)):
             entry = e_ref[i]
 
             @pl.when(entry < entries)
             def _(entry=entry, snap=snap):
                 dma = pltpu.make_async_copy(
-                    snap.at[pl.ds(0, dk)], pool_out.at[layer, entry], sem.at[5]
+                    snap.at[pl.ds(0, dk)], pool_out.at[layer, entry], sem.at[0]
                 )
                 dma.start()
                 dma.wait()
@@ -629,32 +815,39 @@ def _chunk_tables(slots, row_of, positions, src, dst, c, chunks, entries):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gated_delta_kernel(q, k, v, g, beta, pool, layer, slots, row_of, positions,
+def gated_delta_kernel(qkv, g, beta, pool, layer, slots, row_of, positions,
                        src, dst, interpret: bool = False):
-    """The stream's recurrence on the TPU (module docstring): arguments
-    and results as :func:`gated_delta_ref`, the layout held to the
-    contract above (spans aligned to ``TILE``, one a row)."""
+    """The stream's recurrence on the TPU (module docstring): ``qkv``
+    [N, H * (2 dk + dv)] float32 as the mixer's convolution leaves it (every
+    head's q, then every head's k, then v; q and k NOT normalised: the
+    kernel brings a head's rows to length 1 where it holds them, as
+    :func:`unit_heads` does for the other forms), the rest and the results
+    as :func:`gated_delta_ref`, the layout held to the contract above
+    (spans aligned to ``TILE``, one a row)."""
     f32 = jnp.float32
-    h, dk, dv = _heads(q, v, g)
-    n0 = q.shape[0]
+    h = g.shape[-1]
+    dk, dv = pool.shape[2], pool.shape[3] // h
+    n0 = qkv.shape[0]
     entries = pool.shape[1]
     c = CHUNK
+    qkv = qkv.astype(f32)
+    spare = -(2 * h * dk) % LANE
+    if spare:  # v begins a lane group: widths that do not give that are padded to it
+        qkv = jnp.concatenate(
+            [qkv[:, : 2 * h * dk], jnp.zeros((n0, spare), f32), qkv[:, 2 * h * dk :]], axis=1
+        )
     if n0 < c:  # a stream shorter than a chunk: slots of padding after it
         grow = lambda x, fill=0: jnp.pad(  # noqa: E731
             x, ((0, c - n0),) + ((0, 0),) * (x.ndim - 1), constant_values=fill
         )
-        q, k, v, g, beta, row_of = map(grow, (q, k, v, g, beta, row_of))
+        qkv, g, beta, row_of = map(grow, (qkv, g, beta, row_of))
         positions = grow(positions, -1)
-    n = q.shape[0]
+    n, width = qkv.shape
     chunks = n // c + slots.shape[0]
     tables = _chunk_tables(slots, row_of, positions, src, dst, c, chunks, entries)
     live = (positions >= 0)[:, None]
     dkp = -(-dk // LANE) * LANE
     gw = -(-h // LANE) * LANE
-
-    def by_head(x):  # [N, H * dk] -> [H, N, dk padded to whole lane groups]
-        x = x.astype(f32).reshape(n, h, dk).transpose(1, 0, 2)
-        return jnp.pad(x, ((0, 0), (0, 0), (0, dkp - dk)))
 
     def by_token(x):  # [N, H] -> [N, a lane group], 0 where the slot is padding
         return jnp.pad(jnp.where(live, x.astype(f32), 0.0), ((0, 0), (0, gw - h)))
@@ -663,19 +856,19 @@ def gated_delta_kernel(q, k, v, g, beta, pool, layer, slots, row_of, positions,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,
         grid=(chunks,),
-        in_specs=[any_space] * 6,
+        in_specs=[any_space] * 4,
         out_specs=[any_space, any_space],
         scratch_shapes=[
-            pltpu.VMEM((h, c, dkp), f32),  # q
-            pltpu.VMEM((h, c, dkp), f32),  # k
-            pltpu.VMEM((c, h * dv), f32),  # v
-            pltpu.VMEM((c, gw), f32),  # g
-            pltpu.VMEM((c, gw), f32),  # beta
-            pltpu.VMEM((c, h * dv), f32),  # o
+            pltpu.VMEM((2, c, width), f32),  # q | k | v, this chunk's and the next's
+            pltpu.VMEM((2, c, gw), f32),  # g
+            pltpu.VMEM((2, c, gw), f32),  # beta
+            pltpu.VMEM((2, c, h * dv), f32),  # o, this chunk's and the last's
             pltpu.VMEM((dkp, h * dv), f32),  # the running state
             pltpu.VMEM((dkp, h * dv), f32),  # a write's
             pltpu.VMEM((dkp, h * dv), f32),  # the other write's
-            pltpu.SemaphoreType.DMA((6,)),
+            pltpu.SemaphoreType.DMA((2, 3)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
         ],
     )
     o, pool = pl.pallas_call(
@@ -687,8 +880,8 @@ def gated_delta_kernel(q, k, v, g, beta, pool, layer, slots, row_of, positions,
             jax.ShapeDtypeStruct((n, h * dv), f32),
             jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         ],
-        # operand 14 (9 prefetched scalars, then q k v g beta pool)
-        input_output_aliases={14: 1},
+        # operand 12 (9 prefetched scalars, then qkv g beta pool)
+        input_output_aliases={12: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_LIMIT
         ),
@@ -696,7 +889,7 @@ def gated_delta_kernel(q, k, v, g, beta, pool, layer, slots, row_of, positions,
         interpret=interpret,
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32), *tables,
-        by_head(q), by_head(k), v.astype(f32), by_token(g), by_token(beta), pool,
+        qkv, by_token(g), by_token(beta), pool,
     )
     # slots no chunk owns were never written
     return jnp.where(live, o, 0.0)[:n0], pool

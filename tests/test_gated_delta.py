@@ -5,12 +5,16 @@ recurrence, states included, and the chunk's matrix form against the
 recurrence in float64.
 
 TOLERANCES. The kernel computes the same float32 numbers in another order
-(a chunk's products against a token's sums): ``KERNEL_TOL`` 2e-5 on
-outputs and states of order 1, a hundred times the rounding read (2e-6
-over 2048 tokens) and far below any fault (a state started from the wrong
+(a chunk's products against a token's sums, a head's q and k brought to
+length 1 in the kernel where the other forms take them from
+``unit_heads``): ``KERNEL_TOL`` 2e-5 on outputs and states of order 1,
+some ten times the rounding read (3e-6 in the outputs and 7e-6 in the
+states over 2048 tokens) and far below any fault (a state started from the wrong
 entry, a slot of another row let in, a write one slot off: 1e-2 and more).
 In float64 the matrix form agrees with the recurrence to 1e-12.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,20 +24,26 @@ import pytest
 from dynamo_exp_tpu.ops import gated_delta as gd
 
 KERNEL_TOL = 2e-5
-H, DK, DV = 4, 8, 64  # two pairs of heads; 2 * DV fills a lane group
+H, DK, DV = 4, 16, 64  # two pairs of heads; 2 * DV fills a lane group, every head's q and k one
 
 
 def _inputs(rng, n, h=H, dk=DK, dv=DV, beta_top=2.0, dtype=np.float32):
-    """Keys of unit length, scaled queries, decays whose memory runs from
-    a token to hundreds, steps up to ``beta_top``."""
-    q = rng.standard_normal((n, h, dk))
-    k = rng.standard_normal((n, h, dk))
-    q /= np.linalg.norm(q, axis=-1, keepdims=True) * dk**0.5
-    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    """(qkv, g, beta): q and k as a convolution leaves them (no length in
+    particular: lengths from a fifth to five), decays whose memory runs
+    from a token to hundreds, steps up to ``beta_top``."""
+    qk = rng.standard_normal((n, 2 * h, dk))
+    qk *= np.exp(rng.uniform(np.log(0.2), np.log(5.0), (n, 2 * h, 1))) / np.linalg.norm(
+        qk, axis=-1, keepdims=True
+    )
     v = rng.standard_normal((n, h * dv))
     g = -np.exp(rng.uniform(np.log(1e-3), np.log(3.0), (n, h)))
     beta = beta_top * rng.uniform(0.0, 1.0, (n, h)) ** 0.5
-    return [x.astype(dtype) for x in (q.reshape(n, -1), k.reshape(n, -1), v, g, beta)]
+    return [x.astype(dtype) for x in (np.concatenate([qk.reshape(n, -1), v], axis=1), g, beta)]
+
+
+def _ready(qkv, g, beta, h=H, dk=DK):
+    """(q, k, v, g, beta) as the forms that do not normalise take them."""
+    return [*gd.unit_heads(qkv, h, dk), g, beta]
 
 
 def _stream(spans, tail=(), pad_tiles=1):
@@ -53,11 +63,15 @@ def _stream(spans, tail=(), pad_tiles=1):
     return jnp.asarray(row_of, jnp.int32), jnp.asarray(pos, jnp.int32)
 
 
-def _check(spans, src, dst, seed=0, layers=2, entries=8, beta_top=2.0, tail=()):
+def _check(spans, src, dst, seed=0, layers=2, entries=8, beta_top=2.0, tail=(), zeros=()):
+    """``zeros``: (slot, first column, columns) of ``qkv`` set to 0."""
     rng = np.random.default_rng(seed)
     row_of, pos = _stream(spans, tail)
     n = pos.shape[0]
-    args = [jnp.asarray(x) for x in _inputs(rng, n, beta_top=beta_top)]
+    args = _inputs(rng, n, beta_top=beta_top)
+    for slot, first, columns in zeros:
+        args[0][slot, first:first + columns] = 0.0
+    args = [jnp.asarray(x) for x in args]
     pool = jnp.asarray(rng.standard_normal((layers, entries, DK, H * DV)), jnp.float32)
     rows = len(spans) + len(tail)
     slots = jnp.asarray([r for r, *_ in spans] + [r for r, _ in tail] + [entries], jnp.int32)
@@ -66,7 +80,7 @@ def _check(spans, src, dst, seed=0, layers=2, entries=8, beta_top=2.0, tail=()):
         slots, row_of, pos, jnp.asarray(src + pad, jnp.int32),
         jnp.asarray(dst + [entries] * len(pad), jnp.int32),
     )
-    o_ref, pool_ref = gd.gated_delta_ref(*args, pool, 1, *io)
+    o_ref, pool_ref = gd.gated_delta_ref(*_ready(*args), pool, 1, *io)
     o, out = gd.gated_delta_kernel(*args, pool, jnp.int32(1), *io, interpret=True)
     live = np.asarray(pos) >= 0
     assert np.abs(np.asarray(o) - np.asarray(o_ref))[live].max() < KERNEL_TOL
@@ -79,28 +93,41 @@ def _check(spans, src, dst, seed=0, layers=2, entries=8, beta_top=2.0, tail=()):
 
 
 # ------------------------------------------------------------------ kernel
-@pytest.mark.parametrize("tokens", [1, 7, 8, 15, 63, 64, 65, 72, 127, 128, 129, 191])
+@pytest.mark.parametrize(
+    "tokens", [1, 7, 8, 15, 63, 64, 65, 72, 127, 128, 129, 191, 255, 256, 257]
+)
 @pytest.mark.parametrize("start", [0, 16])
 def test_a_span_of_every_remainder(tokens, start):
-    """One span of every length round the chunk's 64 and the tile's 8, from
-    position 0 (zeros) and resumed from its entry, its own write after its
-    last token wherever in a chunk that falls."""
+    """One span of every length round the chunk's 128 (and its half) and
+    the tile's 8, from position 0 (zeros) and resumed from its entry, its
+    own write after its last token wherever in a chunk that falls."""
     _check([(0, start, tokens)], [tokens - 1], [0], seed=tokens)
 
 
-@pytest.mark.parametrize("spans,src,dst", [
+@pytest.mark.parametrize("spans,src,dst,zeros", [
     # several rows a stream: chunks that begin where another row's end
-    ([(0, 0, 100), (1, 40, 5), (2, 8, 64), (3, 0, 3)], [99, 108, 175, 178], [0, 1, 2, 3]),
+    ([(0, 0, 100), (1, 40, 5), (2, 8, 64), (3, 0, 3)], [99, 108, 175, 178], [0, 1, 2, 3], ()),
     # both writes a row: its own entry and a snapshot at a block boundary,
     # 16 tokens into a chunk, at a chunk's end, and in the chunk of the last token
-    ([(0, 16, 200)], [199, 15], [0, 5]),
-    ([(0, 0, 200)], [199, 63], [0, 5]),
-    ([(0, 32, 200), (1, 0, 70)], [199, 191, 200 + 69, 200 + 47], [0, 5, 1, 6]),
+    ([(0, 16, 200)], [199, 15], [0, 5], ()),
+    ([(0, 0, 200)], [199, 63], [0, 5], ()),
+    ([(0, 32, 200), (1, 0, 70)], [199, 191, 200 + 69, 200 + 47], [0, 5, 1, 6], ()),
     # a row with no write at all, and a write to an entry past the pool
-    ([(0, 0, 24), (1, 0, 24)], [23, 47], [0, 8]),
+    ([(0, 0, 24), (1, 0, 24)], [23, 47], [0, 8], ()),
+    # chunks of 128: a snapshot at the first chunk's end, and 16 tokens into the second
+    ([(0, 0, 200)], [199, 127], [0, 5], ()),
+    ([(0, 16, 300)], [299, 128 + 15], [0, 5], ()),
+    # two spans that end mid-chunk (slots 299 and 304 + 129), the second's snapshot at its
+    # first chunk's end, the first's mid-chunk; the second span's chunks begin mid-stream
+    ([(0, 0, 300), (1, 16, 130)], [299, 128 + 15, 304 + 129, 304 + 127], [0, 5, 1, 6], ()),
+    # q and k of no length (the 1e-6 under the root): a token's whole q, a token's whole k,
+    # one head's k of another token, and q and k of a head at a span's first token
+    ([(0, 0, 140), (1, 8, 20)], [139, 63, 144 + 19], [0, 5, 1],
+     ((3, 0, H * DK), (70, H * DK, H * DK), (131, H * DK + DK, DK), (144, 2 * DK, DK),
+      (144, H * DK + 2 * DK, DK))),
 ])
-def test_rows_and_writes(spans, src, dst):
-    _check(spans, src, dst, seed=len(spans))
+def test_rows_and_writes(spans, src, dst, zeros):
+    _check(spans, src, dst, seed=len(spans), zeros=zeros)
 
 
 def test_a_packed_tail_is_left_to_the_one_step_form():
@@ -120,14 +147,15 @@ def test_a_packed_tail_is_left_to_the_one_step_form():
         *args, pool, jnp.int32(0), slots, row_of, jnp.where(alone, -1, pos), src,
         jnp.where(jnp.asarray(alone)[src], 4, dst), interpret=True,
     )
-    o_ref, pool_ref = gd.gated_delta_ref(*args, pool, 0, slots, row_of, pos, src, dst)
+    ready = _ready(*args)
+    o_ref, pool_ref = gd.gated_delta_ref(*ready, pool, 0, slots, row_of, pos, src, dst)
     assert np.abs(np.asarray(o) - np.asarray(o_ref))[:40].max() < KERNEL_TOL
     assert np.abs(np.asarray(out[0, 0]) - np.asarray(pool_ref[0, 0])).max() < KERNEL_TOL
     assert np.array_equal(np.asarray(out[0, 1:]), np.asarray(pool[0, 1:]))
     # and the one-step form gives those rows what the oracle does
     tail = slice(40, 42)
     o1, out1 = gd.gated_delta_step(
-        *(a[tail] for a in args), out, 0, slots[1:3], pos[tail], slots[1:3]
+        *(a[tail] for a in ready), out, 0, slots[1:3], pos[tail], slots[1:3]
     )
     assert np.abs(np.asarray(o1) - np.asarray(o_ref)[tail]).max() < KERNEL_TOL
     assert np.abs(np.asarray(out1) - np.asarray(pool_ref)).max() < KERNEL_TOL
@@ -166,7 +194,7 @@ def test_chunk_tables_name_every_chunk():
 def test_step_matches_its_oracle():
     rng = np.random.default_rng(9)
     r = 4
-    args = [jnp.asarray(x) for x in _inputs(rng, r)]
+    args = _ready(*(jnp.asarray(x) for x in _inputs(rng, r)))
     pool = jnp.asarray(rng.standard_normal((2, 7, DK, H * DV)), jnp.float32)
     pos = jnp.asarray([0, 5, 9, -1], jnp.int32)
     slots = jnp.asarray([0, 1, 2, 7], jnp.int32)
@@ -208,7 +236,7 @@ def test_step_kernel_matches_the_plain_step(case):
     a case is about is asserted on the kernel's pool alone too."""
     r, layers, entries, layer, pos, slots, dst = STEP_CASES[case]
     rng = np.random.default_rng(r + layer)
-    args = [jnp.asarray(x) for x in _inputs(rng, r)]
+    args = _ready(*(jnp.asarray(x) for x in _inputs(rng, r)))
     pool = jnp.asarray(rng.standard_normal((layers, entries, DK, H * DV)), jnp.float32)
     pos, slots, dst = (jnp.asarray(a, jnp.int32) for a in (pos, slots, dst))
     o, out = gd.gated_delta_step_kernel(
@@ -235,7 +263,7 @@ def test_step_kernel_reads_slots_and_writes_dst_row_by_row():
     nothing reads) is such a call; every source stays what it was."""
     rng = np.random.default_rng(2)
     r = 8
-    args = [jnp.asarray(x) for x in _inputs(rng, r)]
+    args = _ready(*(jnp.asarray(x) for x in _inputs(rng, r)))
     pool = jnp.asarray(rng.standard_normal((1, 16, DK, H * DV)), jnp.float32)
     pos = jnp.arange(1, r + 1, dtype=jnp.int32)
     slots = jnp.arange(r, dtype=jnp.int32)
@@ -318,18 +346,19 @@ def _recurrence64(q, k, v, g, beta, s0):
     return np.stack(out), np.stack(after)
 
 
-def chunk_form(q, k, v, g, beta, s0):
+def chunk_form(q, k, v, g, beta, s0, start=2):
     """One head's chunk in the matrix form (the module's docstring), in
     NumPy at the inputs' precision: ``q``, ``k`` [C, dk]; ``v`` [C, dv];
     ``g``, ``beta`` [C]; ``s0`` [dk, dv]. Returns (o [C, dv], the state
     after every token [C, dk, dv]). The algebra the kernel runs, its
-    ``unit_lower_inverse`` included."""
+    ``unit_lower_inverse`` included, the diagonal blocks of ``start`` by
+    rows."""
     c = q.shape[0]
     rows, cols = np.arange(c)[:, None], np.arange(c)[None, :]
     cum = np.cumsum(g)
     decay = np.where(rows >= cols, np.exp(np.minimum(cum[:, None] - cum[None, :], 0)), 0)
     a = np.where(rows > cols, beta[:, None] * decay * (k @ k.T), 0)
-    t = gd.unit_lower_inverse(a, rows, cols, np.matmul)
+    t = gd.unit_lower_inverse(a, rows, cols, np.matmul, start=start)
     u = t @ (beta[:, None] * v)
     w = t @ ((beta * np.exp(cum))[:, None] * k)
     vp = u - w @ s0
@@ -342,28 +371,36 @@ def chunk_form(q, k, v, g, beta, s0):
     return o, after
 
 
-@pytest.mark.parametrize("c", [2, 16, 64])
-def test_matrix_form_is_the_recurrence_in_float64(c):
+@pytest.mark.parametrize(
+    "c, start", [(2, 2), (16, 2), (64, 2), (128, 2), (16, 4), (64, 8), (128, 8), (128, 16),
+                 (128, 128)],
+)
+def test_matrix_form_is_the_recurrence_in_float64(c, start):
     """``chunk_form`` (the algebra the kernel runs, the triangular inverse
-    by blocks included) against the token recurrence, both in float64:
-    outputs and the state after every token."""
+    included: by rows inside diagonal blocks of ``start``, the kernel's 8
+    among them, and by blocks from there) against the token recurrence,
+    both in float64: outputs and the state after every token."""
     rng = np.random.default_rng(c)
-    q, k, v, g, beta = _inputs(rng, c, h=1, dk=12, dv=20, dtype=np.float64)
+    qkv, g, beta = _inputs(rng, c, h=1, dk=12, dv=20, dtype=np.float64)
+    q, k, v = qkv[:, :12], qkv[:, 12:24], qkv[:, 24:]
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / 12**0.5
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
     s0 = rng.standard_normal((12, 20))
-    o, after = chunk_form(q, k, v, g[:, 0], beta[:, 0], s0)
+    o, after = chunk_form(q, k, v, g[:, 0], beta[:, 0], s0, start)
     o_ref, after_ref = _recurrence64(q, k, v, g[:, 0], beta[:, 0], s0)
     assert np.abs(o - o_ref).max() < 1e-12
     assert np.abs(after - after_ref).max() < 1e-12
 
 
-def test_block_inverse_holds_where_a_power_series_would_not():
+@pytest.mark.parametrize("c, start", [(64, 2), (128, 2), (128, 8), (128, 16)])
+def test_block_inverse_holds_where_a_power_series_would_not(c, start):
     """Neighbouring keys that agree and steps of 2: ``A``'s entries reach
     2 and its powers 1e18, while the inverse stays of order 1. Forward
-    substitution in blocks has it to float64's rounding."""
-    c = 64
+    substitution, by rows in the diagonal blocks of ``start`` and in blocks
+    from there, has it to float64's rounding."""
     rows, cols = np.arange(c)[:, None], np.arange(c)[None, :]
     a = np.where(rows > cols, 1.99, 0.0)
-    t = gd.unit_lower_inverse(a, rows, cols, np.matmul)
+    t = gd.unit_lower_inverse(a, rows, cols, np.matmul, start=start)
     assert np.abs(t @ (np.eye(c) + a) - np.eye(c)).max() < 1e-12
     assert np.abs(t).max() < 2.0
 
@@ -395,16 +432,17 @@ def test_kernel_compiles_for_v5e_at_the_published_widths(one_chip, slots):
     assert gd.supported(h, dk, dv, 8)
     pool = (6, 160, dk, h * dv)
     args = (
-        sds((n, h * dk), f), sds((n, h * dk), f), sds((n, h * dv), f), sds((n, h), f),
-        sds((n, h), f), sds(pool, f), sds((), i), sds((33,), i), sds((n,), i), sds((n,), i),
-        sds((66,), i), sds((66,), i),
+        sds((n, h * (2 * dk + dv)), f), sds((n, h), f), sds((n, h), f), sds(pool, f), sds((), i),
+        sds((33,), i), sds((n,), i), sds((n,), i), sds((66,), i), sds((66,), i),
     )
-    compiled = jax.jit(gd.gated_delta_kernel, donate_argnums=(5,)).lower(*args).compile()
+    compiled = jax.jit(gd.gated_delta_kernel, donate_argnums=(3,)).lower(*args).compile()
     mem = compiled.memory_analysis()
     pool_bytes = int(np.prod(pool)) * 4
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 8
-    assert "gated_delta_chunk" in compiled.as_text()
+    # what the trace's readers find the call by: its name, and o first among its results
+    (call,) = re.findall(r"= \((.*?)\) custom-call\(.*gated_delta_chunk", compiled.as_text())
+    assert call.startswith(f"f32[{max(n, gd.CHUNK)},{h * dv}]") and f"f32[{','.join(map(str, pool))}]" in call
 
 
 @pytest.mark.parametrize("rows, plain_temp_mb", [(32, 243), (64, 487)])
