@@ -183,7 +183,7 @@ class BlockDecode:
                     params, mcfg, fed.reshape(-1), pos.reshape(-1), of,
                     table, k, v, out, attn_pages=pages, attn_impl=impl,
                     q_tile=B, mesh=mesh, interpret=interpret,
-                    experts_any_rows=True, project=False,
+                    project=False,
                 )
                 logits = final_logits(params, mcfg, hidden)
                 tokens, fixed_at, lps = block_pass(

@@ -1594,7 +1594,6 @@ class TPUEngine(BlockDecode, AsyncEngine):
             full_sampler, want_lp, with_spec,
         )
         tel = get_telemetry()
-        block = self.cfg.model.block_length
         tel.ragged_dispatches.labels(
             program,
             str(nb),
@@ -1616,12 +1615,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
                     else singles if self._attn_impl == "pallas" else 0
                 )
         if self.cfg.model.is_moe:
-            # nb: a window's rows a step, a mixed dispatch's flat tokens; a
-            # block program's rows are blocks, read in place at any count.
-            by_block = bool(block and windowed)
             tel.moe_dispatches.labels(routed_experts_form(
-                self.cfg.model, self.mesh, nb * block if by_block else nb,
-                self._attn_interpret, any_rows=by_block,
+                self.cfg.model, self.mesh, self._attn_interpret
             )).inc()
         return key
 

@@ -26,9 +26,9 @@ SURVEY.md §2.3):
   attention projections, the router, a dense model's whole feed-forward)
   rides ``xs`` as before: XLA fuses those slices into the dots that
   read them. So do the expert matrices of a forward whose matmuls take
-  ``ragged_dot`` on the slice: off the TPU, and on it a forward of more
-  rows than ``ops/moe.py::IN_PLACE_MAX_ROWS`` (the mixed program's
-  prefill; the bound and why it is there are written beside it).
+  ``ragged_dot`` on the slice: off the TPU, and widths the kernel cannot
+  tile (``ops/moe.py::experts_in_place``; a forward's row count is no
+  part of the rule).
 - **One forward for prefill and decode**: write-then-gather paged
   attention (see ``ops/attention.py``) with static (B, T, Pmax) buckets.
 - **bfloat16 everywhere the MXU touches**, float32 for norms/softmax/rope.
@@ -1252,16 +1252,16 @@ def _conv_half(x, lp, cfg, eps, pool, layer, io):
         return x + y.reshape(x.shape), pool
 
 
-def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows=False,
-              tally=None):
+def _ffn_half(x, lp, cfg, eps, red, mesh, live, layer, interpret, tally=None):
     with jax.named_scope("moe" if "router" in lp else "ffn"):
         return _ffn_block(
-            x, lp, cfg, eps, red, mesh, live, layer, interpret, any_rows, tally
+            x, lp, cfg, eps, red, mesh, live, layer, interpret, tally=tally
         )
 
 
 def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=False,
-               any_rows=False, tally=None):
+               any_rows=False,  # unused: benchmark/comparisons/block_diffusion.py:205 passes a tenth
+               tally=None):
     """The layer's second half: norm, dense SwiGLU or sparse-MoE FFN,
     residual add. ``live`` ([B, T] bool) keeps a dispatch's padding
     rows out of the routed experts' sort (``ops/moe.py::moe_ffn``);
@@ -1332,7 +1332,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
                 live=None if live is None else live.reshape(B * T),
                 layer=layer, mesh=mesh, interpret=interpret,
                 score=cfg.router_score, select_bias=lp.get("router_bias"),
-                scale=cfg.routed_scaling_factor, any_rows=any_rows,
+                scale=cfg.routed_scaling_factor,
                 norm_eps=cfg.router_norm_eps,
                 held_from=cfg.first_expert_held if cfg.num_experts_held else None,
                 tally=tally,
@@ -1359,23 +1359,17 @@ def _expert_parallel(mesh) -> bool:
     return mesh is not None and mesh.shape.get("ep", 1) > 1
 
 
-def routed_experts_form(
-    cfg: ModelConfig, mesh, tokens: int, interpret: bool = False,
-    any_rows: bool = False,
-) -> str:
-    """How a paged forward over ``tokens`` rows has its expert matmuls
-    read their weights on this mesh, by the rule the forwards themselves
-    apply (``_scan_paged_layers``): ``"inplace"`` (the stacks closed over,
-    ``ops/expert_gmm.py`` reading layer ``l``) or ``"sliced"`` (a layer's
-    copy handed to ``ragged_dot`` or to ``moe_ffn_ep``). What
-    ``dynamo_moe_dispatch_total`` is labelled by."""
+def routed_experts_form(cfg: ModelConfig, mesh, interpret: bool = False) -> str:
+    """How a paged forward has its expert matmuls read their weights on
+    this mesh, whatever rows it carries, by the rule the forwards
+    themselves apply (``_scan_paged_layers``): ``"inplace"`` (the stacks
+    closed over, ``ops/expert_gmm.py`` reading layer ``l``) or
+    ``"sliced"`` (a layer's copy handed to ``ragged_dot`` or to
+    ``moe_ffn_ep``). What ``dynamo_moe_dispatch_total`` is labelled by."""
     from ..ops.moe import experts_in_place
 
-    # held experts' rows take the kernel at any count (``moe_ffn``)
     in_place = not _expert_parallel(mesh) and experts_in_place(
-        cfg.hidden_size, cfg.expert_intermediate_size,
-        tokens * cfg.num_experts_per_tok, mesh, interpret,
-        any_rows or bool(cfg.num_experts_held),
+        cfg.hidden_size, cfg.expert_intermediate_size, mesh, interpret
     )
     return "inplace" if in_place else "sliced"
 
@@ -1496,7 +1490,7 @@ def _loop_passes(params, cfg, carry, run_layers):
 def _scan_paged_layers(
     params, cfg, x, k_cache, v_cache, rope_pos, write_at, table, read, mesh,
     live, interpret=False, seq_state=None, state_io=None, scan_form=ScanForm(),
-    experts_any_rows=False, count_picks=False, take_kept=False,
+    count_picks=False, take_kept=False,
 ):
     """The layer loop of both paged forwards: one ``lax.scan`` for each
     run of layers of one kind (``ModelConfig.layer_runs``), carrying
@@ -1552,12 +1546,12 @@ def _scan_paged_layers(
     A routed model's expert matrices (``EXPERT_STACKS``) leave ``xs`` for
     the same reason the pools left ``ys``: the body closes over the whole
     stacks and ``moe_ffn(layer=l)`` addresses routed layer ``l`` in them.
-    That is where ``routed_experts_form`` says ``inplace`` for this
-    forward's rows. Everywhere else a one-run model's ``xs`` are all of
-    ``params["layers"]``, as ever: a dense model, experts sharded over
-    ``ep`` (``moe_ffn_ep``), and a forward whose expert matmuls take
-    ``ragged_dot`` on the slice (off the TPU, or more rows than
-    ``ops/moe.py::IN_PLACE_MAX_ROWS``).
+    That is where ``routed_experts_form`` says ``inplace``, for a decode
+    window's rows and a prompt chunk's alike. Everywhere else a one-run
+    model's ``xs`` are all of ``params["layers"]``, as ever: a dense
+    model, experts sharded over ``ep`` (``moe_ffn_ep``), and a forward
+    whose expert matmuls take ``ragged_dot`` on the slice (off the TPU,
+    or widths the kernel cannot tile).
 
     A run that is not the whole of a stack closes over the stack and
     indexes it by the layer's ordinal, which is what ``scan`` does with
@@ -1577,9 +1571,10 @@ def _scan_paged_layers(
     inv_freq = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     eps = cfg.rms_norm_eps
     layers = params["layers"]
-    in_place = "router" in layers and routed_experts_form(
-        cfg, mesh, x.shape[0] * x.shape[1], interpret, experts_any_rows
-    ) == "inplace"
+    in_place = (
+        "router" in layers
+        and routed_experts_form(cfg, mesh, interpret) == "inplace"
+    )
     win_arr, invf_arr, _ = _per_layer_xs(cfg, inv_freq)
     if cfg.is_latent:
         invf_arr = jnp.tile(inv_freq[None], (L, 1))
@@ -1747,7 +1742,7 @@ def _scan_paged_layers(
                 x = _ffn_half(
                     x, lp, cfg, eps, red, mesh, live,
                     (i + ff0 if ff0 else i) if stacks else None, interpret,
-                    experts_any_rows, tally,
+                    tally,
                 )
                 picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
                 if by_kind:  # the other form's pool rides on untouched
@@ -2338,7 +2333,6 @@ def forward_ragged(
     interpret: bool = False,
     seq_state: jnp.ndarray | None = None,
     state_rows: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None = None,
-    experts_any_rows: bool = False,
     project: bool = True,
     count_picks: bool = False,  # ``forward``'s
     kept_of: jnp.ndarray | None = None,  # ``forward``'s: slots of the [N] stream
@@ -2352,9 +2346,7 @@ def forward_ragged(
     embedding and the page writes stay at ``p``. The caller feeds whole
     blocks (every position of a block a query of this dispatch, or
     written by an earlier one), since the write of a span's K/V precedes
-    the read in the same layer. ``experts_any_rows``: the routed experts
-    read their weights in place at any row count (the block program;
-    ``ops/moe.py::experts_in_place``).
+    the read in the same layer.
 
     The kernel reads the stream in tiles of ``q_tile`` slots, one row a
     tile. With ``packed_from`` (static, a multiple of ``q_tile``) only
@@ -2580,7 +2572,7 @@ def forward_ragged(
         write_at, attn_table, read, mesh,
         (positions >= 0)[None], interpret, seq_state, state_io,
         _scan_form(cfg, attn_impl, q_tile, packed_from, interpret),
-        experts_any_rows, count_picks, kept_of is not None,
+        count_picks, kept_of is not None,
     )
     xo = x[0][out_idx]  # [M, D] — only sampled positions reach lm_head
     logits = _final_logits(params, cfg, xo, eps) if project else xo

@@ -12,6 +12,10 @@ This kernel takes the whole stack and ``layer`` as a scalar-prefetched
 value, so a weight block's index map is ``(layer, group, k, n)``: the
 DMAs read layer ``l``'s blocks where they lie and nothing is copied. A
 per-layer ``[E, K, N]`` array is the same call on ``w[None]`` at layer 0.
+Every routed forward on a TPU takes it, a decode window's few sorted rows
+and a prompt chunk's thousands alike: it is ahead of the copy and
+``ragged_dot`` at every row count (``tools/expert_gmm_rows.py``; PERF.md,
+PR 60).
 
 The grid is ``(n tiles, row tiles, k tiles)`` in the MegaBlocks manner
 (``jax.experimental.pallas.ops.tpu.megablox``): a *row tile* is one

@@ -17,7 +17,7 @@ import jax
 from dynamo_exp_tpu.models import llama
 
 _FORWARD_STATIC = ("attn_pages", "attn_impl", "mesh", "interpret", "count_picks")
-_RAGGED_STATIC = (*_FORWARD_STATIC, "q_tile", "packed_from", "experts_any_rows", "project")
+_RAGGED_STATIC = (*_FORWARD_STATIC, "q_tile", "packed_from", "project")
 
 # The seeded weights as ONE program a configuration: eager, every array of the
 # tree is a draw compiled by itself (8 s for a tiny model; 4 s jitted). The

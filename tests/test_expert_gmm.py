@@ -12,6 +12,7 @@ off the chip's trace (``expert_slab_copy_time_share``) and, here, off
 what XLA:TPU compiles for a described ``v5e:2x2`` (the last tests)."""
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -120,6 +121,41 @@ def test_the_kernel_is_ragged_dot_within_bfloat16_rounding(rows, routing, live_s
     )
 
 
+@pytest.mark.parametrize("experts", [8, 32])
+@pytest.mark.parametrize("sorted_rows", [257, 1024, 4096])
+def test_a_dispatch_of_many_rows_through_the_kernel_is_ragged_dot_on_the_slice(
+    sorted_rows, experts
+):
+    """``moe_ffn`` at a prompt chunk's row counts (tokens x experts a
+    token: more than one block of ``MAX_TM``, which until PR 60 took
+    ``ragged_dot``), stacked weights and a TRACED ``layer``, some rows
+    padding (``live``): the kernel (interpreted) against ``ragged_dot`` on
+    that layer's slice, which is what the same call computes off the TPU."""
+    d, width, top_k = 128, 128, 1 if sorted_rows % 2 else 2
+    tokens = sorted_rows // top_k
+    router, wg, wu, wd = _weights(jax.random.PRNGKey(experts), d, width, experts=experts)
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, d), jnp.float32)
+    x = x.astype(jnp.bfloat16)
+    live = jnp.arange(tokens) % 5 != 0
+
+    @functools.partial(jax.jit, static_argnames="interpret")
+    def run(x, router, wg, wu, wd, live, layer, interpret):
+        return moe.moe_ffn(
+            x, router[layer], wg, wu, wd, top_k, True, live=live, layer=layer,
+            interpret=interpret,
+        )
+
+    args = (x, router, wg, wu, wd, live, jnp.int32(L - 1))
+    text = str(jax.make_jaxpr(functools.partial(run, interpret=True))(*args))
+    assert "expert_gmm" in text and "ragged_dot" not in text
+    got, want = (
+        np.asarray(run(*args, interpret=i).astype(jnp.float32)) for i in (True, False)
+    )
+    assert np.isfinite(got).all() and not got[~np.asarray(live)].any()
+    # a bfloat16 step of the largest value, for each of a token's picks
+    assert np.abs(got - want).max() <= np.abs(want).max() * 2.0**-7 * top_k
+
+
 @pytest.mark.parametrize("rows", [64, 512])
 def test_a_rows_bits_do_not_turn_with_its_place_among_the_sorted_rows(rows):
     """The same rows of one group, placed at another offset among other
@@ -163,8 +199,9 @@ def test_tiling_is_a_function_of_shapes_and_k_alone_splits_the_sum():
 MOE_128 = dataclasses.replace(  # widths the kernel can tile: 128 lanes
     TINY_MOE, hidden_size=128, intermediate_size=128, num_heads=4, num_kv_heads=2,
 )
-# One token more than takes the kernel (ops/moe.py::IN_PLACE_MAX_ROWS).
-MANY_TOKENS = moe.IN_PLACE_MAX_ROWS // MOE_128.num_experts_per_tok + 1
+# A stream of more sorted rows (tokens x experts a token) than one row block
+# of the kernel holds: a prompt chunk's, where a decode window's are fewer.
+MANY_TOKENS = expert_gmm.MAX_TM // MOE_128.num_experts_per_tok + 1
 
 
 def _layer_scan(jaxpr):
@@ -187,8 +224,11 @@ def _ragged_jaxpr(cfg, n=16, **kw):
     return jax.make_jaxpr(lambda p, *a: llama.forward_ragged(p, cfg, *a, **kw))(params, *args).jaxpr, params
 
 
-def test_no_expert_matrix_rides_the_layer_scans_xs():
-    jaxpr, params = _ragged_jaxpr(MOE_128, interpret=True)
+@pytest.mark.parametrize("n", [16, MANY_TOKENS], ids=["few-rows", "many-rows"])
+def test_no_expert_matrix_rides_the_layer_scans_xs(n):
+    jaxpr, params = _ragged_jaxpr(MOE_128, n, interpret=True)
+    text = str(jaxpr)
+    assert "expert_gmm" in text and "ragged_dot" not in text
     xs, consts = _layer_scan(jaxpr)
     stacks = {params["layers"][k].shape for k in llama.EXPERT_STACKS}
     assert all(len(s) == 4 for s in stacks)
@@ -202,16 +242,13 @@ def test_no_expert_matrix_rides_the_layer_scans_xs():
     assert params["layers"]["wq"].shape in [a.shape for a in xs]
 
 
-@pytest.mark.parametrize(
-    "cfg,n,interpret",
-    [(TINY_MOE, 16, False), (TINY_MOE, 16, True), (MOE_128, MANY_TOKENS, True)],
-    ids=["no-tpu", "widths-untiled", "many-rows"],
-)
-def test_a_forward_that_takes_ragged_dot_scans_its_experts_as_it_did(cfg, n, interpret):
+@pytest.mark.parametrize("interpret", [False, True], ids=["no-tpu", "widths-untiled"])
+def test_a_forward_that_takes_ragged_dot_scans_its_experts_as_it_did(interpret):
     """Where the matmuls take ``ragged_dot`` on the slice, the expert
     matrices ride ``xs`` with the rest of the layer, as before PR 28."""
-    assert llama.routed_experts_form(cfg, None, n, interpret) == "sliced"
-    jaxpr, params = _ragged_jaxpr(cfg, n, interpret=interpret)
+    cfg = TINY_MOE  # 64 x 96: widths the kernel cannot tile
+    assert llama.routed_experts_form(cfg, None, interpret) == "sliced"
+    jaxpr, params = _ragged_jaxpr(cfg, interpret=interpret)
     xs, consts = _layer_scan(jaxpr)
     stacks = [params["layers"][k].shape for k in llama.EXPERT_STACKS]
     assert sorted(a.shape for a in xs if a.shape in stacks) == sorted(stacks)
@@ -220,13 +257,28 @@ def test_a_forward_that_takes_ragged_dot_scans_its_experts_as_it_did(cfg, n, int
     assert "ragged_dot" in text and "expert_gmm" not in text
 
 
-def test_the_row_bound_is_one_row_block_and_a_decode_window_is_under_it():
-    assert moe.IN_PLACE_MAX_ROWS == expert_gmm.MAX_TM
-    d, width = 4096, 14336  # Mixtral's, two experts a token
-    for rows, want in [(2, True), (128, True), (256, True), (257, False), (4096, False)]:
-        assert moe.experts_in_place(d, width, rows, None, interpret=True) is want
-        assert not moe.experts_in_place(d, width, rows, None)  # no TPU here
-    assert llama.routed_experts_form(MOE_128, None, MANY_TOKENS - 1, True) == "inplace"
+@pytest.mark.parametrize(
+    "hidden,width,tp,want",
+    [
+        (4096, 14336, 1, True),  # Mixtral's
+        (4096, 14336, 4, True),  # a chip's 3584 columns of them
+        (2048, 1792, 1, True),  # LFM2's
+        (2048, 768, 1, True),  # SDAR's
+        (64, 96, 1, False),  # no lane dimension of a block
+        (128, 384, 2, False),  # tiled whole, not a chip's 192 columns
+        (128, 384, 4, False),  # 96 a chip
+        (128, 640, 4, False),  # 160 a chip
+    ],
+)
+def test_the_kernel_is_taken_by_place_and_widths_never_by_rows(hidden, width, tp, want):
+    """``experts_in_place`` looks at where the mesh sits (or ``interpret``)
+    and at a chip's share of the widths: it is not told a call's rows, so a
+    prompt chunk and a decode window read their experts the same way."""
+    mesh = None
+    if tp > 1:
+        mesh = Mesh(np.array(jax.devices()[:tp]).reshape(1, 1, 1, tp), ("dp", "sp", "ep", "tp"))
+    assert moe.experts_in_place(hidden, width, mesh, interpret=True) is want
+    assert not moe.experts_in_place(hidden, width, mesh)  # no TPU here
 
 
 def test_a_dense_models_scan_is_what_it_was():
@@ -245,9 +297,9 @@ def test_the_forward_with_the_kernel_reads_the_stack_and_agrees_with_the_slice()
     without (``ragged_dot`` on the slice): the kernel's call takes the
     4-D stacks, and the logits agree within bfloat16 rounding."""
     cfg = MOE_128
-    assert llama.routed_experts_form(cfg, None, 16, interpret=True) == "inplace"
-    assert llama.routed_experts_form(cfg, None, 16) == "sliced"  # no TPU here
-    assert llama.routed_experts_form(TINY_MOE, None, 16, interpret=True) == "sliced"  # 64 x 96
+    assert llama.routed_experts_form(cfg, None, interpret=True) == "inplace"
+    assert llama.routed_experts_form(cfg, None) == "sliced"  # no TPU here
+    assert llama.routed_experts_form(TINY_MOE, None, interpret=True) == "sliced"  # 64 x 96
     params = init_params(jax.random.PRNGKey(0), cfg)
     k, v = init_kv_cache(cfg, 8, 8)
     n = 16
@@ -279,8 +331,8 @@ def test_under_a_tp_mesh_the_wrapper_gives_the_unsharded_result_and_gathers_no_w
     router, wg, wu, wd = _weights(jax.random.PRNGKey(3), d, width)
     x = jax.random.normal(jax.random.PRNGKey(4), (rows, d), jnp.float32).astype(jnp.bfloat16)
     live = jnp.asarray(np.random.default_rng(1).random(rows) < 0.7)
-    assert moe.experts_in_place(d, width, rows * TOPK, mesh, interpret=True)
-    assert not moe.experts_in_place(d, width, rows * TOPK, mesh)  # a CPU mesh: ragged_dot under GSPMD
+    assert moe.experts_in_place(d, width, mesh, interpret=True)
+    assert not moe.experts_in_place(d, width, mesh)  # a CPU mesh: ragged_dot under GSPMD
     put = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
     sharded_w = (
         put(wg, P(None, None, None, "tp")), put(wu, P(None, None, None, "tp")),
@@ -468,34 +520,49 @@ def _grew(before: dict, after: dict) -> dict:
 
 
 @pytest.mark.parametrize(
-    "model,interpret,label",
-    [(TINY_MOE, False, "sliced"), (MOE_128, True, "inplace"), (TINY, False, None)],
-    ids=["cpu-sliced", "interpret-inplace", "dense-none"],
+    "model,interpret,label,prompt",
+    [
+        (TINY_MOE, False, "sliced", 9), (MOE_128, True, "inplace", 9),
+        # a prompt chunk of more sorted rows than one row block of the kernel
+        (MOE_128, True, "inplace", 160), (TINY, False, None, 9),
+    ],
+    ids=["cpu-sliced", "interpret-inplace", "interpret-inplace-many-rows", "dense-none"],
 )
 async def test_every_dispatch_of_a_routed_model_counts_once_under_how_it_read_its_experts(
-    model, interpret, label
+    model, interpret, label, prompt
 ):
     from dynamo_exp_tpu.engine import EngineConfig, TPUEngine
     from dynamo_exp_tpu.parallel import single_device_mesh
     from dynamo_exp_tpu.protocols.common import BackendInput
 
+    # pages of 32: few page copies a chunk for the interpreted kernel to lower
+    pool = dict(page_size=32, num_pages=16, max_model_len=256) if prompt > 9 else dict(
+        page_size=8, num_pages=32, max_model_len=64
+    )
     cfg = EngineConfig(
-        model=model, max_decode_slots=2, page_size=8, num_pages=32, max_model_len=64,
-        eos_token_ids=[], pallas_interpret=interpret, decode_window=2,
+        model=model, max_decode_slots=2, eos_token_ids=[], pallas_interpret=interpret,
+        decode_window=2, **pool,
     )
     engine = TPUEngine(cfg, mesh=single_device_mesh(), seed=0)
     engine.start()
     try:
         d0, m0 = _counter("dynamo_ragged_dispatch_total"), _counter("dynamo_moe_dispatch_total")
-        b = BackendInput(token_ids=list(range(5, 14)))
+        b = BackendInput(token_ids=[5 + i % 200 for i in range(prompt)])
         b.stop_conditions.max_tokens = 5
         b.stop_conditions.ignore_eos = True
         out = []
         async for item in await engine.generate(b.to_dict()):
             out += item.get("token_ids", [])
         assert len(out) == 5
-        dispatches = sum(_grew(d0, _counter("dynamo_ragged_dispatch_total")).values())
+        by_variant = _grew(d0, _counter("dynamo_ragged_dispatch_total"))
+        dispatches = sum(by_variant.values())
         assert dispatches >= 2  # a mixed dispatch and a window at the least
+        if prompt > 9:  # its chunk went as ONE mixed dispatch of that many rows
+            slots = max(
+                int(re.search(r'nb="(\d+)"', k).group(1))
+                for k in by_variant if 'program="mixed"' in k
+            )
+            assert slots * model.num_experts_per_tok > expert_gmm.MAX_TM
         grew = _grew(m0, _counter("dynamo_moe_dispatch_total"))
         assert grew == ({} if label is None else {'{experts="%s"}' % label: dispatches})
     finally:

@@ -72,6 +72,8 @@ async def test_engine_serves_windows_and_chunks(engine):
     say what was scored and what was attended."""
     assert engine.k_cache.shape == engine.v_cache.shape == (3, 64, PS, 128)
     before = {k: _counter(k) for k in _COUNTERS}
+    # this worker's earlier files may have served latent prompts (test_pangu.py)
+    expanded = _counter("dynamo_mla_query_tokens_total", form="expanded")
     prompt = PROMPT[:40]
     got = await _run(engine, prompt, 20)
     assert got == _greedy_reference(engine.params, prompt, 20)
@@ -86,7 +88,7 @@ async def test_engine_serves_windows_and_chunks(engine):
     assert took["dynamo_dsa_selected_pairs_total"] < took["dynamo_dsa_index_pairs_total"]
     assert _counter("dynamo_dsa_query_tokens_total", selecting="selecting") >= fed - TOPK
     assert _counter("dynamo_dsa_query_tokens_total", program="window", selecting="all") == 0
-    assert _counter("dynamo_mla_query_tokens_total", form="expanded") == 0
+    assert _counter("dynamo_mla_query_tokens_total", form="expanded") == expanded
 
 
 def test_the_counters_arithmetic():

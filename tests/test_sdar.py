@@ -580,15 +580,14 @@ def test_tiles_of_the_accepted_shapes_are_pinned_and_many_groups_take_smaller_bl
 
 
 def test_the_block_program_reads_experts_in_place_at_any_row_count():
-    """``any_rows``: the kernel (interpreted) at 2048 sorted rows over 8
-    groups gives ``ragged_dot``'s numbers; the mixed program's rule is
-    what it was."""
+    """The kernel (interpreted) at 2048 sorted rows over 8 groups gives
+    ``ragged_dot``'s numbers, and the forwards' rule says so whatever the
+    rows: a block program's pass and a mixed dispatch alike."""
     from dynamo_exp_tpu.ops import moe
 
     cfg = ModelConfig.from_hf_config(_hf(hidden_size=128, head_dim=32))
-    assert llama.routed_experts_form(cfg, None, 1024, True) == "sliced"
-    assert llama.routed_experts_form(cfg, None, 1024, True, any_rows=True) == "inplace"
-    assert llama.routed_experts_form(cfg, None, 1024, False, any_rows=True) == "sliced"
+    assert llama.routed_experts_form(cfg, None, True) == "inplace"
+    assert llama.routed_experts_form(cfg, None, False) == "sliced"
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.standard_normal((1024, 128)), jnp.float32)
     router = jnp.asarray(rng.standard_normal((128, 8)), jnp.float32)
@@ -596,7 +595,7 @@ def test_the_block_program_reads_experts_in_place_at_any_row_count():
     w2 = jnp.asarray(rng.standard_normal((8, 128, 128)) * 0.1, jnp.float32)
     live = jnp.arange(1024) % 7 != 0
     args = (x, router, w1, w3, w2, 2, True, live)
-    got = moe.moe_ffn(*args, interpret=True, any_rows=True)
+    got = moe.moe_ffn(*args, interpret=True)
     want = moe.moe_ffn(*args)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 

@@ -1,31 +1,38 @@
 #!/usr/bin/env python3
-"""The grouped expert matmul at many rows over many groups: the kernel's
-row tile against ``ragged_dot``.
+"""The grouped expert matmul by row count: the kernel reading a layer of the
+stack in place against ``ragged_dot`` on the layer's copy.
 
-    chiprun -- python3 tools/expert_gmm_rows.py [--rows 2048] [--experts 128]
+    chiprun -- python3 tools/expert_gmm_rows.py [--config sdar,lfm2,mixtral-shard]
+        [--rows 512,1024,2048,4096,8192] [--tm-sweep]
 
-At SDAR-30B-A3B's expert shapes (128 experts of [2048, 768] and
-[768, 2048], bfloat16, a stack of ``--layers`` layers) and ``--rows``
-sorted rows spread over the experts as a softmax router over seeded
-scores spreads them (top-8 of 128: every expert gets some), this times
-one matrix product on an idle chip: ``--reps`` products chained inside one
-program (each takes the one before it as a zero addend, so none is
-hoisted), the program's time over ``--reps``, the median of
-``--iterations`` such programs, so that a call's host overhead (about a
-millisecond here) is not in the number:
+At a configuration's expert shapes (``--config``: SDAR-30B-A3B's 128
+experts of [2048, 768], LFM2-8B-A1B's 32 of [2048, 1792], one chip's shard
+of Mixtral-8x7B's 8 of [4096, 3584]; or ``--experts --hidden --width
+--top-k`` for any other), bfloat16, a stack of ``--layers`` layers, and
+``--rows`` sorted rows spread over the experts as a router over seeded
+scores spreads them (top-k of E), this times one matrix product on an idle
+chip, the gate/up orientation [hidden, width] and the down one: ``--reps``
+products chained inside one program (each takes the one before it as a
+zero addend, so none is hoisted), the program's time over ``--reps``, the
+median of ``--iterations`` such programs, so that a call's host overhead
+(about a millisecond here) is not in the number:
 
-- ``gmm_tm<N>``: ``ops/expert_gmm.py`` reading layer 3 of the stack in
-  place with a row tile of N (``ROWS_OVER_MEAN_GROUP`` set to N over the
-  mean group's 16 rows); ``chosen`` marks the one ``tiles`` gives;
+- ``gmm``: ``ops/expert_gmm.py`` reading layer 3 of the stack in place
+  with the row tile ``tiles`` gives (``tm``), which is what every routed
+  forward on a TPU runs (``ops/moe.py::experts_in_place``);
+- ``gmm_tm<N>`` (``--tm-sweep``): the same with a row tile of N
+  (``ROWS_OVER_MEAN_GROUP`` set to N over the mean group), where the
+  shapes allow that tile;
 - ``ragged_dot_slice``: ``jax.lax.ragged_dot`` on layer 3 taken out of
-  the stack inside the program (the copy the mixed program's prefill
-  makes: ``ops/moe.py::IN_PLACE_MAX_ROWS``);
+  the stack inside the program (the copy a forward makes off the TPU's
+  kernel, and the mixed program's prefill made until PR 60);
 - ``ragged_dot_layer``: the same on a layer that was never in a stack (no
   copy: what ``ragged_dot`` alone costs).
 
-Beside each, the least the shapes allow (``hbm_s``: the experts' bytes
-once over the chip's HBM rate; ``mxu_s``: the operations over its bf16
-rate). A micro-benchmark, not code a cell runs (PERF.md section 6, PR 37).
+Beside each, the least the shapes allow (``hbm_s``: the bytes of the
+experts that have a row, once, over the chip's HBM rate; ``mxu_s``: the
+operations over its bf16 rate). A micro-benchmark, not code a cell runs
+(PERF.md section 6, PR 37 and PR 60).
 """
 
 from __future__ import annotations
@@ -41,10 +48,23 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+# (experts, hidden, width a chip, experts a token)
+CONFIGS = {
+    "sdar": (128, 2048, 768, 8),
+    "lfm2": (32, 2048, 1792, 4),
+    "mixtral-shard": (8, 4096, 3584, 2),  # 14336 over tp=4
+}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--rows", type=int, default=2048)
-    ap.add_argument("--experts", type=int, default=128)
+    ap.add_argument("--config", default="sdar", help="names of CONFIGS, comma-separated")
+    ap.add_argument("--experts", type=int)
+    ap.add_argument("--hidden", type=int)
+    ap.add_argument("--width", type=int)
+    ap.add_argument("--top-k", type=int)
+    ap.add_argument("--rows", default="2048", help="sorted rows a call, comma-separated")
+    ap.add_argument("--tm-sweep", action="store_true")
     ap.add_argument("--layers", type=int, default=6)
     ap.add_argument("--iterations", type=int, default=10)
     ap.add_argument("--reps", type=int, default=20)
@@ -60,56 +80,72 @@ def main() -> int:
         raise SystemExit("no TPU: these are device times")
     with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
         peak = json.load(f)["devices"][jax.devices()[0].device_kind]
-    E, M, L = args.experts, args.rows, args.layers
-    rng = np.random.default_rng(0)
-    scores = rng.standard_normal((M // 8, E))
-    ids = np.argsort(-scores, axis=1)[:, :8].reshape(-1)
-    sizes = jnp.asarray(np.bincount(ids, minlength=E), jnp.int32)
-    out = {"rows": M, "experts": E, "largest_group": int(sizes.max()), "results": []}
-    for K, N in ((2048, 768), (768, 2048)):
-        key = jax.random.PRNGKey(K)
-        lhs = jax.random.normal(key, (M, K), jnp.bfloat16)
-        stack = jax.random.normal(key, (L, E, K, N), jnp.bfloat16) * 0.02
-        layer = jnp.array(stack[3])
-        hbm_s = (E * K * N * 2 + M * K * 2 + M * N * 2) / (peak["hbm_gbps"] * 1e9)
-        mxu_s = 2 * M * K * N / (peak["bf16_tflops"] * 1e12)
+    L = args.layers
 
-        def timed(product, x, w, s):
-            @jax.jit
-            def chained(x, w, s):
-                def body(_, x):
-                    return x + (product(x, w, s)[:, :1] * 0).astype(x.dtype)
+    def timed(product, x, w, s):
+        @jax.jit
+        def chained(x, w, s):
+            def body(_, x):
+                return x + (product(x, w, s)[:, :1] * 0).astype(x.dtype)
 
-                return jax.lax.fori_loop(0, args.reps, body, x)
+            return jax.lax.fori_loop(0, args.reps, body, x)
 
+        jax.block_until_ready(chained(x, w, s))
+        took = []
+        for _ in range(args.iterations):
+            t = time.perf_counter()
             jax.block_until_ready(chained(x, w, s))
-            took = []
-            for _ in range(args.iterations):
-                t = time.perf_counter()
-                jax.block_until_ready(chained(x, w, s))
-                took.append((time.perf_counter() - t) / args.reps)
-            return statistics.median(took)
+            took.append((time.perf_counter() - t) / args.reps)
+        return statistics.median(took)
 
-        row = {"k": K, "n": N, "hbm_s": hbm_s, "mxu_s": mxu_s}
-        as_served = expert_gmm.ROWS_OVER_MEAN_GROUP
-        chosen = expert_gmm.tiles(M, K, N, 2, E)[0]
-        mean = max(M // E, 1)
-        for tm in (32, 64, 128, 256):
-            expert_gmm.ROWS_OVER_MEAN_GROUP = max(tm // mean, 1)
-            expert_gmm.expert_gmm.clear_cache()
-            assert expert_gmm.tiles(M, K, N, 2, E)[0] == tm, (tm, expert_gmm.tiles(M, K, N, 2, E))
-            row[f"gmm_tm{tm}" + ("_chosen" if tm == chosen else "")] = timed(
-                lambda x, w, s: expert_gmm.expert_gmm(x, w, s, 3), lhs, stack, sizes
-            )
-        expert_gmm.ROWS_OVER_MEAN_GROUP = as_served
-        expert_gmm.expert_gmm.clear_cache()
-        row["ragged_dot_slice"] = timed(
-            lambda x, w, s: jax.lax.ragged_dot(x, w[3], s), lhs, stack, sizes)
-        row["ragged_dot_layer"] = timed(jax.lax.ragged_dot, lhs, layer, sizes)
-        out["results"].append(row)
-        print(json.dumps(row), flush=True)
+    def in_place(x, w, s):
+        return expert_gmm.expert_gmm(x, w, s, 3)
+
+    explicit = (args.experts, args.hidden, args.width, args.top_k)
+    shapes = (
+        {"custom": explicit} if all(explicit)
+        else {name: CONFIGS[name] for name in args.config.split(",")}
+    )
+    out = []
+    for name, (E, hidden, width, top_k) in shapes.items():
+        for K, N in ((hidden, width), (width, hidden)):
+            key = jax.random.PRNGKey(K)
+            stack = jax.random.normal(key, (L, E, K, N), jnp.bfloat16) * 0.02
+            layer = jnp.array(stack[3])
+            for M in (int(m) for m in args.rows.split(",")):
+                rng = np.random.default_rng(0)
+                scores = rng.standard_normal((M // top_k, E))
+                ids = np.argsort(-scores, axis=1)[:, :top_k].reshape(-1)
+                sizes = jnp.asarray(np.bincount(ids, minlength=E), jnp.int32)
+                lhs = jax.random.normal(key, (M, K), jnp.bfloat16)
+                fetched = int((sizes > 0).sum())
+                chosen = expert_gmm.tiles(M, K, N, 2, E)[0]
+                row = {
+                    "config": name, "experts": E, "k": K, "n": N, "rows": M,
+                    "largest_group": int(sizes.max()), "tm": chosen,
+                    "hbm_s": (fetched * K * N * 2 + M * K * 2 + M * N * 2)
+                    / (peak["hbm_gbps"] * 1e9),
+                    "mxu_s": 2 * M * K * N / (peak["bf16_tflops"] * 1e12),
+                    "gmm": timed(in_place, lhs, stack, sizes),
+                }
+                if args.tm_sweep:
+                    as_served = expert_gmm.ROWS_OVER_MEAN_GROUP
+                    for tm in (32, 64, 128, 256):
+                        expert_gmm.ROWS_OVER_MEAN_GROUP = max(tm * E // M, 1)
+                        if tm != chosen and expert_gmm.tiles(M, K, N, 2, E)[0] == tm:
+                            expert_gmm.expert_gmm.clear_cache()
+                            row[f"gmm_tm{tm}"] = timed(in_place, lhs, stack, sizes)
+                    expert_gmm.ROWS_OVER_MEAN_GROUP = as_served
+                    expert_gmm.expert_gmm.clear_cache()
+                row["ragged_dot_slice"] = timed(
+                    lambda x, w, s: jax.lax.ragged_dot(x, w[3], s), lhs, stack, sizes)
+                row["ragged_dot_layer"] = timed(jax.lax.ragged_dot, lhs, layer, sizes)
+                out.append(row)
+                print(json.dumps(row), flush=True)
+            del stack, layer
     os.makedirs(os.path.join(ROOT, "chiprun_out", "expert_gmm_rows"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "expert_gmm_rows", f"rows{M}.json"), "w") as f:
+    name = "-".join(shapes) + "-rows" + args.rows.replace(",", "_")
+    with open(os.path.join(ROOT, "chiprun_out", "expert_gmm_rows", f"{name}.json"), "w") as f:
         json.dump(out, f, indent=1)
     return 0
 

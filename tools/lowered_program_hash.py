@@ -50,6 +50,12 @@ def main() -> int:
     import run_cell
     from dynamo_exp_tpu.models import ModelConfig, llama
 
+    # No chip is attached: the one rule that asks where a program runs
+    # (``ops/moe.py::experts_in_place``) gets the described chip's answer, so
+    # that a routed model's experts lower as they do there (the kernel on the
+    # stacks) and not as the CPU's ``ragged_dot`` on a slice.
+    jax.default_backend = lambda: "tpu"
+
     kernels: list[str] = []
     plain = tpu_custom_call._lower_mosaic_module_to_asm
 
@@ -117,7 +123,7 @@ def main() -> int:
             def block_pass(params, tokens, positions, row_of, table, k, v, out_idx):
                 return llama.forward_ragged(
                     params, mcfg, tokens, positions, row_of, table, k, v, out_idx,
-                    attn_impl="pallas", q_tile=B, experts_any_rows=True, project=False,
+                    attn_impl="pallas", q_tile=B, project=False,
                 )
 
             n = 64 * B
