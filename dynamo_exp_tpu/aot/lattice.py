@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import hashlib
 import json
-from dataclasses import asdict, dataclass, field
+from dataclasses import asdict, dataclass, field, fields
 
 
 def resolve_ragged_key(
@@ -302,11 +302,22 @@ class CompileManifest:
         return len(self.ragged) + len(self.move_buckets)
 
 
+# ModelConfig fields younger than manifests in use. The rule, once: such a
+# field is named only where a model sets it off its dataclass default, so the
+# manifests of the models that do not keep the hash they had before the field
+# was. A new field of ModelConfig is appended here.
+_LATER_FIELDS = ("mrope_section", "count_expert_reads")
+
+
 def _model_fingerprint(mcfg) -> dict:
     """Every ModelConfig field, JSON-normalized — a changed head count
-    or dtype must change the manifest hash."""
+    or dtype must change the manifest hash — but a ``_LATER_FIELDS`` one
+    at its default."""
+    defaults = {f.name: f.default for f in fields(mcfg) if f.name in _LATER_FIELDS}
     out = {}
     for k, v in sorted(asdict(mcfg).items()):
+        if k in defaults and v == defaults[k]:
+            continue
         out[k] = list(v) if isinstance(v, tuple) else v
     return out
 
@@ -337,6 +348,10 @@ def _attention_form(cfg, attn_impl: str, interpret: bool) -> str:
                 f"heads-sliding-{m.sliding_window}"
                 + ("-by-kind" if m.kv_by_kind else "") + shapes
             )
+        if m.has_indexer:
+            # a grouped-query read masked by the selector, whose key rides
+            # the K pool's row from that lane on
+            shapes += f"-index-key-at-{m.index_lane0}-selected-top-{m.index_topk}"
         return "heads" + shapes
     # the selector: every read masks what the top-k of the index scores
     # left out, inside whichever call reads the row

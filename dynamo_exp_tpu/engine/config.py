@@ -174,12 +174,28 @@ def refuse_latent_tiers(cfg: "EngineConfig | None", tier: str | None = None) -> 
       (``disagg/transfer.py``, ``runtime/reclaim.py``): the wire's page
       layout is the two pools'.
 
+    A grouped-query model with an indexer (``KeyeVL2``: K and V pools of
+    ``Hkv x D`` lanes, and ``index_lanes`` of index key behind the keys in
+    the K pool's row, ``ModelConfig.kv_row_parts``) is refused here by the
+    same tiers for the same reason: each would copy, split or rewind a K
+    row as ``Hkv x D`` lanes of keys and drop or misplace the third thing.
+
     With ``tier`` None the engine's own configuration is checked; with a
     name, that component is refused. Any other model passes, and so does
     ``cfg`` None."""
-    if cfg is None or not cfg.model.is_latent:
+    if cfg is None or not (cfg.model.is_latent or cfg.model.has_indexer):
         return
     armed = _page_tiers_armed(cfg, tier)
+    if armed and not cfg.model.is_latent:
+        m = cfg.model
+        raise ValueError(
+            f"model_type {m.model_type!r} caches three things a token: K and V "
+            f"of {m.num_kv_heads} x {m.head_dim_} and {m.index_head_dim} of "
+            f"index key in {m.index_lanes} lanes behind the keys in the K "
+            f"pool's row, which {', '.join(armed)} does not carry or split: it "
+            "moves pages as K and V of kv-head lanes. Serve it without "
+            "(README.md, models/)"
+        )
     if armed:
         raise ValueError(
             f"model_type {cfg.model.model_type!r} caches one latent row a "

@@ -238,8 +238,8 @@ class _PendingRagged:
     # includes the verify outputs (and the compiled variant is the
     # spec-carrying one).
     with_spec: bool = False
-    # Live token-steps the dispatch routes (a model that holds a share of
-    # its experts: ``_count_picks``): each makes ``num_experts_per_tok``
+    # Live token-steps the dispatch routes (``ModelConfig.counts_picks``:
+    # ``_count_picks``): each makes ``num_experts_per_tok``
     # picks a routed layer, and ``ys`` ends with how many were held.
     routed_rows: int = 0
     tokens_dev: object = None  # windowed carry: next window's tokens [nb]
@@ -553,14 +553,18 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # for failover replay pins the seed request-side instead.
         self._seed_rng = random.Random(seed + 1)
         self._attn_impl, self._attn_interpret = self._resolve_attn()
-        # Attention layers by form, of a model that has sliding ones, and a
-        # looped model's layer-passes as full ones (what
-        # ``_count_attn_work`` counts by; empty for any other model).
+        # Attention layers by form, of a model that has sliding ones, a
+        # looped model's layer-passes as full ones, and the layers of a
+        # grouped-query model with an indexer (what ``_count_attn_work``
+        # counts by; empty for any other model).
         forms = mcfg.attn_forms
+        counted = (
+            "sliding" in forms or mcfg.loop_passes > 1 or mcfg.index_key_in_k_row
+        )
         self._attn_form_layers = {
             form: forms.count(form) * mcfg.loop_passes
             for form in ("sliding", "full") if form in forms
-        } if "sliding" in forms or mcfg.loop_passes > 1 else {}
+        } if counted else {}
         if mcfg.num_mamba_layers and self._attn_impl == "pallas" and (
             cfg.page_size % cfg.ragged_q_tile or cfg.prefill_chunk % cfg.ragged_q_tile
         ):
@@ -866,7 +870,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # returns it last (the rows' entries are fields of the packed
         # buffer); for every other model there is neither.
         has_state = mcfg.has_seq_state
-        count_picks = bool(mcfg.num_experts_held)
+        count_picks = mcfg.counts_picks
         layout = launch_layout(
             self.cfg, (nb, pages, True, full_sampler, want_lp, False)
         )
@@ -1058,7 +1062,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         )
 
         has_state = mcfg.has_seq_state  # as in _windowed_program
-        count_picks = bool(mcfg.num_experts_held)
+        count_picks = mcfg.counts_picks
         layout = launch_layout(
             self.cfg, (nb, pages, False, full_sampler, want_lp, with_spec)
         )
@@ -1420,22 +1424,25 @@ class TPUEngine(BlockDecode, AsyncEngine):
         )
 
     def _count_picks(self, pending: _PendingRagged) -> None:
-        """Of a consumed dispatch of a model that holds a share of its
-        experts, the picks that landed on held experts (the program's last
+        """Of a consumed dispatch of a model whose programs count them
+        (``ModelConfig.counts_picks``: one that holds a share of its
+        experts, or all of them and asks: then none lies elsewhere), the
+        picks that landed on held experts (the program's last
         output, a pair, or one a window's step), those that lay elsewhere
         (``dynamo_moe_picks_total``) and the held experts that had a pick
         (``dynamo_moe_held_expert_reads_total``)."""
         m = self.cfg.model
-        if not m.num_experts_held:
+        if not m.counts_picks:
             return
         # [2], or a window's [steps, 2]: picks held, held experts with a pick
         held, reached = (
             int(n) for n in np.asarray(pending.ys[-1]).reshape(-1, 2).sum(axis=0)  # dynlint: sync-point(ragged consume)
         )
-        total = pending.routed_rows * m.num_experts_per_tok * m.num_routed_layers
         tel = get_telemetry()
         tel.moe_picks.labels("held").inc(held)
-        tel.moe_picks.labels("elsewhere").inc(max(total - held, 0))
+        if m.num_experts_held:  # else none lies elsewhere
+            total = pending.routed_rows * m.num_experts_per_tok * m.num_routed_layers
+            tel.moe_picks.labels("elsewhere").inc(max(total - held, 0))
         tel.moe_held_expert_reads.labels(
             "window" if pending.windowed else "mixed"
         ).inc(reached)
@@ -1469,8 +1476,10 @@ class TPUEngine(BlockDecode, AsyncEngine):
         query and is asked to score all ``p + 1`` (``dynamo_dsa_*_total``:
         every causal pair, also where a stream part skips the scoring
         because none of its queries sees more than ``index_topk``).
+        A grouped-query model with an indexer counts the ``dsa_*`` series
+        alone: its attention's work is ``_count_attn_work``'s.
         Host arithmetic on what the builder holds."""
-        if not self.cfg.model.is_latent:
+        if not (self.cfg.model.is_latent or self.cfg.model.has_indexer):
             return
         positions = np.asarray(positions, np.int64)
         expanded = 0
@@ -1513,6 +1522,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
             tel.dsa_query_tokens.labels(program, "selecting").inc(selecting)
             tel.dsa_query_tokens.labels(program, "all").inc(queries - selecting)
             pairs = kept  # what attention attends: the published work
+        if not self.cfg.model.is_latent:
+            return
         tel.mla_query_tokens.labels(program, "absorbed").inc(queries - expanded)
         if expanded:
             tel.mla_query_tokens.labels(program, "expanded").inc(expanded)
@@ -1520,9 +1531,12 @@ class TPUEngine(BlockDecode, AsyncEngine):
         tel.mla_context_tokens.labels(program).inc(context)
 
     def _count_attn_work(self, positions, steps=None, row_of=None) -> None:
-        """Of a model with sliding layers, and of a looped model (whose
+        """Of a model with sliding layers, of a looped model (whose
         every layer-pass counts as a full layer: a token's queries, keys
-        and context are read anew in each pass), the queries a dispatch
+        and context are read anew in each pass) and of a grouped-query
+        model with an indexer (the key positions a query MAY see stay ``t +
+        1``: the ``min(t + 1, index_topk)`` it attends go to
+        ``dynamo_dsa_selected_pairs_total``), the queries a dispatch
         feeds its
         attention layers, the key positions those may see and the context
         tokens a row's queries see between them, by the layers' form and
@@ -2870,7 +2884,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
             self._last_gauge_pub = now
             tel = get_telemetry()
             tel.publish_engine_gauges(self.metrics())
-            if self.kv.sliding is not None or self.cfg.model.loop_passes > 1:
+            model = self.cfg.model
+            if (
+                self.kv.sliding is not None or model.loop_passes > 1
+                or model.index_key_in_k_row
+            ):
                 self._publish_pools_by_kind(tel)
             if self.host_pool is not None:
                 # G2 tier occupancy (docs/engine_perf.md "Predictive KV
@@ -3002,7 +3020,9 @@ class TPUEngine(BlockDecode, AsyncEngine):
         once (``dynamo_kv_sliding_pool_peak_pages``). A looped model
         publishes its one pool's the same way (``pool="full"``): what a
         token of context costs over every (pass, layer) slot, with the
-        pages' rounding."""
+        pages' rounding; so does a grouped-query model with an indexer: what
+        a token costs with the third thing it caches, the index key in its
+        K row."""
         held, usage = {"full": self.kv.active_pages}, {"full": self.kv.usage}
         if self.kv.sliding is not None:
             held["sliding"] = self.kv.sliding.held

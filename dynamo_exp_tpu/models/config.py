@@ -184,6 +184,16 @@ class ModelConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # KeyeVL2: the same indexer over GROUPED-QUERY attention. There is no
+    # queries' bottleneck, so the index queries come from the normed stream
+    # too; all ``index_head_dim`` lanes of an index query and key are rotary
+    # (``index_rope_dim``); and the index key rides the last
+    # ``index_lanes`` lanes of the K pool's row, behind the KV heads' keys
+    # (``kv_row_parts``). ``mrope_section`` (not empty): the rotary of q and
+    # k is by sections over three position components (temporal, height,
+    # width: ``ops/rope.py::apply_mrope``), so many rotary pairs each; a
+    # text token's three are equal, which is the plain rotary.
+    mrope_section: tuple[int, ...] = ()
     # Experts *held* beside experts routed over: the router scores all
     # ``num_experts``; this device holds ``num_experts_held`` of them, ids
     # ``[first_expert_held, first_expert_held + num_experts_held)`` (one
@@ -191,6 +201,14 @@ class ModelConfig:
     # elsewhere adds nothing here (``ops/moe.py``). 0: all of them.
     num_experts_held: int = 0
     first_expert_held: int = 0
+    # A model that holds ALL its experts and yet has its programs count, on
+    # the device, the picks and the experts they reach as a held share's do
+    # (``counts_picks``): a reader of the grouped matmul's bytes cannot know
+    # from a call's shape how many of 128 matrices a crowding router left
+    # unread. Set by the readers of the models whose programs were first
+    # built with the count (KeyeVL2); the older models' programs stay the
+    # executables they were.
+    count_expert_reads: bool = False
     # Shared experts without a gate (``n_shared_experts`` SwiGLUs of the
     # routed experts' width, fused into one): added to every token's
     # routed sum as they are. 0: none.
@@ -392,6 +410,14 @@ class ModelConfig:
         return self.num_experts_held or self.num_experts
 
     @property
+    def counts_picks(self) -> bool:
+        """Whether the paged programs return, last, the live rows' picks
+        that landed on experts held here and the held experts that had one
+        (``models/llama.py::forward``'s ``count_picks``): a model that holds
+        a share of its experts, or one that asks (``count_expert_reads``)."""
+        return bool(self.num_experts and (self.num_experts_held or self.count_expert_reads))
+
+    @property
     def shared_width(self) -> int:
         """Width of the gateless shared expert (0: none)."""
         return self.num_shared_experts * self.expert_intermediate_size
@@ -422,29 +448,72 @@ class ModelConfig:
     @property
     def index_lanes(self) -> int:
         """``index_head_dim`` padded to whole groups of 128 lanes: the
-        width of an index key's row in the second page pool (0: no
+        lanes an index key takes in the cache (``kv_row_parts``; 0: no
         indexer)."""
         return -(-self.index_head_dim // 128) * 128 if self.has_indexer else 0
+
+    @property
+    def index_rope_dim(self) -> int:
+        """Lanes of an index query and key that turn by position, the
+        first ones: the rotary key's width under latent attention
+        (DeepSeek-V3.2's), all of them under grouped-query attention."""
+        return self.qk_rope_head_dim if self.is_latent else self.index_head_dim
+
+    @property
+    def index_key_in_k_row(self) -> bool:
+        """Whether the index key rides the K pool's row behind the heads'
+        keys: a grouped-query model with an indexer, which so caches three
+        things a token. (A latent model's index key has a pool of its own.)"""
+        return self.has_indexer and not self.is_latent
+
+    @property
+    def index_lane0(self) -> int:
+        """The first lane of the index key in the row of the pool that
+        holds it, from ``kv_row_parts``: 0 in a pool of its own (latent
+        attention), behind the KV heads' keys in the K pool's row
+        (``index_key_in_k_row``)."""
+        for pool in self.kv_row_parts():
+            parts = [part for part, _ in pool]
+            if "index" in parts:
+                return sum(lanes for _, lanes in pool[: parts.index("index")])
+        return 0
+
+    def kv_row_parts(self, form: str = "full") -> tuple[tuple[tuple[str, int], ...], ...]:
+        """What a token and layer's cache row holds, pool by pool, as
+        (part, lanes) in lane order: the ONE place that says where each
+        cached thing lies (``kv_pool_widths_of`` sums it). A latent model:
+        ``(("latent", latent_lanes),)`` and, with an indexer, ``(("index",
+        index_lanes),)`` in the second pool, else nothing there. A
+        grouped-query model: ``(("k", Hkv x D),)`` and ``(("v", Hkv x
+        Dv),)``; with an indexer the index key rides the K pool's row:
+        ``(("k", Hkv x D), ("index", index_lanes))``."""
+        index = (("index", self.index_lanes),) if self.has_indexer else ()
+        if self.is_latent:
+            return (("latent", self.latent_lanes),), index
+        heads = self.kv_heads_of(form)
+        return (
+            (("k", heads * self.head_dim_), *index),
+            (("v", heads * self.value_head_dim_),),
+        )
 
     @property
     def kv_pool_widths(self) -> tuple[int, int]:
         """Lanes of a token's row in the two page pools
         (``models/llama.py::init_kv_cache``): K's and V's heads side by
-        side, or the latent row and, where the model has an indexer, its
-        key (else nothing: the values are the latent row's first
-        ``kv_lora_rank`` lanes either way). Of a cache by kind: the full
-        layers' pools (``kv_pool_widths_of``)."""
+        side (a grouped-query model's index key behind K's), or the latent
+        row and, where the model has an indexer, its key (else nothing: the
+        values are the latent row's first ``kv_lora_rank`` lanes either
+        way). Of a cache by kind: the full layers' pools
+        (``kv_pool_widths_of``)."""
         return self.kv_pool_widths_of("full")
 
     def kv_pool_widths_of(self, form: str) -> tuple[int, int]:
         """``kv_pool_widths`` of the pools that the layers of ``form``
         ("full" / "sliding") write: a head's keys at ``head_dim_`` lanes
         and its values at ``value_head_dim_``, ``kv_heads_of(form)`` heads
-        side by side."""
-        if self.is_latent:
-            return self.latent_lanes, self.index_lanes
-        heads = self.kv_heads_of(form)
-        return heads * self.head_dim_, heads * self.value_head_dim_
+        side by side (``kv_row_parts``)."""
+        first, second = self.kv_row_parts(form)
+        return sum(n for _, n in first), sum(n for _, n in second)
 
     def kv_heads_of(self, form: str) -> int:
         """KV heads of a layer whose attention is of ``form``."""
@@ -513,6 +582,8 @@ class ModelConfig:
             return cls._from_mimo_v2_flash(cfg)
         if model_type == "ouro":
             return cls._from_ouro(cfg)
+        if model_type == "KeyeVL2":
+            return cls._from_keye_vl2(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -1141,6 +1212,85 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_keye_vl2(cls, cfg: dict) -> "ModelConfig":
+        """Kwai's ``KeyeVL2`` (Keye-VL-2.0-30B-A3B), the language model:
+        Qwen3-MoE's stack key for key (grouped-query attention with a
+        per-head RMS norm on q and k, ``num_experts`` routed SwiGLUs top-k
+        by softmax, no shared expert) under a learned sparse selection
+        (``sa_config``: an indexer of ``indexer_num_heads`` heads of
+        ``indexer_head_dim`` with ONE key head, the ``topk`` context tokens
+        of largest index score) and a rotary by sections over three position
+        components (``rope_scaling.mrope_section``). ``q_chunk_size`` /
+        ``kv_chunk_size`` name the published code's tiles and change no sum.
+        The vision tower's keys (``vision_config`` ...) are not read: the
+        tower is not served (``refuse_unserved_tower``).
+        Refused, because nothing here computes it: ``use_sliding_window``,
+        another ``rope_type`` than default, sections that do not sum to half
+        a head, more than one index key head, mixed dense and sparse
+        layers, a bias on attention's projections, another activation than
+        silu."""
+        name = "KeyeVL2"
+        if cfg.get("use_sliding_window", False):
+            raise ValueError(f"{name} with use_sliding_window=true is not supported")
+        if cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1:
+            raise ValueError(
+                f"{name} with mlp_only_layers / decoder_sparse_step != 1 "
+                "(mixed dense+sparse layers) is not supported"
+            )
+        if cfg.get("attention_bias", False):
+            raise ValueError(f"{name} with attention_bias=true is not supported")
+        if str(cfg.get("hidden_act", "silu")) != "silu":
+            raise ValueError(f"{name} with hidden_act other than silu is not supported")
+        scaling = cfg.get("rope_scaling") or {}
+        kind = scaling.get("rope_type", scaling.get("type", "default"))
+        if kind != "default":
+            raise ValueError(f"{name} with rope_type {kind!r} is not supported")
+        heads = cfg["num_attention_heads"]
+        head_dim = cfg.get("head_dim") or cfg["hidden_size"] // heads
+        sections = tuple(int(n) for n in scaling.get("mrope_section") or ())
+        if sections and (len(sections) != 3 or sum(sections) != head_dim // 2):
+            raise ValueError(
+                f"{name} needs three mrope_section entries that sum to half "
+                f"the head ({head_dim // 2}), got {list(sections)}"
+            )
+        sa = cfg.get("sa_config") or {}
+        for key in ("indexer_num_heads", "indexer_head_dim", "topk"):
+            if int(sa.get(key) or 0) < 1:
+                raise ValueError(f"{name} needs sa_config.{key} >= 1")
+        if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+            raise ValueError(f"{name} with more than one index key head is not supported")
+        if sa["indexer_head_dim"] % 2:
+            raise ValueError(f"{name} needs an even sa_config.indexer_head_dim")
+        experts = cfg.get("num_experts") or cfg.get("num_local_experts") or 0
+        if experts < 1:
+            raise ValueError(f"{name} needs num_experts >= 1")
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg.get("intermediate_size", cfg["hidden_size"]),
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim"),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=cfg.get("max_position_embeddings", 32768),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            qk_norm=True,
+            num_experts=experts,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", False),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            index_n_heads=sa["indexer_num_heads"],
+            index_head_dim=sa["indexer_head_dim"],
+            index_topk=sa["topk"],
+            mrope_section=sections,
+            count_expert_reads=True,
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type=name,
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -1153,6 +1303,40 @@ class ModelConfig:
                     k: v for k, v in json.load(f).items() if k in _BLOCK_KEYS
                 }}
         return cls.from_hf_config(cfg)
+
+
+# Families whose published model has a vision (or audio) tower that is not
+# served: the language model alone is, and a request that brings anything
+# but text is refused (``refuse_unserved_tower``). What each would need.
+UNSERVED_TOWERS = {
+    "KeyeVL2": (
+        "its SigLIP-class vision tower and projector, whose configuration is "
+        "in no file here (models/vision.py is a CLIP tower with LLaVA's "
+        "projector, not Keye's)"
+    ),
+}
+
+
+def refuse_unserved_tower(model_type: str, messages) -> None:
+    """Raise ``ValueError`` where a chat request's ``messages`` (dicts, or
+    objects with ``content``) bring a content part that is not text to a
+    family in ``UNSERVED_TOWERS``: the one place image, video and audio
+    input is refused for a model whose tower is left out. Text parts and
+    plain string contents pass, as does every other family."""
+    if model_type not in UNSERVED_TOWERS:
+        return
+    for m in messages:
+        content = m.get("content") if isinstance(m, dict) else getattr(m, "content", None)
+        if not isinstance(content, list):
+            continue
+        for part in content:
+            kind = part.get("type", "text") if isinstance(part, dict) else "text"
+            if kind != "text":
+                raise ValueError(
+                    f"model_type {model_type!r} is served as its language model "
+                    f"alone: a {kind!r} content part needs "
+                    f"{UNSERVED_TOWERS[model_type]}. Send text only"
+                )
 
 
 # The families ``from_hf_config`` builds; any other ``model_type`` raises.
@@ -1220,7 +1404,7 @@ KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
-    "mimo_v2_flash", "ouro",
+    "mimo_v2_flash", "ouro", "KeyeVL2",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -1661,6 +1845,34 @@ TINY_OURO = ModelConfig(  # ouro family shape: 4 layers run 3 times over one
     model_type="ouro",
 )
 
+TINY_KEYE = ModelConfig(  # KeyeVL2 family shape: grouped-query attention (4
+    # heads on 2 KV heads, q/k norms) under a learned selection (top 16 of
+    # the context by an indexer of 4 heads of 16, its key behind K in the K
+    # pool's row), rotary by sections 2 + 3 + 3 over three position
+    # components; three layers, each 8 routed experts top 2, none shared
+    vocab_size=256,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=3,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    rope_theta=1000000.0,
+    max_position_embeddings=512,
+    rms_norm_eps=1e-6,
+    qk_norm=True,
+    num_experts=8,
+    num_experts_per_tok=2,
+    norm_topk_prob=True,
+    moe_intermediate_size=32,
+    index_n_heads=4,
+    index_head_dim=16,
+    index_topk=16,
+    mrope_section=(2, 3, 3),
+    count_expert_reads=True,
+    model_type="KeyeVL2",
+)
+
 MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     vocab_size=32000,
     hidden_size=4096,
@@ -1688,6 +1900,7 @@ PRESETS = {
     "tiny-exaone-moe": TINY_EXAONE_MOE,
     "tiny-mimo": TINY_MIMO,
     "tiny-ouro": TINY_OURO,
+    "tiny-keye": TINY_KEYE,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import paged_attention, write_kv_pages
-from ..ops.rope import apply_rope, rope_frequencies
+from ..ops.rope import apply_mrope, apply_rope, rope_frequencies
 from .config import ModelConfig
 
 Params = dict[str, Any]
@@ -161,7 +161,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     if cfg.is_latent:  # pangu_ultra_moe: latent attention's seven tensors
         del layers["wq"], layers["wk"], layers["wv"]
         layers.update(_init_latent(jax.random.fold_in(rng, 4), cfg, init))
-    if cfg.has_indexer:  # glm_moe_dsa: the selection's indexer, a layer's own
+    if cfg.has_indexer:  # glm_moe_dsa, KeyeVL2: the selection's indexer, a layer's own
         layers.update(_init_indexer(jax.random.fold_in(rng, 5), cfg, init))
     if cfg.num_linear_layers:  # olmo_hybrid: the gated delta rule
         layers.update(_init_linear(jax.random.fold_in(rng, 3), cfg, init))
@@ -256,8 +256,9 @@ def _init_latent(rng, cfg: ModelConfig, init) -> Params:
 
 # The indexer's parameters (learned sparse attention), each stacked over
 # the attention layers: its queries' projection from the queries'
-# bottleneck, its key's from the normed stream with the key's LayerNorm
-# (scale and bias), and the projection of a token's weight a head.
+# bottleneck (from the normed stream where attention is grouped-query and
+# has no bottleneck), its key's from the normed stream with the key's
+# LayerNorm (scale and bias), and the projection of a token's weight a head.
 INDEXER_PARAMS = ("idx_wq", "idx_wk", "idx_k_norm", "idx_k_bias", "idx_wp")
 # The key's LayerNorm's epsilon (DeepSeek-V3.2's indexer: torch's default).
 INDEX_NORM_EPS = 1e-6
@@ -268,7 +269,8 @@ def _init_indexer(rng, cfg: ModelConfig, init) -> Params:
     LayerNorm starts at scale 1 and a small random bias, so that a fault
     that drops the bias shows."""
     dt = _dtype(cfg)
-    La, D, rq = cfg.num_attn_layers, cfg.hidden_size, cfg.q_lora_rank
+    La, D = cfg.num_attn_layers, cfg.hidden_size
+    rq = cfg.q_lora_rank if cfg.is_latent else D  # what ``q_I`` is projected from
     Hi, Di = cfg.index_n_heads, cfg.index_head_dim
     ki = jax.random.split(rng, 4)
     return {
@@ -690,7 +692,15 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
     come from the weights' shapes (a model's forms may differ in them); a
     head's values may be narrower than its keys (``value_head_dim_``) and
     scaled (``value_scale``); with ``rotary_dim`` only a head's first values
-    turn."""
+    turn; with ``mrope_section`` a head's rotary pairs turn by sections over
+    ``rope_pos`` [3, B, T].
+
+    **The indexer** (``cfg.has_indexer``: KeyeVL2), scope ``dsa_index``:
+    :func:`index_projections` from the normed stream. ``attend`` then also
+    gets ``index = (q_I, the index key's row, w)``: it writes the row behind
+    the token's keys in the K pool and lets a query attend only to the
+    ``index_topk`` positions of largest index score, as ``_mla_half``'s
+    ``read`` does."""
     B, T = x.shape[:2]
     hd, dv = cfg.head_dim_, cfg.value_head_dim_
     off = cfg.rms_norm_offset
@@ -714,9 +724,14 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
             q = rms_norm(q, lp["q_norm"], eps, off)
             k = rms_norm(k, lp["k_norm"], eps, off)
         if cfg.use_rope and rope:  # jamba's attention has no positional term
-            q = _turn(q, rope_pos, inv_freq, cfg.rotary_dim)
-            k = _turn(k, rope_pos, inv_freq, cfg.rotary_dim)
-    attn, kv_extra = attend(q, k, v)
+            q = _turn(q, rope_pos, inv_freq, cfg.rotary_dim, cfg.mrope_section)
+            k = _turn(k, rope_pos, inv_freq, cfg.rotary_dim, cfg.mrope_section)
+    if cfg.has_indexer:
+        with jax.named_scope("dsa_index"):
+            q_i, k_i, w_i = index_projections(h, h, lp, cfg, inv_freq, rope_pos)
+        attn, kv_extra = attend(q, k, v, index=(q_i, k_i, w_i))
+    else:
+        attn, kv_extra = attend(q, k, v)
     with jax.named_scope("attn_out"):
         attn_out = red(attn.reshape(B, T, -1) @ lp["wo"])
         if "post_attn_norm" in lp:  # gemma2: norm the block OUTPUT too
@@ -725,9 +740,13 @@ def _attn_half(x, lp, cfg, inv_freq, rope_pos, eps, attend, red, rope=True):
     return x, kv_extra
 
 
-def _turn(x, rope_pos, inv_freq, rotary_dim: int):
+def _turn(x, rope_pos, inv_freq, rotary_dim: int, sections: tuple = ()):
     """``apply_rope`` over a head's first ``rotary_dim`` values (its pairs
-    ``(i, i + rotary_dim / 2)``), the rest as they are; 0: the whole head."""
+    ``(i, i + rotary_dim / 2)``), the rest as they are; 0: the whole head.
+    ``sections`` (KeyeVL2's ``mrope_section``): the whole head by sections
+    over the three components of ``rope_pos`` [3, B, T] (``apply_mrope``)."""
+    if sections:
+        return apply_mrope(x, rope_pos, inv_freq, sections)
     if not rotary_dim:
         return apply_rope(x, rope_pos, inv_freq)
     return jnp.concatenate([
@@ -889,14 +908,24 @@ def _mla_half(x, lp, cfg, inv_freq, rope_pos, eps, write, read, red):
 
 
 def index_projections(h, c_q, lp, cfg, inv_freq, rope_pos):
-    """The indexer's three products of a layer (``_mla_half``): from the
-    normed stream ``h`` [B, T, D] and the queries' bottleneck ``c_q`` [B, T,
-    q_lora_rank], (``q_I`` [B, T, Hi, Di], its first ``qk_rope_head_dim``
-    lanes rotary; the index pool's row [B, T, index_lanes]: ``k_I`` after
-    its LayerNorm, its first lanes rotary, zeros behind ``Di``; ``w`` [B, T,
-    Hi] float32, scaled by ``(Hi * Di)^-1/2``)."""
+    """The indexer's three products of a layer, the one function both
+    attention halves call (``_mla_half``, ``_attn_half``): from the normed
+    stream ``h`` [B, T, D] and what the index queries are projected from,
+    ``c_q`` (latent attention: the queries' bottleneck [B, T, q_lora_rank];
+    grouped-query attention has none: the normed stream again), (``q_I`` [B,
+    T, Hi, Di], its first ``index_rope_dim`` lanes rotary; the index key's
+    row [B, T, index_lanes]: ``k_I`` after its LayerNorm, its first lanes
+    rotary, zeros behind ``Di``; ``w`` [B, T, Hi] float32, scaled by ``(Hi *
+    Di)^-1/2``). Under latent attention the rotary lanes are the rotary
+    key's, at its frequencies (``inv_freq``); under grouped-query attention
+    all ``Di`` lanes turn, at the model's base over that width, by the
+    temporal component where ``rope_pos`` has three."""
     B, T = h.shape[:2]
-    Hi, Di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    Hi, Di, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.index_rope_dim
+    if not cfg.is_latent:
+        inv_freq = rope_frequencies(dr, cfg.rope_theta)
+        if cfg.mrope_section:
+            rope_pos = rope_pos[0]
     q_i = (c_q @ lp["idx_wq"]).reshape(B, T, Hi, Di)
     q_i = jnp.concatenate(
         [apply_rope(q_i[..., :dr], rope_pos, inv_freq), q_i[..., dr:]], axis=-1
@@ -1281,8 +1310,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
     (``ops/moe.py``). The shared expert (qwen2_moe's behind its sigmoid
     gate, pangu_ultra_moe's as it is) is added once, and a model with
     ``post_norms`` norms the routed half's sum as it does a dense one's.
-    ``tally``: ``moe_ffn``'s (the picks held and the experts they reach,
-    where the model holds a share)."""
+    ``tally``: ``moe_ffn``'s (the picks held and the experts they reach)."""
     B, T = x.shape[:2]
     off = cfg.rms_norm_offset
     h = rms_norm(x, lp["mlp_norm"], eps, off) if "mlp_norm" in lp else x
@@ -1497,7 +1525,7 @@ def _scan_paged_layers(
     ``(x, k_cache, v_cache)`` and, where the model has layers that are not
     softmax attention, ``seq_state`` (a pytree by part: ``init_seq_state``). Returns (x,
     k_cache, v_cache, seq_state, held_picks, kept): ``held_picks`` None unless
-    ``count_picks`` (a model that holds a share of its experts: int32 [2],
+    ``count_picks`` (``ModelConfig.counts_picks``: int32 [2],
     the live rows' picks that landed on held experts and the held experts
     that had one, each summed over the routed layers); ``kept`` None unless
     ``take_kept`` (a model with an indexer: what ``read`` put into the
@@ -1710,15 +1738,27 @@ def _scan_paged_layers(
                     )
                 else:
 
-                    def attend(q, k, v):
+                    def attend(q, k, v, index=None):
                         n = k.shape[0] * k.shape[1]  # this dispatch's new tokens
+                        k_row = k.reshape(n, -1)
+                        if index is not None:
+                            # a model with an indexer: the index key rides the
+                            # K pool's row behind the heads' keys, one scatter
+                            q_i, k_i, w_i = index
+                            k_row = jnp.concatenate(
+                                [k_row, k_i.reshape(n, -1)], axis=-1
+                            )
                         with jax.named_scope("kv_write"):
                             kp, vp = write_kv_pages(
-                                k_pool, v_pool, k.reshape(n, -1), v.reshape(n, -1),
+                                k_pool, v_pool, k_row, v.reshape(n, -1),
                                 *write_at, layer=l,
                             )
                         # the run's own KV heads, and its layer's sink logits if any
                         by_form = {"heads": heads, "sink": lp.get("sink")}
+                        if index is not None:
+                            by_form["index"] = (q_i, w_i, kp.reshape(flat))
+                            if take_kept:
+                                by_form["taken"] = taken
                         v_flat = (flat[0], flat[1], vp.shape[-1])
                         with jax.named_scope("attend"):
                             if width is None:
@@ -1798,6 +1838,7 @@ def forward(
     state_slots: jnp.ndarray | None = None,
     count_picks: bool = False,
     kept_of: jnp.ndarray | None = None,
+    rope_positions: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, ...]:
     """One forward step (prefill or decode by bucket shape).
 
@@ -1816,16 +1857,25 @@ def forward(
     so chunked prefill pays lm_head FLOPs for B positions instead of
     B*T — the returned logits are then [B, 1, V].
 
-    ``count_picks`` (a model that holds a share of its experts) returns,
-    last, int32 [2]: how many of the live rows' expert picks landed on held
-    experts, and how many held experts had one (the matrices a layer has
-    to fetch), each summed over the routed layers.
+    ``count_picks`` (``ModelConfig.counts_picks``: a model that holds a
+    share of its experts, or all and asks) returns, last, int32 [2]: how
+    many of the live rows' expert picks landed on held experts, and how
+    many held experts had one (the matrices a layer has to fetch), each
+    summed over the routed layers.
 
     ``kept_of`` ([M] int32 slots of the flat ``[B * T]`` stream; a model
     with an indexer) returns, last, bool [L, M, S]: which positions of its
     row's table each of those queries' selection kept in each layer, as the
     attention was given it (``_selection_tiled`` / ``_selection_ref``; a
     position the query does not see may read either).
+
+    ``rope_positions`` ([3, B, T] int32; a model whose rotary goes by
+    sections, ``ModelConfig.mrope_section``): the temporal, height and width
+    component of each token's position, what q and k turn by, while the
+    cache, the causal mask and the selection go by ``positions``. None: a
+    text token, whose three are its position (the plain rotary, exactly);
+    it is the seam through which a vision tower's soft tokens
+    (``token_embeds``) would bring their grid.
 
     ``attn_pages`` (static) bounds the XLA path's page gather: attention
     reads only the first ``attn_pages`` table columns, so short contexts
@@ -1873,7 +1923,7 @@ def forward(
         else jnp.take(params["embed"], tokens, axis=0)
     )  # [B, T, D]
     x = _maybe_scale_embeds(cfg, x)
-    rope_pos = jnp.maximum(positions, 0)
+    rope_pos = _rope_positions(cfg, positions, rope_positions)
 
     # Pallas decode reads each row's ragged context (a sliding layer's
     # through its width: ``sliding=``); softcapped and query-scaled models
@@ -1929,6 +1979,8 @@ def forward(
             keep["keep"] = _selection_ref(cfg, index, table_l, positions)
             if taken is not None:
                 taken.append(keep["keep"].reshape(B * T, -1)[kept_of])
+            if wrap is None:  # the K row holds the index key behind the heads' keys
+                keep["kv_heads"] = heads
         with scope:
             o = paged_attention(
                 q, kf, vf, table_l, positions,
@@ -1959,6 +2011,23 @@ def forward(
         x = jnp.take_along_axis(x, last_positions[:, None, None], axis=1)
     logits = _final_logits(params, cfg, x, eps)
     return _forward_outputs(logits, k_cache, v_cache, seq_state, held_picks, kept)
+
+
+def _rope_positions(cfg: ModelConfig, positions, rope_positions=None):
+    """What q and k turn by: the positions, padding (-1) as 0; for a model
+    whose rotary goes by sections (``mrope_section``) three components a
+    token, ``[3, ...]``: the caller's, or a text token's position three
+    times."""
+    if not cfg.mrope_section:
+        if rope_positions is not None:
+            raise ValueError(
+                f"model_type {cfg.model_type!r} has no rotary by sections: "
+                "rope_positions is not its argument"
+            )
+        return jnp.maximum(positions, 0)
+    if rope_positions is None:
+        rope_positions = jnp.broadcast_to(positions, (3, *positions.shape))
+    return jnp.maximum(rope_positions, 0)
 
 
 def _kernel_serves(cfg: ModelConfig, attn_impl: str, mesh) -> bool:
@@ -2057,7 +2126,7 @@ def _selection_tiled(cfg, index, table, row_of, bounds, q_tile, interpret, pad=0
         with jax.named_scope("dsa_index"):
             scores = si.index_scores(
                 q_i, w_i, pool, table, row_of, bounds, q_tile=q_tile,
-                interpret=interpret,
+                interpret=interpret, **_index_lanes(cfg),
             )
         with jax.named_scope("dsa_select"):
             tau, allow = si.select_tiled(
@@ -2084,9 +2153,19 @@ def _selection_ref(cfg, index, table, bounds):
 
     q_i, w_i, pool = index
     with jax.named_scope("dsa_index"):
-        scores = si.index_scores_ref(q_i, w_i, pool, table, bounds)
+        scores = si.index_scores_ref(
+            q_i, w_i, pool, table, bounds, **_index_lanes(cfg)
+        )
     with jax.named_scope("dsa_select"):
         return si.kept(scores, *si.select(scores, bounds, cfg.index_topk))
+
+
+def _index_lanes(cfg: ModelConfig) -> dict:
+    """Where the scores read the index key in the pool they are handed:
+    nothing to say for a pool of index keys alone (latent attention), the
+    key's first lane in a K row that holds it behind the heads' keys
+    (``ModelConfig.index_lane0``)."""
+    return {"lane0": cfg.index_lane0} if cfg.index_key_in_k_row else {}
 
 
 def _kept_tiled(selection, at, q_tile):
@@ -2336,6 +2415,7 @@ def forward_ragged(
     project: bool = True,
     count_picks: bool = False,  # ``forward``'s
     kept_of: jnp.ndarray | None = None,  # ``forward``'s: slots of the [N] stream
+    rope_positions: jnp.ndarray | None = None,  # ``forward``'s: [3, N]
 ) -> tuple[jnp.ndarray, ...]:
     """One ragged forward over a flat mixed batch (the single-dispatch
     prefill+decode+spec path, docs/engine_perf.md).
@@ -2408,7 +2488,8 @@ def forward_ragged(
 
     x = jnp.take(params["embed"], tokens, axis=0)[None]  # [1, N, D]
     x = _maybe_scale_embeds(cfg, x)
-    rope_pos = jnp.maximum(positions, 0)[None]  # [1, N]
+    # [1, N]; [3, 1, N] where the rotary goes by sections
+    rope_pos = _rope_positions(cfg, positions, rope_positions)[..., None, :]
 
     attn_table = _cut_tables(page_table, attn_pages)
     # forward()'s gate: a softcap or a query pre-scale lives on the
@@ -2492,10 +2573,11 @@ def forward_ragged(
 
             def tiles(stop, block):
                 # The tiled part [0, stop) in cells of ``block`` slots: one
-                # call, or for a latent model a call a slab (LATENT_SLAB; a
-                # slab is whole tiles and no cell spans two: the same reads
-                # in the same order)
-                if wrap is None:
+                # call, or for a latent model and for one with an indexer
+                # (whose index scores are [slots, context] float32) a call a
+                # slab (LATENT_SLAB; a slab is whole tiles and no cell spans
+                # two: the same reads in the same order)
+                if wrap is None and index is None:
                     return [kernel(slice(0, stop), q_tile, block)]
                 slab = LATENT_SLAB // q_tile * q_tile
                 slabs = [

@@ -217,7 +217,7 @@ SUPPORTED_MODEL_TYPES = (
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
-    "mimo_v2_flash", "ouro",
+    "mimo_v2_flash", "ouro", "KeyeVL2",
 )
 
 
@@ -533,6 +533,53 @@ def _ouro_params(cfg: ModelConfig, get, linear) -> dict:
     return params
 
 
+def _keye_vl2_params(cfg: ModelConfig, get, linear) -> dict:
+    """``KeyeVL2``'s language model (``benchmark/configs/
+    keye-vl-2.0-30b-a3b-l5.json`` lists the names as assumed) into
+    ``models/llama.py::init_params``' stacks: Qwen3-MoE's names
+    (``input_layernorm``, ``self_attn.{q,k,v,o}_proj``, ``self_attn.{q,k}_norm``,
+    ``post_attention_layernorm``, ``mlp.gate`` and
+    ``mlp.experts.N.{gate,up,down}_proj``, no bias) and the indexer's,
+    DeepSeek-V3.2's: ``self_attn.indexer.{wq, wk, k_norm (weight, bias),
+    weights_proj}``, ``wq`` from the hidden size. The rotary pairs are the
+    engine's own (half-split), so no column is re-ordered. ``visual.*`` (the
+    vision tower, not served) is never asked for."""
+    mats = (
+        ("wq", "self_attn.q_proj"), ("wk", "self_attn.k_proj"),
+        ("wv", "self_attn.v_proj"), ("wo", "self_attn.o_proj"),
+        ("idx_wq", "self_attn.indexer.wq"), ("idx_wk", "self_attn.indexer.wk"),
+        ("idx_wp", "self_attn.indexer.weights_proj"), ("router", "mlp.gate"),
+    )
+    vecs = (
+        ("attn_norm", "input_layernorm.weight"),
+        ("mlp_norm", "post_attention_layernorm.weight"),
+        ("q_norm", "self_attn.q_norm.weight"), ("k_norm", "self_attn.k_norm.weight"),
+        ("idx_k_norm", "self_attn.indexer.k_norm.weight"),
+        ("idx_k_bias", "self_attn.indexer.k_norm.bias"),
+    )
+    experts = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+    layers: dict[str, list] = {k: [] for k, _ in (*mats, *vecs, *experts)}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        for key, name in mats:
+            layers[key].append(linear(f"{p}{name}.weight"))
+        for key, name in vecs:
+            layers[key].append(get(p + name))
+        for key, name in experts:
+            layers[key].append(np.stack([
+                linear(f"{p}mlp.experts.{e}.{name}.weight")
+                for e in range(cfg.num_experts)
+            ]))
+    params = {
+        "embed": get("model.embed_tokens.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items()},
+        "final_norm": get("model.norm.weight"),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = linear("lm_head.weight")
+    return params
+
+
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
     """Load a HF checkpoint directory (llama/mistral/qwen2/mixtral
     families) into the stacked param pytree."""
@@ -570,6 +617,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         "exaone_moe": functools.partial(_exaone_moe_params, has=index.__contains__),
         "mimo_v2_flash": functools.partial(_mimo_v2_flash_params, has=index.__contains__),
         "ouro": _ouro_params,
+        "KeyeVL2": _keye_vl2_params,
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

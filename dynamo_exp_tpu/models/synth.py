@@ -196,6 +196,27 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             sliding_window=None, use_sliding_window=False,
             max_window_layers=cfg.num_layers,
         )
+    elif cfg.model_type == "KeyeVL2":  # the family's own keys
+        hf.update(
+            architectures=["KeyeVL2ForConditionalGeneration"], hidden_act="silu",
+            attention_bias=False, head_dim=cfg.head_dim_,
+            num_experts=cfg.num_experts, num_local_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            decoder_sparse_step=1, mlp_only_layers=[],
+            rope_scaling={
+                "mrope_section": list(cfg.mrope_section),
+                "rope_type": "default", "type": "default",
+            },
+            sa_config={
+                "indexer_head_dim": cfg.index_head_dim,
+                "indexer_num_heads": cfg.index_n_heads,
+                "indexer_num_kv_heads": 1, "topk": cfg.index_topk,
+                "q_chunk_size": 512, "kv_chunk_size": 512,
+            },
+            sliding_window=None, use_sliding_window=False,
+            max_window_layers=cfg.num_layers,
+        )
     elif cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -205,7 +226,7 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
             "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe, "
-            "glm_moe_dsa, exaone_moe, mimo_v2_flash and ouro only"
+            "glm_moe_dsa, exaone_moe, mimo_v2_flash, ouro and KeyeVL2 only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

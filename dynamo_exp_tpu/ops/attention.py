@@ -75,6 +75,7 @@ def paged_attention(
     value_dim: int | None = None,
     keep: jnp.ndarray | None = None,  # [B, T, S] bool: a learned selection
     sink: jnp.ndarray | None = None,  # [H] a learned logit a query head
+    kv_heads: int | None = None,  # the K row's heads, where it holds more behind them
 ) -> jnp.ndarray:
     """Causal attention of queries against their sequences' pages.
 
@@ -95,7 +96,9 @@ def paged_attention(
     The value pool's heads may be narrower than the keys' (``v_cache``'s
     row is ``Hkv`` heads of its own width, the result's). ``sink`` (a
     learned logit a query head) joins the softmax's denominator as one
-    more key that carries no value.
+    more key that carries no value. ``kv_heads``: the K row's heads, where
+    the row holds more behind their keys (a grouped-query model's index
+    key, ``ModelConfig.kv_row_parts``), which is then left unread.
     """
     B, T, H, D = q.shape
     P, ps, _ = k_cache.shape
@@ -104,7 +107,11 @@ def paged_attention(
     scale = sm_scale if sm_scale is not None else D ** -0.5
 
     # Gather this batch's pages: [B, Pmax, ps, Hkv*D] -> [B, S, Hkv, D]
-    k = k_cache[page_table].reshape(B, S, Hkv, D)
+    if kv_heads is not None and kv_heads * D != k_cache.shape[2]:
+        Hkv = kv_heads
+        k = k_cache[page_table][..., : Hkv * D].reshape(B, S, Hkv, D)
+    else:
+        k = k_cache[page_table].reshape(B, S, Hkv, D)
     if value_dim is None:
         v = v_cache[page_table].reshape(B, S, Hkv, v_cache.shape[2] // Hkv)
     else:

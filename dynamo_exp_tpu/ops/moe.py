@@ -155,7 +155,7 @@ def moe_ffn(
     norm_eps: float = 1e-6,  # moe_router's
     held_from: int | None = None,  # first expert id held; the weights' E = held
     held_row_block: int | None = None,  # rows a block of the held walk takes
-    tally: list | None = None,  # with ``held_from``: gets [picks held, experts with one]
+    tally: list | None = None,  # gets [live picks on experts here, experts with one]
 ) -> jnp.ndarray:
     """SwiGLU expert FFN with exact top-k dispatch. Returns [N, D].
 
@@ -179,12 +179,14 @@ def moe_ffn(
 
     ``held_from`` (module docstring, "a share of the experts"): the
     weights hold experts ``[held_from, held_from + E_held)`` of the
-    ``E`` the router scores; the picks elsewhere add nothing. ``tally``
-    (a list, or None) is then given, as one traced int32 pair, how many of
-    the live rows' picks landed here (the caller's counter of ``held``
-    against ``elsewhere``) and how many of the held experts had such a
-    pick: the matrices this call has to fetch, which a router that crowds
-    its picks makes far fewer than the held.
+    ``E`` the router scores; the picks elsewhere add nothing.
+
+    ``tally`` (a list, or None; not under ``shard_map``) is given, as one
+    traced int32 pair, how many of the live rows' picks landed on the
+    experts here (the caller's counter of ``held`` against ``elsewhere``;
+    all of them where every expert is here) and how many of these experts
+    had such a pick: the matrices this call has to fetch, which a router
+    that crowds its picks makes far fewer than the held.
     """
     D, width = w_gate.shape[-2:]
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
@@ -194,7 +196,7 @@ def moe_ffn(
         num_experts_per_tok=num_experts_per_tok, norm_topk_prob=norm_topk_prob,
         kernel=kernel, interpret=interpret, score=score, scale=scale,
         norm_eps=norm_eps, held_from=held_from, held_row_block=held_row_block,
-        tally=tally if held_from is not None else None,
+        tally=tally,
     )
     if not (kernel and tp > 1):
         y = experts(x, router_w, w_gate, w_up, w_down, live, layer, select_bias)

@@ -255,6 +255,7 @@ def ragged_paged_attention_ref(
         window=window,
         softcap=softcap,
         value_dim=value_dim,
+        kv_heads=num_kv_heads,
         **({} if keep is None else {"keep": keep[:, None]}),
         **({} if sink is None else {"sink": sink}),
     )
@@ -472,13 +473,19 @@ def _chunk_copies(row, base, slot, table_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
     only leading (untiled) dims — Mosaic rejects slices of a lane
     dim narrower than the 128-lane tile."""
     dmas = []
+    # A K pool whose row holds more than the heads' keys (a model with an
+    # indexer under grouped-query attention: its index key rides behind
+    # them): the copy takes the keys' lanes, whole groups of 128, and leaves
+    # the rest in HBM.
+    lanes = k_buf.shape[-1]
     for j in range(cp):
         idx = jnp.minimum(base + j, pmax - 1)
         pid = table_ref[row, idx]
+        page = k_hbm.at[pid]
+        if k_hbm.shape[-1] != lanes:
+            page = k_hbm.at[pid, :, pl.ds(0, lanes)]
         dmas.append(
-            pltpu.make_async_copy(
-                k_hbm.at[pid], k_buf.at[slot, j], sems.at[slot, 2 * j]
-            )
+            pltpu.make_async_copy(page, k_buf.at[slot, j], sems.at[slot, 2 * j])
         )
         if v_hbm is not None:
             dmas.append(
@@ -1201,6 +1208,9 @@ def ragged_paged_attention(
     qt = q_tile
     latent = value_dim is not None
     Dv = value_dim if latent else v_cache.shape[-1] // Hkv
+    # Lanes of a K row the call reads: the heads' keys. A row may hold more
+    # behind them (``_chunk_copies``).
+    key_lanes = fused if latent else Hkv * D
     # Rows a cell of the one-query call walks on one copy pipeline, a row a
     # query or, where the caller names no block, a tile; 1: a cell is a tile
     # (a learned selection's scores come a tile a cell).
@@ -1254,7 +1264,10 @@ def ragged_paged_attention(
     def scratch_for(queries):
         """``_attend``'s buffers and states for passes of ``queries``."""
         return [
-            *(pltpu.VMEM((2, cp, ps, pool.shape[-1]), pool.dtype) for pool in pools),
+            *(
+                pltpu.VMEM((2, cp, ps, lanes), pool.dtype)
+                for pool, lanes in zip(pools, (key_lanes, Hkv * Dv))
+            ),
             pltpu.VMEM((H * queries, Dv), jnp.float32),
             pltpu.VMEM((H * queries, 128), jnp.float32),
             pltpu.VMEM((H * queries, 128), jnp.float32),

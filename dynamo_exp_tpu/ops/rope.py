@@ -55,8 +55,14 @@ def apply_rope(
     Uses the HF "half-split" convention (rotate_half), matching Llama
     checkpoints: pairs are (x[i], x[i + D/2]).
     """
-    dtype = x.dtype
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., T, D/2]
+    return _rotate(x, angles)
+
+
+def _rotate(x: jnp.ndarray, angles: jnp.ndarray) -> jnp.ndarray:
+    """``x`` [..., T, H, D] turned by ``angles`` [..., T, D/2], pairs
+    (x[i], x[i + D/2]), in float32, cast back."""
+    dtype = x.dtype
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, D/2]
     sin = jnp.sin(angles)[..., None, :]
     xf = x.astype(jnp.float32)
@@ -64,3 +70,30 @@ def apply_rope(
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(dtype)
+
+
+def apply_mrope(
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    inv_freq: jnp.ndarray,
+    sections: tuple[int, ...],
+) -> jnp.ndarray:
+    """:func:`apply_rope` by sections (multimodal rotary): ``positions`` is
+    ``[3, ..., T]`` (temporal, height, width) and rotary pair ``i`` of a
+    head turns by the component whose section holds it, the first
+    ``sections[0]`` pairs by the temporal position, the next
+    ``sections[1]`` by the height, the rest by the width
+    (``sum(sections)`` = D / 2). Each pair's angle is the one product
+    ``apply_rope`` makes, of the component it reads: equal components give
+    ``apply_rope``'s result bit for bit."""
+    if sum(sections) != inv_freq.shape[0] or positions.shape[0] != len(sections):
+        raise ValueError(
+            f"sections {sections} do not cover {inv_freq.shape[0]} rotary pairs "
+            f"by {positions.shape[0]} position components"
+        )
+    bounds = [sum(sections[:i]) for i in range(len(sections) + 1)]
+    angles = jnp.concatenate([
+        positions[c][..., None].astype(jnp.float32) * inv_freq[lo:hi]
+        for c, (lo, hi) in enumerate(zip(bounds, bounds[1:]))
+    ], axis=-1)  # [..., T, D/2]
+    return _rotate(x, angles)

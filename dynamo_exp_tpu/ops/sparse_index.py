@@ -98,15 +98,18 @@ def index_scores_ref(
     pool: jnp.ndarray,  # [P, ps, lanes >= Di]: index keys, zeros behind Di
     page_table: jnp.ndarray,  # [B, Pmax] int32
     bounds: jnp.ndarray,  # [B, T] int32 last context position seen, -1 = none
+    lane0: int = 0,  # the index key's first lane in the pool's row
 ) -> jnp.ndarray:
     """Index scores of each query against its row's pages: ``[B, T, S]``
     float32, ``S = Pmax * ps``, ``-inf`` at positions past the query's
     bound. The products run in the pool's dtype with float32 accumulation,
-    as attention's do."""
+    as attention's do. ``lane0``: where the index key lies in a row that
+    holds more than it (a grouped-query model's K row:
+    ``ModelConfig.kv_row_parts``)."""
     B = q_i.shape[0]
     ps = pool.shape[1]
     S = page_table.shape[1] * ps
-    k = pool[page_table].reshape(B, S, -1)[..., : q_i.shape[-1]]
+    k = pool[page_table].reshape(B, S, -1)[..., lane0 : lane0 + q_i.shape[-1]]
     dots = jnp.einsum(
         "bthd,bsd->bths", q_i.astype(k.dtype), k,
         preferred_element_type=jnp.float32,
@@ -262,6 +265,7 @@ def _scores_kernel(
     n_tiles: int,
     n_chunks_max: int,
     sub: int,
+    lane0: int = 0,
 ):
     """A grid cell is a block of ``nt`` tiles of ``qt`` slots. Where its
     live tiles are one row's (``block_kvlen`` >= 0) it makes ONE
@@ -299,9 +303,13 @@ def _scores_kernel(
             first = s * (U * cp)
 
             def page(at):
+                pid = table_ref[row, jnp.minimum(first + at, pmax - 1)]
+                # a row that holds more than the index key: its lanes alone
+                keys = k_hbm.at[pid] if k_hbm.shape[-1] == lanes else (
+                    k_hbm.at[pid, :, pl.ds(lane0, lanes)]
+                )
                 getattr(pltpu.make_async_copy(
-                    k_hbm.at[table_ref[row, jnp.minimum(first + at, pmax - 1)]],
-                    k_buf.at[slot, at], sems.at[slot, at],
+                    keys, k_buf.at[slot, at], sems.at[slot, at],
                 ), act)()
 
             def of_chunk(u, _):
@@ -493,13 +501,15 @@ def _scores_kernel(
 
 
 def _index_scores(q_i, w_i, pool, page_table, row_of, bounds, q_tile, block, interpret,
-                  product=_PRODUCT_SLOTS, step_chunks=_TILE_STEP_CHUNKS):
+                  product=_PRODUCT_SLOTS, step_chunks=_TILE_STEP_CHUNKS, lane0=None):
     """:func:`index_scores` at a block of ``block`` slots a grid cell, ``product``
     of them a product (``step_chunks`` chunks a step where the block is a tile)."""
     from .ragged_attention import block_plan
 
     N, Hi, Di = q_i.shape
     _, ps, lanes = pool.shape
+    if lane0 is not None:  # the index key's lanes of a row that holds more
+        lanes = key_lanes(Di)
     pmax = page_table.shape[1]
     block = min(block, N)  # a stream shorter than a block is one block
     qt, nt = q_tile, block // q_tile
@@ -521,6 +531,7 @@ def _index_scores(q_i, w_i, pool, page_table, row_of, bounds, q_tile, block, int
     sub = qt * max(d for d in range(1, nt + 1) if nt % d == 0 and d * qt <= max(product, qt))
     shape = dict(
         qt=qt, nt=nt, hi=Hi, ps=ps, cp=cp, pmax=pmax, n_tiles=T, n_chunks_max=C, sub=sub,
+        **({} if lane0 is None else {"lane0": lane0}),
     )
     # chunks a step of a pass: whole steps make the table's chunks
     step = math.gcd(C, step_chunks) if nt == 1 else 1
@@ -567,7 +578,13 @@ def _index_scores(q_i, w_i, pool, page_table, row_of, bounds, q_tile, block, int
     )(*plan, page_table, bounds.reshape(B, block, 1), q_i, weigh, pool)
 
 
-@functools.partial(jax.jit, static_argnames=("q_tile", "interpret"))
+def key_lanes(width: int) -> int:
+    """An index key of ``width`` values padded to whole groups of 128 lanes
+    (``ModelConfig.index_lanes``)."""
+    return -(-width // 128) * 128
+
+
+@functools.partial(jax.jit, static_argnames=("q_tile", "interpret", "lane0"))
 def index_scores(
     q_i: jnp.ndarray,  # [N, Hi, Di] flat query stream (N % q_tile == 0)
     w_i: jnp.ndarray,  # [N, Hi] float32
@@ -577,6 +594,7 @@ def index_scores(
     bounds: jnp.ndarray,  # [N] int32 last context position seen, -1 = none
     q_tile: int = 8,
     interpret: bool = False,
+    lane0: int | None = None,
 ) -> jnp.ndarray:
     """Index scores over a flat query stream whose tiles of ``q_tile``
     slots are one row's each (any slot its own at ``q_tile`` 1), as the
@@ -584,10 +602,16 @@ def index_scores(
     :func:`index_block` slots, read in one pass over their row's keys where
     they are one row's (``ragged_attention.block_plan``, derived here on the
     device). Returns :func:`tiled_shape` float32; a position past a query's
-    bound, and every position of a padding slot, reads ``-inf``."""
-    block = index_block(q_tile, q_i.shape[1], pool.shape[2], pool.dtype.itemsize)
+    bound, and every position of a padding slot, reads ``-inf``. ``lane0``
+    (None: the pool's rows are index keys and nothing else): the index key's
+    first lane in a row that holds more (a grouped-query model's K row,
+    ``ModelConfig.kv_row_parts``): the kernel copies :func:`key_lanes` lanes
+    from there and leaves the rest in HBM."""
+    lanes = pool.shape[2] if lane0 is None else key_lanes(q_i.shape[2])
+    block = index_block(q_tile, q_i.shape[1], lanes, pool.dtype.itemsize)
     return _index_scores(
-        q_i, w_i, pool, page_table, row_of, bounds, q_tile, block, interpret
+        q_i, w_i, pool, page_table, row_of, bounds, q_tile, block, interpret,
+        lane0=lane0,
     )
 
 

@@ -9,6 +9,8 @@ it also converts the backend's token/text stream into OpenAI deltas.
 
 from __future__ import annotations
 
+import json
+import os
 from typing import Any, AsyncIterator
 
 from ..model_card import ModelDeploymentCard
@@ -44,9 +46,24 @@ class OpenAIPreprocessor(Operator):
             mdc.tokenizer_path or mdc.model_path
         )
         self.formatter = PromptFormatter(mdc)
+        # The served family (``config.json``'s ``model_type``), for the one
+        # question asked of it here: whether a content part that is not text
+        # has a tower to go to (``models/config.py::refuse_unserved_tower``).
+        self._family = ""
+        config = os.path.join(mdc.model_path or "", "config.json")
+        if os.path.isfile(config):
+            with open(config) as f:
+                self._family = str(json.load(f).get("model_type", ""))
 
     # --- request path -------------------------------------------------
     def preprocess_chat(self, request: ChatCompletionRequest) -> BackendInput:
+        if any(isinstance(m.content, list) for m in request.messages):
+            from ..models.config import refuse_unserved_tower
+
+            try:
+                refuse_unserved_tower(self._family, request.messages)
+            except ValueError as e:
+                raise InvalidRequestError(str(e)) from None
         prompt = self.formatter.render(
             [m.model_dump(exclude_none=True) for m in request.messages],
             tools=request.tools,

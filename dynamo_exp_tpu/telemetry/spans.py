@@ -343,10 +343,11 @@ class Telemetry:
             "dynamo_moe_picks_total",
             "Expert picks (a live token's chosen experts, every routed "
             "layer) of a model that holds a share of the experts its "
-            "router scores, by where the expert lies: held (here: the row "
-            "is computed) or elsewhere (another rank's: nothing is added "
-            "here). held is counted on the device by the programs "
-            "(ops/moe.py::moe_ffn's tally), the total by the builders",
+            "router scores, or all of them with count_expert_reads "
+            "(KeyeVL2: elsewhere stays 0), by where the expert lies: held "
+            "(here: the row is computed) or elsewhere (another rank's: "
+            "nothing is added here). held is counted on the device by the "
+            "programs (ops/moe.py::moe_ffn's tally), the total by the builders",
             ["where"],  # held | elsewhere
             registry=self.registry,
         )
@@ -387,7 +388,8 @@ class Telemetry:
             "is asked to score, by program: every causal pair, p + 1 index "
             "keys a query at position p (a stream part none of whose queries "
             "sees more than index_topk skips the scoring and is counted all "
-            "the same). A layer's: times layers, and index heads for products",
+            "the same). Latent or grouped-query attention alike (glm_moe_dsa, "
+            "KeyeVL2). A layer's: times layers, and index heads for products",
             ["program"],
             registry=self.registry,
         )
@@ -410,7 +412,10 @@ class Telemetry:
         self.dsa_selected_pairs = Counter(
             "dynamo_dsa_selected_pairs_total",
             "Query-context pairs attended after the selection, by program: "
-            "min(p + 1, index_topk) a query at position p. A layer's",
+            "min(p + 1, index_topk) a query at position p, whichever pool the "
+            "masked read walks (latent rows, or grouped-query K and V pages: "
+            "dynamo_attn_key_positions_total{kind=\"full\"} then keeps the "
+            "p + 1 a query MAY see). A layer's",
             ["program"],
             registry=self.registry,
         )
@@ -1008,7 +1013,10 @@ class Telemetry:
             "Constant-1 geometry of each of the device KV pools (K and V; "
             "pools=1: one latent pool whose row is [c_kv | k_r] and no value "
             "pool; a latent model with an indexer has pools=2, the second "
-            "holding index_width lanes of index key a token): stacked "
+            "holding index_width lanes of index key a token; a grouped-query "
+            "model with an indexer has pools=2 of K and V, its index_width "
+            "lanes of index key the LAST lanes of the K pool's row, which "
+            "width counts): stacked "
             "layers, pages, tokens a page, a row's lanes (fused kv-head "
             "width, or the latent row with its pad_lanes of zero padding), "
             "element type, and token_bytes, the bytes a token holds over "
@@ -1051,7 +1059,9 @@ class Telemetry:
         self.kv_pool_held_bytes = Gauge(
             "dynamo_kv_pool_held_bytes",
             "Bytes of a pool's pages in use over its layers, K and V, by "
-            "pool of a cache by kind",
+            "pool of a cache by kind (pool=full alone for a looped model and "
+            "for a grouped-query model with an indexer, whose K row holds "
+            "its index key too)",
             ["pool"],
             registry=self.registry,
         )
@@ -1066,8 +1076,8 @@ class Telemetry:
         self.kv_context_tokens = Gauge(
             "dynamo_kv_context_tokens",
             "Context tokens of the rows bound to decode slots (written or "
-            "in flight), of a model whose cache is by kind: what "
-            "dynamo_kv_pool_held_bytes is held for",
+            "in flight), of a model that publishes "
+            "dynamo_kv_pool_held_bytes: what those bytes are held for",
             registry=self.registry,
         )
         self.kv_sliding_pages_released = Counter(
