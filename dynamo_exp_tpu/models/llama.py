@@ -2297,17 +2297,22 @@ def ragged_query_block(
     """Stream slots a grid cell of a mixed stream's tiled attention call
     serves (``ops/ragged_attention.py::query_block``, from the model's query
     heads a KV head; a layer's own where ``kv_heads`` names them: a model
-    whose forms differ there has a block a form), or one tile for a model
-    with an indexer, whose
-    selection rides cells of one tile in the absorbed call (its rows of many
-    queries take the expanded call, whose passes are ``expanded_plan``'s).
-    The program and the engine's counter (``dynamo_attn_query_slots_total``)
-    both ask here."""
-    from ..ops.ragged_attention import query_block
+    whose forms differ there has a block a form). Under a learned selection
+    (a model with an indexer) a grouped-query pool's call has a rule of its
+    own (``selected_query_block``: 64 slots, eight tiles, where a cell
+    holds them, the thresholds and the index scores riding the block), and a
+    latent pool's selection rides cells of one tile in the absorbed call (its
+    rows of many queries take the expanded call, whose passes are
+    ``expanded_plan``'s). The program and the engine's counter
+    (``dynamo_attn_query_slots_total``) both ask here."""
+    from ..ops.ragged_attention import query_block, selected_query_block
 
-    if cfg.has_indexer:
+    if cfg.has_indexer and cfg.is_latent:
         return q_tile
-    return query_block(q_tile, cfg.num_heads // (kv_heads or cfg.num_kv_heads))
+    kv_heads = kv_heads or cfg.num_kv_heads
+    if cfg.has_indexer:
+        return selected_query_block(q_tile, cfg.num_heads, cfg.head_dim_, kv_heads)
+    return query_block(q_tile, cfg.num_heads // kv_heads)
 
 
 def one_query_rows(cfg: ModelConfig, n: int, tp: int = 1, q_tile: int = 1) -> int:
@@ -2537,6 +2542,8 @@ def forward_ragged(
         swa = {} if sliding is None else {"sliding": sliding}
         sinks = {} if sink is None else {"sink": sink}
         if use_pallas:
+            from ..ops.ragged_attention import selected_pad
+
             def select(part, tile, pad=0):
                 # ``part``'s learned selection (``_selection_tiled``), and
                 # what it kept for the slots of ``kept_of`` that lie in it
@@ -2559,8 +2566,12 @@ def forward_ragged(
             def kernel(part, tile, block=None, seen=None, selection=None):
                 keep = {}
                 if index is not None:
+                    # a block of several tiles copies its tiles' scores: the
+                    # selection runs to whole blocks (``selected_pad``)
+                    start, stop, _ = part.indices(positions.shape[0])
+                    pad = selected_pad(stop - start, block) if block and block > tile else 0
                     keep["keep"] = (
-                        select(part, tile) if selection is None else selection
+                        select(part, tile, pad) if selection is None else selection
                     )
                 with scope:
                     o = _pallas_ragged(
@@ -2575,11 +2586,13 @@ def forward_ragged(
                 # The tiled part [0, stop) in cells of ``block`` slots: one
                 # call, or for a latent model and for one with an indexer
                 # (whose index scores are [slots, context] float32) a call a
-                # slab (LATENT_SLAB; a slab is whole tiles and no cell spans
-                # two: the same reads in the same order)
+                # slab (LATENT_SLAB; a slab is whole cells, tiles or under a
+                # selection over grouped-query pages blocks of several, and
+                # no cell spans two: the same reads in the same order)
                 if wrap is None and index is None:
                     return [kernel(slice(0, stop), q_tile, block)]
-                slab = LATENT_SLAB // q_tile * q_tile
+                cell = block or q_tile
+                slab = LATENT_SLAB // cell * cell
                 slabs = [
                     slice(a, min(a + slab, stop)) for a in range(0, stop, slab)
                 ]

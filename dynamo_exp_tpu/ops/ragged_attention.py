@@ -32,7 +32,9 @@ Two implementations with identical semantics:
 
   1. **A block of tiles** (:func:`_block_kernel`; the caller names
      ``q_block``, a whole number of tiles: :func:`query_block`, enough that
-     a KV head's score product has about the MXU's 128 rows). A mixed
+     a KV head's score product has about the MXU's 128 rows, or under a
+     learned selection over a grouped-query pool
+     :func:`selected_query_block`'s 64 slots). A mixed
      stream's aligned spans. Where every live tile of the block belongs to
      one row (:func:`block_plan`, derived on the device from ``row_of`` and
      the positions) the cell makes ONE pass over that row's context for all
@@ -61,7 +63,8 @@ Two implementations with identical semantics:
      fetch, 16 page copies behind ~1 us of latency, had nothing to hide
      behind (PERF.md §6, PR 52); a block model's tiles were until PR 56.
   3. **One tile** (:func:`_tile_kernel`): a stream of one row, a call under
-     a learned selection (``keep``, whose scores come a tile a cell), a
+     a learned selection over a LATENT pool (``keep``, whose scores come a
+     tile a cell there) or of one query a row, a
      block named as one tile (a model of 20 query heads a KV head, a latent
      pool's 128 heads; a stream of whole prompts that is no block model's,
      ``models/llama.py::forward_ragged``; the oracle ``q_block=q_tile``),
@@ -140,6 +143,20 @@ the chunk is expanded, the count of ties before each position is a ``[256,
 sums (exact), and the ties a query has passed in earlier chunks are a
 column of scratch beside its running maximum and sum, because the kernel
 walks chunks outside and sub-blocks inside.
+
+Over a GROUPED-QUERY pool (``keye_vl2``: the selection masks a read of K
+and V pages) the tiled call under ``keep`` is form 1 at a block of its own,
+:func:`selected_query_block`'s 64 slots (PR 62; until then one tile a
+cell, every tile re-reading its row's whole context: 5.2 GB of K and V a
+layer and 4,096-slot dispatch at Keye's widths). The thresholds and numbers
+of ties ride the block as ``[w, 1]`` columns, the index scores stay in HBM
+in the scores kernel's layout and a pass copies its tiles' scores of a
+chunk behind the chunk's pages (:class:`_CopiedScores`: the block's 8
+tiles ONE strided copy, a pass of one tile that tile's); the ties a query
+has passed are a ``[w, 1]`` carry, their count one ``[w, 128] x [128,
+128]`` product a chunk. A query's softmax sees the chunks, the mask and the
+order it sees in a cell of its tile alone: the same result to the bit. The
+calls of one query a row (a window's step, a packed tail) stay a row a cell.
 
 **Shapes by layer kind** (``mimo_v2_flash``): the value pool's heads may be
 narrower than the keys' (the call takes the value pool's own row: keys of
@@ -281,6 +298,51 @@ def query_block(q_tile: int, q_per_kv: int) -> int:
     (:func:`_block_vmem_bytes`): the block is the tile."""
     want = max(q_tile, _MXU_ROWS // q_per_kv)
     return -(-want // q_tile) * q_tile
+
+
+# Stream slots a grid cell of the tiled call serves under a learned
+# selection over a grouped-query pool: eight tiles of 8, so a block of one
+# row reads its context, its index scores and its thresholds once for 64
+# queries. Swept on the chip at 8 / 16 / 32 / 64 / 128 at Keye's widths
+# (tools/attn_query_block.py; PERF.md §6, PR 62): 24.9 / 15.2 / 13.7 / 9.71 /
+# 9.65 ms a 4,032-slot call; 64 and 128 cost the same (the pass is bound by
+# its vector work a score element, not by its reads) and 64 leaves more
+# slots in blocks of one row (96.7 % of the cell's against 94.0).
+_SELECTED_SLOTS = 64
+# What a cell of that many slots may hold in VMEM (of a v5e core's 128 MiB;
+# Mosaic is given twice the estimate).
+_SELECTED_VMEM_BYTES = 24 << 20
+
+
+def selected_query_block(q_tile: int, heads: int, width: int, kv_heads: int) -> int:
+    """:func:`query_block` for a call under a learned selection (``keep``)
+    over a grouped-query pool, a rule of its own: ``_SELECTED_SLOTS`` in
+    whole tiles where a cell of ``heads`` query heads of ``width`` lanes on
+    ``kv_heads`` (two bytes a value) holds that in VMEM
+    (:func:`_block_vmem_bytes`), else the largest halving of it that does.
+    :func:`query_block`'s 128 score rows a KV head are 16 slots at 8 query
+    heads a KV head, where the call is still half again as slow (PERF.md
+    §6, PR 62); the unselected callers keep that rule. A stream of
+    tiles of one slot has nothing to read together: the block is the tile.
+    From the call's shapes alone: the program and the engine's counter
+    (``dynamo_attn_query_slots_total``) both ask here
+    (``models/llama.py::ragged_query_block``)."""
+    if q_tile == 1:
+        return 1
+    block = max(1, _SELECTED_SLOTS // q_tile) * q_tile
+    while block > q_tile and _block_vmem_bytes(
+        block, heads, width, kv_heads * width, 1, _CHUNK_TOKENS, 2, 2
+    ) > _SELECTED_VMEM_BYTES:
+        block = max(1, block // q_tile // 2) * q_tile
+    return block
+
+
+def selected_pad(n: int, q_block: int) -> int:
+    """Slots of no query a caller lays behind a stream part's learned
+    selection of ``n`` slots for a call in blocks of ``q_block``: to whole
+    blocks, because the last block's passes copy their tiles' index scores
+    wherever the part ends."""
+    return (-n) % q_block
 
 
 def _to_whole(positions, slots: int, xp):
@@ -449,9 +511,10 @@ def one_query_schedule(rows: int, kv_heads: int, stacked: int) -> str:
     PERF.md §6, PR 56); ``"by_head"`` (head after head through the state's
     references) for one KV head, which has no chain to break and keeps its
     text, for more rows than that and for a cell of one row (the tile
-    kernel: a stream of one slot, a call under a learned selection, the
-    oracle ``q_block=q_tile``, and a tile whose chunk would not be worked
-    together: :func:`one_query_block` answers 1 there). The query heads a
+    kernel: a stream of one slot, a call of one query a row under a learned
+    selection, the oracle ``q_block=q_tile``, and a tile whose chunk would
+    not be worked together: :func:`one_query_block` answers 1 there). The
+    query heads a
     KV head decide nothing: at 16, 8, 4 and 1 of them the call alone
     measured 40–48 % faster a chunk together (PERF.md §6, PR 54). The
     kernel's caller and the engine's counters
@@ -564,6 +627,32 @@ def _heads_together(q, k_of, v_of, live, state, *, hkv, qpk, scale):
     return m_new, l_new, acc * alpha + _side_by_side(values, hkv, qpk)
 
 
+class _CopiedScores:
+    """A pass's index scores, fetched a chunk at a time from the scores
+    kernel's own layout in HBM (``sparse_index.tiled_shape``: chunk-major
+    inside a tile, so a chunk of ``tiles`` tiles is that many pieces of
+    ``[q_tile, S]``, ONE strided copy) into a double buffer beside the
+    chunk's pages: ``_attend``'s ``keep[0]`` where a cell is a block of
+    tiles. A block's whole scores in VMEM would be 8 MB at a table of
+    16,384 tokens, twice that pipelined, and would grow with the table;
+    the copy reads the live chunks alone and holds two of them."""
+
+    def __init__(self, hbm, first, tiles: int, buf, sems):
+        # hbm [T, C, qt, S]; buf [2, >= tiles, qt, S]; sems [2]
+        self.hbm, self.first, self.tiles, self.buf, self.sems = hbm, first, tiles, buf, sems
+
+    def copy(self, c, slot):
+        return pltpu.make_async_copy(
+            self.hbm.at[pl.ds(self.first, self.tiles), c],
+            self.buf.at[slot, pl.ds(0, self.tiles)], self.sems.at[slot],
+        )
+
+    def read(self, slot):
+        """The chunk in buffer ``slot`` a query a row: [tiles * qt, S]."""
+        _, _, qt, S = self.buf.shape
+        return self.buf[slot, : self.tiles].reshape(self.tiles * qt, S)
+
+
 def _attend(
     q_ref,  # [W, H, D] VMEM — the pass's queries
     o_ref,  # [W, H, Dv] VMEM
@@ -639,7 +728,9 @@ def _attend(
     allow_ref [w, 1])``, float32 in VMEM; query ``i`` sees position ``s`` of
     chunk ``c`` only where ``idx_ref[c, i, s] > tau_ref[i]``, or equal to
     it and among the first ``allow_ref[i]`` such positions of the walk
-    (``sparse_index.kept``), every head alike."""
+    (``sparse_index.kept``), every head alike. In a block of tiles the
+    first is a :class:`_CopiedScores`: the scores stay in HBM and a chunk of
+    them comes with the chunk's pages."""
     dvh = hd if vw is None else vw  # lanes of a head in the value pool
     dv = dvh if vd is None else vd
     if sliding is None:
@@ -656,10 +747,15 @@ def _attend(
 
     pools = (table_ref, k_hbm, v_hbm, k_buf, v_buf, sems)
 
+    # A selection whose scores come by copy (a block's: ``_CopiedScores``)
+    # rides the chunk's page copies: started and waited for with them.
+    copied = keep[0] if keep is not None and isinstance(keep[0], _CopiedScores) else None
+
     def chunk_dmas(c, slot):
         """The page copies of this pass's chunk ``c`` into buffer ``slot``."""
         base = c * cp if sliding is None else c * cp + page0
-        return _chunk_copies(row, base, slot, *pools, cp=cp, pmax=pmax)
+        dmas = _chunk_copies(row, base, slot, *pools, cp=cp, pmax=pmax)
+        return dmas if copied is None else [*dmas, copied.copy(c, slot)]
 
     def sink_rows():
         # score rows lie (head, query): a head's logit for each of its ``w``
@@ -767,7 +863,8 @@ def _attend(
         if keep is not None:
             # [w, S] -> a head's score rows (group, query): the same for
             # every head. Through float32: whole (8, 128) tiles stack.
-            idx, tau = keep[0][c], keep[1][...]
+            idx = keep[0][c] if copied is None else copied.read(slot)
+            tau = keep[1][...]
             tie = jnp.where(idx == tau, 1.0, 0.0)
             among = ties_seen + jax.lax.dot_general(
                 tie, upto, (((1,), (0,)), ((), ())),
@@ -932,6 +1029,7 @@ def _block_kernel(
     nt: int,
     qpk: int,
     sinks: bool = False,
+    kept: bool = False,
     **shape,
 ):
     """A grid cell is a block of ``nt`` tiles. Where they belong to one
@@ -940,18 +1038,35 @@ def _block_kernel(
     ``sliding`` (in ``shape``) two more prefetched scalar arrays come
     behind the table (``sliding_starts``: each tile's and each block's
     first visible position), and the one pass covers the union of its
-    queries' windows."""
+    queries' windows. With ``kept`` (a learned selection over a
+    grouped-query pool) the block's thresholds and numbers of ties ([QB, 1]
+    float32 in VMEM) and the stream's index scores (in HBM, the scores
+    kernel's layout) come first among ``refs`` and a double buffer for a
+    chunk of them and its semaphores last: a pass copies its tiles' scores
+    of a chunk behind the chunk's pages (:class:`_CopiedScores`), the one
+    pass all the block's, a pass of one tile that tile's, each with its own
+    ``tau`` and ``allow``."""
     tile_first = block_first = None
     if shape.get("sliding") is not None:
         tile_first, block_first, pos_ref, q_ref, *refs = pos_ref, q_ref, *refs
     if sinks:  # the heads' sink logits, first behind the queries
         shape["sink"], *refs = refs
+    if kept:
+        tau_ref, allow_ref, scores_hbm, *refs, idx_buf, idx_sems = refs
     k_hbm, v_hbm, o_ref, scratch = _pools_out_scratch(refs, shape.get("vd"))
     b = pl.program_id(0)
     merged_len = block_kvlen_ref[b]
 
     def start(ref, at):
         return {} if ref is None else {"first": ref[at]}
+
+    def keep(tile, tiles, at):
+        """``_attend``'s ``keep`` for the ``tiles`` tiles from the block's
+        ``tile`` on, the block's slots ``at``."""
+        if not kept:
+            return {}
+        scores = _CopiedScores(scores_hbm, b * nt + tile, tiles, idx_buf, idx_sems)
+        return {"keep": (scores, tau_ref.at[at], allow_ref.at[at])}
 
     def score_rows(at):
         """() -> the positions ``pos_ref[at]`` [W, 1] as score rows
@@ -967,7 +1082,8 @@ def _block_kernel(
         _attend(
             q_ref, o_ref, score_rows(slice(None)), block_row_ref[b],
             merged_len, table_ref, k_hbm, v_hbm, *scratch, w=nt * qt,
-            qpk=qpk, **start(block_first, b), **shape,
+            qpk=qpk, **start(block_first, b), **keep(0, nt, slice(None)),
+            **shape,
         )
 
     @pl.when(merged_len < 0)
@@ -979,7 +1095,7 @@ def _block_kernel(
                 q_ref.at[at], o_ref.at[at], score_rows(at),
                 tile_row_ref[t], tile_kvlen_ref[t], table_ref, k_hbm,
                 v_hbm, *scratch, w=qt, qpk=qpk, **start(tile_first, t),
-                **shape,
+                **keep(j, 1, at), **shape,
             )
             return 0
 
@@ -1181,11 +1297,17 @@ def ragged_paged_attention(
     may have been given back (the table's entries there are never read).
     None: the program is the one without the argument, to the letter.
 
-    ``keep`` (learned sparse attention; a cell is then one tile):
-    ``(scores, tau, allow)`` of ``ops/sparse_index.py``, the stream's index
-    scores tiled as ``sparse_index.tiled_shape`` and ``select``'s threshold
-    and number of ties a query ([N] each); a query attends only the
-    positions ``sparse_index.kept`` names.
+    ``keep`` (learned sparse attention): ``(scores, tau, allow)`` of
+    ``ops/sparse_index.py``, the stream's index scores tiled as
+    ``sparse_index.tiled_shape`` and ``select``'s threshold and number of
+    ties a query ([N] each); a query attends only the positions
+    ``sparse_index.kept`` names. Over a grouped-query pool the tiled call
+    takes a block of several tiles (:func:`selected_query_block` is the
+    rule) as the unselected call does, each query masked by its own
+    threshold, to the bit what a cell of its tile alone gives; the scores
+    and the two columns then run to whole blocks (:func:`selected_pad`
+    slots of no query behind the stream, of anything). Over a latent pool
+    (``value_dim``) and at one query a row a cell is one tile.
 
     **Values narrower than keys**: the value pool's row is ``Hkv`` heads of
     its own width (``v_cache.shape[-1] // Hkv``), which the result then has:
@@ -1213,7 +1335,7 @@ def ragged_paged_attention(
     key_lanes = fused if latent else Hkv * D
     # Rows a cell of the one-query call walks on one copy pipeline, a row a
     # query or, where the caller names no block, a tile; 1: a cell is a tile
-    # (a learned selection's scores come a tile a cell).
+    # (a learned selection's rows of one query come a row a cell).
     rb = 1
     if keep is None and q_block is None:
         rb = one_query_block(N // qt, H, D, Dv, qt, Hkv)
@@ -1354,28 +1476,46 @@ def ragged_paged_attention(
             tile_row, tile_kvlen, positions, page_table, *starts[:1], q,
             *sink_args, *kept_args, *pools,
         )
-    if keep is not None:
-        raise ValueError("a learned selection rides a call whose block is one tile")
+    if keep is not None and latent:
+        raise ValueError(
+            "a learned selection over a latent pool rides a call whose block is one tile"
+        )
 
     # The last block of a stream that is no whole number of blocks reads
     # past the queries' end: its plan and positions are padded here to
     # -1, what it computes there is masked, and what it writes dropped.
     plan = block_plan(row_of, positions, qt, qb)
     n_blocks = plan[2].shape[0]
-    pos_col = jnp.pad(
-        positions, (0, n_blocks * qb - N), constant_values=-1
-    )[:, None]
+    over = (0, n_blocks * qb - N)
+    pos_col = jnp.pad(positions, over, constant_values=-1)[:, None]
+    column = pl.BlockSpec((qb, 1), lambda t, *_: (t, 0), memory_space=pltpu.VMEM)
+    kept_specs, kept_args = [], ()
+    if keep is not None:
+        # Over a grouped-query pool the selection rides the block: a column
+        # of thresholds and one of ties a block, and the scores left in HBM
+        # for the passes to copy a chunk at a time (``_CopiedScores``). A
+        # last block that is not whole copies tiles past the stream's: the
+        # caller lays slots of no query behind its selection.
+        scores, tau, allow = keep
+        T, _, _, S_i = scores.shape
+        if T < n_blocks * nt:
+            raise ValueError(
+                f"a selection of {T * qt} slots for a stream of {N} in blocks "
+                f"of {qb}: the call asks scores for {n_blocks * nt * qt} "
+                "(selected_pad)"
+            )
+        shape["kept"] = True
+        kept_specs = [column, column, pl.BlockSpec(memory_space=pl.ANY)]
+        kept_args = tuple(
+            jnp.pad(a[:N].astype(jnp.float32), over)[:, None] for a in (tau, allow)
+        ) + (scores,)
+        scratch_shapes += [
+            pltpu.VMEM((2, nt, qt, S_i), jnp.float32), pltpu.SemaphoreType.DMA((2,)),
+        ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 + len(starts),
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec(
-                (qb, 1), lambda t, *_: (t, 0), memory_space=pltpu.VMEM
-            ),
-            query_spec(),
-            *sink_specs,
-            *pool_specs,
-        ],
+        in_specs=[column, query_spec(), *sink_specs, *kept_specs, *pool_specs],
         out_specs=query_spec(Dv),
         scratch_shapes=scratch_shapes,
     )
@@ -1393,7 +1533,7 @@ def ragged_paged_attention(
         out_shape=out_shape,
         interpret=interpret,
         **params,
-    )(*plan, page_table, *starts, pos_col, q, *sink_args, *pools)
+    )(*plan, page_table, *starts, pos_col, q, *sink_args, *kept_args, *pools)
 
 
 # ------------------------------------------- a latent pool, expanded form

@@ -236,7 +236,10 @@ class Telemetry:
             "once for every tile in it) or tile (a block of several rows, "
             "or a model whose block is one tile: the pages once a tile). "
             "Counted by the dispatch builders with the kernel's own rule "
-            "(ops/ragged_attention.py); a kernel engine only",
+            "(ops/ragged_attention.py: query_block, or under a learned "
+            "selection over grouped-query pages selected_query_block; a "
+            "latent pool under a selection stays a tile); a kernel engine "
+            "only",
             ["pass"],  # merged | tile
             registry=self.registry,
         )

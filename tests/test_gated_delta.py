@@ -498,3 +498,37 @@ def test_attention_query_block_compiles_for_v5e(one_chip, heads, kv_heads, head_
         sds((33, 1024), jnp.int32), sds((n,), jnp.int32), sds((n,), jnp.int32),
     ).compile()
     assert "ragged_paged_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block", [64, 128, 8])
+def test_selected_attention_block_compiles_for_v5e(one_chip, block):
+    """Mosaic takes the tiled call under a learned selection over
+    grouped-query pools (``keep``) at Keye-VL-2.0's widths (32 query heads
+    on 4 KV heads of 128, the index key's 128 lanes behind the keys in the K
+    row, a slab of 2048 slots over tables of 16,384 tokens) at the selected
+    call's own block of 64 slots (``selected_query_block``: the scores left
+    in HBM, a chunk of them copied behind the chunk's pages), at the 128 the
+    sweep found no faster, and at the one-tile form beside them; the call
+    keeps its name."""
+    from dynamo_exp_tpu.ops import sparse_index as si
+    from dynamo_exp_tpu.ops.ragged_attention import (
+        ragged_paged_attention, selected_query_block,
+    )
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    heads, kv_heads, hd, n, ps, pmax = 32, 4, 128, 2048, 16, 1024
+    assert selected_query_block(8, heads, hd, kv_heads) == 64
+    f, i = jnp.float32, jnp.int32
+    compiled = jax.jit(
+        lambda q, k, v, t, r, p, s, tau, allow: ragged_paged_attention(
+            q, k, v, t, r, p, num_kv_heads=kv_heads, q_tile=8, q_block=block,
+            keep=(s, tau, allow),
+        )
+    ).lower(
+        sds((n, heads, hd), jnp.bfloat16), sds((2048, ps, kv_heads * hd + 128), jnp.bfloat16),
+        sds((2048, ps, kv_heads * hd), jnp.bfloat16), sds((33, pmax), i), sds((n,), i),
+        sds((n,), i), sds(si.tiled_shape(n, 8, ps, pmax), f), sds((n,), f), sds((n,), f),
+    ).compile()
+    assert "ragged_paged_attention" in compiled.as_text()

@@ -558,14 +558,53 @@ async def test_engine_through_the_kernels():
         num_pages=16,
     )
     eng.start()
+    merged = _counter("dynamo_attn_query_slots_total", **{"pass": "merged"})
     try:
         prompt = PROMPT[3:40]
         got = await _run(eng, prompt, 10)
         assert got == _greedy_reference(eng.params, prompt, 10)
         assert eng._attn_impl == "pallas"
         assert _counter("dynamo_dsa_index_slots_total") > 0
+        # a long prompt's chunks lie in blocks of one row: the selected call
+        # reads them a block a pass (``selected_query_block``), as the
+        # scores kernel does, and the engine's counter asks the same rule
+        assert _counter("dynamo_attn_query_slots_total", **{"pass": "merged"}) > merged
     finally:
         eng.stop()
+
+
+def test_the_attention_counter_asks_the_selected_calls_rule(engine):
+    """``_count_attn_passes`` and the program ask ``ragged_query_block``: a
+    grouped-query model under a selection counts its tiled slots at the
+    selected call's block (64 at these widths too), a span's middle merged
+    and a block of two rows by tile."""
+    import dataclasses
+    import types
+
+    from dynamo_exp_tpu.models.llama import ragged_query_block
+    from dynamo_exp_tpu.ops.ragged_attention import merged_slot_counts
+
+    eng = types.SimpleNamespace(
+        cfg=dataclasses.replace(engine.cfg, attention_impl="pallas"), _attn_impl="pallas",
+        _count_one_query_rows=lambda positions: None,
+    )
+    count = types.MethodType(TPUEngine._count_attn_passes, eng)
+    tile = eng.cfg.ragged_q_tile
+    block = ragged_query_block(CFG, tile, CFG.num_kv_heads)
+    assert block == 64 and tile == 8
+    nb = 512
+    tiled = nb - eng.cfg.ragged_packed_slots(nb)
+    # row 0 fills four blocks and the head of a fifth, which row 1 shares
+    row_of = np.where(np.arange(nb) < 300, 0, 1).astype(np.int32)
+    positions = np.where(np.arange(nb) < tiled, np.arange(nb) % 300 + 7, -1).astype(np.int32)
+    name = "dynamo_attn_query_slots_total"
+    before = {k: _counter(name, **{"pass": k}) for k in ("merged", "tile")}
+    count(nb, row_of, positions)
+    took = {k: _counter(name, **{"pass": k}) - before[k] for k in before}
+    assert (took["merged"], took["tile"]) == merged_slot_counts(
+        row_of[:tiled], positions[:tiled], tile, block
+    )
+    assert took["merged"] == 256 + (tiled - 320) and took["tile"] == 64
 
 
 async def test_preempt_and_resume():

@@ -75,6 +75,50 @@ def test_query_block_follows_from_the_head_layout(heads, kv_heads, q_tile, want)
     assert qb == want and qb % q_tile == 0
 
 
+@pytest.mark.parametrize(
+    "config, want",
+    [
+        # Keye-VL-2.0: a selection over grouped-query pools, 32 heads on 4 KV
+        # heads of 128: the selected call's own block (``query_block`` would
+        # answer 16 there)
+        ("keye-vl-2.0-30b-a3b-l5", 64),
+        # GLM-5: a selection over a latent pool, 64 heads on ONE KV head: the
+        # tile (its rows of many queries take the expanded call)
+        ("glm-5-l5e16", 8),
+        # unselected models keep ``query_block``'s answer
+        ("qwen3-4b", 32),
+        ("sdar-30b-a3b-l6", 16),
+        ("olmo-hybrid-7b-l8", 128),
+    ],
+)
+def test_a_models_query_block_by_its_selection_and_pool(config, want):
+    """``models/llama.py::ragged_query_block``, which the program and the
+    engine's counter both ask: from the call's shapes alone, by whether the
+    model selects and what its pool is."""
+    import json
+    import os
+
+    from dynamo_exp_tpu.models import ModelConfig
+    from dynamo_exp_tpu.models.llama import ragged_query_block
+    from dynamo_exp_tpu.ops.ragged_attention import selected_query_block
+
+    configs = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs")
+    with open(os.path.join(configs, f"{config}.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    got = ragged_query_block(cfg, 8, cfg.num_kv_heads)
+    assert got == ragged_query_block(cfg, 8) == want
+    if cfg.has_indexer and not cfg.is_latent:
+        assert got == selected_query_block(8, cfg.num_heads, cfg.head_dim_, cfg.num_kv_heads)
+        assert query_block(8, cfg.num_heads // cfg.num_kv_heads) == 16
+    elif not cfg.has_indexer:
+        assert got == query_block(8, cfg.num_heads // cfg.num_kv_heads)
+    # a tile of one slot has nothing to read together; heads too wide for a
+    # cell's VMEM halve the block, in whole tiles
+    assert selected_query_block(1, 32, 128, 4) == 1
+    assert selected_query_block(8, 128, 128, 16) == 32
+    assert selected_query_block(24, 32, 128, 4) == 48
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_host_count_and_device_rule_agree_on_a_drawn_stream(seed):
     """The engine counts merged slots on the host with ``numpy``; the

@@ -92,6 +92,27 @@ def timed(fn, calls: int) -> tuple[float, object]:
     return statistics.median(times), out
 
 
+def draw_selection(key, positions, pad: int, ps: int, pmax: int, topk: int, interpret: bool,
+                   tile: int = TILE):
+    """A model with an indexer: drawn index scores in the scores kernel's
+    layout for the stream and ``pad`` slots of no query behind it (what the
+    call asks: ``EXPANDED_SELECTION_PAD``, ``selected_pad``), and the exact
+    top ``topk`` a query (``sparse_index.select_tiled``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_exp_tpu.ops import sparse_index as si
+
+    shape = si.tiled_shape(positions.shape[0] + pad, tile, ps, pmax)
+    bounds = jnp.pad(positions, (0, pad), constant_values=-1)
+    drawn = jax.random.normal(key, shape, jnp.float32)
+    T, C, qt, S = shape
+    at = (jnp.arange(C)[:, None, None] * S + jnp.arange(S)[None, None])[None]
+    scores = jnp.where(at <= bounds.reshape(T, 1, qt, 1), drawn, -jnp.inf)
+    tau, allow = si.select_tiled(scores, bounds.reshape(T, qt), topk, interpret=interpret)
+    return scores, tau.reshape(-1), allow.reshape(-1)
+
+
 def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
     """The latent model's table: both forms of the tiled call over one
     row's chunk, by chunk length and context."""
@@ -102,7 +123,6 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
     from dynamo_exp_tpu.models.llama import latent_form_queries, latent_value_lanes
     import functools
 
-    from dynamo_exp_tpu.ops import sparse_index as si
     from dynamo_exp_tpu.ops.ragged_attention import (
         _EXPANDED_QUERIES, EXPANDED_SELECTION_PAD, expanded_plan, ragged_paged_attention,
         ragged_paged_attention_expanded,
@@ -161,21 +181,9 @@ def latent_forms(args, name, config, mcfg, ecfg, opts, dev) -> int:
             **({} if keep is None else {"keep": keep}),
         )
 
-    @jax.jit
-    def selection(positions):
-        """A model with an indexer: drawn index scores in the scores kernel's
-        layout, longer by a sub-block as the expanded call asks, and the exact
-        top ``index_topk`` a query (``sparse_index.select_tiled``)."""
-        shape = si.tiled_shape(n + EXPANDED_SELECTION_PAD, TILE, ps, pmax)
-        bounds = jnp.pad(positions, (0, EXPANDED_SELECTION_PAD), constant_values=-1)
-        drawn = jax.random.normal(keys[3], shape, jnp.float32)
-        T, C, qt, S = shape
-        at = (jnp.arange(C)[:, None, None] * S + jnp.arange(S)[None, None])[None]
-        scores = jnp.where(at <= bounds.reshape(T, 1, qt, 1), drawn, -jnp.inf)
-        tau, allow = si.select_tiled(
-            scores, bounds.reshape(T, qt), mcfg.index_topk, interpret=interpret
-        )
-        return scores, tau.reshape(-1), allow.reshape(-1)
+    selection = jax.jit(lambda positions: draw_selection(
+        keys[3], positions, EXPANDED_SELECTION_PAD, ps, pmax, mcfg.index_topk, interpret
+    ))
 
     # other sizes of a pass than the module's: "q_max,q_sub,kv_chunk;..."
     tried = [
@@ -261,8 +269,9 @@ def main() -> int:
     from dynamo_exp_tpu import run as server
     from dynamo_exp_tpu.engine import EngineConfig
     from dynamo_exp_tpu.models import ModelConfig
+    from dynamo_exp_tpu.models.llama import ragged_query_block
     from dynamo_exp_tpu.ops.ragged_attention import (
-        block_plan, merged_slot_counts, query_block, ragged_paged_attention,
+        block_plan, merged_slot_counts, ragged_paged_attention, selected_pad,
     )
 
     dev = jax.devices()[0]
@@ -293,18 +302,21 @@ def main() -> int:
     dt = jnp.bfloat16
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
     q = jax.random.normal(keys[0], (tiled, heads, hd), dt)
-    k = jax.random.normal(keys[1], (pool_pages, ps, kv_heads * hd), dt)
+    # a model with an indexer keeps its index key behind the heads' keys
+    k = jax.random.normal(keys[1], (pool_pages, ps, kv_heads * hd + mcfg.index_lanes), dt)
     v = jax.random.normal(keys[2], (pool_pages, ps, kv_heads * hd), dt)
     interpret = dev.platform != "tpu"
     token_bytes = 2 * kv_heads * hd * k.dtype.itemsize
     hbm = run_cell.load_json(BENCH, "peaks.json")["devices"].get(dev.device_kind, {}).get("hbm_gbps")
-    rule = query_block(TILE, heads // kv_heads)
+    rule = ragged_query_block(mcfg, TILE, mcfg.num_kv_heads)
+    selected = mcfg.has_indexer  # the call under a drawn selection (``keep``)
     head = {
         "config": name, "platform": dev.platform, "device_kind": dev.device_kind,
         "heads_per_chip": [heads, kv_heads, hd], "page_size": ps, "nb": args.nb, "tiled_slots": tiled,
         "spans": spans, "live_slots": int((positions >= 0).sum()),
         "context_mean": float((positions[positions >= 0] + 1).mean()),
         "kv_bytes_a_token": token_bytes, "query_block_rule": rule, "seed": args.seed, "calls": args.calls,
+        **({"selected": True, "index_topk": mcfg.index_topk} if selected else {}),
     }
     print(json.dumps(head), flush=True)
     out_dir = os.path.join(ROOT, "chiprun_out", "attn_query_block")
@@ -312,6 +324,17 @@ def main() -> int:
     suffix = "" if dev.platform == "tpu" else ".cpu-rehearsal"
     dev_args = (jnp.asarray(table), jnp.asarray(row_of), jnp.asarray(positions))
     base = None
+    # ONE selection for every block, run to whole blocks of the largest
+    keep = {}
+    if selected:
+        pad = max(selected_pad(tiled, int(b)) for b in args.blocks.split(","))
+        keep["keep"] = jax.block_until_ready(jax.jit(lambda: draw_selection(
+            keys[3], dev_args[2], pad, ps, pmax, mcfg.index_topk, interpret
+        ))())
+        # the one-tile form takes the stream's own tiles and no more
+        one_tile = {"keep": jax.block_until_ready(
+            (keep["keep"][0][: tiled // TILE], keep["keep"][1][:tiled], keep["keep"][2][:tiled])
+        )}
     with open(os.path.join(out_dir, f"{name}{suffix}.jsonl"), "w") as f:
         f.write(json.dumps(head) + "\n")
         for qb in (int(b) for b in args.blocks.split(",")):
@@ -319,6 +342,7 @@ def main() -> int:
                 continue
             ms, out = timed(lambda: ragged_paged_attention(
                 q, k, v, *dev_args, num_kv_heads=kv_heads, q_tile=TILE, q_block=qb, interpret=interpret,
+                **(one_tile if selected and qb == TILE else keep),
             ), args.calls)
             out = np.asarray(out, np.float32)
             base = out if base is None else base
@@ -343,8 +367,14 @@ def main() -> int:
             jnp.full((n,), args.tail_context, jnp.int32),
         )
         qt = jax.random.normal(keys[3], (n, heads, hd), dt)
+        tail_keep = {}
+        if selected:
+            tail_keep["keep"] = jax.block_until_ready(jax.jit(lambda: draw_selection(
+                keys[3], tail_args[2], 0, ps, tail_pages, mcfg.index_topk, interpret, tile=1
+            ))())
         ms, _ = timed(lambda: ragged_paged_attention(
             qt, k, v, *tail_args, num_kv_heads=kv_heads, q_tile=1, interpret=interpret,
+            **tail_keep,
         ), args.calls)
         line = {"tail_rows": n, "tail_context": args.tail_context, "call_ms": 1e3 * ms}
         print(json.dumps(line), flush=True)
