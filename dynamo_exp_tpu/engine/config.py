@@ -93,7 +93,7 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
     - ring prefill (``sp`` > 1): it writes pages from outside the paged
       layer loop that carries the state;
     - tensor parallelism (``tp`` > 1) where the state is a selective
-      scan's or the delta rule's: the recurrence, its kernel and its pool
+      scan's, a Mamba-2 mixer's or the delta rule's: the recurrence, its kernel and its pool
       are one chip's (no channel or head split);
     - ``tier`` = "disaggregated transfer" / "migration"
       (``disagg/transfer.py``, ``runtime/reclaim.py``): named by the
@@ -119,11 +119,16 @@ def refuse_seq_state_tiers(cfg: "EngineConfig | None", tier: str | None = None) 
                 "tensor parallelism (tp > 1) of linear attention",
                 cfg.tp > 1 and cfg.model.num_linear_layers > 0,
             ),
+            (
+                "tensor parallelism (tp > 1) of a Mamba-2 mixer",
+                cfg.tp > 1 and cfg.model.num_mamba2_layers > 0,
+            ),
         ) if on
     ]
     if armed:
         kind = (
             "selective-scan" if cfg.model.num_mamba_layers
+            else "Mamba-2" if cfg.model.num_mamba2_layers
             else "linear-attention" if cfg.model.num_linear_layers
             else "short-convolution"
         )

@@ -1593,7 +1593,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
         (``dynamo_ragged_dispatch_total``), its live and padding slots
         (``dynamo_ragged_slots_total``; a window's rows times its
         steps), for a selective-scan model the live ones as tokens
-        through the scan (``dynamo_ssm_scan_tokens_total``), for a
+        through the scan (``dynamo_ssm_scan_tokens_total``; a Mamba-2
+        model's through its recurrence, ``dynamo_mamba2_tokens_total``), for a
         linear-attention model the same through the delta rule
         (``dynamo_linear_attn_tokens_total``) and those of them that
         took its one-token step (``dynamo_linear_attn_step_rows_total``:
@@ -1736,6 +1737,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
             tel.ssm_scan_tokens.labels(program).inc(live)
         if self.cfg.model.num_linear_layers:
             tel.linear_attn_tokens.labels(program).inc(live)
+        if self.cfg.model.num_mamba2_layers:
+            tel.mamba2_tokens.labels(program).inc(live)
         m = self.cfg.model
         if m.loop_passes > 1:  # the layer applications they take
             tel.layer_passes.inc(live * m.loop_passes * m.num_layers)

@@ -242,6 +242,32 @@ class ModelConfig:
     # (pass, layer) (``kv_layer_slots``). 1: every layer once, every other
     # family.
     loop_passes: int = 1
+    # nemotron_h: each of the published layers is ONE half, ``x + mixer(norm
+    # x)``, its mixer a Mamba-2 mixer, routed experts or attention. A
+    # pre-norm pair ``x += op(norm1 x); x += ff(norm2 x)`` IS two of them, so
+    # the stack is held as pairs whose feed-forward may be ABSENT:
+    # ``layer_types`` names each pair's operator ("mamba2" or
+    # "full_attention") and ``ff_types`` its feed-forward ("routed" or
+    # "none"; None: every other family, ``ff_kinds`` by ``num_dense_layers``).
+    # ``num_layers`` counts the pairs, ``num_block_layers`` the model's own.
+    # The Mamba-2 mixer (``models/llama.py::mamba2_mixer``): ``mamba2_heads``
+    # heads of ``mamba2_head_dim`` channels with a scalar decay a head,
+    # ``mamba_d_state`` states a channel, B and C in ``mamba2_groups``
+    # groups of heads, ONE depthwise convolution of ``mamba_d_conv`` taps
+    # over x, B and C side by side (``mamba2_conv_width``), the output gated
+    # and then normed a group at a time. A sequence carries the
+    # convolution's last inputs and a ``[mamba_d_state, heads * head_dim]``
+    # float32 state a Mamba-2 layer (``has_seq_state``).
+    ff_types: tuple[str, ...] | None = None
+    mamba2_heads: int = 0
+    mamba2_head_dim: int = 0
+    mamba2_groups: int = 1
+    # Experts and shared expert of TWO matrices, ``down(act(up x))``: no gate
+    # matrix anywhere in the tree (``w_gate`` / ``shared_gate`` absent).
+    gated_experts: bool = True
+    # The shared expert's width where it is a key of its own (0:
+    # ``num_shared_experts`` experts of the routed width).
+    shared_expert_width: int = 0
     dtype: str = "bfloat16"
     model_type: str = "llama"
 
@@ -253,10 +279,13 @@ class ModelConfig:
     def op_kinds(self) -> tuple[str, ...]:
         """Each layer's operator: "conv" (gated short convolution),
         "mamba" (selective scan), "linear" (linear attention by the gated
-        delta rule) or "attn" (every softmax attention form, sliding or
-        full: ``attn_forms``)."""
+        delta rule), "mamba2" (a Mamba-2 mixer) or "attn" (every softmax
+        attention form, sliding or full: ``attn_forms``)."""
         types = self.layer_types or ("full_attention",) * self.num_layers
-        names = {"conv": "conv", "mamba": "mamba", "linear_attention": "linear"}
+        names = {
+            "conv": "conv", "mamba": "mamba", "linear_attention": "linear",
+            "mamba2": "mamba2",
+        }
         return tuple(names.get(t, "attn") for t in types)
 
     @property
@@ -316,7 +345,10 @@ class ModelConfig:
 
     @property
     def ff_kinds(self) -> tuple[str, ...]:
-        """Each layer's feed-forward: "dense" or "routed"."""
+        """Each layer's feed-forward: "dense", "routed" or "none" (a layer
+        of one half: ``ff_types``)."""
+        if self.ff_types:
+            return self.ff_types
         return tuple(
             "routed" if self.is_moe and i >= self.num_dense_layers else "dense"
             for i in range(self.num_layers)
@@ -334,7 +366,9 @@ class ModelConfig:
         form changes (``attn_forms``: the form is not in the tuple), so a
         run reads one pool at one static width."""
         runs: list[tuple[str, str, int, int, int, int]] = []
-        seen = dict.fromkeys(("conv", "mamba", "linear", "attn", "dense", "routed"), 0)
+        seen = dict.fromkeys(
+            ("conv", "mamba", "linear", "mamba2", "attn", "dense", "routed", "none"), 0
+        )
         forms = self.attn_forms if self.kv_by_kind else ("",) * self.num_layers
         for i, kind in enumerate(zip(self.op_kinds, self.ff_kinds)):
             if runs and runs[-1][:2] == kind and forms[i] == forms[i - 1]:
@@ -378,6 +412,30 @@ class ModelConfig:
         return self.mamba_expand * self.hidden_size
 
     @property
+    def num_mamba2_layers(self) -> int:
+        return self.op_kinds.count("mamba2")
+
+    @property
+    def mamba2_inner(self) -> int:
+        """Channels of a Mamba-2 mixer: every head's side by side."""
+        return self.mamba2_heads * self.mamba2_head_dim
+
+    @property
+    def mamba2_conv_width(self) -> int:
+        """Channels of a Mamba-2 layer's convolution: x, then every group's
+        B, then every group's C."""
+        return self.mamba2_inner + 2 * self.mamba2_groups * self.mamba_d_state
+
+    @property
+    def num_block_layers(self) -> int:
+        """The model's own count of layers (``num_hidden_layers``): a pair
+        whose feed-forward is present is two of a model whose layers are one
+        half each (``ff_types``), every other model's layer one."""
+        if not self.ff_types:
+            return self.num_layers
+        return self.num_layers + sum(ff != "none" for ff in self.ff_types)
+
+    @property
     def linear_conv_width(self) -> int:
         """Channels of a linear-attention layer's convolutions: q, k and v
         side by side."""
@@ -405,6 +463,19 @@ class ModelConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
+    def expert_stack_width(self) -> int:
+        """The width the expert matrices are STORED at: the experts' own,
+        brought up to whole groups of 128 lanes where it is past one group
+        and not whole (nemotron_h's 1856 -> 1920), the added columns of
+        ``w_up`` and rows of ``w_down`` zeros, which add exactly nothing. A
+        width that is no multiple of 128 the grouped-matmul kernel cannot
+        tile (``ops/expert_gmm.py::supported``), and XLA:TPU lays an array
+        whose last dimension is none out with another dimension on the
+        lanes, so that every kernel call would first copy the whole stack."""
+        width = self.expert_intermediate_size
+        return width if width % 128 == 0 or width < 128 else -(-width // 128) * 128
+
+    @property
     def experts_held(self) -> int:
         """Routed experts whose matrices this device holds."""
         return self.num_experts_held or self.num_experts
@@ -419,8 +490,10 @@ class ModelConfig:
 
     @property
     def shared_width(self) -> int:
-        """Width of the gateless shared expert (0: none)."""
-        return self.num_shared_experts * self.expert_intermediate_size
+        """Width of the shared expert that is added as it is (0: none)."""
+        return self.shared_expert_width or (
+            self.num_shared_experts * self.expert_intermediate_size
+        )
 
     @property
     def is_latent(self) -> bool:
@@ -584,6 +657,8 @@ class ModelConfig:
             return cls._from_ouro(cfg)
         if model_type == "KeyeVL2":
             return cls._from_keye_vl2(cfg)
+        if model_type == "nemotron_h":
+            return cls._from_nemotron_h(cfg)
         if model_type in ("qwen2_moe", "qwen3_moe", "sdar_moe") and (
             cfg.get("mlp_only_layers") or cfg.get("decoder_sparse_step", 1) != 1
         ):
@@ -1291,6 +1366,113 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_nemotron_h(cls, cfg: dict) -> "ModelConfig":
+        """NVIDIA's ``nemotron_h`` (Nemotron-3-Nano-30B-A3B): layer ``i`` is
+        what letter ``i`` of ``hybrid_override_pattern`` says, ``M`` a
+        Mamba-2 mixer, ``E`` routed experts beside a shared one, ``*``
+        attention, each ONE half ``x + mixer(norm x)``. An ``E`` always
+        follows an ``M`` or a ``*``, so the stack is read as pairs with the
+        feed-forward present or absent (``ff_types``): ``ME``, ``M``, ``*E``,
+        ``*``. Any other letter is refused by name (``-``, the family's
+        dense feed-forward, among them), as is an ``E`` that follows no
+        mixer. Attention has no positional term (the family's attention
+        layer applies none: ``rope_theta`` and ``partial_rotary_factor``
+        steer nothing). Experts are two matrices, ``down(relu(up x)^2)``,
+        chosen top-k of sigmoid scores plus ``e_score_correction_bias`` and
+        weighed by the renormalised scores times ``routed_scaling_factor``;
+        the shared expert has the same form at
+        ``moe_shared_expert_intermediate_size``. The mixer's inner width is
+        ``mamba_num_heads * mamba_head_dim`` (``expand`` steers nothing).
+        ``n_routed_experts`` is what this device *holds*, as for
+        ``pangu_ultra_moe`` (``_expert_share``)."""
+        name = "nemotron_h"
+        pattern = str(cfg["hybrid_override_pattern"])
+        n_layers = cfg["num_hidden_layers"]
+        unknown = sorted(set(pattern) - set("ME*"))
+        if unknown:
+            raise ValueError(
+                f"{name} hybrid_override_pattern has layers of kind "
+                f"{', '.join(repr(c) for c in unknown)}: served are 'M' (Mamba-2), "
+                "'E' (routed experts) and '*' (attention)"
+            )
+        if len(pattern) != n_layers:
+            raise ValueError(
+                f"{name} hybrid_override_pattern names {len(pattern)} layers, "
+                f"num_hidden_layers {n_layers}"
+            )
+        ops, ffs = [], []
+        for i, c in enumerate(pattern):
+            if c == "E":
+                if not ops or ffs[-1] != "none":
+                    raise ValueError(
+                        f"{name} layer {i} is an 'E' that follows no 'M' or '*': "
+                        "the stack is served as (mixer, experts) pairs"
+                    )
+                ffs[-1] = "routed"
+            else:
+                ops.append("mamba2" if c == "M" else "full_attention")
+                ffs.append("none")
+        for key, want in (
+            ("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu"),
+            ("use_conv_bias", True), ("mamba_proj_bias", False),
+            ("use_bias", False), ("attention_bias", False), ("mlp_bias", False),
+            ("sliding_window", None), ("norm_topk_prob", True),
+            ("n_shared_experts", 1),
+        ):
+            if cfg.get(key, want) != want:
+                raise ValueError(f"{name} with {key}={cfg[key]!r} is not supported")
+        if cfg.get("n_group", 1) > 1 or cfg.get("topk_group", 1) > 1:
+            raise ValueError(
+                f"{name} with group-limited selection (n_group / topk_group > 1) "
+                "is not supported"
+            )
+        if cfg.get("conv_kernel", 4) < 2:
+            raise ValueError(f"{name} needs conv_kernel >= 2")
+        heads, groups = cfg["mamba_num_heads"], cfg["n_groups"]
+        if heads % groups:
+            raise ValueError(f"{name} needs mamba_num_heads a multiple of n_groups")
+        held, routed, first = _expert_share(
+            cfg, name, "n_routed_experts", "n_routed_experts_global"
+        )
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=len(ops),
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rms_norm_eps=cfg.get("layer_norm_epsilon", cfg.get("norm_eps", 1e-5)),
+            max_position_embeddings=cfg.get("max_position_embeddings", 262144),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            hidden_act="relu2",
+            layer_types=tuple(ops),
+            ff_types=tuple(ffs),
+            mamba_d_state=cfg["ssm_state_size"],
+            mamba_d_conv=cfg.get("conv_kernel", 4),
+            mamba2_heads=heads,
+            mamba2_head_dim=cfg["mamba_head_dim"],
+            mamba2_groups=groups,
+            use_rope=False,
+            num_experts=routed,
+            num_experts_held=held if held < routed else 0,
+            first_expert_held=first,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            num_shared_experts=1,
+            shared_expert_width=cfg.get(
+                "moe_shared_expert_intermediate_size", cfg["moe_intermediate_size"]
+            ),
+            gated_experts=False,
+            router_score="sigmoid",
+            use_expert_bias=True,
+            router_norm_eps=1e-20,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            dtype=cfg.get("torch_dtype", "bfloat16"),
+            model_type=name,
+        )
+
+    @classmethod
     def from_pretrained(cls, path: str) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             cfg = json.load(f)
@@ -1404,7 +1586,7 @@ KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "gemma", "gemma2", "gemma3_text",
     "phi3", "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
-    "mimo_v2_flash", "ouro", "KeyeVL2",
+    "mimo_v2_flash", "ouro", "KeyeVL2", "nemotron_h",
 })
 
 # A block-diffusion model's generation keys and the SDAR family's
@@ -1887,6 +2069,46 @@ MIXTRAL_8X7B = ModelConfig(  # Mixtral-8x7B shape
     model_type="mixtral",
 )
 
+TINY_NEMOTRON_H = ModelConfig(  # nemotron_h family shape: layers of one
+    # half, ``MEM*EMEM*`` as the pairs ME M *E ME M *, so six runs of one pair;
+    # Mamba-2 mixers of 4 heads of 64 channels in 2 groups with 128 states (the
+    # least the chunk kernel tiles: two heads fill a lane group, the states
+    # one); 8 two-matrix relu^2 experts, top-2 by sigmoid
+    # and a selection bias, beside a shared expert twice as wide; no rope; 512
+    # ids, which a synthetic tokenizer needs (models/synth.py)
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=32,
+    num_layers=6,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    max_position_embeddings=512,
+    rms_norm_eps=1e-5,
+    hidden_act="relu2",
+    layer_types=(
+        "mamba2", "mamba2", "full_attention", "mamba2", "mamba2", "full_attention",
+    ),
+    ff_types=("routed", "none", "routed", "routed", "none", "none"),
+    mamba_d_state=128,
+    mamba_d_conv=4,
+    mamba2_heads=4,
+    mamba2_head_dim=64,
+    mamba2_groups=2,
+    use_rope=False,
+    num_experts=8,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    num_shared_experts=1,
+    shared_expert_width=64,
+    gated_experts=False,
+    router_score="sigmoid",
+    use_expert_bias=True,
+    router_norm_eps=1e-20,
+    routed_scaling_factor=2.5,
+    model_type="nemotron_h",
+)
+
 PRESETS = {
     "tiny": TINY,
     "tiny-qwen2": TINY_QWEN2,
@@ -1901,6 +2123,7 @@ PRESETS = {
     "tiny-mimo": TINY_MIMO,
     "tiny-ouro": TINY_OURO,
     "tiny-keye": TINY_KEYE,
+    "tiny-nemotron-h": TINY_NEMOTRON_H,
     "llama-1b": LLAMA_1B,
     "llama-3b": LLAMA_3B,
     "llama-8b": LLAMA_8B,

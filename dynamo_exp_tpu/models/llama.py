@@ -99,7 +99,12 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     whose feed-forwards are of both kinds keeps its dense layers' under
     ``dense_gate``/``dense_up``/``dense_down``; ``w_gate``/``w_up``/
     ``w_down`` are then the routed layers' (``EXPERT_STACKS``). In a
-    model of one kind every stack is ``[L, ...]``, as it always was.
+    model of one kind every stack is ``[L, ...]``, as it always was. A
+    model whose experts are two matrices (``gated_experts`` False:
+    nemotron_h) has no ``w_gate`` and no ``shared_gate``; one whose layers
+    are one half each (``ff_types``) has ``mlp_norm`` over the layers whose
+    feed-forward is there, the routed ones, and the Mamba-2 mixer's
+    (``m2_*``, :func:`_init_mamba2`) over the Mamba-2 layers.
 
     A model whose sliding and full layers differ in their KV heads
     (``ModelConfig.kv_heads_by_form``) stacks the K and V projections by
@@ -126,7 +131,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     )
     La, Lc, Lr = cfg.num_attn_layers, cfg.num_conv_layers, cfg.num_routed_layers
     Lm = cfg.num_mamba_layers
-    Ld = L - Lr
+    Ld = L - Lr - cfg.ff_kinds.count("none")
     ks = jax.random.split(rng, 17)
     # Keys for what lfm2 added, split off a folded key: ``ks`` (and so
     # every other family's weights) stays what it was.
@@ -172,6 +177,10 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         layers["conv_out"] = init(kc[2], (Lc, D, D), D)
     if Lm:  # jamba: the Mamba-1 mixer
         layers.update(_init_mamba(jax.random.fold_in(rng, 2), cfg, init))
+    if cfg.num_mamba2_layers:  # nemotron_h: the Mamba-2 mixer
+        layers.update(_init_mamba2(jax.random.fold_in(rng, 8), cfg, init))
+    if cfg.ff_types:  # layers of one half: a norm a feed-forward that is there
+        layers["mlp_norm"] = jnp.ones((Lr, D), dt)
     if cfg.attention_bias:  # qwen2: bias on q/k/v, none on o
         layers["bq"] = init(ks[9], (L, H * hd), H * hd)
         layers["bk"] = init(ks[10], (L, Hkv * hd), Hkv * hd)
@@ -186,11 +195,17 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         layers["post_ffn_norm"] = init_norm((L, D), dt)
     if cfg.is_moe:
         # the router scores every expert; the matrices are the held ones'
-        E, Ie = cfg.experts_held, cfg.expert_intermediate_size
+        E, Ie, Is = cfg.experts_held, cfg.expert_intermediate_size, cfg.expert_stack_width
         layers["router"] = init(ks[12], (Lr, D, cfg.num_experts), D)
-        layers["w_gate"] = init(ks[5], (Lr, E, D, Ie), D)
-        layers["w_up"] = init(ks[6], (Lr, E, D, Ie), D)
-        layers["w_down"] = init(ks[7], (Lr, E, Ie, D), Ie)
+        if cfg.gated_experts:
+            layers["w_gate"] = init(ks[5], (Lr, E, D, Is), D)
+        layers["w_up"] = init(ks[6], (Lr, E, D, Is), D)
+        layers["w_down"] = init(ks[7], (Lr, E, Is, D), Ie)
+        if Is != Ie:  # stored in whole lane groups: zeros past the experts' width
+            own = (jnp.arange(Is) < Ie).astype(dt)
+            for k in EXPERT_STACKS:
+                if k in layers:
+                    layers[k] = layers[k] * (own[:, None] if k == "w_down" else own)
         if cfg.use_expert_bias:  # lfm2: chooses the experts, weighs nothing
             layers["router_bias"] = ROUTER_BIAS_SCALE * jax.random.normal(
                 kc[3], (Lr, cfg.num_experts), jnp.float32
@@ -208,7 +223,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             layers["shared_router"] = init(ks[16], (L, D), D)
         if cfg.shared_width:  # pangu_ultra_moe: shared experts, no gate
             Is = cfg.shared_width
-            layers["shared_gate"] = init(ks[13], (Lr, D, Is), D)
+            if cfg.gated_experts:
+                layers["shared_gate"] = init(ks[13], (Lr, D, Is), D)
             layers["shared_up"] = init(ks[14], (Lr, D, Is), D)
             layers["shared_down"] = init(ks[15], (Lr, Is, D), Is)
     else:
@@ -296,6 +312,18 @@ MAMBA_PARAMS = (
 )
 
 
+def _random_dt_bias(key, shape):
+    """The inverse softplus of a step drawn log-uniform in ``MAMBA_DT_RANGE``
+    (float32): what the Mamba, delta-rule and Mamba-2 mixers' steps start
+    from."""
+    lo, hi = MAMBA_DT_RANGE
+    step = jnp.exp(
+        jax.random.uniform(key, shape, jnp.float32)
+        * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo)
+    )
+    return step + jnp.log(-jnp.expm1(-step))  # softplus^-1
+
+
 def _init_mamba(rng, cfg: ModelConfig, init) -> Params:
     """Random Mamba-1 mixers, ``[L_mamba, ...]`` each: the projections by
     ``init`` (normal, fan-in scaled); ``A_log = log(1..S)`` a channel and
@@ -307,18 +335,13 @@ def _init_mamba(rng, cfg: ModelConfig, init) -> Params:
     Lm, D, E = cfg.num_mamba_layers, cfg.hidden_size, cfg.mamba_inner
     S, K, R = cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_dt_rank
     km = jax.random.split(rng, 7)
-    lo, hi = MAMBA_DT_RANGE
-    step = jnp.exp(
-        jax.random.uniform(km[5], (Lm, E), jnp.float32)
-        * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo)
-    )
     return {
         "mamba_in": init(km[0], (Lm, D, 2 * E), D),
         "mamba_conv_w": init(km[1], (Lm, K, E), K),
         "mamba_conv_b": init(km[6], (Lm, E), K),
         "mamba_x": init(km[2], (Lm, E, R + 2 * S), E),
         "mamba_dt_w": init(km[3], (Lm, R, E), R),
-        "mamba_dt_b": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "mamba_dt_b": _random_dt_bias(km[5], (Lm, E)),
         "mamba_A_log": jnp.broadcast_to(
             jnp.log(jnp.arange(1, S + 1, dtype=jnp.float32))[None, :, None],
             (Lm, S, E),
@@ -328,6 +351,44 @@ def _init_mamba(rng, cfg: ModelConfig, init) -> Params:
         "mamba_b_norm": jnp.ones((Lm, S), dt),
         "mamba_c_norm": jnp.ones((Lm, S), dt),
         "mamba_out": init(km[4], (Lm, E, D), E),
+    }
+
+
+# A randomly initialised Mamba-2 layer's decay: ``m2_A_log = log a`` with
+# ``a`` uniform in this range a head, and ``m2_dt_b`` from ``MAMBA_DT_RANGE``,
+# the family's own initialisation (``NemotronHMamba2Mixer``). A step decays a
+# head's state by ``exp(-dt a)`` and adds ``dt B x^T``: the state settles
+# near ``B x^T / a`` and neither dies nor explodes over 16k tokens.
+MAMBA2_A_RANGE = (1.0, 16.0)
+# The Mamba-2 mixer's parameters, each stacked over the Mamba-2 layers.
+MAMBA2_PARAMS = (
+    "m2_in", "m2_dt_w", "m2_conv_w", "m2_conv_b", "m2_dt_b", "m2_A_log", "m2_D",
+    "m2_norm", "m2_out",
+)
+
+
+def _init_mamba2(rng, cfg: ModelConfig, init) -> Params:
+    """Random Mamba-2 mixers, ``[L_mamba2, ...]`` each. The published
+    ``in_proj`` is held in two: ``m2_in`` ``[D, E + C]`` (the gate ``z``, then
+    the convolution's ``x | B | C``) and ``m2_dt_w`` ``[D, H]`` (the step,
+    whose product is taken in float32). ``m2_A_log``, ``m2_D`` and
+    ``m2_dt_b`` (a head each) stay float32."""
+    dt = _dtype(cfg)
+    Lm, D, H = cfg.num_mamba2_layers, cfg.hidden_size, cfg.mamba2_heads
+    E, C, K = cfg.mamba2_inner, cfg.mamba2_conv_width, cfg.mamba_d_conv
+    km = jax.random.split(rng, 7)
+    return {
+        "m2_in": init(km[0], (Lm, D, E + C), D),
+        "m2_dt_w": init(km[1], (Lm, D, H), D),
+        "m2_conv_w": init(km[2], (Lm, K, C), K),
+        "m2_conv_b": init(km[3], (Lm, C), K),
+        "m2_dt_b": _random_dt_bias(km[5], (Lm, H)),
+        "m2_A_log": jnp.log(
+            jax.random.uniform(km[6], (Lm, H), jnp.float32, *MAMBA2_A_RANGE)
+        ),
+        "m2_D": jnp.ones((Lm, H), jnp.float32),
+        "m2_norm": jnp.ones((Lm, E), dt),
+        "m2_out": init(km[4], (Lm, E, D), E),
     }
 
 
@@ -355,11 +416,6 @@ def _init_linear(rng, cfg: ModelConfig, init) -> Params:
     Ll, D, H = cfg.num_linear_layers, cfg.hidden_size, cfg.linear_num_heads
     dv, K, C = cfg.linear_value_head_dim, cfg.linear_conv_kernel_dim, cfg.linear_conv_width
     kl = jax.random.split(rng, 7)
-    lo, hi = MAMBA_DT_RANGE
-    step = jnp.exp(
-        jax.random.uniform(kl[5], (Ll, H), jnp.float32)
-        * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo)
-    )
     a_lo, a_hi = LINEAR_A_RANGE
     a = jax.random.uniform(kl[6], (Ll, H), jnp.float32, a_lo, a_hi)
     return {
@@ -367,7 +423,7 @@ def _init_linear(rng, cfg: ModelConfig, init) -> Params:
         "gdn_conv_w": init(kl[1], (Ll, K, C), K),
         "gdn_ab": init(kl[2], (Ll, D, 2 * H), D),
         "gdn_A_log": jnp.log(jnp.maximum(a, 1e-4)),
-        "gdn_dt_b": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+        "gdn_dt_b": _random_dt_bias(kl[5], (Ll, H)),
         "gdn_gate": init(kl[3], (Ll, D, H * dv), D),
         "gdn_norm": jnp.ones((Ll, dv), dt),
         "gdn_out": init(kl[4], (Ll, H * dv, D), H * dv),
@@ -419,6 +475,9 @@ def param_shardings(
         # Replicated: a selective scan under tp > 1 is refused
         # (``engine/config.py::refuse_seq_state_tiers``).
         layers.update((k, P()) for k in MAMBA_PARAMS)
+    if cfg.num_mamba2_layers:
+        # Replicated: refused under tp > 1 likewise.
+        layers.update((k, P()) for k in MAMBA2_PARAMS)
     if cfg.attention_bias:
         layers["bq"] = P(None, tp_axis)
         layers["bk"] = P(None, tp_axis)
@@ -431,7 +490,7 @@ def param_shardings(
         layers["post_ffn_norm"] = P(None, None)
     if cfg.is_moe and cfg.use_expert_bias:
         layers["router_bias"] = P(None, None)
-    if cfg.is_moe and cfg.num_routed_layers < cfg.num_layers:
+    if cfg.is_moe and cfg.ff_kinds.count("dense"):
         layers["dense_gate"] = P(None, None, tp_axis)
         layers["dense_up"] = P(None, None, tp_axis)
         layers["dense_down"] = P(None, tp_axis, None)
@@ -453,6 +512,8 @@ def param_shardings(
             layers["shared_gate"] = P(None, None, tp_axis)
             layers["shared_up"] = P(None, None, tp_axis)
             layers["shared_down"] = P(None, tp_axis, None)
+        if not cfg.gated_experts:
+            del layers["w_gate"], layers["shared_gate"]
     else:
         layers["w_gate"] = P(None, None, tp_axis)
         layers["w_up"] = P(None, None, tp_axis)
@@ -550,9 +611,11 @@ def kv_page_size(k_cache) -> int:
 
 class MambaState(NamedTuple):
     """The two parts a Mamba sequence carries a layer, as pools
-    ``[L_mamba, entries, ...]``."""
+    ``[L_mamba, entries, ...]``: Mamba-1's (jamba) over ``E`` channels,
+    Mamba-2's (nemotron_h) the convolution's over ``x | B | C`` and the
+    state's over every head's channels."""
 
-    conv: jnp.ndarray  # [Lm, entries, d_conv - 1, E], the model's dtype
+    conv: jnp.ndarray  # [Lm, entries, d_conv - 1, E or E + 2 G S], the model's dtype
     ssm: jnp.ndarray  # [Lm, entries, S, E] float32
 
 
@@ -578,6 +641,12 @@ def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
       ``[S, E]`` in float32 (``mamba_mixer``): 9.32 MB an entry at
       Jamba2-3B's 26 layers, not kilobytes, so the engine counts the pool
       among what it holds (``dynamo_seq_state_pool_bytes``);
+    - a Mamba-2 model (nemotron_h): :class:`MambaState` too, the one
+      convolution's last inputs over ``x | B | C`` and the matrix state
+      ``[S, heads * head_dim]`` in float32 (``mamba2_mixer``;
+      ``ops/ssd.py``): 2 MiB a layer at Nemotron-3-Nano's 64 heads of 64
+      channels and 128 states, 12.8 MB an entry over the 6 Mamba-2 layers
+      of its cut;
     - a linear-attention model (olmo_hybrid): :class:`LinearState`, the
       last ``linear_conv_kernel_dim - 1`` inputs of q's, k's and v's
       convolutions and the delta rule's matrix state ``[dk, H * dv]`` in
@@ -590,7 +659,7 @@ def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
     if not cfg.has_seq_state:
         return None
     dt = dtype or _dtype(cfg)
-    kinds = [k for k in ("conv", "mamba", "linear") if k in cfg.op_kinds]
+    kinds = [k for k in ("conv", "mamba", "linear", "mamba2") if k in cfg.op_kinds]
     if len(kinds) > 1:
         raise ValueError(
             f"a model of {' and '.join(kinds)} layers has no state pool: a "
@@ -608,10 +677,12 @@ def init_seq_state(cfg: ModelConfig, entries: int, dtype=None, sharding=None):
                 jnp.float32, device=sharding,
             ),
         )
-    if cfg.num_mamba_layers:
-        Lm, E = cfg.num_mamba_layers, cfg.mamba_inner
+    if cfg.num_mamba_layers or cfg.num_mamba2_layers:
+        Lm, E, C = cfg.num_mamba_layers, cfg.mamba_inner, cfg.mamba_inner
+        if cfg.num_mamba2_layers:
+            Lm, E, C = cfg.num_mamba2_layers, cfg.mamba2_inner, cfg.mamba2_conv_width
         return MambaState(
-            jnp.zeros((Lm, entries, cfg.mamba_d_conv - 1, E), dt, device=sharding),
+            jnp.zeros((Lm, entries, cfg.mamba_d_conv - 1, C), dt, device=sharding),
             jnp.zeros((Lm, entries, cfg.mamba_d_state, E), jnp.float32, device=sharding),
         )
     return jnp.zeros(
@@ -645,6 +716,10 @@ def rms_norm(
 def _act(name: str):
     if name == "gelu_tanh":
         return lambda x: jax.nn.gelu(x, approximate=True)
+    if name == "relu2":
+        from ..ops.moe import relu2
+
+        return relu2
     return jax.nn.silu
 
 
@@ -1246,12 +1321,105 @@ def mamba_mixer(u, lp, cfg, state: "MambaState", layer, io: SeqStateIO, form: Sc
     return out, MambaState(conv, ssm)
 
 
+def _ssd(xbc, dt, a, d, pool, layer, io: SeqStateIO, form: ScanForm):
+    """Mamba-2's recurrence over this forward's stream (``ops/ssd.py`` has
+    the four forms), ``xbc`` [N, H * P + 2 G S] as the convolution leaves it
+    (x, every group's B, every group's C). Returns (y [N, H * P] float32,
+    the states' pool). One token a row takes the in-place kernel wherever
+    the stream takes the chunk kernel (``form.kernel``: the chip), the plain
+    form otherwise."""
+    from ..ops import ssd
+
+    inner = pool.shape[3]
+    cut = inner + (xbc.shape[-1] - inner) // 2
+    x, b, c = xbc[:, :inner], xbc[:, inner:cut], xbc[:, cut:]
+    one_step = (
+        functools.partial(ssd.ssd_step_kernel, interpret=form.interpret)
+        if form.kernel else ssd.ssd_step
+    )
+    return _stream_recurrence(
+        pool, io, form,
+        lambda pool: ssd.ssd_ref(
+            x, dt, a, b, c, d, pool, layer, io.slots, io.row_of, io.positions,
+            io.src, io.dst,
+        ),
+        lambda pool, part, slots, pos, dst: one_step(
+            x[part], dt[part], a, b[part], c[part], d, pool, layer, slots, pos, dst,
+        ),
+        lambda pool, pos, dst: ssd.ssd_kernel(
+            xbc, dt, a, d, pool, layer, io.slots, io.row_of, pos, io.src, dst,
+            interpret=form.interpret,
+        ),
+    )
+
+
+def mamba2_mixer(u, lp, cfg, state: "MambaState", layer, io: SeqStateIO, form: ScanForm):
+    """Nemotron-H's Mamba-2 mixer over a flat token stream ``u`` ([N, D], the
+    normed input), the carried state read from and written to
+    ``state.conv[layer]`` and ``state.ssm[layer]``. Returns ([N, D], the
+    state). ``H`` heads of ``P`` channels (``E = H P``), ``S`` states, B and
+    C in ``G`` groups:
+
+    ``[z | xBC] = u @ m2_in`` (widths ``E | E + 2 G S``), ``dt = softplus(u @
+    m2_dt_w + m2_dt_b)`` (the published ``in_proj``'s three parts);
+    ``[x | B | C] = split(silu(conv_b + sum_j conv_w[j] *
+    xBC_{t-(K-1)+j}))`` (ONE depthwise causal convolution over all three);
+    ``A = -exp(A_log)`` a head; the recurrence (``ops/ssd.py``); ``y =
+    group_rms_norm(y * silu(z))``: the gate BEFORE the norm, each of the
+    ``G`` groups of ``E / G`` channels normed apart under one weight of
+    ``E``; returns ``y @ m2_out``.
+
+    The matrix products run in the model's dtype, the step's with a float32
+    result; everything between the convolution and the output's norm is
+    float32, the state from end to end."""
+    f32 = jnp.float32
+    G, E = cfg.mamba2_groups, cfg.mamba2_inner
+    n = u.shape[0]
+    zx = u @ lp["m2_in"]  # [N, E + C]
+    gate, xbc = zx[:, :E], zx[:, E:]
+    dt = jax.nn.softplus(
+        jnp.dot(u, lp["m2_dt_w"], preferred_element_type=f32) + lp["m2_dt_b"]
+    )
+    with jax.named_scope("mamba2_conv"):
+        taps = lp["m2_conv_w"].astype(f32)  # [K, C]; taps[K-1] weighs xBC_t
+        K = taps.shape[0]
+        held = state.conv[layer, io.slots[io.row_of]]  # [N, K-1, C], one gather
+        back = _stream_history(xbc, held, io, K)
+        acc = lp["m2_conv_b"].astype(f32) + taps[K - 1] * xbc
+        for j in range(1, K):
+            acc = acc + taps[K - 1 - j] * back[j]
+        c = jax.nn.silu(acc)  # [N, C] float32
+        conv = state.conv.at[layer, io.dst].set(
+            _history_after(back, io.src).astype(state.conv.dtype), mode="drop"
+        )
+    with jax.named_scope("ssd_scan"):
+        y, ssm = _ssd(
+            c, dt, -jnp.exp(lp["m2_A_log"]), lp["m2_D"], state.ssm, layer, io, form,
+        )
+    y = y * jax.nn.silu(gate.astype(f32))
+    y = rms_norm(y.reshape(n, G, E // G), jnp.ones((), f32), cfg.rms_norm_eps)
+    out = (y.reshape(n, E) * lp["m2_norm"].astype(f32)).astype(u.dtype) @ lp["m2_out"]
+    return out, MambaState(conv, ssm)
+
+
 def _mamba_half(x, lp, cfg, eps, state, layer, io, form):
     """A layer's first half where its operator is the Mamba mixer: norm,
     ``mamba_mixer`` on the flattened rows, residual add."""
     with jax.named_scope("mamba"):
         h = rms_norm(x, lp["attn_norm"], eps, cfg.rms_norm_offset)
         y, state = mamba_mixer(
+            h.reshape(-1, h.shape[-1]), lp, cfg, state, layer, io, form
+        )
+        return x + y.reshape(x.shape), state
+
+
+def _mamba2_half(x, lp, cfg, eps, state, layer, io, form):
+    """A layer's first half where its operator is the Mamba-2 mixer (a
+    whole ``M`` layer of nemotron_h): norm, ``mamba2_mixer`` on the flattened
+    rows, residual add."""
+    with jax.named_scope("mamba2"):
+        h = rms_norm(x, lp["attn_norm"], eps, cfg.rms_norm_offset)
+        y, state = mamba2_mixer(
             h.reshape(-1, h.shape[-1]), lp, cfg, state, layer, io, form
         )
         return x + y.reshape(x.shape), state
@@ -1314,15 +1482,21 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
     B, T = x.shape[:2]
     off = cfg.rms_norm_offset
     h = rms_norm(x, lp["mlp_norm"], eps, off) if "mlp_norm" in lp else x
+    if "router" in lp and "w_gate" not in lp and _expert_parallel(mesh):
+        raise NotImplementedError("experts of two matrices are not sharded over ep")
     if "router" in lp:
         from ..ops.moe import moe_ffn, moe_ffn_ep
 
         shared = None
-        if "shared_gate" in lp:  # an always-on shared expert
+        if "shared_up" in lp:  # an always-on shared expert
             with jax.named_scope("shared_expert"):
                 act = _act(cfg.hidden_act)
-                sg = act((h @ lp["shared_gate"]).astype(jnp.float32)).astype(x.dtype)
-                shared = (sg * (h @ lp["shared_up"])) @ lp["shared_down"]
+                if "shared_gate" in lp:
+                    sg = act((h @ lp["shared_gate"]).astype(jnp.float32)).astype(x.dtype)
+                    shared = (sg * (h @ lp["shared_up"])) @ lp["shared_down"]
+                else:  # nemotron_h: two matrices, down(act(up x))
+                    su = act((h @ lp["shared_up"]).astype(jnp.float32)).astype(x.dtype)
+                    shared = su @ lp["shared_down"]
                 if "shared_router" in lp:  # qwen2_moe: behind a gate
                     # Learned sigmoid blend; the gate logit uses the replicated
                     # [D] vector, so it is identical on every tp rank and
@@ -1352,7 +1526,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
             y = moe_ffn(
                 h.reshape(B * T, -1),
                 lp["router"],
-                lp["w_gate"],
+                lp.get("w_gate"),  # None: experts of two matrices
                 lp["w_up"],
                 lp["w_down"],
                 cfg.num_experts_per_tok,
@@ -1363,7 +1537,7 @@ def _ffn_block(x, lp, cfg, eps, red, mesh, live=None, layer=None, interpret=Fals
                 scale=cfg.routed_scaling_factor,
                 norm_eps=cfg.router_norm_eps,
                 held_from=cfg.first_expert_held if cfg.num_experts_held else None,
-                tally=tally,
+                tally=tally, act=cfg.hidden_act,
             ).reshape(B, T, -1)
             if shared is not None:
                 y = y + shared
@@ -1397,7 +1571,7 @@ def routed_experts_form(cfg: ModelConfig, mesh, interpret: bool = False) -> str:
     from ..ops.moe import experts_in_place
 
     in_place = not _expert_parallel(mesh) and experts_in_place(
-        cfg.hidden_size, cfg.expert_intermediate_size, mesh, interpret
+        cfg.hidden_size, cfg.expert_stack_width, mesh, interpret
     )
     return "inplace" if in_place else "sliced"
 
@@ -1482,6 +1656,7 @@ _OP_PARAMS = {
     "conv": ("conv_in", "conv_w", "conv_out"),
     "mamba": MAMBA_PARAMS,
     "linear": LINEAR_PARAMS,
+    "mamba2": MAMBA2_PARAMS,
 }
 _ROUTED_PARAMS = (
     "router", "router_bias", "shared_gate", "shared_up", "shared_down",
@@ -1622,6 +1797,10 @@ def _scan_paged_layers(
         for op, ff, first, n, op0, ff0 in cfg.layer_runs:
             # name in the body -> (stack, the run's first index in it)
             source = {k: (layers[k], first) for k in _EVERY_LAYER if k in layers}
+            if cfg.ff_types:  # layers of one half: a norm a feed-forward that is there
+                source.pop("mlp_norm", None)
+                if ff != "none":
+                    source["mlp_norm"] = (layers["mlp_norm"], ff0)
             source.update(
                 (k, (layers[k], op0)) for k in _OP_PARAMS[op] if k in layers
             )
@@ -1629,7 +1808,7 @@ def _scan_paged_layers(
                 source.update(
                     (k, (layers[d], ff0)) for k, d in zip(EXPERT_STACKS, dense_names)
                 )
-            else:
+            elif ff == "routed":
                 source.update(
                     (k, (layers[k], ff0)) for k in _ROUTED_PARAMS if k in layers
                 )
@@ -1638,11 +1817,12 @@ def _scan_paged_layers(
             # (``moe_ffn`` then slices the layer it multiplies); else ``xs``.
             stacks = {}
             if ff == "routed":
-                whole = layers["w_gate"].shape[0] == n
+                names = [k for k in EXPERT_STACKS if k in layers]  # two where no gate
+                whole = layers["w_up"].shape[0] == n
                 if in_place or not whole:
-                    stacks = {k: layers[k] for k in EXPERT_STACKS}
+                    stacks = {k: layers[k] for k in names}
                 else:
-                    source.update((k, (layers[k], ff0)) for k in EXPERT_STACKS)
+                    source.update((k, (layers[k], ff0)) for k in names)
             # What a model whose forms differ stacks by form: the K and V
             # projections at the run's ordinal among the layers of its form,
             # the sink logits among the layers that have them.
@@ -1670,7 +1850,7 @@ def _scan_paged_layers(
             )
             rope = not (cfg.rope_sliding_only and form == "full")
 
-            def layer(carry, layer_in, op=op, op0=op0, ff0=ff0, stacks=stacks,
+            def layer(carry, layer_in, op=op, ff=ff, op0=op0, ff0=ff0, stacks=stacks,
                       closed=closed, kind=kind, pool0=pool0, width=width,
                       rope=rope, heads=heads):
                 x, k_all, v_all, state = carry
@@ -1700,6 +1880,10 @@ def _scan_paged_layers(
                     )
                 elif op == "linear":
                     x, state = _linear_half(
+                        x, lp, cfg, eps, state, l, state_io, scan_form
+                    )
+                elif op == "mamba2":
+                    x, state = _mamba2_half(
                         x, lp, cfg, eps, state, l, state_io, scan_form
                     )
                 elif cfg.is_latent:
@@ -1779,11 +1963,12 @@ def _scan_paged_layers(
                         x, lp, cfg, invf_l, rope_pos, eps, attend, red, rope
                     )
                 tally = [] if count_picks else None
-                x = _ffn_half(
-                    x, lp, cfg, eps, red, mesh, live,
-                    (i + ff0 if ff0 else i) if stacks else None, interpret,
-                    tally,
-                )
+                if ff != "none":  # a layer of one half has no second
+                    x = _ffn_half(
+                        x, lp, cfg, eps, red, mesh, live,
+                        (i + ff0 if ff0 else i) if stacks else None, interpret,
+                        tally,
+                    )
                 picks = (tally[0] if tally else jnp.zeros((2,), jnp.int32)) if count_picks else None
                 if by_kind:  # the other form's pool rides on untouched
                     k_pool = (*k_all[:kind], k_pool, *k_all[kind + 1:])
@@ -2076,10 +2261,15 @@ def _scan_form(cfg, attn_impl, span_align, packed_from, interpret,
     kernel where the attention kernel serves the forward too (the engine
     then lays the stream in tiles) and the widths tile, the oracle
     otherwise."""
-    from ..ops import gated_delta, selective_scan
+    from ..ops import gated_delta, selective_scan, ssd
 
     if cfg.num_mamba_layers:
         fits = selective_scan.supported(cfg.mamba_inner, span_align)
+    elif cfg.num_mamba2_layers:
+        fits = ssd.kernel_supported(
+            cfg.mamba2_heads, cfg.mamba2_head_dim, cfg.mamba2_groups, cfg.mamba_d_state,
+            ssd.TILE if one_token else span_align,  # a row of one token is no span to align
+        )
     else:
         fits = cfg.num_linear_layers > 0 and gated_delta.supported(
             cfg.linear_num_heads, cfg.linear_key_head_dim,

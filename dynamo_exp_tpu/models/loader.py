@@ -41,7 +41,7 @@ def _open_safetensors(path: str):
 # Parameters that stay float32 whatever the model's dtype.
 _FLOAT32 = (
     "router_bias", "mamba_A_log", "mamba_D", "mamba_dt_b", "gdn_A_log", "gdn_dt_b",
-    "sink",
+    "sink", "m2_A_log", "m2_D", "m2_dt_b",
 )
 
 
@@ -217,7 +217,7 @@ SUPPORTED_MODEL_TYPES = (
     "gemma3_text", "phi3",
     "mixtral", "qwen2_moe", "qwen3_moe", "sdar_moe", "lfm2_moe", "jamba",
     "olmo_hybrid", "pangu_ultra_moe", "glm_moe_dsa", "exaone_moe",
-    "mimo_v2_flash", "ouro", "KeyeVL2",
+    "mimo_v2_flash", "ouro", "KeyeVL2", "nemotron_h",
 )
 
 
@@ -580,6 +580,75 @@ def _keye_vl2_params(cfg: ModelConfig, get, linear) -> dict:
     return params
 
 
+def _nemotron_h_params(cfg: ModelConfig, get, linear) -> dict:
+    """NVIDIA's ``nemotron_h`` tensor names into the stacks by kind of
+    ``models/llama.py::init_params``. The published layers are one half
+    each under ``backbone.layers.N`` with ``norm`` and ``mixer``; the stack
+    here is (mixer, experts) pairs, so ``norm`` of an ``M`` or ``*`` layer
+    goes to ``attn_norm`` and an ``E`` layer's to ``mlp_norm``. Over the
+    Mamba-2 layers ``mixer.in_proj`` ([E + C + H, D]: its rows ``z | xBC``
+    into ``m2_in``, the last ``H`` into ``m2_dt_w``), ``mixer.conv1d`` ([C,
+    1, K] depthwise, with its bias), ``mixer.A_log``, ``mixer.D``,
+    ``mixer.dt_bias``, ``mixer.norm`` and ``mixer.out_proj``; over the
+    attention layers ``mixer.{q,k,v,o}_proj``; over the routed layers
+    ``mixer.gate.weight``, ``mixer.gate.e_score_correction_bias``,
+    ``mixer.experts.N.{up,down}_proj`` (a held share's are experts
+    ``first_expert_held`` on) and ``mixer.shared_experts.{up,down}_proj``.
+    ``backbone.embeddings``, ``backbone.norm_f``, an untied ``lm_head``."""
+    names = (
+        "attn_norm", "mlp_norm", "wq", "wk", "wv", "wo", "m2_in", "m2_dt_w",
+        "m2_conv_w", "m2_conv_b", "m2_dt_b", "m2_A_log", "m2_D", "m2_norm", "m2_out",
+        "router", "router_bias", "shared_up", "shared_down", "w_up", "w_down",
+    )
+    layers: dict[str, list] = {k: [] for k in names}
+    split = cfg.mamba2_inner + cfg.mamba2_conv_width  # z | xBC, then dt
+    i = 0
+    for op, ff in zip(cfg.op_kinds, cfg.ff_kinds):
+        p = f"backbone.layers.{i}."
+        m = p + "mixer."
+        layers["attn_norm"].append(get(p + "norm.weight"))
+        if op == "mamba2":
+            w_in = linear(m + "in_proj.weight")  # [D, E + C + H]
+            layers["m2_in"].append(w_in[:, :split])
+            layers["m2_dt_w"].append(w_in[:, split:])
+            layers["m2_conv_w"].append(get(m + "conv1d.weight")[:, 0, :].T)  # [K, C]
+            layers["m2_conv_b"].append(get(m + "conv1d.bias"))
+            layers["m2_dt_b"].append(get(m + "dt_bias"))
+            layers["m2_A_log"].append(get(m + "A_log"))
+            layers["m2_D"].append(get(m + "D"))
+            layers["m2_norm"].append(get(m + "norm.weight"))
+            layers["m2_out"].append(linear(m + "out_proj.weight"))
+        else:
+            for key, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"), ("wo", "o_proj")):
+                layers[key].append(linear(f"{m}{name}.weight"))
+        i += 1
+        if ff == "none":
+            continue
+        p = f"backbone.layers.{i}."
+        m = p + "mixer."
+        layers["mlp_norm"].append(get(p + "norm.weight"))
+        layers["router"].append(linear(m + "gate.weight"))
+        layers["router_bias"].append(get(m + "gate.e_score_correction_bias"))
+        layers["shared_up"].append(linear(m + "shared_experts.up_proj.weight"))
+        layers["shared_down"].append(linear(m + "shared_experts.down_proj.weight"))
+        held = range(cfg.first_expert_held, cfg.first_expert_held + cfg.experts_held)
+        pad = cfg.expert_stack_width - cfg.expert_intermediate_size  # zeros: whole lane groups
+        for key, name, axes in (
+            ("w_up", "up_proj", ((0, 0), (0, 0), (0, pad))),
+            ("w_down", "down_proj", ((0, 0), (0, pad), (0, 0))),
+        ):
+            layers[key].append(np.pad(np.stack([
+                linear(f"{m}experts.{e}.{name}.weight") for e in held
+            ]), axes))
+        i += 1
+    return {
+        "embed": get("backbone.embeddings.weight"),
+        "layers": {k: np.stack(v) for k, v in layers.items()},
+        "final_norm": get("backbone.norm_f.weight"),
+        "lm_head": linear("lm_head.weight"),
+    }
+
+
 def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, ModelConfig]:
     """Load a HF checkpoint directory (llama/mistral/qwen2/mixtral
     families) into the stacked param pytree."""
@@ -618,6 +687,7 @@ def load_params(path: str, cfg: ModelConfig | None = None) -> tuple[Params, Mode
         "mimo_v2_flash": functools.partial(_mimo_v2_flash_params, has=index.__contains__),
         "ouro": _ouro_params,
         "KeyeVL2": _keye_vl2_params,
+        "nemotron_h": _nemotron_h_params,
     }
     if cfg.model_type in by_kind:
         params = by_kind[cfg.model_type](cfg, get, linear)

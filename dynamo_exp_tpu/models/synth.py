@@ -217,6 +217,31 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             sliding_window=None, use_sliding_window=False,
             max_window_layers=cfg.num_layers,
         )
+    elif cfg.model_type == "nemotron_h":  # the family's own keys
+        del hf["rms_norm_eps"], hf["rope_theta"]
+        pattern = "".join(
+            ("M" if op == "mamba2" else "*") + ("E" if ff == "routed" else "")
+            for op, ff in zip(cfg.op_kinds, cfg.ff_kinds)
+        )
+        hf.update(
+            architectures=["NemotronHForCausalLM"], num_hidden_layers=len(pattern),
+            hybrid_override_pattern=pattern, layer_norm_epsilon=cfg.rms_norm_eps,
+            head_dim=cfg.head_dim_, attention_bias=False, mlp_bias=False,
+            use_bias=False, mamba_proj_bias=False, use_conv_bias=True,
+            mlp_hidden_act="relu2", mamba_hidden_act="silu",
+            mamba_num_heads=cfg.mamba2_heads, mamba_head_dim=cfg.mamba2_head_dim,
+            n_groups=cfg.mamba2_groups, ssm_state_size=cfg.mamba_d_state,
+            conv_kernel=cfg.mamba_d_conv, chunk_size=128, expand=2,
+            n_routed_experts=cfg.experts_held,
+            n_routed_experts_global=cfg.num_experts,
+            first_routed_expert=cfg.first_expert_held,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            moe_intermediate_size=cfg.moe_intermediate_size,
+            moe_shared_expert_intermediate_size=cfg.shared_expert_width,
+            n_shared_experts=1, n_group=1, topk_group=1,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            sliding_window=None,
+        )
     elif cfg.head_dim is not None:
         hf["head_dim"] = cfg.head_dim
     if cfg.rope_scaling is not None:
@@ -226,7 +251,8 @@ def write_config(path: str, cfg: ModelConfig) -> None:
             f"config.json written for model_type={cfg.model_type!r} does "
             "not parse back to the same ModelConfig; this writer covers "
             "the llama family, lfm2_moe, jamba, olmo_hybrid, pangu_ultra_moe, "
-            "glm_moe_dsa, exaone_moe, mimo_v2_flash, ouro and KeyeVL2 only"
+            "glm_moe_dsa, exaone_moe, mimo_v2_flash, ouro, KeyeVL2 and "
+            "nemotron_h only"
         )
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f)

@@ -66,6 +66,14 @@ from jax.sharding import PartitionSpec as P
 
 from . import expert_gmm
 
+# What an expert applies to its gate's product (to its up product where it
+# has no gate): silu everywhere but nemotron_h's ``relu(x)^2``.
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+_ACTS = {"silu": jax.nn.silu, "relu2": relu2}
+
 
 def moe_router(
     x: jnp.ndarray,  # [N, D]
@@ -139,7 +147,7 @@ def experts_in_place(
 def moe_ffn(
     x: jnp.ndarray,  # [N, D] tokens (flattened batch*seq)
     router_w: jnp.ndarray,  # [D, E]
-    w_gate: jnp.ndarray,  # [E, D, I], or with ``layer`` the stack [L, E, D, I]
+    w_gate: jnp.ndarray | None,  # [E, D, I], or with ``layer`` the stack [L, E, D, I]
     w_up: jnp.ndarray,
     w_down: jnp.ndarray,  # [E, I, D] / [L, E, I, D]
     num_experts_per_tok: int,
@@ -156,8 +164,13 @@ def moe_ffn(
     held_from: int | None = None,  # first expert id held; the weights' E = held
     held_row_block: int | None = None,  # rows a block of the held walk takes
     tally: list | None = None,  # gets [live picks on experts here, experts with one]
+    act: str = "silu",  # "relu2": relu(x)^2
 ) -> jnp.ndarray:
     """SwiGLU expert FFN with exact top-k dispatch. Returns [N, D].
+
+    ``w_gate`` None: experts of TWO matrices, ``down(act(up x))`` (nemotron_h,
+    ``act`` "relu2"), two grouped products a pick where a gated expert is
+    three; everything else is the one path.
 
     Every (token, selected expert) pair is computed — the sort groups
     pairs by expert so each expert sees one contiguous slab, and a
@@ -188,7 +201,7 @@ def moe_ffn(
     had such a pick: the matrices this call has to fetch, which a router
     that crowds its picks makes far fewer than the held.
     """
-    D, width = w_gate.shape[-2:]
+    D, width = w_up.shape[-2:]
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     kernel = experts_in_place(D, width, mesh, interpret)
     experts = functools.partial(
@@ -196,7 +209,7 @@ def moe_ffn(
         num_experts_per_tok=num_experts_per_tok, norm_topk_prob=norm_topk_prob,
         kernel=kernel, interpret=interpret, score=score, scale=scale,
         norm_eps=norm_eps, held_from=held_from, held_row_block=held_row_block,
-        tally=tally,
+        tally=tally, act=act,
     )
     if not (kernel and tp > 1):
         y = experts(x, router_w, w_gate, w_up, w_down, live, layer, select_bias)
@@ -204,7 +217,7 @@ def moe_ffn(
 
     from ..parallel.mesh import shard_map
 
-    lead = (None,) * (w_gate.ndim - 2)  # [E] or [L, E]
+    lead = (None,) * (w_up.ndim - 2)  # [E] or [L, E]
     # None (no ``live``, no ``layer``) is an empty pytree: its spec is unused.
     @functools.partial(
         shard_map,
@@ -246,12 +259,12 @@ def _routed_experts(
     x, router_w, w_gate, w_up, w_down, live, layer, select_bias=None, *,
     num_experts_per_tok, norm_topk_prob, kernel, interpret,
     score="softmax", scale=1.0, norm_eps=1e-6, held_from=None,
-    held_row_block=None, tally=None,
+    held_row_block=None, tally=None, act="silu",
 ):
     """``moe_ffn``'s arithmetic on the weights one device holds: [N, D]
     float32, before the sum over ``tp`` where the width is sharded."""
     N, D = x.shape
-    E = w_gate.shape[-3]  # experts held: all the router's unless ``held_from``
+    E = w_up.shape[-3]  # experts held: all the router's unless ``held_from``
     K = num_experts_per_tok
     weights, ids = moe_router(
         x, router_w, K, norm_topk_prob,
@@ -291,9 +304,13 @@ def _routed_experts(
         lie in a group: the others are not the grouped matmul's to define."""
         token_of = order_b // K  # originating token per sorted row
         xs = jnp.take(x, token_of, axis=0)  # [rows, D] in expert order
-        g = grouped(xs, w_gate, sizes)
-        u = grouped(xs, w_up, sizes)
-        h = (jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u)
+        if w_gate is None:  # two matrices an expert: down(act(up x))
+            u = grouped(xs, w_up, sizes)
+            h = _ACTS[act](u.astype(jnp.float32)).astype(u.dtype)
+        else:
+            g = grouped(xs, w_gate, sizes)
+            u = grouped(xs, w_up, sizes)
+            h = (_ACTS[act](g.astype(jnp.float32)).astype(u.dtype) * u)
         out = grouped(h, w_down, sizes)  # [rows, D]
         out = out.astype(jnp.float32) * jnp.take(flat_w, order_b)[:, None]
         if keep is not None:
@@ -391,18 +408,22 @@ def moe_ffn_ep(
 
 def moe_ffn_reference(
     x, router_w, w_gate, w_up, w_down, num_experts_per_tok,
-    norm_topk_prob=True,
+    norm_topk_prob=True, act="silu",
 ):
     """Dense oracle: every expert computes every token, combine masks the
-    unselected ones. O(E·N) FLOPs — tests only."""
+    unselected ones. O(E·N) FLOPs — tests only. ``w_gate`` None: the
+    two-matrix form, ``down(act(up x))``."""
     N, D = x.shape
     E = router_w.shape[-1]
     weights, ids = moe_router(x, router_w, num_experts_per_tok, norm_topk_prob)
     combine = jnp.zeros((N, E), jnp.float32)
     combine = combine.at[jnp.arange(N)[:, None], ids].add(weights)
-    g = jnp.einsum("nd,edi->eni", x, w_gate)
     u = jnp.einsum("nd,edi->eni", x, w_up)
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u
+    if w_gate is None:
+        h = _ACTS[act](u.astype(jnp.float32)).astype(u.dtype)
+    else:
+        g = jnp.einsum("nd,edi->eni", x, w_gate)
+        h = _ACTS[act](g.astype(jnp.float32)).astype(u.dtype) * u
     out = jnp.einsum("eni,eid->end", h, w_down)  # [E, N, D]
     y = jnp.einsum("ne,end->nd", combine, out.astype(jnp.float32))
     return y.astype(x.dtype)

@@ -506,6 +506,16 @@ class Telemetry:
             ["program"],  # window | mixed
             registry=self.registry,
         )
+        self.mamba2_tokens = Counter(
+            "dynamo_mamba2_tokens_total",
+            "Tokens a dispatch put through the recurrence of each Mamba-2 "
+            "layer (its live slots, as dynamo_ssm_scan_tokens_total counts "
+            "a selective scan's), by program: mixed is prompt chunks (the "
+            "chunked form) and the decode rows beside them, window is "
+            "decode alone (the one-token step)",
+            ["program"],  # window | mixed
+            registry=self.registry,
+        )
         self.linear_attn_tokens = Counter(
             "dynamo_linear_attn_tokens_total",
             "Tokens a dispatch put through the gated delta rule of each "

@@ -264,11 +264,12 @@ async def test_engine_serves_the_looped_model_and_counts_its_work(engine):
     greedy continuation; every live token counted as passes x layers layer
     applications, its queries as full-attention queries of every layer-pass."""
     assert engine.k_cache.shape == engine.v_cache.shape == (T * L, 96, PS, 64)
+    # kind="full" as they are read below: a worker that ran a model of sliding
+    # layers before this file has counted kind="sliding" in the same registry
     before = {
-        name: _value(name) for name in (
-            "dynamo_layer_passes_total", "dynamo_attn_query_tokens_total",
-            "dynamo_attn_key_positions_total",
-        )
+        "dynamo_layer_passes_total": _value("dynamo_layer_passes_total"),
+        "dynamo_attn_query_tokens_total": _value("dynamo_attn_query_tokens_total", kind="full"),
+        "dynamo_attn_key_positions_total": _value("dynamo_attn_key_positions_total", kind="full"),
     }
     fed = _value("dynamo_ragged_slots_total", kind="live")
     prompts = [PROMPT[:61], PROMPT[61:66], PROMPT[30:67]]
