@@ -127,7 +127,7 @@ def test_a_dispatch_of_many_rows_through_the_kernel_is_ragged_dot_on_the_slice(
     sorted_rows, experts
 ):
     """``moe_ffn`` at a prompt chunk's row counts (tokens x experts a
-    token: more than one block of ``MAX_TM``, which until PR 60 took
+    token: more than a decode window's 256, which until PR 60 took
     ``ragged_dot``), stacked weights and a TRACED ``layer``, some rows
     padding (``live``): the kernel (interpreted) against ``ragged_dot`` on
     that layer's slice, which is what the same call computes off the TPU."""
@@ -156,11 +156,13 @@ def test_a_dispatch_of_many_rows_through_the_kernel_is_ragged_dot_on_the_slice(
     assert np.abs(got - want).max() <= np.abs(want).max() * 2.0**-7 * top_k
 
 
-@pytest.mark.parametrize("rows", [64, 512])
-def test_a_rows_bits_do_not_turn_with_its_place_among_the_sorted_rows(rows):
+@pytest.mark.parametrize("rows,to", [(64, 5), (512, 5), (512, 100)])
+def test_a_rows_bits_do_not_turn_with_its_place_among_the_sorted_rows(rows, to):
     """The same rows of one group, placed at another offset among other
     neighbours and other group sizes: bit-equal results (what kept
-    ``repeatable`` true, PERF.md PR 27)."""
+    ``repeatable`` true, PERF.md PR 27). Moved to row 100 of the one block
+    of 512 the group lies across the block's first sub-block boundary,
+    where it lay inside a sub-block."""
     k, n, take = 256, 384, rows // E
     key = jax.random.PRNGKey(7)
     lhs = jax.random.normal(key, (rows, k), jnp.float32).astype(jnp.bfloat16)
@@ -168,15 +170,107 @@ def test_a_rows_bits_do_not_turn_with_its_place_among_the_sorted_rows(rows):
     even = jnp.full((E,), rows // E, jnp.int32)
     at = 3 * (rows // E)  # group 3's first row
     a = expert_gmm.expert_gmm(lhs, w, even, 2, interpret=True)
-    # group 3's first `take` rows moved to rows 5..5+take, a different row block
-    # boundary and neighbours; groups 0-2 hold the 5 rows before them.
-    moved = jnp.concatenate([lhs[:5], lhs[at:at + take], lhs[5:at], lhs[at + take:]])
-    sizes = jnp.asarray([2, 2, 1, take, 0, 0, 0, rows - 5 - take], jnp.int32)
+    # group 3's first `take` rows moved to rows to..to+take, a different row block
+    # boundary and neighbours; groups 0-2 hold the rows before them.
+    moved = jnp.concatenate([lhs[:to], lhs[at:at + take], lhs[to:at], lhs[at + take:]])
+    sizes = jnp.asarray([2, to - 3, 1, take, 0, 0, 0, rows - to - take], jnp.int32)
     b = expert_gmm.expert_gmm(moved, w, sizes, 2, interpret=True)
     assert np.array_equal(
         np.asarray(a[at:at + take].astype(jnp.float32)),
-        np.asarray(b[5:5 + take].astype(jnp.float32)),
+        np.asarray(b[to:to + take].astype(jnp.float32)),
     )
+
+
+def _whole_block_products(lhs, w, sizes, tk, tm=256):
+    """The kernel's arithmetic before PR 64: every (group, block of ``tm``
+    rows) pair that holds a row multiplies the WHOLE block, float32 partial
+    sums added a ``tk`` slice of ``K`` at a time from zero, and keeps the
+    group's rows; rows of no group are zeros."""
+    out = jnp.zeros((lhs.shape[0], w.shape[-1]), lhs.dtype)
+    ends = np.cumsum(sizes)
+    for g, (lo, hi) in enumerate(zip(ends - sizes, ends)):
+        for b in range(lo // tm, -(-hi // tm) if hi > lo else 0):
+            rows = slice(b * tm, (b + 1) * tm)
+            acc = jnp.zeros((lhs[rows].shape[0], w.shape[-1]), jnp.float32)
+            for k in range(0, lhs.shape[1], tk):
+                acc += jnp.dot(
+                    lhs[rows, k:k + tk], w[g, k:k + tk],
+                    preferred_element_type=jnp.float32,
+                )
+            mine = slice(max(lo, b * tm), min(hi, (b + 1) * tm))
+            out = out.at[mine].set(acc[mine.start - b * tm:mine.stop - b * tm].astype(out.dtype))
+    return out
+
+
+# Group sizes that put a group inside one sub-block of 128 rows, across a
+# sub-block boundary, across a block boundary (at a forced block of 256),
+# empty, a whole sub-block and more, and that leave rows past the last group.
+GROUPINGS = {
+    "inside": [9, 40, 0, 30, 7, 0, 21, 13],
+    "across-sub-blocks": [100, 60, 0, 5, 200, 0, 3, 90],
+    "across-blocks": [250, 12, 300, 0, 0, 131, 1, 128],
+    "aligned": [128, 128, 256, 0, 0, 128, 0, 0],
+    "one-group": [0, 0, 0, 0, 0, 0, 0, 1000],
+}
+
+
+@pytest.mark.parametrize("rows,tm", [(244, None), (1000, None), (1000, 256), (1024, 384)])
+@pytest.mark.parametrize("grouping", GROUPINGS)
+def test_a_tile_that_multiplies_its_groups_sub_blocks_gives_the_whole_blocks_bits(
+    grouping, rows, tm
+):
+    """Bit for bit what a whole-block product a tile gave (the kernel until
+    PR 64), at a decode window's row count (one block) and at several
+    blocks (a forced block of 256 rows, the last ragged; 384, whose third
+    sub-block ends a block): a row is computed from that row alone and its
+    partial sums are added in ``tk`` order, whatever rows are multiplied
+    beside it. Rows past the last group are zeros as far as the last
+    sub-block a tile touched."""
+    k, n = 768, 256  # two k slices of 384
+    sizes = np.asarray(GROUPINGS[grouping])
+    if sizes.sum() > rows:  # a window's rows: the same pattern, fewer rows
+        sizes = sizes * rows // (sizes.sum() + 12)
+    key = jax.random.PRNGKey(rows)
+    lhs = jax.random.normal(key, (rows, k), jnp.float32).astype(jnp.bfloat16)
+    w = _weights(jax.random.fold_in(key, 1), k, n)[1]
+    got = expert_gmm.expert_gmm(lhs, w, jnp.asarray(sizes, jnp.int32), 1, interpret=True, tm=tm)
+    want = _whole_block_products(lhs, w[1], sizes, expert_gmm.tiles(rows, k, n, 2)[1])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    live = int(sizes.sum())
+    defined = min(rows, -(-live // expert_gmm.SUB) * expert_gmm.SUB)
+    assert live and np.array_equal(
+        np.asarray(got[:defined]).view(np.uint16), np.asarray(want[:defined]).view(np.uint16)
+    )
+
+
+def test_a_calls_matrix_fetches_and_product_rows_are_counted_on_the_host():
+    count = expert_gmm.fetches_and_product_rows
+    # One group inside a sub-block; one across a sub-block boundary; one
+    # across a block boundary (two fetches); an empty one (none).
+    assert count([100], 256) == (1, 128)
+    assert count([100, 100], 256) == (2, 128 + 256)
+    assert count([100, 100, 100], 256) == (4, 128 + 256 + 128 + 128)
+    assert count([0, 300, 0], 256) == (2, 256 + 128)
+    assert count([0, 300, 0], 1024) == (1, 384)
+    assert count([0, 0], 256) == (0, 0)
+    # a block shorter than a sub-block is one product of its own length
+    assert count([3, 0, 9], 48) == (2, 96)
+    # LFM2's decode window: 244 sorted rows over 32 experts in one block of
+    # 256. Every expert's matrix is fetched once either way; a whole block
+    # a tile (the rule before PR 64) multiplies 256 rows for each.
+    sizes = np.bincount(np.random.default_rng(0).integers(0, 32, 244), minlength=32)
+    assert (sizes > 0).all()
+    fetches, product_rows = count(sizes, 256)
+    assert fetches == 32 and product_rows <= 34 * expert_gmm.SUB
+    assert count(sizes, 256, sub=256) == (32, 32 * 256)
+    # 32 groups of 100 rows: no multiple of 128 is a group's first row, so
+    # 24 groups lie across one (two sub-blocks) and 8 inside a sub-block,
+    # whatever the block; a block boundary (12 at 256 rows, 3 at 1,024)
+    # fetches the group across it twice.
+    sizes = np.full(32, 100)
+    assert count(sizes, 256) == (32 + 12, 56 * 128)
+    assert count(sizes, 1024) == (32 + 3, 56 * 128)
+    assert count(sizes, 256, sub=256) == (32 + 12, 44 * 256)
 
 
 def test_tiling_is_a_function_of_shapes_and_k_alone_splits_the_sum():
@@ -184,14 +278,15 @@ def test_tiling_is_a_function_of_shapes_and_k_alone_splits_the_sum():
     assert not expert_gmm.supported(64, 96) and not expert_gmm.supported(4096, 100)
     for m in (16, 128, 2048, 16384):
         tm, tk, tn = expert_gmm.tiles(m, 4096, 3584, 2)
-        assert (tm, tk, tn) == (min(m, 256), 512, 3584)
-        assert expert_gmm.tiles(m, 3584, 4096, 2)[1:] == (512, 4096)
+        # one block where VMEM holds the rows, blocks of 768 at half the columns
+        assert (tm, tk, tn) == ((m, 512, 3584) if m <= 128 else (768, 512, 1792))
+        assert expert_gmm.tiles(m, 3584, 4096, 2)[1:] == (512, 4096 if m <= 128 else 2048)
     # 768 = 6 x 128: the largest divisor under the cap; odd row counts round up.
     assert expert_gmm.tiles(40, 768, 2048, 2) == (48, 384, 2048)
     # a wide unsharded matrix is cut to what VMEM holds
     tm, tk, tn = expert_gmm.tiles(512, 4096, 14336, 2)
     assert 14336 % tn == 0 and tn < 14336
-    blocks = 2 * tm * tk * 2 + 2 * tk * tn * 2 + 2 * tm * tn * 2 + 4 * tm * tn
+    blocks = 2 * tm * 4096 * 2 + 2 * tk * tn * 2 + 2 * tm * tn * 2 + 4 * tm * tn
     assert blocks <= expert_gmm.VMEM_BLOCK_BUDGET
 
 
@@ -199,9 +294,10 @@ def test_tiling_is_a_function_of_shapes_and_k_alone_splits_the_sum():
 MOE_128 = dataclasses.replace(  # widths the kernel can tile: 128 lanes
     TINY_MOE, hidden_size=128, intermediate_size=128, num_heads=4, num_kv_heads=2,
 )
-# A stream of more sorted rows (tokens x experts a token) than one row block
-# of the kernel holds: a prompt chunk's, where a decode window's are fewer.
-MANY_TOKENS = expert_gmm.MAX_TM // MOE_128.num_experts_per_tok + 1
+# A stream of more sorted rows (tokens x experts a token) than a decode
+# window has had (256, one row block until PR 64): a prompt chunk's.
+WINDOW_ROWS = 2 * expert_gmm.SUB
+MANY_TOKENS = WINDOW_ROWS // MOE_128.num_experts_per_tok + 1
 
 
 def _layer_scan(jaxpr):
@@ -562,7 +658,7 @@ async def test_every_dispatch_of_a_routed_model_counts_once_under_how_it_read_it
                 int(re.search(r'nb="(\d+)"', k).group(1))
                 for k in by_variant if 'program="mixed"' in k
             )
-            assert slots * model.num_experts_per_tok > expert_gmm.MAX_TM
+            assert slots * model.num_experts_per_tok > WINDOW_ROWS
         grew = _grew(m0, _counter("dynamo_moe_dispatch_total"))
         assert grew == ({} if label is None else {'{experts="%s"}' % label: dispatches})
     finally:

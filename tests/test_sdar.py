@@ -561,8 +561,11 @@ def test_the_engine_refuses_at_construction():
 def test_tiles_of_the_accepted_shapes_are_pinned_and_many_groups_take_smaller_blocks():
     """Mixtral's (8 experts, [4096, 3584] a chip under --tp 4) and LFM2's
     (32 experts, [2048, 1792]) decode windows are one block of at most 256
-    rows, with or without the group count: exactly what they were. A block
-    pass's 2048 rows over 128 experts take 8 mean groups a block."""
+    rows, with or without the group count. Since PR 64 a tile multiplies
+    its own group's sub-blocks of 128 rows, so "many groups take smaller
+    blocks" no longer holds: a block is as long as VMEM holds (in whole
+    sub-blocks, as few blocks as fit and of one length), a block pass's
+    2048 rows over 128 experts two blocks of 1024."""
     for m in (16, 64, 128, 256):
         for groups in (None, 8):
             assert expert_gmm.tiles(m, 4096, 3584, 2, groups) == (m, 512, 3584)
@@ -571,12 +574,25 @@ def test_tiles_of_the_accepted_shapes_are_pinned_and_many_groups_take_smaller_bl
             assert expert_gmm.tiles(m, 2048, 1792, 2, groups) == (m, 512, 1792)
             assert expert_gmm.tiles(m, 1792, 2048, 2, groups) == (m, 256, 2048)
     assert expert_gmm.tiles(40, 768, 2048, 2) == (48, 384, 2048)
-    # Without a group count a call of many rows is what it was.
-    assert expert_gmm.tiles(2048, 2048, 768, 2)[0] == expert_gmm.MAX_TM
-    assert expert_gmm.tiles(2048, 2048, 768, 2, 128) == (128, 512, 768)
-    assert expert_gmm.tiles(2048, 768, 2048, 2, 128) == (128, 384, 2048)
-    assert expert_gmm.tiles(2048, 4096, 3584, 2, 8)[0] == expert_gmm.MAX_TM
-    assert expert_gmm.tiles(512, 2048, 768, 2, 128)[0] == 32
+    # 244 sorted rows (LFM2's window, its padding rows left out) are one block
+    # of two sub-blocks, ragged: the rows are padded to 256 by nobody.
+    assert expert_gmm.tiles(244, 2048, 1792, 2, 32) == (256, 512, 1792)
+    # Thousands of rows: with or without a group count the longest block VMEM holds.
+    assert expert_gmm.tiles(2048, 2048, 768, 2) == (1024, 512, 768)
+    assert expert_gmm.tiles(2048, 2048, 768, 2, 128) == (1024, 512, 768)
+    assert expert_gmm.tiles(2048, 768, 2048, 2, 128) == (1024, 384, 2048)
+    assert expert_gmm.tiles(512, 2048, 768, 2, 128) == (512, 512, 768)
+    assert expert_gmm.tiles(4096, 2048, 768, 2, 128) == (1408, 512, 768)  # 3 blocks
+    assert expert_gmm.tiles(4096, 2048, 1792, 2, 32) == (1024, 512, 1792)
+    # A Mixtral chip's matrix: half its columns leave room for 768 rows where
+    # all of them leave 384, and 3 + 7 fetches of a matrix (and the rows
+    # twice) are fewer bytes than 6 + 7.
+    assert expert_gmm.tiles(2048, 4096, 3584, 2, 8) == (768, 512, 1792)
+    # A forced block (tests, the tool's sweep): whole sub-blocks that VMEM holds.
+    assert expert_gmm.tiles(2048, 4096, 3584, 2, 8, tm=256) == (256, 512, 3584)
+    for tm in (200, 2048):
+        with pytest.raises(ValueError):
+            expert_gmm.tiles(2048, 4096, 3584, 2, 8, tm=tm)
 
 
 def test_the_block_program_reads_experts_in_place_at_any_row_count():
