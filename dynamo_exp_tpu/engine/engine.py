@@ -119,7 +119,7 @@ from .config import (
     refuse_sliding_pool_tiers,
     refuse_seq_state_tiers,
 )
-from .kv_manager import KvEvent, KvPageManager, SlidingPagePool
+from .kv_manager import KvEvent, KvPageManager, SlidingPagePool, no_part
 from .offload import CopyStream, HostKvPool
 from .scheduler import RemoteKv, Scheduler, SeqState, Sequence, _RaggedRow
 from .tiering import SwapRecord, plan_swap_entries
@@ -492,6 +492,12 @@ class TPUEngine(BlockDecode, AsyncEngine):
         self._part = (
             self.profiler.part if self.profiler is not None else _no_part
         )
+        # The parts of phase ``admit``, opened by name alone: handed to
+        # the scheduler and the page manager, which know no profiler.
+        self._admit_part = (
+            self.profiler.part_opener("admit")
+            if self.profiler is not None else no_part
+        )
         self.flight = FlightRecorder()
         launch_seconds = get_telemetry().launch_seconds
         self._launch_seconds = {
@@ -530,6 +536,7 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # A prefix hit's state: snapshot entry -> the sequence's own entry.
         self._copy_state = jax.jit(seq_state_copy, donate_argnums=(0,))
         self._pub_state_hits = {"restored": 0, "shortened": 0, "not_taken": 0}
+        self._pub_pages_taken = {"free": 0, "evicted": 0}
         self._gather_pages = jax.jit(kv_gather_pages)
         self._inject_pages = jax.jit(kv_inject_pages, donate_argnums=(0, 1))
         # Copy-on-write page copy (docs/prefix_sharing.md): device-to-
@@ -2450,6 +2457,8 @@ class TPUEngine(BlockDecode, AsyncEngine):
         # consume), and none nests. What is left outside is the loop's
         # own overhead: a few branches an iteration.
         phase, part = self._phase, self._part
+        admit_part = self._admit_part
+        admit_seconds = get_telemetry().admit_seconds
         self._outbox_owner = threading.get_ident()
         try:
             while self._running:
@@ -2547,16 +2556,21 @@ class TPUEngine(BlockDecode, AsyncEngine):
                         not self._kv_pressure() and not self._swapped_rows()
                     )
                 if may_admit:
+                    # docs/observability.md "The life of an admission":
+                    # the scheduler and the page manager open the parts
+                    # up to ``bind`` themselves, a try that admits
+                    # nothing too.
                     with phase("admit"):
                         while True:
-                            with part("admit", "schedule"):
-                                admitted = self.sched.admit_next()
+                            t_admit = time.monotonic()
+                            admitted = self.sched.admit_next(admit_part)
                             if admitted is None:
                                 break
-                            with part("admit", "bind"):
+                            with admit_part("bind"):
                                 self._on_admitted(admitted)
-                            with part("admit", "record"):
+                            with admit_part("record"):
                                 self._record_admission(admitted)
+                            admit_seconds.observe(time.monotonic() - t_admit)
                 with phase("kv_admin"):
                     # Plan new prefetches over whatever is STILL waiting
                     # (couldn't admit: slots full or pool pressure) —
@@ -2924,6 +2938,11 @@ class TPUEngine(BlockDecode, AsyncEngine):
                 if delta:
                     tel.seq_state_prefix_hits.labels(outcome).inc(delta)
                     self._pub_state_hits[outcome] = total
+            for source, total in self.kv.pages_taken.items():
+                delta = total - self._pub_pages_taken[source]
+                if delta:
+                    tel.kv_pages_taken.labels(source).inc(delta)
+                    self._pub_pages_taken[source] = total
 
     def _service_leases(self) -> None:
         """Engine-loop-thread lease upkeep: apply queued delivery

@@ -32,6 +32,7 @@ G1/G2 tiers), redesigned for the TPU engine:
 
 from __future__ import annotations
 
+import contextlib
 import time
 import uuid
 from collections import OrderedDict
@@ -45,6 +46,15 @@ if TYPE_CHECKING:
     import numpy as np
 
     from .offload import HostKvPool
+
+
+_NO_PART = contextlib.nullcontext()
+
+
+def no_part(name: str, **stats):
+    """The part-opener of a caller that times nothing (see
+    ``allocate_sequence``): one shared no-op."""
+    return _NO_PART
 
 
 @dataclass
@@ -393,6 +403,9 @@ class KvPageManager:
         # state: taken whole, shortened to a deeper-held boundary, or not
         # taken (pages matched and no boundary held).
         self.state_hits = {"restored": 0, "shortened": 0, "not_taken": 0}
+        # Pages handed out by where they came from: the free list, or a
+        # parked page reclaimed (its registration leaves the index).
+        self.pages_taken = {"free": 0, "evicted": 0}
 
     # ---------------------------------------------------------------- stats
     @property
@@ -514,7 +527,11 @@ class KvPageManager:
         return pages, matched
 
     def allocate_sequence(
-        self, tokens: Sequence[int], max_pages: int, request_id: str = ""
+        self,
+        tokens: Sequence[int],
+        max_pages: int,
+        request_id: str = "",
+        part: Callable = no_part,
     ) -> Allocation | None:
         """Pages for a new sequence: attach the longest device-resident
         (G1) shared prefix — including pages still being filled and a
@@ -526,172 +543,206 @@ class KvPageManager:
 
         Returns an ``Allocation`` or None if the pool can't satisfy the
         request right now (caller re-queues).
+
+        ``part`` is the caller's opener of a named stretch (``name ->
+        context manager``; keywords are the stats of a trace event):
+        the engine loop's, for the parts ``hash``, ``match``, ``take``
+        and ``register`` of its phase ``admit``. A try that returns None
+        has opened ``hash`` and ``match`` all the same.
         """
         ps = self.page_size
         n_tokens = len(tokens)
         need_total = (n_tokens + ps - 1) // ps
         if need_total > max_pages:
             return None  # exceeds per-sequence capacity; caller must reject
-        hashes = compute_block_hashes_for_seq(tokens, ps)
-        state_restore: int | None = None
-        if self.sharing and self.state_slots:
-            # Filled pages only: a snapshot's writer has dispatched every
-            # block before its boundary, so nothing is lost, and a sharer
-            # that had to re-fill an orphan would rewind to a position it
-            # holds no state for. No partial tail for the same reason.
-            matched_pages, matched_hashes = self._match_hashes(
-                hashes, require_filled=True
-            )
-            # The prompt's last token is always computed.
-            reach = min(len(matched_pages), (n_tokens - 1) // ps)
-            keep = self._deepest_state(matched_hashes[:reach])
-            if matched_pages:
-                outcome = (
-                    "restored" if keep == reach
-                    else "shortened" if keep else "not_taken"
+        with part("hash", req=request_id, prompt_tokens=n_tokens):
+            hashes = compute_block_hashes_for_seq(tokens, ps)
+        with part("match"):
+            state_restore: int | None = None
+            if self.sharing and self.state_slots:
+                # Filled pages only: a snapshot's writer has dispatched every
+                # block before its boundary, so nothing is lost, and a sharer
+                # that had to re-fill an orphan would rewind to a position it
+                # holds no state for. No partial tail for the same reason.
+                matched_pages, matched_hashes = self._match_hashes(
+                    hashes, require_filled=True
                 )
-                self.state_hits[outcome] += 1
-            matched_pages, matched_hashes = matched_pages[:keep], matched_hashes[:keep]
-            if keep:
-                self._snap_by_hash.move_to_end(matched_hashes[-1])
-                state_restore = self._snap_by_hash[matched_hashes[-1]]
-        elif self.sharing:
-            matched_pages, matched_hashes = self._match_hashes(hashes)
-        else:
-            matched_pages, matched_hashes = [], []
-        # Extend the match into the host tier — match first (no copies);
-        # pages are fetched only once the allocation is known to succeed,
-        # so a pool-exhausted retry loop never repeats the memcpys.
-        g2_hashes: list[int] = []
-        if self.sharing and self.host_pool is not None:
-            g2_hashes = self.host_pool.match_chain(hashes[len(matched_pages) :])
-        # Extend further into the G3 persistent store (membership only;
-        # bytes are checksum-verified at fetch below) — the path a
-        # returning conversation re-attaches through after a restart.
-        g3_hashes: list[int] = []
-        if self.sharing and self.g3_store is not None:
-            g3_hashes = self.g3_store.match_chain(
-                hashes[len(matched_pages) + len(g2_hashes) :]
+                # The prompt's last token is always computed.
+                reach = min(len(matched_pages), (n_tokens - 1) // ps)
+                keep = self._deepest_state(matched_hashes[:reach])
+                if matched_pages:
+                    outcome = (
+                        "restored" if keep == reach
+                        else "shortened" if keep else "not_taken"
+                    )
+                    self.state_hits[outcome] += 1
+                matched_pages = matched_pages[:keep]
+                matched_hashes = matched_hashes[:keep]
+                if keep:
+                    self._snap_by_hash.move_to_end(matched_hashes[-1])
+                    state_restore = self._snap_by_hash[matched_hashes[-1]]
+            elif self.sharing:
+                matched_pages, matched_hashes = self._match_hashes(hashes)
+            else:
+                matched_pages, matched_hashes = [], []
+            # Extend the match into the host tier — match first (no copies);
+            # pages are fetched only once the allocation is known to succeed,
+            # so a pool-exhausted retry loop never repeats the memcpys.
+            g2_hashes: list[int] = []
+            if self.sharing and self.host_pool is not None:
+                g2_hashes = self.host_pool.match_chain(
+                    hashes[len(matched_pages) :]
+                )
+            # Extend further into the G3 persistent store (membership only;
+            # bytes are checksum-verified at fetch below) — the path a
+            # returning conversation re-attaches through after a restart.
+            g3_hashes: list[int] = []
+            if self.sharing and self.g3_store is not None:
+                g3_hashes = self.g3_store.match_chain(
+                    hashes[len(matched_pages) + len(g2_hashes) :]
+                )
+            # Shared partial tail: the prompt ends inside a block some other
+            # sequence registered — attach that page read-shared; the owner
+            # COWs it before its first divergent (decode) write.
+            shared_tail: tuple[int, int] | None = None
+            tail_tokens = tokens[(n_tokens // ps) * ps :]
+            if (
+                self.sharing
+                and self.partial_tail
+                and not self.state_slots
+                and tail_tokens
+                and not g2_hashes
+                and not g3_hashes
+                and len(matched_pages) == n_tokens // ps
+            ):
+                parent = matched_hashes[-1] if matched_hashes else None
+                hit = self.index.partial_match(parent, tail_tokens)
+                if hit is not None:
+                    tpid = self._by_hash.get(hit[0])
+                    if tpid is not None:
+                        shared_tail = (tpid, hit[1])
+            need_fresh = (
+                need_total - len(matched_pages) - (1 if shared_tail else 0)
             )
-        # Shared partial tail: the prompt ends inside a block some other
-        # sequence registered — attach that page read-shared; the owner
-        # COWs it before its first divergent (decode) write.
-        shared_tail: tuple[int, int] | None = None
-        tail_tokens = tokens[(n_tokens // ps) * ps :]
-        if (
-            self.sharing
-            and self.partial_tail
-            and not self.state_slots
-            and tail_tokens
-            and not g2_hashes
-            and not g3_hashes
-            and len(matched_pages) == n_tokens // ps
-        ):
-            parent = matched_hashes[-1] if matched_hashes else None
-            hit = self.index.partial_match(parent, tail_tokens)
-            if hit is not None:
-                tpid = self._by_hash.get(hit[0])
-                if tpid is not None:
-                    shared_tail = (tpid, hit[1])
-        need_fresh = need_total - len(matched_pages) - (1 if shared_tail else 0)
-        # Matched parked pages are about to leave the reclaimable LRU
-        # (_ref_page below); counting them as takeable here would let
-        # _take_free pop an empty LRU and crash the engine loop.
-        attach = matched_pages + ([shared_tail[0]] if shared_tail else [])
-        parked_matches = sum(
-            1 for pid in attach if self._records[pid].ref_count == 0
-        )
-        if need_fresh > self._available_for_take() - parked_matches:
-            return None
-        # fetch() copies each page out under the pool lock, so a
-        # concurrent LRU eviction can't corrupt it before injection; a
-        # miss (evicted since match) just shortens the restored prefix.
-        host_pages: list[tuple[int, "np.ndarray", "np.ndarray"]] = []
-        for h in g2_hashes:
-            data = self.host_pool.fetch(h)
-            if data is None:
-                break
-            host_pages.append((h, data[0], data[1]))
-        # G3 fetches only extend an UNBROKEN chain (a mid-chain G2
-        # eviction makes the G3 tail unmatchable). Each fetch is
-        # checksum-verified inside the store — a corrupt page
-        # quarantines, returns None, and the restored prefix shortens
-        # (the block re-prefills from the prompt, token-identically).
-        # Verified bytes promote through the host pool so sibling
-        # admissions hit RAM next time.
-        persist_pages: list[tuple[int, "np.ndarray", "np.ndarray"]] = []
-        if len(host_pages) == len(g2_hashes):
-            for h in g3_hashes:
-                data = self.g3_store.fetch(h)
+            # Matched parked pages are about to leave the reclaimable LRU
+            # (_ref_page below); counting them as takeable here would let
+            # _take_free pop an empty LRU and crash the engine loop.
+            attach = matched_pages + ([shared_tail[0]] if shared_tail else [])
+            parked_matches = sum(
+                1 for pid in attach if self._records[pid].ref_count == 0
+            )
+            if need_fresh > self._available_for_take() - parked_matches:
+                return None
+        with part("take"):
+            # fetch() copies each page out under the pool lock, so a
+            # concurrent LRU eviction can't corrupt it before injection; a
+            # miss (evicted since match) just shortens the restored prefix.
+            host_pages: list[tuple[int, "np.ndarray", "np.ndarray"]] = []
+            for h in g2_hashes:
+                data = self.host_pool.fetch(h)
                 if data is None:
                     break
-                persist_pages.append((h, data[0], data[1]))
-                if self.host_pool is not None:
-                    self.host_pool.store(h, data[0], data[1])
-        restore_pages = host_pages + persist_pages
-        for pid in attach:  # commit the reuse
-            self._ref_page(pid)
-        fresh = [self._take_free() for _ in range(need_fresh)]
-        uploads = [
-            (fresh[j], h, k, v) for j, (h, k, v) in enumerate(restore_pages)
-        ]
+                host_pages.append((h, data[0], data[1]))
+            # G3 fetches only extend an UNBROKEN chain (a mid-chain G2
+            # eviction makes the G3 tail unmatchable). Each fetch is
+            # checksum-verified inside the store — a corrupt page
+            # quarantines, returns None, and the restored prefix shortens
+            # (the block re-prefills from the prompt, token-identically).
+            # Verified bytes promote through the host pool so sibling
+            # admissions hit RAM next time.
+            persist_pages: list[tuple[int, "np.ndarray", "np.ndarray"]] = []
+            if len(host_pages) == len(g2_hashes):
+                for h in g3_hashes:
+                    data = self.g3_store.fetch(h)
+                    if data is None:
+                        break
+                    persist_pages.append((h, data[0], data[1]))
+                    if self.host_pool is not None:
+                        self.host_pool.store(h, data[0], data[1])
+            restore_pages = host_pages + persist_pages
+            for pid in attach:  # commit the reuse
+                self._ref_page(pid)
+            fresh = [self._take_free() for _ in range(need_fresh)]
+            uploads = [
+                (fresh[j], h, k, v) for j, (h, k, v) in enumerate(restore_pages)
+            ]
         # Register this sequence's own full prompt pages NOW (pending
         # fill): a same-prefix request admitted next can share them.
         # Upload pages are registered by the scheduler with the chain
         # walk it already does (_register_uploads); pages past the
         # uploads are this request's to compute.
-        if self.sharing:
-            for j in range(len(restore_pages), need_fresh):
-                block_idx = len(matched_pages) + j
-                if (block_idx + 1) * ps > n_tokens:
-                    break  # partial tail block: never registered early
-                h = hashes[block_idx]
-                if h in self._by_hash:
-                    continue  # racing duplicate content: stay private
-                rec = self._records[fresh[j]]
-                rec.seq_hash = h
-                rec.filled = False
-                rec.filler = request_id
-                self._by_hash[h] = fresh[j]
-                block = list(tokens[block_idx * ps : (block_idx + 1) * ps])
-                parent = hashes[block_idx - 1] if block_idx else None
-                self.index.insert(parent, h, tokens=block, payload=fresh[j])
-                if self.event_cb:
-                    self.event_cb(
-                        KvEvent(
-                            "stored", [h], parent_hash=parent,
-                            token_blocks=[block],
-                        )
+        with part("register"):
+            if self.sharing:
+                self._register_prompt_pages(
+                    tokens, hashes, fresh, len(matched_pages),
+                    len(restore_pages), request_id,
+                )
+            self.hits += len(attach) + len(restore_pages)
+            self.misses += need_fresh - len(restore_pages)
+            if self.host_pool is not None:
+                self.offload_hits += len(host_pages)
+                self.offload_misses += need_fresh - len(restore_pages)
+            self.prefix_hits["shared"] += len(attach)
+            self.prefix_hits["restore"] += len(host_pages)
+            self.prefix_hits["persist"] += len(persist_pages)
+            self.prefix_hits["miss"] += need_fresh - len(restore_pages)
+            cached_pages = len(matched_pages) + len(restore_pages)
+            cached = cached_pages * ps + (shared_tail[1] if shared_tail else 0)
+            cached = min(cached, n_tokens - 1)
+            wait_fill = [
+                pid for pid in attach if not self._records[pid].filled
+            ]
+            page_ids = matched_pages + fresh
+            if shared_tail:
+                page_ids = matched_pages + fresh + [shared_tail[0]]
+            self._note_active()
+            return Allocation(
+                page_ids,
+                cached,
+                uploads,
+                hashes,
+                cached_pages=cached_pages,
+                wait_fill=wait_fill,
+                shared_tail=shared_tail,
+                state_restore=state_restore,
+            )
+
+    def _register_prompt_pages(
+        self,
+        tokens: Sequence[int],
+        hashes: list[int],
+        fresh: list[int],
+        first_block: int,
+        skip: int,
+        request_id: str,
+    ) -> None:
+        """Register the full prompt pages among ``fresh[skip:]`` as
+        pending fills of ``request_id``; ``fresh[j]`` is the prompt's
+        block ``first_block + j``."""
+        ps = self.page_size
+        n_tokens = len(tokens)
+        for j in range(skip, len(fresh)):
+            block_idx = first_block + j
+            if (block_idx + 1) * ps > n_tokens:
+                break  # partial tail block: never registered early
+            h = hashes[block_idx]
+            if h in self._by_hash:
+                continue  # racing duplicate content: stay private
+            rec = self._records[fresh[j]]
+            rec.seq_hash = h
+            rec.filled = False
+            rec.filler = request_id
+            self._by_hash[h] = fresh[j]
+            block = list(tokens[block_idx * ps : (block_idx + 1) * ps])
+            parent = hashes[block_idx - 1] if block_idx else None
+            self.index.insert(parent, h, tokens=block, payload=fresh[j])
+            if self.event_cb:
+                self.event_cb(
+                    KvEvent(
+                        "stored", [h], parent_hash=parent,
+                        token_blocks=[block],
                     )
-        self.hits += len(attach) + len(restore_pages)
-        self.misses += need_fresh - len(restore_pages)
-        if self.host_pool is not None:
-            self.offload_hits += len(host_pages)
-            self.offload_misses += need_fresh - len(restore_pages)
-        self.prefix_hits["shared"] += len(attach)
-        self.prefix_hits["restore"] += len(host_pages)
-        self.prefix_hits["persist"] += len(persist_pages)
-        self.prefix_hits["miss"] += need_fresh - len(restore_pages)
-        cached_pages = len(matched_pages) + len(restore_pages)
-        cached = cached_pages * ps + (shared_tail[1] if shared_tail else 0)
-        cached = min(cached, n_tokens - 1)
-        wait_fill = [
-            pid for pid in attach if not self._records[pid].filled
-        ]
-        page_ids = matched_pages + fresh
-        if shared_tail:
-            page_ids = matched_pages + fresh + [shared_tail[0]]
-        self._note_active()
-        return Allocation(
-            page_ids,
-            cached,
-            uploads,
-            hashes,
-            cached_pages=cached_pages,
-            wait_fill=wait_fill,
-            shared_tail=shared_tail,
-            state_restore=state_restore,
-        )
+                )
 
     def allocate_page(self) -> int | None:
         """One fresh page (decode crossing a page boundary)."""
@@ -1098,9 +1149,11 @@ class KvPageManager:
     def _take_free(self) -> int:
         if self._free:
             pid = self._free.pop()
+            self.pages_taken["free"] += 1
         else:
             # Evict the least-recently-used parked page.
             pid, _ = self._reclaimable.popitem(last=False)
+            self.pages_taken["evicted"] += 1
             self._evict(pid)
         rec = self._records[pid]
         rec.ref_count = 1

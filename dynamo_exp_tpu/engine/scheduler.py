@@ -34,7 +34,7 @@ from ..protocols.common import BackendInput, FinishReason
 from ..telemetry import get_telemetry
 from ..tokens import chain_hash, compute_block_hash
 from .config import EngineConfig
-from .kv_manager import KvPageManager
+from .kv_manager import KvPageManager, no_part
 from .tiering import KvFootprintForecast, select_packed_index
 
 
@@ -296,10 +296,17 @@ def select_preemption_victim(candidates, max_preemptions: int):
     return min(eligible, key=lambda s: (s.priority, -s.submitted_at))
 
 
+# What a turn of ``Scheduler.admit_next``'s loop can come to: the
+# ``outcome`` label space of ``dynamo_admit_attempts_total``.
+ADMIT_OUTCOMES = ("admitted", "no_slot", "no_pages", "rejected", "dropped")
+
+
 class Scheduler:
     def __init__(self, cfg: EngineConfig, kv: KvPageManager, flight=None):
         self.cfg = cfg
         self.kv = kv
+        attempts = get_telemetry().admit_attempts
+        self._attempts = {o: attempts.labels(o) for o in ADMIT_OUTCOMES}
         self.waiting: deque[Sequence] = deque()
         self.slots: list[Sequence | None] = [None] * cfg.max_decode_slots
         self.active_count = 0  # PREFILL + ACTIVE (slot holders)
@@ -362,85 +369,106 @@ class Scheduler:
                 return i
         return None
 
-    def admit_next(self) -> Sequence | None:
+    def admit_next(self, part: Callable = no_part) -> Sequence | None:
         """Bind the next admissible waiting sequence to a slot + pages
         and put it in PREFILL state. Returns None if nothing can be
-        admitted right now."""
+        admitted right now.
+
+        ``part`` is the engine loop's opener of the named parts of its
+        phase ``admit`` (``KvPageManager.allocate_sequence``): every
+        turn of the loop below is ``schedule`` up to the allocation,
+        whose parts are the page manager's, then ``bind`` and (uploads
+        only) ``register``; and it counts once, by its outcome, in
+        ``dynamo_admit_attempts_total``."""
+        attempts = self._attempts
         while self.waiting:
-            if self.waiting[0].is_cancelled():
-                seq = self.waiting.popleft()
-                seq.state = SeqState.FINISHED
-                seq.emit([], FinishReason.CANCELLED)
-                continue
-            head = self.waiting[0]
-            if head.deadline_unix and time.time() >= head.deadline_unix:
-                # The engine-loop reap is throttled; never let expired
-                # work slip through admission in between scans.
-                self.waiting.popleft()
-                head.state = SeqState.FINISHED
-                get_telemetry().deadline_exceeded.labels(
-                    "engine_admission"
-                ).inc()
-                head.emit([], FinishReason.ERROR)
-                continue
-            slot = self.free_slot()
-            if slot is None:
-                return None
-            seq = self._pick_admission()
-            ps = self.kv.page_size
-            if (
-                len(seq.prompt) > self.cfg.max_model_len
-                # A prompt needing more pages than the pool *has* can
-                # never be allocated — reject instead of waiting forever.
-                # Reachable from small prompts: a preempted sequence's
-                # continuation prompt is its full generated context,
-                # which can outgrow a pool smaller than max_model_len.
-                or (len(seq.prompt) + ps - 1) // ps > self.kv.num_pages
-                or self.cfg.bucket_for(
-                    min(len(seq.prompt), self.cfg.prefill_chunk)
-                )
-                is None
-            ):
-                self._remove_waiting(seq)
-                seq.state = SeqState.FINISHED
-                seq.emit([], FinishReason.ERROR)
-                continue
+            with part("schedule"):
+                if self.waiting[0].is_cancelled():
+                    seq = self.waiting.popleft()
+                    seq.state = SeqState.FINISHED
+                    seq.emit([], FinishReason.CANCELLED)
+                    attempts["dropped"].inc()
+                    continue
+                head = self.waiting[0]
+                if head.deadline_unix and time.time() >= head.deadline_unix:
+                    # The engine-loop reap is throttled; never let expired
+                    # work slip through admission in between scans.
+                    self.waiting.popleft()
+                    head.state = SeqState.FINISHED
+                    get_telemetry().deadline_exceeded.labels(
+                        "engine_admission"
+                    ).inc()
+                    head.emit([], FinishReason.ERROR)
+                    attempts["dropped"].inc()
+                    continue
+                slot = self.free_slot()
+                if slot is None:
+                    attempts["no_slot"].inc()
+                    return None
+                seq = self._pick_admission()
+                ps = self.kv.page_size
+                if (
+                    len(seq.prompt) > self.cfg.max_model_len
+                    # A prompt needing more pages than the pool *has* can
+                    # never be allocated — reject instead of waiting forever.
+                    # Reachable from small prompts: a preempted sequence's
+                    # continuation prompt is its full generated context,
+                    # which can outgrow a pool smaller than max_model_len.
+                    or (len(seq.prompt) + ps - 1) // ps > self.kv.num_pages
+                    or self.cfg.bucket_for(
+                        min(len(seq.prompt), self.cfg.prefill_chunk)
+                    )
+                    is None
+                ):
+                    self._remove_waiting(seq)
+                    seq.state = SeqState.FINISHED
+                    seq.emit([], FinishReason.ERROR)
+                    attempts["rejected"].inc()
+                    continue
             alloc = self.kv.allocate_sequence(
-                seq.prompt, self.cfg.max_pages_per_seq, seq.request_id
+                seq.prompt, self.cfg.max_pages_per_seq, seq.request_id, part
             )
             if alloc is None:
-                return None  # pool exhausted; retry after some decode frees
-            self._remove_waiting(seq)
-            seq.page_ids, seq.cached_len = alloc.page_ids, alloc.cached_len
-            seq.pending_uploads = alloc.uploads
-            seq.prompt_hashes = alloc.hashes
-            seq.wait_fill = list(alloc.wait_fill)
-            seq.shared_tail_pid = (
-                alloc.shared_tail[0] if alloc.shared_tail else -1
-            )
-            # Registered full pages this sequence resumes its hash chain
-            # after (G1 matches + G2 uploads; never the partial tail —
-            # that page registers under ITS OWN chain once this
-            # sequence's tokens complete it post-COW).
-            seq.hashed_pages = alloc.cached_pages
-            seq.fill_marked = alloc.cached_pages
-            seq.parent_hash = (
-                alloc.hashes[seq.hashed_pages - 1] if seq.hashed_pages else None
-            )
-            self._register_uploads(seq, alloc.hashes)
-            seq.tokens = list(seq.prompt)
-            seq.prefill_sent = seq.cached_len
-            block = self.cfg.model.block_length
-            if block:  # chunks begin and end where blocks do
-                seq.prefill_sent = (
-                    min(seq.cached_len, seq.prefill_target(block)) // block * block
+                # Pool exhausted; retry after some decode frees.
+                attempts["no_pages"].inc()
+                return None
+            with part("bind"):
+                self._remove_waiting(seq)
+                seq.page_ids, seq.cached_len = alloc.page_ids, alloc.cached_len
+                seq.pending_uploads = alloc.uploads
+                seq.prompt_hashes = alloc.hashes
+                seq.wait_fill = list(alloc.wait_fill)
+                seq.shared_tail_pid = (
+                    alloc.shared_tail[0] if alloc.shared_tail else -1
                 )
-            seq.slot = slot
-            seq.state_slot = self.kv.take_state_slot()
-            seq.state_restore = alloc.state_restore
-            seq.state = SeqState.PREFILL
-            self.slots[slot] = seq
-            self.active_count += 1
+                # Registered full pages this sequence resumes its hash chain
+                # after (G1 matches + G2 uploads; never the partial tail —
+                # that page registers under ITS OWN chain once this
+                # sequence's tokens complete it post-COW).
+                seq.hashed_pages = alloc.cached_pages
+                seq.fill_marked = alloc.cached_pages
+                seq.parent_hash = (
+                    alloc.hashes[seq.hashed_pages - 1]
+                    if seq.hashed_pages else None
+                )
+                seq.tokens = list(seq.prompt)
+                seq.prefill_sent = seq.cached_len
+                block = self.cfg.model.block_length
+                if block:  # chunks begin and end where blocks do
+                    seq.prefill_sent = (
+                        min(seq.cached_len, seq.prefill_target(block))
+                        // block * block
+                    )
+                seq.slot = slot
+                seq.state_slot = self.kv.take_state_slot()
+                seq.state_restore = alloc.state_restore
+                seq.state = SeqState.PREFILL
+                self.slots[slot] = seq
+                self.active_count += 1
+            if seq.pending_uploads:
+                with part("register"):
+                    self._register_uploads(seq, alloc.hashes)
+            attempts["admitted"].inc()
             return seq
         return None
 
@@ -488,8 +516,6 @@ class Scheduler:
         see them. Content lands at the inject dispatch (engine
         ``_apply_uploads``), so they register as pending fills — a
         same-prefix admission in between shares them but waits."""
-        if not seq.pending_uploads:
-            return
         ps = self.kv.page_size
         first = seq.hashed_pages - len(seq.pending_uploads)
         parent = hashes[first - 1] if first > 0 else None

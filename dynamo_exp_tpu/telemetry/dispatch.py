@@ -61,7 +61,10 @@ stretch is bracketed by ``part(phase, name)``, which also opens a
 reader takes for a phase, and phases do not nest. Parts that alternate
 row by row are summed by the caller from its own clock reads and handed
 over once, by ``add_parts``. Either way the seconds wait in the profiler
-until the phase's exit books them.
+until the phase's exit books them. The parts of ``admit`` lie in the
+scheduler and the page manager, which take ``part_opener("admit")`` as
+an argument and import nothing from here (docs/observability.md "The
+life of an admission").
 
 **Off the loop thread** (docs/observability.md "Off the loop thread").
 What the device waits behind while the loop sits in ``sync`` is not the
@@ -144,8 +147,16 @@ PHASES = (
 #             finish       sched.finish with everything it calls
 #   launch    h2d          the jnp.asarray transfers of a dispatch's arguments
 #             call         the jitted call until it returns
-#   admit     schedule     sched.admit_next
-#             bind         _on_admitted
+#   admit     schedule     admit_next outside the allocation: dead heads,
+#                          free_slot, _pick_admission, the rejections
+#             hash         the prompt's block hashes
+#             match        the prefix match down the tiers, the partial
+#                          tail, the availability check
+#             take         the tiers' fetches, _ref_page, _take_free with
+#                          the evictions under it
+#             register     the prompt's own pages into the index, and
+#                          _register_uploads
+#             bind         the sequence's fields, its slot, _on_admitted
 #             record       the flight recorder's admission record
 #   assemble  plan         _plan_ragged, _plan_chunks, _can_chain
 #             build        the host arrays of one dispatch
@@ -153,7 +164,7 @@ PHASES = (
 PHASE_PARTS = {
     "consume": ("tokens", "pages", "emit", "first_token", "finish"),
     "launch": ("h2d", "call"),
-    "admit": ("schedule", "bind", "record"),
+    "admit": ("schedule", "hash", "match", "take", "register", "bind", "record"),
     "assemble": ("plan", "build", "lookup"),
 }
 OTHER_PART = "other"
@@ -226,7 +237,7 @@ class _Part:
     :meth:`DispatchProfiler.part`). One object a part, reused, like
     :class:`_Phase`; parts do not nest either."""
 
-    __slots__ = ("_prof", "_open", "_name", "_label", "_ann", "_t0")
+    __slots__ = ("_prof", "_open", "_name", "_label", "_ann", "_t0", "stats")
 
     def __init__(self, prof: "DispatchProfiler", phase: str, name: str):
         self._prof, self._name = prof, name
@@ -234,9 +245,17 @@ class _Part:
         self._label = f"dynpart/{phase}.{name}"
         self._ann = None
         self._t0 = 0.0
+        # Stats of the next event alone (``part_opener``): the
+        # annotation drops them unread while no session is on.
+        self.stats: dict | None = None
 
     def __enter__(self):
-        self._ann = self._prof._annotation(self._label)
+        stats = self.stats
+        if stats:
+            self.stats = None
+            self._ann = self._prof._annotation(self._label, **stats)
+        else:
+            self._ann = self._prof._annotation(self._label)
         self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
@@ -333,6 +352,21 @@ class DispatchProfiler:
         phase's exit and, while a profiler session is on, a
         ``dynpart/<phase>.<name>`` event goes to the trace."""
         return self._parts[phase, name]
+
+    def part_opener(self, phase: str):
+        """``part`` of one ``phase`` as a callable ``name -> context
+        manager``, for code under the loop that is to know nothing of
+        this module (the scheduler, the page manager). Keywords become
+        the stats of that one ``dynpart/`` event, as ``dynstall/``
+        carries ``late_ns``."""
+        parts = {name: self._parts[phase, name] for name in PHASE_PARTS[phase]}
+
+        def open_part(name: str, **stats):
+            part = parts[name]
+            part.stats = stats
+            return part
+
+        return open_part
 
     def add_parts(self, phase: str, **seconds: float) -> None:
         """Seconds of parts that alternate row by row inside an open

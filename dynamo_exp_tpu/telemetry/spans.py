@@ -59,6 +59,10 @@ _DISPATCH_BUCKETS = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 )
+# One admission: a page of bookkeeping to a long prompt's hundreds.
+_ADMIT_BUCKETS = (
+    0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+)
 
 # Engine gauges: metrics()-dict key -> (prometheus name, help).
 # jax/_src/dispatch.py's event names (jax 0.9) to the ``stage`` label.
@@ -938,6 +942,33 @@ class Telemetry:
             "by named part; a phase's parts (other = what the named "
             "ones leave) sum to its dynamo_engine_phase_seconds_total",
             ["phase", "part"],  # telemetry/dispatch.py PHASE_PARTS
+            registry=self.registry,
+        )
+        # The life of an admission (docs/observability.md): counted at
+        # the boundaries the parts of phase ``admit`` are timed at.
+        self.admit_attempts = Counter(
+            "dynamo_admit_attempts_total",
+            "Turns of Scheduler.admit_next's loop by what they came to: "
+            "admitted, no_slot (every decode slot taken), no_pages (the "
+            "pool could not hold the prompt: it was hashed and matched "
+            "for nothing), rejected (can never be served), dropped (a "
+            "cancelled or expired head)",
+            ["outcome"],  # engine/scheduler.py ADMIT_OUTCOMES
+            registry=self.registry,
+        )
+        self.admit_seconds = Histogram(
+            "dynamo_admit_seconds",
+            "One admitted sequence, from the entry of the admit_next "
+            "call that admitted it to the end of its flight record",
+            buckets=_ADMIT_BUCKETS,
+            registry=self.registry,
+        )
+        self.kv_pages_taken = Counter(
+            "dynamo_kv_pages_taken_total",
+            "Device pages handed out by the page manager, by source: "
+            "free (the free list) or evicted (a parked page reclaimed, "
+            "its registration dropped from the prefix index)",
+            ["source"],  # free | evicted
             registry=self.registry,
         )
         self.emit_handoff = Histogram(

@@ -5,6 +5,7 @@ record of every page with how it was obtained and which dispatch wrote
 it. All host counts: nothing here reads the device."""
 
 import asyncio
+import time
 
 import pytest
 
@@ -51,6 +52,19 @@ def _delta(before: dict, after: dict) -> dict:
     return {k: v - before.get(k, 0.0) for k, v in after.items() if v != before.get(k, 0.0)}
 
 
+async def _counted(name: str, before: dict, dispatches: int) -> dict:
+    """What ``name`` grew by since ``before``, read once the asking's
+    ``dispatches`` are all counted (a dispatch counts when it is
+    launched, on the loop's thread): a bounded poll, not a sleep that a
+    busy machine outlasts."""
+    deadline = time.monotonic() + 10.0
+    while True:
+        grew = _delta(before, _samples(name))
+        if sum(grew.values()) >= dispatches or time.monotonic() >= deadline:
+            return grew
+        await asyncio.sleep(0.01)
+
+
 async def test_dispatches_are_counted_by_variant_key_and_a_routed_models_rows_by_kind():
     engine = _engine(TINY_MOE)
     engine.start()
@@ -59,8 +73,7 @@ async def test_dispatches_are_counted_by_variant_key_and_a_routed_models_rows_by
         m0 = _samples("dynamo_moe_rows_total")
         prompt = list(range(5, 5 + 21))  # two full pages and a tail of five
         first = await _ask(engine, prompt, 9)
-        await asyncio.sleep(0.1)
-        d1 = _delta(d0, _samples("dynamo_ragged_dispatch_total"))
+        d1 = await _counted("dynamo_ragged_dispatch_total", d0, 2)
         m1 = _delta(m0, _samples("dynamo_moe_rows_total"))
         mixed = {k: v for k, v in d1.items() if 'program="mixed"' in k}
         window = {k: v for k, v in d1.items() if 'program="window"' in k}
@@ -74,8 +87,7 @@ async def test_dispatches_are_counted_by_variant_key_and_a_routed_models_rows_by
         # The cached asking: one live token and 15 padding rows in its prefill.
         m2 = _samples("dynamo_moe_rows_total")
         again = await _ask(engine, prompt, 9)
-        await asyncio.sleep(0.1)
-        m3 = _delta(m2, _samples("dynamo_moe_rows_total"))
+        m3 = await _counted("dynamo_moe_rows_total", m2, 1 + 8 + 15)
         assert again == first
         assert m3[('kind="live"',)] == 1 + 8 and m3[('kind="pad"',)] == 15
     finally:
@@ -92,9 +104,8 @@ async def test_every_models_slots_are_counted_by_program_and_kind(model):
         s0 = _samples("dynamo_ragged_slots_total")
         d0 = _samples("dynamo_ragged_dispatch_total")
         await _ask(engine, list(range(5, 5 + 21)), 9)
-        await asyncio.sleep(0.1)
+        d1 = await _counted("dynamo_ragged_dispatch_total", d0, 2)
         s1 = _delta(s0, _samples("dynamo_ragged_slots_total"))
-        d1 = _delta(d0, _samples("dynamo_ragged_dispatch_total"))
         # One prefill of 21 tokens at the 32 bucket; 8 more tokens from
         # one window of 8 steps at 1 row.
         assert s1 == {
